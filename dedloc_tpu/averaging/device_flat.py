@@ -104,7 +104,7 @@ def _build_prepare(order, total, chunk, compression, use_ef, use_clip):
 
     bounds = _chunk_bounds(total, chunk)
 
-    def prepare(leaves, n, cap, residual):
+    def grad_flat_prepare(leaves, n, cap, residual):
         by_spec = [None] * len(leaves)
         for leaf, pos in zip(leaves, order):
             by_spec[pos] = leaf.astype(jnp.float32).reshape(-1)
@@ -157,7 +157,7 @@ def _build_prepare(order, total, chunk, compression, use_ef, use_clip):
             return wire, (lo, scale), new_residual
         raise ValueError(f"unknown compression {compression!r}")
 
-    fn = jax.jit(prepare)
+    fn = jax.jit(grad_flat_prepare)
     _PREPARE_CACHE[key] = fn
     return fn
 

@@ -109,9 +109,9 @@ def run_ncc(
 
 
 def main(argv=None) -> None:
-    from dedloc_tpu.roles.common import force_cpu_if_requested
+    from dedloc_tpu.utils.backend import ensure_compile_cache
 
-    force_cpu_if_requested()
+    ensure_compile_cache()
     args = parse_config(NccArguments, argv)
     train_examples, eval_examples = load_split_examples(
         args.dataset_name, args.dataset_config_name

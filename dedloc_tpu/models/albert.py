@@ -62,13 +62,15 @@ class AlbertConfig:
     # "flash" (the same math as ONE fused Pallas kernel with a custom-VJP
     # backward: scores never leave VMEM; interpret-mode off TPU), or "ring"
     # (sequence-parallel exact attention: KV shards rotate around the mesh's
-    # ``ring_axis`` via ppermute — requires ``ring_mesh``). All exact.
+    # ``ring_axis`` via ppermute — requires ``mesh``). All exact.
     attention_impl: str = "dense"
     attention_block_size: int = 512
-    # sequence-parallel context for attention_impl="ring": the mesh whose
-    # ``ring_axis`` the sequence dimension is sharded over (set by the
-    # trainer when --training.mesh_seq_devices > 1)
-    ring_mesh: Any = None
+    # the slice mesh the model's jit spans (set by the trainer whenever
+    # --training.mesh_devices > 1). The ops that GSPMD cannot partition open
+    # their own shard_map over it: the Pallas kernels (flash attention,
+    # fused add+LN — batch over "data", heads over "model") and ring
+    # attention (the sequence over ``ring_axis``).
+    mesh: Any = None
     ring_axis: str = "seq"
     # pipeline parallelism (--training.mesh_pipe_devices): the mesh whose
     # ``pipe_axis`` the encoder's layer iterations are staged over — ALBERT's
@@ -156,7 +158,8 @@ class AddLayerNorm(nn.Module):
 
         if cfg.fused_ln:
             return ln_residual(
-                x, residual, scale, bias, eps=cfg.layer_norm_eps
+                x, residual, scale, bias, eps=cfg.layer_norm_eps,
+                mesh=cfg.mesh,
             ).astype(cfg.dtype)
         return ln_residual_reference(
             x.astype(jnp.float32), residual.astype(jnp.float32),
@@ -205,23 +208,24 @@ class AlbertSelfAttention(nn.Module):
                 q, k, v, kv_bias,
                 block_q=cfg.attention_block_size,
                 block_k=cfg.attention_block_size,
+                mesh=cfg.mesh,
             ).reshape(B, S, H)
         elif cfg.attention_impl == "ring":
-            # sequence parallelism: S is sharded over ring_mesh's ring_axis;
+            # sequence parallelism: S is sharded over the mesh's ring_axis;
             # each device keeps its resident queries and rotates KV shards
             # around the ring (ppermute over ICI) — exact, never materializes
             # the S×S score matrix on any one device
             from dedloc_tpu.parallel.ring_attention import ring_attention
 
-            if cfg.ring_mesh is None:
+            if cfg.mesh is None:
                 raise ValueError(
-                    "attention_impl='ring' needs ring_mesh (a Mesh with a "
+                    "attention_impl='ring' needs mesh (a Mesh with a "
                     f"{cfg.ring_axis!r} axis); the trainer sets it when "
                     "--training.mesh_seq_devices > 1"
                 )
             kv_bias = attn_bias[:, 0, 0, :]  # additive [B, S_kv]
             ctx = ring_attention(
-                q, k, v, kv_bias, mesh=cfg.ring_mesh, axis=cfg.ring_axis
+                q, k, v, kv_bias, mesh=cfg.mesh, axis=cfg.ring_axis
             ).reshape(B, S, H)
         elif cfg.attention_impl == "blockwise":
             # long-context path: exact online-softmax over KV blocks — never
@@ -502,7 +506,11 @@ class AlbertEncoder(nn.Module):
         iters = cfg.num_hidden_layers // n_stages
         B, S, H = hidden.shape
         M = cfg.pipe_microbatches or 2 * n_stages
-        layer = AlbertLayer(cfg, self.deterministic)
+        # the stage body already runs per device inside the pipeline's
+        # shard_map: its kernels must not open a second one
+        layer = AlbertLayer(
+            dataclasses.replace(cfg, mesh=None), self.deterministic
+        )
         proto_x = jnp.zeros((max(1, B // M), S, H), hidden.dtype)
         proto_b = jnp.zeros(
             (max(1, B // M),) + attn_bias.shape[1:], attn_bias.dtype

@@ -4,67 +4,93 @@ The reference keeps its wire hot loops in native dependency code (protobuf/
 grpc C++ wheels, NCCL — SURVEY.md §2.7); this package is the TPU build's
 in-tree equivalent (native/wirecodec.cpp). The .so is compiled lazily with
 g++ on first import (no pybind11 in the image, so plain `extern "C"` +
-ctypes); every entry point has a numpy fallback so the framework works on
-machines without a toolchain. `AVAILABLE` reports which path is active.
+ctypes) and named by a hash of its source, so a binary built from any other
+``wirecodec.cpp`` is never loaded — a tree copy keeps no mtimes to judge
+staleness by. Every entry point has a numpy fallback so the framework works
+on machines without a toolchain; a failed build says so in the log, and
+`AVAILABLE` reports which path is active.
 """
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 from typing import Optional, Tuple
 
 import numpy as np
 
+from dedloc_tpu.utils.logging import get_logger
+
+logger = get_logger(__name__)
+
 _HERE = os.path.dirname(os.path.abspath(__file__))
-_SO_PATH = os.path.join(_HERE, "_wirecodec.so")
-_SRC_DIR = os.path.join(os.path.dirname(os.path.dirname(_HERE)), "native")
+_SRC = os.path.join(
+    os.path.dirname(os.path.dirname(_HERE)), "native", "wirecodec.cpp"
+)
 
 _lib: Optional[ctypes.CDLL] = None
 
 
-def _build() -> bool:
-    src = os.path.join(_SRC_DIR, "wirecodec.cpp")
-    if not os.path.exists(src):
-        return False
+def _so_path() -> Optional[str]:
+    """Binary path for the source as it stands, or None without a source."""
+    try:
+        with open(_SRC, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    except OSError:
+        return None
+    return os.path.join(_HERE, f"_wirecodec-{digest}.so")
+
+
+def _build(so_path: str) -> bool:
     # build to a per-pid temp path and rename into place: concurrent
     # importers must never CDLL a half-written .so
-    tmp = f"{_SO_PATH}.{os.getpid()}.tmp"
+    tmp = f"{so_path}.{os.getpid()}.tmp"
     try:
         subprocess.run(
             [
                 "g++", "-O3", "-fPIC", "-std=c++17", "-shared",
-                src, "-o", tmp,
+                _SRC, "-o", tmp,
             ],
             check=True,
             capture_output=True,
             timeout=120,
         )
-        os.replace(tmp, _SO_PATH)
-        return True
-    except (OSError, subprocess.SubprocessError):
+        os.replace(tmp, so_path)
+    except (OSError, subprocess.SubprocessError) as e:
+        detail = getattr(e, "stderr", b"") or b""
+        logger.warning(
+            f"native wire codec build failed ({e!r}); using the numpy "
+            f"fallback. {detail.decode(errors='replace')[-500:]}"
+        )
         try:
             os.unlink(tmp)
         except OSError:
             pass
         return False
-
-
-def _stale() -> bool:
-    src = os.path.join(_SRC_DIR, "wirecodec.cpp")
-    try:
-        return os.path.getmtime(src) > os.path.getmtime(_SO_PATH)
-    except OSError:
-        return False
+    for old in glob.glob(os.path.join(_HERE, "_wirecodec*.so")):
+        if old != so_path:  # binaries of earlier sources
+            try:
+                os.unlink(old)
+            except OSError:
+                pass
+    return True
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    if (not os.path.exists(_SO_PATH) or _stale()) and not _build():
-        if not os.path.exists(_SO_PATH):
-            return None
+    so_path = _so_path()
+    if so_path is None:
+        return None
+    if not os.path.exists(so_path) and not _build(so_path):
+        return None
     try:
-        lib = ctypes.CDLL(_SO_PATH)
-    except OSError:
+        lib = ctypes.CDLL(so_path)
+    except OSError as e:
+        logger.warning(
+            f"native wire codec failed to load ({e!r}); using the numpy "
+            "fallback"
+        )
         return None
     i64, f32p = ctypes.c_int64, ctypes.POINTER(ctypes.c_float)
     u8p = ctypes.POINTER(ctypes.c_uint8)

@@ -28,7 +28,13 @@ them. The additive bias is per KV position (0 keep / -inf drop), broadcast
 over heads — exactly the mask bias AlbertModel builds; it is
 non-differentiable (it comes from the attention mask).
 
-Off-TPU (CPU tests, CI) the same kernels run under ``interpret=True``.
+Off-TPU (CPU tests, CI) the same kernels run under ``interpret=True``
+(``utils.backend.pallas_interpret`` decides, once, for every op here).
+
+On a multi-device mesh a Mosaic kernel cannot be partitioned by GSPMD, so
+``flash_attention(..., mesh=...)`` runs the custom-VJP op under
+``jax.shard_map``: batch over "data", heads over "model" where the mesh has
+that axis, the sequence whole on every device.
 """
 from __future__ import annotations
 
@@ -37,10 +43,19 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import Mesh, PartitionSpec as P
+
+from dedloc_tpu.utils.backend import pallas_interpret
 
 NEG_INF = -1e30
+
+
+def mesh_axis(mesh: Mesh, name: str) -> Optional[str]:
+    """``name`` if the mesh has that axis (shard over it), else None."""
+    return name if name in mesh.axis_names else None
 
 
 def _pick_block(s: int, preferred: int) -> int:
@@ -163,6 +178,7 @@ def _fwd(q3, k3, v3, bias3, block_q, block_k, interpret):
             pltpu.VMEM((gh, bq, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q3, k3, v3, bias3)
     return out, lse
 
@@ -322,6 +338,7 @@ def _bwd(q3, k3, v3, bias3, lse, do, delta, block_q, block_k, interpret):
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q3.dtype),
         scratch_shapes=[pltpu.VMEM((gh, bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q3, k3, v3, bias3, lse, do, delta)
 
     dk, dv = pl.pallas_call(
@@ -349,6 +366,7 @@ def _bwd(q3, k3, v3, bias3, lse, do, delta, block_q, block_k, interpret):
             pltpu.VMEM((gh, bk, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q3, k3, v3, bias3, lse, do, delta)
     return dq, dk, dv
 
@@ -381,6 +399,7 @@ def _bwd_fused(q3, k3, v3, bias3, lse, do, delta, interpret):
             jax.ShapeDtypeStruct((bh, s, d), v3.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_fused",
     )(q3, k3, v3, bias3, lse, do, delta)
     return dq, dk, dv
 
@@ -428,6 +447,25 @@ def _flash_bwd(block_q, block_k, interpret, residuals, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _flash_bshd(q, k, v, bias, block_q, block_k, interpret):
+    """The op on [B, S, H, D] operands as ONE device sees them (the whole
+    arrays off-mesh, this device's batch/head shard under shard_map)."""
+    b, s, h, d = q.shape
+    # named in KERNEL layout so the fused_ln remat policy saves exactly what
+    # the flash backward consumes — the replay then skips the [B,S,H,D] ->
+    # [BH,S,D] relayout passes too
+    to3 = lambda x, nm: checkpoint_name(
+        x.transpose(0, 2, 1, 3).reshape(b * h, s, d), nm
+    )
+    bias3 = jnp.broadcast_to(
+        bias[:, None, :], (b, h, s)
+    ).reshape(b * h, 1, s).astype(jnp.float32)
+    out3 = _flash(to3(q, "flash_qkv"), to3(k, "flash_qkv"),
+                  to3(v, "flash_qkv"), bias3, block_q, block_k, interpret)
+    out3 = _unpack_heads(out3, b * h, d)  # paired layout -> [BH, S, D]
+    return out3.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+
+
 def flash_attention(
     q: jnp.ndarray,  # [B, S, H, D]
     k: jnp.ndarray,
@@ -436,32 +474,30 @@ def flash_attention(
     block_q: int = 512,
     block_k: int = 512,
     interpret: Optional[bool] = None,
+    mesh: Optional[Mesh] = None,
 ) -> jnp.ndarray:
     """Exact fused attention; drop-in for dense/blockwise attention.
 
-    ``interpret=None`` auto-selects: compiled on TPU, interpreter elsewhere
-    (so CPU tests and the virtual mesh exercise identical kernel code).
-    On TPU, effective block sizes must be multiples of 128 (or the whole
-    sequence) for the bias/lse BlockSpecs to be Mosaic-legal.
+    ``interpret=None`` takes ``pallas_interpret()``: compiled on TPU,
+    interpreter elsewhere (so CPU tests and the virtual mesh exercise
+    identical kernel code). On TPU, effective block sizes must be multiples
+    of 128 (or the whole sequence) for the bias/lse BlockSpecs to be
+    Mosaic-legal. ``mesh``: the device mesh the caller's jit spans — the op
+    then runs per shard under ``shard_map`` (see module docstring).
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    b, s, h, d = q.shape
-    # named in KERNEL layout so the fused_ln remat policy saves exactly what
-    # the flash backward consumes — the replay then skips the [B,S,H,D] ->
-    # [BH,S,D] relayout passes too
-    from jax.ad_checkpoint import checkpoint_name
-
-    to3 = lambda x, nm: checkpoint_name(
-        x.transpose(0, 2, 1, 3).reshape(b * h, s, d), nm
-    )
+        interpret = pallas_interpret()
     if bias is None:
-        bias3 = jnp.zeros((b * h, 1, s), jnp.float32)
-    else:
-        bias3 = jnp.broadcast_to(
-            bias[:, None, :], (b, h, s)
-        ).reshape(b * h, 1, s).astype(jnp.float32)
-    out3 = _flash(to3(q, "flash_qkv"), to3(k, "flash_qkv"),
-                  to3(v, "flash_qkv"), bias3, block_q, block_k, interpret)
-    out3 = _unpack_heads(out3, b * h, d)  # paired layout -> [BH, S, D]
-    return out3.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+        bias = jnp.zeros((k.shape[0], k.shape[1]), jnp.float32)
+    op = functools.partial(
+        _flash_bshd, block_q=block_q, block_k=block_k, interpret=interpret
+    )
+    if mesh is not None:
+        qkv = P(mesh_axis(mesh, "data"), None, mesh_axis(mesh, "model"), None)
+        # check_vma=False: pallas_call outputs carry no varying-axes type
+        op = jax.shard_map(
+            op, mesh=mesh,
+            in_specs=(qkv, qkv, qkv, P(mesh_axis(mesh, "data"), None)),
+            out_specs=qkv, check_vma=False,
+        )
+    return op(q, k, v, bias)

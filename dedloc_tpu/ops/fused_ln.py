@@ -32,17 +32,24 @@ dtype (bf16) — the same precision the unfused path's backward sees, since
 its replay also recomputes statistics from bf16 activations.
 
 Off-TPU the kernels run under ``interpret=True`` (CPU tests, virtual mesh).
+
+On a multi-device mesh ``ln_residual(..., mesh=...)`` runs the custom-VJP op
+under ``jax.shard_map`` (GSPMD cannot partition a Mosaic kernel): rows split
+over "data" (and "seq"), γ/β replicated — so the shard_map transpose psums
+the per-shard dγ/dβ partial sums.
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.sharding import Mesh, PartitionSpec as P
 
-from dedloc_tpu.ops.flash_attention import _pick_block
+from dedloc_tpu.ops.flash_attention import _pick_block, mesh_axis
+from dedloc_tpu.utils.backend import pallas_interpret
 
 
 def _t(x):
@@ -100,6 +107,7 @@ def _fwd(x2, r2, gamma, beta, eps, block_n, interpret, with_residuals=True):
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="ln_residual_fwd",
     )(x2, r2, gamma[None, :], beta[None, :])
     return outs if with_residuals else (outs[0], None, None)
 
@@ -155,6 +163,7 @@ def _bwd(xhat, rstd, gamma, dy, block_n, interpret):
             jax.ShapeDtypeStruct((1, h), jnp.float32),
         ],
         interpret=interpret,
+        name="ln_residual_bwd",
     )(xhat, rstd, gamma[None, :], dy)
     return da, dgamma[0], dbeta[0]
 
@@ -204,29 +213,46 @@ def ln_residual(
     eps: float = 1e-12,
     block_n: int | None = None,
     interpret: bool | None = None,
+    mesh: Optional[Mesh] = None,
 ) -> jnp.ndarray:
     """``LayerNorm(x + r) * gamma + beta`` as one fused pass (fp32 stats),
-    returned in ``x.dtype``. ``interpret=None`` auto-selects: compiled on
-    TPU, interpreter elsewhere."""
+    returned in ``x.dtype``. ``interpret=None`` takes ``pallas_interpret()``:
+    compiled on TPU, interpreter elsewhere. ``mesh``: the device mesh the
+    caller's jit spans — the op then runs per row shard under ``shard_map``
+    (see module docstring). The row count each device sees must split into
+    blocks of a multiple of 8 rows (or be one block); the TPU lowering
+    refuses anything else."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     if block_n is None:
         block_n = _default_block_n()
-    h = x.shape[-1]
-    lead = x.shape[:-1]
-    x2 = x.reshape(-1, h)
-    r2 = r.reshape(-1, h)
-    y = _ln_residual(
-        x2, r2,
-        gamma.astype(jnp.float32), beta.astype(jnp.float32),
-        float(eps), block_n, interpret,
-    )
-    return y.reshape(*lead, h)
+
+    def op(x, r, gamma, beta):
+        h = x.shape[-1]
+        y = _ln_residual(
+            x.reshape(-1, h), r.reshape(-1, h),
+            gamma.astype(jnp.float32), beta.astype(jnp.float32),
+            float(eps), block_n, interpret,
+        )
+        return y.reshape(x.shape)
+
+    if mesh is not None:
+        # [B, S, H]: batch over "data", sequence over "seq"; [N, H]: rows
+        # over "data"; H whole
+        rows = P(
+            *(mesh_axis(mesh, "data"), mesh_axis(mesh, "seq"))[: x.ndim - 1]
+        )
+        # check_vma=False: pallas_call outputs carry no varying-axes type
+        op = jax.shard_map(
+            op, mesh=mesh, in_specs=(rows, rows, P(), P()), out_specs=rows,
+            check_vma=False,
+        )
+    return op(x, r, gamma, beta)
 
 
 def ln_residual_reference(x, r, gamma, beta, eps: float = 1e-12):
-    """Pure-jnp twin of ``ln_residual`` (numerics oracle for tests, and the
-    fallback for shapes the TPU kernel does not serve)."""
+    """Pure-jnp twin of ``ln_residual``: the numerics oracle for tests and
+    the unfused model path (``cfg.fused_ln=False``)."""
     a = x.astype(jnp.float32) + r.astype(jnp.float32)
     mu = jnp.mean(a, axis=-1, keepdims=True)
     centred = a - mu

@@ -68,9 +68,21 @@ def expand_segments(
     per_segment: jnp.ndarray, spans, total: int
 ) -> jnp.ndarray:
     """Broadcast a [num_segments] vector back to the flat [total] buffer
-    (inverse of a segment reduction)."""
-    sizes = jnp.asarray([s for _o, s in spans], jnp.int32)
-    return jnp.repeat(per_segment, sizes, total_repeat_length=total)
+    (inverse of a segment reduction): one broadcast per contiguous span,
+    concatenated. The spans are static, so this must NOT go through
+    ``jnp.repeat``: its index arithmetic (a cumsum over ``total`` elements)
+    is all-constant, and XLA constant-folds it at compile time — 145 s on
+    the CPU and 1,357 s on a v5e for ALBERT-large's 17.8M-element buffer
+    (PR 21 chip run)."""
+    assert sum(s for _o, s in spans) == total, (spans, total)
+    parts = [
+        jnp.broadcast_to(per_segment[i], (s,))
+        for i, (_o, s) in enumerate(spans) if s
+    ]
+    return (
+        jnp.concatenate(parts) if parts
+        else jnp.zeros((0,), per_segment.dtype)
+    )
 
 
 class FlatLamb:

@@ -32,22 +32,8 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map
-
-    _SHARD_MAP_KWARGS = {}
-    _pcast = jax.lax.pcast
-except (ImportError, AttributeError):
-    # older jax (< 0.4.5x): shard_map lives under experimental and has no
-    # varying-manual-axes type system (lax.pcast) — disable its replication
-    # checker instead, which is what the pcast annotation exists to satisfy
-    from jax.experimental.shard_map import shard_map
-
-    _SHARD_MAP_KWARGS = {"check_rep": False}
-
-    def _pcast(x, axes, to=None):
-        return x
 
 
 def stage_param_sharding(mesh: Mesh, axis: str = "pipe") -> NamedSharding:
@@ -156,7 +142,7 @@ def pipeline_apply(
         # activation) while the zeros literal is replicated — mark it so
         # the scan's carry type is stable under shard_map's VMA checks
         buf0 = tmap(
-            lambda m: _pcast(
+            lambda m: jax.lax.pcast(
                 jnp.zeros_like(m[0]), (axis,), to="varying"
             ),
             micro,
@@ -169,7 +155,6 @@ def pipeline_apply(
 
     return shard_map(
         pipelined, mesh=mesh, in_specs=in_specs, out_specs=out_spec,
-        **_SHARD_MAP_KWARGS,
     )(stage_params, microbatches)
 
 

@@ -25,12 +25,8 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax (< 0.4.5x) exposes it under experimental
-    from jax.experimental.shard_map import shard_map
 
 NEG_INF = -1e30
 

@@ -76,7 +76,9 @@ def make_accumulate_step(
     O(S/n) memory win, not just the score matrix's.
     """
 
-    def step(params, grad_acc, n_acc, batch, rng):
+    # jitted programs carry stable names: a trace, an IR dump or a compile
+    # event finds "accumulate_step" after any refactor
+    def accumulate_step(params, grad_acc, n_acc, batch, rng):
         if mesh is not None and seq_axis is not None:
             def _constrain(x):
                 if x.ndim >= 2 and seq_length and x.shape[1] == seq_length:
@@ -111,7 +113,7 @@ def make_accumulate_step(
             in_shardings=(p_sh, p_sh, repl, data, repl),
             out_shardings=(p_sh, repl, repl),
         )
-    return jax.jit(step, **kwargs)
+    return jax.jit(accumulate_step, **kwargs)
 
 
 def make_apply_step(
@@ -129,7 +131,7 @@ def make_apply_step(
     whatever movement the elementwise update needs.
     """
 
-    def apply(state: TrainState, grads) -> TrainState:
+    def apply_step(state: TrainState, grads) -> TrainState:
         updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
         new_params = optax.apply_updates(state.params, updates)
         return state.replace(
@@ -151,7 +153,7 @@ def make_apply_step(
             )
         else:
             kwargs.update(in_shardings=(repl, repl), out_shardings=repl)
-    return jax.jit(apply, **kwargs)
+    return jax.jit(apply_step, **kwargs)
 
 
 def _all_finite(tree) -> jnp.ndarray:
@@ -186,7 +188,7 @@ def make_guarded_apply_step(
     that produces non-finite params also rolls back).
     """
 
-    def apply(state: TrainState, grads):
+    def guarded_apply_step(state: TrainState, grads):
         updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
         new_params = optax.apply_updates(state.params, updates)
         new_state = state.replace(
@@ -226,7 +228,7 @@ def make_guarded_apply_step(
             )
         else:
             kwargs.update(in_shardings=(repl, repl), out_shardings=(repl, repl))
-    return jax.jit(apply, **kwargs)
+    return jax.jit(guarded_apply_step, **kwargs)
 
 
 def _replace_opt_states(state, replacements):
@@ -315,13 +317,22 @@ def make_flat_apply_step(
     def _unflatten_like(flat, template, order):
         leaves, treedef = jax.tree_util.tree_flatten(template)
         offsets = np.cumsum([0] + sizes)
-        out = []
-        for leaf, pos in zip(leaves, order):
-            chunk = flat[offsets[pos]:offsets[pos] + sizes[pos]]
-            out.append(chunk.reshape(leaf.shape).astype(leaf.dtype))
+        # the barrier keeps XLA from fusing each 1-D slice with its reshape:
+        # left to itself, XLA:TPU (libtpu 0.0.34) spends ~20 minutes
+        # compiling the 32 slice+reshape pairs of ALBERT-large's 17.8M
+        # buffer (any subset that holds the 1024x4096, 4096x1024 and 1024x2
+        # leaves together does it); barred, the same program compiles in
+        # seconds, for one extra copy of each leaf per global step
+        chunks = jax.lax.optimization_barrier([
+            flat[offsets[pos]:offsets[pos] + sizes[pos]] for pos in order
+        ])
+        out = [
+            chunk.reshape(leaf.shape).astype(leaf.dtype)
+            for chunk, leaf in zip(chunks, leaves)
+        ]
         return jax.tree_util.tree_unflatten(treedef, out)
 
-    def apply(state: TrainState, grads):
+    def flat_apply_step(state: TrainState, grads):
         order, _ = _tree_order(state.params)
         flat_grads = _flatten(grads, order) if from_tree else grads
         flat_params = _flatten(state.params, order)
@@ -393,7 +404,7 @@ def make_flat_apply_step(
     # successors in-place). The incoming gradient buffer/tree is consumed
     # by the relayout but has no same-shaped output to alias — declaring
     # it donated would only emit the unusable-donation warning.
-    return jax.jit(apply, donate_argnums=(0,))
+    return jax.jit(flat_apply_step, donate_argnums=(0,))
 
 
 def make_local_train_step(

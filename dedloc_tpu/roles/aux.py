@@ -24,9 +24,9 @@ from dedloc_tpu.roles.common import (
     build_dht,
     build_model,
     build_optimizer,
-    force_cpu_if_requested,
     single_device_attention_impl,
 )
+from dedloc_tpu.utils.backend import ensure_compile_cache, pin_cpu
 from dedloc_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -64,7 +64,8 @@ def run_aux(
     (run_aux.py:243-263 capability: the aux learns the model from the
     collaboration, not from the caller); the local model config is only the
     fallback while nobody shares state yet."""
-    force_cpu_if_requested()
+    pin_cpu()  # donates bandwidth, never computes on the chip
+    ensure_compile_cache()
     # gated runs: aux peers need envelopes too (leaders reject unsigned
     # joins; gated joiners reject unsigned leader replies)
     authorizer, authority_public_key = build_authorizer(args)

@@ -24,7 +24,8 @@ from dedloc_tpu.averaging.topology import TopologyPlan, plan_topology
 from dedloc_tpu.collaborative.metrics import aggregate_metrics, fetch_metrics
 from dedloc_tpu.core.config import CollaborationArguments, parse_config
 from dedloc_tpu.core.timeutils import get_dht_time
-from dedloc_tpu.roles.common import build_dht, force_cpu_if_requested
+from dedloc_tpu.roles.common import build_dht
+from dedloc_tpu.utils.backend import ensure_compile_cache, pin_cpu
 from dedloc_tpu.telemetry import build_swarm_health
 from dedloc_tpu.telemetry import registry as telemetry
 from dedloc_tpu.utils.checkpoint import save_checkpoint
@@ -108,7 +109,8 @@ def run_coordinator(
     """``upload_fn(checkpoint_path, step)`` is the hub-publish seam
     (run_first_peer.py:123-147's git push); ``max_iterations`` bounds the
     loop for tests (0 = run forever)."""
-    force_cpu_if_requested()
+    pin_cpu()  # host-side bookkeeping only: must not take a chip
+    ensure_compile_cache()
     extra = extra or CoordinatorExtraArguments()
     if upload_fn is None:
         from dedloc_tpu.utils.hub import build_upload_fn

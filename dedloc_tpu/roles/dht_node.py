@@ -10,7 +10,8 @@ import time
 import uuid
 
 from dedloc_tpu.core.config import CollaborationArguments, parse_config
-from dedloc_tpu.roles.common import build_dht, force_cpu_if_requested
+from dedloc_tpu.roles.common import build_dht
+from dedloc_tpu.utils.backend import ensure_compile_cache, pin_cpu
 from dedloc_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -21,7 +22,8 @@ def run_dht_node(
     keepalive_period: float = 30.0,
     max_iterations: int = 0,
 ) -> None:
-    force_cpu_if_requested()
+    pin_cpu()  # a DHT node never computes: must not take a chip
+    ensure_compile_cache()
     dht, _ = build_dht(args, client_mode=False)
     logger.info(
         f"initial DHT node up at {dht.get_visible_address()} "

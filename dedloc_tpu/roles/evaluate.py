@@ -28,8 +28,8 @@ from dedloc_tpu.roles.common import (
     build_loss_fn,
     build_model,
     drop_collator_keys,
-    force_cpu_if_requested,
 )
+from dedloc_tpu.utils.backend import ensure_compile_cache
 from dedloc_tpu.utils.checkpoint import load_latest_checkpoint
 from dedloc_tpu.utils.logging import get_logger
 
@@ -50,7 +50,7 @@ class EvalCLIArguments(CollaborationArguments):
 
 def run_eval(args: CollaborationArguments,
              extra: EvalArguments) -> dict:
-    force_cpu_if_requested()
+    ensure_compile_cache()
     from dedloc_tpu.roles.common import single_device_attention_impl
 
     impl = single_device_attention_impl(args.training.attention_impl)

@@ -9,7 +9,7 @@ in-framework, scriptable harness instead of cloud-specific operations:
 
 - every peer is a subprocess running the real role entry points
   (``python -m dedloc_tpu.roles.{coordinator,trainer,aux}``) on localhost,
-  pinned to CPU (DEDLOC_FORCE_CPU=1) so they never contend for the TPU chip;
+  pinned to CPU (JAX_PLATFORMS=cpu) so they never contend for the TPU chip;
 - bandwidth tiers cycle over trainers and flow into the averager's
   bandwidth-weighted partitioning (the advertised-throughput capability of
   ``throughput=bandwidth``, albert/run_trainer.py:258);
@@ -101,7 +101,7 @@ class LocalFleet:
     # ------------------------------------------------------------- spawning
 
     def _spawn(self, name: str, module: str, flags: List[str]) -> None:
-        env = dict(os.environ, DEDLOC_FORCE_CPU="1")
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         # the child duplicates the descriptor; close the parent's handle so
         # churn respawns don't leak one fd per spawn
         with open(os.path.join(self.args.output_dir, f"{name}.log"), "ab") as log:

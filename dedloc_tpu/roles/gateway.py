@@ -26,7 +26,8 @@ from dedloc_tpu.core.serialization import (
     deserialize_array,
     serialize_array,
 )
-from dedloc_tpu.roles.common import build_dht, force_cpu_if_requested
+from dedloc_tpu.roles.common import build_dht
+from dedloc_tpu.utils.backend import ensure_compile_cache, pin_cpu
 from dedloc_tpu.serving.router import ExpertRouter, RouterPolicy
 from dedloc_tpu.utils.logging import get_logger
 
@@ -94,7 +95,8 @@ def run_gateway(
 ) -> None:
     """Role entry point: DHT (full peer — the gateway must be dialable to
     host ``gateway.infer``), router, refresh loop."""
-    force_cpu_if_requested()
+    pin_cpu()  # routes requests on the host: must not take a chip
+    ensure_compile_cache()
     dht, _ = build_dht(args, client_mode=False)
     prefix = args.dht.experiment_prefix
     policy = policy_from_args(args)

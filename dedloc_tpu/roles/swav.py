@@ -45,8 +45,8 @@ from dedloc_tpu.parallel.train_step import TrainState, zeros_like_grads
 from dedloc_tpu.roles.common import (
     build_dht,
     checkpoint_kwargs,
-    force_cpu_if_requested,
 )
+from dedloc_tpu.utils.backend import ensure_compile_cache
 from dedloc_tpu.utils.checkpoint import save_checkpoint
 from dedloc_tpu.utils.logging import get_logger
 
@@ -101,7 +101,7 @@ def _build_flat_lars_factory(t):
 
 
 def run_swav(args: SwAVCollaborationArguments) -> TrainState:
-    force_cpu_if_requested()
+    ensure_compile_cache()
     t = args.training
     cfg, spec, model, tx = build_swav(args)
     dht, _public_key = build_dht(args)

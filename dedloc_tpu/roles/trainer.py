@@ -14,11 +14,13 @@ accumulation boundary, not per micro-batch (SURVEY.md §7 hard-part b).
 from __future__ import annotations
 
 import json
+import os
 import time
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from dedloc_tpu.collaborative.metrics import LocalMetrics, publish_metrics
 from dedloc_tpu.collaborative.optimizer import CollaborativeOptimizer
@@ -45,9 +47,9 @@ from dedloc_tpu.roles.common import (
     checkpoint_kwargs,
     configure_role_telemetry,
     drop_collator_keys,
-    force_cpu_if_requested,
     synthetic_mlm_batches,
 )
+from dedloc_tpu.utils.backend import describe_backend, ensure_compile_cache
 from dedloc_tpu.utils.checkpoint import load_latest_checkpoint, save_checkpoint
 from dedloc_tpu.utils.logging import get_logger
 from dedloc_tpu.utils.perf import PerfStats
@@ -56,7 +58,14 @@ logger = get_logger(__name__)
 
 
 def run_trainer(args: CollaborationArguments) -> TrainState:
-    force_cpu_if_requested()
+    cache_dir = ensure_compile_cache()
+    # JAX lands on the CPU without a word when it finds no accelerator:
+    # say where this peer computes, and how its Pallas kernels will run
+    logger.info(
+        "backend: "
+        + " ".join(f"{k}={v!r}" for k, v in describe_backend().items())
+        + f" compile_cache={cache_dir!r}"
+    )
     # gated runs: token handshake BEFORE any heavy setup, so bad credentials
     # fail in milliseconds (contributor notebook cell-2 ordering)
     from dedloc_tpu.roles.common import build_authorizer
@@ -140,7 +149,7 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
         args.training.remat_policy,
         args.training.attention_impl,
         args.training.vocab_size,
-        ring_mesh=mesh if args.training.attention_impl == "ring" else None,
+        mesh=mesh,
         pipe_mesh=(
             mesh if mesh is not None and "pipe" in mesh.axis_names else None
         ),
@@ -171,12 +180,16 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
     slice_batch = args.training.per_device_batch_size * max(
         1, args.training.mesh_devices
     )
-    # init with the PER-DEVICE batch: param shapes don't depend on batch
-    # size, and a full slice batch would run this forward unsharded on one
-    # device — 8x the training-time activation memory on a real slice
-    init_ids = jnp.zeros((args.training.per_device_batch_size, seq), jnp.int32)
-    params = model.init(rng, init_ids)["params"]
-    state = jax.jit(lambda p: TrainState.create(p, tx))(params)
+    # ONE jitted init: the init-time forward is dead code under jit (only
+    # the initializers run, whatever the batch), and with a mesh the state
+    # is born replicated on it instead of being staged through device 0
+    state = jax.jit(
+        lambda r: TrainState.create(
+            model.init(r, jnp.zeros((slice_batch, seq), jnp.int32))["params"],
+            tx,
+        ),
+        out_shardings=None if mesh is None else NamedSharding(mesh, P()),
+    )(rng)
 
     # local resume (run_trainer.py:56-70): newest checkpoint* dir wins
     resumed = load_latest_checkpoint(args.training.output_dir)
@@ -211,7 +224,6 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
     if mesh is not None and (
         "model" in mesh.axis_names or "expert" in mesh.axis_names
     ):
-        from jax.sharding import NamedSharding
         from dedloc_tpu.parallel.sharding import (
             ALBERT_EP_RULES,
             ALBERT_TP_RULES,
@@ -325,8 +337,6 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
         # commit state onto the mesh once — otherwise accumulate's
         # replicated in_shardings would re-broadcast the full params from
         # the default device on every micro-batch until the first global step
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
         repl = NamedSharding(mesh, P())
         state = state.replace(
             step=jax.device_put(state.step, repl),
@@ -375,7 +385,7 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
     # decomposition + online MFU, published through the telemetry registry
     # (no-op while telemetry is disabled). The MFU gauge uses the same
     # analytic model-FLOPs formula and peak table as bench.py, so the
-    # in-situ number is comparable to the BENCH_r* trajectory.
+    # in-situ number is comparable to bench.py's ``mfu`` field.
     from dedloc_tpu.data.mlm import max_predictions_for
 
     recorder = StepRecorder(
@@ -385,11 +395,13 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
         ),
         peak_tflops=chip_peak_tflops(),
     )
-    train_log = (
-        open(args.training.train_log_path, "a", buffering=1)
-        if args.training.train_log_path
-        else None
-    )
+    train_log = None
+    if args.training.train_log_path:
+        os.makedirs(
+            os.path.dirname(os.path.abspath(args.training.train_log_path)),
+            exist_ok=True,
+        )
+        train_log = open(args.training.train_log_path, "a", buffering=1)
     wall_start = time.perf_counter()
     try:
         while True:

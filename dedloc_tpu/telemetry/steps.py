@@ -69,9 +69,9 @@ PHASES = (
     "opt_apply", "collab",
 )
 
-# bf16 peak TFLOP/s per chip by PJRT device_kind substring — the same table
-# bench.py uses for the offline MFU report, duplicated here because bench.py
-# is a repo-root script, not an importable package module. Keep in sync.
+# bf16 peak TFLOP/s per chip by PJRT device_kind substring (Google Cloud
+# TPU documentation, per-generation system pages) — THE table: bench.py and
+# the tools import it from here.
 TPU_PEAK_TFLOPS = (
     ("v5 lite", 197.0),  # v5e
     ("v5e", 197.0),
@@ -84,24 +84,30 @@ TPU_PEAK_TFLOPS = (
 
 
 def chip_peak_tflops() -> float:
-    """Peak bf16 TFLOP/s of device 0, or 0.0 off-TPU (MFU gauge omitted)."""
-    try:
-        import jax
+    """Peak bf16 TFLOP/s of device 0. A non-TPU backend returns 0.0 (no
+    utilization is reported there); a TPU that is not in the table raises —
+    a utilization against a guessed peak is worse than none."""
+    import jax
 
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:  # noqa: BLE001 — telemetry must never kill training
+    device = jax.devices()[0]
+    if device.platform != "tpu":
         return 0.0
+    kind = device.device_kind.lower()
     for sub, peak in TPU_PEAK_TFLOPS:
         if sub in kind:
             return peak
-    return 0.0
+    raise ValueError(
+        f"no peak TFLOP/s on record for TPU device_kind "
+        f"{device.device_kind!r}; add it to telemetry.steps.TPU_PEAK_TFLOPS "
+        "with its source"
+    )
 
 
 def albert_tflops_per_sample(cfg, seq: int, max_pred: int) -> float:
     """Analytic MODEL TFLOPs for one ALBERT fwd+bwd sample — the same
     matmul-only formula as bench.py's ``albert_train_flops_per_sample``
     (remat recompute excluded by convention), so the recorder's in-situ MFU
-    gauge is directly comparable to the BENCH_r* ``mfu`` field."""
+    gauge is directly comparable to bench.py's ``mfu`` field."""
     h, i, s = cfg.hidden_size, cfg.intermediate_size, seq
     e, v = cfg.embedding_size, cfg.vocab_size
     per_token_layer = 8 * h * h + 4 * h * s + 4 * h * i

@@ -33,6 +33,8 @@ def test_bench_tiny_prints_one_json_line():
     assert required <= set(record), record
     assert set(record) <= required | optional, record
     assert record["value"] > 0
+    # a CPU smoke never reports under a per-chip metric name
+    assert record["metric"] == "albert_tiny_smoke_samples_per_sec"
 
 
 def test_graft_entry_compiles():
@@ -249,9 +251,10 @@ def test_bench_allreduce_pipeline_beats_monolithic():
 
 
 # ------------------------------------------------------------- bench gate
-# (tools/bench_gate.py: the perf trajectory is machine-guarded, mirroring
-# t1_budget.py --gate. Deterministic half only — these tests gate COMMITTED
-# BENCH_r*.json artifacts and synthetic JSONs, they never run the bench.)
+# (tools/bench_gate.py: a recorded trajectory is machine-guarded, mirroring
+# t1_budget.py --gate. These tests check the gate's LOGIC on synthetic
+# driver records written to tmp_path — they never run the bench and never
+# read a committed record.)
 
 import importlib.util
 
@@ -262,40 +265,58 @@ _spec = importlib.util.spec_from_file_location(
 bench_gate = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(bench_gate)
 
-
-def _bench_paths(*rounds):
-    return [os.path.join(_REPO, f"BENCH_r{r:02d}.json") for r in rounds]
-
-
-def test_bench_gate_passes_on_real_trajectory():
-    """Acceptance: the committed BENCH trajectory gates clean — the best
-    recorded round vs the default BENCH_r*.json glob exits 0. The fresh
-    round is picked dynamically (highest samples/sec) so committing an
-    improved BENCH_r06.json later cannot break this test."""
-    import glob as globmod
-
-    rounds = sorted(globmod.glob(os.path.join(_REPO, "BENCH_r*.json")))
-    loaded = [(p, bench_gate.load_bench(p)) for p in rounds]
-    best = max(
-        (pr for pr in loaded if pr[1] is not None),
-        key=lambda pr: pr[1]["value"],
-    )[0]
-    assert bench_gate.main([best]) == 0
+_HEADLINE = "albert_large_train_samples_per_sec_per_chip"
+# a five-round trajectory in the driver's record layout, rising then flat
+_TRAJECTORY = {
+    1: (85.0, None), 2: (85.2, 0.436), 3: (99.2, 0.507),
+    4: (112.3, 0.574), 5: (112.6, 0.576),
+}
 
 
-def test_bench_gate_catches_synthetic_regression(tmp_path, capsys):
+@pytest.fixture
+def bench_rounds(tmp_path, monkeypatch):
+    """Synthetic BENCH_r01..r05 driver records in tmp_path, installed as
+    the gate's default single-chip baseline glob. Returns {round: path}."""
+    paths = {}
+    for r, (value, mfu) in _TRAJECTORY.items():
+        parsed = {"metric": _HEADLINE, "value": value,
+                  "unit": "samples/sec", "vs_baseline": value / 10.0}
+        if mfu is not None:
+            parsed["mfu"] = mfu
+        path = tmp_path / f"BENCH_r{r:02d}.json"
+        path.write_text(json.dumps(
+            {"n": r, "cmd": "python bench.py", "rc": 0, "parsed": parsed}
+        ))
+        paths[r] = str(path)
+    monkeypatch.setattr(
+        bench_gate, "DEFAULT_BASELINE_GLOB", str(tmp_path / "BENCH_r*.json")
+    )
+    return paths
+
+
+def test_bench_gate_passes_on_trajectory(bench_rounds):
+    """The best round of a trajectory gates clean against the default
+    BENCH_r*.json glob (no explicit baselines) — so a later, better round
+    never breaks the gate."""
+    loaded = {r: bench_gate.load_bench(p) for r, p in bench_rounds.items()}
+    best = max(loaded, key=lambda r: loaded[r]["value"])
+    assert bench_gate.main([bench_rounds[best]]) == 0
+
+
+def test_bench_gate_catches_synthetic_regression(bench_rounds, tmp_path,
+                                                 capsys):
     """Acceptance: a fresh bench JSON regressed >3% on samples/sec exits
     nonzero (and an MFU-only regression is caught independently)."""
     slow = tmp_path / "slow.json"
     slow.write_text(json.dumps({
-        "metric": "albert_large_train_samples_per_sec_per_chip",
+        "metric": _HEADLINE,
         "value": 100.0, "unit": "samples/sec", "vs_baseline": 10.0,
     }))
     assert bench_gate.main([str(slow)]) == 1
     assert "GATE FAILED" in capsys.readouterr().out
     low_mfu = tmp_path / "low_mfu.json"
     low_mfu.write_text(json.dumps({
-        "metric": "albert_large_train_samples_per_sec_per_chip",
+        "metric": _HEADLINE,
         "value": 112.6, "unit": "samples/sec", "vs_baseline": 11.3,
         "mfu": 0.50,
     }))
@@ -303,23 +324,26 @@ def test_bench_gate_catches_synthetic_regression(tmp_path, capsys):
     assert "MFU regressed" in capsys.readouterr().out
 
 
-def test_bench_gate_tolerates_missing_rounds():
+def test_bench_gate_tolerates_missing_rounds(bench_rounds):
     """A sparse trajectory (pruned/missing rounds) still gates: r04 vs only
     {r01, r04} passes without r02/r03/r05 existing in the baseline set."""
-    assert bench_gate.main(_bench_paths(4) + _bench_paths(1, 4)) == 0
+    assert bench_gate.main(
+        [bench_rounds[4], bench_rounds[1], bench_rounds[4]]
+    ) == 0
 
 
-def test_bench_gate_malformed_baseline_warns_not_wedges(tmp_path, capsys):
+def test_bench_gate_malformed_baseline_warns_not_wedges(bench_rounds,
+                                                        tmp_path, capsys):
     """A corrupt baseline artifact warns on stderr and is skipped; the gate
     still judges against the healthy baselines. A corrupt FRESH file is a
     hard error (it IS the thing under test)."""
     garbage = tmp_path / "garbage.json"
     garbage.write_text("{not json")
-    rc = bench_gate.main(_bench_paths(5) + [str(garbage)] + _bench_paths(4))
+    rc = bench_gate.main([bench_rounds[5], str(garbage), bench_rounds[4]])
     captured = capsys.readouterr()
     assert rc == 0
     assert "skipping" in captured.err and "garbage.json" in captured.err
-    assert bench_gate.main([str(garbage)] + _bench_paths(4)) == 2
+    assert bench_gate.main([str(garbage), bench_rounds[4]]) == 2
 
 
 def test_bench_gate_unknown_metric_warns_and_passes(tmp_path, capsys):
@@ -335,15 +359,9 @@ def test_bench_gate_unknown_metric_warns_and_passes(tmp_path, capsys):
 
 
 # ------------------------------------------- MULTICHIP trajectory gate
-# (ISSUE 11 satellite: the MULTICHIP_r*.json rounds were in-tree but
-# unguarded. Contract-tested against the COMMITTED artifacts and
-# synthetic records — never runs a bench. The gated value is the swarm
-# samples/sec derived from the tail's timestamped "global step N applied
-# (group=G, samples~S)" optimizer lines.)
-
-
-def _multichip_path(r):
-    return os.path.join(_REPO, f"MULTICHIP_r{r:02d}.json")
+# (the gated value is the swarm samples/sec derived from a driver record's
+# tail: timestamped "global step N applied (group=G, samples~S)" optimizer
+# lines. Synthetic records only.)
 
 
 def _multichip_tail(rates, n_steps=6, samples=48, start="2026-08-02 10:00"):
@@ -362,47 +380,76 @@ def _multichip_tail(rates, n_steps=6, samples=48, start="2026-08-02 10:00"):
     return "\n".join(lines) + "\n"
 
 
-def test_multichip_trajectory_parses_and_gates_clean(capsys):
-    """The committed MULTICHIP rounds gate: rounds whose tail carries
-    applied steps parse to a swarm samples/sec under a device-count-scoped
-    metric name; the best round gates clean against the default set."""
-    import glob as globmod
+@pytest.fixture
+def multichip_rounds(tmp_path, monkeypatch):
+    """Synthetic MULTICHIP_r01..r05 driver records: r01-r03 captured only a
+    start-up banner (no applied steps), r04/r05 carry a rate. Installed as
+    the gate's default multichip baseline glob. Returns {round: path}."""
+    tails = {
+        1: "WARNING: platform banner only\n",
+        2: "WARNING: platform banner only\n",
+        3: "WARNING: platform banner only\n",
+        4: _multichip_tail(2.0),
+        5: _multichip_tail(2.5),
+    }
+    paths = {}
+    for r, tail in tails.items():
+        path = tmp_path / f"MULTICHIP_r{r:02d}.json"
+        path.write_text(json.dumps({
+            "n_devices": 8, "rc": 0, "ok": True, "skipped": False,
+            "tail": tail,
+        }))
+        paths[r] = str(path)
+    monkeypatch.setattr(
+        bench_gate, "MULTICHIP_BASELINE_GLOB",
+        str(tmp_path / "MULTICHIP_r*.json"),
+    )
+    return paths
 
-    rounds = sorted(globmod.glob(os.path.join(_REPO, "MULTICHIP_r*.json")))
-    assert rounds, "MULTICHIP_r*.json artifacts missing from the tree"
-    loaded = [(p, bench_gate.load_bench(p)) for p in rounds]
+
+def test_multichip_trajectory_parses_and_gates_clean(multichip_rounds,
+                                                     capsys):
+    """Rounds whose tail carries applied steps parse to a swarm samples/sec
+    under a device-count-scoped metric name; the best round gates clean
+    against the default set."""
+    loaded = {
+        r: bench_gate.load_bench(p) for r, p in multichip_rounds.items()
+    }
     capsys.readouterr()  # drain the expected early-round warnings
-    parseable = [pr for pr in loaded if pr[1] is not None]
-    assert parseable, "no MULTICHIP round carries applied-step lines"
-    for _p, rec in parseable:
+    parseable = {r: rec for r, rec in loaded.items() if rec is not None}
+    assert sorted(parseable) == [4, 5]
+    for rec in parseable.values():
         assert rec["metric"] == "multichip8_swarm_samples_per_sec"
         assert rec["value"] > 0 and rec["steps"] >= 2
-    best = max(parseable, key=lambda pr: pr[1]["value"])[0]
-    assert bench_gate.main([best]) == 0
+    # 5 intervals x 48 samples over 5 / 2.5 s
+    assert parseable[5]["value"] == pytest.approx(120.0)
+    best = max(parseable, key=lambda r: parseable[r]["value"])
+    assert bench_gate.main([multichip_rounds[best]]) == 0
 
 
-def test_multichip_rounds_without_steps_are_absent_not_fatal(capsys):
-    """Early rounds whose tail captured only the jax banner (r01-r03)
-    skip with a warning — the missing-round rule, not an error."""
-    record = bench_gate.load_bench(_multichip_path(1))
+def test_multichip_rounds_without_steps_are_absent_not_fatal(
+        multichip_rounds, capsys):
+    """Rounds whose tail captured only a start-up banner skip with a
+    warning — the missing-round rule, not an error."""
+    record = bench_gate.load_bench(multichip_rounds[1])
     assert record is None
     assert "applied-step" in capsys.readouterr().err
     # ...and their presence in the baseline set never wedges a gate
-    fresh = bench_gate.load_bench(_multichip_path(5))
-    assert fresh is not None
+    assert bench_gate.load_bench(multichip_rounds[5]) is not None
     assert bench_gate.main(
-        [_multichip_path(5)] + [_multichip_path(r) for r in (1, 4, 5)]
+        [multichip_rounds[5]] + [multichip_rounds[r] for r in (1, 4, 5)]
     ) == 0
 
 
-def test_multichip_gate_catches_synthetic_regression(tmp_path, capsys):
-    """A fresh multichip round 50% slower than the committed trajectory
+def test_multichip_gate_catches_synthetic_regression(multichip_rounds,
+                                                     tmp_path, capsys):
+    """A fresh multichip round 50% slower than the recorded trajectory
     exits 1; a failed/skipped fresh round is exit 2 (not gateable); a
     different device count gates its own (empty) trajectory and passes as
     the bootstrap case."""
     best = max(
-        (bench_gate.load_bench(_multichip_path(r)) for r in (4, 5)),
-        key=lambda rec: rec["value"] if rec else 0.0,
+        (bench_gate.load_bench(multichip_rounds[r]) for r in (4, 5)),
+        key=lambda rec: rec["value"],
     )
     capsys.readouterr()
     slow_rate = best["value"] / 48 / 2.0  # steps/sec at half throughput

@@ -141,3 +141,34 @@ def test_paired_output_layout_matches_dense(rng):
     )(q)
     gd = jax.grad(lambda q: jnp.sum(dense_attention(q, k, v, bias) ** 2))(q)
     np.testing.assert_allclose(gf, gd, atol=5e-4, rtol=5e-4)
+
+
+def test_under_a_mesh_matches_one_device(rng):
+    """On a multi-device mesh the op runs per shard under shard_map (batch
+    over "data", heads over "model"): values and gradients must equal the
+    unsharded call — this is the path every slice peer trains through."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("data", "model"))
+    q, k, v = _qkv(rng, b=2, s=32, h=2, d=16)
+    mask = np.ones((2, 32), np.float32)
+    mask[1, 20:] = 0.0
+    bias = jnp.where(jnp.asarray(mask) > 0, 0.0, -1e9)
+    w = jnp.asarray(rng.standard_normal(q.shape), jnp.float32)
+
+    def loss(mesh):
+        return lambda q, k, v: jnp.sum(
+            flash_attention(q, k, v, bias, block_q=16, block_k=16, mesh=mesh)
+            * w
+        )
+
+    qkv_sharding = NamedSharding(mesh, P("data", None, "model"))
+    sharded = jax.jit(
+        jax.value_and_grad(loss(mesh), argnums=(0, 1, 2)),
+        in_shardings=(qkv_sharding,) * 3,
+    )(q, k, v)
+    local = jax.value_and_grad(loss(None), argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(sharded[0], local[0], rtol=1e-5)
+    for a, b, name in zip(sharded[1], local[1], "qkv"):
+        np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5,
+                                   err_msg=f"d{name} mismatch")

@@ -129,3 +129,34 @@ def test_param_tree_unchanged_by_fused_ln(rng):
     block = params["albert"]["encoder"]["layer"]["block"]
     assert set(block["layernorm"]) == {"scale", "bias"}
     assert set(block["attention"]["layernorm"]) == {"scale", "bias"}
+
+
+def test_under_a_mesh_matches_one_device(rng):
+    """On a multi-device mesh the op runs per row shard under shard_map
+    with γ/β replicated: y and da must equal the unsharded call, and
+    dγ/dβ — per-shard partial sums — must come back summed over the mesh
+    (the shard_map transpose's psum)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+    x, r, gamma, beta = _inputs(rng, n=64, h=128)
+    x3, r3 = x.reshape(4, 16, 128), r.reshape(4, 16, 128)
+    w = jnp.asarray(rng.standard_normal(x3.shape), jnp.float32)
+
+    def loss(mesh):
+        return lambda x, r, g, b: jnp.sum(
+            ln_residual(x, r, g, b, block_n=8, mesh=mesh) * w
+        )
+
+    rows, repl = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    sharded = jax.jit(
+        jax.value_and_grad(loss(mesh), argnums=(0, 1, 2, 3)),
+        in_shardings=(rows, rows, repl, repl),
+    )(x3, r3, gamma, beta)
+    local = jax.value_and_grad(loss(None), argnums=(0, 1, 2, 3))(
+        x3, r3, gamma, beta
+    )
+    np.testing.assert_allclose(sharded[0], local[0], rtol=1e-5)
+    for a, b, name in zip(sharded[1], local[1],
+                          ["dx", "dr", "dgamma", "dbeta"]):
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4, err_msg=name)

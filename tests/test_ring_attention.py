@@ -112,7 +112,7 @@ def test_albert_ring_impl_matches_dense_model_level():
     devices = np.array(jax.devices()[:2]).reshape(1, 2)
     mesh = Mesh(devices, ("data", "seq"))
     dense_cfg = AlbertConfig.tiny(attention_impl="dense")
-    ring_cfg = AlbertConfig.tiny(attention_impl="ring", ring_mesh=mesh)
+    ring_cfg = AlbertConfig.tiny(attention_impl="ring", mesh=mesh)
     dense_model = AlbertForPreTraining(dense_cfg)
     ring_model = AlbertForPreTraining(ring_cfg)
 

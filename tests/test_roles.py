@@ -6,6 +6,7 @@ import os
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -44,18 +45,23 @@ def test_dht_node_runs(tmp_path):
 
 def test_trainer_single_peer_makes_global_steps(tmp_path):
     # target batch 8 = 2 boundaries of 2x2 samples => global step every 2
+    train_log = tmp_path / "logs" / "not_yet_there" / "train.jsonl"
     args = _args(
         tmp_path,
         [
             "--optimizer.target_batch_size", "8",
             "--training.max_local_steps", "7",
             "--training.save_steps", "1",
+            "--training.train_log_path", str(train_log),
         ],
     )
     state = run_trainer(args)
     assert int(state.step) >= 2
     ckpts = list_checkpoints(args.training.output_dir)
     assert ckpts, "trainer should have saved checkpoints"
+    # the log's directory is the trainer's to create
+    rows = [json.loads(line) for line in train_log.read_text().splitlines()]
+    assert len(rows) >= 2 and all(np.isfinite(r["loss"]) for r in rows)
 
 
 def test_trainer_resumes_from_checkpoint(tmp_path):
@@ -265,7 +271,9 @@ def test_two_slice_peers_hybrid_ici_dcn(tmp_path):
 def test_trainer_zero_sharding_on_mesh(tmp_path):
     """ZeRO-1 wired end-to-end through the trainer role (VERDICT r1 item 5):
     a slice peer with --training.zero_sharding shards its LAMB moments over
-    the mesh's data axis and still makes global steps."""
+    the mesh's data axis and still makes global steps — on the flagship
+    recipe, whose Pallas kernels run under shard_map on a mesh (a TPU
+    cannot partition them any other way)."""
     from jax.sharding import PartitionSpec as P
 
     args = _args(
@@ -276,14 +284,19 @@ def test_trainer_zero_sharding_on_mesh(tmp_path):
             "--training.save_steps", "0",
             "--training.mesh_devices", "4",
             "--training.zero_sharding", "true",
+            "--training.attention_impl", "flash",
+            "--training.remat_policy", "fused_ln",
         ],
     )
     state = run_trainer(args)
     assert int(state.step) >= 1
+    # nothing sits on one device alone
+    assert {
+        len(leaf.sharding.device_set)
+        for leaf in jax.tree.leaves((state.params, state.opt_state))
+    } == {4}
     # the moments really are sharded: some leaf of the opt state must carry
     # a non-replicated PartitionSpec over the data axis
-    import jax
-
     specs = [
         getattr(leaf.sharding, "spec", P())
         for leaf in jax.tree.leaves(state.opt_state)

@@ -16,11 +16,10 @@ import os
 import sys
 import time
 
+os.environ["JAX_PLATFORMS"] = "cpu"  # a virtual-CPU-mesh tool, by design
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax
-
-jax.config.update("jax_platforms", "cpu")
 
 import dataclasses
 
@@ -36,7 +35,7 @@ def measure(sp: int, seq: int) -> dict:
     cfg = AlbertConfig.tiny(
         max_position_embeddings=seq,
         attention_impl="ring",
-        ring_mesh=mesh,
+        mesh=mesh,
     )
     attn = AlbertSelfAttention(cfg, deterministic=True)
     B = 1

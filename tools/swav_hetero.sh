@@ -11,9 +11,10 @@
 #   CORPUS=/root/corpus RUN=/root/corpus/r5_swav TOTAL=4800 CHURN=2400 \
 #     REJOIN=300 bash tools/swav_hetero.sh
 set -u
-export PYTHONPATH="/root/repo${PYTHONPATH:+:$PYTHONPATH}"
-export JAX_COMPILATION_CACHE_DIR=${JAX_COMPILATION_CACHE_DIR:-/root/corpus/jaxcache}
-mkdir -p "$JAX_COMPILATION_CACHE_DIR"
+REPO="$(cd "$(dirname "${BASH_SOURCE[0]}")/.." && pwd)"
+export PYTHONPATH="$REPO${PYTHONPATH:+:$PYTHONPATH}"
+# compile cache: <checkout>/.jax_cache unless JAX_COMPILATION_CACHE_DIR is
+# set (dedloc_tpu/utils/backend.py)
 CORPUS=${CORPUS:-/root/corpus}
 RUN=${RUN:-$CORPUS/r5_swav}
 PREFIX=${PREFIX:-swav5}

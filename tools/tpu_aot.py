@@ -1,0 +1,161 @@
+"""Compile the trainer's device programs for a TPU v5e WITHOUT a chip.
+
+libtpu can build a compile-only client from a topology name, so the real
+XLA:TPU + Mosaic compilers run on a CPU-only box: what this catches is a
+kernel Mosaic refuses and a program XLA:TPU takes minutes to compile — the
+two failures only a chip run used to show (PR 21: ``flat_apply_step`` took
+~20 minutes to compile on the v5e and 1 s on the CPU). It says nothing about
+speed or numerics; those need the chip (``chip_smoke.py``).
+
+    python tools/tpu_aot.py            # every program, one JSON line each
+    python tools/tpu_aot.py flat_apply_step kernels
+
+Each line: {"program", "compile_s", "tpu_custom_calls"}. Exit code 0 when
+everything compiled.
+"""
+from __future__ import annotations
+
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+# a compile-only client needs no metadata server and must not fight a
+# running JAX process for libtpu's lockfile
+os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")
+os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+
+from dedloc_tpu.utils.backend import lowering_for_tpu
+
+MICRO_BATCH, SEQ = 12, 512  # the flagship recipe's per-chip shapes
+
+
+def _on_device(device, tree):
+    """Abstract arrays for ``tree``, placed on the (absent) TPU device."""
+    sharding = SingleDeviceSharding(device)
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree,
+    )
+
+
+def _model_and_state():
+    from dedloc_tpu.core.config import CollaborationArguments, parse_config
+    from dedloc_tpu.parallel.train_step import TrainState
+    from dedloc_tpu.roles.common import build_model, build_optimizer
+
+    args = parse_config(CollaborationArguments, [])
+    cfg, model = build_model("large", "fused_ln", "flash")
+    tx = build_optimizer(args)
+    state = jax.eval_shape(
+        lambda r: TrainState.create(
+            model.init(r, jnp.zeros((MICRO_BATCH, SEQ), jnp.int32))["params"],
+            tx,
+        ),
+        jax.random.PRNGKey(0),
+    )
+    return args, cfg, model, state
+
+
+def accumulate_step(device):
+    """ALBERT-large, flash + fused_ln, micro-batch 12, S=512."""
+    from dedloc_tpu.parallel.train_step import (
+        make_accumulate_step,
+        zeros_like_grads,
+    )
+    from dedloc_tpu.roles.common import (
+        build_loss_fn,
+        drop_collator_keys,
+        synthetic_mlm_batches,
+    )
+
+    _args, cfg, model, state = _model_and_state()
+    batch = drop_collator_keys(
+        next(synthetic_mlm_batches(cfg, MICRO_BATCH, SEQ, 0))
+    )
+    grads = jax.eval_shape(zeros_like_grads, state.params)
+    return make_accumulate_step(build_loss_fn(model)).lower(*_on_device(
+        device,
+        (state.params, grads, jnp.zeros([], jnp.int32), batch,
+         jax.random.PRNGKey(0)),
+    ))
+
+
+def flat_apply_step(device):
+    """The fused flat LAMB apply over ALBERT-large's 17.8M-element buffer."""
+    from dedloc_tpu.averaging.device_flat import named_device_leaves
+    from dedloc_tpu.parallel.train_step import make_flat_apply_step
+    from dedloc_tpu.roles.common import build_flat_opt_factory
+
+    args, _cfg, _model, state = _model_and_state()
+    spec = [
+        (name, tuple(leaf.shape), np.dtype(np.float32))
+        for name, leaf in sorted(named_device_leaves(state.params))
+    ]
+    total = sum(int(np.prod(shape)) for _n, shape, _d in spec)
+    flat_tx = build_flat_opt_factory(args)(spec, state.params)
+    return make_flat_apply_step(flat_tx, spec).lower(*_on_device(
+        device, (state, jax.ShapeDtypeStruct((total,), jnp.float32))
+    ))
+
+
+def kernels(device):
+    """Every Pallas kernel, fwd+bwd, in one program: flash attention at the
+    recipe shape (one tile covers S=512: the fused backward) and at S=2048
+    (several tiles: the two-kernel backward every long-sequence run takes),
+    and the fused add+LayerNorm at the recipe's 6,144 x 1,024 rows."""
+    from dedloc_tpu.ops.flash_attention import flash_attention
+    from dedloc_tpu.ops.fused_ln import ln_residual
+
+    def loss(q, k, v, q_long, x, gamma):
+        return (
+            jnp.sum(flash_attention(q, k, v).astype(jnp.float32))
+            + jnp.sum(
+                flash_attention(q_long, q_long, q_long).astype(jnp.float32)
+            )
+            + jnp.sum(ln_residual(x, x, gamma, gamma).astype(jnp.float32))
+        )
+
+    qkv = jax.ShapeDtypeStruct((MICRO_BATCH, SEQ, 16, 64), jnp.bfloat16)
+    q_long = jax.ShapeDtypeStruct((1, 2048, 16, 64), jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((MICRO_BATCH * SEQ, 1024), jnp.bfloat16)
+    gamma = jax.ShapeDtypeStruct((1024,), jnp.float32)
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
+        *_on_device(device, (qkv, qkv, qkv, q_long, x, gamma))
+    )
+
+
+PROGRAMS = {
+    fn.__name__: fn for fn in (accumulate_step, flat_apply_step, kernels)
+}
+
+
+def main(argv=None) -> int:
+    names = list(argv if argv is not None else sys.argv[1:]) or list(PROGRAMS)
+    device = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2"
+    ).devices[0]
+    for name in names:
+        with lowering_for_tpu():  # Mosaic kernels, not the interpreter
+            lowered = PROGRAMS[name](device)
+        start = time.perf_counter()
+        lowered.compile()
+        print(json.dumps({
+            "program": name,
+            "device_kind": device.device_kind,
+            "compile_s": round(time.perf_counter() - start, 2),
+            "tpu_custom_calls": lowered.as_text().count("tpu_custom_call"),
+        }), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
