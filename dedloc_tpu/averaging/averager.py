@@ -232,6 +232,15 @@ class DecentralizedAverager:
         self.endpoint = None
         self.last_group_size: int = 1
         self.last_contributors: int = 1
+        # where the last round's wall went, read off the monotonic clock on
+        # the DHT loop's thread, telemetry on or off: ``started_at``,
+        # ``matchmaking_s`` (entering the round -> the first group formed,
+        # the wait for the partners included) and ``allreduce_s`` (group
+        # formed -> result; a round that formed no group has 0 here). The
+        # collaborative optimizer hangs them under its ``avg_wire`` span.
+        # None while a round runs.
+        self.last_round_timing: Optional[Dict[str, float]] = None
+        self._round_formed_at: Optional[float] = None
         # hierarchical averaging state: the installed plan, and the fan-out
         # futures a delegate publishes each round's final result through
         # (clique members pull them via the avg.final RPC)
@@ -563,13 +572,37 @@ class DecentralizedAverager:
                 # must never cost a training round
                 logger.warning(f"plan refresh failed: {e!r}")
 
+        self.last_round_timing = None
+
         def _run(node):
-            return self._step_async(
+            return self._timed_step(
                 tree, weight, round_id, expected_size, window
             )
 
         fut = self.dht.run_coroutine(_run, return_future=True)
         return fut if return_future else fut.result()
+
+    async def _timed_step(self, *round_args):
+        started = telemetry.monotonic_clock()
+        self._round_formed_at = None
+        try:
+            return await self._step_async(*round_args)
+        finally:
+            done = telemetry.monotonic_clock()
+            formed = self._round_formed_at
+            if formed is None:
+                formed = done
+            self.last_round_timing = {
+                "started_at": started,
+                "matchmaking_s": max(0.0, formed - started),
+                "allreduce_s": max(0.0, done - formed),
+            }
+
+    async def _form_group(self, round_id: str, **kwargs):
+        group = await self.matchmaking.form_group(round_id, **kwargs)
+        if self._round_formed_at is None:
+            self._round_formed_at = telemetry.monotonic_clock()
+        return group
 
     async def _step_async(
         self, tree: Dict[str, np.ndarray], weight: float, round_id: str,
@@ -652,7 +685,7 @@ class DecentralizedAverager:
             loop = asyncio.get_running_loop()
             resolve_task = loop.run_in_executor(None, fetch.result)
         try:
-            group = await self.matchmaking.form_group(
+            group = await self._form_group(
                 round_id,
                 schema=(
                     spec_fingerprint(fetch.spec) if fetch is not None
@@ -779,7 +812,7 @@ class DecentralizedAverager:
             return True
 
         try:
-            group = await self.matchmaking.form_group(
+            group = await self._form_group(
                 round_id, schema=schema, expected_size=len(members),
                 window=window, scope=plan.gossip_scope(members),
             )
@@ -1025,7 +1058,7 @@ class DecentralizedAverager:
         group = None
         if assignment.clique_size > 1:
             try:
-                group = await self.matchmaking.form_group(
+                group = await self._form_group(
                     round_id, schema=schema,
                     expected_size=assignment.clique_size,
                     # epoch-qualified scope: peers on different plan epochs
@@ -1130,7 +1163,7 @@ class DecentralizedAverager:
                 )
                 if fault is not None:
                     await faults.apply_transport_fault(fault, "hier WAN leg")
-            wan_group = await self.matchmaking.form_group(
+            wan_group = await self._form_group(
                 round_id, schema=schema,
                 expected_size=assignment.wan_size, window=window,
                 scope=plan.wan_scope(),

@@ -373,6 +373,7 @@ class CollaborativeOptimizer:
         self.claim_period = float(claim_period)
         self.contrib_samples_total = 0
         self.contrib_rounds_total = 0
+        self.boundaries_total = 0  # step() calls that brought samples
         self._last_claim_t = 0.0
 
     # ------------------------------------------------------------ properties
@@ -396,112 +397,135 @@ class CollaborativeOptimizer:
     ) -> Tuple[TrainState, Any, Any, bool]:
         """Per-accumulation-boundary call. Returns (state, grad_acc, n_acc,
         performed_global_step). All heavy work happens only when the global
-        target batch is reached."""
+        target batch is reached.
+
+        The one place both roles' step records are filled in: whether this
+        boundary stepped, its samples, the running totals (samples,
+        boundaries, global steps — cumulative over this peer's life, so any
+        two records give a rate) and the wall of this call."""
         assert not self.auxiliary, "auxiliary peers must use step_aux()"
+        record = steps.current()
+        entered = record.elapsed() if record is not None else 0.0
         with self._lock:
-            tele = telemetry.resolve(self.telemetry)
-            if tele is not None and samples > 0:
-                # accumulation-boundary trace; samples == 0 is a retry poll
-                # while a round assembles, not a boundary
+            out = self._step(state, grad_acc, n_acc, samples)
+        if record is not None:
+            record.samples += samples
+            record.attrs.update(
+                stepped=bool(out[3]) or record.attrs.get("stepped", False),
+                opt_step_s=record.elapsed() - entered,
+                samples_total=self.contrib_samples_total,
+                boundaries_total=self.boundaries_total,
+                global_steps_total=self.contrib_rounds_total,
+            )
+        return out
+
+    def _step(self, state: TrainState, grad_acc, n_acc, samples: int):
+        """``step`` proper, under its lock."""
+        tele = telemetry.resolve(self.telemetry)
+        if samples > 0:
+            # an accumulation boundary; samples == 0 is a retry poll while
+            # a round assembles, not a boundary
+            self.boundaries_total += 1
+            if tele is not None:
                 tele.counter("opt.boundaries").inc()
-            self.local_samples_accumulated += samples
-            self.contrib_samples_total += samples
-            if self._ema_started:
-                # samples == 0 is a retry poll while a round assembles —
-                # neither progress nor throughput signal (and it must not
-                # touch the EMA clock: a resume() here would discard the
-                # elapsed interval and inflate samples/sec)
-                if samples > 0:
-                    self.performance_ema.update(samples)
-            else:
-                # first call: start the clock only — measuring from resume()
-                # to now would seed the EMA with a near-zero interval and
-                # publish absurd samples/sec to the DHT (and this also keeps
-                # compile time out of throughput stats)
-                self.performance_ema.resume()
-                self._ema_started = True
+        self.local_samples_accumulated += samples
+        self.contrib_samples_total += samples
+        if self._ema_started:
+            # samples == 0 is a retry poll while a round assembles —
+            # neither progress nor throughput signal (and it must not
+            # touch the EMA clock: a resume() here would discard the
+            # elapsed interval and inflate samples/sec)
+            if samples > 0:
+                self.performance_ema.update(samples)
+        else:
+            # first call: start the clock only — measuring from resume()
+            # to now would seed the EMA with a near-zero interval and
+            # publish absurd samples/sec to the DHT (and this also keeps
+            # compile time out of throughput stats)
+            self.performance_ema.resume()
+            self._ema_started = True
 
-            if self._overlap_inflight is not None:
-                # overlap ledger: the wall since this peer resumed
-                # accumulating was HIDDEN behind the in-flight round — but
-                # only up to the moment the round actually finished
-                # (accumulation past that point hides nothing)
-                now = monotonic_clock()
-                if self._overlap_resumed_at is not None:
-                    done_at = self._overlap_done_at
-                    covered = (min(now, done_at) if done_at is not None
-                               else now)
-                    self._overlap_hidden_s += max(
-                        0.0, covered - self._overlap_resumed_at
-                    )
-                    self._overlap_resumed_at = None
-                if not self._overlap_inflight["future"].done():
-                    # a background round is in flight: keep accumulating —
-                    # its result applies one boundary late (the overlap
-                    # staleness contract, docs/fleet.md). Catch-up/ramp
-                    # decisions wait until the round lands.
-                    with steps.phase("collab"):
-                        self._report(synced=True)
-                    self._overlap_resumed_at = monotonic_clock()
-                    return state, grad_acc, n_acc, False
-                state, grad_acc, n_acc, stepped, applied = (
-                    self._harvest_overlap(state, grad_acc, n_acc)
+        if self._overlap_inflight is not None:
+            # overlap ledger: the wall since this peer resumed
+            # accumulating was HIDDEN behind the in-flight round — but
+            # only up to the moment the round actually finished
+            # (accumulation past that point hides nothing)
+            now = monotonic_clock()
+            if self._overlap_resumed_at is not None:
+                done_at = self._overlap_done_at
+                covered = (min(now, done_at) if done_at is not None
+                           else now)
+                self._overlap_hidden_s += max(
+                    0.0, covered - self._overlap_resumed_at
                 )
-                if applied:
-                    return state, grad_acc, n_acc, stepped
-                # failed overlapped round: its gradients were restored into
-                # the accumulator — fall through to the synchronous path
-
-            with steps.phase("collab"):
-                collab = self.tracker.fetch_collaboration_state()
-            gap = collab.optimizer_step - self.local_step
-            if (
-                gap > self.resync_step_gap
-                or self._desynced
-                # never been synced at all (fresh init joining a live run):
-                # stale-tolerance is for peers that HAVE the collaboration's
-                # state modulo a few applies, not for random-init params
-                or (gap > 0 and self.local_step == 0)
-            ):
-                # we fell FAR behind (or our last round failed while others
-                # averaged) — catch up from peers: full state download
-                if tele is not None:
-                    tele.counter("opt.catch_ups").inc()
-                    tele.event(
-                        "opt.catch_up", gap=gap, desynced=self._desynced,
-                        local_step=self.local_step,
-                    )
-                state = self._catch_up(state, collab)
-                self._desynced = False
-                grad_acc = zeros_like_grads(state.params)
-                n_acc = jax.numpy.zeros([], jax.numpy.int32)
-                self.local_samples_accumulated = 0
-                self._report(synced=True)
+                self._overlap_resumed_at = None
+            if not self._overlap_inflight["future"].done():
+                # a background round is in flight: keep accumulating —
+                # its result applies one boundary late (the overlap
+                # staleness contract, docs/fleet.md). Catch-up/ramp
+                # decisions wait until the round lands.
+                with steps.phase("collab"):
+                    self._report(synced=True)
+                self._overlap_resumed_at = monotonic_clock()
                 return state, grad_acc, n_acc, False
-            if gap > 0:
-                # mildly stale: adopt the counter and KEEP the accumulated
-                # gradients — contribute them to the current round instead
-                # of burning a state download that outlasts the fast peer's
-                # round period (the resync-loop failure mode; see
-                # resync_step_gap above). Our params lag by <= gap applies;
-                # the gradient bias is bounded and weighted by our samples.
-                self.local_step = collab.optimizer_step
+            state, grad_acc, n_acc, stepped, applied = (
+                self._harvest_overlap(state, grad_acc, n_acc)
+            )
+            if applied:
+                return state, grad_acc, n_acc, stepped
+            # failed overlapped round: its gradients were restored into
+            # the accumulator — fall through to the synchronous path
 
-            with steps.phase("collab"):
-                self._report(synced=True)
-            if not collab.ready_for_step:
-                return state, grad_acc, n_acc, False
+        with steps.phase("collab"):
+            collab = self.tracker.fetch_collaboration_state()
+        gap = collab.optimizer_step - self.local_step
+        if (
+            gap > self.resync_step_gap
+            or self._desynced
+            # never been synced at all (fresh init joining a live run):
+            # stale-tolerance is for peers that HAVE the collaboration's
+            # state modulo a few applies, not for random-init params
+            or (gap > 0 and self.local_step == 0)
+        ):
+            # we fell FAR behind (or our last round failed while others
+            # averaged) — catch up from peers: full state download
+            if tele is not None:
+                tele.counter("opt.catch_ups").inc()
+                tele.event(
+                    "opt.catch_up", gap=gap, desynced=self._desynced,
+                    local_step=self.local_step,
+                )
+            state = self._catch_up(state, collab)
+            self._desynced = False
+            grad_acc = zeros_like_grads(state.params)
+            n_acc = jax.numpy.zeros([], jax.numpy.int32)
+            self.local_samples_accumulated = 0
+            self._report(synced=True)
+            return state, grad_acc, n_acc, False
+        if gap > 0:
+            # mildly stale: adopt the counter and KEEP the accumulated
+            # gradients — contribute them to the current round instead
+            # of burning a state download that outlasts the fast peer's
+            # round period (the resync-loop failure mode; see
+            # resync_step_gap above). Our params lag by <= gap applies;
+            # the gradient bias is bounded and weighted by our samples.
+            self.local_step = collab.optimizer_step
 
-            # decide the round shape on a FORCED-fresh view: the cached view
-            # can lag a just-joined peer, and the solo fast path below must
-            # not fire while a partner is mid-round
-            with steps.phase("collab"):
-                collab = self.tracker.fetch_collaboration_state(force=True)
-            if collab.optimizer_step > self.local_step:
-                self.local_step = collab.optimizer_step  # raced again: rejoin
-            if not collab.ready_for_step:
-                return state, grad_acc, n_acc, False
-            return self._global_step(state, grad_acc, n_acc, collab)
+        with steps.phase("collab"):
+            self._report(synced=True)
+        if not collab.ready_for_step:
+            return state, grad_acc, n_acc, False
+
+        # decide the round shape on a FORCED-fresh view: the cached view
+        # can lag a just-joined peer, and the solo fast path below must
+        # not fire while a partner is mid-round
+        with steps.phase("collab"):
+            collab = self.tracker.fetch_collaboration_state(force=True)
+        if collab.optimizer_step > self.local_step:
+            self.local_step = collab.optimizer_step  # raced again: rejoin
+        if not collab.ready_for_step:
+            return state, grad_acc, n_acc, False
+        return self._global_step(state, grad_acc, n_acc, collab)
 
     def _report(self, synced: bool) -> None:
         self.tracker.report_local_progress(
@@ -615,10 +639,10 @@ class CollaborativeOptimizer:
             False,
         )
 
-    def _global_step(self, state: TrainState, grad_acc, n_acc, collab):
-        """Average gradients with the group and apply one optimizer update."""
-        round_id = f"step{collab.optimizer_step}"
-        n = max(int(jax.device_get(n_acc)), 1)
+    def _plan_round(self, collab, n: int, round_id: str):
+        """The shape of the round about to run: the contribution cap, the
+        alone-grace, the ramp / gate weight (and their trace events).
+        Returns (cap, alone_grace, weight_scale)."""
         # contribution cap: sample-weighted averaging assumes equal
         # per-sample gradient quality, so the cap scales with OUR samples
         # per MICRO-batch (the contribution is grad_acc/n_acc, a
@@ -657,6 +681,20 @@ class CollaborativeOptimizer:
                 rounds_since_join=self._rounds_since_join,
                 loss=self._last_loss,
             )
+        return cap, alone_grace, weight_scale
+
+    def _global_step(self, state: TrainState, grad_acc, n_acc, collab):
+        """Average gradients with the group and apply one optimizer update."""
+        round_id = f"step{collab.optimizer_step}"
+        with steps.phase("drain"):
+            # the one host read that waits for the queued accumulates: where
+            # the program blocks on the device, so where the wait is named
+            n = max(int(jax.device_get(n_acc)), 1)
+        with steps.phase("round_plan"):
+            cap, alone_grace, weight_scale = self._plan_round(
+                collab, n, round_id
+            )
+        tele = telemetry.resolve(self.telemetry)
         if (
             collab.num_peers_near_step <= 1
             and not self.client_mode
@@ -689,9 +727,10 @@ class CollaborativeOptimizer:
             # has passed, take the networked path, whose straggler window
             # lets a concurrent starter pair with us.
             self.seam_ms.pop("grads_device_get", None)
+            with steps.phase("grad_flatten"):
+                mean_grads = _fused_mean_clip(grad_acc, n, cap)
             return self._apply_and_advance(
-                state, _fused_mean_clip(grad_acc, n, cap), collab,
-                group_size=1,
+                state, mean_grads, collab, group_size=1,
             )
 
         pipeline = self._ensure_pipeline(grad_acc)
@@ -706,35 +745,32 @@ class CollaborativeOptimizer:
             # inside the averaging round, overlapped with matchmaking (and
             # with the next micro-batches' accumulation in overlap mode).
             use_ef = weight_scale > 0 and self.error_feedback.enabled
-            t0 = time.perf_counter()
-            with steps.phase("grad_flatten"):
+            with steps.phase("grad_flatten") as flatten:
                 fetch = pipeline.fetch(
                     grad_acc, n=n, clip_cap=cap if cap > 0 else None,
                     use_ef=use_ef,
                 )
-            self.seam_ms["grads_device_get"] = (
-                (time.perf_counter() - t0) * 1e3
-            )
+            self.seam_ms["grads_device_get"] = flatten.dur_s * 1e3
             contrib = fetch
             ef_commit = (
                 (lambda: pipeline.commit(fetch)) if use_ef else None
             )
             lossy_d2h = pipeline.ef_enabled
             if use_ef and tele is not None:
-                tele.gauge("opt.ef_residual_norm").set(
-                    pipeline.residual_norm()
-                )
+                with steps.phase("ef_norm"):
+                    # telemetry's own cost, and a host sync: the gauge reads
+                    # a scalar off the device (the ``vdot`` program in a
+                    # trace), behind whatever the device has queued
+                    tele.gauge("opt.ef_residual_norm").set(
+                        pipeline.residual_norm()
+                    )
         else:
             # legacy host seam (non-float leaves refused the pipeline):
             # per-leaf device_get + host flatten + host error feedback
-            mean_grads = _fused_mean_clip(grad_acc, n, cap)
-            t0 = time.perf_counter()
-            with steps.phase("grad_flatten"):
+            with steps.phase("grad_flatten") as flatten:
                 # device_get of the full grad tree (the jit↔host seam)
-                named = _tree_to_named(mean_grads)
-            self.seam_ms["grads_device_get"] = (
-                (time.perf_counter() - t0) * 1e3
-            )
+                named = _tree_to_named(_fused_mean_clip(grad_acc, n, cap))
+            self.seam_ms["grads_device_get"] = flatten.dur_s * 1e3
             # error feedback (collaborative/error_feedback.py): fold the
             # last round's quantization residual into this round's
             # contribution so a lossy wire format doesn't bias the trunk.
@@ -785,27 +821,35 @@ class CollaborativeOptimizer:
 
         self.performance_ema.pause()
         try:
-            wire_start = monotonic_clock()
-            averaged, group_size = self._sync_averager_step(
-                contrib, weight_scale, round_id, expected_size, window,
-            )
-            if averaged is not None and not isinstance(averaged, dict):
-                # an averager (or test stub) that echoed the FlatFetch
-                # contribution back unresolved: resolve it here
-                averaged = averaged.result()
-            wire_wall = max(0.0, monotonic_clock() - wire_start)
-            # phase attribution stays DISJOINT: the averaging round's wall
-            # splits into the exposed remainder of the D2H stream (the
-            # transfer resolves inside the round, overlapped with
-            # matchmaking — only what matchmaking did NOT cover is a real
-            # stall, ~0 on the loopback harness) and the wire round proper
-            exposed_d2h = (
-                min(fetch.exposed_wait_s, wire_wall)
-                if fetch is not None else 0.0
-            )
-            steps.add("avg_wire", wire_wall - exposed_d2h)
-            if fetch is not None:
-                steps.add("d2h_stream", exposed_d2h)
+            with steps.phase("avg_wire") as wire:
+                averaged, group_size = self._sync_averager_step(
+                    contrib, weight_scale, round_id, expected_size, window,
+                )
+                if averaged is not None and not isinstance(averaged, dict):
+                    # an averager (or test stub) that echoed the FlatFetch
+                    # contribution back unresolved: resolve it here
+                    averaged = averaged.result()
+                # the round's wall splits into the exposed remainder of the
+                # D2H stream (the transfer resolves inside the round,
+                # overlapped with matchmaking — only what matchmaking did
+                # NOT cover is a real stall, ~0 on the loopback harness),
+                # a child span, and the wire round proper, avg_wire's own
+                # time. The averager's readings of the same round, taken on
+                # the DHT loop's thread, split it the other way: the wait
+                # for the group, then the all-reduce.
+                if fetch is not None:
+                    steps.add(
+                        "d2h_stream",
+                        min(fetch.exposed_wait_s, wire.elapsed()),
+                    )
+                timing = getattr(self.averager, "last_round_timing", None)
+                if timing is not None:
+                    formed = timing["started_at"] + timing["matchmaking_s"]
+                    steps.attach("matchmaking", timing["started_at"], formed)
+                    steps.attach(
+                        "allreduce", formed, formed + timing["allreduce_s"]
+                    )
+            wire_wall = wire.dur_s
             if self.overlap_averaging and tele is not None:
                 # overlap ledger, synchronous-fallback form: this round ran
                 # on the trainer's critical path (cooldown after a failed
@@ -879,9 +923,10 @@ class CollaborativeOptimizer:
             # local-apply fallback: OUR mean gradients (clip applied, no
             # residual fold, never quantized) — exactly what the legacy
             # path applied here; the device tree never left the chip
+            with steps.phase("grad_flatten"):
+                mean_grads = _fused_mean_clip(grad_acc, n, cap)
             return self._apply_and_advance(
-                state, _fused_mean_clip(grad_acc, n, cap), collab,
-                group_size,
+                state, mean_grads, collab, group_size,
             )
         finally:
             self.performance_ema.resume()
@@ -1237,8 +1282,7 @@ class CollaborativeOptimizer:
         accumulation that ran while an overlapped round was in flight
         (those microbatches belong to the NEXT round)."""
         round_id = f"step{collab.optimizer_step}"
-        t0 = time.perf_counter()
-        with steps.phase("opt_apply"):
+        with steps.phase("opt_apply") as apply:
             # previous boundary's NaN verdict has settled by now — read it
             # without stalling this boundary's dispatch
             self._check_apply_ok()
@@ -1255,7 +1299,8 @@ class CollaborativeOptimizer:
                 # fused FLAT apply: the averaged result crosses host->device
                 # as ONE buffer and the whole optimizer update runs as
                 # segment reductions over it (optim/flat.py)
-                flat_dev = jax.device_put(mean_grads.flat)
+                with steps.phase("h2d_result"):
+                    flat_dev = jax.device_put(mean_grads.flat)
                 new_state, ok = flat_fn(state, flat_dev)
             else:
                 if isinstance(mean_grads, FlatTree):
@@ -1266,7 +1311,7 @@ class CollaborativeOptimizer:
                     )
                 new_state, ok = self._apply_fn(state, mean_grads)
             self._pending_apply_ok = (round_id, ok)
-        self.seam_ms["apply"] = (time.perf_counter() - t0) * 1e3
+        self.seam_ms["apply"] = apply.dur_s * 1e3
         tele = telemetry.resolve(self.telemetry)
         if tele is not None:
             tele.counter("opt.grads_applied").inc()
@@ -1294,12 +1339,11 @@ class CollaborativeOptimizer:
             # overlap harvest: the microbatches accumulated during the
             # flight stay live — they are the next round's contribution
             return new_state, keep_acc[0], keep_acc[1], True
-        return (
-            new_state,
-            zeros_like_grads(new_state.params),
-            jax.numpy.zeros([], jax.numpy.int32),
-            True,
-        )
+        with steps.phase("acc_reset"):
+            # a fresh accumulator: one small eager program per leaf
+            fresh = zeros_like_grads(new_state.params)
+            n_fresh = jax.numpy.zeros([], jax.numpy.int32)
+        return new_state, fresh, n_fresh, True
 
     # -------------------------------------------------------- state recovery
 
@@ -1337,6 +1381,13 @@ class CollaborativeOptimizer:
         idle_needed = self._backup_took * (1.0 / self.backup_duty_cycle - 1.0)
         if now < self._backup_done_at + idle_needed:
             return
+        with steps.phase("backup_launch"):
+            self._launch_backup(state)
+
+    def _launch_backup(self, state: TrainState) -> None:
+        """The part of a backup the training thread pays: one host sync on
+        the apply program (``int(state.step)``), an on-device copy of the
+        state, and the start of the thread that takes it to the host."""
         self._join_backup()
         step, local_step = int(state.step), self.local_step
         # snapshot ON DEVICE first (an HBM copy, ~ms): the next global step's

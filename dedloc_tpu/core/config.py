@@ -422,6 +422,14 @@ class TelemetryArguments:
     # large swarms; the coordinator folds these into the swarm topology
     # record rendered by ``runlog_summary --topology``
     link_top_k: int = 8
+    # one profiler window (telemetry/profile.py): a jax.profiler session
+    # over ``<count>`` accumulation boundaries from boundary ``<first>``,
+    # written under profile_dir ("" = never). The step record's spans are
+    # in it on the profiler's own clock, beside the device's programs:
+    # ``python -m dedloc_tpu.telemetry.profile <profile_dir>`` charges the
+    # device's idle time to them
+    profile_dir: str = ""
+    profile_boundaries: str = "64:32"  # <first>:<count>
 
 
 @dataclass
@@ -508,6 +516,7 @@ class SwAVTrainingArguments:
     save_total_limit: int = 2
     log_every: int = 10
     device_stats_every: int = 100  # HBM stats cadence (0 = off)
+    train_log_path: str = ""  # per-global-step JSONL, as the ALBERT trainer's
 
 
 @dataclass

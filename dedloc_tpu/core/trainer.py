@@ -23,7 +23,7 @@ from dedloc_tpu.core.hooks import HookList, LoopContext, default_hooks
 from dedloc_tpu.telemetry import steps
 from dedloc_tpu.telemetry.steps import StepRecorder
 from dedloc_tpu.utils.logging import get_logger
-from dedloc_tpu.utils.perf import PerfStats, profiler_trace
+from dedloc_tpu.utils.perf import PerfStats
 
 logger = get_logger(__name__)
 
@@ -44,16 +44,14 @@ class Trainer:
         step_fn: StepFn,
         hooks: Optional[HookList] = None,
         perf: Optional[PerfStats] = None,
-        profiler_dir: Optional[str] = None,
         recorder: Optional[StepRecorder] = None,
     ):
         self.step_fn = step_fn
         self.hooks = hooks if hooks is not None else default_hooks()
         self.perf = perf if perf is not None else PerfStats()
-        self.profiler_dir = profiler_dir
-        # step-phase flight recorder (telemetry/steps.py): no-op while
-        # telemetry is disabled; the default instance keeps call sites
-        # unconditional
+        # step-phase flight recorder (telemetry/steps.py): always times,
+        # publishes only while telemetry is enabled. A role passes its own
+        # to add a profiler window (telemetry/profile.py)
         self.recorder = recorder if recorder is not None else StepRecorder()
 
     def train(
@@ -72,7 +70,7 @@ class Trainer:
         ctx.perf = self.perf
         ctx.train_state = state
 
-        with profiler_trace(self.profiler_dir):
+        try:
             self.hooks.dispatch("on_start", ctx)
             while ctx.local_step < max_steps and not ctx.should_stop:
                 self.hooks.dispatch("on_phase_start", ctx)
@@ -82,6 +80,8 @@ class Trainer:
                 self.hooks.dispatch("on_phase_end", ctx)
                 ctx.phase += 1
             self.hooks.dispatch("on_end", ctx)
+        finally:
+            self.recorder.close()  # a profiler window still open
         return state, ctx
 
     def _one_step(self, state: Any, batches: Iterator[Any], ctx: LoopContext):
@@ -97,15 +97,21 @@ class Trainer:
                 ctx.should_stop = True
                 return state
         metrics: Dict[str, Any] = {}
-        with self.perf.timer("train_step"), steps.phase("fwd_bwd"):
-            state, metrics = self.step_fn(state, batch)
-            # block on the loss only — the rest of the state stays async
-            loss = metrics.get("loss")
-            if loss is not None:
-                jax.block_until_ready(loss)
+        with self.perf.timer("train_step"):
+            # whatever step_fn records itself (a role's h2d, the optimizer's
+            # boundary, its post_step) nests under fwd_bwd, whose own time
+            # is then what the host spent enqueueing the step
+            with steps.phase("fwd_bwd"):
+                state, metrics = self.step_fn(state, batch)
+            with steps.phase("loss_sync"):
+                # the loop's own host sync, once per step: block on the loss
+                # only — the rest of the state stays async
+                loss = metrics.get("loss")
+                if loss is not None:
+                    jax.block_until_ready(loss)
+                ctx.loss = float(loss) if loss is not None else float("nan")
         ctx.local_step += 1
         ctx.train_state = state
-        ctx.loss = float(metrics["loss"]) if "loss" in metrics else float("nan")
         if "lr" in metrics:
             ctx.lr = float(metrics["lr"])
         if "global_step" in metrics:

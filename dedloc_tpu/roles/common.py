@@ -1,7 +1,9 @@
 """Shared builders for role entry points: model, optimizer, DHT, data."""
 from __future__ import annotations
 
+import json
 import os
+import time
 from typing import Callable, Dict, Iterator, Optional, Tuple
 
 import jax
@@ -222,6 +224,82 @@ def configure_role_telemetry(args, public_key: bytes):
             telemetry.uninstall(tele)
 
     return tele, close
+
+
+
+class TrainLog:
+    """``--training.train_log_path``: one JSON line per global step, the
+    same in both trainer roles, read off the step record (THIS step's
+    values: ``telemetry.steps.train_log_row``)."""
+
+    def __init__(self, path: str) -> None:
+        os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
+        self._file = open(path, "a", buffering=1)
+        self._start = time.perf_counter()  # the origin of ``wall_s``
+
+    def write(self, opt, row: Dict, loss: float, sps: float) -> None:
+        self._file.write(json.dumps({
+            "wall_s": time.perf_counter() - self._start,
+            "step": opt.local_step,
+            "loss": loss,
+            "samples_per_second": sps,
+            **row,
+            # jit↔host seam breakdown (SURVEY §7b): grads device_get /
+            # apply / async backup. list() snapshots atomically under the
+            # GIL — the backup thread may insert its key mid-step
+            "seam_ms": {k: round(v, 2) for k, v in list(opt.seam_ms.items())},
+        }) + "\n")
+
+    def close(self) -> None:
+        self._file.close()
+
+
+def open_train_log(path: str) -> Optional[TrainLog]:
+    return TrainLog(path) if path else None
+
+
+def publish_step_metrics(
+    dht, args, public_key: bytes, opt, tele, row: Dict, *, samples: int,
+    loss: float, mini_steps: int, sps: float,
+    hbm_bytes: Optional[int] = None,
+) -> None:
+    """One global step's signed ``LocalMetrics`` record onto the DHT
+    metrics bus (run_first_peer.py:176-218 aggregation), for both trainer
+    roles: this step's timings off its record (``row``), and with
+    telemetry on the throttled counter snapshot for the coordinator's
+    swarm-health fold (refreshed at most once per period;
+    stale-but-present between refreshes) and the advertised RPC endpoint,
+    which lets the coordinator resolve OTHER peers' link destinations to
+    this peer's label in the swarm topology fold."""
+    from dedloc_tpu.collaborative.metrics import LocalMetrics, publish_metrics
+    from dedloc_tpu.telemetry.links import endpoint_key
+
+    publish_metrics(
+        dht,
+        args.dht.experiment_prefix,
+        public_key,
+        LocalMetrics(
+            step=opt.local_step,
+            samples_per_second=sps,
+            samples_accumulated=samples,
+            loss=loss,
+            mini_steps=mini_steps,
+            step_time_ms=row["boundary_ms"],
+            data_wait_ms=row["data_wait_ms"],
+            allreduce_ms=row["allreduce_ms"],
+            hbm_bytes=hbm_bytes,
+            telemetry=(
+                tele.maybe_snapshot(args.telemetry.snapshot_period)
+                if tele is not None else None
+            ),
+            endpoint=(
+                endpoint_key(opt.averager.endpoint)
+                if tele is not None and opt.averager.endpoint is not None
+                else None
+            ),
+        ),
+        expiration=args.optimizer.statistics_expiration,
+    )
 
 
 def build_loss_fn(model: AlbertForPreTraining) -> Callable:

@@ -42,9 +42,14 @@ from dedloc_tpu.models.swav import (
 from dedloc_tpu.optim.lars import lars
 from dedloc_tpu.optim.schedules import linear_warmup_cosine_annealing
 from dedloc_tpu.parallel.train_step import TrainState, zeros_like_grads
+from dedloc_tpu.telemetry import steps
+from dedloc_tpu.telemetry.profile import profile_gate
+from dedloc_tpu.telemetry.steps import StepRecorder
 from dedloc_tpu.roles.common import (
     build_dht,
     checkpoint_kwargs,
+    open_train_log,
+    publish_step_metrics,
 )
 from dedloc_tpu.utils.backend import ensure_compile_cache
 from dedloc_tpu.utils.checkpoint import save_checkpoint
@@ -277,6 +282,8 @@ def run_swav(args: SwAVCollaborationArguments) -> TrainState:
                         "can collapse the representation — prefer a later "
                         "--training.queue_start_step"
                     )
+            with steps.phase("h2d"):
+                device_crops = _put_crops(crops)
             local["grad_acc"], local["n_acc"], local["batch_stats"], \
                 local["queue"], metrics = accumulate(
                     state.params,
@@ -284,7 +291,7 @@ def run_swav(args: SwAVCollaborationArguments) -> TrainState:
                     local["queue"],
                     local["grad_acc"],
                     local["n_acc"],
-                    _put_crops(crops),
+                    device_crops,
                     jnp.asarray(opt.local_step, jnp.int32),
                     use_queue,
                 )
@@ -293,49 +300,32 @@ def run_swav(args: SwAVCollaborationArguments) -> TrainState:
             state, local["grad_acc"], local["n_acc"], samples
         )
         if _stepped:
+            with steps.phase("post_step"):
+                _after_global_step(loss)
+        return state, {"loss": loss, "global_step": opt.local_step}
+
+    def _after_global_step(loss) -> None:
+        """The tail of a global step, as the ALBERT trainer's: one host
+        read of the loss, the signed metrics bus, the train log — spans
+        ``loss_sync`` / ``publish`` / ``log`` under ``post_step``."""
+        with steps.phase("loss_sync"):
             # advertise the loss for the trunk-health gate — one host sync
             # per GLOBAL step, the same cadence the ALBERT trainer pays
             loss_host = float(loss)
-            opt.report_loss(loss_host)
-            # ride the signed metrics bus like the ALBERT trainer
-            # (run_first_peer.py:176-218 aggregation): the coordinator's
-            # throughput/loss aggregate and swarm-health view work for SwAV
-            # fleets too, with the throttled telemetry tail attached
-            from dedloc_tpu.collaborative.metrics import (
-                LocalMetrics,
-                publish_metrics,
+        opt.report_loss(loss_host)
+        sps = float(opt.performance_ema.samples_per_second)
+        row = steps.train_log_row(steps.current())
+        with steps.phase("publish"):
+            # ride the signed metrics bus like the ALBERT trainer: the
+            # coordinator's throughput/loss aggregate and swarm-health view
+            # work for SwAV fleets too
+            publish_step_metrics(
+                dht, args, _public_key, opt, tele, row,
+                samples=samples, loss=loss_host, mini_steps=1, sps=sps,
             )
-            from dedloc_tpu.telemetry.links import endpoint_key
-
-            publish_metrics(
-                dht,
-                args.dht.experiment_prefix,
-                _public_key,
-                LocalMetrics(
-                    step=opt.local_step,
-                    samples_per_second=float(
-                        opt.performance_ema.samples_per_second
-                    ),
-                    samples_accumulated=samples,
-                    loss=loss_host,
-                    mini_steps=1,
-                    telemetry=(
-                        tele.maybe_snapshot(args.telemetry.snapshot_period)
-                        if tele is not None
-                        else None
-                    ),
-                    # advertised RPC endpoint for the coordinator's link →
-                    # peer-label resolution in the swarm topology fold
-                    endpoint=(
-                        endpoint_key(opt.averager.endpoint)
-                        if tele is not None
-                        and opt.averager.endpoint is not None
-                        else None
-                    ),
-                ),
-                expiration=args.optimizer.statistics_expiration,
-            )
-        return state, {"loss": loss, "global_step": opt.local_step}
+        if train_log is not None:
+            with steps.phase("log"):
+                train_log.write(opt, row, loss_host, sps)
 
     def _put_crops(crops):
         if mesh is None:
@@ -379,7 +369,11 @@ def run_swav(args: SwAVCollaborationArguments) -> TrainState:
             save_every=t.save_steps,
             device_stats_every=t.device_stats_every,
         ),
+        recorder=StepRecorder(
+            telemetry=tele, profile=profile_gate(args.telemetry)
+        ),
     )
+    train_log = open_train_log(t.train_log_path)
     try:
         state, _ctx = trainer.train(
             state,
@@ -387,6 +381,8 @@ def run_swav(args: SwAVCollaborationArguments) -> TrainState:
             max_steps=t.max_local_steps or 10**9,
         )
     finally:
+        if train_log is not None:
+            train_log.close()
         tele_close()
         opt.shutdown()
         dht.shutdown()

@@ -13,19 +13,15 @@ accumulation boundary, not per micro-batch (SURVEY.md §7 hard-part b).
 """
 from __future__ import annotations
 
-import json
-import os
-import time
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from dedloc_tpu.collaborative.metrics import LocalMetrics, publish_metrics
 from dedloc_tpu.collaborative.optimizer import CollaborativeOptimizer
 from dedloc_tpu.telemetry import steps
-from dedloc_tpu.telemetry.links import endpoint_key
+from dedloc_tpu.telemetry.profile import profile_gate
 from dedloc_tpu.telemetry.steps import (
     StepRecorder,
     albert_tflops_per_sample,
@@ -47,6 +43,8 @@ from dedloc_tpu.roles.common import (
     checkpoint_kwargs,
     configure_role_telemetry,
     drop_collator_keys,
+    open_train_log,
+    publish_step_metrics,
     synthetic_mlm_batches,
 )
 from dedloc_tpu.utils.backend import describe_backend, ensure_compile_cache
@@ -375,17 +373,17 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
     mini_steps = 0
     boundary = 0
     last_saved_step = opt.local_step
-    # telemetry: phase timers on the flagship path (vissl PerfStats
-    # capability, vissl/utils/perf_stats.py:12-249). data_wait and the
-    # boundary wall are host-honest; per-micro-batch device time is NOT
-    # blocked on (that would serialize the async dispatch chain) — it shows
-    # up in the boundary wall instead.
+    # the flight recorder (telemetry/steps.py) is the loop's one timer: every
+    # boundary is a record of nested host spans, always timed, published
+    # through the telemetry registry only when that is enabled. It feeds
+    # PerfStats (the operator's --training.log_perf_steps report; vissl
+    # PerfStats capability, vissl/utils/perf_stats.py:12-249) and, with
+    # --telemetry.profile_*, opens the profiler window. Per-micro-batch
+    # device time is NOT blocked on (that would serialize the async dispatch
+    # chain): it is the ``drain`` span inside opt.step, and the device
+    # planes of a profile. The MFU gauge uses the same analytic model-FLOPs
+    # formula and peak table as bench.py.
     perf = PerfStats()
-    # step-phase flight recorder (telemetry/steps.py): per-boundary phase
-    # decomposition + online MFU, published through the telemetry registry
-    # (no-op while telemetry is disabled). The MFU gauge uses the same
-    # analytic model-FLOPs formula and peak table as bench.py, so the
-    # in-situ number is comparable to bench.py's ``mfu`` field.
     from dedloc_tpu.data.mlm import max_predictions_for
 
     recorder = StepRecorder(
@@ -394,33 +392,23 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
             cfg, seq, max_predictions_for(seq)
         ),
         peak_tflops=chip_peak_tflops(),
+        perf=perf,
+        profile=profile_gate(args.telemetry),
     )
-    train_log = None
-    if args.training.train_log_path:
-        os.makedirs(
-            os.path.dirname(os.path.abspath(args.training.train_log_path)),
-            exist_ok=True,
-        )
-        train_log = open(args.training.train_log_path, "a", buffering=1)
-    wall_start = time.perf_counter()
+    train_log = open_train_log(args.training.train_log_path)
+    samples = slice_batch * args.training.gradient_accumulation_steps
     try:
         while True:
             # one accumulation boundary = gradient_accumulation_steps
-            # micro-batches; the flight recorder treats the boundary as ONE
-            # step record (data_wait/h2d/fwd_bwd here, grad_flatten/
-            # avg_wire/opt_apply/collab inside opt.step via the live
-            # step-context)
-            boundary_start = time.perf_counter()
-            data_wait = 0.0
-            with recorder.step(
-                step=opt.local_step,
-                samples=slice_batch * args.training.gradient_accumulation_steps,
-            ) as srec:
+            # micro-batches = ONE step record, which runs to the start of
+            # the next boundary: data_wait/h2d/fwd_bwd here, the optimizer's
+            # spans inside opt.step (which also stamps stepped, samples and
+            # the running totals on the record), the tail of a global step
+            # as post_step
+            with recorder.step(step=opt.local_step) as srec:
                 for _ in range(args.training.gradient_accumulation_steps):
-                    t0 = time.perf_counter()
                     with steps.phase("data_wait"):
                         batch = drop_collator_keys(next(batches))
-                    data_wait += time.perf_counter() - t0
                     if mesh is not None:
                         with steps.phase("h2d"):
                             batch = put_batch(
@@ -431,138 +419,65 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
                                 ),
                                 seq_length=seq,
                             )
-                    data_rng, sub = jax.random.split(data_rng)
                     with steps.phase("fwd_bwd"):
+                        # everything the host enqueues for one micro-batch
+                        data_rng, sub = jax.random.split(data_rng)
                         grad_acc, n_acc, metrics = accumulate(
                             state.params, grad_acc, n_acc, batch, sub
                         )
-                    loss_sum_dev = loss_sum_dev + metrics["loss"]
+                        loss_sum_dev = loss_sum_dev + metrics["loss"]
                     mini_steps += 1
-                if srec is not None:
-                    # recording only: settle the async dispatch chain so
-                    # fwd_bwd measures execution, not dispatch (the
-                    # documented cost of in-situ attribution — opt.step
-                    # device_gets these grads immediately after anyway)
-                    with steps.phase("fwd_bwd"):
-                        jax.block_until_ready((grad_acc, n_acc))
-                # per-BOUNDARY stall so it is directly comparable to the
-                # boundary wall time below
-                perf.metric("data_wait").update(data_wait)
-
-                samples = (
-                    slice_batch * args.training.gradient_accumulation_steps
-                )
-                t0 = time.perf_counter()
                 state, grad_acc, n_acc, stepped = opt.step(
                     state, grad_acc, n_acc, samples
                 )
-                if srec is not None:
-                    srec.attrs["stepped"] = stepped
-                # most boundaries are a cheap DHT progress report; the
-                # averaging round only happens when the collaboration steps
-                # — keep the two in separate metrics or the round cost is
-                # diluted ~targetN x
-                perf.metric(
-                    "allreduce" if stepped else "collab_report"
-                ).update(time.perf_counter() - t0)
-                perf.metric("boundary").update(
-                    time.perf_counter() - boundary_start
-                )
-            if stepped:
-                loss_sum = float(loss_sum_dev)  # the one sync per global step
-                loss_sum_dev = jnp.zeros([])
-                # advertise the loss for the trunk-health gate — free here,
-                # the scalar is already on the host
-                opt.report_loss(loss_sum / max(mini_steps, 1))
-                sps = float(opt.performance_ema.samples_per_second)
-                publish_metrics(
-                    dht,
-                    args.dht.experiment_prefix,
-                    public_key,
-                    LocalMetrics(
-                        step=opt.local_step,
-                        samples_per_second=sps,
-                        samples_accumulated=samples,
-                        loss=loss_sum,
-                        mini_steps=mini_steps,
-                        step_time_ms=perf.metric("boundary").recent_mean * 1e3,
-                        data_wait_ms=perf.metric("data_wait").recent_mean * 1e3,
-                        allreduce_ms=perf.metric("allreduce").recent_mean * 1e3,
-                        hbm_bytes=_hbm_bytes_in_use(),
-                        # throttled counter snapshot for the coordinator's
-                        # swarm-health aggregation (refreshed at most once
-                        # per period; stale-but-present between refreshes)
-                        telemetry=(
-                            tele.maybe_snapshot(args.telemetry.snapshot_period)
-                            if tele is not None
-                            else None
-                        ),
-                        # advertised RPC endpoint: lets the coordinator
-                        # resolve OTHER peers' link destinations to this
-                        # peer's label in the swarm topology fold
-                        endpoint=(
-                            endpoint_key(opt.averager.endpoint)
-                            if tele is not None
-                            and opt.averager.endpoint is not None
-                            else None
-                        ),
-                    ),
-                    expiration=args.optimizer.statistics_expiration,
-                )
-                logger.info(
-                    f"global step {opt.local_step}: loss "
-                    f"{loss_sum / max(mini_steps, 1):.4f}"
-                )
-                if train_log is not None:
-                    train_log.write(
-                        json.dumps(
-                            {
-                                "wall_s": time.perf_counter() - wall_start,
-                                "step": opt.local_step,
-                                "loss": loss_sum / max(mini_steps, 1),
-                                "samples_per_second": sps,
-                                "samples": samples,
-                                "boundary_ms": perf.metric(
-                                    "boundary"
-                                ).recent_mean
-                                * 1e3,
-                                "data_wait_ms": perf.metric(
-                                    "data_wait"
-                                ).recent_mean
-                                * 1e3,
-                                "allreduce_ms": perf.metric(
-                                    "allreduce"
-                                ).recent_mean
-                                * 1e3,
-                                # jit↔host seam breakdown (SURVEY §7b):
-                                # grads device_get / apply / async backup
-                                # list() snapshots atomically under the GIL —
-                                # the backup thread may insert its key mid-step
-                                "seam_ms": {
-                                    k: round(v, 2)
-                                    for k, v in list(opt.seam_ms.items())
-                                },
-                            }
-                        )
-                        + "\n"
-                    )
-                if (
-                    args.training.log_perf_steps
-                    and opt.local_step % args.training.log_perf_steps == 0
-                ):
-                    logger.info("perf phases:\n" + perf.report_str())
-                mini_steps = 0
-                if (
-                    args.training.save_steps
-                    and opt.local_step - last_saved_step
-                    >= args.training.save_steps
-                ):
-                    # cadence by DISTANCE, not divisibility: a collaborative
-                    # local_step can jump over exact multiples (catch-ups
-                    # adopt the global counter), and a modulo check then
-                    # never fires again for the rest of the run
-                    _save(args, state, opt.local_step)
-                    last_saved_step = opt.local_step
+                if stepped:
+                    with steps.phase("post_step"):
+                        with steps.phase("loss_sync"):
+                            # the one sync per global step
+                            loss_sum = float(loss_sum_dev)
+                        loss_sum_dev = jnp.zeros([])
+                        loss = loss_sum / max(mini_steps, 1)
+                        # advertise the loss for the trunk-health gate —
+                        # free here, the scalar is already on the host
+                        opt.report_loss(loss)
+                        sps = float(opt.performance_ema.samples_per_second)
+                        # THIS boundary's values, off its record
+                        row = steps.train_log_row(srec)
+                        with steps.phase("publish"):
+                            publish_step_metrics(
+                                dht, args, public_key, opt, tele, row,
+                                samples=samples, loss=loss_sum,
+                                mini_steps=mini_steps, sps=sps,
+                                hbm_bytes=_hbm_bytes_in_use(),
+                            )
+                        mini_steps = 0
+                        with steps.phase("log"):
+                            logger.info(
+                                f"global step {opt.local_step}: loss "
+                                f"{loss:.4f}"
+                            )
+                            if train_log is not None:
+                                train_log.write(opt, row, loss, sps)
+                            if (
+                                args.training.log_perf_steps
+                                and opt.local_step
+                                % args.training.log_perf_steps == 0
+                            ):
+                                logger.info(
+                                    "perf phases:\n" + perf.report_str()
+                                )
+                            if (
+                                args.training.save_steps
+                                and opt.local_step - last_saved_step
+                                >= args.training.save_steps
+                            ):
+                                # cadence by DISTANCE, not divisibility: a
+                                # collaborative local_step can jump over
+                                # exact multiples (catch-ups adopt the
+                                # global counter), and a modulo check then
+                                # never fires again for the rest of the run
+                                _save(args, state, opt.local_step)
+                                last_saved_step = opt.local_step
 
             boundary += 1
             if (
@@ -572,6 +487,7 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
                 logger.info(f"reached max_local_steps={boundary}; stopping")
                 break
     finally:
+        recorder.close()
         if train_log is not None:
             train_log.close()
         tele_close()

@@ -14,8 +14,9 @@ Record shape (see docs/observability.md):
     {"current_step": N,
      "peers": [{"peer": "ab12…", "step": N, "behind": 0,
                 "rpc_failures": 0.0, "rounds_attempted": 3.0,
-                "phases": {"data_wait": 0.01, "fwd_bwd": 0.4, ...},  # mean s
-                "dominant_phase": "fwd_bwd", "mfu": 0.57,
+                "phases": {"data_wait": 0.01, "fwd_bwd": 0.004,
+                           "drain": 2.9, ...},  # mean SELF seconds per span
+                "dominant_phase": "drain", "mfu": 0.57,
                 "overlap_efficiency": 0.93, ...}, ...],
      "straggler": "<peer label of the worst offender, or None>",
      "retry_rate": <state-sync retries / attempts, swarm-wide>,
@@ -24,7 +25,10 @@ Record shape (see docs/observability.md):
 
 The ``phases``/``dominant_phase``/``mfu``/``overlap_*`` fields come from the
 step-phase flight recorder (``telemetry/steps.py``); peers on pre-recorder
-builds simply lack them — their rows fold unchanged.
+builds simply lack them — their rows fold unchanged. Each mean is over the
+boundaries that HAVE the span (``phase_counts`` says how many): a peer's
+compute is ``fwd_bwd`` (the enqueue, every boundary) plus ``drain`` (the
+wait for the device, on the boundaries that make a global step).
 """
 from __future__ import annotations
 

@@ -39,6 +39,7 @@ import contextvars
 import hashlib
 import json
 import math
+import sys
 import threading
 import uuid
 from collections import deque
@@ -57,6 +58,22 @@ def monotonic_clock() -> float:
     gets plain ``time.monotonic``. Alias of ``timeutils.monotonic`` — kept
     as the registry's public name for clock injection."""
     return timeutils.monotonic()
+
+
+def trace_annotation(name: str, **kwargs):
+    """A ``jax.profiler`` annotation ``dedloc/<name>`` (a step annotation
+    when given ``step_num=``), or None while jax is not imported — nothing
+    here imports it, the simulator runs without. Entered around a span it
+    puts the span on the profiler's clock: any ``jax.profiler`` session that
+    covers the run holds it on the host plane of the same xplane as the
+    device's programs (``telemetry/profile.py`` joins the two). With no
+    session active an annotation costs about a microsecond."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    if kwargs:
+        return jax.profiler.StepTraceAnnotation(f"dedloc/{name}", **kwargs)
+    return jax.profiler.TraceAnnotation(f"dedloc/{name}")
 
 
 # ---------------------------------------------------------------------------
@@ -347,11 +364,16 @@ class Telemetry:
         if remote and caller:
             linkage["caller"] = caller
         token = _TRACE.set((trace_id, span_id, self.peer, False))
+        annotation = trace_annotation(name)
+        if annotation is not None:
+            annotation.__enter__()
         start = self.clock()
         try:
             yield ctx
         finally:
             _TRACE.reset(token)
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
             # clamped at 0: a span that straddles a FakeClock exit sees the
             # clock retreat by the whole fake offset — a huge negative
             # duration would poison the histogram min/mean forever

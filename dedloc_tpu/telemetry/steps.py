@@ -3,71 +3,113 @@
 ``tools/profile_albert.py`` answers "where do the cycles go" offline, by
 marginal-cost ablation on an idle chip (docs/perf.md). This module answers
 the *production* form of the question — "where did step N's wall-clock go,
-on this peer, in this run" — by decomposing every training step into named
-phases and publishing the breakdown through the existing telemetry registry
-(events + histograms + gauges), so the coordinator's swarm-health fold and
-``runlog_summary --steps`` can rank peers by phase skew without attaching a
-profiler to a volunteer's box.
+on this peer, in this run" — by recording every accumulation boundary as a
+TREE of named host spans, and publishing the breakdown through the existing
+telemetry registry (events + histograms + gauges), so the coordinator's
+swarm-health fold and ``runlog_summary --steps`` can rank peers by phase
+skew without attaching a profiler to a volunteer's box.
 
-Canonical phases (docs/observability.md "Step-phase flight recorder"):
+A record runs from the start of one boundary to the start of the next. Its
+spans (docs/observability.md "Step-phase flight recorder"):
 
 - ``data_wait``    host input-pipeline stall (``next(batches)``)
-- ``h2d``          host→device batch transfer (``put_batch`` on a mesh)
-- ``fwd_bwd``      jitted accumulate dispatch + the boundary's
-                   ``block_until_ready`` (XLA runs async — without the
-                   block a timer measures dispatch, not execution)
+- ``h2d``          host→device batch transfer (``put_batch`` on a mesh,
+                   SwAV's crop upload)
+- ``fwd_bwd``      what the host spends ENQUEUEING the jitted accumulate
+                   (XLA runs async; the recorder never blocks on the device)
+- ``drain``        where the program really waits for those accumulates:
+                   the host read of the micro-batch counter at the top of a
+                   global step. A peer's compute is ``fwd_bwd + drain``
+- ``round_plan``   the shape of the round about to run: contribution cap,
+                   ramp / gate weight and their trace events
 - ``grad_flatten`` launching the device-side flatten/quantize program (or,
                    on the legacy path, the per-leaf device_get + host
                    flatten of the mean grads — the jit↔host seam crossing)
-- ``d2h_stream``   the EXPOSED remainder of the async device→host gradient
-                   stream: the transfer overlaps matchmaking (and, in
-                   overlap mode, accumulation), so this phase reads ~0
-                   when the overlap works and grows when the link is the
-                   bottleneck (averaging/device_flat.py)
-- ``avg_wire``     the synchronous averaging round (matchmaking + wire),
-                   net of the exposed D2H wait above
-- ``opt_apply``    optimizer apply + NaN guard
+- ``ef_norm``      telemetry's own: the ``opt.ef_residual_norm`` gauge reads
+                   a scalar off the device (a host sync; telemetry on only)
+- ``avg_wire``     the synchronous averaging round. Children:
+                   ``d2h_stream`` (the EXPOSED remainder of the async
+                   device→host gradient stream, ~0 when matchmaking hides
+                   it) and, measured on the DHT loop's thread by the
+                   averager, ``matchmaking`` (entering the round → group
+                   formed, the wait for the partner included) and
+                   ``allreduce`` (group formed → result)
+- ``opt_apply``    optimizer apply + NaN guard; child ``h2d_result`` (the
+                   averaged flat buffer going back to the device)
+- ``backup_launch`` the on-thread part of the state backup: one host sync
+                   on the apply program + an on-device copy of the state
+- ``acc_reset``    the fresh gradient accumulator after an apply (one small
+                   eager program per leaf)
 - ``collab``       progress-tracker reads/reports (DHT overhead)
+- ``post_step``    the tail of a global step; children ``loss_sync`` (the
+                   one host read of the loss), ``publish`` (signed metrics
+                   to the DHT), ``log`` (log lines, train log, checkpoint)
 
-Phase names are open — instrumented code may record others — but the six
-canonical ones are what the cross-peer skew views key on. Phases must be
-DISJOINT (never nest two live phases): the whole point of the recorder is
-that per-step phase sums track the step wall, so the residual
-(``untimed_s``) measures what the instrumentation missed.
+Span names are open — instrumented code may record others — but the
+canonical ones are what the cross-peer skew views key on. Spans NEST: a
+span opened inside another is its child. ``phases`` in the record is each
+name's SELF time (its duration minus what its children on this thread
+cover), so it is disjoint by construction and Σ phases + ``untimed_s`` =
+wall; ``spans`` carries the tree itself, ``[name, parent, t0_s, t1_s]`` with
+offsets from the record's start (a run of more than 64 spans is folded:
+repeated leaf spans of one name under one parent become ``[name, parent,
+first t0_s, last t1_s, count, total_s]``). Spans ATTACHED from another
+thread's clock readings (``matchmaking`` / ``allreduce``) are in ``spans``
+only: they split their parent, they are not this thread's time.
 
 Design rules, mirroring ``registry.py``:
 
-- **Zero overhead when disabled.** ``StepRecorder.step`` resolves the
-  telemetry registry once; with telemetry off it yields ``None`` and sets
-  no context, and the module-level ``phase()`` helper used by code that
-  does not hold the recorder (the collaborative optimizer) is a single
-  contextvar load returning a shared no-op.
+- **Timing is always on, publishing is not.** ``StepRecorder.step`` always
+  times into its in-memory ring — a handful of clock reads and dict writes
+  per boundary against hundreds of milliseconds of work — so an ordinary
+  run can say which span of a slow global step stalled (one INFO line).
+  Events, histograms, gauges and the JSONL mirror stay behind
+  ``--telemetry.enabled``.
+- **The recorder syncs nothing.** No ``block_until_ready`` is added for a
+  timer's sake: a span measures what the host did, and the device's time is
+  read off the profiler's device planes.
+- **On the profiler's clock.** Each span also enters a
+  ``jax.profiler.TraceAnnotation("dedloc/<name>")`` and each record a
+  ``StepTraceAnnotation("dedloc/boundary", step_num=<boundary>)`` (only
+  when jax is already imported — the simulator builds recorders without
+  it), so any profiler session that covers the run holds the host spans in
+  the same xplane as the device's programs (``telemetry/profile.py``).
 - **FakeClock-compatible.** All timing uses the registry's monotonic
   clock (``registry.monotonic_clock``), which advances with the FakeClock
-  offset — fault-injection tests produce deterministic phase durations.
-- **One event per phase plus one summary.** Each finished step emits a
-  ``step.phase`` event per recorded phase and one ``step.record`` event
-  carrying the full breakdown (wall, samples, per-phase seconds, untimed
-  residual, dominant phase, online MFU); each phase also feeds the
-  ``step.phase.<name>`` histogram so metrics-bus snapshots carry
-  ``step.phase.<name>.mean`` for the coordinator's swarm-health fold.
+  offset — fault-injection tests produce deterministic span durations.
+- **One event per phase plus one summary.** With telemetry on, each
+  finished record emits a ``step.phase`` event per phase (self time) and one
+  ``step.record`` event carrying the full breakdown (wall, samples and
+  running totals, per-phase seconds, spans, untimed residual, dominant
+  phase, online MFU); each phase also feeds the ``step.phase.<name>``
+  histogram so metrics-bus snapshots carry ``step.phase.<name>.mean`` for
+  the coordinator's swarm-health fold.
 """
 from __future__ import annotations
 
 import contextvars
+import statistics
 from collections import deque
 from contextlib import contextmanager
-from typing import Any, Deque, Dict, Iterator, Optional
+from typing import Any, Deque, Dict, Iterator, List, Optional
 
 from dedloc_tpu.telemetry import registry
+from dedloc_tpu.utils.logging import get_logger
 
-# the canonical phase set, in pipeline order — the cross-peer views key on
+logger = get_logger(__name__)
+
+# the canonical span names, in pipeline order — the cross-peer views key on
 # these (tools/runlog_summary.py keeps a deliberate copy, _CANONICAL_PHASES,
 # because the tool is stdlib-only; keep the two in sync)
 PHASES = (
-    "data_wait", "h2d", "fwd_bwd", "grad_flatten", "d2h_stream", "avg_wire",
-    "opt_apply", "collab",
+    "data_wait", "h2d", "fwd_bwd", "drain", "round_plan", "grad_flatten",
+    "ef_norm", "avg_wire",
+    "d2h_stream", "opt_apply", "h2d_result", "backup_launch", "acc_reset",
+    "collab", "post_step", "loss_sync", "publish", "log",
 )
+
+# a record holding more spans than this folds its repeated leaf spans
+MAX_SPANS = 64
 
 # bf16 peak TFLOP/s per chip by PJRT device_kind substring (Google Cloud
 # TPU documentation, per-generation system pages) — THE table: bench.py and
@@ -118,43 +160,168 @@ def albert_tflops_per_sample(cfg, seq: int, max_pred: int) -> float:
     return 3.0 * fwd / 1e12
 
 
+class Span:
+    """One timed region: a context manager that, inside a live record, is a
+    node of its span tree, and outside one just times (``dur_s`` is always
+    there for the call site — ``CollaborativeOptimizer.seam_ms`` reads it)."""
+
+    __slots__ = ("name", "t0", "t1", "children_s", "leaf", "_ctx", "_clock",
+                 "_annotation")
+
+    def __init__(self, name: str, ctx: "Optional[_StepContext]") -> None:
+        self.name = name
+        self.t0 = self.t1 = 0.0
+        self.children_s = 0.0  # what this thread's child spans cover
+        self.leaf = True
+        self._ctx = ctx
+        self._clock = ctx._clock if ctx is not None else registry.monotonic_clock
+        self._annotation = None
+
+    @property
+    def dur_s(self) -> float:
+        return max(0.0, self.t1 - self.t0)
+
+    def elapsed(self) -> float:
+        """Seconds since the span was entered, while it is open."""
+        return max(0.0, self._clock() - self.t0)
+
+    def __enter__(self) -> "Span":
+        self._annotation = registry.trace_annotation(self.name)
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        if self._ctx is not None:
+            self._ctx._stack.append(self)
+        self.t0 = self._clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = self._clock()
+        if self._ctx is not None:
+            self._ctx._close(self)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+
+
 class _StepContext:
-    """The live step being recorded: a mutable phase ledger plus free-form
-    attrs (``ctx.attrs["stepped"] = True``) merged into the final record."""
+    """The live record: the stack of open spans, the closed ones, the
+    per-name self times, plus free-form attrs (``ctx.attrs["stepped"] =
+    True``) merged into the final record."""
 
-    __slots__ = ("phases", "attrs", "step", "samples", "_clock")
+    __slots__ = ("phases", "totals", "spans", "attrs", "step", "boundary",
+                 "samples", "_clock", "_start", "_stack")
 
-    def __init__(self, step: Optional[int], samples: int, clock) -> None:
-        self.phases: Dict[str, float] = {}
+    def __init__(self, step: Optional[int], boundary: int, samples: int,
+                 clock) -> None:
+        self.phases: Dict[str, float] = {}  # name -> SELF seconds
+        self.totals: Dict[str, float] = {}  # name -> seconds, children in
+        # closed spans: [name, parent, t0, t1, leaf] or, folded,
+        # [name, parent, first t0, last t1, True, count, total]
+        self.spans: List[list] = []
         self.attrs: Dict[str, Any] = {}
         self.step = step
+        self.boundary = boundary
         self.samples = int(samples)
         self._clock = clock
+        self._start = clock()
+        self._stack: List[Span] = []
+
+    def elapsed(self) -> float:
+        """Seconds since the record began, on the record's clock."""
+        return max(0.0, self._clock() - self._start)
+
+    def total(self, name: str) -> float:
+        """Seconds the closed spans called ``name`` took so far, their
+        children included."""
+        return self.totals.get(name, 0.0)
+
+    def phase(self, name: str) -> Span:
+        """A span called ``name``, child of whichever span is open now."""
+        return Span(name, self)
 
     def add(self, name: str, seconds: float) -> None:
-        """Credit ``seconds`` to phase ``name`` (accumulates — a phase may
-        be entered many times per step, e.g. data_wait per micro-batch)."""
-        self.phases[name] = self.phases.get(name, 0.0) + max(0.0, seconds)
+        """A span of ``seconds`` that ends now, for call sites that hold a
+        duration and not a region (the exposed part of the D2H stream)."""
+        span = Span(name, self)
+        span.t1 = self._clock()
+        span.t0 = span.t1 - max(0.0, seconds)
+        self._close(span, pop=False)
 
-    @contextmanager
-    def phase(self, name: str, block_on: Any = None) -> Iterator[None]:
-        """Time a region into phase ``name``. ``block_on``: pytree of jax
-        arrays blocked on before the clock stops (the TPU analogue of
-        CUDA-event timing — XLA dispatch is async)."""
-        start = self._clock()
-        try:
-            yield
-        finally:
-            if block_on is not None:
-                import jax
+    def attach(self, name: str, t0: float, t1: float) -> None:
+        """A span read off this clock by ANOTHER thread (the averager's
+        ``matchmaking`` / ``allreduce`` on the DHT loop), as a child of the
+        open span. It splits its parent for the reader; it is not this
+        thread's time, so neither ``phases`` nor the parent's self time
+        change."""
+        parent = self._stack[-1].name if self._stack else None
+        self._record(name, parent, t0, t1, True)
 
-                jax.block_until_ready(block_on)
-            self.add(name, self._clock() - start)
+    # ------------------------------------------------------------- internal
+
+    def _close(self, span: Span, pop: bool = True) -> None:
+        if pop:
+            self._stack.pop()
+        parent = self._stack[-1] if self._stack else None
+        dur = span.dur_s
+        if parent is not None:
+            parent.children_s += dur
+            parent.leaf = False
+        self.phases[span.name] = (
+            self.phases.get(span.name, 0.0) + max(0.0, dur - span.children_s)
+        )
+        self.totals[span.name] = self.totals.get(span.name, 0.0) + dur
+        self._record(
+            span.name, parent.name if parent is not None else None,
+            span.t0, span.t1, span.leaf,
+        )
+
+    def _record(self, name, parent, t0, t1, leaf) -> None:
+        self.spans.append(
+            [name, parent, t0 - self._start, max(t0, t1) - self._start, leaf]
+        )
+        if len(self.spans) > MAX_SPANS:
+            self._fold()
+
+    def _fold(self) -> None:
+        """Merge the leaf spans of one name under one parent into a single
+        entry with a count and a total: a boundary of many micro-batches
+        stays a bounded record."""
+        folded: Dict[tuple, list] = {}
+        out: List[list] = []
+        for span in self.spans:
+            if not span[4]:
+                out.append(span)
+                continue
+            key = (span[0], span[1])
+            count, total = (
+                (span[5], span[6]) if len(span) > 5 else (1, span[3] - span[2])
+            )
+            into = folded.get(key)
+            if into is None:
+                folded[key] = into = [*span[:5], 0, 0.0]
+                out.append(into)
+            into[2] = min(into[2], span[2])
+            into[3] = max(into[3], span[3])
+            into[5] += count
+            into[6] += total
+        self.spans = [
+            s[:5] if len(s) > 5 and s[5] == 1 else s for s in out
+        ]
+
+    def finished_spans(self) -> List[list]:
+        """``[name, parent, t0_s, t1_s]`` (``+ [count, total_s]`` when
+        folded), microsecond precision, in closing order."""
+        if any(len(s) > 5 for s in self.spans):
+            self._fold()  # a record that folded once ends folded throughout
+        return [
+            [s[0], s[1], round(s[2], 6), round(s[3], 6)]
+            + ([s[5], round(s[6], 6)] if len(s) > 5 else [])
+            for s in self.spans
+        ]
 
 
-# the live step context (per-thread / per-task): instrumented code that does
-# not hold the recorder — the collaborative optimizer's grad_flatten /
-# avg_wire / opt_apply seams — attributes its phases through this
+# the live record (per-thread / per-task): instrumented code that does not
+# hold the recorder — the collaborative optimizer, the roles' step
+# functions — opens its spans through this
 _CURRENT: contextvars.ContextVar[Optional[_StepContext]] = (
     contextvars.ContextVar("dedloc_step", default=None)
 )
@@ -164,35 +331,68 @@ def current() -> Optional[_StepContext]:
     return _CURRENT.get()
 
 
-@contextmanager
-def _null() -> Iterator[None]:
-    yield
-
-
-def phase(name: str, block_on: Any = None):
-    """Module-level phase timer: times into the innermost live step record,
-    or no-ops (one contextvar load) when no step is being recorded."""
-    ctx = _CURRENT.get()
-    return ctx.phase(name, block_on) if ctx is not None else _null()
+def phase(name: str) -> Span:
+    """A span in the live record, or a bare timer when no step is being
+    recorded (one contextvar load more than ``Span`` itself)."""
+    return Span(name, _CURRENT.get())
 
 
 def add(name: str, seconds: float) -> None:
-    """Credit pre-measured seconds to the live step record (no-op when none
-    is live) — for call sites that already hold a duration."""
+    """Credit pre-measured seconds to the live record (no-op when none is
+    live) — for call sites that already hold a duration."""
     ctx = _CURRENT.get()
     if ctx is not None:
         ctx.add(name, seconds)
 
 
+def attach(name: str, t0: float, t1: float) -> None:
+    """``_StepContext.attach`` on the live record (no-op when none is)."""
+    ctx = _CURRENT.get()
+    if ctx is not None:
+        ctx.attach(name, t0, t1)
+
+
+def train_log_row(rec: _StepContext) -> Dict[str, Any]:
+    """THIS boundary's values for a role's ``--training.train_log_path``
+    line and its ``LocalMetrics``, read off the live record at the point of
+    publishing (inside ``post_step``): the boundary's wall so far, its data
+    stall, the wall of its ``opt.step`` call, every span's total, and the
+    running totals ``opt.step`` stamped."""
+    row: Dict[str, Any] = {
+        "samples": rec.samples,
+        "boundary_ms": rec.elapsed() * 1e3,
+        "data_wait_ms": rec.total("data_wait") * 1e3,
+        "allreduce_ms": rec.attrs.get("opt_step_s", 0.0) * 1e3,
+        "spans_ms": {k: round(v * 1e3, 3) for k, v in rec.totals.items()},
+    }
+    for key in ("samples_total", "boundaries_total", "global_steps_total"):
+        if key in rec.attrs:
+            row[key] = rec.attrs[key]
+    return row
+
+
 class StepRecorder:
-    """Bounded ring of per-step phase breakdowns + an online MFU gauge.
+    """Bounded ring of per-boundary records + an online MFU gauge + the
+    slow-step notice.
 
     One recorder per trainer loop. ``model_tflops_per_sample`` and
     ``peak_tflops`` enable the MFU gauge (0 disables it — e.g. CPU smoke
     runs); throughput for the gauge is a ring-window mean (samples over
     recorded wall), so it tracks the same quantity the bench headline
-    measures rather than a single noisy step.
+    measures rather than a single noisy step. ``perf`` (a
+    ``utils/perf.PerfStats``) receives every finished record's wall as
+    ``boundary`` and its phases under their names — the operator's
+    ``--training.log_perf_steps`` report, fed from the one timer there is.
+    ``profile`` (``telemetry/profile.ProfileGate``) is told each boundary's
+    index, and opens and closes the profiler window the operator asked for.
     """
+
+    # a global step is slow when its wall exceeds this many times the
+    # running median of the last SLOW_WINDOW global steps (at least
+    # SLOW_MIN_STEPS of them: the first ones hold compilation)
+    SLOW_FACTOR = 1.5
+    SLOW_WINDOW = 32
+    SLOW_MIN_STEPS = 4
 
     def __init__(
         self,
@@ -201,48 +401,73 @@ class StepRecorder:
         peak_tflops: float = 0.0,
         ring: int = 256,
         mfu_window: int = 32,
+        perf=None,
+        profile=None,
     ) -> None:
         self.telemetry = telemetry
         self.model_tflops_per_sample = float(model_tflops_per_sample)
         self.peak_tflops = float(peak_tflops)
         self.records: Deque[Dict[str, Any]] = deque(maxlen=ring)
         self.mfu_window = int(mfu_window)
+        self.perf = perf
+        self.profile = profile
+        self.boundaries = 0  # records begun: the next record's index
+        # the global step being assembled (its records' wall and span
+        # totals), and the last SLOW_WINDOW finished ones
+        self._step_wall = 0.0
+        self._step_totals: Dict[str, float] = {}
+        self._recent_steps: Deque[tuple] = deque(maxlen=self.SLOW_WINDOW)
 
     @contextmanager
     def step(
         self, step: Optional[int] = None, samples: int = 0
-    ) -> Iterator[Optional[_StepContext]]:
-        """Record one training step. Yields the live ``_StepContext`` (or
-        None with telemetry disabled — callers use the yielded value only
-        behind an ``is not None`` check, the disabled path costs one
-        resolve)."""
+    ) -> Iterator[_StepContext]:
+        """Record one accumulation boundary; yields the live record. The
+        timing always runs; what is published (events, histograms, gauges)
+        waits for a telemetry registry."""
         tele = registry.resolve(self.telemetry)
-        if tele is None:
-            yield None
-            return
-        ctx = _StepContext(step, samples, tele.clock)
+        boundary = self.boundaries
+        self.boundaries += 1
+        if self.profile is not None:
+            self.profile.at_boundary(boundary)
+        ctx = _StepContext(
+            step, boundary, samples,
+            tele.clock if tele is not None else registry.monotonic_clock,
+        )
+        annotation = registry.trace_annotation("boundary", step_num=boundary)
+        if annotation is not None:
+            annotation.__enter__()
         token = _CURRENT.set(ctx)
-        start = tele.clock()
         try:
             yield ctx
         finally:
             _CURRENT.reset(token)
-            wall = max(0.0, tele.clock() - start)
+            wall = ctx.elapsed()
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
             self._finish(tele, ctx, wall)
+
+    def close(self) -> None:
+        """End of the loop: a profiler window still open is closed."""
+        if self.profile is not None:
+            self.profile.close()
 
     # ------------------------------------------------------------- internal
 
     def _finish(
-        self, tele: registry.Telemetry, ctx: _StepContext, wall: float
+        self, tele: Optional[registry.Telemetry], ctx: _StepContext,
+        wall: float,
     ) -> None:
         phases = dict(ctx.phases)
         untimed = max(0.0, wall - sum(phases.values()))
         record: Dict[str, Any] = {
             "step": ctx.step,
+            "boundary": ctx.boundary,
             "samples": ctx.samples,
             "wall_s": wall,
             "phases": phases,
             "untimed_s": untimed,
+            "spans": ctx.finished_spans(),
             **ctx.attrs,
         }
         dominant = max(phases, key=phases.get) if phases else None
@@ -252,6 +477,13 @@ class StepRecorder:
         if mfu is not None:
             record["mfu"] = mfu
         self.records.append(record)
+        self._notice_slow_step(record, ctx.totals)
+        if self.perf is not None:
+            self.perf.metric("boundary").update(wall)
+            for name, dur in phases.items():
+                self.perf.metric(name).update(dur)
+        if tele is None:
+            return
         tele.histogram("step.wall").observe(wall)
         for name, dur in phases.items():
             tele.histogram(f"step.phase.{name}").observe(dur)
@@ -259,6 +491,41 @@ class StepRecorder:
         tele.event("step.record", dur_s=wall, **{
             k: v for k, v in record.items() if k != "wall_s"
         })
+
+    def _notice_slow_step(self, record, totals: Dict[str, float]) -> None:
+        """Fold the record into the global step being assembled; when the
+        step completes, compare its wall with the running median and say —
+        ONE line, at INFO: a WARNING is a failed step to whoever counts
+        them — which spans it spent more in than a median step does."""
+        self._step_wall += record["wall_s"]
+        for name, seconds in totals.items():
+            self._step_totals[name] = (
+                self._step_totals.get(name, 0.0) + seconds
+            )
+        if not record.get("stepped"):
+            return
+        wall, spans = self._step_wall, self._step_totals
+        self._step_wall, self._step_totals = 0.0, {}
+        recent = list(self._recent_steps)
+        self._recent_steps.append((wall, spans))
+        if len(recent) < self.SLOW_MIN_STEPS:
+            return
+        median = statistics.median(w for w, _s in recent)
+        if wall <= self.SLOW_FACTOR * median:
+            return
+        usual = {
+            name: statistics.median(s.get(name, 0.0) for _w, s in recent)
+            for name in spans
+        }
+        over = sorted(spans, key=lambda n: usual[n] - spans[n])
+        logger.info(
+            f"slow global step {record.get('step')}: {wall:.3f} s against a "
+            f"median of {median:.3f} s over the last {len(recent)}; spans "
+            "(s, against their median): " + ", ".join(
+                f"{n} {spans[n]:.3f} ({spans[n] - usual[n]:+.3f})"
+                for n in over[:6]
+            )
+        )
 
     def _update_mfu(self, tele, record) -> Optional[float]:
         if self.model_tflops_per_sample <= 0 or self.peak_tflops <= 0:
@@ -272,6 +539,7 @@ class StepRecorder:
             return None
         sps = samples / wall
         mfu = sps * self.model_tflops_per_sample / self.peak_tflops
-        tele.gauge("step.samples_per_sec").set(sps)
-        tele.gauge("step.mfu").set(mfu)
+        if tele is not None:
+            tele.gauge("step.samples_per_sec").set(sps)
+            tele.gauge("step.mfu").set(mfu)
         return mfu

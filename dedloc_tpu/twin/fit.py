@@ -34,8 +34,11 @@ Fitting rules — each one exists to keep the twin honest:
 - **Loss** is connection deaths over transfers (``rpc.conn_lost`` events
   per endpoint; per-peer ``conns_lost``/``rpc_calls`` from a swarm-health
   fold), clamped to the simulator's meaningful range.
-- **Compute** is the ``step.phase.fwd_bwd`` mean per peer (event logs:
-  ``step.record`` phases; coordinator JSONL: the folded ``phases`` map).
+- **Compute** is ``fwd_bwd + drain`` per boundary, per peer: what the host
+  spends enqueueing the accumulates plus where it waits for them (the
+  recorder blocks on nothing, so neither alone is the device's time). Event
+  logs: ``step.record`` phases; coordinator JSONL: the folded ``phases``
+  means, ``drain``'s weighted by how many boundaries have one.
 - **Nothing is fitted silently.** Every dimension that degrades to a
   default lands in ``coverage`` — the fit of a jammed, truncated or
   pre-link-schema log *reports* its blind spots instead of hiding them.
@@ -210,6 +213,21 @@ def _resolve_label(dst: str, labels: set, endpoint_map: Dict[str, str]):
     if host in labels:
         return host
     return None
+
+
+
+def _compute_seconds(phases, counts=None) -> Optional[float]:
+    """A peer's compute per boundary off its step phases: ``fwd_bwd`` (the
+    enqueue) plus ``drain`` (the wait for the device, on the boundaries that
+    make a global step; ``counts`` — per-phase sample counts beside folded
+    means — weights it by how many boundaries have one)."""
+    fwd = phases.get("fwd_bwd")
+    if fwd is None:
+        return None
+    drain = float(phases.get("drain") or 0.0)
+    if counts and counts.get("fwd_bwd"):
+        drain *= float(counts.get("drain", 0.0)) / float(counts["fwd_bwd"])
+    return float(fwd) + drain
 
 
 def fit_twin(rows: List[Dict[str, Any]],
@@ -595,11 +613,15 @@ def fit_twin(rows: List[Dict[str, Any]],
     for r in events:
         if r.get("event") == ev.STEP_RECORD:
             step_records.setdefault(str(r.get("peer", "?")), []).append(r)
-    health_phases: Dict[str, Dict[str, float]] = {}
+    health_compute: Dict[str, float] = {}
     for health in healths:  # newest record wins per peer
         for p in health.get("peers", []):
             if isinstance(p, dict) and isinstance(p.get("phases"), dict):
-                health_phases[safe_label(p.get("peer", "?"))] = p["phases"]
+                compute = _compute_seconds(
+                    p["phases"], p.get("phase_counts") or {}
+                )
+                if compute is not None:
+                    health_compute[safe_label(p.get("peer", "?"))] = compute
 
     peers: Dict[str, Dict[str, float]] = {}
     peers_with_compute = 0
@@ -608,16 +630,15 @@ def fit_twin(rows: List[Dict[str, Any]],
         samples = DEFAULT_SAMPLES_PER_BOUNDARY
         records = step_records.get(label, [])
         fwd = [
-            float(r["phases"]["fwd_bwd"]) for r in records
-            if isinstance(r.get("phases"), dict)
-            and r["phases"].get("fwd_bwd") is not None
+            c for c in (
+                _compute_seconds(r["phases"]) for r in records
+                if isinstance(r.get("phases"), dict)
+            ) if c is not None
         ]
         if fwd:
             compute = sum(fwd) / len(fwd)
-        elif label in health_phases and (
-            health_phases[label].get("fwd_bwd") is not None
-        ):
-            compute = float(health_phases[label]["fwd_bwd"])
+        elif label in health_compute:
+            compute = health_compute[label]
         sample_values = [
             float(r["samples"]) for r in records
             if r.get("samples") is not None

@@ -1,4 +1,4 @@
-"""Step-phase performance timers + profiler gate.
+"""Step-phase performance timers.
 
 Capability of vissl's PerfTimer/PerfMetric/PerfStats (reference:
 swav/vissl/vissl/utils/perf_stats.py:12-249) — context-manager timers wrapped
@@ -13,8 +13,9 @@ TPU-native differences from the reference:
   async — without blocking, a timer around a jitted call measures dispatch,
   not execution.
 - whole-program tracing goes through ``jax.profiler`` (xplane traces viewable
-  in tensorboard/xprof) behind one config flag — the §5 "tracing behind one
-  flag" requirement — instead of per-op CUDA events.
+  in tensorboard/xprof) behind one gate both roles share —
+  ``telemetry/profile.py``, ``--telemetry.profile_dir`` — instead of per-op
+  CUDA events.
 
 Unified with the swarm-telemetry clock (docs/observability.md): PerfStats
 times on ``telemetry.registry.monotonic_clock`` — real monotonic time in
@@ -31,7 +32,7 @@ from __future__ import annotations
 import math
 from collections import deque
 from contextlib import contextmanager
-from typing import Any, Deque, Dict, Iterator, Optional
+from typing import Any, Deque, Dict, Iterator
 
 from dedloc_tpu.telemetry import registry as _telemetry
 
@@ -141,23 +142,3 @@ class PerfStats:
 
     def reset(self) -> None:
         self.metrics.clear()
-
-
-@contextmanager
-def profiler_trace(log_dir: Optional[str]) -> Iterator[None]:
-    """Gate a ``jax.profiler`` trace behind one flag (§5 tracing requirement).
-
-    ``log_dir`` falsy → no-op. Otherwise emits an xplane trace for the wrapped
-    region (replaces vissl's MONITOR_PERF_STATS + CUDA-event plumbing,
-    defaults.yaml:81-83, with the XLA-native profiler).
-    """
-    if not log_dir:
-        yield
-        return
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()

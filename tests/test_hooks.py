@@ -14,7 +14,8 @@ from dedloc_tpu.core.hooks import (
     MetricsPublisherHook,
     default_hooks,
 )
-from dedloc_tpu.utils.perf import PerfStats, profiler_trace
+from dedloc_tpu.telemetry.profile import ProfileGate, profile_gate
+from dedloc_tpu.utils.perf import PerfStats
 
 
 class Recorder(Hook):
@@ -113,19 +114,29 @@ def test_perf_stats_disabled_is_noop():
     assert stats.report() == {}
 
 
-def test_profiler_trace_noop_without_dir():
-    with profiler_trace(None):
-        pass
-    with profiler_trace(""):
-        pass
+def test_profile_gate_absent_without_dir_or_telemetry():
+    from dedloc_tpu.core.config import TelemetryArguments
+
+    assert profile_gate(TelemetryArguments(enabled=True, profile_dir="")) is None
+    # the gate sits behind --telemetry.enabled like everything that writes
+    assert profile_gate(
+        TelemetryArguments(enabled=False, profile_dir="/tmp/x")
+    ) is None
 
 
-def test_profiler_trace_writes(tmp_path):
+def test_profile_gate_writes_its_window(tmp_path):
     import jax.numpy as jnp
 
-    with profiler_trace(str(tmp_path)):
+    gate = ProfileGate(str(tmp_path), first=1, count=2)
+    gate.at_boundary(0)
+    assert not any(tmp_path.rglob("*"))  # before the window: nothing started
+    for boundary in (1, 2):
+        gate.at_boundary(boundary)
         (jnp.ones((4, 4)) * 2).block_until_ready()
-    assert any(tmp_path.rglob("*"))  # xplane artifacts written
+    gate.at_boundary(3)  # the window is over: stopped, on its own thread
+    gate.close()  # waits until the profile is on disk
+    assert any(tmp_path.rglob("*.xplane.pb"))
+    gate.close()  # idempotent
 
 
 def test_device_stats_hook_runs(monkeypatch, caplog):

@@ -3,7 +3,7 @@ timer path, the enabled=False no-op, the falsy profiler gate, and the
 report formatting BASELINE tables are copied from."""
 import pytest
 
-from dedloc_tpu.utils.perf import PerfMetric, PerfStats, profiler_trace
+from dedloc_tpu.utils.perf import PerfMetric, PerfStats
 
 
 def test_timer_block_on_blocks_before_stopping_the_clock():
@@ -35,15 +35,23 @@ def test_disabled_stats_record_nothing():
     assert stats.report() == {}
 
 
-def test_profiler_trace_falsy_log_dir_is_a_noop():
-    """A falsy log_dir must gate the whole jax.profiler path off — the body
-    still runs, nothing is traced, nothing is imported or started."""
-    ran = []
-    with profiler_trace(None):
-        ran.append("none")
-    with profiler_trace(""):
-        ran.append("empty")
-    assert ran == ["none", "empty"]
+def test_profile_gate_rejects_a_malformed_window():
+    """``--telemetry.profile_boundaries`` is ``<first>:<count>``; anything
+    else fails at role start, not at the window."""
+    import pytest
+
+    from dedloc_tpu.core.config import TelemetryArguments
+    from dedloc_tpu.telemetry.profile import profile_gate
+
+    gate = profile_gate(TelemetryArguments(
+        enabled=True, profile_dir="/tmp/p", profile_boundaries="8:4"
+    ))
+    assert (gate.first, gate.count) == (8, 4)
+    for bad in ("8", "a:b", "4:0", "-1:3"):
+        with pytest.raises(ValueError, match="profile_boundaries"):
+            profile_gate(TelemetryArguments(
+                enabled=True, profile_dir="/tmp/p", profile_boundaries=bad
+            ))
 
 
 def test_report_str_formats_known_values():

@@ -62,6 +62,28 @@ def test_trainer_single_peer_makes_global_steps(tmp_path):
     # the log's directory is the trainer's to create
     rows = [json.loads(line) for line in train_log.read_text().splitlines()]
     assert len(rows) >= 2 and all(np.isfinite(r["loss"]) for r in rows)
+    # each line holds THIS global step's values, read off its step record
+    # (no recent means), and the optimizer's running totals
+    for row in rows:
+        assert row["samples"] == 4
+        assert 0 < row["data_wait_ms"] < row["boundary_ms"]
+        assert 0 < row["allreduce_ms"] < row["boundary_ms"]
+        assert {"data_wait", "fwd_bwd", "drain", "grad_flatten", "opt_apply",
+                "collab", "loss_sync"} <= set(row["spans_ms"])
+        assert row["spans_ms"]["data_wait"] == pytest.approx(
+            row["data_wait_ms"], abs=1e-3
+        )
+    totals = [r["samples_total"] for r in rows]
+    assert totals == sorted(totals) and totals[-1] <= 7 * 4
+    assert [r["global_steps_total"] for r in rows] == list(
+        range(1, len(rows) + 1)
+    )
+    # telemetry is off: no registry, no event log, no profile — the output
+    # directory holds the checkpoints and the train log's own directory
+    from dedloc_tpu.telemetry import registry
+
+    assert registry.active() is None
+    assert sorted(p.name for p in tmp_path.iterdir()) == ["logs", "out"]
 
 
 def test_trainer_resumes_from_checkpoint(tmp_path):

@@ -1,0 +1,477 @@
+"""The step record as a span tree on the profiler's clock (ISSUE 23):
+nesting and self times, the spans bound, the running totals stamped by
+``CollaborativeOptimizer.step``, a real ``jax.profiler`` session holding the
+``dedloc/*`` host events, the profile reader (``attribute_idle``) on a
+synthetic profile and on one recorded on a TPU v5e, and the slow-step
+notice."""
+import glob
+import logging
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from dedloc_tpu.telemetry import profile, registry, steps
+from dedloc_tpu.telemetry.registry import Telemetry
+from dedloc_tpu.telemetry.steps import MAX_SPANS, StepRecorder
+from dedloc_tpu.testing.faults import FakeClock
+
+pytestmark = pytest.mark.telemetry
+
+FIXTURE = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "fixtures",
+    "albert_solo_profile_v5e.json.gz",
+)
+
+
+def _span_seconds(span):
+    return span[5] if len(span) > 4 else span[3] - span[2]
+
+
+def approx(expected):
+    """A FakeClock offsets the real clock: real microseconds leak in."""
+    return pytest.approx(expected, abs=2e-3)
+
+
+# ------------------------------------------------------------------- the tree
+
+
+def test_nested_spans_give_self_times_that_sum_to_the_wall():
+    rec = StepRecorder()
+    with FakeClock() as clock:
+        with rec.step(step=7) as srec:
+            with steps.phase("data_wait"):
+                clock.advance(0.25)
+            with steps.phase("fwd_bwd"):  # the SwAV shape: a parent
+                clock.advance(0.5)  # its own time
+                with steps.phase("h2d"):
+                    clock.advance(0.125)
+                with steps.phase("post_step"):
+                    with steps.phase("loss_sync"):
+                        clock.advance(1.0)
+                    clock.advance(0.0625)
+                    with steps.phase("publish"):
+                        clock.advance(0.5)
+            clock.advance(0.03125)  # nobody's: untimed
+            assert srec.total("fwd_bwd") == approx(2.1875)
+    record = rec.records[-1]
+    assert record["phases"] == approx({
+        "data_wait": 0.25, "fwd_bwd": 0.5, "h2d": 0.125,
+        "post_step": 0.0625, "loss_sync": 1.0, "publish": 0.5,
+    })
+    assert record["untimed_s"] == approx(0.03125)
+    assert sum(record["phases"].values()) + record["untimed_s"] == (
+        approx(record["wall_s"])
+    )
+    tree = {(s[0], s[1]): (s[2], s[3]) for s in record["spans"]}
+    assert tree[("loss_sync", "post_step")] == approx((0.875, 1.875))
+    assert tree[("post_step", "fwd_bwd")] == approx((0.875, 2.4375))
+    assert tree[("fwd_bwd", None)] == approx((0.25, 2.4375))
+    assert record["boundary"] == 0 and record["step"] == 7
+    with rec.step():
+        pass
+    assert rec.records[-1]["boundary"] == 1  # the recorder's own index
+
+
+def test_added_span_counts_and_attached_span_does_not():
+    """``add`` is this thread's time under the open span (the exposed D2H
+    wait inside ``avg_wire``); ``attach`` is another thread's reading of the
+    same wall (the averager's matchmaking / all-reduce split): in ``spans``,
+    never in ``phases``."""
+    rec = StepRecorder()
+    with FakeClock() as clock:
+        with rec.step():
+            with steps.phase("avg_wire") as wire:
+                start = registry.monotonic_clock()
+                clock.advance(1.0)
+                steps.add("d2h_stream", 0.25)
+                steps.attach("matchmaking", start, start + 0.75)
+                steps.attach("allreduce", start + 0.75, start + 1.0)
+    assert wire.dur_s == approx(1.0)
+    record = rec.records[-1]
+    assert record["phases"] == approx(
+        {"avg_wire": 0.75, "d2h_stream": 0.25}
+    )
+    assert record["untimed_s"] == approx(0.0)
+    spans = {s[0]: s for s in record["spans"]}
+    assert spans["matchmaking"][1] == spans["allreduce"][1] == "avg_wire"
+    assert _span_seconds(spans["matchmaking"]) + _span_seconds(
+        spans["allreduce"]
+    ) == approx(_span_seconds(spans["avg_wire"]))
+
+
+def test_a_span_outside_any_record_still_times():
+    """``CollaborativeOptimizer.seam_ms`` reads ``dur_s`` whether or not a
+    role is recording."""
+    assert steps.current() is None
+    with FakeClock() as clock:
+        with steps.phase("opt_apply") as span:
+            clock.advance(0.5)
+    assert span.dur_s == approx(0.5)
+
+
+def test_spans_are_bounded_by_folding_repeated_leaves():
+    rec = StepRecorder()
+    with FakeClock() as clock:
+        with rec.step():
+            for _ in range(100):  # a boundary of 100 micro-batches
+                with steps.phase("data_wait"):
+                    clock.advance(0.01)
+                with steps.phase("fwd_bwd"):
+                    clock.advance(0.02)
+            with steps.phase("post_step"):
+                with steps.phase("publish"):
+                    clock.advance(0.5)
+    record = rec.records[-1]
+    assert len(record["spans"]) <= MAX_SPANS
+    by_name = {s[0]: s for s in record["spans"]}
+    assert by_name["data_wait"][4:] == [100, approx(1.0)]
+    assert by_name["fwd_bwd"][4:] == [100, approx(2.0)]
+    assert by_name["fwd_bwd"][2:4] == approx([0.01, 3.0])
+    assert by_name["publish"][:2] == ["publish", "post_step"]
+    assert by_name["publish"][2:] == approx([3.0, 3.5])
+    # the fold loses no time: phases and wall are untouched by it
+    assert record["phases"]["fwd_bwd"] == approx(2.0)
+    assert sum(record["phases"].values()) == approx(record["wall_s"])
+
+
+# ------------------------------------------------- published when telemetry is on
+
+
+def test_enabled_telemetry_publishes_the_tree():
+    tele = Telemetry(peer="p0")
+    rec = StepRecorder(telemetry=tele)
+    with FakeClock() as clock:
+        with rec.step(step=3):
+            with steps.phase("opt_apply"):
+                clock.advance(0.25)
+                with steps.phase("h2d_result"):
+                    clock.advance(0.125)
+    (event,) = [e for e in tele.events if e["event"] == "step.record"]
+    assert [s[:2] for s in event["spans"]] == [
+        ["h2d_result", "opt_apply"], ["opt_apply", None],
+    ]
+    assert [s[2:] for s in event["spans"]] == [
+        approx([0.25, 0.375]), approx([0.0, 0.375]),
+    ]
+    assert event["boundary"] == 0
+    snapshot = tele.snapshot()
+    assert snapshot["step.phase.opt_apply.mean"] == approx(0.25)
+    assert snapshot["step.phase.h2d_result.mean"] == approx(0.125)
+
+
+# -------------------------- the optimizer fills the record, in the core loop
+
+
+def test_core_trainer_record_with_a_real_optimizer_is_disjoint_and_counted():
+    """SwAV's shape: ``core.Trainer`` wraps the whole step function — H2D,
+    dispatch AND ``opt.step`` with its own spans — in ``fwd_bwd``. The
+    record stays disjoint, and ``opt.step`` itself stamps it."""
+    from dedloc_tpu.collaborative import CollaborativeOptimizer
+    from dedloc_tpu.core.trainer import Trainer
+    from dedloc_tpu.dht import DHT
+    from dedloc_tpu.optim import lamb
+    from dedloc_tpu.parallel.train_step import (
+        TrainState,
+        make_accumulate_step,
+        zeros_like_grads,
+    )
+
+    def toy_loss(params, batch, rng):
+        loss = jnp.mean((batch["x"] @ params["w"] - batch["y"]) ** 2)
+        return loss, {"loss": loss}
+
+    dht = DHT(start=True, listen_host="127.0.0.1")
+    tx = lamb(0.05, weight_decay=0.0)
+    opt = CollaborativeOptimizer(
+        tx, dht, "corespans", target_batch_size=32,
+        metadata_expiration=0.2, averaging_expiration=0.5,
+        averaging_timeout=5.0, listen_host="127.0.0.1",
+        min_refresh_period=0.05, default_refresh_period=0.1,
+    )
+    try:
+        time.sleep(0.5)  # past the cold-start grace: the solo path
+        params = {"w": jnp.array([[0.5], [0.5]])}
+        accumulate = make_accumulate_step(toy_loss)
+        x = jax.random.normal(jax.random.PRNGKey(0), (16, 2))
+        batch = {"x": x, "y": x @ jnp.array([[1.0], [-2.0]])}
+        local = {"acc": zeros_like_grads(params), "n": jnp.zeros([], jnp.int32)}
+
+        def step_fn(state, data):
+            with steps.phase("h2d"):
+                data = jax.device_put(data)
+            local["acc"], local["n"], metrics = accumulate(
+                state.params, local["acc"], local["n"], data,
+                jax.random.PRNGKey(0),
+            )
+            state, local["acc"], local["n"], stepped = opt.step(
+                state, local["acc"], local["n"], 16
+            )
+            if stepped:
+                with steps.phase("post_step"), steps.phase("loss_sync"):
+                    float(metrics["loss"])
+            return state, {"loss": metrics["loss"]}
+
+        trainer = Trainer(step_fn)
+        trainer.train(
+            TrainState.create(params, tx), iter([batch] * 12), max_steps=12
+        )
+    finally:
+        opt.shutdown()
+        dht.shutdown()
+
+    records = list(trainer.recorder.records)
+    assert len(records) == 12
+    assert any(r["stepped"] for r in records)
+    running = 0
+    for record in records:
+        assert record["samples"] == 16
+        running += record["samples"]
+        assert record["samples_total"] == running  # monotone, the running sum
+        assert sum(record["phases"].values()) <= record["wall_s"] + 1e-9
+        assert sum(record["phases"].values()) + record["untimed_s"] == (
+            pytest.approx(record["wall_s"])
+        )
+        assert 0 < record["opt_step_s"] <= record["wall_s"]
+        parents = {s[0]: s[1] for s in record["spans"]}
+        assert parents["fwd_bwd"] is None and parents["h2d"] == "fwd_bwd"
+        assert parents["collab"] == "fwd_bwd"  # opened inside opt.step
+        assert parents["loss_sync"] in (None, "post_step")
+    steps_seen = [r["global_steps_total"] for r in records]
+    assert steps_seen == sorted(steps_seen) and steps_seen[-1] >= 1
+    assert [r["boundaries_total"] for r in records] == list(range(1, 13))
+    stepping = next(r for r in records if r["stepped"])
+    parents = {s[0]: s[1] for s in stepping["spans"]}
+    for name in ("drain", "grad_flatten", "opt_apply", "backup_launch",
+                 "post_step"):
+        assert parents[name] == "fwd_bwd", (name, stepping["spans"])
+
+
+# ------------------------------------------------------- the profiler's clock
+
+
+def test_a_profiler_session_holds_the_spans_on_the_host_plane(tmp_path):
+    """A real ``jax.profiler`` session on the CPU backend: the host plane
+    carries ``dedloc/boundary`` (with its ``step_num``) and one
+    ``dedloc/<span>`` per span, and their durations are the record's."""
+    rec = StepRecorder()
+    double = jax.jit(lambda x: x @ x)
+    x = jnp.ones((64, 64))
+    double(x).block_until_ready()
+    profile.start_session(str(tmp_path))
+    try:
+        for step in range(3):
+            with rec.step(step=step):
+                with steps.phase("fwd_bwd"):
+                    y = double(x)
+                with steps.phase("drain"):
+                    y.block_until_ready()
+                    time.sleep(0.003)
+                    with steps.phase("inner"):
+                        time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    assert glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    loaded = profile.load_profile(str(tmp_path))
+    (thread,) = [
+        t for t, spans in loaded["hosts"].items()
+        if any(n == "boundary" for n, _s, _d in spans)
+    ]
+    events = loaded["hosts"][thread]
+    for name in ("boundary", "fwd_bwd", "drain", "inner"):
+        on_plane = [d / 1e9 for n, _s, d in events if n == name]
+        if name == "boundary":
+            in_record = [r["wall_s"] for r in rec.records]
+        else:
+            in_record = [
+                _span_seconds(s) for r in rec.records for s in r["spans"]
+                if s[0] == name
+            ]
+        assert len(on_plane) == len(in_record) == 3
+        assert on_plane == pytest.approx(in_record, abs=1e-3), name
+    # nested on the plane as in the record: inner sits inside its drain
+    drains = [(s, s + d) for n, s, d in events if n == "drain"]
+    for _n, s, d in (e for e in events if e[0] == "inner"):
+        assert any(a <= s and s + d <= b for a, b in drains)
+
+
+def test_telemetry_spans_are_on_the_profiler_plane_too(tmp_path):
+    tele = Telemetry(peer="p0")
+    profile.start_session(str(tmp_path))
+    try:
+        with tele.span("mm.form_group", round_id="r1"):
+            time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    events = [
+        ev for spans in profile.load_profile(str(tmp_path))["hosts"].values()
+        for ev in spans
+    ]
+    (span,) = [ev for ev in events if ev[0] == "mm.form_group"]
+    (logged,) = [e for e in tele.events if e["event"] == "mm.form_group"]
+    assert span[2] / 1e9 == pytest.approx(logged["dur_s"], abs=1e-3)
+
+
+# -------------------------------------------------------------- attribute_idle
+
+
+def _ms(*events):
+    return [(n, s * 1e6, d * 1e6) for n, s, d in events]
+
+
+def test_attribute_idle_on_a_synthetic_profile():
+    """Two devices; the idler one decides. Its gaps: 10-20 ms (the host in
+    ``opt_apply``, then ``backup_launch``), 120-130 ms (``data_wait`` then
+    ``fwd_bwd`` on peer 0; peer 1 in ``avg_wire``), 230-232 ms (between spans:
+    the root).
+    A 50 us helper program stays inside its gap."""
+    synthetic = {
+        "devices": {
+            "/device:TPU:0": _ms(
+                ("jit_accumulate_step(1)", 0, 10),
+                ("jit_convert_element_type(2)", 12, 0.05),
+                ("jit_accumulate_step(1)", 20, 100),
+                ("jit_accumulate_step(1)", 130, 100),
+                ("jit_accumulate_step(1)", 232, 10),
+            ),
+            "/device:TPU:1": _ms(
+                ("jit_accumulate_step(1)", 0, 120),
+                ("jit_accumulate_step(1)", 121, 121),
+            ),
+        },
+        "hosts": {
+            "bench-peer0": _ms(
+                ("boundary", 0, 125), ("boundary", 125, 120),
+                ("opt_apply", 5, 11), ("h2d_result", 6, 2),
+                ("backup_launch", 16, 6), ("data_wait", 110, 15),
+                ("data_wait", 125, 4.5), ("fwd_bwd", 129.5, 1),
+                ("fwd_bwd", 225, 5),
+            ),
+            "bench-peer1": _ms(
+                ("boundary", 100, 100), ("avg_wire", 110, 80),
+                ("d2h_stream", 121, 4),
+            ),
+            "dht-loop": _ms(("mm.form_group", 119, 3)),
+        },
+    }
+    result = profile.attribute_idle(synthetic)
+    assert result["device"] == "/device:TPU:0"
+    assert result["idle_s"] == pytest.approx(0.022)
+    assert result["window_s"] == pytest.approx(0.242)
+    peer0 = result["threads"]["bench-peer0"]
+    assert peer0["peer"] and peer0["idle_s"] == pytest.approx(0.022)
+    assert peer0["spans"] == pytest.approx({
+        "opt_apply": 0.006, "backup_launch": 0.004,
+        "data_wait": 0.005 + 0.0045, "boundary": 0.002,
+        "fwd_bwd": 0.0005,
+    })
+    assert peer0["named_share"] == pytest.approx(1 - 0.002 / 0.022)
+    # the other peer thread is named too, over what ITS records bracket
+    peer1 = result["threads"]["bench-peer1"]
+    assert peer1["peer"] and peer1["spans"] == pytest.approx(
+        {"avg_wire": 0.006, "d2h_stream": 0.004}
+    )
+    # a thread with spans but no records (a DHT loop): reported, not a peer
+    loop = result["threads"]["dht-loop"]
+    assert not loop["peer"]
+    assert loop["spans"] == pytest.approx({"mm.form_group": 0.002})
+
+
+def test_attribute_idle_needs_a_device_plane():
+    with pytest.raises(ValueError, match="no device plane"):
+        profile.attribute_idle({"devices": {}, "hosts": {}})
+
+
+def test_profile_cli_prints_gaps_by_span(tmp_path, capsys):
+    saved = tmp_path / "p.json.gz"
+    profile.save_profile({
+        "devices": {"/device:TPU:0": _ms(("jit_a(1)", 0, 10), ("jit_a(1)", 20, 10))},
+        "hosts": {"main": _ms(("boundary", 0, 30), ("drain", 9, 12))},
+    }, str(saved))
+    assert profile.main([str(saved)]) == 0
+    out = capsys.readouterr().out
+    assert "idle 0.0100 s of 0.0300 s" in out
+    assert "peer thread main" in out and "100.0 % under a named span" in out
+    assert "drain" in out
+
+
+def test_attribute_idle_on_a_recorded_chip_profile():
+    """``albert_large_s512.solo`` on a TPU v5e, 30 boundaries profiled
+    through ``--telemetry.profile_dir`` (my chip run, PR 23): the host's
+    spans and the device's programs of ONE xplane, in the neutral form. The
+    clocks are shared to a millisecond or two: the host's ``drain`` (a read
+    that waits for the queued accumulates) returns just after the last
+    ``accumulate_step`` ends on the device, the solo boundary's programs
+    start on the device inside the host spans that launch them — and so
+    the idle time between programs can be charged to spans by name."""
+    assert os.path.getsize(FIXTURE) <= 200 * 1024
+    recorded = profile.load_saved(FIXTURE)
+    (device,) = recorded["devices"]
+    (thread,) = recorded["hosts"]
+    programs, spans = recorded["devices"][device], recorded["hosts"][thread]
+
+    def host(name):
+        return [(s, s + d) for n, s, d in spans if n == name]
+
+    def on_device(name):
+        return [(s, s + d) for n, s, d in programs if n.startswith(f"jit_{name}(")]
+
+    assert len(host("boundary")) == 30 and len(on_device("accumulate_step")) == 60
+    drains = host("drain")
+    assert len(drains) == 2  # two global steps
+    for _start, end in drains:
+        last_accumulate = max(
+            e for _s, e in on_device("accumulate_step") if e <= end + 5e6
+        )
+        assert 0 <= end - last_accumulate <= 5e6  # ns: read 1.5 ms on the chip
+    for launched, span in (("_fused_mean_clip", "grad_flatten"),
+                           ("guarded_apply_step", "opt_apply")):
+        for start, _end in on_device(launched):
+            assert any(a <= start <= b for a, b in host(span)), (launched, span)
+
+    result = profile.attribute_idle(recorded)
+    row = result["threads"][thread]
+    assert result["device"] == device and row["peer"]
+    assert result["idle_s"] == pytest.approx(0.156, abs=1e-3)
+    assert row["idle_s"] == pytest.approx(result["idle_s"])
+    assert row["named_share"] >= 0.99
+    # after a drain the device waits for the boundary's own host work
+    assert list(row["spans"])[:3] == ["collab", "backup_launch", "acc_reset"]
+
+
+# ------------------------------------------------------------ slow-step notice
+
+
+def test_slow_global_step_is_one_info_line(caplog):
+    rec = StepRecorder()
+
+    def global_step(clock, step, wire):
+        for boundary in range(3):
+            with rec.step(step=step) as srec:
+                with steps.phase("fwd_bwd"):
+                    clock.advance(0.25)
+                if boundary == 2:
+                    with steps.phase("avg_wire"):
+                        clock.advance(wire)
+                    srec.attrs["stepped"] = True
+
+    package_logger = logging.getLogger("dedloc_tpu")  # does not propagate
+    package_logger.addHandler(caplog.handler)
+    try:
+        with FakeClock() as clock:
+            for step in range(8):
+                global_step(clock, step, 0.25)
+            assert not [r for r in caplog.records if "slow global" in r.message]
+            global_step(clock, 8, 2.0)  # 2.75 s against a median of 1.0 s
+            global_step(clock, 9, 0.25)
+    finally:
+        package_logger.removeHandler(caplog.handler)
+    lines = [r for r in caplog.records if "slow global step" in r.message]
+    assert len(lines) == 1
+    assert lines[0].levelno == logging.INFO  # a WARNING is a failed step
+    message = lines[0].getMessage()
+    assert "slow global step 8: 2.750 s against a median of 1.000 s" in message
+    assert "avg_wire 2.000 (+1.750)" in message
+    assert message.index("avg_wire") < message.index("fwd_bwd")

@@ -41,15 +41,26 @@ spec.loader.exec_module(runlog_summary)
 # ------------------------------------------------------------ recorder units
 
 
-def test_recorder_noop_when_telemetry_disabled():
+def test_recorder_times_but_publishes_nothing_when_telemetry_disabled(
+    tmp_path, monkeypatch
+):
+    monkeypatch.chdir(tmp_path)
+    assert registry.active() is None
     rec = StepRecorder()  # no injected registry, no global installed
-    with rec.step(step=1, samples=8) as srec:
-        assert srec is None
-        # the module-level helper must be a no-op too (one contextvar load)
-        with steps.phase("data_wait"):
-            pass
-    assert not rec.records
+    with FakeClock() as clock:
+        with rec.step(step=1, samples=8) as srec:
+            # the timing is always on: the record is live, and the
+            # module-level helper times into it
+            assert srec is steps.current()
+            with steps.phase("data_wait"):
+                clock.advance(0.5)
     assert steps.current() is None
+    assert rec.records[-1]["phases"] == pytest.approx(
+        {"data_wait": 0.5}, abs=2e-3
+    )
+    # publishing is not: no registry appeared, no file of any kind
+    assert registry.active() is None
+    assert not list(tmp_path.iterdir())
 
 
 def test_recorder_records_phases_events_histograms():
@@ -619,6 +630,34 @@ def test_attribution_data_stall_vs_slow_wire_two_peers(tmp_path, capsys):
             f"{name}: phases cover only {phase_sum / wall:.1%} of wall "
             f"(records: {rec.records})"
         )
+
+    # a networked round's record splits avg_wire two ways: its own child
+    # d2h_stream, and the averager's reading of the same wall on the DHT
+    # loop's thread (last_round_timing) — the wait for the group, then the
+    # all-reduce — which together account for the round (the hop onto the
+    # loop and back is all that is left out)
+    for name, rec in recorders.items():
+        networked = [
+            r for r in rec.records
+            if any(s[0] == "matchmaking" for s in r["spans"])
+        ]
+        assert networked, f"{name}: no round with the averager's split"
+        for record in networked:
+            spans = {s[0]: s for s in record["spans"]}
+            assert spans["matchmaking"][1] == spans["allreduce"][1] == "avg_wire"
+            wire = spans["avg_wire"][3] - spans["avg_wire"][2]
+            split = (
+                spans["matchmaking"][3] - spans["matchmaking"][2]
+                + spans["allreduce"][3] - spans["allreduce"][2]
+            )
+            assert 0 < split <= wire + 1e-6
+            assert split >= wire - 0.05, (wire, split)
+            # the attached split is not this thread's time: phases keep
+            # today's meaning (avg_wire net of the exposed D2H wait)
+            assert "matchmaking" not in record["phases"]
+            assert record["phases"]["avg_wire"] == pytest.approx(
+                wire - record["phases"].get("d2h_stream", 0.0), abs=1e-6
+            )
 
     # the operator view: --steps over the two event logs names the phases
     runlog_summary.main(["--steps", logs["stall"], logs["wire"]])

@@ -95,6 +95,7 @@ def test_swav_role_end_to_end(tmp_path):
             "--training.total_steps", "50",
             "--training.save_steps", "2",
             "--training.output_dir", str(tmp_path / "out"),
+            "--training.train_log_path", str(tmp_path / "logs" / "train.jsonl"),
             # 2 boundaries of 2x2 samples per global step
             "--optimizer.target_batch_size", "8",
             "--averager.averaging_expiration", "1.0",
@@ -105,6 +106,26 @@ def test_swav_role_end_to_end(tmp_path):
     state = run_swav(args)
     assert int(state.step) >= 1, "should have made at least one global step"
     assert list_checkpoints(args.training.output_dir)
+    # the same train-log line as the ALBERT trainer, off the same step
+    # record: this boundary's values and the optimizer's running totals
+    import json
+
+    rows = [
+        json.loads(line)
+        for line in (tmp_path / "logs" / "train.jsonl").read_text().splitlines()
+    ]
+    assert rows and all(np.isfinite(r["loss"]) for r in rows)
+    for row in rows:
+        assert row["samples"] == 4 and row["samples_total"] % 4 == 0
+        assert 0 < row["allreduce_ms"] <= row["boundary_ms"]
+        # the spans closed so far (the core loop's fwd_bwd, which wraps
+        # them all in SwAV, is still open when the line is written)
+        assert {"h2d", "drain", "opt_apply", "loss_sync"} <= set(
+            row["spans_ms"]
+        )
+    assert [r["global_steps_total"] for r in rows] == list(
+        range(1, len(rows) + 1)
+    )
     # the queue path was actually crossed (queue_start_step=1 semantics,
     # swav_1node_resnet_submit.yaml:95): not just configured, ENGAGED
     assert any("queue engaged" in m for m in records), records

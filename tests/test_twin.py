@@ -385,8 +385,11 @@ def test_fit_coordinator_jsonl_with_topology_and_phases():
                 {"peer": "aa", "step": 9, "rpc_calls": 50.0,
                  "conns_lost": 5.0,
                  "phases": {"fwd_bwd": 0.4, "data_wait": 0.05}},
+                # compute is fwd_bwd (the enqueue) + drain (the wait for
+                # the device, on the 1 boundary in 4 that has one)
                 {"peer": "bb", "step": 9, "rpc_calls": 60.0,
-                 "phases": {"fwd_bwd": 0.2}},
+                 "phases": {"fwd_bwd": 0.02, "drain": 0.72},
+                 "phase_counts": {"fwd_bwd": 40.0, "drain": 10.0}},
             ],
             "topology": {
                 "peers": {"aa": "10.0.0.1:7", "bb": "10.0.0.2:7"},

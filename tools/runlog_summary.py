@@ -916,8 +916,10 @@ def print_topology(all_rows):
 # coordinator's swarm_health.peers[].phases carries the folded means.)
 
 _CANONICAL_PHASES = (
-    "data_wait", "h2d", "fwd_bwd", "grad_flatten", "d2h_stream", "avg_wire",
-    "opt_apply", "collab",
+    "data_wait", "h2d", "fwd_bwd", "drain", "round_plan", "grad_flatten",
+    "ef_norm", "avg_wire",
+    "d2h_stream", "opt_apply", "h2d_result", "backup_launch", "acc_reset",
+    "collab", "post_step", "loss_sync", "publish", "log",
 )
 
 
