@@ -200,7 +200,10 @@ class AlbertSelfAttention(nn.Module):
         if cfg.attention_impl == "flash":
             # fused Pallas kernel: scores stay in VMEM, flash backward
             # (attention dropout is 0.0 in the reference recipe, so the
-            # fused path loses nothing)
+            # fused path loses nothing). The kernels index the projections'
+            # [B, S, H·D] directly — split_heads and the reshape back are
+            # the same bytes, nothing is transposed — and tag q/k/v
+            # "flash_qkv" for the fused_ln remat policy
             from dedloc_tpu.ops.flash_attention import flash_attention
 
             kv_bias = attn_bias[:, 0, 0, :]  # additive [B, S_kv]
@@ -379,8 +382,10 @@ def remat_policy_object(name: str):
             )
         ),
         # fused-LN recipe (pairs with cfg.fused_ln): save ONLY the
-        # named matmul outputs (q/k/v in flash layout, FFN up) plus
-        # every Pallas kernel's outputs — flash (out, lse) and the
+        # named matmul outputs ("flash_qkv": the q/k/v projections as
+        # the dense layers write them, [B, S, H·D], which is the layout
+        # the flash kernels read; "ffn_up") plus every Pallas kernel's
+        # outputs — flash (out in that same layout, lse) and the
         # fused add+LN's (y, x̂, rstd). The backward then replays no
         # elementwise chain; dropping the two out-projection dot
         # saves pays for the x̂ residuals, so HBM is ~neutral vs

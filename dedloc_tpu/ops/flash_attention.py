@@ -16,17 +16,27 @@ S: sequence length is bounded by HBM, not VMEM (verified S=16k on a v5e).
 
 Backward follows the standard flash recipe: save only (out, logsumexp) as
 residuals, recompute probability tiles on the fly in two kernels (dq over
-query blocks, kv innermost; dk/dv over kv blocks, q innermost) using
-delta = rowsum(dO ⊙ O).
+query blocks, kv innermost; dk/dv over kv blocks, q innermost) — or in ONE
+when a single tile covers the sequence — with delta = rowsum(dO ⊙ O) taken
+inside the kernels from the dO and O blocks they load.
 
-Layout contract: [B, S, H, D] in/out (the model's layout); internally heads
-fold into the grid as [B*H, S, D]. Per-position scalars (bias, lse, delta)
-ride as ROW vectors [BH, 1, S]: a [BH, S, 1] column layout would be
-128×-padded by the TPU's (8, 128) tiling — 2 GB of HBM for S=16k — so rows
-travel packed and are transposed to columns in VMEM where the math needs
-them. The additive bias is per KV position (0 keep / -inf drop), broadcast
-over heads — exactly the mask bias AlbertModel builds; it is
-non-differentiable (it comes from the attention mask).
+Layout contract: ONE layout, the model's. q, k, v, dO go in and out, dq, dk,
+dv come out as [B, S, H·D], the array a dense projection writes and the
+out-projection reads ([B, S, H, D] at the public entry is the same bytes);
+no transpose on either side, so what the remat policy stashes is what the
+kernels read. A BlockSpec block is (1, block, W) at (b, j, p): a COLUMN
+BLOCK of g adjacent heads, g = the fewest whose g·D lanes fill whole
+128-lane tiles (2 at D=64, 1 at D=128) — or the whole width H·D where the
+head count does not divide into such blocks (the tiny test models). Inside
+a block the g heads are separated by zeroing the other heads' lanes of one
+operand of each product (``_only_head``). A program takes several column
+blocks (``_pick_heads``); batch is a grid axis. Per-position scalars ride
+as ROW vectors — the additive bias [B, 1, S], lse [B·H, 1, S]: a [.., S, 1]
+column layout would be 128×-padded by the TPU's (8, 128) tiling — 2 GB of
+HBM for S=16k — so rows travel packed and are transposed to columns in
+VMEM where the math needs them. The bias is per KV position (0 keep / -inf
+drop), the same for every head — exactly the mask bias AlbertModel builds;
+it is non-differentiable (it comes from the attention mask).
 
 Off-TPU (CPU tests, CI) the same kernels run under ``interpret=True``
 (``utils.backend.pallas_interpret`` decides, once, for every op here).
@@ -34,11 +44,14 @@ Off-TPU (CPU tests, CI) the same kernels run under ``interpret=True``
 On a multi-device mesh a Mosaic kernel cannot be partitioned by GSPMD, so
 ``flash_attention(..., mesh=...)`` runs the custom-VJP op under
 ``jax.shard_map``: batch over "data", heads over "model" where the mesh has
-that axis, the sequence whole on every device.
+that axis (a shard is [B/dp, S, (H/tp)·D]), the sequence whole on every
+device.
 """
 from __future__ import annotations
 
+import collections
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -70,13 +83,94 @@ def _t(x):
     return jnp.swapaxes(x, -1, -2)
 
 
+# --------------------------------------------- heads inside a column block
+
+
+def _heads_per_block(h: int, d: int) -> int:
+    """Adjacent heads that share one column block: the fewest whose lanes
+    fill whole 128-lane tiles (2 at D=64, 1 at D=128), or all ``h`` heads —
+    the whole width, the one other block Mosaic takes — when those do not
+    divide the head count (the tiny test models, an odd head count)."""
+    g = 128 // math.gcd(128, d)
+    return g if h % g == 0 else h
+
+
+def _pick_heads(h: int, g: int, block_q: int, block_k: int,
+                budget_mb: float) -> int:
+    """Heads per program, in whole column blocks of ``g``: amortise
+    grid-step overhead while keeping the per-head transient (fp32 scores +
+    bf16 probs ≈ 6·Bq·Bk bytes) within a conservative VMEM budget (~16
+    MB/core total on v5e)."""
+    per_head_mb = 6.0 * block_q * block_k / 2**20
+    n = max(1, 8 // g)
+    while n > 1 and ((h // g) % n or n * g * per_head_mb > budget_mb):
+        n //= 2
+    return n * g
+
+
+def _geometry(q, d: int, block_q: int, block_k: int, budget_mb: float):
+    """(B, S, H, heads per column block, heads per program, Bq, Bk) for
+    [B, S, H·D] operands."""
+    b, s, width = q.shape
+    h = width // d
+    g = _heads_per_block(h, d)
+    bq = _pick_block(s, block_q)
+    bk = _pick_block(s, block_k)
+    return b, s, h, g, _pick_heads(h, g, bq, bk, budget_mb), bq, bk
+
+
+def _column_blocks(width: int, g: int, d: int):
+    """(first head, lane slice) of each column block of a [N, width] tile."""
+    w = g * d
+    return [(c * g, slice(c * w, (c + 1) * w)) for c in range(width // w)]
+
+
+def _only_head(x, i: int, d: int):
+    """``x`` [N, g·D] with the lanes of every head but the i-th zeroed.
+    Contracting over all g·D lanes then gives head i's product exactly (the
+    other terms are zeros) at the MXU passes a D-deep contraction takes
+    anyway, and a product WITH it lands in head i's lanes of a [N, g·D]
+    tile and nowhere else — so the g heads of a block sum into a tile that
+    is already in the model's layout. (Lane slices and a concatenate, the
+    other way, measured 5-10 % slower on a v5e.)"""
+    if x.shape[-1] == d:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((lane >= i * d) & (lane < (i + 1) * d), x,
+                     jnp.zeros_like(x))
+
+
+def _per_head_lanes(columns, d: int):
+    """g per-head columns [N, 1] -> [N, g·D], column i across head i's
+    lanes (to scale a block's accumulator head by head)."""
+    *first, out = columns
+    lane = jax.lax.broadcasted_iota(
+        jnp.int32, (out.shape[0], len(columns) * d), 1
+    )
+    for i in reversed(range(len(first))):
+        out = jnp.where(lane < (i + 1) * d, first[i], out)
+    return out
+
+
+def _dot(a, b, contract_a: int, contract_b: int):
+    return jax.lax.dot_general(
+        a, b, (((contract_a,), (contract_b,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _add(total, term):
+    return term if total is None else total + term
+
+
 # ------------------------------------------------------------------ forward
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, scale, gh, packed):
-    kb = pl.program_id(2)
-    nk = pl.num_programs(2)
+                acc_ref, m_ref, l_ref, *, scale, d, g):
+    kb = pl.program_id(3)
+    nk = pl.num_programs(3)
+    blocks = _column_blocks(q_ref.shape[-1], g, d)
 
     @pl.when(kb == 0)
     def _init():
@@ -84,386 +178,301 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    # gh heads per program (unrolled): one grid step's DMAs and semaphore
-    # work amortise over gh heads' matmuls — at D=64 the per-head dots are
-    # too small to hide the per-program overhead (measured on v5e).
-    for g in range(gh):
-        q = q_ref[g]  # [Bq, D]
-        k = k_ref[g]  # [Bk, D]
-        v = v_ref[g]
-        b = bias_ref[g]  # [1, Bk]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale + b.astype(jnp.float32)
+    b = bias_ref[:].astype(jnp.float32)  # [1, Bk]
+    # several column blocks per program (unrolled): one grid step's DMAs and
+    # semaphore work amortise over their heads' matmuls — at D=64 the
+    # per-head dots are too small to hide the per-program overhead
+    # (measured on v5e)
+    for h0, cols in blocks:
+        q = q_ref[:, cols]  # [Bq, g·D]: g heads side by side
+        k = k_ref[:, cols]  # [Bk, g·D]
+        v = v_ref[:, cols]
+        pv, corrs = None, []
+        for i in range(g):
+            h = h0 + i
+            s = _dot(q, _only_head(k, i, d), 1, 1) * scale + b
 
-        # softmax state lives as COLUMNS [Bq, 1] in scratch (it never touches
-        # HBM) so the running max/denominator broadcast against s with zero
-        # cross-lane relayouts; only the lse OUTPUT is a row (HBM tiling).
-        m_prev, l_prev = m_ref[g], l_ref[g]  # [Bq, 1] columns
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)  # [Bq, 1]
-        l_ref[g] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[g] = m_new
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_ref[g] = acc_ref[g] * corr + pv
+            # softmax state lives as COLUMNS [Bq, 1] in scratch (it never
+            # touches HBM) so the running max/denominator broadcast against
+            # s with zero cross-lane relayouts; only the lse OUTPUT is a row
+            # (HBM tiling).
+            m_prev, l_prev = m_ref[h], l_ref[h]  # [Bq, 1] columns
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)  # [Bq, 1]
+            l_ref[h] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+            m_ref[h] = m_new
+            corrs.append(corr)
+            pv = _add(
+                pv, _dot(p.astype(v.dtype), _only_head(v, i, d), 1, 0)
+            )
+        acc_ref[:, cols] = acc_ref[:, cols] * _per_head_lanes(corrs, d) + pv
 
     @pl.when(kb == nk - 1)
     def _flush():
-        d = q_ref.shape[-1]
-        for g in range(gh):
-            safe_l = jnp.maximum(l_ref[g], 1e-30)  # [Bq, 1]
-            o = (acc_ref[g] / safe_l).astype(o_ref.dtype)
-            if packed:
-                # PAIRED output layout: two D=64 heads share one 128-lane
-                # tile, so the (remat-saved) output has no lane padding in
-                # HBM — half the residual bytes of a [..., 64] layout
-                o_ref[g // 2, :, (g % 2) * d:(g % 2 + 1) * d] = o
-            else:
-                o_ref[g] = o
-            lse_ref[g] = _t(m_ref[g] + jnp.log(safe_l))  # -> [1, Bq] row
+        for h0, cols in blocks:
+            safe_l = [
+                jnp.maximum(l_ref[h0 + i], 1e-30) for i in range(g)
+            ]  # [Bq, 1] each
+            o_ref[:, cols] = (
+                acc_ref[:, cols] / _per_head_lanes(safe_l, d)
+            ).astype(o_ref.dtype)
+            for i in range(g):
+                lse_ref[h0 + i] = _t(  # -> [1, Bq] row
+                    m_ref[h0 + i] + jnp.log(safe_l[i])
+                )
 
 
-def _pick_heads(bh: int, block_q: int, block_k: int, budget_mb: float = 6.0):
-    """Heads per program: amortise grid-step overhead while keeping the
-    per-head transient (fp32 scores + bf16 probs ≈ 6·Bq·Bk bytes) within a
-    conservative VMEM budget (~16 MB/core total on v5e)."""
-    per_head_mb = 6.0 * block_q * block_k / 2**20
-    g = 8
-    while g > 1 and (bh % g or g * per_head_mb > budget_mb):
-        g //= 2
-    return g
-
-
-def _fwd(q3, k3, v3, bias3, block_q, block_k, interpret):
-    """Returns (out, lse). ``out`` is [BH//2, S, 2D] PAIRED when D < 128 and
-    the head-group size is even (no lane padding in HBM — matters because
-    the remat policy saves this tensor per layer), else [BH, S, D]."""
-    bh, s, d = q3.shape
-    bq = _pick_block(s, block_q)
-    bk = _pick_block(s, block_k)
-    gh = _pick_heads(bh, bq, bk)
-    packed = d < 128 and gh % 2 == 0
-    scale = 1.0 / (d ** 0.5)
-    if packed:
-        out_spec = pl.BlockSpec((gh // 2, bq, 2 * d),
-                                lambda i, j, kb: (i, j, 0))
-        out_shape = jax.ShapeDtypeStruct((bh // 2, s, 2 * d), q3.dtype)
-    else:
-        out_spec = pl.BlockSpec((gh, bq, d), lambda i, j, kb: (i, j, 0))
-        out_shape = jax.ShapeDtypeStruct((bh, s, d), q3.dtype)
+def _fwd(q, k, v, bias, d, block_q, block_k, interpret):
+    """Returns (out [B, S, H·D], lse [B·H, 1, S])."""
+    b, s, h, g, hp, bq, bk = _geometry(q, d, block_q, block_k, budget_mb=6.0)
+    hpb = h // hp  # programs across the width
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, gh=gh, packed=packed),
-        grid=(bh // gh, s // bq, s // bk),
+        functools.partial(_fwd_kernel, scale=1.0 / (d ** 0.5), d=d, g=g),
+        grid=(b, hpb, s // bq, s // bk),
         in_specs=[
-            pl.BlockSpec((gh, bq, d), lambda i, j, kb: (i, j, 0)),
-            pl.BlockSpec((gh, bk, d), lambda i, j, kb: (i, kb, 0)),
-            pl.BlockSpec((gh, bk, d), lambda i, j, kb: (i, kb, 0)),
-            pl.BlockSpec((gh, 1, bk), lambda i, j, kb: (i, 0, kb)),
+            pl.BlockSpec((None, bq, hp * d), lambda n, p, j, kb: (n, j, p)),
+            pl.BlockSpec((None, bk, hp * d), lambda n, p, j, kb: (n, kb, p)),
+            pl.BlockSpec((None, bk, hp * d), lambda n, p, j, kb: (n, kb, p)),
+            pl.BlockSpec((None, 1, bk), lambda n, p, j, kb: (n, 0, kb)),
         ],
         out_specs=[
-            out_spec,
-            pl.BlockSpec((gh, 1, bq), lambda i, j, kb: (i, 0, j)),
+            pl.BlockSpec((None, bq, hp * d), lambda n, p, j, kb: (n, j, p)),
+            pl.BlockSpec((hp, 1, bq),
+                         lambda n, p, j, kb: (n * hpb + p, 0, j)),
         ],
         out_shape=[
-            out_shape,
-            jax.ShapeDtypeStruct((bh, 1, s), jnp.float32),
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((b * h, 1, s), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((gh, bq, d), jnp.float32),
-            pltpu.VMEM((gh, bq, 1), jnp.float32),
-            pltpu.VMEM((gh, bq, 1), jnp.float32),
+            pltpu.VMEM((bq, hp * d), jnp.float32),
+            pltpu.VMEM((hp, bq, 1), jnp.float32),
+            pltpu.VMEM((hp, bq, 1), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
-    )(q3, k3, v3, bias3)
+    )(q, k, v, bias)
     return out, lse
-
-
-def _unpack_heads(out, bh: int, d: int):
-    """[BH//2, S, 2D] paired -> [BH, S, D] (cheap relayout; inverse pairing
-    of the fwd kernel's flush)."""
-    if out.shape[0] == bh:
-        return out
-    half, s, _ = out.shape
-    return out.reshape(half, s, 2, d).transpose(0, 2, 1, 3).reshape(bh, s, d)
 
 
 # ----------------------------------------------------------------- backward
 
+_Head = collections.namedtuple("_Head", "p ds q k do")
 
-def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, delta_ref,
-               dq_ref, dq_acc_ref, *, scale, gh):
-    kb = pl.program_id(2)
-    nk = pl.num_programs(2)
+
+def _backward_heads(refs, bias_ref, lse_ref, h0, cols, *, scale, d, g):
+    """The g heads of one column block (heads ``h0``.., lanes ``cols`` of
+    the program's tiles), one at a time: each head's probability tile ``p``
+    and the gradient ``ds`` of its scores ([Bq, Bk], recomputed from the
+    residuals in fp32, cast for the MXU), with q, k and dO cut down to that
+    head's lanes for the products that follow."""
+    # dO stays in its native (bf16) dtype for the dots — MXU at full rate
+    q, k, v, do, o = (ref[:, cols] for ref in refs)
+    # delta = rowsum(dO ⊙ O) per head, as the COLUMN the math needs
+    prod = do.astype(jnp.float32) * o.astype(jnp.float32)
+    b = bias_ref[:].astype(jnp.float32)  # [1, Bk]
+    for i in range(g):
+        k_i = _only_head(k, i, d)
+        s = _dot(q, k_i, 1, 1) * scale + b
+        p = jnp.exp(s - _t(lse_ref[h0 + i]))  # [1, Bq] row -> column
+        dp = _dot(do, _only_head(v, i, d), 1, 1)
+        delta = jnp.sum(_only_head(prod, i, d), axis=-1, keepdims=True)
+        ds = p * (dp - delta) * scale
+        yield _Head(p.astype(do.dtype), ds.astype(q.dtype),
+                    _only_head(q, i, d), k_i, _only_head(do, i, d))
+
+
+def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
+               dq_ref, dq_acc_ref, *, scale, d, g):
+    kb = pl.program_id(3)
+    nk = pl.num_programs(3)
 
     @pl.when(kb == 0)
     def _init():
         dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
 
-    for g in range(gh):
-        q = q_ref[g]
-        k = k_ref[g]
-        v = v_ref[g]
-        b = bias_ref[g]  # [1, Bk]
-        do = do_ref[g]  # native (bf16) dtype — MXU runs at full rate
-        lse = _t(lse_ref[g])  # [1, Bq] row -> [Bq, 1] column
-        delta = _t(delta_ref[g])
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale + b.astype(jnp.float32)
-        p = jnp.exp(s - lse)  # [Bq, Bk]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta) * scale
-        dq_acc_ref[g] = dq_acc_ref[g] + jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    for h0, cols in _column_blocks(q_ref.shape[-1], g, d):
+        dq = dq_acc_ref[:, cols]
+        for head in _backward_heads(
+            (q_ref, k_ref, v_ref, do_ref, o_ref), bias_ref, lse_ref, h0,
+            cols, scale=scale, d=d, g=g,
+        ):
+            dq = dq + _dot(head.ds, head.k, 1, 0)
+        dq_acc_ref[:, cols] = dq
 
     @pl.when(kb == nk - 1)
     def _flush():
-        for g in range(gh):
-            dq_ref[g] = dq_acc_ref[g].astype(dq_ref.dtype)
+        dq_ref[:] = dq_acc_ref[:].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, delta_ref,
-                dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, scale, gh):
-    qb = pl.program_id(2)
-    nq = pl.num_programs(2)
+def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
+                dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, scale, d, g):
+    qb = pl.program_id(3)
+    nq = pl.num_programs(3)
 
     @pl.when(qb == 0)
     def _init():
         dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
 
-    for g in range(gh):
-        q = q_ref[g]
-        k = k_ref[g]
-        v = v_ref[g]
-        b = bias_ref[g]  # [1, Bk]
-        do = do_ref[g]
-        lse = _t(lse_ref[g])  # [Bq, 1]
-        delta = _t(delta_ref[g])
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale + b.astype(jnp.float32)
-        p = jnp.exp(s - lse)  # [Bq, Bk]
-        dv_acc_ref[g] = dv_acc_ref[g] + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta) * scale  # [Bq, Bk]
-        dk_acc_ref[g] = dk_acc_ref[g] + jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    for h0, cols in _column_blocks(q_ref.shape[-1], g, d):
+        dk, dv = dk_acc_ref[:, cols], dv_acc_ref[:, cols]
+        for head in _backward_heads(
+            (q_ref, k_ref, v_ref, do_ref, o_ref), bias_ref, lse_ref, h0,
+            cols, scale=scale, d=d, g=g,
+        ):
+            dv = dv + _dot(head.p, head.do, 0, 0)
+            dk = dk + _dot(head.ds, head.q, 0, 0)
+        dk_acc_ref[:, cols] = dk
+        dv_acc_ref[:, cols] = dv
 
     @pl.when(qb == nq - 1)
     def _flush():
-        for g in range(gh):
-            dk_ref[g] = dk_acc_ref[g].astype(dk_ref.dtype)
-            dv_ref[g] = dv_acc_ref[g].astype(dv_ref.dtype)
+        dk_ref[:] = dk_acc_ref[:].astype(dk_ref.dtype)
+        dv_ref[:] = dv_acc_ref[:].astype(dv_ref.dtype)
 
 
 def _dqkv_fused_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref,
-                       delta_ref, dq_ref, dk_ref, dv_ref, *, scale, gh):
+                       o_ref, dq_ref, dk_ref, dv_ref, *, scale, d, g):
     """Single-block backward: when one (Bq, Bk) tile covers the whole
     sequence, dq/dk/dv share ONE score/prob computation and one set of
     input DMAs instead of recomputing them in two kernels."""
-    for g in range(gh):
-        q = q_ref[g]
-        k = k_ref[g]
-        v = v_ref[g]
-        b = bias_ref[g]  # [1, Bk]
-        do = do_ref[g]
-        lse = _t(lse_ref[g])  # [Bq, 1]
-        delta = _t(delta_ref[g])
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale + b.astype(jnp.float32)
-        p = jnp.exp(s - lse)  # [Bq, Bk]
-        pb = p.astype(do.dtype)
-        dv_ref[g] = jax.lax.dot_general(
-            pb, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(dv_ref.dtype)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = (p * (dp - delta) * scale).astype(q.dtype)  # [Bq, Bk]
-        dq_ref[g] = jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(dq_ref.dtype)
-        dk_ref[g] = jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(dk_ref.dtype)
+    for h0, cols in _column_blocks(q_ref.shape[-1], g, d):
+        dq = dk = dv = None
+        for head in _backward_heads(
+            (q_ref, k_ref, v_ref, do_ref, o_ref), bias_ref, lse_ref, h0,
+            cols, scale=scale, d=d, g=g,
+        ):
+            dv = _add(dv, _dot(head.p, head.do, 0, 0))
+            dq = _add(dq, _dot(head.ds, head.k, 1, 0))
+            dk = _add(dk, _dot(head.ds, head.q, 0, 0))
+        dq_ref[:, cols] = dq.astype(dq_ref.dtype)
+        dk_ref[:, cols] = dk.astype(dk_ref.dtype)
+        dv_ref[:, cols] = dv.astype(dv_ref.dtype)
 
 
-def _bwd(q3, k3, v3, bias3, lse, do, delta, block_q, block_k, interpret):
-    bh, s, d = q3.shape
-    bq = _pick_block(s, block_q)
-    bk = _pick_block(s, block_k)
-    scale = 1.0 / (d ** 0.5)
-    if bq == s and bk == s:
-        return _bwd_fused(q3, k3, v3, bias3, lse, do, delta, interpret)
+def _bwd(q, k, v, bias, lse, do, out, d, block_q, block_k, interpret):
     # bwd transients per head are ~3x the fwd's (s, p, dp, ds live at once)
-    gh = _pick_heads(bh, bq, bk, budget_mb=4.0)
+    b, s, h, g, hp, bq, bk = _geometry(q, d, block_q, block_k, budget_mb=4.0)
+    if bq == s and bk == s:
+        return _bwd_fused(q, k, v, bias, lse, do, out, d, interpret)
+    hpb = h // hp
+    kernel_args = dict(scale=1.0 / (d ** 0.5), d=d, g=g)
+
+    # grid (B, programs across the width, outer S block, inner S block):
+    # each spec says which of the two S positions it follows
+    def wide(rows, at):
+        return pl.BlockSpec((None, rows, hp * d),
+                            lambda n, p, x, y: (n, at(x, y), p))
+
+    def in_specs(q_at, k_at):  # q, k, v, bias, lse, dO, O
+        return [
+            wide(bq, q_at), wide(bk, k_at), wide(bk, k_at),
+            pl.BlockSpec((None, 1, bk),
+                         lambda n, p, x, y: (n, 0, k_at(x, y))),
+            pl.BlockSpec((hp, 1, bq),
+                         lambda n, p, x, y: (n * hpb + p, 0, q_at(x, y))),
+            wide(bq, q_at), wide(bq, q_at),
+        ]
+
+    outer, inner = (lambda x, y: x), (lambda x, y: y)
 
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, gh=gh),
-        grid=(bh // gh, s // bq, s // bk),
-        in_specs=[
-            pl.BlockSpec((gh, bq, d), lambda i, j, kb: (i, j, 0)),
-            pl.BlockSpec((gh, bk, d), lambda i, j, kb: (i, kb, 0)),
-            pl.BlockSpec((gh, bk, d), lambda i, j, kb: (i, kb, 0)),
-            pl.BlockSpec((gh, 1, bk), lambda i, j, kb: (i, 0, kb)),
-            pl.BlockSpec((gh, 1, bq), lambda i, j, kb: (i, 0, j)),
-            pl.BlockSpec((gh, bq, d), lambda i, j, kb: (i, j, 0)),
-            pl.BlockSpec((gh, 1, bq), lambda i, j, kb: (i, 0, j)),
-        ],
-        out_specs=pl.BlockSpec((gh, bq, d), lambda i, j, kb: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, s, d), q3.dtype),
-        scratch_shapes=[pltpu.VMEM((gh, bq, d), jnp.float32)],
+        functools.partial(_dq_kernel, **kernel_args),
+        grid=(b, hpb, s // bq, s // bk),
+        in_specs=in_specs(q_at=outer, k_at=inner),
+        out_specs=wide(bq, outer),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((bq, hp * d), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dq",
-    )(q3, k3, v3, bias3, lse, do, delta)
+    )(q, k, v, bias, lse, do, out)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, gh=gh),
-        grid=(bh // gh, s // bk, s // bq),
-        in_specs=[
-            pl.BlockSpec((gh, bq, d), lambda i, j, qb: (i, qb, 0)),
-            pl.BlockSpec((gh, bk, d), lambda i, j, qb: (i, j, 0)),
-            pl.BlockSpec((gh, bk, d), lambda i, j, qb: (i, j, 0)),
-            pl.BlockSpec((gh, 1, bk), lambda i, j, qb: (i, 0, j)),
-            pl.BlockSpec((gh, 1, bq), lambda i, j, qb: (i, 0, qb)),
-            pl.BlockSpec((gh, bq, d), lambda i, j, qb: (i, qb, 0)),
-            pl.BlockSpec((gh, 1, bq), lambda i, j, qb: (i, 0, qb)),
-        ],
-        out_specs=[
-            pl.BlockSpec((gh, bk, d), lambda i, j, qb: (i, j, 0)),
-            pl.BlockSpec((gh, bk, d), lambda i, j, qb: (i, j, 0)),
-        ],
+        functools.partial(_dkv_kernel, **kernel_args),
+        grid=(b, hpb, s // bk, s // bq),
+        in_specs=in_specs(q_at=inner, k_at=outer),
+        out_specs=[wide(bk, outer), wide(bk, outer)],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), k3.dtype),
-            jax.ShapeDtypeStruct((bh, s, d), v3.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((gh, bk, d), jnp.float32),
-            pltpu.VMEM((gh, bk, d), jnp.float32),
+            pltpu.VMEM((bk, hp * d), jnp.float32),
+            pltpu.VMEM((bk, hp * d), jnp.float32),
         ],
         interpret=interpret,
         name="flash_bwd_dkv",
-    )(q3, k3, v3, bias3, lse, do, delta)
+    )(q, k, v, bias, lse, do, out)
     return dq, dk, dv
 
 
-def _bwd_fused(q3, k3, v3, bias3, lse, do, delta, interpret):
-    bh, s, d = q3.shape
-    scale = 1.0 / (d ** 0.5)
+def _bwd_fused(q, k, v, bias, lse, do, out, d, interpret):
     # fused kernel holds s, p, dp, ds (~4 full tiles) at once per head
-    gh = _pick_heads(bh, s, s, budget_mb=3.0)
+    b, s, h, g, hp, _bq, _bk = _geometry(q, d, q.shape[1], q.shape[1],
+                                         budget_mb=3.0)
+    hpb = h // hp
+    wide = pl.BlockSpec((None, s, hp * d), lambda n, p: (n, 0, p))
     dq, dk, dv = pl.pallas_call(
-        functools.partial(_dqkv_fused_kernel, scale=scale, gh=gh),
-        grid=(bh // gh,),
+        functools.partial(
+            _dqkv_fused_kernel, scale=1.0 / (d ** 0.5), d=d, g=g
+        ),
+        grid=(b, hpb),
         in_specs=[
-            pl.BlockSpec((gh, s, d), lambda i: (i, 0, 0)),
-            pl.BlockSpec((gh, s, d), lambda i: (i, 0, 0)),
-            pl.BlockSpec((gh, s, d), lambda i: (i, 0, 0)),
-            pl.BlockSpec((gh, 1, s), lambda i: (i, 0, 0)),
-            pl.BlockSpec((gh, 1, s), lambda i: (i, 0, 0)),
-            pl.BlockSpec((gh, s, d), lambda i: (i, 0, 0)),
-            pl.BlockSpec((gh, 1, s), lambda i: (i, 0, 0)),
+            wide, wide, wide,
+            pl.BlockSpec((None, 1, s), lambda n, p: (n, 0, 0)),
+            pl.BlockSpec((hp, 1, s), lambda n, p: (n * hpb + p, 0, 0)),
+            wide, wide,
         ],
-        out_specs=[
-            pl.BlockSpec((gh, s, d), lambda i: (i, 0, 0)),
-            pl.BlockSpec((gh, s, d), lambda i: (i, 0, 0)),
-            pl.BlockSpec((gh, s, d), lambda i: (i, 0, 0)),
-        ],
+        out_specs=[wide, wide, wide],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), q3.dtype),
-            jax.ShapeDtypeStruct((bh, s, d), k3.dtype),
-            jax.ShapeDtypeStruct((bh, s, d), v3.dtype),
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=interpret,
         name="flash_bwd_fused",
-    )(q3, k3, v3, bias3, lse, do, delta)
+    )(q, k, v, bias, lse, do, out)
     return dq, dk, dv
 
 
 # --------------------------------------------------------------- public op
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash(q3, k3, v3, bias3, block_q, block_k, interpret):
-    out, _lse = _fwd(q3, k3, v3, bias3, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash(q, k, v, bias, d, block_q, block_k, interpret):
+    out, _lse = _fwd(q, k, v, bias, d, block_q, block_k, interpret)
     return out
 
 
-def _flash_fwd(q3, k3, v3, bias3, block_q, block_k, interpret):
-    # ``out`` may be head-PAIRED [BH//2, S, 2D] (see _fwd): that exact array
-    # is what the dots_no_batch_attn remat policy saves per layer, so the
-    # packed layout halves the residual's HBM footprint at D=64
-    out, lse = _fwd(q3, k3, v3, bias3, block_q, block_k, interpret)
-    return out, (q3, k3, v3, bias3, out, lse)
+def _flash_fwd(q, k, v, bias, d, block_q, block_k, interpret):
+    # ``out`` is what the remat policies save per layer (a Pallas output),
+    # in the layout the out-projection reads: no lane padding at any D
+    out, lse = _fwd(q, k, v, bias, d, block_q, block_k, interpret)
+    return out, (q, k, v, bias, out, lse)
 
 
-def _flash_bwd(block_q, block_k, interpret, residuals, g):
-    q3, k3, v3, bias3, out, lse = residuals
-    bh, _, d = q3.shape
-    if out.shape[0] != bh:  # paired layout: delta on packed forms, then
-        half = bh // 2      # one cheap permutation for the kernels' do
-        prod = g.astype(jnp.float32) * out.astype(jnp.float32)
-        s_len = prod.shape[1]
-        delta = (
-            prod.reshape(half, s_len, 2, d).sum(-1)
-            .transpose(0, 2, 1).reshape(bh, 1, s_len)
-        )
-        do = _unpack_heads(g, bh, d)
-    else:
-        delta = jnp.sum(
-            g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
-        )[:, None, :]  # [BH, 1, S] row layout (see module docstring)
-        do = g
-    dq, dk, dv = _bwd(q3, k3, v3, bias3, lse, do, delta, block_q, block_k,
+def _flash_bwd(d, block_q, block_k, interpret, residuals, g):
+    q, k, v, bias, out, lse = residuals
+    dq, dk, dv = _bwd(q, k, v, bias, lse, g, out, d, block_q, block_k,
                       interpret)
     # the mask bias is non-differentiable input
-    return dq, dk, dv, jnp.zeros_like(bias3)
+    return dq, dk, dv, jnp.zeros_like(bias)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _flash_bshd(q, k, v, bias, block_q, block_k, interpret):
-    """The op on [B, S, H, D] operands as ONE device sees them (the whole
+def _flash_local(q, k, v, bias, d, block_q, block_k, interpret):
+    """The op on [B, S, H·D] operands as ONE device sees them (the whole
     arrays off-mesh, this device's batch/head shard under shard_map)."""
-    b, s, h, d = q.shape
-    # named in KERNEL layout so the fused_ln remat policy saves exactly what
-    # the flash backward consumes — the replay then skips the [B,S,H,D] ->
-    # [BH,S,D] relayout passes too
-    to3 = lambda x, nm: checkpoint_name(
-        x.transpose(0, 2, 1, 3).reshape(b * h, s, d), nm
-    )
-    bias3 = jnp.broadcast_to(
-        bias[:, None, :], (b, h, s)
-    ).reshape(b * h, 1, s).astype(jnp.float32)
-    out3 = _flash(to3(q, "flash_qkv"), to3(k, "flash_qkv"),
-                  to3(v, "flash_qkv"), bias3, block_q, block_k, interpret)
-    out3 = _unpack_heads(out3, b * h, d)  # paired layout -> [BH, S, D]
-    return out3.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+    # named as the dense layers give them, which is what the kernels read:
+    # the fused_ln remat policy saves exactly what the flash backward
+    # consumes, in the one layout the stash and both kernels share
+    q, k, v = (checkpoint_name(x, "flash_qkv") for x in (q, k, v))
+    bias = bias[:, None, :].astype(jnp.float32)  # [B, 1, S] row per sample
+    return _flash(q, k, v, bias, d, block_q, block_k, interpret)
 
 
 def flash_attention(
@@ -487,17 +496,26 @@ def flash_attention(
     """
     if interpret is None:
         interpret = pallas_interpret()
+    b, s, h, d = q.shape
     if bias is None:
-        bias = jnp.zeros((k.shape[0], k.shape[1]), jnp.float32)
+        bias = jnp.zeros((b, s), jnp.float32)
     op = functools.partial(
-        _flash_bshd, block_q=block_q, block_k=block_k, interpret=interpret
+        _flash_local, d=d, block_q=block_q, block_k=block_k,
+        interpret=interpret,
     )
     if mesh is not None:
-        qkv = P(mesh_axis(mesh, "data"), None, mesh_axis(mesh, "model"), None)
+        # heads over "model": a shard's columns are its (H/tp)·D
+        qkv = P(mesh_axis(mesh, "data"), None, mesh_axis(mesh, "model"))
         # check_vma=False: pallas_call outputs carry no varying-axes type
         op = jax.shard_map(
             op, mesh=mesh,
             in_specs=(qkv, qkv, qkv, P(mesh_axis(mesh, "data"), None)),
             out_specs=qkv, check_vma=False,
         )
-    return op(q, k, v, bias)
+    # [B, S, H, D] <-> [B, S, H·D] is the same bytes: the dense layers'
+    # own layout goes in and comes out, nothing is transposed
+    heads_flat = (b, s, h * d)
+    return op(
+        q.reshape(heads_flat), k.reshape(heads_flat), v.reshape(heads_flat),
+        bias,
+    ).reshape(q.shape)

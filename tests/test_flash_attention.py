@@ -128,19 +128,40 @@ def test_flash_rejects_attention_dropout_in_training_only(rng):
         )
 
 
-def test_paired_output_layout_matches_dense(rng):
-    # D=64 with an even head-group triggers the PAIRED [BH//2, S, 2D] output
-    # layout (halves the remat-saved residual's HBM); math must be identical
-    q, k, v = _qkv(rng, b=2, s=128, h=4, d=64)
-    bias = jnp.zeros((2, 128))
-    out = flash_attention(q, k, v, bias)
-    ref = dense_attention(q, k, v, bias)
-    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
-    gf = jax.grad(
-        lambda q: jnp.sum(flash_attention(q, k, v, bias) ** 2)
-    )(q)
-    gd = jax.grad(lambda q: jnp.sum(dense_attention(q, k, v, bias) ** 2))(q)
-    np.testing.assert_allclose(gf, gd, atol=5e-4, rtol=5e-4)
+@pytest.mark.parametrize("masked", [False, True], ids=["nobias", "mask"])
+@pytest.mark.parametrize("block", [64, 32], ids=["one_tile", "tiles"])
+@pytest.mark.parametrize("h,d", [(16, 64), (4, 16), (2, 128), (3, 64)])
+def test_model_layout_matches_dense(rng, h, d, block, masked):
+    """The kernels index [B, S, H·D] directly, a column block of adjacent
+    heads at a time: 2 heads per 128 lanes at (16, 64), one at (2, 128), the
+    whole width where the heads do not fill or divide into 128-lane blocks
+    ((4, 16): 64 lanes; (3, 64): 192). Forward and all three gradients
+    against dense attention; block 64 covers S (the fused backward), 32
+    takes the two-kernel one."""
+    b, s = 2, 64
+    q, k, v = _qkv(rng, b=b, s=s, h=h, d=d)
+    bias = None
+    if masked:
+        mask = np.ones((b, s), np.float32)
+        mask[0, 40:] = 0.0
+        mask[1, 5:9] = 0.0
+        bias = jnp.where(jnp.asarray(mask) > 0, 0.0, -1e9)
+    w = jnp.asarray(rng.standard_normal(q.shape), jnp.float32)
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v) * w)
+
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, bias, block_q=block, block_k=block
+    )
+    dense = lambda q, k, v: dense_attention(q, k, v, bias)
+    np.testing.assert_allclose(flash(q, k, v), dense(q, k, v),
+                               atol=2e-5, rtol=2e-5)
+    gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    for a, b_, name in zip(gf, gd, "qkv"):
+        np.testing.assert_allclose(a, b_, atol=5e-4, rtol=5e-4,
+                                   err_msg=f"d{name} mismatch")
 
 
 def test_under_a_mesh_matches_one_device(rng):

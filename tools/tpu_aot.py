@@ -10,13 +10,18 @@ speed or numerics; those need the chip (``chip_smoke.py``).
     python tools/tpu_aot.py            # every program, one JSON line each
     python tools/tpu_aot.py flat_apply_step kernels
 
-Each line: {"program", "compile_s", "tpu_custom_calls"}. Exit code 0 when
-everything compiled.
+Each line: {"program", "compile_s", "tpu_custom_calls", "layer_body_copies"}
+— the last is the shapes of the ``copy`` instructions inside the compiled
+program's while bodies (the scanned layer, forward and backward): relayouts
+the compiler put around an op whose layout differs from its neighbours', paid
+once per layer iteration. Exit code 0 when everything compiled, 3 when no
+v5e can be described here (no libtpu, or one without a compile-only client).
 """
 from __future__ import annotations
 
 import json
 import os
+import re
 import sys
 import time
 
@@ -133,6 +138,25 @@ def kernels(device):
     )
 
 
+def layer_body_copies(hlo_text: str) -> list:
+    """Result shapes of the ``copy`` instructions that sit directly in a
+    while body of an optimized HLO module (``compiled.as_text()``)."""
+    bodies = set(re.findall(r"\bbody=%?([\w.\-]+)", hlo_text))
+    copies, inside = [], False
+    for line in hlo_text.splitlines():
+        if not line.startswith(" "):  # a computation's header, or its "}"
+            header = re.match(r"%?([\w.\-]+) \(", line)
+            inside = bool(header) and header.group(1) in bodies
+        elif inside:
+            copy = re.match(r"\s+%?[\w.\-]+ = (\w+\[[\d,]*\])\S* copy\(", line)
+            if copy:
+                copies.append(copy.group(1))
+    return copies
+
+
+NO_V5E = 3  # exit code: nothing to compile with, which is not a failure
+
+
 PROGRAMS = {
     fn.__name__: fn for fn in (accumulate_step, flat_apply_step, kernels)
 }
@@ -140,19 +164,25 @@ PROGRAMS = {
 
 def main(argv=None) -> int:
     names = list(argv if argv is not None else sys.argv[1:]) or list(PROGRAMS)
-    device = topologies.get_topology_desc(
-        platform="tpu", topology_name="v5e:2x2"
-    ).devices[0]
+    try:
+        device = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        ).devices[0]
+    except Exception as e:  # whatever this jaxlib raises without libtpu
+        print(f"no v5e:2x2 topology can be described here: {e!r}",
+              file=sys.stderr)
+        return NO_V5E
     for name in names:
         with lowering_for_tpu():  # Mosaic kernels, not the interpreter
             lowered = PROGRAMS[name](device)
         start = time.perf_counter()
-        lowered.compile()
+        compiled = lowered.compile()
         print(json.dumps({
             "program": name,
             "device_kind": device.device_kind,
             "compile_s": round(time.perf_counter() - start, 2),
             "tpu_custom_calls": lowered.as_text().count("tpu_custom_call"),
+            "layer_body_copies": layer_body_copies(compiled.as_text()),
         }), flush=True)
     return 0
 
