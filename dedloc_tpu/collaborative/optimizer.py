@@ -20,6 +20,7 @@ TPU-native split (SURVEY.md §7 hard-parts b,c):
 """
 from __future__ import annotations
 
+import collections
 import threading
 import time
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -77,7 +78,6 @@ def _named_to_tree(named: Dict[str, np.ndarray], like):
     )
 
 
-@jax.jit
 def _fused_mean_clip(grad_acc, n, cap):
     """The accumulator mean plus the contribution clip as ONE fused jitted
     program: ``grad_acc / n`` per leaf, one global-norm reduce, one scale.
@@ -95,6 +95,16 @@ def _fused_mean_clip(grad_acc, n, cap):
         cap > 0, jax.numpy.minimum(1.0, cap / (gnorm + 1e-12)), 1.0
     )
     return jax.tree.map(lambda g: g * scale, mean)
+
+
+# for the boundaries that apply the peer's OWN mean (solo, and the
+# local-apply fallback): the accumulator is dead once the mean exists (a
+# fresh one follows the apply), so the mean takes the accumulator's buffers —
+# 4 bytes a parameter that would otherwise be held twice across the apply,
+# which is what decides whether a 350 M-parameter state fits the chip. Both
+# are "_fused_mean_clip" to a trace or a compile listener.
+_fused_mean_clip_in_place = jax.jit(_fused_mean_clip, donate_argnums=(0,))
+_fused_mean_clip = jax.jit(_fused_mean_clip)
 
 
 class CollaborativeOptimizer:
@@ -350,6 +360,11 @@ class CollaborativeOptimizer:
         self.backup_duty_cycle = 0.5
         self._backup_done_at = 0.0
         self._backup_took = 0.0
+        # (start, end, bytes) of the transfers the backup thread has
+        # finished, on the step records' clock: ``step`` attaches each to
+        # the record that is live when it next runs (the averager's
+        # ``last_round_timing`` is the pattern)
+        self._finished_backups: collections.deque = collections.deque()
         # jit↔host seam telemetry (ms, last global step)
         self.seam_ms: Dict[str, float] = {}
         self._desynced = False
@@ -406,6 +421,17 @@ class CollaborativeOptimizer:
         assert not self.auxiliary, "auxiliary peers must use step_aux()"
         record = steps.current()
         entered = record.elapsed() if record is not None else 0.0
+        while self._finished_backups:
+            t0, t1, nbytes = self._finished_backups.popleft()
+            tele = telemetry.resolve(self.telemetry)
+            if tele is not None:
+                tele.counter("opt.backup_bytes").inc(nbytes)
+            if record is not None:
+                # another thread's time: a span beside this thread's own
+                record.attach("backup_transfer", t0, t1)
+                record.attrs["opt.backup_bytes"] = (
+                    record.attrs.get("opt.backup_bytes", 0) + nbytes
+                )
         with self._lock:
             out = self._step(state, grad_acc, n_acc, samples)
         if record is not None:
@@ -728,7 +754,7 @@ class CollaborativeOptimizer:
             # lets a concurrent starter pair with us.
             self.seam_ms.pop("grads_device_get", None)
             with steps.phase("grad_flatten"):
-                mean_grads = _fused_mean_clip(grad_acc, n, cap)
+                mean_grads = _fused_mean_clip_in_place(grad_acc, n, cap)
             return self._apply_and_advance(
                 state, mean_grads, collab, group_size=1,
             )
@@ -924,7 +950,7 @@ class CollaborativeOptimizer:
             # residual fold, never quantized) — exactly what the legacy
             # path applied here; the device tree never left the chip
             with steps.phase("grad_flatten"):
-                mean_grads = _fused_mean_clip(grad_acc, n, cap)
+                mean_grads = _fused_mean_clip_in_place(grad_acc, n, cap)
             return self._apply_and_advance(
                 state, mean_grads, collab, group_size,
             )
@@ -1395,16 +1421,35 @@ class CollaborativeOptimizer:
         # live arrays — device_get on a donated buffer would raise "Array has
         # been deleted" mid-transfer on exactly the slow links the duty cycle
         # exists for
-        snapshot = jax.tree.map(
+        leaves, treedef = jax.tree.flatten(jax.tree.map(
             jax.numpy.copy, (state.params, state.opt_state)
-        )
+        ))
 
         def backup() -> None:
-            t0 = time.perf_counter()
-            host_state = jax.device_get(snapshot)
+            t0, started = time.perf_counter(), monotonic_clock()
+            # Transfers are served in order: with every leaf requested up
+            # front (what ``device_get`` does) the training thread's next
+            # read of a scalar, or its next eager dispatch, waits behind
+            # the whole snapshot — 0.86 s of a 4.27 GB one, with the device
+            # idle (PERF.md, PR 25). So one leaf is requested ahead of the
+            # one being read, and each device copy is let go as soon as its
+            # bytes are on the host: the snapshot (12 bytes a parameter
+            # under LAMB) shrinks while the transfer runs instead of
+            # staying whole until its end.
+            nbytes = 0
+            leaves[0].copy_to_host_async()
+            for i in range(len(leaves)):
+                if i + 1 < len(leaves):
+                    leaves[i + 1].copy_to_host_async()
+                leaves[i] = np.asarray(leaves[i])
+                nbytes += leaves[i].nbytes
+            host_state = jax.tree.unflatten(treedef, leaves)
             self.averager.set_shared_state(
                 _tree_to_named(host_state),
                 {"step": step, "local_step": local_step},
+            )
+            self._finished_backups.append(
+                (started, monotonic_clock(), nbytes)
             )
             self.averager.publish_state_provider(
                 expiration=self.tracker.metadata_expiration * 4,

@@ -301,7 +301,12 @@ class TrainingArguments:
     """Local-step recipe, mirroring AlbertTrainingArguments
     (albert/arguments.py:104-128)."""
 
-    model_size: str = "large"  # tiny (CI fixture) | large
+    # tiny (CI fixture) | large (ALBERT); ouro_tiny | ouro_2p6b (the looped
+    # decoder, models/ouro.py) — roles/common.MODEL_FAMILIES is the table
+    model_size: str = "large"
+    # depth override (0 = the model's own): a chip's share of a deeper
+    # deployment keeps every width and cuts layers. No width is settable.
+    num_hidden_layers: int = 0
     # override model remat: nothing|dots|dots_no_batch|dots_no_batch_attn|
     # fused_ln|fused_ln_gelu (fused_ln — saved Pallas outputs + named
     # matmuls, pairs the fused add+LN kernel on automatically — is the

@@ -36,7 +36,11 @@ column layout would be 128×-padded by the TPU's (8, 128) tiling — 2 GB of
 HBM for S=16k — so rows travel packed and are transposed to columns in
 VMEM where the math needs them. The bias is per KV position (0 keep / -inf
 drop), the same for every head — exactly the mask bias AlbertModel builds;
-it is non-differentiable (it comes from the attention mask).
+it is non-differentiable (it comes from the attention mask). A decoder's
+causal mask is a MODE of the same kernels (``causal=True``; see "causal
+tiles" below): tiles above the diagonal are neither fetched nor computed,
+tiles the diagonal crosses get an iota mask, and the kernels are named
+``flash_causal_*`` in a device trace.
 
 Off-TPU (CPU tests, CI) the same kernels run under ``interpret=True``
 (``utils.backend.pallas_interpret`` decides, once, for every op here).
@@ -163,11 +167,61 @@ def _add(total, term):
     return term if total is None else total + term
 
 
+# ------------------------------------------------------------ causal tiles
+#
+# With ``causal`` a query sees the keys at its own position and before. A
+# (query tile, key tile) pair is then one of three: wholly above the
+# diagonal (nothing to do: its K/V blocks are not even fetched — the index
+# maps clamp to the last tile that is needed, which Pallas does not copy
+# again — and the body is skipped), wholly on or below it (the plain body),
+# or crossed by it (the body with an iota mask). Only the crossed tiles pay
+# for the mask.
+
+
+def _last_k_tile(qi, bq: int, bk: int):
+    """The last key tile query tile ``qi`` needs."""
+    return (qi * bq + bq - 1) // bk
+
+
+def _first_q_tile(ki, bq: int, bk: int):
+    """The first query tile that sees key tile ``ki``."""
+    return (ki * bk) // bq
+
+
+def _tile_mask(qi, ki, bq: int, bk: int):
+    """[Bq, Bk] bool: key position <= query position, in tile (qi, ki)."""
+    rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    return cols <= rows
+
+
+def _for_tile(causal: bool, qi, ki, bq: int, bk: int, body) -> None:
+    """Run ``body(mask)`` for tile (qi, ki): ``mask`` is None where every
+    key of the tile is visible to every query (always, when not causal)."""
+    if not causal:
+        body(None)
+        return
+    q0, k0 = qi * bq, ki * bk
+    below = k0 + bk - 1 <= q0
+
+    @pl.when(below)
+    def _plain():
+        body(None)
+
+    @pl.when(jnp.logical_not(below) & (k0 <= q0 + bq - 1))
+    def _crossed():
+        body(_tile_mask(qi, ki, bq, bk))
+
+
+def _masked(s, mask):
+    return s if mask is None else jnp.where(mask, s, NEG_INF)
+
+
 # ------------------------------------------------------------------ forward
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, scale, d, g):
+                acc_ref, m_ref, l_ref, *, scale, d, g, causal):
     kb = pl.program_id(3)
     nk = pl.num_programs(3)
     blocks = _column_blocks(q_ref.shape[-1], g, d)
@@ -178,35 +232,45 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    b = bias_ref[:].astype(jnp.float32)  # [1, Bk]
-    # several column blocks per program (unrolled): one grid step's DMAs and
-    # semaphore work amortise over their heads' matmuls — at D=64 the
-    # per-head dots are too small to hide the per-program overhead
-    # (measured on v5e)
-    for h0, cols in blocks:
-        q = q_ref[:, cols]  # [Bq, g·D]: g heads side by side
-        k = k_ref[:, cols]  # [Bk, g·D]
-        v = v_ref[:, cols]
-        pv, corrs = None, []
-        for i in range(g):
-            h = h0 + i
-            s = _dot(q, _only_head(k, i, d), 1, 1) * scale + b
+    def tile(mask):
+        b = bias_ref[:].astype(jnp.float32)  # [1, Bk]
+        # several column blocks per program (unrolled): one grid step's DMAs
+        # and semaphore work amortise over their heads' matmuls — at D=64
+        # the per-head dots are too small to hide the per-program overhead
+        # (measured on v5e)
+        for h0, cols in blocks:
+            q = q_ref[:, cols]  # [Bq, g·D]: g heads side by side
+            k = k_ref[:, cols]  # [Bk, g·D]
+            v = v_ref[:, cols]
+            pv, corrs = None, []
+            for i in range(g):
+                h = h0 + i
+                s = _masked(
+                    _dot(q, _only_head(k, i, d), 1, 1) * scale + b, mask
+                )
 
-            # softmax state lives as COLUMNS [Bq, 1] in scratch (it never
-            # touches HBM) so the running max/denominator broadcast against
-            # s with zero cross-lane relayouts; only the lse OUTPUT is a row
-            # (HBM tiling).
-            m_prev, l_prev = m_ref[h], l_ref[h]  # [Bq, 1] columns
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m_prev - m_new)  # [Bq, 1]
-            l_ref[h] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-            m_ref[h] = m_new
-            corrs.append(corr)
-            pv = _add(
-                pv, _dot(p.astype(v.dtype), _only_head(v, i, d), 1, 0)
+                # softmax state lives as COLUMNS [Bq, 1] in scratch (it
+                # never touches HBM) so the running max/denominator
+                # broadcast against s with zero cross-lane relayouts; only
+                # the lse OUTPUT is a row (HBM tiling).
+                m_prev, l_prev = m_ref[h], l_ref[h]  # [Bq, 1] columns
+                m_new = jnp.maximum(
+                    m_prev, jnp.max(s, axis=-1, keepdims=True)
+                )
+                p = jnp.exp(s - m_new)
+                corr = jnp.exp(m_prev - m_new)  # [Bq, 1]
+                l_ref[h] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+                m_ref[h] = m_new
+                corrs.append(corr)
+                pv = _add(
+                    pv, _dot(p.astype(v.dtype), _only_head(v, i, d), 1, 0)
+                )
+            acc_ref[:, cols] = (
+                acc_ref[:, cols] * _per_head_lanes(corrs, d) + pv
             )
-        acc_ref[:, cols] = acc_ref[:, cols] * _per_head_lanes(corrs, d) + pv
+
+    _for_tile(causal, pl.program_id(2), kb, q_ref.shape[0], k_ref.shape[0],
+              tile)
 
     @pl.when(kb == nk - 1)
     def _flush():
@@ -223,18 +287,32 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                 )
 
 
-def _fwd(q, k, v, bias, d, block_q, block_k, interpret):
+def _name(kernel: str, causal: bool) -> str:
+    """The causal kernels keep names of their own in a device trace."""
+    return f"flash_causal_{kernel}" if causal else f"flash_{kernel}"
+
+
+def _fwd(q, k, v, bias, d, block_q, block_k, causal, interpret):
     """Returns (out [B, S, H·D], lse [B·H, 1, S])."""
     b, s, h, g, hp, bq, bk = _geometry(q, d, block_q, block_k, budget_mb=6.0)
     hpb = h // hp  # programs across the width
+
+    def k_at(j, kb):  # a tile above the diagonal re-names the last needed
+        return jnp.minimum(kb, _last_k_tile(j, bq, bk)) if causal else kb
+
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=1.0 / (d ** 0.5), d=d, g=g),
+        functools.partial(
+            _fwd_kernel, scale=1.0 / (d ** 0.5), d=d, g=g, causal=causal
+        ),
         grid=(b, hpb, s // bq, s // bk),
         in_specs=[
             pl.BlockSpec((None, bq, hp * d), lambda n, p, j, kb: (n, j, p)),
-            pl.BlockSpec((None, bk, hp * d), lambda n, p, j, kb: (n, kb, p)),
-            pl.BlockSpec((None, bk, hp * d), lambda n, p, j, kb: (n, kb, p)),
-            pl.BlockSpec((None, 1, bk), lambda n, p, j, kb: (n, 0, kb)),
+            pl.BlockSpec((None, bk, hp * d),
+                         lambda n, p, j, kb: (n, k_at(j, kb), p)),
+            pl.BlockSpec((None, bk, hp * d),
+                         lambda n, p, j, kb: (n, k_at(j, kb), p)),
+            pl.BlockSpec((None, 1, bk),
+                         lambda n, p, j, kb: (n, 0, k_at(j, kb))),
         ],
         out_specs=[
             pl.BlockSpec((None, bq, hp * d), lambda n, p, j, kb: (n, j, p)),
@@ -251,7 +329,7 @@ def _fwd(q, k, v, bias, d, block_q, block_k, interpret):
             pltpu.VMEM((hp, bq, 1), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_fwd",
+        name=_name("fwd", causal),
     )(q, k, v, bias)
     return out, lse
 
@@ -261,12 +339,13 @@ def _fwd(q, k, v, bias, d, block_q, block_k, interpret):
 _Head = collections.namedtuple("_Head", "p ds q k do")
 
 
-def _backward_heads(refs, bias_ref, lse_ref, h0, cols, *, scale, d, g):
+def _backward_heads(refs, bias_ref, lse_ref, h0, cols, mask, *, scale, d, g):
     """The g heads of one column block (heads ``h0``.., lanes ``cols`` of
     the program's tiles), one at a time: each head's probability tile ``p``
     and the gradient ``ds`` of its scores ([Bq, Bk], recomputed from the
     residuals in fp32, cast for the MXU), with q, k and dO cut down to that
-    head's lanes for the products that follow."""
+    head's lanes for the products that follow. ``mask``: the causal mask of
+    a tile the diagonal crosses, else None."""
     # dO stays in its native (bf16) dtype for the dots — MXU at full rate
     q, k, v, do, o = (ref[:, cols] for ref in refs)
     # delta = rowsum(dO ⊙ O) per head, as the COLUMN the math needs
@@ -274,7 +353,7 @@ def _backward_heads(refs, bias_ref, lse_ref, h0, cols, *, scale, d, g):
     b = bias_ref[:].astype(jnp.float32)  # [1, Bk]
     for i in range(g):
         k_i = _only_head(k, i, d)
-        s = _dot(q, k_i, 1, 1) * scale + b
+        s = _masked(_dot(q, k_i, 1, 1) * scale + b, mask)
         p = jnp.exp(s - _t(lse_ref[h0 + i]))  # [1, Bq] row -> column
         dp = _dot(do, _only_head(v, i, d), 1, 1)
         delta = jnp.sum(_only_head(prod, i, d), axis=-1, keepdims=True)
@@ -284,7 +363,7 @@ def _backward_heads(refs, bias_ref, lse_ref, h0, cols, *, scale, d, g):
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
-               dq_ref, dq_acc_ref, *, scale, d, g):
+               dq_ref, dq_acc_ref, *, scale, d, g, causal):
     kb = pl.program_id(3)
     nk = pl.num_programs(3)
 
@@ -292,14 +371,18 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
     def _init():
         dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
 
-    for h0, cols in _column_blocks(q_ref.shape[-1], g, d):
-        dq = dq_acc_ref[:, cols]
-        for head in _backward_heads(
-            (q_ref, k_ref, v_ref, do_ref, o_ref), bias_ref, lse_ref, h0,
-            cols, scale=scale, d=d, g=g,
-        ):
-            dq = dq + _dot(head.ds, head.k, 1, 0)
-        dq_acc_ref[:, cols] = dq
+    def tile(mask):
+        for h0, cols in _column_blocks(q_ref.shape[-1], g, d):
+            dq = dq_acc_ref[:, cols]
+            for head in _backward_heads(
+                (q_ref, k_ref, v_ref, do_ref, o_ref), bias_ref, lse_ref, h0,
+                cols, mask, scale=scale, d=d, g=g,
+            ):
+                dq = dq + _dot(head.ds, head.k, 1, 0)
+            dq_acc_ref[:, cols] = dq
+
+    _for_tile(causal, pl.program_id(2), kb, q_ref.shape[0], k_ref.shape[0],
+              tile)
 
     @pl.when(kb == nk - 1)
     def _flush():
@@ -307,7 +390,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
-                dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, scale, d, g):
+                dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, scale, d, g,
+                causal):
     qb = pl.program_id(3)
     nq = pl.num_programs(3)
 
@@ -316,16 +400,20 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
         dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
 
-    for h0, cols in _column_blocks(q_ref.shape[-1], g, d):
-        dk, dv = dk_acc_ref[:, cols], dv_acc_ref[:, cols]
-        for head in _backward_heads(
-            (q_ref, k_ref, v_ref, do_ref, o_ref), bias_ref, lse_ref, h0,
-            cols, scale=scale, d=d, g=g,
-        ):
-            dv = dv + _dot(head.p, head.do, 0, 0)
-            dk = dk + _dot(head.ds, head.q, 0, 0)
-        dk_acc_ref[:, cols] = dk
-        dv_acc_ref[:, cols] = dv
+    def tile(mask):
+        for h0, cols in _column_blocks(q_ref.shape[-1], g, d):
+            dk, dv = dk_acc_ref[:, cols], dv_acc_ref[:, cols]
+            for head in _backward_heads(
+                (q_ref, k_ref, v_ref, do_ref, o_ref), bias_ref, lse_ref, h0,
+                cols, mask, scale=scale, d=d, g=g,
+            ):
+                dv = dv + _dot(head.p, head.do, 0, 0)
+                dk = dk + _dot(head.ds, head.q, 0, 0)
+            dk_acc_ref[:, cols] = dk
+            dv_acc_ref[:, cols] = dv
+
+    _for_tile(causal, qb, pl.program_id(2), q_ref.shape[0], k_ref.shape[0],
+              tile)
 
     @pl.when(qb == nq - 1)
     def _flush():
@@ -334,15 +422,18 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
 
 
 def _dqkv_fused_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref,
-                       o_ref, dq_ref, dk_ref, dv_ref, *, scale, d, g):
+                       o_ref, dq_ref, dk_ref, dv_ref, *, scale, d, g,
+                       causal):
     """Single-block backward: when one (Bq, Bk) tile covers the whole
     sequence, dq/dk/dv share ONE score/prob computation and one set of
     input DMAs instead of recomputing them in two kernels."""
+    s = q_ref.shape[0]
+    mask = _tile_mask(0, 0, s, s) if causal else None
     for h0, cols in _column_blocks(q_ref.shape[-1], g, d):
         dq = dk = dv = None
         for head in _backward_heads(
             (q_ref, k_ref, v_ref, do_ref, o_ref), bias_ref, lse_ref, h0,
-            cols, scale=scale, d=d, g=g,
+            cols, mask, scale=scale, d=d, g=g,
         ):
             dv = _add(dv, _dot(head.p, head.do, 0, 0))
             dq = _add(dq, _dot(head.ds, head.k, 1, 0))
@@ -352,13 +443,14 @@ def _dqkv_fused_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref,
         dv_ref[:, cols] = dv.astype(dv_ref.dtype)
 
 
-def _bwd(q, k, v, bias, lse, do, out, d, block_q, block_k, interpret):
+def _bwd(q, k, v, bias, lse, do, out, d, block_q, block_k, causal,
+         interpret):
     # bwd transients per head are ~3x the fwd's (s, p, dp, ds live at once)
     b, s, h, g, hp, bq, bk = _geometry(q, d, block_q, block_k, budget_mb=4.0)
     if bq == s and bk == s:
-        return _bwd_fused(q, k, v, bias, lse, do, out, d, interpret)
+        return _bwd_fused(q, k, v, bias, lse, do, out, d, causal, interpret)
     hpb = h // hp
-    kernel_args = dict(scale=1.0 / (d ** 0.5), d=d, g=g)
+    kernel_args = dict(scale=1.0 / (d ** 0.5), d=d, g=g, causal=causal)
 
     # grid (B, programs across the width, outer S block, inner S block):
     # each spec says which of the two S positions it follows
@@ -376,23 +468,32 @@ def _bwd(q, k, v, bias, lse, do, out, d, block_q, block_k, interpret):
             wide(bq, q_at), wide(bq, q_at),
         ]
 
-    outer, inner = (lambda x, y: x), (lambda x, y: y)
+    def outer(x, y):
+        return x
+
+    # the inner position of a causal grid stays on the tiles its outer one
+    # needs (see "causal tiles"): nothing above the diagonal is fetched
+    def inner_k(x, y):
+        return jnp.minimum(y, _last_k_tile(x, bq, bk)) if causal else y
+
+    def inner_q(x, y):
+        return jnp.maximum(y, _first_q_tile(x, bq, bk)) if causal else y
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **kernel_args),
         grid=(b, hpb, s // bq, s // bk),
-        in_specs=in_specs(q_at=outer, k_at=inner),
+        in_specs=in_specs(q_at=outer, k_at=inner_k),
         out_specs=wide(bq, outer),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, hp * d), jnp.float32)],
         interpret=interpret,
-        name="flash_bwd_dq",
+        name=_name("bwd_dq", causal),
     )(q, k, v, bias, lse, do, out)
 
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, **kernel_args),
         grid=(b, hpb, s // bk, s // bq),
-        in_specs=in_specs(q_at=inner, k_at=outer),
+        in_specs=in_specs(q_at=inner_q, k_at=outer),
         out_specs=[wide(bk, outer), wide(bk, outer)],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -403,12 +504,12 @@ def _bwd(q, k, v, bias, lse, do, out, d, block_q, block_k, interpret):
             pltpu.VMEM((bk, hp * d), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name=_name("bwd_dkv", causal),
     )(q, k, v, bias, lse, do, out)
     return dq, dk, dv
 
 
-def _bwd_fused(q, k, v, bias, lse, do, out, d, interpret):
+def _bwd_fused(q, k, v, bias, lse, do, out, d, causal, interpret):
     # fused kernel holds s, p, dp, ds (~4 full tiles) at once per head
     b, s, h, g, hp, _bq, _bk = _geometry(q, d, q.shape[1], q.shape[1],
                                          budget_mb=3.0)
@@ -416,7 +517,8 @@ def _bwd_fused(q, k, v, bias, lse, do, out, d, interpret):
     wide = pl.BlockSpec((None, s, hp * d), lambda n, p: (n, 0, p))
     dq, dk, dv = pl.pallas_call(
         functools.partial(
-            _dqkv_fused_kernel, scale=1.0 / (d ** 0.5), d=d, g=g
+            _dqkv_fused_kernel, scale=1.0 / (d ** 0.5), d=d, g=g,
+            causal=causal,
         ),
         grid=(b, hpb),
         in_specs=[
@@ -432,7 +534,7 @@ def _bwd_fused(q, k, v, bias, lse, do, out, d, interpret):
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=interpret,
-        name="flash_bwd_fused",
+        name=_name("bwd_fused", causal),
     )(q, k, v, bias, lse, do, out)
     return dq, dk, dv
 
@@ -440,23 +542,23 @@ def _bwd_fused(q, k, v, bias, lse, do, out, d, interpret):
 # --------------------------------------------------------------- public op
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash(q, k, v, bias, d, block_q, block_k, interpret):
-    out, _lse = _fwd(q, k, v, bias, d, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash(q, k, v, bias, d, block_q, block_k, causal, interpret):
+    out, _lse = _fwd(q, k, v, bias, d, block_q, block_k, causal, interpret)
     return out
 
 
-def _flash_fwd(q, k, v, bias, d, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, bias, d, block_q, block_k, causal, interpret):
     # ``out`` is what the remat policies save per layer (a Pallas output),
     # in the layout the out-projection reads: no lane padding at any D
-    out, lse = _fwd(q, k, v, bias, d, block_q, block_k, interpret)
+    out, lse = _fwd(q, k, v, bias, d, block_q, block_k, causal, interpret)
     return out, (q, k, v, bias, out, lse)
 
 
-def _flash_bwd(d, block_q, block_k, interpret, residuals, g):
+def _flash_bwd(d, block_q, block_k, causal, interpret, residuals, g):
     q, k, v, bias, out, lse = residuals
     dq, dk, dv = _bwd(q, k, v, bias, lse, g, out, d, block_q, block_k,
-                      interpret)
+                      causal, interpret)
     # the mask bias is non-differentiable input
     return dq, dk, dv, jnp.zeros_like(bias)
 
@@ -464,7 +566,7 @@ def _flash_bwd(d, block_q, block_k, interpret, residuals, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _flash_local(q, k, v, bias, d, block_q, block_k, interpret):
+def _flash_local(q, k, v, bias, d, block_q, block_k, causal, interpret):
     """The op on [B, S, H·D] operands as ONE device sees them (the whole
     arrays off-mesh, this device's batch/head shard under shard_map)."""
     # named as the dense layers give them, which is what the kernels read:
@@ -472,7 +574,7 @@ def _flash_local(q, k, v, bias, d, block_q, block_k, interpret):
     # consumes, in the one layout the stash and both kernels share
     q, k, v = (checkpoint_name(x, "flash_qkv") for x in (q, k, v))
     bias = bias[:, None, :].astype(jnp.float32)  # [B, 1, S] row per sample
-    return _flash(q, k, v, bias, d, block_q, block_k, interpret)
+    return _flash(q, k, v, bias, d, block_q, block_k, causal, interpret)
 
 
 def flash_attention(
@@ -484,6 +586,7 @@ def flash_attention(
     block_k: int = 512,
     interpret: Optional[bool] = None,
     mesh: Optional[Mesh] = None,
+    causal: bool = False,
 ) -> jnp.ndarray:
     """Exact fused attention; drop-in for dense/blockwise attention.
 
@@ -493,6 +596,8 @@ def flash_attention(
     of 128 (or the whole sequence) for the bias/lse BlockSpecs to be
     Mosaic-legal. ``mesh``: the device mesh the caller's jit spans — the op
     then runs per shard under ``shard_map`` (see module docstring).
+    ``causal``: a decoder's mask, inside the kernels (query i sees keys
+    0..i; the KV bias still applies on top).
     """
     if interpret is None:
         interpret = pallas_interpret()
@@ -500,7 +605,7 @@ def flash_attention(
     if bias is None:
         bias = jnp.zeros((b, s), jnp.float32)
     op = functools.partial(
-        _flash_local, d=d, block_q=block_q, block_k=block_k,
+        _flash_local, d=d, block_q=block_q, block_k=block_k, causal=causal,
         interpret=interpret,
     )
     if mesh is not None:

@@ -41,6 +41,7 @@ def _local_template(args: CollaborationArguments):
         args.training.remat_policy,
         impl,
         args.training.vocab_size,
+        num_hidden_layers=args.training.num_hidden_layers,
     )
     seq = min(args.training.seq_length, cfg.max_position_embeddings)
     params = jax.eval_shape(

@@ -1,10 +1,11 @@
 """Shared builders for role entry points: model, optimizer, DHT, data."""
 from __future__ import annotations
 
+import dataclasses
 import json
 import os
 import time
-from typing import Callable, Dict, Iterator, Optional, Tuple
+from typing import Any, Callable, Dict, Iterator, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -20,10 +21,152 @@ from dedloc_tpu.models.albert import (
     albert_pretraining_loss,
     albert_pretraining_loss_gathered,
 )
-from dedloc_tpu.optim import lamb, linear_warmup_linear_decay
+from dedloc_tpu.models.ouro import (
+    OuroConfig,
+    OuroForCausalLM,
+    ouro_loss,
+    ouro_train_tflops_per_sample,
+    ouro_weight_decay_mask,
+)
+from dedloc_tpu.optim import (
+    albert_weight_decay_mask,
+    lamb,
+    linear_warmup_linear_decay,
+)
 from dedloc_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
+
+
+@dataclasses.dataclass(frozen=True)
+class ModelFamily:
+    """Everything the trainer role needs to know about a kind of model:
+    the one table from a ``--training.model_size`` name to its config,
+    module, loss, synthetic batch source and analytic FLOPs."""
+
+    config: Any  # the config class; ``config.named(size)`` -> constructor
+    module: Callable  # cfg -> nn.Module
+    loss: Callable  # module -> loss_fn(params, batch, rng)
+    # (cfg, batch_size, seq_length, seed) -> iterator of host batches
+    synthetic_batches: Callable
+    # (cfg, seq) -> analytic model TFLOPs of one fwd+bwd sample
+    tflops_per_sample: Callable
+    # params -> tree of bools, True where LAMB's weight decay applies
+    weight_decay_mask: Callable
+    # metrics of the loss that are vectors over the passes: summed on the
+    # device with the loss and read with it, once per global step
+    step_gauges: Tuple[str, ...] = ()
+
+
+def _albert_loss(model: AlbertForPreTraining) -> Callable:
+    """Gathered masked-position loss when the batch carries ``mlm_positions``
+    (the fast TPU layout); dense per-position loss otherwise. With an MoE
+    config the Switch load-balancing aux loss (sowed into the "losses"
+    collection by the encoder) is added at ``cfg.moe_aux_weight``."""
+    moe = getattr(model.cfg, "moe_experts", 0) > 0
+
+    def loss_fn(params, batch, rng):
+        gathered = "mlm_positions" in batch
+        apply_kwargs = dict(
+            mlm_positions=batch["mlm_positions"] if gathered else None,
+        )
+        if moe:
+            (mlm_logits, sop_logits), mutated = model.apply(
+                {"params": params},
+                batch["input_ids"],
+                batch["attention_mask"],
+                batch["token_type_ids"],
+                mutable=("losses",),
+                **apply_kwargs,
+            )
+        else:
+            mlm_logits, sop_logits = model.apply(
+                {"params": params},
+                batch["input_ids"],
+                batch["attention_mask"],
+                batch["token_type_ids"],
+                **apply_kwargs,
+            )
+        if gathered:
+            loss, metrics = albert_pretraining_loss_gathered(
+                mlm_logits,
+                sop_logits,
+                batch["mlm_label_ids"],
+                batch["mlm_weights"],
+                batch["sop_labels"],
+            )
+        else:
+            loss, metrics = albert_pretraining_loss(
+                mlm_logits, sop_logits, batch["mlm_labels"], batch["sop_labels"]
+            )
+        if moe:
+            aux = sum(
+                jnp.sum(leaf)
+                for leaf in jax.tree_util.tree_leaves(mutated["losses"])
+            )
+            loss = loss + model.cfg.moe_aux_weight * aux
+            metrics = dict(metrics, moe_aux=aux)
+        return loss, metrics
+
+    return loss_fn
+
+
+def _albert_tflops(cfg: AlbertConfig, seq: int) -> float:
+    from dedloc_tpu.telemetry.steps import albert_tflops_per_sample
+
+    return albert_tflops_per_sample(cfg, seq, max_predictions_for(seq))
+
+
+def _ouro_loss(model: OuroForCausalLM) -> Callable:
+    def loss_fn(params, batch, rng):
+        return ouro_loss(model, params, batch)
+
+    return loss_fn
+
+
+def _ouro_batches(cfg: OuroConfig, batch_size: int, seq_length: int,
+                  seed: int) -> Iterator[Dict[str, np.ndarray]]:
+    from dedloc_tpu.data.causal_lm import synthetic_causal_lm_batches
+
+    return synthetic_causal_lm_batches(
+        cfg.vocab_size, batch_size,
+        min(seq_length, cfg.max_position_embeddings), seed,
+    )
+
+
+ALBERT = ModelFamily(
+    config=AlbertConfig, module=AlbertForPreTraining, loss=_albert_loss,
+    synthetic_batches=lambda *a: synthetic_mlm_batches(*a),
+    tflops_per_sample=_albert_tflops,
+    weight_decay_mask=albert_weight_decay_mask,
+)
+OURO = ModelFamily(
+    config=OuroConfig, module=OuroForCausalLM, loss=_ouro_loss,
+    synthetic_batches=_ouro_batches,
+    tflops_per_sample=ouro_train_tflops_per_sample,
+    weight_decay_mask=ouro_weight_decay_mask,
+    step_gauges=("lm.exit_prob", "lm.loss"),
+)
+MODEL_FAMILIES: Dict[str, ModelFamily] = {
+    "tiny": ALBERT, "large": ALBERT, "ouro_tiny": OURO, "ouro_2p6b": OURO,
+}
+
+
+def model_family(model) -> ModelFamily:
+    """The family of a ``--training.model_size`` name, a config or a
+    module."""
+    if isinstance(model, str):
+        if model not in MODEL_FAMILIES:
+            raise ValueError(
+                f"unknown model_size {model!r} "
+                f"(expected one of {sorted(MODEL_FAMILIES)})"
+            )
+        return MODEL_FAMILIES[model]
+    cfg = getattr(model, "cfg", model)
+    for family in (ALBERT, OURO):
+        if isinstance(cfg, family.config):
+            return family
+    raise TypeError(f"no model family for {type(cfg).__name__}")
 
 
 def build_model(
@@ -38,32 +181,46 @@ def build_model(
     moe_mesh=None,
     moe_capacity_factor: float = 0.0,
     moe_aux_weight: float = -1.0,
-) -> Tuple[AlbertConfig, AlbertForPreTraining]:
+    num_hidden_layers: int = 0,
+):
+    """(config, module) of a ``--training.model_size`` name, with the
+    trainer's overrides. No WIDTH is an override: the depth is the one size
+    a run may cut (a chip's share of a deeper deployment)."""
+    family = model_family(model_size)
     overrides = {}
     if remat_policy:
         overrides["remat_policy"] = remat_policy
-        from dedloc_tpu.models.albert import fused_ln_for_policy
-
-        overrides["fused_ln"] = fused_ln_for_policy(remat_policy)
     if attention_impl:
         overrides["attention_impl"] = attention_impl
     if vocab_size:
         overrides["vocab_size"] = vocab_size
     if mesh is not None:
         overrides["mesh"] = mesh
-    if pipe_mesh is not None:
-        overrides["pipe_mesh"] = pipe_mesh
-        overrides["pipe_microbatches"] = pipe_microbatches
-    if moe_experts:
-        overrides["moe_experts"] = moe_experts
-        if moe_mesh is not None:
-            overrides["moe_mesh"] = moe_mesh
-        if moe_capacity_factor > 0:
-            overrides["moe_capacity_factor"] = moe_capacity_factor
-        if moe_aux_weight >= 0:
-            overrides["moe_aux_weight"] = moe_aux_weight
-    cfg = AlbertConfig.named(model_size)(**overrides)
-    return cfg, AlbertForPreTraining(cfg)
+    if num_hidden_layers:
+        overrides["num_hidden_layers"] = num_hidden_layers
+    if family is ALBERT:
+        if remat_policy:
+            from dedloc_tpu.models.albert import fused_ln_for_policy
+
+            overrides["fused_ln"] = fused_ln_for_policy(remat_policy)
+        if pipe_mesh is not None:
+            overrides["pipe_mesh"] = pipe_mesh
+            overrides["pipe_microbatches"] = pipe_microbatches
+        if moe_experts:
+            overrides["moe_experts"] = moe_experts
+            if moe_mesh is not None:
+                overrides["moe_mesh"] = moe_mesh
+            if moe_capacity_factor > 0:
+                overrides["moe_capacity_factor"] = moe_capacity_factor
+            if moe_aux_weight >= 0:
+                overrides["moe_aux_weight"] = moe_aux_weight
+    elif pipe_mesh is not None or moe_experts:
+        raise ValueError(
+            f"model_size {model_size!r}: the pipeline and MoE paths are "
+            "ALBERT's"
+        )
+    cfg = family.config.named(model_size)(**overrides)
+    return cfg, family.module(cfg)
 
 
 def build_optimizer(args: CollaborationArguments):
@@ -79,6 +236,9 @@ def build_optimizer(args: CollaborationArguments):
         weight_decay=args.training.weight_decay,
         clamp_value=args.training.clamp_value,
         max_grad_norm=args.training.max_grad_norm,
+        weight_decay_mask=model_family(
+            args.training.model_size
+        ).weight_decay_mask,
     )
 
 
@@ -96,10 +256,10 @@ def build_flat_opt_factory(args: CollaborationArguments):
 
     def factory(spec, params):
         from dedloc_tpu.optim.flat import FlatLamb, tree_flags
-        from dedloc_tpu.optim.lamb import albert_weight_decay_mask
 
+        mask = model_family(args.training.model_size).weight_decay_mask
         flags = tree_flags(
-            albert_weight_decay_mask(params), params,
+            mask(params), params,
             [name for name, _shape, _dtype in spec],
         )
         return FlatLamb(
@@ -302,57 +462,10 @@ def publish_step_metrics(
     )
 
 
-def build_loss_fn(model: AlbertForPreTraining) -> Callable:
-    """Gathered masked-position loss when the batch carries ``mlm_positions``
-    (the fast TPU layout); dense per-position loss otherwise. With an MoE
-    config the Switch load-balancing aux loss (sowed into the "losses"
-    collection by the encoder) is added at ``cfg.moe_aux_weight``."""
-    moe = getattr(model.cfg, "moe_experts", 0) > 0
-
-    def loss_fn(params, batch, rng):
-        gathered = "mlm_positions" in batch
-        apply_kwargs = dict(
-            mlm_positions=batch["mlm_positions"] if gathered else None,
-        )
-        if moe:
-            (mlm_logits, sop_logits), mutated = model.apply(
-                {"params": params},
-                batch["input_ids"],
-                batch["attention_mask"],
-                batch["token_type_ids"],
-                mutable=("losses",),
-                **apply_kwargs,
-            )
-        else:
-            mlm_logits, sop_logits = model.apply(
-                {"params": params},
-                batch["input_ids"],
-                batch["attention_mask"],
-                batch["token_type_ids"],
-                **apply_kwargs,
-            )
-        if gathered:
-            loss, metrics = albert_pretraining_loss_gathered(
-                mlm_logits,
-                sop_logits,
-                batch["mlm_label_ids"],
-                batch["mlm_weights"],
-                batch["sop_labels"],
-            )
-        else:
-            loss, metrics = albert_pretraining_loss(
-                mlm_logits, sop_logits, batch["mlm_labels"], batch["sop_labels"]
-            )
-        if moe:
-            aux = sum(
-                jnp.sum(leaf)
-                for leaf in jax.tree_util.tree_leaves(mutated["losses"])
-            )
-            loss = loss + model.cfg.moe_aux_weight * aux
-            metrics = dict(metrics, moe_aux=aux)
-        return loss, metrics
-
-    return loss_fn
+def build_loss_fn(model) -> Callable:
+    """The family's loss for ``model``: (params, batch, rng) -> (loss,
+    metrics)."""
+    return model_family(model).loss(model)
 
 
 def synthetic_mlm_batches(
@@ -393,6 +506,8 @@ def drop_collator_keys(batch: Dict[str, np.ndarray]) -> Dict[str, jnp.ndarray]:
             "mlm_weights",
             "sop_labels",
         )
+    elif "labels" in batch:  # causal LM: inputs and next-token labels
+        keep = ("input_ids", "labels")
     else:
         keep = (
             "input_ids",

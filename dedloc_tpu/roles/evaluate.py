@@ -59,6 +59,7 @@ def run_eval(args: CollaborationArguments,
         args.training.remat_policy,
         impl,
         args.training.vocab_size,
+        num_hidden_layers=args.training.num_hidden_layers,
     )
     if not args.training.dataset_path:
         raise ValueError("--training.dataset_path: a tokenized dir is required")

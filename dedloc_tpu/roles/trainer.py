@@ -22,11 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from dedloc_tpu.collaborative.optimizer import CollaborativeOptimizer
 from dedloc_tpu.telemetry import steps
 from dedloc_tpu.telemetry.profile import profile_gate
-from dedloc_tpu.telemetry.steps import (
-    StepRecorder,
-    albert_tflops_per_sample,
-    chip_peak_tflops,
-)
+from dedloc_tpu.telemetry.steps import StepRecorder, chip_peak_tflops
 from dedloc_tpu.core.config import CollaborationArguments, parse_config
 from dedloc_tpu.data.streaming import peer_shuffle_seed
 from dedloc_tpu.parallel.train_step import (
@@ -35,6 +31,7 @@ from dedloc_tpu.parallel.train_step import (
     zeros_like_grads,
 )
 from dedloc_tpu.roles.common import (
+    ALBERT,
     build_dht,
     build_flat_opt_factory,
     build_loss_fn,
@@ -43,9 +40,9 @@ from dedloc_tpu.roles.common import (
     checkpoint_kwargs,
     configure_role_telemetry,
     drop_collator_keys,
+    model_family,
     open_train_log,
     publish_step_metrics,
-    synthetic_mlm_batches,
 )
 from dedloc_tpu.utils.backend import describe_backend, ensure_compile_cache
 from dedloc_tpu.utils.checkpoint import load_latest_checkpoint, save_checkpoint
@@ -158,7 +155,9 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
         ),
         moe_capacity_factor=args.training.moe_capacity_factor,
         moe_aux_weight=args.training.moe_aux_weight,
+        num_hidden_layers=args.training.num_hidden_layers,
     )
+    family = model_family(cfg)
     tx = build_optimizer(args)
     # gated: record-sign with the token key, so the signed subkey digests
     # to this peer's verified identity (ledger binding, roles/common.py)
@@ -222,6 +221,11 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
     if mesh is not None and (
         "model" in mesh.axis_names or "expert" in mesh.axis_names
     ):
+        if family is not ALBERT:
+            raise ValueError(
+                "mesh_model_devices / mesh_expert_devices: the tensor- and "
+                "expert-parallel layouts are ALBERT's (parallel/sharding.py)"
+            )
         from dedloc_tpu.parallel.sharding import (
             ALBERT_EP_RULES,
             ALBERT_TP_RULES,
@@ -367,9 +371,12 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
 
     # the running loss stays ON DEVICE (a lazy sum) — a float() in the loop
     # would synchronize the host with the accumulate kernels and serialize
-    # the input pipeline against XLA dispatch; the host reads one scalar per
-    # GLOBAL step, right where the value is published
-    loss_sum_dev = jnp.zeros([])
+    # the input pipeline against XLA dispatch; the host reads it once per
+    # GLOBAL step, right where the value is published — and with it, in the
+    # same read, the family's per-pass gauges (a looped model's exit
+    # distribution and per-pass loss)
+    summed = ("loss",) + family.step_gauges
+    sums_dev: dict = {}
     mini_steps = 0
     boundary = 0
     last_saved_step = opt.local_step
@@ -384,13 +391,9 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
     # planes of a profile. The MFU gauge uses the same analytic model-FLOPs
     # formula and peak table as bench.py.
     perf = PerfStats()
-    from dedloc_tpu.data.mlm import max_predictions_for
-
     recorder = StepRecorder(
         telemetry=tele,
-        model_tflops_per_sample=albert_tflops_per_sample(
-            cfg, seq, max_predictions_for(seq)
-        ),
+        model_tflops_per_sample=family.tflops_per_sample(cfg, seq),
         peak_tflops=chip_peak_tflops(),
         perf=perf,
         profile=profile_gate(args.telemetry),
@@ -425,7 +428,10 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
                         grad_acc, n_acc, metrics = accumulate(
                             state.params, grad_acc, n_acc, batch, sub
                         )
-                        loss_sum_dev = loss_sum_dev + metrics["loss"]
+                        sums_dev = {
+                            k: sums_dev[k] + metrics[k] if k in sums_dev
+                            else metrics[k] for k in summed
+                        }
                     mini_steps += 1
                 state, grad_acc, n_acc, stepped = opt.step(
                     state, grad_acc, n_acc, samples
@@ -434,9 +440,19 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
                     with steps.phase("post_step"):
                         with steps.phase("loss_sync"):
                             # the one sync per global step
-                            loss_sum = float(loss_sum_dev)
-                        loss_sum_dev = jnp.zeros([])
+                            sums = jax.device_get(sums_dev)
+                        sums_dev = {}
+                        loss_sum = float(sums["loss"])
                         loss = loss_sum / max(mini_steps, 1)
+                        for name in family.step_gauges:
+                            # means over the global step's tokens, onto the
+                            # step record and (telemetry on) into gauges
+                            for t, value in enumerate(
+                                sums[name] / max(mini_steps, 1), start=1
+                            ):
+                                srec.attrs[f"{name}.{t}"] = float(value)
+                                if tele is not None:
+                                    tele.gauge(f"{name}.{t}").set(float(value))  # dedlint: emits=gauge:lm.exit_prob.*,gauge:lm.loss.*
                         # advertise the loss for the trunk-health gate —
                         # free here, the scalar is already on the host
                         opt.report_loss(loss)
@@ -534,6 +550,17 @@ def _make_batches(
     ``dataset_path`` is set (tokenize_wikitext103 output layout)."""
     seed = peer_shuffle_seed(public_key)  # per-peer independent shuffling
     batch_size = slice_batch or args.training.per_device_batch_size
+    family = model_family(cfg)
+    if not args.training.streaming_files and not args.training.dataset_path:
+        return family.synthetic_batches(
+            cfg, batch_size, args.training.seq_length, seed
+        )
+    if family is not ALBERT:
+        raise ValueError(
+            "--training.streaming_files / --training.dataset_path read "
+            "ALBERT's MLM corpora; a causal-LM corpus goes through "
+            "data/causal_lm.pack_rows"
+        )
     if args.training.streaming_files:
         # sahajbert-style streaming mode (dataset_streaming.py capability):
         # weighted lazy mix + per-peer shuffle buffer + on-the-fly tokenize
@@ -579,13 +606,6 @@ def _make_batches(
             buffer_size=args.training.streaming_buffer_size,
             max_predictions=max_predictions_for(seq),
         ), size=8)
-    if not args.training.dataset_path:
-        return synthetic_mlm_batches(
-            cfg,
-            batch_size,
-            args.training.seq_length,
-            seed,
-        )
     from dedloc_tpu.data.disk import tokenized_dataset_batches
 
     return tokenized_dataset_batches(

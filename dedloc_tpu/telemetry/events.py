@@ -77,6 +77,7 @@ MM_ROUNDS_ATTEMPTED = "mm.rounds_attempted"
 MM_ROUNDS_FORMED = "mm.rounds_formed"
 NET_BYTES_IN = "net.bytes_in"
 NET_BYTES_OUT = "net.bytes_out"
+OPT_BACKUP_BYTES = "opt.backup_bytes"
 OPT_BOUNDARIES = "opt.boundaries"
 OPT_CATCH_UP = "opt.catch_up"
 OPT_CATCH_UPS = "opt.catch_ups"
@@ -191,6 +192,7 @@ COUNTERS = frozenset({
     "mm.rounds_formed",
     "net.bytes_in",
     "net.bytes_out",
+    "opt.backup_bytes",
     "opt.boundaries",
     "opt.catch_ups",
     "opt.d2h_bytes",
@@ -332,6 +334,8 @@ EMITTED = COUNTERS | GAUGES | HISTOGRAMS | EVENTS
 # declared dynamic-name families (emit-site pragmas)
 EMITTED_PREFIXES = (
     "link.",
+    "lm.exit_prob.",
+    "lm.loss.",
     "perf.",
     "step.phase.",
 )

@@ -56,6 +56,71 @@ def test_lamb_weight_decay_mask():
     assert mask["encoder"]["layernorm"]["bias"] is False
 
 
+def _undecayed(mask):
+    return sorted(
+        "/".join(k.key for k in path)
+        for path, decayed in jax.tree_util.tree_leaves_with_path(mask)
+        if not decayed
+    )
+
+
+def test_albert_mask_knows_no_other_models_names():
+    """The family-neutral rule is bias + LayerNorm and nothing else: a
+    module that happens to be called ``norm`` keeps its decay, and
+    ALBERT's own undecayed set is the reference recipe's, leaf for leaf."""
+    from dedloc_tpu.roles.common import build_model
+
+    mask = albert_weight_decay_mask(
+        {"norm": {"kernel": jnp.ones((2, 2)), "weight": jnp.ones(2)}}
+    )
+    assert mask == {"norm": {"kernel": True, "weight": True}}
+
+    _cfg, model = build_model("tiny")
+    ids = jnp.zeros((1, 8), jnp.int32)
+    params = jax.eval_shape(
+        lambda r: model.init(r, ids, ids, ids)["params"],
+        jax.random.PRNGKey(0),
+    )
+    block = "albert/encoder/layer/block"
+    assert _undecayed(albert_weight_decay_mask(params)) == sorted(
+        [f"{block}/{m}/bias" for m in (
+            "attention/dense", "attention/key", "attention/query",
+            "attention/value", "attention/layernorm", "ffn", "ffn_output",
+            "layernorm",
+        )]
+        + [f"{block}/attention/layernorm/scale", f"{block}/layernorm/scale"]
+        + ["albert/embedding_projection/bias",
+           "albert/embeddings_layernorm/bias",
+           "albert/embeddings_layernorm/scale", "albert/pooler/bias",
+           "mlm_dense/bias", "mlm_layernorm/bias", "mlm_layernorm/scale",
+           "sop_classifier/bias"]
+    )
+
+
+def test_each_family_supplies_its_own_mask():
+    """``build_optimizer`` takes the mask from the model table: Ouro's
+    RMSNorm weights (stacked [L, H], the final ``norm`` among them) and the
+    gate's bias go undecayed, every matrix decays."""
+    from dedloc_tpu.roles.common import build_model, model_family
+
+    assert model_family("large").weight_decay_mask is albert_weight_decay_mask
+    _cfg, model = build_model("ouro_tiny")
+    params = jax.eval_shape(
+        lambda r: model.init(r, jnp.zeros((1, 8), jnp.int32))["params"],
+        jax.random.PRNGKey(0),
+    )
+    block = "model/layers/block"
+    assert _undecayed(
+        model_family("ouro_tiny").weight_decay_mask(params)
+    ) == sorted(
+        [f"{block}/{n}/weight" for n in (
+            "input_layernorm", "input_layernorm_2",
+            "post_attention_layernorm", "post_attention_layernorm_2",
+        )]
+        + ["model/norm/weight", "model/early_exit_gate/bias"]
+    )
+
+
 def test_lamb_trust_ratio_clamp():
     """Huge params: ||w|| must be clamped at clamp_value in the trust ratio."""
     params = {"w": jnp.full((10,), 1e6)}

@@ -1,0 +1,208 @@
+"""``ouro_tiny`` through the model against the plain reference
+(``benchmark/reference/ouro.py``: float32, matmul precision 'highest', no
+kernels, no remat, whole logits) on seeded random weights: loss, per-pass
+logits, exit distribution, the whole gradient and its worst leaf, with dense
+and with flash attention; the chunked head + loss against the unchunked one;
+the exit distribution summing to 1 and the entropy term's sign; and a
+reference one pass short, or with the final norm outside the loop, failing
+by orders of magnitude."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import ouro as reference
+from dedloc_tpu.models.ouro import (
+    OuroConfig,
+    OuroForCausalLM,
+    chunked_cross_entropy,
+    exit_distribution,
+    gated_loss,
+    ouro_logits,
+    ouro_loss,
+    ouro_train_tflops_per_sample,
+)
+
+# float32 on both sides: what is left is the order of the arithmetic
+LOSS_TOL, GRAD_TOL, LEAF_TOL = 2e-6, 2e-5, 1e-4
+
+
+def _setup(impl, **overrides):
+    cfg = OuroConfig.tiny(
+        dtype=jnp.float32, attention_impl=impl, attention_block_size=32,
+        **overrides,
+    )
+    model = OuroForCausalLM(cfg)
+    rows = np.random.default_rng(0).integers(
+        0, cfg.vocab_size, (2, 65)
+    ).astype(np.int32)
+    batch = {"input_ids": jnp.asarray(rows[:, :-1]),
+             "labels": jnp.asarray(rows[:, 1:])}
+    params = model.init(jax.random.PRNGKey(1), batch["input_ids"])["params"]
+    # away from the initialiser's symmetry: norms off 1, gate bias off 0
+    leaves, treedef = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(2), len(leaves))
+    params = jax.tree.unflatten(treedef, [
+        leaf + 0.1 * jax.random.normal(key, leaf.shape)
+        for leaf, key in zip(leaves, keys)
+    ])
+    return cfg, model, params, batch
+
+
+def _reference_kwargs(cfg, **changes):
+    kwargs = dict(
+        num_heads=cfg.num_attention_heads, eps=cfg.rms_norm_eps,
+        theta=cfg.rope_theta, passes=cfg.total_ut_steps,
+        beta=cfg.exit_entropy_beta,
+    )
+    kwargs.update(changes)
+    return kwargs
+
+
+def _compare(grads, ref_grads):
+    """(whole-gradient relative L2, worst leaf's)."""
+    a, b = jax.tree.leaves(grads), jax.tree.leaves(ref_grads)
+    whole = np.sqrt(sum(float(jnp.sum((x - y) ** 2)) for x, y in zip(a, b)))
+    whole /= np.sqrt(sum(float(jnp.sum(y ** 2)) for y in b))
+    worst = max(
+        float(jnp.linalg.norm(x - y) / jnp.linalg.norm(y)) for x, y in zip(a, b)
+    )
+    return whole, worst
+
+
+def _role_and_reference(impl, **reference_changes):
+    cfg, model, params, batch = _setup(impl)
+    with jax.default_matmul_precision("highest"):
+        (loss, metrics), grads = jax.value_and_grad(
+            lambda p: ouro_loss(model, p, batch), has_aux=True
+        )(params)
+        kwargs = _reference_kwargs(cfg, **reference_changes)
+        ref_loss, ref_grads = jax.value_and_grad(
+            lambda p: reference.loss_fn(p, batch, **kwargs)
+        )(params)
+        out = reference.forward(params, batch, **kwargs)
+        hiddens, _gates = model.apply({"params": params}, batch["input_ids"])
+        logits = ouro_logits(params, hiddens, cfg)
+    return cfg, loss, metrics, grads, logits, ref_loss, ref_grads, out
+
+
+@pytest.mark.parametrize("impl", ["dense", "flash"])
+def test_model_matches_reference(impl):
+    cfg, loss, metrics, grads, logits, ref_loss, ref_grads, out = (
+        _role_and_reference(impl)
+    )
+    assert abs(float(loss) - float(ref_loss)) <= LOSS_TOL * abs(float(ref_loss))
+    np.testing.assert_allclose(logits, out["logits"], atol=2e-5)
+    assert logits.shape == (cfg.total_ut_steps, 2, 64, cfg.vocab_size)
+    np.testing.assert_allclose(
+        metrics["lm.exit_prob"], jnp.mean(out["p"], axis=(1, 2)), atol=1e-6
+    )
+    np.testing.assert_allclose(
+        metrics["lm.loss"], jnp.mean(out["ce"], axis=(1, 2)), rtol=1e-5
+    )
+    whole, worst = _compare(grads, ref_grads)
+    assert whole <= GRAD_TOL and worst <= LEAF_TOL, (whole, worst)
+    # every leaf is trained: the gate, the final norm, both embeddings
+    assert all(float(jnp.linalg.norm(g)) > 0 for g in jax.tree.leaves(grads))
+
+
+@pytest.mark.parametrize("change", [
+    {"passes": 2},  # one pass short
+    {"final_norm_inside": False},  # the final norm applied outside the loop
+])
+def test_a_different_function_fails_by_orders_of_magnitude(change):
+    _cfg, loss, _m, grads, _l, ref_loss, ref_grads, _out = (
+        _role_and_reference("dense", **change)
+    )
+    whole, worst = _compare(grads, ref_grads)
+    # read: gradient 0.26 / 0.38 relative L2, worst leaf 1.4 / 2.3, loss
+    # 4.2e-3 / 1.5e-3 relative (random weights keep every pass near ln V)
+    assert whole > 1e3 * GRAD_TOL and worst > 1e3 * LEAF_TOL, (whole, worst)
+    assert (
+        abs(float(loss) - float(ref_loss)) > 1e2 * LOSS_TOL * abs(float(ref_loss))
+    )
+
+
+@pytest.mark.parametrize("chunk", [16, 64, 128])
+def test_chunked_head_matches_unchunked(chunk):
+    """[T, N] cross-entropy and its gradients, one (pass, chunk) of logits at
+    a time under remat, against the whole [T, N, V] logits at once."""
+    keys = jax.random.split(jax.random.PRNGKey(3), 3)
+    hiddens = jax.random.normal(keys[0], (3, 128, 32))
+    head = jax.random.normal(keys[1], (32, 256)) * 0.2
+    labels = jax.random.randint(keys[2], (128,), 0, 256)
+
+    def whole(h, w):
+        log_probs = jax.nn.log_softmax(jnp.einsum("tnh,hv->tnv", h, w), -1)
+        return -jnp.take_along_axis(
+            log_probs, jnp.broadcast_to(labels, (3, 128))[..., None], -1
+        )[..., 0]
+
+    weights = jnp.arange(3 * 128, dtype=jnp.float32).reshape(3, 128) / 100
+    with jax.default_matmul_precision("highest"):
+        got = chunked_cross_entropy(hiddens, head, labels, chunk)
+        np.testing.assert_allclose(got, whole(hiddens, head), atol=1e-5)
+        g_got = jax.grad(
+            lambda h, w: jnp.sum(
+                chunked_cross_entropy(h, w, labels, chunk) * weights
+            ), (0, 1)
+        )(hiddens, head)
+        g_want = jax.grad(
+            lambda h, w: jnp.sum(whole(h, w) * weights), (0, 1)
+        )(hiddens, head)
+    for a, b in zip(g_got, g_want):
+        np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+def test_chunk_must_divide_the_tokens():
+    with pytest.raises(ValueError, match="must divide"):
+        chunked_cross_entropy(
+            jnp.zeros((2, 48, 8)), jnp.zeros((8, 16)),
+            jnp.zeros((48,), jnp.int32), 32,
+        )
+
+
+def test_exit_distribution_sums_to_one_and_entropy_is_a_bonus():
+    gates = jax.random.normal(jax.random.PRNGKey(4), (4, 50)) * 3.0
+    p, log_p = exit_distribution(gates)
+    np.testing.assert_allclose(jnp.sum(p, axis=0), 1.0, atol=1e-6)
+    assert bool(jnp.all(p > 0))
+    np.testing.assert_allclose(jnp.exp(log_p), p, rtol=1e-6)
+    # by hand: lambda = 1/2 everywhere gives 1/2, 1/4, 1/8 and the rest, 1/8
+    p_half, _ = exit_distribution(jnp.zeros((4, 1)))
+    np.testing.assert_allclose(p_half[:, 0], [0.5, 0.25, 0.125, 0.125])
+    # saturated gates neither overflow nor produce NaN
+    p_sat, log_sat = exit_distribution(jnp.array([[80.0], [-80.0], [0.0]]))
+    assert bool(jnp.all(jnp.isfinite(log_sat))) and float(p_sat[0, 0]) == 1.0
+    # the entropy term LOWERS the loss (a bonus for spreading the exits):
+    # equal CE at every pass leaves only -beta * H(p)
+    ce = jnp.ones((4, 50))
+    loss0, _ = gated_loss(ce, gates, beta=0.0)
+    loss1, metrics = gated_loss(ce, gates, beta=0.05)
+    assert float(loss0) == pytest.approx(1.0, abs=1e-6)
+    assert float(metrics["exit_entropy"]) > 0
+    assert float(loss1) == pytest.approx(
+        1.0 - 0.05 * float(metrics["exit_entropy"]), abs=1e-6
+    )
+    # uniform p has the largest entropy, log 4
+    uniform, _ = exit_distribution(
+        jnp.log(jnp.array([[1 / 3], [1 / 2], [1.0], [0.0]]))
+    )
+    np.testing.assert_allclose(uniform[:, 0], 0.25, atol=1e-6)
+
+
+def test_published_preset_and_flop_model():
+    cfg = OuroConfig.named("ouro_2p6b")()
+    assert (cfg.hidden_size, cfg.num_attention_heads, cfg.head_dim,
+            cfg.intermediate_size, cfg.vocab_size, cfg.num_hidden_layers,
+            cfg.total_ut_steps) == (2048, 16, 128, 5632, 49152, 48, 4)
+    with pytest.raises(ValueError, match="unknown model_size"):
+        OuroConfig.named("ouro_7b")
+    # by hand at 3 layers, S=4,096: per token and layer 2*4*2048^2 (q k v o)
+    # + 2*3*2048*5632 (SwiGLU) + 2*2*2048*2048.5 (the causal triangle);
+    # per pass the head 2*2048*49152; four passes; backward twice the forward
+    layer = 2 * 4 * 2048 ** 2 + 2 * 3 * 2048 * 5632 + 2 * 2 * 2048 * 2048.5
+    want = 3 * 4 * (3 * layer + 2 * 2048 * 49152) * 4096 / 1e12
+    small = OuroConfig.ouro_2p6b(num_hidden_layers=3)
+    assert ouro_train_tflops_per_sample(small, 4096) == pytest.approx(want)
+    assert 27.0 < want < 28.0  # TFLOPs a row

@@ -9,13 +9,21 @@ speed or numerics; those need the chip (``chip_smoke.py``).
 
     python tools/tpu_aot.py            # every program, one JSON line each
     python tools/tpu_aot.py flat_apply_step kernels
+    OURO_LAYERS=4 OURO_BATCH=1 python tools/tpu_aot.py ouro_accumulate_step
 
-Each line: {"program", "compile_s", "tpu_custom_calls", "layer_body_copies"}
-— the last is the shapes of the ``copy`` instructions inside the compiled
-program's while bodies (the scanned layer, forward and backward): relayouts
-the compiler put around an op whose layout differs from its neighbours', paid
-once per layer iteration. Exit code 0 when everything compiled, 3 when no
-v5e can be described here (no libtpu, or one without a compile-only client).
+Each line: {"program", "compile_s", "tpu_custom_calls", "layer_body_copies",
+"memory"} — ``layer_body_copies`` is the shapes of the ``copy`` instructions
+inside the compiled program's while bodies (the scanned layer, forward and
+backward): relayouts the compiler put around an op whose layout differs from
+its neighbours', paid once per layer iteration; ``memory`` is the compiler's
+own analysis (argument / output / temp / alias bytes on the one device): what
+a large state leaves for activations is read here before a chip is asked.
+The Ouro programs (the looped decoder at its published widths) take depth,
+rows and sequence length from ``benchmark/configs/ouro_2p6b_s4096.json``'s
+flags; ``OURO_LAYERS`` / ``OURO_BATCH`` override the first two, to size a
+cut that cell does not run. Exit code 0
+when everything compiled, 3 when no v5e can be described here (no libtpu, or
+one without a compile-only client).
 """
 from __future__ import annotations
 
@@ -138,6 +146,74 @@ def kernels(device):
     )
 
 
+def _ouro_model_and_state():
+    from dedloc_tpu.core.config import CollaborationArguments, parse_config
+    from dedloc_tpu.parallel.train_step import TrainState
+    from dedloc_tpu.roles.common import build_model, build_optimizer
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+        repo, "benchmark", "configs", "ouro_2p6b_s4096.json"
+    )) as f:
+        flags = json.load(f)["flags"]
+    layers = int(os.environ.get(
+        "OURO_LAYERS", flags["--training.num_hidden_layers"]
+    ))
+    batch = int(os.environ.get(
+        "OURO_BATCH", flags["--training.per_device_batch_size"]
+    ))
+    ids = jnp.zeros((batch, flags["--training.seq_length"]), jnp.int32)
+    args = parse_config(
+        CollaborationArguments,
+        ["--training.model_size", flags["--training.model_size"]],
+    )
+    _cfg, model = build_model(
+        args.training.model_size, num_hidden_layers=layers
+    )
+    state = jax.eval_shape(
+        lambda r: TrainState.create(
+            model.init(r, ids)["params"], build_optimizer(args)
+        ),
+        jax.random.PRNGKey(0),
+    )
+    return args, model, state, ids
+
+
+def ouro_accumulate_step(device):
+    """Ouro-2.6B cut in depth: causal flash attention at D=128 over 8 x 8
+    tiles, the scan over layers inside the scan over four passes, the
+    chunked head + gated loss."""
+    from dedloc_tpu.parallel.train_step import (
+        make_accumulate_step,
+        zeros_like_grads,
+    )
+    from dedloc_tpu.roles.common import build_loss_fn
+
+    _args, model, state, ids = _ouro_model_and_state()
+    grads = jax.eval_shape(zeros_like_grads, state.params)
+    return make_accumulate_step(build_loss_fn(model)).lower(*_on_device(
+        device,
+        (state.params, grads, jnp.zeros([], jnp.int32),
+         {"input_ids": ids, "labels": ids}, jax.random.PRNGKey(0)),
+    ))
+
+
+def ouro_guarded_apply_step(device):
+    """The per-leaf LAMB apply of the solo boundary over the same state:
+    its temp bytes are the rollback's second copy of params + moments."""
+    from dedloc_tpu.parallel.train_step import (
+        make_guarded_apply_step,
+        zeros_like_grads,
+    )
+    from dedloc_tpu.roles.common import build_optimizer
+
+    args, _model, state, _ids = _ouro_model_and_state()
+    grads = jax.eval_shape(zeros_like_grads, state.params)
+    return make_guarded_apply_step(build_optimizer(args)).lower(
+        *_on_device(device, (state, grads))
+    )
+
+
 def layer_body_copies(hlo_text: str) -> list:
     """Result shapes of the ``copy`` instructions that sit directly in a
     while body of an optimized HLO module (``compiled.as_text()``)."""
@@ -158,7 +234,10 @@ NO_V5E = 3  # exit code: nothing to compile with, which is not a failure
 
 
 PROGRAMS = {
-    fn.__name__: fn for fn in (accumulate_step, flat_apply_step, kernels)
+    fn.__name__: fn for fn in (
+        accumulate_step, flat_apply_step, kernels, ouro_accumulate_step,
+        ouro_guarded_apply_step,
+    )
 }
 
 
@@ -177,12 +256,20 @@ def main(argv=None) -> int:
             lowered = PROGRAMS[name](device)
         start = time.perf_counter()
         compiled = lowered.compile()
+        seconds = round(time.perf_counter() - start, 2)
+        memory = compiled.memory_analysis()
         print(json.dumps({
             "program": name,
             "device_kind": device.device_kind,
-            "compile_s": round(time.perf_counter() - start, 2),
+            "compile_s": seconds,
             "tpu_custom_calls": lowered.as_text().count("tpu_custom_call"),
             "layer_body_copies": layer_body_copies(compiled.as_text()),
+            "memory": {
+                "argument_bytes": memory.argument_size_in_bytes,
+                "output_bytes": memory.output_size_in_bytes,
+                "temp_bytes": memory.temp_size_in_bytes,
+                "alias_bytes": memory.alias_size_in_bytes,
+            },
         }), flush=True)
     return 0
 
