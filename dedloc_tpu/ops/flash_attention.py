@@ -20,6 +20,24 @@ query blocks, kv innermost; dk/dv over kv blocks, q innermost) — or in ONE
 when a single tile covers the sequence — with delta = rowsum(dO ⊙ O) taken
 inside the kernels from the dO and O blocks they load.
 
+One-tile forms. When one (Bq, Bk) tile covers the sequence (``_pick_block``
+gives S for both blocks: S=512 under the default 512, every tiny test
+model) both directions take a kernel written for that case — the forward
+(``_fwd_one_tile``) as the backward (``_bwd_fused``): grid (B, programs
+across the width), no scratch, no ``pl.when`` phases. The shapes decide,
+nothing else does. A row's softmax is complete after its one tile, so the
+one-tile forward keeps no running max or sum, corrects nothing by
+exp(m_prev - m_new) and accumulates nothing across steps; it is the tiled
+kernel's arithmetic in the same order (there the correction is exactly 0 and
+the accumulator exactly p·v), so ``out`` and ``lse`` are the same bits, and
+0.21 ms a call against 0.40 at (12, 512, 16 x 64) on a v5e: what the tiled form
+pays for is the [Bq, 1] state columns (one lane in 128 of every register,
+masked stores, lane broadcasts) and the accumulator's round trip through
+VMEM, not arithmetic. The softmax scale stays a multiply on the float32
+scores in both forms: folding it into q where 1/sqrt(D) is a power of two
+(exact: the same bits) was measured on the chip and is not faster (0.212 ms
+against 0.209), nor is a reciprocal-and-multiply in place of the one divide.
+
 Layout contract: ONE layout, the model's. q, k, v, dO go in and out, dq, dk,
 dv come out as [B, S, H·D], the array a dense projection writes and the
 out-projection reads ([B, S, H, D] at the public entry is the same bytes);
@@ -80,6 +98,12 @@ def _pick_block(s: int, preferred: int) -> int:
     while s % block:
         block //= 2
     return max(block, 1)
+
+
+def _one_tile(s: int, block_q: int, block_k: int) -> bool:
+    """One (Bq, Bk) tile covers the whole sequence: forward and backward
+    then take their one-tile kernels (see "One-tile forms")."""
+    return _pick_block(s, block_q) == s and _pick_block(s, block_k) == s
 
 
 def _t(x):
@@ -287,6 +311,35 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                 )
 
 
+def _fwd_one_tile_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
+                         scale, d, g, causal):
+    """Single-block forward: when one (Bq, Bk) tile covers the whole
+    sequence a row's softmax is complete after its one tile, so there is no
+    running state to initialise, correct or carry — each head's max, sum and
+    p·v are taken once, and a column block is normalised and stored once."""
+    s = q_ref.shape[0]
+    mask = _tile_mask(0, 0, s, s) if causal else None
+    b = bias_ref[:].astype(jnp.float32)  # [1, S]
+    for h0, cols in _column_blocks(q_ref.shape[-1], g, d):
+        q = q_ref[:, cols]  # [S, g·D]: g heads side by side
+        k = k_ref[:, cols]
+        v = v_ref[:, cols]
+        pv, ls = None, []
+        for i in range(g):
+            x = _masked(
+                _dot(q, _only_head(k, i, d), 1, 1) * scale + b, mask
+            )
+            # the floor the tiled kernel's state starts from: a row whose
+            # every score is -inf stays finite
+            m = jnp.maximum(jnp.max(x, axis=-1, keepdims=True), NEG_INF)
+            p = jnp.exp(x - m)
+            l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+            lse_ref[h0 + i] = _t(m + jnp.log(l))  # [S, 1] -> [1, S] row
+            ls.append(l)
+            pv = _add(pv, _dot(p.astype(v.dtype), _only_head(v, i, d), 1, 0))
+        o_ref[:, cols] = (pv / _per_head_lanes(ls, d)).astype(o_ref.dtype)
+
+
 def _name(kernel: str, causal: bool) -> str:
     """The causal kernels keep names of their own in a device trace."""
     return f"flash_causal_{kernel}" if causal else f"flash_{kernel}"
@@ -294,6 +347,12 @@ def _name(kernel: str, causal: bool) -> str:
 
 def _fwd(q, k, v, bias, d, block_q, block_k, causal, interpret):
     """Returns (out [B, S, H·D], lse [B·H, 1, S])."""
+    if _one_tile(q.shape[1], block_q, block_k):
+        return _fwd_one_tile(q, k, v, bias, d, causal, interpret)
+    return _fwd_tiled(q, k, v, bias, d, block_q, block_k, causal, interpret)
+
+
+def _fwd_tiled(q, k, v, bias, d, block_q, block_k, causal, interpret):
     b, s, h, g, hp, bq, bk = _geometry(q, d, block_q, block_k, budget_mb=6.0)
     hpb = h // hp  # programs across the width
 
@@ -330,6 +389,38 @@ def _fwd(q, k, v, bias, d, block_q, block_k, causal, interpret):
         ],
         interpret=interpret,
         name=_name("fwd", causal),
+    )(q, k, v, bias)
+    return out, lse
+
+
+def _fwd_one_tile(q, k, v, bias, d, causal, interpret):
+    b, s, h, g, hp, _bq, _bk = _geometry(q, d, q.shape[1], q.shape[1],
+                                         budget_mb=6.0)
+    hpb = h // hp
+    wide = pl.BlockSpec((None, s, hp * d), lambda n, p: (n, 0, p))
+    out, lse = pl.pallas_call(
+        functools.partial(
+            _fwd_one_tile_kernel, scale=1.0 / (d ** 0.5), d=d, g=g,
+            causal=causal,
+        ),
+        grid=(b, hpb),
+        in_specs=[
+            wide, wide, wide,
+            pl.BlockSpec((None, 1, s), lambda n, p: (n, 0, 0)),
+        ],
+        out_specs=[
+            wide,
+            pl.BlockSpec((hp, 1, s), lambda n, p: (n * hpb + p, 0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((b * h, 1, s), jnp.float32),
+        ],
+        interpret=interpret,
+        name=_name("fwd", causal),
+        # the same name in a device trace; a lowered module tells the two
+        # forward forms apart by this (tools/tpu_aot.py counts them)
+        metadata={"form": "one_tile"},
     )(q, k, v, bias)
     return out, lse
 
@@ -445,10 +536,10 @@ def _dqkv_fused_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref,
 
 def _bwd(q, k, v, bias, lse, do, out, d, block_q, block_k, causal,
          interpret):
+    if _one_tile(q.shape[1], block_q, block_k):
+        return _bwd_fused(q, k, v, bias, lse, do, out, d, causal, interpret)
     # bwd transients per head are ~3x the fwd's (s, p, dp, ds live at once)
     b, s, h, g, hp, bq, bk = _geometry(q, d, block_q, block_k, budget_mb=4.0)
-    if bq == s and bk == s:
-        return _bwd_fused(q, k, v, bias, lse, do, out, d, causal, interpret)
     hpb = h // hp
     kernel_args = dict(scale=1.0 / (d ** 0.5), d=d, g=g, causal=causal)
 

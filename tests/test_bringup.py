@@ -264,8 +264,10 @@ def test_flat_apply_and_kernels_compile_for_a_v5e_in_seconds():
     rows = _tpu_aot("flat_apply_step", "kernels")
     assert rows["flat_apply_step"]["compile_s"] < 60
     assert rows["kernels"]["compile_s"] < 60
-    # flash fwd (x2), fused bwd, dq, dkv, ln fwd, ln bwd
-    assert rows["kernels"]["tpu_custom_calls"] == 7
+    # flash fwd (x3), fused bwd (x2), dq, dkv, ln fwd, ln bwd
+    assert rows["kernels"]["tpu_custom_calls"] == 9
+    # one tile at (12, 512, 16 x 64) and, causal, at D=128; tiles at S=2,048
+    assert rows["kernels"]["flash_fwd_forms"] == {"one_tile": 2, "tiles": 1}
     assert rows["kernels"]["device_kind"] == "TPU v5 lite"
 
 
@@ -280,6 +282,8 @@ def test_accumulate_step_has_no_relayout_copies_around_flash_attention():
     transpose left in — or put back into — the wrapper, which brings a
     per-head shape ([.., 16, 512, 64], [.., 192, 512, 64]) with it."""
     row = _tpu_aot("accumulate_step")["accumulate_step"]
+    # S=512 under a 512 block: the scanned layer's one forward call
+    assert row["flash_fwd_forms"] == {"one_tile": 1, "tiles": 0}
     copies = row["layer_body_copies"]
     assert len(copies) <= 3, copies
     assert not [shape for shape in copies if shape.endswith(",512,64]")]

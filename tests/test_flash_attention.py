@@ -85,9 +85,10 @@ def test_odd_sequence_blocks(rng):
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
-def test_bfloat16_path(rng):
+@pytest.mark.parametrize("block", [64, 128], ids=["tiles", "one_tile"])
+def test_bfloat16_path(rng, block):
     q, k, v = _qkv(rng, dtype=jnp.bfloat16)
-    out = flash_attention(q, k, v, block_q=64, block_k=64)
+    out = flash_attention(q, k, v, block_q=block, block_k=block)
     ref = dense_attention(q, k, v)
     assert out.dtype == jnp.bfloat16
     np.testing.assert_allclose(
@@ -162,6 +163,62 @@ def test_model_layout_matches_dense(rng, h, d, block, masked):
     for a, b_, name in zip(gf, gd, "qkv"):
         np.testing.assert_allclose(a, b_, atol=5e-4, rtol=5e-4,
                                    err_msg=f"d{name} mismatch")
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("masked", [False, True], ids=["nobias", "mask"])
+@pytest.mark.parametrize("h,d", [(16, 64), (4, 16), (2, 128), (3, 64)])
+def test_one_tile_forward_is_the_tiled_kernel_at_one_tile(rng, h, d, masked,
+                                                          causal):
+    """Where one tile covers the sequence ``_fwd`` takes a kernel with no
+    online-softmax state (as ``_bwd`` takes the fused backward). It is the
+    same arithmetic in the same order — at one tile the tiled kernel's
+    correction is exactly 0 and its accumulator exactly p·v — so ``out`` and
+    the ``lse`` the backward reads are the same BITS, in bfloat16 and in
+    float32, with a sample whose every key is masked (-1e30: the row's
+    softmax is uniform) and one whose bias is -inf (the row comes out 0 and
+    finite from both)."""
+    from dedloc_tpu.ops.flash_attention import _fwd_one_tile, _fwd_tiled
+
+    b, s = 3, 64
+    bias = np.zeros((b, 1, s), np.float32)
+    if masked:
+        bias[0, 0, 40:] = -1e9
+        bias[1] = -1e30
+        bias[2] = -np.inf
+    bias = jnp.asarray(bias)
+    for dtype in (jnp.bfloat16, jnp.float32):
+        q, k, v = (
+            jnp.asarray(rng.standard_normal((b, s, h * d)), dtype)
+            for _ in range(3)
+        )
+        out, lse = _fwd_one_tile(q, k, v, bias, d, causal, True)
+        want_out, want_lse = _fwd_tiled(q, k, v, bias, d, s, s, causal, True)
+        assert out.dtype == dtype and lse.dtype == jnp.float32
+        np.testing.assert_array_equal(
+            out.astype(jnp.float32), want_out.astype(jnp.float32)
+        )
+        np.testing.assert_array_equal(lse, want_lse)
+        assert np.isfinite(np.asarray(out, np.float32)).all()
+
+
+def test_forward_form_follows_the_shapes(rng):
+    """The forward's form is chosen as the backward's is, from the shapes
+    alone: one tile -> the one-tile forward beside the fused backward (a
+    lowering carries the form in the kernel's metadata, which
+    tools/tpu_aot.py counts); several tiles -> the online-softmax kernel
+    beside the two-kernel backward. Both forms keep the kernel's name."""
+    q, k, v = _qkv(rng, b=1, s=64, h=2, d=64)
+
+    def traced(block):
+        return str(jax.make_jaxpr(jax.grad(lambda q: jnp.sum(flash_attention(
+            q, k, v, block_q=block, block_k=block, interpret=False
+        ))))(q))
+
+    one_tile, tiles = traced(64), traced(32)
+    assert "flash_fwd" in one_tile and "flash_fwd" in tiles
+    assert "one_tile" in one_tile and "flash_bwd_fused" in one_tile
+    assert "one_tile" not in tiles and "flash_bwd_dq" in tiles
 
 
 def test_under_a_mesh_matches_one_device(rng):

@@ -1,7 +1,8 @@
 """The causal mode of the flash kernels against dense causal attention, on
 the CPU interpreter: forward and all three gradients, at D=128 (one head per
-column block) and D=64 (two), a sequence of one tile and of 2-4 tiles with
-unequal ``block_q`` / ``block_k``, with a KV bias on top. Small shapes: the
+column block) and D=64 (two), a sequence of one tile (the one-tile forward,
+whose ``lse`` the fused backward reads) and of 2-4 tiles with unequal
+``block_q`` / ``block_k``, with a KV bias on top. Small shapes: the
 interpreter is slow."""
 import jax
 import jax.numpy as jnp
@@ -39,6 +40,7 @@ SHAPES = [
     # (S, H, D, block_q, block_k): tiles
     (64, 2, 128, 64, 64),  # one tile: the fused backward, masked
     (64, 2, 64, 64, 64),
+    (64, 3, 64, 64, 64),  # one tile, odd head count: the whole-width block
     (128, 2, 128, 64, 32),  # 2 x 4 tiles, two key tiles to a query tile
     (128, 2, 64, 32, 64),  # 4 x 2 tiles, two query tiles to a key tile
     (96, 1, 64, 32, 32),  # 3 x 3, one head: the whole-width block

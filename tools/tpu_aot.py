@@ -11,11 +11,15 @@ speed or numerics; those need the chip (``chip_smoke.py``).
     python tools/tpu_aot.py flat_apply_step kernels
     OURO_LAYERS=4 OURO_BATCH=1 python tools/tpu_aot.py ouro_accumulate_step
 
-Each line: {"program", "compile_s", "tpu_custom_calls", "layer_body_copies",
-"memory"} — ``layer_body_copies`` is the shapes of the ``copy`` instructions
-inside the compiled program's while bodies (the scanned layer, forward and
-backward): relayouts the compiler put around an op whose layout differs from
-its neighbours', paid once per layer iteration; ``memory`` is the compiler's
+Each line: {"program", "compile_s", "tpu_custom_calls", "flash_fwd_forms",
+"layer_body_copies", "memory"} — ``flash_fwd_forms`` counts the flash
+forward calls of the lowered module by the form their shapes chose
+(``one_tile``: one tile covers the sequence; ``tiles``: the online-softmax
+kernel; a scanned layer body is one call); ``layer_body_copies`` is the
+shapes of the ``copy`` instructions inside the compiled program's while
+bodies (the scanned layer, forward and backward): relayouts the compiler put
+around an op whose layout differs from its neighbours', paid once per layer
+iteration; ``memory`` is the compiler's
 own analysis (argument / output / temp / alias bytes on the one device): what
 a large state leaves for activations is read here before a chip is asked.
 The Ouro programs (the looped decoder at its published widths) take depth,
@@ -24,6 +28,13 @@ flags; ``OURO_LAYERS`` / ``OURO_BATCH`` override the first two, to size a
 cut that cell does not run. Exit code 0
 when everything compiled, 3 when no v5e can be described here (no libtpu, or
 one without a compile-only client).
+
+A kernel's instruction counts, with no chip:
+``LIBTPU_INIT_ARGS=--xla_mosaic_dump_to=<dir> python tools/tpu_aot.py kernels``
+writes each Mosaic kernel's final code (``<dir>/*-post-finalize-llo.txt``).
+A count is not a time: the one-tile forward dropped 41 % of ``flash_fwd``'s
+instructions and 47 % of its time on the v5e, and variants that differ by
+256 vector multiplies a head ran within 1.2 % of each other (PERF.md, PR 26).
 """
 from __future__ import annotations
 
@@ -122,15 +133,20 @@ def flat_apply_step(device):
 
 def kernels(device):
     """Every Pallas kernel, fwd+bwd, in one program: flash attention at the
-    recipe shape (one tile covers S=512: the fused backward) and at S=2048
-    (several tiles: the two-kernel backward every long-sequence run takes),
-    and the fused add+LayerNorm at the recipe's 6,144 x 1,024 rows."""
+    recipe shape (one tile covers S=512: the one-tile forward and the fused
+    backward), at one causal tile of D=128 (one head per column block), and
+    at S=2048 (several tiles: the online-softmax forward and the two-kernel
+    backward every long-sequence run takes); the fused add+LayerNorm at the
+    recipe's 6,144 x 1,024 rows."""
     from dedloc_tpu.ops.flash_attention import flash_attention
     from dedloc_tpu.ops.fused_ln import ln_residual
 
-    def loss(q, k, v, q_long, x, gamma):
+    def loss(q, k, v, q_wide, q_long, x, gamma):
         return (
             jnp.sum(flash_attention(q, k, v).astype(jnp.float32))
+            + jnp.sum(flash_attention(
+                q_wide, q_wide, q_wide, causal=True
+            ).astype(jnp.float32))
             + jnp.sum(
                 flash_attention(q_long, q_long, q_long).astype(jnp.float32)
             )
@@ -138,11 +154,12 @@ def kernels(device):
         )
 
     qkv = jax.ShapeDtypeStruct((MICRO_BATCH, SEQ, 16, 64), jnp.bfloat16)
+    q_wide = jax.ShapeDtypeStruct((2, SEQ, 16, 128), jnp.bfloat16)
     q_long = jax.ShapeDtypeStruct((1, 2048, 16, 64), jnp.bfloat16)
     x = jax.ShapeDtypeStruct((MICRO_BATCH * SEQ, 1024), jnp.bfloat16)
     gamma = jax.ShapeDtypeStruct((1024,), jnp.float32)
-    return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
-        *_on_device(device, (qkv, qkv, qkv, q_long, x, gamma))
+    return jax.jit(jax.grad(loss, argnums=tuple(range(7)))).lower(
+        *_on_device(device, (qkv, qkv, qkv, q_wide, q_long, x, gamma))
     )
 
 
@@ -230,6 +247,19 @@ def layer_body_copies(hlo_text: str) -> list:
     return copies
 
 
+def flash_fwd_forms(lowered_text: str) -> dict:
+    """The flash forward calls of a lowered module, by form: the one-tile
+    call carries ``form: one_tile`` in its kernel metadata (the two forms
+    share a kernel name, which is what a device trace is read by)."""
+    calls = [
+        line for line in lowered_text.splitlines()
+        if re.search(r'kernel_name = "flash_(causal_)?fwd"', line)
+    ]
+    # the serialized kernel body on the same line is base64: no "_" in it
+    one_tile = sum("one_tile" in line for line in calls)
+    return {"one_tile": one_tile, "tiles": len(calls) - one_tile}
+
+
 NO_V5E = 3  # exit code: nothing to compile with, which is not a failure
 
 
@@ -258,11 +288,13 @@ def main(argv=None) -> int:
         compiled = lowered.compile()
         seconds = round(time.perf_counter() - start, 2)
         memory = compiled.memory_analysis()
+        lowered_text = lowered.as_text()
         print(json.dumps({
             "program": name,
             "device_kind": device.device_kind,
             "compile_s": seconds,
-            "tpu_custom_calls": lowered.as_text().count("tpu_custom_call"),
+            "tpu_custom_calls": lowered_text.count("tpu_custom_call"),
+            "flash_fwd_forms": flash_fwd_forms(lowered_text),
             "layer_body_copies": layer_body_copies(compiled.as_text()),
             "memory": {
                 "argument_bytes": memory.argument_size_in_bytes,
