@@ -317,7 +317,7 @@ def run_grad_pipeline() -> None:
 
     from dedloc_tpu.averaging.device_flat import DeviceFlatPipeline
     from dedloc_tpu.averaging.partition import TreeLayout
-    from dedloc_tpu.collaborative.optimizer import _tree_to_named
+    from dedloc_tpu.utils.checkpoint import tree_to_named
 
     tiny = os.environ.get("DEDLOC_BENCH_TINY", "") == "1"
     timing = os.environ.get("DEDLOC_BENCH_TIMING", "1") != "0"
@@ -350,7 +350,7 @@ def run_grad_pipeline() -> None:
 
     def legacy_boundary():
         mean = jax.tree.map(lambda g: g / n_micro, tree)
-        named = _tree_to_named(mean)  # per-leaf device_get
+        named = tree_to_named(mean)  # per-leaf device_get
         layout = TreeLayout.for_tree(named)
         return layout.flatten_into(named)
 

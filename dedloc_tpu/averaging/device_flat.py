@@ -65,7 +65,7 @@ DEFAULT_D2H_CHUNK = 1 << 20
 
 def named_device_leaves(tree) -> List[Tuple[str, Any]]:
     """(name, leaf) pairs with the SAME deterministic naming as the
-    optimizer's host-side ``_tree_to_named`` (jax keystr paths), so the
+    optimizer's host-side ``tree_to_named`` (jax keystr paths), so the
     device pipeline's sorted spec matches the host TreeLayout exactly."""
     import jax
 

@@ -50,32 +50,10 @@ from dedloc_tpu.parallel.train_step import (
     make_guarded_apply_step,
     zeros_like_grads,
 )
+from dedloc_tpu.utils.checkpoint import named_to_tree, tree_to_named
 from dedloc_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
-
-
-def _tree_to_named(tree) -> Dict[str, np.ndarray]:
-    """Flatten a pytree into {path: np.array} with deterministic names."""
-    flat = jax.tree_util.tree_flatten_with_path(tree)[0]
-    out = {}
-    for i, (path, leaf) in enumerate(flat):
-        name = jax.tree_util.keystr(path) or f"leaf{i}"
-        out[name] = np.asarray(leaf)
-    return out
-
-
-def _named_to_tree(named: Dict[str, np.ndarray], like):
-    """Inverse of _tree_to_named given a structural template."""
-    flat, treedef = jax.tree_util.tree_flatten_with_path(like)
-    leaves = []
-    for i, (path, leaf) in enumerate(flat):
-        name = jax.tree_util.keystr(path) or f"leaf{i}"
-        arr = named[name]
-        leaves.append(np.asarray(arr, dtype=leaf.dtype).reshape(leaf.shape))
-    return jax.tree_util.tree_unflatten(
-        jax.tree_util.tree_structure(like), leaves
-    )
 
 
 def _fused_mean_clip(grad_acc, n, cap):
@@ -201,14 +179,6 @@ class CollaborativeOptimizer:
         telemetry_registry=None,  # per-peer telemetry scope, forwarded to
         # the averager/matchmaking/RPC stack (telemetry/registry.py); None
         # falls back to the process-global registry at each site
-        device_flat: bool = True,  # device-resident flat gradient pipeline
-        # (averaging/device_flat.py): the boundary's mean/clip/error-
-        # feedback/quantize all run in one fused jit on the accelerator and
-        # the compressed representation streams to the host in async chunks
-        # — the grad_flatten phase transfers 2-4x fewer PCIe bytes under a
-        # lossy wire format and the host codec becomes decode-only. Falls
-        # back to the legacy per-leaf host path automatically when the
-        # gradient tree is refused (non-float leaves).
         flat_opt_factory: Optional[Callable] = None,  # (spec, params) ->
         # optim.flat.FlatLamb/FlatLars: enables the fused FLAT apply — the
         # averaged result device_puts as ONE buffer and the whole optimizer
@@ -335,9 +305,13 @@ class CollaborativeOptimizer:
             tx, mesh=mesh, opt_state_sharding=opt_state_sharding,
             param_sharding=param_sharding, post_apply=post_apply,
         )
-        # device-resident flat gradient pipeline (built lazily from the
-        # first boundary's gradient tree; see the constructor docstring)
-        self.device_flat = bool(device_flat)
+        # device-resident flat gradient pipeline (averaging/device_flat.py):
+        # the boundary's mean/clip/error-feedback/quantize run in one fused
+        # jit on the accelerator and the compressed representation streams
+        # to the host in async chunks. Built lazily from the first
+        # boundary's gradient tree; cleared by _ensure_pipeline when that
+        # tree is refused (non-float leaves): the legacy per-leaf host path
+        self.device_flat = True
         self.flat_opt_factory = flat_opt_factory
         self._pipeline: Optional[DeviceFlatPipeline] = None
         self._flat_apply_fn = None
@@ -795,7 +769,7 @@ class CollaborativeOptimizer:
             # per-leaf device_get + host flatten + host error feedback
             with steps.phase("grad_flatten") as flatten:
                 # device_get of the full grad tree (the jit↔host seam)
-                named = _tree_to_named(_fused_mean_clip(grad_acc, n, cap))
+                named = tree_to_named(_fused_mean_clip(grad_acc, n, cap))
             self.seam_ms["grads_device_get"] = flatten.dur_s * 1e3
             # error feedback (collaborative/error_feedback.py): fold the
             # last round's quantization residual into this round's
@@ -912,7 +886,7 @@ class CollaborativeOptimizer:
                     # a plain named dict (legacy/stubbed averager): rebuild
                     # the params-shaped tree here so _apply_and_advance can
                     # tell it apart from a device gradient tree
-                    averaged = _named_to_tree(
+                    averaged = named_to_tree(
                         averaged, zeros_like_grads(state.params)
                     )
                 return self._apply_and_advance(
@@ -1061,6 +1035,12 @@ class CollaborativeOptimizer:
             self._flat_apply_fn = None
         return self._flat_apply_fn
 
+    def _reset_error_feedback(self) -> None:
+        """Drop the carried quantization residual, host and device form."""
+        self.error_feedback.reset()
+        if self._pipeline is not None:
+            self._pipeline.reset_residual()
+
     def _check_apply_ok(self, final: bool = False) -> None:
         """Read the PREVIOUS guarded apply's NaN verdict. Called at the
         next boundary (the flag has long settled — reading it then costs
@@ -1083,6 +1063,9 @@ class CollaborativeOptimizer:
             logger.warning(
                 f"{round_id}: non-finite params; update was rolled back"
             )
+            # that round's quantization residual is non-finite as well:
+            # carried forward it would poison every later contribution
+            self._reset_error_feedback()
             tele = telemetry.resolve(self.telemetry)
             if tele is not None:
                 tele.counter("opt.nan_rollbacks").inc()
@@ -1239,7 +1222,7 @@ class CollaborativeOptimizer:
             # before adopting (a FlatTree from our own averager is already
             # layout-checked)
             try:
-                averaged = _named_to_tree(
+                averaged = named_to_tree(
                     averaged, zeros_like_grads(state.params)
                 )
             except (KeyError, ValueError) as e:
@@ -1290,7 +1273,7 @@ class CollaborativeOptimizer:
         else:
             # legacy: mean * n_micro reconstructs the committed sum
             _tag, named, n_micro = restore
-            restored = _named_to_tree(
+            restored = named_to_tree(
                 named, zeros_like_grads(state.params)
             )
             grad_acc = jax.tree.map(
@@ -1332,7 +1315,7 @@ class CollaborativeOptimizer:
                 if isinstance(mean_grads, FlatTree):
                     # flat result without a flat apply: rebuild the
                     # params-shaped tree from the named views (zero-copy)
-                    mean_grads = _named_to_tree(
+                    mean_grads = named_to_tree(
                         mean_grads, zeros_like_grads(state.params)
                     )
                 new_state, ok = self._apply_fn(state, mean_grads)
@@ -1445,7 +1428,7 @@ class CollaborativeOptimizer:
                 nbytes += leaves[i].nbytes
             host_state = jax.tree.unflatten(treedef, leaves)
             self.averager.set_shared_state(
-                _tree_to_named(host_state),
+                tree_to_named(host_state),
                 {"step": step, "local_step": local_step},
             )
             self._finished_backups.append(
@@ -1532,7 +1515,7 @@ class CollaborativeOptimizer:
             return state
         template = jax.device_get((state.params, state.opt_state))
         try:
-            params, opt_state = _named_to_tree(named, template)
+            params, opt_state = named_to_tree(named, template)
         except (KeyError, ValueError) as e:
             logger.warning(f"peer state incompatible ({e!r}); keeping local")
             return state
@@ -1554,9 +1537,7 @@ class CollaborativeOptimizer:
         # the carried quantization residual belongs to gradients computed on
         # params we are about to replace — feeding it forward would inject
         # stale signal into the first post-resync round
-        self.error_feedback.reset()
-        if self._pipeline is not None:
-            self._pipeline.reset_residual()
+        self._reset_error_feedback()
         new_state = self.load_state_from_peers(state)
         # even if nobody shares state, adopt the global step counter so we
         # rejoin the current round instead of contesting old ones
@@ -1577,7 +1558,7 @@ class CollaborativeOptimizer:
         if schema is None:
             return None
         # shared state is the flattened (params, opt_state) tuple, so param
-        # leaves carry the "[0]" tuple-index prefix (_tree_to_named keystr
+        # leaves carry the "[0]" tuple-index prefix (tree_to_named keystr
         # naming); gradients are params-shaped => strip that prefix. A wrong
         # template still fails cleanly at join time (schema handshake).
         template = {

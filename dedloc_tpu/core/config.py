@@ -279,21 +279,6 @@ class CollaborativeOptimizerArguments:
     # (docs/observability.md "signed contribution ledger")
     ledger_claims: bool = True
     claim_period: float = 30.0  # dht-time seconds between claim refreshes
-    # device-resident flat gradient pipeline (averaging/device_flat.py):
-    # the boundary's mean/clip/error-feedback/quantize run in ONE fused jit
-    # on the accelerator, and the (compressed, under fp16/uint8 wire
-    # formats) flat buffer streams to the host in async chunks overlapped
-    # with matchmaking / accumulation. Off restores the legacy per-leaf
-    # device_get + host flatten + host codec path.
-    device_flat: bool = True
-    # fused flat optimizer apply (optim/flat.py + make_flat_apply_step):
-    # the averaged result crosses host->device as ONE buffer and the whole
-    # LAMB update runs as segment reductions over it, with the NaN guard
-    # fused in. Per-leaf guarded apply otherwise. Fleet-wide choice, like
-    # --averager.compression: peers should agree so replica params evolve
-    # identically (the flat math agrees with the per-leaf chain to float32
-    # reduction-order, ~1e-7 relative — see docs/perf.md round 6).
-    flat_apply: bool = True
 
 
 @dataclass
@@ -519,8 +504,6 @@ class SwAVTrainingArguments:
     output_dir: str = "outputs_swav"
     save_steps: int = 0
     save_total_limit: int = 2
-    log_every: int = 10
-    device_stats_every: int = 100  # HBM stats cadence (0 = off)
     train_log_path: str = ""  # per-global-step JSONL, as the ALBERT trainer's
 
 

@@ -14,10 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dedloc_tpu.collaborative.optimizer import (
-    CollaborativeOptimizer,
-    _tree_to_named,
-)
+from dedloc_tpu.collaborative.optimizer import CollaborativeOptimizer
 from dedloc_tpu.core.config import CollaborationArguments, parse_config
 from dedloc_tpu.roles.common import (
     build_authorizer,
@@ -27,6 +24,7 @@ from dedloc_tpu.roles.common import (
     single_device_attention_impl,
 )
 from dedloc_tpu.utils.backend import ensure_compile_cache, pin_cpu
+from dedloc_tpu.utils.checkpoint import tree_to_named
 from dedloc_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -50,7 +48,7 @@ def _local_template(args: CollaborationArguments):
     )
     return {
         k: np.zeros(v.shape, np.float32)
-        for k, v in _tree_to_named(params).items()
+        for k, v in tree_to_named(params).items()
     }
 
 

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from dedloc_tpu.collaborative.metrics import make_validators
+from dedloc_tpu.collaborative.optimizer import CollaborativeOptimizer
 from dedloc_tpu.core.config import CollaborationArguments
 from dedloc_tpu.data.mlm import SpecialTokens, mask_tokens, max_predictions_for
 from dedloc_tpu.dht.dht import DHT
@@ -245,8 +246,8 @@ def build_optimizer(args: CollaborationArguments):
 def build_flat_opt_factory(args: CollaborationArguments):
     """(spec, params) -> optim.flat.FlatLamb for the SAME hyperparameters
     as ``build_optimizer`` — the fused flat apply's math twin of the
-    per-leaf chain (--optimizer.flat_apply; equivalence locked by
-    tests/test_optim.py). Returns a factory because the TreeLayout spec
+    per-leaf chain (equivalence locked by tests/test_optim.py). Returns a
+    factory because the TreeLayout spec
     only exists once the first gradient tree does."""
     schedule = linear_warmup_linear_decay(
         args.training.learning_rate,
@@ -363,6 +364,81 @@ def checkpoint_kwargs(args, public_key: bytes) -> Dict:
     )
 
 
+def build_collaborative_optimizer(
+    args, tx, dht, public_key: bytes, *, batch_size_per_step: int,
+    flat_opt_factory: Callable, mesh=None, opt_state_sharding=None,
+    param_sharding=None, post_apply=None, authorizer=None,
+    authority_public_key=None,
+) -> CollaborativeOptimizer:
+    """THE wiring of ``--dht.*`` / ``--averager.*`` / ``--optimizer.*`` /
+    ``--checkpoint.*`` into a training peer's ``CollaborativeOptimizer``,
+    for either trainer role's argument tree. A role passes only what is its
+    own: its batch, its mesh and shardings, its flat twin of ``tx``
+    (``flat_opt_factory``: the fused flat apply; the optimizer itself falls
+    back to the per-leaf apply on a mesh or a failed build), its
+    ``post_apply`` and, where its tree has an ``auth`` group, the
+    authorizer."""
+    return CollaborativeOptimizer(
+        tx,
+        dht,
+        prefix=args.dht.experiment_prefix,
+        target_batch_size=args.optimizer.target_batch_size,
+        batch_size_per_step=batch_size_per_step,
+        batch_size_lead=args.optimizer.batch_size_lead,
+        bandwidth=args.averager.bandwidth,
+        compression=args.averager.compression,
+        chunk_size=args.averager.chunk_size,
+        # hierarchical two-level averaging (--averager.topology_plan):
+        # clique-first reduction per the operator-installed plan
+        topology_plan=args.averager.topology_plan or None,
+        # live re-planning: follow the coordinator's plan record UNLESS
+        # the operator pinned a manual plan (pin = opt-out, docs/fleet.md)
+        plan_follow=(
+            args.averager.plan_follow and not args.averager.topology_plan
+        ),
+        plan_refresh_period=args.averager.plan_refresh_period,
+        error_feedback=args.optimizer.error_feedback,
+        overlap_averaging=args.optimizer.overlap_averaging,
+        # signed contribution ledger (--optimizer.ledger_claims /
+        # --averager.ledger_receipts; docs/observability.md)
+        ledger_claims=args.optimizer.ledger_claims,
+        claim_period=args.optimizer.claim_period,
+        ledger_receipts=args.averager.ledger_receipts,
+        target_group_size=args.averager.target_group_size,
+        averaging_expiration=args.averager.averaging_expiration,
+        averaging_timeout=args.averager.averaging_timeout,
+        metadata_expiration=args.averager.metadata_expiration,
+        statistics_expiration=args.optimizer.statistics_expiration,
+        contrib_clip_per_sample=args.optimizer.contrib_clip_per_sample,
+        ramp_rounds=args.optimizer.ramp_rounds,
+        health_gate_loss_ratio=args.optimizer.health_gate_loss_ratio,
+        state_sync_retries=args.averager.state_sync_retries,
+        state_sync_backoff=args.averager.state_sync_backoff,
+        flat_opt_factory=flat_opt_factory,
+        # swarm checkpointing (--checkpoint.*): sharded state serving +
+        # catalog announcements + multi-peer restore, blob as fallback
+        **checkpoint_kwargs(args, public_key),
+        min_refresh_period=args.averager.min_refresh_period,
+        max_refresh_period=args.averager.max_refresh_period,
+        default_refresh_period=args.averager.default_refresh_period,
+        expected_drift_peers=args.averager.expected_drift_peers,
+        expected_drift_rate=args.averager.expected_drift_rate,
+        performance_ema_alpha=args.averager.performance_ema_alpha,
+        client_mode=args.dht.client_mode,
+        relay=args.dht.relay or None,
+        listen_port=args.averager.listen_port,
+        advertised_host=args.dht.advertised_host or None,
+        allow_state_sharing=args.optimizer.allow_state_sharing,
+        mesh=mesh,
+        opt_state_sharding=opt_state_sharding,
+        param_sharding=param_sharding,
+        post_apply=post_apply,
+        authorizer=authorizer,
+        authority_public_key=authority_public_key,
+        verbose=True,
+    )
+
+
 def configure_role_telemetry(args, public_key: bytes):
     """Install the process-global swarm-telemetry registry for a role
     (docs/observability.md, ``--telemetry.*`` knobs). THE one place the
@@ -384,7 +460,6 @@ def configure_role_telemetry(args, public_key: bytes):
             telemetry.uninstall(tele)
 
     return tele, close
-
 
 
 class TrainLog:

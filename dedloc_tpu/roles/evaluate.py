@@ -124,11 +124,11 @@ def _restore(tree, params_template):
     trainer's _save; accept either that pair layout or bare params."""
     import numpy as np
 
-    from dedloc_tpu.collaborative.optimizer import _named_to_tree
+    from dedloc_tpu.utils.checkpoint import named_to_tree
 
     host_template = jax.device_get(params_template)
     try:
-        params, _opt = _named_to_tree(tree, (host_template, None))
+        params, _opt = named_to_tree(tree, (host_template, None))
         return jax.device_put(params)
     except (KeyError, TypeError, ValueError):
         pass
@@ -138,9 +138,9 @@ def _restore(tree, params_template):
         k[3:]: v for k, v in tree.items() if k.startswith("[0]")
     }
     if stripped:
-        params = _named_to_tree(stripped, host_template)
+        params = named_to_tree(stripped, host_template)
         return jax.device_put(params)
-    params = _named_to_tree(tree, host_template)
+    params = named_to_tree(tree, host_template)
     return jax.device_put(params)
 
 

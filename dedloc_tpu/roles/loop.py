@@ -1,0 +1,214 @@
+"""The boundary loop: the one place in the tree that iterates accumulation
+boundaries, for every model.
+
+Capability parity with albert/run_trainer.py:130-170 (CollaborativeCallback)
+and the vissl phase loop with its log / perf / checkpoint / publish hooks
+(swav/vissl/vissl/trainer/trainer_main.py:138-204): jitted accumulate per
+micro-batch; at every accumulation boundary hand control to the
+collaborative optimizer (global-step averaging, NaN rollback); on a global
+step read the loss once, publish signed metrics, log, save.
+
+A role (``roles/trainer.py``, ``roles/swav.py``) is a BUILDER: it parses,
+builds model, optimizer, DHT and state, and hands this loop a ``LoopModel``
+— data and closures, not hooks.
+"""
+from __future__ import annotations
+
+import dataclasses
+from typing import Callable, Iterator, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from dedloc_tpu.parallel.train_step import TrainState, zeros_like_grads
+from dedloc_tpu.roles.common import open_train_log, publish_step_metrics
+from dedloc_tpu.telemetry import steps
+from dedloc_tpu.telemetry.profile import profile_gate
+from dedloc_tpu.telemetry.steps import StepRecorder, chip_peak_tflops
+from dedloc_tpu.utils.logging import get_logger
+from dedloc_tpu.utils.perf import PerfStats
+
+logger = get_logger(__name__)
+
+
+@dataclasses.dataclass
+class LoopModel:
+    """What a model brings to the boundary loop."""
+
+    # host micro-batches; a source that ends (StopIteration) ends the run
+    # gracefully, any other exception propagates
+    batches: Iterator
+    # (state, grad_acc, n_acc, batch) -> (grad_acc, n_acc, metrics): enqueue
+    # one micro-batch; closes over whatever else the model carries (an rng
+    # stream; SwAV's batch_stats and queue)
+    micro_step: Callable
+    # (state, step) -> None: write the model's checkpoint payload
+    save: Callable
+    # host batch -> device batch, timed as ``h2d`` (None: the micro-step
+    # takes the drawn batch as it is)
+    put: Optional[Callable] = None
+    # metrics beside the loss that are vectors over the passes: summed on
+    # the device with the loss and read with it, once per global step
+    step_gauges: Tuple[str, ...] = ()
+    # analytic model TFLOPs of one fwd+bwd sample, for the MFU gauge
+    # (0: no gauge)
+    tflops_per_sample: float = 0.0
+
+
+def run_boundary_loop(
+    args, model: LoopModel, state: TrainState, opt, dht, public_key: bytes,
+    tele, tele_close: Callable[[], None], log_perf_steps: int = 0,
+) -> TrainState:
+    """Train until ``--training.max_local_steps`` boundaries or the end of
+    the data, then close recorder, train log, telemetry, optimizer and DHT
+    (also on an exception). ``args`` is either role's argument tree;
+    ``log_perf_steps`` the global steps between ``perf phases`` reports."""
+    t = args.training
+    grad_acc = zeros_like_grads(state.params)
+    n_acc = jnp.zeros([], jnp.int32)
+    # the running loss stays ON DEVICE (a lazy sum) — a float() in the loop
+    # would synchronize the host with the accumulate kernels and serialize
+    # the input pipeline against XLA dispatch; the host reads it once per
+    # GLOBAL step, right where the value is published — and with it, in the
+    # same read, the model's per-pass gauges (a looped model's exit
+    # distribution and per-pass loss)
+    summed = ("loss",) + model.step_gauges
+    sums_dev: dict = {}
+    mini_steps = 0
+    boundary = 0
+    last_saved_step = opt.local_step
+    # the flight recorder (telemetry/steps.py) is the loop's one timer: every
+    # boundary is a record of nested host spans, always timed, published
+    # through the telemetry registry only when that is enabled. It feeds
+    # PerfStats (the operator's --training.log_perf_steps report; vissl
+    # PerfStats capability, vissl/utils/perf_stats.py:12-249) and, with
+    # --telemetry.profile_*, opens the profiler window. Per-micro-batch
+    # device time is NOT blocked on (that would serialize the async dispatch
+    # chain): it is the ``drain`` span inside opt.step, and the device
+    # planes of a profile.
+    perf = PerfStats()
+    recorder = StepRecorder(
+        telemetry=tele,
+        model_tflops_per_sample=model.tflops_per_sample,
+        peak_tflops=chip_peak_tflops(),
+        perf=perf,
+        profile=profile_gate(args.telemetry),
+    )
+    train_log = open_train_log(t.train_log_path)
+    samples = opt.batch_size_per_step
+    exhausted = False
+    try:
+        while True:
+            # one accumulation boundary = gradient_accumulation_steps
+            # micro-batches = ONE step record, which runs to the start of
+            # the next boundary: data_wait/h2d/fwd_bwd here, the optimizer's
+            # spans inside opt.step (which also stamps stepped, samples and
+            # the running totals on the record), the tail of a global step
+            # as post_step
+            with recorder.step(step=opt.local_step) as srec:
+                for _ in range(t.gradient_accumulation_steps):
+                    with steps.phase("data_wait"):
+                        batch = next(model.batches, None)
+                    if batch is None:
+                        exhausted = True
+                        break
+                    if model.put is not None:
+                        with steps.phase("h2d"):
+                            batch = model.put(batch)
+                    with steps.phase("fwd_bwd"):
+                        # everything the host enqueues for one micro-batch
+                        grad_acc, n_acc, metrics = model.micro_step(
+                            state, grad_acc, n_acc, batch
+                        )
+                        sums_dev = {
+                            k: sums_dev[k] + metrics[k] if k in sums_dev
+                            else metrics[k] for k in summed
+                        }
+                    mini_steps += 1
+                if exhausted:
+                    logger.info("the batch source ended; stopping")
+                    break
+                state, grad_acc, n_acc, stepped = opt.step(
+                    state, grad_acc, n_acc, samples
+                )
+                if stepped:
+                    with steps.phase("post_step"):
+                        with steps.phase("loss_sync"):
+                            # the one sync per global step
+                            sums = jax.device_get(sums_dev)
+                        sums_dev = {}
+                        loss_sum = float(sums["loss"])
+                        loss = loss_sum / max(mini_steps, 1)
+                        for name in model.step_gauges:
+                            # means over the global step's tokens, onto the
+                            # step record and (telemetry on) into gauges
+                            for p, value in enumerate(
+                                sums[name] / max(mini_steps, 1), start=1
+                            ):
+                                srec.attrs[f"{name}.{p}"] = float(value)
+                                if tele is not None:
+                                    tele.gauge(f"{name}.{p}").set(float(value))  # dedlint: emits=gauge:lm.exit_prob.*,gauge:lm.loss.*
+                        # advertise the loss for the trunk-health gate —
+                        # free here, the scalar is already on the host
+                        opt.report_loss(loss)
+                        sps = float(opt.performance_ema.samples_per_second)
+                        # THIS boundary's values, off its record
+                        row = steps.train_log_row(srec)
+                        with steps.phase("publish"):
+                            publish_step_metrics(
+                                dht, args, public_key, opt, tele, row,
+                                samples=samples, loss=loss_sum,
+                                mini_steps=mini_steps, sps=sps,
+                                hbm_bytes=_hbm_bytes_in_use(),
+                            )
+                        mini_steps = 0
+                        with steps.phase("log"):
+                            logger.info(
+                                f"global step {opt.local_step}: loss "
+                                f"{loss:.4f}"
+                            )
+                            if train_log is not None:
+                                train_log.write(opt, row, loss, sps)
+                            if (
+                                log_perf_steps
+                                and opt.local_step % log_perf_steps == 0
+                            ):
+                                logger.info(
+                                    "perf phases:\n" + perf.report_str()
+                                )
+                            if (
+                                t.save_steps
+                                and opt.local_step - last_saved_step
+                                >= t.save_steps
+                            ):
+                                # cadence by DISTANCE, not divisibility: a
+                                # collaborative local_step can jump over
+                                # exact multiples (catch-ups adopt the
+                                # global counter), and a modulo check then
+                                # never fires again for the rest of the run
+                                model.save(state, opt.local_step)
+                                last_saved_step = opt.local_step
+
+            boundary += 1
+            if t.max_local_steps and boundary >= t.max_local_steps:
+                logger.info(f"reached max_local_steps={boundary}; stopping")
+                break
+    finally:
+        recorder.close()
+        if train_log is not None:
+            train_log.close()
+        tele_close()
+        opt.shutdown()
+        dht.shutdown()
+    return state
+
+
+def _hbm_bytes_in_use() -> Optional[int]:
+    """Device bytes_in_use via PJRT memory_stats (None off-TPU/unsupported)."""
+    try:
+        stats = jax.local_devices()[0].memory_stats()
+        if stats:
+            return int(stats.get("bytes_in_use", 0)) or None
+    except Exception:  # noqa: BLE001 — telemetry must never kill training
+        pass
+    return None

@@ -4,8 +4,8 @@ Capability parity with the reference's swav workload driver (reference:
 swav/vissl/vissl/trainer/trainer_main.py:138-204 phase loop +
 swav/ClassyVision/classy_vision/optim/sgd_collaborative.py:132-171): build
 ResNet-50 trunk + prototypes head, LARC-SGD with warmup-cosine schedule,
-DHT + CollaborativeOptimizer (target_batch_size 32768), multicrop pipeline,
-and run the phase-loop Trainer with the default hook pipeline.
+DHT + CollaborativeOptimizer (target_batch_size 32768), multicrop pipeline —
+a BUILDER: what iterates boundaries is ``roles/loop.py``, as for every model.
 
 TPU-native shape (SURVEY.md §3.4): the reference's two communication worlds —
 NCCL all_reduce inside the sinkhorn loop and hivemind averaging per optimizer
@@ -17,16 +17,11 @@ loss (standard_train_step.py:153).
 """
 from __future__ import annotations
 
-from typing import Iterator, List
-
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
-from dedloc_tpu.collaborative.optimizer import CollaborativeOptimizer
 from dedloc_tpu.core.config import SwAVCollaborationArguments, parse_config
-from dedloc_tpu.core.hooks import default_hooks
-from dedloc_tpu.core.trainer import Trainer
 from dedloc_tpu.data.multicrop import (
     MultiCropSpec,
     image_folder_multicrop_batches,
@@ -41,18 +36,20 @@ from dedloc_tpu.models.swav import (
 )
 from dedloc_tpu.optim.lars import lars
 from dedloc_tpu.optim.schedules import linear_warmup_cosine_annealing
-from dedloc_tpu.parallel.train_step import TrainState, zeros_like_grads
-from dedloc_tpu.telemetry import steps
-from dedloc_tpu.telemetry.profile import profile_gate
-from dedloc_tpu.telemetry.steps import StepRecorder
+from dedloc_tpu.parallel.train_step import TrainState
 from dedloc_tpu.roles.common import (
+    build_collaborative_optimizer,
     build_dht,
-    checkpoint_kwargs,
-    open_train_log,
-    publish_step_metrics,
+    configure_role_telemetry,
 )
+from dedloc_tpu.roles.loop import LoopModel, run_boundary_loop
 from dedloc_tpu.utils.backend import ensure_compile_cache
-from dedloc_tpu.utils.checkpoint import save_checkpoint
+from dedloc_tpu.utils.checkpoint import (
+    load_latest_checkpoint,
+    named_to_tree,
+    save_checkpoint,
+    tree_to_named,
+)
 from dedloc_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -86,7 +83,7 @@ def build_swav(args: SwAVCollaborationArguments):
 
 def _build_flat_lars_factory(t):
     """(spec, params) -> optim.flat.FlatLars mirroring ``build_swav``'s
-    LARS hyperparameters (fused flat apply; --optimizer.flat_apply)."""
+    LARS hyperparameters (the fused flat apply)."""
     schedule = linear_warmup_cosine_annealing(
         t.learning_rate, t.warmup_steps, t.total_steps
     )
@@ -109,13 +106,11 @@ def run_swav(args: SwAVCollaborationArguments) -> TrainState:
     ensure_compile_cache()
     t = args.training
     cfg, spec, model, tx = build_swav(args)
-    dht, _public_key = build_dht(args)
+    dht, public_key = build_dht(args)
     logger.info(f"swav peer DHT listening on {dht.port}")
     # swarm telemetry (--telemetry.*, docs/observability.md): same wiring as
     # the ALBERT trainer; disabled (default) costs nothing
-    from dedloc_tpu.roles.common import configure_role_telemetry
-
-    tele, tele_close = configure_role_telemetry(args, _public_key)
+    tele, tele_close = configure_role_telemetry(args, public_key)
 
     # slice-as-one-peer (same mapping as the ALBERT trainer): crops shard
     # over the data axis, so the sinkhorn sums inside the jitted loss ride
@@ -157,62 +152,24 @@ def run_swav(args: SwAVCollaborationArguments) -> TrainState:
         else None
     )
 
-    opt = CollaborativeOptimizer(
-        tx,
-        dht,
-        prefix=args.dht.experiment_prefix,
-        target_batch_size=args.optimizer.target_batch_size,
-        batch_size_lead=args.optimizer.batch_size_lead,
-        batch_size_per_step=(
-            slice_batch * t.gradient_accumulation_steps
-        ),
-        bandwidth=args.averager.bandwidth,
-        compression=args.averager.compression,
-        chunk_size=args.averager.chunk_size,
-        error_feedback=args.optimizer.error_feedback,
-        overlap_averaging=args.optimizer.overlap_averaging,
-        target_group_size=args.averager.target_group_size,
-        averaging_expiration=args.averager.averaging_expiration,
-        averaging_timeout=args.averager.averaging_timeout,
-        metadata_expiration=args.averager.metadata_expiration,
-        statistics_expiration=args.optimizer.statistics_expiration,
-        contrib_clip_per_sample=args.optimizer.contrib_clip_per_sample,
-        ramp_rounds=args.optimizer.ramp_rounds,
-        health_gate_loss_ratio=args.optimizer.health_gate_loss_ratio,
-        state_sync_retries=args.averager.state_sync_retries,
-        state_sync_backoff=args.averager.state_sync_backoff,
-        # device-resident gradient pipeline + fused flat LARS apply (same
-        # knobs as the ALBERT trainer; docs/perf.md round 6)
-        device_flat=args.optimizer.device_flat,
-        flat_opt_factory=(
-            _build_flat_lars_factory(t)
-            if args.optimizer.flat_apply else None
-        ),
-        # swarm checkpointing (--checkpoint.*): same wiring as the ALBERT
-        # trainer — sharded serving/catalog/restore with blob fallback
-        **checkpoint_kwargs(args, _public_key),
-        client_mode=args.dht.client_mode,
-        relay=args.dht.relay or None,
-        listen_port=args.averager.listen_port,
-        advertised_host=args.dht.advertised_host or None,
+    opt = build_collaborative_optimizer(
+        args, tx, dht, public_key,
+        batch_size_per_step=slice_batch * t.gradient_accumulation_steps,
+        flat_opt_factory=_build_flat_lars_factory(t),
         mesh=mesh,
         post_apply=make_prototype_post_apply(),
-        verbose=True,
     )
     # disk resume (same contract as the ALBERT trainer): newest checkpoint
     # restores params + batch_stats and seeds the collaborative counter; a
     # LIVE collaboration below still wins. LARC momentum is not part of the
     # swav checkpoint (the reference's vissl phase resume also rebuilds the
     # optimizer) — it re-warms within a few steps.
-    from dedloc_tpu.collaborative.optimizer import _named_to_tree
-    from dedloc_tpu.utils.checkpoint import load_latest_checkpoint
-
     resumed = load_latest_checkpoint(t.output_dir)
     if resumed is not None:
         ckpt_step, tree, meta = resumed
         template = jax.device_get((state.params, batch_stats))
         try:
-            params_t, bs_t = _named_to_tree(tree, template)
+            params_t, bs_t = named_to_tree(tree, template)
             state = state.replace(
                 step=jnp.asarray(ckpt_step, jnp.int32),
                 params=jax.device_put(params_t),
@@ -238,8 +195,6 @@ def run_swav(args: SwAVCollaborationArguments) -> TrainState:
     accumulate = make_swav_accumulate_step(
         model, cfg, mesh=mesh, num_crop_groups=len(spec.sizes)
     )
-    grad_acc = zeros_like_grads(state.params)
-    n_acc = jnp.zeros([], jnp.int32)
     if t.image_folder:
         # real JPEGs through the full SSL augmentation stack
         # (ImgPilToMultiCrop + flip + color distortion + blur + normalize)
@@ -248,144 +203,72 @@ def run_swav(args: SwAVCollaborationArguments) -> TrainState:
         )
     else:
         batches = synthetic_multicrop_batches(spec, slice_batch, seed=t.seed)
-    samples = slice_batch * t.gradient_accumulation_steps
 
-    # mutable local (non-collaborative) state, closed over by the step fn
-    local = {"batch_stats": batch_stats, "queue": queue,
-             "grad_acc": grad_acc, "n_acc": n_acc}
+    queue_engaged = False
+    crop_sharding = (
+        None if mesh is None else NamedSharding(mesh, PartitionSpec("data"))
+    )
 
-    def step_fn(state, micro_batches: List[List[np.ndarray]]):
-        # one trainer step = one accumulation boundary
-        loss = jnp.zeros([])
-        for crops in micro_batches:
-            use_queue = bool(
-                cfg.queue_length and opt.local_step >= cfg.queue_start_step
-            )
-            if use_queue and not local.get("queue_engaged"):
-                local["queue_engaged"] = True
-                logger.info(
-                    f"queue engaged at global step {opt.local_step} "
-                    f"(queue_start_step={cfg.queue_start_step}, "
-                    f"length={cfg.queue_length})"
-                )
-                if cfg.queue_start_step < 2 * t.warmup_steps:
-                    # measured negative (BASELINE.md round 5): engaging the
-                    # queue on a near-random trunk fills it with embeddings
-                    # that mislead sinkhorn and collapse the representation
-                    # (linear probe BELOW the random-trunk control); the
-                    # reference engages its queue deep into training
-                    # (swav/README.md:28, queue.start_iter ~98-100k)
-                    logger.warning(
-                        "queue engaged before the trunk is trained "
-                        f"(start {cfg.queue_start_step} < 2x warmup "
-                        f"{t.warmup_steps}); stale near-random embeddings "
-                        "can collapse the representation — prefer a later "
-                        "--training.queue_start_step"
-                    )
-            with steps.phase("h2d"):
-                device_crops = _put_crops(crops)
-            local["grad_acc"], local["n_acc"], local["batch_stats"], \
-                local["queue"], metrics = accumulate(
-                    state.params,
-                    local["batch_stats"],
-                    local["queue"],
-                    local["grad_acc"],
-                    local["n_acc"],
-                    device_crops,
-                    jnp.asarray(opt.local_step, jnp.int32),
-                    use_queue,
-                )
-            loss = metrics["loss"]
-        state, local["grad_acc"], local["n_acc"], _stepped = opt.step(
-            state, local["grad_acc"], local["n_acc"], samples
+    def micro_step(state, grad_acc, n_acc, crops):
+        # batch_stats and the queue are local (non-collaborative) state,
+        # carried from micro-batch to micro-batch here
+        nonlocal batch_stats, queue, queue_engaged
+        use_queue = bool(
+            cfg.queue_length and opt.local_step >= cfg.queue_start_step
         )
-        if _stepped:
-            with steps.phase("post_step"):
-                _after_global_step(loss)
-        return state, {"loss": loss, "global_step": opt.local_step}
-
-    def _after_global_step(loss) -> None:
-        """The tail of a global step, as the ALBERT trainer's: one host
-        read of the loss, the signed metrics bus, the train log — spans
-        ``loss_sync`` / ``publish`` / ``log`` under ``post_step``."""
-        with steps.phase("loss_sync"):
-            # advertise the loss for the trunk-health gate — one host sync
-            # per GLOBAL step, the same cadence the ALBERT trainer pays
-            loss_host = float(loss)
-        opt.report_loss(loss_host)
-        sps = float(opt.performance_ema.samples_per_second)
-        row = steps.train_log_row(steps.current())
-        with steps.phase("publish"):
-            # ride the signed metrics bus like the ALBERT trainer: the
-            # coordinator's throughput/loss aggregate and swarm-health view
-            # work for SwAV fleets too
-            publish_step_metrics(
-                dht, args, _public_key, opt, tele, row,
-                samples=samples, loss=loss_host, mini_steps=1, sps=sps,
+        if use_queue and not queue_engaged:
+            queue_engaged = True
+            logger.info(
+                f"queue engaged at global step {opt.local_step} "
+                f"(queue_start_step={cfg.queue_start_step}, "
+                f"length={cfg.queue_length})"
             )
-        if train_log is not None:
-            with steps.phase("log"):
-                train_log.write(opt, row, loss_host, sps)
+            if cfg.queue_start_step < 2 * t.warmup_steps:
+                # measured negative (BASELINE.md round 5): engaging the
+                # queue on a near-random trunk fills it with embeddings
+                # that mislead sinkhorn and collapse the representation
+                # (linear probe BELOW the random-trunk control); the
+                # reference engages its queue deep into training
+                # (swav/README.md:28, queue.start_iter ~98-100k)
+                logger.warning(
+                    "queue engaged before the trunk is trained "
+                    f"(start {cfg.queue_start_step} < 2x warmup "
+                    f"{t.warmup_steps}); stale near-random embeddings "
+                    "can collapse the representation — prefer a later "
+                    "--training.queue_start_step"
+                )
+        grad_acc, n_acc, batch_stats, queue, metrics = accumulate(
+            state.params, batch_stats, queue, grad_acc, n_acc, crops,
+            jnp.asarray(opt.local_step, jnp.int32), use_queue,
+        )
+        return grad_acc, n_acc, metrics
 
-    def _put_crops(crops):
-        if mesh is None:
+    def put_crops(crops):
+        if crop_sharding is None:
             return [jnp.asarray(c) for c in crops]
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        return [jax.device_put(jnp.asarray(c), crop_sharding) for c in crops]
 
-        data = NamedSharding(mesh, P("data"))
-        return [jax.device_put(jnp.asarray(c), data) for c in crops]
-
-    def grouped(it: Iterator, k: int) -> Iterator[list]:
-        while True:
-            group = []
-            for _ in range(k):
-                try:
-                    group.append(next(it))
-                except StopIteration:
-                    # PEP 479: returning (not leaking StopIteration) ends the
-                    # generator so Trainer stops gracefully on finite data
-                    return
-            yield group
-
-    def save_fn(ctx):
-        host = jax.device_get(
-            (ctx.train_state.params, local["batch_stats"])
-        )
-        from dedloc_tpu.collaborative.optimizer import _tree_to_named
-
+    def save(state, step):
+        host = jax.device_get((state.params, batch_stats))
         save_checkpoint(
             t.output_dir,
-            opt.local_step,
-            _tree_to_named(host),
-            metadata={"local_step": opt.local_step},
+            step,
+            tree_to_named(host),
+            metadata={"local_step": step},
             save_total_limit=t.save_total_limit,
         )
 
-    trainer = Trainer(
-        step_fn,
-        hooks=default_hooks(
-            log_every=t.log_every,
-            save_fn=save_fn if t.save_steps else None,
-            save_every=t.save_steps,
-            device_stats_every=t.device_stats_every,
+    state = run_boundary_loop(
+        args,
+        LoopModel(
+            batches=batches, micro_step=micro_step, save=save, put=put_crops
         ),
-        recorder=StepRecorder(
-            telemetry=tele, profile=profile_gate(args.telemetry)
-        ),
+        state, opt, dht, public_key, tele, tele_close,
     )
-    train_log = open_train_log(t.train_log_path)
-    try:
-        state, _ctx = trainer.train(
-            state,
-            grouped(batches, t.gradient_accumulation_steps),
-            max_steps=t.max_local_steps or 10**9,
-        )
-    finally:
-        if train_log is not None:
-            train_log.close()
-        tele_close()
-        opt.shutdown()
-        dht.shutdown()
+    if t.save_steps:
+        # vissl saves at every phase end (log_hooks.py:268-330): the run
+        # that ends leaves its last state on disk, whatever the cadence
+        save(state, opt.local_step)
     return state
 
 

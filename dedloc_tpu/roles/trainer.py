@@ -1,11 +1,11 @@
-"""Trainer peer: the canonical collaborative training loop.
+"""Trainer peer: the builder of the canonical collaborative training run.
 
 Capability parity with albert/run_trainer.py:210-297 — build model + LAMB +
 DHT + CollaborativeOptimizer, resume from the latest local checkpoint, pull
 newer state from peers at start (on_train_begin semantics :124-128), then
-loop: jitted accumulate per micro-batch; at every accumulation boundary hand
-control to the collaborative optimizer (global-step averaging, NaN rollback)
-and publish signed metrics (:130-170).
+hand the boundary loop (``roles/loop.py``) the model's micro-step: jitted
+accumulate per micro-batch; at every accumulation boundary the
+collaborative optimizer takes control (:130-170).
 
 TPU-native shape: the hot path is ONE jitted accumulate step with a donated
 device-resident grad accumulator; the jit↔Python seam is crossed once per
@@ -19,35 +19,30 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from dedloc_tpu.collaborative.optimizer import CollaborativeOptimizer
-from dedloc_tpu.telemetry import steps
-from dedloc_tpu.telemetry.profile import profile_gate
-from dedloc_tpu.telemetry.steps import StepRecorder, chip_peak_tflops
 from dedloc_tpu.core.config import CollaborationArguments, parse_config
 from dedloc_tpu.data.streaming import peer_shuffle_seed
-from dedloc_tpu.parallel.train_step import (
-    TrainState,
-    make_accumulate_step,
-    zeros_like_grads,
-)
+from dedloc_tpu.parallel.train_step import TrainState, make_accumulate_step
 from dedloc_tpu.roles.common import (
     ALBERT,
+    build_collaborative_optimizer,
     build_dht,
     build_flat_opt_factory,
     build_loss_fn,
     build_model,
     build_optimizer,
-    checkpoint_kwargs,
     configure_role_telemetry,
     drop_collator_keys,
     model_family,
-    open_train_log,
-    publish_step_metrics,
 )
+from dedloc_tpu.roles.loop import LoopModel, run_boundary_loop
 from dedloc_tpu.utils.backend import describe_backend, ensure_compile_cache
-from dedloc_tpu.utils.checkpoint import load_latest_checkpoint, save_checkpoint
+from dedloc_tpu.utils.checkpoint import (
+    load_latest_checkpoint,
+    named_to_tree,
+    save_checkpoint,
+    tree_to_named,
+)
 from dedloc_tpu.utils.logging import get_logger
-from dedloc_tpu.utils.perf import PerfStats
 
 logger = get_logger(__name__)
 
@@ -194,7 +189,7 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
     if resumed is not None:
         step, tree, meta = resumed
         template = jax.device_get((state.params, state.opt_state))
-        params_t, opt_t = _named_to_tree_pair(tree, template)
+        params_t, opt_t = named_to_tree(tree, template)
         state = state.replace(
             step=jnp.asarray(step, jnp.int32),
             params=jax.device_put(params_t),
@@ -256,72 +251,17 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
             tp_rules=shard_rules,
         )
 
-    opt = CollaborativeOptimizer(
-        tx,
-        dht,
-        prefix=args.dht.experiment_prefix,
-        target_batch_size=args.optimizer.target_batch_size,
+    opt = build_collaborative_optimizer(
+        args, tx, dht, public_key,
         batch_size_per_step=(
             slice_batch * args.training.gradient_accumulation_steps
         ),
-        batch_size_lead=args.optimizer.batch_size_lead,
-        bandwidth=args.averager.bandwidth,
-        compression=args.averager.compression,
-        chunk_size=args.averager.chunk_size,
-        # hierarchical two-level averaging (--averager.topology_plan):
-        # clique-first reduction per the operator-installed plan
-        topology_plan=args.averager.topology_plan or None,
-        # live re-planning: follow the coordinator's plan record UNLESS
-        # the operator pinned a manual plan (pin = opt-out, docs/fleet.md)
-        plan_follow=(
-            args.averager.plan_follow and not args.averager.topology_plan
-        ),
-        plan_refresh_period=args.averager.plan_refresh_period,
-        error_feedback=args.optimizer.error_feedback,
-        overlap_averaging=args.optimizer.overlap_averaging,
-        # signed contribution ledger (--optimizer.ledger_claims /
-        # --averager.ledger_receipts; docs/observability.md)
-        ledger_claims=args.optimizer.ledger_claims,
-        claim_period=args.optimizer.claim_period,
-        ledger_receipts=args.averager.ledger_receipts,
-        target_group_size=args.averager.target_group_size,
-        averaging_expiration=args.averager.averaging_expiration,
-        averaging_timeout=args.averager.averaging_timeout,
-        metadata_expiration=args.averager.metadata_expiration,
-        statistics_expiration=args.optimizer.statistics_expiration,
-        contrib_clip_per_sample=args.optimizer.contrib_clip_per_sample,
-        ramp_rounds=args.optimizer.ramp_rounds,
-        health_gate_loss_ratio=args.optimizer.health_gate_loss_ratio,
-        state_sync_retries=args.averager.state_sync_retries,
-        state_sync_backoff=args.averager.state_sync_backoff,
-        # device-resident gradient pipeline + fused flat apply
-        # (--optimizer.device_flat / --optimizer.flat_apply; docs/perf.md
-        # round 6): compressed D2H streaming and one-buffer apply
-        device_flat=args.optimizer.device_flat,
-        flat_opt_factory=(
-            build_flat_opt_factory(args)
-            if args.optimizer.flat_apply else None
-        ),
-        # swarm checkpointing (--checkpoint.*): sharded state serving +
-        # catalog announcements + multi-peer restore, blob as fallback
-        **checkpoint_kwargs(args, public_key),
-        min_refresh_period=args.averager.min_refresh_period,
-        max_refresh_period=args.averager.max_refresh_period,
-        default_refresh_period=args.averager.default_refresh_period,
-        expected_drift_peers=args.averager.expected_drift_peers,
-        expected_drift_rate=args.averager.expected_drift_rate,
-        performance_ema_alpha=args.averager.performance_ema_alpha,
-        client_mode=args.dht.client_mode,
-        relay=args.dht.relay or None,
-        listen_port=args.averager.listen_port,
-        advertised_host=args.dht.advertised_host or None,
-        allow_state_sharing=args.optimizer.allow_state_sharing,
+        flat_opt_factory=build_flat_opt_factory(args),
         mesh=mesh,
         opt_state_sharding=opt_sharding,
         param_sharding=param_sharding,
         authorizer=authorizer,
         authority_public_key=authority_public_key,
-        verbose=True,
     )
     # catch up with the collaboration before training (:124-128)
     # disk-resume seeds the collaborative counter; a DEEPER live
@@ -363,183 +303,49 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
         seq_length=seq,
         param_sharding=param_sharding,
     )
-    grad_acc = zeros_like_grads(state.params)
-    n_acc = jnp.zeros([], jnp.int32)
-
-    batches = _make_batches(args, cfg, public_key, slice_batch)
     data_rng = jax.random.PRNGKey(peer_shuffle_seed(public_key))
 
-    # the running loss stays ON DEVICE (a lazy sum) — a float() in the loop
-    # would synchronize the host with the accumulate kernels and serialize
-    # the input pipeline against XLA dispatch; the host reads it once per
-    # GLOBAL step, right where the value is published — and with it, in the
-    # same read, the family's per-pass gauges (a looped model's exit
-    # distribution and per-pass loss)
-    summed = ("loss",) + family.step_gauges
-    sums_dev: dict = {}
-    mini_steps = 0
-    boundary = 0
-    last_saved_step = opt.local_step
-    # the flight recorder (telemetry/steps.py) is the loop's one timer: every
-    # boundary is a record of nested host spans, always timed, published
-    # through the telemetry registry only when that is enabled. It feeds
-    # PerfStats (the operator's --training.log_perf_steps report; vissl
-    # PerfStats capability, vissl/utils/perf_stats.py:12-249) and, with
-    # --telemetry.profile_*, opens the profiler window. Per-micro-batch
-    # device time is NOT blocked on (that would serialize the async dispatch
-    # chain): it is the ``drain`` span inside opt.step, and the device
-    # planes of a profile. The MFU gauge uses the same analytic model-FLOPs
-    # formula and peak table as bench.py.
-    perf = PerfStats()
-    recorder = StepRecorder(
-        telemetry=tele,
-        model_tflops_per_sample=family.tflops_per_sample(cfg, seq),
-        peak_tflops=chip_peak_tflops(),
-        perf=perf,
-        profile=profile_gate(args.telemetry),
+    def micro_step(state, grad_acc, n_acc, batch):
+        nonlocal data_rng
+        data_rng, sub = jax.random.split(data_rng)
+        return accumulate(state.params, grad_acc, n_acc, batch, sub)
+
+    def put(batch):
+        return put_batch(
+            batch, mesh,
+            seq_axis="seq" if "seq" in mesh.axis_names else None,
+            seq_length=seq,
+        )
+
+    def save(state, step):
+        host = jax.device_get((state.params, state.opt_state))
+        save_checkpoint(
+            args.training.output_dir,
+            step,
+            tree_to_named(host),
+            metadata={"step": int(state.step), "local_step": step},
+            save_total_limit=args.training.save_total_limit,
+        )
+
+    return run_boundary_loop(
+        args,
+        LoopModel(
+            # drop_collator_keys runs inside the draw: timed as data_wait
+            batches=map(
+                drop_collator_keys,
+                _make_batches(args, cfg, public_key, slice_batch),
+            ),
+            micro_step=micro_step,
+            save=save,
+            put=put if mesh is not None else None,
+            step_gauges=family.step_gauges,
+            # the MFU gauge uses the same analytic model-FLOPs formula and
+            # peak table as bench.py
+            tflops_per_sample=family.tflops_per_sample(cfg, seq),
+        ),
+        state, opt, dht, public_key, tele, tele_close,
+        log_perf_steps=args.training.log_perf_steps,
     )
-    train_log = open_train_log(args.training.train_log_path)
-    samples = slice_batch * args.training.gradient_accumulation_steps
-    try:
-        while True:
-            # one accumulation boundary = gradient_accumulation_steps
-            # micro-batches = ONE step record, which runs to the start of
-            # the next boundary: data_wait/h2d/fwd_bwd here, the optimizer's
-            # spans inside opt.step (which also stamps stepped, samples and
-            # the running totals on the record), the tail of a global step
-            # as post_step
-            with recorder.step(step=opt.local_step) as srec:
-                for _ in range(args.training.gradient_accumulation_steps):
-                    with steps.phase("data_wait"):
-                        batch = drop_collator_keys(next(batches))
-                    if mesh is not None:
-                        with steps.phase("h2d"):
-                            batch = put_batch(
-                                batch, mesh,
-                                seq_axis=(
-                                    "seq" if "seq" in mesh.axis_names
-                                    else None
-                                ),
-                                seq_length=seq,
-                            )
-                    with steps.phase("fwd_bwd"):
-                        # everything the host enqueues for one micro-batch
-                        data_rng, sub = jax.random.split(data_rng)
-                        grad_acc, n_acc, metrics = accumulate(
-                            state.params, grad_acc, n_acc, batch, sub
-                        )
-                        sums_dev = {
-                            k: sums_dev[k] + metrics[k] if k in sums_dev
-                            else metrics[k] for k in summed
-                        }
-                    mini_steps += 1
-                state, grad_acc, n_acc, stepped = opt.step(
-                    state, grad_acc, n_acc, samples
-                )
-                if stepped:
-                    with steps.phase("post_step"):
-                        with steps.phase("loss_sync"):
-                            # the one sync per global step
-                            sums = jax.device_get(sums_dev)
-                        sums_dev = {}
-                        loss_sum = float(sums["loss"])
-                        loss = loss_sum / max(mini_steps, 1)
-                        for name in family.step_gauges:
-                            # means over the global step's tokens, onto the
-                            # step record and (telemetry on) into gauges
-                            for t, value in enumerate(
-                                sums[name] / max(mini_steps, 1), start=1
-                            ):
-                                srec.attrs[f"{name}.{t}"] = float(value)
-                                if tele is not None:
-                                    tele.gauge(f"{name}.{t}").set(float(value))  # dedlint: emits=gauge:lm.exit_prob.*,gauge:lm.loss.*
-                        # advertise the loss for the trunk-health gate —
-                        # free here, the scalar is already on the host
-                        opt.report_loss(loss)
-                        sps = float(opt.performance_ema.samples_per_second)
-                        # THIS boundary's values, off its record
-                        row = steps.train_log_row(srec)
-                        with steps.phase("publish"):
-                            publish_step_metrics(
-                                dht, args, public_key, opt, tele, row,
-                                samples=samples, loss=loss_sum,
-                                mini_steps=mini_steps, sps=sps,
-                                hbm_bytes=_hbm_bytes_in_use(),
-                            )
-                        mini_steps = 0
-                        with steps.phase("log"):
-                            logger.info(
-                                f"global step {opt.local_step}: loss "
-                                f"{loss:.4f}"
-                            )
-                            if train_log is not None:
-                                train_log.write(opt, row, loss, sps)
-                            if (
-                                args.training.log_perf_steps
-                                and opt.local_step
-                                % args.training.log_perf_steps == 0
-                            ):
-                                logger.info(
-                                    "perf phases:\n" + perf.report_str()
-                                )
-                            if (
-                                args.training.save_steps
-                                and opt.local_step - last_saved_step
-                                >= args.training.save_steps
-                            ):
-                                # cadence by DISTANCE, not divisibility: a
-                                # collaborative local_step can jump over
-                                # exact multiples (catch-ups adopt the
-                                # global counter), and a modulo check then
-                                # never fires again for the rest of the run
-                                _save(args, state, opt.local_step)
-                                last_saved_step = opt.local_step
-
-            boundary += 1
-            if (
-                args.training.max_local_steps
-                and boundary >= args.training.max_local_steps
-            ):
-                logger.info(f"reached max_local_steps={boundary}; stopping")
-                break
-    finally:
-        recorder.close()
-        if train_log is not None:
-            train_log.close()
-        tele_close()
-        opt.shutdown()
-        dht.shutdown()
-    return state
-
-
-def _hbm_bytes_in_use() -> Optional[int]:
-    """Device bytes_in_use via PJRT memory_stats (None off-TPU/unsupported)."""
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-        if stats:
-            return int(stats.get("bytes_in_use", 0)) or None
-    except Exception:  # noqa: BLE001 — telemetry must never kill training
-        pass
-    return None
-
-
-def _save(args: CollaborationArguments, state: TrainState, step: int) -> None:
-    from dedloc_tpu.collaborative.optimizer import _tree_to_named
-
-    host = jax.device_get((state.params, state.opt_state))
-    save_checkpoint(
-        args.training.output_dir,
-        step,
-        _tree_to_named(host),
-        metadata={"step": int(state.step), "local_step": step},
-        save_total_limit=args.training.save_total_limit,
-    )
-
-
-def _named_to_tree_pair(named, template):
-    from dedloc_tpu.collaborative.optimizer import _named_to_tree
-
-    return _named_to_tree(named, template)
 
 
 def _make_batches(

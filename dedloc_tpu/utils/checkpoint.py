@@ -21,6 +21,7 @@ import tempfile
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from dedloc_tpu.core.serialization import (
@@ -67,6 +68,28 @@ def sweep_orphan_tmpdirs(
             shutil.rmtree(path, ignore_errors=True)
             swept.append(path)
     return swept
+
+
+def tree_to_named(tree) -> Dict[str, np.ndarray]:
+    """Flatten a pytree into {path: np.array} with deterministic names: the
+    naming of checkpoints, shared state and the gradient wire."""
+    flat = jax.tree_util.tree_flatten_with_path(tree)[0]
+    out = {}
+    for i, (path, leaf) in enumerate(flat):
+        name = jax.tree_util.keystr(path) or f"leaf{i}"
+        out[name] = np.asarray(leaf)
+    return out
+
+
+def named_to_tree(named: Dict[str, np.ndarray], like):
+    """Inverse of ``tree_to_named`` given a structural template."""
+    flat, treedef = jax.tree_util.tree_flatten_with_path(like)
+    leaves = []
+    for i, (path, leaf) in enumerate(flat):
+        name = jax.tree_util.keystr(path) or f"leaf{i}"
+        arr = named[name]
+        leaves.append(np.asarray(arr, dtype=leaf.dtype).reshape(leaf.shape))
+    return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 def list_checkpoints(output_dir: str) -> List[Tuple[int, str]]:

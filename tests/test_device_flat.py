@@ -15,7 +15,7 @@ from dedloc_tpu.averaging.device_flat import (
     named_device_leaves,
 )
 from dedloc_tpu.averaging.partition import FlatTree, TreeLayout
-from dedloc_tpu.collaborative.optimizer import _tree_to_named
+from dedloc_tpu.utils.checkpoint import tree_to_named
 
 pytestmark = pytest.mark.wirepath
 
@@ -41,10 +41,10 @@ def _hostile_tree(rng):
 
 
 def _host_flat(tree, n=1):
-    """The legacy host reference: per-leaf mean, _tree_to_named naming,
+    """The legacy host reference: per-leaf mean, tree_to_named naming,
     TreeLayout.flatten_into."""
     mean = jax.tree.map(lambda g: g / n, tree)
-    named = _tree_to_named(mean)
+    named = tree_to_named(mean)
     layout = TreeLayout.for_tree(named)
     return layout.flatten_into(
         named, np.empty(layout.total_size, np.float32)
@@ -176,7 +176,7 @@ def test_mixed_float_dtypes_accepted_and_widened(rng):
         "bf16": jnp.asarray(rng.standard_normal(5), jnp.bfloat16),
         "f16": jnp.asarray(rng.standard_normal(5), jnp.float16),
     }
-    host_named = _tree_to_named(tree)
+    host_named = tree_to_named(tree)
     layout = TreeLayout.for_tree(host_named)
     # the host layout records the ORIGINAL dtypes; the device spec is
     # uniformly fp32 — compare values, which must agree exactly
@@ -291,9 +291,9 @@ def test_matches_tree_detects_schema_change(rng):
     assert not pipe.matches_tree({"only": jnp.zeros((1,), jnp.float32)})
 
 
-def test_named_device_leaves_matches_tree_to_named_naming(rng):
+def test_named_device_leaves_matchestree_to_named_naming(rng):
     tree = _hostile_tree(rng)
-    host_names = sorted(_tree_to_named(tree))
+    host_names = sorted(tree_to_named(tree))
     dev_names = sorted(name for name, _leaf in named_device_leaves(tree))
     assert host_names == dev_names
 

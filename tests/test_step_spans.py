@@ -162,91 +162,102 @@ def test_enabled_telemetry_publishes_the_tree():
     assert snapshot["step.phase.h2d_result.mean"] == approx(0.125)
 
 
-# -------------------------- the optimizer fills the record, in the core loop
+# ------------------------------- the optimizer fills the record, in the loop
 
 
-def test_core_trainer_record_with_a_real_optimizer_is_disjoint_and_counted():
-    """SwAV's shape: ``core.Trainer`` wraps the whole step function — H2D,
-    dispatch AND ``opt.step`` with its own spans — in ``fwd_bwd``. The
+def test_loop_record_with_a_real_optimizer_is_disjoint_and_counted(tmp_path):
+    """The one loop's shape (``roles/loop.py``), for every model: draw, H2D
+    and dispatch are SIBLINGS of the spans ``opt.step`` opens, and
+    ``post_step`` with its ``loss_sync`` is on stepping records only. The
     record stays disjoint, and ``opt.step`` itself stamps it."""
-    from dedloc_tpu.collaborative import CollaborativeOptimizer
-    from dedloc_tpu.core.trainer import Trainer
-    from dedloc_tpu.dht import DHT
+    from dedloc_tpu.core.config import CollaborationArguments, parse_config
     from dedloc_tpu.optim import lamb
-    from dedloc_tpu.parallel.train_step import (
-        TrainState,
-        make_accumulate_step,
-        zeros_like_grads,
+    from dedloc_tpu.parallel.train_step import TrainState, make_accumulate_step
+    from dedloc_tpu.roles.common import (
+        build_collaborative_optimizer,
+        build_dht,
     )
+    from dedloc_tpu.roles.loop import LoopModel, run_boundary_loop
 
     def toy_loss(params, batch, rng):
         loss = jnp.mean((batch["x"] @ params["w"] - batch["y"]) ** 2)
         return loss, {"loss": loss}
 
-    dht = DHT(start=True, listen_host="127.0.0.1")
+    args = parse_config(CollaborationArguments, [
+        "--dht.listen_host", "127.0.0.1",
+        "--dht.experiment_prefix", "loopspans",
+        "--training.max_local_steps", "12",
+        "--training.gradient_accumulation_steps", "1",
+        "--training.output_dir", str(tmp_path),
+        "--optimizer.target_batch_size", "32",
+        "--averager.metadata_expiration", "0.2",
+        "--averager.averaging_expiration", "0.5",
+        "--averager.averaging_timeout", "5.0",
+        "--averager.min_refresh_period", "0.05",
+        "--averager.default_refresh_period", "0.1",
+    ])
+    dht, public_key = build_dht(args)
     tx = lamb(0.05, weight_decay=0.0)
-    opt = CollaborativeOptimizer(
-        tx, dht, "corespans", target_batch_size=32,
-        metadata_expiration=0.2, averaging_expiration=0.5,
-        averaging_timeout=5.0, listen_host="127.0.0.1",
-        min_refresh_period=0.05, default_refresh_period=0.1,
+    opt = build_collaborative_optimizer(
+        args, tx, dht, public_key, batch_size_per_step=16,
+        flat_opt_factory=None,
     )
+    time.sleep(0.5)  # past the cold-start grace: the solo path
+    params = {"w": jnp.array([[0.5], [0.5]])}
+    accumulate = make_accumulate_step(toy_loss)
+    x = jax.random.normal(jax.random.PRNGKey(0), (16, 2))
+    batch = {"x": x, "y": x @ jnp.array([[1.0], [-2.0]])}
+
+    def micro_step(state, grad_acc, n_acc, data):
+        return accumulate(
+            state.params, grad_acc, n_acc, data, jax.random.PRNGKey(0)
+        )
+
+    tele = registry.install(Telemetry(peer="loop"))
     try:
-        time.sleep(0.5)  # past the cold-start grace: the solo path
-        params = {"w": jnp.array([[0.5], [0.5]])}
-        accumulate = make_accumulate_step(toy_loss)
-        x = jax.random.normal(jax.random.PRNGKey(0), (16, 2))
-        batch = {"x": x, "y": x @ jnp.array([[1.0], [-2.0]])}
-        local = {"acc": zeros_like_grads(params), "n": jnp.zeros([], jnp.int32)}
-
-        def step_fn(state, data):
-            with steps.phase("h2d"):
-                data = jax.device_put(data)
-            local["acc"], local["n"], metrics = accumulate(
-                state.params, local["acc"], local["n"], data,
-                jax.random.PRNGKey(0),
-            )
-            state, local["acc"], local["n"], stepped = opt.step(
-                state, local["acc"], local["n"], 16
-            )
-            if stepped:
-                with steps.phase("post_step"), steps.phase("loss_sync"):
-                    float(metrics["loss"])
-            return state, {"loss": metrics["loss"]}
-
-        trainer = Trainer(step_fn)
-        trainer.train(
-            TrainState.create(params, tx), iter([batch] * 12), max_steps=12
+        run_boundary_loop(  # shuts the optimizer and the DHT down itself
+            args,
+            LoopModel(
+                batches=iter([batch] * 12), micro_step=micro_step,
+                save=None, put=jax.device_put,
+            ),
+            TrainState.create(params, tx), opt, dht, public_key,
+            None, lambda: None,
         )
     finally:
-        opt.shutdown()
-        dht.shutdown()
+        registry.uninstall(tele)
 
-    records = list(trainer.recorder.records)
+    records = [e for e in tele.events if e["event"] == "step.record"]
     assert len(records) == 12
-    assert any(r["stepped"] for r in records)
+    assert any(r.get("stepped") for r in records)
     running = 0
     for record in records:
         assert record["samples"] == 16
         running += record["samples"]
         assert record["samples_total"] == running  # monotone, the running sum
-        assert sum(record["phases"].values()) <= record["wall_s"] + 1e-9
+        wall = record["dur_s"]
+        assert sum(record["phases"].values()) <= wall + 1e-9
         assert sum(record["phases"].values()) + record["untimed_s"] == (
-            pytest.approx(record["wall_s"])
+            pytest.approx(wall)
         )
-        assert 0 < record["opt_step_s"] <= record["wall_s"]
+        assert 0 < record["opt_step_s"] <= wall
         parents = {s[0]: s[1] for s in record["spans"]}
-        assert parents["fwd_bwd"] is None and parents["h2d"] == "fwd_bwd"
-        assert parents["collab"] == "fwd_bwd"  # opened inside opt.step
-        assert parents["loss_sync"] in (None, "post_step")
+        for name in ("data_wait", "h2d", "fwd_bwd", "collab"):
+            assert parents[name] is None, (name, record["spans"])
+        if record.get("stepped"):
+            assert parents["loss_sync"] == "post_step"
+        else:
+            assert "post_step" not in parents and "loss_sync" not in parents
     steps_seen = [r["global_steps_total"] for r in records]
     assert steps_seen == sorted(steps_seen) and steps_seen[-1] >= 1
     assert [r["boundaries_total"] for r in records] == list(range(1, 13))
-    stepping = next(r for r in records if r["stepped"])
+    stepping = next(r for r in records if r.get("stepped"))
     parents = {s[0]: s[1] for s in stepping["spans"]}
     for name in ("drain", "grad_flatten", "opt_apply", "backup_launch",
                  "post_step"):
-        assert parents[name] == "fwd_bwd", (name, stepping["spans"])
+        assert parents[name] is None, (name, stepping["spans"])
+    for name in ("publish", "log"):
+        assert parents[name] == "post_step"
 
 
 # ------------------------------------------------------- the profiler's clock

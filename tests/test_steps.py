@@ -121,23 +121,46 @@ def test_recorder_mfu_gauge_tracks_ring_throughput():
     )
 
 
-def test_core_trainer_records_step_phases():
-    from dedloc_tpu.core.trainer import Trainer
+def test_boundary_loop_records_step_phases():
+    """The one loop (roles/loop.py) with no model to speak of: every
+    boundary is a record, its draw / upload / enqueue are spans."""
+    import optax
 
+    from dedloc_tpu.core.config import CollaborationArguments
+    from dedloc_tpu.parallel.train_step import TrainState
+    from dedloc_tpu.roles.loop import LoopModel, run_boundary_loop
+
+    class NeverSteps:  # what the loop touches of an optimizer and a DHT
+        local_step, batch_size_per_step, closed = 0, 1, 0
+
+        def step(self, state, grad_acc, n_acc, samples):
+            return state, grad_acc, n_acc, False
+
+        def shutdown(self):
+            self.closed += 1
+
+    opt = NeverSteps()
+    args = CollaborationArguments()
+    args.training.max_local_steps = 3
     tele = registry.install(Telemetry(peer="core"))
     try:
-        def step_fn(state, batch):
-            return state + batch, {"loss": jnp.asarray(0.5)}
+        def micro_step(state, grad_acc, n_acc, batch):
+            return grad_acc, n_acc + 1, {"loss": batch * 0.5}
 
-        trainer = Trainer(step_fn)
-        state, ctx = trainer.train(
-            jnp.zeros([]), iter([jnp.ones([])] * 3), max_steps=3
+        state = run_boundary_loop(
+            args,
+            LoopModel(
+                batches=iter([jnp.ones([])] * 5), micro_step=micro_step,
+                save=None, put=jax.device_put,
+            ),
+            TrainState.create({"w": jnp.zeros([])}, optax.sgd(0.1)),
+            opt, opt, b"key", None, lambda: None,
         )
-        assert ctx.local_step == 3
+        assert int(state.step) == 0
+        assert opt.closed == 2  # as the optimizer and as the DHT
         records = [e for e in tele.events if e["event"] == "step.record"]
         assert len(records) == 3
-        phases = records[-1]["phases"]
-        assert {"data_wait", "fwd_bwd", "hooks"} <= set(phases)
+        assert {"data_wait", "h2d", "fwd_bwd"} <= set(records[-1]["phases"])
     finally:
         registry.uninstall(tele)
 

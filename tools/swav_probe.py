@@ -164,15 +164,12 @@ def probe(args) -> None:
     assert loaded is not None, f"no checkpoint under {args.checkpoint_dir}"
     step, tree, _meta = loaded
     out["checkpoint_step"] = step
-    # checkpoints hold _tree_to_named((params, batch_stats)) — rebuild via
+    # checkpoints hold tree_to_named((params, batch_stats)) — rebuild via
     # the same naming template
-    from dedloc_tpu.collaborative.optimizer import (
-        _named_to_tree,
-        _tree_to_named,
-    )
+    from dedloc_tpu.utils.checkpoint import named_to_tree
 
     template = jax.device_get((variables["params"], variables["batch_stats"]))
-    params, batch_stats = _named_to_tree(tree, template)
+    params, batch_stats = named_to_tree(tree, template)
     out.update(probe_for(params, batch_stats, "trained_trunk"))
     print(json.dumps(out))
 
