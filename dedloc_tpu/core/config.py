@@ -292,11 +292,13 @@ class TrainingArguments:
     # depth override (0 = the model's own): a chip's share of a deeper
     # deployment keeps every width and cuts layers. No width is settable.
     num_hidden_layers: int = 0
-    # override model remat: nothing|dots|dots_no_batch|dots_no_batch_attn|
-    # fused_ln|fused_ln_gelu (fused_ln — saved Pallas outputs + named
-    # matmuls, pairs the fused add+LN kernel on automatically — is the
-    # fastest measured policy for the seq-512 recipe on v5e; the policy
-    # table lives in models/albert.py, measurements in docs/perf.md)
+    # override model remat: nothing|kernel_outputs|dots|dots_no_batch|
+    # dots_no_batch_attn|fused_ln|fused_ln_gelu (fused_ln — saved Pallas
+    # outputs + named matmuls, pairs the fused add+LN kernel on
+    # automatically — is the fastest measured policy for the seq-512 recipe
+    # on v5e; kernel_outputs — the Pallas outputs alone — is the looped
+    # decoder's own default; the policy table lives in models/albert.py,
+    # measurements in docs/perf.md and PERF.md)
     remat_policy: str = ""
     attention_impl: str = ""  # override: dense|blockwise|flash|ring
     vocab_size: int = 0  # override model vocab (0 = size default); must cover

@@ -367,6 +367,12 @@ def remat_policy_object(name: str):
     on every parallelism path). Raises on unknown names."""
     table = {
         "nothing": jax.checkpoint_policies.nothing_saveable,
+        # ONLY the Pallas kernels' outputs (flash: out in the model's own
+        # layout + lse): the custom-VJP backward reads them as they were,
+        # so the replay re-runs no kernel; every matmul output is still
+        # recomputed. For a model whose STATE fills the chip (the looped
+        # decoder, models/ouro.py: 17 MB a layer iteration)
+        "kernel_outputs": _pallas_outputs_saveable,
         "dots": jax.checkpoint_policies.checkpoint_dots,
         "dots_no_batch": (
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable

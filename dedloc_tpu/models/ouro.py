@@ -63,11 +63,12 @@ class OuroConfig:
     initializer_range: float = 0.02
     dtype: Any = jnp.bfloat16  # compute dtype; params stay fp32
     # the layer always runs under remat; the policy is a name from
-    # albert.remat_policy_object's table. "nothing" keeps one layer input
-    # per (pass, layer) and replays the layer in the backward: at
-    # 350-400 M parameters the state leaves no room for a q/k/v/FFN stash
-    # of 16 layer iterations
-    remat_policy: str = "nothing"
+    # albert.remat_policy_object's table. "kernel_outputs" keeps one layer
+    # input per (pass, layer) plus the flash kernel's out + lse (what its
+    # backward reads: 17 MB a layer iteration) and replays the rest of the
+    # layer in the backward: at 350-400 M parameters the state leaves no
+    # room for a q/k/v/FFN stash of 12-16 layer iterations
+    remat_policy: str = "kernel_outputs"
     # "flash": the causal mode of ops/flash_attention.py; "dense": XLA's
     # materialized S² scores (tests, tiny models)
     attention_impl: str = "flash"

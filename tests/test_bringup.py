@@ -287,3 +287,20 @@ def test_accumulate_step_has_no_relayout_copies_around_flash_attention():
     copies = row["layer_body_copies"]
     assert len(copies) <= 3, copies
     assert not [shape for shape in copies if shape.endswith(",512,64]")]
+
+
+def test_ouro_accumulate_step_keeps_the_flash_outputs_and_fits_the_cap():
+    """The looped decoder's accumulate_step (Ouro-2.6B cut to the cell's 3
+    layers, 1 row of 4,096), compiled for a v5e: the layer's remat policy
+    keeps the causal flash kernel's out + lse, so the lowered module calls
+    the forward kernel ONCE (the forward scan's body) and the backward's
+    replay of the layer holds none — 2 call sites under policy ``nothing``,
+    12 of 24 executions a micro-batch (PR 28). The stash is paid in the
+    program's scratch: 5.30 GB against 5.04, which with a draining
+    snapshot's 9.96 GB of state stays under the 15.3 GB the cell is sized
+    by; a policy that also kept ``flash_qkv`` would read 6.2 GB here."""
+    row = _tpu_aot("ouro_accumulate_step")["ouro_accumulate_step"]
+    assert row["flash_fwd_forms"] == {"one_tile": 0, "tiles": 1}
+    # forward, dq, dkv: one site each
+    assert row["tpu_custom_calls"] == 3
+    assert row["memory"]["temp_bytes"] <= 5.35e9, row["memory"]

@@ -5,13 +5,18 @@ logits, exit distribution, the whole gradient and its worst leaf, with dense
 and with flash attention; the chunked head + loss against the unchunked one;
 the exit distribution summing to 1 and the entropy term's sign; and a
 reference one pass short, or with the final norm outside the loop, failing
-by orders of magnitude."""
+by orders of magnitude; and what the layer keeps under remat (the flash
+kernel's outputs): the same bits as replaying everything, one forward kernel
+call in the gradient's jaxpr instead of two."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from benchmark.reference import ouro as reference
+from dedloc_tpu.models.albert import remat_policy_object
 from dedloc_tpu.models.ouro import (
     OuroConfig,
     OuroForCausalLM,
@@ -206,3 +211,59 @@ def test_published_preset_and_flop_model():
     small = OuroConfig.ouro_2p6b(num_hidden_layers=3)
     assert ouro_train_tflops_per_sample(small, 4096) == pytest.approx(want)
     assert 27.0 < want < 28.0  # TFLOPs a row
+
+
+# ------------------------------------- what the layer keeps under remat
+
+
+def _flash_value_and_grad(remat_policy):
+    """(function, its argument): loss and gradients of ``ouro_tiny`` with
+    the causal flash kernels under one layer policy."""
+    _cfg, model, params, batch = _setup("flash", remat_policy=remat_policy)
+    return jax.value_and_grad(lambda p: ouro_loss(model, p, batch)[0]), params
+
+
+def _pallas_calls(jaxpr_text, name):
+    """Call sites of the Pallas kernel ``name`` in a printed jaxpr (its
+    pallas_call equations carry the names, nested jaxprs are printed in
+    place: scan bodies, remat's replay, the custom VJP's halves)."""
+    return len(re.findall(rf"\bname={name}\b", jaxpr_text))
+
+
+def test_default_policy_keeps_the_kernel_outputs_and_the_same_bits():
+    """The saved ``out`` / ``lse`` are the bits the replay would have
+    produced: the loss and EVERY gradient leaf agree exactly."""
+    fn, params = _flash_value_and_grad(OuroConfig().remat_policy)
+    ref_fn, ref_params = _flash_value_and_grad("nothing")
+    loss, grads = fn(params)
+    ref_loss, ref_grads = ref_fn(ref_params)
+    assert float(loss) == float(ref_loss)
+    jax.tree_util.tree_map_with_path(  # raises on a different tree, too
+        lambda path, leaf, ref_leaf: np.testing.assert_array_equal(
+            leaf, ref_leaf, err_msg=jax.tree_util.keystr(path)
+        ),
+        grads, ref_grads,
+    )
+
+
+@pytest.mark.parametrize("policy,forward_calls", [
+    ("kernel_outputs", 1),  # the forward scan's; the backward reads its stash
+    ("nothing", 2),  # + the one in the backward's replay of the layer
+])
+def test_forward_kernel_call_sites_in_the_gradient(policy, forward_calls):
+    """The engagement count with no chip: a scanned layer body is ONE call
+    site per appearance, so a remat replay of the kernel is a second one."""
+    fn, params = _flash_value_and_grad(policy)
+    jaxpr = str(jax.make_jaxpr(fn)(params))  # traced, not run
+    assert _pallas_calls(jaxpr, "flash_causal_fwd") == forward_calls
+    # the two-kernel backward runs once under either policy
+    assert _pallas_calls(jaxpr, "flash_causal_bwd_dq") == 1
+    assert _pallas_calls(jaxpr, "flash_causal_bwd_dkv") == 1
+
+
+def test_default_policy_is_a_table_entry_and_unknown_names_raise():
+    assert OuroConfig().remat_policy == "kernel_outputs"
+    assert callable(remat_policy_object("kernel_outputs"))
+    assert callable(remat_policy_object("nothing"))  # stays in the table
+    with pytest.raises(ValueError, match="unknown remat_policy"):
+        remat_policy_object("kernel_output")

@@ -13,9 +13,13 @@ speed or numerics; those need the chip (``chip_smoke.py``).
 
 Each line: {"program", "compile_s", "tpu_custom_calls", "flash_fwd_forms",
 "layer_body_copies", "memory"} — ``flash_fwd_forms`` counts the flash
-forward calls of the lowered module by the form their shapes chose
+forward call SITES of the lowered module by the form their shapes chose
 (``one_tile``: one tile covers the sequence; ``tiles``: the online-softmax
-kernel; a scanned layer body is one call); ``layer_body_copies`` is the
+kernel). A scanned layer body is one site however often it runs, and a
+remat replay of the kernel is a second site in the backward's body: "one
+call per scanned layer" holds only when the layer's remat policy keeps the
+kernel's outputs (ALBERT's ``fused_ln``, Ouro's ``kernel_outputs``: 1;
+Ouro under ``nothing``: 2, PR 28); ``layer_body_copies`` is the
 shapes of the ``copy`` instructions inside the compiled program's while
 bodies (the scanned layer, forward and backward): relayouts the compiler put
 around an op whose layout differs from its neighbours', paid once per layer
@@ -248,9 +252,10 @@ def layer_body_copies(hlo_text: str) -> list:
 
 
 def flash_fwd_forms(lowered_text: str) -> dict:
-    """The flash forward calls of a lowered module, by form: the one-tile
-    call carries ``form: one_tile`` in its kernel metadata (the two forms
-    share a kernel name, which is what a device trace is read by)."""
+    """The flash forward call sites of a lowered module, by form: the
+    one-tile call carries ``form: one_tile`` in its kernel metadata (the two
+    forms share a kernel name, which is what a device trace is read by). A
+    site inside a scan body counts once; a remat replay is a site more."""
     calls = [
         line for line in lowered_text.splitlines()
         if re.search(r'kernel_name = "flash_(causal_)?fwd"', line)
