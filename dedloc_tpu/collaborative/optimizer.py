@@ -56,23 +56,31 @@ from dedloc_tpu.utils.logging import get_logger
 logger = get_logger(__name__)
 
 
-def _fused_mean_clip(grad_acc, n, cap):
+def _fused_mean_clip(grad_acc, n, cap, exempt=None):
     """The accumulator mean plus the contribution clip as ONE fused jitted
     program: ``grad_acc / n`` per leaf, one global-norm reduce, one scale.
     ``cap <= 0`` disables the clip (the scale multiplies by exactly 1.0, a
     bitwise no-op). Replaces the Python-level sum of per-leaf ``vdot``s
-    that used to emit O(leaves) tiny kernels per boundary."""
+    that used to emit O(leaves) tiny kernels per boundary. ``exempt``
+    (static: one bool per leaf, or None) marks leaves that carry a
+    statistic, not a gradient (``optim.lamb.sign_stepped``): they are left
+    out of the norm and are not scaled."""
     mean = jax.tree.map(lambda g: g / n, grad_acc)
+    leaves, treedef = jax.tree.flatten(mean)
+    exempt = exempt or (False,) * len(leaves)
     gnorm = jax.numpy.sqrt(
         sum(
             jax.numpy.vdot(g, g).real
-            for g in jax.tree.leaves(mean)
+            for g, skip in zip(leaves, exempt) if not skip
         )
     )
     scale = jax.numpy.where(
         cap > 0, jax.numpy.minimum(1.0, cap / (gnorm + 1e-12)), 1.0
     )
-    return jax.tree.map(lambda g: g * scale, mean)
+    return jax.tree.unflatten(
+        treedef,
+        [g if skip else g * scale for g, skip in zip(leaves, exempt)],
+    )
 
 
 # for the boundaries that apply the peer's OWN mean (solo, and the
@@ -81,8 +89,10 @@ def _fused_mean_clip(grad_acc, n, cap):
 # 4 bytes a parameter that would otherwise be held twice across the apply,
 # which is what decides whether a 350 M-parameter state fits the chip. Both
 # are "_fused_mean_clip" to a trace or a compile listener.
-_fused_mean_clip_in_place = jax.jit(_fused_mean_clip, donate_argnums=(0,))
-_fused_mean_clip = jax.jit(_fused_mean_clip)
+_fused_mean_clip_in_place = jax.jit(
+    _fused_mean_clip, donate_argnums=(0,), static_argnames=("exempt",)
+)
+_fused_mean_clip = jax.jit(_fused_mean_clip, static_argnames=("exempt",))
 
 
 class CollaborativeOptimizer:
@@ -121,6 +131,9 @@ class CollaborativeOptimizer:
         # relayed deployments want this pinned (--averager.listen_port)
         advertised_host: Optional[str] = None,
         post_apply: Optional[Callable[[TrainState], TrainState]] = None,
+        sign_step_mask: Optional[Callable] = None,  # params-shaped tree ->
+        # tree of bools: leaves that carry a statistic stepped by its sign
+        # (optim.lamb.sign_stepped), left out of the contribution clip
         authorizer=None,  # token authorizer for gated public runs
         authority_public_key: Optional[bytes] = None,
         contrib_clip_per_sample: float = 0.0,  # cap the contributed
@@ -313,6 +326,7 @@ class CollaborativeOptimizer:
         # tree is refused (non-float leaves): the legacy per-leaf host path
         self.device_flat = True
         self.flat_opt_factory = flat_opt_factory
+        self.sign_step_mask = sign_step_mask
         self._pipeline: Optional[DeviceFlatPipeline] = None
         self._flat_apply_fn = None
         self._flat_apply_spec = None
@@ -366,6 +380,15 @@ class CollaborativeOptimizer:
         self._last_claim_t = 0.0
 
     # ------------------------------------------------------------ properties
+
+    def _clip_exempt(self, grad_acc):
+        """One bool per leaf of ``grad_acc`` for ``_fused_mean_clip`` (None:
+        every leaf is a gradient)."""
+        if self.sign_step_mask is None:
+            return None
+        return tuple(
+            bool(m) for m in jax.tree.leaves(self.sign_step_mask(grad_acc))
+        )
 
     @property
     def collaboration_state(self) -> CollaborationState:
@@ -728,7 +751,9 @@ class CollaborativeOptimizer:
             # lets a concurrent starter pair with us.
             self.seam_ms.pop("grads_device_get", None)
             with steps.phase("grad_flatten"):
-                mean_grads = _fused_mean_clip_in_place(grad_acc, n, cap)
+                mean_grads = _fused_mean_clip_in_place(
+                    grad_acc, n, cap, exempt=self._clip_exempt(grad_acc)
+                )
             return self._apply_and_advance(
                 state, mean_grads, collab, group_size=1,
             )
@@ -769,7 +794,9 @@ class CollaborativeOptimizer:
             # per-leaf device_get + host flatten + host error feedback
             with steps.phase("grad_flatten") as flatten:
                 # device_get of the full grad tree (the jit↔host seam)
-                named = tree_to_named(_fused_mean_clip(grad_acc, n, cap))
+                named = tree_to_named(_fused_mean_clip(
+                    grad_acc, n, cap, exempt=self._clip_exempt(grad_acc)
+                ))
             self.seam_ms["grads_device_get"] = flatten.dur_s * 1e3
             # error feedback (collaborative/error_feedback.py): fold the
             # last round's quantization residual into this round's
@@ -924,7 +951,9 @@ class CollaborativeOptimizer:
             # residual fold, never quantized) — exactly what the legacy
             # path applied here; the device tree never left the chip
             with steps.phase("grad_flatten"):
-                mean_grads = _fused_mean_clip_in_place(grad_acc, n, cap)
+                mean_grads = _fused_mean_clip_in_place(
+                    grad_acc, n, cap, exempt=self._clip_exempt(grad_acc)
+                )
             return self._apply_and_advance(
                 state, mean_grads, collab, group_size,
             )

@@ -292,6 +292,13 @@ class TrainingArguments:
     # depth override (0 = the model's own): a chip's share of a deeper
     # deployment keeps every width and cuts layers. No width is settable.
     num_hidden_layers: int = 0
+    # "index/count": the share of every expert layer's routed experts this
+    # peer's chip holds, as one of ``count`` chips that divide a layer (a
+    # model with a dropless routed layer: models/deepseek_v3.py). The layer
+    # scores ALL experts and computes its own experts' part; "0/1" = every
+    # expert. Together with ``vocab_size`` (rows of the vocabulary held)
+    # and ``num_hidden_layers`` it states a chip's share of a deployment.
+    expert_shard: str = "0/1"
     # override model remat: nothing|kernel_outputs|dots|dots_no_batch|
     # dots_no_batch_attn|fused_ln|fused_ln_gelu (fused_ln — saved Pallas
     # outputs + named matmuls, pairs the fused add+LN kernel on

@@ -114,9 +114,10 @@ def _dense(features: int, cfg: OuroConfig, name: str) -> nn.Dense:
 
 
 class RMSNorm(nn.Module):
-    """x · rsqrt(mean(x²) + eps) · weight, statistics in float32."""
+    """x · rsqrt(mean(x²) + eps) · weight, statistics in float32. ``cfg``:
+    any config with ``rms_norm_eps`` and ``dtype``."""
 
-    cfg: OuroConfig
+    cfg: Any
 
     @nn.compact
     def __call__(self, x):
@@ -149,6 +150,28 @@ def apply_rope(x, cos, sin):
     return (
         x32 * cos[None, :, None, :] + rotated * sin[None, :, None, :]
     ).astype(x.dtype)
+
+
+def swiglu(cfg, x, width: int):
+    """down(silu(gate x) · up x) with ``gate_proj`` / ``up_proj`` /
+    ``down_proj`` created in the CALLING module's scope (call it inside a
+    compact method)."""
+    # named for the remat policies that stash the FFN's matmul outputs
+    gate = checkpoint_name(_dense(width, cfg, "gate_proj")(x), "ffn_up")
+    up = checkpoint_name(_dense(width, cfg, "up_proj")(x), "ffn_up")
+    return _dense(cfg.hidden_size, cfg, "down_proj")(nn.silu(gate) * up)
+
+
+class SwiGLU(nn.Module):
+    """``swiglu`` in a scope of its own (a decoder whose layer has more than
+    one: a dense FFN, shared experts)."""
+
+    cfg: Any
+    width: int
+
+    @nn.compact
+    def __call__(self, x):
+        return swiglu(self.cfg, x, self.width)
 
 
 class OuroAttention(nn.Module):
@@ -207,14 +230,7 @@ class OuroLayer(nn.Module):
         )
         hidden = hidden + RMSNorm(cfg, name="input_layernorm_2")(attn)
         x = RMSNorm(cfg, name="post_attention_layernorm")(hidden)
-        # named for the remat policies that stash the FFN's matmul outputs
-        gate = checkpoint_name(
-            _dense(cfg.intermediate_size, cfg, "gate_proj")(x), "ffn_up"
-        )
-        up = checkpoint_name(
-            _dense(cfg.intermediate_size, cfg, "up_proj")(x), "ffn_up"
-        )
-        mlp = _dense(cfg.hidden_size, cfg, "down_proj")(nn.silu(gate) * up)
+        mlp = swiglu(cfg, x, cfg.intermediate_size)
         return hidden + RMSNorm(cfg, name="post_attention_layernorm_2")(mlp)
 
 

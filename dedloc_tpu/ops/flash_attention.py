@@ -114,12 +114,13 @@ def _t(x):
 # --------------------------------------------- heads inside a column block
 
 
-def _heads_per_block(h: int, d: int) -> int:
+def _heads_per_block(h: int, d: int, dv: int) -> int:
     """Adjacent heads that share one column block: the fewest whose lanes
-    fill whole 128-lane tiles (2 at D=64, 1 at D=128), or all ``h`` heads —
-    the whole width, the one other block Mosaic takes — when those do not
-    divide the head count (the tiny test models, an odd head count)."""
-    g = 128 // math.gcd(128, d)
+    fill whole 128-lane tiles at BOTH widths (2 at D=64, 1 at D=128, 2 at
+    192 / 128), or all ``h`` heads — the whole width, the one other block
+    Mosaic takes — when those do not divide the head count (the tiny test
+    models, an odd head count)."""
+    g = max(128 // math.gcd(128, d), 128 // math.gcd(128, dv))
     return g if h % g == 0 else h
 
 
@@ -136,21 +137,28 @@ def _pick_heads(h: int, g: int, block_q: int, block_k: int,
     return n * g
 
 
-def _geometry(q, d: int, block_q: int, block_k: int, budget_mb: float):
+def _geometry(q, d: int, dv: int, block_q: int, block_k: int,
+              budget_mb: float):
     """(B, S, H, heads per column block, heads per program, Bq, Bk) for
-    [B, S, H·D] operands."""
+    [B, S, H·D] operands (``q``: [B, S, H·d]; v and out are H·dv wide)."""
     b, s, width = q.shape
     h = width // d
-    g = _heads_per_block(h, d)
+    g = _heads_per_block(h, d, dv)
     bq = _pick_block(s, block_q)
     bk = _pick_block(s, block_k)
     return b, s, h, g, _pick_heads(h, g, bq, bk, budget_mb), bq, bk
 
 
-def _column_blocks(width: int, g: int, d: int):
-    """(first head, lane slice) of each column block of a [N, width] tile."""
-    w = g * d
-    return [(c * g, slice(c * w, (c + 1) * w)) for c in range(width // w)]
+def _column_blocks(width: int, g: int, d: int, dv: int):
+    """(first head, q/k lane slice, v/out lane slice) of each column block
+    of a program's tiles: [N, width] for q and k (heads ``d`` wide),
+    [N, width / d · dv] for v, out and dO. One slice twice where the two
+    widths are equal."""
+    w, wv = g * d, g * dv
+    return [
+        (c * g, slice(c * w, (c + 1) * w), slice(c * wv, (c + 1) * wv))
+        for c in range(width // w)
+    ]
 
 
 def _only_head(x, i: int, d: int):
@@ -245,10 +253,10 @@ def _masked(s, mask):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, scale, d, g, causal):
+                acc_ref, m_ref, l_ref, *, scale, d, dv, g, causal):
     kb = pl.program_id(3)
     nk = pl.num_programs(3)
-    blocks = _column_blocks(q_ref.shape[-1], g, d)
+    blocks = _column_blocks(q_ref.shape[-1], g, d, dv)
 
     @pl.when(kb == 0)
     def _init():
@@ -262,10 +270,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         # and semaphore work amortise over their heads' matmuls — at D=64
         # the per-head dots are too small to hide the per-program overhead
         # (measured on v5e)
-        for h0, cols in blocks:
+        for h0, cols, vcols in blocks:
             q = q_ref[:, cols]  # [Bq, g·D]: g heads side by side
             k = k_ref[:, cols]  # [Bk, g·D]
-            v = v_ref[:, cols]
+            v = v_ref[:, vcols]
             pv, corrs = None, []
             for i in range(g):
                 h = h0 + i
@@ -287,10 +295,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                 m_ref[h] = m_new
                 corrs.append(corr)
                 pv = _add(
-                    pv, _dot(p.astype(v.dtype), _only_head(v, i, d), 1, 0)
+                    pv, _dot(p.astype(v.dtype), _only_head(v, i, dv), 1, 0)
                 )
-            acc_ref[:, cols] = (
-                acc_ref[:, cols] * _per_head_lanes(corrs, d) + pv
+            acc_ref[:, vcols] = (
+                acc_ref[:, vcols] * _per_head_lanes(corrs, dv) + pv
             )
 
     _for_tile(causal, pl.program_id(2), kb, q_ref.shape[0], k_ref.shape[0],
@@ -298,12 +306,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
 
     @pl.when(kb == nk - 1)
     def _flush():
-        for h0, cols in blocks:
+        for h0, _cols, vcols in blocks:
             safe_l = [
                 jnp.maximum(l_ref[h0 + i], 1e-30) for i in range(g)
             ]  # [Bq, 1] each
-            o_ref[:, cols] = (
-                acc_ref[:, cols] / _per_head_lanes(safe_l, d)
+            o_ref[:, vcols] = (
+                acc_ref[:, vcols] / _per_head_lanes(safe_l, dv)
             ).astype(o_ref.dtype)
             for i in range(g):
                 lse_ref[h0 + i] = _t(  # -> [1, Bq] row
@@ -312,7 +320,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
 
 
 def _fwd_one_tile_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
-                         scale, d, g, causal):
+                         scale, d, dv, g, causal):
     """Single-block forward: when one (Bq, Bk) tile covers the whole
     sequence a row's softmax is complete after its one tile, so there is no
     running state to initialise, correct or carry — each head's max, sum and
@@ -320,10 +328,10 @@ def _fwd_one_tile_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
     s = q_ref.shape[0]
     mask = _tile_mask(0, 0, s, s) if causal else None
     b = bias_ref[:].astype(jnp.float32)  # [1, S]
-    for h0, cols in _column_blocks(q_ref.shape[-1], g, d):
+    for h0, cols, vcols in _column_blocks(q_ref.shape[-1], g, d, dv):
         q = q_ref[:, cols]  # [S, g·D]: g heads side by side
         k = k_ref[:, cols]
-        v = v_ref[:, cols]
+        v = v_ref[:, vcols]
         pv, ls = None, []
         for i in range(g):
             x = _masked(
@@ -336,24 +344,29 @@ def _fwd_one_tile_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
             l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
             lse_ref[h0 + i] = _t(m + jnp.log(l))  # [S, 1] -> [1, S] row
             ls.append(l)
-            pv = _add(pv, _dot(p.astype(v.dtype), _only_head(v, i, d), 1, 0))
-        o_ref[:, cols] = (pv / _per_head_lanes(ls, d)).astype(o_ref.dtype)
+            pv = _add(pv, _dot(p.astype(v.dtype), _only_head(v, i, dv), 1, 0))
+        o_ref[:, vcols] = (pv / _per_head_lanes(ls, dv)).astype(o_ref.dtype)
 
 
-def _name(kernel: str, causal: bool) -> str:
-    """The causal kernels keep names of their own in a device trace."""
+def _name(kernel: str, causal: bool, d: int, dv: int) -> str:
+    """The causal kernels keep names of their own in a device trace, and so
+    do the two-width ones (latent attention: q/k wider than v and out)."""
+    if d != dv:
+        return f"flash_mla_{kernel}" if causal else f"flash_mla_full_{kernel}"
     return f"flash_causal_{kernel}" if causal else f"flash_{kernel}"
 
 
-def _fwd(q, k, v, bias, d, block_q, block_k, causal, interpret):
-    """Returns (out [B, S, H·D], lse [B·H, 1, S])."""
+def _fwd(q, k, v, bias, d, dv, block_q, block_k, causal, interpret):
+    """Returns (out [B, S, H·dv], lse [B·H, 1, S])."""
     if _one_tile(q.shape[1], block_q, block_k):
-        return _fwd_one_tile(q, k, v, bias, d, causal, interpret)
-    return _fwd_tiled(q, k, v, bias, d, block_q, block_k, causal, interpret)
+        return _fwd_one_tile(q, k, v, bias, d, dv, causal, interpret)
+    return _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, causal,
+                      interpret)
 
 
-def _fwd_tiled(q, k, v, bias, d, block_q, block_k, causal, interpret):
-    b, s, h, g, hp, bq, bk = _geometry(q, d, block_q, block_k, budget_mb=6.0)
+def _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, causal, interpret):
+    b, s, h, g, hp, bq, bk = _geometry(q, d, dv, block_q, block_k,
+                                       budget_mb=6.0)
     hpb = h // hp  # programs across the width
 
     def k_at(j, kb):  # a tile above the diagonal re-names the last needed
@@ -361,63 +374,65 @@ def _fwd_tiled(q, k, v, bias, d, block_q, block_k, causal, interpret):
 
     out, lse = pl.pallas_call(
         functools.partial(
-            _fwd_kernel, scale=1.0 / (d ** 0.5), d=d, g=g, causal=causal
+            _fwd_kernel, scale=1.0 / (d ** 0.5), d=d, dv=dv, g=g,
+            causal=causal,
         ),
         grid=(b, hpb, s // bq, s // bk),
         in_specs=[
             pl.BlockSpec((None, bq, hp * d), lambda n, p, j, kb: (n, j, p)),
             pl.BlockSpec((None, bk, hp * d),
                          lambda n, p, j, kb: (n, k_at(j, kb), p)),
-            pl.BlockSpec((None, bk, hp * d),
+            pl.BlockSpec((None, bk, hp * dv),
                          lambda n, p, j, kb: (n, k_at(j, kb), p)),
             pl.BlockSpec((None, 1, bk),
                          lambda n, p, j, kb: (n, 0, k_at(j, kb))),
         ],
         out_specs=[
-            pl.BlockSpec((None, bq, hp * d), lambda n, p, j, kb: (n, j, p)),
+            pl.BlockSpec((None, bq, hp * dv), lambda n, p, j, kb: (n, j, p)),
             pl.BlockSpec((hp, 1, bq),
                          lambda n, p, j, kb: (n * hpb + p, 0, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(v.shape, q.dtype),
             jax.ShapeDtypeStruct((b * h, 1, s), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, hp * d), jnp.float32),
+            pltpu.VMEM((bq, hp * dv), jnp.float32),
             pltpu.VMEM((hp, bq, 1), jnp.float32),
             pltpu.VMEM((hp, bq, 1), jnp.float32),
         ],
         interpret=interpret,
-        name=_name("fwd", causal),
+        name=_name("fwd", causal, d, dv),
     )(q, k, v, bias)
     return out, lse
 
 
-def _fwd_one_tile(q, k, v, bias, d, causal, interpret):
-    b, s, h, g, hp, _bq, _bk = _geometry(q, d, q.shape[1], q.shape[1],
+def _fwd_one_tile(q, k, v, bias, d, dv, causal, interpret):
+    b, s, h, g, hp, _bq, _bk = _geometry(q, d, dv, q.shape[1], q.shape[1],
                                          budget_mb=6.0)
     hpb = h // hp
     wide = pl.BlockSpec((None, s, hp * d), lambda n, p: (n, 0, p))
+    wide_v = pl.BlockSpec((None, s, hp * dv), lambda n, p: (n, 0, p))
     out, lse = pl.pallas_call(
         functools.partial(
-            _fwd_one_tile_kernel, scale=1.0 / (d ** 0.5), d=d, g=g,
+            _fwd_one_tile_kernel, scale=1.0 / (d ** 0.5), d=d, dv=dv, g=g,
             causal=causal,
         ),
         grid=(b, hpb),
         in_specs=[
-            wide, wide, wide,
+            wide, wide, wide_v,
             pl.BlockSpec((None, 1, s), lambda n, p: (n, 0, 0)),
         ],
         out_specs=[
-            wide,
+            wide_v,
             pl.BlockSpec((hp, 1, s), lambda n, p: (n * hpb + p, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(v.shape, q.dtype),
             jax.ShapeDtypeStruct((b * h, 1, s), jnp.float32),
         ],
         interpret=interpret,
-        name=_name("fwd", causal),
+        name=_name("fwd", causal, d, dv),
         # the same name in a device trace; a lowered module tells the two
         # forward forms apart by this (tools/tpu_aot.py counts them)
         metadata={"form": "one_tile"},
@@ -430,7 +445,8 @@ def _fwd_one_tile(q, k, v, bias, d, causal, interpret):
 _Head = collections.namedtuple("_Head", "p ds q k do")
 
 
-def _backward_heads(refs, bias_ref, lse_ref, h0, cols, mask, *, scale, d, g):
+def _backward_heads(refs, bias_ref, lse_ref, h0, cols, vcols, mask, *,
+                    scale, d, dv, g):
     """The g heads of one column block (heads ``h0``.., lanes ``cols`` of
     the program's tiles), one at a time: each head's probability tile ``p``
     and the gradient ``ds`` of its scores ([Bq, Bk], recomputed from the
@@ -438,7 +454,9 @@ def _backward_heads(refs, bias_ref, lse_ref, h0, cols, mask, *, scale, d, g):
     head's lanes for the products that follow. ``mask``: the causal mask of
     a tile the diagonal crosses, else None."""
     # dO stays in its native (bf16) dtype for the dots — MXU at full rate
-    q, k, v, do, o = (ref[:, cols] for ref in refs)
+    q_ref, k_ref, v_ref, do_ref, o_ref = refs
+    q, k = q_ref[:, cols], k_ref[:, cols]
+    v, do, o = v_ref[:, vcols], do_ref[:, vcols], o_ref[:, vcols]
     # delta = rowsum(dO ⊙ O) per head, as the COLUMN the math needs
     prod = do.astype(jnp.float32) * o.astype(jnp.float32)
     b = bias_ref[:].astype(jnp.float32)  # [1, Bk]
@@ -446,15 +464,15 @@ def _backward_heads(refs, bias_ref, lse_ref, h0, cols, mask, *, scale, d, g):
         k_i = _only_head(k, i, d)
         s = _masked(_dot(q, k_i, 1, 1) * scale + b, mask)
         p = jnp.exp(s - _t(lse_ref[h0 + i]))  # [1, Bq] row -> column
-        dp = _dot(do, _only_head(v, i, d), 1, 1)
-        delta = jnp.sum(_only_head(prod, i, d), axis=-1, keepdims=True)
+        dp = _dot(do, _only_head(v, i, dv), 1, 1)
+        delta = jnp.sum(_only_head(prod, i, dv), axis=-1, keepdims=True)
         ds = p * (dp - delta) * scale
         yield _Head(p.astype(do.dtype), ds.astype(q.dtype),
-                    _only_head(q, i, d), k_i, _only_head(do, i, d))
+                    _only_head(q, i, d), k_i, _only_head(do, i, dv))
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
-               dq_ref, dq_acc_ref, *, scale, d, g, causal):
+               dq_ref, dq_acc_ref, *, scale, d, dv, g, causal):
     kb = pl.program_id(3)
     nk = pl.num_programs(3)
 
@@ -463,11 +481,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
         dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
 
     def tile(mask):
-        for h0, cols in _column_blocks(q_ref.shape[-1], g, d):
+        for h0, cols, vcols in _column_blocks(q_ref.shape[-1], g, d, dv):
             dq = dq_acc_ref[:, cols]
             for head in _backward_heads(
                 (q_ref, k_ref, v_ref, do_ref, o_ref), bias_ref, lse_ref, h0,
-                cols, mask, scale=scale, d=d, g=g,
+                cols, vcols, mask, scale=scale, d=d, dv=dv, g=g,
             ):
                 dq = dq + _dot(head.ds, head.k, 1, 0)
             dq_acc_ref[:, cols] = dq
@@ -481,7 +499,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
-                dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, scale, d, g,
+                dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, scale, d, dv, g,
                 causal):
     qb = pl.program_id(3)
     nq = pl.num_programs(3)
@@ -492,16 +510,16 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
 
     def tile(mask):
-        for h0, cols in _column_blocks(q_ref.shape[-1], g, d):
-            dk, dv = dk_acc_ref[:, cols], dv_acc_ref[:, cols]
+        for h0, cols, vcols in _column_blocks(q_ref.shape[-1], g, d, dv):
+            dk, dv_ = dk_acc_ref[:, cols], dv_acc_ref[:, vcols]
             for head in _backward_heads(
                 (q_ref, k_ref, v_ref, do_ref, o_ref), bias_ref, lse_ref, h0,
-                cols, mask, scale=scale, d=d, g=g,
+                cols, vcols, mask, scale=scale, d=d, dv=dv, g=g,
             ):
-                dv = dv + _dot(head.p, head.do, 0, 0)
+                dv_ = dv_ + _dot(head.p, head.do, 0, 0)
                 dk = dk + _dot(head.ds, head.q, 0, 0)
             dk_acc_ref[:, cols] = dk
-            dv_acc_ref[:, cols] = dv
+            dv_acc_ref[:, vcols] = dv_
 
     _for_tile(causal, qb, pl.program_id(2), q_ref.shape[0], k_ref.shape[0],
               tile)
@@ -513,50 +531,54 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
 
 
 def _dqkv_fused_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref,
-                       o_ref, dq_ref, dk_ref, dv_ref, *, scale, d, g,
+                       o_ref, dq_ref, dk_ref, dv_ref, *, scale, d, dv, g,
                        causal):
     """Single-block backward: when one (Bq, Bk) tile covers the whole
     sequence, dq/dk/dv share ONE score/prob computation and one set of
     input DMAs instead of recomputing them in two kernels."""
     s = q_ref.shape[0]
     mask = _tile_mask(0, 0, s, s) if causal else None
-    for h0, cols in _column_blocks(q_ref.shape[-1], g, d):
-        dq = dk = dv = None
+    for h0, cols, vcols in _column_blocks(q_ref.shape[-1], g, d, dv):
+        dq = dk = dv_ = None
         for head in _backward_heads(
             (q_ref, k_ref, v_ref, do_ref, o_ref), bias_ref, lse_ref, h0,
-            cols, mask, scale=scale, d=d, g=g,
+            cols, vcols, mask, scale=scale, d=d, dv=dv, g=g,
         ):
-            dv = _add(dv, _dot(head.p, head.do, 0, 0))
+            dv_ = _add(dv_, _dot(head.p, head.do, 0, 0))
             dq = _add(dq, _dot(head.ds, head.k, 1, 0))
             dk = _add(dk, _dot(head.ds, head.q, 0, 0))
         dq_ref[:, cols] = dq.astype(dq_ref.dtype)
         dk_ref[:, cols] = dk.astype(dk_ref.dtype)
-        dv_ref[:, cols] = dv.astype(dv_ref.dtype)
+        dv_ref[:, vcols] = dv_.astype(dv_ref.dtype)
 
 
-def _bwd(q, k, v, bias, lse, do, out, d, block_q, block_k, causal,
+def _bwd(q, k, v, bias, lse, do, out, d, dv, block_q, block_k, causal,
          interpret):
     if _one_tile(q.shape[1], block_q, block_k):
-        return _bwd_fused(q, k, v, bias, lse, do, out, d, causal, interpret)
+        return _bwd_fused(q, k, v, bias, lse, do, out, d, dv, causal,
+                          interpret)
     # bwd transients per head are ~3x the fwd's (s, p, dp, ds live at once)
-    b, s, h, g, hp, bq, bk = _geometry(q, d, block_q, block_k, budget_mb=4.0)
+    b, s, h, g, hp, bq, bk = _geometry(q, d, dv, block_q, block_k,
+                                       budget_mb=4.0)
     hpb = h // hp
-    kernel_args = dict(scale=1.0 / (d ** 0.5), d=d, g=g, causal=causal)
+    kernel_args = dict(scale=1.0 / (d ** 0.5), d=d, dv=dv, g=g,
+                       causal=causal)
 
     # grid (B, programs across the width, outer S block, inner S block):
-    # each spec says which of the two S positions it follows
-    def wide(rows, at):
-        return pl.BlockSpec((None, rows, hp * d),
+    # each spec says which of the two S positions it follows, and which of
+    # the two widths (q, k and their gradients; v, out, dO and dv)
+    def wide(rows, at, width=d):
+        return pl.BlockSpec((None, rows, hp * width),
                             lambda n, p, x, y: (n, at(x, y), p))
 
     def in_specs(q_at, k_at):  # q, k, v, bias, lse, dO, O
         return [
-            wide(bq, q_at), wide(bk, k_at), wide(bk, k_at),
+            wide(bq, q_at), wide(bk, k_at), wide(bk, k_at, dv),
             pl.BlockSpec((None, 1, bk),
                          lambda n, p, x, y: (n, 0, k_at(x, y))),
             pl.BlockSpec((hp, 1, bq),
                          lambda n, p, x, y: (n * hpb + p, 0, q_at(x, y))),
-            wide(bq, q_at), wide(bq, q_at),
+            wide(bq, q_at, dv), wide(bq, q_at, dv),
         ]
 
     def outer(x, y):
@@ -578,86 +600,89 @@ def _bwd(q, k, v, bias, lse, do, out, d, block_q, block_k, causal,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, hp * d), jnp.float32)],
         interpret=interpret,
-        name=_name("bwd_dq", causal),
+        name=_name("bwd_dq", causal, d, dv),
     )(q, k, v, bias, lse, do, out)
 
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, **kernel_args),
         grid=(b, hpb, s // bk, s // bq),
         in_specs=in_specs(q_at=inner_q, k_at=outer),
-        out_specs=[wide(bk, outer), wide(bk, outer)],
+        out_specs=[wide(bk, outer), wide(bk, outer, dv)],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, hp * d), jnp.float32),
-            pltpu.VMEM((bk, hp * d), jnp.float32),
+            pltpu.VMEM((bk, hp * dv), jnp.float32),
         ],
         interpret=interpret,
-        name=_name("bwd_dkv", causal),
+        name=_name("bwd_dkv", causal, d, dv),
     )(q, k, v, bias, lse, do, out)
     return dq, dk, dv
 
 
-def _bwd_fused(q, k, v, bias, lse, do, out, d, causal, interpret):
+def _bwd_fused(q, k, v, bias, lse, do, out, d, dv, causal, interpret):
     # fused kernel holds s, p, dp, ds (~4 full tiles) at once per head
-    b, s, h, g, hp, _bq, _bk = _geometry(q, d, q.shape[1], q.shape[1],
+    b, s, h, g, hp, _bq, _bk = _geometry(q, d, dv, q.shape[1], q.shape[1],
                                          budget_mb=3.0)
     hpb = h // hp
     wide = pl.BlockSpec((None, s, hp * d), lambda n, p: (n, 0, p))
-    dq, dk, dv = pl.pallas_call(
+    wide_v = pl.BlockSpec((None, s, hp * dv), lambda n, p: (n, 0, p))
+    dq, dk, dv_ = pl.pallas_call(
         functools.partial(
-            _dqkv_fused_kernel, scale=1.0 / (d ** 0.5), d=d, g=g,
+            _dqkv_fused_kernel, scale=1.0 / (d ** 0.5), d=d, dv=dv, g=g,
             causal=causal,
         ),
         grid=(b, hpb),
         in_specs=[
-            wide, wide, wide,
+            wide, wide, wide_v,
             pl.BlockSpec((None, 1, s), lambda n, p: (n, 0, 0)),
             pl.BlockSpec((hp, 1, s), lambda n, p: (n * hpb + p, 0, 0)),
-            wide, wide,
+            wide_v, wide_v,
         ],
-        out_specs=[wide, wide, wide],
+        out_specs=[wide, wide, wide_v],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=interpret,
-        name=_name("bwd_fused", causal),
+        name=_name("bwd_fused", causal, d, dv),
     )(q, k, v, bias, lse, do, out)
-    return dq, dk, dv
+    return dq, dk, dv_
 
 
 # --------------------------------------------------------------- public op
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash(q, k, v, bias, d, block_q, block_k, causal, interpret):
-    out, _lse = _fwd(q, k, v, bias, d, block_q, block_k, causal, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, bias, d, dv, block_q, block_k, causal, interpret):
+    out, _lse = _fwd(q, k, v, bias, d, dv, block_q, block_k, causal,
+                     interpret)
     return out
 
 
-def _flash_fwd(q, k, v, bias, d, block_q, block_k, causal, interpret):
+def _flash_fwd(q, k, v, bias, d, dv, block_q, block_k, causal, interpret):
     # ``out`` is what the remat policies save per layer (a Pallas output),
     # in the layout the out-projection reads: no lane padding at any D
-    out, lse = _fwd(q, k, v, bias, d, block_q, block_k, causal, interpret)
+    out, lse = _fwd(q, k, v, bias, d, dv, block_q, block_k, causal,
+                    interpret)
     return out, (q, k, v, bias, out, lse)
 
 
-def _flash_bwd(d, block_q, block_k, causal, interpret, residuals, g):
+def _flash_bwd(d, dv, block_q, block_k, causal, interpret, residuals, g):
     q, k, v, bias, out, lse = residuals
-    dq, dk, dv = _bwd(q, k, v, bias, lse, g, out, d, block_q, block_k,
-                      causal, interpret)
+    dq, dk, dv_ = _bwd(q, k, v, bias, lse, g, out, d, dv, block_q, block_k,
+                       causal, interpret)
     # the mask bias is non-differentiable input
-    return dq, dk, dv, jnp.zeros_like(bias)
+    return dq, dk, dv_, jnp.zeros_like(bias)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _flash_local(q, k, v, bias, d, block_q, block_k, causal, interpret):
+def _flash_local(q, k, v, bias, d, dv, block_q, block_k, causal, interpret):
     """The op on [B, S, H·D] operands as ONE device sees them (the whole
     arrays off-mesh, this device's batch/head shard under shard_map)."""
     # named as the dense layers give them, which is what the kernels read:
@@ -665,13 +690,13 @@ def _flash_local(q, k, v, bias, d, block_q, block_k, causal, interpret):
     # consumes, in the one layout the stash and both kernels share
     q, k, v = (checkpoint_name(x, "flash_qkv") for x in (q, k, v))
     bias = bias[:, None, :].astype(jnp.float32)  # [B, 1, S] row per sample
-    return _flash(q, k, v, bias, d, block_q, block_k, causal, interpret)
+    return _flash(q, k, v, bias, d, dv, block_q, block_k, causal, interpret)
 
 
 def flash_attention(
     q: jnp.ndarray,  # [B, S, H, D]
     k: jnp.ndarray,
-    v: jnp.ndarray,
+    v: jnp.ndarray,  # [B, S, H, Dv]: Dv = D, or narrower (latent attention)
     bias: Optional[jnp.ndarray] = None,  # [B, S_kv] additive
     block_q: int = 512,
     block_k: int = 512,
@@ -688,16 +713,21 @@ def flash_attention(
     Mosaic-legal. ``mesh``: the device mesh the caller's jit spans — the op
     then runs per shard under ``shard_map`` (see module docstring).
     ``causal``: a decoder's mask, inside the kernels (query i sees keys
-    0..i; the KV bias still applies on top).
+    0..i; the KV bias still applies on top). ``v`` may have a head width of
+    its own (latent attention: q and k 192 wide, v and the result 128): the
+    same kernels with two column-block widths, scores scaled by
+    1/sqrt(q's width), named ``flash_mla_*`` in a device trace; nothing is
+    padded.
     """
     if interpret is None:
         interpret = pallas_interpret()
     b, s, h, d = q.shape
+    dv = v.shape[-1]
     if bias is None:
         bias = jnp.zeros((b, s), jnp.float32)
     op = functools.partial(
-        _flash_local, d=d, block_q=block_q, block_k=block_k, causal=causal,
-        interpret=interpret,
+        _flash_local, d=d, dv=dv, block_q=block_q, block_k=block_k,
+        causal=causal, interpret=interpret,
     )
     if mesh is not None:
         # heads over "model": a shard's columns are its (H/tp)·D
@@ -712,6 +742,6 @@ def flash_attention(
     # own layout goes in and comes out, nothing is transposed
     heads_flat = (b, s, h * d)
     return op(
-        q.reshape(heads_flat), k.reshape(heads_flat), v.reshape(heads_flat),
-        bias,
-    ).reshape(q.shape)
+        q.reshape(heads_flat), k.reshape(heads_flat),
+        v.reshape(b, s, h * dv), bias,
+    ).reshape(v.shape)

@@ -107,11 +107,22 @@ class FlatLamb:
         clamp_value: float = 10000.0,
         debias: bool = True,
         max_grad_norm: Optional[float] = None,
+        sign_flags: Optional[Sequence[bool]] = None,
+        sign_step: float = 0.001,
     ) -> None:
         self.spans = spec_spans(spec)
         self.total = sum(s for _o, s in self.spans)
         self.decay_flags = np.asarray(list(decay_flags), np.float32)
         assert len(self.decay_flags) == len(self.spans)
+        # spans stepped by the sign of their gradient instead
+        # (``optim.lamb.sign_stepped``, the per-leaf twin); none: this
+        # class's program is what it was
+        self.sign_flags = np.asarray(
+            list(sign_flags) if sign_flags is not None
+            else [False] * len(self.spans), bool,
+        )
+        assert len(self.sign_flags) == len(self.spans)
+        self.sign_step = float(sign_step)
         self.learning_rate = learning_rate
         self.b1, self.b2, self.eps = b1, b2, eps
         self.weight_decay = float(weight_decay)
@@ -138,6 +149,12 @@ class FlatLamb:
         ``flat_updates`` is the DELTA to add to the params (lr folded in,
         descent-negated — optax ``apply_updates`` convention)."""
         g = flat_grads
+        signed = None
+        if self.sign_flags.any():
+            signed = expand_segments(
+                jnp.asarray(self.sign_flags), self.spans, self.total
+            )
+            g = jnp.where(signed, 0.0, g)  # out of the clip's norm too
         if self.max_grad_norm is not None:
             # optax.clip_by_global_norm semantics on the flat buffer: the
             # global norm IS the one vdot
@@ -161,7 +178,12 @@ class FlatLamb:
         ratio = trust_ratio_scale(w_norm, u_norm, self.clamp_value)
         trusted = adam_step * expand_segments(ratio, self.spans, self.total)
         lr = self._lr(sched_count)
-        return -lr * trusted, mu, nu, count
+        updates = -lr * trusted
+        if signed is not None:
+            updates = jnp.where(
+                signed, -self.sign_step * jnp.sign(flat_grads), updates
+            )
+        return updates, mu, nu, count
 
 
 class FlatLars:

@@ -133,11 +133,17 @@ def lamb(
     debias: bool = True,
     weight_decay_mask: Optional[Callable] = albert_weight_decay_mask,
     max_grad_norm: Optional[float] = None,
+    sign_step_mask: Optional[Callable] = None,
+    sign_step: float = 0.001,
 ) -> optax.GradientTransformation:
     """Full LAMB chain: [clip] -> moments+decay -> trust ratio -> lr.
 
     Weight decay is added to the adam update BEFORE the trust ratio (the
     torch_optimizer.Lamb formulation the reference trains with).
+
+    ``sign_step_mask`` (params -> tree of bools, from the model table as
+    ``weight_decay_mask`` is): the leaves it marks are not LAMB's — see
+    ``sign_stepped``.
     """
     # Decay must enter before the trust-ratio scaling, so we fold it into the
     # update inside a custom wrapper around the shared scale_by_lamb math.
@@ -181,4 +187,33 @@ def lamb(
     chain.append(
         optax.scale_by_learning_rate(learning_rate)  # negates for descent
     )
-    return optax.chain(*chain)
+    if sign_step_mask is None:
+        return optax.chain(*chain)
+    return sign_stepped(optax.chain(*chain), sign_step_mask, sign_step)
+
+
+def sign_stepped(
+    inner: optax.GradientTransformation, mask: Callable, step: float,
+) -> optax.GradientTransformation:
+    """``inner`` for every leaf but those ``mask(params)`` marks: a marked
+    leaf moves by ``-step · sign(g)`` — no moments, trust ratio, decay or
+    learning-rate schedule — and ``inner`` sees zeros in its place, so it
+    adds nothing to a global-norm clip. (The leaf's "gradient" is a
+    statistic that rides the gradient's paths: an expert layer's load
+    excess, stepped as DeepSeek-V3's bias rule steps it.) The state is
+    ``inner``'s own."""
+
+    def update_fn(updates, state, params):
+        marked = mask(params)
+        inner_updates, state = inner.update(
+            jax.tree.map(
+                lambda g, m: jnp.zeros_like(g) if m else g, updates, marked
+            ),
+            state, params,
+        )
+        return jax.tree.map(
+            lambda u, g, m: (-step * jnp.sign(g)).astype(u.dtype) if m else u,
+            inner_updates, updates, marked,
+        ), state
+
+    return optax.GradientTransformation(inner.init, update_fn)

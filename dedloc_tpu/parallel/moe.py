@@ -1,14 +1,13 @@
-"""Expert parallelism: Switch-style mixture-of-experts FFN, TPU-native.
+"""Sparse expert layers: the Switch top-1 FFN (ALBERT's option) and the
+dropless top-k routed layer of a fine-grained expert decoder.
 
-The reference repo has no MoE (SURVEY.md §2.5: EP "out of scope" — though
-hivemind, the library it builds on, began life as a decentralized
-mixture-of-experts system). This module supplies the EP axis the TPU
-framework would use for sparse scaling: experts shard over a mesh axis and
-the token shuffle lowers to XLA all-to-alls, in the classic GShard/Switch
+**Switch top-1** (``moe_ffn``; the reference repo has no MoE, SURVEY.md
+§2.5). ALBERT's OPTIONAL sparse FFN (``--training.moe_experts``), run at toy
+widths on a CPU mesh only: experts shard over a mesh axis and the token
+shuffle lowers to XLA all-to-alls, in the classic GShard/Switch
 dispatch-einsum formulation — no hand-written collectives, the sharding
-annotations alone place the communication on ICI.
+annotations alone place the communication.
 
-Design (top-1 / Switch routing, jit-exact and static-shaped):
 - router logits -> softmax gate, top-1 expert per token;
 - capacity C = ceil(T / E · capacity_factor): each expert processes at most
   C tokens per batch, tokens beyond capacity fall through on the residual
@@ -18,15 +17,41 @@ Design (top-1 / Switch routing, jit-exact and static-shaped):
   sharded over data, XLA inserts the all-to-alls;
 - auxiliary load-balancing loss (mean gate · mean assignment per expert,
   scaled by E) exactly as in Switch, returned for the trainer to add.
+
+**Dropless top-k** (``route_top_k``, ``routed_experts``; DeepSeek-V3's
+``noaux_tc``, the layer ``models/deepseek_v3.py`` runs). Scores are sigmoids
+over ALL experts; a correction bias enters the CHOICE of the top k and not
+their weights; the weights are the chosen scores renormalised and scaled.
+The layer is TOLD which experts it holds (``held = (first, count)``: one
+chip's share of an expert-parallel deployment): it routes over all of them
+and computes its own part — slots that chose an absent expert contribute
+nothing (on the chips of a deployment their rows would leave over ICI;
+nothing here stands in for those chips). No capacity, no dropped slot, no
+``[T, E, C]`` mask: the (token, slot) pairs that chose a held expert are
+sorted by expert into row tiles of ONE expert each (a group is padded to
+whole tiles), and a loop over the tiles IN USE — a dynamic trip count, so
+the work follows the rows that came, not the worst case the static shapes
+must allow — gathers a tile's rows, runs its expert's SwiGLU and adds the
+weighted result back to its tokens. The backward is the same loop written
+by hand (a dynamic trip count has no reverse-mode rule): it replays the
+tile's forward and accumulates the held experts' gradients in place.
+
+The bias is not trained by a gradient. ``with_load_cotangent`` defines the
+bias leaf's COTANGENT as ``load_e − mean load`` of the micro-batch (``load``:
+each expert's share of the routed (token, slot) pairs), so the statistic
+rides every path a gradient rides — the accumulator, the mean over peers,
+the wire, the flat layout — and ``optim`` steps such leaves by its sign.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -133,3 +158,226 @@ def moe_ffn(
         "tec,ech->th", combine.astype(cfg.dtype), expert_out
     )
     return y.astype(x.dtype), aux_loss
+
+
+# ------------------------------------------------- dropless top-k routing
+
+
+def route_top_k(scores, bias, top_k: int, scale: float):
+    """(choice [T, k] int32, weights [T, k] float32) from sigmoid scores
+    [T, E] (float32) and the correction bias [E]: the bias enters the choice
+    alone; the weights are the chosen scores over their sum, times
+    ``scale``."""
+    _, choice = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+    picked = jnp.take_along_axis(scores, choice, axis=-1)
+    weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    return choice, weights * scale
+
+
+def expert_load(choice, num_experts: int):
+    """[E] float32: each expert's share of the routed (token, slot) pairs."""
+    counts = jnp.zeros((num_experts,), jnp.float32).at[
+        choice.reshape(-1)
+    ].add(1.0)
+    return counts / choice.size
+
+
+@jax.custom_vjp
+def with_load_cotangent(x, bias, load):
+    """``x``, unchanged. In the backward ``bias`` receives ``load − mean
+    load`` whatever reaches ``x``: the load statistic is accumulated, averaged
+    over peers and shipped as the bias leaf's gradient would be (the module
+    docstring says why)."""
+    return x
+
+
+def _load_fwd(x, bias, load):
+    return x, load
+
+
+def _load_bwd(load, g):
+    return g, load - jnp.mean(load), jnp.zeros_like(load)
+
+
+with_load_cotangent.defvjp(_load_fwd, _load_bwd)
+
+
+def _tile_plan(choice, held: Tuple[int, int], tile: int):
+    """Where every (token, slot) pair that chose a held expert goes: rows
+    sorted by expert, each expert's group padded to whole tiles.
+
+    Returns ``row_slot`` [R] (the flat slot index t·k + j of each row, -1
+    for padding; R = the static worst case), ``tile_expert`` [R / tile]
+    (the LOCAL expert of each tile), ``tiles`` (how many are in use) and
+    ``dropped`` (valid pairs that found no row: 0 by construction, counted
+    so that a run can say so)."""
+    first, count = held
+    tokens, k = choice.shape
+    local = choice.reshape(-1) - first
+    valid = (local >= 0) & (local < count)
+    key = jnp.where(valid, local, count)  # absent experts sort last
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+    padded = (sizes + tile - 1) // tile * tile
+    ends, padded_ends = jnp.cumsum(sizes), jnp.cumsum(padded)
+    # a token chooses an expert once: at most tokens · min(k, count) rows
+    rows = tokens * min(k, count) + count * tile
+    rows = (rows + tile - 1) // tile * tile
+    sorted_key = key[order]
+    group = jnp.minimum(sorted_key, count - 1)
+    rank = jnp.arange(tokens * k, dtype=jnp.int32) - (ends - sizes)[group]
+    position = jnp.where(
+        sorted_key < count, (padded_ends - padded)[group] + rank, rows
+    )
+    row_slot = jnp.full((rows,), -1, jnp.int32).at[position].set(
+        order, mode="drop"
+    )
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(
+            padded_ends, jnp.arange(rows // tile, dtype=jnp.int32) * tile,
+            side="right",
+        ),
+        count - 1,
+    ).astype(jnp.int32)
+    tiles = padded_ends[-1] // tile
+    dropped = jnp.sum(valid) - jnp.sum(row_slot >= 0)
+    return row_slot, tile_expert, tiles, dropped
+
+
+def _tile_forward(x, gate, up, down, tokens):
+    """One tile through its expert: (rows, gate·x, up·x, hidden, out)."""
+    rows = x.at[tokens].get(mode="promise_in_bounds")
+    g = jnp.dot(rows, gate, preferred_element_type=jnp.float32)
+    u = jnp.dot(rows, up, preferred_element_type=jnp.float32)
+    hidden = (jax.nn.silu(g) * u).astype(x.dtype)
+    out = jnp.dot(hidden, down, preferred_element_type=jnp.float32)
+    return rows, g, u, hidden, out
+
+
+def _tile_operands(t, tile, row_token, row_weight, tile_expert, weights):
+    tokens = jax.lax.dynamic_slice(row_token, (t * tile,), (tile,))
+    scale = jax.lax.dynamic_slice(row_weight, (t * tile,), (tile,))
+    expert = tile_expert[t]
+    return tokens, scale, tuple(
+        jax.lax.dynamic_index_in_dim(w, expert, keepdims=False)
+        for w in weights
+    ), expert
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+def _grouped_swiglu(x, row_weight, gate, up, down, row_token, tile_expert,
+                    tiles, tile):
+    out, _ = _grouped_swiglu_fwd(
+        x, row_weight, gate, up, down, row_token, tile_expert, tiles, tile
+    )
+    return out
+
+
+def _grouped_swiglu_fwd(x, row_weight, gate, up, down, row_token,
+                        tile_expert, tiles, tile):
+    def body(t, total):
+        tokens, scale, weights, _e = _tile_operands(
+            t, tile, row_token, row_weight, tile_expert, (gate, up, down)
+        )
+        out = _tile_forward(x, *weights, tokens)[-1]
+        return total.at[tokens].add(out * scale[:, None])
+
+    with jax.named_scope("moe_routed"):
+        total = jax.lax.fori_loop(
+            0, tiles, body, jnp.zeros(x.shape, jnp.float32)
+        )
+    return total, (x, row_weight, gate, up, down, row_token, tile_expert,
+                   tiles)
+
+
+def _grouped_swiglu_bwd(tile, residuals, d_total):
+    x, row_weight, gate, up, down, row_token, tile_expert, tiles = residuals
+
+    def body(t, carry):
+        dx, d_weight, d_gate, d_up, d_down = carry
+        tokens, scale, (w_gate, w_up, w_down), expert = _tile_operands(
+            t, tile, row_token, row_weight, tile_expert, (gate, up, down)
+        )
+        rows, g, u, hidden, out = _tile_forward(
+            x, w_gate, w_up, w_down, tokens
+        )
+        d_scaled = d_total.at[tokens].get(mode="promise_in_bounds")
+        d_weight = jax.lax.dynamic_update_slice(
+            d_weight, jnp.sum(d_scaled * out, axis=-1), (t * tile,)
+        )
+        d_out = (d_scaled * scale[:, None]).astype(x.dtype)
+        d_hidden = jax.lax.dot_general(
+            d_out, w_down, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        sig = jax.nn.sigmoid(g)
+        d_u = (d_hidden * g * sig).astype(x.dtype)
+        d_g = (d_hidden * u * sig * (1.0 + g * (1.0 - sig))).astype(x.dtype)
+        d_rows = jax.lax.dot_general(
+            d_g, w_gate, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) + jax.lax.dot_general(
+            d_u, w_up, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+        def add(acc, lhs, rhs):  # acc[expert] += lhsᵀ rhs, in place
+            term = jax.lax.dot_general(
+                lhs, rhs, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            old = jax.lax.dynamic_index_in_dim(acc, expert, keepdims=True)
+            return jax.lax.dynamic_update_index_in_dim(
+                acc, old + term[None], expert, 0
+            )
+
+        return (
+            dx.at[tokens].add(d_rows), d_weight, add(d_gate, rows, d_g),
+            add(d_up, rows, d_u), add(d_down, hidden, d_out),
+        )
+
+    with jax.named_scope("moe_routed"):
+        dx, d_weight, d_gate, d_up, d_down = jax.lax.fori_loop(
+            0, tiles, body, (
+                jnp.zeros(x.shape, jnp.float32),
+                jnp.zeros(row_weight.shape, jnp.float32),
+                jnp.zeros(gate.shape, jnp.float32),
+                jnp.zeros(up.shape, jnp.float32),
+                jnp.zeros(down.shape, jnp.float32),
+            ),
+        )
+    int_zero = lambda a: np.zeros(a.shape, jax.dtypes.float0)  # noqa: E731
+    return (
+        dx.astype(x.dtype), d_weight, d_gate.astype(gate.dtype),
+        d_up.astype(up.dtype), d_down.astype(down.dtype),
+        int_zero(row_token), int_zero(tile_expert), int_zero(tiles),
+    )
+
+
+_grouped_swiglu.defvjp(_grouped_swiglu_fwd, _grouped_swiglu_bwd)
+
+
+def routed_experts(x, choice, weights, gate, up, down,
+                   held: Tuple[int, int], tile: int = 256):
+    """The held experts' part of Σ_{e in choice} w_e · SwiGLU_e(x).
+
+    ``x`` [T, H] in the compute dtype; ``choice`` / ``weights`` [T, k] from
+    ``route_top_k``; ``gate`` / ``up`` [n, H, F] and ``down`` [n, F, H] the
+    HELD experts' matrices in the compute dtype, expert ``held[0] + i`` at
+    index i. Returns (y [T, H] float32, stats): ``stats['local_slot_share']``
+    the share of routed slots that chose a held expert,
+    ``stats['dropped_slots']`` the valid slots the plan lost (0)."""
+    tile = min(tile, max(8, x.shape[0]))
+    with jax.named_scope("moe_routed"):
+        row_slot, tile_expert, tiles, dropped = _tile_plan(choice, held, tile)
+        real = row_slot >= 0
+        slot = jnp.maximum(row_slot, 0)
+        row_token = slot // choice.shape[1]
+        row_weight = jnp.where(real, weights.reshape(-1)[slot], 0.0)
+    y = _grouped_swiglu(
+        x, row_weight, gate, up, down, row_token, tile_expert, tiles, tile
+    )
+    return y, {
+        "local_slot_share": jnp.sum(real) / choice.size,
+        "dropped_slots": dropped.astype(jnp.float32),
+    }

@@ -22,6 +22,14 @@ from dedloc_tpu.models.albert import (
     albert_pretraining_loss,
     albert_pretraining_loss_gathered,
 )
+from dedloc_tpu.models.deepseek_v3 import (
+    DeepseekV3Config,
+    DeepseekV3ForCausalLM,
+    deepseek_v3_loss,
+    deepseek_v3_sign_step_mask,
+    deepseek_v3_train_tflops_per_sample,
+    deepseek_v3_weight_decay_mask,
+)
 from dedloc_tpu.models.ouro import (
     OuroConfig,
     OuroForCausalLM,
@@ -54,9 +62,17 @@ class ModelFamily:
     tflops_per_sample: Callable
     # params -> tree of bools, True where LAMB's weight decay applies
     weight_decay_mask: Callable
-    # metrics of the loss that are vectors over the passes: summed on the
-    # device with the loss and read with it, once per global step
+    # metrics of the loss that become gauges: summed on the device with the
+    # loss and read with it, once per global step, as means over the step's
+    # micro-batches — a vector (over passes, over expert layers) as
+    # ``name.1`` .. ``name.n``, a scalar as ``name``
     step_gauges: Tuple[str, ...] = ()
+    # metrics of the loss that are counts: the step's total, into a counter
+    step_counters: Tuple[str, ...] = ()
+    # params -> tree of bools, True for leaves stepped by the sign of their
+    # cotangent (``optim.lamb.sign_stepped``) at ``sign_step``; None: none
+    sign_step_mask: Optional[Callable] = None
+    sign_step: float = 0.0
 
 
 def _albert_loss(model: AlbertForPreTraining) -> Callable:
@@ -118,14 +134,13 @@ def _albert_tflops(cfg: AlbertConfig, seq: int) -> float:
     return albert_tflops_per_sample(cfg, seq, max_predictions_for(seq))
 
 
-def _ouro_loss(model: OuroForCausalLM) -> Callable:
-    def loss_fn(params, batch, rng):
-        return ouro_loss(model, params, batch)
+def _without_rng(loss: Callable) -> Callable:
+    """A (model, params, batch) loss in the table's form: module ->
+    loss_fn(params, batch, rng)."""
+    return lambda model: lambda params, batch, rng: loss(model, params, batch)
 
-    return loss_fn
 
-
-def _ouro_batches(cfg: OuroConfig, batch_size: int, seq_length: int,
+def _ouro_batches(cfg, batch_size: int, seq_length: int,
                   seed: int) -> Iterator[Dict[str, np.ndarray]]:
     from dedloc_tpu.data.causal_lm import synthetic_causal_lm_batches
 
@@ -142,14 +157,30 @@ ALBERT = ModelFamily(
     weight_decay_mask=albert_weight_decay_mask,
 )
 OURO = ModelFamily(
-    config=OuroConfig, module=OuroForCausalLM, loss=_ouro_loss,
+    config=OuroConfig, module=OuroForCausalLM,
+    loss=_without_rng(ouro_loss),
     synthetic_batches=_ouro_batches,
     tflops_per_sample=ouro_train_tflops_per_sample,
     weight_decay_mask=ouro_weight_decay_mask,
     step_gauges=("lm.exit_prob", "lm.loss"),
 )
+DEEPSEEK_V3 = ModelFamily(
+    config=DeepseekV3Config, module=DeepseekV3ForCausalLM,
+    loss=_without_rng(deepseek_v3_loss),
+    # ids over the held slice of the vocabulary (``cfg.vocab_size`` rows)
+    synthetic_batches=_ouro_batches,
+    tflops_per_sample=deepseek_v3_train_tflops_per_sample,
+    weight_decay_mask=deepseek_v3_weight_decay_mask,
+    step_gauges=(
+        "moe.load_max_over_mean", "moe.local_slot_share", "moe.bias_abs_max",
+    ),
+    step_counters=("moe.dropped_slots",),
+    sign_step_mask=deepseek_v3_sign_step_mask,
+    sign_step=DeepseekV3Config.bias_update_speed,
+)
 MODEL_FAMILIES: Dict[str, ModelFamily] = {
     "tiny": ALBERT, "large": ALBERT, "ouro_tiny": OURO, "ouro_2p6b": OURO,
+    "kanana2_tiny": DEEPSEEK_V3, "kanana2_30b_a3b": DEEPSEEK_V3,
 }
 
 
@@ -164,7 +195,7 @@ def model_family(model) -> ModelFamily:
             )
         return MODEL_FAMILIES[model]
     cfg = getattr(model, "cfg", model)
-    for family in (ALBERT, OURO):
+    for family in (ALBERT, OURO, DEEPSEEK_V3):
         if isinstance(cfg, family.config):
             return family
     raise TypeError(f"no model family for {type(cfg).__name__}")
@@ -183,6 +214,7 @@ def build_model(
     moe_capacity_factor: float = 0.0,
     moe_aux_weight: float = -1.0,
     num_hidden_layers: int = 0,
+    expert_shard: str = "0/1",
 ):
     """(config, module) of a ``--training.model_size`` name, with the
     trainer's overrides. No WIDTH is an override: the depth is the one size
@@ -199,6 +231,14 @@ def build_model(
         overrides["mesh"] = mesh
     if num_hidden_layers:
         overrides["num_hidden_layers"] = num_hidden_layers
+    if expert_shard != "0/1":
+        if "expert_shard" not in family.config.__dataclass_fields__:
+            raise ValueError(
+                f"model_size {model_size!r} has no routed expert layer to "
+                f"hold a share of (--training.expert_shard {expert_shard})"
+            )
+        index, _, count = expert_shard.partition("/")
+        overrides["expert_shard"] = (int(index), int(count))
     if family is ALBERT:
         if remat_policy:
             from dedloc_tpu.models.albert import fused_ln_for_policy
@@ -232,14 +272,15 @@ def build_optimizer(args: CollaborationArguments):
         warmup_steps=args.training.warmup_steps,
         total_steps=args.training.total_steps,
     )
+    family = model_family(args.training.model_size)
     return lamb(
         learning_rate=schedule,
         weight_decay=args.training.weight_decay,
         clamp_value=args.training.clamp_value,
         max_grad_norm=args.training.max_grad_norm,
-        weight_decay_mask=model_family(
-            args.training.model_size
-        ).weight_decay_mask,
+        weight_decay_mask=family.weight_decay_mask,
+        sign_step_mask=family.sign_step_mask,
+        sign_step=family.sign_step,
     )
 
 
@@ -258,16 +299,20 @@ def build_flat_opt_factory(args: CollaborationArguments):
     def factory(spec, params):
         from dedloc_tpu.optim.flat import FlatLamb, tree_flags
 
-        mask = model_family(args.training.model_size).weight_decay_mask
-        flags = tree_flags(
-            mask(params), params,
-            [name for name, _shape, _dtype in spec],
-        )
+        family = model_family(args.training.model_size)
+        names = [name for name, _shape, _dtype in spec]
+        signed = family.sign_step_mask
         return FlatLamb(
-            spec, flags, schedule,
+            spec, tree_flags(family.weight_decay_mask(params), params, names),
+            schedule,
             weight_decay=args.training.weight_decay,
             clamp_value=args.training.clamp_value,
             max_grad_norm=args.training.max_grad_norm,
+            sign_flags=(
+                tree_flags(signed(params), params, names)
+                if signed is not None else None
+            ),
+            sign_step=family.sign_step,
         )
 
     return factory
@@ -367,8 +412,8 @@ def checkpoint_kwargs(args, public_key: bytes) -> Dict:
 def build_collaborative_optimizer(
     args, tx, dht, public_key: bytes, *, batch_size_per_step: int,
     flat_opt_factory: Callable, mesh=None, opt_state_sharding=None,
-    param_sharding=None, post_apply=None, authorizer=None,
-    authority_public_key=None,
+    param_sharding=None, post_apply=None, sign_step_mask=None,
+    authorizer=None, authority_public_key=None,
 ) -> CollaborativeOptimizer:
     """THE wiring of ``--dht.*`` / ``--averager.*`` / ``--optimizer.*`` /
     ``--checkpoint.*`` into a training peer's ``CollaborativeOptimizer``,
@@ -433,6 +478,7 @@ def build_collaborative_optimizer(
         opt_state_sharding=opt_state_sharding,
         param_sharding=param_sharding,
         post_apply=post_apply,
+        sign_step_mask=sign_step_mask,
         authorizer=authorizer,
         authority_public_key=authority_public_key,
         verbose=True,

@@ -47,9 +47,12 @@ class LoopModel:
     # host batch -> device batch, timed as ``h2d`` (None: the micro-step
     # takes the drawn batch as it is)
     put: Optional[Callable] = None
-    # metrics beside the loss that are vectors over the passes: summed on
-    # the device with the loss and read with it, once per global step
+    # metrics beside the loss that become gauges: summed on the device
+    # with the loss and read with it, once per global step (a vector, over
+    # passes or expert layers, as ``name.1`` .. ``name.n``; a scalar as
+    # ``name``); and those that are counts, the step's total into a counter
     step_gauges: Tuple[str, ...] = ()
+    step_counters: Tuple[str, ...] = ()
     # analytic model TFLOPs of one fwd+bwd sample, for the MFU gauge
     # (0: no gauge)
     tflops_per_sample: float = 0.0
@@ -72,7 +75,7 @@ def run_boundary_loop(
     # GLOBAL step, right where the value is published — and with it, in the
     # same read, the model's per-pass gauges (a looped model's exit
     # distribution and per-pass loss)
-    summed = ("loss",) + model.step_gauges
+    summed = ("loss",) + model.step_gauges + model.step_counters
     sums_dev: dict = {}
     mini_steps = 0
     boundary = 0
@@ -142,12 +145,21 @@ def run_boundary_loop(
                         for name in model.step_gauges:
                             # means over the global step's tokens, onto the
                             # step record and (telemetry on) into gauges
-                            for p, value in enumerate(
-                                sums[name] / max(mini_steps, 1), start=1
-                            ):
-                                srec.attrs[f"{name}.{p}"] = float(value)
+                            mean = sums[name] / max(mini_steps, 1)
+                            values = (
+                                {name: mean} if mean.ndim == 0 else {
+                                    f"{name}.{p}": v
+                                    for p, v in enumerate(mean, start=1)
+                                }
+                            )
+                            for key, value in values.items():
+                                srec.attrs[key] = float(value)
                                 if tele is not None:
-                                    tele.gauge(f"{name}.{p}").set(float(value))  # dedlint: emits=gauge:lm.exit_prob.*,gauge:lm.loss.*
+                                    tele.gauge(key).set(float(value))  # dedlint: emits=gauge:lm.exit_prob.*,gauge:lm.loss.*,gauge:moe.load_max_over_mean.*,gauge:moe.local_slot_share,gauge:moe.bias_abs_max
+                        for name in model.step_counters:
+                            srec.attrs[name] = float(sums[name])
+                            if tele is not None:
+                                tele.counter(name).inc(float(sums[name]))  # dedlint: emits=counter:moe.dropped_slots
                         # advertise the loss for the trunk-health gate —
                         # free here, the scalar is already on the host
                         opt.report_loss(loss)

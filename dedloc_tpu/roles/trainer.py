@@ -151,6 +151,7 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
         moe_capacity_factor=args.training.moe_capacity_factor,
         moe_aux_weight=args.training.moe_aux_weight,
         num_hidden_layers=args.training.num_hidden_layers,
+        expert_shard=args.training.expert_shard,
     )
     family = model_family(cfg)
     tx = build_optimizer(args)
@@ -260,6 +261,7 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
         mesh=mesh,
         opt_state_sharding=opt_sharding,
         param_sharding=param_sharding,
+        sign_step_mask=family.sign_step_mask,
         authorizer=authorizer,
         authority_public_key=authority_public_key,
     )
@@ -339,6 +341,7 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
             save=save,
             put=put if mesh is not None else None,
             step_gauges=family.step_gauges,
+            step_counters=family.step_counters,
             # the MFU gauge uses the same analytic model-FLOPs formula and
             # peak table as bench.py
             tflops_per_sample=family.tflops_per_sample(cfg, seq),

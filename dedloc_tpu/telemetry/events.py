@@ -75,6 +75,9 @@ MM_LEADER_DISSOLVED = "mm.leader_dissolved"
 MM_ROUNDS_ABORTED = "mm.rounds_aborted"
 MM_ROUNDS_ATTEMPTED = "mm.rounds_attempted"
 MM_ROUNDS_FORMED = "mm.rounds_formed"
+MOE_BIAS_ABS_MAX = "moe.bias_abs_max"
+MOE_DROPPED_SLOTS = "moe.dropped_slots"
+MOE_LOCAL_SLOT_SHARE = "moe.local_slot_share"
 NET_BYTES_IN = "net.bytes_in"
 NET_BYTES_OUT = "net.bytes_out"
 OPT_BACKUP_BYTES = "opt.backup_bytes"
@@ -190,6 +193,7 @@ COUNTERS = frozenset({
     "mm.rounds_aborted",
     "mm.rounds_attempted",
     "mm.rounds_formed",
+    "moe.dropped_slots",
     "net.bytes_in",
     "net.bytes_out",
     "opt.backup_bytes",
@@ -234,6 +238,8 @@ COUNTERS = frozenset({
 })
 GAUGES = frozenset({
     "expert.load_ewma",
+    "moe.bias_abs_max",
+    "moe.local_slot_share",
     "opt.ef_residual_norm",
     "opt.overlap_efficiency",
     "opt.weight_scale",
@@ -336,6 +342,7 @@ EMITTED_PREFIXES = (
     "link.",
     "lm.exit_prob.",
     "lm.loss.",
+    "moe.load_max_over_mean.",
     "perf.",
     "step.phase.",
 )

@@ -167,9 +167,13 @@ def test_model_layout_matches_dense(rng, h, d, block, masked):
 
 @pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
 @pytest.mark.parametrize("masked", [False, True], ids=["nobias", "mask"])
-@pytest.mark.parametrize("h,d", [(16, 64), (4, 16), (2, 128), (3, 64)])
-def test_one_tile_forward_is_the_tiled_kernel_at_one_tile(rng, h, d, masked,
-                                                          causal):
+@pytest.mark.parametrize(
+    "h,d,dv", [(16, 64, 64), (4, 16, 16), (2, 128, 128), (3, 64, 64),
+               (4, 24, 16)],
+    ids=["16-64", "4-16", "2-128", "3-64", "4-24-16"],
+)
+def test_one_tile_forward_is_the_tiled_kernel_at_one_tile(rng, h, d, dv,
+                                                          masked, causal):
     """Where one tile covers the sequence ``_fwd`` takes a kernel with no
     online-softmax state (as ``_bwd`` takes the fused backward). It is the
     same arithmetic in the same order — at one tile the tiled kernel's
@@ -189,11 +193,13 @@ def test_one_tile_forward_is_the_tiled_kernel_at_one_tile(rng, h, d, masked,
     bias = jnp.asarray(bias)
     for dtype in (jnp.bfloat16, jnp.float32):
         q, k, v = (
-            jnp.asarray(rng.standard_normal((b, s, h * d)), dtype)
-            for _ in range(3)
+            jnp.asarray(rng.standard_normal((b, s, h * width)), dtype)
+            for width in (d, d, dv)
         )
-        out, lse = _fwd_one_tile(q, k, v, bias, d, causal, True)
-        want_out, want_lse = _fwd_tiled(q, k, v, bias, d, s, s, causal, True)
+        out, lse = _fwd_one_tile(q, k, v, bias, d, dv, causal, True)
+        want_out, want_lse = _fwd_tiled(
+            q, k, v, bias, d, dv, s, s, causal, True
+        )
         assert out.dtype == dtype and lse.dtype == jnp.float32
         np.testing.assert_array_equal(
             out.astype(jnp.float32), want_out.astype(jnp.float32)

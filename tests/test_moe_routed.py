@@ -1,0 +1,181 @@
+"""The dropless top-k routed layer (``parallel/moe.py``): the choice takes
+the bias and the weights do not; nothing is dropped however skewed the
+router; absent experts contribute nothing; the tile plan puts every valid
+(token, slot) pair in a row of its own expert's tiles; the load statistic
+leaves the backward as the bias's cotangent."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dedloc_tpu.parallel.moe import (
+    _tile_plan,
+    expert_load,
+    route_top_k,
+    routed_experts,
+    with_load_cotangent,
+)
+
+T, H, F, E, K = 48, 16, 8, 16, 3
+
+
+def _layer(seed=0):
+    r = jax.random.split(jax.random.PRNGKey(seed), 5)
+    return dict(
+        x=jax.random.normal(r[0], (T, H)),
+        router=jax.random.normal(r[1], (H, E)),
+        gate=jax.random.normal(r[2], (E, H, F)) * 0.3,
+        up=jax.random.normal(r[3], (E, H, F)) * 0.3,
+        down=jax.random.normal(r[4], (E, F, H)) * 0.3,
+    )
+
+
+def _dense(p, choice, weights, held):
+    """Σ over the held experts, each applied to every token (no sort)."""
+    y = jnp.zeros_like(p["x"])
+    for e in range(held[0], held[0] + held[1]):
+        mine = jnp.sum(jnp.where(choice == e, weights, 0.0), axis=-1)
+        y = y + mine[:, None] * (
+            (jax.nn.silu(p["x"] @ p["gate"][e]) * (p["x"] @ p["up"][e]))
+            @ p["down"][e]
+        )
+    return y
+
+
+def _routed(p, choice, weights, held, tile=8):
+    lo, n = held
+    return routed_experts(
+        p["x"], choice, weights, p["gate"][lo:lo + n], p["up"][lo:lo + n],
+        p["down"][lo:lo + n], held, tile=tile,
+    )
+
+
+def test_the_bias_enters_the_choice_and_not_the_weights():
+    scores = jax.nn.sigmoid(_layer()["x"] @ _layer()["router"])
+    bias = jnp.zeros((E,)).at[5].set(10.0)  # expert 5 wins every token
+    choice, weights = route_top_k(scores, bias, K, 2.448)
+    assert bool(jnp.all(jnp.any(choice == 5, axis=-1)))
+    plain_choice, _ = route_top_k(scores, jnp.zeros((E,)), K, 2.448)
+    assert not bool(jnp.all(jnp.any(plain_choice == 5, axis=-1)))
+    picked = jnp.take_along_axis(scores, choice, axis=-1)
+    np.testing.assert_allclose(
+        weights, picked / picked.sum(-1, keepdims=True) * 2.448, rtol=1e-6
+    )
+    np.testing.assert_allclose(weights.sum(-1), 2.448, rtol=1e-6)
+
+
+@pytest.mark.parametrize("held", [(0, 16), (4, 4), (15, 1)])
+def test_matches_a_dense_loop_over_the_held_experts(held):
+    p = _layer()
+    scores = jax.nn.sigmoid(p["x"] @ p["router"])
+    choice, weights = route_top_k(scores, jnp.zeros((E,)), K, 2.448)
+    y, stats = _routed(p, choice, weights, held)
+    np.testing.assert_allclose(
+        y, _dense(p, choice, weights, held), atol=1e-5, rtol=1e-5
+    )
+    assert float(stats["dropped_slots"]) == 0.0
+    inside = (choice >= held[0]) & (choice < held[0] + held[1])
+    assert float(stats["local_slot_share"]) == pytest.approx(
+        float(jnp.mean(inside))
+    )
+
+
+def test_dropless_when_every_token_goes_to_one_expert():
+    """A router so skewed that all T tokens choose expert 2 (and two more):
+    a capacity-bound layer would drop most of them; here expert 2 takes all
+    T rows and ``moe.dropped_slots`` reads 0."""
+    p = _layer(1)
+    scores = jax.nn.sigmoid(p["x"] @ p["router"])
+    choice, weights = route_top_k(
+        scores, jnp.zeros((E,)).at[2].set(100.0), K, 2.448
+    )
+    assert bool(jnp.all(choice[:, 0] == 2))
+    for held in ((2, 1), (0, 4)):
+        y, stats = _routed(p, choice, weights, held)
+        assert float(stats["dropped_slots"]) == 0.0
+        np.testing.assert_allclose(
+            y, _dense(p, choice, weights, held), atol=1e-5, rtol=1e-5
+        )
+    row_slot, tile_expert, tiles, dropped = _tile_plan(choice, (2, 1), 8)
+    assert int(jnp.sum(row_slot >= 0)) == T and int(tiles) == T // 8
+    assert int(dropped) == 0
+
+
+def test_absent_experts_contribute_nothing():
+    p = _layer(2)
+    scores = jax.nn.sigmoid(p["x"] @ p["router"])
+    choice, weights = route_top_k(scores, jnp.zeros((E,)), K, 2.448)
+    held = (8, 4)
+    y, stats = _routed(p, choice, weights, held)
+    # a token none of whose choices is held gets exactly zero
+    untouched = ~jnp.any((choice >= 8) & (choice < 12), axis=-1)
+    assert bool(jnp.any(untouched))
+    assert float(jnp.max(jnp.abs(y[untouched]))) == 0.0
+    # and the absent experts' matrices do not matter: none is even passed;
+    # the held ones' gradients are those of the dense loop
+    def loss(fn):
+        return lambda q: jnp.sum(jnp.sin(fn(dict(p, **q))))
+
+    names = ("x", "gate", "up", "down")
+    got = jax.grad(loss(lambda q: _routed(q, choice, weights, held)[0]))(
+        {n: p[n] for n in names}
+    )
+    want = jax.grad(loss(lambda q: _dense(q, choice, weights, held)))(
+        {n: p[n] for n in names}
+    )
+    for n in names:
+        np.testing.assert_allclose(got[n], want[n], atol=2e-5, rtol=1e-4)
+        if n != "x":
+            assert float(jnp.max(jnp.abs(got[n][:8]))) == 0.0
+
+
+def test_weights_gradient_reaches_the_router():
+    p = _layer(3)
+
+    def through(fn):
+        def loss(router):
+            scores = jax.nn.sigmoid(p["x"] @ router)
+            choice, weights = route_top_k(scores, jnp.zeros((E,)), K, 2.448)
+            return jnp.sum(jnp.sin(fn(p, choice, weights, (0, 8))))
+        return jax.grad(loss)(p["router"])
+
+    np.testing.assert_allclose(
+        through(lambda *a: _routed(*a)[0]), through(_dense),
+        atol=2e-5, rtol=1e-4,
+    )
+
+
+def test_tile_plan_rows_belong_to_their_tiles_expert():
+    choice = jnp.asarray(
+        np.random.default_rng(0).integers(0, E, (T, K)), jnp.int32
+    )
+    held, tile = (4, 6), 8
+    row_slot, tile_expert, tiles, dropped = _tile_plan(choice, held, tile)
+    row_slot, tile_expert = np.asarray(row_slot), np.asarray(tile_expert)
+    flat = np.asarray(choice).reshape(-1)
+    valid = (flat >= 4) & (flat < 10)
+    placed = row_slot[row_slot >= 0]
+    assert sorted(placed) == sorted(np.nonzero(valid)[0]) and int(dropped) == 0
+    for row, slot in enumerate(row_slot):
+        if slot >= 0:
+            assert row < int(tiles) * tile
+            assert flat[slot] - 4 == tile_expert[row // tile]
+
+
+def test_load_statistic_is_the_bias_cotangent():
+    choice = jnp.asarray(
+        np.random.default_rng(1).integers(0, E, (T, K)), jnp.int32
+    )
+    load = expert_load(choice, E)
+    assert float(load.sum()) == pytest.approx(1.0)
+    np.testing.assert_allclose(
+        load, np.bincount(np.asarray(choice).reshape(-1), minlength=E) / (T * K)
+    )
+    x = jnp.ones((4, 3))
+    gx, gb = jax.grad(
+        lambda x, b: 7.0 * jnp.sum(with_load_cotangent(x, b, load) ** 2),
+        (0, 1),
+    )(x, jnp.zeros((E,)))
+    np.testing.assert_allclose(gx, 14.0 * x)  # x passes through untouched
+    # whatever reaches x, the bias receives load − mean load, unscaled
+    np.testing.assert_allclose(gb, load - load.mean(), atol=1e-8)

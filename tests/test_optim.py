@@ -1,6 +1,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from dedloc_tpu.optim import (
     lamb,
@@ -322,3 +323,132 @@ def test_cosine_schedule():
     assert float(s(0)) == 0.0
     assert abs(float(s(10)) - 1.0) < 1e-2
     assert float(s(110)) < 1e-6
+
+
+def _sign_mask(params):
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: path[-1].key == "load_bias", params
+    )
+
+
+def _sign_step_setup():
+    rng = np.random.default_rng(4)
+    params = {
+        "dense": {"kernel": jnp.asarray(rng.standard_normal((5, 4)), jnp.float32)},
+        "router": {"load_bias": jnp.asarray(rng.standard_normal((6,)), jnp.float32)},
+        "norm": {"weight": jnp.ones((5,))},
+    }
+    decay = lambda p: jax.tree_util.tree_map_with_path(  # noqa: E731
+        lambda path, _: path[-1].key == "kernel", p
+    )
+    return params, decay
+
+
+def _random_like(tree, seed, scale=1.0):
+    r = np.random.default_rng(seed)
+    return jax.tree.map(
+        lambda p: jnp.asarray(scale * r.standard_normal(p.shape), jnp.float32),
+        tree,
+    )
+
+
+def test_sign_step_rule_per_leaf_and_flat_agree():
+    """A leaf the model table marks is stepped by ``-gamma * sign(g)`` —
+    exactly ±gamma or 0, no moments, trust ratio, decay or schedule — in the
+    per-leaf chain and in its flat twin alike; every other leaf moves as
+    under plain LAMB (to reduction order)."""
+    import optax
+
+    from dedloc_tpu.optim.flat import FlatLamb, tree_flags
+
+    params, decay = _sign_step_setup()
+    gamma = 0.001
+    sched = lambda c: 0.01 * (1.0 + 0.05 * c.astype(jnp.float32))  # noqa: E731
+    tx = lamb(sched, weight_decay=0.01, max_grad_norm=1.0,
+              weight_decay_mask=decay, sign_step_mask=_sign_mask,
+              sign_step=gamma)
+    spec, flags = _spec_and_flags(params, decay)
+    names = [n for n, _, _ in spec]
+    ftx = FlatLamb(
+        spec, flags, sched, weight_decay=0.01, max_grad_norm=1.0,
+        sign_flags=tree_flags(_sign_mask(params), params, names),
+        sign_step=gamma,
+    )
+    tree_params, tree_state = params, tx.init(params)
+    flat_params = jnp.asarray(_flatten_sorted(params))
+    mu = nu = jnp.zeros_like(flat_params)
+    count = sched_count = jnp.zeros([], jnp.int32)
+    for i in range(10):
+        grads = _random_like(tree_params, 70 + i)
+        # an exactly balanced expert: the sign rule leaves it where it is
+        grads["router"]["load_bias"] = grads["router"]["load_bias"].at[2].set(0.0)
+        before = tree_params["router"]["load_bias"]
+        updates, tree_state = tx.update(grads, tree_state, tree_params)
+        tree_params = optax.apply_updates(tree_params, updates)
+        moved = np.asarray(tree_params["router"]["load_bias"] - before)
+        want = -gamma * np.sign(np.asarray(grads["router"]["load_bias"]))
+        np.testing.assert_allclose(moved, want, atol=1e-7)
+        assert moved[2] == 0.0
+        delta, mu, nu, count = ftx.update(
+            jnp.asarray(_flatten_sorted(grads)), flat_params, mu, nu, count,
+            sched_count,
+        )
+        sched_count = sched_count + 1
+        flat_params = flat_params + delta
+    np.testing.assert_allclose(
+        np.asarray(flat_params), _flatten_sorted(jax.device_get(tree_params)),
+        rtol=1e-5, atol=1e-7,
+    )
+
+
+@pytest.mark.parametrize("path", ["per_leaf", "flat", "solo_mean"])
+def test_sign_stepped_leaves_stay_out_of_the_clip(path):
+    """The marked leaf carries a statistic, not a gradient: whatever it
+    holds, the clip's norm — and so every other leaf's update — is what it
+    is without it, in the per-leaf chain, the flat twin and the solo
+    boundary's fused mean + clip."""
+    import optax
+
+    from dedloc_tpu.collaborative import optimizer
+    from dedloc_tpu.optim.flat import FlatLamb, tree_flags
+
+    params, decay = _sign_step_setup()
+    grads = _random_like(params, 9, scale=3.0)  # norm well over the clip
+    loud = jax.tree.map(jnp.copy, grads)
+    loud["router"]["load_bias"] = 1e3 * jnp.ones((6,))
+    if path == "per_leaf":
+        tx = lamb(0.01, max_grad_norm=1.0, weight_decay_mask=decay,
+                  sign_step_mask=_sign_mask)
+        outs = [tx.update(g, tx.init(params), params)[0] for g in (grads, loud)]
+        kernels = [o["dense"]["kernel"] for o in outs]
+    elif path == "flat":
+        spec, flags = _spec_and_flags(params, decay)
+        names = [n for n, _, _ in spec]
+        ftx = FlatLamb(
+            spec, flags, 0.01, max_grad_norm=1.0,
+            sign_flags=tree_flags(_sign_mask(params), params, names),
+        )
+        flat = jnp.asarray(_flatten_sorted(params))
+        zero, count = jnp.zeros_like(flat), jnp.zeros([], jnp.int32)
+        kernels = [
+            ftx.update(jnp.asarray(_flatten_sorted(g)), flat, zero, zero,
+                       count, count)[0][:20]  # "['dense']['kernel']" sorts first
+            for g in (grads, loud)
+        ]
+    else:
+        exempt = tuple(jax.tree.leaves(_sign_mask(params)))
+        outs = [
+            optimizer._fused_mean_clip(g, 1, 1.0, exempt=exempt)
+            for g in (grads, loud)
+        ]
+        kernels = [o["dense"]["kernel"] for o in outs]
+        # the statistic itself is not scaled by the clip
+        np.testing.assert_array_equal(
+            outs[1]["router"]["load_bias"], loud["router"]["load_bias"]
+        )
+        norm = np.sqrt(sum(
+            float(jnp.sum(x * x)) for x, skip in
+            zip(jax.tree.leaves(outs[1]), exempt) if not skip
+        ))
+        assert norm == pytest.approx(1.0, rel=1e-5)
+    np.testing.assert_array_equal(np.asarray(kernels[0]), np.asarray(kernels[1]))

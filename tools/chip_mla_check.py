@@ -1,0 +1,81 @@
+"""On the chip: the two-width causal flash kernels (latent attention) against
+dense causal attention at kanana-2's shape (32 heads, q/k 192 wide, v and
+out 128, S=4,096: 8 x 8 tiles of 512), forward and the three gradients, in
+bf16 against a float32 reference at matmul precision 'highest'; then the
+kernels' wall per call.
+
+    chiprun --chips 1 -- python tools/chip_mla_check.py
+
+Prints one JSON line; exit code 1 if an error exceeds 0.02 relative L2
+(bf16 rounding of the operands alone is ~0.004)."""
+from __future__ import annotations
+
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+
+from dedloc_tpu.ops.flash_attention import flash_attention
+
+B, S, H, D, DV = 1, 4096, 32, 192, 128
+
+
+def dense(q, k, v):
+    with jax.default_matmul_precision("highest"):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(jnp.float32(D))
+        s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -jnp.inf)
+        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+
+
+def rel(a, b):
+    a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+
+def main() -> int:
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, k, v, w = (
+        jax.random.normal(x, (B, S, H, width), jnp.float32)
+        for x, width in zip(keys, (D, D, DV, DV))
+    )
+    bf = lambda x: x.astype(jnp.bfloat16)  # noqa: E731
+
+    def flash_loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True)
+        return jnp.sum(out.astype(jnp.float32) * w), out
+
+    def dense_loss(q, k, v):
+        out = dense(q, k, v)
+        return jnp.sum(out * w), out
+
+    flash = jax.jit(jax.value_and_grad(flash_loss, (0, 1, 2), has_aux=True))
+    (_, out), grads = flash(bf(q), bf(k), bf(v))
+    # the reference sees the same bf16-rounded operands, in float32
+    r = lambda x: bf(x).astype(jnp.float32)  # noqa: E731
+    (_, ref_out), ref_grads = jax.jit(
+        jax.value_and_grad(dense_loss, (0, 1, 2), has_aux=True)
+    )(r(q), r(k), r(v))
+    errors = {"out": rel(out, ref_out)}
+    errors.update(
+        {n: rel(g, rg) for n, g, rg in zip(("dq", "dk", "dv"), grads, ref_grads)}
+    )
+    jax.block_until_ready(flash(bf(q), bf(k), bf(v)))
+    start = time.perf_counter()
+    for _ in range(20):
+        result = flash(bf(q), bf(k), bf(v))
+    jax.block_until_ready(result)
+    print(json.dumps({
+        "device": jax.devices()[0].device_kind, "shape": [B, S, H, D, DV],
+        "relative_l2": errors,
+        "fwd_plus_bwd_wall_ms": (time.perf_counter() - start) / 20 * 1e3,
+    }))
+    return 0 if max(errors.values()) <= 0.02 else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -167,29 +167,49 @@ def kernels(device):
     )
 
 
-def _ouro_model_and_state():
+def mla_kernels(device):
+    """The two-width causal kernels (latent attention: q/k 192 wide, v and
+    out 128) at the kanana-2 cell's shape, 8 x 8 tiles of 512, fwd+bwd."""
+    from dedloc_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return jnp.sum(
+            flash_attention(q, k, v, causal=True).astype(jnp.float32)
+        )
+
+    qk = jax.ShapeDtypeStruct((1, 4096, 32, 192), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 4096, 32, 128), jnp.bfloat16)
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_on_device(device, (qk, qk, v))
+    )
+
+
+def _lm_model_and_state(config: str, prefix: str):
+    """(args, model, state, ids) of a causal-LM cell's recipe, from its
+    configuration file's flags; ``<prefix>_LAYERS`` / ``<prefix>_BATCH`` in
+    the environment size another cut."""
     from dedloc_tpu.core.config import CollaborationArguments, parse_config
     from dedloc_tpu.parallel.train_step import TrainState
     from dedloc_tpu.roles.common import build_model, build_optimizer
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(
-        repo, "benchmark", "configs", "ouro_2p6b_s4096.json"
-    )) as f:
+    with open(os.path.join(repo, "benchmark", "configs", config)) as f:
         flags = json.load(f)["flags"]
-    layers = int(os.environ.get(
-        "OURO_LAYERS", flags["--training.num_hidden_layers"]
+    flags["--training.num_hidden_layers"] = int(os.environ.get(
+        f"{prefix}_LAYERS", flags["--training.num_hidden_layers"]
     ))
     batch = int(os.environ.get(
-        "OURO_BATCH", flags["--training.per_device_batch_size"]
+        f"{prefix}_BATCH", flags["--training.per_device_batch_size"]
     ))
     ids = jnp.zeros((batch, flags["--training.seq_length"]), jnp.int32)
     args = parse_config(
         CollaborationArguments,
-        ["--training.model_size", flags["--training.model_size"]],
+        [str(x) for pair in flags.items() for x in pair],
     )
+    t = args.training
     _cfg, model = build_model(
-        args.training.model_size, num_hidden_layers=layers
+        t.model_size, vocab_size=t.vocab_size,
+        num_hidden_layers=t.num_hidden_layers, expert_shard=t.expert_shard,
     )
     state = jax.eval_shape(
         lambda r: TrainState.create(
@@ -204,18 +224,36 @@ def ouro_accumulate_step(device):
     """Ouro-2.6B cut in depth: causal flash attention at D=128 over 8 x 8
     tiles, the scan over layers inside the scan over four passes, the
     chunked head + gated loss."""
+    return _lm_accumulate_step(device, *_ouro_model_and_state())
+
+
+def _ouro_model_and_state():
+    return _lm_model_and_state("ouro_2p6b_s4096.json", "OURO")
+
+
+def _lm_accumulate_step(device, _args, model, state, ids):
     from dedloc_tpu.parallel.train_step import (
         make_accumulate_step,
         zeros_like_grads,
     )
     from dedloc_tpu.roles.common import build_loss_fn
 
-    _args, model, state, ids = _ouro_model_and_state()
     grads = jax.eval_shape(zeros_like_grads, state.params)
     return make_accumulate_step(build_loss_fn(model)).lower(*_on_device(
         device,
         (state.params, grads, jnp.zeros([], jnp.int32),
          {"input_ids": ids, "labels": ids}, jax.random.PRNGKey(0)),
+    ))
+
+
+def kanana_accumulate_step(device):
+    """kanana-2-30b-a3b at one chip's share (depth, experts held and
+    vocabulary rows from ``benchmark/configs/kanana2_30b_a3b_s4096.json``;
+    ``KANANA_LAYERS`` / ``KANANA_BATCH`` size another cut): the two-width
+    causal kernels over 8 x 8 tiles, the dense layer and the scanned expert
+    layers with the routed tile loop, the chunked head."""
+    return _lm_accumulate_step(device, *_lm_model_and_state(
+        "kanana2_30b_a3b_s4096.json", "KANANA"
     ))
 
 
@@ -258,7 +296,7 @@ def flash_fwd_forms(lowered_text: str) -> dict:
     site inside a scan body counts once; a remat replay is a site more."""
     calls = [
         line for line in lowered_text.splitlines()
-        if re.search(r'kernel_name = "flash_(causal_)?fwd"', line)
+        if re.search(r'kernel_name = "flash_(causal_|mla_)?fwd"', line)
     ]
     # the serialized kernel body on the same line is base64: no "_" in it
     one_tile = sum("one_tile" in line for line in calls)
@@ -271,7 +309,7 @@ NO_V5E = 3  # exit code: nothing to compile with, which is not a failure
 PROGRAMS = {
     fn.__name__: fn for fn in (
         accumulate_step, flat_apply_step, kernels, ouro_accumulate_step,
-        ouro_guarded_apply_step,
+        ouro_guarded_apply_step, mla_kernels, kanana_accumulate_step,
     )
 }
 
