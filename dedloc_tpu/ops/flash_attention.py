@@ -44,10 +44,22 @@ out-projection reads ([B, S, H, D] at the public entry is the same bytes);
 no transpose on either side, so what the remat policy stashes is what the
 kernels read. A BlockSpec block is (1, block, W) at (b, j, p): a COLUMN
 BLOCK of g adjacent heads, g = the fewest whose g·D lanes fill whole
-128-lane tiles (2 at D=64, 1 at D=128) — or the whole width H·D where the
-head count does not divide into such blocks (the tiny test models). Inside
-a block the g heads are separated by zeroing the other heads' lanes of one
-operand of each product (``_only_head``). A program takes several column
+128-lane tiles (2 at D=64, 1 at D=128, 2 at q/k 192 beside v 128) — or the
+whole width H·D where the head count does not divide into such blocks (the
+tiny test models). Inside a block every per-head product contracts over,
+and lands in, the head's own lane WINDOW (``_window``): the smallest run of
+whole 128-lane tiles that covers the head's lanes — the block itself at
+D=64 (both heads) and D=128, lanes 0-255 / 128-383 of a 384-lane block at
+192, the head's own tile of v, dO and out at 128 x 2. What a window holds
+of another head (all of it at D=64, 64 lanes of 256 at 192, nothing where
+the window is the head) is zeroed on one operand of each product
+(``_only_head``): the dropped terms are products with exact zeros, so the
+MXU spends no pass on a lane tile the head does not touch. Windows overlap
+where heads share a tile (lanes 128-255 at 192): accumulators and outputs
+are read and written by the aligned SEGMENTS between window edges
+(``_segments``), each head's product added into the segments of its window
+(``_into``). A call whose windows are narrower than its blocks says so in
+its kernel ``metadata`` (``_lanes``). A program takes several column
 blocks (``_pick_heads``); batch is a grid axis. Per-position scalars ride
 as ROW vectors — the additive bias [B, 1, S], lse [B·H, 1, S]: a [.., S, 1]
 column layout would be 128×-padded by the TPU's (8, 128) tiling — 2 GB of
@@ -161,31 +173,120 @@ def _column_blocks(width: int, g: int, d: int, dv: int):
     ]
 
 
-def _only_head(x, i: int, d: int):
-    """``x`` [N, g·D] with the lanes of every head but the i-th zeroed.
-    Contracting over all g·D lanes then gives head i's product exactly (the
-    other terms are zeros) at the MXU passes a D-deep contraction takes
-    anyway, and a product WITH it lands in head i's lanes of a [N, g·D]
-    tile and nowhere else — so the g heads of a block sum into a tile that
-    is already in the model's layout. (Lane slices and a concatenate, the
-    other way, measured 5-10 % slower on a v5e.)"""
-    if x.shape[-1] == d:
+def _window(i: int, width: int, g: int) -> slice:
+    """Head i's lane WINDOW inside a column block of ``g`` heads ``width``
+    wide: the smallest run of whole 128-lane tiles that covers the head's
+    lanes — what its products contract over and land in — or the whole
+    block where the block is not whole lane tiles (the tiny test models).
+    The block itself at 64 x 2 (both heads) and 128 x 1; lanes 0-255 and
+    128-383 at 192 x 2; the head's own tile, 0-127 / 128-255, at 128 x 2."""
+    if (g * width) % 128:
+        return slice(0, g * width)
+    return slice(i * width // 128 * 128, -(-(i + 1) * width // 128) * 128)
+
+
+def _segments(width: int, g: int):
+    """A column block's lanes cut at every window edge: the aligned pieces
+    an accumulator or an output is read and written by, each covered whole
+    by every window that touches it. One piece, the block, where the window
+    is the block; the three lane tiles at 192 x 2 (heads 0 | 0 and 1 | 1)."""
+    windows = [_window(i, width, g) for i in range(g)]
+    edges = sorted({edge for w in windows for edge in (w.start, w.stop)})
+    return [slice(a, b) for a, b in zip(edges, edges[1:])]
+
+
+def _at(block: slice, part: slice) -> slice:
+    """``part`` of a column block, as lanes of the program's tile."""
+    return slice(block.start + part.start, block.start + part.stop)
+
+
+def _segments_at(block: slice, width: int, g: int):
+    return [_at(block, seg) for seg in _segments(width, g)]
+
+
+def _per_window(width: int, g: int, make):
+    """[``make(head i's window)`` for the g heads]: made once per distinct
+    window, so heads that share one (D=64) share its loads."""
+    windows = [_window(i, width, g) for i in range(g)]
+    made = {}
+    for w in windows:
+        if (w.start, w.stop) not in made:
+            made[w.start, w.stop] = make(w)
+    return [made[w.start, w.stop] for w in windows]
+
+
+def _only_head(x, i: int, width: int, g: int):
+    """``x`` [N, head i's window] with what the window holds of the other
+    heads zeroed (64 lanes of 256 at 192 x 2; nothing where the window is
+    the head: D=128, and v / dO / out at 128 x 2). Contracting over the
+    window then gives head i's product exactly (the other terms are zeros),
+    and a product WITH it lands in head i's lanes of the window and nowhere
+    else — so the heads of a block sum into tiles that are already in the
+    model's layout. Windows are whole lane tiles: cutting one out of a
+    block is a choice of vector registers. Cutting a HEAD out where it is
+    half a lane tile (D=64: lane slices and a concatenate) is a relayout,
+    and measured 5-10 % slower on a v5e than the zeroing."""
+    if x.shape[-1] == width:
         return x
+    lo = i * width - _window(i, width, g).start
     lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    return jnp.where((lane >= i * d) & (lane < (i + 1) * d), x,
+    return jnp.where((lane >= lo) & (lane < lo + width), x,
                      jnp.zeros_like(x))
 
 
-def _per_head_lanes(columns, d: int):
-    """g per-head columns [N, 1] -> [N, g·D], column i across head i's
-    lanes (to scale a block's accumulator head by head)."""
-    *first, out = columns
-    lane = jax.lax.broadcasted_iota(
-        jnp.int32, (out.shape[0], len(columns) * d), 1
-    )
-    for i in reversed(range(len(first))):
-        out = jnp.where(lane < (i + 1) * d, first[i], out)
+def _into(totals, i: int, width: int, g: int, term) -> None:
+    """Add head i's product ``term`` [N, its window] into ``totals``, one
+    running sum per segment of the block (None: nothing yet)."""
+    w = _window(i, width, g)
+    for n, seg in enumerate(_segments(width, g)):
+        if w.start <= seg.start and seg.stop <= w.stop:
+            whole = (seg.start, seg.stop) == (w.start, w.stop)
+            piece = term if whole else term[
+                :, seg.start - w.start:seg.stop - w.start
+            ]
+            totals[n] = piece if totals[n] is None else totals[n] + piece
+
+
+def _per_head_lanes(columns, width: int, seg: slice):
+    """The g per-head columns [N, 1] across lanes ``seg`` of their column
+    block, column i over head i's lanes (to scale an accumulator head by
+    head): the column itself, to broadcast, where one head owns the segment
+    (its own tile: D=128, and v's 128 x 2), else a select by lane."""
+    heads = [
+        i for i in range(len(columns))
+        if i * width < seg.stop and (i + 1) * width > seg.start
+    ]
+    out = columns[heads[-1]]
+    if len(heads) > 1:
+        lane = jax.lax.broadcasted_iota(
+            jnp.int32, (out.shape[0], seg.stop - seg.start), 1
+        )
+        for i in reversed(heads[:-1]):
+            out = jnp.where(
+                lane < (i + 1) * width - seg.start, columns[i], out
+            )
     return out
+
+
+def _lanes(d: int, dv: int, g: int) -> Optional[dict]:
+    """What a kernel's shapes chose, for its ``metadata``: the lanes a
+    head's products contract over and land in (its window) and the lanes of
+    a column block, at both widths — where a window is narrower than its
+    block; None where every window is the block (D=64, D=128). A lowered
+    module carries it (tools/tpu_aot.py prints it per program as
+    ``flash_windows``). None, not "window == block", because metadata is
+    not free: XLA:TPU sees it as frontend attributes of the custom call and
+    schedules the program AROUND the call differently (Ouro's
+    accumulate_step: 14 ``copy`` instructions in its scanned bodies with
+    it, 19 without), and the equal-width callers keep the programs they
+    had."""
+    qk, v = _window(0, d, g), _window(0, dv, g)
+    lanes = {
+        "qk_window": qk.stop - qk.start, "qk_block": g * d,
+        "v_window": v.stop - v.start, "v_block": g * dv,
+    }
+    whole = (lanes["qk_window"], lanes["v_window"]) == (g * d, g * dv)
+    return None if whole else lanes
 
 
 def _dot(a, b, contract_a: int, contract_b: int):
@@ -193,10 +294,6 @@ def _dot(a, b, contract_a: int, contract_b: int):
         a, b, (((contract_a,), (contract_b,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-
-
-def _add(total, term):
-    return term if total is None else total + term
 
 
 # ------------------------------------------------------------ causal tiles
@@ -271,14 +368,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         # the per-head dots are too small to hide the per-program overhead
         # (measured on v5e)
         for h0, cols, vcols in blocks:
-            q = q_ref[:, cols]  # [Bq, g·D]: g heads side by side
-            k = k_ref[:, cols]  # [Bk, g·D]
-            v = v_ref[:, vcols]
-            pv, corrs = None, []
+            # per head, its window of the block: [Bq, g·D] for both heads
+            # at D=64, [Bq, 256] of the 384 lanes at 192
+            q = _per_window(d, g, lambda w: q_ref[:, _at(cols, w)])
+            k = _per_window(d, g, lambda w: k_ref[:, _at(cols, w)])
+            v = _per_window(dv, g, lambda w: v_ref[:, _at(vcols, w)])
+            pv, corrs = [None] * len(_segments(dv, g)), []
             for i in range(g):
                 h = h0 + i
                 s = _masked(
-                    _dot(q, _only_head(k, i, d), 1, 1) * scale + b, mask
+                    _dot(q[i], _only_head(k[i], i, d, g), 1, 1) * scale + b,
+                    mask,
                 )
 
                 # softmax state lives as COLUMNS [Bq, 1] in scratch (it
@@ -294,12 +394,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                 l_ref[h] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
                 m_ref[h] = m_new
                 corrs.append(corr)
-                pv = _add(
-                    pv, _dot(p.astype(v.dtype), _only_head(v, i, dv), 1, 0)
+                _into(pv, i, dv, g, _dot(
+                    p.astype(v[i].dtype), _only_head(v[i], i, dv, g), 1, 0
+                ))
+            for seg, pv_seg in zip(_segments(dv, g), pv):
+                at = _at(vcols, seg)
+                acc_ref[:, at] = (
+                    acc_ref[:, at] * _per_head_lanes(corrs, dv, seg) + pv_seg
                 )
-            acc_ref[:, vcols] = (
-                acc_ref[:, vcols] * _per_head_lanes(corrs, dv) + pv
-            )
 
     _for_tile(causal, pl.program_id(2), kb, q_ref.shape[0], k_ref.shape[0],
               tile)
@@ -310,9 +412,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
             safe_l = [
                 jnp.maximum(l_ref[h0 + i], 1e-30) for i in range(g)
             ]  # [Bq, 1] each
-            o_ref[:, vcols] = (
-                acc_ref[:, vcols] / _per_head_lanes(safe_l, dv)
-            ).astype(o_ref.dtype)
+            for seg in _segments(dv, g):
+                at = _at(vcols, seg)
+                o_ref[:, at] = (
+                    acc_ref[:, at] / _per_head_lanes(safe_l, dv, seg)
+                ).astype(o_ref.dtype)
             for i in range(g):
                 lse_ref[h0 + i] = _t(  # -> [1, Bq] row
                     m_ref[h0 + i] + jnp.log(safe_l[i])
@@ -329,13 +433,13 @@ def _fwd_one_tile_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
     mask = _tile_mask(0, 0, s, s) if causal else None
     b = bias_ref[:].astype(jnp.float32)  # [1, S]
     for h0, cols, vcols in _column_blocks(q_ref.shape[-1], g, d, dv):
-        q = q_ref[:, cols]  # [S, g·D]: g heads side by side
-        k = k_ref[:, cols]
-        v = v_ref[:, vcols]
-        pv, ls = None, []
+        q = _per_window(d, g, lambda w: q_ref[:, _at(cols, w)])
+        k = _per_window(d, g, lambda w: k_ref[:, _at(cols, w)])
+        v = _per_window(dv, g, lambda w: v_ref[:, _at(vcols, w)])
+        pv, ls = [None] * len(_segments(dv, g)), []
         for i in range(g):
             x = _masked(
-                _dot(q, _only_head(k, i, d), 1, 1) * scale + b, mask
+                _dot(q[i], _only_head(k[i], i, d, g), 1, 1) * scale + b, mask
             )
             # the floor the tiled kernel's state starts from: a row whose
             # every score is -inf stays finite
@@ -344,8 +448,13 @@ def _fwd_one_tile_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
             l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
             lse_ref[h0 + i] = _t(m + jnp.log(l))  # [S, 1] -> [1, S] row
             ls.append(l)
-            pv = _add(pv, _dot(p.astype(v.dtype), _only_head(v, i, dv), 1, 0))
-        o_ref[:, vcols] = (pv / _per_head_lanes(ls, dv)).astype(o_ref.dtype)
+            _into(pv, i, dv, g, _dot(
+                p.astype(v[i].dtype), _only_head(v[i], i, dv, g), 1, 0
+            ))
+        for seg, pv_seg in zip(_segments(dv, g), pv):
+            o_ref[:, _at(vcols, seg)] = (
+                pv_seg / _per_head_lanes(ls, dv, seg)
+            ).astype(o_ref.dtype)
 
 
 def _name(kernel: str, causal: bool, d: int, dv: int) -> str:
@@ -403,6 +512,7 @@ def _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, causal, interpret):
         ],
         interpret=interpret,
         name=_name("fwd", causal, d, dv),
+        metadata=_lanes(d, dv, g),
     )(q, k, v, bias)
     return out, lse
 
@@ -435,7 +545,7 @@ def _fwd_one_tile(q, k, v, bias, d, dv, causal, interpret):
         name=_name("fwd", causal, d, dv),
         # the same name in a device trace; a lowered module tells the two
         # forward forms apart by this (tools/tpu_aot.py counts them)
-        metadata={"form": "one_tile"},
+        metadata={"form": "one_tile", **(_lanes(d, dv, g) or {})},
     )(q, k, v, bias)
     return out, lse
 
@@ -451,24 +561,32 @@ def _backward_heads(refs, bias_ref, lse_ref, h0, cols, vcols, mask, *,
     the program's tiles), one at a time: each head's probability tile ``p``
     and the gradient ``ds`` of its scores ([Bq, Bk], recomputed from the
     residuals in fp32, cast for the MXU), with q, k and dO cut down to that
-    head's lanes for the products that follow. ``mask``: the causal mask of
-    a tile the diagonal crosses, else None."""
+    head's lanes of its window for the products that follow (``_into``
+    adds them up). ``mask``: the causal mask of a tile the diagonal
+    crosses, else None."""
     # dO stays in its native (bf16) dtype for the dots — MXU at full rate
     q_ref, k_ref, v_ref, do_ref, o_ref = refs
-    q, k = q_ref[:, cols], k_ref[:, cols]
-    v, do, o = v_ref[:, vcols], do_ref[:, vcols], o_ref[:, vcols]
-    # delta = rowsum(dO ⊙ O) per head, as the COLUMN the math needs
-    prod = do.astype(jnp.float32) * o.astype(jnp.float32)
+    q = _per_window(d, g, lambda w: q_ref[:, _at(cols, w)])
+    k = _per_window(d, g, lambda w: k_ref[:, _at(cols, w)])
+
+    def v_side(w):  # v, dO and dO ⊙ O over one window
+        at = _at(vcols, w)
+        v, do, o = v_ref[:, at], do_ref[:, at], o_ref[:, at]
+        return v, do, do.astype(jnp.float32) * o.astype(jnp.float32)
+
+    v_do_prod = _per_window(dv, g, v_side)
     b = bias_ref[:].astype(jnp.float32)  # [1, Bk]
-    for i in range(g):
-        k_i = _only_head(k, i, d)
-        s = _masked(_dot(q, k_i, 1, 1) * scale + b, mask)
+    for i, (v, do, prod) in enumerate(v_do_prod):
+        k_i = _only_head(k[i], i, d, g)
+        s = _masked(_dot(q[i], k_i, 1, 1) * scale + b, mask)
         p = jnp.exp(s - _t(lse_ref[h0 + i]))  # [1, Bq] row -> column
-        dp = _dot(do, _only_head(v, i, dv), 1, 1)
-        delta = jnp.sum(_only_head(prod, i, dv), axis=-1, keepdims=True)
+        dp = _dot(do, _only_head(v, i, dv, g), 1, 1)
+        # delta = rowsum(dO ⊙ O) per head, as the COLUMN the math needs
+        delta = jnp.sum(_only_head(prod, i, dv, g), axis=-1, keepdims=True)
         ds = p * (dp - delta) * scale
-        yield _Head(p.astype(do.dtype), ds.astype(q.dtype),
-                    _only_head(q, i, d), k_i, _only_head(do, i, dv))
+        yield _Head(p.astype(do.dtype), ds.astype(q[i].dtype),
+                    _only_head(q[i], i, d, g), k_i,
+                    _only_head(do, i, dv, g))
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
@@ -482,13 +600,15 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
 
     def tile(mask):
         for h0, cols, vcols in _column_blocks(q_ref.shape[-1], g, d, dv):
-            dq = dq_acc_ref[:, cols]
-            for head in _backward_heads(
+            dq_at = _segments_at(cols, d, g)
+            dq = [dq_acc_ref[:, at] for at in dq_at]
+            for i, head in enumerate(_backward_heads(
                 (q_ref, k_ref, v_ref, do_ref, o_ref), bias_ref, lse_ref, h0,
                 cols, vcols, mask, scale=scale, d=d, dv=dv, g=g,
-            ):
-                dq = dq + _dot(head.ds, head.k, 1, 0)
-            dq_acc_ref[:, cols] = dq
+            )):
+                _into(dq, i, d, g, _dot(head.ds, head.k, 1, 0))
+            for at, total in zip(dq_at, dq):
+                dq_acc_ref[:, at] = total
 
     _for_tile(causal, pl.program_id(2), kb, q_ref.shape[0], k_ref.shape[0],
               tile)
@@ -511,15 +631,19 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
 
     def tile(mask):
         for h0, cols, vcols in _column_blocks(q_ref.shape[-1], g, d, dv):
-            dk, dv_ = dk_acc_ref[:, cols], dv_acc_ref[:, vcols]
-            for head in _backward_heads(
+            dk_at, dv_at = _segments_at(cols, d, g), _segments_at(vcols, dv, g)
+            dk = [dk_acc_ref[:, at] for at in dk_at]
+            dv_ = [dv_acc_ref[:, at] for at in dv_at]
+            for i, head in enumerate(_backward_heads(
                 (q_ref, k_ref, v_ref, do_ref, o_ref), bias_ref, lse_ref, h0,
                 cols, vcols, mask, scale=scale, d=d, dv=dv, g=g,
-            ):
-                dv_ = dv_ + _dot(head.p, head.do, 0, 0)
-                dk = dk + _dot(head.ds, head.q, 0, 0)
-            dk_acc_ref[:, cols] = dk
-            dv_acc_ref[:, vcols] = dv_
+            )):
+                _into(dv_, i, dv, g, _dot(head.p, head.do, 0, 0))
+                _into(dk, i, d, g, _dot(head.ds, head.q, 0, 0))
+            for at, total in zip(dk_at, dk):
+                dk_acc_ref[:, at] = total
+            for at, total in zip(dv_at, dv_):
+                dv_acc_ref[:, at] = total
 
     _for_tile(causal, qb, pl.program_id(2), q_ref.shape[0], k_ref.shape[0],
               tile)
@@ -539,17 +663,21 @@ def _dqkv_fused_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref,
     s = q_ref.shape[0]
     mask = _tile_mask(0, 0, s, s) if causal else None
     for h0, cols, vcols in _column_blocks(q_ref.shape[-1], g, d, dv):
-        dq = dk = dv_ = None
-        for head in _backward_heads(
+        dq, dk = ([None] * len(_segments(d, g)) for _ in range(2))
+        dv_ = [None] * len(_segments(dv, g))
+        for i, head in enumerate(_backward_heads(
             (q_ref, k_ref, v_ref, do_ref, o_ref), bias_ref, lse_ref, h0,
             cols, vcols, mask, scale=scale, d=d, dv=dv, g=g,
+        )):
+            _into(dv_, i, dv, g, _dot(head.p, head.do, 0, 0))
+            _into(dq, i, d, g, _dot(head.ds, head.k, 1, 0))
+            _into(dk, i, d, g, _dot(head.ds, head.q, 0, 0))
+        for ref, block, width, totals in (
+            (dq_ref, cols, d, dq), (dk_ref, cols, d, dk),
+            (dv_ref, vcols, dv, dv_),
         ):
-            dv_ = _add(dv_, _dot(head.p, head.do, 0, 0))
-            dq = _add(dq, _dot(head.ds, head.k, 1, 0))
-            dk = _add(dk, _dot(head.ds, head.q, 0, 0))
-        dq_ref[:, cols] = dq.astype(dq_ref.dtype)
-        dk_ref[:, cols] = dk.astype(dk_ref.dtype)
-        dv_ref[:, vcols] = dv_.astype(dv_ref.dtype)
+            for at, total in zip(_segments_at(block, width, g), totals):
+                ref[:, at] = total.astype(ref.dtype)
 
 
 def _bwd(q, k, v, bias, lse, do, out, d, dv, block_q, block_k, causal,
@@ -601,6 +729,7 @@ def _bwd(q, k, v, bias, lse, do, out, d, dv, block_q, block_k, causal,
         scratch_shapes=[pltpu.VMEM((bq, hp * d), jnp.float32)],
         interpret=interpret,
         name=_name("bwd_dq", causal, d, dv),
+        metadata=_lanes(d, dv, g),
     )(q, k, v, bias, lse, do, out)
 
     dk, dv = pl.pallas_call(
@@ -618,6 +747,7 @@ def _bwd(q, k, v, bias, lse, do, out, d, dv, block_q, block_k, causal,
         ],
         interpret=interpret,
         name=_name("bwd_dkv", causal, d, dv),
+        metadata=_lanes(d, dv, g),
     )(q, k, v, bias, lse, do, out)
     return dq, dk, dv
 
@@ -649,6 +779,7 @@ def _bwd_fused(q, k, v, bias, lse, do, out, d, dv, causal, interpret):
         ],
         interpret=interpret,
         name=_name("bwd_fused", causal, d, dv),
+        metadata=_lanes(d, dv, g),
     )(q, k, v, bias, lse, do, out)
     return dq, dk, dv_
 

@@ -268,7 +268,36 @@ def test_flat_apply_and_kernels_compile_for_a_v5e_in_seconds():
     assert rows["kernels"]["tpu_custom_calls"] == 9
     # one tile at (12, 512, 16 x 64) and, causal, at D=128; tiles at S=2,048
     assert rows["kernels"]["flash_fwd_forms"] == {"one_tile": 2, "tiles": 1}
+    # D=64 (two heads a lane tile) and D=128: a head's window is its block
+    assert set(rows["kernels"]["flash_windows"]) == {
+        "flash_fwd", "flash_bwd_fused", "flash_causal_fwd",
+        "flash_causal_bwd_fused", "flash_bwd_dq", "flash_bwd_dkv",
+    }
+    # ("block": no window metadata on the call, the parent's programs)
+    assert set(rows["kernels"]["flash_windows"].values()) == {"block"}
     assert rows["kernels"]["device_kind"] == "TPU v5 lite"
+
+
+def test_two_width_kernels_contract_over_a_heads_own_lane_tiles():
+    """Latent attention (q/k 192 wide, v and out 128; two heads a column
+    block of 384 / 256 lanes), compiled for a v5e alone and inside
+    kanana-2's accumulate_step at the cell's cut: every per-head product
+    of the three kernels contracts over, and lands in, the head's own lane
+    window — 256 of the 384 q/k lanes, the head's own 128-lane tile of v,
+    dO and out — as each call's metadata says (``flash_windows``)."""
+    rows = _tpu_aot("mla_kernels", "kanana_accumulate_step")
+    windowed = {
+        "qk_window": 256, "qk_block": 384, "v_window": 128, "v_block": 256,
+    }
+    for row in rows.values():
+        assert row["flash_windows"] == {
+            "flash_mla_fwd": windowed, "flash_mla_bwd_dq": windowed,
+            "flash_mla_bwd_dkv": windowed,
+        }
+    # the dense layer and the scanned expert layers: a forward site each
+    assert rows["kanana_accumulate_step"]["flash_fwd_forms"] == {
+        "one_tile": 0, "tiles": 2
+    }
 
 
 def test_accumulate_step_has_no_relayout_copies_around_flash_attention():
@@ -284,6 +313,9 @@ def test_accumulate_step_has_no_relayout_copies_around_flash_attention():
     row = _tpu_aot("accumulate_step")["accumulate_step"]
     # S=512 under a 512 block: the scanned layer's one forward call
     assert row["flash_fwd_forms"] == {"one_tile": 1, "tiles": 0}
+    assert row["flash_windows"] == {
+        "flash_fwd": "block", "flash_bwd_fused": "block"
+    }
     copies = row["layer_body_copies"]
     assert len(copies) <= 3, copies
     assert not [shape for shape in copies if shape.endswith(",512,64]")]
@@ -301,6 +333,11 @@ def test_ouro_accumulate_step_keeps_the_flash_outputs_and_fits_the_cap():
     by; a policy that also kept ``flash_qkv`` would read 6.2 GB here."""
     row = _tpu_aot("ouro_accumulate_step")["ouro_accumulate_step"]
     assert row["flash_fwd_forms"] == {"one_tile": 0, "tiles": 1}
+    assert row["flash_windows"] == {  # D=128: a head is one lane tile
+        name: "block" for name in (
+            "flash_causal_fwd", "flash_causal_bwd_dq", "flash_causal_bwd_dkv"
+        )
+    }
     # forward, dq, dkv: one site each
     assert row["tpu_custom_calls"] == 3
     assert row["memory"]["temp_bytes"] <= 5.35e9, row["memory"]

@@ -12,7 +12,10 @@ speed or numerics; those need the chip (``chip_smoke.py``).
     OURO_LAYERS=4 OURO_BATCH=1 python tools/tpu_aot.py ouro_accumulate_step
 
 Each line: {"program", "compile_s", "tpu_custom_calls", "flash_fwd_forms",
-"layer_body_copies", "memory"} — ``flash_fwd_forms`` counts the flash
+"flash_windows", "layer_body_copies", "memory"} — ``flash_windows`` is each
+flash kernel's lane window beside its column block, from the call's
+metadata (``"block"`` for a call that carries none: D=64, D=128);
+``flash_fwd_forms`` counts the flash
 forward call SITES of the lowered module by the form their shapes chose
 (``one_tile``: one tile covers the sequence; ``tiles``: the online-softmax
 kernel). A scanned layer body is one site however often it runs, and a
@@ -303,6 +306,37 @@ def flash_fwd_forms(lowered_text: str) -> dict:
     return {"one_tile": one_tile, "tiles": len(calls) - one_tile}
 
 
+def flash_windows(lowered_text: str) -> dict:
+    """The flash kernels of a lowered module, by kernel name: the lanes a
+    head's products contract over and land in (``qk_window``, ``v_window``)
+    beside the lanes of a column block (``qk_block``, ``v_block``), as a
+    call carries them in its kernel metadata: 256 of 384 and 128 of 256 at
+    q/k 192, v 128. ``"block"`` for a call that carries none: its windows
+    are its column blocks (D=64, two heads a 128-lane tile; D=128), and
+    metadata — which moves XLA's choices around a call — is kept off the
+    programs that do not need it. A list where call sites of one name
+    differ."""
+    found = {}
+    for line in lowered_text.splitlines():
+        name = re.search(r'kernel_name = "(flash_\w+)"', line)
+        if not name:
+            continue
+        meta = re.search(r'kernel_metadata = "(\{[^"]*\})"', line)
+        meta = json.loads(
+            meta.group(1).replace("\\0A", "").replace("\\22", '"')
+        ) if meta else {}
+        windows = {
+            key: value for key, value in meta.items() if key != "form"
+        } or "block"
+        sites = found.setdefault(name.group(1), [])
+        if windows not in sites:
+            sites.append(windows)
+    return {
+        name: sites[0] if len(sites) == 1 else sites
+        for name, sites in sorted(found.items())
+    }
+
+
 NO_V5E = 3  # exit code: nothing to compile with, which is not a failure
 
 
@@ -338,6 +372,7 @@ def main(argv=None) -> int:
             "compile_s": seconds,
             "tpu_custom_calls": lowered_text.count("tpu_custom_call"),
             "flash_fwd_forms": flash_fwd_forms(lowered_text),
+            "flash_windows": flash_windows(lowered_text),
             "layer_body_copies": layer_body_copies(compiled.as_text()),
             "memory": {
                 "argument_bytes": memory.argument_size_in_bytes,
