@@ -87,6 +87,7 @@ class DeepseekV3Config:
     num_experts_per_tok: int = 6
     n_shared_experts: int = 2
     routed_scaling_factor: float = 2.448
+    route_eps: float = 1e-20  # added to the chosen scores' sum (HF's own)
     max_position_embeddings: int = 32768
     rms_norm_eps: float = 1e-6
     rope_theta: float = 1e6
@@ -222,11 +223,13 @@ class LatentAttention(nn.Module):
 
 
 class RoutedFFN(nn.Module):
-    """Σ over the chosen HELD experts + the shared experts; returns (y,
-    routing) with ``routing`` = scores [T, E], choice [T, k], load [E] and
-    the two counts of ``parallel/moe.routed_experts``."""
+    """Σ over the chosen HELD experts + the shared experts (where the model
+    has any); returns (y, routing) with ``routing`` = scores [T, E], choice
+    [T, k], load [E] and the two counts of ``parallel/moe.routed_experts``.
+    ``cfg``: this model's, or any config with the routed layer's fields
+    under the same names (``models/lfm2_moe.Lfm2MoeConfig``)."""
 
-    cfg: DeepseekV3Config
+    cfg: Any
 
     @nn.compact
     def __call__(self, x):
@@ -247,20 +250,21 @@ class RoutedFFN(nn.Module):
             precision=jax.lax.Precision.HIGHEST,
         ))
         choice, weights = route_top_k(
-            scores, bias, cfg.num_experts_per_tok, cfg.routed_scaling_factor
+            scores, bias, cfg.num_experts_per_tok, cfg.routed_scaling_factor,
+            cfg.route_eps,
         )
         routed, counts = routed_experts(
             tokens, choice, weights, gate.astype(cfg.dtype),
             up.astype(cfg.dtype), down.astype(cfg.dtype), (first, held),
             tile=cfg.moe_row_tile,
         )
-        shared = SwiGLU(
-            cfg, cfg.n_shared_experts * F, name="shared_experts"
-        )(x)
+        routed = routed.reshape(B, S, H)
+        if cfg.n_shared_experts:
+            routed = routed + SwiGLU(
+                cfg, cfg.n_shared_experts * F, name="shared_experts"
+            )(x).astype(jnp.float32)
         load = expert_load(choice, E)
-        y = (routed.reshape(B, S, H) + shared.astype(jnp.float32)).astype(
-            cfg.dtype
-        )
+        y = routed.astype(cfg.dtype)
         y = with_load_cotangent(y, bias, load)
         return y, dict(counts, scores=scores, choice=choice, load=load)
 

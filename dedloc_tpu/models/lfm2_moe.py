@@ -1,0 +1,442 @@
+"""LFM2-MoE (``model_type: lfm2_moe``; the published model this file was
+written for is LiquidAI/LFM2-24B-A2B), in Flax: a pre-norm decoder whose
+layers are of more than one KIND — a doubly gated short convolution in three
+layers of four, grouped-query attention in the fourth — over a dense SwiGLU
+FFN in the first layers and a dropless, bias-balanced routed FFN in the
+others. ``benchmark/reference/lfm2_moe.py`` carries the same equations in
+plain ``jax.numpy``:
+
+    x [S, H]; eps 1e-5:  h = x + Mixer(RMSNorm(x));  y = h + FFN(RMSNorm(h))
+    Mixer ``conv``:  (B | C | u) = W_in x  (H -> 3H)
+              y = W_out (C ⊙ conv3(B ⊙ u))   causal, depthwise, 3 taps, no
+              bias, no activation
+    Mixer ``full_attention``:  q = W_q x [S, 32, 64]; k, v = W_k x, W_v x
+              [S, 8, 64]; RMSNorm over each head's 64 lanes of q and of k
+              (one weight for q, one for k), THEN RoPE (rotate-half);
+              out = W_o softmax_causal(q kᵀ / 8) v, a kv head for 4 heads
+    FFN, the first ``num_dense_layers`` layers: SwiGLU, dense width
+    FFN, the others: s = sigmoid(W_r u) in float32 over ALL experts
+              choice = top_k(s + b);  w = s[choice] / (Σ s[choice] + 1e-6)
+              FFN(u) = Σ_{e in choice} w_e SwiGLU_e(u)   (no shared expert)
+    after the stack a final RMSNorm; the head is the embedding, transposed
+    loss: mean next-token cross-entropy; b stepped by the sign of its load
+    excess after every GLOBAL step, as ``models/deepseek_v3.py``'s
+
+The program's shape. ``layer_types`` (the published pattern) and
+``num_dense_layers`` say what each layer is. The leading dense layers are
+unrolled; the expert layers are cut into whole PERIODS of the pattern (four
+layers: attention, conv, conv, conv), one ``nn.scan`` over the periods with
+every layer of a period a remat'd block of its own kind, and what is left
+over after the last whole period is unrolled. Every parameter of a scanned
+layer is stacked over the periods, one leaf per position in the period.
+RMSNorm, RoPE, SwiGLU and the chunked head + cross-entropy are Ouro's; the
+routed layer is ``models/deepseek_v3.RoutedFFN`` (``parallel/moe.py``) at
+this model's sizes; the mixers' kernels are ``ops/short_conv.py`` and the
+grouped-query mode of ``ops/flash_attention.py``.
+
+**A chip's share**, as for the other expert decoder: ``expert_shard`` (the
+experts held of every layer), ``vocab_size`` (rows held) and
+``num_hidden_layers``. A depth below the published one keeps ONE leading
+dense layer and then the published pattern from the first expert layer on
+(``layer_plan``): 5 of LFM2-24B-A2B's 40 are its layers 0, 2, 3, 4, 5.
+"""
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Dict, List, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from dedloc_tpu.models.albert import remat_policy_object
+from dedloc_tpu.models.deepseek_v3 import BIAS, RoutedFFN
+from dedloc_tpu.models.ouro import (
+    RMSNorm,
+    SwiGLU,
+    _dense,
+    apply_rope,
+    chunked_cross_entropy,
+    rope_tables,
+)
+from dedloc_tpu.ops.short_conv import TAPS, short_conv
+
+CONV, ATTENTION = "conv", "full_attention"
+
+
+def _published_pattern(layers: int) -> Tuple[str, ...]:
+    """LFM2-24B-A2B's ``layer_types``: attention in layers 2, 6, 10, ..."""
+    return tuple(ATTENTION if i % 4 == 2 else CONV for i in range(layers))
+
+
+@dataclasses.dataclass(frozen=True)
+class Lfm2MoeConfig:
+    """LFM2-24B-A2B as published (``config.json``); what it does not fix is
+    in ``benchmark/configs/lfm2_24b_a2b_s4096.json`` under ``assumed``."""
+
+    vocab_size: int = 65536
+    hidden_size: int = 2048
+    num_hidden_layers: int = 40
+    num_dense_layers: int = 2
+    layer_types: Tuple[str, ...] = _published_pattern(40)
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    head_dim: int = 64
+    intermediate_size: int = 11776
+    moe_intermediate_size: int = 1536
+    num_experts: int = 64
+    num_experts_per_tok: int = 4
+    routed_scaling_factor: float = 1.0
+    route_eps: float = 1e-6
+    conv_L_cache: int = 3
+    max_position_embeddings: int = 128000
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 1e6
+    initializer_range: float = 0.02
+    bias_update_speed: float = 0.001  # as DeepseekV3Config's
+    expert_shard: Tuple[int, int] = (0, 1)
+    moe_row_tile: int = 256
+    dtype: Any = jnp.bfloat16  # compute dtype; params stay fp32
+    remat_policy: str = "kernel_outputs"
+    attention_impl: str = "flash"  # or "dense" (tests, tiny models)
+    attention_block_size: int = 512
+    loss_chunk_tokens: int = 512
+    mesh: Any = None
+
+    def __post_init__(self):
+        index, count = self.expert_shard
+        if not (0 <= index < count) or self.num_experts % count:
+            raise ValueError(
+                f"expert_shard {index}/{count}: the count must divide the "
+                f"{self.num_experts} routed experts, 0 <= index < count"
+            )
+        if self.conv_L_cache != TAPS:
+            raise ValueError(f"the conv kernels take {TAPS} taps")
+        if not 1 <= self.num_hidden_layers <= len(self.layer_types):
+            raise ValueError(
+                f"num_hidden_layers {self.num_hidden_layers}: the pattern "
+                f"has {len(self.layer_types)} layers"
+            )
+
+    # the routed layer's fields under ``RoutedFFN``'s names
+    n_routed_experts = property(lambda self: self.num_experts)
+    n_shared_experts = 0
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """(first expert held, how many)."""
+        index, count = self.expert_shard
+        n = self.num_experts // count
+        return index * n, n
+
+    @property
+    def layer_plan(self) -> List[Tuple[int, str, bool]]:
+        """(published layer, mixer kind, routed FFN?) of every layer run:
+        the published stack, or — cut in depth — ONE leading dense layer
+        and the published pattern from the first expert layer on."""
+        if self.num_hidden_layers == len(self.layer_types):
+            kept = list(range(self.num_hidden_layers))
+            dense = self.num_dense_layers
+        else:
+            kept = [0] + list(range(
+                self.num_dense_layers,
+                self.num_dense_layers + self.num_hidden_layers - 1,
+            ))
+            dense = 1
+        return [
+            (i, self.layer_types[i], n >= dense) for n, i in enumerate(kept)
+        ]
+
+    @staticmethod
+    def named(model_size: str):
+        ctors = {"lfm2_24b_a2b": Lfm2MoeConfig.lfm2_24b_a2b,
+                 "lfm2_tiny": Lfm2MoeConfig.tiny}
+        if model_size not in ctors:
+            raise ValueError(
+                f"unknown model_size {model_size!r} "
+                f"(expected one of {sorted(ctors)})"
+            )
+        return ctors[model_size]
+
+    @staticmethod
+    def lfm2_24b_a2b(**overrides) -> "Lfm2MoeConfig":
+        return Lfm2MoeConfig(**overrides)
+
+    @staticmethod
+    def tiny(**overrides) -> "Lfm2MoeConfig":
+        """Test-sized: every mechanism (two dense layers, a period of
+        attention + three convolutions and a layer over, grouped heads
+        with their q / k norm, 16 experts top-3, a chunked tied head), no
+        published width."""
+        base = dict(
+            vocab_size=256, hidden_size=32, num_hidden_layers=7,
+            num_dense_layers=2, layer_types=_published_pattern(7),
+            num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+            intermediate_size=48, moe_intermediate_size=16, num_experts=16,
+            num_experts_per_tok=3, max_position_embeddings=128,
+            moe_row_tile=8, attention_impl="dense", loss_chunk_tokens=32,
+        )
+        base.update(overrides)
+        return Lfm2MoeConfig(**base)
+
+
+class ShortConvMixer(nn.Module):
+    """W_out (C ⊙ conv3(B ⊙ u)) with (B | C | u) = W_in x: the kernel reads
+    ``in_proj``'s output as it is and writes what ``out_proj`` reads."""
+
+    cfg: Lfm2MoeConfig
+
+    @nn.compact
+    def __call__(self, hidden):
+        cfg = self.cfg
+        bcu = _dense(TAPS * cfg.hidden_size, cfg, "in_proj")(hidden)
+        taps = self.param(
+            "conv", nn.initializers.normal(cfg.initializer_range),
+            (cfg.hidden_size, cfg.conv_L_cache), jnp.float32,
+        )
+        y = short_conv(bcu, taps, mesh=cfg.mesh)
+        return _dense(cfg.hidden_size, cfg, "out_proj")(y)
+
+
+class GroupedAttention(nn.Module):
+    cfg: Lfm2MoeConfig
+
+    @nn.compact
+    def __call__(self, hidden, rope):
+        cfg = self.cfg
+        B, S, _ = hidden.shape
+        H, KV, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                    cfg.head_dim)
+        cos, sin = rope
+        q = _dense(H * D, cfg, "q_proj")(hidden).reshape(B, S, H, D)
+        k = _dense(KV * D, cfg, "k_proj")(hidden).reshape(B, S, KV, D)
+        v = _dense(KV * D, cfg, "v_proj")(hidden).reshape(B, S, KV, D)
+        # per head, over its own lanes; then the rotation
+        q = apply_rope(RMSNorm(cfg, name="q_layernorm")(q), cos, sin)
+        k = apply_rope(RMSNorm(cfg, name="k_layernorm")(k), cos, sin)
+        if cfg.attention_impl == "flash":
+            from dedloc_tpu.ops.flash_attention import flash_attention
+
+            ctx = flash_attention(
+                q, k, v, causal=True, block_q=cfg.attention_block_size,
+                block_k=cfg.attention_block_size, mesh=cfg.mesh,
+            )
+        elif cfg.attention_impl == "dense":
+            q, k, v = (checkpoint_name(x, "flash_qkv") for x in (q, k, v))
+            grouped = q.reshape(B, S, KV, H // KV, D)
+            logits = jnp.einsum(
+                "bqcgd,bkcd->bcgqk", grouped, k,
+                preferred_element_type=jnp.float32,
+            ) / jnp.sqrt(jnp.float32(D))
+            visible = jnp.tril(jnp.ones((S, S), bool))
+            logits = jnp.where(visible, logits, -1e30)
+            probs = jax.nn.softmax(logits, axis=-1).astype(cfg.dtype)
+            ctx = jnp.einsum("bcgqk,bkcd->bqcgd", probs, v)
+        else:
+            raise ValueError(
+                f"attention_impl={cfg.attention_impl!r}: this model takes "
+                "'flash' or 'dense'"
+            )
+        return _dense(cfg.hidden_size, cfg, "out_proj")(
+            ctx.reshape(B, S, H * D)
+        )
+
+
+class DecoderLayer(nn.Module):
+    """h = x + Mixer(RMSNorm(x)); y = h + FFN(RMSNorm(h)): ``mixer`` is
+    ``conv`` or ``full_attention``, the FFN a dense SwiGLU (``sparse``
+    False; returns y) or the routed layer (returns (y, routing))."""
+
+    cfg: Lfm2MoeConfig
+    mixer: str
+    sparse: bool
+
+    @nn.compact
+    def __call__(self, hidden, rope):
+        cfg = self.cfg
+        x = RMSNorm(cfg, name="operator_norm")(hidden)
+        if self.mixer == CONV:
+            hidden = hidden + ShortConvMixer(cfg, name="conv")(x)
+        else:
+            hidden = hidden + GroupedAttention(cfg, name="self_attn")(x, rope)
+        x = RMSNorm(cfg, name="ffn_norm")(hidden)
+        if not self.sparse:
+            return hidden + SwiGLU(
+                cfg, cfg.intermediate_size, name="feed_forward"
+            )(x)
+        y, routing = RoutedFFN(cfg, name="feed_forward")(x)
+        return hidden + y, routing
+
+
+def _layer(cfg: Lfm2MoeConfig, mixer: str, sparse: bool, name: str):
+    return nn.remat(
+        DecoderLayer, policy=remat_policy_object(cfg.remat_policy)
+    )(cfg, mixer, sparse, name=name)
+
+
+class _Period(nn.Module):
+    """Scan body: one period of the pattern, a remat'd layer per position.
+    carry = hidden; rope broadcast; per-step out = the period's routing."""
+
+    cfg: Lfm2MoeConfig
+    mixers: Tuple[str, ...]
+
+    @nn.compact
+    def __call__(self, hidden, rope):
+        routings = []
+        for i, mixer in enumerate(self.mixers):
+            hidden, routing = _layer(self.cfg, mixer, True, f"layer_{i}")(
+                hidden, rope
+            )
+            routings.append(routing)
+        return hidden, jax.tree.map(lambda *xs: jnp.stack(xs), *routings)
+
+
+def _period_of(mixers: List[str]) -> int:
+    """The shortest period the expert layers' mixer kinds repeat with."""
+    for period in range(1, len(mixers) + 1):
+        if all(a == b for a, b in zip(mixers, mixers[period:])):
+            return period
+    return max(len(mixers), 1)
+
+
+class Lfm2MoeForCausalLM(nn.Module):
+    """``__call__(input_ids)`` -> (hidden [B, S, H] after the final norm, in
+    the compute dtype; routing, every entry stacked over the expert layers
+    in order). The head is ``embed_tokens`` transposed, applied by
+    ``lfm2_moe_loss`` a chunk of tokens at a time."""
+
+    cfg: Lfm2MoeConfig
+
+    @nn.compact
+    def __call__(self, input_ids) -> Tuple[jnp.ndarray, Dict[str, Any]]:
+        cfg = self.cfg
+        embed = self.param(
+            "embed_tokens", nn.initializers.normal(cfg.initializer_range),
+            (cfg.vocab_size, cfg.hidden_size), jnp.float32,
+        )
+        hidden = jnp.take(embed, input_ids, axis=0).astype(cfg.dtype)
+        rope = rope_tables(input_ids.shape[1], cfg.head_dim, cfg.rope_theta)
+        plan = cfg.layer_plan
+        dense = [mixer for _i, mixer, sparse in plan if not sparse]
+        mixers = [mixer for _i, mixer, sparse in plan if sparse]
+        for i, mixer in enumerate(dense):
+            hidden = _layer(cfg, mixer, False, f"dense_layer_{i}")(
+                hidden, rope
+            )
+        period = _period_of(mixers)
+        periods = len(mixers) // period
+        routings = []
+        if periods:
+            stack = nn.scan(
+                _Period,
+                variable_axes={"params": 0},
+                split_rngs={"params": True},
+                in_axes=nn.broadcast,
+                length=periods,
+            )
+            hidden, routing = stack(
+                cfg, tuple(mixers[:period]), name="layers"
+            )(hidden, rope)
+            # [periods, period, ...] -> [layers, ...]
+            routings.append(jax.tree.map(
+                lambda x: x.reshape((-1,) + x.shape[2:]), routing
+            ))
+        for i, mixer in enumerate(mixers[periods * period:]):
+            hidden, routing = _layer(cfg, mixer, True, f"tail_layer_{i}")(
+                hidden, rope
+            )
+            routings.append(jax.tree.map(lambda x: x[None], routing))
+        routing = jax.tree.map(
+            lambda *xs: jnp.concatenate(xs), *routings
+        ) if len(routings) > 1 else routings[0]
+        return RMSNorm(cfg, name="norm")(hidden), routing
+
+
+def _leaf_name(path) -> str:
+    return path[-1].key
+
+
+def lfm2_moe_loss(model: Lfm2MoeForCausalLM, params,
+                  batch: Dict[str, jnp.ndarray]):
+    """(loss, metrics) of one micro-batch: ``input_ids`` and next-token
+    ``labels``, [B, S] each, no padding; the metrics are
+    ``models/deepseek_v3.deepseek_v3_loss``'s (the routing gauges and this
+    micro-batch's routing as the step computed it)."""
+    cfg = model.cfg
+    hidden, routing = model.apply({"params": params}, batch["input_ids"])
+    ce = chunked_cross_entropy(
+        hidden.reshape(1, -1, cfg.hidden_size),
+        params["embed_tokens"].astype(cfg.dtype).T,  # the tied head
+        batch["labels"].reshape(-1), cfg.loss_chunk_tokens,
+    )
+    loss = jnp.mean(ce)
+    load = routing["load"]  # [L, E]
+    return loss, {
+        "loss": loss,
+        "moe.load_max_over_mean": jnp.max(load, axis=1) / jnp.mean(
+            load, axis=1
+        ),
+        "moe.local_slot_share": jnp.mean(routing["local_slot_share"]),
+        "moe.bias_abs_max": jnp.max(jnp.stack([
+            jnp.max(jnp.abs(leaf))
+            for path, leaf in jax.tree_util.tree_leaves_with_path(params)
+            if _leaf_name(path) == BIAS
+        ])),
+        "moe.dropped_slots": jnp.sum(routing["dropped_slots"]),
+        "moe.choice": routing["choice"],
+        "moe.scores": routing["scores"],
+    }
+
+
+def lfm2_moe_weight_decay_mask(params):
+    """True where weight decay applies: every matrix and the conv taps; not
+    the RMSNorm ``weight``s nor the correction bias."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: _leaf_name(path) not in ("weight", BIAS), params
+    )
+
+
+def lfm2_moe_sign_step_mask(params):
+    """True for the leaves stepped by the sign of their (load) cotangent:
+    the expert layers' correction biases."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: _leaf_name(path) == BIAS, params
+    )
+
+
+def lfm2_moe_layer_flops_per_token(cfg: Lfm2MoeConfig,
+                                   seq: int) -> Dict[str, float]:
+    """Forward matmul FLOPs a token of one layer part, by kind: the two
+    mixers, the two FFNs (routed work for the HELD experts at the expected
+    share of slots) and the tied head over the held rows."""
+    h, d = cfg.hidden_size, cfg.head_dim
+    heads, kv = cfg.num_attention_heads, cfg.num_key_value_heads
+    held_share = cfg.held_experts[1] / cfg.num_experts
+    return {
+        CONV: 2 * h * TAPS * h + 2 * h * h,  # in_proj, out_proj
+        ATTENTION: (
+            2 * h * (heads + 2 * kv) * d + 2 * heads * d * h  # q k v, out
+            + 2 * 2 * heads * d * (seq + 1) / 2  # QKᵀ, PV over the triangle
+        ),
+        "dense_ffn": 2 * 3 * h * cfg.intermediate_size,
+        "routed_ffn": (
+            2 * h * cfg.num_experts
+            + 2 * 3 * h * cfg.moe_intermediate_size
+            * cfg.num_experts_per_tok * held_share
+        ),
+        "head": 2 * h * cfg.vocab_size,
+    }
+
+
+def lfm2_moe_train_tflops_per_sample(cfg: Lfm2MoeConfig, seq: int) -> float:
+    """Analytic MODEL TFLOPs of one forward + backward row of ``seq``
+    tokens (matmuls only, backward = 2x forward, remat's replays and the
+    element-wise convolution not counted)."""
+    part = lfm2_moe_layer_flops_per_token(cfg, seq)
+    per_token = part["head"] + sum(
+        part[mixer] + part["routed_ffn" if sparse else "dense_ffn"]
+        for _i, mixer, sparse in cfg.layer_plan
+    )
+    return 3.0 * per_token * seq / 1e12

@@ -181,14 +181,12 @@ class OuroAttention(nn.Module):
     def __call__(self, hidden, rope):
         cfg = self.cfg
         B, S, _ = hidden.shape
-        H, D = cfg.num_attention_heads, cfg.head_dim
-        if cfg.num_key_value_heads != H:
-            raise ValueError("grouped-query attention is not implemented: "
-                             "Ouro-2.6B has as many KV heads as heads")
+        H, KV, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                    cfg.head_dim)
         cos, sin = rope
         q = _dense(H * D, cfg, "q_proj")(hidden).reshape(B, S, H, D)
-        k = _dense(H * D, cfg, "k_proj")(hidden).reshape(B, S, H, D)
-        v = _dense(H * D, cfg, "v_proj")(hidden).reshape(B, S, H, D)
+        k = _dense(KV * D, cfg, "k_proj")(hidden).reshape(B, S, KV, D)
+        v = _dense(KV * D, cfg, "v_proj")(hidden).reshape(B, S, KV, D)
         q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
         if cfg.attention_impl == "flash":
             from dedloc_tpu.ops.flash_attention import flash_attention
@@ -199,6 +197,8 @@ class OuroAttention(nn.Module):
             )
         elif cfg.attention_impl == "dense":
             q, k, v = (checkpoint_name(x, "flash_qkv") for x in (q, k, v))
+            if KV != H:  # each kv head for its H / KV query heads
+                k, v = (jnp.repeat(x, H // KV, axis=2) for x in (k, v))
             logits = jnp.einsum(
                 "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
             ) / jnp.sqrt(jnp.float32(D))
@@ -402,8 +402,9 @@ def ouro_train_tflops_per_sample(cfg: OuroConfig, seq: int) -> float:
     counted, causal attention at its triangle)."""
     h, i, d = cfg.hidden_size, cfg.intermediate_size, cfg.head_dim
     width = cfg.num_attention_heads * d
+    kv_width = cfg.num_key_value_heads * d
     per_token_layer = (
-        2 * 4 * h * width  # q, k, v, o
+        2 * 2 * h * (width + kv_width)  # q, o; k, v
         + 2 * 3 * h * i  # gate, up, down
         + 2 * 2 * width * (seq + 1) / 2  # QKᵀ and PV over the triangle
     )

@@ -70,7 +70,10 @@ it is non-differentiable (it comes from the attention mask). A decoder's
 causal mask is a MODE of the same kernels (``causal=True``; see "causal
 tiles" below): tiles above the diagonal are neither fetched nor computed,
 tiles the diagonal crosses get an iota mask, and the kernels are named
-``flash_causal_*`` in a device trace.
+``flash_causal_*`` in a device trace. GROUPED-QUERY attention is read from
+the shapes: k and v with fewer heads than q stay that narrow in HBM, forward
+and backward, and dk / dv are summed over a group inside the kernel (see
+"grouped-query heads" below; ``flash_gqa_*`` in a device trace).
 
 Off-TPU (CPU tests, CI) the same kernels run under ``interpret=True``
 (``utils.backend.pallas_interpret`` decides, once, for every op here).
@@ -289,11 +292,131 @@ def _lanes(d: int, dv: int, g: int) -> Optional[dict]:
     return None if whole else lanes
 
 
+def _metadata(d: int, dv: int, g: int, q, k) -> Optional[dict]:
+    """A call's kernel ``metadata``: its windows where they are narrower
+    than its blocks (``_lanes``), its head counts where k has fewer than q;
+    None for every other call (see ``_lanes`` for why not more)."""
+    h, kvh = q.shape[-1] // d, k.shape[-1] // d
+    if kvh == h:
+        return _lanes(d, dv, g)
+    return {"heads": h, "kv_heads": kvh}
+
+
 def _dot(a, b, contract_a: int, contract_b: int):
     return jax.lax.dot_general(
         a, b, (((contract_a,), (contract_b,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
+
+
+# ----------------------------------------------------- grouped-query heads
+#
+# With fewer key/value heads than query heads (``group`` = H / H_kv query
+# heads read one kv head) k and v stay at their own width in HBM, forward
+# and backward: a program's kv BLOCK is the column block(s) of k / v that
+# hold its query heads' kv heads, and a query column block takes its ONE kv
+# head out of it (the g heads of a column block share a kv head: ``group``
+# is a multiple of g). At D=128 that kv head is a lane tile of its own. At
+# D=64 it is one HALF of a 128-lane tile while the block's two query heads
+# sit in both halves, so the tile is rebuilt with the kv head in BOTH
+# (``_group_tile``: one lane rotation by 64 and a select, once per kv head
+# and tile, shared by every query head of the group in the program); the
+# per-head products then zero the other head's half exactly as the
+# equal-count kernels do. dK / dV: the heads of a column block sum into the
+# block's tile slot by slot, the tile is folded onto the kv head's half
+# (``_fold_group``) and added to the kv block's float32 accumulator, which
+# lives across the group's query programs — the ``members`` axis of the dkv
+# grid — and is written once per kv head.
+
+
+def _grouped(q, k, d: int, dv: int, g: int, hp: int):
+    """(group, kv heads per kv block, query heads per program) of a call:
+    (1, hp, hp) with as many kv heads as heads. A grouped program's kv block
+    is whole column blocks (``g`` kv heads at least); where a program's
+    query heads span fewer kv heads than that, ``g·group / hp`` programs
+    share the block."""
+    h, kvh = q.shape[-1] // d, k.shape[-1] // d
+    if kvh == h:
+        return 1, hp, hp
+    group = h // kvh
+    if (d != dv or h % kvh or g > 2 or (g * d) % 128 or kvh % g
+            or group % g):
+        raise ValueError(
+            f"grouped-query attention takes {h} query heads over {kvh} kv "
+            f"heads of {d} only where a column block of {g} heads is whole "
+            "lane tiles and shares one kv head (head widths 64 and 128)"
+        )
+
+    def kv_block(hp):
+        return max(g, hp // group)
+
+    while hp > g and (kvh % kv_block(hp) or (kv_block(hp) * group) % hp):
+        hp //= 2
+    return group, kv_block(hp), hp
+
+
+def _group_slot(h0: int, hp: int, kvb: int, group: int, g: int, program):
+    """Where the kv head of the query column block at local head ``h0``
+    sits in the program's kv block: (column block, slot in it). Both are
+    ints where one query program covers the kv block; else the block is
+    ONE column block and the slot follows the program's place in its group
+    (``program``: its index across the query width) — a traced scalar."""
+    if kvb * group == hp:
+        return (h0 // group) // g, (h0 // group) % g
+    if g == 1:
+        return 0, 0
+    return 0, ((program * hp + h0) // group) % g
+
+
+def _swap_halves(x, d: int):
+    """[N, 2·d] with its two lane halves exchanged: a lane rotation by d.
+    Mosaic rotates 32-bit words only; rows of a narrower dtype are packed
+    in pairs into such words lane by lane, so the rotation is the same."""
+    if x.dtype.itemsize == 4:
+        return pltpu.roll(x, d, 1)
+    return pltpu.bitcast(
+        pltpu.roll(pltpu.bitcast(x, jnp.uint32), d, 1), x.dtype
+    )
+
+
+def _low_half(shape, d: int):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 1) < d
+
+
+def _group_tile(ref, at, d: int, g: int):
+    """The kv column block ``at`` = (column block, slot) of ``ref``'s tile
+    with that slot's kv head in EVERY slot: the tile itself at D=128; at
+    D=64 the head's half rotated into the other half too."""
+    block, slot = at
+    x = ref[:, block * g * d:(block + 1) * g * d]
+    if g == 1:
+        return x
+    other = _swap_halves(x, d)
+    return jnp.where(_low_half(x.shape, d) == (slot == 0), x, other)
+
+
+def _group_tiles(refs, h0, hp, group, g, d, program, made):
+    """[each of ``refs``' (k, v) tiles with the kv head of the query column
+    block at ``h0`` in every slot], and where that head sits; built once
+    per kv head (``made``: the program's tiles so far)."""
+    at = _group_slot(h0, hp, refs[0].shape[-1] // d, group, g, program)
+    key = h0 // group
+    if key not in made:
+        made[key] = [_group_tile(ref, at, d, g) for ref in refs]
+    return made[key], at
+
+
+def _fold_group(acc_ref, at, total, d: int, g: int) -> None:
+    """Add a query column block's dK or dV ``total`` (head i's part in slot
+    i) to its kv head's slot of the accumulator."""
+    block, slot = at
+    lanes = slice(block * g * d, (block + 1) * g * d)
+    if g == 1:
+        acc_ref[:, lanes] += total
+        return
+    both = total + _swap_halves(total, d)  # every slot: the heads' sum
+    mine = _low_half(total.shape, d) == (slot == 0)
+    acc_ref[:, lanes] += jnp.where(mine, both, jnp.zeros_like(both))
 
 
 # ------------------------------------------------------------ causal tiles
@@ -350,9 +473,10 @@ def _masked(s, mask):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, scale, d, dv, g, causal):
+                acc_ref, m_ref, l_ref, *, scale, d, dv, g, causal, group=1):
     kb = pl.program_id(3)
     nk = pl.num_programs(3)
+    program = pl.program_id(1) if group > 1 else None
     blocks = _column_blocks(q_ref.shape[-1], g, d, dv)
 
     @pl.when(kb == 0)
@@ -363,6 +487,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
 
     def tile(mask):
         b = bias_ref[:].astype(jnp.float32)  # [1, Bk]
+        made = {}
         # several column blocks per program (unrolled): one grid step's DMAs
         # and semaphore work amortise over their heads' matmuls — at D=64
         # the per-head dots are too small to hide the per-program overhead
@@ -371,8 +496,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
             # per head, its window of the block: [Bq, g·D] for both heads
             # at D=64, [Bq, 256] of the 384 lanes at 192
             q = _per_window(d, g, lambda w: q_ref[:, _at(cols, w)])
-            k = _per_window(d, g, lambda w: k_ref[:, _at(cols, w)])
-            v = _per_window(dv, g, lambda w: v_ref[:, _at(vcols, w)])
+            if group == 1:
+                k = _per_window(d, g, lambda w: k_ref[:, _at(cols, w)])
+                v = _per_window(dv, g, lambda w: v_ref[:, _at(vcols, w)])
+            else:  # the group's ONE kv head, for every head of the block
+                (k_tile, v_tile), _ = _group_tiles(
+                    (k_ref, v_ref), h0, q_ref.shape[-1] // d, group, g, d,
+                    program, made,
+                )
+                k, v = [k_tile] * g, [v_tile] * g
             pv, corrs = [None] * len(_segments(dv, g)), []
             for i in range(g):
                 h = h0 + i
@@ -457,9 +589,13 @@ def _fwd_one_tile_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
             ).astype(o_ref.dtype)
 
 
-def _name(kernel: str, causal: bool, d: int, dv: int) -> str:
+def _name(kernel: str, causal: bool, d: int, dv: int,
+          group: int = 1) -> str:
     """The causal kernels keep names of their own in a device trace, and so
-    do the two-width ones (latent attention: q/k wider than v and out)."""
+    do the two-width ones (latent attention: q/k wider than v and out) and
+    the grouped-query ones (fewer kv heads than heads)."""
+    if group > 1:
+        return f"flash_gqa_{kernel}" if causal else f"flash_gqa_full_{kernel}"
     if d != dv:
         return f"flash_mla_{kernel}" if causal else f"flash_mla_full_{kernel}"
     return f"flash_causal_{kernel}" if causal else f"flash_{kernel}"
@@ -467,7 +603,9 @@ def _name(kernel: str, causal: bool, d: int, dv: int) -> str:
 
 def _fwd(q, k, v, bias, d, dv, block_q, block_k, causal, interpret):
     """Returns (out [B, S, H·dv], lse [B·H, 1, S])."""
-    if _one_tile(q.shape[1], block_q, block_k):
+    # grouped-query calls take the tiled form at every length (one tile is
+    # then a grid of one): the one-tile kernels have no kv block of their own
+    if _one_tile(q.shape[1], block_q, block_k) and k.shape == q.shape:
         return _fwd_one_tile(q, k, v, bias, d, dv, causal, interpret)
     return _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, causal,
                       interpret)
@@ -476,23 +614,27 @@ def _fwd(q, k, v, bias, d, dv, block_q, block_k, causal, interpret):
 def _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, causal, interpret):
     b, s, h, g, hp, bq, bk = _geometry(q, d, dv, block_q, block_k,
                                        budget_mb=6.0)
+    group, kvb, hp = _grouped(q, k, d, dv, g, hp)
     hpb = h // hp  # programs across the width
 
     def k_at(j, kb):  # a tile above the diagonal re-names the last needed
         return jnp.minimum(kb, _last_k_tile(j, bq, bk)) if causal else kb
 
+    def kv_at(p):  # the kv block of query program p
+        return p if group == 1 else p * hp // group // kvb
+
     out, lse = pl.pallas_call(
         functools.partial(
             _fwd_kernel, scale=1.0 / (d ** 0.5), d=d, dv=dv, g=g,
-            causal=causal,
+            causal=causal, group=group,
         ),
         grid=(b, hpb, s // bq, s // bk),
         in_specs=[
             pl.BlockSpec((None, bq, hp * d), lambda n, p, j, kb: (n, j, p)),
-            pl.BlockSpec((None, bk, hp * d),
-                         lambda n, p, j, kb: (n, k_at(j, kb), p)),
-            pl.BlockSpec((None, bk, hp * dv),
-                         lambda n, p, j, kb: (n, k_at(j, kb), p)),
+            pl.BlockSpec((None, bk, kvb * d),
+                         lambda n, p, j, kb: (n, k_at(j, kb), kv_at(p))),
+            pl.BlockSpec((None, bk, kvb * dv),
+                         lambda n, p, j, kb: (n, k_at(j, kb), kv_at(p))),
             pl.BlockSpec((None, 1, bk),
                          lambda n, p, j, kb: (n, 0, k_at(j, kb))),
         ],
@@ -502,7 +644,7 @@ def _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, causal, interpret):
                          lambda n, p, j, kb: (n * hpb + p, 0, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(v.shape, q.dtype),
+            jax.ShapeDtypeStruct((b, s, h * dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, 1, s), jnp.float32),
         ],
         scratch_shapes=[
@@ -511,8 +653,8 @@ def _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, causal, interpret):
             pltpu.VMEM((hp, bq, 1), jnp.float32),
         ],
         interpret=interpret,
-        name=_name("fwd", causal, d, dv),
-        metadata=_lanes(d, dv, g),
+        name=_name("fwd", causal, d, dv, group),
+        metadata=_metadata(d, dv, g, q, k),
     )(q, k, v, bias)
     return out, lse
 
@@ -556,22 +698,27 @@ _Head = collections.namedtuple("_Head", "p ds q k do")
 
 
 def _backward_heads(refs, bias_ref, lse_ref, h0, cols, vcols, mask, *,
-                    scale, d, dv, g):
+                    scale, d, dv, g, group_kv=None):
     """The g heads of one column block (heads ``h0``.., lanes ``cols`` of
     the program's tiles), one at a time: each head's probability tile ``p``
     and the gradient ``ds`` of its scores ([Bq, Bk], recomputed from the
     residuals in fp32, cast for the MXU), with q, k and dO cut down to that
     head's lanes of its window for the products that follow (``_into``
     adds them up). ``mask``: the causal mask of a tile the diagonal
-    crosses, else None."""
+    crosses, else None. ``group_kv``: a grouped-query block's (k, v) tiles
+    (``_group_tiles``) in place of the refs' own columns."""
     # dO stays in its native (bf16) dtype for the dots — MXU at full rate
     q_ref, k_ref, v_ref, do_ref, o_ref = refs
     q = _per_window(d, g, lambda w: q_ref[:, _at(cols, w)])
-    k = _per_window(d, g, lambda w: k_ref[:, _at(cols, w)])
+    if group_kv is None:
+        k = _per_window(d, g, lambda w: k_ref[:, _at(cols, w)])
+    else:
+        k = [group_kv[0]] * g
 
     def v_side(w):  # v, dO and dO ⊙ O over one window
         at = _at(vcols, w)
-        v, do, o = v_ref[:, at], do_ref[:, at], o_ref[:, at]
+        v = v_ref[:, at] if group_kv is None else group_kv[1]
+        do, o = do_ref[:, at], o_ref[:, at]
         return v, do, do.astype(jnp.float32) * o.astype(jnp.float32)
 
     v_do_prod = _per_window(dv, g, v_side)
@@ -590,21 +737,28 @@ def _backward_heads(refs, bias_ref, lse_ref, h0, cols, vcols, mask, *,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
-               dq_ref, dq_acc_ref, *, scale, d, dv, g, causal):
+               dq_ref, dq_acc_ref, *, scale, d, dv, g, causal, group=1):
     kb = pl.program_id(3)
     nk = pl.num_programs(3)
+    program = pl.program_id(1) if group > 1 else None
 
     @pl.when(kb == 0)
     def _init():
         dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
 
     def tile(mask):
+        made = {}
         for h0, cols, vcols in _column_blocks(q_ref.shape[-1], g, d, dv):
             dq_at = _segments_at(cols, d, g)
             dq = [dq_acc_ref[:, at] for at in dq_at]
+            group_kv = None if group == 1 else _group_tiles(
+                (k_ref, v_ref), h0, q_ref.shape[-1] // d, group, g, d,
+                program, made,
+            )[0]
             for i, head in enumerate(_backward_heads(
                 (q_ref, k_ref, v_ref, do_ref, o_ref), bias_ref, lse_ref, h0,
                 cols, vcols, mask, scale=scale, d=d, dv=dv, g=g,
+                group_kv=group_kv,
             )):
                 _into(dq, i, d, g, _dot(head.ds, head.k, 1, 0))
             for at, total in zip(dq_at, dq):
@@ -620,24 +774,48 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
                 dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, scale, d, dv, g,
-                causal):
-    qb = pl.program_id(3)
-    nq = pl.num_programs(3)
+                causal, group=1):
+    # grid (B, across the kv width, kv tile, [the kv block's query programs
+    # — a grouped call's ``members`` —], query tile): the accumulators live
+    # over everything inside the kv tile's axis
+    qb = pl.program_id(3 if group == 1 else 4)
+    nq = pl.num_programs(3 if group == 1 else 4)
+    if group > 1:
+        member, members = pl.program_id(3), pl.num_programs(3)
+        program = pl.program_id(1) * members + member
 
-    @pl.when(qb == 0)
+    def of_group(mine, at):  # ... and in the group's first / last program
+        return mine if group == 1 else mine & (member == at % members)
+
+    @pl.when(of_group(qb == 0, 0))
     def _init():
         dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
 
     def tile(mask):
+        made = {}
         for h0, cols, vcols in _column_blocks(q_ref.shape[-1], g, d, dv):
+            heads = functools.partial(
+                _backward_heads, (q_ref, k_ref, v_ref, do_ref, o_ref),
+                bias_ref, lse_ref, h0, cols, vcols, mask, scale=scale, d=d,
+                dv=dv, g=g,
+            )
+            if group > 1:  # sum the block's heads, fold onto their kv head
+                group_kv, at = _group_tiles(
+                    (k_ref, v_ref), h0, q_ref.shape[-1] // d, group, g, d,
+                    program, made,
+                )
+                dk, dv_ = [None], [None]
+                for i, head in enumerate(heads(group_kv=group_kv)):
+                    _into(dv_, i, dv, g, _dot(head.p, head.do, 0, 0))
+                    _into(dk, i, d, g, _dot(head.ds, head.q, 0, 0))
+                _fold_group(dk_acc_ref, at, dk[0], d, g)
+                _fold_group(dv_acc_ref, at, dv_[0], d, g)
+                continue
             dk_at, dv_at = _segments_at(cols, d, g), _segments_at(vcols, dv, g)
             dk = [dk_acc_ref[:, at] for at in dk_at]
             dv_ = [dv_acc_ref[:, at] for at in dv_at]
-            for i, head in enumerate(_backward_heads(
-                (q_ref, k_ref, v_ref, do_ref, o_ref), bias_ref, lse_ref, h0,
-                cols, vcols, mask, scale=scale, d=d, dv=dv, g=g,
-            )):
+            for i, head in enumerate(heads()):
                 _into(dv_, i, dv, g, _dot(head.p, head.do, 0, 0))
                 _into(dk, i, d, g, _dot(head.ds, head.q, 0, 0))
             for at, total in zip(dk_at, dk):
@@ -648,7 +826,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
     _for_tile(causal, qb, pl.program_id(2), q_ref.shape[0], k_ref.shape[0],
               tile)
 
-    @pl.when(qb == nq - 1)
+    @pl.when(of_group(qb == nq - 1, -1))
     def _flush():
         dk_ref[:] = dk_acc_ref[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_acc_ref[:].astype(dv_ref.dtype)
@@ -682,6 +860,9 @@ def _dqkv_fused_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref,
 
 def _bwd(q, k, v, bias, lse, do, out, d, dv, block_q, block_k, causal,
          interpret):
+    if k.shape != q.shape:
+        return _bwd_grouped(q, k, v, bias, lse, do, out, d, block_q, block_k,
+                            causal, interpret)
     if _one_tile(q.shape[1], block_q, block_k):
         return _bwd_fused(q, k, v, bias, lse, do, out, d, dv, causal,
                           interpret)
@@ -748,6 +929,83 @@ def _bwd(q, k, v, bias, lse, do, out, d, dv, block_q, block_k, causal,
         interpret=interpret,
         name=_name("bwd_dkv", causal, d, dv),
         metadata=_lanes(d, dv, g),
+    )(q, k, v, bias, lse, do, out)
+    return dq, dk, dv
+
+
+def _bwd_grouped(q, k, v, bias, lse, do, out, d, block_q, block_k, causal,
+                 interpret):
+    """The two-kernel backward with fewer kv heads than heads (see
+    "grouped-query heads"): dq as ever, its k / v blocks the group's; dk and
+    dv on a grid with one more axis, the query programs that share a kv
+    block, inside the kv tile's and outside the query tile's."""
+    b, s, h, g, hp, bq, bk = _geometry(q, d, d, block_q, block_k,
+                                       budget_mb=4.0)
+    group, kvb, hp = _grouped(q, k, d, d, g, hp)
+    hpb, members = h // hp, kvb * group // hp
+    kernel_args = dict(scale=1.0 / (d ** 0.5), d=d, dv=d, g=g,
+                       causal=causal, group=group)
+    names = dict(interpret=interpret, metadata=_metadata(d, d, g, q, k))
+
+    def last_k(j, kb):
+        return jnp.minimum(kb, _last_k_tile(j, bq, bk)) if causal else kb
+
+    def first_q(kt, qt):
+        return jnp.maximum(qt, _first_q_tile(kt, bq, bk)) if causal else qt
+
+    def kv_at(p):
+        return p * hp // group // kvb
+
+    q_side = pl.BlockSpec((None, bq, hp * d), lambda n, p, j, kb: (n, j, p))
+    kv_side = pl.BlockSpec(
+        (None, bk, kvb * d), lambda n, p, j, kb: (n, last_k(j, kb), kv_at(p))
+    )
+    dq = pl.pallas_call(
+        functools.partial(_dq_kernel, **kernel_args),
+        grid=(b, hpb, s // bq, s // bk),
+        in_specs=[
+            q_side, kv_side, kv_side,
+            pl.BlockSpec((None, 1, bk),
+                         lambda n, p, j, kb: (n, 0, last_k(j, kb))),
+            pl.BlockSpec((hp, 1, bq),
+                         lambda n, p, j, kb: (n * hpb + p, 0, j)),
+            q_side, q_side,
+        ],
+        out_specs=q_side,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((bq, hp * d), jnp.float32)],
+        name=_name("bwd_dq", causal, d, d, group), **names,
+    )(q, k, v, bias, lse, do, out)
+
+    # (B, kv blocks, kv tile, query programs of the block, query tile)
+    q_side = pl.BlockSpec(
+        (None, bq, hp * d),
+        lambda n, c, kt, m, qt: (n, first_q(kt, qt), c * members + m),
+    )
+    kv_side = pl.BlockSpec(
+        (None, bk, kvb * d), lambda n, c, kt, m, qt: (n, kt, c)
+    )
+    dk, dv = pl.pallas_call(
+        functools.partial(_dkv_kernel, **kernel_args),
+        grid=(b, h // hp // members, s // bk, members, s // bq),
+        in_specs=[
+            q_side, kv_side, kv_side,
+            pl.BlockSpec((None, 1, bk), lambda n, c, kt, m, qt: (n, 0, kt)),
+            pl.BlockSpec(
+                (hp, 1, bq),
+                lambda n, c, kt, m, qt: (
+                    n * hpb + c * members + m, 0, first_q(kt, qt)
+                ),
+            ),
+            q_side, q_side,
+        ],
+        out_specs=[kv_side, kv_side],
+        out_shape=[
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        scratch_shapes=[pltpu.VMEM((bk, kvb * d), jnp.float32)] * 2,
+        name=_name("bwd_dkv", causal, d, d, group), **names,
     )(q, k, v, bias, lse, do, out)
     return dq, dk, dv
 
@@ -826,8 +1084,8 @@ def _flash_local(q, k, v, bias, d, dv, block_q, block_k, causal, interpret):
 
 def flash_attention(
     q: jnp.ndarray,  # [B, S, H, D]
-    k: jnp.ndarray,
-    v: jnp.ndarray,  # [B, S, H, Dv]: Dv = D, or narrower (latent attention)
+    k: jnp.ndarray,  # [B, S, H_kv, D]: H_kv = H, or a divisor (grouped)
+    v: jnp.ndarray,  # [B, S, H_kv, Dv]: Dv = D, or narrower (latent)
     bias: Optional[jnp.ndarray] = None,  # [B, S_kv] additive
     block_q: int = 512,
     block_k: int = 512,
@@ -848,12 +1106,15 @@ def flash_attention(
     its own (latent attention: q and k 192 wide, v and the result 128): the
     same kernels with two column-block widths, scores scaled by
     1/sqrt(q's width), named ``flash_mla_*`` in a device trace; nothing is
-    padded.
+    padded. ``k`` and ``v`` may have FEWER heads than ``q`` (grouped-query
+    attention: each kv head serves H / H_kv adjacent query heads): they are
+    read at their own width, dk / dv are summed over a group inside the
+    kernel, named ``flash_gqa_*``; head widths 64 and 128.
     """
     if interpret is None:
         interpret = pallas_interpret()
     b, s, h, d = q.shape
-    dv = v.shape[-1]
+    kvh, dv = v.shape[-2:]
     if bias is None:
         bias = jnp.zeros((b, s), jnp.float32)
     op = functools.partial(
@@ -871,8 +1132,7 @@ def flash_attention(
         )
     # [B, S, H, D] <-> [B, S, H·D] is the same bytes: the dense layers'
     # own layout goes in and comes out, nothing is transposed
-    heads_flat = (b, s, h * d)
     return op(
-        q.reshape(heads_flat), k.reshape(heads_flat),
-        v.reshape(b, s, h * dv), bias,
-    ).reshape(v.shape)
+        q.reshape(b, s, h * d), k.reshape(b, s, kvh * d),
+        v.reshape(b, s, kvh * dv), bias,
+    ).reshape(b, s, h, dv)

@@ -19,7 +19,8 @@ annotations alone place the communication.
   scaled by E) exactly as in Switch, returned for the trainer to add.
 
 **Dropless top-k** (``route_top_k``, ``routed_experts``; DeepSeek-V3's
-``noaux_tc``, the layer ``models/deepseek_v3.py`` runs). Scores are sigmoids
+``noaux_tc``, the layer ``models/deepseek_v3.RoutedFFN`` runs for that
+model and for ``models/lfm2_moe.py``). Scores are sigmoids
 over ALL experts; a correction bias enters the CHOICE of the top k and not
 their weights; the weights are the chosen scores renormalised and scaled.
 The layer is TOLD which experts it holds (``held = (first, count)``: one
@@ -163,14 +164,15 @@ def moe_ffn(
 # ------------------------------------------------- dropless top-k routing
 
 
-def route_top_k(scores, bias, top_k: int, scale: float):
+def route_top_k(scores, bias, top_k: int, scale: float, eps: float = 1e-20):
     """(choice [T, k] int32, weights [T, k] float32) from sigmoid scores
     [T, E] (float32) and the correction bias [E]: the bias enters the choice
-    alone; the weights are the chosen scores over their sum, times
+    alone; the weights are the chosen scores over their sum (+ ``eps``, the
+    published model's own: 1e-20 DeepSeek-V3's, 1e-6 LFM2's), times
     ``scale``."""
     _, choice = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
     picked = jnp.take_along_axis(scores, choice, axis=-1)
-    weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + eps)
     return choice, weights * scale
 
 

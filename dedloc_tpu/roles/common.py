@@ -30,6 +30,14 @@ from dedloc_tpu.models.deepseek_v3 import (
     deepseek_v3_train_tflops_per_sample,
     deepseek_v3_weight_decay_mask,
 )
+from dedloc_tpu.models.lfm2_moe import (
+    Lfm2MoeConfig,
+    Lfm2MoeForCausalLM,
+    lfm2_moe_loss,
+    lfm2_moe_sign_step_mask,
+    lfm2_moe_train_tflops_per_sample,
+    lfm2_moe_weight_decay_mask,
+)
 from dedloc_tpu.models.ouro import (
     OuroConfig,
     OuroForCausalLM,
@@ -178,9 +186,19 @@ DEEPSEEK_V3 = ModelFamily(
     sign_step_mask=deepseek_v3_sign_step_mask,
     sign_step=DeepseekV3Config.bias_update_speed,
 )
+LFM2_MOE = dataclasses.replace(
+    DEEPSEEK_V3,  # the same source, gauges, counter and sign rule
+    config=Lfm2MoeConfig, module=Lfm2MoeForCausalLM,
+    loss=_without_rng(lfm2_moe_loss),
+    tflops_per_sample=lfm2_moe_train_tflops_per_sample,
+    weight_decay_mask=lfm2_moe_weight_decay_mask,
+    sign_step_mask=lfm2_moe_sign_step_mask,
+    sign_step=Lfm2MoeConfig.bias_update_speed,
+)
 MODEL_FAMILIES: Dict[str, ModelFamily] = {
     "tiny": ALBERT, "large": ALBERT, "ouro_tiny": OURO, "ouro_2p6b": OURO,
     "kanana2_tiny": DEEPSEEK_V3, "kanana2_30b_a3b": DEEPSEEK_V3,
+    "lfm2_tiny": LFM2_MOE, "lfm2_24b_a2b": LFM2_MOE,
 }
 
 
@@ -195,7 +213,7 @@ def model_family(model) -> ModelFamily:
             )
         return MODEL_FAMILIES[model]
     cfg = getattr(model, "cfg", model)
-    for family in (ALBERT, OURO, DEEPSEEK_V3):
+    for family in (ALBERT, OURO, DEEPSEEK_V3, LFM2_MOE):
         if isinstance(cfg, family.config):
             return family
     raise TypeError(f"no model family for {type(cfg).__name__}")

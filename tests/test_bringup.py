@@ -341,3 +341,30 @@ def test_ouro_accumulate_step_keeps_the_flash_outputs_and_fits_the_cap():
     # forward, dq, dkv: one site each
     assert row["tpu_custom_calls"] == 3
     assert row["memory"]["temp_bytes"] <= 5.35e9, row["memory"]
+
+
+def test_lfm2_accumulate_step_keeps_what_its_backward_reads():
+    """LFM2-24B-A2B at the cell's cut (5 layers: four short-convolution
+    mixers, one grouped-query attention; 1 row of 4,096), compiled for a
+    v5e alone and inside its accumulate_step: the grouped kernels read k / v
+    at 8 heads beside q's 32 (their metadata says so; no window metadata);
+    under remat ``kernel_outputs`` every kernel's outputs are kept, so each
+    forward kernel has ONE call site per mixer and the backward replays
+    none — short_conv 4 + 4, flash_gqa 1 + 1 + 1; and the program's scratch
+    beside 28 bytes a parameter of state with a draining snapshot (13.14 GB)
+    stays under the allocator's 16.91 GB with 1 GB to spare."""
+    rows = _tpu_aot("gqa_kernels", "lfm2_accumulate_step")
+    heads = {"heads": 32, "kv_heads": 8}
+    for row in rows.values():
+        assert row["flash_windows"] == {
+            "flash_gqa_fwd": heads, "flash_gqa_bwd_dq": heads,
+            "flash_gqa_bwd_dkv": heads,
+        }
+    row = rows["lfm2_accumulate_step"]
+    assert row["kernel_calls"] == {
+        "flash_gqa_fwd": 1, "flash_gqa_bwd_dq": 1, "flash_gqa_bwd_dkv": 1,
+        "short_conv_fwd": 4, "short_conv_bwd": 4,
+    }
+    assert row["tpu_custom_calls"] == 11
+    assert row["flash_fwd_forms"] == {"one_tile": 0, "tiles": 1}
+    assert 469_285_248 * 28 + row["memory"]["temp_bytes"] <= 15.9e9

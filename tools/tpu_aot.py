@@ -12,9 +12,11 @@ speed or numerics; those need the chip (``chip_smoke.py``).
     OURO_LAYERS=4 OURO_BATCH=1 python tools/tpu_aot.py ouro_accumulate_step
 
 Each line: {"program", "compile_s", "tpu_custom_calls", "flash_fwd_forms",
-"flash_windows", "layer_body_copies", "memory"} — ``flash_windows`` is each
-flash kernel's lane window beside its column block, from the call's
-metadata (``"block"`` for a call that carries none: D=64, D=128);
+"flash_windows", "layer_body_copies", "memory"} (and, for the programs of
+``COUNT_KERNEL_CALLS``, "kernel_calls": call sites by kernel name) —
+``flash_windows`` is each flash kernel's lane window beside its column
+block, from the call's metadata (``"block"`` for a call that carries none:
+D=64, D=128; its head counts for a grouped-query call);
 ``flash_fwd_forms`` counts the flash
 forward call SITES of the lowered module by the form their shapes chose
 (``one_tile``: one tile covers the sequence; ``tiles``: the online-softmax
@@ -187,6 +189,27 @@ def mla_kernels(device):
     )
 
 
+def gqa_kernels(device):
+    """The grouped-query causal kernels at the LFM2 cell's shape (32 query
+    heads over 8 kv heads of 64, 8 x 8 tiles of 512), fwd+bwd, and the
+    doubly gated short convolution at (1, 4096, 3 x 2048)."""
+    from dedloc_tpu.ops.flash_attention import flash_attention
+    from dedloc_tpu.ops.short_conv import short_conv
+
+    def loss(q, k, v, bcu, w):
+        return jnp.sum(
+            flash_attention(q, k, v, causal=True).astype(jnp.float32)
+        ) + jnp.sum(short_conv(bcu, w).astype(jnp.float32))
+
+    q = jax.ShapeDtypeStruct((1, 4096, 32, 64), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 4096, 8, 64), jnp.bfloat16)
+    bcu = jax.ShapeDtypeStruct((1, 4096, 3 * 2048), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((2048, 3), jnp.float32)
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *_on_device(device, (q, kv, kv, bcu, w))
+    )
+
+
 def _lm_model_and_state(config: str, prefix: str):
     """(args, model, state, ids) of a causal-LM cell's recipe, from its
     configuration file's flags; ``<prefix>_LAYERS`` / ``<prefix>_BATCH`` in
@@ -260,6 +283,17 @@ def kanana_accumulate_step(device):
     ))
 
 
+def lfm2_accumulate_step(device):
+    """LFM2-24B-A2B at one chip's share (``benchmark/configs/
+    lfm2_24b_a2b_s4096.json``; ``LFM2_LAYERS`` / ``LFM2_BATCH`` size another
+    cut): four short-convolution mixers and one grouped-query attention, the
+    dense layer and one scanned period of expert layers, the tied chunked
+    head."""
+    return _lm_accumulate_step(device, *_lm_model_and_state(
+        "lfm2_24b_a2b_s4096.json", "LFM2"
+    ))
+
+
 def ouro_guarded_apply_step(device):
     """The per-leaf LAMB apply of the solo boundary over the same state:
     its temp bytes are the rollback's second copy of params + moments."""
@@ -299,7 +333,7 @@ def flash_fwd_forms(lowered_text: str) -> dict:
     site inside a scan body counts once; a remat replay is a site more."""
     calls = [
         line for line in lowered_text.splitlines()
-        if re.search(r'kernel_name = "flash_(causal_|mla_)?fwd"', line)
+        if re.search(r'kernel_name = "flash_(causal_|mla_|gqa_)?fwd"', line)
     ]
     # the serialized kernel body on the same line is base64: no "_" in it
     one_tile = sum("one_tile" in line for line in calls)
@@ -337,6 +371,18 @@ def flash_windows(lowered_text: str) -> dict:
     }
 
 
+def kernel_calls(lowered_text: str) -> dict:
+    """Call SITES of every Pallas kernel of a lowered module, by kernel
+    name: which kernels a remat policy replays (a replay is a second site of
+    the forward kernel, in the backward) and which it keeps the outputs
+    of."""
+    names = re.findall(r'kernel_name = "(\w+)"', lowered_text)
+    return {name: names.count(name) for name in sorted(set(names))}
+
+
+# programs whose row also carries ``kernel_calls`` (the others print the
+# rows they always did)
+COUNT_KERNEL_CALLS = {"gqa_kernels", "lfm2_accumulate_step"}
 NO_V5E = 3  # exit code: nothing to compile with, which is not a failure
 
 
@@ -344,6 +390,7 @@ PROGRAMS = {
     fn.__name__: fn for fn in (
         accumulate_step, flat_apply_step, kernels, ouro_accumulate_step,
         ouro_guarded_apply_step, mla_kernels, kanana_accumulate_step,
+        gqa_kernels, lfm2_accumulate_step,
     )
 }
 
@@ -366,6 +413,10 @@ def main(argv=None) -> int:
         seconds = round(time.perf_counter() - start, 2)
         memory = compiled.memory_analysis()
         lowered_text = lowered.as_text()
+        extra = (
+            {"kernel_calls": kernel_calls(lowered_text)}
+            if name in COUNT_KERNEL_CALLS else {}
+        )
         print(json.dumps({
             "program": name,
             "device_kind": device.device_kind,
@@ -380,6 +431,7 @@ def main(argv=None) -> int:
                 "temp_bytes": memory.temp_size_in_bytes,
                 "alias_bytes": memory.alias_size_in_bytes,
             },
+            **extra,
         }), flush=True)
     return 0
 
