@@ -64,6 +64,11 @@ from dedloc_tpu.parallel.moe import (
 )
 
 BIAS = "e_score_correction_bias"  # the leaf the sign rule steps
+# a routed layer's held matrices: the leaves whose gradients the tile loop
+# can leave in a float32 accumulator it is handed (``parallel/moe.py``) — as
+# the collection GRAD_SINKS beside ``params``, the same names and stacking
+EXPERT_LEAVES = ("experts_gate", "experts_up", "experts_down")
+GRAD_SINKS = "grad_sinks"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -225,9 +230,11 @@ class LatentAttention(nn.Module):
 class RoutedFFN(nn.Module):
     """Σ over the chosen HELD experts + the shared experts (where the model
     has any); returns (y, routing) with ``routing`` = scores [T, E], choice
-    [T, k], load [E] and the two counts of ``parallel/moe.routed_experts``.
+    [T, k], load [E] and the counts of ``parallel/moe.routed_experts``.
     ``cfg``: this model's, or any config with the routed layer's fields
-    under the same names (``models/lfm2_moe.Lfm2MoeConfig``)."""
+    under the same names (``models/lfm2_moe.Lfm2MoeConfig``). An apply that
+    carries the collection ``GRAD_SINKS`` hands this layer's three buffers
+    to the tile loop's backward."""
 
     cfg: Any
 
@@ -253,10 +260,13 @@ class RoutedFFN(nn.Module):
             scores, bias, cfg.num_experts_per_tok, cfg.routed_scaling_factor,
             cfg.route_eps,
         )
+        sinks = tuple(
+            self.get_variable(GRAD_SINKS, name) for name in EXPERT_LEAVES
+        ) if self.has_variable(GRAD_SINKS, EXPERT_LEAVES[0]) else None
         routed, counts = routed_experts(
             tokens, choice, weights, gate.astype(cfg.dtype),
             up.astype(cfg.dtype), down.astype(cfg.dtype), (first, held),
-            tile=cfg.moe_row_tile,
+            tile=cfg.moe_row_tile, grad_sinks=sinks,
         )
         routed = routed.reshape(B, S, H)
         if cfg.n_shared_experts:
@@ -336,7 +346,7 @@ class DeepseekV3ForCausalLM(nn.Module):
             )
         stack = nn.scan(
             _ScannedLayer,
-            variable_axes={"params": 0},
+            variable_axes={"params": 0, GRAD_SINKS: 0},
             split_rngs={"params": True},
             in_axes=nn.broadcast,
             length=cfg.num_expert_layers,
@@ -345,16 +355,31 @@ class DeepseekV3ForCausalLM(nn.Module):
         return RMSNorm(cfg, name="norm")(hidden), routing
 
 
+def apply_with_grad_sinks(model, params, input_ids, grad_sinks):
+    """``model.apply`` on ``params``, with ``grad_sinks`` (None, or the
+    subtree of a float32 gradient accumulator that ``routed_grad_sink_mask``
+    marks) riding beside them as the collection ``GRAD_SINKS``."""
+    variables = {"params": params}
+    if grad_sinks is not None:
+        variables[GRAD_SINKS] = grad_sinks
+    return model.apply(variables, input_ids)
+
+
 def deepseek_v3_loss(model: DeepseekV3ForCausalLM, params,
-                     batch: Dict[str, jnp.ndarray]):
+                     batch: Dict[str, jnp.ndarray], grad_sinks=None):
     """(loss, metrics) of one micro-batch: ``input_ids`` and next-token
     ``labels``, [B, S] each, no padding. Beside the loss, the routing
     gauges of ``docs/observability.md`` and this micro-batch's routing as
     the step itself computed it (``moe.choice`` [L, T, k], ``moe.scores``
     [L, T, E]: what a check routes its reference by and compares; 8 MB at
-    the published sizes, summed by nothing)."""
+    the published sizes, summed by nothing). ``grad_sinks``:
+    ``apply_with_grad_sinks``'s; differentiated with respect to them too,
+    their cotangent is ``sink + gradient`` of the leaf of the same name,
+    whose own gradient is then zero."""
     cfg = model.cfg
-    hidden, routing = model.apply({"params": params}, batch["input_ids"])
+    hidden, routing = apply_with_grad_sinks(
+        model, params, batch["input_ids"], grad_sinks
+    )
     ce = chunked_cross_entropy(
         hidden.reshape(1, -1, cfg.hidden_size),
         params["lm_head"].astype(cfg.dtype),
@@ -372,6 +397,7 @@ def deepseek_v3_loss(model: DeepseekV3ForCausalLM, params,
             params["layers"]["block"]["mlp"][BIAS]
         )),
         "moe.dropped_slots": jnp.sum(routing["dropped_slots"]),
+        "moe.grad_sink_leaves": jnp.sum(routing["grad_sink_leaves"]),
         "moe.choice": routing["choice"],
         "moe.scores": routing["scores"],
     }
@@ -386,6 +412,14 @@ def deepseek_v3_weight_decay_mask(params):
     ``weight``s nor the correction bias."""
     return jax.tree_util.tree_map_with_path(
         lambda path, _: _leaf_name(path) not in ("weight", BIAS), params
+    )
+
+
+def routed_grad_sink_mask(params):
+    """True for the leaves whose gradient the routed loop can add into an
+    accumulator in place: the held experts' matrices."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: _leaf_name(path) in EXPERT_LEAVES, params
     )
 
 

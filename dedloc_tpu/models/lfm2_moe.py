@@ -51,7 +51,12 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from dedloc_tpu.models.albert import remat_policy_object
-from dedloc_tpu.models.deepseek_v3 import BIAS, RoutedFFN
+from dedloc_tpu.models.deepseek_v3 import (
+    BIAS,
+    GRAD_SINKS,
+    RoutedFFN,
+    apply_with_grad_sinks,
+)
 from dedloc_tpu.models.ouro import (
     RMSNorm,
     SwiGLU,
@@ -331,7 +336,7 @@ class Lfm2MoeForCausalLM(nn.Module):
         if periods:
             stack = nn.scan(
                 _Period,
-                variable_axes={"params": 0},
+                variable_axes={"params": 0, GRAD_SINKS: 0},
                 split_rngs={"params": True},
                 in_axes=nn.broadcast,
                 length=periods,
@@ -359,13 +364,15 @@ def _leaf_name(path) -> str:
 
 
 def lfm2_moe_loss(model: Lfm2MoeForCausalLM, params,
-                  batch: Dict[str, jnp.ndarray]):
+                  batch: Dict[str, jnp.ndarray], grad_sinks=None):
     """(loss, metrics) of one micro-batch: ``input_ids`` and next-token
-    ``labels``, [B, S] each, no padding; the metrics are
+    ``labels``, [B, S] each, no padding; the metrics and ``grad_sinks`` are
     ``models/deepseek_v3.deepseek_v3_loss``'s (the routing gauges and this
     micro-batch's routing as the step computed it)."""
     cfg = model.cfg
-    hidden, routing = model.apply({"params": params}, batch["input_ids"])
+    hidden, routing = apply_with_grad_sinks(
+        model, params, batch["input_ids"], grad_sinks
+    )
     ce = chunked_cross_entropy(
         hidden.reshape(1, -1, cfg.hidden_size),
         params["embed_tokens"].astype(cfg.dtype).T,  # the tied head
@@ -385,6 +392,7 @@ def lfm2_moe_loss(model: Lfm2MoeForCausalLM, params,
             if _leaf_name(path) == BIAS
         ])),
         "moe.dropped_slots": jnp.sum(routing["dropped_slots"]),
+        "moe.grad_sink_leaves": jnp.sum(routing["grad_sink_leaves"]),
         "moe.choice": routing["choice"],
         "moe.scores": routing["scores"],
     }

@@ -35,7 +35,15 @@ the work follows the rows that came, not the worst case the static shapes
 must allow — gathers a tile's rows, runs its expert's SwiGLU and adds the
 weighted result back to its tokens. The backward is the same loop written
 by hand (a dynamic trip count has no reverse-mode rule): it replays the
-tile's forward and accumulates the held experts' gradients in place.
+tile's forward and accumulates the held experts' gradients in place — into
+a zeroed float32 buffer that leaves as the matrices' cotangent, or, handed
+``grad_sinks`` (the caller's float32 gradient accumulator for the three held
+matrices), into those: the loop's carry starts from the sinks, what comes out
+is the sinks' cotangent — ``sink + d`` in float32, never rounded to the
+compute dtype — and the matrices' own cotangent is zero. An accumulator that
+adds a gradient into a running sum anyway (``parallel/train_step.
+make_accumulate_step``) takes the sink's cotangent as its new leaf: no zero
+fill, no second pass over a held matrix to add it.
 
 The bias is not trained by a gradient. ``with_load_cotangent`` defines the
 bias leaf's COTANGENT as ``load_e − mean load`` of the micro-batch (``load``:
@@ -266,16 +274,19 @@ def _tile_operands(t, tile, row_token, row_weight, tile_expert, weights):
     ), expert
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
-def _grouped_swiglu(x, row_weight, gate, up, down, row_token, tile_expert,
-                    tiles, tile):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9,))
+def _grouped_swiglu(x, row_weight, gate, up, down, sinks, row_token,
+                    tile_expert, tiles, tile):
+    """``sinks``: None, or float32 (gate, up, down)-shaped buffers the
+    forward ignores and the backward accumulates into."""
     out, _ = _grouped_swiglu_fwd(
-        x, row_weight, gate, up, down, row_token, tile_expert, tiles, tile
+        x, row_weight, gate, up, down, sinks, row_token, tile_expert, tiles,
+        tile,
     )
     return out
 
 
-def _grouped_swiglu_fwd(x, row_weight, gate, up, down, row_token,
+def _grouped_swiglu_fwd(x, row_weight, gate, up, down, sinks, row_token,
                         tile_expert, tiles, tile):
     def body(t, total):
         tokens, scale, weights, _e = _tile_operands(
@@ -288,17 +299,19 @@ def _grouped_swiglu_fwd(x, row_weight, gate, up, down, row_token,
         total = jax.lax.fori_loop(
             0, tiles, body, jnp.zeros(x.shape, jnp.float32)
         )
-    return total, (x, row_weight, gate, up, down, row_token, tile_expert,
-                   tiles)
+    return total, (x, row_weight, gate, up, down, sinks, row_token,
+                   tile_expert, tiles)
 
 
 def _grouped_swiglu_bwd(tile, residuals, d_total):
-    x, row_weight, gate, up, down, row_token, tile_expert, tiles = residuals
+    (x, row_weight, gate, up, down, sinks, row_token, tile_expert,
+     tiles) = residuals
+    held = (gate, up, down)
 
     def body(t, carry):
         dx, d_weight, d_gate, d_up, d_down = carry
         tokens, scale, (w_gate, w_up, w_down), expert = _tile_operands(
-            t, tile, row_token, row_weight, tile_expert, (gate, up, down)
+            t, tile, row_token, row_weight, tile_expert, held
         )
         rows, g, u, hidden, out = _tile_forward(
             x, w_gate, w_up, w_down, tokens
@@ -343,15 +356,22 @@ def _grouped_swiglu_bwd(tile, residuals, d_total):
             0, tiles, body, (
                 jnp.zeros(x.shape, jnp.float32),
                 jnp.zeros(row_weight.shape, jnp.float32),
-                jnp.zeros(gate.shape, jnp.float32),
-                jnp.zeros(up.shape, jnp.float32),
-                jnp.zeros(down.shape, jnp.float32),
+                # the one difference a sink makes: where the sums start
+                *(sinks if sinks is not None else (
+                    jnp.zeros(w.shape, jnp.float32) for w in held
+                )),
             ),
         )
+    summed = (d_gate, d_up, d_down)
+    if sinks is None:
+        d_held = tuple(d.astype(w.dtype) for d, w in zip(summed, held))
+        d_sinks = None
+    else:  # the sums left in the sinks, float32 as they are
+        d_held = tuple(jnp.zeros_like(w) for w in held)
+        d_sinks = summed
     int_zero = lambda a: np.zeros(a.shape, jax.dtypes.float0)  # noqa: E731
     return (
-        dx.astype(x.dtype), d_weight, d_gate.astype(gate.dtype),
-        d_up.astype(up.dtype), d_down.astype(down.dtype),
+        dx.astype(x.dtype), d_weight, *d_held, d_sinks,
         int_zero(row_token), int_zero(tile_expert), int_zero(tiles),
     )
 
@@ -360,15 +380,19 @@ _grouped_swiglu.defvjp(_grouped_swiglu_fwd, _grouped_swiglu_bwd)
 
 
 def routed_experts(x, choice, weights, gate, up, down,
-                   held: Tuple[int, int], tile: int = 256):
+                   held: Tuple[int, int], tile: int = 256, grad_sinks=None):
     """The held experts' part of Σ_{e in choice} w_e · SwiGLU_e(x).
 
     ``x`` [T, H] in the compute dtype; ``choice`` / ``weights`` [T, k] from
     ``route_top_k``; ``gate`` / ``up`` [n, H, F] and ``down`` [n, F, H] the
     HELD experts' matrices in the compute dtype, expert ``held[0] + i`` at
-    index i. Returns (y [T, H] float32, stats): ``stats['local_slot_share']``
+    index i; ``grad_sinks``: None, or three float32 buffers of the held
+    matrices' shapes whose COTANGENT is ``sink + d matrix`` while the
+    matrices' own is zero (the module docstring says who wants that).
+    Returns (y [T, H] float32, stats): ``stats['local_slot_share']``
     the share of routed slots that chose a held expert,
-    ``stats['dropped_slots']`` the valid slots the plan lost (0)."""
+    ``stats['dropped_slots']`` the valid slots the plan lost (0),
+    ``stats['grad_sink_leaves']`` the sinks the loop was handed (3 or 0)."""
     tile = min(tile, max(8, x.shape[0]))
     with jax.named_scope("moe_routed"):
         row_slot, tile_expert, tiles, dropped = _tile_plan(choice, held, tile)
@@ -377,9 +401,14 @@ def routed_experts(x, choice, weights, gate, up, down,
         row_token = slot // choice.shape[1]
         row_weight = jnp.where(real, weights.reshape(-1)[slot], 0.0)
     y = _grouped_swiglu(
-        x, row_weight, gate, up, down, row_token, tile_expert, tiles, tile
+        x, row_weight, gate, up, down,
+        None if grad_sinks is None else tuple(grad_sinks),
+        row_token, tile_expert, tiles, tile,
     )
     return y, {
+        "grad_sink_leaves": jnp.float32(
+            0 if grad_sinks is None else len(grad_sinks)
+        ),
         "local_slot_share": jnp.sum(real) / choice.size,
         "dropped_slots": dropped.astype(jnp.float32),
     }

@@ -15,6 +15,7 @@ gradient_accumulation_steps (albert/arguments.py:109).
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -50,8 +51,59 @@ class TrainState(struct.PyTreeNode):
 LossFn = Callable[..., Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]]
 
 
+@dataclasses.dataclass(frozen=True)
+class GradSinkLoss:
+    """A loss whose backward can leave some leaves' gradients IN the float32
+    accumulator: ``loss(params, batch, rng, grad_sinks=None)`` and
+    ``sink_mask`` (a parameter-shaped tree -> tree of bools). Handed the
+    accumulator's marked leaves as ``grad_sinks`` (a nested dict, branches
+    without one dropped) and differentiated with respect to them too, the
+    sinks' cotangent is ``sink + gradient`` and the marked parameters' own
+    gradient zero. Called without them it is any other loss."""
+
+    loss: Callable
+    sink_mask: Callable
+
+    def __call__(self, params, batch, rng, grad_sinks=None):
+        return self.loss(params, batch, rng, grad_sinks=grad_sinks)
+
+
 def zeros_like_grads(params):
     return jax.tree.map(lambda p: jnp.zeros_like(p, dtype=jnp.float32), params)
+
+
+def _marked_subtree(tree, mask):
+    """The nested dict of ``tree``'s leaves where ``mask`` (a tree of bools
+    of its structure) is True."""
+    marked: Dict[str, Any] = {}
+    leaves = jax.tree_util.tree_flatten_with_path(tree)[0]
+    for (path, leaf), keep in zip(leaves, jax.tree.leaves(mask)):
+        if keep:
+            node = marked
+            for key in path[:-1]:
+                node = node.setdefault(key.key, {})
+            node[path[-1].key] = leaf
+    return marked
+
+
+def _noting_cotangents(sinks, reached: set):
+    """``sinks`` behind identities whose backward notes in ``reached``, while
+    the gradient is traced, the path of each leaf a cotangent comes back to.
+    A leaf that no module read has none, and its rule never runs."""
+
+    def through(path, leaf):
+        @jax.custom_vjp
+        def identity(x):
+            return x
+
+        def noted(_, cotangent):
+            reached.add(path)
+            return (cotangent,)
+
+        identity.defvjp(lambda x: (x, None), noted)
+        return identity(leaf)
+
+    return jax.tree_util.tree_map_with_path(through, sinks)
 
 
 def make_accumulate_step(
@@ -74,7 +126,15 @@ def make_accumulate_step(
     seq-sharded and ring attention's in_specs match with zero per-layer
     relayout — and non-attention activations are S/n per device, the full
     O(S/n) memory win, not just the score matrix's.
+
+    A ``GradSinkLoss`` on ONE device (no ``mesh``: under a data mesh the
+    gradient is a mean over devices and the accumulator is not) is handed
+    the accumulator's marked leaves and returns them summed: for those the
+    new accumulator is the sink's cotangent, no ``a + g`` pass of its own.
+    A marked leaf that no module read would come back zero: tracing the step
+    raises on one.
     """
+    sink_mask = getattr(loss_fn, "sink_mask", None) if mesh is None else None
 
     # jitted programs carry stable names: a trace, an IR dump or a compile
     # event finds "accumulate_step" after any refactor
@@ -90,11 +150,37 @@ def make_accumulate_step(
                 )
 
             batch = jax.tree.map(_constrain, batch)
-        (_, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params, batch, rng
+        # the accumulator's leaves that the loss's backward sums into
+        # itself: none for a plain loss, whose step is the ``a + g`` it was
+        sinks = {} if sink_mask is None else _marked_subtree(
+            grad_acc, sink_mask(grad_acc)
         )
-        grad_acc = jax.tree.map(
-            lambda a, g: a + g.astype(jnp.float32), grad_acc, grads
+        reached: set = set()
+
+        def loss(params, sinks):
+            if not sinks:
+                return loss_fn(params, batch, rng)
+            return loss_fn(
+                params, batch, rng,
+                grad_sinks=_noting_cotangents(sinks, reached),
+            )
+
+        (_, metrics), (grads, sunk) = jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True
+        )(params, sinks)
+        sunk = dict(jax.tree_util.tree_flatten_with_path(sunk)[0])
+        unread = [
+            jax.tree_util.keystr(path) for path in sunk if path not in reached
+        ]
+        if unread:  # its cotangent is zero: the accumulated sum would be lost
+            raise ValueError(
+                f"marked as gradient sinks, read by no module: {unread}"
+            )
+        grad_acc = jax.tree_util.tree_map_with_path(
+            lambda path, a, g: (
+                sunk[path] if path in sunk else a + g.astype(jnp.float32)
+            ),
+            grad_acc, grads,
         )
         return grad_acc, n_acc + 1, metrics
 

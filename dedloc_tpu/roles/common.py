@@ -29,6 +29,7 @@ from dedloc_tpu.models.deepseek_v3 import (
     deepseek_v3_sign_step_mask,
     deepseek_v3_train_tflops_per_sample,
     deepseek_v3_weight_decay_mask,
+    routed_grad_sink_mask,
 )
 from dedloc_tpu.models.lfm2_moe import (
     Lfm2MoeConfig,
@@ -50,6 +51,7 @@ from dedloc_tpu.optim import (
     lamb,
     linear_warmup_linear_decay,
 )
+from dedloc_tpu.parallel.train_step import GradSinkLoss
 from dedloc_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -81,6 +83,10 @@ class ModelFamily:
     # cotangent (``optim.lamb.sign_stepped``) at ``sign_step``; None: none
     sign_step_mask: Optional[Callable] = None
     sign_step: float = 0.0
+    # params -> tree of bools, True for leaves whose gradient the loss's
+    # backward can add into the float32 accumulator in place: the loss then
+    # takes ``grad_sinks`` (``parallel/train_step.GradSinkLoss``); None: none
+    grad_sink_mask: Optional[Callable] = None
 
 
 def _albert_loss(model: AlbertForPreTraining) -> Callable:
@@ -143,9 +149,11 @@ def _albert_tflops(cfg: AlbertConfig, seq: int) -> float:
 
 
 def _without_rng(loss: Callable) -> Callable:
-    """A (model, params, batch) loss in the table's form: module ->
-    loss_fn(params, batch, rng)."""
-    return lambda model: lambda params, batch, rng: loss(model, params, batch)
+    """A (model, params, batch, **kwargs) loss in the table's form: module
+    -> loss_fn(params, batch, rng, **kwargs)."""
+    return lambda model: lambda params, batch, rng, **kwargs: loss(
+        model, params, batch, **kwargs
+    )
 
 
 def _ouro_batches(cfg, batch_size: int, seq_length: int,
@@ -181,13 +189,15 @@ DEEPSEEK_V3 = ModelFamily(
     weight_decay_mask=deepseek_v3_weight_decay_mask,
     step_gauges=(
         "moe.load_max_over_mean", "moe.local_slot_share", "moe.bias_abs_max",
+        "moe.grad_sink_leaves",
     ),
     step_counters=("moe.dropped_slots",),
     sign_step_mask=deepseek_v3_sign_step_mask,
     sign_step=DeepseekV3Config.bias_update_speed,
+    grad_sink_mask=routed_grad_sink_mask,
 )
 LFM2_MOE = dataclasses.replace(
-    DEEPSEEK_V3,  # the same source, gauges, counter and sign rule
+    DEEPSEEK_V3,  # the same source, gauges, counter, sign rule and sinks
     config=Lfm2MoeConfig, module=Lfm2MoeForCausalLM,
     loss=_without_rng(lfm2_moe_loss),
     tflops_per_sample=lfm2_moe_train_tflops_per_sample,
@@ -603,8 +613,13 @@ def publish_step_metrics(
 
 def build_loss_fn(model) -> Callable:
     """The family's loss for ``model``: (params, batch, rng) -> (loss,
-    metrics)."""
-    return model_family(model).loss(model)
+    metrics) — a ``GradSinkLoss`` where the family marks sink leaves, so
+    whoever builds ``make_accumulate_step`` on it builds the same program."""
+    family = model_family(model)
+    loss_fn = family.loss(model)
+    if family.grad_sink_mask is None:
+        return loss_fn
+    return GradSinkLoss(loss_fn, family.grad_sink_mask)
 
 
 def synthetic_mlm_batches(

@@ -77,6 +77,7 @@ MM_ROUNDS_ATTEMPTED = "mm.rounds_attempted"
 MM_ROUNDS_FORMED = "mm.rounds_formed"
 MOE_BIAS_ABS_MAX = "moe.bias_abs_max"
 MOE_DROPPED_SLOTS = "moe.dropped_slots"
+MOE_GRAD_SINK_LEAVES = "moe.grad_sink_leaves"
 MOE_LOCAL_SLOT_SHARE = "moe.local_slot_share"
 NET_BYTES_IN = "net.bytes_in"
 NET_BYTES_OUT = "net.bytes_out"
@@ -239,6 +240,7 @@ COUNTERS = frozenset({
 GAUGES = frozenset({
     "expert.load_ewma",
     "moe.bias_abs_max",
+    "moe.grad_sink_leaves",
     "moe.local_slot_share",
     "opt.ef_residual_norm",
     "opt.overlap_efficiency",

@@ -298,6 +298,9 @@ def test_two_width_kernels_contract_over_a_heads_own_lane_tiles():
     assert rows["kanana_accumulate_step"]["flash_fwd_forms"] == {
         "one_tile": 0, "tiles": 2
     }
+    # the routed loop's backward sums into the accumulator's expert leaves
+    # (gradient sinks): no add pass of its own over one (3 before PR 33)
+    assert rows["kanana_accumulate_step"]["expert_grad_passes"]["adds"] == 0
 
 
 def test_accumulate_step_has_no_relayout_copies_around_flash_attention():
@@ -368,3 +371,9 @@ def test_lfm2_accumulate_step_keeps_what_its_backward_reads():
     assert row["tpu_custom_calls"] == 11
     assert row["flash_fwd_forms"] == {"one_tile": 0, "tiles": 1}
     assert 469_285_248 * 28 + row["memory"]["temp_bytes"] <= 15.9e9
+    # gradient sinks (PR 33): the tile loops' backward starts from the
+    # accumulator's twelve expert leaves and leaves the sums there — no
+    # zeroed float32 carry, no ``grad_acc + result`` pass (12 + 12 before),
+    # and the scratch those buffers took is gone (1,170,841,600 before)
+    assert row["expert_grad_passes"] == {"adds": 0, "zero_fills": 0}
+    assert row["memory"]["temp_bytes"] <= 1_170_841_600

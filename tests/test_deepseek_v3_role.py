@@ -5,6 +5,7 @@ correction-bias entry moves by exactly ±gamma or 0 a global step; the step
 records carry the routing gauges and the counter that must read 0."""
 import json
 
+import jax
 import numpy as np
 import pytest
 
@@ -29,6 +30,32 @@ def _args(tmp_path, argv=()):
         "--averager.default_refresh_period", "0.3",
     ]
     return parse_config(CollaborationArguments, base + list(argv))
+
+
+def _two_micro_batches(accumulate, params, batches):
+    import jax.numpy as jnp
+
+    from dedloc_tpu.parallel.train_step import zeros_like_grads
+
+    acc, n = zeros_like_grads(params), jnp.zeros([], jnp.int32)
+    for i, batch in enumerate(batches):
+        acc, n, metrics = accumulate(
+            params, acc, n, batch, jax.random.PRNGKey(i)
+        )
+    return acc, metrics
+
+
+def _sink_case(size, **overrides):
+    """(model, params, two batches, the table's loss) of a tiny decoder."""
+    import jax.numpy as jnp
+
+    from dedloc_tpu.roles.common import build_loss_fn
+
+    cfg, model = build_model(size, **overrides)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 2, 32), 0, cfg.vocab_size)
+    params = model.init(jax.random.PRNGKey(0), ids[0])["params"]
+    batches = [{"input_ids": x, "labels": jnp.roll(x, -1, 1)} for x in ids]
+    return model, params, batches, build_loss_fn(model)
 
 
 @pytest.mark.parametrize("shard", ["0/1", "1/4"])
@@ -101,3 +128,93 @@ def test_the_table_builds_the_expert_decoder():
         build_model("ouro_tiny", expert_shard="0/2")
     with pytest.raises(ValueError, match="must divide"):
         build_model("kanana2_tiny", expert_shard="0/3")
+
+
+def test_accumulate_step_leaves_expert_gradients_in_the_accumulator():
+    """``make_accumulate_step(build_loss_fn(model))`` — the call the role and
+    the benchmark make — hands the accumulator's expert leaves to the tile
+    loop: over two micro-batches they hold the float32 sums the plain step
+    rounds to bf16 first, every other leaf is the plain step's exactly."""
+    from dedloc_tpu.models.deepseek_v3 import EXPERT_LEAVES
+    from dedloc_tpu.parallel.train_step import (
+        GradSinkLoss,
+        make_accumulate_step,
+    )
+
+    _model, params, batches, loss_fn = _sink_case("kanana2_tiny")
+    assert isinstance(loss_fn, GradSinkLoss)
+    sunk, metrics = _two_micro_batches(
+        make_accumulate_step(loss_fn), params, batches
+    )
+    plain, plain_metrics = _two_micro_batches(
+        make_accumulate_step(loss_fn.loss), params, batches
+    )
+    # three stacked leaves, two expert layers each
+    assert float(metrics["moe.grad_sink_leaves"]) == 6.0
+    assert float(plain_metrics["moe.grad_sink_leaves"]) == 0.0
+    assert float(metrics["loss"]) == float(plain_metrics["loss"])
+    seen = 0
+    for (path, got), want in zip(
+        jax.tree_util.tree_leaves_with_path(sunk), jax.tree.leaves(plain)
+    ):
+        if path[-1].key in EXPERT_LEAVES:
+            seen += 1
+            apart = float(np.linalg.norm(got - want) / np.linalg.norm(want))
+            assert 0.0 < apart < 2.0 ** -8, (path, apart)
+        else:
+            np.testing.assert_array_equal(got, want, err_msg=str(path))
+    assert seen == 3
+
+
+def test_accumulate_step_under_a_mesh_keeps_the_plain_path():
+    """Under a data mesh the gradient is a mean over devices and the
+    accumulator is not: the step lowers to the program it was."""
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+
+    from dedloc_tpu.parallel.train_step import (
+        make_accumulate_step,
+        zeros_like_grads,
+    )
+
+    _model, params, batches, loss_fn = _sink_case("kanana2_tiny")
+    mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+    operands = (
+        params, zeros_like_grads(params), jnp.zeros([], jnp.int32),
+        batches[0], jax.random.PRNGKey(0),
+    )
+    meshed = make_accumulate_step(loss_fn, mesh=mesh).lower(*operands)
+    assert meshed.as_text() == make_accumulate_step(
+        loss_fn.loss, mesh=mesh
+    ).lower(*operands).as_text()
+    assert meshed.as_text() != make_accumulate_step(loss_fn).lower(
+        *operands
+    ).as_text()
+    _acc, _n, metrics = meshed.compile()(*operands)
+    assert float(metrics["moe.grad_sink_leaves"]) == 0.0
+
+
+@pytest.mark.parametrize("stray", ["router", "lm_head"])
+def test_a_marked_leaf_that_no_module_reads_stops_the_trace(stray):
+    """A sink nobody sums into comes back zero, and the step would write
+    that zero over the accumulated gradient: tracing it raises instead."""
+    import jax.numpy as jnp
+
+    from dedloc_tpu.models.deepseek_v3 import EXPERT_LEAVES
+    from dedloc_tpu.parallel.train_step import (
+        GradSinkLoss,
+        make_accumulate_step,
+        zeros_like_grads,
+    )
+
+    _model, params, batches, loss_fn = _sink_case("kanana2_tiny")
+    too_wide = GradSinkLoss(loss_fn.loss, lambda tree: (
+        jax.tree_util.tree_map_with_path(
+            lambda path, _: path[-1].key in EXPERT_LEAVES + (stray,), tree
+        )
+    ))
+    with pytest.raises(ValueError, match=f"read by no module.*{stray}"):
+        make_accumulate_step(too_wide).lower(
+            params, zeros_like_grads(params), jnp.zeros([], jnp.int32),
+            batches[0], jax.random.PRNGKey(0),
+        )

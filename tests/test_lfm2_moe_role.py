@@ -48,6 +48,32 @@ def _bias_leaves(tree):
     ]
 
 
+def _two_micro_batches(accumulate, params, batches):
+    import jax.numpy as jnp
+
+    from dedloc_tpu.parallel.train_step import zeros_like_grads
+
+    acc, n = zeros_like_grads(params), jnp.zeros([], jnp.int32)
+    for i, batch in enumerate(batches):
+        acc, n, metrics = accumulate(
+            params, acc, n, batch, jax.random.PRNGKey(i)
+        )
+    return acc, metrics
+
+
+def _sink_case(size, **overrides):
+    """(model, params, two batches, the table's loss) of a tiny decoder."""
+    import jax.numpy as jnp
+
+    from dedloc_tpu.roles.common import build_loss_fn
+
+    cfg, model = build_model(size, **overrides)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 2, 32), 0, cfg.vocab_size)
+    params = model.init(jax.random.PRNGKey(0), ids[0])["params"]
+    batches = [{"input_ids": x, "labels": jnp.roll(x, -1, 1)} for x in ids]
+    return model, params, batches, build_loss_fn(model)
+
+
 @pytest.mark.parametrize(
     "shard,layers", [("0/1", "0"), ("1/4", "5")],
     ids=["whole", "share_1_of_4_cut_to_5"],
@@ -153,3 +179,100 @@ def test_ouro_takes_grouped_heads():
     assert attn["q_proj"]["kernel"].shape[-1] == 2 * cfg.head_dim
     hiddens, _gates = OuroForCausalLM(cfg).apply({"params": params}, ids)
     assert bool(jnp.isfinite(hiddens).all())
+
+
+def test_accumulate_step_leaves_expert_gradients_in_the_accumulator():
+    """The scanned period's expert leaves (a layer each) AND the tail
+    layer's are sinks of ``make_accumulate_step(build_loss_fn(model))``:
+    float32 sums where the plain step adds bf16-rounded gradients, every
+    other leaf exactly the plain step's."""
+    from dedloc_tpu.models.deepseek_v3 import EXPERT_LEAVES
+    from dedloc_tpu.parallel.train_step import make_accumulate_step
+
+    _model, params, batches, loss_fn = _sink_case("lfm2_tiny")
+    sunk, metrics = _two_micro_batches(
+        make_accumulate_step(loss_fn), params, batches
+    )
+    plain, plain_metrics = _two_micro_batches(
+        make_accumulate_step(loss_fn.loss), params, batches
+    )
+    assert float(metrics["moe.grad_sink_leaves"]) == 15.0  # 5 layers x 3
+    assert float(plain_metrics["moe.grad_sink_leaves"]) == 0.0
+    assert float(metrics["loss"]) == float(plain_metrics["loss"])
+    seen = 0
+    for (path, got), want in zip(
+        jax.tree_util.tree_leaves_with_path(sunk), jax.tree.leaves(plain)
+    ):
+        if path[-1].key in EXPERT_LEAVES:
+            seen += 1
+            apart = float(np.linalg.norm(got - want) / np.linalg.norm(want))
+            assert 0.0 < apart < 2.0 ** -8, (path, apart)
+        else:
+            np.testing.assert_array_equal(got, want, err_msg=str(path))
+    assert seen == 15
+
+
+def test_accumulate_step_under_a_mesh_keeps_the_plain_path():
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+
+    from dedloc_tpu.parallel.train_step import (
+        make_accumulate_step,
+        zeros_like_grads,
+    )
+
+    _model, params, batches, loss_fn = _sink_case("lfm2_tiny")
+    mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+    operands = (
+        params, zeros_like_grads(params), jnp.zeros([], jnp.int32),
+        batches[0], jax.random.PRNGKey(0),
+    )
+    meshed = make_accumulate_step(loss_fn, mesh=mesh).lower(*operands)
+    assert meshed.as_text() == make_accumulate_step(
+        loss_fn.loss, mesh=mesh
+    ).lower(*operands).as_text()
+    assert meshed.as_text() != make_accumulate_step(loss_fn).lower(
+        *operands
+    ).as_text()
+
+
+@pytest.mark.parametrize("size", ["tiny", "ouro_tiny"])
+def test_a_model_without_sink_leaves_lowers_to_the_step_it_was(size):
+    """No family but the expert decoders marks sink leaves: their loss is a
+    plain function and ``accumulate_step`` the module it was before sinks —
+    value_and_grad, then ``a + g`` a leaf."""
+    import jax.numpy as jnp
+
+    from dedloc_tpu.parallel.train_step import (
+        make_accumulate_step,
+        zeros_like_grads,
+    )
+    from dedloc_tpu.roles.common import build_loss_fn, drop_collator_keys
+
+    cfg, model = build_model(size)
+    loss_fn = build_loss_fn(model)
+    assert not hasattr(loss_fn, "sink_mask")
+    batch = drop_collator_keys(
+        next(model_family(size).synthetic_batches(cfg, 2, 32, 0))
+    )
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((2, 32), jnp.int32)
+    )["params"]
+
+    def accumulate_step(params, grad_acc, n_acc, batch, rng):
+        (_, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            params, batch, rng
+        )
+        grad_acc = jax.tree.map(
+            lambda a, g: a + g.astype(jnp.float32), grad_acc, grads
+        )
+        return grad_acc, n_acc + 1, metrics
+
+    operands = (
+        params, zeros_like_grads(params), jnp.zeros([], jnp.int32), batch,
+        jax.random.PRNGKey(0),
+    )
+    assert make_accumulate_step(loss_fn).lower(*operands).as_text() == (
+        jax.jit(accumulate_step, donate_argnums=(1, 2))
+        .lower(*operands).as_text()
+    )

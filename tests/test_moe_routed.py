@@ -2,7 +2,8 @@
 the bias and the weights do not; nothing is dropped however skewed the
 router; absent experts contribute nothing; the tile plan puts every valid
 (token, slot) pair in a row of its own expert's tiles; the load statistic
-leaves the backward as the bias's cotangent."""
+leaves the backward as the bias's cotangent; handed gradient sinks, the
+backward sums the held matrices' gradients into them."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -179,3 +180,76 @@ def test_load_statistic_is_the_bias_cotangent():
     np.testing.assert_allclose(gx, 14.0 * x)  # x passes through untouched
     # whatever reaches x, the bias receives load − mean load, unscaled
     np.testing.assert_allclose(gb, load - load.mean(), atol=1e-8)
+
+
+def _skewed_choice():
+    """Of the held experts 4..7: expert 5 takes 20 rows (three tiles of 8),
+    4 and 7 ten each (two), 6 none; the other slots go to absent experts."""
+    t = np.arange(T)
+    first = np.where(t < 20, 5, np.where(t < 30, 4, np.where(t < 40, 7, 0)))
+    return jnp.asarray(np.stack([first, 8 + t % 4, 12 + t % 4], -1), jnp.int32)
+
+
+def _relative(got, want):
+    return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("sink", [False, True], ids=["no_sink", "sink"])
+def test_held_gradients_accumulate_over_two_micro_batches(sink, dtype):
+    """A float32 accumulator over two micro-batches: ``acc + d`` in a pass
+    of its own (no sink), or handed to the loop as its sinks, whose
+    cotangent is then the new accumulator while the matrices' own is zero.
+    In float32 both are the dense loop's sums to 1e-6; in bfloat16 the sink
+    keeps the float32 sum the other path rounds."""
+    held, names = (4, 4), ("gate", "up", "down")
+    choice, weights = _skewed_choice(), jnp.full((T, K), 0.7)
+    _, tile_expert, tiles, _ = _tile_plan(choice, held, 8)
+    assert np.bincount(
+        np.asarray(tile_expert)[:int(tiles)], minlength=4
+    ).tolist() == [2, 3, 0, 2]
+    start = {
+        n: 0.5 + jnp.zeros(_layer()[n][4:8].shape, jnp.float32) for n in names
+    }
+    acc, plain, want = dict(start), dict(start), dict(start)
+    for seed in (0, 1):
+        p = _layer(seed)
+        p = {n: v.astype(dtype).astype(jnp.float32) for n, v in p.items()}
+        dense = jax.grad(
+            lambda q: jnp.sum(jnp.sin(_dense(dict(p, **q), choice, weights, held)))
+        )({n: p[n] for n in names})
+        want = {n: want[n] + dense[n][4:8] for n in names}
+
+        def loss(mats, sinks):
+            y, stats = routed_experts(
+                p["x"].astype(dtype), choice, weights,
+                *(mats[n].astype(dtype) for n in names), held, tile=8,
+                grad_sinks=sinks,
+            )
+            return jnp.sum(jnp.sin(y)), stats
+
+        mats = {n: p[n][4:8] for n in names}
+        d_mats, stats = jax.grad(loss, has_aux=True)(mats, None)
+        plain = {n: plain[n] + d_mats[n] for n in names}
+        assert float(stats["dropped_slots"]) == 0.0
+        assert float(stats["grad_sink_leaves"]) == 0.0
+        if sink:
+            (d_mats, d_sinks), stats = jax.grad(loss, (0, 1), has_aux=True)(
+                mats, tuple(acc[n] for n in names)
+            )
+            assert all(float(jnp.max(jnp.abs(d_mats[n]))) == 0.0 for n in names)
+            assert all(d.dtype == jnp.float32 for d in d_sinks)
+            assert float(stats["dropped_slots"]) == 0.0
+            assert float(stats["grad_sink_leaves"]) == 3.0
+            acc = dict(zip(names, d_sinks))
+        else:
+            acc = plain
+    exact = dtype == jnp.float32
+    for n in names:
+        # the expert no tile reached keeps what the accumulator held
+        assert float(jnp.max(jnp.abs(acc[n][2] - start[n][2]))) == 0.0
+        assert _relative(acc[n], want[n]) < (1e-6 if exact else 1e-2)
+        # against the pass of its own: the same sums, but for its rounding
+        # of each micro-batch's gradient to the compute dtype
+        assert _relative(acc[n], plain[n]) < (1e-6 if exact else 2.0 ** -8)

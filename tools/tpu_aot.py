@@ -13,7 +13,8 @@ speed or numerics; those need the chip (``chip_smoke.py``).
 
 Each line: {"program", "compile_s", "tpu_custom_calls", "flash_fwd_forms",
 "flash_windows", "layer_body_copies", "memory"} (and, for the programs of
-``COUNT_KERNEL_CALLS``, "kernel_calls": call sites by kernel name) —
+``COUNT_KERNEL_CALLS``, "kernel_calls": call sites by kernel name; for the
+two expert programs, "expert_grad_passes": ``expert_grad_passes``' counts) —
 ``flash_windows`` is each flash kernel's lane window beside its column
 block, from the call's metadata (``"block"`` for a call that carries none:
 D=64, D=128; its head counts for a grouped-query call);
@@ -380,9 +381,45 @@ def kernel_calls(lowered_text: str) -> dict:
     return {name: names.count(name) for name in sorted(set(names))}
 
 
+def expert_grad_passes(hlo_text: str) -> dict:
+    """Passes over a held expert matrix's float32 gradient that move no
+    FLOP, in an optimized HLO module (``compiled.as_text()``): ``adds`` —
+    the entry's add instructions (alone or the root of a fusion) that read
+    a ``grad_acc…experts_*`` parameter, the accumulate XLA could not fuse
+    into a dynamic-trip-count loop; ``zero_fills`` — float32 ``broadcast``s
+    of such a parameter's shape (a layer's matrix, or the scanned stack of
+    them) outside fused computations: the backward loop's zeroed carry.
+    Both 0 where the tile loop sums into the accumulator itself
+    (``parallel/moe.py``'s gradient sinks)."""
+    entry = hlo_text[hlo_text.index("\nENTRY "):]
+    shapes = set()
+    for dims in re.findall(
+        r"%grad_acc__\w*____experts_[\w.]+ = f32\[([\d,]+)\]\S* parameter\(", entry
+    ):
+        dims = dims.split(",")
+        shapes.add(",".join(dims[-3:]))
+        if any(d != "1" for d in dims[:-3]):
+            shapes.add(",".join(dims))
+    adds = sum(
+        1 for line in entry.splitlines()
+        if re.match(r"\s+(ROOT )?%[\w.\-]*add[\w.\-]* = ", line)
+        and re.search(r"\(.*%grad_acc__\w*____experts_", line)
+    )
+    fills, fused = 0, False
+    for line in hlo_text.splitlines():
+        if not line.startswith(" "):
+            fused = "fused_computation" in line
+        elif not fused:
+            fill = re.match(r"\s+%[\w.\-]+ = f32\[([\d,]+)\]\S* broadcast\(", line)
+            fills += bool(fill) and fill.group(1) in shapes
+    return {"adds": adds, "zero_fills": fills}
+
+
 # programs whose row also carries ``kernel_calls`` (the others print the
-# rows they always did)
+# rows they always did), and those with a routed expert layer, whose row
+# carries ``expert_grad_passes``
 COUNT_KERNEL_CALLS = {"gqa_kernels", "lfm2_accumulate_step"}
+COUNT_EXPERT_GRAD_PASSES = {"kanana_accumulate_step", "lfm2_accumulate_step"}
 NO_V5E = 3  # exit code: nothing to compile with, which is not a failure
 
 
@@ -413,10 +450,13 @@ def main(argv=None) -> int:
         seconds = round(time.perf_counter() - start, 2)
         memory = compiled.memory_analysis()
         lowered_text = lowered.as_text()
+        compiled_text = compiled.as_text()
         extra = (
             {"kernel_calls": kernel_calls(lowered_text)}
             if name in COUNT_KERNEL_CALLS else {}
         )
+        if name in COUNT_EXPERT_GRAD_PASSES:
+            extra["expert_grad_passes"] = expert_grad_passes(compiled_text)
         print(json.dumps({
             "program": name,
             "device_kind": device.device_kind,
@@ -424,7 +464,7 @@ def main(argv=None) -> int:
             "tpu_custom_calls": lowered_text.count("tpu_custom_call"),
             "flash_fwd_forms": flash_fwd_forms(lowered_text),
             "flash_windows": flash_windows(lowered_text),
-            "layer_body_copies": layer_body_copies(compiled.as_text()),
+            "layer_body_copies": layer_body_copies(compiled_text),
             "memory": {
                 "argument_bytes": memory.argument_size_in_bytes,
                 "output_bytes": memory.output_size_in_bytes,
