@@ -46,6 +46,17 @@ every member sees the same bytes). A dead HOST is unrecoverable without
 redundancy: its span cannot be gathered, the round raises AllreduceFailed
 for everyone, and the group re-forms next round (the reference's 'group
 failure costs one round' semantics, contributor notebook cell 3).
+
+Where a round's time goes (docs/observability.md, "Wire-path counters and
+spans"): every round is cut two ways on ``telemetry.monotonic_clock``,
+telemetry on or off. STAGES (``RoundTrace``) cut the round's own coroutine
+end to end — ``ar_resolve`` / ``ar_prepare`` / ``ar_scatter`` /
+``ar_gather`` (child ``ar_straggler``) / ``ar_finish`` — and tile it. KINDS
+(``_Sections``) sum what the loop thread did inside it, wherever the chunk
+coroutines interleave: ``ar_encode`` / ``ar_decode`` / ``ar_reduce`` /
+``ar_copy`` / ``ar_frame``. The round's wall minus the kinds is the loop
+thread in none of the round's own code: awaiting a socket, the partner, the
+GIL, another coroutine.
 """
 from __future__ import annotations
 
@@ -62,6 +73,7 @@ from dedloc_tpu.core.serialization import (
     wire_roundtrip,
 )
 from dedloc_tpu.averaging.partition import partition_weighted
+from dedloc_tpu.core.timeutils import monotonic as _clock
 from dedloc_tpu.dht.protocol import Endpoint, RPCClient, RPCError, RPCServer
 from dedloc_tpu.telemetry import registry as telemetry
 from dedloc_tpu.telemetry.links import endpoint_key
@@ -77,6 +89,139 @@ DEFAULT_CHUNK_SIZE = 131072
 
 class AllreduceFailed(Exception):
     pass
+
+
+class _Section:
+    """One KIND of a round's work on the loop thread, as a context manager
+    entered around every synchronous piece of it (no ``await`` inside, so
+    sections never nest or overlap and ONE object a kind serves the whole
+    round): two clock reads into the kind's sum and, with telemetry on
+    (``annotate``), a ``dedloc/<kind>`` annotation on the profiler's host
+    plane. Kept lean — it runs some 700 times a round on a thread that
+    shares the GIL with the partner's loop."""
+
+    __slots__ = ("kind", "annotate", "count", "total_s", "first_t0",
+                 "last_t1", "_t0", "_annotation")
+
+    def __init__(self, kind: str, annotate: bool):
+        self.kind = kind
+        self.annotate = annotate
+        self.count = 0
+        self.total_s = self.first_t0 = self.last_t1 = 0.0
+        self._annotation = None
+
+    def __enter__(self) -> None:
+        if self.annotate:
+            self._annotation = telemetry.trace_annotation(self.kind)
+            if self._annotation is not None:
+                self._annotation.__enter__()
+        self._t0 = _clock()
+
+    def __exit__(self, *exc) -> None:
+        t1 = _clock()
+        if not self.count:
+            self.first_t0 = self._t0
+        self.count += 1
+        self.total_s += t1 - self._t0
+        self.last_t1 = t1
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
+
+
+class _Sections:
+    """What the loop thread spent inside one round, by KIND of work:
+
+    - ``encode`` (span ``ar_encode``): ``serialize_array`` of the parts I
+      send and of the reduced chunks a member first pulls, and
+      ``wire_roundtrip`` (encode AND decode: splitting it would cost a
+      second pass, so it counts whole here) of my own part of my span and of
+      the reduced chunks I adopt
+    - ``decode`` (``ar_decode``): ``deserialize_array`` of the parts I host
+      and of the reduced chunks I pull
+    - ``reduce`` (``ar_reduce``): the accumulator's axpy / scale and the
+      finalize scale
+    - ``copy`` (``ar_copy``): ``np.copyto`` into the result, the
+      ``local_span`` copy
+    """
+
+    __slots__ = ("encode", "decode", "reduce", "copy")
+
+    def __init__(self, annotate: bool = False):
+        self.encode = _Section("ar_encode", annotate)
+        self.decode = _Section("ar_decode", annotate)
+        self.reduce = _Section("ar_reduce", annotate)
+        self.copy = _Section("ar_copy", annotate)
+
+    def rows(self) -> Dict[str, Tuple[int, float, float, float]]:
+        """``{span name: (count, total_s, first t0, last t1)}`` of the kinds
+        that ran."""
+        return {
+            s.kind: (s.count, s.total_s, s.first_t0, s.last_t1)
+            for s in (self.encode, self.decode, self.reduce, self.copy)
+            if s.count
+        }
+
+
+class RoundTrace:
+    """The span tree of one averaging round, as the loop thread read it:
+    ``spans`` holds ``(name, parent, t0, t1)`` — or, folded, ``(name,
+    parent, first t0, last t1, count, total_s)`` — on
+    ``telemetry.monotonic_clock``, the clock of the trainer's step record,
+    which attaches them under ``avg_wire`` (``steps.attach``).
+
+    STAGES are sequential: ``stage(name)`` closes the open one at the same
+    clock reading that opens the next, so they tile the round (``allreduce``
+    in the step record) exactly. With telemetry on (``annotate``) each stage
+    is also a ``dedloc/<name>`` annotation on the profiler's host plane.
+    ``GroupAllReduce.begin_trace`` starts one; whoever started it closes it
+    — a failed round leaves no stage open."""
+
+    def __init__(self, annotate: bool, frame_mark: Tuple[float, int, float]):
+        self.annotate = annotate
+        self.spans: List[tuple] = []
+        self.started_at: Optional[float] = None
+        self.loop_cpu_s = 0.0  # the loop thread's CPU seconds, set by close
+        # (seconds, frames, when) of the peer's frame accumulators as last
+        # read: ``GroupAllReduce`` reads ``ar_frame`` as a delta against it
+        self.frame_mark = frame_mark
+        self._cpu0 = telemetry.thread_cpu_clock()
+        self._open: Optional[list] = None  # [name, t0, annotation]
+
+    @property
+    def open_stage(self) -> Optional[str]:
+        return self._open[0] if self._open is not None else None
+
+    def stage(self, name: str, at: Optional[float] = None) -> float:
+        """Close the open stage and open ``name``, both at ``at`` (now when
+        left out); returns that reading."""
+        if at is None:
+            at = telemetry.monotonic_clock()
+        if self.started_at is None:
+            self.started_at = at
+        self._close_open(at)
+        annotation = (
+            telemetry.trace_annotation(name) if self.annotate else None
+        )
+        if annotation is not None:
+            annotation.__enter__()
+        self._open = [name, at, annotation]
+        return at
+
+    def close(self, at: Optional[float] = None) -> None:
+        """End of the round: the open stage ends at ``at`` (now when left
+        out)."""
+        self._close_open(telemetry.monotonic_clock() if at is None else at)
+        self.loop_cpu_s = max(0.0, telemetry.thread_cpu_clock() - self._cpu0)
+
+    def _close_open(self, at: float) -> None:
+        if self._open is None:
+            return
+        name, t0, annotation = self._open
+        self._open = None
+        self.spans.append((name, "allreduce", t0, max(t0, at)))
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
 
 
 def span_chunks(
@@ -114,7 +259,7 @@ class _ChunkState:
 
 
 class _RoundState:
-    def __init__(self):
+    def __init__(self, annotate: bool = False):
         self.chunks: Dict[int, _ChunkState] = {}
         # set by run() on the hosting member; handlers may buffer parts that
         # arrive first, but no chunk finalizes until these exist
@@ -122,7 +267,12 @@ class _RoundState:
         self.chunk_bounds: Optional[List[Tuple[int, int]]] = None
         self.local_span: Optional[np.ndarray] = None  # my fp32 span slice
         self.span_lo = 0
-        self.reduce_s = 0.0  # CPU seconds spent in axpy/scale on this host
+        # the loop thread's work for this round, by kind — the handlers'
+        # part of it included, from the first part that lands
+        self.sections = _Sections(annotate)
+        # when the first part (or zero-weight marker) of ANOTHER member
+        # landed: how late the partner started sending
+        self.first_part_at: Optional[float] = None
         # hierarchical averaging (averaging/topology.py): a clique-level
         # round runs in SUM mode — finalize serves the raw weighted sum
         # (and its total weight) instead of the mean, so the clique's
@@ -156,16 +306,16 @@ class _RoundState:
         must not be re-scaled) but ``norm=W_clique`` (the denominator must
         count every clique member it already folded in)."""
         st = self.chunk(c)
-        t0 = telemetry.monotonic_clock()
-        if st.acc is None:
-            if own and part.dtype == np.float32 and part.flags["C_CONTIGUOUS"]:
-                st.acc = part
+        with self.sections.reduce:
+            if st.acc is None:
+                if (own and part.dtype == np.float32
+                        and part.flags["C_CONTIGUOUS"]):
+                    st.acc = part
+                else:
+                    st.acc = np.array(part, dtype=np.float32)
+                native.scale(st.acc, weight)
             else:
-                st.acc = np.array(part, dtype=np.float32)
-            native.scale(st.acc, weight)
-        else:
-            native.axpy(st.acc, part, weight)
-        self.reduce_s += telemetry.monotonic_clock() - t0
+                native.axpy(st.acc, part, weight)
         st.weight += weight if norm is None else norm
 
     def maybe_finalize(self, c: int) -> None:
@@ -195,18 +345,18 @@ class _RoundState:
             st.done.set_result(st.acc)
             return
         if st.weight > 0:
-            t0 = telemetry.monotonic_clock()
-            reduced = native.scale(st.acc, 1.0 / st.weight)
-            self.reduce_s += telemetry.monotonic_clock() - t0
+            with self.sections.reduce:
+                reduced = native.scale(st.acc, 1.0 / st.weight)
         else:
             # all-aux group: nothing to average; serve my own slice (copied —
             # local_span may view a flat buffer the caller reuses next round,
             # and slow members pull chunks after this round returns)
             lo, hi = self.chunk_bounds[c]
-            reduced = np.array(
-                self.local_span[lo - self.span_lo : hi - self.span_lo],
-                dtype=np.float32,
-            )
+            with self.sections.copy:
+                reduced = np.array(
+                    self.local_span[lo - self.span_lo : hi - self.span_lo],
+                    dtype=np.float32,
+                )
         st.done.set_result(reduced)
 
     def maybe_finalize_all(self) -> None:
@@ -248,19 +398,23 @@ class GroupAllReduce:
         telemetry_registry=None,  # per-peer scope (telemetry/registry.py)
     ):
         self.client = client
+        self.server = server
         self.telemetry = telemetry_registry
         self.compression = compression
         self.timeout = timeout
         self.straggler_timeout = straggler_timeout
         self.chunk_size = int(chunk_size)
         self._rounds: Dict[str, _RoundState] = {}
+        self.last_trace: Optional[RoundTrace] = None  # of the latest run()
         if server is not None:
             server.register("avg.part", self._rpc_part)
             server.register("avg.get_reduced", self._rpc_get_reduced)
 
     def _round(self, round_id: str) -> _RoundState:
         if round_id not in self._rounds:
-            self._rounds[round_id] = _RoundState()
+            self._rounds[round_id] = _RoundState(
+                annotate=telemetry.resolve(self.telemetry) is not None
+            )
             # bound handler-created entries too: without this, parts arriving
             # after run()'s cleanup would accumulate forever
             asyncio.get_running_loop().call_later(
@@ -275,6 +429,8 @@ class GroupAllReduce:
         zero-weight marker from an auxiliary peer with no data, covering
         every chunk of the round)."""
         state = self._round(args["round_id"])
+        if state.first_part_at is None:
+            state.first_part_at = telemetry.monotonic_clock()
         sender = int(args["sender"])
         weight = float(args["weight"])
         # a hierarchical delegate's normalization weight (its clique's
@@ -297,7 +453,8 @@ class GroupAllReduce:
             # served to gatherers) must never be mutated again — the late
             # sender simply missed this round, per the straggler SLA
             return {}
-        part = deserialize_array(data)
+        with state.sections.decode:
+            part = deserialize_array(data)
         if weight > 0:
             state.accumulate(c, part, weight, own=True, norm=norm)
         st.arrived.add(sender)
@@ -316,10 +473,37 @@ class GroupAllReduce:
             asyncio.shield(st.done), timeout=self.timeout
         )
         if st.wire is None:  # encode once, serve n-1 gatherers from cache
-            st.wire = serialize_array(data, self.compression, checksum=True)
+            with state.sections.encode:
+                st.wire = serialize_array(
+                    data, self.compression, checksum=True
+                )
         return {"data": st.wire, "weight": st.weight}
 
     # ------------------------------------------------------------------ run
+
+    def _frame_totals(self) -> Tuple[float, int]:
+        """(seconds, frames) of synchronous frame I/O this peer's client and
+        server have done so far (``dht/protocol._frame_work``)."""
+        ends = [e for e in (self.client, self.server) if e is not None]
+        return (
+            sum(e.frame_s for e in ends), sum(e.frames for e in ends)
+        )
+
+    def begin_trace(
+        self, stage: str, at: Optional[float] = None
+    ) -> RoundTrace:
+        """Start a round's span tree with ``stage`` open from ``at`` (now
+        when left out), on the loop thread: the averager starts it when the
+        group forms and passes it to ``run`` (which otherwise starts its
+        own), then closes it when its step is done."""
+        if at is None:
+            at = telemetry.monotonic_clock()
+        trace = RoundTrace(
+            telemetry.resolve(self.telemetry) is not None,
+            (*self._frame_totals(), at),
+        )
+        trace.stage(stage, at)
+        return trace
 
     async def run(
         self,
@@ -332,6 +516,7 @@ class GroupAllReduce:
         chunk_size: Optional[int] = None,
         norm_weight: Optional[float] = None,
         normalize: bool = True,
+        trace: Optional[RoundTrace] = None,
     ):
         """Run one round. ``endpoints[i] is None`` marks a client-mode member
         (it hosts nothing); my own endpoint entry is ignored. Returns the
@@ -359,6 +544,10 @@ class GroupAllReduce:
           finalized with different total weights (a straggler was dropped
           from part of the span): a delegate must never advertise a
           denominator its sum does not actually carry.
+
+        ``trace``: the round's span tree when the caller began one (the
+        averager, at group formation, and it closes it); left out, the round
+        traces itself from here. Either way ``last_trace`` holds it.
         """
         n = len(endpoints)
         assert 0 <= my_index < n
@@ -368,52 +557,67 @@ class GroupAllReduce:
         can_host = [ep is not None for ep in endpoints]
         if not any(can_host):
             raise AllreduceFailed(f"round {round_id}: no member can host a span")
-        spans = partition_weighted(len(vector), list(bandwidths), can_host)
-        # every member announces itself to every host — auxiliary peers send a
-        # zero-weight marker instead of data, so hosts know not to wait
-        senders = set(range(n))
-
-        my_state = None
-        lo, hi = spans[my_index]
-        hosts_span = hi > lo
-        if hosts_span:
-            my_state = self._round(round_id)
-            my_state.normalize = normalize  # before expected_senders: no
-            # chunk may finalize under the wrong mode
-            my_state.expected_senders = set(senders)
-            my_state.chunk_bounds = span_chunks(lo, hi, chunk_size)
-            my_state.span_lo = lo
-            my_state.local_span = np.ascontiguousarray(
-                vector[lo:hi], dtype=np.float32
-            )
-            for c in range(len(my_state.chunk_bounds)):
-                # pre-create every chunk state: maybe_finalize skips chunks
-                # it has never seen, so an all-dataless round whose markers
-                # all landed BEFORE run() would otherwise finalize nothing
-                # eagerly and idle out the full straggler window
-                my_state.chunk(c)
-            my_state.maybe_finalize_all()
-
-        tele = telemetry.resolve(self.telemetry)
-        span_cm = (
-            # trace_seed: every member derives the round's trace id from the
-            # shared round_id, so per-peer traces stitch even without an
-            # enclosing avg.round span (bare GroupAllReduce harnesses)
-            tele.span(
-                "allreduce.round", trace_seed=round_id, round_id=round_id,
-                group_size=n,
-            )
-            if tele is not None
-            else telemetry.null_span()
-        )
+        own_trace = trace is None
+        if own_trace:
+            trace = self.begin_trace("ar_prepare")
+        elif trace.open_stage != "ar_prepare":
+            trace.stage("ar_prepare")
+        self.last_trace = trace
+        run_cpu0 = telemetry.thread_cpu_clock()
         try:
+            spans = partition_weighted(len(vector), list(bandwidths), can_host)
+            # every member announces itself to every host — auxiliary peers
+            # send a zero-weight marker instead of data, so hosts know not to
+            # wait
+            senders = set(range(n))
+
+            my_state = None
+            lo, hi = spans[my_index]
+            hosts_span = hi > lo
+            if hosts_span:
+                my_state = self._round(round_id)
+                my_state.normalize = normalize  # before expected_senders: no
+                # chunk may finalize under the wrong mode
+                my_state.expected_senders = set(senders)
+                my_state.chunk_bounds = span_chunks(lo, hi, chunk_size)
+                my_state.span_lo = lo
+                with my_state.sections.copy:
+                    my_state.local_span = np.ascontiguousarray(
+                        vector[lo:hi], dtype=np.float32
+                    )
+                for c in range(len(my_state.chunk_bounds)):
+                    # pre-create every chunk state: maybe_finalize skips chunks
+                    # it has never seen, so an all-dataless round whose markers
+                    # all landed BEFORE run() would otherwise finalize nothing
+                    # eagerly and idle out the full straggler window
+                    my_state.chunk(c)
+                my_state.maybe_finalize_all()
+
+            tele = telemetry.resolve(self.telemetry)
+            # a member that hosts nothing (client mode) still encodes, decodes
+            # and copies: its sections live here and not on a _RoundState
+            sections = (
+                my_state.sections if my_state is not None
+                else _Sections(annotate=tele is not None)
+            )
+            span_cm = (
+                # trace_seed: every member derives the round's trace id from
+                # the shared round_id, so per-peer traces stitch even without
+                # an enclosing avg.round span (bare GroupAllReduce harnesses)
+                tele.span(
+                    "allreduce.round", trace_seed=round_id, round_id=round_id,
+                    group_size=n,
+                )
+                if tele is not None
+                else telemetry.null_span()
+            )
             with span_cm as ctx:
                 try:
                     result = await asyncio.wait_for(
                         self._run_inner(
                             round_id, my_index, vector, weight, endpoints,
                             spans, my_state, senders, ctx, chunk_size,
-                            norm_weight, normalize,
+                            norm_weight, normalize, trace, sections,
                         ),
                         timeout=self.timeout,
                     )
@@ -431,22 +635,78 @@ class GroupAllReduce:
                         ctx["ok"] = False
                         ctx["error"] = type(e).__name__
                     raise AllreduceFailed(f"round {round_id}: {e!r}") from e
-                if tele is not None:
-                    tele.counter("allreduce.rounds").inc()
-                    ctx["ok"] = True
-                    ctx["bytes"] = int(vector.nbytes)
-                    if my_state is not None:
-                        ctx["reduce_s"] = round(my_state.reduce_s, 6)
-                return result
+                else:
+                    if tele is not None:
+                        tele.counter("allreduce.rounds").inc()
+                        ctx["ok"] = True
+                        ctx["bytes"] = int(vector.nbytes)
+                    return result
+                finally:
+                    self._fold_kinds(
+                        trace, sections, my_state, run_cpu0,
+                        ctx if tele is not None else None,
+                    )
         finally:
+            if own_trace:
+                trace.close()
             # deferred cleanup: slower members may still pull our reduced span
             asyncio.get_running_loop().call_later(
                 self.timeout, self._rounds.pop, round_id, None
             )
 
+    def _fold_kinds(
+        self, trace: RoundTrace, sections: _Sections,
+        my_state: Optional[_RoundState], run_cpu0: float,
+        ctx: Optional[dict],
+    ) -> None:
+        """End of ``run``, failed or not: the kinds' sums so far become one
+        folded span each (a section that lands later — a slow member pulling
+        my reduced chunks — is on ITS critical path and in nobody's record),
+        ``ar_frame`` the delta of the client's and server's frame
+        accumulators since the trace began (``began``; the last ``run``'s
+        end when a hierarchical round runs twice on one trace),
+        ``ar_partner_lag`` from there to the first part another member
+        delivered. With telemetry on the same numbers are fields of the
+        ``allreduce.round`` event (``ctx``)."""
+        now = telemetry.monotonic_clock()
+        kinds = sections.rows()
+        frame_s0, frames0, began = trace.frame_mark
+        frame_s, frames = self._frame_totals()
+        trace.frame_mark = (frame_s, frames, now)
+        if frames > frames0:
+            kinds["ar_frame"] = (
+                frames - frames0, frame_s - frame_s0, began, now
+            )
+        for kind, (count, total_s, t0, t1) in kinds.items():
+            trace.spans.append((kind, "allreduce", t0, t1, count, total_s))
+        lag = None
+        if my_state is not None and my_state.first_part_at is not None:
+            lag = max(0.0, my_state.first_part_at - began)
+            trace.spans.append(
+                ("ar_partner_lag", "allreduce", began, began + lag)
+            )
+        if ctx is None:
+            return
+        busy = sum(row[1] for row in kinds.values())
+        for kind, field in (
+            ("ar_encode", "encode_s"), ("ar_decode", "decode_s"),
+            ("ar_reduce", "reduce_s"), ("ar_copy", "copy_s"),
+            ("ar_frame", "frame_s"),
+        ):
+            if kind in kinds:
+                ctx[field] = round(kinds[kind][1], 6)
+        # the loop thread in none of the round's own code, from the trace's
+        # start (group formed, when the averager began it) to here
+        ctx["wait_s"] = round(max(0.0, now - began - busy), 6)
+        if lag is not None:
+            ctx["partner_lag_s"] = round(lag, 6)
+        ctx["loop_cpu_s"] = round(
+            max(0.0, telemetry.thread_cpu_clock() - run_cpu0), 6
+        )
+
     async def _run_inner(
         self, round_id, my_index, vector, weight, endpoints, spans, my_state,
-        senders, ctx, chunk_size, norm_weight=None, normalize=True,
+        senders, ctx, chunk_size, norm_weight, normalize, trace, sections,
     ):
         n = len(endpoints)
         norm = weight if norm_weight is None else float(norm_weight)
@@ -492,22 +752,20 @@ class GroupAllReduce:
                 {"round_id": round_id, "chunk": c},
                 timeout=self.timeout,
             )
-            data = deserialize_array(reply["data"])
+            with sections.decode:
+                data = deserialize_array(reply["data"])
             if data.size != chi - clo:
                 raise ValueError(
                     f"chunk size mismatch: got {data.size}, want {chi - clo}"
                 )
-            np.copyto(out[clo:chi], data.reshape(-1), casting="unsafe")
+            with sections.copy:
+                np.copyto(out[clo:chi], data.reshape(-1), casting="unsafe")
             if not normalize:
                 chunk_weights.append(float(reply.get("weight", 0.0)))
             if tele is not None:
-                raw = (chi - clo) * 4
                 dt = telemetry.monotonic_clock() - t0
                 wire = len(reply["data"])
-                tele.counter("allreduce.bytes_received").inc(raw)
-                tele.counter("allreduce.chunks_received").inc()
-                tele.counter("avg.bytes_saved").inc(max(0, raw - wire))
-                tele.histogram("allreduce.chunk_latency_s").observe(dt)
+                tele.counter("allreduce.bytes_received").inc((chi - clo) * 4)
                 # NOT fed into the LinkTable: this wall includes the host's
                 # reduce/straggler park (the request waits for the chunk to
                 # finalize), which would blame a stalled SENDER's delay on
@@ -532,8 +790,10 @@ class GroupAllReduce:
                 # emulation wants all replicas to apply bit-identical
                 # values — a host keeping its fp32 low bits would drift
                 # its params from the rest of the group every round
-                data = wire_roundtrip(data, self.compression)
-            np.copyto(out[clo:chi], data, casting="unsafe")
+                with sections.encode:
+                    data = wire_roundtrip(data, self.compression)
+            with sections.copy:
+                np.copyto(out[clo:chi], data, casting="unsafe")
 
         gathers = []
         for j in range(n):
@@ -553,6 +813,7 @@ class GroupAllReduce:
         gather_task = asyncio.ensure_future(
             asyncio.gather(*gathers)
         )
+        trace.stage("ar_scatter")
 
         try:
             # scatter: send my slice of each host's span, chunk by chunk
@@ -585,7 +846,10 @@ class GroupAllReduce:
                                 self.compression is not CompressionType.NONE
                             )
                             if lossy:
-                                part = wire_roundtrip(part, self.compression)
+                                with sections.encode:
+                                    part = wire_roundtrip(
+                                        part, self.compression
+                                    )
                             # the roundtripped array is fresh (never a view
                             # of local_span), so the accumulator may adopt
                             # and scale it in place instead of copying again
@@ -620,18 +884,14 @@ class GroupAllReduce:
                 # encodes — serializing the whole vector up front would
                 # block the loop for the full codec latency and hold every
                 # compressed payload in memory at once
-                payload = serialize_array(
-                    vector[clo:chi], self.compression, checksum=True
-                )
+                with sections.encode:
+                    payload = serialize_array(
+                        vector[clo:chi], self.compression, checksum=True
+                    )
                 if tele is not None:
-                    raw = (chi - clo) * 4
                     # logical tensor bytes moved (pre-compression fp32);
                     # the frame-level wire view lives in net.bytes_*
-                    tele.counter("allreduce.bytes_sent").inc(raw)
-                    tele.counter("allreduce.chunks_sent").inc()
-                    tele.counter("avg.bytes_saved").inc(
-                        max(0, raw - len(payload))
-                    )
+                    tele.counter("allreduce.bytes_sent").inc((chi - clo) * 4)
                 part_args = {
                     "round_id": round_id, "sender": my_index,
                     "weight": weight, "chunk": c, "data": payload,
@@ -663,6 +923,7 @@ class GroupAllReduce:
                     j, c, clo, chi = host_chunks[row]
                     sends.append(send_chunk(j, c, clo, chi))
             await asyncio.gather(*sends)
+            sent_at = trace.stage("ar_gather")
 
             # straggler window (arguments.py:23-28 semantics): once my own
             # sends are out, give the remaining senders ``straggler_timeout``
@@ -692,8 +953,13 @@ class GroupAllReduce:
                             missing=sorted(missing),
                         )
                     my_state.finalize_all()
+                trace.spans.append((
+                    "ar_straggler", "ar_gather", sent_at,
+                    telemetry.monotonic_clock(),
+                ))
 
             await gather_task
+            trace.stage("ar_finish")
         except BaseException:
             gather_task.cancel()
             raise

@@ -236,11 +236,14 @@ class DecentralizedAverager:
         # the DHT loop's thread, telemetry on or off: ``started_at``,
         # ``matchmaking_s`` (entering the round -> the first group formed,
         # the wait for the partners included) and ``allreduce_s`` (group
-        # formed -> result; a round that formed no group has 0 here). The
-        # collaborative optimizer hangs them under its ``avg_wire`` span.
-        # None while a round runs.
-        self.last_round_timing: Optional[Dict[str, float]] = None
+        # formed -> result; a round that formed no group has 0 here), and
+        # what is inside ``allreduce``: ``spans`` (the ``ar_*`` stages and
+        # kinds, ``_timed_step``) and ``loop_cpu_s``. The collaborative
+        # optimizer hangs them under its ``avg_wire`` span. None while a
+        # round runs.
+        self.last_round_timing: Optional[Dict[str, Any]] = None
         self._round_formed_at: Optional[float] = None
+        self._round_trace = None  # allreduce.RoundTrace of the round running
         # hierarchical averaging state: the installed plan, and the fan-out
         # futures a delegate publishes each round's final result through
         # (clique members pull them via the avg.final RPC)
@@ -583,8 +586,16 @@ class DecentralizedAverager:
         return fut if return_future else fut.result()
 
     async def _timed_step(self, *round_args):
+        """The round, timed for the trainer's step record on the record's
+        clock: ``matchmaking_s`` (entering → the first group formed),
+        ``allreduce_s`` (→ result) and, from the formation on, the span tree
+        inside ``allreduce`` (``allreduce.RoundTrace``: ``spans`` as
+        ``(name, parent, t0, t1[, count, total_s])``, every ``run`` of a
+        hierarchical round appended) with the loop thread's CPU seconds
+        over it — telemetry on or off."""
         started = telemetry.monotonic_clock()
         self._round_formed_at = None
+        self._round_trace = None
         try:
             return await self._step_async(*round_args)
         finally:
@@ -592,17 +603,33 @@ class DecentralizedAverager:
             formed = self._round_formed_at
             if formed is None:
                 formed = done
+            trace, self._round_trace = self._round_trace, None
+            if trace is not None:
+                trace.close(at=done)  # a failed round leaves no stage open
             self.last_round_timing = {
                 "started_at": started,
                 "matchmaking_s": max(0.0, formed - started),
                 "allreduce_s": max(0.0, done - formed),
+                "spans": trace.spans if trace is not None else [],
+                "loop_cpu_s": trace.loop_cpu_s if trace is not None else 0.0,
             }
 
     async def _form_group(self, round_id: str, **kwargs):
         group = await self.matchmaking.form_group(round_id, **kwargs)
         if self._round_formed_at is None:
             self._round_formed_at = telemetry.monotonic_clock()
+            # the round's span tree starts where ``allreduce`` does, waiting
+            # for the contribution to come off the device
+            self._round_trace = self.allreduce.begin_trace(
+                "ar_resolve", at=self._round_formed_at
+            )
         return group
+
+    def _stage(self, name: str) -> None:
+        """The round's coroutine enters stage ``name`` (nothing before a
+        group has formed: the tree starts there)."""
+        if self._round_trace is not None:
+            self._round_trace.stage(name)
 
     async def _step_async(
         self, tree: Dict[str, np.ndarray], weight: float, round_id: str,
@@ -710,6 +737,7 @@ class DecentralizedAverager:
                 logger.warning(f"{round_id}: device-flat fetch failed: {e!r}")
                 self.last_contributors = 0
                 return None, 1
+        self._stage("ar_prepare")
         self.last_group_size = len(group.members)
         # gradient-bearing member count for the caller's divergence guard:
         # a {trainer, aux} group averages nothing for the trainer
@@ -731,6 +759,7 @@ class DecentralizedAverager:
                 # group-negotiated size (min of advertised; 0 = monolithic
                 # if any member can't chunk), never the local config alone
                 chunk_size=group.chunk_size,
+                trace=self._round_trace,
             )
         except AllreduceFailed as e:
             logger.warning(f"allreduce failed for {round_id}: {e}")
@@ -825,6 +854,7 @@ class DecentralizedAverager:
         if not await settle():
             self.last_contributors = 0
             return None, 1
+        self._stage("ar_prepare")
         self.last_group_size = len(group.members)
         self.last_contributors = group.contributors
         if len(group.members) == 1:
@@ -837,7 +867,7 @@ class DecentralizedAverager:
                 f"{self.prefix}:{round_id}:{group.nonce}",
                 group.my_index, flat, weight,
                 group.endpoints, group.bandwidths,
-                chunk_size=group.chunk_size,
+                chunk_size=group.chunk_size, trace=self._round_trace,
             )
         except AllreduceFailed as e:
             logger.warning(f"gossip round failed for {round_id}: {e}")
@@ -1074,6 +1104,7 @@ class DecentralizedAverager:
         if not await settle():
             self.last_contributors = 0
             return None, 1
+        self._stage("ar_prepare")
         flat = self._flatten(tree)
 
         sum_vec: Optional[np.ndarray] = None
@@ -1104,7 +1135,7 @@ class DecentralizedAverager:
                     group.my_index, flat, weight,
                     group.endpoints, group.bandwidths,
                     chunk_size=group.chunk_size,
-                    normalize=False,
+                    normalize=False, trace=self._round_trace,
                 )
             except AllreduceFailed as e:
                 logger.warning(f"clique sum failed for {round_id}: {e}")
@@ -1194,7 +1225,7 @@ class DecentralizedAverager:
                     1.0 if w_sum > 0 else 0.0,
                     wan_group.endpoints, wan_group.bandwidths,
                     chunk_size=wan_group.chunk_size,
-                    norm_weight=w_sum,
+                    norm_weight=w_sum, trace=self._round_trace,
                 )
             else:
                 # singleton clique: plain (flat-semantics) contribution
@@ -1202,7 +1233,7 @@ class DecentralizedAverager:
                     f"{self.prefix}:{round_id}:{wan_group.nonce}",
                     wan_group.my_index, flat, weight,
                     wan_group.endpoints, wan_group.bandwidths,
-                    chunk_size=wan_group.chunk_size,
+                    chunk_size=wan_group.chunk_size, trace=self._round_trace,
                 )
         except (MatchmakingFailed, AllreduceFailed, ConnectionError,
                 OSError) as e:

@@ -342,12 +342,21 @@ class DeviceFlatPipeline:
         computed on params this peer no longer holds)."""
         self._residual = None
 
-    def residual_norm(self) -> float:
+    def residual_norm_launch(self):
+        """The carried residual's L2 norm as a device scalar whose transfer
+        to the host has STARTED (``float()`` of it later costs no wait once
+        the device got there), or 0.0 with no residual: a reader on the
+        round's path launches it and reads it at its next boundary."""
         if self._residual is None:
             return 0.0
         import jax.numpy as jnp
 
-        return float(jnp.sqrt(jnp.vdot(self._residual, self._residual).real))
+        norm = jnp.sqrt(jnp.vdot(self._residual, self._residual).real)
+        norm.copy_to_host_async()
+        return norm
+
+    def residual_norm(self) -> float:
+        return float(self.residual_norm_launch())
 
     # --------------------------------------------------------------- fetch
 

@@ -353,6 +353,10 @@ class CollaborativeOptimizer:
         # the record that is live when it next runs (the averager's
         # ``last_round_timing`` is the pattern)
         self._finished_backups: collections.deque = collections.deque()
+        # the error-feedback residual's norm, launched on the round's path
+        # (a device scalar on its way to the host) for the
+        # ``opt.ef_residual_norm`` gauge: ``step`` reads it when it next runs
+        self._pending_ef_norm = None
         # jit↔host seam telemetry (ms, last global step)
         self.seam_ms: Dict[str, float] = {}
         self._desynced = False
@@ -418,6 +422,11 @@ class CollaborativeOptimizer:
         assert not self.auxiliary, "auxiliary peers must use step_aux()"
         record = steps.current()
         entered = record.elapsed() if record is not None else 0.0
+        ef_norm, self._pending_ef_norm = self._pending_ef_norm, None
+        if ef_norm is not None:
+            tele = telemetry.resolve(self.telemetry)
+            if tele is not None:
+                tele.gauge("opt.ef_residual_norm").set(float(ef_norm))
         while self._finished_backups:
             t0, t1, nbytes = self._finished_backups.popleft()
             tele = telemetry.resolve(self.telemetry)
@@ -783,12 +792,10 @@ class CollaborativeOptimizer:
             lossy_d2h = pipeline.ef_enabled
             if use_ef and tele is not None:
                 with steps.phase("ef_norm"):
-                    # telemetry's own cost, and a host sync: the gauge reads
-                    # a scalar off the device (the ``vdot`` program in a
-                    # trace), behind whatever the device has queued
-                    tele.gauge("opt.ef_residual_norm").set(
-                        pipeline.residual_norm()
-                    )
+                    # telemetry's own cost: LAUNCH the ``vdot`` program and
+                    # the scalar's transfer, sync nothing — ``step`` sets the
+                    # gauge when it next runs, a boundary later
+                    self._pending_ef_norm = pipeline.residual_norm_launch()
         else:
             # legacy host seam (non-float leaves refused the pipeline):
             # per-leaf device_get + host flatten + host error feedback
@@ -876,6 +883,20 @@ class CollaborativeOptimizer:
                     steps.attach(
                         "allreduce", formed, formed + timing["allreduce_s"]
                     )
+                    # and inside ``allreduce``: the stages of the round's
+                    # coroutine (they tile it) and the kinds of work the
+                    # loop thread summed (averaging/allreduce.RoundTrace)
+                    for name, parent, t0, t1, *folded in timing.get(
+                        "spans", ()
+                    ):
+                        count, total_s = folded or (None, None)
+                        steps.attach(
+                            name, t0, t1, parent=parent, count=count,
+                            total_s=total_s,
+                        )
+                    record = steps.current()
+                    if record is not None and "loop_cpu_s" in timing:
+                        record.attrs["ar_loop_cpu_s"] = timing["loop_cpu_s"]
             wire_wall = wire.dur_s
             if self.overlap_averaging and tele is not None:
                 # overlap ledger, synchronous-fallback form: this round ran

@@ -27,6 +27,7 @@ import time
 from typing import Any, Awaitable, Callable, Dict, Optional, Tuple
 
 from dedloc_tpu.core.serialization import pack_obj, unpack_obj
+from dedloc_tpu.core.timeutils import monotonic as _clock
 from dedloc_tpu.dht import transport as transport_mod
 from dedloc_tpu.telemetry import registry as telemetry
 from dedloc_tpu.testing import faults
@@ -40,7 +41,9 @@ MAX_FRAME = 512 * 1024 * 1024  # tensors ride this transport too
 _LEN = struct.Struct("!I")
 
 
-async def read_frame(reader: asyncio.StreamReader) -> Any:
+async def read_frame(reader: asyncio.StreamReader, owner=None) -> Any:
+    """One frame off ``reader``. ``owner`` (an ``RPCClient`` / ``RPCServer``)
+    is charged the unpack on its frame accumulator (``_frame_work``)."""
     header = await reader.readexactly(_LEN.size)
     (length,) = _LEN.unpack(header)
     if length > MAX_FRAME:
@@ -48,7 +51,9 @@ async def read_frame(reader: asyncio.StreamReader) -> Any:
     payload = await reader.readexactly(length)
     if telemetry._active is not None:  # process-wide wire accounting
         telemetry._active.counter("net.bytes_in").inc(_LEN.size + length)
-    return unpack_obj(payload)
+    if owner is None:
+        return unpack_obj(payload)
+    return _frame_work(owner, unpack_obj, payload)
 
 
 def write_frame(writer: asyncio.StreamWriter, obj: Any) -> None:
@@ -59,6 +64,31 @@ def write_frame(writer: asyncio.StreamWriter, obj: Any) -> None:
         telemetry._active.counter("net.bytes_out").inc(
             _LEN.size + len(payload)
         )
+
+
+def _frame_work(owner, work, *args):
+    """Run one synchronous piece of frame I/O (``write_frame``: pack + the
+    hand-over to the transport; ``unpack_obj`` of a frame read) and add its
+    seconds to ``owner``'s always-on accumulator: ``frame_s`` / ``frames``
+    on every ``RPCClient`` and ``RPCServer``, two clock reads a frame. An
+    all-reduce round reads them as a delta (its ``ar_frame`` span): what the
+    loop thread spent framing while the round ran, DHT chatter on the same
+    loop included. With telemetry on the piece is ``dedloc/frame`` on the
+    profiler's host plane."""
+    annotation = (
+        telemetry.trace_annotation("frame")
+        if telemetry.resolve(owner.telemetry) is not None else None
+    )
+    if annotation is not None:
+        annotation.__enter__()
+    t0 = _clock()
+    try:
+        return work(*args)
+    finally:
+        owner.frame_s += _clock() - t0
+        owner.frames += 1
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
 
 
 def _expire_response(fut: "asyncio.Future") -> None:
@@ -157,6 +187,10 @@ class RPCServer:
             int, Tuple[asyncio.Future, asyncio.StreamWriter]
         ] = {}
         self._next_call_id = 0
+        # seconds / count of this server's synchronous frame work
+        # (``_frame_work``), always on
+        self.frame_s = 0.0
+        self.frames = 0
 
     def register(self, method: str, handler: Handler) -> None:
         self._handlers[method] = handler
@@ -184,7 +218,7 @@ class RPCServer:
         if tc is not None:
             request["tc"] = tc
         try:
-            write_frame(writer, request)
+            _frame_work(self, write_frame, writer, request)
             await writer.drain()
             reply = await asyncio.wait_for(fut, timeout=timeout)
         finally:
@@ -231,7 +265,7 @@ class RPCServer:
         try:
             while True:
                 try:
-                    msg = await read_frame(reader)
+                    msg = await read_frame(reader, self)
                 except (asyncio.IncompleteReadError, ConnectionResetError, OSError):
                     return
                 if msg.get("method") is None:
@@ -311,7 +345,7 @@ class RPCServer:
                 tele.counter("rpc.server.errors").inc()
             reply = {"id": req_id, "ok": False, "error": repr(e)}
         try:
-            write_frame(writer, reply)
+            _frame_work(self, write_frame, writer, reply)
             await writer.drain()
         except (OSError, RuntimeError):
             # best-effort reply: any transport-level failure (reset, broken
@@ -336,6 +370,10 @@ class RPCClient:
         self._readers: Dict[Endpoint, asyncio.Task] = {}
         self._next_id = 0
         self._conn_locks: Dict[Endpoint, asyncio.Lock] = {}
+        # seconds / count of this client's synchronous frame work
+        # (``_frame_work``), always on
+        self.frame_s = 0.0
+        self.frames = 0
         # circuit relay: requests relayed to THIS (otherwise unreachable)
         # peer arrive on its outbound relay connection and dispatch here —
         # point this at an RPCServer's handler dict to expose its methods
@@ -388,7 +426,7 @@ class RPCClient:
     async def _read_loop(self, endpoint: Endpoint, reader: asyncio.StreamReader):
         try:
             while True:
-                msg = await read_frame(reader)
+                msg = await read_frame(reader, self)
                 if msg.get("method") is not None:
                     # relayed request piped to us down our own outbound
                     # connection (circuit relay): serve it and reply in-band
@@ -418,7 +456,7 @@ class RPCClient:
         if conn is None:
             return
         try:
-            write_frame(conn[1], reply)
+            _frame_work(self, write_frame, conn[1], reply)
             await conn[1].drain()
         except (OSError, RuntimeError):
             # best-effort reply: any transport-level failure (reset, broken
@@ -610,7 +648,7 @@ class RPCClient:
         tc = trace_field(tele)
         if tc is not None:
             request["tc"] = tc
-        write_frame(writer, request)
+        _frame_work(self, write_frame, writer, request)
         # hand-rolled deadline instead of asyncio.wait_for: the response
         # future is a bare Future (no task wrapping needed), so the whole
         # timeout is one timer that fails the future — wait_for's

@@ -13,15 +13,11 @@ a consumer reads a key nothing emits (docs/contributor.md).
 
 ALLREDUCE_BYTES_RECEIVED = "allreduce.bytes_received"
 ALLREDUCE_BYTES_SENT = "allreduce.bytes_sent"
-ALLREDUCE_CHUNK_LATENCY_S = "allreduce.chunk_latency_s"
-ALLREDUCE_CHUNKS_RECEIVED = "allreduce.chunks_received"
-ALLREDUCE_CHUNKS_SENT = "allreduce.chunks_sent"
 ALLREDUCE_FAILURES = "allreduce.failures"
 ALLREDUCE_LINK = "allreduce.link"
 ALLREDUCE_ROUND = "allreduce.round"
 ALLREDUCE_ROUNDS = "allreduce.rounds"
 ALLREDUCE_STRAGGLERS = "allreduce.stragglers"
-AVG_BYTES_SAVED = "avg.bytes_saved"
 AVG_ROUND = "avg.round"
 AVG_TOPOLOGY_FALLBACK = "avg.topology.fallback"
 AVG_TOPOLOGY_FALLBACKS = "avg.topology.fallbacks"
@@ -159,12 +155,9 @@ WATCH_ROLLBACKS = "watch.rollbacks"
 COUNTERS = frozenset({
     "allreduce.bytes_received",
     "allreduce.bytes_sent",
-    "allreduce.chunks_received",
-    "allreduce.chunks_sent",
     "allreduce.failures",
     "allreduce.rounds",
     "allreduce.stragglers",
-    "avg.bytes_saved",
     "avg.topology.fallbacks",
     "avg.topology.replans",
     "avg.topology.rounds",
@@ -250,7 +243,6 @@ GAUGES = frozenset({
     "step.samples_per_sec",
 })
 HISTOGRAMS = frozenset({
-    "allreduce.chunk_latency_s",
     "allreduce.round",
     "avg.round",
     "ckpt.manifest.serve",

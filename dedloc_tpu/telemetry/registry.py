@@ -41,6 +41,7 @@ import json
 import math
 import sys
 import threading
+import time
 import uuid
 from collections import deque
 from contextlib import contextmanager
@@ -58,6 +59,17 @@ def monotonic_clock() -> float:
     gets plain ``time.monotonic``. Alias of ``timeutils.monotonic`` — kept
     as the registry's public name for clock injection."""
     return timeutils.monotonic()
+
+
+def thread_cpu_clock() -> float:
+    """CPU seconds the CALLING thread has run (``time.thread_time``). A
+    span's wall minus the CPU its thread spent inside it is what the GIL and
+    the scheduler took — nothing else in the process can say that. Under a
+    FakeClock or a simulator time source it reads 0.0: the host's CPU has no
+    place on a scripted timeline."""
+    if timeutils._dht_time_source is not None or timeutils._dht_time_offset:
+        return 0.0
+    return time.thread_time()
 
 
 def trace_annotation(name: str, **kwargs):

@@ -25,15 +25,27 @@ spans (docs/observability.md "Step-phase flight recorder"):
 - ``grad_flatten`` launching the device-side flatten/quantize program (or,
                    on the legacy path, the per-leaf device_get + host
                    flatten of the mean grads — the jit↔host seam crossing)
-- ``ef_norm``      telemetry's own: the ``opt.ef_residual_norm`` gauge reads
-                   a scalar off the device (a host sync; telemetry on only)
+- ``ef_norm``      telemetry's own: LAUNCHING the scalar program and its
+                   transfer for the ``opt.ef_residual_norm`` gauge (no host
+                   sync: ``opt.step`` sets the gauge when it next runs)
 - ``avg_wire``     the synchronous averaging round. Children:
                    ``d2h_stream`` (the EXPOSED remainder of the async
                    device→host gradient stream, ~0 when matchmaking hides
                    it) and, measured on the DHT loop's thread by the
                    averager, ``matchmaking`` (entering the round → group
                    formed, the wait for the partner included) and
-                   ``allreduce`` (group formed → result)
+                   ``allreduce`` (group formed → result). ``allreduce`` is
+                   cut two ways (``averaging/allreduce.py``): its STAGES
+                   ``ar_resolve`` / ``ar_prepare`` / ``ar_scatter`` /
+                   ``ar_gather`` (child ``ar_straggler``) / ``ar_finish``
+                   are the round's coroutine end to end and tile it; its
+                   KINDS ``ar_encode`` / ``ar_decode`` / ``ar_reduce`` /
+                   ``ar_copy`` / ``ar_frame`` are folded sums of what the
+                   loop thread did inside it and overlap the stages
+                   (``allreduce`` − Σ kinds: the loop thread waiting);
+                   ``ar_partner_lag`` is how late the partner's first part
+                   landed. ``ar_loop_cpu_s`` on the record is the loop
+                   thread's CPU time over ``allreduce``
 - ``opt_apply``    optimizer apply + NaN guard; child ``h2d_result`` (the
                    averaged flat buffer going back to the device)
 - ``backup_launch`` the on-thread part of the state backup: one host sync
@@ -54,8 +66,10 @@ wall; ``spans`` carries the tree itself, ``[name, parent, t0_s, t1_s]`` with
 offsets from the record's start (a run of more than 64 spans is folded:
 repeated leaf spans of one name under one parent become ``[name, parent,
 first t0_s, last t1_s, count, total_s]``). Spans ATTACHED from another
-thread's clock readings (``matchmaking`` / ``allreduce``) are in ``spans``
-only: they split their parent, they are not this thread's time.
+thread's clock readings (``matchmaking`` / ``allreduce`` / ``ar_*``,
+``backup_transfer``; ``attach`` is the one way in, and takes a folded span
+whole) are in ``spans`` only: they split their parent, they are not this
+thread's time.
 
 Design rules, mirroring ``registry.py``:
 
@@ -207,13 +221,16 @@ class _StepContext:
     per-name self times, plus free-form attrs (``ctx.attrs["stepped"] =
     True``) merged into the final record."""
 
-    __slots__ = ("phases", "totals", "spans", "attrs", "step", "boundary",
-                 "samples", "_clock", "_start", "_stack")
+    __slots__ = ("phases", "totals", "attached", "spans", "attrs", "step",
+                 "boundary", "samples", "_clock", "_start", "_stack",
+                 "_folded")
 
     def __init__(self, step: Optional[int], boundary: int, samples: int,
                  clock) -> None:
         self.phases: Dict[str, float] = {}  # name -> SELF seconds
         self.totals: Dict[str, float] = {}  # name -> seconds, children in
+        # name -> seconds of the spans ATTACHED from other threads' readings
+        self.attached: Dict[str, float] = {}
         # closed spans: [name, parent, t0, t1, leaf] or, folded,
         # [name, parent, first t0, last t1, True, count, total]
         self.spans: List[list] = []
@@ -224,6 +241,7 @@ class _StepContext:
         self._clock = clock
         self._start = clock()
         self._stack: List[Span] = []
+        self._folded = False  # the record overflowed MAX_SPANS once
 
     def elapsed(self) -> float:
         """Seconds since the record began, on the record's clock."""
@@ -246,14 +264,26 @@ class _StepContext:
         span.t0 = span.t1 - max(0.0, seconds)
         self._close(span, pop=False)
 
-    def attach(self, name: str, t0: float, t1: float) -> None:
+    def attach(
+        self, name: str, t0: float, t1: float, parent: Optional[str] = None,
+        count: Optional[int] = None, total_s: Optional[float] = None,
+    ) -> None:
         """A span read off this clock by ANOTHER thread (the averager's
-        ``matchmaking`` / ``allreduce`` on the DHT loop), as a child of the
-        open span. It splits its parent for the reader; it is not this
-        thread's time, so neither ``phases`` nor the parent's self time
+        ``matchmaking`` / ``allreduce`` / ``ar_*`` on the DHT loop, the
+        backup thread's ``backup_transfer``), as a child of ``parent`` (the
+        open span when left out). With ``count`` and ``total_s`` it is a
+        FOLDED entry: ``count`` sections between ``t0`` and ``t1`` that took
+        ``total_s`` together. It splits its parent for the reader; it is not
+        this thread's time, so neither ``phases`` nor the parent's self time
         change."""
-        parent = self._stack[-1].name if self._stack else None
-        self._record(name, parent, t0, t1, True)
+        if parent is None and self._stack:
+            parent = self._stack[-1].name
+        seconds = max(0.0, t1 - t0) if total_s is None else total_s
+        self.attached[name] = self.attached.get(name, 0.0) + seconds
+        self._record(
+            name, parent, t0, t1, True,
+            *(() if count is None else (int(count), float(seconds))),
+        )
 
     # ------------------------------------------------------------- internal
 
@@ -274,10 +304,11 @@ class _StepContext:
             span.t0, span.t1, span.leaf,
         )
 
-    def _record(self, name, parent, t0, t1, leaf) -> None:
-        self.spans.append(
-            [name, parent, t0 - self._start, max(t0, t1) - self._start, leaf]
-        )
+    def _record(self, name, parent, t0, t1, leaf, *folded) -> None:
+        self.spans.append([
+            name, parent, t0 - self._start, max(t0, t1) - self._start, leaf,
+            *folded,
+        ])
         if len(self.spans) > MAX_SPANS:
             self._fold()
 
@@ -285,6 +316,7 @@ class _StepContext:
         """Merge the leaf spans of one name under one parent into a single
         entry with a count and a total: a boundary of many micro-batches
         stays a bounded record."""
+        self._folded = True
         folded: Dict[tuple, list] = {}
         out: List[list] = []
         for span in self.spans:
@@ -304,13 +336,14 @@ class _StepContext:
             into[5] += count
             into[6] += total
         self.spans = [
-            s[:5] if len(s) > 5 and s[5] == 1 else s for s in out
+            s[:5] if len(s) > 5 and s[5] == 1 and s[6] == s[3] - s[2] else s
+            for s in out
         ]
 
     def finished_spans(self) -> List[list]:
         """``[name, parent, t0_s, t1_s]`` (``+ [count, total_s]`` when
         folded), microsecond precision, in closing order."""
-        if any(len(s) > 5 for s in self.spans):
+        if self._folded:
             self._fold()  # a record that folded once ends folded throughout
         return [
             [s[0], s[1], round(s[2], 6), round(s[3], 6)]
@@ -345,11 +378,11 @@ def add(name: str, seconds: float) -> None:
         ctx.add(name, seconds)
 
 
-def attach(name: str, t0: float, t1: float) -> None:
+def attach(name: str, t0: float, t1: float, **where) -> None:
     """``_StepContext.attach`` on the live record (no-op when none is)."""
     ctx = _CURRENT.get()
     if ctx is not None:
-        ctx.attach(name, t0, t1)
+        ctx.attach(name, t0, t1, **where)
 
 
 def train_log_row(rec: _StepContext) -> Dict[str, Any]:
@@ -412,10 +445,11 @@ class StepRecorder:
         self.perf = perf
         self.profile = profile
         self.boundaries = 0  # records begun: the next record's index
-        # the global step being assembled (its records' wall and span
-        # totals), and the last SLOW_WINDOW finished ones
+        # the global step being assembled (its records' wall, span totals
+        # and attached spans' totals), and the last SLOW_WINDOW finished ones
         self._step_wall = 0.0
         self._step_totals: Dict[str, float] = {}
+        self._step_attached: Dict[str, float] = {}
         self._recent_steps: Deque[tuple] = deque(maxlen=self.SLOW_WINDOW)
 
     @contextmanager
@@ -477,7 +511,7 @@ class StepRecorder:
         if mfu is not None:
             record["mfu"] = mfu
         self.records.append(record)
-        self._notice_slow_step(record, ctx.totals)
+        self._notice_slow_step(record, ctx.totals, ctx.attached)
         if self.perf is not None:
             self.perf.metric("boundary").update(wall)
             for name, dur in phases.items():
@@ -492,38 +526,54 @@ class StepRecorder:
             k: v for k, v in record.items() if k != "wall_s"
         })
 
-    def _notice_slow_step(self, record, totals: Dict[str, float]) -> None:
+    def _notice_slow_step(
+        self, record, totals: Dict[str, float], attached: Dict[str, float],
+    ) -> None:
         """Fold the record into the global step being assembled; when the
         step completes, compare its wall with the running median and say —
         ONE line, at INFO: a WARNING is a failed step to whoever counts
-        them — which spans it spent more in than a median step does."""
+        them — which spans it spent more in than a median step does, and
+        which spans OTHER threads ran beside it (the attached ones: a
+        ``backup_transfer``, an averaging stage), against their medians."""
         self._step_wall += record["wall_s"]
-        for name, seconds in totals.items():
-            self._step_totals[name] = (
-                self._step_totals.get(name, 0.0) + seconds
-            )
+        for into, seconds_by_name in (
+            (self._step_totals, totals), (self._step_attached, attached),
+        ):
+            for name, seconds in seconds_by_name.items():
+                into[name] = into.get(name, 0.0) + seconds
         if not record.get("stepped"):
             return
-        wall, spans = self._step_wall, self._step_totals
-        self._step_wall, self._step_totals = 0.0, {}
+        wall, spans, beside = (
+            self._step_wall, self._step_totals, self._step_attached
+        )
+        self._step_wall, self._step_totals, self._step_attached = 0.0, {}, {}
         recent = list(self._recent_steps)
-        self._recent_steps.append((wall, spans))
+        self._recent_steps.append((wall, spans, beside))
         if len(recent) < self.SLOW_MIN_STEPS:
             return
-        median = statistics.median(w for w, _s in recent)
+        median = statistics.median(w for w, _s, _b in recent)
         if wall <= self.SLOW_FACTOR * median:
             return
-        usual = {
-            name: statistics.median(s.get(name, 0.0) for _w, s in recent)
-            for name in spans
-        }
-        over = sorted(spans, key=lambda n: usual[n] - spans[n])
+
+        def over_usual(now: Dict[str, float], column: int, top: int) -> str:
+            usual = {
+                name: statistics.median(
+                    step[column].get(name, 0.0) for step in recent
+                )
+                for name in now
+            }
+            over = sorted(now, key=lambda n: usual[n] - now[n])
+            return ", ".join(
+                f"{n} {now[n]:.3f} ({now[n] - usual[n]:+.3f})"
+                for n in over[:top]
+            )
+
         logger.info(
             f"slow global step {record.get('step')}: {wall:.3f} s against a "
             f"median of {median:.3f} s over the last {len(recent)}; spans "
-            "(s, against their median): " + ", ".join(
-                f"{n} {spans[n]:.3f} ({spans[n] - usual[n]:+.3f})"
-                for n in over[:6]
+            "(s, against their median): " + over_usual(spans, 1, 6) + (
+                "; on other threads beside it: " + over_usual(beside, 2, 4)
+                if beside else ""
             )
         )
 

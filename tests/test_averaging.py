@@ -11,6 +11,7 @@ from dedloc_tpu.averaging.allreduce import (
     DEFAULT_CHUNK_SIZE,
     AllreduceFailed,
     GroupAllReduce,
+    span_chunks,
 )
 from dedloc_tpu.averaging.matchmaking import Matchmaking, MatchmakingFailed, Member
 from dedloc_tpu.averaging.partition import (
@@ -82,7 +83,8 @@ async def _allreduce_swarm(vectors, weights, bandwidths, client_mask=None,
                            compression=CompressionType.NONE,
                            chunk_size=DEFAULT_CHUNK_SIZE, dead=(),
                            straggler_timeout=5.0, telemetries=None,
-                           round_id="round1", fault_setup=None):
+                           round_id="round1", fault_setup=None,
+                           reducers_out=None, timeout=10.0):
     """Run a full group all-reduce among n in-process peers over loopback
     RPC; returns results. ``dead`` members never run (straggler scenarios —
     pass a short ``straggler_timeout`` to keep those tests fast). Shared
@@ -93,7 +95,9 @@ async def _allreduce_swarm(vectors, weights, bandwidths, client_mask=None,
     estimates per simulated peer; each listening peer then also emits the
     peer.endpoint self-identification event like a real averager.
     ``fault_setup(clients, endpoints)`` runs after the sockets exist and
-    before the round — the hook link-level fault injection needs."""
+    before the round — the hook link-level fault injection needs.
+    ``reducers_out`` (a list) receives the peers' ``GroupAllReduce`` objects:
+    their ``last_trace`` is the round's span tree."""
     n = len(vectors)
     client_mask = client_mask or [False] * n
     telemetries = telemetries or [None] * n
@@ -109,7 +113,7 @@ async def _allreduce_swarm(vectors, weights, bandwidths, client_mask=None,
         clients.append(client)
         servers.append(server)
         reducers.append(GroupAllReduce(client, server, compression=compression,
-                                       timeout=10.0,
+                                       timeout=timeout,
                                        straggler_timeout=straggler_timeout,
                                        chunk_size=chunk_size,
                                        telemetry_registry=telemetries[i]))
@@ -118,6 +122,8 @@ async def _allreduce_swarm(vectors, weights, bandwidths, client_mask=None,
             telemetries[i].event(
                 "peer.endpoint", endpoint=f"127.0.0.1:{server.port}"
             )
+    if reducers_out is not None:
+        reducers_out.extend(reducers)
     eff_bw = [0.0 if client_mask[i] else bandwidths[i] for i in range(n)]
     if fault_setup is not None:
         fault_setup(clients, endpoints)
@@ -272,6 +278,307 @@ def test_allreduce_dead_member_fails_round(rng):
                 await s.stop()
 
     asyncio.run(run())
+
+
+# ------------------------------------------- the span tree inside a round
+
+STAGES = ("ar_resolve", "ar_prepare", "ar_scatter", "ar_gather", "ar_finish")
+KINDS = ("ar_encode", "ar_decode", "ar_reduce", "ar_copy", "ar_frame")
+
+
+def _by_name(spans):
+    out = {}
+    for span in spans:
+        out.setdefault(span[0], []).append(span)
+    return out
+
+
+def _hosted_chunks(dim, n, chunk_size, index):
+    """Chunks of the span member ``index`` hosts, as every member derives
+    them: equal bandwidths, everyone can host."""
+    lo, hi = partition_weighted(dim, [1.0] * n, [True] * n)[index]
+    return len(span_chunks(lo, hi, chunk_size))
+
+
+def test_round_span_tree_tiles_allreduce_and_counts_the_chunk_work(rng):
+    """A real two-peer loopback round (float16 wire, chunked) through the
+    averager: ``last_round_timing["spans"]`` holds the five stages, which
+    tile ``allreduce`` (group formed → result), and the loop thread's work
+    by kind, whose counts are the chunk operations the code performs."""
+    from dedloc_tpu.averaging import DecentralizedAverager
+    from dedloc_tpu.dht import DHT
+    from dedloc_tpu.telemetry.registry import Telemetry
+
+    dim, chunk = 10_000, 1_000
+    first = DHT(start=True, listen_host="127.0.0.1")
+    second = DHT(start=True, listen_host="127.0.0.1",
+                 initial_peers=[first.get_visible_address()])
+    teles = [Telemetry(peer=f"p{i}") for i in range(2)]
+    avgs = [
+        DecentralizedAverager(
+            dht, "spans", averaging_expiration=5.0, averaging_timeout=10.0,
+            listen_host="127.0.0.1", chunk_size=chunk,
+            compression=CompressionType.FLOAT16, telemetry_registry=tele,
+        )
+        for dht, tele in zip((first, second), teles)
+    ]
+    trees = [{"w": rng.standard_normal(dim).astype(np.float32)}
+             for _ in avgs]
+    out = {}
+
+    def peer(i):
+        out[i] = avgs[i].step(trees[i], weight=1.0, round_id="g1",
+                              expected_size=2)
+
+    try:
+        threads = [threading.Thread(target=peer, args=(i,)) for i in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert all(out[i][1] == 2 for i in range(2)), out
+        for i, (avg, tele) in enumerate(zip(avgs, teles)):
+            timing = avg.last_round_timing
+            spans = _by_name(timing["spans"])
+            formed = timing["started_at"] + timing["matchmaking_s"]
+            done = formed + timing["allreduce_s"]
+            # the stages: each once, in order, edge to edge from the group's
+            # formation to the step's end — they tile allreduce
+            stages = [s for s in timing["spans"] if s[0] in STAGES]
+            assert [s[0] for s in stages] == list(STAGES)
+            assert all(s[1] == "allreduce" for s in stages)
+            assert stages[0][2] == formed
+            for before, after in zip(stages, stages[1:]):
+                assert before[3] == after[2]
+            assert stages[-1][3] == pytest.approx(done, abs=1e-9)
+            assert sum(s[3] - s[2] for s in stages) == pytest.approx(
+                timing["allreduce_s"], abs=1e-3
+            )
+            (straggler,) = spans["ar_straggler"]
+            (gather,) = spans["ar_gather"]
+            assert straggler[1] == "ar_gather"
+            assert gather[2] == straggler[2] <= straggler[3] <= gather[3]
+            # the kinds: folded sums whose counts are the chunk operations
+            mine = _hosted_chunks(dim, 2, chunk, i)
+            theirs = _hosted_chunks(dim, 2, chunk, 1 - i)
+            kinds = {k: spans[k][0] for k in KINDS}
+            assert all(len(spans[k]) == 1 and len(kinds[k]) == 6
+                       and kinds[k][1] == "allreduce" for k in KINDS)
+            # encode: my parts to the partner, my own part and the reduced
+            # chunks I adopt through the codec, the partner's first pull
+            assert kinds["ar_encode"][4] == theirs + 3 * mine
+            # decode: the partner's parts of my span, the chunks I pull
+            assert kinds["ar_decode"][4] == mine + theirs
+            # reduce: two accumulates and one finalize per hosted chunk
+            assert kinds["ar_reduce"][4] == 3 * mine
+            # copy: every chunk of the result, and the local_span copy
+            assert kinds["ar_copy"][4] == mine + theirs + 1
+            assert kinds["ar_frame"][4] >= 2 * (mine + theirs)
+            for kind in kinds.values():
+                # (a partner's first part may land a moment before my own
+                # reading of "formed": no lower bound on a kind's first t0)
+                assert kind[2] <= kind[3] <= done
+                assert 0 < kind[5] <= kind[3] - kind[2] + 1e-9
+            (lag,) = spans["ar_partner_lag"]
+            assert lag[2] == formed and 0 <= lag[3] - lag[2] <= (
+                timing["allreduce_s"]
+            )
+            assert 0 <= timing["loop_cpu_s"] <= timing["allreduce_s"] + 0.05
+            # the operator's event carries the same sums
+            (event,) = [e for e in tele.events
+                        if e["event"] == "allreduce.round"]
+            for kind, field in (("ar_encode", "encode_s"),
+                                ("ar_decode", "decode_s"),
+                                ("ar_reduce", "reduce_s"),
+                                ("ar_copy", "copy_s")):
+                assert event[field] == round(kinds[kind][5], 6)
+            assert event["frame_s"] > 0 and event["wait_s"] >= 0
+            assert event["partner_lag_s"] == round(lag[3] - lag[2], 6)
+            assert event["chunks"] == mine + theirs
+    finally:
+        for avg in avgs:
+            avg.shutdown()
+        second.shutdown()
+        first.shutdown()
+
+
+def test_round_span_tree_under_a_frozen_clock_is_deterministic(
+    rng, monkeypatch
+):
+    """Under a frozen FakeClock the only time that passes is what the test
+    puts in: 10 ms inside every ``serialize_array``. Every stage edge then
+    lands on a whole number of those, the stages tile to the float, each
+    peer's ``ar_encode`` total is its own serialize calls (its
+    ``wire_roundtrip`` sections took nothing), and the loop's CPU reads 0."""
+    from dedloc_tpu.averaging import allreduce as ar
+    from dedloc_tpu.testing.faults import FakeClock
+
+    n, dim, chunk, tick = 2, 6_000, 500, 0.01
+    vectors = [rng.standard_normal(dim).astype(np.float32) for _ in range(n)]
+    reducers = []
+    with FakeClock(frozen=True) as clock:
+        real = ar.serialize_array
+
+        def slow_serialize(*args, **kwargs):
+            clock.advance(tick)
+            return real(*args, **kwargs)
+
+        monkeypatch.setattr(ar, "serialize_array", slow_serialize)
+        asyncio.run(_allreduce_swarm(
+            vectors, [1.0] * n, [1.0] * n,
+            compression=CompressionType.FLOAT16, chunk_size=chunk,
+            reducers_out=reducers,
+        ))
+    for i, reducer in enumerate(reducers):
+        trace = reducer.last_trace
+        assert trace.open_stage is None and trace.loop_cpu_s == 0.0
+        spans = _by_name(trace.spans)
+        stages = [s for s in trace.spans if s[0] in STAGES]
+        # a bare run() traces itself from ar_prepare
+        assert [s[0] for s in stages] == list(STAGES[1:])
+        for span in trace.spans:
+            for edge in span[2:4]:
+                ticks = (edge - trace.started_at) / tick
+                assert abs(ticks - round(ticks)) < 1e-6, span
+        for before, after in zip(stages, stages[1:]):
+            assert before[3] == after[2]
+        mine = _hosted_chunks(dim, n, chunk, i)
+        theirs = _hosted_chunks(dim, n, chunk, 1 - i)
+        (encode,) = spans["ar_encode"]
+        assert encode[4] == theirs + 3 * mine
+        assert encode[5] == pytest.approx((theirs + mine) * tick, abs=1e-9)
+        for kind in ("ar_decode", "ar_reduce", "ar_copy"):
+            assert spans[kind][0][5] == 0.0
+        assert spans["ar_frame"][0][5] == 0.0
+
+
+@pytest.mark.parametrize("telemetry_on", [False, True])
+def test_round_span_tree_is_timed_with_telemetry_off_and_annotated_with_it_on(
+    rng, monkeypatch, telemetry_on
+):
+    """Telemetry OFF: the spans are there, and that is all — no
+    ``dedloc/ar_*`` annotation is entered per chunk (or at all), no event.
+    Telemetry ON: the same spans, every stage and section also an
+    annotation on the loop thread, ONE ``allreduce.round`` event and one
+    ``allreduce.link`` per hop — nothing per chunk."""
+    from dedloc_tpu.telemetry import registry
+    from dedloc_tpu.telemetry.registry import Telemetry
+
+    entered = []
+    real = registry.trace_annotation
+
+    def spy(name, **kwargs):
+        entered.append(name)
+        return real(name, **kwargs)
+
+    monkeypatch.setattr(registry, "trace_annotation", spy)
+    assert registry.active() is None
+    n, dim, chunk = 2, 6_000, 500
+    vectors = [rng.standard_normal(dim).astype(np.float32) for _ in range(n)]
+    teles = [Telemetry(peer=f"p{i}") for i in range(n)] if telemetry_on else None
+    reducers = []
+    asyncio.run(_allreduce_swarm(
+        vectors, [1.0] * n, [1.0] * n, compression=CompressionType.FLOAT16,
+        chunk_size=chunk, telemetries=teles, reducers_out=reducers,
+    ))
+    chunks = dim // chunk
+    for reducer in reducers:
+        spans = _by_name(reducer.last_trace.spans)
+        assert set(STAGES[1:]) | set(KINDS) <= set(spans)
+        assert spans["ar_encode"][0][4] == 2 * chunks
+    ours = [name for name in entered
+            if name.startswith("ar_") or name == "frame"]
+    if not telemetry_on:
+        assert ours == []
+        return
+    for stage in STAGES[1:]:
+        assert ours.count(stage) == n
+    assert ours.count("ar_encode") == n * 2 * chunks
+    assert ours.count("ar_decode") == n * chunks
+    assert ours.count("frame") >= n * 2 * chunks
+    for tele in teles:
+        names = [e["event"] for e in tele.events]
+        assert names.count("allreduce.round") == 1
+        assert names.count("allreduce.link") == n - 1
+        assert len(names) <= 4, names  # + peer.endpoint: nothing per chunk
+        assert not {"allreduce.chunks_sent", "allreduce.chunks_received",
+                    "avg.bytes_saved"} & set(tele.counters)
+        assert "allreduce.chunk_latency_s" not in tele.histograms
+
+
+def test_failed_round_leaves_closed_stages_and_no_dangling_state(rng):
+    """A dead member fails the round for the others (``AllreduceFailed``):
+    whatever stage was open is closed at the failure, the kinds summed so
+    far are folded, nothing stays open."""
+    n, dim = 3, 3_000
+    vectors = [rng.standard_normal(dim).astype(np.float32) for _ in range(n)]
+    reducers = []
+    with pytest.raises(AllreduceFailed):
+        asyncio.run(_allreduce_swarm(
+            vectors, [1.0] * n, [1.0] * n, chunk_size=500, dead=(2,),
+            reducers_out=reducers, timeout=2.0,
+        ))
+    assert reducers[2].last_trace is None  # never ran
+    for reducer in reducers[:2]:
+        trace = reducer.last_trace
+        assert trace.open_stage is None
+        stages = [s for s in trace.spans if s[0] in STAGES]
+        assert stages and stages[0][0] == "ar_prepare"
+        for before, after in zip(stages, stages[1:]):
+            assert before[3] == after[2]
+        # it got as far as waiting for the dead member's parts or chunks
+        assert stages[-1][0] in ("ar_scatter", "ar_gather")
+        assert "ar_encode" in _by_name(trace.spans)
+
+
+def test_matchmaking_failure_leaves_an_empty_span_tree():
+    """A round that formed no group has no ``allreduce`` to cut: the timing
+    is there, ``spans`` is empty, and the averager holds no trace."""
+    from dedloc_tpu.averaging import DecentralizedAverager
+    from dedloc_tpu.dht import DHT
+
+    dht = DHT(start=True, listen_host="127.0.0.1")
+    avg = DecentralizedAverager(
+        dht, "nogroup", averaging_expiration=0.2, averaging_timeout=5.0,
+        listen_host="127.0.0.1",
+    )
+
+    async def refuse(round_id, **kwargs):
+        raise MatchmakingFailed("nobody home")
+
+    avg.matchmaking.form_group = refuse
+    try:
+        averaged, size = avg.step(
+            {"w": np.ones(8, np.float32)}, weight=1.0, round_id="g1"
+        )
+        assert averaged is None and size == 1
+        timing = avg.last_round_timing
+        assert timing["spans"] == [] and timing["allreduce_s"] == 0.0
+        assert timing["loop_cpu_s"] == 0.0 and avg._round_trace is None
+    finally:
+        avg.shutdown()
+        dht.shutdown()
+
+
+def test_client_mode_member_sums_its_own_sections(rng):
+    """A member that hosts nothing still encodes what it sends and decodes
+    and copies what it pulls: its kinds are summed without a hosted span
+    (no reduce, no partner's part to lag behind)."""
+    n, dim, chunk = 3, 3_000, 500
+    vectors = [rng.standard_normal(dim).astype(np.float32) for _ in range(n)]
+    reducers = []
+    asyncio.run(_allreduce_swarm(
+        vectors, [1.0] * n, [1.0] * n, client_mask=[False, False, True],
+        compression=CompressionType.FLOAT16, chunk_size=chunk,
+        reducers_out=reducers,
+    ))
+    spans = _by_name(reducers[2].last_trace.spans)
+    chunks = dim // chunk
+    assert spans["ar_encode"][0][4] == chunks  # every chunk goes to a host
+    assert spans["ar_decode"][0][4] == chunks
+    assert spans["ar_copy"][0][4] == chunks
+    assert "ar_reduce" not in spans and "ar_partner_lag" not in spans
+    assert reducers[2].last_trace.open_stage is None
 
 
 # -------------------------------------------------------------- matchmaking

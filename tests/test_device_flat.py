@@ -206,9 +206,15 @@ def test_device_ef_commit_discipline(rng):
     np.testing.assert_array_equal(f2.result().flat, f1.result().flat)
     pipe.commit(f2)
     assert pipe.residual_norm() > 0
+    # the non-blocking form a reader on the round's path uses: a device
+    # scalar on its way to the host, the same number when read
+    launched = pipe.residual_norm_launch()
+    assert not isinstance(launched, float)
+    assert float(launched) == pipe.residual_norm()
     # post-resync reset
     pipe.reset_residual()
     assert pipe.residual_norm() == 0.0
+    assert pipe.residual_norm_launch() == 0.0
 
 
 def test_device_ef_uint8_drift_free_over_rounds(rng):

@@ -7,6 +7,8 @@ import json
 import sys
 from pathlib import Path
 
+import pytest
+
 spec = importlib.util.spec_from_file_location(
     "runlog_summary",
     Path(__file__).resolve().parent.parent / "tools" / "runlog_summary.py",
@@ -107,6 +109,57 @@ def test_health_view_renders_rounds_faults_and_per_peer_table(
     assert row_a == "| peerA | 5 | 0 | 1 | 1 | 1 | 1 | 0 |"
     (row_b,) = [ln for ln in out.splitlines() if ln.startswith("| peerB |")]
     assert row_b == "| peerB | 1 | 1 | 0 | 0 | 0 | 0 | 0 |"
+
+
+@pytest.mark.parametrize("as_json", [False, True])
+def test_health_wire_path_table_splits_the_round_by_kind(
+    tmp_path, capsys, as_json
+):
+    """The wire-path table puts a round's wall down to what the loop thread
+    did (encode / decode / reduce / copy / frame), the wait that is none of
+    those, the partner's lag and the loop's CPU — means over the peer's
+    ``allreduce.round`` events; a pre-ISSUE-34 event (reduce_s and
+    gather_wait_s only) still folds, with zeros."""
+    round_ = {"t": 100.0, "peer": "peerA", "event": "allreduce.round",
+              "dur_s": 0.7, "round_id": "r1", "ok": True, "chunks": 136,
+              "gather_wait_s": 0.6, "encode_s": 0.2, "decode_s": 0.1,
+              "reduce_s": 0.04, "copy_s": 0.02, "frame_s": 0.06,
+              "wait_s": 0.3, "partner_lag_s": 0.01, "loop_cpu_s": 0.35}
+    events = [
+        round_,
+        dict(round_, t=101.0, round_id="r2", encode_s=0.4, wait_s=0.1),
+        {"t": 100.0, "peer": "old", "event": "allreduce.round", "dur_s": 0.5,
+         "round_id": "r1", "ok": True, "chunks": 4, "reduce_s": 0.1,
+         "gather_wait_s": 0.4},
+    ]
+    path = _write_events(tmp_path, events)
+    if as_json:
+        runlog_summary.main(["--json", "--health", path])
+        wire = json.loads(capsys.readouterr().out)["wire"]
+        assert wire["peerA"] == {
+            "rounds": 2, "dur_mean_s": 0.7, "gather_wait_mean_s": 0.6,
+            "chunks_mean": 136.0, "encode_mean_s": 0.3, "decode_mean_s": 0.1,
+            "reduce_mean_s": 0.04, "copy_mean_s": 0.02, "frame_mean_s": 0.06,
+            "wait_mean_s": 0.2, "partner_lag_mean_s": 0.01,
+            "loop_cpu_mean_s": 0.35,
+        }
+        assert wire["old"]["reduce_mean_s"] == 0.1
+        assert wire["old"]["encode_mean_s"] == wire["old"]["wait_mean_s"] == 0
+        return
+    runlog_summary.main(["--health", path])
+    out = capsys.readouterr().out
+    header = next(ln for ln in out.splitlines()
+                  if ln.startswith("| peer | rounds | dur"))
+    assert header == (
+        "| peer | rounds | dur | encode | decode | reduce | copy | frame |"
+        " wait | partner lag | loop cpu | gather wait | chunks |"
+    )
+    (row,) = [ln for ln in out.splitlines()
+              if ln.startswith("| peerA | 2 | 0.700s")]
+    assert row == (
+        "| peerA | 2 | 0.700s | 0.300s | 0.100s | 0.040s | 0.020s | 0.060s |"
+        " 0.200s | 0.010s | 0.350s | 0.600s | 136.0 |"
+    )
 
 
 def test_health_view_renders_checkpoint_restore_section(tmp_path, capsys):

@@ -137,6 +137,94 @@ def test_spans_are_bounded_by_folding_repeated_leaves():
     assert sum(record["phases"].values()) == approx(record["wall_s"])
 
 
+def _record_with_attached_kinds(clock, rec, micro_batches):
+    """A networked boundary: ``avg_wire`` with the averager's split and,
+    inside ``allreduce``, two stages and one folded kind, all ATTACHED."""
+    with rec.step() as srec:
+        for _ in range(micro_batches):
+            with steps.phase("fwd_bwd"):
+                clock.advance(0.01)
+        with steps.phase("avg_wire"):
+            start = registry.monotonic_clock()
+            clock.advance(1.0)
+            steps.attach("allreduce", start, start + 1.0)
+            steps.attach("ar_scatter", start, start + 0.4, parent="allreduce")
+            steps.attach("ar_gather", start + 0.4, start + 1.0,
+                         parent="allreduce")
+            steps.attach("ar_straggler", start + 0.4, start + 0.5,
+                         parent="ar_gather")
+            # 68 encode sections between +0.1 and +0.9 that took 0.25 s
+            steps.attach("ar_encode", start + 0.1, start + 0.9,
+                         parent="allreduce", count=68, total_s=0.25)
+        srec.attrs["stepped"] = True
+    return rec.records[-1]
+
+
+@pytest.mark.parametrize("micro_batches", [2, 100])
+def test_attached_folded_span_keeps_its_total_and_stays_out_of_phases(
+    micro_batches
+):
+    """``attach(..., parent=, count=, total_s=)`` lands as a folded entry
+    under the parent it names — in a short record, which it does not make
+    fold, and in one that overflows ``MAX_SPANS`` and folds around it — and
+    is nobody's phase: another thread's time."""
+    rec = StepRecorder()
+    with FakeClock() as clock:
+        record = _record_with_attached_kinds(clock, rec, micro_batches)
+    spans = {s[0]: s for s in record["spans"]}
+    assert spans["ar_encode"][:2] == ["ar_encode", "allreduce"]
+    assert spans["ar_encode"][4:] == [68, approx(0.25)]
+    assert spans["ar_encode"][3] - spans["ar_encode"][2] == approx(0.8)
+    assert spans["ar_scatter"][1] == spans["ar_gather"][1] == "allreduce"
+    assert spans["ar_straggler"][1] == "ar_gather"
+    assert spans["allreduce"][1] == "avg_wire"
+    # stages tile their parent
+    assert _span_seconds(spans["ar_scatter"]) + _span_seconds(
+        spans["ar_gather"]
+    ) == approx(_span_seconds(spans["allreduce"]))
+    assert set(record["phases"]) == {"fwd_bwd", "avg_wire"}
+    assert record["phases"]["avg_wire"] == approx(1.0)
+    assert sum(record["phases"].values()) == approx(record["wall_s"])
+    # a short record is left as it was: an attached folded span folds nothing
+    fwd = [s for s in record["spans"] if s[0] == "fwd_bwd"]
+    assert len(fwd) == (2 if micro_batches == 2 else 1)
+    assert len(record["spans"]) <= MAX_SPANS
+
+
+def test_the_benchmark_span_reducers_read_a_folded_total():
+    """``benchmark/reducers/span.py`` reads a folded entry's ``total_s`` (not
+    its first-to-last extent), and ``span_residual.py`` what is left of a
+    span after the named ones: ``allreduce`` minus the kinds, floored at 0;
+    a record that carries none of them (the parent's program) gives nothing."""
+    import types
+
+    from benchmark.reducers import span, span_residual
+
+    rec = StepRecorder()
+    with FakeClock() as clock:
+        record = _record_with_attached_kinds(clock, rec, 2)
+    run = types.SimpleNamespace(step_records=[record])
+    assert span.reduce(
+        run, {"name": "ar_encode", "stepped": True}
+    ) == approx(250.0)
+    assert span.reduce(
+        run, {"name": "ar_gather", "stepped": True}
+    ) == approx(600.0)
+    residual = {"of": "allreduce", "names": ["ar_encode", "ar_decode"],
+                "stepped": True}
+    assert span_residual.reduce(run, residual) == approx(750.0)
+    assert span_residual.reduce(
+        run, dict(residual, names=["ar_decode"])
+    ) is None  # none of the kinds recorded: nothing, not the whole span
+    assert span_residual.reduce(run, dict(residual, stepped=False)) is None
+    greedy = dict(record, spans=record["spans"] + [
+        ["ar_decode", "allreduce", 0.0, 2.0, 9, 2.0]
+    ])
+    assert span_residual.reduce(
+        types.SimpleNamespace(step_records=[greedy]), residual
+    ) == 0.0
+
+
 # ------------------------------------------------- published when telemetry is on
 
 
@@ -463,9 +551,20 @@ def test_slow_global_step_is_one_info_line(caplog):
             with rec.step(step=step) as srec:
                 with steps.phase("fwd_bwd"):
                     clock.advance(0.25)
+                if boundary == 1 and wire > 1:
+                    # the backup thread's transfer, attached beside whatever
+                    # this thread was doing: only the slow step has one
+                    now = registry.monotonic_clock()
+                    steps.attach("backup_transfer", now - 1.5, now)
                 if boundary == 2:
                     with steps.phase("avg_wire"):
+                        start = registry.monotonic_clock()
                         clock.advance(wire)
+                        steps.attach("allreduce", start, start + wire)
+                        steps.attach(
+                            "ar_encode", start, start + wire,
+                            parent="allreduce", count=4, total_s=wire / 2,
+                        )
                     srec.attrs["stepped"] = True
 
     package_logger = logging.getLogger("dedloc_tpu")  # does not propagate
@@ -486,3 +585,11 @@ def test_slow_global_step_is_one_info_line(caplog):
     assert "slow global step 8: 2.750 s against a median of 1.000 s" in message
     assert "avg_wire 2.000 (+1.750)" in message
     assert message.index("avg_wire") < message.index("fwd_bwd")
+    # what OTHER threads ran beside the held step (attached spans never
+    # enter ``totals``), against their own medians, marked as off-thread
+    own, _, beside = message.partition("; on other threads beside it: ")
+    assert "backup_transfer" not in own and "allreduce" not in own
+    assert "allreduce 2.000 (+1.750)" in beside
+    assert "backup_transfer 1.500 (+1.500)" in beside
+    assert "ar_encode 1.000 (+0.875)" in beside  # a folded span: its total_s
+    assert beside.index("allreduce") < beside.index("ar_encode")

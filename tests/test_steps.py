@@ -616,6 +616,19 @@ def test_attribution_data_stall_vs_slow_wire_two_peers(tmp_path, capsys):
                 if name == "stall":
                     stall_visible.set()  # first stalled boundary reported
             assert stepped, f"{name} never performed a global step"
+            # the residual's norm was LAUNCHED on the round's path (the
+            # ef_norm span syncs nothing) and is read by the next opt.step
+            launched = opt._pending_ef_norm
+            ran_ef_norm = any(
+                s[0] == "ef_norm" for r in rec.records for s in r["spans"]
+            )
+            assert (launched is not None) == ran_ef_norm
+            opt.step(state, grad_acc, n_acc, samples=0)
+            assert opt._pending_ef_norm is None
+            if ran_ef_norm:
+                assert teles[name].gauges[
+                    "opt.ef_residual_norm"
+                ].value == pytest.approx(float(launched))
         except Exception as e:  # noqa: BLE001
             errors.append((name, e))
 
@@ -681,6 +694,27 @@ def test_attribution_data_stall_vs_slow_wire_two_peers(tmp_path, capsys):
             assert record["phases"]["avg_wire"] == pytest.approx(
                 wire - record["phases"].get("d2h_stream", 0.0), abs=1e-6
             )
+            # inside allreduce, the averager's span tree (ISSUE 34): the
+            # stages of the round's coroutine tile it, the kinds of work the
+            # loop thread summed are folded entries beside them — all
+            # attached, so none is a phase of this thread
+            allreduce = spans["allreduce"][3] - spans["allreduce"][2]
+            stages = [
+                s for s in record["spans"]
+                if s[0] in ("ar_resolve", "ar_prepare", "ar_scatter",
+                            "ar_gather", "ar_finish")
+            ]
+            assert stages and all(s[1] == "allreduce" for s in stages)
+            assert sum(s[3] - s[2] for s in stages) == pytest.approx(
+                allreduce, abs=1e-3
+            )
+            assert not [n for n in record["phases"] if n.startswith("ar_")]
+            if len(stages) == 5:  # a group of two: the wire was crossed
+                for kind in ("ar_encode", "ar_decode", "ar_reduce",
+                             "ar_copy", "ar_frame"):
+                    assert spans[kind][1] == "allreduce"
+                    assert len(spans[kind]) == 6 and spans[kind][4] >= 1
+                assert 0 <= record["ar_loop_cpu_s"] <= allreduce + 0.05
 
     # the operator view: --steps over the two event logs names the phases
     runlog_summary.main(["--steps", logs["stall"], logs["wire"]])

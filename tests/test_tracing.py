@@ -186,6 +186,41 @@ def test_frames_carry_tc_only_when_telemetry_traces(monkeypatch):
     assert all("caller" not in e for e in serves if e is not adopted[0])
 
 
+def test_frame_work_is_summed_on_the_client_and_the_server():
+    """Every ``RPCClient`` and ``RPCServer`` keeps an always-on sum of its
+    synchronous frame work (pack + write, unpack of what it reads), telemetry
+    on or off — what an all-reduce round reads as its ``ar_frame`` delta.
+    A call is two frames on each side: the client writes the request and
+    reads the reply, the server the reverse."""
+
+    async def run():
+        server = RPCServer("127.0.0.1", 0)
+
+        async def echo(peer, args):
+            return {"n": len(args["blob"])}
+
+        server.register("echo", echo)
+        await server.start()
+        client = RPCClient(request_timeout=5.0)
+        assert (client.frames, client.frame_s) == (0, 0.0)
+        assert (server.frames, server.frame_s) == (0, 0.0)
+        try:
+            for k in range(3):
+                reply = await client.call(
+                    ("127.0.0.1", server.port), "echo",
+                    {"blob": b"x" * 200_000},
+                )
+                assert reply == {"n": 200_000}
+                assert client.frames == server.frames == 2 * (k + 1)
+            assert 0 < client.frame_s < 5 and 0 < server.frame_s < 5
+        finally:
+            await client.close()
+            await server.stop()
+
+    assert registry.active() is None  # telemetry off: still summed
+    asyncio.run(run())
+
+
 # ---------------------------------------------- tentpole acceptance scenario
 
 

@@ -223,22 +223,34 @@ def _health_rounds(rows):
     return rounds
 
 
+# allreduce.round fields summed per peer for the wire-path table: the kinds
+# of loop-thread work inside the round, the wait that is none of them, the
+# partner's lag and the loop thread's CPU (docs/observability.md)
+_WIRE_FIELDS = (
+    ("encode", "encode_s"), ("decode", "decode_s"), ("reduce", "reduce_s"),
+    ("copy", "copy_s"), ("frame", "frame_s"), ("wait", "wait_s"),
+    ("partner_lag", "partner_lag_s"), ("loop_cpu", "loop_cpu_s"),
+)
+
+
 def _wire_per_peer(rows):
-    """Per-peer pipelined-allreduce aggregates (reduce- vs wire-bound)."""
+    """Per-peer pipelined-allreduce aggregates: where the loop thread's
+    time went inside a round (codec, framing, reduce, copies, waiting)."""
     wire_rounds = [r for r in rows if r["event"] == ev.ALLREDUCE_ROUND
                    and ("reduce_s" in r or "gather_wait_s" in r)]
     per_peer_wire = {}
     for r in wire_rounds:
         acc = per_peer_wire.setdefault(
             r.get("peer", "?"),
-            {"rounds": 0, "dur": 0.0, "reduce": 0.0, "gather": 0.0,
-             "chunks": 0},
+            {"rounds": 0, "dur": 0.0, "gather": 0.0, "chunks": 0,
+             **{key: 0.0 for key, _field in _WIRE_FIELDS}},
         )
         acc["rounds"] += 1
         acc["dur"] += float(r.get("dur_s", 0.0))
-        acc["reduce"] += float(r.get("reduce_s", 0.0))
         acc["gather"] += float(r.get("gather_wait_s", 0.0))
         acc["chunks"] += int(r.get("chunks", 0))
+        for key, field in _WIRE_FIELDS:
+            acc[key] += float(r.get(field, 0.0))
     return per_peer_wire
 
 
@@ -326,9 +338,12 @@ def health_data(rows):
             peer: {
                 "rounds": a["rounds"],
                 "dur_mean_s": round(a["dur"] / a["rounds"], 6),
-                "reduce_mean_s": round(a["reduce"] / a["rounds"], 6),
                 "gather_wait_mean_s": round(a["gather"] / a["rounds"], 6),
                 "chunks_mean": round(a["chunks"] / a["rounds"], 2),
+                **{
+                    f"{key}_mean_s": round(a[key] / a["rounds"], 6)
+                    for key, _field in _WIRE_FIELDS
+                },
             }
             for peer, a in _wire_per_peer(rows).items()
         },
@@ -387,22 +402,27 @@ def print_health(rows):
     per_peer = _health_per_peer(rows)
 
     # wire-path attribution (pipelined all-reduce, docs/observability.md):
-    # every hosting member's allreduce.round span carries reduce_s (CPU time
-    # in the eager per-chunk reduce) and gather_wait_s (wall from gather
-    # launch to the last reduced chunk landing) — a slow round whose
-    # gather_wait dwarfs reduce_s is wire-bound, the reverse is CPU-bound
+    # every member's allreduce.round span carries what its loop thread did
+    # inside the round by kind (encode_s / decode_s / reduce_s / copy_s /
+    # frame_s), wait_s (the round's wall minus those: a socket, the partner,
+    # the GIL), the partner's lag, the loop thread's CPU seconds, and
+    # gather_wait_s (wall from gather launch to the last reduced chunk
+    # landing) — a slow round whose wait dwarfs the kinds is wire- or
+    # partner-bound, the reverse is CPU-bound on this peer's loop
     per_peer_wire = _wire_per_peer(rows)
     if per_peer_wire:
         print("\nwire path (mean per all-reduce round):")
-        print("| peer | rounds | dur | reduce | gather wait | chunks |")
-        print("|---|---|---|---|---|---|")
+        print("| peer | rounds | dur | encode | decode | reduce | copy |"
+              " frame | wait | partner lag | loop cpu | gather wait |"
+              " chunks |")
+        print("|" + "---|" * 13)
         for peer in sorted(per_peer_wire):
             a = per_peer_wire[peer]
             k = a["rounds"]
             print(
-                f"| {peer} | {k} | {a['dur'] / k:.3f}s |"
-                f" {a['reduce'] / k:.3f}s | {a['gather'] / k:.3f}s |"
-                f" {a['chunks'] / k:.1f} |"
+                f"| {peer} | {k} | {a['dur'] / k:.3f}s | " + " | ".join(
+                    f"{a[key] / k:.3f}s" for key, _field in _WIRE_FIELDS
+                ) + f" | {a['gather'] / k:.3f}s | {a['chunks'] / k:.1f} |"
             )
 
     # checkpoint/restore view (swarm checkpointing, docs/fleet.md restart
