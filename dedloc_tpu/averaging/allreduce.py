@@ -57,6 +57,18 @@ coroutines interleave: ``ar_encode`` / ``ar_decode`` / ``ar_reduce`` /
 ``ar_copy`` / ``ar_frame``. The round's wall minus the kinds is the loop
 thread in none of the round's own code: awaiting a socket, the partner, the
 GIL, another coroutine.
+
+A chunk's wire bytes exist ONCE (``core/serialization.encode_array`` /
+``decode_array``, ``dht/protocol.Blob``): the encoded array is attached to
+its ``avg.part`` / ``avg.get_reduced`` frame by reference — msgpack packs a
+small header, the socket gets a view of the array's own buffer — and the
+receiver decodes the frame's buffer straight into its destination: a fresh
+array the accumulator adopts for a hosted part, ``out[clo:chi]`` for a
+gathered chunk. A hosted chunk's reduced value is encoded once
+(``_ChunkState.wire``) for every gatherer, the host itself included. The
+peer's ``RPCClient`` / ``RPCServer`` count the attachments they carried; a
+round reads them as a delta (``RoundTrace.attached_chunks``: 4 per hosted
+chunk in a two-peer group).
 """
 from __future__ import annotations
 
@@ -68,13 +80,19 @@ import numpy as np
 from dedloc_tpu import native
 from dedloc_tpu.core.serialization import (
     CompressionType,
-    deserialize_array,
-    serialize_array,
+    decode_array,
+    encode_array,
     wire_roundtrip,
 )
 from dedloc_tpu.averaging.partition import partition_weighted
 from dedloc_tpu.core.timeutils import monotonic as _clock
-from dedloc_tpu.dht.protocol import Endpoint, RPCClient, RPCError, RPCServer
+from dedloc_tpu.dht.protocol import (
+    Blob,
+    Endpoint,
+    RPCClient,
+    RPCError,
+    RPCServer,
+)
 from dedloc_tpu.telemetry import registry as telemetry
 from dedloc_tpu.telemetry.links import endpoint_key
 from dedloc_tpu.utils.logging import get_logger
@@ -132,17 +150,20 @@ class _Section:
 class _Sections:
     """What the loop thread spent inside one round, by KIND of work:
 
-    - ``encode`` (span ``ar_encode``): ``serialize_array`` of the parts I
-      send and of the reduced chunks a member first pulls, and
-      ``wire_roundtrip`` (encode AND decode: splitting it would cost a
-      second pass, so it counts whole here) of my own part of my span and of
-      the reduced chunks I adopt
-    - ``decode`` (``ar_decode``): ``deserialize_array`` of the parts I host
-      and of the reduced chunks I pull
+    - ``encode`` (span ``ar_encode``): ``encode_array`` (codec + crc) of
+      the parts I send and, once a hosted chunk, of its reduced value — for
+      whoever first wants it, a gatherer or me; and ``wire_roundtrip``
+      (encode AND decode: splitting it would cost a second pass, so it
+      counts whole here) of my own part of my span
+    - ``decode`` (``ar_decode``): ``decode_array`` (crc + codec) of the
+      parts I host, into the array the accumulator adopts, and of every
+      reduced chunk — pulled or my own — straight into the result, whose
+      pages are first touched here
     - ``reduce`` (``ar_reduce``): the accumulator's axpy / scale and the
       finalize scale
-    - ``copy`` (``ar_copy``): ``np.copyto`` into the result, the
-      ``local_span`` copy
+    - ``copy`` (``ar_copy``): the ``local_span`` copy (a view when the
+      vector is contiguous float32) and an all-aux group's served slices;
+      no payload is copied into the result any more
     """
 
     __slots__ = ("encode", "decode", "reduce", "copy")
@@ -177,13 +198,21 @@ class RoundTrace:
     ``GroupAllReduce.begin_trace`` starts one; whoever started it closes it
     — a failed round leaves no stage open."""
 
-    def __init__(self, annotate: bool, frame_mark: Tuple[float, int, float]):
+    def __init__(
+        self, annotate: bool, frame_mark: Tuple[float, int, int, int, float]
+    ):
         self.annotate = annotate
         self.spans: List[tuple] = []
         self.started_at: Optional[float] = None
         self.loop_cpu_s = 0.0  # the loop thread's CPU seconds, set by close
-        # (seconds, frames, when) of the peer's frame accumulators as last
-        # read: ``GroupAllReduce`` reads ``ar_frame`` as a delta against it
+        # frame attachments (chunk payloads handed over by reference) the
+        # peer's client and server carried while the round ran, both
+        # directions, and their bytes
+        self.attached_chunks = 0
+        self.attached_bytes = 0
+        # (seconds, frames, attachments, their bytes, when) of the peer's
+        # frame accumulators as last read: ``GroupAllReduce`` reads
+        # ``ar_frame`` and the attachment counts as a delta against it
         self.frame_mark = frame_mark
         self._cpu0 = telemetry.thread_cpu_clock()
         self._open: Optional[list] = None  # [name, t0, annotation]
@@ -243,8 +272,9 @@ class _ChunkState:
     """One chunk of MY span: eagerly-accumulated weighted sum + the set of
     senders whose copy arrived. ``done`` resolves to the reduced fp32 chunk
     the moment the last expected sender delivers (or the straggler window
-    closes); ``wire`` caches the serialized reply so n-1 gatherers cost one
-    encode."""
+    closes); ``wire`` holds its encoding — ``(header, encoded array)``,
+    made once — for the n-1 gatherers' replies and the host's own
+    adoption."""
 
     __slots__ = ("acc", "weight", "arrived", "done", "wire")
 
@@ -255,7 +285,7 @@ class _ChunkState:
         self.done: asyncio.Future = (
             asyncio.get_running_loop().create_future()
         )
-        self.wire: Optional[bytes] = None
+        self.wire: Optional[Tuple[dict, np.ndarray]] = None
 
 
 class _RoundState:
@@ -454,7 +484,7 @@ class GroupAllReduce:
             # sender simply missed this round, per the straggler SLA
             return {}
         with state.sections.decode:
-            part = deserialize_array(data)
+            part = decode_array(args["h"], data.view)
         if weight > 0:
             state.accumulate(c, part, weight, own=True, norm=norm)
         st.arrived.add(sender)
@@ -472,21 +502,33 @@ class GroupAllReduce:
         data = await asyncio.wait_for(
             asyncio.shield(st.done), timeout=self.timeout
         )
-        if st.wire is None:  # encode once, serve n-1 gatherers from cache
-            with state.sections.encode:
-                st.wire = serialize_array(
-                    data, self.compression, checksum=True
+        header, wire = self._encoded(st, data, state.sections)
+        return {"h": header, "data": Blob(wire), "weight": st.weight}
+
+    def _encoded(
+        self, st: _ChunkState, reduced: np.ndarray, sections: _Sections
+    ) -> Tuple[dict, np.ndarray]:
+        """Chunk ``st``'s reduced value on the wire: encoded ONCE, by
+        whoever first wants it — a gatherer's request or the host's own
+        adoption — and kept; the array is never mutated after."""
+        if st.wire is None:
+            with sections.encode:
+                st.wire = encode_array(
+                    reduced, self.compression, checksum=True
                 )
-        return {"data": st.wire, "weight": st.weight}
+        return st.wire
 
     # ------------------------------------------------------------------ run
 
-    def _frame_totals(self) -> Tuple[float, int]:
+    def _frame_totals(self) -> Tuple[float, int, int, int]:
         """(seconds, frames) of synchronous frame I/O this peer's client and
-        server have done so far (``dht/protocol._frame_work``)."""
+        server have done so far (``dht/protocol._frame_work``) and
+        (attachments, their bytes) those frames carried."""
         ends = [e for e in (self.client, self.server) if e is not None]
         return (
-            sum(e.frame_s for e in ends), sum(e.frames for e in ends)
+            sum(e.frame_s for e in ends), sum(e.frames for e in ends),
+            sum(e.attached for e in ends),
+            sum(e.attached_bytes for e in ends),
         )
 
     def begin_trace(
@@ -664,15 +706,18 @@ class GroupAllReduce:
         my reduced chunks — is on ITS critical path and in nobody's record),
         ``ar_frame`` the delta of the client's and server's frame
         accumulators since the trace began (``began``; the last ``run``'s
-        end when a hierarchical round runs twice on one trace),
-        ``ar_partner_lag`` from there to the first part another member
-        delivered. With telemetry on the same numbers are fields of the
-        ``allreduce.round`` event (``ctx``)."""
+        end when a hierarchical round runs twice on one trace), the
+        trace's ``attached_chunks`` / ``attached_bytes`` the same delta of
+        their attachment counts, ``ar_partner_lag`` from there to the first
+        part another member delivered. With telemetry on the same numbers
+        are fields of the ``allreduce.round`` event (``ctx``)."""
         now = telemetry.monotonic_clock()
         kinds = sections.rows()
-        frame_s0, frames0, began = trace.frame_mark
-        frame_s, frames = self._frame_totals()
-        trace.frame_mark = (frame_s, frames, now)
+        frame_s0, frames0, attached0, attached_bytes0, began = trace.frame_mark
+        frame_s, frames, attached, attached_bytes = self._frame_totals()
+        trace.frame_mark = (frame_s, frames, attached, attached_bytes, now)
+        trace.attached_chunks += attached - attached0
+        trace.attached_bytes += attached_bytes - attached_bytes0
         if frames > frames0:
             kinds["ar_frame"] = (
                 frames - frames0, frame_s - frame_s0, began, now
@@ -687,6 +732,8 @@ class GroupAllReduce:
             )
         if ctx is None:
             return
+        ctx["attached_chunks"] = attached - attached0
+        ctx["attached_bytes"] = attached_bytes - attached_bytes0
         busy = sum(row[1] for row in kinds.values())
         for kind, field in (
             ("ar_encode", "encode_s"), ("ar_decode", "decode_s"),
@@ -753,13 +800,9 @@ class GroupAllReduce:
                 timeout=self.timeout,
             )
             with sections.decode:
-                data = deserialize_array(reply["data"])
-            if data.size != chi - clo:
-                raise ValueError(
-                    f"chunk size mismatch: got {data.size}, want {chi - clo}"
-                )
-            with sections.copy:
-                np.copyto(out[clo:chi], data.reshape(-1), casting="unsafe")
+                # straight into the result: a size that is not this
+                # chunk's raises ValueError, as a failed crc does
+                decode_array(reply["h"], reply["data"].view, out=out[clo:chi])
             if not normalize:
                 chunk_weights.append(float(reply.get("weight", 0.0)))
             if tele is not None:
@@ -784,16 +827,15 @@ class GroupAllReduce:
             data = await asyncio.shield(my_state.chunk(c).done)
             if not normalize:
                 chunk_weights.append(float(my_state.chunk(c).weight))
-            if self.compression is not CompressionType.NONE:
-                # adopt my own span THROUGH the wire codec: every other
-                # member decodes the lossy wire bytes, and synchronous-SGD
-                # emulation wants all replicas to apply bit-identical
-                # values — a host keeping its fp32 low bits would drift
-                # its params from the rest of the group every round
-                with sections.encode:
-                    data = wire_roundtrip(data, self.compression)
-            with sections.copy:
-                np.copyto(out[clo:chi], data, casting="unsafe")
+            # adopt my own span THROUGH the wire codec: every other member
+            # decodes the lossy wire bytes, and synchronous-SGD emulation
+            # wants all replicas to apply bit-identical values — a host
+            # keeping its fp32 low bits would drift its params from the
+            # rest of the group every round. The encoding is the one the
+            # gatherers are served (no crc: it never left this process)
+            header, wire = self._encoded(my_state.chunk(c), data, sections)
+            with sections.decode:
+                decode_array(header, wire, out=out[clo:chi], verify=False)
 
         gathers = []
         for j in range(n):
@@ -885,7 +927,7 @@ class GroupAllReduce:
                 # block the loop for the full codec latency and hold every
                 # compressed payload in memory at once
                 with sections.encode:
-                    payload = serialize_array(
+                    header, wire = encode_array(
                         vector[clo:chi], self.compression, checksum=True
                     )
                 if tele is not None:
@@ -894,7 +936,8 @@ class GroupAllReduce:
                     tele.counter("allreduce.bytes_sent").inc((chi - clo) * 4)
                 part_args = {
                     "round_id": round_id, "sender": my_index,
-                    "weight": weight, "chunk": c, "data": payload,
+                    "weight": weight, "chunk": c, "h": header,
+                    "data": Blob(wire),
                 }
                 if norm != weight:
                     # hierarchical delegate: axpy scale 1.0, denominator
@@ -908,10 +951,10 @@ class GroupAllReduce:
                 if tele is not None:
                     dt = telemetry.monotonic_clock() - t0
                     tele.links().observe_transfer(
-                        endpoints[j], len(payload), dt
+                        endpoints[j], wire.nbytes, dt
                     )
                     acc = _acc(j)
-                    acc["sent_bytes"] += len(payload)
+                    acc["sent_bytes"] += wire.nbytes
                     acc["chunks_sent"] += 1
                     acc["send_s"] += dt
                     acc["max_chunk_s"] = max(acc["max_chunk_s"], dt)

@@ -238,7 +238,8 @@ class DecentralizedAverager:
         # the wait for the partners included) and ``allreduce_s`` (group
         # formed -> result; a round that formed no group has 0 here), and
         # what is inside ``allreduce``: ``spans`` (the ``ar_*`` stages and
-        # kinds, ``_timed_step``) and ``loop_cpu_s``. The collaborative
+        # kinds, ``_timed_step``), ``loop_cpu_s`` and ``attached_chunks``
+        # (payloads its frames carried by reference). The collaborative
         # optimizer hangs them under its ``avg_wire`` span. None while a
         # round runs.
         self.last_round_timing: Optional[Dict[str, Any]] = None
@@ -592,7 +593,8 @@ class DecentralizedAverager:
         inside ``allreduce`` (``allreduce.RoundTrace``: ``spans`` as
         ``(name, parent, t0, t1[, count, total_s])``, every ``run`` of a
         hierarchical round appended) with the loop thread's CPU seconds
-        over it — telemetry on or off."""
+        over it and the attachments its frames carried — telemetry on or
+        off."""
         started = telemetry.monotonic_clock()
         self._round_formed_at = None
         self._round_trace = None
@@ -612,6 +614,10 @@ class DecentralizedAverager:
                 "allreduce_s": max(0.0, done - formed),
                 "spans": trace.spans if trace is not None else [],
                 "loop_cpu_s": trace.loop_cpu_s if trace is not None else 0.0,
+                # chunk payloads the round's frames carried by reference
+                "attached_chunks": (
+                    trace.attached_chunks if trace is not None else 0
+                ),
             }
 
     async def _form_group(self, round_id: str, **kwargs):

@@ -897,6 +897,9 @@ class CollaborativeOptimizer:
                     record = steps.current()
                     if record is not None and "loop_cpu_s" in timing:
                         record.attrs["ar_loop_cpu_s"] = timing["loop_cpu_s"]
+                        record.attrs["ar_attached_chunks"] = timing.get(
+                            "attached_chunks", 0
+                        )
             wire_wall = wire.dur_s
             if self.overlap_averaging and tele is not None:
                 # overlap ledger, synchronous-fallback form: this round ran

@@ -7,11 +7,18 @@ msgpack (self-describing, protobuf-free — see SURVEY.md §2.7).
 
 All encoders take/return numpy arrays: device arrays are fetched to host by
 the caller at the jit↔asyncio seam (SURVEY.md §7 hard-part b).
+
+Two forms of one codec: ``encode_array`` / ``decode_array`` work BY REFERENCE
+(header dict + the encoded array; decode from any buffer, into the caller's
+float32 destination) for the all-reduce, whose payloads ride RPC frames as
+attachments; ``serialize_array`` / ``deserialize_array`` wrap them into
+self-contained ``bytes`` (msgpack of header + payload) for disk, serving,
+gossip and state transfer.
 """
 from __future__ import annotations
 
 import enum
-from typing import Any, Dict
+from typing import Any, Dict, Optional, Tuple
 
 import msgpack
 import numpy as np
@@ -25,11 +32,18 @@ class CompressionType(enum.Enum):
     UINT8 = "uint8"  # per-tensor affine quantization with fp32 scale/zero-point
 
 
-def serialize_array(
+def encode_array(
     x: np.ndarray,
     compression: CompressionType = CompressionType.NONE,
     checksum: bool = False,
-) -> bytes:
+) -> Tuple[Dict[str, Any], np.ndarray]:
+    """Encode ``x`` for the wire BY REFERENCE: ``(header, wire)`` where
+    ``wire`` is the contiguous array whose buffer IS the payload (``x``
+    itself under ``NONE`` when it is contiguous) and ``header`` the small
+    dict that decodes it (shape, dtype, compression, ``lo`` / ``scale``,
+    ``crc`` over the payload bytes). The all-reduce hands ``wire`` to the
+    socket as a frame attachment (``dht/protocol.Blob``); callers that want
+    self-contained ``bytes`` use ``serialize_array``."""
     x = np.asarray(x)
     header: Dict[str, Any] = {
         "shape": list(x.shape),
@@ -37,43 +51,77 @@ def serialize_array(
         "compression": compression.value,
     }
     if compression is CompressionType.NONE:
-        payload = np.ascontiguousarray(x).tobytes()
+        wire = np.ascontiguousarray(x)
     elif compression is CompressionType.FLOAT16:
         if x.dtype == np.float16:
-            payload = np.ascontiguousarray(x).tobytes()
+            wire = np.ascontiguousarray(x)
         else:
-            payload = native.f32_to_f16(x.astype(np.float32, copy=False)).tobytes()
+            wire = native.f32_to_f16(x.astype(np.float32, copy=False))
     elif compression is CompressionType.UINT8:
-        q, lo, scale = native.quantize_uint8(x.astype(np.float32, copy=False))
+        wire, lo, scale = native.quantize_uint8(
+            x.astype(np.float32, copy=False)
+        )
         header["lo"], header["scale"] = lo, scale
-        payload = q.tobytes()
     else:  # pragma: no cover
         raise ValueError(f"unknown compression {compression}")
     if checksum:
-        header["crc"] = native.crc32c(payload)
-    return msgpack.packb({"h": header, "p": payload}, use_bin_type=True)
+        header["crc"] = native.crc32c(wire)
+    return header, wire
 
 
-def deserialize_array(data: bytes) -> np.ndarray:
-    obj = msgpack.unpackb(data, raw=False)
-    header, payload = obj["h"], obj["p"]
-    if "crc" in header and native.crc32c(payload) != header["crc"]:
+def decode_array(
+    header: Dict[str, Any],
+    payload,
+    out: Optional[np.ndarray] = None,
+    verify: bool = True,
+) -> np.ndarray:
+    """Decode one payload (any buffer: ``bytes``, a frame attachment's
+    memoryview, an encoded array) under ``header``. With ``out`` — a
+    contiguous float32 destination of the payload's size, e.g. a slice of
+    the round's result — the values are written INTO it, whatever dtype the
+    header names, and ``out`` is returned; without, a fresh array of the
+    header's dtype. ``verify=False`` skips the crc: for a payload that
+    never left this process."""
+    if verify and "crc" in header and native.crc32c(payload) != header["crc"]:
         raise ValueError("wire chunk checksum mismatch (corrupt frame)")
     shape = tuple(header["shape"])
     dtype = np.dtype(header["dtype"])
     compression = CompressionType(header["compression"])
     if compression is CompressionType.NONE:
-        return np.frombuffer(payload, dtype=dtype).reshape(shape).copy()
+        x = np.frombuffer(payload, dtype=dtype).reshape(shape)
+        if out is None:
+            return x.copy()
+        out = native.f32_destination(out, x)
+        np.copyto(out, x.reshape(out.shape), casting="unsafe")
+        return out
     if compression is CompressionType.FLOAT16:
         h = np.frombuffer(payload, dtype=np.float16).reshape(shape)
+        if out is not None:
+            return native.f16_to_f32(h, out=out)
         if dtype == np.float16:
             return h.copy()
         return native.f16_to_f32(h).astype(dtype, copy=False)
     if compression is CompressionType.UINT8:
         q = np.frombuffer(payload, dtype=np.uint8).reshape(shape)
-        x = native.dequantize_uint8(q, header["lo"], header["scale"])
-        return x.astype(dtype, copy=False)
+        x = native.dequantize_uint8(q, header["lo"], header["scale"], out=out)
+        return x if out is not None else x.astype(dtype, copy=False)
     raise ValueError(f"unknown compression {compression}")  # pragma: no cover
+
+
+def serialize_array(
+    x: np.ndarray,
+    compression: CompressionType = CompressionType.NONE,
+    checksum: bool = False,
+) -> bytes:
+    """``encode_array`` as self-contained ``bytes`` (msgpack of header +
+    payload): disk, serving, gossip and state paths."""
+    header, wire = encode_array(x, compression, checksum)
+    return msgpack.packb({"h": header, "p": wire.tobytes()}, use_bin_type=True)
+
+
+def deserialize_array(data: bytes) -> np.ndarray:
+    obj = msgpack.unpackb(data, raw=False)
+    return decode_array(obj["h"], obj["p"])
 
 
 def wire_roundtrip(
@@ -110,10 +158,12 @@ def deserialize_tree(data: bytes) -> Dict[str, np.ndarray]:
     return {k: deserialize_array(v) for k, v in obj.items()}
 
 
-def pack_obj(obj: Any) -> bytes:
-    """msgpack helper for small control-plane objects (DHT values, metadata)."""
-    return msgpack.packb(obj, use_bin_type=True)
+def pack_obj(obj: Any, default=None) -> bytes:
+    """msgpack helper for small control-plane objects (DHT values, metadata)
+    and RPC frames; ``default`` is msgpack's hook for types it does not
+    know (``dht/protocol``'s attachments)."""
+    return msgpack.packb(obj, use_bin_type=True, default=default)
 
 
-def unpack_obj(data: bytes) -> Any:
-    return msgpack.unpackb(data, raw=False)
+def unpack_obj(data, ext_hook=msgpack.ExtType) -> Any:
+    return msgpack.unpackb(data, raw=False, ext_hook=ext_hook)

@@ -24,7 +24,9 @@ import contextlib
 import socket
 import struct
 import time
-from typing import Any, Awaitable, Callable, Dict, Optional, Tuple
+from typing import Any, Awaitable, Callable, Dict, List, Optional, Tuple
+
+import msgpack
 
 from dedloc_tpu.core.serialization import pack_obj, unpack_obj
 from dedloc_tpu.core.timeutils import monotonic as _clock
@@ -41,29 +43,167 @@ MAX_FRAME = 512 * 1024 * 1024  # tensors ride this transport too
 _LEN = struct.Struct("!I")
 
 
+# bit 31 of a frame's length word (free: MAX_FRAME is 2**29) marks a frame
+# with ATTACHMENTS; a peer that predates them reads the word as a length
+# over MAX_FRAME and refuses the frame (ValueError) instead of mis-reading it
+_ATTACHED = 1 << 31
+_BLOB_EXT = 66  # msgpack ext code of an attachment's placeholder
+# buffer formats (struct codes) of plain numbers: what a Blob may wrap
+_PLAIN_FORMATS = frozenset("cbB?hHiIlLqQnNefd")
+
+
+class Blob:
+    """A binary payload that rides a frame OUT OF BAND: anywhere in a
+    message (any nesting depth, requests and replies) a ``Blob`` packs as a
+    6-byte msgpack placeholder and its bytes follow the msgpack part raw,
+    handed to ``writer.write`` as a view of the wrapped object's own buffer
+    — never copied by the packer. ``read_frame`` gives it back as a ``Blob``
+    over the frame's buffer (``.view``: a flat byte memoryview), so a relay
+    that forwards the message re-attaches it by reference.
+
+    Wraps anything with a C-contiguous buffer (``bytes``, a memoryview, a
+    numpy array of a plain dtype); anything else is refused here, on the
+    send side. The wrapped buffer must not change until the frame is
+    written out."""
+
+    __slots__ = ("view",)
+
+    def __init__(self, data):
+        view = memoryview(data)  # TypeError / ValueError: no buffer at all
+        if view.format.lstrip("@=<") not in _PLAIN_FORMATS:
+            # object pointers, structs: bytes that mean nothing elsewhere
+            raise TypeError(f"an attachment of format {view.format!r}")
+        if not view.c_contiguous:
+            raise ValueError("an attachment must be C-contiguous")
+        if view.nbytes == 0:
+            view = memoryview(b"")  # a zero-sized shape cannot be cast
+        elif view.ndim != 1 or view.format != "B":
+            view = view.cast("B")
+        self.view = view
+
+    def __len__(self) -> int:
+        return self.view.nbytes
+
+
+def _pack(obj: Any) -> Tuple[bytes, List[Blob]]:
+    """msgpack of ``obj`` and the attachments it named, in wire order. The
+    ``default`` hook runs only for types msgpack does not know: a message
+    without a ``Blob`` packs exactly as ``pack_obj`` packs it."""
+    blobs: List[Blob] = []
+
+    def attach(o):
+        if type(o) is Blob:
+            blobs.append(o)
+            return msgpack.ExtType(_BLOB_EXT, _LEN.pack(len(o)))
+        raise TypeError(f"can not serialize {type(o).__name__!r} object")
+
+    return pack_obj(obj, default=attach), blobs
+
+
+def _unpack_attached(body: bytes) -> Tuple[Any, List[Blob]]:
+    """The message and the attachments of an attachment frame's body
+    (layout: ``write_frame``). Each placeholder claims the next ``n`` bytes
+    after the msgpack part; claims must tile the rest of the body exactly."""
+    view = memoryview(body)
+    if len(view) < _LEN.size:
+        raise ValueError("attachment frame shorter than its header")
+    (packed,) = _LEN.unpack_from(view)
+    cursor = _LEN.size + packed
+    if cursor > len(view):
+        raise ValueError("attachment frame: msgpack part overruns the frame")
+    blobs: List[Blob] = []
+
+    def resolve(code, data):
+        nonlocal cursor
+        if code != _BLOB_EXT:
+            return msgpack.ExtType(code, data)
+        (nbytes,) = _LEN.unpack(data)
+        if cursor + nbytes > len(view):
+            raise ValueError("attachment overruns the frame")
+        blob = Blob(view[cursor:cursor + nbytes])
+        cursor += nbytes
+        blobs.append(blob)
+        return blob
+
+    msg = unpack_obj(view[_LEN.size:_LEN.size + packed], ext_hook=resolve)
+    if cursor != len(view):
+        raise ValueError("attachment frame: bytes no placeholder claims")
+    return msg, blobs
+
+
 async def read_frame(reader: asyncio.StreamReader, owner=None) -> Any:
     """One frame off ``reader``. ``owner`` (an ``RPCClient`` / ``RPCServer``)
-    is charged the unpack on its frame accumulator (``_frame_work``)."""
+    is charged the unpack on its frame accumulator (``_frame_work``) and the
+    frame's attachments on ``attached`` / ``attached_bytes``."""
     header = await reader.readexactly(_LEN.size)
     (length,) = _LEN.unpack(header)
+    has_blobs = length & _ATTACHED
+    length &= ~_ATTACHED
     if length > MAX_FRAME:
         raise ValueError(f"frame too large: {length}")
     payload = await reader.readexactly(length)
     if telemetry._active is not None:  # process-wide wire accounting
         telemetry._active.counter("net.bytes_in").inc(_LEN.size + length)
-    if owner is None:
-        return unpack_obj(payload)
-    return _frame_work(owner, unpack_obj, payload)
+    unpack = _unpack_attached if has_blobs else unpack_obj
+    got = (
+        unpack(payload) if owner is None
+        else _frame_work(owner, unpack, payload)
+    )
+    if not has_blobs:
+        return got
+    msg, blobs = got
+    if owner is not None:
+        owner.attached += len(blobs)
+        owner.attached_bytes += sum(map(len, blobs))
+    return msg
 
 
-def write_frame(writer: asyncio.StreamWriter, obj: Any) -> None:
-    payload = pack_obj(obj)
-    writer.write(_LEN.pack(len(payload)))
-    writer.write(payload)
-    if telemetry._active is not None:  # process-wide wire accounting
-        telemetry._active.counter("net.bytes_out").inc(
-            _LEN.size + len(payload)
-        )
+def write_frame(
+    writer: asyncio.StreamWriter, obj: Any
+) -> Optional[Tuple[int, int]]:
+    """One frame onto ``writer``: ``!I`` length, then the msgpack of
+    ``obj`` — byte for byte what it always was for a message that holds no
+    ``Blob``. With attachments the frame is
+
+        ``!I`` _ATTACHED | body length   (body = everything after this word)
+        ``!I`` length of the msgpack part
+        msgpack of ``obj``, each Blob an ext(_BLOB_EXT, ``!I`` its bytes)
+        the attachments' bytes, raw, in the placeholders' order
+
+    and each attachment goes to ``writer.write`` as a view of its own
+    buffer. Returns ``(attachments, their bytes)`` for such a frame, None
+    for a plain one."""
+    payload, blobs = _pack(obj)
+    if not blobs:
+        writer.write(_LEN.pack(len(payload)))
+        writer.write(payload)
+        if telemetry._active is not None:  # process-wide wire accounting
+            telemetry._active.counter("net.bytes_out").inc(
+                _LEN.size + len(payload)
+            )
+        return None
+    attached_bytes = sum(map(len, blobs))
+    body = _LEN.size + len(payload) + attached_bytes
+    if body > MAX_FRAME:
+        raise ValueError(f"frame too large: {body}")
+    writer.write(
+        _LEN.pack(_ATTACHED | body) + _LEN.pack(len(payload)) + payload
+    )
+    for blob in blobs:
+        if len(blob):
+            writer.write(blob.view)
+    if telemetry._active is not None:
+        telemetry._active.counter("net.bytes_out").inc(_LEN.size + body)
+    return len(blobs), attached_bytes
+
+
+def _send_frame(owner, writer: asyncio.StreamWriter, obj: Any) -> None:
+    """``write_frame`` charged to ``owner``: its seconds on the frame
+    accumulator, its attachments on ``attached`` / ``attached_bytes``."""
+    sent = _frame_work(owner, write_frame, writer, obj)
+    if sent is not None:
+        owner.attached += sent[0]
+        owner.attached_bytes += sent[1]
 
 
 def _frame_work(owner, work, *args):
@@ -188,9 +328,12 @@ class RPCServer:
         ] = {}
         self._next_call_id = 0
         # seconds / count of this server's synchronous frame work
-        # (``_frame_work``), always on
+        # (``_frame_work``) and count / bytes of the attachments its frames
+        # carried, both directions: always on
         self.frame_s = 0.0
         self.frames = 0
+        self.attached = 0
+        self.attached_bytes = 0
 
     def register(self, method: str, handler: Handler) -> None:
         self._handlers[method] = handler
@@ -218,7 +361,7 @@ class RPCServer:
         if tc is not None:
             request["tc"] = tc
         try:
-            _frame_work(self, write_frame, writer, request)
+            _send_frame(self, writer, request)
             await writer.drain()
             reply = await asyncio.wait_for(fut, timeout=timeout)
         finally:
@@ -345,7 +488,7 @@ class RPCServer:
                 tele.counter("rpc.server.errors").inc()
             reply = {"id": req_id, "ok": False, "error": repr(e)}
         try:
-            _frame_work(self, write_frame, writer, reply)
+            _send_frame(self, writer, reply)
             await writer.drain()
         except (OSError, RuntimeError):
             # best-effort reply: any transport-level failure (reset, broken
@@ -371,9 +514,12 @@ class RPCClient:
         self._next_id = 0
         self._conn_locks: Dict[Endpoint, asyncio.Lock] = {}
         # seconds / count of this client's synchronous frame work
-        # (``_frame_work``), always on
+        # (``_frame_work``) and count / bytes of the attachments its frames
+        # carried, both directions: always on
         self.frame_s = 0.0
         self.frames = 0
+        self.attached = 0
+        self.attached_bytes = 0
         # circuit relay: requests relayed to THIS (otherwise unreachable)
         # peer arrive on its outbound relay connection and dispatch here —
         # point this at an RPCServer's handler dict to expose its methods
@@ -456,7 +602,7 @@ class RPCClient:
         if conn is None:
             return
         try:
-            _frame_work(self, write_frame, conn[1], reply)
+            _send_frame(self, conn[1], reply)
             await conn[1].drain()
         except (OSError, RuntimeError):
             # best-effort reply: any transport-level failure (reset, broken
@@ -648,7 +794,7 @@ class RPCClient:
         tc = trace_field(tele)
         if tc is not None:
             request["tc"] = tc
-        _frame_work(self, write_frame, writer, request)
+        _send_frame(self, writer, request)
         # hand-rolled deadline instead of asyncio.wait_for: the response
         # future is a bare Future (no task wrapping needed), so the whole
         # timeout is one timer that fails the future — wait_for's

@@ -128,11 +128,29 @@ def f32_to_f16(x: np.ndarray) -> np.ndarray:
     return out
 
 
-def f16_to_f32(x: np.ndarray) -> np.ndarray:
+def f32_destination(out: Optional[np.ndarray], like: np.ndarray) -> np.ndarray:
+    """The float32 array a decoder writes: ``out`` when the caller gave its
+    destination (contiguous float32 of ``like``'s size — peer-controlled
+    sizes must fail loudly, not write out of bounds), else a fresh one."""
+    if out is None:
+        return np.empty(like.shape, dtype=np.float32)
+    if (out.dtype != np.float32 or not out.flags["C_CONTIGUOUS"]
+            or not out.flags["WRITEABLE"]):
+        raise ValueError("decode destination must be writable contiguous float32")
+    if out.size != like.size:
+        raise ValueError(
+            f"decode size mismatch: got {like.size}, want {out.size}"
+        )
+    return out
+
+
+def f16_to_f32(x: np.ndarray, out: Optional[np.ndarray] = None) -> np.ndarray:
+    """IEEE fp16 -> fp32, into ``out`` when given (returned)."""
     x = np.ascontiguousarray(x, dtype=np.float16)
+    out = f32_destination(out, x)
     if _lib is None:
-        return x.astype(np.float32)
-    out = np.empty(x.shape, dtype=np.float32)
+        out.reshape(x.shape)[...] = x
+        return out
     _lib.f16_to_f32(
         x.ctypes.data_as(ctypes.POINTER(ctypes.c_uint16)),
         _ptr(out, ctypes.c_float),
@@ -163,11 +181,15 @@ def quantize_uint8(x: np.ndarray) -> Tuple[np.ndarray, float, float]:
     return q, float(lo.value), float(scale.value)
 
 
-def dequantize_uint8(q: np.ndarray, lo: float, scale: float) -> np.ndarray:
+def dequantize_uint8(
+    q: np.ndarray, lo: float, scale: float, out: Optional[np.ndarray] = None
+) -> np.ndarray:
+    """Affine decode ``q * scale + lo``, into ``out`` when given (returned)."""
     q = np.ascontiguousarray(q, dtype=np.uint8)
+    out = f32_destination(out, q)
     if _lib is None:
-        return q.astype(np.float32) * scale + lo
-    out = np.empty(q.shape, dtype=np.float32)
+        out.reshape(q.shape)[...] = q.astype(np.float32) * scale + lo
+        return out
     _lib.dequantize_uint8(
         _ptr(q, ctypes.c_uint8), _ptr(out, ctypes.c_float), q.size, lo, scale
     )

@@ -45,7 +45,9 @@ spans (docs/observability.md "Step-phase flight recorder"):
                    (``allreduce`` − Σ kinds: the loop thread waiting);
                    ``ar_partner_lag`` is how late the partner's first part
                    landed. ``ar_loop_cpu_s`` on the record is the loop
-                   thread's CPU time over ``allreduce``
+                   thread's CPU time over ``allreduce``,
+                   ``ar_attached_chunks`` the chunk payloads its frames
+                   carried by reference
 - ``opt_apply``    optimizer apply + NaN guard; child ``h2d_result`` (the
                    averaged flat buffer going back to the device)
 - ``backup_launch`` the on-thread part of the state backup: one host sync

@@ -84,7 +84,7 @@ async def _allreduce_swarm(vectors, weights, bandwidths, client_mask=None,
                            chunk_size=DEFAULT_CHUNK_SIZE, dead=(),
                            straggler_timeout=5.0, telemetries=None,
                            round_id="round1", fault_setup=None,
-                           reducers_out=None, timeout=10.0):
+                           reducers_out=None, timeout=10.0, **run_kwargs):
     """Run a full group all-reduce among n in-process peers over loopback
     RPC; returns results. ``dead`` members never run (straggler scenarios —
     pass a short ``straggler_timeout`` to keep those tests fast). Shared
@@ -97,7 +97,8 @@ async def _allreduce_swarm(vectors, weights, bandwidths, client_mask=None,
     ``fault_setup(clients, endpoints)`` runs after the sockets exist and
     before the round — the hook link-level fault injection needs.
     ``reducers_out`` (a list) receives the peers' ``GroupAllReduce`` objects:
-    their ``last_trace`` is the round's span tree."""
+    their ``last_trace`` is the round's span tree. ``run_kwargs`` go to
+    every member's ``run`` (``normalize=False``: a SUM-mode round)."""
     n = len(vectors)
     client_mask = client_mask or [False] * n
     telemetries = telemetries or [None] * n
@@ -131,7 +132,7 @@ async def _allreduce_swarm(vectors, weights, bandwidths, client_mask=None,
         results = await asyncio.gather(
             *(
                 reducers[i].run(round_id, i, vectors[i], weights[i],
-                                endpoints, eff_bw)
+                                endpoints, eff_bw, **run_kwargs)
                 for i in range(n)
                 if i not in dead
             )
@@ -364,16 +365,21 @@ def test_round_span_tree_tiles_allreduce_and_counts_the_chunk_work(rng):
             kinds = {k: spans[k][0] for k in KINDS}
             assert all(len(spans[k]) == 1 and len(kinds[k]) == 6
                        and kinds[k][1] == "allreduce" for k in KINDS)
-            # encode: my parts to the partner, my own part and the reduced
-            # chunks I adopt through the codec, the partner's first pull
-            assert kinds["ar_encode"][4] == theirs + 3 * mine
-            # decode: the partner's parts of my span, the chunks I pull
-            assert kinds["ar_decode"][4] == mine + theirs
+            # encode: my parts to the partner, my own part through the
+            # codec, and each hosted chunk's reduced value ONCE — for the
+            # partner's pull and my own adoption alike
+            assert kinds["ar_encode"][4] == theirs + 2 * mine
+            # decode: the partner's parts of my span, and every chunk of
+            # the result (pulled or my own) straight into it
+            assert kinds["ar_decode"][4] == 2 * mine + theirs
             # reduce: two accumulates and one finalize per hosted chunk
             assert kinds["ar_reduce"][4] == 3 * mine
-            # copy: every chunk of the result, and the local_span copy
-            assert kinds["ar_copy"][4] == mine + theirs + 1
+            # copy: the local_span copy alone — no payload is copied
+            assert kinds["ar_copy"][4] == 1
             assert kinds["ar_frame"][4] >= 2 * (mine + theirs)
+            # every payload rode its frame by reference: parts out and in,
+            # reduced chunks served and gathered
+            assert timing["attached_chunks"] == 2 * (mine + theirs)
             for kind in kinds.values():
                 # (a partner's first part may land a moment before my own
                 # reading of "formed": no lower bound on a kind's first t0)
@@ -395,6 +401,8 @@ def test_round_span_tree_tiles_allreduce_and_counts_the_chunk_work(rng):
             assert event["frame_s"] > 0 and event["wait_s"] >= 0
             assert event["partner_lag_s"] == round(lag[3] - lag[2], 6)
             assert event["chunks"] == mine + theirs
+            assert event["attached_chunks"] == 2 * (mine + theirs)
+            assert event["attached_bytes"] == 2 * dim * 2  # fp16, both ways
     finally:
         for avg in avgs:
             avg.shutdown()
@@ -406,9 +414,9 @@ def test_round_span_tree_under_a_frozen_clock_is_deterministic(
     rng, monkeypatch
 ):
     """Under a frozen FakeClock the only time that passes is what the test
-    puts in: 10 ms inside every ``serialize_array``. Every stage edge then
+    puts in: 10 ms inside every ``encode_array``. Every stage edge then
     lands on a whole number of those, the stages tile to the float, each
-    peer's ``ar_encode`` total is its own serialize calls (its
+    peer's ``ar_encode`` total is its own encode calls (its
     ``wire_roundtrip`` sections took nothing), and the loop's CPU reads 0."""
     from dedloc_tpu.averaging import allreduce as ar
     from dedloc_tpu.testing.faults import FakeClock
@@ -417,13 +425,13 @@ def test_round_span_tree_under_a_frozen_clock_is_deterministic(
     vectors = [rng.standard_normal(dim).astype(np.float32) for _ in range(n)]
     reducers = []
     with FakeClock(frozen=True) as clock:
-        real = ar.serialize_array
+        real = ar.encode_array
 
-        def slow_serialize(*args, **kwargs):
+        def slow_encode(*args, **kwargs):
             clock.advance(tick)
             return real(*args, **kwargs)
 
-        monkeypatch.setattr(ar, "serialize_array", slow_serialize)
+        monkeypatch.setattr(ar, "encode_array", slow_encode)
         asyncio.run(_allreduce_swarm(
             vectors, [1.0] * n, [1.0] * n,
             compression=CompressionType.FLOAT16, chunk_size=chunk,
@@ -445,8 +453,9 @@ def test_round_span_tree_under_a_frozen_clock_is_deterministic(
         mine = _hosted_chunks(dim, n, chunk, i)
         theirs = _hosted_chunks(dim, n, chunk, 1 - i)
         (encode,) = spans["ar_encode"]
-        assert encode[4] == theirs + 3 * mine
+        assert encode[4] == theirs + 2 * mine
         assert encode[5] == pytest.approx((theirs + mine) * tick, abs=1e-9)
+        assert trace.attached_chunks == 2 * (mine + theirs)
         for kind in ("ar_decode", "ar_reduce", "ar_copy"):
             assert spans[kind][0][5] == 0.0
         assert spans["ar_frame"][0][5] == 0.0
@@ -485,7 +494,10 @@ def test_round_span_tree_is_timed_with_telemetry_off_and_annotated_with_it_on(
     for reducer in reducers:
         spans = _by_name(reducer.last_trace.spans)
         assert set(STAGES[1:]) | set(KINDS) <= set(spans)
-        assert spans["ar_encode"][0][4] == 2 * chunks
+        # (two peers, half the chunks each: parts sent + own parts +
+        # hosted chunks encoded once; parts hosted + every chunk decoded)
+        assert spans["ar_encode"][0][4] == 3 * chunks // 2
+        assert spans["ar_decode"][0][4] == 3 * chunks // 2
     ours = [name for name in entered
             if name.startswith("ar_") or name == "frame"]
     if not telemetry_on:
@@ -493,8 +505,8 @@ def test_round_span_tree_is_timed_with_telemetry_off_and_annotated_with_it_on(
         return
     for stage in STAGES[1:]:
         assert ours.count(stage) == n
-    assert ours.count("ar_encode") == n * 2 * chunks
-    assert ours.count("ar_decode") == n * chunks
+    assert ours.count("ar_encode") == n * 3 * chunks // 2
+    assert ours.count("ar_decode") == n * 3 * chunks // 2
     assert ours.count("frame") >= n * 2 * chunks
     for tele in teles:
         names = [e["event"] for e in tele.events]
@@ -562,8 +574,8 @@ def test_matchmaking_failure_leaves_an_empty_span_tree():
 
 def test_client_mode_member_sums_its_own_sections(rng):
     """A member that hosts nothing still encodes what it sends and decodes
-    and copies what it pulls: its kinds are summed without a hosted span
-    (no reduce, no partner's part to lag behind)."""
+    what it pulls (into the result: nothing to copy): its kinds are summed
+    without a hosted span (no reduce, no partner's part to lag behind)."""
     n, dim, chunk = 3, 3_000, 500
     vectors = [rng.standard_normal(dim).astype(np.float32) for _ in range(n)]
     reducers = []
@@ -576,8 +588,10 @@ def test_client_mode_member_sums_its_own_sections(rng):
     chunks = dim // chunk
     assert spans["ar_encode"][0][4] == chunks  # every chunk goes to a host
     assert spans["ar_decode"][0][4] == chunks
-    assert spans["ar_copy"][0][4] == chunks
+    assert "ar_copy" not in spans
     assert "ar_reduce" not in spans and "ar_partner_lag" not in spans
+    # its client carried every payload: parts out, reduced chunks in
+    assert reducers[2].last_trace.attached_chunks == 2 * chunks
     assert reducers[2].last_trace.open_stage is None
 
 

@@ -65,6 +65,51 @@ def test_quantize_constant_array():
     assert np.allclose(native.dequantize_uint8(q, lo, scale), 2.5)
 
 
+@pytest.mark.parametrize("use_native", [True, False])
+def test_decoders_write_into_the_callers_destination(monkeypatch, use_native):
+    """``f16_to_f32`` / ``dequantize_uint8`` with ``out=``: the same bits as
+    without, written into (a slice of) the caller's float32 array and
+    nowhere else — native path and numpy fallback alike; a destination of
+    another size, dtype or layout is refused before any pointer is used."""
+    if not use_native:
+        monkeypatch.setattr(native, "_lib", None)
+    elif not native.AVAILABLE:
+        pytest.skip("no native codec here")
+    rng = np.random.default_rng(5)
+    h = rng.standard_normal(1001).astype(np.float16)
+    q = rng.integers(0, 256, 1001).astype(np.uint8)
+    for decode, src in (
+        (native.f16_to_f32, h),
+        (lambda x, out=None: native.dequantize_uint8(x, -1.5, 0.0123, out=out),
+         q),
+    ):
+        fresh = decode(src)
+        buf = np.full(1001 + 8, np.nan, np.float32)
+        got = decode(src, out=buf[4:-4])
+        assert got.base is buf
+        np.testing.assert_array_equal(got.view(np.uint32),
+                                      fresh.view(np.uint32))
+        assert np.isnan(buf[:4]).all() and np.isnan(buf[-4:]).all()
+        for bad in (np.empty(1000, np.float32), np.empty(1001, np.float64),
+                    np.empty(2002, np.float32)[::2]):
+            with pytest.raises(ValueError):
+                decode(src, out=bad)
+        read_only = np.empty(1001, np.float32)
+        read_only.flags.writeable = False
+        with pytest.raises(ValueError):
+            decode(src, out=read_only)
+
+
+def test_crc32c_reads_any_contiguous_buffer():
+    """The crc is taken over the encoded ARRAY (send side) and over the
+    frame's memoryview (receive side): the same number as over its bytes."""
+    x = np.random.default_rng(6).standard_normal(999).astype(np.float16)
+    want = native.crc32c(x.tobytes())
+    assert native.crc32c(x) == want
+    assert native.crc32c(memoryview(x.tobytes())) == want
+    assert native.crc32c(np.empty(0, np.float16)) == native.crc32c(b"")
+
+
 def test_axpy_and_scale():
     rng = np.random.default_rng(2)
     x = rng.standard_normal(1000).astype(np.float32)

@@ -118,13 +118,15 @@ def test_health_wire_path_table_splits_the_round_by_kind(
     """The wire-path table puts a round's wall down to what the loop thread
     did (encode / decode / reduce / copy / frame), the wait that is none of
     those, the partner's lag and the loop's CPU — means over the peer's
-    ``allreduce.round`` events; a pre-ISSUE-34 event (reduce_s and
-    gather_wait_s only) still folds, with zeros."""
+    ``allreduce.round`` events, with the payloads its frames carried by
+    reference (``attached_chunks`` / ``attached_bytes``); a pre-ISSUE-34
+    event (reduce_s and gather_wait_s only) still folds, with zeros."""
     round_ = {"t": 100.0, "peer": "peerA", "event": "allreduce.round",
               "dur_s": 0.7, "round_id": "r1", "ok": True, "chunks": 136,
               "gather_wait_s": 0.6, "encode_s": 0.2, "decode_s": 0.1,
               "reduce_s": 0.04, "copy_s": 0.02, "frame_s": 0.06,
-              "wait_s": 0.3, "partner_lag_s": 0.01, "loop_cpu_s": 0.35}
+              "wait_s": 0.3, "partner_lag_s": 0.01, "loop_cpu_s": 0.35,
+              "attached_chunks": 272, "attached_bytes": 71_303_168}
     events = [
         round_,
         dict(round_, t=101.0, round_id="r2", encode_s=0.4, wait_s=0.1),
@@ -138,13 +140,16 @@ def test_health_wire_path_table_splits_the_round_by_kind(
         wire = json.loads(capsys.readouterr().out)["wire"]
         assert wire["peerA"] == {
             "rounds": 2, "dur_mean_s": 0.7, "gather_wait_mean_s": 0.6,
-            "chunks_mean": 136.0, "encode_mean_s": 0.3, "decode_mean_s": 0.1,
+            "chunks_mean": 136.0, "attached_mean": 272.0,
+            "attached_mb_mean": 71.303, "encode_mean_s": 0.3,
+            "decode_mean_s": 0.1,
             "reduce_mean_s": 0.04, "copy_mean_s": 0.02, "frame_mean_s": 0.06,
             "wait_mean_s": 0.2, "partner_lag_mean_s": 0.01,
             "loop_cpu_mean_s": 0.35,
         }
         assert wire["old"]["reduce_mean_s"] == 0.1
         assert wire["old"]["encode_mean_s"] == wire["old"]["wait_mean_s"] == 0
+        assert wire["old"]["attached_mean"] == 0
         return
     runlog_summary.main(["--health", path])
     out = capsys.readouterr().out
@@ -152,13 +157,14 @@ def test_health_wire_path_table_splits_the_round_by_kind(
                   if ln.startswith("| peer | rounds | dur"))
     assert header == (
         "| peer | rounds | dur | encode | decode | reduce | copy | frame |"
-        " wait | partner lag | loop cpu | gather wait | chunks |"
+        " wait | partner lag | loop cpu | gather wait | chunks | attached |"
+        " attached MB |"
     )
     (row,) = [ln for ln in out.splitlines()
               if ln.startswith("| peerA | 2 | 0.700s")]
     assert row == (
         "| peerA | 2 | 0.700s | 0.300s | 0.100s | 0.040s | 0.020s | 0.060s |"
-        " 0.200s | 0.010s | 0.350s | 0.600s | 136.0 |"
+        " 0.200s | 0.010s | 0.350s | 0.600s | 136.0 | 272.0 | 71.3 |"
     )
 
 

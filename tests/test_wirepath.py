@@ -232,6 +232,209 @@ def test_all_aux_chunked_group_serves_local_spans(rng):
         np.testing.assert_allclose(r, expected, atol=5e-3)
 
 
+# ------------------------------- the by-reference wire path (attachments)
+
+
+def _parents_round(vectors, weights, eff_bw, compression, chunk_size,
+                   normalize=True):
+    """The round's result computed the way the tree BEFORE attachment frames
+    computed it, chunk by chunk: every part through ``serialize_array`` /
+    ``deserialize_array`` (a host's own part through ``wire_roundtrip``),
+    the same native axpy / scale in the same order (own part first), the
+    reduced chunk served through ``serialize_array`` and adopted by its
+    host through ``wire_roundtrip``. At most two members contribute data in
+    the cases below, so the order the remote parts arrive in cannot change
+    a bit (``a + b == b + a``)."""
+    from dedloc_tpu import native
+    from dedloc_tpu.core.serialization import (
+        deserialize_array,
+        serialize_array,
+    )
+
+    dim = len(vectors[0])
+    spans = partition_weighted(dim, list(eff_bw), [bw > 0 for bw in eff_bw])
+    served = np.empty(dim, np.float32)  # what a gatherer decodes
+    adopted = np.empty(dim, np.float32)  # what the host itself keeps
+    for host, (lo, hi) in enumerate(spans):
+        for clo, chi in span_chunks(lo, hi, chunk_size):
+            acc, total = None, 0.0
+            order = [host] + [i for i in range(len(vectors)) if i != host]
+            for i in order:
+                if weights[i] <= 0:
+                    continue
+                piece = vectors[i][clo:chi]
+                if i == host:
+                    part = (wire_roundtrip(piece, compression)
+                            if compression is not CompressionType.NONE
+                            else piece)
+                else:
+                    part = deserialize_array(
+                        serialize_array(piece, compression, checksum=True)
+                    )
+                if acc is None:
+                    acc = native.scale(
+                        np.array(part, dtype=np.float32), weights[i]
+                    )
+                else:
+                    native.axpy(acc, part, weights[i])
+                total += weights[i]
+            reduced = native.scale(acc, 1.0 / total) if normalize else acc
+            served[clo:chi] = deserialize_array(
+                serialize_array(reduced, compression, checksum=True)
+            )
+            adopted[clo:chi] = (
+                wire_roundtrip(reduced, compression)
+                if compression is not CompressionType.NONE else reduced
+            )
+    # the two readings of one chunk agree in the parent too
+    np.testing.assert_array_equal(served.view(np.uint32),
+                                  adopted.view(np.uint32))
+    return served
+
+
+# (weights, client_mask, bandwidths, run kwargs): at most two data senders
+_BIT_EQUAL_GROUPS = {
+    "two_peers": ([1.0, 3.0], [False, False], [1.0, 2.0], {}),
+    "three_peers_one_aux": (
+        [2.0, 1.0, 0.0], [False, False, False], [1.0, 1.0, 3.0], {}),
+    "three_peers_one_client_mode": (
+        [0.0, 1.0, 2.0], [False, False, True], [2.0, 1.0, 1.0], {}),
+    "two_peers_sum_mode": (
+        [1.0, 3.0], [False, False], [1.0, 1.0], {"normalize": False}),
+}
+
+
+@pytest.mark.parametrize("group", sorted(_BIT_EQUAL_GROUPS))
+@pytest.mark.parametrize("compression", list(CompressionType),
+                         ids=lambda c: c.value)
+def test_round_is_bit_equal_to_the_parents_wire_path(rng, compression, group):
+    """Attachments change how a chunk's bytes TRAVEL, not one bit of what
+    arrives: for every codec the result equals the value computed with the
+    parent's ``serialize_array`` / ``wire_roundtrip`` path, and every member
+    holds the same bytes."""
+    weights, client_mask, bandwidths, run_kwargs = _BIT_EQUAL_GROUPS[group]
+    n, dim, chunk = len(weights), 5_000, 700  # a ragged last chunk
+    vectors = [
+        (rng.standard_normal(dim) * 3).astype(np.float32) for _ in range(n)
+    ]
+    results = asyncio.run(_pipelined_swarm(
+        vectors, weights, bandwidths, client_mask, compression,
+        chunk_size=chunk, **run_kwargs,
+    ))
+    eff_bw = [0.0 if c else bw for c, bw in zip(client_mask, bandwidths)]
+    expected = _parents_round(
+        vectors, weights, eff_bw, compression, chunk,
+        normalize=run_kwargs.get("normalize", True),
+    )
+    if run_kwargs.get("normalize", True) is False:
+        assert all(w == sum(weights) for _out, w in results)
+        results = [out for out, _w in results]
+    for r in results:
+        assert r.dtype == np.float32 and r.shape == (dim,)
+        np.testing.assert_array_equal(r.view(np.uint32),
+                                      expected.view(np.uint32))
+    for r in results[1:]:
+        assert r.tobytes() == results[0].tobytes()
+
+
+@pytest.mark.parametrize("hop", ["part", "reduced"])
+@pytest.mark.parametrize("compression", list(CompressionType),
+                         ids=lambda c: c.value)
+def test_corrupted_attachment_fails_the_round(rng, compression, hop):
+    """One flipped bit in one attachment — a part on its way to its host,
+    or a reduced chunk on its way back — is caught by the crc over the
+    frame's buffer and costs the round (``AllreduceFailed``), never a
+    silently wrong average."""
+    from dedloc_tpu.dht.protocol import Blob
+
+    n, dim = 2, 3_000
+    vectors = [rng.standard_normal(dim).astype(np.float32) for _ in range(n)]
+    flipped = []
+
+    def corrupt(blob):
+        raw = bytearray(blob.view)
+        raw[len(raw) // 2] ^= 0x10
+        flipped.append(len(raw))
+        return Blob(bytes(raw))
+
+    def fault_setup(clients, endpoints):
+        real_call = clients[0].call
+
+        async def call(endpoint, method, args=None, timeout=None):
+            if hop == "part" and method == "avg.part" and not flipped:
+                args = dict(args, data=corrupt(args["data"]))
+            reply = await real_call(endpoint, method, args, timeout)
+            if hop == "reduced" and method == "avg.get_reduced" and not flipped:
+                reply = dict(reply, data=corrupt(reply["data"]))
+            return reply
+
+        clients[0].call = call
+
+    with pytest.raises(AllreduceFailed, match="checksum"):
+        asyncio.run(_pipelined_swarm(
+            vectors, [1.0] * n, [1.0] * n, [False] * n, compression,
+            chunk_size=500, fault_setup=fault_setup, straggler_timeout=0.5,
+            timeout=3.0,
+        ))
+    assert len(flipped) == 1
+
+
+def test_round_copies_no_payload_through_msgpack_or_tobytes(rng, monkeypatch):
+    """The copy count: during a round nothing larger than 4 KiB goes through
+    ``msgpack.packb`` / ``unpackb`` (the chunk payloads are 16 KiB of fp16
+    here), ``ndarray.tobytes`` is never called, and each peer counts every
+    payload as an attachment: parts out and in, reduced chunks served and
+    gathered — 4 x the chunks it hosts in a two-peer group."""
+    import sys
+
+    import msgpack
+
+    n, dim, chunk = 2, 65_536, 8_192
+    vectors = [rng.standard_normal(dim).astype(np.float32) for _ in range(n)]
+    packed, unpacked, c_calls = [], [], []
+    real_packb, real_unpackb = msgpack.packb, msgpack.unpackb
+
+    def packb(obj, **kwargs):
+        out = real_packb(obj, **kwargs)
+        packed.append(len(out))
+        return out
+
+    def unpackb(data, **kwargs):
+        unpacked.append(len(data))
+        return real_unpackb(data, **kwargs)
+
+    monkeypatch.setattr(msgpack, "packb", packb)
+    monkeypatch.setattr(msgpack, "unpackb", unpackb)
+
+    def profile(frame, event, arg):
+        # built-in methods cannot be patched: the interpreter's own C-call
+        # hook sees them by name
+        if event == "c_call" and arg.__name__ in ("tobytes", "tostring"):
+            c_calls.append(arg.__qualname__)
+
+    reducers = []
+    sys.setprofile(profile)
+    try:
+        results = asyncio.run(_pipelined_swarm(
+            vectors, [1.0] * n, [1.0] * n, [False] * n,
+            CompressionType.FLOAT16, chunk_size=chunk, reducers_out=reducers,
+        ))
+    finally:
+        sys.setprofile(None)
+    np.testing.assert_allclose(results[0], sum(vectors) / n, atol=5e-3)
+    assert c_calls == []
+    assert packed and unpacked
+    assert max(packed) <= 4096 and max(unpacked) <= 4096
+    hosted = dim // n // chunk
+    for reducer in reducers:
+        trace = reducer.last_trace
+        assert trace.attached_chunks == 4 * hosted
+        assert trace.attached_bytes == 4 * hosted * chunk * 2  # fp16
+        # and outside a round nothing is attached: the counters stand still
+        ends = (reducer.client, reducer.server)
+        assert sum(e.attached for e in ends) == trace.attached_chunks
+
+
 def test_dead_host_still_fails_chunked_round():
     """The host-failure contract survives chunking: a member that hosts a
     span and never runs fails the round for everyone, within the timeout."""
@@ -472,24 +675,26 @@ def test_late_straggler_part_cannot_mutate_finalized_chunk(rng):
             np.testing.assert_allclose(result, vec, atol=1e-6)
             # the round state is still serving (deferred cleanup): the
             # straggler's part arrives LATE
-            from dedloc_tpu.core.serialization import serialize_array
+            from dedloc_tpu.core.serialization import (
+                decode_array,
+                encode_array,
+            )
+            from dedloc_tpu.dht.protocol import Blob
 
-            late = serialize_array(
+            header, wire = encode_array(
                 np.full(50, 100.0, np.float32), CompressionType.NONE,
                 checksum=True,
             )
             await client.call(
                 endpoints[0], "avg.part",
                 {"round_id": "late", "sender": 1, "weight": 1.0,
-                 "chunk": 0, "data": late},
+                 "chunk": 0, "h": header, "data": Blob(wire)},
             )
             reply = await client.call(
                 endpoints[0], "avg.get_reduced",
                 {"round_id": "late", "chunk": 0},
             )
-            from dedloc_tpu.core.serialization import deserialize_array
-
-            served = deserialize_array(reply["data"])
+            served = decode_array(reply["h"], reply["data"].view)
             np.testing.assert_allclose(served, np.ones(50, np.float32),
                                        atol=1e-6)
         finally:

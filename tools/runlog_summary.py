@@ -243,12 +243,16 @@ def _wire_per_peer(rows):
         acc = per_peer_wire.setdefault(
             r.get("peer", "?"),
             {"rounds": 0, "dur": 0.0, "gather": 0.0, "chunks": 0,
+             "attached": 0, "attached_bytes": 0,
              **{key: 0.0 for key, _field in _WIRE_FIELDS}},
         )
         acc["rounds"] += 1
         acc["dur"] += float(r.get("dur_s", 0.0))
         acc["gather"] += float(r.get("gather_wait_s", 0.0))
         acc["chunks"] += int(r.get("chunks", 0))
+        # payloads the round's frames carried by reference, both directions
+        acc["attached"] += int(r.get("attached_chunks", 0))
+        acc["attached_bytes"] += int(r.get("attached_bytes", 0))
         for key, field in _WIRE_FIELDS:
             acc[key] += float(r.get(field, 0.0))
     return per_peer_wire
@@ -340,6 +344,10 @@ def health_data(rows):
                 "dur_mean_s": round(a["dur"] / a["rounds"], 6),
                 "gather_wait_mean_s": round(a["gather"] / a["rounds"], 6),
                 "chunks_mean": round(a["chunks"] / a["rounds"], 2),
+                "attached_mean": round(a["attached"] / a["rounds"], 2),
+                "attached_mb_mean": round(
+                    a["attached_bytes"] / a["rounds"] / 1e6, 3
+                ),
                 **{
                     f"{key}_mean_s": round(a[key] / a["rounds"], 6)
                     for key, _field in _WIRE_FIELDS
@@ -414,8 +422,8 @@ def print_health(rows):
         print("\nwire path (mean per all-reduce round):")
         print("| peer | rounds | dur | encode | decode | reduce | copy |"
               " frame | wait | partner lag | loop cpu | gather wait |"
-              " chunks |")
-        print("|" + "---|" * 13)
+              " chunks | attached | attached MB |")
+        print("|" + "---|" * 15)
         for peer in sorted(per_peer_wire):
             a = per_peer_wire[peer]
             k = a["rounds"]
@@ -423,6 +431,8 @@ def print_health(rows):
                 f"| {peer} | {k} | {a['dur'] / k:.3f}s | " + " | ".join(
                     f"{a[key] / k:.3f}s" for key, _field in _WIRE_FIELDS
                 ) + f" | {a['gather'] / k:.3f}s | {a['chunks'] / k:.1f} |"
+                f" {a['attached'] / k:.1f} |"
+                f" {a['attached_bytes'] / k / 1e6:.1f} |"
             )
 
     # checkpoint/restore view (swarm checkpointing, docs/fleet.md restart
