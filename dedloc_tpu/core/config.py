@@ -289,8 +289,9 @@ class TrainingArguments:
     # tiny (CI fixture) | large (ALBERT); ouro_tiny | ouro_2p6b (the looped
     # decoder, models/ouro.py); kanana2_tiny | kanana2_30b_a3b
     # (models/deepseek_v3.py); lfm2_tiny | lfm2_24b_a2b (the conv-hybrid
-    # expert decoder, models/lfm2_moe.py) — roles/common.MODEL_FAMILIES is
-    # the table
+    # expert decoder, models/lfm2_moe.py); smallthinker_tiny |
+    # smallthinker_21b_a3b (the band-and-global expert decoder,
+    # models/smallthinker.py) — roles/common.MODEL_FAMILIES is the table
     model_size: str = "large"
     # depth override (0 = the model's own): a chip's share of a deeper
     # deployment keeps every width and cuts layers. No width is settable.
@@ -298,7 +299,7 @@ class TrainingArguments:
     # "index/count": the share of every expert layer's routed experts this
     # peer's chip holds, as one of ``count`` chips that divide a layer (a
     # model with a dropless routed layer: models/deepseek_v3.py,
-    # models/lfm2_moe.py). The layer
+    # models/lfm2_moe.py, models/smallthinker.py). The layer
     # scores ALL experts and computes its own experts' part; "0/1" = every
     # expert. Together with ``vocab_size`` (rows of the vocabulary held)
     # and ``num_hidden_layers`` it states a chip's share of a deployment.

@@ -70,10 +70,23 @@ it is non-differentiable (it comes from the attention mask). A decoder's
 causal mask is a MODE of the same kernels (``causal=True``; see "causal
 tiles" below): tiles above the diagonal are neither fetched nor computed,
 tiles the diagonal crosses get an iota mask, and the kernels are named
-``flash_causal_*`` in a device trace. GROUPED-QUERY attention is read from
-the shapes: k and v with fewer heads than q stay that narrow in HBM, forward
-and backward, and dk / dv are summed over a group inside the kernel (see
-"grouped-query heads" below; ``flash_gqa_*`` in a device trace).
+``flash_causal_*`` in a device trace. A BAND (``band=w``: a sliding window,
+query i sees keys i-w+1 .. i) is the causal mask with a second edge: tiles
+wholly before the band are not even grid steps — the inner grid axis is as
+long as the band's tiles and sweeps from the outer tile's first needed tile
+— the tiles its lower edge crosses get the iota mask too, and the kernels
+are named ``flash_band_*``. What a query may see is ONE description
+(``_Mask``: causal, band) that the index maps, the sweep's length, the tile
+cases, the iota mask, the names and the count of visited tiles all read.
+GROUPED-QUERY attention is read from the shapes: k and v with fewer heads
+than q stay that narrow in HBM, forward and backward, and dk / dv are summed
+over a group inside the kernel (see "grouped-query heads" below;
+``flash_gqa_*`` in a device trace). A group that is no power of two (seven
+query heads a kv head) is ONE program, where a column block is one head
+(D=128): halving the heads a program takes never lands on a divisor of it,
+and one head a program — what halving falls to — ran the backward kernels
+at 63 / 68 % of their roofline against 90 / 90 % (v5e, 28 / 4 x 128 at
+S=16,384, PR 36).
 
 Off-TPU (CPU tests, CI) the same kernels run under ``interpret=True``
 (``utils.backend.pallas_interpret`` decides, once, for every op here).
@@ -89,7 +102,7 @@ from __future__ import annotations
 import collections
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -292,14 +305,16 @@ def _lanes(d: int, dv: int, g: int) -> Optional[dict]:
     return None if whole else lanes
 
 
-def _metadata(d: int, dv: int, g: int, q, k) -> Optional[dict]:
+def _metadata(d: int, dv: int, g: int, q, k, mask) -> Optional[dict]:
     """A call's kernel ``metadata``: its windows where they are narrower
-    than its blocks (``_lanes``), its head counts where k has fewer than q;
-    None for every other call (see ``_lanes`` for why not more)."""
+    than its blocks (``_lanes``), its head counts where k has fewer than q,
+    its band where it has one; None for every other call (see ``_lanes``
+    for why not more)."""
     h, kvh = q.shape[-1] // d, k.shape[-1] // d
-    if kvh == h:
-        return _lanes(d, dv, g)
-    return {"heads": h, "kv_heads": kvh}
+    found = _lanes(d, dv, g) if kvh == h else {"heads": h, "kv_heads": kvh}
+    if mask.band is not None:
+        found = dict(found or {}, band=mask.band)
+    return found
 
 
 def _dot(a, b, contract_a: int, contract_b: int):
@@ -350,6 +365,11 @@ def _grouped(q, k, d: int, dv: int, g: int, hp: int):
     def kv_block(hp):
         return max(g, hp // group)
 
+    if g == 1 and group & (group - 1):
+        # halving never lands on a divisor of a group that is no power of
+        # two (seven): a program takes one WHOLE group — its kv block
+        # fetched once, dk / dv summed inside the program
+        return group, 1, group
     while hp > g and (kvh % kv_block(hp) or (kv_block(hp) * group) % hp):
         hp //= 2
     return group, kv_block(hp), hp
@@ -428,6 +448,34 @@ def _fold_group(acc_ref, at, total, d: int, g: int) -> None:
 # again — and the body is skipped), wholly on or below it (the plain body),
 # or crossed by it (the body with an iota mask). Only the crossed tiles pay
 # for the mask.
+#
+# A BAND (a sliding window: ``band`` = w, query i sees keys i-w+1 .. i) has a
+# second edge, below the diagonal, and the same three cases on it. A tile
+# wholly before the band is not even a grid step: the inner axis of a band
+# call is as long as the most tiles any outer tile needs (9 of 32 at
+# S=16,384, w=4,096, 512 x 512 tiles) and sweeps from the outer tile's FIRST
+# needed tile on. What a query may see is ONE description, ``_Mask``, and
+# everything that depends on it reads it: the tile a grid step names
+# (``_k_tile`` / ``_q_tile``), the sweep's length (``_sweep``), the three
+# cases (``_for_tile``), the iota mask (``_tile_mask``), the kernels' names
+# and the count of visited tiles (``visited_tiles``).
+
+
+class _Mask(NamedTuple):
+    """What a query may see of the keys, beyond the KV bias."""
+
+    causal: bool = False  # keys at the query's position and before
+    band: Optional[int] = None  # and only the last ``band`` of those
+
+
+def _clip(x, low=None, high=None):
+    """``x`` held to [low, high], a Python int or a traced scalar."""
+    static = isinstance(x, int)
+    if low is not None:
+        x = max(x, low) if static else jnp.maximum(x, low)
+    if high is not None:
+        x = min(x, high) if static else jnp.minimum(x, high)
+    return x
 
 
 def _last_k_tile(qi, bq: int, bk: int):
@@ -435,34 +483,130 @@ def _last_k_tile(qi, bq: int, bk: int):
     return (qi * bq + bq - 1) // bk
 
 
+def _first_k_tile(mask: _Mask, qi, bq: int, bk: int):
+    """The first key tile query tile ``qi`` needs: the one that holds the
+    band's first key of the tile's first query; 0 without a band."""
+    if mask.band is None:
+        return 0
+    return _clip(qi * bq - mask.band + 1, low=0) // bk
+
+
 def _first_q_tile(ki, bq: int, bk: int):
     """The first query tile that sees key tile ``ki``."""
     return (ki * bk) // bq
 
 
-def _tile_mask(qi, ki, bq: int, bk: int):
-    """[Bq, Bk] bool: key position <= query position, in tile (qi, ki)."""
+def _last_q_tile(mask: _Mask, ki, bq: int, bk: int, nq: int):
+    """The last query tile that sees key tile ``ki``: the one that holds
+    the last query whose band reaches the tile's last key."""
+    if mask.band is None:
+        return nq - 1
+    return _clip((ki * bk + bk + mask.band - 2) // bq, high=nq - 1)
+
+
+def _from(first, step):
+    """Tile ``step`` of a sweep that starts at ``first``."""
+    return step if isinstance(first, int) and first == 0 else first + step
+
+
+def _k_tile(mask: _Mask, qi, step, bq: int, bk: int):
+    """The key tile that step ``step`` of query tile ``qi``'s sweep NAMES
+    (an index map's answer): a step past the last needed tile re-names that
+    tile, which is not fetched again."""
+    if not mask.causal:
+        return step
+    return jnp.minimum(
+        _from(_first_k_tile(mask, qi, bq, bk), step), _last_k_tile(qi, bq, bk)
+    )
+
+
+def _q_tile(mask: _Mask, ki, step, bq: int, bk: int, nq: int):
+    """The query tile that step ``step`` of key tile ``ki``'s sweep names.
+    Without a band the sweep is over every query tile and the steps before
+    the first needed one name it; with one it starts there."""
+    if not mask.causal:
+        return step
+    first = _first_q_tile(ki, bq, bk)
+    if mask.band is None:
+        return jnp.maximum(step, first)
+    return jnp.minimum(first + step, _last_q_tile(mask, ki, bq, bk, nq))
+
+
+def _sweep(mask: _Mask, nq: int, nk: int, bq: int, bk: int, over: str) -> int:
+    """Steps of the inner grid axis: the most key tiles a query tile needs
+    (``over`` "k") or query tiles a key tile is seen by ("q"). Every tile
+    of the axis unless there is a band."""
+    if mask.band is None:
+        return nk if over == "k" else nq
+    if over == "k":
+        return max(
+            _last_k_tile(qi, bq, bk) - _first_k_tile(mask, qi, bq, bk) + 1
+            for qi in range(nq)
+        )
+    return max(
+        _last_q_tile(mask, ki, bq, bk, nq) - _first_q_tile(ki, bq, bk) + 1
+        for ki in range(nk)
+    )
+
+
+def visited_tiles(seq: int, block_q: int, block_k: int, causal: bool,
+                  band: Optional[int] = None) -> int:
+    """(query tile, key tile) pairs a call's kernels compute, crossed ones
+    included: 528 of 1,024 at S=16,384 and 512 x 512 tiles under the causal
+    mask, 252 under a band of 4,096."""
+    bq, bk = _pick_block(seq, block_q), _pick_block(seq, block_k)
+    mask = _mask_of(causal, band, seq)
+    if not mask.causal:
+        return (seq // bq) * (seq // bk)
+    return sum(
+        _last_k_tile(qi, bq, bk) - _first_k_tile(mask, qi, bq, bk) + 1
+        for qi in range(seq // bq)
+    )
+
+
+def _mask_of(causal: bool, band: Optional[int], seq: int) -> _Mask:
+    """The call's mask; a band as long as the sequence IS the causal mask
+    (and the causal kernels, under their names)."""
+    if band is not None and (not causal or band < 1):
+        raise ValueError(
+            f"band={band}: a band is a causal mask's second edge "
+            "(causal=True, band >= 1)"
+        )
+    return _Mask(causal, None if band is None or band >= seq else int(band))
+
+
+def _tile_mask(mask: _Mask, qi, ki, bq: int, bk: int):
+    """[Bq, Bk] bool: key position <= query position (and inside the
+    band), in tile (qi, ki)."""
     rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    return cols <= rows
+    if mask.band is None:
+        return cols <= rows
+    return (cols <= rows) & (rows - cols < mask.band)
 
 
-def _for_tile(causal: bool, qi, ki, bq: int, bk: int, body) -> None:
-    """Run ``body(mask)`` for tile (qi, ki): ``mask`` is None where every
-    key of the tile is visible to every query (always, when not causal)."""
-    if not causal:
+def _for_tile(mask: _Mask, qi, ki, bq: int, bk: int, seq: int, body) -> None:
+    """Run ``body(tile mask)`` for tile (qi, ki): None where every key of
+    the tile is visible to every query (always, when not causal); not at
+    all where none is (a step of the sweep past its last tile)."""
+    if not mask.causal:
         body(None)
         return
     q0, k0 = qi * bq, ki * bk
-    below = k0 + bk - 1 <= q0
+    plain = k0 + bk - 1 <= q0
+    some = k0 <= q0 + bq - 1
+    if mask.band is not None:  # ... whose sweep may also run past the end
+        inside = q0 < seq
+        plain &= inside & (q0 + bq - 1 - k0 < mask.band)
+        some &= inside & (q0 - (k0 + bk - 1) < mask.band)
 
-    @pl.when(below)
+    @pl.when(plain)
     def _plain():
         body(None)
 
-    @pl.when(jnp.logical_not(below) & (k0 <= q0 + bq - 1))
+    @pl.when(jnp.logical_not(plain) & some)
     def _crossed():
-        body(_tile_mask(qi, ki, bq, bk))
+        body(_tile_mask(mask, qi, ki, bq, bk))
 
 
 def _masked(s, mask):
@@ -473,9 +617,11 @@ def _masked(s, mask):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, scale, d, dv, g, causal, group=1):
-    kb = pl.program_id(3)
+                acc_ref, m_ref, l_ref, *, scale, d, dv, g, mask, seq,
+                group=1):
+    kb = pl.program_id(3)  # a step of the key sweep, not yet a tile
     nk = pl.num_programs(3)
+    qi, bq, bk = pl.program_id(2), q_ref.shape[0], k_ref.shape[0]
     program = pl.program_id(1) if group > 1 else None
     blocks = _column_blocks(q_ref.shape[-1], g, d, dv)
 
@@ -535,8 +681,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                     acc_ref[:, at] * _per_head_lanes(corrs, dv, seg) + pv_seg
                 )
 
-    _for_tile(causal, pl.program_id(2), kb, q_ref.shape[0], k_ref.shape[0],
-              tile)
+    _for_tile(mask, qi, _from(_first_k_tile(mask, qi, bq, bk), kb), bq, bk,
+              seq, tile)
 
     @pl.when(kb == nk - 1)
     def _flush():
@@ -556,13 +702,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
 
 
 def _fwd_one_tile_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
-                         scale, d, dv, g, causal):
+                         scale, d, dv, g, mask):
     """Single-block forward: when one (Bq, Bk) tile covers the whole
     sequence a row's softmax is complete after its one tile, so there is no
     running state to initialise, correct or carry — each head's max, sum and
     p·v are taken once, and a column block is normalised and stored once."""
     s = q_ref.shape[0]
-    mask = _tile_mask(0, 0, s, s) if causal else None
+    mask = _tile_mask(mask, 0, 0, s, s) if mask.causal else None
     b = bias_ref[:].astype(jnp.float32)  # [1, S]
     for h0, cols, vcols in _column_blocks(q_ref.shape[-1], g, d, dv):
         q = _per_window(d, g, lambda w: q_ref[:, _at(cols, w)])
@@ -589,11 +735,15 @@ def _fwd_one_tile_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
             ).astype(o_ref.dtype)
 
 
-def _name(kernel: str, causal: bool, d: int, dv: int,
+def _name(kernel: str, mask: _Mask, d: int, dv: int,
           group: int = 1) -> str:
     """The causal kernels keep names of their own in a device trace, and so
-    do the two-width ones (latent attention: q/k wider than v and out) and
-    the grouped-query ones (fewer kv heads than heads)."""
+    do the two-width ones (latent attention: q/k wider than v and out), the
+    grouped-query ones (fewer kv heads than heads) and every call with a
+    band (its metadata says how long, and its head counts)."""
+    if mask.band is not None:
+        return f"flash_band_{kernel}"
+    causal = mask.causal
     if group > 1:
         return f"flash_gqa_{kernel}" if causal else f"flash_gqa_full_{kernel}"
     if d != dv:
@@ -601,24 +751,24 @@ def _name(kernel: str, causal: bool, d: int, dv: int,
     return f"flash_causal_{kernel}" if causal else f"flash_{kernel}"
 
 
-def _fwd(q, k, v, bias, d, dv, block_q, block_k, causal, interpret):
+def _fwd(q, k, v, bias, d, dv, block_q, block_k, mask, interpret):
     """Returns (out [B, S, H·dv], lse [B·H, 1, S])."""
     # grouped-query calls take the tiled form at every length (one tile is
     # then a grid of one): the one-tile kernels have no kv block of their own
     if _one_tile(q.shape[1], block_q, block_k) and k.shape == q.shape:
-        return _fwd_one_tile(q, k, v, bias, d, dv, causal, interpret)
-    return _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, causal,
+        return _fwd_one_tile(q, k, v, bias, d, dv, mask, interpret)
+    return _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, mask,
                       interpret)
 
 
-def _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, causal, interpret):
+def _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, mask, interpret):
     b, s, h, g, hp, bq, bk = _geometry(q, d, dv, block_q, block_k,
                                        budget_mb=6.0)
     group, kvb, hp = _grouped(q, k, d, dv, g, hp)
     hpb = h // hp  # programs across the width
 
     def k_at(j, kb):  # a tile above the diagonal re-names the last needed
-        return jnp.minimum(kb, _last_k_tile(j, bq, bk)) if causal else kb
+        return _k_tile(mask, j, kb, bq, bk)
 
     def kv_at(p):  # the kv block of query program p
         return p if group == 1 else p * hp // group // kvb
@@ -626,9 +776,9 @@ def _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, causal, interpret):
     out, lse = pl.pallas_call(
         functools.partial(
             _fwd_kernel, scale=1.0 / (d ** 0.5), d=d, dv=dv, g=g,
-            causal=causal, group=group,
+            mask=mask, seq=s, group=group,
         ),
-        grid=(b, hpb, s // bq, s // bk),
+        grid=(b, hpb, s // bq, _sweep(mask, s // bq, s // bk, bq, bk, "k")),
         in_specs=[
             pl.BlockSpec((None, bq, hp * d), lambda n, p, j, kb: (n, j, p)),
             pl.BlockSpec((None, bk, kvb * d),
@@ -653,13 +803,13 @@ def _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, causal, interpret):
             pltpu.VMEM((hp, bq, 1), jnp.float32),
         ],
         interpret=interpret,
-        name=_name("fwd", causal, d, dv, group),
-        metadata=_metadata(d, dv, g, q, k),
+        name=_name("fwd", mask, d, dv, group),
+        metadata=_metadata(d, dv, g, q, k, mask),
     )(q, k, v, bias)
     return out, lse
 
 
-def _fwd_one_tile(q, k, v, bias, d, dv, causal, interpret):
+def _fwd_one_tile(q, k, v, bias, d, dv, mask, interpret):
     b, s, h, g, hp, _bq, _bk = _geometry(q, d, dv, q.shape[1], q.shape[1],
                                          budget_mb=6.0)
     hpb = h // hp
@@ -668,7 +818,7 @@ def _fwd_one_tile(q, k, v, bias, d, dv, causal, interpret):
     out, lse = pl.pallas_call(
         functools.partial(
             _fwd_one_tile_kernel, scale=1.0 / (d ** 0.5), d=d, dv=dv, g=g,
-            causal=causal,
+            mask=mask,
         ),
         grid=(b, hpb),
         in_specs=[
@@ -684,10 +834,11 @@ def _fwd_one_tile(q, k, v, bias, d, dv, causal, interpret):
             jax.ShapeDtypeStruct((b * h, 1, s), jnp.float32),
         ],
         interpret=interpret,
-        name=_name("fwd", causal, d, dv),
+        name=_name("fwd", mask, d, dv),
         # the same name in a device trace; a lowered module tells the two
         # forward forms apart by this (tools/tpu_aot.py counts them)
-        metadata={"form": "one_tile", **(_lanes(d, dv, g) or {})},
+        metadata={"form": "one_tile", **(_metadata(d, dv, g, q, k, mask)
+                                         or {})},
     )(q, k, v, bias)
     return out, lse
 
@@ -737,9 +888,10 @@ def _backward_heads(refs, bias_ref, lse_ref, h0, cols, vcols, mask, *,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
-               dq_ref, dq_acc_ref, *, scale, d, dv, g, causal, group=1):
-    kb = pl.program_id(3)
+               dq_ref, dq_acc_ref, *, scale, d, dv, g, mask, seq, group=1):
+    kb = pl.program_id(3)  # a step of the key sweep, not yet a tile
     nk = pl.num_programs(3)
+    qi, bq, bk = pl.program_id(2), q_ref.shape[0], k_ref.shape[0]
     program = pl.program_id(1) if group > 1 else None
 
     @pl.when(kb == 0)
@@ -764,8 +916,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
             for at, total in zip(dq_at, dq):
                 dq_acc_ref[:, at] = total
 
-    _for_tile(causal, pl.program_id(2), kb, q_ref.shape[0], k_ref.shape[0],
-              tile)
+    _for_tile(mask, qi, _from(_first_k_tile(mask, qi, bq, bk), kb), bq, bk,
+              seq, tile)
 
     @pl.when(kb == nk - 1)
     def _flush():
@@ -774,12 +926,13 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
                 dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, scale, d, dv, g,
-                causal, group=1):
+                mask, seq, group=1):
     # grid (B, across the kv width, kv tile, [the kv block's query programs
-    # — a grouped call's ``members`` —], query tile): the accumulators live
-    # over everything inside the kv tile's axis
+    # — a grouped call's ``members`` —], a step of the query sweep): the
+    # accumulators live over everything inside the kv tile's axis
     qb = pl.program_id(3 if group == 1 else 4)
     nq = pl.num_programs(3 if group == 1 else 4)
+    ki, bq, bk = pl.program_id(2), q_ref.shape[0], k_ref.shape[0]
     if group > 1:
         member, members = pl.program_id(3), pl.num_programs(3)
         program = pl.program_id(1) * members + member
@@ -823,8 +976,9 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
             for at, total in zip(dv_at, dv_):
                 dv_acc_ref[:, at] = total
 
-    _for_tile(causal, qb, pl.program_id(2), q_ref.shape[0], k_ref.shape[0],
-              tile)
+    # a band's sweep starts at the key tile's first query tile
+    first = 0 if mask.band is None else _first_q_tile(ki, bq, bk)
+    _for_tile(mask, _from(first, qb), ki, bq, bk, seq, tile)
 
     @pl.when(of_group(qb == nq - 1, -1))
     def _flush():
@@ -834,12 +988,12 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
 
 def _dqkv_fused_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref,
                        o_ref, dq_ref, dk_ref, dv_ref, *, scale, d, dv, g,
-                       causal):
+                       mask):
     """Single-block backward: when one (Bq, Bk) tile covers the whole
     sequence, dq/dk/dv share ONE score/prob computation and one set of
     input DMAs instead of recomputing them in two kernels."""
     s = q_ref.shape[0]
-    mask = _tile_mask(0, 0, s, s) if causal else None
+    mask = _tile_mask(mask, 0, 0, s, s) if mask.causal else None
     for h0, cols, vcols in _column_blocks(q_ref.shape[-1], g, d, dv):
         dq, dk = ([None] * len(_segments(d, g)) for _ in range(2))
         dv_ = [None] * len(_segments(dv, g))
@@ -858,20 +1012,20 @@ def _dqkv_fused_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref,
                 ref[:, at] = total.astype(ref.dtype)
 
 
-def _bwd(q, k, v, bias, lse, do, out, d, dv, block_q, block_k, causal,
+def _bwd(q, k, v, bias, lse, do, out, d, dv, block_q, block_k, mask,
          interpret):
     if k.shape != q.shape:
         return _bwd_grouped(q, k, v, bias, lse, do, out, d, block_q, block_k,
-                            causal, interpret)
+                            mask, interpret)
     if _one_tile(q.shape[1], block_q, block_k):
-        return _bwd_fused(q, k, v, bias, lse, do, out, d, dv, causal,
+        return _bwd_fused(q, k, v, bias, lse, do, out, d, dv, mask,
                           interpret)
     # bwd transients per head are ~3x the fwd's (s, p, dp, ds live at once)
     b, s, h, g, hp, bq, bk = _geometry(q, d, dv, block_q, block_k,
                                        budget_mb=4.0)
-    hpb = h // hp
-    kernel_args = dict(scale=1.0 / (d ** 0.5), d=d, dv=dv, g=g,
-                       causal=causal)
+    hpb, nq, nk = h // hp, s // bq, s // bk
+    kernel_args = dict(scale=1.0 / (d ** 0.5), d=d, dv=dv, g=g, mask=mask,
+                       seq=s)
 
     # grid (B, programs across the width, outer S block, inner S block):
     # each spec says which of the two S positions it follows, and which of
@@ -894,28 +1048,28 @@ def _bwd(q, k, v, bias, lse, do, out, d, dv, block_q, block_k, causal,
         return x
 
     # the inner position of a causal grid stays on the tiles its outer one
-    # needs (see "causal tiles"): nothing above the diagonal is fetched
+    # needs (see "causal tiles"): nothing outside the mask is fetched
     def inner_k(x, y):
-        return jnp.minimum(y, _last_k_tile(x, bq, bk)) if causal else y
+        return _k_tile(mask, x, y, bq, bk)
 
     def inner_q(x, y):
-        return jnp.maximum(y, _first_q_tile(x, bq, bk)) if causal else y
+        return _q_tile(mask, x, y, bq, bk, nq)
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **kernel_args),
-        grid=(b, hpb, s // bq, s // bk),
+        grid=(b, hpb, nq, _sweep(mask, nq, nk, bq, bk, "k")),
         in_specs=in_specs(q_at=outer, k_at=inner_k),
         out_specs=wide(bq, outer),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, hp * d), jnp.float32)],
         interpret=interpret,
-        name=_name("bwd_dq", causal, d, dv),
-        metadata=_lanes(d, dv, g),
+        name=_name("bwd_dq", mask, d, dv),
+        metadata=_metadata(d, dv, g, q, k, mask),
     )(q, k, v, bias, lse, do, out)
 
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, **kernel_args),
-        grid=(b, hpb, s // bk, s // bq),
+        grid=(b, hpb, nk, _sweep(mask, nq, nk, bq, bk, "q")),
         in_specs=in_specs(q_at=inner_q, k_at=outer),
         out_specs=[wide(bk, outer), wide(bk, outer, dv)],
         out_shape=[
@@ -927,13 +1081,13 @@ def _bwd(q, k, v, bias, lse, do, out, d, dv, block_q, block_k, causal,
             pltpu.VMEM((bk, hp * dv), jnp.float32),
         ],
         interpret=interpret,
-        name=_name("bwd_dkv", causal, d, dv),
-        metadata=_lanes(d, dv, g),
+        name=_name("bwd_dkv", mask, d, dv),
+        metadata=_metadata(d, dv, g, q, k, mask),
     )(q, k, v, bias, lse, do, out)
     return dq, dk, dv
 
 
-def _bwd_grouped(q, k, v, bias, lse, do, out, d, block_q, block_k, causal,
+def _bwd_grouped(q, k, v, bias, lse, do, out, d, block_q, block_k, mask,
                  interpret):
     """The two-kernel backward with fewer kv heads than heads (see
     "grouped-query heads"): dq as ever, its k / v blocks the group's; dk and
@@ -942,16 +1096,17 @@ def _bwd_grouped(q, k, v, bias, lse, do, out, d, block_q, block_k, causal,
     b, s, h, g, hp, bq, bk = _geometry(q, d, d, block_q, block_k,
                                        budget_mb=4.0)
     group, kvb, hp = _grouped(q, k, d, d, g, hp)
-    hpb, members = h // hp, kvb * group // hp
-    kernel_args = dict(scale=1.0 / (d ** 0.5), d=d, dv=d, g=g,
-                       causal=causal, group=group)
-    names = dict(interpret=interpret, metadata=_metadata(d, d, g, q, k))
+    hpb, members, nq, nk = h // hp, kvb * group // hp, s // bq, s // bk
+    kernel_args = dict(scale=1.0 / (d ** 0.5), d=d, dv=d, g=g, mask=mask,
+                       seq=s, group=group)
+    names = dict(interpret=interpret,
+                 metadata=_metadata(d, d, g, q, k, mask))
 
     def last_k(j, kb):
-        return jnp.minimum(kb, _last_k_tile(j, bq, bk)) if causal else kb
+        return _k_tile(mask, j, kb, bq, bk)
 
     def first_q(kt, qt):
-        return jnp.maximum(qt, _first_q_tile(kt, bq, bk)) if causal else qt
+        return _q_tile(mask, kt, qt, bq, bk, nq)
 
     def kv_at(p):
         return p * hp // group // kvb
@@ -962,7 +1117,7 @@ def _bwd_grouped(q, k, v, bias, lse, do, out, d, block_q, block_k, causal,
     )
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **kernel_args),
-        grid=(b, hpb, s // bq, s // bk),
+        grid=(b, hpb, nq, _sweep(mask, nq, nk, bq, bk, "k")),
         in_specs=[
             q_side, kv_side, kv_side,
             pl.BlockSpec((None, 1, bk),
@@ -974,7 +1129,7 @@ def _bwd_grouped(q, k, v, bias, lse, do, out, d, block_q, block_k, causal,
         out_specs=q_side,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, hp * d), jnp.float32)],
-        name=_name("bwd_dq", causal, d, d, group), **names,
+        name=_name("bwd_dq", mask, d, d, group), **names,
     )(q, k, v, bias, lse, do, out)
 
     # (B, kv blocks, kv tile, query programs of the block, query tile)
@@ -987,7 +1142,8 @@ def _bwd_grouped(q, k, v, bias, lse, do, out, d, block_q, block_k, causal,
     )
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, **kernel_args),
-        grid=(b, h // hp // members, s // bk, members, s // bq),
+        grid=(b, h // hp // members, nk, members,
+              _sweep(mask, nq, nk, bq, bk, "q")),
         in_specs=[
             q_side, kv_side, kv_side,
             pl.BlockSpec((None, 1, bk), lambda n, c, kt, m, qt: (n, 0, kt)),
@@ -1005,12 +1161,12 @@ def _bwd_grouped(q, k, v, bias, lse, do, out, d, block_q, block_k, causal,
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((bk, kvb * d), jnp.float32)] * 2,
-        name=_name("bwd_dkv", causal, d, d, group), **names,
+        name=_name("bwd_dkv", mask, d, d, group), **names,
     )(q, k, v, bias, lse, do, out)
     return dq, dk, dv
 
 
-def _bwd_fused(q, k, v, bias, lse, do, out, d, dv, causal, interpret):
+def _bwd_fused(q, k, v, bias, lse, do, out, d, dv, mask, interpret):
     # fused kernel holds s, p, dp, ds (~4 full tiles) at once per head
     b, s, h, g, hp, _bq, _bk = _geometry(q, d, dv, q.shape[1], q.shape[1],
                                          budget_mb=3.0)
@@ -1020,7 +1176,7 @@ def _bwd_fused(q, k, v, bias, lse, do, out, d, dv, causal, interpret):
     dq, dk, dv_ = pl.pallas_call(
         functools.partial(
             _dqkv_fused_kernel, scale=1.0 / (d ** 0.5), d=d, dv=dv, g=g,
-            causal=causal,
+            mask=mask,
         ),
         grid=(b, hpb),
         in_specs=[
@@ -1036,8 +1192,8 @@ def _bwd_fused(q, k, v, bias, lse, do, out, d, dv, causal, interpret):
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=interpret,
-        name=_name("bwd_fused", causal, d, dv),
-        metadata=_lanes(d, dv, g),
+        name=_name("bwd_fused", mask, d, dv),
+        metadata=_metadata(d, dv, g, q, k, mask),
     )(q, k, v, bias, lse, do, out)
     return dq, dk, dv_
 
@@ -1046,24 +1202,24 @@ def _bwd_fused(q, k, v, bias, lse, do, out, d, dv, causal, interpret):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _flash(q, k, v, bias, d, dv, block_q, block_k, causal, interpret):
-    out, _lse = _fwd(q, k, v, bias, d, dv, block_q, block_k, causal,
+def _flash(q, k, v, bias, d, dv, block_q, block_k, mask, interpret):
+    out, _lse = _fwd(q, k, v, bias, d, dv, block_q, block_k, mask,
                      interpret)
     return out
 
 
-def _flash_fwd(q, k, v, bias, d, dv, block_q, block_k, causal, interpret):
+def _flash_fwd(q, k, v, bias, d, dv, block_q, block_k, mask, interpret):
     # ``out`` is what the remat policies save per layer (a Pallas output),
     # in the layout the out-projection reads: no lane padding at any D
-    out, lse = _fwd(q, k, v, bias, d, dv, block_q, block_k, causal,
+    out, lse = _fwd(q, k, v, bias, d, dv, block_q, block_k, mask,
                     interpret)
     return out, (q, k, v, bias, out, lse)
 
 
-def _flash_bwd(d, dv, block_q, block_k, causal, interpret, residuals, g):
+def _flash_bwd(d, dv, block_q, block_k, mask, interpret, residuals, g):
     q, k, v, bias, out, lse = residuals
     dq, dk, dv_ = _bwd(q, k, v, bias, lse, g, out, d, dv, block_q, block_k,
-                       causal, interpret)
+                       mask, interpret)
     # the mask bias is non-differentiable input
     return dq, dk, dv_, jnp.zeros_like(bias)
 
@@ -1071,7 +1227,7 @@ def _flash_bwd(d, dv, block_q, block_k, causal, interpret, residuals, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _flash_local(q, k, v, bias, d, dv, block_q, block_k, causal, interpret):
+def _flash_local(q, k, v, bias, d, dv, block_q, block_k, mask, interpret):
     """The op on [B, S, H·D] operands as ONE device sees them (the whole
     arrays off-mesh, this device's batch/head shard under shard_map)."""
     # named as the dense layers give them, which is what the kernels read:
@@ -1079,7 +1235,7 @@ def _flash_local(q, k, v, bias, d, dv, block_q, block_k, causal, interpret):
     # consumes, in the one layout the stash and both kernels share
     q, k, v = (checkpoint_name(x, "flash_qkv") for x in (q, k, v))
     bias = bias[:, None, :].astype(jnp.float32)  # [B, 1, S] row per sample
-    return _flash(q, k, v, bias, d, dv, block_q, block_k, causal, interpret)
+    return _flash(q, k, v, bias, d, dv, block_q, block_k, mask, interpret)
 
 
 def flash_attention(
@@ -1092,6 +1248,7 @@ def flash_attention(
     interpret: Optional[bool] = None,
     mesh: Optional[Mesh] = None,
     causal: bool = False,
+    band: Optional[int] = None,
 ) -> jnp.ndarray:
     """Exact fused attention; drop-in for dense/blockwise attention.
 
@@ -1102,14 +1259,22 @@ def flash_attention(
     Mosaic-legal. ``mesh``: the device mesh the caller's jit spans — the op
     then runs per shard under ``shard_map`` (see module docstring).
     ``causal``: a decoder's mask, inside the kernels (query i sees keys
-    0..i; the KV bias still applies on top). ``v`` may have a head width of
-    its own (latent attention: q and k 192 wide, v and the result 128): the
+    0..i; the KV bias still applies on top). ``band``: with ``causal``, a
+    sliding window — query i sees keys i-band+1 .. i, its own position
+    counted; tiles outside the band are neither grid steps nor fetched, the
+    kernels are named ``flash_band_*``, and a band no shorter than the
+    sequence IS the causal call (the same kernels, names and bits). ``v``
+    may have a head width of its own (latent attention: q and k 192 wide, v and the result 128): the
     same kernels with two column-block widths, scores scaled by
     1/sqrt(q's width), named ``flash_mla_*`` in a device trace; nothing is
     padded. ``k`` and ``v`` may have FEWER heads than ``q`` (grouped-query
     attention: each kv head serves H / H_kv adjacent query heads): they are
     read at their own width, dk / dv are summed over a group inside the
-    kernel, named ``flash_gqa_*``; head widths 64 and 128.
+    kernel, named ``flash_gqa_*``; head widths 64 and 128. A group that is
+    no power of two (28 heads over 4: seven) gets a whole group a program
+    — its kv head fetched once, dk / dv summed inside the program — and is
+    taken at head width 128 alone (at 64 two heads share a lane tile and
+    a kv head: the group must be even).
     """
     if interpret is None:
         interpret = pallas_interpret()
@@ -1119,7 +1284,7 @@ def flash_attention(
         bias = jnp.zeros((b, s), jnp.float32)
     op = functools.partial(
         _flash_local, d=d, dv=dv, block_q=block_q, block_k=block_k,
-        causal=causal, interpret=interpret,
+        mask=_mask_of(causal, band, s), interpret=interpret,
     )
     if mesh is not None:
         # heads over "model": a shard's columns are its (H/tp)·D

@@ -23,6 +23,10 @@ annotations alone place the communication.
 model and for ``models/lfm2_moe.py``). Scores are sigmoids
 over ALL experts; a correction bias enters the CHOICE of the top k and not
 their weights; the weights are the chosen scores renormalised and scaled.
+(``route_top_k_softmax`` is the other published rule, ``models/
+smallthinker.py``'s: the top k of the logits, a softmax over the chosen,
+no bias; its experts gate with a ReLU — ``routed_experts(activation=
+"relu")`` — where the others gate with a SiLU.)
 The layer is TOLD which experts it holds (``held = (first, count)``: one
 chip's share of an expert-parallel deployment): it routes over all of them
 and computes its own part — slots that chose an absent expert contribute
@@ -184,6 +188,14 @@ def route_top_k(scores, bias, top_k: int, scale: float, eps: float = 1e-20):
     return choice, weights * scale
 
 
+def route_top_k_softmax(logits, top_k: int):
+    """(choice [T, k] int32, weights [T, k] float32) from router LOGITS
+    [T, E] (float32): the top k of the logits, a softmax over the chosen
+    ones alone — no bias, no scale; the weights sum to 1."""
+    picked, choice = jax.lax.top_k(logits, top_k)
+    return choice, jax.nn.softmax(picked, axis=-1)
+
+
 def expert_load(choice, num_experts: int):
     """[E] float32: each expert's share of the routed (token, slot) pairs."""
     counts = jnp.zeros((num_experts,), jnp.float32).at[
@@ -254,12 +266,29 @@ def _tile_plan(choice, held: Tuple[int, int], tile: int):
     return row_slot, tile_expert, tiles, dropped
 
 
-def _tile_forward(x, gate, up, down, tokens):
+def _silu_bwd(g, u, d_hidden):
+    sig = jax.nn.sigmoid(g)
+    return d_hidden * u * sig * (1.0 + g * (1.0 - sig)), d_hidden * g * sig
+
+
+def _relu_bwd(g, u, d_hidden):
+    on = g > 0
+    return jnp.where(on, d_hidden * u, 0.0), jnp.where(on, d_hidden * g, 0.0)
+
+
+# a gated expert's activation: act, and (d g, d u) of hidden = act(g) · u
+ACTIVATIONS = {
+    "silu": (jax.nn.silu, _silu_bwd),  # SwiGLU
+    "relu": (jax.nn.relu, _relu_bwd),  # ReGLU
+}
+
+
+def _tile_forward(x, gate, up, down, tokens, activation):
     """One tile through its expert: (rows, gate·x, up·x, hidden, out)."""
     rows = x.at[tokens].get(mode="promise_in_bounds")
     g = jnp.dot(rows, gate, preferred_element_type=jnp.float32)
     u = jnp.dot(rows, up, preferred_element_type=jnp.float32)
-    hidden = (jax.nn.silu(g) * u).astype(x.dtype)
+    hidden = (ACTIVATIONS[activation][0](g) * u).astype(x.dtype)
     out = jnp.dot(hidden, down, preferred_element_type=jnp.float32)
     return rows, g, u, hidden, out
 
@@ -274,25 +303,26 @@ def _tile_operands(t, tile, row_token, row_weight, tile_expert, weights):
     ), expert
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(9,))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10))
 def _grouped_swiglu(x, row_weight, gate, up, down, sinks, row_token,
-                    tile_expert, tiles, tile):
+                    tile_expert, tiles, tile, activation):
     """``sinks``: None, or float32 (gate, up, down)-shaped buffers the
-    forward ignores and the backward accumulates into."""
+    forward ignores and the backward accumulates into. ``activation``: the
+    gate's, a name of ``ACTIVATIONS`` (the function keeps its first name)."""
     out, _ = _grouped_swiglu_fwd(
         x, row_weight, gate, up, down, sinks, row_token, tile_expert, tiles,
-        tile,
+        tile, activation,
     )
     return out
 
 
 def _grouped_swiglu_fwd(x, row_weight, gate, up, down, sinks, row_token,
-                        tile_expert, tiles, tile):
+                        tile_expert, tiles, tile, activation):
     def body(t, total):
         tokens, scale, weights, _e = _tile_operands(
             t, tile, row_token, row_weight, tile_expert, (gate, up, down)
         )
-        out = _tile_forward(x, *weights, tokens)[-1]
+        out = _tile_forward(x, *weights, tokens, activation)[-1]
         return total.at[tokens].add(out * scale[:, None])
 
     with jax.named_scope("moe_routed"):
@@ -303,7 +333,7 @@ def _grouped_swiglu_fwd(x, row_weight, gate, up, down, sinks, row_token,
                    tile_expert, tiles)
 
 
-def _grouped_swiglu_bwd(tile, residuals, d_total):
+def _grouped_swiglu_bwd(tile, activation, residuals, d_total):
     (x, row_weight, gate, up, down, sinks, row_token, tile_expert,
      tiles) = residuals
     held = (gate, up, down)
@@ -314,7 +344,7 @@ def _grouped_swiglu_bwd(tile, residuals, d_total):
             t, tile, row_token, row_weight, tile_expert, held
         )
         rows, g, u, hidden, out = _tile_forward(
-            x, w_gate, w_up, w_down, tokens
+            x, w_gate, w_up, w_down, tokens, activation
         )
         d_scaled = d_total.at[tokens].get(mode="promise_in_bounds")
         d_weight = jax.lax.dynamic_update_slice(
@@ -325,9 +355,10 @@ def _grouped_swiglu_bwd(tile, residuals, d_total):
             d_out, w_down, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        sig = jax.nn.sigmoid(g)
-        d_u = (d_hidden * g * sig).astype(x.dtype)
-        d_g = (d_hidden * u * sig * (1.0 + g * (1.0 - sig))).astype(x.dtype)
+        d_g, d_u = (
+            d.astype(x.dtype)
+            for d in ACTIVATIONS[activation][1](g, u, d_hidden)
+        )
         d_rows = jax.lax.dot_general(
             d_g, w_gate, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -380,11 +411,15 @@ _grouped_swiglu.defvjp(_grouped_swiglu_fwd, _grouped_swiglu_bwd)
 
 
 def routed_experts(x, choice, weights, gate, up, down,
-                   held: Tuple[int, int], tile: int = 256, grad_sinks=None):
-    """The held experts' part of Σ_{e in choice} w_e · SwiGLU_e(x).
+                   held: Tuple[int, int], tile: int = 256, grad_sinks=None,
+                   activation: str = "silu"):
+    """The held experts' part of Σ_{e in choice} w_e · GLU_e(x), with
+    GLU_e(x) = down_e(act(gate_e x) ⊙ up_e x) and ``activation`` the gate's:
+    "silu" (SwiGLU) or "relu" (ReGLU).
 
     ``x`` [T, H] in the compute dtype; ``choice`` / ``weights`` [T, k] from
-    ``route_top_k``; ``gate`` / ``up`` [n, H, F] and ``down`` [n, F, H] the
+    ``route_top_k`` or ``route_top_k_softmax``; ``gate`` / ``up`` [n, H, F]
+    and ``down`` [n, F, H] the
     HELD experts' matrices in the compute dtype, expert ``held[0] + i`` at
     index i; ``grad_sinks``: None, or three float32 buffers of the held
     matrices' shapes whose COTANGENT is ``sink + d matrix`` while the
@@ -403,7 +438,7 @@ def routed_experts(x, choice, weights, gate, up, down,
     y = _grouped_swiglu(
         x, row_weight, gate, up, down,
         None if grad_sinks is None else tuple(grad_sinks),
-        row_token, tile_expert, tiles, tile,
+        row_token, tile_expert, tiles, tile, activation,
     )
     return y, {
         "grad_sink_leaves": jnp.float32(

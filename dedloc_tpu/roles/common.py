@@ -46,6 +46,13 @@ from dedloc_tpu.models.ouro import (
     ouro_train_tflops_per_sample,
     ouro_weight_decay_mask,
 )
+from dedloc_tpu.models.smallthinker import (
+    SmallThinkerConfig,
+    SmallThinkerForCausalLM,
+    smallthinker_loss,
+    smallthinker_train_tflops_per_sample,
+    smallthinker_weight_decay_mask,
+)
 from dedloc_tpu.optim import (
     albert_weight_decay_mask,
     lamb,
@@ -205,10 +212,23 @@ LFM2_MOE = dataclasses.replace(
     sign_step_mask=lfm2_moe_sign_step_mask,
     sign_step=Lfm2MoeConfig.bias_update_speed,
 )
+SMALLTHINKER = dataclasses.replace(
+    DEEPSEEK_V3,  # the same source, counter and sinks; no bias: no sign rule
+    config=SmallThinkerConfig, module=SmallThinkerForCausalLM,
+    loss=_without_rng(smallthinker_loss),
+    tflops_per_sample=smallthinker_train_tflops_per_sample,
+    weight_decay_mask=smallthinker_weight_decay_mask,
+    step_gauges=(
+        "moe.load_max_over_mean", "moe.local_slot_share",
+        "moe.grad_sink_leaves", "attn.band_tile_share",
+    ),
+    sign_step_mask=None, sign_step=0.0,
+)
 MODEL_FAMILIES: Dict[str, ModelFamily] = {
     "tiny": ALBERT, "large": ALBERT, "ouro_tiny": OURO, "ouro_2p6b": OURO,
     "kanana2_tiny": DEEPSEEK_V3, "kanana2_30b_a3b": DEEPSEEK_V3,
     "lfm2_tiny": LFM2_MOE, "lfm2_24b_a2b": LFM2_MOE,
+    "smallthinker_tiny": SMALLTHINKER, "smallthinker_21b_a3b": SMALLTHINKER,
 }
 
 
@@ -223,7 +243,7 @@ def model_family(model) -> ModelFamily:
             )
         return MODEL_FAMILIES[model]
     cfg = getattr(model, "cfg", model)
-    for family in (ALBERT, OURO, DEEPSEEK_V3, LFM2_MOE):
+    for family in (ALBERT, OURO, DEEPSEEK_V3, LFM2_MOE, SMALLTHINKER):
         if isinstance(cfg, family.config):
             return family
     raise TypeError(f"no model family for {type(cfg).__name__}")

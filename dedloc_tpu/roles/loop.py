@@ -155,7 +155,7 @@ def run_boundary_loop(
                             for key, value in values.items():
                                 srec.attrs[key] = float(value)
                                 if tele is not None:
-                                    tele.gauge(key).set(float(value))  # dedlint: emits=gauge:lm.exit_prob.*,gauge:lm.loss.*,gauge:moe.load_max_over_mean.*,gauge:moe.local_slot_share,gauge:moe.bias_abs_max,gauge:moe.grad_sink_leaves
+                                    tele.gauge(key).set(float(value))  # dedlint: emits=gauge:lm.exit_prob.*,gauge:lm.loss.*,gauge:moe.load_max_over_mean.*,gauge:moe.local_slot_share,gauge:moe.bias_abs_max,gauge:moe.grad_sink_leaves,gauge:attn.band_tile_share
                         for name in model.step_counters:
                             srec.attrs[name] = float(sums[name])
                             if tele is not None:

@@ -18,6 +18,7 @@ ALLREDUCE_LINK = "allreduce.link"
 ALLREDUCE_ROUND = "allreduce.round"
 ALLREDUCE_ROUNDS = "allreduce.rounds"
 ALLREDUCE_STRAGGLERS = "allreduce.stragglers"
+ATTN_BAND_TILE_SHARE = "attn.band_tile_share"
 AVG_ROUND = "avg.round"
 AVG_TOPOLOGY_FALLBACK = "avg.topology.fallback"
 AVG_TOPOLOGY_FALLBACKS = "avg.topology.fallbacks"
@@ -231,6 +232,7 @@ COUNTERS = frozenset({
     "watch.rollbacks",
 })
 GAUGES = frozenset({
+    "attn.band_tile_share",
     "expert.load_ewma",
     "moe.bias_abs_max",
     "moe.grad_sink_leaves",

@@ -377,3 +377,35 @@ def test_lfm2_accumulate_step_keeps_what_its_backward_reads():
     # and the scratch those buffers took is gone (1,170,841,600 before)
     assert row["expert_grad_passes"] == {"adds": 0, "zero_fills": 0}
     assert row["memory"]["temp_bytes"] <= 1_170_841_600
+
+
+def test_smallthinker_accumulate_step_takes_the_band_and_a_group_of_seven():
+    """SmallThinker-21BA3B at the cell's cut (one period: a global NoPE
+    layer and three band-4096 RoPE layers; 1 row of 16,384), compiled for a
+    v5e alone and inside its accumulate_step: the band kernels carry their
+    band and head counts (28 over 4: a whole group of seven a program gets
+    through Mosaic inside the default scoped VMEM), the global layer's are
+    the grouped causal kernels with the metadata they always had; under
+    remat ``kernel_outputs`` no kernel is replayed — 3 + 1 sites a kind;
+    the ReLU-gated tile loop's backward sums into the accumulator's twelve
+    expert leaves (gradient sinks); and the program's scratch beside 28
+    bytes a parameter of state with a draining snapshot stays under the
+    15.3 GB line this tree's cells are sized under."""
+    rows = _tpu_aot("band_kernels", "smallthinker_accumulate_step")
+    heads = {"heads": 28, "kv_heads": 4}
+    band = dict(heads, band=4096)
+    for row in rows.values():
+        assert row["flash_windows"] == {
+            "flash_band_fwd": band, "flash_band_bwd_dq": band,
+            "flash_band_bwd_dkv": band, "flash_gqa_fwd": heads,
+            "flash_gqa_bwd_dq": heads, "flash_gqa_bwd_dkv": heads,
+        }
+    row = rows["smallthinker_accumulate_step"]
+    assert row["kernel_calls"] == {
+        "flash_band_fwd": 3, "flash_band_bwd_dq": 3, "flash_band_bwd_dkv": 3,
+        "flash_gqa_fwd": 1, "flash_gqa_bwd_dq": 1, "flash_gqa_bwd_dkv": 1,
+    }
+    assert row["tpu_custom_calls"] == 12
+    assert row["flash_fwd_forms"] == {"one_tile": 0, "tiles": 4}
+    assert row["expert_grad_passes"] == {"adds": 0, "zero_fills": 0}
+    assert 370_547_200 * 28 + row["memory"]["temp_bytes"] <= 15.3e9

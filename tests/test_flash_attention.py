@@ -182,7 +182,11 @@ def test_one_tile_forward_is_the_tiled_kernel_at_one_tile(rng, h, d, dv,
     float32, with a sample whose every key is masked (-1e30: the row's
     softmax is uniform) and one whose bias is -inf (the row comes out 0 and
     finite from both)."""
-    from dedloc_tpu.ops.flash_attention import _fwd_one_tile, _fwd_tiled
+    from dedloc_tpu.ops.flash_attention import (
+        _fwd_one_tile,
+        _fwd_tiled,
+        _Mask,
+    )
 
     b, s = 3, 64
     bias = np.zeros((b, 1, s), np.float32)
@@ -196,9 +200,9 @@ def test_one_tile_forward_is_the_tiled_kernel_at_one_tile(rng, h, d, dv,
             jnp.asarray(rng.standard_normal((b, s, h * width)), dtype)
             for width in (d, d, dv)
         )
-        out, lse = _fwd_one_tile(q, k, v, bias, d, dv, causal, True)
+        out, lse = _fwd_one_tile(q, k, v, bias, d, dv, _Mask(causal), True)
         want_out, want_lse = _fwd_tiled(
-            q, k, v, bias, d, dv, s, s, causal, True
+            q, k, v, bias, d, dv, s, s, _Mask(causal), True
         )
         assert out.dtype == dtype and lse.dtype == jnp.float32
         np.testing.assert_array_equal(

@@ -11,10 +11,24 @@ the same calls, read as the benchmark reads its ``flash_gqa_*_roofline`` and
 
     chiprun --chips 1 -- python tools/chip_gqa_check.py
 
+Another shape and a BAND (a sliding window inside the kernels, named
+``flash_band_*`` and held to ``benchmark/flops_smallthinker.py``'s count of
+the tiles inside the band) by flags — SmallThinker's 28 query heads over 4 kv
+heads of 128 at S=16,384, its band of 4,096 and the global layer's full
+triangle, each also with ONE query head a program in place of the whole
+group of seven the kernels take (the question PR 36 settled on the chip):
+
+    chiprun --chips 1 -- python tools/chip_gqa_check.py --heads 28 \
+        --kv-heads 4 --head-dim 128 --seq 16384 --band 4096 none \
+        --one-head-programs --conv 0
+
 Prints one JSON line; exit code 1 if an error exceeds 0.02 relative L2
 (bf16 rounding of the operands alone is ~0.004)."""
 from __future__ import annotations
 
+import argparse
+import contextlib
+import importlib
 import json
 import os
 import statistics
@@ -28,27 +42,31 @@ import jax.numpy as jnp
 
 from benchmark.flops import roofline_seconds
 from benchmark.flops_lfm2 import conv_kernel_cost, gqa_kernel_cost
+from benchmark.flops_smallthinker import band_kernel_cost
 from benchmark.peaks import chip_peaks
 from benchmark.reducers.conv_kernel_roofline import on_chip_tensors
 from benchmark.trace import OPS, load_xplane, op_name
 from dedloc_tpu.ops.flash_attention import flash_attention
 from dedloc_tpu.ops.short_conv import short_conv, short_conv_reference
 
-B, S, H, KV, D, HIDDEN = 1, 4096, 32, 8, 64, 2048
-GQA = ("flash_gqa_fwd", "flash_gqa_bwd_dq", "flash_gqa_bwd_dkv")
+B, HIDDEN = 1, 2048
+KERNELS = ("fwd", "bwd_dq", "bwd_dkv")
 CONV = ("short_conv_fwd", "short_conv_bwd")
 
 
-def cost(kernel: str, on_chip=frozenset()):
-    """(FLOPs, bytes) of one call at the shapes checked here."""
-    if kernel in GQA:
-        return gqa_kernel_cost(kernel, B, H, KV, S, D, 512, 512)
-    return conv_kernel_cost(kernel, B, S, HIDDEN, on_chip=on_chip)
+def attention_cost(kernel: str, shape, band):
+    """(FLOPs, bytes) of one ``flash_gqa_*`` / ``flash_band_*`` call."""
+    s, h, kv, d = shape
+    if band is None:
+        return gqa_kernel_cost(f"flash_gqa_{kernel}", B, h, kv, s, d, 512, 512)
+    return band_kernel_cost(
+        f"flash_band_{kernel}", B, h, kv, s, d, 512, 512, band
+    )
 
 
-def device_times(run, calls: int = 10) -> dict:
-    """Per kernel: median device ms of a call over a traced window of
-    ``calls`` forward + backward passes, and its share of the roofline."""
+def traced_ops(run, calls: int = 10):
+    """[(op name, device seconds, HLO text)] of a traced window of ``calls``
+    executions of ``run``."""
     with tempfile.TemporaryDirectory() as trace_dir:
         with jax.profiler.trace(trace_dir):
             for _ in range(calls):
@@ -57,15 +75,20 @@ def device_times(run, calls: int = 10) -> dict:
         trace = load_xplane(trace_dir)
     # under a plain jit(grad) the trace names a kernel's op by JAX's name
     # stack around the kernel's name
-    ops = [
+    return [
         (op_name(name), duration / 1e9, name) for lines in trace.values()
         for name, _start, duration in lines.get(OPS, [])
     ]
+
+
+def device_times(ops, kernels: dict) -> dict:
+    """Per kernel of ``kernels`` (name -> cost(on-chip tensors)): median
+    device ms of a call among ``ops`` and its share of the roofline."""
     if not ops:
         return {}  # off the chip: no device plane to read
     peaks = chip_peaks(jax.devices()[0].device_kind)
     out = {}
-    for kernel in GQA + CONV:
+    for kernel, cost in kernels.items():
         events = [(d, text) for name, d, text in ops if kernel in name]
         if not events:
             continue
@@ -75,26 +98,54 @@ def device_times(run, calls: int = 10) -> dict:
         on_chip = sorted(
             on_chip_tensors(kernel, events[0][1]) if kernel in CONV else ()
         )
-        least, which = roofline_seconds(
-            *cost(kernel, frozenset(on_chip)), peaks
-        )
+        least, which = roofline_seconds(*cost(frozenset(on_chip)), peaks)
         out[kernel] = {
             "calls": len(events), "device_ms": median * 1e3,
             "roofline_pct": 100.0 * least / median, "bound": which,
             "on_chip": on_chip,
         }
     if not out:
-        print(f"no {GQA + CONV} among the traced ops: "
+        print(f"no {sorted(kernels)} among the traced ops: "
               f"{sorted({name for name, _d, _t in ops})}", file=sys.stderr)
     return out
 
 
-def dense(q, k, v):
-    with jax.default_matmul_precision("highest"):
-        k, v = (jnp.repeat(x, H // KV, axis=2) for x in (k, v))
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(jnp.float32(D))
-        s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -jnp.inf)
-        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+def dense(q, k, v, band):
+    """Masked attention in float32, a query head at a time under
+    ``jax.checkpoint`` (28 heads of 16,384 x 16,384 scores do not fit)."""
+    s, group = q.shape[1], q.shape[2] // k.shape[2]
+    i = jnp.arange(s)
+    seen = i[None, :] <= i[:, None]
+    if band is not None:
+        seen &= i[:, None] - i[None, :] < band
+
+    @jax.checkpoint
+    def head(q, k, v):  # [S, D] each
+        with jax.default_matmul_precision("highest"):
+            x = jnp.where(seen, q @ k.T / jnp.sqrt(jnp.float32(q.shape[-1])),
+                          -jnp.inf)
+            return jax.nn.softmax(x, axis=-1) @ v
+
+    k, v = (jnp.repeat(x[0], group, axis=1) for x in (k, v))
+    out = jax.lax.map(
+        lambda x: head(*x), tuple(jnp.swapaxes(x, 0, 1) for x in (q[0], k, v))
+    )
+    return jnp.swapaxes(out, 0, 1)[None]
+
+
+@contextlib.contextmanager
+def one_head_programs():
+    """The plan ``_grouped`` would fall to by halving: ONE query head a
+    program, a group's programs sharing its kv block."""
+    fa = importlib.import_module("dedloc_tpu.ops.flash_attention")
+    whole = fa._grouped
+    fa._grouped = lambda q, k, d, dv, g, hp: (
+        q.shape[-1] // k.shape[-1], 1, 1
+    )
+    try:
+        yield
+    finally:
+        fa._grouped = whole
 
 
 def rel(a, b):
@@ -102,64 +153,116 @@ def rel(a, b):
     return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
 
 
-def main() -> int:
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--heads", type=int, default=32)
+    parser.add_argument("--kv-heads", type=int, default=8)
+    parser.add_argument("--head-dim", type=int, default=64)
+    parser.add_argument("--seq", type=int, default=4096)
+    parser.add_argument(
+        "--band", nargs="+", default=["none"],
+        help="bands to check, each a length or 'none' (the causal mask)",
+    )
+    parser.add_argument(
+        "--one-head-programs", action="store_true",
+        help="also time each band with one query head a program",
+    )
+    parser.add_argument("--conv", type=int, choices=(0, 1), default=1)
+    opts = parser.parse_args(argv)
+    shape = (opts.seq, opts.heads, opts.kv_heads, opts.head_dim)
+    S, H, KV, D = shape
+    bands = [None if b == "none" else int(b) for b in opts.band]
+
     keys = jax.random.split(jax.random.PRNGKey(0), 8)
     q, w = (jax.random.normal(x, (B, S, H, D), jnp.float32) for x in keys[:2])
     k, v = (
         jax.random.normal(x, (B, S, KV, D), jnp.float32) for x in keys[2:4]
     )
-    bcu = jax.random.normal(keys[4], (B, S, 3 * HIDDEN), jnp.float32)
-    taps = jax.random.normal(keys[5], (HIDDEN, 3), jnp.float32)
-    t = jax.random.normal(keys[6], (B, S, HIDDEN), jnp.float32)
     bf = lambda x: x.astype(jnp.bfloat16)  # noqa: E731
     # the references see the same bf16-rounded operands, in float32
     r = lambda x: bf(x).astype(jnp.float32)  # noqa: E731
 
-    def flash_loss(q, k, v):
-        out = flash_attention(q, k, v, causal=True)
-        return jnp.sum(out.astype(jnp.float32) * w), out
+    def attention(op, band):
+        def loss(q, k, v):
+            out = op(q, k, v, band)
+            return jnp.sum(out.astype(jnp.float32) * w), out
+        return jax.jit(jax.value_and_grad(loss, (0, 1, 2), has_aux=True))
 
-    def dense_loss(q, k, v):
-        out = dense(q, k, v)
-        return jnp.sum(out * w), out
+    def flash(q, k, v, band):
+        return flash_attention(q, k, v, causal=True, band=band)
 
-    def conv_loss(op):
-        def loss(bcu, taps):
-            out = op(bcu, taps)
-            return jnp.sum(out.astype(jnp.float32) * t), out
-        return loss
+    errors, kernels = {}, {}
+    for band in bands:
+        tag = "causal" if band is None else f"band_{band}"
+        step = attention(flash, band)
+        (_, out), grads = step(bf(q), bf(k), bf(v))
+        (_, ref_out), ref_grads = attention(dense, band)(r(q), r(k), r(v))
+        errors[f"{tag}.out"] = rel(out, ref_out)
+        errors.update({
+            f"{tag}.{n}": rel(g, rg)
+            for n, g, rg in zip(("dq", "dk", "dv"), grads, ref_grads)
+        })
+        del ref_out, ref_grads
+        family = "flash_gqa" if band is None else "flash_band"
+        costs = {
+            f"{family}_{kernel}": (
+                lambda _on_chip, kernel=kernel: attention_cost(
+                    kernel, shape, band
+                )
+            ) for kernel in KERNELS
+        }
+        kernels[tag] = device_times(
+            traced_ops(lambda: step(bf(q), bf(k), bf(v))), costs
+        )
+        if opts.one_head_programs:
+            with one_head_programs():
+                single = attention(flash, band)
+                (_, out1), grads1 = single(bf(q), bf(k), bf(v))
+                errors[f"{tag}.one_head.out"] = rel(out1, out)
+                errors.update({
+                    f"{tag}.one_head.{n}": rel(g1, g) for n, g1, g in
+                    zip(("dq", "dk", "dv"), grads1, grads)
+                })
+                kernels[f"{tag}.one_head"] = device_times(
+                    traced_ops(lambda: single(bf(q), bf(k), bf(v))), costs
+                )
 
-    flash = jax.jit(jax.value_and_grad(flash_loss, (0, 1, 2), has_aux=True))
-    conv = jax.jit(
-        jax.value_and_grad(conv_loss(short_conv), (0, 1), has_aux=True)
-    )
-    (_, out), grads = flash(bf(q), bf(k), bf(v))
-    (_, ref_out), ref_grads = jax.jit(
-        jax.value_and_grad(dense_loss, (0, 1, 2), has_aux=True)
-    )(r(q), r(k), r(v))
-    errors = {"out": rel(out, ref_out)}
-    errors.update({
-        n: rel(g, rg) for n, g, rg in zip(("dq", "dk", "dv"), grads, ref_grads)
-    })
-    (_, y), conv_grads = conv(bf(bcu), taps)
-    (_, ref_y), ref_conv_grads = jax.jit(jax.value_and_grad(
-        conv_loss(short_conv_reference), (0, 1), has_aux=True
-    ))(r(bcu), taps)
-    errors["conv_y"] = rel(y, ref_y)
-    errors.update({
-        n: rel(g, rg)
-        for n, g, rg in zip(("conv_d_bcu", "conv_dw"), conv_grads,
-                            ref_conv_grads)
-    })
+    if opts.conv:
+        bcu = jax.random.normal(keys[4], (B, S, 3 * HIDDEN), jnp.float32)
+        taps = jax.random.normal(keys[5], (HIDDEN, 3), jnp.float32)
+        t = jax.random.normal(keys[6], (B, S, HIDDEN), jnp.float32)
 
-    def both():
-        return flash(bf(q), bf(k), bf(v)), conv(bf(bcu), taps)
+        def conv_loss(op):
+            def loss(bcu, taps):
+                out = op(bcu, taps)
+                return jnp.sum(out.astype(jnp.float32) * t), out
+            return loss
 
-    jax.block_until_ready(both())
+        conv = jax.jit(
+            jax.value_and_grad(conv_loss(short_conv), (0, 1), has_aux=True)
+        )
+        (_, y), conv_grads = conv(bf(bcu), taps)
+        (_, ref_y), ref_conv_grads = jax.jit(jax.value_and_grad(
+            conv_loss(short_conv_reference), (0, 1), has_aux=True
+        ))(r(bcu), taps)
+        errors["conv_y"] = rel(y, ref_y)
+        errors.update({
+            n: rel(g, rg)
+            for n, g, rg in zip(("conv_d_bcu", "conv_dw"), conv_grads,
+                                ref_conv_grads)
+        })
+        kernels["conv"] = device_times(
+            traced_ops(lambda: conv(bf(bcu), taps)), {
+                kernel: (lambda on_chip, kernel=kernel: conv_kernel_cost(
+                    kernel, B, S, HIDDEN, on_chip=on_chip
+                )) for kernel in CONV
+            },
+        )
+
     print(json.dumps({
         "device": jax.devices()[0].device_kind,
         "shape": {"attention": [B, S, H, KV, D], "conv": [B, S, 3 * HIDDEN]},
-        "relative_l2": errors, "kernels": device_times(both),
+        "relative_l2": errors, "kernels": kernels,
     }))
     return 0 if max(errors.values()) <= 0.02 else 1
 

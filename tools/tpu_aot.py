@@ -211,6 +211,27 @@ def gqa_kernels(device):
     )
 
 
+def band_kernels(device):
+    """The band kernels beside the grouped-query causal ones at the
+    SmallThinker cell's shape: 28 query heads over 4 kv heads of 128 (a
+    whole group of seven a program), S=16,384 in 32 x 32 tiles of 512, a
+    band of 4,096 (a sweep of 9 key tiles a query tile), fwd+bwd."""
+    from dedloc_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return sum(
+            jnp.sum(flash_attention(
+                q, k, v, causal=True, band=band
+            ).astype(jnp.float32)) for band in (4096, None)
+        )
+
+    q = jax.ShapeDtypeStruct((1, 16384, 28, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 16384, 4, 128), jnp.bfloat16)
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_on_device(device, (q, kv, kv))
+    )
+
+
 def _lm_model_and_state(config: str, prefix: str):
     """(args, model, state, ids) of a causal-LM cell's recipe, from its
     configuration file's flags; ``<prefix>_LAYERS`` / ``<prefix>_BATCH`` in
@@ -295,6 +316,18 @@ def lfm2_accumulate_step(device):
     ))
 
 
+def smallthinker_accumulate_step(device):
+    """SmallThinker-21BA3B at one chip's share (``benchmark/configs/
+    smallthinker_21b_a3b_s16384.json``; ``SMALLTHINKER_LAYERS`` /
+    ``SMALLTHINKER_BATCH`` size another cut): one scanned period of a
+    global NoPE layer and three band-4096 RoPE layers at S=16,384, every
+    layer routed (ReGLU experts, the router fed before attention), the
+    untied chunked head."""
+    return _lm_accumulate_step(device, *_lm_model_and_state(
+        "smallthinker_21b_a3b_s16384.json", "SMALLTHINKER"
+    ))
+
+
 def ouro_guarded_apply_step(device):
     """The per-leaf LAMB apply of the solo boundary over the same state:
     its temp bytes are the rollback's second copy of params + moments."""
@@ -334,7 +367,7 @@ def flash_fwd_forms(lowered_text: str) -> dict:
     site inside a scan body counts once; a remat replay is a site more."""
     calls = [
         line for line in lowered_text.splitlines()
-        if re.search(r'kernel_name = "flash_(causal_|mla_|gqa_)?fwd"', line)
+        if re.search(r'kernel_name = "flash_(causal_|mla_|gqa_|band_)?fwd"', line)
     ]
     # the serialized kernel body on the same line is base64: no "_" in it
     one_tile = sum("one_tile" in line for line in calls)
@@ -418,8 +451,10 @@ def expert_grad_passes(hlo_text: str) -> dict:
 # programs whose row also carries ``kernel_calls`` (the others print the
 # rows they always did), and those with a routed expert layer, whose row
 # carries ``expert_grad_passes``
-COUNT_KERNEL_CALLS = {"gqa_kernels", "lfm2_accumulate_step"}
-COUNT_EXPERT_GRAD_PASSES = {"kanana_accumulate_step", "lfm2_accumulate_step"}
+COUNT_KERNEL_CALLS = {"gqa_kernels", "lfm2_accumulate_step", "band_kernels",
+                      "smallthinker_accumulate_step"}
+COUNT_EXPERT_GRAD_PASSES = {"kanana_accumulate_step", "lfm2_accumulate_step",
+                            "smallthinker_accumulate_step"}
 NO_V5E = 3  # exit code: nothing to compile with, which is not a failure
 
 
@@ -427,7 +462,8 @@ PROGRAMS = {
     fn.__name__: fn for fn in (
         accumulate_step, flat_apply_step, kernels, ouro_accumulate_step,
         ouro_guarded_apply_step, mla_kernels, kanana_accumulate_step,
-        gqa_kernels, lfm2_accumulate_step,
+        gqa_kernels, lfm2_accumulate_step, band_kernels,
+        smallthinker_accumulate_step,
     )
 }
 
