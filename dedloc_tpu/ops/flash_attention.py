@@ -30,13 +30,43 @@ one-tile forward keeps no running max or sum, corrects nothing by
 exp(m_prev - m_new) and accumulates nothing across steps; it is the tiled
 kernel's arithmetic in the same order (there the correction is exactly 0 and
 the accumulator exactly p·v), so ``out`` and ``lse`` are the same bits, and
-0.21 ms a call against 0.40 at (12, 512, 16 x 64) on a v5e: what the tiled form
-pays for is the [Bq, 1] state columns (one lane in 128 of every register,
-masked stores, lane broadcasts) and the accumulator's round trip through
-VMEM, not arithmetic. The softmax scale stays a multiply on the float32
-scores in both forms: folding it into q where 1/sqrt(D) is a power of two
-(exact: the same bits) was measured on the chip and is not faster (0.212 ms
-against 0.209), nor is a reciprocal-and-multiply in place of the one divide.
+0.21 ms a call against 0.40 at (12, 512, 16 x 64) on a v5e for the tiled
+kernel as it was then (PR 26).
+
+The tiled form's state. The running max ``m`` and denominator ``l`` of a
+head are TILES [Bq, 128] of float32 scratch with the row's value in every
+lane (``STATE_LANES``), beside the [Bq, H·Dv] accumulator. A row reduce
+leaves its result in every lane already, so ``m_new`` meets ``m_prev``,
+``s`` and the accumulator register against register: no lane permute, no
+masked store, and ``corr = exp(m_prev - m_new)`` IS the accumulator's
+multiplier where a head owns its lane tile (a select by lane where two
+share one, D=64). It is the same arithmetic in the same order as with
+[Bq, 1] columns — the same bits, on the CPU and on the v5e — and what it
+buys is not the permutes' own time: as columns, the state chained a
+program's heads (head i+1's q·kᵀ did not start under head i's softmax: the
+compiled body ran the MXU full for ~500 bundles a head and a quarter full
+for ~1,300 more, no unit saturated); as tiles the heads overlap and the
+body's bundles are 88-90 % MXU at D=128 and 192 / 128, 74 % at D=64 (the
+store slot fills first there). A call on a v5e, forward alone, columns →
+tiles (PR 37): 1.132 → 0.594 ms at (1, 4096, 16 x 128) causal, 35 → 66 % of
+its roofline; 12.55 → 5.94 and 26.08 → 12.35 ms at (1, 16384, 28 / 4 x 128),
+band 4,096 and causal, 38 → 81 %; 2.421 → 1.637 ms at 32 x 192 / 128, 41 →
+60 % (its ceiling 83); 1.869 → 1.237 ms at 32 / 8 x 64, 21 → 32 % (ceiling
+50). What it still pays for: the tile's ``exp`` and two row reduces, the
+accumulator's read-modify-write a key tile, the init and flush of a query
+tile (794 + 1,252 bundles beside 4,500 a key tile, four heads of 128 a
+program), and at D=64 the lanes zeroed for the other head. Skipping the
+correction on a tile where no row's max rose was built and measured: 0.693
+ms where this reads 0.594 (holding p·v across a branch costs more than the
+multiply it saves, and the body is MXU-bound), and at random weights no
+tile of any cell's shape takes the skip. With nothing ordering the heads
+the scheduler holds every head's score tile on its stack at once, so a
+program of seven heads asks for its scoped VMEM (``_fwd_vmem``).
+
+The softmax scale stays a multiply on the float32 scores in both forms:
+folding it into q where 1/sqrt(D) is a power of two (exact: the same bits)
+was measured on the chip and is not faster (0.212 ms against 0.209), nor is
+a reciprocal-and-multiply in place of the one divide.
 
 Layout contract: ONE layout, the model's. q, k, v, dO go in and out, dq, dk,
 dv come out as [B, S, H·D], the array a dense projection writes and the
@@ -263,23 +293,45 @@ def _into(totals, i: int, width: int, g: int, term) -> None:
             totals[n] = piece if totals[n] is None else totals[n] + piece
 
 
+STATE_LANES = 128  # a register's lanes: the width of a row's state tile
+
+
+def _across(x, width: int):
+    """``x`` [N, L] with ONE value a row in all its L lanes, ``width`` lanes
+    wide: whole copies of its registers side by side (or the first lanes of
+    them) — no lane moves. A column [N, 1] is returned as it is, to
+    broadcast."""
+    lanes = x.shape[-1]
+    if lanes in (1, width):
+        return x
+    if width < lanes:
+        return x[:, :width]
+    if width % lanes:
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], width))
+    return pltpu.repeat(x, width // lanes, axis=1)
+
+
 def _per_head_lanes(columns, width: int, seg: slice):
-    """The g per-head columns [N, 1] across lanes ``seg`` of their column
-    block, column i over head i's lanes (to scale an accumulator head by
-    head): the column itself, to broadcast, where one head owns the segment
-    (its own tile: D=128, and v's 128 x 2), else a select by lane."""
+    """The g per-head values — columns [N, 1], or tiles [N, L] that hold
+    the value in every lane — across lanes ``seg`` of their column block,
+    head i's over head i's lanes (to scale an accumulator head by head):
+    the column itself, to broadcast, or the tile's registers as they are,
+    where one head owns the segment (its own tile: D=128, and v's 128 x 2),
+    else a select by lane."""
     heads = [
         i for i in range(len(columns))
         if i * width < seg.stop and (i + 1) * width > seg.start
     ]
-    out = columns[heads[-1]]
+    lanes = seg.stop - seg.start
+    out = _across(columns[heads[-1]], lanes)
     if len(heads) > 1:
         lane = jax.lax.broadcasted_iota(
-            jnp.int32, (out.shape[0], seg.stop - seg.start), 1
+            jnp.int32, (out.shape[0], lanes), 1
         )
         for i in reversed(heads[:-1]):
             out = jnp.where(
-                lane < (i + 1) * width - seg.start, columns[i], out
+                lane < (i + 1) * width - seg.start,
+                _across(columns[i], lanes), out,
             )
     return out
 
@@ -659,16 +711,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                     mask,
                 )
 
-                # softmax state lives as COLUMNS [Bq, 1] in scratch (it
-                # never touches HBM) so the running max/denominator
-                # broadcast against s with zero cross-lane relayouts; only
-                # the lse OUTPUT is a row (HBM tiling).
-                m_prev, l_prev = m_ref[h], l_ref[h]  # [Bq, 1] columns
+                # m and l are TILES [Bq, 128], the row's value in every
+                # lane (see "The tiled form's state"): the row max leaves
+                # its reduce in every lane too, so nothing below moves a
+                # lane; only the lse OUTPUT is a row (HBM tiling).
+                m_prev, l_prev = m_ref[h], l_ref[h]
                 m_new = jnp.maximum(
                     m_prev, jnp.max(s, axis=-1, keepdims=True)
                 )
-                p = jnp.exp(s - m_new)
-                corr = jnp.exp(m_prev - m_new)  # [Bq, 1]
+                p = jnp.exp(s - _across(m_new, s.shape[-1]))
+                corr = jnp.exp(m_prev - m_new)
                 l_ref[h] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
                 m_ref[h] = m_new
                 corrs.append(corr)
@@ -689,15 +741,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         for h0, _cols, vcols in blocks:
             safe_l = [
                 jnp.maximum(l_ref[h0 + i], 1e-30) for i in range(g)
-            ]  # [Bq, 1] each
+            ]  # [Bq, 128] tiles
             for seg in _segments(dv, g):
                 at = _at(vcols, seg)
                 o_ref[:, at] = (
                     acc_ref[:, at] / _per_head_lanes(safe_l, dv, seg)
                 ).astype(o_ref.dtype)
             for i in range(g):
-                lse_ref[h0 + i] = _t(  # -> [1, Bq] row
-                    m_ref[h0 + i] + jnp.log(safe_l[i])
+                lse_ref[h0 + i] = _t(  # one lane of the tile -> [1, Bq] row
+                    (m_ref[h0 + i] + jnp.log(safe_l[i]))[:, :1]
                 )
 
 
@@ -761,6 +813,25 @@ def _fwd(q, k, v, bias, d, dv, block_q, block_k, mask, interpret):
                       interpret)
 
 
+def _fwd_vmem(q, bq: int, bk: int, hp: int, kvb: int, d: int, dv: int):
+    """Compiler parameters of a tiled forward call: None — the compiler's
+    own scoped-VMEM limit, 16 MiB on a v5e — unless the call needs more.
+    With the state lane-dense nothing orders a program's heads, so the
+    scheduler starts every head's q·kᵀ ahead of the first head's softmax and
+    holds each head's score tile (float32, then bf16 probabilities:
+    6·Bq·Bk bytes, ``_pick_heads``' figure) on its stack at once. Four heads
+    at 512 x 512 fit beside the blocks and the state; a whole group of
+    seven (``_grouped``: 10.5 MB of tiles, 16.5 MB in all) does not, and
+    asks for what it needs."""
+    size = q.dtype.itemsize
+    blocks = 2 * size * (bq * hp * (d + dv) + bk * kvb * (d + dv))
+    state = 4 * bq * hp * (dv + 2 * STATE_LANES)
+    need = blocks + state + hp * 6 * bq * bk
+    if need <= 14 * 2**20:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=need + 4 * 2**20)
+
+
 def _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, mask, interpret):
     b, s, h, g, hp, bq, bk = _geometry(q, d, dv, block_q, block_k,
                                        budget_mb=6.0)
@@ -799,12 +870,13 @@ def _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, mask, interpret):
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, hp * dv), jnp.float32),
-            pltpu.VMEM((hp, bq, 1), jnp.float32),
-            pltpu.VMEM((hp, bq, 1), jnp.float32),
+            pltpu.VMEM((hp, bq, STATE_LANES), jnp.float32),
+            pltpu.VMEM((hp, bq, STATE_LANES), jnp.float32),
         ],
         interpret=interpret,
         name=_name("fwd", mask, d, dv, group),
         metadata=_metadata(d, dv, g, q, k, mask),
+        compiler_params=_fwd_vmem(q, bq, bk, hp, kvb, d, dv),
     )(q, k, v, bias)
     return out, lse
 

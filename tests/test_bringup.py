@@ -384,7 +384,9 @@ def test_smallthinker_accumulate_step_takes_the_band_and_a_group_of_seven():
     layer and three band-4096 RoPE layers; 1 row of 16,384), compiled for a
     v5e alone and inside its accumulate_step: the band kernels carry their
     band and head counts (28 over 4: a whole group of seven a program gets
-    through Mosaic inside the default scoped VMEM), the global layer's are
+    through Mosaic — the backward kernels inside the default scoped VMEM,
+    the forward with the 23.75 MiB it asks for since its heads overlap,
+    ``_fwd_vmem``), the global layer's are
     the grouped causal kernels with the metadata they always had; under
     remat ``kernel_outputs`` no kernel is replayed — 3 + 1 sites a kind;
     the ReLU-gated tile loop's backward sums into the accumulator's twelve

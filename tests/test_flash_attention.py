@@ -260,3 +260,114 @@ def test_under_a_mesh_matches_one_device(rng):
     for a, b, name in zip(sharded[1], local[1], "qkv"):
         np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5,
                                    err_msg=f"d{name} mismatch")
+
+
+# the four geometries the decoder cells run, in miniature:
+# (query heads, kv heads, q/k width, v width, band)
+_STATE_GEOMETRIES = {
+    "d128_one_head_a_block": (2, 2, 128, 128, None),
+    "d128_group_of_three_full": (6, 2, 128, 128, None),
+    "d128_group_of_three_band": (6, 2, 128, 128, 80),
+    "qk192_v128": (2, 2, 192, 128, None),
+    "d64_two_heads_a_tile_grouped": (4, 2, 64, 64, None),
+}
+
+
+@pytest.mark.parametrize(
+    "h,kv,d,dv,band", _STATE_GEOMETRIES.values(), ids=_STATE_GEOMETRIES.keys()
+)
+def test_tiled_forward_carries_its_state(rng, h, kv, d, dv, band):
+    """The online-softmax state over a sweep of three and four key tiles,
+    at each layout the state tiles take: a query tile whose every row's max
+    is set in its FIRST key tile and never rises (the correction is the
+    identity from then on), one whose max rises in its LAST, and a sample
+    whose every score is -inf in every tile (it stays finite). ``out`` and ``lse`` against dense float32 attention; and where the
+    shapes have a one-tile form, its bits from the tiled kernel at one
+    tile, on the same operands."""
+    from dedloc_tpu.ops.flash_attention import (
+        _fwd_one_tile,
+        _fwd_tiled,
+        _mask_of,
+    )
+
+    s, block, big = 128, 32, 16.0
+    q, k, v = (
+        0.1 * rng.standard_normal((2, s, n, width)).astype(np.float32)
+        for n, width in ((h, d), (kv, d), (kv, dv))
+    )
+    amp = np.sqrt(big * np.sqrt(d))  # a score of ``big`` where both carry it
+    # query tile 2 (rows 64-95): key 20, in the first tile every row of it
+    # visits (band or not), outscores all that follow
+    q[0, 64:96, :, 0] += amp
+    k[0, 20, :, 0] += amp
+    # query tile 3 (rows 96-127): every row's own key, in the LAST tile it
+    # visits, outscores the keys before it
+    q[0, 96:, :, 1] += amp
+    k[0, 96:, :, 1] += amp * (np.arange(32)[:, None] + 1) / 32
+    bias = np.zeros((2, 1, s), np.float32)
+    bias[1] = -np.inf
+    mask = _mask_of(True, band, s)
+    flat = [jnp.asarray(x.reshape(2, s, -1)) for x in (q, k, v)]
+
+    out, lse = _fwd_tiled(*flat, jnp.asarray(bias), d, dv, block, block,
+                          mask, True)
+    out = np.asarray(out).reshape(2, s, h, dv)
+    lse = np.asarray(lse).reshape(2, h, s)
+    assert np.isfinite(out).all() and np.isfinite(lse).all()
+
+    i = np.arange(s)
+    seen = i[None, :] <= i[:, None]
+    if band is not None:
+        seen &= i[:, None] - i[None, :] < band
+    group = h // kv
+    for head in range(h):
+        x = q[0, :, head] @ k[0, :, head // group].T / np.sqrt(np.float32(d))
+        x = np.where(seen, x, -np.inf).astype(np.float32)
+        # the cases are what they claim: which key tiles raise a row's max
+        rises = [
+            [bool((x[rows, t * block:(t + 1) * block].max(-1)
+                   > x[rows, :t * block].max(-1, initial=-np.inf)).any())
+             for t in range(4)]
+            for rows in (slice(64, 96), slice(96, 128))
+        ]
+        assert rises[0] == [True, False, False, False], rises
+        assert rises[1][-1] and sum(rises[1]) >= 2, rises
+        top = x.max(-1, keepdims=True)
+        p = np.exp(x - top)
+        want_lse = (top + np.log(p.sum(-1, keepdims=True)))[:, 0]
+        want = (p / p.sum(-1, keepdims=True)) @ v[0, :, head // group]
+        np.testing.assert_allclose(out[0, :, head], want, atol=2e-5,
+                                   rtol=2e-5)
+        np.testing.assert_allclose(lse[0, head], want_lse, atol=2e-5,
+                                   rtol=2e-5)
+
+    if kv == h:  # a one-tile form exists: the same bits at one tile
+        one, one_lse = _fwd_one_tile(*flat, jnp.asarray(bias), d, dv, mask,
+                                     True)
+        tiled, tiled_lse = _fwd_tiled(*flat, jnp.asarray(bias), d, dv, s, s,
+                                      mask, True)
+        np.testing.assert_array_equal(one, tiled)
+        np.testing.assert_array_equal(one_lse, tiled_lse)
+
+
+@pytest.mark.parametrize(
+    "hp,kvb,d,dv,asks",
+    [(4, 4, 128, 128, False), (4, 4, 192, 128, False), (4, 2, 64, 64, False),
+     (7, 1, 128, 128, True)],
+    ids=["ouro", "kanana2", "lfm2", "smallthinker_group_of_seven"],
+)
+def test_only_a_program_of_seven_heads_asks_for_its_vmem(hp, kvb, d, dv,
+                                                         asks):
+    """The tiled forward's heads overlap, so the compiler holds a score
+    tile a head on its stack: four heads at 512 x 512 stay inside its
+    default limit and their calls carry no compiler parameters (the
+    programs they had); a whole group of seven asks for what it needs
+    (16.48 MB by the v5e compiler's own count, PR 37)."""
+    from dedloc_tpu.ops.flash_attention import _fwd_vmem
+
+    q = jax.ShapeDtypeStruct((1, 4096, hp * d), jnp.bfloat16)
+    params = _fwd_vmem(q, 512, 512, hp, kvb, d, dv)
+    if not asks:
+        assert params is None
+    else:
+        assert 16.48 * 2**20 < params.vmem_limit_bytes <= 32 * 2**20

@@ -1,7 +1,11 @@
 """On the chip: the causal flash kernels against dense causal attention at
 Ouro's shape (16 heads x 128, S=4,096: 8 x 8 tiles of 512), forward and the
 three gradients, in bf16 against a float32 reference at matmul precision
-'highest'; then the kernels' wall per call.
+'highest'; then the kernels' wall per forward + backward, and each kernel's
+DEVICE time per call with its share of the roofline — a profiler window
+over the same calls, read as the benchmark reads its
+``flash_causal_*_roofline`` metrics (``benchmark/trace.py``,
+``benchmark/flops_lm.causal_kernel_cost``).
 
     chiprun --chips 1 -- python tools/chip_causal_check.py
 
@@ -19,9 +23,12 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 
+from benchmark.flops_lm import causal_kernel_cost
 from dedloc_tpu.ops.flash_attention import flash_attention
+from tools.chip_gqa_check import device_times, traced_ops
 
 B, S, H, D = 1, 4096, 16, 128
+KERNELS = ("flash_causal_fwd", "flash_causal_bwd_dq", "flash_causal_bwd_dkv")
 
 
 def dense(q, k, v):
@@ -65,10 +72,18 @@ def main() -> int:
     for _ in range(20):
         result = flash(bf(q), bf(k), bf(v))
     jax.block_until_ready(result)
+    wall_ms = (time.perf_counter() - start) / 20 * 1e3
     print(json.dumps({
         "device": jax.devices()[0].device_kind, "shape": [B, S, H, D],
         "relative_l2": errors,
-        "fwd_plus_bwd_wall_ms": (time.perf_counter() - start) / 20 * 1e3,
+        "fwd_plus_bwd_wall_ms": wall_ms,
+        "kernels": device_times(
+            traced_ops(lambda: flash(bf(q), bf(k), bf(v))), {
+                kernel: (lambda _on_chip, kernel=kernel: causal_kernel_cost(
+                    kernel, B, H, S, D, 512, 512
+                )) for kernel in KERNELS
+            },
+        ),
     }))
     return 0 if max(errors.values()) <= 0.02 else 1
 

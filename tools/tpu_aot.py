@@ -45,6 +45,16 @@ writes each Mosaic kernel's final code (``<dir>/*-post-finalize-llo.txt``).
 A count is not a time: the one-tile forward dropped 41 % of ``flash_fwd``'s
 instructions and 47 % of its time on the v5e, and variants that differ by
 256 vector multiplies a head ran within 1.2 % of each other (PERF.md, PR 26).
+The SCHEDULE, one line a VLIW bundle, is nearer a time:
+``LIBTPU_INIT_ARGS="--xla_jf_dump_to=<dir> --xla_jf_dump_llo_text=true"``
+writes ``<dir>/*-<kernel>.1-71-final_bundles.txt`` (``PF:`` marks where a
+``pl.when`` region starts: init, a plain tile's body, a crossed tile's, the
+flush) and ``*-final_hlo-static-per-bundle-utilization.txt`` (the MXU / XLU /
+VALU / EUP / load / store slots each bundle fills, their capacities on the
+first lines). ~190 MB and a ``Raising signal 6`` at exit, after the files are
+written: one small program a run. Bundles of a tile's body a head tracked the
+chip for PR 37's forward (1,896 → 1,127 at D=128; 1.132 → 0.594 ms a call) and
+showed WHY before the chip did: MXU slots 49 % full, no unit saturated.
 """
 from __future__ import annotations
 
