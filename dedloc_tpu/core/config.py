@@ -291,7 +291,10 @@ class TrainingArguments:
     # (models/deepseek_v3.py); lfm2_tiny | lfm2_24b_a2b (the conv-hybrid
     # expert decoder, models/lfm2_moe.py); smallthinker_tiny |
     # smallthinker_21b_a3b (the band-and-global expert decoder,
-    # models/smallthinker.py) — roles/common.MODEL_FAMILIES is the table
+    # models/smallthinker.py); sdar_tiny | sdar_30b_a3b (the block-diffusion
+    # expert decoder, models/sdar_moe.py: seq_length counts a row's CLEAN
+    # tokens, the stack sees twice as many positions) —
+    # roles/common.MODEL_FAMILIES is the table
     model_size: str = "large"
     # depth override (0 = the model's own): a chip's share of a deeper
     # deployment keeps every width and cuts layers. No width is settable.
@@ -299,9 +302,9 @@ class TrainingArguments:
     # "index/count": the share of every expert layer's routed experts this
     # peer's chip holds, as one of ``count`` chips that divide a layer (a
     # model with a dropless routed layer: models/deepseek_v3.py,
-    # models/lfm2_moe.py, models/smallthinker.py). The layer
-    # scores ALL experts and computes its own experts' part; "0/1" = every
-    # expert. Together with ``vocab_size`` (rows of the vocabulary held)
+    # models/lfm2_moe.py, models/smallthinker.py, models/sdar_moe.py). The
+    # layer scores ALL experts and computes its own experts' part; "0/1" =
+    # every expert. Together with ``vocab_size`` (rows of the vocabulary held)
     # and ``num_hidden_layers`` it states a chip's share of a deployment.
     expert_shard: str = "0/1"
     # override model remat: nothing|kernel_outputs|dots|dots_no_batch|

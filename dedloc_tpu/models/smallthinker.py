@@ -234,16 +234,20 @@ class BandAttention(nn.Module):
         )
 
 
-class RoutedReGLU(nn.Module):
-    """Σ_{e in C} w_e ReGLU_e(x) over the HELD experts, with C and w from
-    the router LOGITS it is handed (``router_input`` W_r: the layer's
-    normalised input, from before attention); returns (y, routing) with
+class RoutedGLU(nn.Module):
+    """Σ_{e in C} w_e GLU_e(x) over the HELD experts (``activation``: the
+    gate's, "relu" here, "silu" in ``models/sdar_moe.py``), with C and w —
+    the top k and a softmax over the chosen ones — from the router LOGITS of
+    ``router_input`` (this model: the layer's normalised input, from before
+    attention). ``cfg``: this model's, or any config with the routed
+    layer's fields under the same names. Returns (y, routing) with
     ``routing`` = the logits [T, E] (as ``scores``), choice [T, k], load [E]
     and the counts of ``parallel/moe.routed_experts``. An apply that carries
     the collection ``GRAD_SINKS`` hands this layer's three buffers to the
     tile loop's backward."""
 
-    cfg: SmallThinkerConfig
+    cfg: Any
+    activation: str = "relu"
 
     @nn.compact
     def __call__(self, x, router_input):
@@ -271,7 +275,8 @@ class RoutedReGLU(nn.Module):
         routed, counts = routed_experts(
             x.reshape(B * S, H), choice, weights, gate.astype(cfg.dtype),
             up.astype(cfg.dtype), down.astype(cfg.dtype), (first, held),
-            tile=cfg.moe_row_tile, grad_sinks=sinks, activation="relu",
+            tile=cfg.moe_row_tile, grad_sinks=sinks,
+            activation=self.activation,
         )
         return routed.reshape(B, S, H).astype(cfg.dtype), dict(
             counts, scores=logits, choice=choice, load=expert_load(choice, E)
@@ -293,7 +298,7 @@ class DecoderLayer(nn.Module):
         hidden = hidden + BandAttention(
             cfg, self.rotated, self.banded, name="self_attn"
         )(x, rope)
-        y, routing = RoutedReGLU(cfg, name="block_sparse_moe")(
+        y, routing = RoutedGLU(cfg, name="block_sparse_moe")(
             RMSNorm(cfg, name="post_attention_layernorm")(hidden), x
         )
         return hidden + y, routing

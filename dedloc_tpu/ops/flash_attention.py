@@ -111,7 +111,14 @@ cases, the iota mask, the names and the count of visited tiles all read.
 GROUPED-QUERY attention is read from the shapes: k and v with fewer heads
 than q stay that narrow in HBM, forward and backward, and dk / dv are summed
 over a group inside the kernel (see "grouped-query heads" below;
-``flash_gqa_*`` in a device trace). A group that is no power of two (seven
+``flash_gqa_*`` in a device trace). BLOCK DIFFUSION (``block_diffusion=B``)
+is the third field of the same description: the sequence is a NOISY copy of
+a row followed by the CLEAN one, both cut into blocks of B positions, and a
+query sees the clean blocks before its own (a clean query: its own too) and,
+where it is noisy, the noisy keys of its own block — keys AFTER it among
+them. A tile then needs up to TWO runs of tiles of the other axis (see
+"block-diffusion tiles" below); the kernels are named ``flash_bd_*``. A
+group that is no power of two (seven
 query heads a kv head) is ONE program, where a column block is one head
 (D=128): halving the heads a program takes never lands on a divisor of it,
 and one head a program — what halving falls to — ran the backward kernels
@@ -360,12 +367,15 @@ def _lanes(d: int, dv: int, g: int) -> Optional[dict]:
 def _metadata(d: int, dv: int, g: int, q, k, mask) -> Optional[dict]:
     """A call's kernel ``metadata``: its windows where they are narrower
     than its blocks (``_lanes``), its head counts where k has fewer than q,
-    its band where it has one; None for every other call (see ``_lanes``
-    for why not more)."""
+    its band or its blocks where it has them; None for every other call
+    (see ``_lanes`` for why not more)."""
     h, kvh = q.shape[-1] // d, k.shape[-1] // d
     found = _lanes(d, dv, g) if kvh == h else {"heads": h, "kv_heads": kvh}
     if mask.band is not None:
         found = dict(found or {}, band=mask.band)
+    if mask.blocks is not None:
+        found = dict(found or {}, block=mask.blocks.length,
+                     stream=mask.blocks.stream)
     return found
 
 
@@ -513,11 +523,21 @@ def _fold_group(acc_ref, at, total, d: int, g: int) -> None:
 # and the count of visited tiles (``visited_tiles``).
 
 
+class _Blocks(NamedTuple):
+    """Block diffusion's two streams in one call (see "block-diffusion
+    tiles"): [noisy ; clean], ``stream`` positions each, in blocks of
+    ``length``."""
+
+    length: int
+    stream: int
+
+
 class _Mask(NamedTuple):
     """What a query may see of the keys, beyond the KV bias."""
 
     causal: bool = False  # keys at the query's position and before
     band: Optional[int] = None  # and only the last ``band`` of those
+    blocks: Optional[_Blocks] = None  # or: the two-stream block rule
 
 
 def _clip(x, low=None, high=None):
@@ -565,6 +585,8 @@ def _k_tile(mask: _Mask, qi, step, bq: int, bk: int):
     """The key tile that step ``step`` of query tile ``qi``'s sweep NAMES
     (an index map's answer): a step past the last needed tile re-names that
     tile, which is not fetched again."""
+    if mask.blocks is not None:
+        return _bd_tile(mask.blocks, qi, step, bq, bk, "k", clamp=True)[0]
     if not mask.causal:
         return step
     return jnp.minimum(
@@ -576,6 +598,8 @@ def _q_tile(mask: _Mask, ki, step, bq: int, bk: int, nq: int):
     """The query tile that step ``step`` of key tile ``ki``'s sweep names.
     Without a band the sweep is over every query tile and the steps before
     the first needed one name it; with one it starts there."""
+    if mask.blocks is not None:
+        return _bd_tile(mask.blocks, ki, step, bk, bq, "q", clamp=True)[0]
     if not mask.causal:
         return step
     first = _first_q_tile(ki, bq, bk)
@@ -587,7 +611,14 @@ def _q_tile(mask: _Mask, ki, step, bq: int, bk: int, nq: int):
 def _sweep(mask: _Mask, nq: int, nk: int, bq: int, bk: int, over: str) -> int:
     """Steps of the inner grid axis: the most key tiles a query tile needs
     (``over`` "k") or query tiles a key tile is seen by ("q"). Every tile
-    of the axis unless there is a band."""
+    of the axis unless there is a band (or the block rule: the longer of
+    its two runs together)."""
+    if mask.blocks is not None:
+        outer, inner = (bq, bk) if over == "k" else (bk, bq)
+        return max(
+            _bd_tiles_of(mask.blocks, t, outer, inner, over)
+            for t in range(nq if over == "k" else nk)
+        )
     if mask.band is None:
         return nk if over == "k" else nq
     if over == "k":
@@ -602,12 +633,20 @@ def _sweep(mask: _Mask, nq: int, nk: int, bq: int, bk: int, over: str) -> int:
 
 
 def visited_tiles(seq: int, block_q: int, block_k: int, causal: bool,
-                  band: Optional[int] = None) -> int:
+                  band: Optional[int] = None,
+                  block_diffusion: Optional[int] = None) -> int:
     """(query tile, key tile) pairs a call's kernels compute, crossed ones
     included: 528 of 1,024 at S=16,384 and 512 x 512 tiles under the causal
-    mask, 252 under a band of 4,096."""
+    mask, 252 under a band of 4,096; 80 of 256 at S=2 x 4,096 under the
+    block rule (``seq`` counts both streams)."""
+    mask = _mask_of(causal, band, seq, block_diffusion)
+    if mask.blocks is not None:
+        bq, bk = _bd_blocks(mask.blocks, block_q, block_k)
+        return sum(
+            _bd_tiles_of(mask.blocks, qi, bq, bk, "k")
+            for qi in range(seq // bq)
+        )
     bq, bk = _pick_block(seq, block_q), _pick_block(seq, block_k)
-    mask = _mask_of(causal, band, seq)
     if not mask.causal:
         return (seq // bq) * (seq // bk)
     return sum(
@@ -616,9 +655,20 @@ def visited_tiles(seq: int, block_q: int, block_k: int, causal: bool,
     )
 
 
-def _mask_of(causal: bool, band: Optional[int], seq: int) -> _Mask:
+def _mask_of(causal: bool, band: Optional[int], seq: int,
+             block_diffusion: Optional[int] = None) -> _Mask:
     """The call's mask; a band as long as the sequence IS the causal mask
     (and the causal kernels, under their names)."""
+    if block_diffusion is not None:
+        if causal or band is not None or block_diffusion < 1 or seq % (
+            2 * block_diffusion
+        ):
+            raise ValueError(
+                f"block_diffusion={block_diffusion}: the block rule is a "
+                "mask of its own (no causal, no band) over two streams of "
+                f"whole blocks; the call has {seq} positions"
+            )
+        return _Mask(blocks=_Blocks(int(block_diffusion), seq // 2))
     if band is not None and (not causal or band < 1):
         raise ValueError(
             f"band={band}: a band is a causal mask's second edge "
@@ -663,6 +713,172 @@ def _for_tile(mask: _Mask, qi, ki, bq: int, bk: int, seq: int, body) -> None:
 
 def _masked(s, mask):
     return s if mask is None else jnp.where(mask, s, NEG_INF)
+
+
+def _for_k_step(mask: _Mask, qi, step, bq: int, bk: int, seq: int,
+                body) -> None:
+    """``_for_tile`` for step ``step`` of query tile ``qi``'s key sweep."""
+    if mask.blocks is not None:
+        _bd_for_step(mask.blocks, "k", qi, step, bq, bk, body)
+        return
+    _for_tile(mask, qi, _from(_first_k_tile(mask, qi, bq, bk), step), bq, bk,
+              seq, body)
+
+
+def _for_q_step(mask: _Mask, ki, step, bq: int, bk: int, seq: int,
+                body) -> None:
+    """``_for_tile`` for step ``step`` of key tile ``ki``'s query sweep (a
+    band's starts at the key tile's first query tile)."""
+    if mask.blocks is not None:
+        _bd_for_step(mask.blocks, "q", ki, step, bq, bk, body)
+        return
+    first = 0 if mask.band is None else _first_q_tile(ki, bq, bk)
+    _for_tile(mask, _from(first, step), ki, bq, bk, seq, body)
+
+
+# -------------------------------------------------- block-diffusion tiles
+#
+# Block diffusion trains on TWO copies of a row in one sequence: positions
+# 0 .. L-1 hold the NOISY stream, L .. 2L-1 the CLEAN one, both cut into
+# blocks of B positions (block of position p of either stream: p // B).
+# Query i sees key j iff
+#     i clean:  j clean and block(j) <= block(i)
+#     i noisy: (j noisy and block(j) == block(i))
+#              or (j clean and block(j) < block(i))
+# so a noisy query sees keys AFTER it (inside its block), never the clean
+# copy of its own block, and no clean query sees a noisy key. Tiles never
+# straddle the two streams, nor a block a tile (``_bd_blocks``). In tiles, a
+# query tile needs up to TWO runs of key tiles — the clean tiles up to its
+# diagonal, and (noisy) its own noisy diagonal tile — and a clean key tile
+# is seen by two runs of query tiles, the noisy ones and the clean ones from
+# its diagonal on (``_bd_runs``). A sweep walks the first run, then the
+# second (``_bd_tile``); a step past both re-names the last tile and is
+# skipped, as a causal sweep's. Within a tile the rule is ONE comparison of
+# block indices, low <= block(i) - block(j) <= high, with (low, high) by
+# quadrant — clean x clean (0, any), noisy x clean (1, any), noisy x noisy
+# (0, 0) — and a tile is plain, crossed or empty by the least and greatest
+# difference it holds (``_bd_for_step``): only tiles a stream's diagonal
+# crosses pay for the iota mask. At L = 4,096 and 512 x 512 tiles: 80
+# visited pairs (36 + 36 + 8) of the 256 a dense call and the 136 a causal
+# call over 2L would visit, 24 of them crossed.
+
+_ANY = 2 ** 30  # no upper limit on a difference of block indices
+
+
+def _where(cond, a, b):
+    """``a if cond else b``, for a Python bool or a traced scalar."""
+    return (a if cond else b) if isinstance(cond, bool) else jnp.where(
+        cond, a, b
+    )
+
+
+def _bd_blocks(blocks: _Blocks, block_q: int, block_k: int):
+    """(Bq, Bk) of a block-diffusion call: tiles of ONE stream (they divide
+    L, so none straddles the two) that hold whole blocks."""
+    bq = _pick_block(blocks.stream, block_q)
+    bk = _pick_block(blocks.stream, block_k)
+    if bq % blocks.length or bk % blocks.length:
+        raise ValueError(
+            f"block diffusion: tiles of {bq} x {bk} positions do not hold "
+            f"whole blocks of {blocks.length}"
+        )
+    return bq, bk
+
+
+def _bd_runs(blocks: _Blocks, outer, b_outer: int, b_inner: int, over: str):
+    """The two runs of inner tiles that outer tile ``outer`` needs, each
+    (first tile, count), in sweep order; a count may be 0. ``over`` "k":
+    the key tiles of a query tile; "q": the query tiles of a key tile.
+    ``outer`` is a Python int or a traced scalar."""
+    b, n_outer = blocks.length, blocks.stream // b_outer
+    n_inner = blocks.stream // b_inner
+    clean = outer >= n_outer
+    lo = (outer - _where(clean, n_outer, 0)) * b_outer  # in its stream
+    hi = lo + b_outer - 1
+    if over == "k":
+        # the CLEAN keys of the blocks up to a clean query's own, before a
+        # noisy query's own; then a noisy query's own NOISY blocks
+        own = lo // b_inner
+        return (
+            (n_inner, _where(clean, hi, hi - b) // b_inner + 1),
+            (own, _where(clean, 0, hi // b_inner - own + 1)),
+        )
+    # the NOISY queries of a noisy key's own blocks, of the blocks after a
+    # clean key's; then the CLEAN queries from a clean key's own block on
+    first = _where(clean, lo + b, lo) // b_inner
+    own = lo // b_inner
+    return (
+        (first, _where(clean, n_inner, hi // b_inner + 1) - first),
+        (n_inner + own, _where(clean, n_inner - own, 0)),
+    )
+
+
+def _bd_tiles_of(blocks: _Blocks, outer, b_outer: int, b_inner: int,
+                 over: str):
+    """Inner tiles outer tile ``outer`` needs: both runs together."""
+    return sum(
+        count for _first, count in _bd_runs(
+            blocks, outer, b_outer, b_inner, over
+        )
+    )
+
+
+def _bd_tile(blocks: _Blocks, outer, step, b_outer: int, b_inner: int,
+             over: str, clamp: bool):
+    """(inner tile, valid) of step ``step`` of outer tile ``outer``'s
+    sweep. ``clamp``: an index map's answer, a step past both runs naming
+    the last tile again."""
+    (a_first, a_count), (b_first, b_count) = _bd_runs(
+        blocks, outer, b_outer, b_inner, over
+    )
+    valid = step < a_count + b_count
+    if clamp:
+        step = jnp.minimum(step, a_count + b_count - 1)
+    return jnp.where(
+        step < a_count, a_first + step, b_first + step - a_count
+    ), valid
+
+
+def _block_of(x, length: int):
+    """Block index of positions ``x`` (non-negative int32)."""
+    if length & (length - 1):
+        return jax.lax.div(x, jnp.int32(length))
+    return jax.lax.shift_right_logical(x, jnp.int32(length.bit_length() - 1))
+
+
+def _bd_for_step(blocks: _Blocks, over: str, outer, step, bq: int, bk: int,
+                 body) -> None:
+    """Run ``body(tile mask)`` for the tile that step ``step`` of outer
+    tile ``outer``'s sweep visits: None where the whole tile is visible, not
+    at all for a step past the sweep's runs."""
+    b, nq, nk = blocks.length, blocks.stream // bq, blocks.stream // bk
+    inner, valid = _bd_tile(
+        blocks, outer, step, *((bq, bk) if over == "k" else (bk, bq)), over,
+        clamp=False,
+    )
+    qi, ki = (outer, inner) if over == "k" else (inner, outer)
+    q_clean, k_clean = qi >= nq, ki >= nk
+    q0 = (qi - jnp.where(q_clean, nq, 0)) * bq  # in their streams
+    k0 = (ki - jnp.where(k_clean, nk, 0)) * bk
+    # visible: low <= block(query) - block(key) <= high, by quadrant (a
+    # clean query never visits a noisy key tile)
+    low = (k_clean & jnp.logical_not(q_clean)).astype(jnp.int32)
+    high = jnp.where(k_clean, _ANY, 0)
+    least = q0 // b - (k0 + bk - 1) // b  # ... over the tile's pairs
+    most = (q0 + bq - 1) // b - k0 // b
+    plain = valid & (least >= low) & (most <= high)
+    some = valid & (most >= low) & (least <= high)
+
+    @pl.when(plain)
+    def _plain():
+        body(None)
+
+    @pl.when(jnp.logical_not(plain) & some)
+    def _crossed():
+        rows = q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        cols = k0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        apart = _block_of(rows, b) - _block_of(cols, b)
+        body((apart >= low) & (apart <= high))
 
 
 # ------------------------------------------------------------------ forward
@@ -733,8 +949,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                     acc_ref[:, at] * _per_head_lanes(corrs, dv, seg) + pv_seg
                 )
 
-    _for_tile(mask, qi, _from(_first_k_tile(mask, qi, bq, bk), kb), bq, bk,
-              seq, tile)
+    _for_k_step(mask, qi, kb, bq, bk, seq, tile)
 
     @pl.when(kb == nk - 1)
     def _flush():
@@ -792,7 +1007,10 @@ def _name(kernel: str, mask: _Mask, d: int, dv: int,
     """The causal kernels keep names of their own in a device trace, and so
     do the two-width ones (latent attention: q/k wider than v and out), the
     grouped-query ones (fewer kv heads than heads) and every call with a
-    band (its metadata says how long, and its head counts)."""
+    band (its metadata says how long, and its head counts) or the block
+    rule (its blocks' and its streams' length)."""
+    if mask.blocks is not None:
+        return f"flash_bd_{kernel}"
     if mask.band is not None:
         return f"flash_band_{kernel}"
     causal = mask.causal
@@ -988,8 +1206,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
             for at, total in zip(dq_at, dq):
                 dq_acc_ref[:, at] = total
 
-    _for_tile(mask, qi, _from(_first_k_tile(mask, qi, bq, bk), kb), bq, bk,
-              seq, tile)
+    _for_k_step(mask, qi, kb, bq, bk, seq, tile)
 
     @pl.when(kb == nk - 1)
     def _flush():
@@ -1048,9 +1265,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
             for at, total in zip(dv_at, dv_):
                 dv_acc_ref[:, at] = total
 
-    # a band's sweep starts at the key tile's first query tile
-    first = 0 if mask.band is None else _first_q_tile(ki, bq, bk)
-    _for_tile(mask, _from(first, qb), ki, bq, bk, seq, tile)
+    _for_q_step(mask, ki, qb, bq, bk, seq, tile)
 
     @pl.when(of_group(qb == nq - 1, -1))
     def _flush():
@@ -1321,6 +1536,7 @@ def flash_attention(
     mesh: Optional[Mesh] = None,
     causal: bool = False,
     band: Optional[int] = None,
+    block_diffusion: Optional[int] = None,
 ) -> jnp.ndarray:
     """Exact fused attention; drop-in for dense/blockwise attention.
 
@@ -1335,7 +1551,11 @@ def flash_attention(
     sliding window — query i sees keys i-band+1 .. i, its own position
     counted; tiles outside the band are neither grid steps nor fetched, the
     kernels are named ``flash_band_*``, and a band no shorter than the
-    sequence IS the causal call (the same kernels, names and bits). ``v``
+    sequence IS the causal call (the same kernels, names and bits).
+    ``block_diffusion``: a mask of its own — the S positions are a noisy
+    stream then a clean one, S / 2 each, in blocks of this many positions,
+    under the rule of "block-diffusion tiles" (``flash_bd_*``); the tiles
+    are tiles of one stream. ``v``
     may have a head width of its own (latent attention: q and k 192 wide, v and the result 128): the
     same kernels with two column-block widths, scores scaled by
     1/sqrt(q's width), named ``flash_mla_*`` in a device trace; nothing is
@@ -1354,9 +1574,12 @@ def flash_attention(
     kvh, dv = v.shape[-2:]
     if bias is None:
         bias = jnp.zeros((b, s), jnp.float32)
+    mask = _mask_of(causal, band, s, block_diffusion)
+    if mask.blocks is not None:
+        block_q, block_k = _bd_blocks(mask.blocks, block_q, block_k)
     op = functools.partial(
         _flash_local, d=d, dv=dv, block_q=block_q, block_k=block_k,
-        mask=_mask_of(causal, band, s), interpret=interpret,
+        mask=mask, interpret=interpret,
     )
     if mesh is not None:
         # heads over "model": a shard's columns are its (H/tp)·D

@@ -46,6 +46,12 @@ from dedloc_tpu.models.ouro import (
     ouro_train_tflops_per_sample,
     ouro_weight_decay_mask,
 )
+from dedloc_tpu.models.sdar_moe import (
+    SdarMoeConfig,
+    SdarMoeForDiffusionLM,
+    sdar_moe_loss,
+    sdar_moe_train_tflops_per_sample,
+)
 from dedloc_tpu.models.smallthinker import (
     SmallThinkerConfig,
     SmallThinkerForCausalLM,
@@ -173,6 +179,18 @@ def _ouro_batches(cfg, batch_size: int, seq_length: int,
     )
 
 
+def _sdar_batches(cfg, batch_size: int, seq_length: int,
+                  seed: int) -> Iterator[Dict[str, np.ndarray]]:
+    from dedloc_tpu.data.block_diffusion import (
+        synthetic_block_diffusion_batches,
+    )
+
+    return synthetic_block_diffusion_batches(
+        cfg.vocab_size, batch_size,
+        min(seq_length, cfg.max_position_embeddings), cfg.block_length, seed,
+    )
+
+
 ALBERT = ModelFamily(
     config=AlbertConfig, module=AlbertForPreTraining, loss=_albert_loss,
     synthetic_batches=lambda *a: synthetic_mlm_batches(*a),
@@ -224,11 +242,26 @@ SMALLTHINKER = dataclasses.replace(
     ),
     sign_step_mask=None, sign_step=0.0,
 )
+SDAR_MOE = dataclasses.replace(
+    SMALLTHINKER,  # the same counter's rule, sinks and decay mask; no bias
+    config=SdarMoeConfig, module=SdarMoeForDiffusionLM,
+    loss=_without_rng(sdar_moe_loss),
+    # a row, its noisy copy and the weight of every position's loss
+    synthetic_batches=_sdar_batches,
+    tflops_per_sample=sdar_moe_train_tflops_per_sample,
+    step_gauges=(
+        "moe.load_max_over_mean", "moe.local_slot_share",
+        "moe.grad_sink_leaves", "attn.bd_tile_share",
+        "diffusion.masked_share",
+    ),
+    step_counters=("moe.dropped_slots", "diffusion.masked_tokens"),
+)
 MODEL_FAMILIES: Dict[str, ModelFamily] = {
     "tiny": ALBERT, "large": ALBERT, "ouro_tiny": OURO, "ouro_2p6b": OURO,
     "kanana2_tiny": DEEPSEEK_V3, "kanana2_30b_a3b": DEEPSEEK_V3,
     "lfm2_tiny": LFM2_MOE, "lfm2_24b_a2b": LFM2_MOE,
     "smallthinker_tiny": SMALLTHINKER, "smallthinker_21b_a3b": SMALLTHINKER,
+    "sdar_tiny": SDAR_MOE, "sdar_30b_a3b": SDAR_MOE,
 }
 
 
@@ -243,7 +276,8 @@ def model_family(model) -> ModelFamily:
             )
         return MODEL_FAMILIES[model]
     cfg = getattr(model, "cfg", model)
-    for family in (ALBERT, OURO, DEEPSEEK_V3, LFM2_MOE, SMALLTHINKER):
+    for family in (ALBERT, OURO, DEEPSEEK_V3, LFM2_MOE, SMALLTHINKER,
+                   SDAR_MOE):
         if isinstance(cfg, family.config):
             return family
     raise TypeError(f"no model family for {type(cfg).__name__}")
@@ -680,6 +714,8 @@ def drop_collator_keys(batch: Dict[str, np.ndarray]) -> Dict[str, jnp.ndarray]:
             "mlm_weights",
             "sop_labels",
         )
+    elif "loss_weights" in batch:  # block diffusion: x~, x and 1 / t
+        keep = ("input_ids", "labels", "loss_weights")
     elif "labels" in batch:  # causal LM: inputs and next-token labels
         keep = ("input_ids", "labels")
     else:

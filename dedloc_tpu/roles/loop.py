@@ -155,11 +155,11 @@ def run_boundary_loop(
                             for key, value in values.items():
                                 srec.attrs[key] = float(value)
                                 if tele is not None:
-                                    tele.gauge(key).set(float(value))  # dedlint: emits=gauge:lm.exit_prob.*,gauge:lm.loss.*,gauge:moe.load_max_over_mean.*,gauge:moe.local_slot_share,gauge:moe.bias_abs_max,gauge:moe.grad_sink_leaves,gauge:attn.band_tile_share
+                                    tele.gauge(key).set(float(value))  # dedlint: emits=gauge:lm.exit_prob.*,gauge:lm.loss.*,gauge:moe.load_max_over_mean.*,gauge:moe.local_slot_share,gauge:moe.bias_abs_max,gauge:moe.grad_sink_leaves,gauge:attn.band_tile_share,gauge:attn.bd_tile_share,gauge:diffusion.masked_share
                         for name in model.step_counters:
                             srec.attrs[name] = float(sums[name])
                             if tele is not None:
-                                tele.counter(name).inc(float(sums[name]))  # dedlint: emits=counter:moe.dropped_slots
+                                tele.counter(name).inc(float(sums[name]))  # dedlint: emits=counter:moe.dropped_slots,counter:diffusion.masked_tokens
                         # advertise the loss for the trunk-health gate —
                         # free here, the scalar is already on the host
                         opt.report_loss(loss)

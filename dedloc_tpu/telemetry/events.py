@@ -19,6 +19,7 @@ ALLREDUCE_ROUND = "allreduce.round"
 ALLREDUCE_ROUNDS = "allreduce.rounds"
 ALLREDUCE_STRAGGLERS = "allreduce.stragglers"
 ATTN_BAND_TILE_SHARE = "attn.band_tile_share"
+ATTN_BD_TILE_SHARE = "attn.bd_tile_share"
 AVG_ROUND = "avg.round"
 AVG_TOPOLOGY_FALLBACK = "avg.topology.fallback"
 AVG_TOPOLOGY_FALLBACKS = "avg.topology.fallbacks"
@@ -45,6 +46,8 @@ CKPT_SHARDS_FETCHED = "ckpt.shards_fetched"
 CKPT_SHARDS_RESUMED = "ckpt.shards_resumed"
 CKPT_SHARDS_SERVED = "ckpt.shards_served"
 CKPT_VERIFY_FAILURES = "ckpt.verify_failures"
+DIFFUSION_MASKED_SHARE = "diffusion.masked_share"
+DIFFUSION_MASKED_TOKENS = "diffusion.masked_tokens"
 EXPERT_ANNOUNCES = "expert.announces"
 EXPERT_BYTES_SERVED = "expert.bytes_served"
 EXPERT_COMPUTE = "expert.compute"
@@ -173,6 +176,7 @@ COUNTERS = frozenset({
     "ckpt.shards_resumed",
     "ckpt.shards_served",
     "ckpt.verify_failures",
+    "diffusion.masked_tokens",
     "expert.announces",
     "expert.bytes_served",
     "expert.requests",
@@ -233,6 +237,8 @@ COUNTERS = frozenset({
 })
 GAUGES = frozenset({
     "attn.band_tile_share",
+    "attn.bd_tile_share",
+    "diffusion.masked_share",
     "expert.load_ewma",
     "moe.bias_abs_max",
     "moe.grad_sink_leaves",

@@ -411,3 +411,35 @@ def test_smallthinker_accumulate_step_takes_the_band_and_a_group_of_seven():
     assert row["flash_fwd_forms"] == {"one_tile": 0, "tiles": 4}
     assert row["expert_grad_passes"] == {"adds": 0, "zero_fills": 0}
     assert 370_547_200 * 28 + row["memory"]["temp_bytes"] <= 15.3e9
+
+
+def test_sdar_accumulate_step_takes_the_block_rule_and_a_group_of_eight():
+    """SDAR-30B-A3B-Chat at the cell's cut (four layers; 1 row of 4,096
+    clean tokens = 8,192 positions, a noisy stream then a clean one),
+    compiled for a v5e alone and inside its accumulate_step: the
+    block-diffusion kernels carry their blocks (4), their streams' length
+    (4,096) and their head counts (32 over 4: a whole group of eight a
+    program gets through Mosaic); under remat ``kernel_outputs`` no kernel
+    is replayed — 4 sites a kernel, one a layer of the unrolled period; the
+    SiLU-gated tile loop's backward sums into the accumulator's twelve
+    expert leaves (a scan over single layers zero-filled, copied and cast
+    3.0 GB of stacked expert matrices: ``models/sdar_moe._Period``); and the
+    program's scratch beside 28 bytes a parameter of state with a draining
+    snapshot stays under the 15.3 GB line this tree's cells are sized
+    under."""
+    rows = _tpu_aot("bd_kernels", "sdar_accumulate_step")
+    blocks = {"heads": 32, "kv_heads": 4, "block": 4, "stream": 4096}
+    for row in rows.values():
+        assert row["flash_windows"] == {
+            "flash_bd_fwd": blocks, "flash_bd_bwd_dq": blocks,
+            "flash_bd_bwd_dkv": blocks,
+        }
+    row = rows["sdar_accumulate_step"]
+    assert row["kernel_calls"] == {
+        "flash_bd_fwd": 4, "flash_bd_bwd_dq": 4, "flash_bd_bwd_dkv": 4,
+    }
+    assert row["tpu_custom_calls"] == 12
+    assert row["flash_fwd_forms"] == {"one_tile": 0, "tiles": 4}
+    assert row["expert_grad_passes"] == {"adds": 0, "zero_fills": 0}
+    assert row["layer_body_copies"] == []
+    assert 456_346_624 * 28 + row["memory"]["temp_bytes"] <= 15.3e9

@@ -17,7 +17,7 @@ from benchmark.reference import smallthinker as reference
 from dedloc_tpu.models.smallthinker import (
     BAND_ROPE,
     GLOBAL_NOPE,
-    RoutedReGLU,
+    RoutedGLU,
     SmallThinkerConfig,
     SmallThinkerForCausalLM,
     band_tile_share,
@@ -220,7 +220,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
             name: layer[name][first:first + held]
             for name in reference.EXPERTS
         })
-        y, routing = RoutedReGLU(share).apply({"params": mine}, x, n)
+        y, routing = RoutedGLU(share).apply({"params": mine}, x, n)
         total = total + y
         local += float(routing["local_slot_share"])
         np.testing.assert_array_equal(routing["choice"], whole["choice"])

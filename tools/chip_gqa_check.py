@@ -22,6 +22,14 @@ group of seven the kernels take (the question PR 36 settled on the chip):
         --kv-heads 4 --head-dim 128 --seq 16384 --band 4096 none \
         --one-head-programs --conv 0
 
+The two-stream BLOCK-DIFFUSION rule (``flash_bd_*``, held to
+``benchmark/flops_sdar.py``'s count of the rule's tiles) by ``--block-diffusion
+<block length>``, ``--seq`` counting both streams — SDAR's 32 query heads
+over 4 kv heads of 128 at 2 x 4,096 positions, blocks of 4:
+
+    chiprun --chips 1 -- python tools/chip_gqa_check.py --heads 32 \
+        --kv-heads 4 --head-dim 128 --seq 8192 --block-diffusion 4 --conv 0
+
 Prints one JSON line; exit code 1 if an error exceeds 0.02 relative L2
 (bf16 rounding of the operands alone is ~0.004)."""
 from __future__ import annotations
@@ -42,6 +50,7 @@ import jax.numpy as jnp
 
 from benchmark.flops import roofline_seconds
 from benchmark.flops_lfm2 import conv_kernel_cost, gqa_kernel_cost
+from benchmark.flops_sdar import bd_kernel_cost
 from benchmark.flops_smallthinker import band_kernel_cost
 from benchmark.peaks import chip_peaks
 from benchmark.reducers.conv_kernel_roofline import on_chip_tensors
@@ -54,9 +63,18 @@ KERNELS = ("fwd", "bwd_dq", "bwd_dkv")
 CONV = ("short_conv_fwd", "short_conv_bwd")
 
 
+class Blocks(int):
+    """A mask spec beside a band's length: the two-stream rule's blocks."""
+
+
 def attention_cost(kernel: str, shape, band):
-    """(FLOPs, bytes) of one ``flash_gqa_*`` / ``flash_band_*`` call."""
+    """(FLOPs, bytes) of one ``flash_gqa_*`` / ``flash_band_*`` /
+    ``flash_bd_*`` call."""
     s, h, kv, d = shape
+    if isinstance(band, Blocks):
+        return bd_kernel_cost(
+            f"flash_bd_{kernel}", B, h, kv, s // 2, d, 512, 512, int(band)
+        )
     if band is None:
         return gqa_kernel_cost(f"flash_gqa_{kernel}", B, h, kv, s, d, 512, 512)
     return band_kernel_cost(
@@ -115,9 +133,19 @@ def dense(q, k, v, band):
     ``jax.checkpoint`` (28 heads of 16,384 x 16,384 scores do not fit)."""
     s, group = q.shape[1], q.shape[2] // k.shape[2]
     i = jnp.arange(s)
-    seen = i[None, :] <= i[:, None]
-    if band is not None:
-        seen &= i[:, None] - i[None, :] < band
+    if isinstance(band, Blocks):
+        # [noisy ; clean]: a clean query sees the clean blocks up to its
+        # own, a noisy one those BEFORE its own and its own noisy block
+        clean, blk = i >= s // 2, (i % (s // 2)) // int(band)
+        apart = blk[:, None] - blk[None, :]  # query's block - key's
+        seen = jnp.where(
+            clean[:, None], clean[None, :] & (apart >= 0),
+            jnp.where(clean[None, :], apart >= 1, apart == 0),
+        )
+    else:
+        seen = i[None, :] <= i[:, None]
+        if band is not None:
+            seen &= i[:, None] - i[None, :] < band
 
     @jax.checkpoint
     def head(q, k, v):  # [S, D] each
@@ -167,11 +195,18 @@ def main(argv=None) -> int:
         "--one-head-programs", action="store_true",
         help="also time each band with one query head a program",
     )
+    parser.add_argument(
+        "--block-diffusion", type=int, default=0,
+        help="check the two-stream block rule at this block length IN PLACE "
+             "of the bands (--seq counts both streams)",
+    )
     parser.add_argument("--conv", type=int, choices=(0, 1), default=1)
     opts = parser.parse_args(argv)
     shape = (opts.seq, opts.heads, opts.kv_heads, opts.head_dim)
     S, H, KV, D = shape
     bands = [None if b == "none" else int(b) for b in opts.band]
+    if opts.block_diffusion:
+        bands = [Blocks(opts.block_diffusion)]
 
     keys = jax.random.split(jax.random.PRNGKey(0), 8)
     q, w = (jax.random.normal(x, (B, S, H, D), jnp.float32) for x in keys[:2])
@@ -189,11 +224,16 @@ def main(argv=None) -> int:
         return jax.jit(jax.value_and_grad(loss, (0, 1, 2), has_aux=True))
 
     def flash(q, k, v, band):
+        if isinstance(band, Blocks):
+            return flash_attention(q, k, v, block_diffusion=int(band))
         return flash_attention(q, k, v, causal=True, band=band)
 
     errors, kernels = {}, {}
     for band in bands:
         tag = "causal" if band is None else f"band_{band}"
+        family = "flash_gqa" if band is None else "flash_band"
+        if isinstance(band, Blocks):
+            tag, family = f"block_diffusion_{band}", "flash_bd"
         step = attention(flash, band)
         (_, out), grads = step(bf(q), bf(k), bf(v))
         (_, ref_out), ref_grads = attention(dense, band)(r(q), r(k), r(v))
@@ -203,7 +243,6 @@ def main(argv=None) -> int:
             for n, g, rg in zip(("dq", "dk", "dv"), grads, ref_grads)
         })
         del ref_out, ref_grads
-        family = "flash_gqa" if band is None else "flash_band"
         costs = {
             f"{family}_{kernel}": (
                 lambda _on_chip, kernel=kernel: attention_cost(

@@ -242,6 +242,26 @@ def band_kernels(device):
     )
 
 
+def bd_kernels(device):
+    """The block-diffusion kernels at the SDAR cell's shape: 32 query heads
+    over 4 kv heads of 128 (a whole group of eight a program), 2 x 4,096
+    positions (a noisy then a clean stream) in 16 x 16 tiles of 512 under
+    the two-stream rule with blocks of 4 — 80 tiles visited, a sweep of 9
+    key tiles a query tile and 16 query tiles a key tile — fwd+bwd."""
+    from dedloc_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return jnp.sum(
+            flash_attention(q, k, v, block_diffusion=4).astype(jnp.float32)
+        )
+
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 8192, 4, 128), jnp.bfloat16)
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_on_device(device, (q, kv, kv))
+    )
+
+
 def _lm_model_and_state(config: str, prefix: str):
     """(args, model, state, ids) of a causal-LM cell's recipe, from its
     configuration file's flags; ``<prefix>_LAYERS`` / ``<prefix>_BATCH`` in
@@ -289,7 +309,7 @@ def _ouro_model_and_state():
     return _lm_model_and_state("ouro_2p6b_s4096.json", "OURO")
 
 
-def _lm_accumulate_step(device, _args, model, state, ids):
+def _lm_accumulate_step(device, _args, model, state, ids, **more_of_batch):
     from dedloc_tpu.parallel.train_step import (
         make_accumulate_step,
         zeros_like_grads,
@@ -300,7 +320,8 @@ def _lm_accumulate_step(device, _args, model, state, ids):
     return make_accumulate_step(build_loss_fn(model)).lower(*_on_device(
         device,
         (state.params, grads, jnp.zeros([], jnp.int32),
-         {"input_ids": ids, "labels": ids}, jax.random.PRNGKey(0)),
+         dict(input_ids=ids, labels=ids, **more_of_batch),
+         jax.random.PRNGKey(0)),
     ))
 
 
@@ -336,6 +357,22 @@ def smallthinker_accumulate_step(device):
     return _lm_accumulate_step(device, *_lm_model_and_state(
         "smallthinker_21b_a3b_s16384.json", "SMALLTHINKER"
     ))
+
+
+def sdar_accumulate_step(device):
+    """SDAR-30B-A3B-Chat at one chip's share (``benchmark/configs/
+    sdar_30b_a3b_s4096.json``; ``SDAR_LAYERS`` / ``SDAR_BATCH`` size another
+    cut): four scanned layers over [noisy ; clean] = 2 x 4,096 positions —
+    the block-diffusion kernels at a group of eight, q / k normed per head,
+    every layer routed (SwiGLU experts, 16 of 128 held) — and the untied
+    chunked head with the weighted loss over the noisy stream's 4,096."""
+    args, model, state, ids = _lm_model_and_state(
+        "sdar_30b_a3b_s4096.json", "SDAR"
+    )
+    return _lm_accumulate_step(
+        device, args, model, state, ids,
+        loss_weights=jnp.zeros(ids.shape, jnp.float32),
+    )
 
 
 def ouro_guarded_apply_step(device):
@@ -377,7 +414,7 @@ def flash_fwd_forms(lowered_text: str) -> dict:
     site inside a scan body counts once; a remat replay is a site more."""
     calls = [
         line for line in lowered_text.splitlines()
-        if re.search(r'kernel_name = "flash_(causal_|mla_|gqa_|band_)?fwd"', line)
+        if re.search(r'kernel_name = "flash_(causal_|mla_|gqa_|band_|bd_)?fwd"', line)
     ]
     # the serialized kernel body on the same line is base64: no "_" in it
     one_tile = sum("one_tile" in line for line in calls)
@@ -462,9 +499,11 @@ def expert_grad_passes(hlo_text: str) -> dict:
 # rows they always did), and those with a routed expert layer, whose row
 # carries ``expert_grad_passes``
 COUNT_KERNEL_CALLS = {"gqa_kernels", "lfm2_accumulate_step", "band_kernels",
-                      "smallthinker_accumulate_step"}
+                      "smallthinker_accumulate_step", "bd_kernels",
+                      "sdar_accumulate_step"}
 COUNT_EXPERT_GRAD_PASSES = {"kanana_accumulate_step", "lfm2_accumulate_step",
-                            "smallthinker_accumulate_step"}
+                            "smallthinker_accumulate_step",
+                            "sdar_accumulate_step"}
 NO_V5E = 3  # exit code: nothing to compile with, which is not a failure
 
 
@@ -473,20 +512,28 @@ PROGRAMS = {
         accumulate_step, flat_apply_step, kernels, ouro_accumulate_step,
         ouro_guarded_apply_step, mla_kernels, kanana_accumulate_step,
         gqa_kernels, lfm2_accumulate_step, band_kernels,
-        smallthinker_accumulate_step,
+        smallthinker_accumulate_step, bd_kernels, sdar_accumulate_step,
     )
 }
 
 
-def main(argv=None) -> int:
-    names = list(argv if argv is not None else sys.argv[1:]) or list(PROGRAMS)
+def v5e_device():
+    """One (absent) device of a described v5e host, or None with the reason
+    on stderr where this jaxlib and libtpu cannot describe one."""
     try:
-        device = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
         ).devices[0]
     except Exception as e:  # whatever this jaxlib raises without libtpu
         print(f"no v5e:2x2 topology can be described here: {e!r}",
               file=sys.stderr)
+        return None
+
+
+def main(argv=None) -> int:
+    names = list(argv if argv is not None else sys.argv[1:]) or list(PROGRAMS)
+    device = v5e_device()
+    if device is None:
         return NO_V5E
     for name in names:
         with lowering_for_tpu():  # Mosaic kernels, not the interpreter
