@@ -373,6 +373,33 @@ def remat_policy_object(name: str):
         # recomputed. For a model whose STATE fills the chip (the looped
         # decoder, models/ouro.py: 17 MB a layer iteration)
         "kernel_outputs": _pallas_outputs_saveable,
+        # what a layer's Pallas BACKWARD kernels read as well as what the
+        # forward ones wrote: the flash operands q / k / v ("flash_qkv":
+        # after the q / k norm and RoPE, [B, S, H·D], the layout kernels
+        # and stash share) and the short convolution's B | C | u
+        # ("short_conv_bcu": ``in_proj``'s output as it wrote it) beside
+        # out + lse / y. The replay of a layer still runs the input norm
+        # (the projections' weight gradients read its output), the
+        # out-projection, the post-attention norm, the router and the
+        # routed loop; it loses RoPE, the relayouts around it and every
+        # projection whose output the kernel reads AS IT IS — q / k / v
+        # where nothing but RoPE (a linear map) lies between, every v,
+        # ``in_proj``. Behind a per-head q / k RMSNorm (SDAR, LFM2) the
+        # q and k matmuls STAY: the norm's backward reads the norm's
+        # input. Over "kernel_outputs", a layer a micro-batch:
+        # B·S·(H + 2·H_kv)·D·2 bytes for an attention (151 MB at
+        # SmallThinker's 16,384 x (28 + 2·4) x 128), B·S·3·hidden·2 for
+        # a convolution (50 MB at LFM2's 4,096 x 3 x 2,048). For the
+        # decoders whose state leaves that room (models/smallthinker.py,
+        # sdar_moe.py, lfm2_moe.py)
+        "kernel_operands": (
+            jax.checkpoint_policies.save_from_both_policies(
+                jax.checkpoint_policies.save_only_these_names(
+                    "flash_qkv", "short_conv_bcu"
+                ),
+                _pallas_outputs_saveable,
+            )
+        ),
         "dots": jax.checkpoint_policies.checkpoint_dots,
         "dots_no_batch": (
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable

@@ -103,7 +103,18 @@ class Lfm2MoeConfig:
     expert_shard: Tuple[int, int] = (0, 1)
     moe_row_tile: int = 256
     dtype: Any = jnp.bfloat16  # compute dtype; params stay fp32
-    remat_policy: str = "kernel_outputs"
+    # a name of albert.remat_policy_object's table. "kernel_operands": a
+    # conv layer keeps B | C | u as ``short_conv_bwd`` reads it beside y, so
+    # the backward's replay runs no ``in_proj``; the attention layer keeps
+    # q / k / v beside out + lse and loses v_proj, RoPE and the relayouts
+    # (q_proj and k_proj stay: the per-head RMSNorm's backward reads the
+    # norm's input): 4,096 x 3 x 2,048 x 2 bytes = 50 MB a conv layer a
+    # micro-batch and 4,096 x (32 + 2·8) x 64 x 2 = 25 MB the attention
+    # layer, 0.23 GB in the benchmark's cell, where accumulate_step's
+    # scratch reads 0.72 GB against 0.66 beside 13.14 GB of state while a
+    # backup drains. A smaller chip or a larger share:
+    # --training.remat_policy kernel_outputs
+    remat_policy: str = "kernel_operands"
     attention_impl: str = "flash"  # or "dense" (tests, tiny models)
     attention_block_size: int = 512
     loss_chunk_tokens: int = 512
@@ -223,6 +234,13 @@ class GroupedAttention(nn.Module):
         if cfg.attention_impl == "flash":
             from dedloc_tpu.ops.flash_attention import flash_attention
 
+            # the kernels' operands as buffers of their own: without the
+            # barrier XLA:TPU folds RoPE's last add + cast into each of
+            # their consumers and, under "kernel_operands", keeps the
+            # float32 pieces BEFORE that add for the backward (as in
+            # models/smallthinker.py and sdar_moe.py; accumulate_step's
+            # scratch 1.03 GB without it, 0.72 with it)
+            q, k, v = jax.lax.optimization_barrier((q, k, v))
             ctx = flash_attention(
                 q, k, v, causal=True, block_q=cfg.attention_block_size,
                 block_k=cfg.attention_block_size, mesh=cfg.mesh,

@@ -94,7 +94,17 @@ class SdarMoeConfig:
     expert_shard: Tuple[int, int] = (0, 1)
     moe_row_tile: int = 256
     dtype: Any = jnp.bfloat16  # compute dtype; params stay fp32
-    remat_policy: str = "kernel_outputs"
+    # a name of albert.remat_policy_object's table. "kernel_operands": the
+    # layer keeps q / k / v as the flash kernels read them (after the q / k
+    # norm and RoPE) beside out + lse, so the backward's replay runs no
+    # v projection, normalising multiply, RoPE or relayout; q_proj and
+    # k_proj STAY in it (the per-head RMSNorm's backward reads the norm's
+    # input): 8,192 x (32 + 2·4) x 128 x 2 bytes = 84 MB a layer a
+    # micro-batch, 0.34 GB in the benchmark's cell of four layers, where
+    # accumulate_step's scratch reads 1.28 GB (1.57 before the barrier in
+    # TwoStreamAttention) beside 12.78 GB of state while a backup drains. A
+    # smaller chip or a larger share: --training.remat_policy kernel_outputs
+    remat_policy: str = "kernel_operands"
     attention_impl: str = "flash"  # or "dense" (tests, tiny models)
     attention_block_size: int = 512
     loss_chunk_tokens: int = 512
@@ -187,6 +197,15 @@ class TwoStreamAttention(nn.Module):
         if cfg.attention_impl == "flash":
             from dedloc_tpu.ops.flash_attention import flash_attention
 
+            # the kernels' operands as buffers of their own. Without the
+            # barrier XLA:TPU folds RoPE's last add + cast into each of
+            # their consumers and relays the float32 pieces BEFORE that add
+            # out around every one (`copy`, `add_convert_fusion`, `reshape`:
+            # 20 ms a micro-batch in the benchmark's cell): accumulate_step
+            # 185.9 → 166.2 ms with the barrier alone, 159.9 with the
+            # operands kept too; scratch 2.55 GB without it under
+            # "kernel_operands", 1.28 with it (PERF.md section 6, PR 41)
+            q, k, v = jax.lax.optimization_barrier((q, k, v))
             ctx = flash_attention(
                 q, k, v, block_diffusion=cfg.block_length,
                 block_q=cfg.attention_block_size,

@@ -106,7 +106,16 @@ class SmallThinkerConfig:
     expert_shard: Tuple[int, int] = (0, 1)
     moe_row_tile: int = 256
     dtype: Any = jnp.bfloat16  # compute dtype; params stay fp32
-    remat_policy: str = "kernel_outputs"
+    # a name of albert.remat_policy_object's table. "kernel_operands": the
+    # layer keeps q / k / v as the flash kernels read them beside out + lse,
+    # so the backward's replay runs no q / k / v projection, RoPE or
+    # relayout (RoPE's own backward is linear: it needs no stash): 16,384 x
+    # (28 + 2·4) x 128 x 2 bytes = 151 MB a layer a micro-batch, 0.60 GB in
+    # the benchmark's cell of four layers, where accumulate_step's scratch
+    # reads 2.53 GB against 2.50 (the stash takes the place of the replay's
+    # own q / k / v) beside 10.38 GB of state while a backup drains. A
+    # smaller chip or a larger share: --training.remat_policy kernel_outputs
+    remat_policy: str = "kernel_operands"
     attention_impl: str = "flash"  # or "dense" (tests, tiny models)
     attention_block_size: int = 512
     loss_chunk_tokens: int = 512
@@ -205,6 +214,15 @@ class BandAttention(nn.Module):
         if cfg.attention_impl == "flash":
             from dedloc_tpu.ops.flash_attention import flash_attention
 
+            # the kernels' operands as buffers of their own. Without the
+            # barrier XLA:TPU folds RoPE's last add + cast into each of
+            # their consumers and relays the float32 pieces BEFORE that add
+            # out around every one (and under "kernel_operands" keeps THEM
+            # for the backward: 4x the bytes): accumulate_step 395.8 → 374.5
+            # ms a micro-batch in the benchmark's cell with the barrier
+            # alone, 361.8 with the operands kept too; scratch 4.00 GB
+            # without it, 2.53 with it (PERF.md section 6, PR 41)
+            q, k, v = jax.lax.optimization_barrier((q, k, v))
             ctx = flash_attention(
                 q, k, v, causal=True, band=band,
                 block_q=cfg.attention_block_size,

@@ -36,6 +36,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -252,14 +253,21 @@ def short_conv(bcu: jnp.ndarray, w: jnp.ndarray,
     (a Mosaic kernel cannot be partitioned by GSPMD)."""
     if interpret is None:
         interpret = pallas_interpret()
+
+    def local(x, taps):
+        # named where the kernels take it, as ``_flash_local`` names q / k /
+        # v: the ``kernel_operands`` remat policy keeps what the backward
+        # kernel reads, as ONE device sees it
+        return _short_conv(checkpoint_name(x, "short_conv_bcu"), taps,
+                           interpret)
+
     if mesh is not None:
         rows = P(mesh_axis(mesh, "data"), None, None)
-        op = jax.shard_map(
-            lambda x, taps: _short_conv(x, taps, interpret), mesh=mesh,
-            in_specs=(rows, P()), out_specs=rows, check_vma=False,
-        )
-        return op(bcu, w)
-    return _short_conv(bcu, w, interpret)
+        return jax.shard_map(
+            local, mesh=mesh, in_specs=(rows, P()), out_specs=rows,
+            check_vma=False,
+        )(bcu, w)
+    return local(bcu, w)
 
 
 def short_conv_reference(bcu, w):

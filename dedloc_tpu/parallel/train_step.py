@@ -27,6 +27,10 @@ import optax
 from flax import struct
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from dedloc_tpu.utils.logging import get_logger
+
+logger = get_logger(__name__)
+
 
 class TrainState(struct.PyTreeNode):
     """Model + optimizer state keyed by the GLOBAL collaboration step.
@@ -70,6 +74,42 @@ class GradSinkLoss:
 
 def zeros_like_grads(params):
     return jax.tree.map(lambda p: jnp.zeros_like(p, dtype=jnp.float32), params)
+
+
+def _residual_bytes(backward, arguments) -> int:
+    """Bytes of the leaves of ``backward`` (what ``jax.vjp`` returned: a
+    pytree of the residuals its forward kept) that are none of
+    ``arguments``' own leaves."""
+    held = {id(x) for x in jax.tree.leaves(arguments)}
+    kept = {
+        id(x): x for x in jax.tree.leaves(backward)
+        if isinstance(x, jax.Array) and id(x) not in held
+    }
+    return sum(x.size * x.dtype.itemsize for x in kept.values())
+
+
+def stash_bytes(loss_fn: LossFn, params, batch, rng) -> int:
+    """Bytes the forward of ONE micro-batch hands its backward besides the
+    arguments: the residuals of ``jax.vjp`` over ``loss_fn`` with respect to
+    ``params`` — under a layer remat policy the layer inputs and what the
+    policy keeps (``models/albert.remat_policy_object``), plus what lies
+    outside the remat'd layers (position tables, the final norm, the head's
+    chunks). From the shapes alone: ``params`` and ``batch`` may be
+    ``jax.ShapeDtypeStruct`` trees, nothing is allocated or run. It counts
+    what JAX's backward READS; which of it XLA keeps in HBM and which it
+    fuses into a producer is the compiler's (``memory_analysis``). The
+    accumulate step reads the same number off its own trace
+    (``make_accumulate_step``: ``step.gauges``)."""
+    found = {}
+
+    def forward(params, batch, rng):
+        _, backward, _ = jax.vjp(
+            lambda p: loss_fn(p, batch, rng), params, has_aux=True
+        )
+        found["bytes"] = _residual_bytes(backward, (params, batch, rng))
+
+    jax.eval_shape(forward, params, batch, rng)
+    return found["bytes"]
 
 
 def _marked_subtree(tree, mask):
@@ -133,8 +173,16 @@ def make_accumulate_step(
     new accumulator is the sink's cotangent, no ``a + g`` pass of its own.
     A marked leaf that no module read would come back zero: tracing the step
     raises on one.
+
+    ``step.gauges`` (a dict, filled when the step is traced): ``remat.
+    kept_bytes`` — the bytes one micro-batch's forward hands its backward
+    besides the step's arguments (``stash_bytes``: the mechanism's counter
+    of the model's layer remat policy), read off the trace itself.
     """
     sink_mask = getattr(loss_fn, "sink_mask", None) if mesh is None else None
+    # host-side readings of the step's last TRACE (no output of the program):
+    # on the returned step as ``step.gauges``, empty until it is traced
+    gauges: Dict[str, float] = {}
 
     # jitted programs carry stable names: a trace, an IR dump or a compile
     # event finds "accumulate_step" after any refactor
@@ -165,9 +213,14 @@ def make_accumulate_step(
                 grad_sinks=_noting_cotangents(sinks, reached),
             )
 
-        (_, metrics), (grads, sunk) = jax.value_and_grad(
-            loss, argnums=(0, 1), has_aux=True
-        )(params, sinks)
+        # ``jax.value_and_grad`` in its two halves (the same program), so
+        # that the trace can read what the forward keeps for the backward
+        value, backward, metrics = jax.vjp(loss, params, sinks, has_aux=True)
+        kept = _residual_bytes(backward, (params, grad_acc, batch, rng))
+        gauges["remat.kept_bytes"] = float(kept)
+        # once a trace, beside whatever the caller logs next
+        logger.info(f"accumulate_step traced: kept_bytes={kept}")
+        grads, sunk = backward(jnp.ones_like(value))
         sunk = dict(jax.tree_util.tree_flatten_with_path(sunk)[0])
         unread = [
             jax.tree_util.keystr(path) for path in sunk if path not in reached
@@ -199,7 +252,9 @@ def make_accumulate_step(
             in_shardings=(p_sh, p_sh, repl, data, repl),
             out_shardings=(p_sh, repl, repl),
         )
-    return jax.jit(accumulate_step, **kwargs)
+    step = jax.jit(accumulate_step, **kwargs)
+    step.gauges = gauges
+    return step
 
 
 def make_apply_step(

@@ -15,7 +15,7 @@ builds model, optimizer, DHT and state, and hands this loop a ``LoopModel``
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Iterator, Optional, Tuple
+from typing import Callable, Dict, Iterator, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -53,6 +53,10 @@ class LoopModel:
     # ``name``); and those that are counts, the step's total into a counter
     step_gauges: Tuple[str, ...] = ()
     step_counters: Tuple[str, ...] = ()
+    # gauges the builder's programs read on the HOST (off their own trace:
+    # no output of the device program), a live dict: what it holds goes
+    # onto every stepping record beside them
+    host_gauges: Dict[str, float] = dataclasses.field(default_factory=dict)
     # analytic model TFLOPs of one fwd+bwd sample, for the MFU gauge
     # (0: no gauge)
     tflops_per_sample: float = 0.0
@@ -156,6 +160,10 @@ def run_boundary_loop(
                                 srec.attrs[key] = float(value)
                                 if tele is not None:
                                     tele.gauge(key).set(float(value))  # dedlint: emits=gauge:lm.exit_prob.*,gauge:lm.loss.*,gauge:moe.load_max_over_mean.*,gauge:moe.local_slot_share,gauge:moe.bias_abs_max,gauge:moe.grad_sink_leaves,gauge:attn.band_tile_share,gauge:attn.bd_tile_share,gauge:diffusion.masked_share
+                        for key, value in model.host_gauges.items():
+                            srec.attrs[key] = value
+                            if tele is not None:
+                                tele.gauge(key).set(value)  # dedlint: emits=gauge:remat.kept_bytes
                         for name in model.step_counters:
                             srec.attrs[name] = float(sums[name])
                             if tele is not None:

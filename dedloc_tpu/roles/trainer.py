@@ -294,6 +294,9 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
     opt.seed_state_sharing(state)
 
     loss_fn = build_loss_fn(model)
+    # what the layers keep for their backward is a name of the table in
+    # models/albert.py; its bytes are the gauge ``remat.kept_bytes``
+    logger.info(f"remat: remat_policy={cfg.remat_policy}")
     accumulate = make_accumulate_step(
         loss_fn,
         mesh=mesh,
@@ -342,6 +345,8 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
             put=put if mesh is not None else None,
             step_gauges=family.step_gauges,
             step_counters=family.step_counters,
+            # ``remat.kept_bytes``, once the step has been traced
+            host_gauges=accumulate.gauges,
             # the MFU gauge uses the same analytic model-FLOPs formula and
             # peak table as bench.py
             tflops_per_sample=family.tflops_per_sample(cfg, seq),

@@ -108,6 +108,7 @@ OPT_WEIGHT_SCALE = "opt.weight_scale"
 PEER_ENDPOINT = "peer.endpoint"
 PLAN_SYNC_RETRIES = "plan_sync.retries"
 PLAN_SYNC_RETRY = "plan_sync.retry"
+REMAT_KEPT_BYTES = "remat.kept_bytes"
 RPC_CLIENT_CALLS = "rpc.client.calls"
 RPC_CLIENT_FAILURE = "rpc.client.failure"
 RPC_CLIENT_FAILURES = "rpc.client.failures"
@@ -246,6 +247,7 @@ GAUGES = frozenset({
     "opt.ef_residual_norm",
     "opt.overlap_efficiency",
     "opt.weight_scale",
+    "remat.kept_bytes",
     "serve.known_experts",
     "step.mfu",
     "step.samples_per_sec",

@@ -301,6 +301,8 @@ def test_two_width_kernels_contract_over_a_heads_own_lane_tiles():
     # the routed loop's backward sums into the accumulator's expert leaves
     # (gradient sinks): no add pass of its own over one (3 before PR 33)
     assert rows["kanana_accumulate_step"]["expert_grad_passes"]["adds"] == 0
+    # 0.05 GB under the line: the layers keep the kernels' OUTPUTS alone
+    assert rows["kanana_accumulate_step"]["remat_policy"] == "kernel_outputs"
 
 
 def test_accumulate_step_has_no_relayout_copies_around_flash_attention():
@@ -335,6 +337,7 @@ def test_ouro_accumulate_step_keeps_the_flash_outputs_and_fits_the_cap():
     snapshot's 9.96 GB of state stays under the 15.3 GB the cell is sized
     by; a policy that also kept ``flash_qkv`` would read 6.2 GB here."""
     row = _tpu_aot("ouro_accumulate_step")["ouro_accumulate_step"]
+    assert row["remat_policy"] == "kernel_outputs"
     assert row["flash_fwd_forms"] == {"one_tile": 0, "tiles": 1}
     assert row["flash_windows"] == {  # D=128: a head is one lane tile
         name: "block" for name in (
@@ -377,6 +380,13 @@ def test_lfm2_accumulate_step_keeps_what_its_backward_reads():
     # and the scratch those buffers took is gone (1,170,841,600 before)
     assert row["expert_grad_passes"] == {"adds": 0, "zero_fills": 0}
     assert row["memory"]["temp_bytes"] <= 1_170_841_600
+    # since PR 41 the conv layers keep B | C | u and the attention layer
+    # q / k / v for their backward kernels (remat ``kernel_operands``: the
+    # call sites above are unchanged): 721,006,080 bytes of scratch against
+    # 657,255,424 under ``kernel_outputs`` (1,028,988,928 without the
+    # barrier before the flash call)
+    assert row["remat_policy"] == "kernel_operands"
+    assert row["memory"]["temp_bytes"] <= 0.76e9
 
 
 def test_smallthinker_accumulate_step_takes_the_band_and_a_group_of_seven():
@@ -411,6 +421,14 @@ def test_smallthinker_accumulate_step_takes_the_band_and_a_group_of_seven():
     assert row["flash_fwd_forms"] == {"one_tile": 0, "tiles": 4}
     assert row["expert_grad_passes"] == {"adds": 0, "zero_fills": 0}
     assert 370_547_200 * 28 + row["memory"]["temp_bytes"] <= 15.3e9
+    # since PR 41 the layers keep q / k / v for their backward kernels
+    # (remat ``kernel_operands``: still no kernel replayed, above) as the
+    # bf16 buffers the kernels read: 2,530,225,152 bytes of scratch against
+    # 2,504,165,376 under ``kernel_outputs``. Without the barrier before the
+    # flash call XLA keeps the float32 pieces of RoPE's last add instead
+    # (4,004,325,376)
+    assert row["remat_policy"] == "kernel_operands"
+    assert row["memory"]["temp_bytes"] <= 2.6e9
 
 
 def test_sdar_accumulate_step_takes_the_block_rule_and_a_group_of_eight():
@@ -443,3 +461,9 @@ def test_sdar_accumulate_step_takes_the_block_rule_and_a_group_of_eight():
     assert row["expert_grad_passes"] == {"adds": 0, "zero_fills": 0}
     assert row["layer_body_copies"] == []
     assert 456_346_624 * 28 + row["memory"]["temp_bytes"] <= 15.3e9
+    # since PR 41 the layers keep q / k / v for their backward kernels (remat
+    # ``kernel_operands``) behind a barrier that makes them buffers of their
+    # own: 1,282,795,008 bytes of scratch — UNDER the 1,574,085,632 the
+    # program read before either (2,545,200,128 without the barrier)
+    assert row["remat_policy"] == "kernel_operands"
+    assert row["memory"]["temp_bytes"] <= 1.35e9

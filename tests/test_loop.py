@@ -21,6 +21,7 @@ from dedloc_tpu.core.config import (
     parse_config,
 )
 from dedloc_tpu.dht.dht import DHT
+from dedloc_tpu.roles.common import model_family
 from dedloc_tpu.telemetry import registry
 from dedloc_tpu.telemetry.registry import Telemetry
 from dedloc_tpu.utils.checkpoint import list_checkpoints
@@ -225,6 +226,31 @@ def test_record_is_one_span_tree_and_the_loss_is_read_once_a_global_step(runs):
         if m.startswith("global step ") and ": loss " in m
     ]
     assert logged == pytest.approx(first.losses, abs=1e-4)
+
+
+def test_the_trace_s_gauge_is_on_every_stepping_record(runs):
+    """``remat.kept_bytes``: read on the host off the accumulate step's own
+    trace (no output of the device program), logged when the step is traced
+    — the policy's name at build — and stamped on every record that made a
+    global step; SwAV's role has no remat'd layer and no such gauge."""
+    family, first, _second, _saved = runs
+    stepping = [r for r in first.records if r.get("stepped")]
+    policy = [m for m in first.messages() if m.startswith("remat: ")]
+    traced = [
+        m for m in first.messages() if m.startswith("accumulate_step traced")
+    ]
+    if family == "swav":
+        assert not policy and not traced
+        assert not any("remat.kept_bytes" in r for r in stepping)
+        return
+    cfg = model_family(MODEL_SIZE[family]).config.named(MODEL_SIZE[family])()
+    assert policy == [f"remat: remat_policy={cfg.remat_policy}"]
+    (line,) = traced  # one shape of batch, one trace
+    kept = int(line.rsplit("kept_bytes=", 1)[1])
+    assert kept > 0
+    assert [r["remat.kept_bytes"] for r in stepping] == [
+        float(kept)
+    ] * len(stepping)
 
 
 def test_max_local_steps_ends_the_run_and_shuts_everything_down(runs):

@@ -14,6 +14,7 @@ import pytest
 from dedloc_tpu.core.config import CollaborationArguments, parse_config
 from dedloc_tpu.models.deepseek_v3 import EXPERT_LEAVES
 from dedloc_tpu.models.smallthinker import SmallThinkerConfig
+from dedloc_tpu.parallel.train_step import stash_bytes
 from dedloc_tpu.roles.common import (
     DEEPSEEK_V3,
     SMALLTHINKER,
@@ -78,6 +79,17 @@ def test_smallthinker_tiny_trainer_makes_global_steps(tmp_path, shard, layers):
         assert "moe.bias_abs_max" not in rec  # no bias leaf, no sign step
     losses = [rec["loss"] for rec in stepped if "loss" in rec]
     assert all(np.isfinite(losses))
+    # the remat policy's counter: what the builder read from the shapes
+    cfg, model = build_model(
+        "smallthinker_tiny", num_hidden_layers=int(layers), expert_shard=shard
+    )
+    assert cfg.remat_policy == "kernel_operands"
+    kept = stash_bytes(  # the same number, from the shapes alone
+        build_loss_fn(model), state.params,
+        next(SMALLTHINKER.synthetic_batches(cfg, 2, 32, 0)),
+        jax.random.PRNGKey(0),
+    )
+    assert {rec["remat.kept_bytes"] for rec in stepped} == {float(kept)}
 
 
 def test_the_table_builds_the_band_and_global_decoder():

@@ -14,7 +14,11 @@ speed or numerics; those need the chip (``chip_smoke.py``).
 Each line: {"program", "compile_s", "tpu_custom_calls", "flash_fwd_forms",
 "flash_windows", "layer_body_copies", "memory"} (and, for the programs of
 ``COUNT_KERNEL_CALLS``, "kernel_calls": call sites by kernel name; for the
-two expert programs, "expert_grad_passes": ``expert_grad_passes``' counts) —
+expert programs, "expert_grad_passes": ``expert_grad_passes``' counts; for
+the five decoder programs of ``LM_CELLS``, "remat_policy": the layer policy
+they were built under — the model's default, or ``<PREFIX>_REMAT`` from the
+environment, e.g. ``SMALLTHINKER_REMAT=kernel_outputs``: read ``memory``'s
+``temp_bytes`` under both before asking a chip for the stash's room) —
 ``flash_windows`` is each flash kernel's lane window beside its column
 block, from the call's metadata (``"block"`` for a call that carries none:
 D=64, D=128; its head counts for a grouped-query call);
@@ -58,6 +62,7 @@ showed WHY before the chip did: MXU slots 49 % full, no unit saturated.
 """
 from __future__ import annotations
 
+import functools
 import json
 import os
 import re
@@ -262,10 +267,13 @@ def bd_kernels(device):
     )
 
 
+@functools.lru_cache(maxsize=None)
 def _lm_model_and_state(config: str, prefix: str):
     """(args, model, state, ids) of a causal-LM cell's recipe, from its
     configuration file's flags; ``<prefix>_LAYERS`` / ``<prefix>_BATCH`` in
-    the environment size another cut."""
+    the environment size another cut, ``<prefix>_REMAT`` names another row
+    of the layer remat policy table (``--training.remat_policy``; the
+    model's own default without it)."""
     from dedloc_tpu.core.config import CollaborationArguments, parse_config
     from dedloc_tpu.parallel.train_step import TrainState
     from dedloc_tpu.roles.common import build_model, build_optimizer
@@ -286,7 +294,8 @@ def _lm_model_and_state(config: str, prefix: str):
     )
     t = args.training
     _cfg, model = build_model(
-        t.model_size, vocab_size=t.vocab_size,
+        t.model_size, os.environ.get(f"{prefix}_REMAT", t.remat_policy),
+        vocab_size=t.vocab_size,
         num_hidden_layers=t.num_hidden_layers, expert_shard=t.expert_shard,
     )
     state = jax.eval_shape(
@@ -298,6 +307,19 @@ def _lm_model_and_state(config: str, prefix: str):
     return args, model, state, ids
 
 
+# the decoder cells' accumulate programs: (configuration file, prefix of the
+# environment's overrides). Their rows carry ``remat_policy``
+LM_CELLS = {
+    "ouro_accumulate_step": ("ouro_2p6b_s4096.json", "OURO"),
+    "kanana_accumulate_step": ("kanana2_30b_a3b_s4096.json", "KANANA"),
+    "lfm2_accumulate_step": ("lfm2_24b_a2b_s4096.json", "LFM2"),
+    "smallthinker_accumulate_step": (
+        "smallthinker_21b_a3b_s16384.json", "SMALLTHINKER"
+    ),
+    "sdar_accumulate_step": ("sdar_30b_a3b_s4096.json", "SDAR"),
+}
+
+
 def ouro_accumulate_step(device):
     """Ouro-2.6B cut in depth: causal flash attention at D=128 over 8 x 8
     tiles, the scan over layers inside the scan over four passes, the
@@ -306,7 +328,7 @@ def ouro_accumulate_step(device):
 
 
 def _ouro_model_and_state():
-    return _lm_model_and_state("ouro_2p6b_s4096.json", "OURO")
+    return _lm_model_and_state(*LM_CELLS["ouro_accumulate_step"])
 
 
 def _lm_accumulate_step(device, _args, model, state, ids, **more_of_batch):
@@ -332,7 +354,7 @@ def kanana_accumulate_step(device):
     causal kernels over 8 x 8 tiles, the dense layer and the scanned expert
     layers with the routed tile loop, the chunked head."""
     return _lm_accumulate_step(device, *_lm_model_and_state(
-        "kanana2_30b_a3b_s4096.json", "KANANA"
+        *LM_CELLS["kanana_accumulate_step"]
     ))
 
 
@@ -343,7 +365,7 @@ def lfm2_accumulate_step(device):
     dense layer and one scanned period of expert layers, the tied chunked
     head."""
     return _lm_accumulate_step(device, *_lm_model_and_state(
-        "lfm2_24b_a2b_s4096.json", "LFM2"
+        *LM_CELLS["lfm2_accumulate_step"]
     ))
 
 
@@ -355,7 +377,7 @@ def smallthinker_accumulate_step(device):
     layer routed (ReGLU experts, the router fed before attention), the
     untied chunked head."""
     return _lm_accumulate_step(device, *_lm_model_and_state(
-        "smallthinker_21b_a3b_s16384.json", "SMALLTHINKER"
+        *LM_CELLS["smallthinker_accumulate_step"]
     ))
 
 
@@ -367,7 +389,7 @@ def sdar_accumulate_step(device):
     every layer routed (SwiGLU experts, 16 of 128 held) — and the untied
     chunked head with the weighted loss over the noisy stream's 4,096."""
     args, model, state, ids = _lm_model_and_state(
-        "sdar_30b_a3b_s4096.json", "SDAR"
+        *LM_CELLS["sdar_accumulate_step"]
     )
     return _lm_accumulate_step(
         device, args, model, state, ids,
@@ -550,6 +572,12 @@ def main(argv=None) -> int:
         )
         if name in COUNT_EXPERT_GRAD_PASSES:
             extra["expert_grad_passes"] = expert_grad_passes(compiled_text)
+        if name in LM_CELLS:
+            # the layer policy the program was built under; ``memory`` below
+            # is what it costs (``temp_bytes``: the stash is inside it)
+            extra["remat_policy"] = _lm_model_and_state(
+                *LM_CELLS[name]
+            )[1].cfg.remat_policy
         print(json.dumps({
             "program": name,
             "device_kind": device.device_kind,
