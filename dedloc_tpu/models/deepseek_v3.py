@@ -393,6 +393,7 @@ def deepseek_v3_loss(model: DeepseekV3ForCausalLM, params,
             load, axis=1
         ),
         "moe.local_slot_share": jnp.mean(routing["local_slot_share"]),
+        "moe.bulk_row_share": jnp.mean(routing["bulk_row_share"]),
         "moe.bias_abs_max": jnp.max(jnp.abs(
             params["layers"]["block"]["mlp"][BIAS]
         )),

@@ -404,6 +404,7 @@ def lfm2_moe_loss(model: Lfm2MoeForCausalLM, params,
             load, axis=1
         ),
         "moe.local_slot_share": jnp.mean(routing["local_slot_share"]),
+        "moe.bulk_row_share": jnp.mean(routing["bulk_row_share"]),
         "moe.bias_abs_max": jnp.max(jnp.stack([
             jnp.max(jnp.abs(leaf))
             for path, leaf in jax.tree_util.tree_leaves_with_path(params)

@@ -374,6 +374,7 @@ def sdar_moe_loss(model: SdarMoeForDiffusionLM, params,
             load, axis=1
         ),
         "moe.local_slot_share": jnp.mean(routing["local_slot_share"]),
+        "moe.bulk_row_share": jnp.mean(routing["bulk_row_share"]),
         "moe.dropped_slots": jnp.sum(routing["dropped_slots"]),
         "moe.grad_sink_leaves": jnp.sum(routing["grad_sink_leaves"]),
         "attn.bd_tile_share": jnp.float32(

@@ -49,6 +49,53 @@ adds a gradient into a running sum anyway (``parallel/train_step.
 make_accumulate_step``) takes the sink's cotangent as its new leaf: no zero
 fill, no second pass over a held matrix to add it.
 
+**The walk: runs of tiles, then tails.** Rows sorted by expert lie in RUNS,
+and an iteration pays some costs whatever its rows: the expert's three bf16
+matrices are read (6·HF bytes in the forward, H x F the matrix, 6·HF again in
+the backward) and the three float32 gradient slices are read and written
+under their weight-gradient dots (24·HF; XLA fuses ``old + term`` into the
+dot, so no ``term`` is stored) — 36·HF, against 12 matmul-sized passes of
+2·rows·HF FLOPs. On a v5e (197 TFLOP/s over 819 GB/s: 240 FLOP a byte) a
+weight-gradient dot that contracts over 256 rows has 64 FLOPs a byte of its
+slice and is bound by the slice's bytes, the row-wise matmuls have 256 a byte
+of their matrix and sit at the ridge; at 1,024 rows both are the MXU's. On
+the chip an iteration costs ~0.12 ms before its first row in SmallThinker's
+layer (PERF.md section 6, PR 42). So the loops walk a group of p padded
+tiles as p // m BULK iterations of m consecutive tiles of that one expert,
+then p % m TAIL iterations of one tile, m = ``RUN_TILES`` = 2: pairs of 512
+rows at the cells' tile of 256, then at most one single tile
+(``_run_schedule``: the bulk and the tail iterations' start rows, two small
+int32 schedules at their static bounds, and the two dynamic counts) — ONE
+body in two ``fori_loop``s, which differ in one static integer. Why pairs
+and not the 1,024 rows this arithmetic asks for, measured (PERF.md section
+6): iterations of four tiles alone are the WORST walk where a balanced
+expert holds two to four tiles (SDAR, LFM2: nothing reaches four); above
+1,024 rows an iteration's temporaries push the layer's accumulators out of
+fast memory and the walk LOSES (SDAR); one uniform tile of 512 or 1,024
+pads every near-empty expert to it; and a third loop (fours, pairs, one)
+read within 0.4 % of pairs in SmallThinker's step for a loop body more to
+trace, five times a start. The plan
+does not change: a group is still padded to ``tile`` rows (a near-empty
+expert pays one tile, as before), the same static bound, no capacity, no
+dropped slot; matrices stay bf16 operands under float32 accumulation; a
+bulk iteration's weight gradient is ONE float32-accumulating dot over its
+rows where the single-size walk added m partial sums in float32 (another
+order of the same additions), summed into the sinks or the zeroed buffers
+as before. ``stats['bulk_row_share']`` says how much of the work the bulk
+iterations took; a routing whose groups stay at one tile runs the tail loop
+alone (the bulk loop's trip count is 0). A row's value can move in
+float32's last digits with WHO shares its iteration (the blocking of its
+matmuls: 6e-7 on the CPU, ``tests/test_sdar_model.py``), where the
+single-size walk (``run_tiles=1``) gives a row the same bits whatever the
+other rows do.
+
+**Element-at-a-time passes.** A gather or scatter of scalars is serial on a
+TPU (~9 ns an element), and the plan's static bound is T · k slots however
+few are held: so the plan takes its sorted keys from the ONE sort that gives
+the order, counts the groups by compare-and-sum, and the loops gather a
+row's weight — and scatter its gradient — per iteration, over the rows in
+use. What is left over the bound is the scatter of the order into rows.
+
 The bias is not trained by a gradient. ``with_load_cotangent`` defines the
 bias leaf's COTANGENT as ``load_e − mean load`` of the micro-batch (``load``:
 each expert's share of the routed (token, slot) pairs), so the statistic
@@ -224,28 +271,79 @@ def _load_bwd(load, g):
 with_load_cotangent.defvjp(_load_fwd, _load_bwd)
 
 
-def _tile_plan(choice, held: Tuple[int, int], tile: int):
+# consecutive tiles of ONE expert a bulk iteration of the walk takes (the
+# module docstring says why 2; PERF.md section 6, PR 42, what else was read
+# on the chip). 1 is the single-size walk, one tile an iteration.
+RUN_TILES = 2
+
+
+def _run_schedule(sizes, padded, tile: int, m: int, rows: int):
+    """How the loops walk the plan's groups (``sizes`` real and ``padded``
+    padded rows a held expert, ``rows`` the plan's static bound): a group of
+    p padded tiles is p // m BULK iterations of ``m`` consecutive tiles, then
+    p % m TAIL iterations of one.
+
+    Returns the walk — ``(starts, count)`` for the bulk and for the tail
+    iterations: every iteration's start row at the static bound (``rows //
+    (m · tile)``; at most m − 1 tails a group) and how many there are — in
+    which every padded tile in use lies in exactly one iteration and an
+    iteration in one group; and ``bulk_rows``, the real rows the bulk
+    iterations hold."""
+    count = sizes.shape[0]
+    bulk, tail = padded // tile // m, padded // tile % m
+    first = jnp.cumsum(padded) - padded  # a group's first row
+
+    def schedule(counts, at, step, bound):
+        ends = jnp.cumsum(counts)
+        i = jnp.arange(bound, dtype=jnp.int32)
+        group = jnp.minimum(  # compare_all: no loop for 16 boundaries
+            jnp.searchsorted(ends, i, side="right", method="compare_all"),
+            count - 1,
+        )
+        starts = at[group] + (i - (ends - counts)[group]) * step
+        return starts.astype(jnp.int32), ends[-1]
+
+    # a group's bulk iterations come first and hold its first rows
+    return (
+        schedule(bulk, first, m * tile, rows // (m * tile)),
+        schedule(tail, first + bulk * (m * tile), tile,
+                 min(rows // tile, count * (m - 1))),
+    ), jnp.sum(jnp.minimum(sizes, bulk * (m * tile)))
+
+
+def _tile_plan(choice, held: Tuple[int, int], tile: int,
+               m: int = RUN_TILES):
     """Where every (token, slot) pair that chose a held expert goes: rows
     sorted by expert, each expert's group padded to whole tiles.
 
     Returns ``row_slot`` [R] (the flat slot index t·k + j of each row, -1
     for padding; R = the static worst case), ``tile_expert`` [R / tile]
-    (the LOCAL expert of each tile), ``tiles`` (how many are in use) and
+    (the LOCAL expert of each tile), ``tiles`` (how many are in use),
     ``dropped`` (valid pairs that found no row: 0 by construction, counted
-    so that a run can say so)."""
+    so that a run can say so) and ``_run_schedule``'s pair: the walk over
+    the tiles in use at ``m`` tiles a bulk iteration, and the real rows the
+    bulk iterations hold."""
     first, count = held
     tokens, k = choice.shape
     local = choice.reshape(-1) - first
     valid = (local >= 0) & (local < count)
     key = jnp.where(valid, local, count)  # absent experts sort last
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+    # ONE sort gives the order and the sorted keys (a gather of T · k
+    # elements otherwise), a compare-and-sum the group sizes (a scatter-add
+    # of T · k otherwise): element-at-a-time ops on a TPU, ~9 ns each
+    sorted_key, order = jax.lax.sort(
+        (key, jnp.arange(tokens * k, dtype=jnp.int32)), num_keys=1,
+        is_stable=True,
+    )
+    sizes = jnp.sum(
+        key[:, None] == jnp.arange(count, dtype=key.dtype), axis=0,
+        dtype=jnp.int32,
+    )
     padded = (sizes + tile - 1) // tile * tile
     ends, padded_ends = jnp.cumsum(sizes), jnp.cumsum(padded)
     # a token chooses an expert once: at most tokens · min(k, count) rows
     rows = tokens * min(k, count) + count * tile
     rows = (rows + tile - 1) // tile * tile
-    sorted_key = key[order]
     group = jnp.minimum(sorted_key, count - 1)
     rank = jnp.arange(tokens * k, dtype=jnp.int32) - (ends - sizes)[group]
     position = jnp.where(
@@ -263,7 +361,9 @@ def _tile_plan(choice, held: Tuple[int, int], tile: int):
     ).astype(jnp.int32)
     tiles = padded_ends[-1] // tile
     dropped = jnp.sum(valid) - jnp.sum(row_slot >= 0)
-    return row_slot, tile_expert, tiles, dropped
+    return row_slot, tile_expert, tiles, dropped, _run_schedule(
+        sizes, padded, tile, m, rows
+    )
 
 
 def _silu_bwd(g, u, d_hidden):
@@ -293,62 +393,93 @@ def _tile_forward(x, gate, up, down, tokens, activation):
     return rows, g, u, hidden, out
 
 
-def _tile_operands(t, tile, row_token, row_weight, tile_expert, weights):
-    tokens = jax.lax.dynamic_slice(row_token, (t * tile,), (tile,))
-    scale = jax.lax.dynamic_slice(row_weight, (t * tile,), (tile,))
-    expert = tile_expert[t]
-    return tokens, scale, tuple(
+def _tile_operands(start, rows, plan, slot_weight, weights):
+    """An iteration's ``rows`` rows from ``start`` on (whole tiles of ONE
+    expert): their flat slots (out of range for padding), tokens and
+    weights — gathered HERE, from the rows in use, not over the plan's
+    static bound —, that expert's matrices and its index."""
+    row_slot, tile_expert, k, tile = plan
+    slots = jax.lax.dynamic_slice(row_slot, (start,), (rows,))
+    real = slots >= 0
+    at = jnp.maximum(slots, 0)
+    scale = jnp.where(
+        real, slot_weight.at[at].get(mode="promise_in_bounds"), 0.0
+    )
+    expert = tile_expert[start // tile]
+    return jnp.where(real, slots, slot_weight.size), at // k, scale, tuple(
         jax.lax.dynamic_index_in_dim(w, expert, keepdims=False)
         for w in weights
     ), expert
 
 
+def _walk(body, carry, walk, tile: int, m: int):
+    """``body(start, rows, carry)`` over the walk's iterations: the bulk
+    loop (``m · tile`` rows an iteration), then the tail loop (``tile``),
+    its carry starting from the first's — one body, two loops of a dynamic
+    trip count, which differ in one static integer. A loop whose static
+    bound is 0 (no tails at m = 1; a plan smaller than a bulk iteration)
+    is not built."""
+    for (starts, count), rows in zip(walk, (m * tile, tile)):
+        if starts.size:
+            carry = jax.lax.fori_loop(
+                0, count,
+                lambda i, c, s=starts, r=rows: body(s[i], r, c), carry,
+            )
+    return carry
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10))
-def _grouped_swiglu(x, row_weight, gate, up, down, sinks, row_token,
-                    tile_expert, tiles, tile, activation):
-    """``sinks``: None, or float32 (gate, up, down)-shaped buffers the
-    forward ignores and the backward accumulates into. ``activation``: the
-    gate's, a name of ``ACTIVATIONS`` (the function keeps its first name)."""
+def _grouped_swiglu(x, slot_weight, gate, up, down, sinks, row_slot,
+                    tile_expert, walk, shape, activation):
+    """``slot_weight`` [T · k]: every slot's weight, flat; ``row_slot`` /
+    ``tile_expert``: ``_tile_plan``'s; ``sinks``: None, or float32 (gate, up,
+    down)-shaped buffers the forward ignores and the backward accumulates
+    into; ``walk``: ``_run_schedule``'s; ``shape``: (k, tile, tiles a bulk
+    iteration); ``activation``: the gate's, a name of ``ACTIVATIONS`` (the
+    function keeps its first name)."""
     out, _ = _grouped_swiglu_fwd(
-        x, row_weight, gate, up, down, sinks, row_token, tile_expert, tiles,
-        tile, activation,
+        x, slot_weight, gate, up, down, sinks, row_slot, tile_expert, walk,
+        shape, activation,
     )
     return out
 
 
-def _grouped_swiglu_fwd(x, row_weight, gate, up, down, sinks, row_token,
-                        tile_expert, tiles, tile, activation):
-    def body(t, total):
-        tokens, scale, weights, _e = _tile_operands(
-            t, tile, row_token, row_weight, tile_expert, (gate, up, down)
+def _grouped_swiglu_fwd(x, slot_weight, gate, up, down, sinks, row_slot,
+                        tile_expert, walk, shape, activation):
+    plan = (row_slot, tile_expert, *shape[:2])
+
+    def body(start, rows, total):
+        _slots, tokens, scale, weights, _e = _tile_operands(
+            start, rows, plan, slot_weight, (gate, up, down)
         )
         out = _tile_forward(x, *weights, tokens, activation)[-1]
         return total.at[tokens].add(out * scale[:, None])
 
     with jax.named_scope("moe_routed"):
-        total = jax.lax.fori_loop(
-            0, tiles, body, jnp.zeros(x.shape, jnp.float32)
+        total = _walk(
+            body, jnp.zeros(x.shape, jnp.float32), walk, *shape[1:]
         )
-    return total, (x, row_weight, gate, up, down, sinks, row_token,
-                   tile_expert, tiles)
+    return total, (x, slot_weight, gate, up, down, sinks, row_slot,
+                   tile_expert, walk)
 
 
-def _grouped_swiglu_bwd(tile, activation, residuals, d_total):
-    (x, row_weight, gate, up, down, sinks, row_token, tile_expert,
-     tiles) = residuals
+def _grouped_swiglu_bwd(shape, activation, residuals, d_total):
+    (x, slot_weight, gate, up, down, sinks, row_slot, tile_expert,
+     walk) = residuals
     held = (gate, up, down)
+    plan = (row_slot, tile_expert, *shape[:2])
 
-    def body(t, carry):
+    def body(start, rows, carry):
         dx, d_weight, d_gate, d_up, d_down = carry
-        tokens, scale, (w_gate, w_up, w_down), expert = _tile_operands(
-            t, tile, row_token, row_weight, tile_expert, held
+        slots, tokens, scale, (w_gate, w_up, w_down), expert = (
+            _tile_operands(start, rows, plan, slot_weight, held)
         )
         rows, g, u, hidden, out = _tile_forward(
             x, w_gate, w_up, w_down, tokens, activation
         )
         d_scaled = d_total.at[tokens].get(mode="promise_in_bounds")
-        d_weight = jax.lax.dynamic_update_slice(
-            d_weight, jnp.sum(d_scaled * out, axis=-1), (t * tile,)
+        d_weight = d_weight.at[slots].set(  # a slot has one row
+            jnp.sum(d_scaled * out, axis=-1), mode="drop"
         )
         d_out = (d_scaled * scale[:, None]).astype(x.dtype)
         d_hidden = jax.lax.dot_general(
@@ -383,15 +514,15 @@ def _grouped_swiglu_bwd(tile, activation, residuals, d_total):
         )
 
     with jax.named_scope("moe_routed"):
-        dx, d_weight, d_gate, d_up, d_down = jax.lax.fori_loop(
-            0, tiles, body, (
+        dx, d_weight, d_gate, d_up, d_down = _walk(
+            body, (
                 jnp.zeros(x.shape, jnp.float32),
-                jnp.zeros(row_weight.shape, jnp.float32),
+                jnp.zeros(slot_weight.shape, jnp.float32),
                 # the one difference a sink makes: where the sums start
                 *(sinks if sinks is not None else (
                     jnp.zeros(w.shape, jnp.float32) for w in held
                 )),
-            ),
+            ), walk, *shape[1:],
         )
     summed = (d_gate, d_up, d_down)
     if sinks is None:
@@ -403,7 +534,8 @@ def _grouped_swiglu_bwd(tile, activation, residuals, d_total):
     int_zero = lambda a: np.zeros(a.shape, jax.dtypes.float0)  # noqa: E731
     return (
         dx.astype(x.dtype), d_weight, *d_held, d_sinks,
-        int_zero(row_token), int_zero(tile_expert), int_zero(tiles),
+        int_zero(row_slot), int_zero(tile_expert),
+        jax.tree.map(int_zero, walk),
     )
 
 
@@ -412,7 +544,7 @@ _grouped_swiglu.defvjp(_grouped_swiglu_fwd, _grouped_swiglu_bwd)
 
 def routed_experts(x, choice, weights, gate, up, down,
                    held: Tuple[int, int], tile: int = 256, grad_sinks=None,
-                   activation: str = "silu"):
+                   activation: str = "silu", run_tiles: int = RUN_TILES):
     """The held experts' part of Σ_{e in choice} w_e · GLU_e(x), with
     GLU_e(x) = down_e(act(gate_e x) ⊙ up_e x) and ``activation`` the gate's:
     "silu" (SwiGLU) or "relu" (ReGLU).
@@ -423,27 +555,34 @@ def routed_experts(x, choice, weights, gate, up, down,
     HELD experts' matrices in the compute dtype, expert ``held[0] + i`` at
     index i; ``grad_sinks``: None, or three float32 buffers of the held
     matrices' shapes whose COTANGENT is ``sink + d matrix`` while the
-    matrices' own is zero (the module docstring says who wants that).
+    matrices' own is zero (the module docstring says who wants that);
+    ``run_tiles``: consecutive tiles of one expert a bulk iteration of the
+    walk takes — the module's, but for a test or ``tools/
+    chip_routed_check.py`` (1: the single-size walk).
     Returns (y [T, H] float32, stats): ``stats['local_slot_share']``
     the share of routed slots that chose a held expert,
     ``stats['dropped_slots']`` the valid slots the plan lost (0),
-    ``stats['grad_sink_leaves']`` the sinks the loop was handed (3 or 0)."""
+    ``stats['grad_sink_leaves']`` the sinks the loop was handed (3 or 0),
+    ``stats['bulk_row_share']`` the share of those slots' rows that the
+    walk's bulk iterations took (0.0 where no held expert drew
+    ``run_tiles`` tiles of rows)."""
     tile = min(tile, max(8, x.shape[0]))
     with jax.named_scope("moe_routed"):
-        row_slot, tile_expert, tiles, dropped = _tile_plan(choice, held, tile)
-        real = row_slot >= 0
-        slot = jnp.maximum(row_slot, 0)
-        row_token = slot // choice.shape[1]
-        row_weight = jnp.where(real, weights.reshape(-1)[slot], 0.0)
+        row_slot, tile_expert, _tiles, dropped, (walk, bulk_rows) = (
+            _tile_plan(choice, held, tile, run_tiles)
+        )
+        real = jnp.sum(row_slot >= 0)
     y = _grouped_swiglu(
-        x, row_weight, gate, up, down,
+        x, weights.reshape(-1).astype(jnp.float32), gate, up, down,
         None if grad_sinks is None else tuple(grad_sinks),
-        row_token, tile_expert, tiles, tile, activation,
+        row_slot, tile_expert, walk, (choice.shape[1], tile, run_tiles),
+        activation,
     )
     return y, {
+        "bulk_row_share": bulk_rows / jnp.maximum(real, 1),
         "grad_sink_leaves": jnp.float32(
             0 if grad_sinks is None else len(grad_sinks)
         ),
-        "local_slot_share": jnp.sum(real) / choice.size,
+        "local_slot_share": real / choice.size,
         "dropped_slots": dropped.astype(jnp.float32),
     }

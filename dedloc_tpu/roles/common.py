@@ -214,7 +214,7 @@ DEEPSEEK_V3 = ModelFamily(
     weight_decay_mask=deepseek_v3_weight_decay_mask,
     step_gauges=(
         "moe.load_max_over_mean", "moe.local_slot_share", "moe.bias_abs_max",
-        "moe.grad_sink_leaves",
+        "moe.grad_sink_leaves", "moe.bulk_row_share",
     ),
     step_counters=("moe.dropped_slots",),
     sign_step_mask=deepseek_v3_sign_step_mask,
@@ -238,7 +238,7 @@ SMALLTHINKER = dataclasses.replace(
     weight_decay_mask=smallthinker_weight_decay_mask,
     step_gauges=(
         "moe.load_max_over_mean", "moe.local_slot_share",
-        "moe.grad_sink_leaves", "attn.band_tile_share",
+        "moe.grad_sink_leaves", "moe.bulk_row_share", "attn.band_tile_share",
     ),
     sign_step_mask=None, sign_step=0.0,
 )
@@ -251,7 +251,7 @@ SDAR_MOE = dataclasses.replace(
     tflops_per_sample=sdar_moe_train_tflops_per_sample,
     step_gauges=(
         "moe.load_max_over_mean", "moe.local_slot_share",
-        "moe.grad_sink_leaves", "attn.bd_tile_share",
+        "moe.grad_sink_leaves", "moe.bulk_row_share", "attn.bd_tile_share",
         "diffusion.masked_share",
     ),
     step_counters=("moe.dropped_slots", "diffusion.masked_tokens"),

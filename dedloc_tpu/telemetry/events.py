@@ -76,6 +76,7 @@ MM_ROUNDS_ABORTED = "mm.rounds_aborted"
 MM_ROUNDS_ATTEMPTED = "mm.rounds_attempted"
 MM_ROUNDS_FORMED = "mm.rounds_formed"
 MOE_BIAS_ABS_MAX = "moe.bias_abs_max"
+MOE_BULK_ROW_SHARE = "moe.bulk_row_share"
 MOE_DROPPED_SLOTS = "moe.dropped_slots"
 MOE_GRAD_SINK_LEAVES = "moe.grad_sink_leaves"
 MOE_LOCAL_SLOT_SHARE = "moe.local_slot_share"
@@ -242,6 +243,7 @@ GAUGES = frozenset({
     "diffusion.masked_share",
     "expert.load_ewma",
     "moe.bias_abs_max",
+    "moe.bulk_row_share",
     "moe.grad_sink_leaves",
     "moe.local_slot_share",
     "opt.ef_residual_norm",

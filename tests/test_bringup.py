@@ -300,7 +300,13 @@ def test_two_width_kernels_contract_over_a_heads_own_lane_tiles():
     }
     # the routed loop's backward sums into the accumulator's expert leaves
     # (gradient sinks): no add pass of its own over one (3 before PR 33)
-    assert rows["kanana_accumulate_step"]["expert_grad_passes"]["adds"] == 0
+    passes = rows["kanana_accumulate_step"]["expert_grad_passes"]
+    assert passes["adds"] == 0
+    # the routed walk (PR 42): a bulk and a tail loop a direction in the
+    # scanned layer's body (2 loops with the single-size walk), the three
+    # ``old + term`` adds of each backward loop riding their dots' fusions
+    assert (passes["tile_loops"], passes["fused_adds"],
+            passes["loose_adds"]) == (4, 6, 0)
     # 0.05 GB under the line: the layers keep the kernels' OUTPUTS alone
     assert rows["kanana_accumulate_step"]["remat_policy"] == "kernel_outputs"
 
@@ -378,7 +384,14 @@ def test_lfm2_accumulate_step_keeps_what_its_backward_reads():
     # accumulator's twelve expert leaves and leaves the sums there — no
     # zeroed float32 carry, no ``grad_acc + result`` pass (12 + 12 before),
     # and the scratch those buffers took is gone (1,170,841,600 before)
-    assert row["expert_grad_passes"] == {"adds": 0, "zero_fills": 0}
+    # … and the walk's loops (PR 42): four routed layers x two directions x
+    # the bulk and the tail loop (8 loops with the single-size walk),
+    # every backward loop's three ``old + term`` adds inside the fusion of
+    # their weight-gradient dot: a slice read and written once, no ``term``
+    assert row["expert_grad_passes"] == {
+        "adds": 0, "zero_fills": 0, "tile_loops": 16, "fused_adds": 24,
+        "loose_adds": 0,
+    }
     assert row["memory"]["temp_bytes"] <= 1_170_841_600
     # since PR 41 the conv layers keep B | C | u and the attention layer
     # q / k / v for their backward kernels (remat ``kernel_operands``: the
@@ -419,7 +432,14 @@ def test_smallthinker_accumulate_step_takes_the_band_and_a_group_of_seven():
     }
     assert row["tpu_custom_calls"] == 12
     assert row["flash_fwd_forms"] == {"one_tile": 0, "tiles": 4}
-    assert row["expert_grad_passes"] == {"adds": 0, "zero_fills": 0}
+    # … and the walk's loops (PR 42): four routed layers x two directions x
+    # the bulk and the tail loop (8 loops with the single-size walk),
+    # every backward loop's three ``old + term`` adds inside the fusion of
+    # their weight-gradient dot: a slice read and written once, no ``term``
+    assert row["expert_grad_passes"] == {
+        "adds": 0, "zero_fills": 0, "tile_loops": 16, "fused_adds": 24,
+        "loose_adds": 0,
+    }
     assert 370_547_200 * 28 + row["memory"]["temp_bytes"] <= 15.3e9
     # since PR 41 the layers keep q / k / v for their backward kernels
     # (remat ``kernel_operands``: still no kernel replayed, above) as the
@@ -458,7 +478,14 @@ def test_sdar_accumulate_step_takes_the_block_rule_and_a_group_of_eight():
     }
     assert row["tpu_custom_calls"] == 12
     assert row["flash_fwd_forms"] == {"one_tile": 0, "tiles": 4}
-    assert row["expert_grad_passes"] == {"adds": 0, "zero_fills": 0}
+    # … and the walk's loops (PR 42): four routed layers x two directions x
+    # the bulk and the tail loop (8 loops with the single-size walk),
+    # every backward loop's three ``old + term`` adds inside the fusion of
+    # their weight-gradient dot: a slice read and written once, no ``term``
+    assert row["expert_grad_passes"] == {
+        "adds": 0, "zero_fills": 0, "tile_loops": 16, "fused_adds": 24,
+        "loose_adds": 0,
+    }
     assert row["layer_body_copies"] == []
     assert 456_346_624 * 28 + row["memory"]["temp_bytes"] <= 15.3e9
     # since PR 41 the layers keep q / k / v for their backward kernels (remat

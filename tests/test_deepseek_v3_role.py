@@ -98,6 +98,8 @@ def test_kanana2_tiny_trainer_steps_the_bias_by_gamma(tmp_path, shard):
     count = int(shard.split("/")[1])
     for n, rec in enumerate(stepped, start=1):
         assert rec["moe.dropped_slots"] == 0.0
+        # the walk's counter (``parallel/moe.py``): a share of the held rows
+        assert 0.0 <= rec["moe.bulk_row_share"] <= 1.0
         assert all(rec[f"moe.load_max_over_mean.{i}"] >= 1.0 for i in (1, 2))
         assert rec["moe.local_slot_share"] == pytest.approx(
             1.0 / count, abs=0.0 if count == 1 else 0.2

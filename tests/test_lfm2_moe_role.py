@@ -120,6 +120,8 @@ def test_lfm2_tiny_trainer_steps_the_bias_by_gamma(tmp_path, shard, layers):
     count = int(shard.split("/")[1])
     for n, rec in enumerate(stepped, start=1):
         assert rec["moe.dropped_slots"] == 0.0
+        # the walk's counter (``parallel/moe.py``): a share of the held rows
+        assert 0.0 <= rec["moe.bulk_row_share"] <= 1.0
         assert all(
             rec[f"moe.load_max_over_mean.{i}"] >= 1.0
             for i in range(1, expert_layers + 1)

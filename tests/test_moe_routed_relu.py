@@ -121,3 +121,76 @@ def test_the_two_activations_differ():
     with pytest.raises(KeyError):
         routed_experts(p["x"], choice, weights, p["gate"], p["up"], p["down"],
                        (0, E), tile=8, activation="gelu")
+
+
+def _routing(engages):
+    """A fixed choice [T, K]: with ``engages`` 40 tokens put held expert 5
+    first (five tiles of 8: a bulk iteration of four and a tail), without
+    it no held expert draws four tiles."""
+    t = np.arange(T)
+    if engages:
+        first = np.where(t < 40, 5, 4)
+        rest = [6 + t % 2, 12 + t % 4]
+    else:
+        first = 4 + t % 4
+        rest = [8 + t % 4, 12 + t % 4]
+    return jnp.asarray(np.stack([first] + rest, -1), jnp.int32)
+
+
+@pytest.mark.parametrize("engages", [True, False],
+                         ids=["bulk_engages", "tails_alone"])
+@pytest.mark.parametrize("sinks", [False, True], ids=["plain", "sinks"])
+@pytest.mark.parametrize("activation", ["relu", "silu"])
+def test_the_run_length_walk_against_a_dense_loop(
+    activation, sinks, engages
+):
+    """Forward, dx, the ROUTER weights' gradient (through the slots'
+    weights) and the three held matrices' gradients, where the walk takes
+    bulk iterations of four tiles and where it never does."""
+    p = _layer(6)
+    choice = _routing(engages)
+    lo, n = held = (4, 4)
+    act = ACTIVATIONS[activation][0]
+    trained = ("x", "router") + NAMES
+
+    def weights_of(q):  # a softmax over the chosen logits
+        picked = jnp.take_along_axis(q["x"] @ q["router"], choice, axis=-1)
+        return jax.nn.softmax(picked, axis=-1)
+
+    def routed(q, grad_sinks=None):
+        return routed_experts(
+            q["x"], choice, weights_of(q), *(q[m][lo:lo + n] for m in NAMES),
+            held, tile=8, grad_sinks=grad_sinks, activation=activation,
+            run_tiles=4,
+        )
+
+    y, stats = routed(p)
+    np.testing.assert_allclose(
+        y, _dense(p, choice, weights_of(p), held, act), atol=1e-5, rtol=1e-5
+    )
+    assert float(stats["dropped_slots"]) == 0.0
+    assert float(stats["bulk_row_share"]) == pytest.approx(
+        32 / 96 if engages else 0.0
+    )
+    want = jax.grad(lambda q: jnp.sum(jnp.sin(
+        _dense(dict(p, **q), choice, weights_of(dict(p, **q)), held, act)
+    )))({m: p[m] for m in trained})
+    start = tuple(
+        jnp.full(p[m][lo:lo + n].shape, 0.25, jnp.float32) for m in NAMES
+    ) if sinks else None
+    (got, d_sinks), stats = jax.grad(
+        lambda q, s: (lambda out: (jnp.sum(jnp.sin(out[0])), out[1]))(
+            routed(dict(p, **q), s)
+        ), (0, 1), has_aux=True, allow_int=True,
+    )({m: p[m] for m in trained}, start)
+    assert float(stats["grad_sink_leaves"]) == (3.0 if sinks else 0.0)
+    for i, m in enumerate(NAMES):
+        held_grad = d_sinks[i] - 0.25 if sinks else got[m][lo:lo + n]
+        np.testing.assert_allclose(
+            held_grad, want[m][lo:lo + n], atol=1e-5, rtol=1e-5
+        )
+        if sinks:
+            assert float(jnp.max(jnp.abs(got[m][lo:lo + n]))) == 0.0
+    assert float(jnp.max(jnp.abs(want["router"]))) > 0.0
+    for m in ("x", "router"):
+        np.testing.assert_allclose(got[m], want[m], atol=1e-5, rtol=1e-5)

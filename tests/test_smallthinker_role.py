@@ -67,6 +67,8 @@ def test_smallthinker_tiny_trainer_makes_global_steps(tmp_path, shard, layers):
     count = int(shard.split("/")[1])
     for rec in stepped:
         assert rec["moe.dropped_slots"] == 0.0
+        # the walk's counter (``parallel/moe.py``): a share of the held rows
+        assert 0.0 <= rec["moe.bulk_row_share"] <= 1.0
         assert all(
             rec[f"moe.load_max_over_mean.{i}"] >= 1.0
             for i in range(1, n_layers + 1)

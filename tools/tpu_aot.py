@@ -492,7 +492,14 @@ def expert_grad_passes(hlo_text: str) -> dict:
     of such a parameter's shape (a layer's matrix, or the scanned stack of
     them) outside fused computations: the backward loop's zeroed carry.
     Both 0 where the tile loop sums into the accumulator itself
-    (``parallel/moe.py``'s gradient sinks)."""
+    (``parallel/moe.py``'s gradient sinks). And the loops' own structure:
+    ``tile_loops`` — the ``while`` instructions of a DYNAMIC trip count whose
+    state carries one layer's held matrices (the routed walk: a bulk and a
+    tail loop a layer and direction since PR 42, one before); ``fused_adds``
+    / ``loose_adds`` — the ``old + term`` adds over one expert's float32
+    ``[1, H, F]`` slice inside those loops' bodies, by whether the add rides
+    the fusion of its weight-gradient ``convolution`` (the slice read and
+    written once, under the dot) or is a pass of its own."""
     entry = hlo_text[hlo_text.index("\nENTRY "):]
     shapes = set()
     for dims in re.findall(
@@ -514,7 +521,49 @@ def expert_grad_passes(hlo_text: str) -> dict:
         elif not fused:
             fill = re.match(r"\s+%[\w.\-]+ = f32\[([\d,]+)\]\S* broadcast\(", line)
             fills += bool(fill) and fill.group(1) in shapes
-    return {"adds": adds, "zero_fills": fills}
+    return {"adds": adds, "zero_fills": fills, **_tile_loops(hlo_text, shapes)}
+
+
+def _tile_loops(hlo_text: str, shapes: set) -> dict:
+    """``expert_grad_passes``' loop counts; ``shapes``: the held matrices'
+    dims as the entry's ``grad_acc`` parameters have them."""
+    computations, name = {}, None
+    for line in hlo_text.splitlines():
+        if line.startswith(" "):
+            computations[name].append(line)
+        else:
+            header = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(", line)
+            name = header.group(1) if header else None
+            computations.setdefault(name, [])
+    held = [re.escape(f"[{dims}]") for dims in shapes if dims.count(",") == 2]
+    slices = {"1," + dims.split(",", 1)[1] for dims in shapes
+              if dims.count(",") == 2}
+
+    def is_slice_add(line):
+        add = re.match(
+            r"\s+(?:ROOT )?%[\w.\-]+ = f32\[([\d,]+)\]\S* add\(", line
+        )
+        return bool(add) and add.group(1) in slices
+
+    loops = fused = loose = 0
+    for lines in computations.values():
+        for line in lines:
+            body = re.search(r" while\(.*\bbody=%?([\w.\-]+)", line)
+            if not body or "known_trip_count" in line or not any(
+                re.search(shape, line.split(" while(")[0]) for shape in held
+            ):
+                continue
+            loops += 1
+            for inner in computations.get(body.group(1), []):
+                loose += is_slice_add(inner)
+                called = re.search(r" fusion\(.*\bcalls=%?([\w.\-]+)", inner)
+                text = computations.get(called.group(1), []) if called else []
+                found = sum(is_slice_add(t) for t in text)
+                if any(" convolution(" in t for t in text):
+                    fused += found
+                else:
+                    loose += found
+    return {"tile_loops": loops, "fused_adds": fused, "loose_adds": loose}
 
 
 # programs whose row also carries ``kernel_calls`` (the others print the
