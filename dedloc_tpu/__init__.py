@@ -22,7 +22,8 @@ Layer map (mirrors SURVEY.md §1 of the reference analysis):
     training   dedloc_tpu.parallel          (pjit step, mesh, grad-accum,
                                              ring attention, ZeRO-1)
     kernels    dedloc_tpu.ops               (Pallas flash attention)
-    models     dedloc_tpu.models            (ALBERT, ResNet-50/SwAV)
+    models     dedloc_tpu.models            (ALBERT, ResNet-50/SwAV, five
+                                             decoders over decoder.py)
     data       dedloc_tpu.data              (MLM+SOP, streaming, multicrop,
                                              tokenizer, prepare CLI)
     eval       dedloc_tpu.finetune          (NER/NCC drivers, linear probe)

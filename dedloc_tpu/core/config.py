@@ -316,7 +316,7 @@ class TrainingArguments:
     # kernel_operands — those and what the backward kernels READ: q / k / v,
     # the convolution's B | C | u — is the default of smallthinker, sdar and
     # lfm2, and kernel_outputs is what a peer with less memory to spare
-    # passes there; the policy table lives in models/albert.py, measurements
+    # passes there; the policy table lives in models/remat.py, measurements
     # in docs/perf.md and PERF.md)
     remat_policy: str = ""
     attention_impl: str = ""  # override: dense|blockwise|flash|ring

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from dedloc_tpu.models.remat import remat_policy_object
+
 Dtype = Any
 
 
@@ -352,104 +354,6 @@ def fused_ln_for_policy(remat_policy: str) -> bool:
     the (y, x̂, rstd) outputs they rely on. One source of truth for every
     builder (bench, roles, profiler)."""
     return remat_policy in FUSED_LN_POLICIES
-
-
-def _pallas_outputs_saveable(prim, *_, **__) -> bool:
-    """Remat-policy predicate: save the outputs of Pallas kernels (here the
-    flash-attention out/lse residuals) instead of re-running them backward."""
-    return getattr(prim, "name", "") == "pallas_call"
-
-
-def remat_policy_object(name: str):
-    """Resolve a remat-policy NAME to the jax.checkpoint policy object — the
-    one table both the scanned encoder and the pipeline-parallel stage wrap
-    their layer body with (so --training.remat_policy means the same thing
-    on every parallelism path). Raises on unknown names."""
-    table = {
-        "nothing": jax.checkpoint_policies.nothing_saveable,
-        # ONLY the Pallas kernels' outputs (flash: out in the model's own
-        # layout + lse): the custom-VJP backward reads them as they were,
-        # so the replay re-runs no kernel; every matmul output is still
-        # recomputed. For a model whose STATE fills the chip (the looped
-        # decoder, models/ouro.py: 17 MB a layer iteration)
-        "kernel_outputs": _pallas_outputs_saveable,
-        # what a layer's Pallas BACKWARD kernels read as well as what the
-        # forward ones wrote: the flash operands q / k / v ("flash_qkv":
-        # after the q / k norm and RoPE, [B, S, H·D], the layout kernels
-        # and stash share) and the short convolution's B | C | u
-        # ("short_conv_bcu": ``in_proj``'s output as it wrote it) beside
-        # out + lse / y. The replay of a layer still runs the input norm
-        # (the projections' weight gradients read its output), the
-        # out-projection, the post-attention norm, the router and the
-        # routed loop; it loses RoPE, the relayouts around it and every
-        # projection whose output the kernel reads AS IT IS — q / k / v
-        # where nothing but RoPE (a linear map) lies between, every v,
-        # ``in_proj``. Behind a per-head q / k RMSNorm (SDAR, LFM2) the
-        # q and k matmuls STAY: the norm's backward reads the norm's
-        # input. Over "kernel_outputs", a layer a micro-batch:
-        # B·S·(H + 2·H_kv)·D·2 bytes for an attention (151 MB at
-        # SmallThinker's 16,384 x (28 + 2·4) x 128), B·S·3·hidden·2 for
-        # a convolution (50 MB at LFM2's 4,096 x 3 x 2,048). For the
-        # decoders whose state leaves that room (models/smallthinker.py,
-        # sdar_moe.py, lfm2_moe.py)
-        "kernel_operands": (
-            jax.checkpoint_policies.save_from_both_policies(
-                jax.checkpoint_policies.save_only_these_names(
-                    "flash_qkv", "short_conv_bcu"
-                ),
-                _pallas_outputs_saveable,
-            )
-        ),
-        "dots": jax.checkpoint_policies.checkpoint_dots,
-        "dots_no_batch": (
-            jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-        ),
-        # dots_no_batch + flash-attention outputs (out, lse): the
-        # custom-VJP backward then runs straight from saved residuals
-        # instead of re-running the forward kernel during remat
-        # (~30 MB/layer extra HBM at B=32, measured step win on v5e)
-        "dots_no_batch_attn": (
-            jax.checkpoint_policies.save_from_both_policies(
-                jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-                _pallas_outputs_saveable,
-            )
-        ),
-        # fused-LN recipe (pairs with cfg.fused_ln): save ONLY the
-        # named matmul outputs ("flash_qkv": the q/k/v projections as
-        # the dense layers write them, [B, S, H·D], which is the layout
-        # the flash kernels read; "ffn_up") plus every Pallas kernel's
-        # outputs — flash (out in that same layout, lse) and the
-        # fused add+LN's (y, x̂, rstd). The backward then replays no
-        # elementwise chain; dropping the two out-projection dot
-        # saves pays for the x̂ residuals, so HBM is ~neutral vs
-        # dots_no_batch_attn.
-        "fused_ln": (
-            jax.checkpoint_policies.save_from_both_policies(
-                jax.checkpoint_policies.save_only_these_names(
-                    "flash_qkv", "ffn_up"
-                ),
-                _pallas_outputs_saveable,
-            )
-        ),
-        # fused_ln + the gelu output: the backward's one remaining
-        # forward replay (gelu of the FFN up-projection) runs from a
-        # saved residual instead — costs [B,S,intermediate] bf16 per
-        # layer iteration of extra HBM (ffn_up stays saved: gelu's
-        # VJP still needs its primal input)
-        "fused_ln_gelu": (
-            jax.checkpoint_policies.save_from_both_policies(
-                jax.checkpoint_policies.save_only_these_names(
-                    "flash_qkv", "ffn_up", "ffn_gelu"
-                ),
-                _pallas_outputs_saveable,
-            )
-        ),
-    }
-    if name not in table:
-        raise ValueError(
-            f"unknown remat_policy {name!r}; expected one of {sorted(table)}"
-        )
-    return table[name]
 
 
 class _ScannedAlbertLayer(nn.Module):

@@ -1,0 +1,538 @@
+"""What the decoders share, written once; it imports no model's file.
+
+A decoder of this tree (``models/ouro.py``, ``deepseek_v3.py``,
+``lfm2_moe.py``, ``smallthinker.py``, ``sdar_moe.py``) is its config, the
+mixer that is its own, its layer's wiring of norms and residuals, its FLOP
+model and — where the objective is its own — its loss; a new one is one such
+module + one ``roles/common.MODEL_FAMILIES`` entry. The rest is here: the
+blocks, ONE description of who sees whom (``Visibility``) under ``attend``
+and ``GroupedQueryAttention``, the two routed layers over ONE
+``held_expert_ffn``, the stack (``scan_periods``), the head + loss tail and the
+leaf masks. What differs between models arrives as an argument (a name, a
+rule, a function of ``params``), never by a model's name or config class;
+``cfg`` is any config with the fields a function reads.
+
+TPU notes: matmuls in bf16 with float32 accumulation; norms, softmax, the
+router and the loss in float32; q/k/v leave their projections as [B, S, H·D]
+and go to the flash kernels in that layout, named ``flash_qkv`` (RoPE is
+applied before the name, so what a policy stashes is what the kernels read);
+RMSNorm is XLA's.
+"""
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Callable, Dict, Optional, Sequence, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from dedloc_tpu.parallel.moe import (
+    expert_load,
+    route_top_k,
+    route_top_k_softmax,
+    routed_experts,
+    with_load_cotangent,
+)
+
+BIAS = "e_score_correction_bias"  # the leaf the sign rule steps
+# a routed layer's held matrices: the leaves whose gradients the tile loop
+# can leave in a float32 accumulator it is handed (``parallel/moe.py``) — as
+# the collection GRAD_SINKS beside ``params``, the same names and stacking
+EXPERT_LEAVES = ("experts_gate", "experts_up", "experts_down")
+GRAD_SINKS = "grad_sinks"
+
+
+def named_config(model_size: str, ctors: Dict[str, Callable]) -> Callable:
+    """The constructor a ``--training.model_size`` name stands for."""
+    if model_size not in ctors:
+        raise ValueError(
+            f"unknown model_size {model_size!r} "
+            f"(expected one of {sorted(ctors)})"
+        )
+    return ctors[model_size]
+
+
+def held_range(expert_shard: Tuple[int, int],
+               num_experts: int) -> Tuple[int, int]:
+    """(first expert held, how many) of a chip's share ``expert_shard`` =
+    (index, count): experts [index·E/count, (index+1)·E/count)."""
+    index, count = expert_shard
+    if not (0 <= index < count) or num_experts % count:
+        raise ValueError(
+            f"expert_shard {index}/{count}: the count must divide the "
+            f"{num_experts} routed experts, 0 <= index < count"
+        )
+    return index * (num_experts // count), num_experts // count
+
+
+def dense(features: int, cfg, name: str) -> nn.Dense:
+    return nn.Dense(
+        features, use_bias=False, dtype=cfg.dtype, param_dtype=jnp.float32,
+        kernel_init=nn.initializers.normal(cfg.initializer_range), name=name,
+    )
+
+
+class RMSNorm(nn.Module):
+    """x · rsqrt(mean(x²) + eps) · weight, statistics in float32. ``cfg``:
+    any config with ``rms_norm_eps`` and ``dtype``."""
+
+    cfg: Any
+
+    @nn.compact
+    def __call__(self, x):
+        weight = self.param(
+            "weight", nn.initializers.ones, (x.shape[-1],), jnp.float32
+        )
+        x32 = x.astype(jnp.float32)
+        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+        return (
+            x32 * jax.lax.rsqrt(var + self.cfg.rms_norm_eps) * weight
+        ).astype(self.cfg.dtype)
+
+
+def rope_tables(seq: int, head_dim: int, theta: float):
+    """cos, sin [S, D] of rotate-half RoPE: the D/2 frequencies repeated
+    over both halves."""
+    inv_freq = 1.0 / (
+        theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+    )
+    angles = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    angles = jnp.concatenate([angles, angles], axis=-1)
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+def apply_rope(x, cos, sin):
+    """x [B, S, H, D]: x·cos + rotate_half(x)·sin, in float32."""
+    half = x.shape[-1] // 2
+    x32 = x.astype(jnp.float32)
+    rotated = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
+    return (
+        x32 * cos[None, :, None, :] + rotated * sin[None, :, None, :]
+    ).astype(x.dtype)
+
+
+def swiglu(cfg, x, width: int):
+    """down(silu(gate x) · up x) with ``gate_proj`` / ``up_proj`` /
+    ``down_proj`` created in the CALLING module's scope (call it inside a
+    compact method)."""
+    # named for the remat policies that stash the FFN's matmul outputs
+    gate = checkpoint_name(dense(width, cfg, "gate_proj")(x), "ffn_up")
+    up = checkpoint_name(dense(width, cfg, "up_proj")(x), "ffn_up")
+    return dense(cfg.hidden_size, cfg, "down_proj")(nn.silu(gate) * up)
+
+
+class SwiGLU(nn.Module):
+    """``swiglu`` in a scope of its own (a decoder whose layer has more than
+    one: a dense FFN, shared experts)."""
+
+    cfg: Any
+    width: int
+
+    @nn.compact
+    def __call__(self, x):
+        return swiglu(self.cfg, x, self.width)
+
+
+def embed_tokens(module: nn.Module, input_ids, tied_head: bool = False):
+    """The ids embedded by ``embed_tokens`` [V, H], in the compute dtype.
+    Created in ``module``'s scope (call it inside a compact method), and
+    beside it an untied head's ``lm_head`` [H, V]: declared there so the whole
+    model is one parameter tree, applied by the loss a chunk at a time."""
+    cfg = module.cfg
+    init = nn.initializers.normal(cfg.initializer_range)
+    embed = module.param(
+        "embed_tokens", init, (cfg.vocab_size, cfg.hidden_size), jnp.float32
+    )
+    if not tied_head:
+        module.param(
+            "lm_head", init, (cfg.hidden_size, cfg.vocab_size), jnp.float32
+        )
+    return jnp.take(embed, input_ids, axis=0).astype(cfg.dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class Visibility:
+    """Which keys a query sees, in ``ops/flash_attention.flash_attention``'s
+    own keywords: ``causal`` (keys 0..i), with it ``band`` (keys i-band+1..i),
+    or ``block_diffusion`` (a noisy then a clean stream, blocks this long)."""
+
+    causal: bool = False
+    band: Optional[int] = None
+    block_diffusion: Optional[int] = None
+
+    def matrix(self, seq: int):
+        """[S, S] bool, rows queries: the same rule for the dense reference."""
+        i = jnp.arange(seq)
+        q, k = i[:, None], i[None, :]
+        if self.block_diffusion is not None:
+            # over [noisy ; clean]: a clean query sees the clean blocks up to
+            # its own, a noisy one ITS noisy block and the clean ones BEFORE it
+            half = seq // 2
+            q_blk, k_blk = ((x % half) // self.block_diffusion for x in (q, k))
+            return jnp.where(
+                q >= half, (k >= half) & (k_blk <= q_blk),
+                jnp.where(k >= half, k_blk < q_blk, k_blk == q_blk),
+            )
+        visible = k <= q if self.causal else jnp.ones((seq, seq), bool)
+        if self.band is not None:
+            visible &= q - k < self.band
+        return visible
+
+
+def attend(cfg, q, k, v, visible: Visibility):
+    """softmax(q kᵀ / sqrt(q's width) + visibility) v for q [B, S, H, D],
+    k [B, S, KV, D], v [B, S, KV, Dv], kv head j serving H / KV adjacent query
+    heads: the flash kernels (``cfg.attention_impl`` "flash"; [B, S, H, Dv])
+    or XLA's materialized S² scores ("dense", for tests and tiny models;
+    [B, S, KV, H / KV, Dv] — the same bytes)."""
+    if cfg.attention_impl == "flash":
+        from dedloc_tpu.ops.flash_attention import flash_attention
+
+        return flash_attention(
+            q, k, v, **dataclasses.asdict(visible),
+            block_q=cfg.attention_block_size,
+            block_k=cfg.attention_block_size, mesh=cfg.mesh,
+        )
+    if cfg.attention_impl != "dense":
+        raise ValueError(
+            f"attention_impl={cfg.attention_impl!r}: a decoder takes "
+            "'flash' or 'dense'"
+        )
+    B, S, H, D = q.shape
+    KV = k.shape[2]
+    q, k, v = (checkpoint_name(x, "flash_qkv") for x in (q, k, v))
+    logits = jnp.einsum(
+        "bqcgd,bkcd->bcgqk", q.reshape(B, S, KV, H // KV, D), k,
+        preferred_element_type=jnp.float32,
+    ) / jnp.sqrt(jnp.float32(D))
+    logits = jnp.where(visible.matrix(S), logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1).astype(cfg.dtype)
+    return jnp.einsum("bcgqk,bkcd->bqcgd", probs, v)
+
+
+class GroupedQueryAttention(nn.Module):
+    """Grouped-query attention under ``visible``: ``q_proj`` / ``k_proj`` /
+    ``v_proj``, an RMSNorm over each head's own lanes of q and of k where
+    ``qk_norms`` names the two (a weight each, shared by the heads), THEN
+    rotate-half RoPE where ``rotated``; the output projection ``out_name``."""
+
+    cfg: Any
+    visible: Visibility
+    rotated: bool = True
+    qk_norms: Tuple[Optional[str], Optional[str]] = (None, None)
+    out_name: str = "o_proj"
+
+    @nn.compact
+    def __call__(self, hidden, rope):
+        cfg = self.cfg
+        B, S, _ = hidden.shape
+        H, KV, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                    cfg.head_dim)
+        q = dense(H * D, cfg, "q_proj")(hidden).reshape(B, S, H, D)
+        k = dense(KV * D, cfg, "k_proj")(hidden).reshape(B, S, KV, D)
+        v = dense(KV * D, cfg, "v_proj")(hidden).reshape(B, S, KV, D)
+
+        def positioned(x, norm):  # per head, over its own lanes; then RoPE
+            if norm is not None:
+                x = RMSNorm(cfg, name=norm)(x)
+            return apply_rope(x, *rope) if self.rotated else x
+
+        q, k = map(positioned, (q, k), self.qk_norms)
+        if cfg.attention_impl == "flash":
+            # the kernels' operands as buffers of their own. Without the
+            # barrier XLA:TPU folds RoPE's last add + cast into each of
+            # their consumers and relays the float32 pieces BEFORE that add
+            # out around every one (`copy`, `add_convert_fusion`, `reshape`:
+            # 20 ms a micro-batch in SDAR's cell), and under
+            # "kernel_operands" keeps THEM for the backward (4x the bytes).
+            # accumulate_step a micro-batch in the benchmark's cells (PERF.md
+            # section 6, PR 41): SmallThinker 395.8 → 374.5 ms with the
+            # barrier alone, 361.8 with the operands kept too, scratch 4.00
+            # GB without it, 2.53 with it; SDAR 185.9 → 166.2 → 159.9 ms,
+            # 2.55 → 1.28 GB; LFM2's scratch 1.03 → 0.72 GB
+            q, k, v = jax.lax.optimization_barrier((q, k, v))
+        ctx = attend(cfg, q, k, v, self.visible)
+        return dense(cfg.hidden_size, cfg, self.out_name)(
+            ctx.reshape(B, S, H * D)
+        )
+
+
+def held_expert_ffn(module: nn.Module, tokens, choice, weights,
+                    activation: str = "silu"):
+    """``parallel/moe.routed_experts`` over the experts ``module.cfg`` HOLDS:
+    the three ``EXPERT_LEAVES`` created in ``module``'s scope (call it inside
+    a compact method) and, where the apply carries the collection
+    ``GRAD_SINKS``, this layer's three buffers handed to the tile loop's
+    backward. ``tokens`` [T, H]; returns (y [T, H] float32, counts)."""
+    cfg = module.cfg
+    H, F = tokens.shape[-1], cfg.moe_intermediate_size
+    first, held = cfg.held_experts
+    init = nn.initializers.normal(cfg.initializer_range)
+    gate, up, down = (
+        module.param(name, init, shape, jnp.float32)
+        for name, shape in zip(
+            EXPERT_LEAVES, ((held, H, F), (held, H, F), (held, F, H))
+        )
+    )
+    sinks = tuple(
+        module.get_variable(GRAD_SINKS, name) for name in EXPERT_LEAVES
+    ) if module.has_variable(GRAD_SINKS, EXPERT_LEAVES[0]) else None
+    return routed_experts(
+        tokens, choice, weights, gate.astype(cfg.dtype),
+        up.astype(cfg.dtype), down.astype(cfg.dtype), (first, held),
+        tile=cfg.moe_row_tile, grad_sinks=sinks, activation=activation,
+    )
+
+
+class RoutedFFN(nn.Module):
+    """Σ over the chosen HELD experts + the shared experts (where the model
+    has any), chosen by sigmoid scores + the stepped ``BIAS`` (DeepSeek-V3's
+    rule; kanana-2, LFM2). Returns (y, routing): scores [T, E], choice
+    [T, k], load [E] and ``parallel/moe.routed_experts``' counts; the load
+    leaves the backward as the bias leaf's cotangent (``moe.py``'s rule)."""
+
+    cfg: Any
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        B, S, H = x.shape
+        E = cfg.n_routed_experts
+        init = nn.initializers.normal(cfg.initializer_range)
+        router = self.param("router", init, (H, E), jnp.float32)
+        bias = self.param(BIAS, nn.initializers.zeros, (E,), jnp.float32)
+        tokens = x.reshape(B * S, H)
+        # the router in float32 at full precision: the top-k is discrete
+        scores = jax.nn.sigmoid(jnp.dot(
+            tokens.astype(jnp.float32), router,
+            precision=jax.lax.Precision.HIGHEST,
+        ))
+        choice, weights = route_top_k(
+            scores, bias, cfg.num_experts_per_tok, cfg.routed_scaling_factor,
+            cfg.route_eps,
+        )
+        routed, counts = held_expert_ffn(self, tokens, choice, weights)
+        routed = routed.reshape(B, S, H)
+        if cfg.n_shared_experts:
+            routed = routed + SwiGLU(
+                cfg, cfg.n_shared_experts * cfg.moe_intermediate_size,
+                name="shared_experts",
+            )(x).astype(jnp.float32)
+        load = expert_load(choice, E)
+        y = with_load_cotangent(routed.astype(cfg.dtype), bias, load)
+        return y, dict(counts, scores=scores, choice=choice, load=load)
+
+
+class RoutedGLU(nn.Module):
+    """Σ_{e in C} w_e GLU_e(x) over the HELD experts (``activation``: the
+    gate's, "relu" for SmallThinker, "silu" for SDAR), with C and w — the
+    top k and a softmax over the chosen ones — from the router LOGITS of
+    ``router_input`` (SmallThinker: the layer's normalised input, from
+    before attention); no bias, no scale, no shared expert. Returns
+    (y, routing): the logits [T, E] (as ``scores``), choice [T, k], load [E]
+    and ``parallel/moe.routed_experts``' counts."""
+
+    cfg: Any
+    activation: str = "relu"
+
+    @nn.compact
+    def __call__(self, x, router_input):
+        cfg = self.cfg
+        B, S, H = x.shape
+        E = cfg.num_experts
+        router = self.param(
+            "router", nn.initializers.normal(cfg.initializer_range), (H, E),
+            jnp.float32,
+        )
+        # the router in float32 at full precision: the top-k is discrete
+        logits = jnp.dot(
+            router_input.reshape(B * S, H).astype(jnp.float32), router,
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        choice, weights = route_top_k_softmax(logits, cfg.num_experts_per_tok)
+        routed, counts = held_expert_ffn(
+            self, x.reshape(B * S, H), choice, weights, self.activation
+        )
+        return routed.reshape(B, S, H).astype(cfg.dtype), dict(
+            counts, scores=logits, choice=choice, load=expert_load(choice, E)
+        )
+
+
+def period_of(kinds: Sequence) -> int:
+    """The shortest period a stack's layer kinds repeat with."""
+    for period in range(1, len(kinds) + 1):
+        if all(a == b for a, b in zip(kinds, kinds[period:])):
+            return period
+    return max(len(kinds), 1)
+
+
+class _Period(nn.Module):
+    """Scan body: one period of the pattern, a layer per position
+    (``layer_{i}``, leaves of its own). carry = hidden; rope broadcast;
+    per-step out = the period's routing, stacked."""
+
+    layer: Callable[..., nn.Module]
+    kinds: Tuple[tuple, ...]
+
+    @nn.compact
+    def __call__(self, hidden, rope):
+        routings = []
+        for i, kind in enumerate(self.kinds):
+            hidden, routing = self.layer(*kind, name=f"layer_{i}")(
+                hidden, rope
+            )
+            routings.append(routing)
+        return hidden, jax.tree.map(lambda *xs: jnp.stack(xs), *routings)
+
+
+class ScannedBlock(nn.Module):
+    """Scan body of a stack of ONE kind, a layer a step, named ``block``:
+    carry = hidden; rope broadcast; per-step out = the layer's second
+    output (its routing, or None)."""
+
+    layer: Callable[..., nn.Module]
+
+    @nn.compact
+    def __call__(self, hidden, rope):
+        return self.layer(name="block")(hidden, rope)
+
+
+def scan_layers(body, length: int):
+    """``body`` under ``nn.scan``, ``length`` steps: carry = hidden; rope
+    broadcast; every parameter (and gradient sink) of a step stacked on
+    axis 0."""
+    return nn.scan(
+        body, variable_axes={"params": 0, GRAD_SINKS: 0},
+        split_rngs={"params": True}, in_axes=nn.broadcast, length=length,
+    )
+
+
+def scan_periods(layer: Callable[..., nn.Module], kinds: Sequence[tuple],
+                 period: int, hidden, rope):
+    """A stack of routed layers, from inside the model's compact method:
+    ``layer(*kind, name=...)`` makes the (remat'd) layer of one kind, called
+    as ``(hidden, rope) -> (hidden, routing)``. Whole PERIODS of ``period``
+    kinds run under ONE ``nn.scan`` (``layers``: every parameter stacked over
+    the periods, one leaf per position in the period); what is left over
+    after the last is unrolled (``tail_layer_{i}``). Returns (hidden, routing
+    with every entry stacked over the layers in order)."""
+    periods = len(kinds) // period
+    routings = []
+    if periods:
+        hidden, routing = scan_layers(_Period, periods)(
+            layer, tuple(kinds[:period]), name="layers"
+        )(hidden, rope)
+        # [periods, period, ...] -> [layers, ...]
+        routings.append(jax.tree.map(
+            lambda x: x.reshape((-1,) + x.shape[2:]), routing
+        ))
+    for i, kind in enumerate(kinds[periods * period:]):
+        hidden, routing = layer(*kind, name=f"tail_layer_{i}")(hidden, rope)
+        routings.append(jax.tree.map(lambda x: x[None], routing))
+    return hidden, jax.tree.map(lambda *xs: jnp.concatenate(xs), *routings)
+
+
+def chunked_cross_entropy(hiddens, lm_head, labels, chunk_tokens: int):
+    """Per-token CE of every pass, [T, N] float32, from hiddens [T, N, H],
+    the head [H, V] (already in the compute dtype) and labels [N]: one
+    (pass, chunk) of logits at a time, under remat — the backward replays
+    the chunk's matmul instead of keeping [T, N, V]."""
+    T, N, H = hiddens.shape
+    chunk = min(chunk_tokens, N)
+    if N % chunk:
+        raise ValueError(
+            f"loss_chunk_tokens ({chunk_tokens}) must divide the "
+            f"micro-batch's tokens ({N})"
+        )
+
+    @jax.checkpoint
+    def one(h, y):  # [chunk, H], [chunk] -> [chunk]
+        logits = jnp.dot(h, lm_head, preferred_element_type=jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
+        return lse - picked
+
+    h = hiddens.reshape(T * (N // chunk), chunk, H)
+    y = jnp.broadcast_to(
+        labels.reshape(1, N // chunk, chunk), (T, N // chunk, chunk)
+    ).reshape(T * (N // chunk), chunk)
+    return jax.lax.map(lambda hy: one(*hy), (h, y)).reshape(T, N)
+
+
+def apply_with_grad_sinks(model, params, input_ids, grad_sinks):
+    """``model.apply`` on ``params``, with ``grad_sinks`` (None, or the
+    subtree of a float32 gradient accumulator that ``routed_grad_sink_mask``
+    marks) riding beside them as the collection ``GRAD_SINKS``."""
+    variables = {"params": params}
+    if grad_sinks is not None:
+        variables[GRAD_SINKS] = grad_sinks
+    return model.apply(variables, input_ids)
+
+
+def routed_metrics(routing, params, gauges: Dict[str, Callable]):
+    """The routing gauges of ``docs/observability.md`` from a stack's
+    ``routing`` [layers, ...], a family's own ``gauges`` (name -> function
+    of ``params``) among them, and the micro-batch's routing as the step
+    itself computed it (``moe.choice`` [L, T, k], ``moe.scores`` [L, T, E]:
+    what a check routes its reference by and compares; 8 MB at the published
+    sizes, summed by nothing)."""
+    load = routing["load"]  # [L, E]
+    return {
+        "moe.load_max_over_mean": jnp.max(load, axis=1) / jnp.mean(
+            load, axis=1
+        ),
+        "moe.local_slot_share": jnp.mean(routing["local_slot_share"]),
+        "moe.bulk_row_share": jnp.mean(routing["bulk_row_share"]),
+        **{name: gauge(params) for name, gauge in gauges.items()},
+        "moe.dropped_slots": jnp.sum(routing["dropped_slots"]),
+        "moe.grad_sink_leaves": jnp.sum(routing["grad_sink_leaves"]),
+        "moe.choice": routing["choice"],
+        "moe.scores": routing["scores"],
+    }
+
+
+def expert_lm_loss(model, params, batch: Dict[str, jnp.ndarray], grad_sinks,
+                   head: Callable, gauges: Dict[str, Callable]):
+    """(loss, metrics) of one micro-batch of an expert decoder under the
+    plain next-token objective: ``input_ids`` and next-token ``labels``,
+    [B, S] each, no padding; the mean cross-entropy under ``head(params)``
+    ([H, V], in the compute dtype) a chunk of tokens at a time, beside
+    ``routed_metrics``. ``grad_sinks``: ``apply_with_grad_sinks``'s;
+    differentiated with respect to them too, their cotangent is ``sink +
+    gradient`` of the leaf of that name, whose own gradient is then zero."""
+    cfg = model.cfg
+    hidden, routing = apply_with_grad_sinks(
+        model, params, batch["input_ids"], grad_sinks
+    )
+    ce = chunked_cross_entropy(
+        hidden.reshape(1, -1, cfg.hidden_size), head(params),
+        batch["labels"].reshape(-1), cfg.loss_chunk_tokens,
+    )
+    loss = jnp.mean(ce)
+    return loss, {"loss": loss, **routed_metrics(routing, params, gauges)}
+
+
+def _leaves_named(params, names, among: bool):
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: (path[-1].key in names) == among, params
+    )
+
+
+def weight_decay_mask(params, exempt: Tuple[str, ...] = ("weight",)):
+    """True where weight decay applies: every matrix; not the leaves named
+    in ``exempt`` (the RMSNorm ``weight``s, a model's biases)."""
+    return _leaves_named(params, exempt, False)
+
+
+def sign_step_mask(params):
+    """True for the leaves stepped by the sign of their (load) cotangent:
+    the expert layers' correction biases."""
+    return _leaves_named(params, (BIAS,), True)
+
+
+def routed_grad_sink_mask(params):
+    """True for the leaves whose gradient the routed loop can add into an
+    accumulator in place: the held experts' matrices."""
+    return _leaves_named(params, EXPERT_LEAVES, True)

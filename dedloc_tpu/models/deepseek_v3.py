@@ -20,10 +20,10 @@ same in plain ``jax.numpy`` — are:
               b_e <- b_e − γ · sign(load_e − mean load)
 
 The program's shape: the leading dense layer(s) outside the scan, the expert
-layers stacked under ``nn.scan`` + remat as Ouro's are; RMSNorm, RoPE
-tables, SwiGLU and the chunked head + cross-entropy are Ouro's
-(``models/ouro.py``). The kernels take q and k 192 wide beside v 128 wide as
-they are (``ops/flash_attention.py``: two column-block widths, nothing
+layers stacked under ``nn.scan`` + remat as Ouro's are; the blocks, the
+routed layer (``RoutedFFN``), the loss tail and the leaf masks are
+``models/decoder.py``'s. The kernels take q and k 192 wide beside v 128 wide
+as they are (``ops/flash_attention.py``: two column-block widths, nothing
 padded); the one rotary key head is broadcast into k's layout first.
 
 **A chip's share.** ``expert_shard = (index, count)`` tells every expert
@@ -36,39 +36,35 @@ smaller vocabulary.
 The load statistic of the bias rule leaves the backward as the bias leaf's
 cotangent (``parallel/moe.with_load_cotangent``), so it is accumulated over
 micro-batches and averaged over peers as a gradient is;
-``sign_step_mask`` marks those leaves for ``optim``'s sign rule.
+``decoder.sign_step_mask`` marks those leaves for ``optim``'s sign rule.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Tuple
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 
-from dedloc_tpu.models.albert import remat_policy_object
-from dedloc_tpu.models.ouro import (
+from dedloc_tpu.models.decoder import (
+    BIAS,
     RMSNorm,
+    RoutedFFN,
+    ScannedBlock,
     SwiGLU,
-    _dense,
-    chunked_cross_entropy,
+    Visibility,
+    attend,
+    dense,
+    embed_tokens,
+    expert_lm_loss,
+    held_range,
+    named_config,
     rope_tables,
+    scan_layers,
+    weight_decay_mask,
 )
-from dedloc_tpu.parallel.moe import (
-    expert_load,
-    route_top_k,
-    routed_experts,
-    with_load_cotangent,
-)
-
-BIAS = "e_score_correction_bias"  # the leaf the sign rule steps
-# a routed layer's held matrices: the leaves whose gradients the tile loop
-# can leave in a float32 accumulator it is handed (``parallel/moe.py``) — as
-# the collection GRAD_SINKS beside ``params``, the same names and stacking
-EXPERT_LEAVES = ("experts_gate", "experts_up", "experts_down")
-GRAD_SINKS = "grad_sinks"
+from dedloc_tpu.models.remat import remat_layer
 
 
 @dataclasses.dataclass(frozen=True)
@@ -111,19 +107,12 @@ class DeepseekV3Config:
     mesh: Any = None
 
     def __post_init__(self):
-        index, count = self.expert_shard
-        if not (0 <= index < count) or self.n_routed_experts % count:
-            raise ValueError(
-                f"expert_shard {index}/{count}: the count must divide the "
-                f"{self.n_routed_experts} routed experts, 0 <= index < count"
-            )
+        held_range(self.expert_shard, self.n_routed_experts)  # raises
 
     @property
     def held_experts(self) -> Tuple[int, int]:
         """(first expert held, how many)."""
-        index, count = self.expert_shard
-        n = self.n_routed_experts // count
-        return index * n, n
+        return held_range(self.expert_shard, self.n_routed_experts)
 
     @property
     def num_expert_layers(self) -> int:
@@ -131,14 +120,10 @@ class DeepseekV3Config:
 
     @staticmethod
     def named(model_size: str):
-        ctors = {"kanana2_30b_a3b": DeepseekV3Config.kanana2_30b_a3b,
-                 "kanana2_tiny": DeepseekV3Config.tiny}
-        if model_size not in ctors:
-            raise ValueError(
-                f"unknown model_size {model_size!r} "
-                f"(expected one of {sorted(ctors)})"
-            )
-        return ctors[model_size]
+        return named_config(model_size, {
+            "kanana2_30b_a3b": DeepseekV3Config.kanana2_30b_a3b,
+            "kanana2_tiny": DeepseekV3Config.tiny,
+        })
 
     @staticmethod
     def kanana2_30b_a3b(**overrides) -> "DeepseekV3Config":
@@ -183,11 +168,11 @@ class LatentAttention(nn.Module):
         nope, rot, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                          cfg.v_head_dim)
         cos, sin = rope
-        q = _dense(H * (nope + rot), cfg, "q_proj")(hidden).reshape(
+        q = dense(H * (nope + rot), cfg, "q_proj")(hidden).reshape(
             B, S, H, nope + rot
         )
-        latent = _dense(rank + rot, cfg, "kv_a_proj_with_mqa")(hidden)
-        kv = _dense(H * (nope + dv), cfg, "kv_b_proj")(
+        latent = dense(rank + rot, cfg, "kv_a_proj_with_mqa")(hidden)
+        kv = dense(H * (nope + dv), cfg, "kv_b_proj")(
             RMSNorm(cfg, name="kv_a_layernorm")(latent[..., :rank])
         ).reshape(B, S, H, nope + dv)
         q_rope = apply_rope_interleaved(q[..., nope:], cos, sin)
@@ -201,82 +186,10 @@ class LatentAttention(nn.Module):
             axis=-1,
         )
         v = kv[..., nope:]
-        if cfg.attention_impl == "flash":
-            from dedloc_tpu.ops.flash_attention import flash_attention
-
-            ctx = flash_attention(
-                q, k, v, causal=True, block_q=cfg.attention_block_size,
-                block_k=cfg.attention_block_size, mesh=cfg.mesh,
-            )
-        elif cfg.attention_impl == "dense":
-            q, k, v = (checkpoint_name(x, "flash_qkv") for x in (q, k, v))
-            logits = jnp.einsum(
-                "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
-            ) / jnp.sqrt(jnp.float32(nope + rot))
-            visible = jnp.tril(jnp.ones((S, S), bool))
-            logits = jnp.where(visible[None, None], logits, -1e30)
-            probs = jax.nn.softmax(logits, axis=-1).astype(cfg.dtype)
-            ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-        else:
-            raise ValueError(
-                f"attention_impl={cfg.attention_impl!r}: this model takes "
-                "'flash' or 'dense'"
-            )
-        return _dense(cfg.hidden_size, cfg, "o_proj")(
+        ctx = attend(cfg, q, k, v, Visibility(causal=True))
+        return dense(cfg.hidden_size, cfg, "o_proj")(
             ctx.reshape(B, S, H * dv)
         )
-
-
-class RoutedFFN(nn.Module):
-    """Σ over the chosen HELD experts + the shared experts (where the model
-    has any); returns (y, routing) with ``routing`` = scores [T, E], choice
-    [T, k], load [E] and the counts of ``parallel/moe.routed_experts``.
-    ``cfg``: this model's, or any config with the routed layer's fields
-    under the same names (``models/lfm2_moe.Lfm2MoeConfig``). An apply that
-    carries the collection ``GRAD_SINKS`` hands this layer's three buffers
-    to the tile loop's backward."""
-
-    cfg: Any
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        B, S, H = x.shape
-        E, F = cfg.n_routed_experts, cfg.moe_intermediate_size
-        first, held = cfg.held_experts
-        init = nn.initializers.normal(cfg.initializer_range)
-        router = self.param("router", init, (H, E), jnp.float32)
-        bias = self.param(BIAS, nn.initializers.zeros, (E,), jnp.float32)
-        gate = self.param("experts_gate", init, (held, H, F), jnp.float32)
-        up = self.param("experts_up", init, (held, H, F), jnp.float32)
-        down = self.param("experts_down", init, (held, F, H), jnp.float32)
-        tokens = x.reshape(B * S, H)
-        # the router in float32 at full precision: the top-k is discrete
-        scores = jax.nn.sigmoid(jnp.dot(
-            tokens.astype(jnp.float32), router,
-            precision=jax.lax.Precision.HIGHEST,
-        ))
-        choice, weights = route_top_k(
-            scores, bias, cfg.num_experts_per_tok, cfg.routed_scaling_factor,
-            cfg.route_eps,
-        )
-        sinks = tuple(
-            self.get_variable(GRAD_SINKS, name) for name in EXPERT_LEAVES
-        ) if self.has_variable(GRAD_SINKS, EXPERT_LEAVES[0]) else None
-        routed, counts = routed_experts(
-            tokens, choice, weights, gate.astype(cfg.dtype),
-            up.astype(cfg.dtype), down.astype(cfg.dtype), (first, held),
-            tile=cfg.moe_row_tile, grad_sinks=sinks,
-        )
-        routed = routed.reshape(B, S, H)
-        if cfg.n_shared_experts:
-            routed = routed + SwiGLU(
-                cfg, cfg.n_shared_experts * F, name="shared_experts"
-            )(x).astype(jnp.float32)
-        load = expert_load(choice, E)
-        y = routed.astype(cfg.dtype)
-        y = with_load_cotangent(y, bias, load)
-        return y, dict(counts, scores=scores, choice=choice, load=load)
 
 
 class DecoderLayer(nn.Module):
@@ -299,22 +212,6 @@ class DecoderLayer(nn.Module):
         return hidden + y, routing
 
 
-def _remat(cfg: DeepseekV3Config):
-    return nn.remat(
-        DecoderLayer, policy=remat_policy_object(cfg.remat_policy)
-    )
-
-
-class _ScannedLayer(nn.Module):
-    """Scan body: carry = hidden; rope broadcast; per-step out = routing."""
-
-    cfg: DeepseekV3Config
-
-    @nn.compact
-    def __call__(self, hidden, rope):
-        return _remat(self.cfg)(self.cfg, True, name="block")(hidden, rope)
-
-
 class DeepseekV3ForCausalLM(nn.Module):
     """``__call__(input_ids)`` -> (hidden [B, S, H] after the final norm, in
     the compute dtype; routing, every entry stacked over the expert
@@ -326,110 +223,41 @@ class DeepseekV3ForCausalLM(nn.Module):
     @nn.compact
     def __call__(self, input_ids) -> Tuple[jnp.ndarray, Dict[str, Any]]:
         cfg = self.cfg
-        init = nn.initializers.normal(cfg.initializer_range)
-        embed = self.param(
-            "embed_tokens", init, (cfg.vocab_size, cfg.hidden_size),
-            jnp.float32,
-        )
-        self.param(
-            "lm_head", init, (cfg.hidden_size, cfg.vocab_size), jnp.float32
-        )
-        hidden = jnp.take(embed, input_ids, axis=0).astype(cfg.dtype)
+        hidden = embed_tokens(self, input_ids)
         cos, sin = rope_tables(
             input_ids.shape[1], cfg.qk_rope_head_dim, cfg.rope_theta
         )
         half = cfg.qk_rope_head_dim // 2
         rope = (cos[:, :half], sin[:, :half])  # one column per pair
         for i in range(cfg.first_k_dense_replace):
-            hidden = _remat(cfg)(cfg, False, name=f"dense_layer_{i}")(
-                hidden, rope
-            )
-        stack = nn.scan(
-            _ScannedLayer,
-            variable_axes={"params": 0, GRAD_SINKS: 0},
-            split_rngs={"params": True},
-            in_axes=nn.broadcast,
-            length=cfg.num_expert_layers,
-        )
-        hidden, routing = stack(cfg, name="layers")(hidden, rope)
+            hidden = remat_layer(
+                DecoderLayer, cfg, False, name=f"dense_layer_{i}"
+            )(hidden, rope)
+        # a layer a step, not ``scan_periods``' unrolled period (ROADMAP A3.1)
+        hidden, routing = scan_layers(ScannedBlock, cfg.num_expert_layers)(
+            functools.partial(remat_layer, DecoderLayer, cfg, True),
+            name="layers",
+        )(hidden, rope)
         return RMSNorm(cfg, name="norm")(hidden), routing
-
-
-def apply_with_grad_sinks(model, params, input_ids, grad_sinks):
-    """``model.apply`` on ``params``, with ``grad_sinks`` (None, or the
-    subtree of a float32 gradient accumulator that ``routed_grad_sink_mask``
-    marks) riding beside them as the collection ``GRAD_SINKS``."""
-    variables = {"params": params}
-    if grad_sinks is not None:
-        variables[GRAD_SINKS] = grad_sinks
-    return model.apply(variables, input_ids)
 
 
 def deepseek_v3_loss(model: DeepseekV3ForCausalLM, params,
                      batch: Dict[str, jnp.ndarray], grad_sinks=None):
-    """(loss, metrics) of one micro-batch: ``input_ids`` and next-token
-    ``labels``, [B, S] each, no padding. Beside the loss, the routing
-    gauges of ``docs/observability.md`` and this micro-batch's routing as
-    the step itself computed it (``moe.choice`` [L, T, k], ``moe.scores``
-    [L, T, E]: what a check routes its reference by and compares; 8 MB at
-    the published sizes, summed by nothing). ``grad_sinks``:
-    ``apply_with_grad_sinks``'s; differentiated with respect to them too,
-    their cotangent is ``sink + gradient`` of the leaf of the same name,
-    whose own gradient is then zero."""
-    cfg = model.cfg
-    hidden, routing = apply_with_grad_sinks(
-        model, params, batch["input_ids"], grad_sinks
-    )
-    ce = chunked_cross_entropy(
-        hidden.reshape(1, -1, cfg.hidden_size),
-        params["lm_head"].astype(cfg.dtype),
-        batch["labels"].reshape(-1), cfg.loss_chunk_tokens,
-    )
-    loss = jnp.mean(ce)
-    load = routing["load"]  # [L, E]
-    return loss, {
-        "loss": loss,
-        "moe.load_max_over_mean": jnp.max(load, axis=1) / jnp.mean(
-            load, axis=1
-        ),
-        "moe.local_slot_share": jnp.mean(routing["local_slot_share"]),
-        "moe.bulk_row_share": jnp.mean(routing["bulk_row_share"]),
-        "moe.bias_abs_max": jnp.max(jnp.abs(
-            params["layers"]["block"]["mlp"][BIAS]
-        )),
-        "moe.dropped_slots": jnp.sum(routing["dropped_slots"]),
-        "moe.grad_sink_leaves": jnp.sum(routing["grad_sink_leaves"]),
-        "moe.choice": routing["choice"],
-        "moe.scores": routing["scores"],
-    }
-
-
-def _leaf_name(path) -> str:
-    return path[-1].key
-
-
-def deepseek_v3_weight_decay_mask(params):
-    """True where weight decay applies: every matrix; not the RMSNorm
-    ``weight``s nor the correction bias."""
-    return jax.tree_util.tree_map_with_path(
-        lambda path, _: _leaf_name(path) not in ("weight", BIAS), params
+    """``decoder.expert_lm_loss`` under the untied head, with the largest
+    bias magnitude as a gauge."""
+    return expert_lm_loss(
+        model, params, batch, grad_sinks,
+        head=lambda p: p["lm_head"].astype(model.cfg.dtype),
+        gauges={"moe.bias_abs_max": lambda p: jnp.max(jnp.abs(
+            p["layers"]["block"]["mlp"][BIAS]
+        ))},
     )
 
 
-def routed_grad_sink_mask(params):
-    """True for the leaves whose gradient the routed loop can add into an
-    accumulator in place: the held experts' matrices."""
-    return jax.tree_util.tree_map_with_path(
-        lambda path, _: _leaf_name(path) in EXPERT_LEAVES, params
-    )
-
-
-def deepseek_v3_sign_step_mask(params):
-    """True for the leaves stepped by the sign of their (load) cotangent:
-    the expert layers' correction biases."""
-    return jax.tree_util.tree_map_with_path(
-        lambda path, _: _leaf_name(path) == BIAS, params
-    )
+# decayed: every matrix; not the RMSNorm ``weight``s nor the correction bias
+deepseek_v3_weight_decay_mask = functools.partial(
+    weight_decay_mask, exempt=("weight", BIAS)
+)
 
 
 def deepseek_v3_train_tflops_per_sample(cfg: DeepseekV3Config,

@@ -20,19 +20,17 @@ plain ``jax.numpy``:
               FFN(u) = Σ_{e in choice} w_e SwiGLU_e(u)   (no shared expert)
     after the stack a final RMSNorm; the head is the embedding, transposed
     loss: mean next-token cross-entropy; b stepped by the sign of its load
-    excess after every GLOBAL step, as ``models/deepseek_v3.py``'s
+    excess after every GLOBAL step (``decoder.RoutedFFN``'s rule)
 
 The program's shape. ``layer_types`` (the published pattern) and
 ``num_dense_layers`` say what each layer is. The leading dense layers are
-unrolled; the expert layers are cut into whole PERIODS of the pattern (four
-layers: attention, conv, conv, conv), one ``nn.scan`` over the periods with
-every layer of a period a remat'd block of its own kind, and what is left
-over after the last whole period is unrolled. Every parameter of a scanned
-layer is stacked over the periods, one leaf per position in the period.
-RMSNorm, RoPE, SwiGLU and the chunked head + cross-entropy are Ouro's; the
-routed layer is ``models/deepseek_v3.RoutedFFN`` (``parallel/moe.py``) at
-this model's sizes; the mixers' kernels are ``ops/short_conv.py`` and the
-grouped-query mode of ``ops/flash_attention.py``.
+unrolled; the expert layers are ``decoder.scan_periods``' stack at the
+pattern's period (four layers: attention, conv, conv, conv), every layer a
+remat'd block of its own kind. The blocks, the grouped-query attention (here
+with a q / k norm, ``out_proj``), the routed layer (``decoder.RoutedFFN`` at
+this model's sizes), the loss tail and the leaf masks are
+``models/decoder.py``'s; the mixers' kernels are ``ops/short_conv.py`` and
+the grouped-query mode of ``ops/flash_attention.py``.
 
 **A chip's share**, as for the other expert decoder: ``expert_shard`` (the
 experts held of every layer), ``vocab_size`` (rows held) and
@@ -43,28 +41,31 @@ dense layer and then the published pattern from the first expert layer on
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, List, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 
-from dedloc_tpu.models.albert import remat_policy_object
-from dedloc_tpu.models.deepseek_v3 import (
+from dedloc_tpu.models.decoder import (
     BIAS,
-    GRAD_SINKS,
-    RoutedFFN,
-    apply_with_grad_sinks,
-)
-from dedloc_tpu.models.ouro import (
+    GroupedQueryAttention,
     RMSNorm,
+    RoutedFFN,
     SwiGLU,
-    _dense,
-    apply_rope,
-    chunked_cross_entropy,
+    Visibility,
+    dense,
+    embed_tokens,
+    expert_lm_loss,
+    held_range,
+    named_config,
+    period_of,
     rope_tables,
+    scan_periods,
+    weight_decay_mask,
 )
+from dedloc_tpu.models.remat import remat_layer
 from dedloc_tpu.ops.short_conv import TAPS, short_conv
 
 CONV, ATTENTION = "conv", "full_attention"
@@ -103,7 +104,7 @@ class Lfm2MoeConfig:
     expert_shard: Tuple[int, int] = (0, 1)
     moe_row_tile: int = 256
     dtype: Any = jnp.bfloat16  # compute dtype; params stay fp32
-    # a name of albert.remat_policy_object's table. "kernel_operands": a
+    # a name of models/remat.py's table. "kernel_operands": a
     # conv layer keeps B | C | u as ``short_conv_bwd`` reads it beside y, so
     # the backward's replay runs no ``in_proj``; the attention layer keeps
     # q / k / v beside out + lse and loses v_proj, RoPE and the relayouts
@@ -121,12 +122,7 @@ class Lfm2MoeConfig:
     mesh: Any = None
 
     def __post_init__(self):
-        index, count = self.expert_shard
-        if not (0 <= index < count) or self.num_experts % count:
-            raise ValueError(
-                f"expert_shard {index}/{count}: the count must divide the "
-                f"{self.num_experts} routed experts, 0 <= index < count"
-            )
+        held_range(self.expert_shard, self.num_experts)  # raises
         if self.conv_L_cache != TAPS:
             raise ValueError(f"the conv kernels take {TAPS} taps")
         if not 1 <= self.num_hidden_layers <= len(self.layer_types):
@@ -142,9 +138,7 @@ class Lfm2MoeConfig:
     @property
     def held_experts(self) -> Tuple[int, int]:
         """(first expert held, how many)."""
-        index, count = self.expert_shard
-        n = self.num_experts // count
-        return index * n, n
+        return held_range(self.expert_shard, self.num_experts)
 
     @property
     def layer_plan(self) -> List[Tuple[int, str, bool]]:
@@ -166,14 +160,10 @@ class Lfm2MoeConfig:
 
     @staticmethod
     def named(model_size: str):
-        ctors = {"lfm2_24b_a2b": Lfm2MoeConfig.lfm2_24b_a2b,
-                 "lfm2_tiny": Lfm2MoeConfig.tiny}
-        if model_size not in ctors:
-            raise ValueError(
-                f"unknown model_size {model_size!r} "
-                f"(expected one of {sorted(ctors)})"
-            )
-        return ctors[model_size]
+        return named_config(model_size, {
+            "lfm2_24b_a2b": Lfm2MoeConfig.lfm2_24b_a2b,
+            "lfm2_tiny": Lfm2MoeConfig.tiny,
+        })
 
     @staticmethod
     def lfm2_24b_a2b(**overrides) -> "Lfm2MoeConfig":
@@ -206,64 +196,13 @@ class ShortConvMixer(nn.Module):
     @nn.compact
     def __call__(self, hidden):
         cfg = self.cfg
-        bcu = _dense(TAPS * cfg.hidden_size, cfg, "in_proj")(hidden)
+        bcu = dense(TAPS * cfg.hidden_size, cfg, "in_proj")(hidden)
         taps = self.param(
             "conv", nn.initializers.normal(cfg.initializer_range),
             (cfg.hidden_size, cfg.conv_L_cache), jnp.float32,
         )
         y = short_conv(bcu, taps, mesh=cfg.mesh)
-        return _dense(cfg.hidden_size, cfg, "out_proj")(y)
-
-
-class GroupedAttention(nn.Module):
-    cfg: Lfm2MoeConfig
-
-    @nn.compact
-    def __call__(self, hidden, rope):
-        cfg = self.cfg
-        B, S, _ = hidden.shape
-        H, KV, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                    cfg.head_dim)
-        cos, sin = rope
-        q = _dense(H * D, cfg, "q_proj")(hidden).reshape(B, S, H, D)
-        k = _dense(KV * D, cfg, "k_proj")(hidden).reshape(B, S, KV, D)
-        v = _dense(KV * D, cfg, "v_proj")(hidden).reshape(B, S, KV, D)
-        # per head, over its own lanes; then the rotation
-        q = apply_rope(RMSNorm(cfg, name="q_layernorm")(q), cos, sin)
-        k = apply_rope(RMSNorm(cfg, name="k_layernorm")(k), cos, sin)
-        if cfg.attention_impl == "flash":
-            from dedloc_tpu.ops.flash_attention import flash_attention
-
-            # the kernels' operands as buffers of their own: without the
-            # barrier XLA:TPU folds RoPE's last add + cast into each of
-            # their consumers and, under "kernel_operands", keeps the
-            # float32 pieces BEFORE that add for the backward (as in
-            # models/smallthinker.py and sdar_moe.py; accumulate_step's
-            # scratch 1.03 GB without it, 0.72 with it)
-            q, k, v = jax.lax.optimization_barrier((q, k, v))
-            ctx = flash_attention(
-                q, k, v, causal=True, block_q=cfg.attention_block_size,
-                block_k=cfg.attention_block_size, mesh=cfg.mesh,
-            )
-        elif cfg.attention_impl == "dense":
-            q, k, v = (checkpoint_name(x, "flash_qkv") for x in (q, k, v))
-            grouped = q.reshape(B, S, KV, H // KV, D)
-            logits = jnp.einsum(
-                "bqcgd,bkcd->bcgqk", grouped, k,
-                preferred_element_type=jnp.float32,
-            ) / jnp.sqrt(jnp.float32(D))
-            visible = jnp.tril(jnp.ones((S, S), bool))
-            logits = jnp.where(visible, logits, -1e30)
-            probs = jax.nn.softmax(logits, axis=-1).astype(cfg.dtype)
-            ctx = jnp.einsum("bcgqk,bkcd->bqcgd", probs, v)
-        else:
-            raise ValueError(
-                f"attention_impl={cfg.attention_impl!r}: this model takes "
-                "'flash' or 'dense'"
-            )
-        return _dense(cfg.hidden_size, cfg, "out_proj")(
-            ctx.reshape(B, S, H * D)
-        )
+        return dense(cfg.hidden_size, cfg, "out_proj")(y)
 
 
 class DecoderLayer(nn.Module):
@@ -282,7 +221,11 @@ class DecoderLayer(nn.Module):
         if self.mixer == CONV:
             hidden = hidden + ShortConvMixer(cfg, name="conv")(x)
         else:
-            hidden = hidden + GroupedAttention(cfg, name="self_attn")(x, rope)
+            hidden = hidden + GroupedQueryAttention(
+                cfg, Visibility(causal=True),
+                qk_norms=("q_layernorm", "k_layernorm"), out_name="out_proj",
+                name="self_attn",
+            )(x, rope)
         x = RMSNorm(cfg, name="ffn_norm")(hidden)
         if not self.sparse:
             return hidden + SwiGLU(
@@ -290,38 +233,6 @@ class DecoderLayer(nn.Module):
             )(x)
         y, routing = RoutedFFN(cfg, name="feed_forward")(x)
         return hidden + y, routing
-
-
-def _layer(cfg: Lfm2MoeConfig, mixer: str, sparse: bool, name: str):
-    return nn.remat(
-        DecoderLayer, policy=remat_policy_object(cfg.remat_policy)
-    )(cfg, mixer, sparse, name=name)
-
-
-class _Period(nn.Module):
-    """Scan body: one period of the pattern, a remat'd layer per position.
-    carry = hidden; rope broadcast; per-step out = the period's routing."""
-
-    cfg: Lfm2MoeConfig
-    mixers: Tuple[str, ...]
-
-    @nn.compact
-    def __call__(self, hidden, rope):
-        routings = []
-        for i, mixer in enumerate(self.mixers):
-            hidden, routing = _layer(self.cfg, mixer, True, f"layer_{i}")(
-                hidden, rope
-            )
-            routings.append(routing)
-        return hidden, jax.tree.map(lambda *xs: jnp.stack(xs), *routings)
-
-
-def _period_of(mixers: List[str]) -> int:
-    """The shortest period the expert layers' mixer kinds repeat with."""
-    for period in range(1, len(mixers) + 1):
-        if all(a == b for a, b in zip(mixers, mixers[period:])):
-            return period
-    return max(len(mixers), 1)
 
 
 class Lfm2MoeForCausalLM(nn.Module):
@@ -335,102 +246,39 @@ class Lfm2MoeForCausalLM(nn.Module):
     @nn.compact
     def __call__(self, input_ids) -> Tuple[jnp.ndarray, Dict[str, Any]]:
         cfg = self.cfg
-        embed = self.param(
-            "embed_tokens", nn.initializers.normal(cfg.initializer_range),
-            (cfg.vocab_size, cfg.hidden_size), jnp.float32,
-        )
-        hidden = jnp.take(embed, input_ids, axis=0).astype(cfg.dtype)
+        hidden = embed_tokens(self, input_ids, tied_head=True)
         rope = rope_tables(input_ids.shape[1], cfg.head_dim, cfg.rope_theta)
-        plan = cfg.layer_plan
-        dense = [mixer for _i, mixer, sparse in plan if not sparse]
-        mixers = [mixer for _i, mixer, sparse in plan if sparse]
-        for i, mixer in enumerate(dense):
-            hidden = _layer(cfg, mixer, False, f"dense_layer_{i}")(
-                hidden, rope
-            )
-        period = _period_of(mixers)
-        periods = len(mixers) // period
-        routings = []
-        if periods:
-            stack = nn.scan(
-                _Period,
-                variable_axes={"params": 0, GRAD_SINKS: 0},
-                split_rngs={"params": True},
-                in_axes=nn.broadcast,
-                length=periods,
-            )
-            hidden, routing = stack(
-                cfg, tuple(mixers[:period]), name="layers"
-            )(hidden, rope)
-            # [periods, period, ...] -> [layers, ...]
-            routings.append(jax.tree.map(
-                lambda x: x.reshape((-1,) + x.shape[2:]), routing
-            ))
-        for i, mixer in enumerate(mixers[periods * period:]):
-            hidden, routing = _layer(cfg, mixer, True, f"tail_layer_{i}")(
-                hidden, rope
-            )
-            routings.append(jax.tree.map(lambda x: x[None], routing))
-        routing = jax.tree.map(
-            lambda *xs: jnp.concatenate(xs), *routings
-        ) if len(routings) > 1 else routings[0]
+        layer = functools.partial(remat_layer, DecoderLayer, cfg)
+        kinds = [(mixer, sparse) for _i, mixer, sparse in cfg.layer_plan]
+        for i, kind in enumerate(kind for kind in kinds if not kind[1]):
+            hidden = layer(*kind, name=f"dense_layer_{i}")(hidden, rope)
+        routed = [kind for kind in kinds if kind[1]]
+        hidden, routing = scan_periods(
+            layer, routed, period_of(routed), hidden, rope
+        )
         return RMSNorm(cfg, name="norm")(hidden), routing
-
-
-def _leaf_name(path) -> str:
-    return path[-1].key
 
 
 def lfm2_moe_loss(model: Lfm2MoeForCausalLM, params,
                   batch: Dict[str, jnp.ndarray], grad_sinks=None):
-    """(loss, metrics) of one micro-batch: ``input_ids`` and next-token
-    ``labels``, [B, S] each, no padding; the metrics and ``grad_sinks`` are
-    ``models/deepseek_v3.deepseek_v3_loss``'s (the routing gauges and this
-    micro-batch's routing as the step computed it)."""
-    cfg = model.cfg
-    hidden, routing = apply_with_grad_sinks(
-        model, params, batch["input_ids"], grad_sinks
-    )
-    ce = chunked_cross_entropy(
-        hidden.reshape(1, -1, cfg.hidden_size),
-        params["embed_tokens"].astype(cfg.dtype).T,  # the tied head
-        batch["labels"].reshape(-1), cfg.loss_chunk_tokens,
-    )
-    loss = jnp.mean(ce)
-    load = routing["load"]  # [L, E]
-    return loss, {
-        "loss": loss,
-        "moe.load_max_over_mean": jnp.max(load, axis=1) / jnp.mean(
-            load, axis=1
-        ),
-        "moe.local_slot_share": jnp.mean(routing["local_slot_share"]),
-        "moe.bulk_row_share": jnp.mean(routing["bulk_row_share"]),
-        "moe.bias_abs_max": jnp.max(jnp.stack([
+    """``decoder.expert_lm_loss`` under the TIED head, with the largest bias
+    magnitude of any layer as a gauge."""
+    return expert_lm_loss(
+        model, params, batch, grad_sinks,
+        head=lambda p: p["embed_tokens"].astype(model.cfg.dtype).T,
+        gauges={"moe.bias_abs_max": lambda p: jnp.max(jnp.stack([
             jnp.max(jnp.abs(leaf))
-            for path, leaf in jax.tree_util.tree_leaves_with_path(params)
-            if _leaf_name(path) == BIAS
-        ])),
-        "moe.dropped_slots": jnp.sum(routing["dropped_slots"]),
-        "moe.grad_sink_leaves": jnp.sum(routing["grad_sink_leaves"]),
-        "moe.choice": routing["choice"],
-        "moe.scores": routing["scores"],
-    }
-
-
-def lfm2_moe_weight_decay_mask(params):
-    """True where weight decay applies: every matrix and the conv taps; not
-    the RMSNorm ``weight``s nor the correction bias."""
-    return jax.tree_util.tree_map_with_path(
-        lambda path, _: _leaf_name(path) not in ("weight", BIAS), params
+            for path, leaf in jax.tree_util.tree_leaves_with_path(p)
+            if path[-1].key == BIAS
+        ]))},
     )
 
 
-def lfm2_moe_sign_step_mask(params):
-    """True for the leaves stepped by the sign of their (load) cotangent:
-    the expert layers' correction biases."""
-    return jax.tree_util.tree_map_with_path(
-        lambda path, _: _leaf_name(path) == BIAS, params
-    )
+# decayed: every matrix and the conv taps; not the RMSNorm ``weight``s nor
+# the correction bias
+lfm2_moe_weight_decay_mask = functools.partial(
+    weight_decay_mask, exempt=("weight", BIAS)
+)
 
 
 def lfm2_moe_layer_flops_per_token(cfg: Lfm2MoeConfig,

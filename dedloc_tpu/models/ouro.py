@@ -16,30 +16,42 @@ embeddings.
 
 The program's shape is ALBERT's twice over: an ``nn.scan`` over the L
 layers (their parameters stacked on axis 0) inside an ``nn.scan`` over the
-passes (parameters broadcast), the layer body under a remat policy from
-``albert.remat_policy_object``'s table. The model returns the hidden state
-of every pass, [T, B, S, H] in bf16 (134 MB at 8,192 tokens); the head and
-its cross-entropy are NOT part of the scan: ``ouro_loss`` computes them one
+passes (parameters broadcast), the layer body under a remat policy of
+``models/remat.py``'s table. The model returns the hidden state of every
+pass, [T, B, S, H] in bf16 (134 MB at 8,192 tokens); the head and its
+cross-entropy are NOT part of the scan: ``ouro_loss`` computes them one
 (pass, token chunk) at a time under remat, so at most one chunk of the
-[tokens, 49,152] float32 logits lives at a time, forward or backward.
-
-TPU notes: matmuls in bf16 with float32 accumulation; norms, softmax, the
-gate and the loss in float32; q/k/v leave their projections as [B, S, H·D]
-and go to the flash kernels in that layout, named ``flash_qkv`` (RoPE is
-applied before the name, so what a policy stashes is what the kernels
-read); RMSNorm is XLA's.
+[tokens, 49,152] float32 logits lives at a time, forward or backward. The
+blocks (RMSNorm, RoPE, SwiGLU, the chunked head + cross-entropy) and the TPU
+notes are ``models/decoder.py``'s; the attention module stays here (see
+``OuroAttention``).
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 
-from dedloc_tpu.models.albert import remat_policy_object
+from dedloc_tpu.models.decoder import (
+    RMSNorm,
+    ScannedBlock,
+    Visibility,
+    apply_rope,
+    attend,
+    chunked_cross_entropy,
+    dense,
+    embed_tokens,
+    named_config,
+    rope_tables,
+    scan_layers,
+    swiglu,
+    weight_decay_mask,
+)
+from dedloc_tpu.models.remat import remat_layer
 
 
 @dataclasses.dataclass(frozen=True)
@@ -62,8 +74,8 @@ class OuroConfig:
     exit_entropy_beta: float = 0.05
     initializer_range: float = 0.02
     dtype: Any = jnp.bfloat16  # compute dtype; params stay fp32
-    # the layer always runs under remat; the policy is a name from
-    # albert.remat_policy_object's table. "kernel_outputs" keeps one layer
+    # the layer always runs under remat; the policy is a name of
+    # models/remat.py's table. "kernel_outputs" keeps one layer
     # input per (pass, layer) plus the flash kernel's out + lse (what its
     # backward reads: 17 MB a layer iteration) and replays the rest of the
     # layer in the backward: at 350-400 M parameters the state leaves no
@@ -79,14 +91,10 @@ class OuroConfig:
 
     @staticmethod
     def named(model_size: str):
-        ctors = {"ouro_2p6b": OuroConfig.ouro_2p6b,
-                 "ouro_tiny": OuroConfig.tiny}
-        if model_size not in ctors:
-            raise ValueError(
-                f"unknown model_size {model_size!r} "
-                f"(expected one of {sorted(ctors)})"
-            )
-        return ctors[model_size]
+        return named_config(model_size, {
+            "ouro_2p6b": OuroConfig.ouro_2p6b,
+            "ouro_tiny": OuroConfig.tiny,
+        })
 
     @staticmethod
     def ouro_2p6b(**overrides) -> "OuroConfig":
@@ -106,75 +114,11 @@ class OuroConfig:
         return OuroConfig(**base)
 
 
-def _dense(features: int, cfg: OuroConfig, name: str) -> nn.Dense:
-    return nn.Dense(
-        features, use_bias=False, dtype=cfg.dtype, param_dtype=jnp.float32,
-        kernel_init=nn.initializers.normal(cfg.initializer_range), name=name,
-    )
-
-
-class RMSNorm(nn.Module):
-    """x · rsqrt(mean(x²) + eps) · weight, statistics in float32. ``cfg``:
-    any config with ``rms_norm_eps`` and ``dtype``."""
-
-    cfg: Any
-
-    @nn.compact
-    def __call__(self, x):
-        weight = self.param(
-            "weight", nn.initializers.ones, (x.shape[-1],), jnp.float32
-        )
-        x32 = x.astype(jnp.float32)
-        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-        return (
-            x32 * jax.lax.rsqrt(var + self.cfg.rms_norm_eps) * weight
-        ).astype(self.cfg.dtype)
-
-
-def rope_tables(seq: int, head_dim: int, theta: float):
-    """cos, sin [S, D] of rotate-half RoPE: the D/2 frequencies repeated
-    over both halves."""
-    inv_freq = 1.0 / (
-        theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
-    )
-    angles = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    angles = jnp.concatenate([angles, angles], axis=-1)
-    return jnp.cos(angles), jnp.sin(angles)
-
-
-def apply_rope(x, cos, sin):
-    """x [B, S, H, D]: x·cos + rotate_half(x)·sin, in float32."""
-    half = x.shape[-1] // 2
-    x32 = x.astype(jnp.float32)
-    rotated = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
-    return (
-        x32 * cos[None, :, None, :] + rotated * sin[None, :, None, :]
-    ).astype(x.dtype)
-
-
-def swiglu(cfg, x, width: int):
-    """down(silu(gate x) · up x) with ``gate_proj`` / ``up_proj`` /
-    ``down_proj`` created in the CALLING module's scope (call it inside a
-    compact method)."""
-    # named for the remat policies that stash the FFN's matmul outputs
-    gate = checkpoint_name(_dense(width, cfg, "gate_proj")(x), "ffn_up")
-    up = checkpoint_name(_dense(width, cfg, "up_proj")(x), "ffn_up")
-    return _dense(cfg.hidden_size, cfg, "down_proj")(nn.silu(gate) * up)
-
-
-class SwiGLU(nn.Module):
-    """``swiglu`` in a scope of its own (a decoder whose layer has more than
-    one: a dense FFN, shared experts)."""
-
-    cfg: Any
-    width: int
-
-    @nn.compact
-    def __call__(self, x):
-        return swiglu(self.cfg, x, self.width)
-
-
 class OuroAttention(nn.Module):
+    """Causal attention with RoPE over whole heads. Not
+    ``decoder.GroupedQueryAttention``, whose ``optimization_barrier`` this
+    model's program does not have (its policy keeps no operand)."""
+
     cfg: OuroConfig
 
     @nn.compact
@@ -183,42 +127,20 @@ class OuroAttention(nn.Module):
         B, S, _ = hidden.shape
         H, KV, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
                     cfg.head_dim)
-        cos, sin = rope
-        q = _dense(H * D, cfg, "q_proj")(hidden).reshape(B, S, H, D)
-        k = _dense(KV * D, cfg, "k_proj")(hidden).reshape(B, S, KV, D)
-        v = _dense(KV * D, cfg, "v_proj")(hidden).reshape(B, S, KV, D)
-        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-        if cfg.attention_impl == "flash":
-            from dedloc_tpu.ops.flash_attention import flash_attention
-
-            ctx = flash_attention(
-                q, k, v, causal=True, block_q=cfg.attention_block_size,
-                block_k=cfg.attention_block_size, mesh=cfg.mesh,
-            )
-        elif cfg.attention_impl == "dense":
-            q, k, v = (checkpoint_name(x, "flash_qkv") for x in (q, k, v))
-            if KV != H:  # each kv head for its H / KV query heads
-                k, v = (jnp.repeat(x, H // KV, axis=2) for x in (k, v))
-            logits = jnp.einsum(
-                "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
-            ) / jnp.sqrt(jnp.float32(D))
-            visible = jnp.tril(jnp.ones((S, S), bool))
-            logits = jnp.where(visible[None, None], logits, -1e30)
-            probs = jax.nn.softmax(logits, axis=-1).astype(cfg.dtype)
-            ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-        else:
-            raise ValueError(
-                f"attention_impl={cfg.attention_impl!r}: an Ouro model takes "
-                "'flash' or 'dense'"
-            )
-        return _dense(cfg.hidden_size, cfg, "o_proj")(
+        q = dense(H * D, cfg, "q_proj")(hidden).reshape(B, S, H, D)
+        k = dense(KV * D, cfg, "k_proj")(hidden).reshape(B, S, KV, D)
+        v = dense(KV * D, cfg, "v_proj")(hidden).reshape(B, S, KV, D)
+        q, k = apply_rope(q, *rope), apply_rope(k, *rope)
+        ctx = attend(cfg, q, k, v, Visibility(causal=True))
+        return dense(cfg.hidden_size, cfg, "o_proj")(
             ctx.reshape(B, S, H * D)
         )
 
 
 class OuroLayer(nn.Module):
     """One decoder layer, sandwich-normed: a norm before AND after each
-    sub-layer, the residual added after the second."""
+    sub-layer, the residual added after the second. Returns (hidden, None):
+    a scan step's carry and its (absent) per-step out."""
 
     cfg: OuroConfig
 
@@ -231,20 +153,8 @@ class OuroLayer(nn.Module):
         hidden = hidden + RMSNorm(cfg, name="input_layernorm_2")(attn)
         x = RMSNorm(cfg, name="post_attention_layernorm")(hidden)
         mlp = swiglu(cfg, x, cfg.intermediate_size)
-        return hidden + RMSNorm(cfg, name="post_attention_layernorm_2")(mlp)
-
-
-class _ScannedLayer(nn.Module):
-    """Inner scan body: carry = hidden; rope broadcast; no per-step out."""
-
-    cfg: OuroConfig
-
-    @nn.compact
-    def __call__(self, hidden, rope):
-        layer_cls = nn.remat(
-            OuroLayer, policy=remat_policy_object(self.cfg.remat_policy)
-        )
-        return layer_cls(self.cfg, name="block")(hidden, rope), None
+        mlp = RMSNorm(cfg, name="post_attention_layernorm_2")(mlp)
+        return hidden + mlp, None
 
 
 class _Pass(nn.Module):
@@ -256,14 +166,10 @@ class _Pass(nn.Module):
     @nn.compact
     def __call__(self, hidden, rope):
         cfg = self.cfg
-        stack = nn.scan(
-            _ScannedLayer,
-            variable_axes={"params": 0},  # L distinct layers, stacked
-            split_rngs={"params": True},
-            in_axes=nn.broadcast,
-            length=cfg.num_hidden_layers,
-        )
-        hidden, _ = stack(cfg, name="layers")(hidden, rope)
+        # L distinct layers, stacked, a layer a step
+        hidden, _ = scan_layers(ScannedBlock, cfg.num_hidden_layers)(
+            functools.partial(remat_layer, OuroLayer, cfg), name="layers"
+        )(hidden, rope)
         hidden = RMSNorm(cfg, name="norm")(hidden)
         gate = nn.Dense(
             1, dtype=jnp.float32, param_dtype=jnp.float32,
@@ -284,16 +190,7 @@ class OuroForCausalLM(nn.Module):
     @nn.compact
     def __call__(self, input_ids) -> Tuple[jnp.ndarray, jnp.ndarray]:
         cfg = self.cfg
-        init = nn.initializers.normal(cfg.initializer_range)
-        embed = self.param(
-            "embed_tokens", init, (cfg.vocab_size, cfg.hidden_size),
-            jnp.float32,
-        )
-        # untied: declared here so the whole model is one parameter tree
-        self.param(
-            "lm_head", init, (cfg.hidden_size, cfg.vocab_size), jnp.float32
-        )
-        hidden = jnp.take(embed, input_ids, axis=0).astype(cfg.dtype)
+        hidden = embed_tokens(self, input_ids)
         rope = rope_tables(input_ids.shape[1], cfg.head_dim, cfg.rope_theta)
         passes = nn.scan(
             _Pass,
@@ -329,33 +226,6 @@ def exit_distribution(gate_logits):
     return jnp.exp(log_p), log_p
 
 
-def chunked_cross_entropy(hiddens, lm_head, labels, chunk_tokens: int):
-    """Per-token CE of every pass, [T, N] float32, from hiddens [T, N, H],
-    the head [H, V] (already in the compute dtype) and labels [N]: one
-    (pass, chunk) of logits at a time, under remat — the backward replays
-    the chunk's matmul instead of keeping [T, N, V]."""
-    T, N, H = hiddens.shape
-    chunk = min(chunk_tokens, N)
-    if N % chunk:
-        raise ValueError(
-            f"loss_chunk_tokens ({chunk_tokens}) must divide the "
-            f"micro-batch's tokens ({N})"
-        )
-
-    @jax.checkpoint
-    def one(h, y):  # [chunk, H], [chunk] -> [chunk]
-        logits = jnp.dot(h, lm_head, preferred_element_type=jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
-        return lse - picked
-
-    h = hiddens.reshape(T * (N // chunk), chunk, H)
-    y = jnp.broadcast_to(
-        labels.reshape(1, N // chunk, chunk), (T, N // chunk, chunk)
-    ).reshape(T * (N // chunk), chunk)
-    return jax.lax.map(lambda hy: one(*hy), (h, y)).reshape(T, N)
-
-
 def gated_loss(ce, gate_logits, beta: float):
     """The paper's stage-I objective from per-pass CE [T, N] and gate
     logits [T, N]: (loss, metrics with the per-pass means ``lm.loss`` and
@@ -385,15 +255,12 @@ def ouro_loss(model: OuroForCausalLM, params, batch: Dict[str, jnp.ndarray]):
     return gated_loss(ce, gates.reshape(T, -1), cfg.exit_entropy_beta)
 
 
-def ouro_weight_decay_mask(params):
-    """True where weight decay applies: every matrix (projections, both
-    embedding matrices, the gate's kernel); not the RMSNorm ``weight``s nor
-    the gate's ``bias`` (the reference recipe's no_decay = bias + norm
-    weights, ``optim.lamb.albert_weight_decay_mask``, in this model's
-    names)."""
-    return jax.tree_util.tree_map_with_path(
-        lambda path, _: path[-1].key not in ("weight", "bias"), params
-    )
+# decayed: every matrix (projections, both embeddings, the gate's kernel);
+# not the RMSNorm ``weight``s nor the gate's ``bias`` — the reference recipe's
+# no_decay (``optim.lamb.albert_weight_decay_mask``) in this model's names
+ouro_weight_decay_mask = functools.partial(
+    weight_decay_mask, exempt=("weight", "bias")
+)
 
 
 def ouro_train_tflops_per_sample(cfg: OuroConfig, seq: int) -> float:

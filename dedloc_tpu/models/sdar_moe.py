@@ -31,15 +31,14 @@ same equations in plain ``jax.numpy``:
     — the prediction for position p is read AT position p (no shift); no
     auxiliary or balancing term, no leaf stepped by a sign.
 
-The program's shape: a uniform stack (every layer the same kind) under ONE
-``nn.scan`` whose step runs ``SCAN_PERIOD`` remat'd layers, each with
-leaves of its own (``models/smallthinker.py``'s period; see ``_Period`` for
-why not a layer a step); RMSNorm, RoPE and the chunked head + cross-entropy are Ouro's, the
-routed layer is ``models/smallthinker.RoutedGLU`` with a SiLU gate
-(``parallel/moe.routed_experts`` and its gradient sinks); attention is ONE
-call of ``ops/flash_attention.py`` over both streams with
-``block_diffusion=B``: the visibility rule is inside the grouped kernels
-(``flash_bd_*``), tiles outside it are neither grid steps nor fetched.
+The program's shape: a uniform stack, ``decoder.scan_periods``' at a period
+of ``SCAN_PERIOD`` layers (see there for why not a layer a step). The blocks,
+the grouped-query attention (here with a q / k norm), the routed layer
+(``decoder.RoutedGLU`` with a SiLU gate) and the routing metrics are
+``models/decoder.py``'s; attention is ONE call of ``ops/flash_attention.py``
+over both streams with ``block_diffusion=B``: the visibility rule is inside
+the grouped kernels (``flash_bd_*``), tiles outside it are neither grid steps
+nor fetched.
 
 **A chip's share**, as for the other expert decoders: ``expert_shard`` (the
 experts held of every layer), ``vocab_size`` (rows held of the embedding AND
@@ -48,27 +47,38 @@ of the head; the LAST held row is the mask id) and ``num_hidden_layers``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Tuple
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 
-from dedloc_tpu.models.albert import remat_policy_object
-from dedloc_tpu.models.deepseek_v3 import GRAD_SINKS, apply_with_grad_sinks
-from dedloc_tpu.models.ouro import (
+from dedloc_tpu.models.decoder import (
+    GroupedQueryAttention,
     RMSNorm,
-    _dense,
-    apply_rope,
+    RoutedGLU,
+    Visibility,
+    apply_with_grad_sinks,
     chunked_cross_entropy,
+    embed_tokens,
+    held_range,
+    named_config,
     rope_tables,
+    routed_metrics,
+    scan_periods,
 )
-from dedloc_tpu.models.smallthinker import RoutedGLU
+from dedloc_tpu.models.remat import remat_layer
 from dedloc_tpu.ops.flash_attention import _pick_block, visited_tiles
 
-
-SCAN_PERIOD = 4  # layers a scan step runs, unrolled (see ``_Period``)
+# Layers a scan step runs, unrolled, each with leaves of its own. Not a layer
+# a step: a scan over single layers hands the backward every layer's expert
+# gradients STACKED, so the tile loop's gradient sinks are slices of a fresh
+# buffer — at four layers of 16 held experts a zero fill, a copy of the
+# accumulator's leaves and a bf16 copy of the weights, 3.0 GB of scratch
+# beside a state that leaves 2.5 (``tools/tpu_aot.py sdar_accumulate_step``:
+# 5.62 GB a layer a step). With a period's layers unrolled the sinks ARE the
+# accumulator's leaves.
+SCAN_PERIOD = 4
 
 
 @dataclasses.dataclass(frozen=True)
@@ -94,7 +104,7 @@ class SdarMoeConfig:
     expert_shard: Tuple[int, int] = (0, 1)
     moe_row_tile: int = 256
     dtype: Any = jnp.bfloat16  # compute dtype; params stay fp32
-    # a name of albert.remat_policy_object's table. "kernel_operands": the
+    # a name of models/remat.py's table. "kernel_operands": the
     # layer keeps q / k / v as the flash kernels read them (after the q / k
     # norm and RoPE) beside out + lse, so the backward's replay runs no
     # v projection, normalising multiply, RoPE or relayout; q_proj and
@@ -102,7 +112,7 @@ class SdarMoeConfig:
     # input): 8,192 x (32 + 2·4) x 128 x 2 bytes = 84 MB a layer a
     # micro-batch, 0.34 GB in the benchmark's cell of four layers, where
     # accumulate_step's scratch reads 1.28 GB (1.57 before the barrier in
-    # TwoStreamAttention) beside 12.78 GB of state while a backup drains. A
+    # decoder.GroupedQueryAttention) beside 12.78 GB of state while a backup drains. A
     # smaller chip or a larger share: --training.remat_policy kernel_outputs
     remat_policy: str = "kernel_operands"
     attention_impl: str = "flash"  # or "dense" (tests, tiny models)
@@ -111,19 +121,12 @@ class SdarMoeConfig:
     mesh: Any = None
 
     def __post_init__(self):
-        index, count = self.expert_shard
-        if not (0 <= index < count) or self.num_experts % count:
-            raise ValueError(
-                f"expert_shard {index}/{count}: the count must divide the "
-                f"{self.num_experts} routed experts, 0 <= index < count"
-            )
+        held_range(self.expert_shard, self.num_experts)  # raises
 
     @property
     def held_experts(self) -> Tuple[int, int]:
         """(first expert held, how many)."""
-        index, count = self.expert_shard
-        n = self.num_experts // count
-        return index * n, n
+        return held_range(self.expert_shard, self.num_experts)
 
     @property
     def mask_token_id(self) -> int:
@@ -132,14 +135,10 @@ class SdarMoeConfig:
 
     @staticmethod
     def named(model_size: str):
-        ctors = {"sdar_30b_a3b": SdarMoeConfig.sdar_30b_a3b,
-                 "sdar_tiny": SdarMoeConfig.tiny}
-        if model_size not in ctors:
-            raise ValueError(
-                f"unknown model_size {model_size!r} "
-                f"(expected one of {sorted(ctors)})"
-            )
-        return ctors[model_size]
+        return named_config(model_size, {
+            "sdar_30b_a3b": SdarMoeConfig.sdar_30b_a3b,
+            "sdar_tiny": SdarMoeConfig.tiny,
+        })
 
     @staticmethod
     def sdar_30b_a3b(**overrides) -> "SdarMoeConfig":
@@ -162,122 +161,24 @@ class SdarMoeConfig:
         return SdarMoeConfig(**base)
 
 
-def block_visibility(length: int, block: int):
-    """[2L, 2L] bool, rows queries: the rule over [noisy ; clean]."""
-    i = jnp.arange(2 * length)
-    clean, blk = i >= length, (i % length) // block
-    q_clean, k_clean = clean[:, None], clean[None, :]
-    q_blk, k_blk = blk[:, None], blk[None, :]
-    return jnp.where(
-        q_clean, k_clean & (k_blk <= q_blk),
-        jnp.where(k_clean, k_blk < q_blk, k_blk == q_blk),
-    )
-
-
-class TwoStreamAttention(nn.Module):
-    """Grouped-query attention over [noisy ; clean] under the block rule,
-    q and k normalised per head and rotated by their position IN THEIR
-    STREAM."""
-
-    cfg: SdarMoeConfig
-
-    @nn.compact
-    def __call__(self, hidden, rope):
-        cfg = self.cfg
-        B, S, _ = hidden.shape
-        H, KV, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                    cfg.head_dim)
-        cos, sin = rope
-        q = _dense(H * D, cfg, "q_proj")(hidden).reshape(B, S, H, D)
-        k = _dense(KV * D, cfg, "k_proj")(hidden).reshape(B, S, KV, D)
-        v = _dense(KV * D, cfg, "v_proj")(hidden).reshape(B, S, KV, D)
-        # per head, over its own lanes; then the rotation
-        q = apply_rope(RMSNorm(cfg, name="q_norm")(q), cos, sin)
-        k = apply_rope(RMSNorm(cfg, name="k_norm")(k), cos, sin)
-        if cfg.attention_impl == "flash":
-            from dedloc_tpu.ops.flash_attention import flash_attention
-
-            # the kernels' operands as buffers of their own. Without the
-            # barrier XLA:TPU folds RoPE's last add + cast into each of
-            # their consumers and relays the float32 pieces BEFORE that add
-            # out around every one (`copy`, `add_convert_fusion`, `reshape`:
-            # 20 ms a micro-batch in the benchmark's cell): accumulate_step
-            # 185.9 → 166.2 ms with the barrier alone, 159.9 with the
-            # operands kept too; scratch 2.55 GB without it under
-            # "kernel_operands", 1.28 with it (PERF.md section 6, PR 41)
-            q, k, v = jax.lax.optimization_barrier((q, k, v))
-            ctx = flash_attention(
-                q, k, v, block_diffusion=cfg.block_length,
-                block_q=cfg.attention_block_size,
-                block_k=cfg.attention_block_size, mesh=cfg.mesh,
-            )
-        elif cfg.attention_impl == "dense":
-            q, k, v = (checkpoint_name(x, "flash_qkv") for x in (q, k, v))
-            grouped = q.reshape(B, S, KV, H // KV, D)
-            logits = jnp.einsum(
-                "bqcgd,bkcd->bcgqk", grouped, k,
-                preferred_element_type=jnp.float32,
-            ) / jnp.sqrt(jnp.float32(D))
-            logits = jnp.where(
-                block_visibility(S // 2, cfg.block_length), logits, -1e30
-            )
-            probs = jax.nn.softmax(logits, axis=-1).astype(cfg.dtype)
-            ctx = jnp.einsum("bcgqk,bkcd->bqcgd", probs, v)
-        else:
-            raise ValueError(
-                f"attention_impl={cfg.attention_impl!r}: this model takes "
-                "'flash' or 'dense'"
-            )
-        return _dense(cfg.hidden_size, cfg, "o_proj")(
-            ctx.reshape(B, S, H * D)
-        )
-
-
 class DecoderLayer(nn.Module):
     """h' = h + Attn(RMSNorm(h)); h'' = h' + Experts(RMSNorm(h')), routed
-    by the same normalised stream. Returns (h'', routing)."""
+    by the same normalised stream. Attention is grouped-query over [noisy ;
+    clean] under the block rule, q and k normalised per head and rotated by
+    their position IN THEIR STREAM. Returns (h'', routing)."""
 
     cfg: SdarMoeConfig
 
     @nn.compact
     def __call__(self, hidden, rope):
         cfg = self.cfg
-        hidden = hidden + TwoStreamAttention(cfg, name="self_attn")(
-            RMSNorm(cfg, name="input_layernorm")(hidden), rope
-        )
+        hidden = hidden + GroupedQueryAttention(
+            cfg, Visibility(block_diffusion=cfg.block_length),
+            qk_norms=("q_norm", "k_norm"), name="self_attn",
+        )(RMSNorm(cfg, name="input_layernorm")(hidden), rope)
         x = RMSNorm(cfg, name="post_attention_layernorm")(hidden)
         y, routing = RoutedGLU(cfg, activation="silu", name="mlp")(x, x)
         return hidden + y, routing
-
-
-def _layer(cfg: SdarMoeConfig, name: str):
-    return nn.remat(
-        DecoderLayer, policy=remat_policy_object(cfg.remat_policy)
-    )(cfg, name=name)
-
-
-class _Period(nn.Module):
-    """Scan body: ``layers`` remat'd layers, each with leaves of its own.
-    carry = hidden; rope broadcast; per-step out = their routing, stacked.
-
-    Not a layer a step: a scan over single layers hands the backward every
-    layer's expert gradients STACKED, so the tile loop's gradient sinks are
-    slices of a fresh buffer — at four layers of 16 held experts a zero
-    fill, a copy of the accumulator's leaves and a bf16 copy of the weights,
-    3.0 GB of scratch beside a state that leaves 2.5 (``tools/tpu_aot.py
-    sdar_accumulate_step``: 5.62 GB a layer a step). With a period's layers
-    unrolled the sinks ARE the accumulator's leaves."""
-
-    cfg: SdarMoeConfig
-    layers: int
-
-    @nn.compact
-    def __call__(self, hidden, rope):
-        routings = []
-        for i in range(self.layers):
-            hidden, routing = _layer(self.cfg, f"layer_{i}")(hidden, rope)
-            routings.append(routing)
-        return hidden, jax.tree.map(lambda *xs: jnp.stack(xs), *routings)
 
 
 class SdarMoeForDiffusionLM(nn.Module):
@@ -295,39 +196,18 @@ class SdarMoeForDiffusionLM(nn.Module):
                  both_streams: bool = False) -> Tuple[jnp.ndarray, Dict]:
         cfg = self.cfg
         length = input_ids.shape[1] // 2
-        init = nn.initializers.normal(cfg.initializer_range)
-        embed = self.param(
-            "embed_tokens", init, (cfg.vocab_size, cfg.hidden_size),
-            jnp.float32,
-        )
-        self.param(
-            "lm_head", init, (cfg.hidden_size, cfg.vocab_size), jnp.float32
-        )
-        hidden = jnp.take(embed, input_ids, axis=0).astype(cfg.dtype)
+        hidden = embed_tokens(self, input_ids)
         # positions 0 .. L-1 TWICE: a position's two copies rotate alike
         rope = tuple(
             jnp.concatenate([table, table]) for table in rope_tables(
                 length, cfg.head_dim, cfg.rope_theta
             )
         )
-        period = min(SCAN_PERIOD, cfg.num_hidden_layers)
-        periods = cfg.num_hidden_layers // period
-        stack = nn.scan(
-            _Period,
-            variable_axes={"params": 0, GRAD_SINKS: 0},
-            split_rngs={"params": True},
-            in_axes=nn.broadcast,
-            length=periods,
+        hidden, routing = scan_periods(
+            functools.partial(remat_layer, DecoderLayer, cfg),
+            ((),) * cfg.num_hidden_layers,
+            min(SCAN_PERIOD, cfg.num_hidden_layers), hidden, rope,
         )
-        hidden, routing = stack(cfg, period, name="layers")(hidden, rope)
-        # [periods, period, ...] -> [layers, ...]
-        routings = [jax.tree.map(
-            lambda x: x.reshape((-1,) + x.shape[2:]), routing
-        )]
-        for i in range(cfg.num_hidden_layers - periods * period):
-            hidden, routing = _layer(cfg, f"tail_layer_{i}")(hidden, rope)
-            routings.append(jax.tree.map(lambda x: x[None], routing))
-        routing = jax.tree.map(lambda *xs: jnp.concatenate(xs), *routings)
         if not both_streams:
             hidden = hidden[:, :length]
         return RMSNorm(cfg, name="norm")(hidden), routing
@@ -335,9 +215,8 @@ class SdarMoeForDiffusionLM(nn.Module):
 
 def bd_tile_share(cfg: SdarMoeConfig, seq: int) -> float:
     """(query tile, key tile) pairs the block-diffusion kernels visit over
-    those of a causal call on the same 2L positions and tiles, from the
-    shapes: 80 / 136 at L = 4,096 and 512 x 512 tiles; 1 where a tile is a
-    stream."""
+    those of a causal call on the same 2L positions and tiles, from the shapes:
+    80 / 136 at L = 4,096 and 512 x 512 tiles; 1 where a tile is a stream."""
     block = _pick_block(seq, cfg.attention_block_size)  # of ONE stream
     return visited_tiles(
         2 * seq, block, block, False, block_diffusion=cfg.block_length
@@ -348,10 +227,10 @@ def sdar_moe_loss(model: SdarMoeForDiffusionLM, params,
                   batch: Dict[str, jnp.ndarray], grad_sinks=None):
     """(loss, metrics) of one micro-batch of ``data/block_diffusion.py``:
     ``input_ids`` (x~), ``labels`` (x) and ``loss_weights`` (w), [B, L]
-    each. The routed metrics and ``grad_sinks`` are
-    ``models/deepseek_v3.deepseek_v3_loss``'s without a bias to report
-    (``moe.scores`` holds the router's LOGITS, over both streams); beside
-    them the masked positions' share and count, and ``attn.bd_tile_share``."""
+    each. The routed metrics are ``decoder.routed_metrics``' (``moe.scores``
+    holds the router's LOGITS, over both streams; no bias to report) and
+    ``grad_sinks`` ``decoder.expert_lm_loss``'s; beside them the masked
+    positions' share and count, and ``attn.bd_tile_share``."""
     cfg = model.cfg
     clean, weights = batch["labels"], batch["loss_weights"]
     hidden, routing = apply_with_grad_sinks(
@@ -364,24 +243,15 @@ def sdar_moe_loss(model: SdarMoeForDiffusionLM, params,
         cfg.loss_chunk_tokens,
     )
     loss = jnp.sum(ce * weights.reshape(1, -1)) / clean.size
-    load = routing["load"]  # [layers, E]
     masked = jnp.sum(weights > 0)
+    share = bd_tile_share(cfg, clean.shape[1])
     return loss, {
         "loss": loss,
         "diffusion.masked_share": masked / clean.size,
         "diffusion.masked_tokens": masked.astype(jnp.float32),
-        "moe.load_max_over_mean": jnp.max(load, axis=1) / jnp.mean(
-            load, axis=1
-        ),
-        "moe.local_slot_share": jnp.mean(routing["local_slot_share"]),
-        "moe.bulk_row_share": jnp.mean(routing["bulk_row_share"]),
-        "moe.dropped_slots": jnp.sum(routing["dropped_slots"]),
-        "moe.grad_sink_leaves": jnp.sum(routing["grad_sink_leaves"]),
-        "attn.bd_tile_share": jnp.float32(
-            bd_tile_share(cfg, clean.shape[1])
-        ),
-        "moe.choice": routing["choice"],
-        "moe.scores": routing["scores"],
+        **routed_metrics(routing, params, {
+            "attn.bd_tile_share": lambda _p: jnp.float32(share),
+        }),
     }
 
 

@@ -14,7 +14,7 @@ backward  (dy) -> (da, dγ, dβ)       one kernel: da serves both dx and dr
                                      (the residual add backpropagates the
                                      same cotangent to both inputs)
 
-Designed to compose with the ``fused_ln`` remat policy (models/albert.py):
+Designed to compose with the ``fused_ln`` remat policy (models/remat.py):
 Pallas outputs are saveable, so (y, x̂, rstd) survive remat and the backward
 runs straight from them — no add/LN replay at all. The policy drops the two
 out-projection matmul saves the adds used to consume (attention out-proj,

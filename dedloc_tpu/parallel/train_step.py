@@ -92,7 +92,7 @@ def stash_bytes(loss_fn: LossFn, params, batch, rng) -> int:
     """Bytes the forward of ONE micro-batch hands its backward besides the
     arguments: the residuals of ``jax.vjp`` over ``loss_fn`` with respect to
     ``params`` — under a layer remat policy the layer inputs and what the
-    policy keeps (``models/albert.remat_policy_object``), plus what lies
+    policy keeps (``models/remat.remat_policy_object``), plus what lies
     outside the remat'd layers (position tables, the final norm, the head's
     chunks). From the shapes alone: ``params`` and ``batch`` may be
     ``jax.ShapeDtypeStruct`` trees, nothing is allocated or run. It counts

@@ -22,20 +22,18 @@ from dedloc_tpu.models.albert import (
     albert_pretraining_loss,
     albert_pretraining_loss_gathered,
 )
+from dedloc_tpu.models.decoder import routed_grad_sink_mask, sign_step_mask
 from dedloc_tpu.models.deepseek_v3 import (
     DeepseekV3Config,
     DeepseekV3ForCausalLM,
     deepseek_v3_loss,
-    deepseek_v3_sign_step_mask,
     deepseek_v3_train_tflops_per_sample,
     deepseek_v3_weight_decay_mask,
-    routed_grad_sink_mask,
 )
 from dedloc_tpu.models.lfm2_moe import (
     Lfm2MoeConfig,
     Lfm2MoeForCausalLM,
     lfm2_moe_loss,
-    lfm2_moe_sign_step_mask,
     lfm2_moe_train_tflops_per_sample,
     lfm2_moe_weight_decay_mask,
 )
@@ -217,7 +215,7 @@ DEEPSEEK_V3 = ModelFamily(
         "moe.grad_sink_leaves", "moe.bulk_row_share",
     ),
     step_counters=("moe.dropped_slots",),
-    sign_step_mask=deepseek_v3_sign_step_mask,
+    sign_step_mask=sign_step_mask,
     sign_step=DeepseekV3Config.bias_update_speed,
     grad_sink_mask=routed_grad_sink_mask,
 )
@@ -227,7 +225,6 @@ LFM2_MOE = dataclasses.replace(
     loss=_without_rng(lfm2_moe_loss),
     tflops_per_sample=lfm2_moe_train_tflops_per_sample,
     weight_decay_mask=lfm2_moe_weight_decay_mask,
-    sign_step_mask=lfm2_moe_sign_step_mask,
     sign_step=Lfm2MoeConfig.bias_update_speed,
 )
 SMALLTHINKER = dataclasses.replace(
@@ -276,8 +273,7 @@ def model_family(model) -> ModelFamily:
             )
         return MODEL_FAMILIES[model]
     cfg = getattr(model, "cfg", model)
-    for family in (ALBERT, OURO, DEEPSEEK_V3, LFM2_MOE, SMALLTHINKER,
-                   SDAR_MOE):
+    for family in MODEL_FAMILIES.values():
         if isinstance(cfg, family.config):
             return family
     raise TypeError(f"no model family for {type(cfg).__name__}")

@@ -295,7 +295,7 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
 
     loss_fn = build_loss_fn(model)
     # what the layers keep for their backward is a name of the table in
-    # models/albert.py; its bytes are the gauge ``remat.kept_bytes``
+    # models/remat.py; its bytes are the gauge ``remat.kept_bytes``
     logger.info(f"remat: remat_policy={cfg.remat_policy}")
     accumulate = make_accumulate_step(
         loss_fn,

@@ -12,13 +12,11 @@ import numpy as np
 import pytest
 
 from benchmark.reference import deepseek_v3 as reference
+from dedloc_tpu.models.decoder import BIAS, RoutedFFN, sign_step_mask
 from dedloc_tpu.models.deepseek_v3 import (
-    BIAS,
     DeepseekV3Config,
     DeepseekV3ForCausalLM,
-    RoutedFFN,
     deepseek_v3_loss,
-    deepseek_v3_sign_step_mask,
     deepseek_v3_train_tflops_per_sample,
     deepseek_v3_weight_decay_mask,
 )
@@ -185,7 +183,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
 def test_masks_and_flops():
     cfg, _model, params, _batch = _setup()
     decay = deepseek_v3_weight_decay_mask(params)
-    signed = deepseek_v3_sign_step_mask(params)
+    signed = sign_step_mask(params)
     mlp = "mlp"
     assert signed["layers"]["block"][mlp][BIAS] is True
     assert decay["layers"]["block"][mlp][BIAS] is False

@@ -10,7 +10,8 @@ import numpy as np
 import pytest
 
 from dedloc_tpu.core.config import CollaborationArguments, parse_config
-from dedloc_tpu.models.deepseek_v3 import BIAS, DeepseekV3Config
+from dedloc_tpu.models.decoder import BIAS, EXPERT_LEAVES
+from dedloc_tpu.models.deepseek_v3 import DeepseekV3Config
 from dedloc_tpu.roles.common import DEEPSEEK_V3, build_model, model_family
 from dedloc_tpu.roles.trainer import run_trainer
 
@@ -137,7 +138,6 @@ def test_accumulate_step_leaves_expert_gradients_in_the_accumulator():
     the benchmark make — hands the accumulator's expert leaves to the tile
     loop: over two micro-batches they hold the float32 sums the plain step
     rounds to bf16 first, every other leaf is the plain step's exactly."""
-    from dedloc_tpu.models.deepseek_v3 import EXPERT_LEAVES
     from dedloc_tpu.parallel.train_step import (
         GradSinkLoss,
         make_accumulate_step,
@@ -202,7 +202,6 @@ def test_a_marked_leaf_that_no_module_reads_stops_the_trace(stray):
     that zero over the accumulated gradient: tracing it raises instead."""
     import jax.numpy as jnp
 
-    from dedloc_tpu.models.deepseek_v3 import EXPERT_LEAVES
     from dedloc_tpu.parallel.train_step import (
         GradSinkLoss,
         make_accumulate_step,

@@ -13,14 +13,13 @@ import numpy as np
 import pytest
 
 from benchmark.reference import lfm2_moe as reference
-from dedloc_tpu.models.deepseek_v3 import BIAS, RoutedFFN
+from dedloc_tpu.models.decoder import BIAS, RoutedFFN, sign_step_mask
 from dedloc_tpu.models.lfm2_moe import (
     ATTENTION,
     CONV,
     Lfm2MoeConfig,
     Lfm2MoeForCausalLM,
     lfm2_moe_loss,
-    lfm2_moe_sign_step_mask,
     lfm2_moe_train_tflops_per_sample,
     lfm2_moe_weight_decay_mask,
 )
@@ -223,7 +222,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
 def test_masks_and_flops():
     cfg, _model, params, _batch = _setup()
     decay = lfm2_moe_weight_decay_mask(params)
-    signed = lfm2_moe_sign_step_mask(params)
+    signed = sign_step_mask(params)
     ffn = "feed_forward"
     assert signed["layers"]["layer_0"][ffn][BIAS] is True
     assert signed["tail_layer_0"][ffn][BIAS] is True

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from dedloc_tpu.core.config import CollaborationArguments, parse_config
-from dedloc_tpu.models.deepseek_v3 import BIAS
+from dedloc_tpu.models.decoder import BIAS, EXPERT_LEAVES
 from dedloc_tpu.models.lfm2_moe import Lfm2MoeConfig
 from dedloc_tpu.roles.common import (
     DEEPSEEK_V3,
@@ -188,7 +188,6 @@ def test_accumulate_step_leaves_expert_gradients_in_the_accumulator():
     layer's are sinks of ``make_accumulate_step(build_loss_fn(model))``:
     float32 sums where the plain step adds bf16-rounded gradients, every
     other leaf exactly the plain step's."""
-    from dedloc_tpu.models.deepseek_v3 import EXPERT_LEAVES
     from dedloc_tpu.parallel.train_step import make_accumulate_step
 
     _model, params, batches, loss_fn = _sink_case("lfm2_tiny")

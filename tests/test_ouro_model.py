@@ -16,17 +16,17 @@ import numpy as np
 import pytest
 
 from benchmark.reference import ouro as reference
-from dedloc_tpu.models.albert import remat_policy_object
+from dedloc_tpu.models.decoder import chunked_cross_entropy
 from dedloc_tpu.models.ouro import (
     OuroConfig,
     OuroForCausalLM,
-    chunked_cross_entropy,
     exit_distribution,
     gated_loss,
     ouro_logits,
     ouro_loss,
     ouro_train_tflops_per_sample,
 )
+from dedloc_tpu.models.remat import remat_policy_object
 
 # float32 on both sides: what is left is the order of the arithmetic
 LOSS_TOL, GRAD_TOL, LEAF_TOL = 2e-6, 2e-5, 1e-4

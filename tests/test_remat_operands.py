@@ -12,10 +12,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dedloc_tpu.models.albert import remat_policy_object
 from dedloc_tpu.models.deepseek_v3 import DeepseekV3Config
 from dedloc_tpu.models.lfm2_moe import Lfm2MoeConfig
 from dedloc_tpu.models.ouro import OuroConfig
+from dedloc_tpu.models.remat import remat_policy_object
 from dedloc_tpu.models.sdar_moe import SdarMoeConfig
 from dedloc_tpu.models.smallthinker import SmallThinkerConfig
 from dedloc_tpu.parallel.train_step import stash_bytes

@@ -17,18 +17,17 @@ import numpy as np
 import pytest
 
 from benchmark.reference import sdar_moe as reference
-from dedloc_tpu.models import smallthinker
 from dedloc_tpu.data.block_diffusion import block_diffusion_batches
+from dedloc_tpu.models import decoder
+from dedloc_tpu.models.decoder import RoutedGLU, Visibility
 from dedloc_tpu.models.sdar_moe import (
     SdarMoeConfig,
     SdarMoeForDiffusionLM,
     bd_tile_share,
-    block_visibility,
     sdar_moe_flops_per_row,
     sdar_moe_loss,
     sdar_moe_train_tflops_per_sample,
 )
-from dedloc_tpu.models.smallthinker import RoutedGLU
 from dedloc_tpu.roles.common import SDAR_MOE
 
 # float32 on both sides: what is left is the order of the arithmetic
@@ -219,8 +218,8 @@ def test_no_leak(impl, monkeypatch):
     what ATTENTION shows a position, so the experts walk their rows one
     tile an iteration (``run_tiles=1``), where a row's value is the same
     bits whatever the other rows do; the walk that ships is the next test."""
-    monkeypatch.setattr(smallthinker, "routed_experts", functools.partial(
-        smallthinker.routed_experts, run_tiles=1
+    monkeypatch.setattr(decoder, "routed_experts", functools.partial(
+        decoder.routed_experts, run_tiles=1
     ))
     _no_leak(impl, np.testing.assert_array_equal)
 
@@ -255,7 +254,7 @@ def test_the_block_diffusion_kernels_inside_the_model():
     assert float(metrics["attn.bd_tile_share"]) == pytest.approx(24 / 36)
     assert bd_tile_share(SdarMoeConfig(), 4096) == 80 / 136
     np.testing.assert_array_equal(
-        block_visibility(8, 4),
+        Visibility(block_diffusion=4).matrix(16),
         reference.visible(8, 4),
     )
 

@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from dedloc_tpu.core.config import CollaborationArguments, parse_config
-from dedloc_tpu.models.deepseek_v3 import EXPERT_LEAVES
+from dedloc_tpu.models.decoder import EXPERT_LEAVES
 from dedloc_tpu.models.sdar_moe import SdarMoeConfig
 from dedloc_tpu.roles.common import (
     DEEPSEEK_V3,

@@ -14,10 +14,10 @@ import numpy as np
 import pytest
 
 from benchmark.reference import smallthinker as reference
+from dedloc_tpu.models.decoder import RoutedGLU
 from dedloc_tpu.models.smallthinker import (
     BAND_ROPE,
     GLOBAL_NOPE,
-    RoutedGLU,
     SmallThinkerConfig,
     SmallThinkerForCausalLM,
     band_tile_share,

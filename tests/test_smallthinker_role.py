@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from dedloc_tpu.core.config import CollaborationArguments, parse_config
-from dedloc_tpu.models.deepseek_v3 import EXPERT_LEAVES
+from dedloc_tpu.models.decoder import EXPERT_LEAVES
 from dedloc_tpu.models.smallthinker import SmallThinkerConfig
 from dedloc_tpu.parallel.train_step import stash_bytes
 from dedloc_tpu.roles.common import (
