@@ -104,6 +104,10 @@ def run_boundary_loop(
     train_log = open_train_log(t.train_log_path)
     samples = opt.batch_size_per_step
     exhausted = False
+    # the role's set-up record (telemetry/steps.py) is open until the end of
+    # the first global step: the laps below are its share of the loop, and
+    # no-ops from then on
+    first_micro = steps.current_setup() is not None
     try:
         while True:
             # one accumulation boundary = gradient_accumulation_steps
@@ -122,6 +126,8 @@ def run_boundary_loop(
                     if model.put is not None:
                         with steps.phase("h2d"):
                             batch = model.put(batch)
+                    if first_micro:
+                        steps.lap("data_source")  # ... and its first batch
                     with steps.phase("fwd_bwd"):
                         # everything the host enqueues for one micro-batch
                         grad_acc, n_acc, metrics = model.micro_step(
@@ -131,14 +137,24 @@ def run_boundary_loop(
                             k: sums_dev[k] + metrics[k] if k in sums_dev
                             else metrics[k] for k in summed
                         }
+                    if first_micro:
+                        first_micro = False
+                        # trace + lower + compile-or-load + dispatch; the
+                        # device's first run lands in the laps that follow
+                        steps.lap("first_micro_batch")
                     mini_steps += 1
                 if exhausted:
                     logger.info("the batch source ended; stopping")
                     break
+                # the rest of the first global step's micro-batches, and the
+                # calls below that only report progress
+                steps.lap("accumulate")
                 state, grad_acc, n_acc, stepped = opt.step(
                     state, grad_acc, n_acc, samples
                 )
                 if stepped:
+                    # the boundary programs' first calls
+                    steps.lap("first_boundary")
                     with steps.phase("post_step"):
                         with steps.phase("loss_sync"):
                             # the one sync per global step
@@ -208,6 +224,9 @@ def run_boundary_loop(
                                 # never fires again for the rest of the run
                                 model.save(state, opt.local_step)
                                 last_saved_step = opt.local_step
+                    # the peer is training: the set-up record ends here
+                    steps.lap("first_post_step")
+                    steps.close_setup(tele)
 
             boundary += 1
             if t.max_local_steps and boundary >= t.max_local_steps:

@@ -43,6 +43,7 @@ from dedloc_tpu.roles.common import (
     configure_role_telemetry,
 )
 from dedloc_tpu.roles.loop import LoopModel, run_boundary_loop
+from dedloc_tpu.telemetry import steps
 from dedloc_tpu.utils.backend import ensure_compile_cache
 from dedloc_tpu.utils.checkpoint import (
     load_latest_checkpoint,
@@ -103,14 +104,23 @@ def _build_flat_lars_factory(t):
 
 
 def run_swav(args: SwAVCollaborationArguments) -> TrainState:
+    # this peer's set-up record (telemetry/steps.py), under the same lap
+    # names as ``run_trainer``; a phase this role lacks has no lap
+    with steps.setup_record(logger):
+        return _run_swav(args)
+
+
+def _run_swav(args: SwAVCollaborationArguments) -> TrainState:
     ensure_compile_cache()
     t = args.training
     cfg, spec, model, tx = build_swav(args)
+    steps.lap("prepare")
     dht, public_key = build_dht(args)
     logger.info(f"swav peer DHT listening on {dht.port}")
     # swarm telemetry (--telemetry.*, docs/observability.md): same wiring as
     # the ALBERT trainer; disabled (default) costs nothing
     tele, tele_close = configure_role_telemetry(args, public_key)
+    steps.lap("dht")
 
     # slice-as-one-peer (same mapping as the ALBERT trainer): crops shard
     # over the data axis, so the sinkhorn sums inside the jitted loss ride
@@ -151,6 +161,8 @@ def run_swav(args: SwAVCollaborationArguments) -> TrainState:
         if cfg.queue_length
         else None
     )
+    # the EAGER init: one small program per initializer and shape
+    steps.lap("init_state")
 
     opt = build_collaborative_optimizer(
         args, tx, dht, public_key,
@@ -159,6 +171,7 @@ def run_swav(args: SwAVCollaborationArguments) -> TrainState:
         mesh=mesh,
         post_apply=make_prototype_post_apply(),
     )
+    steps.lap("collab_optimizer")
     # disk resume (same contract as the ALBERT trainer): newest checkpoint
     # restores params + batch_stats and seeds the collaborative counter; a
     # LIVE collaboration below still wins. LARC momentum is not part of the
@@ -180,6 +193,7 @@ def run_swav(args: SwAVCollaborationArguments) -> TrainState:
         except (KeyError, ValueError) as e:
             logger.warning(f"checkpoint incompatible ({e!r}); starting fresh")
             resumed = None  # genuinely fresh: keep cold-start adoption below
+    steps.lap("resume")
     # a DEEPER live collaboration wins over the disk checkpoint; the
     # reverse race (fresh partner raced ahead while we compiled) must not
     # (only_if_newer — see load_state_from_peers). Cold starts keep the
@@ -187,10 +201,12 @@ def run_swav(args: SwAVCollaborationArguments) -> TrainState:
     state = opt.load_state_from_peers(
         state, only_if_newer=resumed is not None
     )
+    steps.lap("state_from_peers")
     # share a pre-training snapshot (same as the ALBERT trainer): partners
     # that start while this peer is still compiling must find a provider —
     # and a resumed peer's deep state must be visible before its first step
     opt.seed_state_sharing(state)
+    steps.lap("seed_state_sharing")
 
     accumulate = make_swav_accumulate_step(
         model, cfg, mesh=mesh, num_crop_groups=len(spec.sizes)
@@ -203,6 +219,7 @@ def run_swav(args: SwAVCollaborationArguments) -> TrainState:
         )
     else:
         batches = synthetic_multicrop_batches(spec, slice_batch, seed=t.seed)
+    steps.lap("data_source")
 
     queue_engaged = False
     crop_sharding = (

@@ -35,6 +35,7 @@ from dedloc_tpu.roles.common import (
     model_family,
 )
 from dedloc_tpu.roles.loop import LoopModel, run_boundary_loop
+from dedloc_tpu.telemetry import steps
 from dedloc_tpu.utils.backend import describe_backend, ensure_compile_cache
 from dedloc_tpu.utils.checkpoint import (
     load_latest_checkpoint,
@@ -48,6 +49,14 @@ logger = get_logger(__name__)
 
 
 def run_trainer(args: CollaborationArguments) -> TrainState:
+    # the start of this peer, entry -> end of its first global step, is ONE
+    # set-up record (telemetry/steps.py): each ``steps.lap`` below closes the
+    # phase that ends there, the boundary loop closes the record
+    with steps.setup_record(logger):
+        return _run_trainer(args)
+
+
+def _run_trainer(args: CollaborationArguments) -> TrainState:
     cache_dir = ensure_compile_cache()
     # JAX lands on the CPU without a word when it finds no accelerator:
     # say where this peer computes, and how its Pallas kernels will run
@@ -155,6 +164,9 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
     )
     family = model_family(cfg)
     tx = build_optimizer(args)
+    # the backend, the token handshake, the mesh, the model and optimizer
+    # OBJECTS: no array yet
+    steps.lap("prepare")
     # gated: record-sign with the token key, so the signed subkey digests
     # to this peer's verified identity (ledger binding, roles/common.py)
     dht, public_key = build_dht(
@@ -167,6 +179,7 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
     # swarm telemetry (--telemetry.*, docs/observability.md): disabled
     # (default) => None and the instrumented seams stay free
     tele, tele_close = configure_role_telemetry(args, public_key)
+    steps.lap("dht")
 
     rng = jax.random.PRNGKey(args.training.seed)
     seq = min(args.training.seq_length, cfg.max_position_embeddings)
@@ -183,6 +196,9 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
         ),
         out_shardings=None if mesh is None else NamedSharding(mesh, P()),
     )(rng)
+    # the init's trace, lowering and compile; the device runs it beside what
+    # follows, up to the first host read of the state
+    steps.lap("init_state")
 
     # local resume (run_trainer.py:56-70): newest checkpoint* dir wins
     resumed = load_latest_checkpoint(args.training.output_dir)
@@ -202,6 +218,7 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
         # global step, not restart at 0
         resumed_local_step = int(meta.get("local_step", step))
         logger.info(f"resumed from local checkpoint at step {step}")
+    steps.lap("resume")
 
     if args.training.zero_sharding and mesh is None:
         raise ValueError(
@@ -265,6 +282,7 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
         authorizer=authorizer,
         authority_public_key=authority_public_key,
     )
+    steps.lap("collab_optimizer")
     # catch up with the collaboration before training (:124-128)
     # disk-resume seeds the collaborative counter; a DEEPER live
     # collaboration below still wins — only_if_newer guards the reverse
@@ -277,6 +295,7 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
     state = opt.load_state_from_peers(
         state, only_if_newer=resumed_local_step > 0
     )
+    steps.lap("state_from_peers")
     if mesh is not None:
         # commit state onto the mesh once — otherwise accumulate's
         # replicated in_shardings would re-broadcast the full params from
@@ -289,9 +308,11 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
                 state.opt_state, opt_sharding or repl
             ),
         )
+        steps.lap("mesh_commit")
     # share a pre-training snapshot: partners that miss the first rounds
     # (slow hosts still compiling) must find a state provider immediately
     opt.seed_state_sharing(state)
+    steps.lap("seed_state_sharing")
 
     loss_fn = build_loss_fn(model)
     # what the layers keep for their backward is a name of the table in
@@ -332,14 +353,15 @@ def run_trainer(args: CollaborationArguments) -> TrainState:
             save_total_limit=args.training.save_total_limit,
         )
 
+    # drop_collator_keys runs inside the draw: timed as data_wait
+    batches = map(
+        drop_collator_keys, _make_batches(args, cfg, public_key, slice_batch)
+    )
+    steps.lap("data_source")
     return run_boundary_loop(
         args,
         LoopModel(
-            # drop_collator_keys runs inside the draw: timed as data_wait
-            batches=map(
-                drop_collator_keys,
-                _make_batches(args, cfg, public_key, slice_batch),
-            ),
+            batches=batches,
             micro_step=micro_step,
             save=save,
             put=put if mesh is not None else None,

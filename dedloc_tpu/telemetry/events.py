@@ -133,6 +133,7 @@ SERVE_REROUTE = "serve.reroute"
 SERVE_REROUTED = "serve.rerouted"
 SERVE_RETRIES = "serve.retries"
 SERVE_TOKENS = "serve.tokens"
+SETUP_RECORD = "setup.record"
 STATE_SERVE = "state.serve"
 STATE_SERVED = "state.served"
 STATE_SERVED_BYTES = "state.served_bytes"
@@ -317,6 +318,7 @@ EVENTS = frozenset({
     "serve.reject",
     "serve.request",
     "serve.reroute",
+    "setup.record",
     "state.serve",
     "state_sync.checksum_failure",
     "state_sync.failed",

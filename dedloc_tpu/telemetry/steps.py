@@ -73,6 +73,14 @@ thread's clock readings (``matchmaking`` / ``allreduce`` / ``ar_*``,
 whole) are in ``spans`` only: they split their parent, they are not this
 thread's time.
 
+The SET-UP RECORD (``setup_record`` / ``lap`` / ``close_setup``) is the same
+machinery pointed at a peer's start: ONE record a peer, from the role's
+entry to the end of its first global step, whose spans are LAPS (each runs
+from the end of the one before it, so they tile the start) and whose
+``first_call.<program>`` children are made from JAX's own compile events
+(docs/observability.md "Set-up record"). It closes with one ``set-up:``
+line on the role's logger, telemetry on or off.
+
 Design rules, mirroring ``registry.py``:
 
 - **Timing is always on, publishing is not.** ``StepRecorder.step`` always
@@ -104,6 +112,7 @@ Design rules, mirroring ``registry.py``:
 from __future__ import annotations
 
 import contextvars
+import re
 import statistics
 from collections import deque
 from contextlib import contextmanager
@@ -385,6 +394,251 @@ def attach(name: str, t0: float, t1: float, **where) -> None:
     ctx = _CURRENT.get()
     if ctx is not None:
         ctx.attach(name, t0, t1, **where)
+
+
+# ------------------------------------------------------------------ set-up
+#
+# The start of a peer, role entry -> end of its first global step, as ONE
+# record on the machinery above. Its spans are LAPS: ``lap(name)`` closes a
+# span that began where the lap before it ended, so a role marks the end of
+# each phase with one line and the laps tile the start by construction.
+# While the record is open two ``jax.monitoring`` listeners file every
+# ``/jax/core/compile/*`` event of the record's thread under the lap it fell
+# in; they are removed when the record closes.
+
+# /jax/core/compile/<event> -> the key its seconds are summed under
+_COMPILE_KINDS = {
+    "jaxpr_trace_duration": "trace_s",
+    "jaxpr_to_mlir_module_duration": "lower_s",
+    "backend_compile_duration": "backend_s",  # a compile, or the cache's load
+}
+# a program whose first call (start of its trace -> end of its compile or
+# cache load) took at least this long is a ``first_call.<program>`` span; a
+# shorter one (SwAV's eager init runs hundreds) stays in its lap's sums
+FIRST_CALL_MIN_S = 0.05
+# JAX emits ``jaxpr_trace_duration`` around a CACHED trace too (microseconds):
+# only a longer event is a trace (the benchmark's
+# ``reducers/setup_compile.py`` counts by this same constant)
+TRACE_MIN_S = 1e-3
+# the line names this many programs, the costliest first calls
+TRACED_PROGRAMS = 8
+
+_SETUP: contextvars.ContextVar[Optional["_SetupContext"]] = (
+    contextvars.ContextVar("dedloc_setup", default=None)
+)
+
+
+def _program_name(fun_name: Any) -> str:
+    """``accumulate_step`` from the trace event's ``accumulate_step`` and from
+    the lowering's and the backend's ``jit(accumulate_step)``."""
+    name = str(fun_name).removeprefix("jit(").removesuffix(")")
+    return re.sub(r"\s+", "_", name) or "?"
+
+
+class _SetupContext(_StepContext):
+    """The live set-up record: a ``_StepContext`` whose spans are laps, plus
+    what the compile events of its thread add up to."""
+
+    __slots__ = ("compile", "traces", "cache", "complete", "_log", "_lap",
+                 "_pending", "_listeners")
+
+    def __init__(self, log, clock) -> None:
+        super().__init__(None, 0, 0, clock)
+        # lap name -> {"trace_s", "lower_s", "backend_s", "programs"}: the
+        # OUTERMOST compile events inside it (a trace nested in a trace is
+        # its parent's time), so the sums are wall seconds of the lap
+        self.compile: Dict[str, Dict[str, float]] = {}
+        self.traces: Dict[str, int] = {}  # program -> outermost traces
+        self.cache = {"hits": 0, "misses": 0}  # the persistent cache's
+        self.complete = False  # closed at the end of a first global step
+        self._log = log
+        self._lap = self._start
+        self._pending: List[tuple] = []  # (kind, program, t0, t1), by t1
+        self._listeners = self._listen()
+
+    def _listen(self) -> tuple:
+        import jax
+
+        def on_duration(event: str, duration: float, **kw) -> None:
+            kind = _COMPILE_KINDS.get(event.rpartition("/")[2])
+            if kind is None or _SETUP.get() is not self:
+                return  # another thread's (another peer's) compile
+            now = self._clock()
+            self._pending.append(
+                (kind, _program_name(kw.get("fun_name", "")),
+                 now - float(duration), now)
+            )
+
+        def on_event(event: str, **kw) -> None:
+            if _SETUP.get() is not self:
+                return
+            if event == "/jax/compilation_cache/cache_hits":
+                self.cache["hits"] += 1
+            elif event == "/jax/compilation_cache/cache_misses":
+                self.cache["misses"] += 1
+
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        jax.monitoring.register_event_listener(on_event)
+        return on_duration, on_event
+
+    def _unlisten(self) -> None:
+        import jax
+
+        on_duration, on_event = self._listeners
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+        jax.monitoring.unregister_event_listener(on_event)
+
+    def lap(self, name: str) -> None:
+        """Close a span called ``name`` from the end of the lap before it to
+        now, with the first calls that fell inside it as its children."""
+        span = Span(name, self)
+        span.t0, span.t1 = self._lap, self._clock()
+        self._stack.append(span)
+        self._file_compiles(span)
+        self._close(span)
+        self._lap = span.t1
+
+    def _file_compiles(self, span: Span) -> None:
+        pending, self._pending = self._pending, []
+        # events arrive as they END: one that starts before the start of
+        # those before it contains them (an inner jit traced inside an outer
+        # trace, an eager compile inside a trace) — keep the outermost
+        roots: List[tuple] = []
+        for item in pending:
+            while roots and roots[-1][2] >= item[2]:
+                roots.pop()
+            roots.append(item)
+        if not roots:
+            return
+        sums = self.compile.setdefault(span.name, {
+            "trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0, "programs": 0,
+        })
+        # one first call: the outermost events of one program in a row, up
+        # to the backend's (a trace, its lowering, its compile or load)
+        calls: List[list] = []
+        call = None
+        for kind, program, t0, t1 in roots:
+            sums[kind] += t1 - t0
+            if kind == "trace_s" and t1 - t0 >= TRACE_MIN_S:
+                self.traces[program] = self.traces.get(program, 0) + 1
+            if call is None or call[0] != program:
+                call = [program, t0, t1]
+                calls.append(call)
+            call[2] = t1
+            if kind == "backend_s":
+                sums["programs"] += 1
+                call = None
+        for program, t0, t1 in calls:
+            t0, t1 = max(t0, span.t0), min(t1, span.t1)
+            if t1 - t0 >= FIRST_CALL_MIN_S:
+                child = Span(f"first_call.{program}", self)
+                child.t0, child.t1 = t0, t1
+                self._close(child, pop=False)
+
+    # ---------------------------------------------------------- the close
+
+    def first_calls(self) -> Dict[str, float]:
+        """program -> seconds inside its ``first_call`` spans."""
+        return {
+            name.removeprefix("first_call."): seconds
+            for name, seconds in self.totals.items()
+            if name.startswith("first_call.")
+        }
+
+    def traced(self, first_calls: Dict[str, float]) -> Dict[str, int]:
+        """program -> times traced, for the costliest first calls."""
+        top = sorted(first_calls, key=first_calls.get, reverse=True)
+        return {p: self.traces.get(p, 0) for p in top[:TRACED_PROGRAMS]}
+
+    def compiled(self) -> Dict[str, float]:
+        """The laps' compile sums, added up."""
+        return {
+            key: sum(c[key] for c in self.compile.values())
+            for key in ("trace_s", "lower_s", "backend_s", "programs")
+        }
+
+    def line(self, total: float) -> str:
+        """The ``set-up:`` line: ``key=value`` in seconds, the laps in the
+        order they first closed, then what the compile events add up to."""
+        first_calls = self.first_calls()
+        laps = " ".join(
+            f"{name}={seconds:.3f}" for name, seconds in self.totals.items()
+            if not name.startswith("first_call.")
+        )
+        compiled = self.compiled()
+        return (
+            f"set-up: total={total:.3f} complete={int(self.complete)} {laps}"
+            f" | first_calls={sum(first_calls.values()):.3f}"
+            f" trace={compiled['trace_s']:.3f} lower={compiled['lower_s']:.3f}"
+            f" backend={compiled['backend_s']:.3f}"
+            f" programs={compiled['programs']}"
+            f" hits={self.cache['hits']} misses={self.cache['misses']}"
+            + "".join(
+                f" traces[{program}]={count}"
+                for program, count in self.traced(first_calls).items()
+            )
+        )
+
+    def close(
+        self, telemetry: Optional[registry.Telemetry] = None,
+        complete: bool = False,
+    ) -> None:
+        """Remove the listeners, log the line, publish (telemetry on).
+        ``complete``: at the end of a first global step."""
+        self.complete = complete
+        self._unlisten()
+        _SETUP.set(None)
+        if self._pending or self._clock() - self._lap >= 1e-3:
+            self.lap("rest")  # a record abandoned between two laps
+        total = self._lap - self._start
+        self._log.info(self.line(total))
+        tele = registry.resolve(telemetry)
+        if tele is None:
+            return
+        tele.event(
+            "setup.record", dur_s=total, complete=self.complete,
+            phases=dict(self.phases),
+            untimed_s=max(0.0, total - sum(self.phases.values())),
+            spans=self.finished_spans(), compile=self.compile,
+            **self.compiled(), traces=self.traced(self.first_calls()),
+            cache_hits=self.cache["hits"], cache_misses=self.cache["misses"],
+            **self.attrs,
+        )
+
+
+@contextmanager
+def setup_record(log) -> Iterator[_SetupContext]:
+    """Open this thread's set-up record around a role (``log``: the role's
+    logger, which gets the ``set-up:`` line). ``close_setup`` closes it at
+    the end of the first global step; a role that ends or raises before one
+    closes it here, ``complete=0``."""
+    ctx = _SetupContext(log, registry.monotonic_clock)
+    _SETUP.set(ctx)
+    try:
+        yield ctx
+    finally:
+        if _SETUP.get() is ctx:
+            ctx.close()
+
+
+def current_setup() -> Optional[_SetupContext]:
+    return _SETUP.get()
+
+
+def lap(name: str) -> None:
+    """``_SetupContext.lap`` on this thread's set-up record (no-op when none
+    is open: every start after the first global step)."""
+    ctx = _SETUP.get()
+    if ctx is not None:
+        ctx.lap(name)
+
+
+def close_setup(telemetry: Optional[registry.Telemetry] = None) -> None:
+    """The end of the first global step: close this thread's set-up record
+    (no-op when none is open)."""
+    ctx = _SETUP.get()
+    if ctx is not None:
+        ctx.close(telemetry, complete=True)
 
 
 def train_log_row(rec: _StepContext) -> Dict[str, Any]:

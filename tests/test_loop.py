@@ -48,6 +48,7 @@ class Observed:
     )
     log: List[logging.LogRecord] = dataclasses.field(default_factory=list)
     records: List[dict] = dataclasses.field(default_factory=list)
+    setup_records: List[dict] = dataclasses.field(default_factory=list)
 
     def messages(self, level=logging.INFO):
         return [r.getMessage() for r in self.log if r.levelno >= level]
@@ -150,6 +151,9 @@ def _observe(family, jump=0, finite=0, poison=()):
         seen.records = [
             e for e in tele.events if e["event"] == "step.record"
         ]
+        seen.setup_records = [
+            e for e in tele.events if e["event"] == "setup.record"
+        ]
 
 
 def _run(family, argv):
@@ -191,6 +195,54 @@ def _runs_of(family, tmp_path_factory):
 @pytest.fixture(scope="module", params=sorted(MODEL_SIZE))
 def runs(request, tmp_path_factory):
     return _runs_of(request.param, tmp_path_factory)
+
+
+def test_a_start_is_one_setup_record_closed_at_its_first_global_step(runs):
+    """Telemetry on (off: ``tests/test_setup_record.py``): every start logs
+    ONE ``set-up:`` line and writes ONE ``setup.record`` event, closed at the
+    end of the first global step; a run that resumes is a start too, and
+    one whose source ends before a global step leaves its record
+    ``complete=0``."""
+    family, first, second, _saved = runs
+    program = "step" if family == "swav" else "accumulate_step"
+    for run in (first, second):
+        lines = [m for m in run.messages() if m.startswith("set-up:")]
+        assert len(lines) == 1 and len(run.setup_records) == 1
+        record = run.setup_records[0]
+        stepped = any(c[1] for c in run.opt_calls)
+        assert record["complete"] is stepped
+        assert f"complete={int(stepped)}" in lines[0]
+        tree = {s[0]: s for s in record["spans"]}
+        for lap in ("init_state", "resume", "first_micro_batch") + (
+                        ("first_boundary", "first_post_step") if stepped
+                        else ()):
+            assert tree[lap][1] is None, lap  # a lap: top level
+        assert ("first_boundary" in tree) == stepped
+        # the accumulate program's first call lies inside the first
+        # micro-batch's lap, traced once this start
+        call = tree[f"first_call.{program}"]
+        assert call[1] == "first_micro_batch"
+        assert tree["first_micro_batch"][2] <= call[2] <= call[3] <= (
+            tree["first_micro_batch"][3]
+        )
+        assert record["traces"][program] == 1
+        assert record["compile"]["first_micro_batch"]["programs"] >= 1
+        # laps tile the record: nothing of it is under no span
+        assert sum(record["phases"].values()) == pytest.approx(record["dur_s"])
+        assert record["untimed_s"] == pytest.approx(0.0, abs=1e-6)
+        # it closes where the first global step's post_step ends: before the
+        # second global step's first record opens
+        stepping = [r for r in run.records if r.get("stepped")]
+        assert len(stepping) < 2 or record["t"] <= stepping[1]["t"]
+    assert first.setup_records[0]["complete"]
+    # the step records of the first global step are what they were: the
+    # set-up record is their parent in time, not a copy of their spans
+    assert not {"first_micro_batch", "first_boundary"} & {
+        s[0] for r in first.records for s in r["spans"]
+    }
+    assert not {"fwd_bwd", "opt_apply", "post_step"} & set(
+        first.setup_records[0]["phases"]
+    )
 
 
 def test_record_is_one_span_tree_and_the_loss_is_read_once_a_global_step(runs):

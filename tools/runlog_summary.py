@@ -73,7 +73,9 @@ logs (``ledger.claim``/``ledger.receipt`` events — refolded through the
 same schemas and fold the coordinator runs). Reading guide in
 docs/observability.md; the discrepancy runbook is docs/fleet.md.
 
-``--steps`` renders the step-phase flight recorder's view (per-step
+``--steps`` renders, ahead of the steps, each peer's set-up record
+(``setup.record``: the laps of its start, the first calls inside them, the
+compile sums) and the step-phase flight recorder's view (per-step
 ``step.record`` / ``step.phase`` events from ``telemetry/steps.py``, or a
 coordinator metrics JSONL whose ``swarm_health.peers[].phases`` already
 folded the per-peer means): a step-time waterfall per peer with the
@@ -1080,6 +1082,66 @@ def _phase_skews(per_peer):
     return skews
 
 
+def _setup_records(rows):
+    """The set-up records (``setup.record``: role entry -> end of the first
+    global step, one a start) as the --steps view shows them ahead of the
+    steps: per record its laps (top-level spans, by name, in the order they
+    first closed), the first calls inside them and the compile sums."""
+    out = []
+    for r in rows:
+        if r.get("event") != ev.SETUP_RECORD:
+            continue
+        laps, first_calls = {}, {}
+        for span in r.get("spans") or []:
+            seconds = float(span[5] if len(span) > 4 else span[3] - span[2])
+            into = laps if span[1] is None else first_calls
+            key = span[0] if span[1] is None else (span[1], span[0])
+            into[key] = into.get(key, 0.0) + seconds
+        out.append({
+            "t": r.get("t"), "peer": r.get("peer", "?"),
+            "total_s": float(r.get("dur_s", 0.0)),
+            "complete": bool(r.get("complete")),
+            "laps": laps,
+            "first_calls": [
+                {"lap": lap, "span": name, "s": round(seconds, 6)}
+                for (lap, name), seconds in first_calls.items()
+            ],
+            **{
+                key: r.get(key, 0)
+                for key in ("trace_s", "lower_s", "backend_s", "programs")
+            },
+            "cache_hits": r.get("cache_hits", 0),
+            "cache_misses": r.get("cache_misses", 0),
+            "traces": r.get("traces") or {},
+        })
+    return sorted(out, key=lambda rec: (rec["peer"], rec["t"] or 0.0))
+
+
+def _print_setup(records):
+    print("set-up (role entry -> end of the first global step):")
+    for rec in records:
+        print(
+            f"peer {rec['peer']}  total {rec['total_s']:.3f}s"
+            + ("" if rec["complete"] else "  (no global step: incomplete)")
+            + f"  trace {rec['trace_s']:.3f}s lower {rec['lower_s']:.3f}s "
+            f"backend {rec['backend_s']:.3f}s over {rec['programs']} "
+            f"programs (cache hits {rec['cache_hits']}, misses "
+            f"{rec['cache_misses']})"
+        )
+        for name, seconds in rec["laps"].items():
+            print(f"  {name:<22} {seconds:9.3f}s  "
+                  f"{_bar(seconds, rec['total_s'])}")
+            for call in rec["first_calls"]:
+                if call["lap"] == name:
+                    program = call["span"].removeprefix("first_call.")
+                    traced = rec["traces"].get(program)
+                    print(
+                        f"    {call['span']:<36} {call['s']:9.3f}s"
+                        + (f"  traced x{traced}" if traced is not None else "")
+                    )
+    print()
+
+
 def steps_data(all_rows):
     """The --steps view as one JSON-able document."""
     event_rows = [r for r in all_rows if "event" in r]
@@ -1099,6 +1161,7 @@ def steps_data(all_rows):
     exposed = sum(float(r.get("exposed_s", 0.0)) for r in ledgers)
     doc = {
         "view": "steps",
+        "setup": _setup_records(event_rows),
         "per_peer": {
             peer: {
                 **acc,
@@ -1147,6 +1210,9 @@ def print_steps(all_rows):
             "JSONL needs swarm_health.peers[].phases)"
         )
 
+    setup = _setup_records(event_rows)
+    if setup:
+        _print_setup(setup)
     print("step-time waterfall (mean per step):")
     for peer in sorted(per_peer):
         acc = per_peer[peer]
