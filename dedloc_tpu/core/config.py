@@ -316,8 +316,11 @@ class TrainingArguments:
     # kernel_operands — those and what the backward kernels READ: q / k / v,
     # the convolution's B | C | u — is the default of smallthinker, sdar and
     # lfm2, and kernel_outputs is what a peer with less memory to spare
-    # passes there; the policy table lives in models/remat.py, measurements
-    # in docs/perf.md and PERF.md)
+    # passes there; under either, the four rotate-half decoders — ouro,
+    # smallthinker, sdar, lfm2 — hand the flash kernels q / k / v from
+    # behind decoder.GroupedQueryAttention's optimization_barrier, kanana2's
+    # LatentAttention does not: it moves nothing there; the policy table
+    # lives in models/remat.py, measurements in docs/perf.md and PERF.md)
     remat_policy: str = ""
     attention_impl: str = ""  # override: dense|blockwise|flash|ring
     vocab_size: int = 0  # override model vocab (0 = size default); must cover

@@ -186,6 +186,8 @@ class LatentAttention(nn.Module):
             axis=-1,
         )
         v = kv[..., nope:]
+        # no optimization_barrier (decoder.GroupedQueryAttention's): offline
+        # it moves none of this program's 23 layer-body copies (PR 45)
         ctx = attend(cfg, q, k, v, Visibility(causal=True))
         return dense(cfg.hidden_size, cfg, "o_proj")(
             ctx.reshape(B, S, H * dv)

@@ -22,9 +22,8 @@ pass, [T, B, S, H] in bf16 (134 MB at 8,192 tokens); the head and its
 cross-entropy are NOT part of the scan: ``ouro_loss`` computes them one
 (pass, token chunk) at a time under remat, so at most one chunk of the
 [tokens, 49,152] float32 logits lives at a time, forward or backward. The
-blocks (RMSNorm, RoPE, SwiGLU, the chunked head + cross-entropy) and the TPU
-notes are ``models/decoder.py``'s; the attention module stays here (see
-``OuroAttention``).
+blocks (RMSNorm, RoPE, SwiGLU, grouped-query attention, the chunked head +
+cross-entropy) and the TPU notes are ``models/decoder.py``'s.
 """
 from __future__ import annotations
 
@@ -37,13 +36,11 @@ import jax
 import jax.numpy as jnp
 
 from dedloc_tpu.models.decoder import (
+    GroupedQueryAttention,
     RMSNorm,
     ScannedBlock,
     Visibility,
-    apply_rope,
-    attend,
     chunked_cross_entropy,
-    dense,
     embed_tokens,
     named_config,
     rope_tables,
@@ -79,7 +76,10 @@ class OuroConfig:
     # input per (pass, layer) plus the flash kernel's out + lse (what its
     # backward reads: 17 MB a layer iteration) and replays the rest of the
     # layer in the backward: at 350-400 M parameters the state leaves no
-    # room for a q/k/v/FFN stash of 12-16 layer iterations
+    # room for a q/k/v/FFN stash of 12-16 layer iterations. The kernels'
+    # operands are buffers of their own all the same
+    # (decoder.GroupedQueryAttention's barrier: 0.14 GB LESS scratch),
+    # replayed and not kept: "kernel_operands" would keep them, for 6.2 GB
     remat_policy: str = "kernel_outputs"
     # "flash": the causal mode of ops/flash_attention.py; "dense": XLA's
     # materialized S² scores (tests, tiny models)
@@ -114,29 +114,6 @@ class OuroConfig:
         return OuroConfig(**base)
 
 
-class OuroAttention(nn.Module):
-    """Causal attention with RoPE over whole heads. Not
-    ``decoder.GroupedQueryAttention``, whose ``optimization_barrier`` this
-    model's program does not have (its policy keeps no operand)."""
-
-    cfg: OuroConfig
-
-    @nn.compact
-    def __call__(self, hidden, rope):
-        cfg = self.cfg
-        B, S, _ = hidden.shape
-        H, KV, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                    cfg.head_dim)
-        q = dense(H * D, cfg, "q_proj")(hidden).reshape(B, S, H, D)
-        k = dense(KV * D, cfg, "k_proj")(hidden).reshape(B, S, KV, D)
-        v = dense(KV * D, cfg, "v_proj")(hidden).reshape(B, S, KV, D)
-        q, k = apply_rope(q, *rope), apply_rope(k, *rope)
-        ctx = attend(cfg, q, k, v, Visibility(causal=True))
-        return dense(cfg.hidden_size, cfg, "o_proj")(
-            ctx.reshape(B, S, H * D)
-        )
-
-
 class OuroLayer(nn.Module):
     """One decoder layer, sandwich-normed: a norm before AND after each
     sub-layer, the residual added after the second. Returns (hidden, None):
@@ -147,9 +124,9 @@ class OuroLayer(nn.Module):
     @nn.compact
     def __call__(self, hidden, rope):
         cfg = self.cfg
-        attn = OuroAttention(cfg, name="self_attn")(
-            RMSNorm(cfg, name="input_layernorm")(hidden), rope
-        )
+        attn = GroupedQueryAttention(
+            cfg, Visibility(causal=True), name="self_attn"
+        )(RMSNorm(cfg, name="input_layernorm")(hidden), rope)
         hidden = hidden + RMSNorm(cfg, name="input_layernorm_2")(attn)
         x = RMSNorm(cfg, name="post_attention_layernorm")(hidden)
         mlp = swiglu(cfg, x, cfg.intermediate_size)

@@ -339,9 +339,12 @@ def test_ouro_accumulate_step_keeps_the_flash_outputs_and_fits_the_cap():
     the forward kernel ONCE (the forward scan's body) and the backward's
     replay of the layer holds none — 2 call sites under policy ``nothing``,
     12 of 24 executions a micro-batch (PR 28). The stash is paid in the
-    program's scratch: 5.30 GB against 5.04, which with a draining
-    snapshot's 9.96 GB of state stays under the 15.3 GB the cell is sized
-    by; a policy that also kept ``flash_qkv`` would read 6.2 GB here."""
+    program's scratch: 5.30 GB against 5.04 — 5.16 since the kernels'
+    operands sit behind ``decoder.GroupedQueryAttention``'s barrier (PR 45:
+    the 11 float32 relayouts of RoPE's pieces left the layer bodies) —
+    which with a draining snapshot's 9.96 GB of state stays under the 15.3
+    GB the cell is sized by; a policy that also kept ``flash_qkv`` would
+    read 6.2 GB here."""
     row = _tpu_aot("ouro_accumulate_step")["ouro_accumulate_step"]
     assert row["remat_policy"] == "kernel_outputs"
     assert row["flash_fwd_forms"] == {"one_tile": 0, "tiles": 1}
@@ -352,7 +355,9 @@ def test_ouro_accumulate_step_keeps_the_flash_outputs_and_fits_the_cap():
     }
     # forward, dq, dkv: one site each
     assert row["tpu_custom_calls"] == 3
-    assert row["memory"]["temp_bytes"] <= 5.35e9, row["memory"]
+    assert row["memory"]["temp_bytes"] <= 5.2e9, row["memory"]
+    copies = row["layer_body_copies"]
+    assert not [shape for shape in copies if shape.startswith("f32")], copies
 
 
 def test_lfm2_accumulate_step_keeps_what_its_backward_reads():
