@@ -307,20 +307,23 @@ class TrainingArguments:
     # every expert. Together with ``vocab_size`` (rows of the vocabulary held)
     # and ``num_hidden_layers`` it states a chip's share of a deployment.
     expert_shard: str = "0/1"
-    # override model remat: nothing|kernel_outputs|kernel_operands|dots|
-    # dots_no_batch|dots_no_batch_attn|fused_ln|fused_ln_gelu (fused_ln —
-    # saved Pallas outputs + named matmuls, pairs the fused add+LN kernel on
-    # automatically — is the fastest measured policy for the seq-512 recipe
-    # on v5e; kernel_outputs — the Pallas outputs alone — is the default of
-    # the decoders whose state fills the chip (ouro, kanana2);
-    # kernel_operands — those and what the backward kernels READ: q / k / v,
-    # the convolution's B | C | u — is the default of smallthinker, sdar and
-    # lfm2, and kernel_outputs is what a peer with less memory to spare
-    # passes there; under either, the four rotate-half decoders — ouro,
-    # smallthinker, sdar, lfm2 — hand the flash kernels q / k / v from
-    # behind decoder.GroupedQueryAttention's optimization_barrier, kanana2's
-    # LatentAttention does not: it moves nothing there; the policy table
-    # lives in models/remat.py, measurements in docs/perf.md and PERF.md)
+    # override model remat: nothing|kernel_outputs|kernel_operands|
+    # whole_mixer|dots|dots_no_batch|dots_no_batch_attn|fused_ln|
+    # fused_ln_gelu (fused_ln — saved Pallas outputs + named matmuls, pairs
+    # the fused add+LN kernel on automatically — is the fastest measured
+    # policy for the seq-512 recipe on v5e; kernel_outputs — the Pallas
+    # outputs alone — is the default of the decoders whose state fills the
+    # chip (ouro, kanana2); kernel_operands — those and what the backward
+    # kernels READ: q / k / v, the convolution's B | C | u; whole_mixer —
+    # those, the stream after the mixer and a q / k norm's input, so the
+    # replay runs no matmul of the mixer — is the default of smallthinker,
+    # sdar and lfm2, and kernel_operands, then kernel_outputs, is what a
+    # peer with less memory to spare passes there; under any, the four
+    # rotate-half decoders — ouro, smallthinker, sdar, lfm2 — hand the flash
+    # kernels q / k / v from behind decoder.GroupedQueryAttention's
+    # optimization_barrier, kanana2's LatentAttention does not: it moves
+    # nothing there; the policy table lives in models/remat.py, measurements
+    # in docs/perf.md and PERF.md)
     remat_policy: str = ""
     attention_impl: str = ""  # override: dense|blockwise|flash|ring
     vocab_size: int = 0  # override model vocab (0 = size default); must cover

@@ -16,6 +16,8 @@ TPU notes: matmuls in bf16 with float32 accumulation; norms, softmax, the
 router and the loss in float32; q/k/v leave their projections as [B, S, H·D]
 and go to the flash kernels in that layout, named ``flash_qkv`` (RoPE is
 applied before the name, so what a policy stashes is what the kernels read);
+the stream after a mixer is named ``mixer_residual`` and a per-head q / k
+norm's input ``qk_norm_input``, for the policies of ``models/remat.py``;
 RMSNorm is XLA's.
 """
 from __future__ import annotations
@@ -212,6 +214,14 @@ def attend(cfg, q, k, v, visible: Visibility):
     return jnp.einsum("bcgqk,bkcd->bqcgd", probs, v)
 
 
+def mixer_residual(hidden, mixed):
+    """``hidden + mixed``, a layer's stream after its mixer, named
+    ``mixer_residual``: the post-mixer norm, the router and the experts'
+    backward read this sum, so a policy that keeps it takes the mixer's
+    out-projection out of the replay."""
+    return checkpoint_name(hidden + mixed, "mixer_residual")
+
+
 class GroupedQueryAttention(nn.Module):
     """Grouped-query attention under ``visible``: ``q_proj`` / ``k_proj`` /
     ``v_proj``, an RMSNorm over each head's own lanes of q and of k where
@@ -236,7 +246,14 @@ class GroupedQueryAttention(nn.Module):
 
         def positioned(x, norm):  # per head, over its own lanes; then RoPE
             if norm is not None:
-                x = RMSNorm(cfg, name=norm)(x)
+                # the norm's backward reads the norm's INPUT: a policy
+                # that keeps it takes the projection out of the replay, as
+                # a kept ``flash_qkv`` takes ``v_proj``. Named per head,
+                # the shape the matmul's fusion writes and the norm reads:
+                # [B, S, H·D] is a relayout away (PERF.md section 6, PR 46)
+                x = RMSNorm(cfg, name=norm)(
+                    checkpoint_name(x, "qk_norm_input")
+                )
             return apply_rope(x, *rope) if self.rotated else x
 
         q, k = map(positioned, (q, k), self.qk_norms)

@@ -59,6 +59,7 @@ from dedloc_tpu.models.decoder import (
     embed_tokens,
     expert_lm_loss,
     held_range,
+    mixer_residual,
     named_config,
     period_of,
     rope_tables,
@@ -104,18 +105,20 @@ class Lfm2MoeConfig:
     expert_shard: Tuple[int, int] = (0, 1)
     moe_row_tile: int = 256
     dtype: Any = jnp.bfloat16  # compute dtype; params stay fp32
-    # a name of models/remat.py's table. "kernel_operands": a
-    # conv layer keeps B | C | u as ``short_conv_bwd`` reads it beside y, so
-    # the backward's replay runs no ``in_proj``; the attention layer keeps
-    # q / k / v beside out + lse and loses v_proj, RoPE and the relayouts
-    # (q_proj and k_proj stay: the per-head RMSNorm's backward reads the
-    # norm's input): 4,096 x 3 x 2,048 x 2 bytes = 50 MB a conv layer a
-    # micro-batch and 4,096 x (32 + 2·8) x 64 x 2 = 25 MB the attention
-    # layer, 0.23 GB in the benchmark's cell, where accumulate_step's
-    # scratch reads 0.72 GB against 0.66 beside 13.14 GB of state while a
-    # backup drains. A smaller chip or a larger share:
-    # --training.remat_policy kernel_outputs
-    remat_policy: str = "kernel_operands"
+    # a name of models/remat.py's table. "whole_mixer": a conv layer keeps
+    # B | C | u as ``short_conv_bwd`` reads it beside y, the attention layer
+    # q / k / v beside out + lse and the q / k norm's INPUT (what its
+    # backward reads), every layer the stream after its mixer, so the
+    # backward's replay runs no matmul of a mixer — ``in_proj``,
+    # q / k / v_proj, ``out_proj`` —, no RoPE and no relayout: 4,096 x
+    # (3 + 1) x 2,048 x 2 bytes = 67 MB a conv layer a micro-batch and
+    # 4,096 x ((32 + 2·8) x 64 + (32 + 8) x 64 + 2,048) x 2 = 63 MB the
+    # attention layer, 0.33 GB in the benchmark's cell (0.23 of them
+    # "kernel_operands"'), where accumulate_step's scratch reads 0.81 GB
+    # (0.72 under "kernel_operands") beside 13.14 GB of state while a backup
+    # drains. A smaller chip or a larger share: --training.remat_policy
+    # kernel_operands, then kernel_outputs
+    remat_policy: str = "whole_mixer"
     attention_impl: str = "flash"  # or "dense" (tests, tiny models)
     attention_block_size: int = 512
     loss_chunk_tokens: int = 512
@@ -219,13 +222,14 @@ class DecoderLayer(nn.Module):
         cfg = self.cfg
         x = RMSNorm(cfg, name="operator_norm")(hidden)
         if self.mixer == CONV:
-            hidden = hidden + ShortConvMixer(cfg, name="conv")(x)
+            mixed = ShortConvMixer(cfg, name="conv")(x)
         else:
-            hidden = hidden + GroupedQueryAttention(
+            mixed = GroupedQueryAttention(
                 cfg, Visibility(causal=True),
                 qk_norms=("q_layernorm", "k_layernorm"), out_name="out_proj",
                 name="self_attn",
             )(x, rope)
+        hidden = mixer_residual(hidden, mixed)
         x = RMSNorm(cfg, name="ffn_norm")(hidden)
         if not self.sparse:
             return hidden + SwiGLU(

@@ -29,24 +29,48 @@ def remat_policy_object(name: str):
         # after the q / k norm and RoPE, [B, S, H·D], the layout kernels
         # and stash share) and the short convolution's B | C | u
         # ("short_conv_bcu": ``in_proj``'s output as it wrote it) beside
-        # out + lse / y. The replay of a layer still runs the input norm
-        # (the projections' weight gradients read its output), the
-        # out-projection, the post-attention norm, the router and the
-        # routed loop; it loses RoPE, the relayouts around it and every
-        # projection whose output the kernel reads AS IT IS — q / k / v
-        # where nothing but RoPE (a linear map) lies between, every v,
-        # ``in_proj``. Behind a per-head q / k RMSNorm (SDAR, LFM2) the
-        # q and k matmuls STAY: the norm's backward reads the norm's
-        # input. Over "kernel_outputs", a layer a micro-batch:
-        # B·S·(H + 2·H_kv)·D·2 bytes for an attention (151 MB at
-        # SmallThinker's 16,384 x (28 + 2·4) x 128), B·S·3·hidden·2 for
-        # a convolution (50 MB at LFM2's 4,096 x 3 x 2,048). For the
-        # decoders whose state leaves that room (models/smallthinker.py,
-        # sdar_moe.py, lfm2_moe.py)
+        # out + lse / y. The replay of a layer loses RoPE, the relayouts
+        # around it and every projection whose output the kernel reads AS
+        # IT IS — q / k / v where nothing but RoPE (a linear map) lies
+        # between, every v, ``in_proj``; it still runs the input norm (the
+        # projections' weight gradients read its output), the
+        # out-projection, behind a per-head q / k RMSNorm (SDAR, LFM2) the
+        # q and k matmuls (the norm's backward reads the norm's INPUT), the
+        # post-attention norm, the router and the routed loop. Over
+        # "kernel_outputs", a layer a micro-batch: B·S·(H + 2·H_kv)·D·2
+        # bytes for an attention (151 MB at SmallThinker's 16,384 x
+        # (28 + 2·4) x 128), B·S·3·hidden·2 for a convolution (50 MB at
+        # LFM2's 4,096 x 3 x 2,048). The rung under "whole_mixer"
         "kernel_operands": (
             jax.checkpoint_policies.save_from_both_policies(
                 jax.checkpoint_policies.save_only_these_names(
                     "flash_qkv", "short_conv_bcu"
+                ),
+                _pallas_outputs_saveable,
+            )
+        ),
+        # "kernel_operands" + the two values of a mixer that its matmuls
+        # write and something other than a kernel reads: the stream after
+        # the mixer ("mixer_residual": hidden + Mixer(norm(hidden)), which
+        # the post-attention norm, the router and the experts' backward
+        # read) and the input of a per-head q / k RMSNorm ("qk_norm_input":
+        # ``q_proj``'s and ``k_proj``'s outputs where the model has such a
+        # norm). The replay of a layer then runs NO matmul of the mixer —
+        # the out-projection and, in SDAR and LFM2's attention layer, q_proj
+        # and k_proj leave it as v_proj did — and the backward's router
+        # reads the sum the forward routed by. It still runs the input
+        # norm, the q / k norm's multiply, the post-attention norm, the
+        # router and the routed loop. Over "kernel_operands", a layer a
+        # micro-batch: B·S·hidden·2 bytes, + B·S·(H + H_kv)·D·2 behind a
+        # q / k norm (109 MB at SDAR's 8,192 x (2,048 + 4,096 + 512), 84
+        # at SmallThinker's 16,384 x 2,560, 17 a convolution layer of
+        # LFM2's). For the decoders whose state leaves that room
+        # (models/smallthinker.py, sdar_moe.py, lfm2_moe.py)
+        "whole_mixer": (
+            jax.checkpoint_policies.save_from_both_policies(
+                jax.checkpoint_policies.save_only_these_names(
+                    "flash_qkv", "short_conv_bcu", "mixer_residual",
+                    "qk_norm_input",
                 ),
                 _pallas_outputs_saveable,
             )

@@ -62,6 +62,7 @@ from dedloc_tpu.models.decoder import (
     chunked_cross_entropy,
     embed_tokens,
     held_range,
+    mixer_residual,
     named_config,
     rope_tables,
     routed_metrics,
@@ -104,17 +105,18 @@ class SdarMoeConfig:
     expert_shard: Tuple[int, int] = (0, 1)
     moe_row_tile: int = 256
     dtype: Any = jnp.bfloat16  # compute dtype; params stay fp32
-    # a name of models/remat.py's table. "kernel_operands": the
-    # layer keeps q / k / v as the flash kernels read them (after the q / k
-    # norm and RoPE) beside out + lse, so the backward's replay runs no
-    # v projection, normalising multiply, RoPE or relayout; q_proj and
-    # k_proj STAY in it (the per-head RMSNorm's backward reads the norm's
-    # input): 8,192 x (32 + 2·4) x 128 x 2 bytes = 84 MB a layer a
-    # micro-batch, 0.34 GB in the benchmark's cell of four layers, where
-    # accumulate_step's scratch reads 1.28 GB (1.57 before the barrier in
-    # decoder.GroupedQueryAttention) beside 12.78 GB of state while a backup drains. A
-    # smaller chip or a larger share: --training.remat_policy kernel_outputs
-    remat_policy: str = "kernel_operands"
+    # a name of models/remat.py's table. "whole_mixer": the layer keeps
+    # q / k / v as the flash kernels read them (after the q / k norm and
+    # RoPE) beside out + lse, the q / k norm's INPUT (what its backward
+    # reads) and the stream after attention, so the backward's replay runs
+    # no matmul of the mixer — q_proj, k_proj, v_proj, o_proj —, no RoPE and
+    # no relayout: 8,192 x ((32 + 2·4) x 128 + (32 + 4) x 128 + 2,048) x 2
+    # bytes = 193 MB a layer a micro-batch (84 of them "kernel_operands"'),
+    # 0.77 GB in the benchmark's cell of four layers, where accumulate_step's
+    # scratch reads 1.70 GB (1.28 under "kernel_operands") beside 12.78 GB
+    # of state while a backup drains. A smaller chip or a larger share:
+    # --training.remat_policy kernel_operands, then kernel_outputs
+    remat_policy: str = "whole_mixer"
     attention_impl: str = "flash"  # or "dense" (tests, tiny models)
     attention_block_size: int = 512
     loss_chunk_tokens: int = 512
@@ -172,10 +174,10 @@ class DecoderLayer(nn.Module):
     @nn.compact
     def __call__(self, hidden, rope):
         cfg = self.cfg
-        hidden = hidden + GroupedQueryAttention(
+        hidden = mixer_residual(hidden, GroupedQueryAttention(
             cfg, Visibility(block_diffusion=cfg.block_length),
             qk_norms=("q_norm", "k_norm"), name="self_attn",
-        )(RMSNorm(cfg, name="input_layernorm")(hidden), rope)
+        )(RMSNorm(cfg, name="input_layernorm")(hidden), rope))
         x = RMSNorm(cfg, name="post_attention_layernorm")(hidden)
         y, routing = RoutedGLU(cfg, activation="silu", name="mlp")(x, x)
         return hidden + y, routing

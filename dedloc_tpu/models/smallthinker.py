@@ -57,6 +57,7 @@ from dedloc_tpu.models.decoder import (
     embed_tokens,
     expert_lm_loss,
     held_range,
+    mixer_residual,
     named_config,
     period_of,
     rope_tables,
@@ -100,16 +101,17 @@ class SmallThinkerConfig:
     expert_shard: Tuple[int, int] = (0, 1)
     moe_row_tile: int = 256
     dtype: Any = jnp.bfloat16  # compute dtype; params stay fp32
-    # a name of models/remat.py's table. "kernel_operands": the
-    # layer keeps q / k / v as the flash kernels read them beside out + lse,
-    # so the backward's replay runs no q / k / v projection, RoPE or
-    # relayout (RoPE's own backward is linear: it needs no stash): 16,384 x
-    # (28 + 2·4) x 128 x 2 bytes = 151 MB a layer a micro-batch, 0.60 GB in
-    # the benchmark's cell of four layers, where accumulate_step's scratch
-    # reads 2.53 GB against 2.50 (the stash takes the place of the replay's
-    # own q / k / v) beside 10.38 GB of state while a backup drains. A
-    # smaller chip or a larger share: --training.remat_policy kernel_outputs
-    remat_policy: str = "kernel_operands"
+    # a name of models/remat.py's table. "whole_mixer": the layer keeps
+    # q / k / v as the flash kernels read them beside out + lse, and the
+    # stream after attention, so the backward's replay runs no q / k / v / o
+    # projection, RoPE or relayout (RoPE's own backward is linear: it needs
+    # no stash): 16,384 x ((28 + 2·4) x 128 + 2,560) x 2 bytes = 235 MB a
+    # layer a micro-batch (151 of them "kernel_operands"'), 0.94 GB in the
+    # benchmark's cell of four layers, where accumulate_step's scratch reads
+    # 2.91 GB (2.53 under "kernel_operands") beside 10.38 GB of state while
+    # a backup drains. A smaller chip or a larger share:
+    # --training.remat_policy kernel_operands, then kernel_outputs
+    remat_policy: str = "whole_mixer"
     attention_impl: str = "flash"  # or "dense" (tests, tiny models)
     attention_block_size: int = 512
     loss_chunk_tokens: int = 512
@@ -186,10 +188,10 @@ class DecoderLayer(nn.Module):
         cfg = self.cfg
         x = RMSNorm(cfg, name="input_layernorm")(hidden)
         band = cfg.sliding_window_size if self.banded else None
-        hidden = hidden + GroupedQueryAttention(
+        hidden = mixer_residual(hidden, GroupedQueryAttention(
             cfg, Visibility(causal=True, band=band), rotated=self.rotated,
             name="self_attn",
-        )(x, rope)
+        )(x, rope))
         y, routing = RoutedGLU(cfg, name="block_sparse_moe")(
             RMSNorm(cfg, name="post_attention_layernorm")(hidden), x
         )

@@ -1,12 +1,17 @@
-"""The layer remat policy ``kernel_operands`` (``models/albert.py``): a
-decoder layer keeps what its Pallas backward kernels READ — q / k / v as the
-flash kernels take them, the short convolution's B | C | u — beside what the
-forward ones wrote. For the three families that take it as their default:
-the same bits as ``kernel_outputs`` and ``nothing`` (loss and every gradient
-leaf); the engagement count with no chip (the projections that feed a kernel
-run once a layer in the gradient, not twice; every kernel still once); and
-the mechanism's counter ``remat.kept_bytes`` against the shapes' arithmetic
-at the published widths."""
+"""The layer remat policies ``kernel_operands`` and ``whole_mixer``
+(``models/remat.py``). Under the first a decoder layer keeps what its Pallas
+backward kernels READ — q / k / v as the flash kernels take them, the short
+convolution's B | C | u — beside what the forward ones wrote; under the
+second, the default of the three families with memory to spend, also the
+stream after the mixer and the input of a per-head q / k RMSNorm, so the
+replay of a layer runs no matmul of the mixer. For each of the two and each
+of the three families: the same bits as ``kernel_outputs`` and ``nothing``
+(loss and every gradient leaf); the engagement count with no chip (a
+projection whose output is kept runs once a layer in the gradient, not twice;
+every kernel still once); and the mechanism's counter ``remat.kept_bytes``
+against the shapes' arithmetic at the published widths."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -46,6 +51,9 @@ TINY = {
     )),
 }
 FAMILIES = sorted(TINY)
+# the rung PR 41 added and the one above it, the three families' default
+POLICIES = ("kernel_operands", "whole_mixer")
+CASES = [(family, policy) for family in FAMILIES for policy in POLICIES]
 
 
 def _tiny(family, remat_policy):
@@ -75,22 +83,22 @@ def _tiny(family, remat_policy):
     return cfg, lambda p: loss_fn(p, batch, jax.random.PRNGKey(3))[0], params
 
 
-def _default(family):
-    return model_family(TINY[family][0]).config().remat_policy
+@functools.lru_cache(maxsize=None)  # a reference is run once a family
+def _loss_and_grad(family, remat_policy):
+    _cfg, loss, params = _tiny(family, remat_policy)
+    return jax.value_and_grad(loss)(params)
 
 
-@pytest.mark.parametrize("family", FAMILIES)
-def test_the_default_policy_gives_the_same_bits(family):
+@pytest.mark.parametrize("family,policy", CASES)
+def test_the_default_policy_gives_the_same_bits(family, policy):
     """The stash holds the values the forward computed and the backward
     reads them instead of recomputing the same values: loss and EVERY
     gradient leaf — the held experts' and, in LFM2, the bias leaves'
     cotangent (the load statistic: a replay that re-routes shows there) —
     equal those of ``kernel_outputs`` and of ``nothing``."""
-    _cfg, loss, params = _tiny(family, _default(family))
-    got_loss, got = jax.value_and_grad(loss)(params)
+    got_loss, got = _loss_and_grad(family, policy)
     for other in ("kernel_outputs", "nothing"):
-        _cfg, ref_loss_fn, ref_params = _tiny(family, other)
-        ref_loss, ref = jax.value_and_grad(ref_loss_fn)(ref_params)
+        ref_loss, ref = _loss_and_grad(family, other)
         assert float(got_loss) == float(ref_loss), other
         jax.tree_util.tree_map_with_path(  # raises on a different tree, too
             lambda path, leaf, ref_leaf: np.testing.assert_array_equal(
@@ -112,17 +120,23 @@ def _equations(jaxpr):
                     yield from _equations(inner)
 
 
+@functools.lru_cache(maxsize=None)
 def _sites(family, remat_policy):
-    """(matmul sites [tokens, hidden] x [hidden, width] by width, Pallas
-    call sites by kernel name) in the jaxpr of ``family``'s gradient."""
+    """(matmul sites [tokens, in] x [in, out] by (in, out) — the forward's
+    form, contracted over the weight's ROWS: a backward's ``g @ Wᵀ`` and
+    ``xᵀ @ g`` are not —, Pallas call sites by kernel name) in the jaxpr of
+    ``family``'s gradient."""
     cfg, loss, params = _tiny(family, remat_policy)
     jaxpr = jax.make_jaxpr(jax.grad(loss))(params).jaxpr  # traced, not run
     matmuls, kernels = {}, {}
     for eqn in _equations(jaxpr):
         if eqn.primitive.name == "dot_general":
-            lhs, rhs = (v.aval.shape for v in eqn.invars)
-            if len(rhs) == 2 and lhs[-1] == rhs[0] == cfg.hidden_size:
-                matmuls[rhs[1]] = matmuls.get(rhs[1], 0) + 1
+            lhs, rhs = (v.aval for v in eqn.invars)
+            (over_lhs, over_rhs), _batch = eqn.params["dimension_numbers"]
+            if rhs.ndim == 2 and (tuple(over_lhs), tuple(over_rhs)) == (
+                (lhs.ndim - 1,), (0,)
+            ):
+                matmuls[rhs.shape] = matmuls.get(rhs.shape, 0) + 1
         elif eqn.primitive.name == "pallas_call":
             name = eqn.params["name"]
             kernels[name] = kernels.get(name, 0) + 1
@@ -130,38 +144,55 @@ def _sites(family, remat_policy):
 
 
 def _projection_sites(family, cfg):
-    """{width: (sites under ``kernel_outputs``, under ``kernel_operands``)}
-    of the matmuls that feed a kernel. A projection whose output the kernel
-    reads AS IT IS (SmallThinker's q / k / v — RoPE's backward is linear —,
-    every v, LFM2's ``in_proj``) leaves the replay: forward + replay become
-    the forward alone. One behind a per-head RMSNorm (SDAR's and LFM2's q
-    and k) stays: the norm's backward reads the norm's INPUT."""
+    """{(in, out): sites under (``kernel_outputs``, ``kernel_operands``,
+    ``whole_mixer``)} of the mixers' matmuls. A projection whose output the
+    kernel reads AS IT IS (SmallThinker's q / k / v — RoPE's backward is
+    linear —, every v, LFM2's ``in_proj``) leaves the replay under
+    ``kernel_operands``: forward + replay become the forward alone. One
+    behind a per-head RMSNorm (SDAR's and LFM2's q and k) stays until the
+    norm's INPUT, which the norm's backward reads, is kept, and the
+    out-projection until the sum it is added into is: ``whole_mixer``."""
+    hidden = cfg.hidden_size
     heads = cfg.num_attention_heads * cfg.head_dim
     kv = cfg.num_key_value_heads * cfg.head_dim  # k_proj and v_proj
     layers = cfg.num_hidden_layers
     if family == "smallthinker":
-        return {heads: (2 * layers, layers), kv: (4 * layers, 2 * layers)}
+        return {
+            (hidden, heads): (2 * layers, layers, layers),
+            (hidden, kv): (4 * layers, 2 * layers, 2 * layers),
+            (heads, hidden): (2 * layers, 2 * layers, layers),
+        }
     if family == "sdar":
-        return {heads: (2 * layers, 2 * layers), kv: (4 * layers, 3 * layers)}
+        return {
+            (hidden, heads): (2 * layers, 2 * layers, layers),
+            (hidden, kv): (4 * layers, 3 * layers, 2 * layers),
+            (heads, hidden): (2 * layers, 2 * layers, layers),
+        }
     conv = sum(kind == "conv" for _index, kind, _sparse in cfg.layer_plan)
     attn = layers - conv
     return {
-        3 * cfg.hidden_size: (2 * conv, conv),
-        heads: (2 * attn, 2 * attn), kv: (4 * attn, 3 * attn),
+        (hidden, 3 * hidden): (2 * conv, conv, conv),
+        (hidden, hidden): (2 * conv, 2 * conv, conv),  # a conv's out_proj
+        (hidden, heads): (2 * attn, 2 * attn, attn),
+        (hidden, kv): (4 * attn, 3 * attn, 2 * attn),
+        (heads, hidden): (2 * attn, 2 * attn, attn),
     }
 
 
-@pytest.mark.parametrize("family", FAMILIES)
-def test_the_projections_that_feed_a_kernel_run_once(family):
-    """The engagement count with no chip. Under ``kernel_outputs`` a layer's
-    q / k / v (or ``in_proj``) matmul has two sites in the gradient — the
-    forward's and the backward's replay of the layer; under
-    ``kernel_operands`` the replay's is gone where the kernel's operand is
-    all the backward needs (``_projection_sites``), and every kernel,
-    forward and backward, still has the sites it had."""
+@pytest.mark.parametrize("family,policy", CASES)
+def test_the_projections_that_feed_a_kernel_run_once(family, policy):
+    """The engagement count with no chip. Under ``kernel_outputs`` a mixer's
+    matmul has two sites in the gradient — the forward's and the backward's
+    replay of the layer; under ``kernel_operands`` the replay's is gone
+    where the kernel's operand is all the backward needs, under
+    ``whole_mixer`` for every matmul of the mixer (``_projection_sites``),
+    and every kernel, forward and backward, still has the sites it had."""
     cfg, before, kernels_before = _sites(family, "kernel_outputs")
-    _cfg, after, kernels_after = _sites(family, _default(family))
-    expected = _projection_sites(family, cfg)
+    _cfg, after, kernels_after = _sites(family, policy)
+    expected = {
+        shape: (sites[0], sites[1 + POLICIES.index(policy)])
+        for shape, sites in _projection_sites(family, cfg).items()
+    }
     assert {w: (before[w], after[w]) for w in expected} == expected
     # nothing else moved: the router, the experts, the head
     assert {w: n for w, n in before.items() if w not in expected} == {
@@ -175,63 +206,84 @@ def test_the_projections_that_feed_a_kernel_run_once(family):
         ]
 
 
-# (model name, the cell's cut, operand bytes a micro-batch of one row at the
-# cell's sequence length: q + k + v of an attention layer, B | C | u of a
-# convolution layer, bf16)
+# (model name, the cell's cut, the cell's sequence length, bytes a micro-batch
+# of one row in bf16: the kernels' operands — q + k + v of an attention
+# layer, B | C | u of a convolution layer —, what ``whole_mixer`` keeps
+# besides — the stream after every mixer and, behind a q / k norm, q_proj's
+# and k_proj's outputs)
 PUBLISHED = {
     "smallthinker": (
         "smallthinker_21b_a3b", dict(num_hidden_layers=4, vocab_size=18992,
                                      expert_shard="0/8"), 16384,
-        4 * 16384 * (28 + 2 * 4) * 128 * 2,
+        4 * 16384 * (28 + 2 * 4) * 128 * 2, 4 * 16384 * 2560 * 2,
     ),
     "sdar": (
         "sdar_30b_a3b", dict(num_hidden_layers=4, vocab_size=18992,
                              expert_shard="0/8"), 4096,
         4 * 2 * 4096 * (32 + 2 * 4) * 128 * 2,  # both streams' positions
+        4 * 8192 * (4096 + 512 + 2048) * 2,
     ),
     "lfm2": (
         "lfm2_24b_a2b", dict(num_hidden_layers=5, vocab_size=8192,
                              expert_shard="0/8"), 4096,
         4 * 4096 * 3 * 2048 * 2 + 4096 * (32 + 2 * 8) * 64 * 2,
+        5 * 4096 * 2048 * 2 + 4096 * (2048 + 512) * 2,
     ),
+}
+# ... the sums ISSUE 46 and docs/observability.md state
+MIXER_BYTES = {
+    "smallthinker": 335_544_320, "sdar": 436_207_616, "lfm2": 104_857_600,
 }
 
 
-@pytest.mark.parametrize("family", FAMILIES)
-def test_kept_bytes_is_the_shapes_arithmetic(family):
-    """``remat.kept_bytes`` at the published widths and the benchmark
-    cell's cut, from ``jax.eval_shape`` (nothing allocated): the new default
-    keeps exactly the kernels' operands more than ``kernel_outputs`` — 151
-    MB a layer in SmallThinker's cell."""
-    name, cut, seq, operands = PUBLISHED[family]
-    kept = {}
-    for policy in ("", "kernel_outputs"):
-        cfg, model = build_model(name, policy, **cut)
-        ids = jax.ShapeDtypeStruct((1, seq), jnp.int32)
-        params = jax.eval_shape(
-            lambda r: model.init(r, jnp.zeros((1, seq), jnp.int32))["params"],
-            jax.random.PRNGKey(0),
-        )
-        batch = jax.eval_shape(lambda: drop_collator_keys(
-            next(model_family(cfg).synthetic_batches(cfg, 1, seq, 0))
-        ))
-        assert batch["input_ids"].shape == ids.shape
-        kept[cfg.remat_policy] = stash_bytes(
-            build_loss_fn(model), params, batch, jax.random.PRNGKey(0)
-        )
-    assert kept["kernel_operands"] - kept["kernel_outputs"] == operands
+@functools.lru_cache(maxsize=None)  # ``kernel_outputs``: once a family
+def _kept_bytes(family, remat_policy):
+    """``remat.kept_bytes`` of ``family`` at the published widths and the
+    benchmark cell's cut under ``remat_policy`` ("": the family's default),
+    from ``jax.eval_shape`` (nothing allocated); with the policy's name."""
+    name, cut, seq = PUBLISHED[family][:3]
+    cfg, model = build_model(name, remat_policy, **cut)
+    params = jax.eval_shape(
+        lambda r: model.init(r, jnp.zeros((1, seq), jnp.int32))["params"],
+        jax.random.PRNGKey(0),
+    )
+    batch = jax.eval_shape(lambda: drop_collator_keys(
+        next(model_family(cfg).synthetic_batches(cfg, 1, seq, 0))
+    ))
+    assert batch["input_ids"].shape == (1, seq)
+    return cfg.remat_policy, stash_bytes(
+        build_loss_fn(model), params, batch, jax.random.PRNGKey(0)
+    )
+
+
+@pytest.mark.parametrize("family,policy", CASES)
+def test_kept_bytes_is_the_shapes_arithmetic(family, policy):
+    """``kernel_operands`` keeps exactly the kernels' operands more than
+    ``kernel_outputs`` — 151 MB a layer in SmallThinker's cell —,
+    ``whole_mixer``, the families' default, exactly the sums and the q / k
+    norm's inputs more than that."""
+    operands, mixer = PUBLISHED[family][3:]
+    assert mixer == MIXER_BYTES[family]
+    if policy == "whole_mixer":
+        built, kept = _kept_bytes(family, "")  # the default IS this row
+        operands += mixer
+    else:
+        built, kept = _kept_bytes(family, policy)
+    assert built == policy
+    _name, kept_outputs = _kept_bytes(family, "kernel_outputs")
+    assert kept - kept_outputs == operands
     if family == "smallthinker":
-        assert operands // 4 == 150_994_944  # "151 MB a layer"
+        assert PUBLISHED[family][3] // 4 == 150_994_944  # "151 MB a layer"
     # the layer inputs and the kernels' outputs are in both readings
-    assert kept["kernel_outputs"] > operands // 2
+    assert kept_outputs > operands // 2
 
 
 def test_the_table_and_the_five_defaults():
     """One table: the new row resolves, a name that is none of its rows
     still raises, and each decoder family states the default its cell's
     memory allows."""
-    assert callable(remat_policy_object("kernel_operands"))
-    assert callable(remat_policy_object("kernel_outputs"))
+    for row in POLICIES + ("kernel_outputs",):
+        assert callable(remat_policy_object(row))
     with pytest.raises(ValueError, match="unknown remat_policy"):
         remat_policy_object("kernel_operand")
     assert {
@@ -240,9 +292,9 @@ def test_the_table_and_the_five_defaults():
             DeepseekV3Config, OuroConfig,
         )
     } == {
-        "SmallThinkerConfig": "kernel_operands",
-        "SdarMoeConfig": "kernel_operands",
-        "Lfm2MoeConfig": "kernel_operands",
+        "SmallThinkerConfig": "whole_mixer",
+        "SdarMoeConfig": "whole_mixer",
+        "Lfm2MoeConfig": "whole_mixer",
         "DeepseekV3Config": "kernel_outputs",
         "OuroConfig": "kernel_outputs",
     }
