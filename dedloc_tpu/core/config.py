@@ -293,7 +293,9 @@ class TrainingArguments:
     # smallthinker_21b_a3b (the band-and-global expert decoder,
     # models/smallthinker.py); sdar_tiny | sdar_30b_a3b (the block-diffusion
     # expert decoder, models/sdar_moe.py: seq_length counts a row's CLEAN
-    # tokens, the stack sees twice as many positions) —
+    # tokens, the stack sees twice as many positions); laguna_tiny |
+    # laguna_xs2_33b_a3b (full and window-512 attention with a head count,
+    # a RoPE and a gate a head per kind, models/laguna.py) —
     # roles/common.MODEL_FAMILIES is the table
     model_size: str = "large"
     # depth override (0 = the model's own): a chip's share of a deeper
@@ -302,7 +304,8 @@ class TrainingArguments:
     # "index/count": the share of every expert layer's routed experts this
     # peer's chip holds, as one of ``count`` chips that divide a layer (a
     # model with a dropless routed layer: models/deepseek_v3.py,
-    # models/lfm2_moe.py, models/smallthinker.py, models/sdar_moe.py). The
+    # models/lfm2_moe.py, models/smallthinker.py, models/sdar_moe.py,
+    # models/laguna.py). The
     # layer scores ALL experts and computes its own experts' part; "0/1" =
     # every expert. Together with ``vocab_size`` (rows of the vocabulary held)
     # and ``num_hidden_layers`` it states a chip's share of a deployment.
@@ -317,9 +320,9 @@ class TrainingArguments:
     # kernels READ: q / k / v, the convolution's B | C | u; whole_mixer —
     # those, the stream after the mixer and a q / k norm's input, so the
     # replay runs no matmul of the mixer — is the default of smallthinker,
-    # sdar and lfm2, and kernel_operands, then kernel_outputs, is what a
-    # peer with less memory to spare passes there; under any, the four
-    # rotate-half decoders — ouro, smallthinker, sdar, lfm2 — hand the flash
+    # sdar, lfm2 and laguna, and kernel_operands, then kernel_outputs, is what a
+    # peer with less memory to spare passes there; under any, the five
+    # rotate-half decoders — ouro, smallthinker, sdar, lfm2, laguna — hand the flash
     # kernels q / k / v from behind decoder.GroupedQueryAttention's
     # optimization_barrier, kanana2's LatentAttention does not: it moves
     # nothing there; the policy table lives in models/remat.py, measurements

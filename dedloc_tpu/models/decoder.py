@@ -23,11 +23,13 @@ RMSNorm is XLA's.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from dedloc_tpu.parallel.moe import (
@@ -94,19 +96,62 @@ class RMSNorm(nn.Module):
         ).astype(self.cfg.dtype)
 
 
-def rope_tables(seq: int, head_dim: int, theta: float):
+def rope_tables(seq: int, head_dim: int, theta: float, inv_freq=None,
+                scale=None):
     """cos, sin [S, D] of rotate-half RoPE: the D/2 frequencies repeated
-    over both halves."""
-    inv_freq = 1.0 / (
-        theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
-    )
+    over both halves. ``head_dim`` is the width that is ROTATED (a table
+    narrower than the head rotates the head's first lanes alone,
+    ``apply_rope``); ``inv_freq`` [D/2]: frequencies of the caller's own in
+    place of theta^(-2i/D) (``yarn_inv_freq``); ``scale``: a factor on both
+    tables (YaRN's attention factor)."""
+    if inv_freq is None:
+        inv_freq = 1.0 / (
+            theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+        )
     angles = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv_freq[None, :]
     angles = jnp.concatenate([angles, angles], axis=-1)
-    return jnp.cos(angles), jnp.sin(angles)
+    if scale is None:
+        return jnp.cos(angles), jnp.sin(angles)
+    return jnp.cos(angles) * scale, jnp.sin(angles) * scale
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's dim/2 inverse frequencies (``rope_type: yarn``; arXiv
+    2309.00071), float32: f_i = theta^(-2i/dim) kept where a pair turns more
+    than ``beta_fast`` times over the ``original`` positions, divided by
+    ``factor`` where it turns less than ``beta_slow`` times, and a linear
+    ramp between — r_i = clip((i - low) / (high - low), 0, 1) with low =
+    floor(c(beta_fast)), high = ceil(c(beta_slow)), c(b) = dim · ln(original
+    / (2π b)) / (2 ln theta), both held to [0, dim - 1]; f_i / factor · r_i
+    + f_i · (1 - r_i). Host arithmetic in float64: a constant of the
+    program."""
+    f = theta ** -(np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def turns_at(beta):
+        return dim * math.log(original / (2 * math.pi * beta)) / (
+            2 * math.log(theta)
+        )
+
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), dim - 1)
+    ramp = np.clip(
+        (np.arange(dim // 2) - low) / max(high - low, 1e-3), 0.0, 1.0
+    )
+    return (f / factor * ramp + f * (1.0 - ramp)).astype(np.float32)
 
 
 def apply_rope(x, cos, sin):
-    """x [B, S, H, D]: x·cos + rotate_half(x)·sin, in float32."""
+    """x [B, S, H, D]: x·cos + rotate_half(x)·sin, in float32. Tables
+    narrower than D (partial rotary) rotate the head's FIRST lanes and pass
+    the others as they are."""
+    width = cos.shape[-1]
+    if width < x.shape[-1]:
+        with jax.named_scope("rope_partial"):
+            return jnp.concatenate(
+                [apply_rope(x[..., :width], cos, sin), x[..., width:]],
+                axis=-1,
+            )
     half = x.shape[-1] // 2
     x32 = x.astype(jnp.float32)
     rotated = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
@@ -222,24 +267,41 @@ def mixer_residual(hidden, mixed):
     return checkpoint_name(hidden + mixed, "mixer_residual")
 
 
+def head_gate(cfg, hidden, heads: int, name: str = "g_proj"):
+    """sigmoid(W_g hidden) [B, S, heads], float32: ONE gate a query head,
+    for ``GroupedQueryAttention``'s ``gate``. ``W_g`` [H, heads] is created
+    in the CALLING module's scope (call it inside a compact method); its
+    output is named ``attn_gate`` for the policies of ``models/remat.py``
+    ([B, S, heads]: 1 MB where the gated context is B·S·heads·D)."""
+    logits = checkpoint_name(dense(heads, cfg, name)(hidden), "attn_gate")
+    return jax.nn.sigmoid(logits.astype(jnp.float32))
+
+
 class GroupedQueryAttention(nn.Module):
     """Grouped-query attention under ``visible``: ``q_proj`` / ``k_proj`` /
-    ``v_proj``, an RMSNorm over each head's own lanes of q and of k where
-    ``qk_norms`` names the two (a weight each, shared by the heads), THEN
-    rotate-half RoPE where ``rotated``; the output projection ``out_name``."""
+    ``v_proj`` over ``heads`` query heads (None: ``cfg.num_attention_heads``;
+    a stack whose layer kinds differ in their head count states each kind's)
+    and ``cfg.num_key_value_heads`` kv heads, an RMSNorm over each head's own
+    lanes of q and of k where ``qk_norms`` names the two (a weight each,
+    shared by the heads), THEN rotate-half RoPE where ``rotated``, over as
+    many of a head's first lanes as the tables of ``rope`` are wide; the
+    output projection ``out_name``. ``gate`` [B, S, heads] (``head_gate``):
+    each head's context times its gate, between the kernels and the output
+    projection."""
 
     cfg: Any
     visible: Visibility
     rotated: bool = True
     qk_norms: Tuple[Optional[str], Optional[str]] = (None, None)
     out_name: str = "o_proj"
+    heads: Optional[int] = None
 
     @nn.compact
-    def __call__(self, hidden, rope):
+    def __call__(self, hidden, rope, gate=None):
         cfg = self.cfg
         B, S, _ = hidden.shape
-        H, KV, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                    cfg.head_dim)
+        H, KV, D = (self.heads or cfg.num_attention_heads,
+                    cfg.num_key_value_heads, cfg.head_dim)
         q = dense(H * D, cfg, "q_proj")(hidden).reshape(B, S, H, D)
         k = dense(KV * D, cfg, "k_proj")(hidden).reshape(B, S, KV, D)
         v = dense(KV * D, cfg, "v_proj")(hidden).reshape(B, S, KV, D)
@@ -271,6 +333,12 @@ class GroupedQueryAttention(nn.Module):
             # 2.55 → 1.28 GB; LFM2's scratch 1.03 → 0.72 GB
             q, k, v = jax.lax.optimization_barrier((q, k, v))
         ctx = attend(cfg, q, k, v, self.visible)
+        if gate is not None:
+            # [B, S, KV, H / KV, D] (dense) is [B, S, H, D]: adjacent heads
+            with jax.named_scope("attn_gate"):
+                ctx = (
+                    ctx.reshape(B, S, H, D) * gate[..., None]
+                ).astype(cfg.dtype)
         return dense(cfg.hidden_size, cfg, self.out_name)(
             ctx.reshape(B, S, H * D)
         )
@@ -304,13 +372,18 @@ def held_expert_ffn(module: nn.Module, tokens, choice, weights,
 
 
 class RoutedFFN(nn.Module):
-    """Σ over the chosen HELD experts + the shared experts (where the model
-    has any), chosen by sigmoid scores + the stepped ``BIAS`` (DeepSeek-V3's
-    rule; kanana-2, LFM2). Returns (y, routing): scores [T, E], choice
-    [T, k], load [E] and ``parallel/moe.routed_experts``' counts; the load
-    leaves the backward as the bias leaf's cotangent (``moe.py``'s rule)."""
+    """Σ over the chosen HELD experts + a shared expert ``shared_width``
+    wide on every chip (0: the model has none), chosen by sigmoid scores
+    (DeepSeek-V3's rule; kanana-2, LFM2, Laguna) — where ``biased`` with
+    the stepped ``BIAS`` in the CHOICE — the chosen scores renormalised x
+    ``cfg.routed_scaling_factor``. Returns (y, routing): scores [T, E],
+    choice [T, k], load [E] and ``parallel/moe.routed_experts``' counts;
+    the load leaves the backward as the bias leaf's cotangent (``moe.py``'s
+    rule) where there is one."""
 
     cfg: Any
+    shared_width: int = 0
+    biased: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -319,7 +392,9 @@ class RoutedFFN(nn.Module):
         E = cfg.n_routed_experts
         init = nn.initializers.normal(cfg.initializer_range)
         router = self.param("router", init, (H, E), jnp.float32)
-        bias = self.param(BIAS, nn.initializers.zeros, (E,), jnp.float32)
+        bias = self.param(
+            BIAS, nn.initializers.zeros, (E,), jnp.float32
+        ) if self.biased else None
         tokens = x.reshape(B * S, H)
         # the router in float32 at full precision: the top-k is discrete
         scores = jax.nn.sigmoid(jnp.dot(
@@ -332,13 +407,14 @@ class RoutedFFN(nn.Module):
         )
         routed, counts = held_expert_ffn(self, tokens, choice, weights)
         routed = routed.reshape(B, S, H)
-        if cfg.n_shared_experts:
+        if self.shared_width:
             routed = routed + SwiGLU(
-                cfg, cfg.n_shared_experts * cfg.moe_intermediate_size,
-                name="shared_experts",
+                cfg, self.shared_width, name="shared_experts",
             )(x).astype(jnp.float32)
         load = expert_load(choice, E)
-        y = with_load_cotangent(routed.astype(cfg.dtype), bias, load)
+        y = routed.astype(cfg.dtype)
+        if self.biased:
+            y = with_load_cotangent(y, bias, load)
         return y, dict(counts, scores=scores, choice=choice, load=load)
 
 
@@ -491,7 +567,8 @@ def apply_with_grad_sinks(model, params, input_ids, grad_sinks):
 def routed_metrics(routing, params, gauges: Dict[str, Callable]):
     """The routing gauges of ``docs/observability.md`` from a stack's
     ``routing`` [layers, ...], a family's own ``gauges`` (name -> function
-    of ``params``) among them, and the micro-batch's routing as the step
+    of ``params`` and ``routing``) among them, and the micro-batch's routing
+    as the step
     itself computed it (``moe.choice`` [L, T, k], ``moe.scores`` [L, T, E]:
     what a check routes its reference by and compares; 8 MB at the published
     sizes, summed by nothing)."""
@@ -502,7 +579,7 @@ def routed_metrics(routing, params, gauges: Dict[str, Callable]):
         ),
         "moe.local_slot_share": jnp.mean(routing["local_slot_share"]),
         "moe.bulk_row_share": jnp.mean(routing["bulk_row_share"]),
-        **{name: gauge(params) for name, gauge in gauges.items()},
+        **{name: gauge(params, routing) for name, gauge in gauges.items()},
         "moe.dropped_slots": jnp.sum(routing["dropped_slots"]),
         "moe.grad_sink_leaves": jnp.sum(routing["grad_sink_leaves"]),
         "moe.choice": routing["choice"],

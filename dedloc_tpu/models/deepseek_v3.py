@@ -210,7 +210,10 @@ class DecoderLayer(nn.Module):
         x = RMSNorm(cfg, name="post_attention_layernorm")(hidden)
         if not self.sparse:
             return hidden + SwiGLU(cfg, cfg.intermediate_size, name="mlp")(x)
-        y, routing = RoutedFFN(cfg, name="mlp")(x)
+        y, routing = RoutedFFN(
+            cfg, shared_width=cfg.n_shared_experts * cfg.moe_intermediate_size,
+            name="mlp",
+        )(x)
         return hidden + y, routing
 
 
@@ -250,7 +253,7 @@ def deepseek_v3_loss(model: DeepseekV3ForCausalLM, params,
     return expert_lm_loss(
         model, params, batch, grad_sinks,
         head=lambda p: p["lm_head"].astype(model.cfg.dtype),
-        gauges={"moe.bias_abs_max": lambda p: jnp.max(jnp.abs(
+        gauges={"moe.bias_abs_max": lambda p, _r: jnp.max(jnp.abs(
             p["layers"]["block"]["mlp"][BIAS]
         ))},
     )

@@ -134,9 +134,8 @@ class Lfm2MoeConfig:
                 f"has {len(self.layer_types)} layers"
             )
 
-    # the routed layer's fields under ``RoutedFFN``'s names
+    # the routed layer's field under ``RoutedFFN``'s name
     n_routed_experts = property(lambda self: self.num_experts)
-    n_shared_experts = 0
 
     @property
     def held_experts(self) -> Tuple[int, int]:
@@ -270,7 +269,7 @@ def lfm2_moe_loss(model: Lfm2MoeForCausalLM, params,
     return expert_lm_loss(
         model, params, batch, grad_sinks,
         head=lambda p: p["embed_tokens"].astype(model.cfg.dtype).T,
-        gauges={"moe.bias_abs_max": lambda p: jnp.max(jnp.stack([
+        gauges={"moe.bias_abs_max": lambda p, _r: jnp.max(jnp.stack([
             jnp.max(jnp.abs(leaf))
             for path, leaf in jax.tree_util.tree_leaves_with_path(p)
             if path[-1].key == BIAS

@@ -65,12 +65,22 @@ def remat_policy_object(name: str):
         # q / k norm (109 MB at SDAR's 8,192 x (2,048 + 4,096 + 512), 84
         # at SmallThinker's 16,384 x 2,560, 17 a convolution layer of
         # LFM2's). For the decoders whose state leaves that room
-        # (models/smallthinker.py, sdar_moe.py, lfm2_moe.py)
+        # (models/smallthinker.py, sdar_moe.py, lfm2_moe.py). A per-head
+        # output gate (``decoder.head_gate``, models/laguna.py) multiplies
+        # the flash kernel's OUTPUT before the out-projection: every rung
+        # from "kernel_outputs" up keeps ``out`` itself, and the GATED
+        # context (the out-projection's operand, a second [B, S, H·D]) is
+        # kept by none — the backward's replay makes it again from ``out``
+        # with one multiply. The gate's logits ("attn_gate": [B, S, H], 1 MB
+        # at 8,192 x 64 against the context's 134 MB) are kept HERE, so
+        # this rung's replay still runs no matmul of the mixer; under the
+        # rungs below the replay runs ``g_proj`` (2·B·S·hidden·H FLOPs, a
+        # 1/128th of q_proj's) behind the input norm it runs anyway
         "whole_mixer": (
             jax.checkpoint_policies.save_from_both_policies(
                 jax.checkpoint_policies.save_only_these_names(
                     "flash_qkv", "short_conv_bcu", "mixer_residual",
-                    "qk_norm_input",
+                    "qk_norm_input", "attn_gate",
                 ),
                 _pallas_outputs_saveable,
             )

@@ -252,7 +252,7 @@ def sdar_moe_loss(model: SdarMoeForDiffusionLM, params,
         "diffusion.masked_share": masked / clean.size,
         "diffusion.masked_tokens": masked.astype(jnp.float32),
         **routed_metrics(routing, params, {
-            "attn.bd_tile_share": lambda _p: jnp.float32(share),
+            "attn.bd_tile_share": lambda _p, _r: jnp.float32(share),
         }),
     }
 

@@ -238,7 +238,7 @@ def smallthinker_loss(model: SmallThinkerForCausalLM, params,
     return expert_lm_loss(
         model, params, batch, grad_sinks,
         head=lambda p: p["lm_head"].astype(model.cfg.dtype),
-        gauges={"attn.band_tile_share": lambda _p: jnp.float32(share)},
+        gauges={"attn.band_tile_share": lambda _p, _r: jnp.float32(share)},
     )
 
 

@@ -225,11 +225,15 @@ def moe_ffn(
 
 def route_top_k(scores, bias, top_k: int, scale: float, eps: float = 1e-20):
     """(choice [T, k] int32, weights [T, k] float32) from sigmoid scores
-    [T, E] (float32) and the correction bias [E]: the bias enters the choice
+    [T, E] (float32) and the correction bias [E] (None: a model without
+    one chooses by its scores): the bias enters the choice
     alone; the weights are the chosen scores over their sum (+ ``eps``, the
     published model's own: 1e-20 DeepSeek-V3's, 1e-6 LFM2's), times
     ``scale``."""
-    _, choice = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+    _, choice = jax.lax.top_k(
+        scores if bias is None else scores + jax.lax.stop_gradient(bias),
+        top_k,
+    )
     picked = jnp.take_along_axis(scores, choice, axis=-1)
     weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + eps)
     return choice, weights * scale

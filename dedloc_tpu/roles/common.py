@@ -30,6 +30,13 @@ from dedloc_tpu.models.deepseek_v3 import (
     deepseek_v3_train_tflops_per_sample,
     deepseek_v3_weight_decay_mask,
 )
+from dedloc_tpu.models.laguna import (
+    LagunaConfig,
+    LagunaForCausalLM,
+    laguna_loss,
+    laguna_train_tflops_per_sample,
+    laguna_weight_decay_mask,
+)
 from dedloc_tpu.models.lfm2_moe import (
     Lfm2MoeConfig,
     Lfm2MoeForCausalLM,
@@ -253,12 +260,24 @@ SDAR_MOE = dataclasses.replace(
     ),
     step_counters=("moe.dropped_slots", "diffusion.masked_tokens"),
 )
+LAGUNA = dataclasses.replace(
+    SMALLTHINKER,  # the same source, counter and sinks; no bias: no sign rule
+    config=LagunaConfig, module=LagunaForCausalLM,
+    loss=_without_rng(laguna_loss),
+    tflops_per_sample=laguna_train_tflops_per_sample,
+    weight_decay_mask=laguna_weight_decay_mask,
+    step_gauges=SMALLTHINKER.step_gauges + (
+        "attn.band_visible_share", "attn.gate_mean.full_attention",
+        "attn.gate_mean.sliding_attention",
+    ),
+)
 MODEL_FAMILIES: Dict[str, ModelFamily] = {
     "tiny": ALBERT, "large": ALBERT, "ouro_tiny": OURO, "ouro_2p6b": OURO,
     "kanana2_tiny": DEEPSEEK_V3, "kanana2_30b_a3b": DEEPSEEK_V3,
     "lfm2_tiny": LFM2_MOE, "lfm2_24b_a2b": LFM2_MOE,
     "smallthinker_tiny": SMALLTHINKER, "smallthinker_21b_a3b": SMALLTHINKER,
     "sdar_tiny": SDAR_MOE, "sdar_30b_a3b": SDAR_MOE,
+    "laguna_tiny": LAGUNA, "laguna_xs2_33b_a3b": LAGUNA,
 }
 
 

@@ -19,6 +19,7 @@ ALLREDUCE_ROUND = "allreduce.round"
 ALLREDUCE_ROUNDS = "allreduce.rounds"
 ALLREDUCE_STRAGGLERS = "allreduce.stragglers"
 ATTN_BAND_TILE_SHARE = "attn.band_tile_share"
+ATTN_BAND_VISIBLE_SHARE = "attn.band_visible_share"
 ATTN_BD_TILE_SHARE = "attn.bd_tile_share"
 AVG_ROUND = "avg.round"
 AVG_TOPOLOGY_FALLBACK = "avg.topology.fallback"
@@ -240,6 +241,7 @@ COUNTERS = frozenset({
 })
 GAUGES = frozenset({
     "attn.band_tile_share",
+    "attn.band_visible_share",
     "attn.bd_tile_share",
     "diffusion.masked_share",
     "expert.load_ewma",
@@ -347,6 +349,7 @@ EMITTED = COUNTERS | GAUGES | HISTOGRAMS | EVENTS
 
 # declared dynamic-name families (emit-site pragmas)
 EMITTED_PREFIXES = (
+    "attn.gate_mean.",
     "link.",
     "lm.exit_prob.",
     "lm.loss.",

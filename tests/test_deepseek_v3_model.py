@@ -166,7 +166,11 @@ def test_the_shares_add_up_to_the_uncut_layer():
             name: layer[name][first:first + held]
             for name in ("experts_gate", "experts_up", "experts_down")
         })
-        y, routing = RoutedFFN(share).apply({"params": mine}, x)
+        y, routing = RoutedFFN(
+            share, shared_width=(
+                share.n_shared_experts * share.moe_intermediate_size
+            ),
+        ).apply({"params": mine}, x)
         with jax.default_matmul_precision("highest"):
             shared = reference._swiglu(x, layer["shared_experts"])
         total = total + (y - shared)

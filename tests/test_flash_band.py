@@ -169,3 +169,59 @@ def test_a_group_of_seven_needs_one_head_a_column_block():
     kv = jnp.zeros((1, 64, 2, 64), jnp.float32)
     with pytest.raises(ValueError, match="grouped-query"):
         flash_attention(q, kv, kv, causal=True, block_q=32, block_k=32)
+
+
+# Laguna's shapes: a band EQUAL to the tile (every query tile but the first
+# visits two key tiles and BOTH are crossed), under a group of eight (two
+# programs of four heads share a kv head) and — the full layers' call — a
+# group of SIX, a whole group a program
+GROUPS = [(6, 1, 128), (12, 2, 128), (8, 1, 128), (16, 2, 128)]
+
+
+@pytest.mark.parametrize("band", [BLOCK, None], ids=["band_is_the_tile",
+                                                     "causal"])
+@pytest.mark.parametrize(
+    "h,kv,d", GROUPS, ids=[f"{h}_{kv}x{d}" for h, kv, d in GROUPS]
+)
+def test_groups_of_six_and_eight_at_a_band_equal_to_the_tile(h, kv, d, band):
+    q, k, v, do = _operands(h, kv, d, seed=3)
+    out, vjp = jax.vjp(
+        lambda *x: flash_attention(*x, causal=True, band=band, block_q=BLOCK,
+                                   block_k=BLOCK), q, k, v,
+    )
+    want, want_vjp = jax.vjp(lambda *x: _dense(*x, band or S), q, k, v)
+    np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
+    for got, ref, name in zip(vjp(do), want_vjp(do), ("dq", "dk", "dv")):
+        np.testing.assert_allclose(got, ref, atol=1e-4, rtol=1e-4,
+                                   err_msg=name)
+
+
+def test_a_band_equal_to_the_tile_visits_two_crossed_tiles():
+    """31 of the triangle's 136 at S=8,192 and 512 x 512 tiles; the sweep
+    is two tiles long on both axes, and neither tile of a pair is plain:
+    the first holds one visible pair a row short of the diagonal's, the
+    second the diagonal."""
+    assert visited_tiles(8192, 512, 512, True, band=512) == 31
+    assert visited_tiles(8192, 512, 512, True) == 136
+    mask = fa._Mask(True, 512)
+    assert fa._sweep(mask, 16, 16, 512, 512, "k") == 2
+    assert fa._sweep(mask, 16, 16, 512, 512, "q") == 2
+    assert [fa._first_k_tile(mask, qi, 512, 512) for qi in (0, 1, 9)] == [
+        0, 0, 8
+    ]
+    for qi, ki in ((9, 8), (9, 9)):  # both crossed: some pair masked
+        seen = np.asarray(fa._tile_mask(mask, qi, ki, 512, 512))
+        assert seen.any() and not seen.all()
+    assert int(np.asarray(fa._tile_mask(mask, 9, 8, 512, 512)).sum()) + int(
+        np.asarray(fa._tile_mask(mask, 9, 9, 512, 512)).sum()
+    ) == 512 * 512  # a query's 512 keys, over the two tiles
+    # a tile narrower than the band: four key tiles of 128 a query tile + 1
+    assert visited_tiles(8192, 512, 128, True, band=512) == 4 + 15 * 8
+    assert visited_tiles(8192, 512, 256, True, band=512) == 2 + 15 * 4
+    found = _kernels(12, 2, 128, BLOCK, seq=128)
+    # 2 groups of six heads (a whole group a program), 4 query tiles, 2 keys
+    assert found["flash_band_fwd"] == (
+        (1, 2, 4, 2), {"heads": 12, "kv_heads": 2, "band": 32}
+    )
+    eight = _kernels(16, 2, 128, BLOCK, seq=128)
+    assert eight["flash_band_fwd"][0][2:] == (4, 2)

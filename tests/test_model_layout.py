@@ -2,12 +2,13 @@
 no parameter.
 
 (a) By ``ast``: ``models/decoder.py`` and ``models/remat.py`` import no other
-module of ``dedloc_tpu/models/``; the five decoder files import from
+module of ``dedloc_tpu/models/``; the six decoder files import from
 ``dedloc_tpu.models`` nothing but those two, and no private name of theirs.
 (b) For every name of ``roles/common.MODEL_FAMILIES``: ``model_family`` agrees
 with itself on a name, a config and a module, and the parameter tree of
 ``jax.eval_shape(model.init, ...)`` equals ``fixtures/model_param_trees.json``,
-recorded from the tree BEFORE ``models/decoder.py`` existed (PR 43's parent)
+recorded from the tree BEFORE ``models/decoder.py`` existed (PR 43's parent;
+a model added since — Laguna, PR 47 — from the tree that added it)
 by this file's own ``param_tree``:
 
     git archive <commit> | tar -x -C <dir>; cd <dir>
@@ -29,11 +30,13 @@ FIXTURE = os.path.join(
     "model_param_trees.json",
 )
 SHARED = ("decoder", "remat")
-DECODERS = ("ouro", "deepseek_v3", "lfm2_moe", "smallthinker", "sdar_moe")
+DECODERS = ("ouro", "deepseek_v3", "lfm2_moe", "smallthinker", "sdar_moe",
+            "laguna")
 NAMES = (
     "tiny", "large", "ouro_tiny", "ouro_2p6b", "kanana2_tiny",
     "kanana2_30b_a3b", "lfm2_tiny", "lfm2_24b_a2b", "smallthinker_tiny",
-    "smallthinker_21b_a3b", "sdar_tiny", "sdar_30b_a3b",
+    "smallthinker_21b_a3b", "sdar_tiny", "sdar_30b_a3b", "laguna_tiny",
+    "laguna_xs2_33b_a3b",
 )
 
 

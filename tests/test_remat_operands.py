@@ -5,7 +5,7 @@ convolution's B | C | u — beside what the forward ones wrote; under the
 second, the default of the three families with memory to spend, also the
 stream after the mixer and the input of a per-head q / k RMSNorm, so the
 replay of a layer runs no matmul of the mixer. For each of the two and each
-of the three families: the same bits as ``kernel_outputs`` and ``nothing``
+of the four families (Laguna's with a gate a head on the kernels' output): the same bits as ``kernel_outputs`` and ``nothing``
 (loss and every gradient leaf); the engagement count with no chip (a
 projection whose output is kept runs once a layer in the gradient, not twice;
 every kernel still once); and the mechanism's counter ``remat.kept_bytes``
@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 from dedloc_tpu.models.deepseek_v3 import DeepseekV3Config
+from dedloc_tpu.models.laguna import LagunaConfig
 from dedloc_tpu.models.lfm2_moe import Lfm2MoeConfig
 from dedloc_tpu.models.ouro import OuroConfig
 from dedloc_tpu.models.remat import remat_policy_object
@@ -48,6 +49,13 @@ TINY = {
     "lfm2": ("lfm2_tiny", dict(
         head_dim=64, num_hidden_layers=3, attention_block_size=16,
         vocab_size=320,
+    )),
+    # a dense full-attention layer (6 / 2 heads, half a head rotated) and a
+    # sparse sliding one (8 / 2) at a band equal to the tile, a gate a head
+    # on the kernels' output; a vocabulary whose width is no projection's
+    "laguna": ("laguna_tiny", dict(
+        head_dim=128, num_hidden_layers=2, attention_block_size=16,
+        sliding_window=16, vocab_size=320,
     )),
 }
 FAMILIES = sorted(TINY)
@@ -156,6 +164,17 @@ def _projection_sites(family, cfg):
     heads = cfg.num_attention_heads * cfg.head_dim
     kv = cfg.num_key_value_heads * cfg.head_dim  # k_proj and v_proj
     layers = cfg.num_hidden_layers
+    if family == "laguna":
+        # a width a KIND of layer (one layer of each here): q / k / v leave
+        # the replay with the kernels' operands, the out-projection and the
+        # gate's ``g_proj`` with the sum and the gate's logits
+        widths = [n for _kind, n, _sparse in cfg.layer_plan]
+        return {
+            **{(hidden, n * cfg.head_dim): (2, 1, 1) for n in widths},
+            (hidden, kv): (4 * layers, 2 * layers, 2 * layers),
+            **{(n * cfg.head_dim, hidden): (2, 2, 1) for n in widths},
+            **{(hidden, n): (2, 2, 1) for n in widths},
+        }
     if family == "smallthinker":
         return {
             (hidden, heads): (2 * layers, layers, layers),
@@ -229,10 +248,19 @@ PUBLISHED = {
         4 * 4096 * 3 * 2048 * 2 + 4096 * (32 + 2 * 8) * 64 * 2,
         5 * 4096 * 2048 * 2 + 4096 * (2048 + 512) * 2,
     ),
+    # three sliding layers at 64 heads, two full ones at 48, 8 kv heads;
+    # the stream after every mixer and every gate's logits
+    "laguna": (
+        "laguna_xs2_33b_a3b", dict(num_hidden_layers=5, vocab_size=12544,
+                                   expert_shard="0/32"), 8192,
+        8192 * (3 * (64 + 2 * 8) + 2 * (48 + 2 * 8)) * 128 * 2,
+        5 * 8192 * 2048 * 2 + 8192 * (3 * 64 + 2 * 48) * 2,
+    ),
 }
 # ... the sums ISSUE 46 and docs/observability.md state
 MIXER_BYTES = {
     "smallthinker": 335_544_320, "sdar": 436_207_616, "lfm2": 104_857_600,
+    "laguna": 172_490_752,
 }
 
 
@@ -278,7 +306,7 @@ def test_kept_bytes_is_the_shapes_arithmetic(family, policy):
     assert kept_outputs > operands // 2
 
 
-def test_the_table_and_the_five_defaults():
+def test_the_table_and_the_six_defaults():
     """One table: the new row resolves, a name that is none of its rows
     still raises, and each decoder family states the default its cell's
     memory allows."""
@@ -289,9 +317,10 @@ def test_the_table_and_the_five_defaults():
     assert {
         cls.__name__: cls().remat_policy for cls in (
             SmallThinkerConfig, SdarMoeConfig, Lfm2MoeConfig,
-            DeepseekV3Config, OuroConfig,
+            DeepseekV3Config, OuroConfig, LagunaConfig,
         )
     } == {
+        "LagunaConfig": "whole_mixer",
         "SmallThinkerConfig": "whole_mixer",
         "SdarMoeConfig": "whole_mixer",
         "Lfm2MoeConfig": "whole_mixer",

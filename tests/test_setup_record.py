@@ -60,9 +60,19 @@ def log():
     logger.removeHandler(handler)
 
 
-# a FakeClock moves the real monotonic clock: the test's own microseconds
-# ride on every scripted duration
+# float sums of the scripted advances: the records of this file run on a
+# FROZEN FakeClock (``scripted_clock``), as ``tests/test_step_spans.py``'s do
+# and for its reason — an offset-only clock rides on the real one, and a
+# thread another test left running in the worker holds the interpreter for
+# milliseconds: a 1 ms pause between the last lap and the close adds a lap
+# called "rest" (``_SetupContext.close``), which failed
+# ``test_runlog_summary_steps_prints_the_setup_record_ahead_of_the_steps``
+# once in two whole runs under ``-n 6`` (PR 47)
 REAL = 5e-3
+
+
+def scripted_clock() -> FakeClock:
+    return FakeClock(frozen=True)
 
 
 def _filed(record, *events):
@@ -105,7 +115,7 @@ def _tree(spans):
 
 
 def test_laps_tile_the_record_and_the_line_says_so(log):
-    with FakeClock() as clock:
+    with scripted_clock() as clock:
         with steps.setup_record(log) as record:
             assert steps.current_setup() is record
             clock.advance(0.25)
@@ -139,7 +149,7 @@ def test_laps_tile_the_record_and_the_line_says_so(log):
 def test_a_record_abandoned_before_a_global_step_says_complete_0(log):
     before = _listeners()
     with pytest.raises(RuntimeError):
-        with FakeClock() as clock, steps.setup_record(log) as record:
+        with scripted_clock() as clock, steps.setup_record(log) as record:
             clock.advance(0.5)
             steps.lap("dht")
             clock.advance(0.25)
@@ -153,7 +163,7 @@ def test_a_record_abandoned_before_a_global_step_says_complete_0(log):
 
 
 def test_the_line_round_trips_through_the_reducers_parser(log):
-    with FakeClock() as clock, steps.setup_record(log) as record:
+    with scripted_clock() as clock, steps.setup_record(log) as record:
         clock.advance(0.125)
         steps.lap("prepare")
         _filed(
@@ -199,7 +209,7 @@ def test_the_line_round_trips_through_the_reducers_parser(log):
 
 
 def test_a_trace_inside_a_trace_is_its_parents_time(log):
-    with FakeClock() as clock, steps.setup_record(log) as record:
+    with scripted_clock() as clock, steps.setup_record(log) as record:
         _filed(
             record,
             ("trace_s", "_where", 0.5, 0.75),  # an inner jit, traced inside
@@ -319,7 +329,7 @@ def test_the_line_is_always_logged_and_the_event_only_with_telemetry(
     log, enabled
 ):
     tele = Telemetry(peer="setup-unit") if enabled else None
-    with FakeClock() as clock, steps.setup_record(log) as record:
+    with scripted_clock() as clock, steps.setup_record(log) as record:
         _filed(record, ("backend_s", "flat_apply_step", 0.0, 1.0))
         record.cache["hits"] += 1
         clock.advance(1.5)
@@ -351,7 +361,7 @@ def test_runlog_summary_steps_prints_the_setup_record_ahead_of_the_steps(
 ):
     path = tmp_path / "events.jsonl"
     tele = Telemetry(peer="setup-view", event_log_path=str(path))
-    with FakeClock() as clock, steps.setup_record(log) as record:
+    with scripted_clock() as clock, steps.setup_record(log) as record:
         clock.advance(0.5)
         steps.lap("init_state")
         _filed(

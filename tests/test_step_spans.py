@@ -30,8 +30,21 @@ def _span_seconds(span):
     return span[5] if len(span) > 4 else span[3] - span[2]
 
 
+def scripted_clock() -> FakeClock:
+    """The clock every scripted record of this file runs on: FROZEN, so the
+    spans read ``advance``'s seconds and nothing else. An offset-only
+    FakeClock rides on the real clock, and what runs BETWEEN two advances
+    leaks in — microseconds alone, but a thread another test of the worker
+    left running (a DHT loop, an averager, a backup thread: xdist's
+    ``--dist loadfile`` puts whole files of those in this process) holds
+    the interpreter for its 5 ms switch interval, and one such hold inside
+    a boundary of 100 micro-batches broke ``approx``'s 2 ms: 5-7 failures
+    in 100 with one busy thread beside the test, 0 in 100 frozen."""
+    return FakeClock(frozen=True)
+
+
 def approx(expected):
-    """A FakeClock offsets the real clock: real microseconds leak in."""
+    """Float sums of the scripted advances: rounding, no real time."""
     return pytest.approx(expected, abs=2e-3)
 
 
@@ -40,7 +53,7 @@ def approx(expected):
 
 def test_nested_spans_give_self_times_that_sum_to_the_wall():
     rec = StepRecorder()
-    with FakeClock() as clock:
+    with scripted_clock() as clock:
         with rec.step(step=7) as srec:
             with steps.phase("data_wait"):
                 clock.advance(0.25)
@@ -81,7 +94,7 @@ def test_added_span_counts_and_attached_span_does_not():
     same wall (the averager's matchmaking / all-reduce split): in ``spans``,
     never in ``phases``."""
     rec = StepRecorder()
-    with FakeClock() as clock:
+    with scripted_clock() as clock:
         with rec.step():
             with steps.phase("avg_wire") as wire:
                 start = registry.monotonic_clock()
@@ -106,7 +119,7 @@ def test_a_span_outside_any_record_still_times():
     """``CollaborativeOptimizer.seam_ms`` reads ``dur_s`` whether or not a
     role is recording."""
     assert steps.current() is None
-    with FakeClock() as clock:
+    with scripted_clock() as clock:
         with steps.phase("opt_apply") as span:
             clock.advance(0.5)
     assert span.dur_s == approx(0.5)
@@ -114,7 +127,7 @@ def test_a_span_outside_any_record_still_times():
 
 def test_spans_are_bounded_by_folding_repeated_leaves():
     rec = StepRecorder()
-    with FakeClock() as clock:
+    with scripted_clock() as clock:
         with rec.step():
             for _ in range(100):  # a boundary of 100 micro-batches
                 with steps.phase("data_wait"):
@@ -169,7 +182,7 @@ def test_attached_folded_span_keeps_its_total_and_stays_out_of_phases(
     fold, and in one that overflows ``MAX_SPANS`` and folds around it — and
     is nobody's phase: another thread's time."""
     rec = StepRecorder()
-    with FakeClock() as clock:
+    with scripted_clock() as clock:
         record = _record_with_attached_kinds(clock, rec, micro_batches)
     spans = {s[0]: s for s in record["spans"]}
     assert spans["ar_encode"][:2] == ["ar_encode", "allreduce"]
@@ -201,7 +214,7 @@ def test_the_benchmark_span_reducers_read_a_folded_total():
     from benchmark.reducers import span, span_residual
 
     rec = StepRecorder()
-    with FakeClock() as clock:
+    with scripted_clock() as clock:
         record = _record_with_attached_kinds(clock, rec, 2)
     run = types.SimpleNamespace(step_records=[record])
     assert span.reduce(
@@ -231,7 +244,7 @@ def test_the_benchmark_span_reducers_read_a_folded_total():
 def test_enabled_telemetry_publishes_the_tree():
     tele = Telemetry(peer="p0")
     rec = StepRecorder(telemetry=tele)
-    with FakeClock() as clock:
+    with scripted_clock() as clock:
         with rec.step(step=3):
             with steps.phase("opt_apply"):
                 clock.advance(0.25)
@@ -570,7 +583,7 @@ def test_slow_global_step_is_one_info_line(caplog):
     package_logger = logging.getLogger("dedloc_tpu")  # does not propagate
     package_logger.addHandler(caplog.handler)
     try:
-        with FakeClock() as clock:
+        with scripted_clock() as clock:
             for step in range(8):
                 global_step(clock, step, 0.25)
             assert not [r for r in caplog.records if "slow global" in r.message]

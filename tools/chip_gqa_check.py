@@ -30,6 +30,19 @@ over 4 kv heads of 128 at 2 x 4,096 positions, blocks of 4:
     chiprun --chips 1 -- python tools/chip_gqa_check.py --heads 32 \
         --kv-heads 4 --head-dim 128 --seq 8192 --block-diffusion 4 --conv 0
 
+Laguna-XS.2's two calls (a band EQUAL to the tile under a group of eight,
+the full triangle under a whole group of SIX a program), the band also at a
+key tile of 256 and of 128 (``--block-k``: the kernels' existing ``block_k``
+argument; a roofline share then counts the narrower tiles the band holds —
+62 and 124 for the 31 of 512 — so the device ms a call is the number to
+compare across tiles):
+
+    chiprun --chips 1 -- python tools/chip_gqa_check.py --heads 64 \
+        --kv-heads 8 --head-dim 128 --seq 8192 --band 512 \
+        --block-k 512 256 128 --conv 0
+    chiprun --chips 1 -- python tools/chip_gqa_check.py --heads 48 \
+        --kv-heads 8 --head-dim 128 --seq 8192 --band none --conv 0
+
 Prints one JSON line; exit code 1 if an error exceeds 0.02 relative L2
 (bf16 rounding of the operands alone is ~0.004)."""
 from __future__ import annotations
@@ -37,6 +50,7 @@ from __future__ import annotations
 import argparse
 import contextlib
 import importlib
+import itertools
 import json
 import os
 import statistics
@@ -67,18 +81,21 @@ class Blocks(int):
     """A mask spec beside a band's length: the two-stream rule's blocks."""
 
 
-def attention_cost(kernel: str, shape, band):
+def attention_cost(kernel: str, shape, band, block_k: int = 512):
     """(FLOPs, bytes) of one ``flash_gqa_*`` / ``flash_band_*`` /
-    ``flash_bd_*`` call."""
+    ``flash_bd_*`` call at query tiles of 512 and key tiles of
+    ``block_k``."""
     s, h, kv, d = shape
     if isinstance(band, Blocks):
         return bd_kernel_cost(
-            f"flash_bd_{kernel}", B, h, kv, s // 2, d, 512, 512, int(band)
+            f"flash_bd_{kernel}", B, h, kv, s // 2, d, 512, block_k, int(band)
         )
     if band is None:
-        return gqa_kernel_cost(f"flash_gqa_{kernel}", B, h, kv, s, d, 512, 512)
+        return gqa_kernel_cost(
+            f"flash_gqa_{kernel}", B, h, kv, s, d, 512, block_k
+        )
     return band_kernel_cost(
-        f"flash_band_{kernel}", B, h, kv, s, d, 512, 512, band
+        f"flash_band_{kernel}", B, h, kv, s, d, 512, block_k, band
     )
 
 
@@ -200,6 +217,11 @@ def main(argv=None) -> int:
         help="check the two-stream block rule at this block length IN PLACE "
              "of the bands (--seq counts both streams)",
     )
+    parser.add_argument(
+        "--block-k", type=int, nargs="+", default=[512],
+        help="key tiles to run every band at (query tiles stay 512); a "
+             "tile other than 512 is tagged .bk<n>",
+    )
     parser.add_argument("--conv", type=int, choices=(0, 1), default=1)
     opts = parser.parse_args(argv)
     shape = (opts.seq, opts.heads, opts.kv_heads, opts.head_dim)
@@ -223,17 +245,26 @@ def main(argv=None) -> int:
             return jnp.sum(out.astype(jnp.float32) * w), out
         return jax.jit(jax.value_and_grad(loss, (0, 1, 2), has_aux=True))
 
-    def flash(q, k, v, band):
-        if isinstance(band, Blocks):
-            return flash_attention(q, k, v, block_diffusion=int(band))
-        return flash_attention(q, k, v, causal=True, band=band)
+    def flash_at(block_k):
+        def flash(q, k, v, band):
+            if isinstance(band, Blocks):
+                return flash_attention(
+                    q, k, v, block_diffusion=int(band), block_k=block_k
+                )
+            return flash_attention(
+                q, k, v, causal=True, band=band, block_k=block_k
+            )
+        return flash
 
     errors, kernels = {}, {}
-    for band in bands:
+    for band, block_k in itertools.product(bands, opts.block_k):
         tag = "causal" if band is None else f"band_{band}"
         family = "flash_gqa" if band is None else "flash_band"
         if isinstance(band, Blocks):
             tag, family = f"block_diffusion_{band}", "flash_bd"
+        if block_k != 512:
+            tag += f".bk{block_k}"
+        flash = flash_at(block_k)
         step = attention(flash, band)
         (_, out), grads = step(bf(q), bf(k), bf(v))
         (_, ref_out), ref_grads = attention(dense, band)(r(q), r(k), r(v))
@@ -246,7 +277,7 @@ def main(argv=None) -> int:
         costs = {
             f"{family}_{kernel}": (
                 lambda _on_chip, kernel=kernel: attention_cost(
-                    kernel, shape, band
+                    kernel, shape, band, block_k
                 )
             ) for kernel in KERNELS
         }

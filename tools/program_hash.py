@@ -11,7 +11,10 @@ locations are stripped (``strip-debuginfo``) before a kernel is serialised.
 Run it in both trees (the parent unpacked by ``git archive``: its own
 ``tools/`` and ``dedloc_tpu/`` are what it imports) and compare the lines:
 equal hashes are equal inputs to the same compilers. PR 40 held the eleven
-programs of the eight older cells to their parent's this way."""
+programs of the eight older cells to their parent's this way, PR 47 the
+thirteen of the nine (under a ``GroupedQueryAttention`` and a ``RoutedFFN``
+that gained arguments) beside its own two, ``laguna_kernels`` and
+``laguna_accumulate_step``."""
 from __future__ import annotations
 
 import hashlib

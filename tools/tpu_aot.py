@@ -15,7 +15,7 @@ Each line: {"program", "compile_s", "tpu_custom_calls", "flash_fwd_forms",
 "flash_windows", "layer_body_copies", "memory"} (and, for the programs of
 ``COUNT_KERNEL_CALLS``, "kernel_calls": call sites by kernel name; for the
 expert programs, "expert_grad_passes": ``expert_grad_passes``' counts; for
-the five decoder programs of ``LM_CELLS``, "remat_policy": the layer policy
+the six decoder programs of ``LM_CELLS``, "remat_policy": the layer policy
 they were built under — the model's default, or ``<PREFIX>_REMAT`` from the
 environment, e.g. ``SMALLTHINKER_REMAT=kernel_outputs``: read ``memory``'s
 ``temp_bytes`` under both before asking a chip for the stash's room) —
@@ -267,6 +267,29 @@ def bd_kernels(device):
     )
 
 
+def laguna_kernels(device):
+    """The Laguna cell's kernels: the band ones at a band EQUAL to the tile
+    (64 query heads over 8 kv heads of 128, a group of eight, S=8,192 in
+    16 x 16 tiles of 512, a band of 512: a sweep of 2 key tiles a query
+    tile, both crossed) beside the grouped causal ones at 48 / 8 (a whole
+    group of SIX a program), fwd+bwd."""
+    from dedloc_tpu.ops.flash_attention import flash_attention
+
+    def loss(q_band, q_full, k, v):
+        return jnp.sum(flash_attention(
+            q_band, k, v, causal=True, band=512
+        ).astype(jnp.float32)) + jnp.sum(flash_attention(
+            q_full, k, v, causal=True
+        ).astype(jnp.float32))
+
+    q_band = jax.ShapeDtypeStruct((1, 8192, 64, 128), jnp.bfloat16)
+    q_full = jax.ShapeDtypeStruct((1, 8192, 48, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16)
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        *_on_device(device, (q_band, q_full, kv, kv))
+    )
+
+
 @functools.lru_cache(maxsize=None)
 def _lm_model_and_state(config: str, prefix: str):
     """(args, model, state, ids) of a causal-LM cell's recipe, from its
@@ -317,6 +340,7 @@ LM_CELLS = {
         "smallthinker_21b_a3b_s16384.json", "SMALLTHINKER"
     ),
     "sdar_accumulate_step": ("sdar_30b_a3b_s4096.json", "SDAR"),
+    "laguna_accumulate_step": ("laguna_xs2_33b_a3b_s8192.json", "LAGUNA"),
 }
 
 
@@ -395,6 +419,20 @@ def sdar_accumulate_step(device):
         device, args, model, state, ids,
         loss_weights=jnp.zeros(ids.shape, jnp.float32),
     )
+
+
+def laguna_accumulate_step(device):
+    """Laguna-XS.2 at one chip's share (``benchmark/configs/
+    laguna_xs2_33b_a3b_s8192.json``; ``LAGUNA_LAYERS`` / ``LAGUNA_BATCH``
+    size another cut): the leading dense layer under full attention and one
+    scanned period of three window-512 layers and a full one at S=8,192 —
+    48 and 64 query heads over 8 kv heads, half a full head's lanes rotated
+    under YaRN, a gate a head —, sigmoid top-8 of 256 (8 held) beside a
+    shared expert, the untied chunked head. Its boundary programs are the
+    other expert decoders' (``guarded_apply_step`` over another tree)."""
+    return _lm_accumulate_step(device, *_lm_model_and_state(
+        *LM_CELLS["laguna_accumulate_step"]
+    ))
 
 
 def ouro_guarded_apply_step(device):
@@ -571,10 +609,12 @@ def _tile_loops(hlo_text: str, shapes: set) -> dict:
 # carries ``expert_grad_passes``
 COUNT_KERNEL_CALLS = {"gqa_kernels", "lfm2_accumulate_step", "band_kernels",
                       "smallthinker_accumulate_step", "bd_kernels",
-                      "sdar_accumulate_step"}
+                      "sdar_accumulate_step", "laguna_kernels",
+                      "laguna_accumulate_step"}
 COUNT_EXPERT_GRAD_PASSES = {"kanana_accumulate_step", "lfm2_accumulate_step",
                             "smallthinker_accumulate_step",
-                            "sdar_accumulate_step"}
+                            "sdar_accumulate_step",
+                            "laguna_accumulate_step"}
 NO_V5E = 3  # exit code: nothing to compile with, which is not a failure
 
 
@@ -584,6 +624,7 @@ PROGRAMS = {
         ouro_guarded_apply_step, mla_kernels, kanana_accumulate_step,
         gqa_kernels, lfm2_accumulate_step, band_kernels,
         smallthinker_accumulate_step, bd_kernels, sdar_accumulate_step,
+        laguna_kernels, laguna_accumulate_step,
     )
 }
 
