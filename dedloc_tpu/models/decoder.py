@@ -17,7 +17,10 @@ router and the loss in float32; q/k/v leave their projections as [B, S, H·D]
 and go to the flash kernels in that layout, named ``flash_qkv`` (RoPE is
 applied before the name, so what a policy stashes is what the kernels read);
 the stream after a mixer is named ``mixer_residual`` and a per-head q / k
-norm's input ``qk_norm_input``, for the policies of ``models/remat.py``;
+norm's input ``qk_norm_input``, for the policies of ``models/remat.py``; a
+per-head output gate — the one element-wise op on a flash kernel's OUTPUT —
+is a kernel pair of its own behind the flash call (``ops/head_gate.py``), in
+the [B, S, H·D] layout the kernel wrote and the out-projection reads;
 RMSNorm is XLA's.
 """
 from __future__ import annotations
@@ -287,7 +290,10 @@ class GroupedQueryAttention(nn.Module):
     many of a head's first lanes as the tables of ``rope`` are wide; the
     output projection ``out_name``. ``gate`` [B, S, heads] (``head_gate``):
     each head's context times its gate, between the kernels and the output
-    projection."""
+    projection — ``ops/head_gate.gate_heads``' kernels behind the flash
+    kernels on one device, XLA's expression behind ``"dense"`` attention and
+    on a mesh (the op carries no ``shard_map``; no gated model runs on
+    one)."""
 
     cfg: Any
     visible: Visibility
@@ -333,15 +339,21 @@ class GroupedQueryAttention(nn.Module):
             # 2.55 → 1.28 GB; LFM2's scratch 1.03 → 0.72 GB
             q, k, v = jax.lax.optimization_barrier((q, k, v))
         ctx = attend(cfg, q, k, v, self.visible)
+        # [B, S, KV, H / KV, D] (dense) is [B, S, H, D]: adjacent heads
+        ctx = ctx.reshape(B, S, H * D)
         if gate is not None:
-            # [B, S, KV, H / KV, D] (dense) is [B, S, H, D]: adjacent heads
+            from dedloc_tpu.ops.head_gate import gate_heads, gate_heads_xla
+
+            # on one device, behind the flash kernels: ONE pass over the
+            # context in the layout the kernel wrote and o_proj reads
+            # (``head_gate_fwd`` / ``head_gate_bwd``; a head width that is
+            # no whole lane tile falls back inside). XLA's expression put
+            # the gate's heads on sublanes by writing a float32 [B, S, H·D]
+            # broadcast of it twice a direction (PERF.md section 6, PR 48)
+            fused = cfg.attention_impl == "flash" and cfg.mesh is None
             with jax.named_scope("attn_gate"):
-                ctx = (
-                    ctx.reshape(B, S, H, D) * gate[..., None]
-                ).astype(cfg.dtype)
-        return dense(cfg.hidden_size, cfg, self.out_name)(
-            ctx.reshape(B, S, H * D)
-        )
+                ctx = (gate_heads if fused else gate_heads_xla)(ctx, gate)
+        return dense(cfg.hidden_size, cfg, self.out_name)(ctx)
 
 
 def held_expert_ffn(module: nn.Module, tokens, choice, weights,

@@ -51,7 +51,9 @@ bias, with this model's shared width), the loss tail and the leaf mask are
 ``models/decoder.py``'s; the kernels are the grouped-query mode of
 ``ops/flash_attention.py`` — a whole group of SIX a program in the full
 layers, a group of eight under ``band=512`` in the others: a band EQUAL to
-the tile, every query tile's two key tiles both crossed.
+the tile, every query tile's two key tiles both crossed — and, behind them,
+the gate's own pair (``ops/head_gate.py``: ``g_h · a_h`` in the layout the
+flash kernel wrote and ``W_o`` reads).
 
 **A chip's share**, as for the other expert decoders: ``expert_shard`` (the
 experts held of every sparse layer; the shared expert is on every chip),
@@ -140,15 +142,16 @@ class LagunaConfig:
     # / v as the flash kernels read them beside out + lse, the gate's
     # logits and the stream after attention, so the backward's replay runs
     # no projection of the mixer, no RoPE and no relayout; the gated
-    # context is made again from ``out`` with one multiply: 8,192 x ((64 +
-    # 2·8) x 128 + 64 + 2,048) x 2 bytes = 202 MB a sliding layer a
-    # micro-batch, 169 MB a full one; in the benchmark's cell of five
-    # layers accumulate_step's scratch reads 2.94 GB (2.80 under
-    # "kernel_operands", 2.14 under "kernel_outputs") beside 10.91 GB of
-    # state while a backup drains, and the allocator's peak (the
-    # boundary's: 12.06 GB) does not move with it. A smaller chip or a
-    # larger share: --training.remat_policy kernel_operands, then
-    # kernel_outputs
+    # context is made again from ``out`` with one more call of the gate's
+    # kernel (``ops/head_gate.py``: 0.37 ms): 8,192 x ((64 + 2·8) x 128 +
+    # 64 + 2,048) x 2 bytes = 202 MB a sliding layer a micro-batch, 169 MB
+    # a full one; in the benchmark's cell of five layers accumulate_step's
+    # scratch reads 2.47 GB (2.94 before the gate was a kernel pair, PR
+    # 48; then 2.80 under "kernel_operands", 2.14 under "kernel_outputs")
+    # beside 10.91 GB of state while a backup drains, and the allocator's
+    # peak (the boundary's: 12.06 GB) does not move with it. A smaller
+    # chip or a larger share: --training.remat_policy kernel_operands,
+    # then kernel_outputs
     remat_policy: str = "whole_mixer"
     attention_impl: str = "flash"  # or "dense" (tests, tiny models)
     attention_block_size: int = 512

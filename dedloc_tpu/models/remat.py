@@ -5,10 +5,22 @@ import flax.linen as nn
 import jax
 
 
-def _pallas_outputs_saveable(prim, *_, **__) -> bool:
+# Pallas kernels whose output is NOT kept: one cheap pass away from values
+# that are. ``head_gate_fwd`` (ops/head_gate.py) makes the gated context from
+# the flash kernel's ``out`` and the gate: keeping it is 134 MB a layer at
+# 8,192 x 64 x 128 for a 0.37 ms pass, and on the v5e the step takes the same
+# time either way (remat's reduce-precision pass over a kept value costs what
+# the replayed call does: PERF.md section 5, PR 48)
+REPLAYED_KERNELS = ("head_gate_fwd",)
+
+
+def _pallas_outputs_saveable(prim, *_, **params) -> bool:
     """Remat-policy predicate: save the outputs of Pallas kernels (here the
-    flash-attention out/lse residuals) instead of re-running them backward."""
-    return getattr(prim, "name", "") == "pallas_call"
+    flash-attention out/lse residuals) instead of re-running them backward,
+    but those of ``REPLAYED_KERNELS``."""
+    return getattr(prim, "name", "") == "pallas_call" and (
+        params.get("name") not in REPLAYED_KERNELS
+    )
 
 
 def remat_policy_object(name: str):
@@ -67,15 +79,19 @@ def remat_policy_object(name: str):
         # LFM2's). For the decoders whose state leaves that room
         # (models/smallthinker.py, sdar_moe.py, lfm2_moe.py). A per-head
         # output gate (``decoder.head_gate``, models/laguna.py) multiplies
-        # the flash kernel's OUTPUT before the out-projection: every rung
-        # from "kernel_outputs" up keeps ``out`` itself, and the GATED
-        # context (the out-projection's operand, a second [B, S, H·D]) is
-        # kept by none — the backward's replay makes it again from ``out``
-        # with one multiply. The gate's logits ("attn_gate": [B, S, H], 1 MB
-        # at 8,192 x 64 against the context's 134 MB) are kept HERE, so
-        # this rung's replay still runs no matmul of the mixer; under the
-        # rungs below the replay runs ``g_proj`` (2·B·S·hidden·H FLOPs, a
-        # 1/128th of q_proj's) behind the input norm it runs anyway
+        # the flash kernel's OUTPUT before the out-projection, behind the
+        # flash kernels in a kernel of its own (``head_gate_fwd``,
+        # ops/head_gate.py): every rung from "kernel_outputs" up keeps
+        # ``out`` itself, and the GATED context (the out-projection's
+        # operand, a second [B, S, H·D]) is kept by none although a Pallas
+        # kernel writes it (``REPLAYED_KERNELS``) — the backward's replay
+        # makes it again from ``out`` with one more call of that kernel
+        # (0.37 ms a layer at Laguna's cell). The gate's logits
+        # ("attn_gate": [B, S, H], 1 MB at 8,192 x 64 against the context's
+        # 134 MB) are kept HERE, so this rung's replay still runs no matmul
+        # of the mixer; under the rungs below the replay runs ``g_proj``
+        # (2·B·S·hidden·H FLOPs, a 1/128th of q_proj's) behind the input
+        # norm it runs anyway
         "whole_mixer": (
             jax.checkpoint_policies.save_from_both_policies(
                 jax.checkpoint_policies.save_only_these_names(

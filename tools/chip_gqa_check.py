@@ -43,8 +43,17 @@ compare across tiles):
     chiprun --chips 1 -- python tools/chip_gqa_check.py --heads 48 \
         --kv-heads 8 --head-dim 128 --seq 8192 --band none --conv 0
 
+The per-head output gate's kernel pair (``ops/head_gate.py``) alone, at
+``(1, --seq, --heads x --head-dim)``, held to XLA's expression over the SAME
+bf16 operands (the forward and ``d_ctx`` by the share of elements whose bits
+differ, ``d_gate`` by relative L2) and timed against the bytes a call moves:
+
+    chiprun --chips 1 -- python tools/chip_gqa_check.py --heads 64 \
+        --kv-heads 8 --head-dim 128 --seq 8192 --band --conv 0 --head-gate 1
+
 Prints one JSON line; exit code 1 if an error exceeds 0.02 relative L2
-(bf16 rounding of the operands alone is ~0.004)."""
+(bf16 rounding of the operands alone is ~0.004) or a gated element's bits
+differ."""
 from __future__ import annotations
 
 import argparse
@@ -70,11 +79,15 @@ from benchmark.peaks import chip_peaks
 from benchmark.reducers.conv_kernel_roofline import on_chip_tensors
 from benchmark.trace import OPS, load_xplane, op_name
 from dedloc_tpu.ops.flash_attention import flash_attention
+from dedloc_tpu.ops.head_gate import gate_heads, gate_heads_xla
 from dedloc_tpu.ops.short_conv import short_conv, short_conv_reference
 
 B, HIDDEN = 1, 2048
 KERNELS = ("fwd", "bwd_dq", "bwd_dkv")
 CONV = ("short_conv_fwd", "short_conv_bwd")
+# the gate's kernels: [B, S, H·D] bf16 arrays read + written a call, beside
+# the float32 gate [B, S, H] once each way
+GATE = {"head_gate_fwd": (2, 1), "head_gate_bwd": (3, 2)}
 
 
 class Blocks(int):
@@ -205,7 +218,7 @@ def main(argv=None) -> int:
     parser.add_argument("--head-dim", type=int, default=64)
     parser.add_argument("--seq", type=int, default=4096)
     parser.add_argument(
-        "--band", nargs="+", default=["none"],
+        "--band", nargs="*", default=["none"],
         help="bands to check, each a length or 'none' (the causal mask)",
     )
     parser.add_argument(
@@ -223,6 +236,7 @@ def main(argv=None) -> int:
              "tile other than 512 is tagged .bk<n>",
     )
     parser.add_argument("--conv", type=int, choices=(0, 1), default=1)
+    parser.add_argument("--head-gate", type=int, choices=(0, 1), default=0)
     opts = parser.parse_args(argv)
     shape = (opts.seq, opts.heads, opts.kv_heads, opts.head_dim)
     S, H, KV, D = shape
@@ -329,12 +343,40 @@ def main(argv=None) -> int:
             },
         )
 
+    if opts.head_gate:
+        ctx, dy = (
+            bf(jax.random.normal(x, (B, S, H * D), jnp.float32))
+            for x in keys[4:6]
+        )
+        gate = jax.nn.sigmoid(jax.random.normal(keys[6], (B, S, H)))
+
+        def gated(op):
+            def pair(ctx, gate, dy):
+                out, vjp = jax.vjp(op, ctx, gate)
+                return (out,) + vjp(dy)
+            return jax.jit(pair)
+
+        pair = gated(gate_heads)
+        out, d_ctx, d_gate = pair(ctx, gate, dy)
+        ref_out, ref_d_ctx, ref_d_gate = gated(gate_heads_xla)(ctx, gate, dy)
+        errors["gate.out_bits_differ"] = float(jnp.mean(out != ref_out))
+        errors["gate.d_ctx_bits_differ"] = float(jnp.mean(d_ctx != ref_d_ctx))
+        errors["gate.d_gate"] = rel(d_gate, ref_d_gate)
+        kernels["head_gate"] = device_times(
+            traced_ops(lambda: pair(ctx, gate, dy)), {
+                kernel: (lambda _on_chip, wide=wide, narrow=narrow: (
+                    0.0, B * S * (wide * H * D * 2 + narrow * H * 4)
+                )) for kernel, (wide, narrow) in GATE.items()
+            },
+        )
+
     print(json.dumps({
         "device": jax.devices()[0].device_kind,
         "shape": {"attention": [B, S, H, KV, D], "conv": [B, S, 3 * HIDDEN]},
         "relative_l2": errors, "kernels": kernels,
     }))
-    return 0 if max(errors.values()) <= 0.02 else 1
+    exact = errors.get("gate.out_bits_differ", 0.0) == 0.0
+    return 0 if exact and max(errors.values(), default=0.0) <= 0.02 else 1
 
 
 if __name__ == "__main__":

@@ -14,7 +14,11 @@ equal hashes are equal inputs to the same compilers. PR 40 held the eleven
 programs of the eight older cells to their parent's this way, PR 47 the
 thirteen of the nine (under a ``GroupedQueryAttention`` and a ``RoutedFFN``
 that gained arguments) beside its own two, ``laguna_kernels`` and
-``laguna_accumulate_step``."""
+``laguna_accumulate_step``, PR 48 those thirteen and ``laguna_kernels``
+(under a ``GroupedQueryAttention`` whose gate became a kernel pair and a
+``_pallas_outputs_saveable`` that reads a kernel's name) beside
+``laguna_accumulate_step``, which it changed, and its own
+``head_gate_kernels``."""
 from __future__ import annotations
 
 import hashlib

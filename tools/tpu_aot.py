@@ -18,7 +18,10 @@ expert programs, "expert_grad_passes": ``expert_grad_passes``' counts; for
 the six decoder programs of ``LM_CELLS``, "remat_policy": the layer policy
 they were built under — the model's default, or ``<PREFIX>_REMAT`` from the
 environment, e.g. ``SMALLTHINKER_REMAT=kernel_outputs``: read ``memory``'s
-``temp_bytes`` under both before asking a chip for the stash's room) —
+``temp_bytes`` under both before asking a chip for the stash's room; for
+``laguna_accumulate_step``, "attn_gate_float32_mb": ``scoped_float32_mb`` of
+the per-head gate's scope, and in its ``kernel_calls`` the gate's own pair —
+``head_gate_fwd`` 10 for five layers: the forward is replayed, PR 48) —
 ``flash_windows`` is each flash kernel's lane window beside its column
 block, from the call's metadata (``"block"`` for a call that carries none:
 D=64, D=128; its head counts for a grouped-query call);
@@ -290,6 +293,28 @@ def laguna_kernels(device):
     )
 
 
+def head_gate_kernels(device):
+    """The per-head output gate's kernel pair alone at the Laguna cell's two
+    shapes: (1, 8192, 64 x 128) and (1, 8192, 48 x 128), bf16 context, float32
+    gate; the gated context and, from a cotangent, both gradients."""
+    from dedloc_tpu.ops.head_gate import gate_heads
+
+    def both(ctx, gate, dy):
+        gated, vjp = jax.vjp(gate_heads, ctx, gate)
+        return gated, vjp(dy)
+
+    def pairs(*operands):
+        return [both(*operands[:3]), both(*operands[3:])]
+
+    operands = [
+        jax.ShapeDtypeStruct((1, 8192, heads * width), dtype)
+        for heads in (64, 48)
+        for width, dtype in ((128, jnp.bfloat16), (1, jnp.float32),
+                             (128, jnp.bfloat16))
+    ]
+    return jax.jit(pairs).lower(*_on_device(device, operands))
+
+
 @functools.lru_cache(maxsize=None)
 def _lm_model_and_state(config: str, prefix: str):
     """(args, model, state, ids) of a causal-LM cell's recipe, from its
@@ -521,6 +546,31 @@ def kernel_calls(lowered_text: str) -> dict:
     return {name: names.count(name) for name in sorted(set(names))}
 
 
+def scoped_float32_mb(hlo_text: str, scope: str) -> float:
+    """MB of float32 that the instructions under the op name ``scope`` write
+    outside fused computations, in an optimized HLO module
+    (``compiled.as_text()``; a fusion counts by its result): what an
+    element-wise op between bf16 neighbours costs in HBM when XLA does it
+    in arrays of its own. Laguna's ``attn_gate`` read 7,267 before the gate
+    was a kernel pair (ten float32 ``[1, 8192, 8192 | 6144]`` broadcasts, ten
+    reshapes of them and ten relayout copies of the context's cotangent),
+    9.4 since (PR 48): the gates and their gradients, [1, 8192, 64 | 48]."""
+    total, fused = 0, False
+    for line in hlo_text.splitlines():
+        if not line.startswith(" "):
+            fused = "fused_computation" in line
+        elif not fused and f"/{scope}/" in line:
+            wrote = re.match(
+                r"\s+(?:ROOT )?%[\w.\-]+ = f32\[([\d,]+)\]\S* (?!bitcast|get-tuple-element|parameter)",
+                line,
+            )
+            if wrote:
+                total += 4 * int(np.prod(
+                    [int(d) for d in wrote.group(1).split(",")]
+                ))
+    return round(total / 1e6, 1)
+
+
 def expert_grad_passes(hlo_text: str) -> dict:
     """Passes over a held expert matrix's float32 gradient that move no
     FLOP, in an optimized HLO module (``compiled.as_text()``): ``adds`` —
@@ -610,7 +660,7 @@ def _tile_loops(hlo_text: str, shapes: set) -> dict:
 COUNT_KERNEL_CALLS = {"gqa_kernels", "lfm2_accumulate_step", "band_kernels",
                       "smallthinker_accumulate_step", "bd_kernels",
                       "sdar_accumulate_step", "laguna_kernels",
-                      "laguna_accumulate_step"}
+                      "head_gate_kernels", "laguna_accumulate_step"}
 COUNT_EXPERT_GRAD_PASSES = {"kanana_accumulate_step", "lfm2_accumulate_step",
                             "smallthinker_accumulate_step",
                             "sdar_accumulate_step",
@@ -624,7 +674,7 @@ PROGRAMS = {
         ouro_guarded_apply_step, mla_kernels, kanana_accumulate_step,
         gqa_kernels, lfm2_accumulate_step, band_kernels,
         smallthinker_accumulate_step, bd_kernels, sdar_accumulate_step,
-        laguna_kernels, laguna_accumulate_step,
+        laguna_kernels, laguna_accumulate_step, head_gate_kernels,
     )
 }
 
@@ -662,6 +712,10 @@ def main(argv=None) -> int:
         )
         if name in COUNT_EXPERT_GRAD_PASSES:
             extra["expert_grad_passes"] = expert_grad_passes(compiled_text)
+        if name == "laguna_accumulate_step":
+            extra["attn_gate_float32_mb"] = scoped_float32_mb(
+                compiled_text, "attn_gate"
+            )
         if name in LM_CELLS:
             # the layer policy the program was built under; ``memory`` below
             # is what it costs (``temp_bytes``: the stash is inside it)
