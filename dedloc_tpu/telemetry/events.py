@@ -146,6 +146,8 @@ STATE_SYNC_FAILURES = "state_sync.failures"
 STATE_SYNC_OK = "state_sync.ok"
 STATE_SYNC_RETRIES = "state_sync.retries"
 STATE_SYNC_RETRY = "state_sync.retry"
+STEP_HELD_S = "step.held_s"
+STEP_HOLDS = "step.holds"
 STEP_MFU = "step.mfu"
 STEP_PHASE = "step.phase"
 STEP_PHASE_AVG_WIRE = "step.phase.avg_wire"
@@ -236,6 +238,8 @@ COUNTERS = frozenset({
     "state_sync.failures",
     "state_sync.ok",
     "state_sync.retries",
+    "step.held_s",
+    "step.holds",
     "watch.actuations",
     "watch.rollbacks",
 })

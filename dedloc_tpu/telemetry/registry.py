@@ -61,13 +61,23 @@ def monotonic_clock() -> float:
     return timeutils.monotonic()
 
 
+def scripted_time() -> bool:
+    """True under a FakeClock or a simulator-installed time source: the
+    timeline is scripted, and what the host really did (its CPU, its
+    threads' states) has no place on it."""
+    return (
+        timeutils._dht_time_source is not None
+        or bool(timeutils._dht_time_offset)
+    )
+
+
 def thread_cpu_clock() -> float:
     """CPU seconds the CALLING thread has run (``time.thread_time``). A
     span's wall minus the CPU its thread spent inside it is what the GIL and
     the scheduler took — nothing else in the process can say that. Under a
     FakeClock or a simulator time source it reads 0.0: the host's CPU has no
     place on a scripted timeline."""
-    if timeutils._dht_time_source is not None or timeutils._dht_time_offset:
+    if scripted_time():
         return 0.0
     return time.thread_time()
 

@@ -1,13 +1,11 @@
 """Per-step flight recorder: in-situ hot-path attribution for the trainer.
 
-``tools/profile_albert.py`` answers "where do the cycles go" offline, by
-marginal-cost ablation on an idle chip (docs/perf.md). This module answers
-the *production* form of the question — "where did step N's wall-clock go,
-on this peer, in this run" — by recording every accumulation boundary as a
-TREE of named host spans, and publishing the breakdown through the existing
-telemetry registry (events + histograms + gauges), so the coordinator's
-swarm-health fold and ``runlog_summary --steps`` can rank peers by phase
-skew without attaching a profiler to a volunteer's box.
+"Where did step N's wall-clock go, on this peer, in this run": every
+accumulation boundary is recorded as a TREE of named host spans, and the
+breakdown is published through the existing telemetry registry (events +
+histograms + gauges), so the coordinator's swarm-health fold and
+``runlog_summary --steps`` can rank peers by phase skew without attaching a
+profiler to a volunteer's box.
 
 A record runs from the start of one boundary to the start of the next. Its
 spans (docs/observability.md "Step-phase flight recorder"):
@@ -73,6 +71,23 @@ thread's clock readings (``matchmaking`` / ``allreduce`` / ``ar_*``,
 whole) are in ``spans`` only: they split their parent, they are not this
 thread's time.
 
+HOST COUNTERS ride on every record, read on the record's own thread at its
+start and end: ``cpu_s`` (the thread's CPU seconds over the record), ``sys_s``
+(those of them in the kernel), ``minflt`` / ``majflt`` (page faults) and
+``nivcsw`` (involuntary context switches: the thread was preempted). On a
+scripted timeline they read 0.
+
+The HOLD RECORD (docs/observability.md "Hold record"): a span that runs for
+longer than ``max(HOLD_MIN_S, HOLD_FACTOR x the ninth decile of its name's
+last closed durations)`` is HELD, once a global step has run behind the set-up
+record. The rule is applied when a span closes (so a hold is never missed) and, every ~100 ms, by ONE watcher thread a process to
+the innermost OPEN span of each live record — which, when the rule holds,
+samples the operating system's view of the blocked thread WHILE it is
+blocked: scheduler state, kernel wait channel, system call, the Python call
+site, which other threads of the process were busy, the machine's pressure.
+A hold leaves an entry in the record's ``holds`` and ONE ``held:`` line at
+INFO, telemetry on or off.
+
 The SET-UP RECORD (``setup_record`` / ``lap`` / ``close_setup``) is the same
 machinery pointed at a peer's start: ONE record a peer, from the role's
 entry to the end of its first global step, whose spans are LAPS (each runs
@@ -112,8 +127,13 @@ Design rules, mirroring ``registry.py``:
 from __future__ import annotations
 
 import contextvars
+import os
 import re
+import resource
 import statistics
+import sys
+import threading
+import weakref
 from collections import deque
 from contextlib import contextmanager
 from typing import Any, Deque, Dict, Iterator, List, Optional
@@ -190,14 +210,19 @@ class Span:
     node of its span tree, and outside one just times (``dur_s`` is always
     there for the call site — ``CollaborativeOptimizer.seam_ms`` reads it)."""
 
-    __slots__ = ("name", "t0", "t1", "children_s", "leaf", "_ctx", "_clock",
-                 "_annotation")
+    __slots__ = ("name", "t0", "t1", "children_s", "leaf", "cpu_s",
+                 "excused_s", "hold", "_c0", "_ctx", "_clock", "_annotation")
 
     def __init__(self, name: str, ctx: "Optional[_StepContext]") -> None:
         self.name = name
         self.t0 = self.t1 = 0.0
         self.children_s = 0.0  # what this thread's child spans cover
         self.leaf = True
+        # the thread's CPU seconds inside the span (None: never entered)
+        self.cpu_s: Optional[float] = None
+        self.excused_s = 0.0  # the excess its children's holds reported
+        self.hold: "Optional[_Hold]" = None  # the watcher's samples, if held
+        self._c0 = 0.0
         self._ctx = ctx
         self._clock = ctx._clock if ctx is not None else registry.monotonic_clock
         self._annotation = None
@@ -214,14 +239,19 @@ class Span:
         self._annotation = registry.trace_annotation(self.name)
         if self._annotation is not None:
             self._annotation.__enter__()
-        if self._ctx is not None:
-            self._ctx._stack.append(self)
+        if self._ctx is None:
+            self.t0 = self._clock()
+            return self
+        self._c0 = registry.thread_cpu_clock()
+        # stamped BEFORE it is on the stack: the watcher reads the top's t0
         self.t0 = self._clock()
+        self._ctx._stack.append(self)
         return self
 
     def __exit__(self, *exc) -> None:
         self.t1 = self._clock()
         if self._ctx is not None:
+            self.cpu_s = registry.thread_cpu_clock() - self._c0
             self._ctx._close(self)
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
@@ -232,14 +262,16 @@ class _StepContext:
     per-name self times, plus free-form attrs (``ctx.attrs["stepped"] =
     True``) merged into the final record."""
 
-    __slots__ = ("phases", "totals", "attached", "spans", "attrs", "step",
-                 "boundary", "samples", "_clock", "_start", "_stack",
-                 "_folded")
+    __slots__ = ("phases", "totals", "attached", "spans", "holds", "attrs",
+                 "step", "boundary", "samples", "tid", "ident", "_clock",
+                 "_start", "_stack", "_folded", "_recorder", "_steady",
+                 "_judged", "_counters", "_attached_at", "_watched")
 
     def __init__(self, step: Optional[int], boundary: int, samples: int,
-                 clock) -> None:
+                 clock, recorder: "Optional[StepRecorder]" = None) -> None:
         self.phases: Dict[str, float] = {}  # name -> SELF seconds
         self.totals: Dict[str, float] = {}  # name -> seconds, children in
+        self.holds: List[Dict[str, Any]] = []  # the spans the rule held
         # name -> seconds of the spans ATTACHED from other threads' readings
         self.attached: Dict[str, float] = {}
         # closed spans: [name, parent, t0, t1, leaf] or, folded,
@@ -253,6 +285,29 @@ class _StepContext:
         self._start = clock()
         self._stack: List[Span] = []
         self._folded = False  # the record overflowed MAX_SPANS once
+        # the record's own thread, for the watcher: /proc's id and Python's
+        self.tid = threading.get_native_id()
+        self.ident = threading.get_ident()
+        self._recorder = recorder  # whose hold rule its spans are put to
+        # the rule waits for the set-up record to close: until then the
+        # spans hold compilation and say nothing of what is usual; and the
+        # first global step behind it is not JUDGED (``StepRecorder.step``)
+        self._steady = _SETUP.get() is None
+        self._judged = False
+        self._counters = _thread_counters()
+        self._attached_at: List[tuple] = []  # (name, t0, t1), this clock
+        # (entry of ``holds``, what the watcher saw of it): merged when the
+        # record ends
+        self._watched: List[tuple] = []
+
+    def counters(self) -> Dict[str, Any]:
+        """The thread's CPU seconds (``sys_s``: those in the kernel), page
+        faults and involuntary context switches since the record began."""
+        return {
+            key: after - before for key, before, after in zip(
+                _COUNTER_KEYS, self._counters, _thread_counters(),
+            )
+        }
 
     def elapsed(self) -> float:
         """Seconds since the record began, on the record's clock."""
@@ -291,6 +346,7 @@ class _StepContext:
             parent = self._stack[-1].name
         seconds = max(0.0, t1 - t0) if total_s is None else total_s
         self.attached[name] = self.attached.get(name, 0.0) + seconds
+        self._attached_at.append((name, t0, t1))
         self._record(
             name, parent, t0, t1, True,
             *(() if count is None else (int(count), float(seconds))),
@@ -303,8 +359,11 @@ class _StepContext:
             self._stack.pop()
         parent = self._stack[-1] if self._stack else None
         dur = span.dur_s
+        if self._recorder is not None:
+            self._recorder._span_closed(self, span, parent, dur)
         if parent is not None:
             parent.children_s += dur
+            parent.excused_s += span.excused_s
             parent.leaf = False
         self.phases[span.name] = (
             self.phases.get(span.name, 0.0) + max(0.0, dur - span.children_s)
@@ -660,9 +719,425 @@ def train_log_row(rec: _StepContext) -> Dict[str, Any]:
     return row
 
 
+# ------------------------------------------------------------ the hold record
+#
+# What the operating system says about a thread of THIS process, read from
+# /proc by the one watcher thread while a span overruns and once more when
+# it has closed: never by the span's own thread. But for ``_thread_counters``
+# (a record's start and end) nothing here runs until the hold rule
+# (``StepRecorder``) has found a span held.
+
+_TASKS = "/proc/self/task"
+_PROC = os.path.isdir(_TASKS)
+_TICK_S = 1.0 / os.sysconf("SC_CLK_TCK") if _PROC else 0.01
+_RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", None)
+_HERE = os.sep + "dedloc_tpu" + os.sep
+# a hold's entry names this many busy threads / other Python threads / spans
+# beside it
+BUSY_THREADS = 5
+OTHER_THREADS = 8
+BESIDE_SPANS = 6
+# the line says so when the watcher's reads of /proc took this long
+SLOW_READ_S = 0.05
+# ... and when the watcher went this long without a look while the span was
+# open (its period is a tenth of a second): it was kept out itself
+WATCHER_AWAY_S = 0.25
+
+
+# a record's host counters, in ``_thread_counters``' order
+_COUNTER_KEYS = ("cpu_s", "sys_s", "minflt", "majflt", "nivcsw")
+
+
+def _thread_counters() -> tuple:
+    """(CPU seconds, those of them in the kernel, minor faults, major
+    faults, involuntary context switches) of the CALLING thread so far;
+    zeros on a scripted timeline, as ``registry.thread_cpu_clock`` reads
+    there."""
+    if _RUSAGE_THREAD is None or registry.scripted_time():
+        return (0.0, 0.0, 0, 0, 0)
+    usage = resource.getrusage(_RUSAGE_THREAD)
+    return (
+        registry.thread_cpu_clock(), usage.ru_stime, usage.ru_minflt,
+        usage.ru_majflt, usage.ru_nivcsw,
+    )
+
+
+def _read(path: str) -> str:
+    try:
+        with open(path) as f:
+            return f.read()
+    except OSError:  # the thread ended, or this kernel lacks the file
+        return ""
+
+
+def _task_stat(tid) -> Optional[tuple]:
+    """(comm, scheduler state, CPU seconds, those of them in the kernel) of
+    one thread of this process."""
+    head, _, tail = _read(f"{_TASKS}/{tid}/stat").rpartition(") ")
+    fields = tail.split()
+    if len(fields) < 13:
+        return None
+    user_s, sys_s = int(fields[11]) * _TICK_S, int(fields[12]) * _TICK_S
+    return head.partition("(")[2], fields[0], user_s + sys_s, sys_s
+
+
+def _threads() -> Dict[int, tuple]:
+    """tid -> (name, CPU seconds, those in the kernel) of every thread of
+    this process; a Python thread goes by its ``threading`` name, another
+    by the kernel's comm."""
+    names = {t.native_id: t.name for t in threading.enumerate()}
+    try:
+        tids = os.listdir(_TASKS)
+    except OSError:
+        return {}
+    found = {}
+    for tid in tids:
+        stat = _task_stat(tid)
+        if stat is not None:
+            found[int(tid)] = (names.get(int(tid)) or stat[0], *stat[2:])
+    return found
+
+
+def _machine() -> Dict[str, float]:
+    """The machine's side at one instant: the ``some`` totals of
+    /proc/pressure in seconds (tasks stalled for want of CPU, memory, I/O),
+    this process's page faults and — the one that is no running total — the
+    threads runnable now."""
+    now: Dict[str, float] = {}
+    for kind in ("cpu", "memory", "io"):
+        match = re.search(r"some .*total=(\d+)", _read(f"/proc/pressure/{kind}"))
+        if match:
+            now[f"{kind}_s"] = int(match.group(1)) / 1e6
+    load = _read("/proc/loadavg").split()
+    if len(load) > 3:
+        now["runnable"] = float(load[3].partition("/")[0])
+    usage = resource.getrusage(resource.RUSAGE_SELF)
+    now["minflt"], now["majflt"] = usage.ru_minflt, usage.ru_majflt
+    return now
+
+
+def _where(frame) -> str:
+    code = frame.f_code
+    return f"{os.path.basename(code.co_filename)}:{frame.f_lineno} {code.co_name}"
+
+
+def _site(frame):
+    """The innermost frame inside this package (the call site: which jitted
+    call, which ``device_get``), else the innermost frame."""
+    innermost = frame
+    while frame is not None:
+        if _HERE in frame.f_code.co_filename:
+            return frame
+        frame = frame.f_back
+    return innermost
+
+
+def _frames(frame) -> List[str]:
+    """Where a thread is in Python, from its current frame: its innermost
+    three frames and, when it is none of them, its call site."""
+    found: List[str] = []
+    site = _site(frame)
+    while frame is not None and len(found) < 3:
+        found.append(_where(frame))
+        if frame is site:
+            site = None
+        frame = frame.f_back
+    if site is not None:
+        found.append(_where(site))
+    return found
+
+
+class _Hold:
+    """What the watcher saw of ONE span while it overran. Every read of /proc
+    is the WATCHER's, made outside ``lock`` (which only guards the merge): the
+    span's own thread marks the hold closed at the span's close (``close``)
+    and wakes the watcher, which reads every thread's clock and the
+    machine's counters once more (``finish``). A record that ends before
+    that reading has waits for it (``seen``), FINISH_WAIT_S at most."""
+
+    # past this many samples the watcher looks once a COARSE_S
+    FINE_SAMPLES = 200
+    COARSE_S = 1.0
+    FINISH_WAIT_S = 0.1
+
+    __slots__ = ("lock", "closed", "done", "tid", "samples", "state", "wchan",
+                 "syscall", "frames", "others", "threads", "machine", "busy",
+                 "psi", "read_s", "_watcher", "_since", "_closed_at", "_next")
+
+    def __init__(self, tid: int, ident: int) -> None:
+        self.lock = threading.Lock()
+        self.closed = False
+        self.done = threading.Event()  # the closing reading is in
+        self.tid = tid
+        self.samples = 0
+        self.state: Dict[str, int] = {}
+        self.wchan: Dict[str, int] = {}
+        self.syscall: Dict[str, int] = {}
+        # Python's view, once and first (it asks the kernel nothing): where
+        # the held thread is, and where every other Python thread is while
+        # it is held (the backup thread in its transfer, the DHT loop in a
+        # round)
+        frames = sys._current_frames()
+        skip = (ident, threading.get_ident())  # made on the watcher's thread
+        self.frames = _frames(frames.get(ident))
+        self.others = [
+            [t.name, _where(_site(frames[t.ident]))]
+            for t in threading.enumerate()
+            if t.ident not in skip and t.ident in frames
+        ][:OTHER_THREADS]
+        # every thread's CPU and the machine's counters where the watcher
+        # came (read by the first ``sample``, after the held thread itself)
+        # and what they had moved by when the span closed (``finish``)
+        self.threads: Optional[Dict[int, tuple]] = None
+        self.machine: Dict[str, float] = {}
+        self.busy: Optional[List[list]] = None
+        self.psi: Dict[str, Any] = {}
+        self.read_s = 0.0  # the longest one round of /proc reads took
+        self._watcher = threading.get_native_id()
+        self._since = self._closed_at = self._next = registry.monotonic_clock()
+
+    def sample(self) -> bool:
+        """One look at the held thread; False once its span has closed."""
+        now = registry.monotonic_clock()
+        if self.closed or now < self._next:
+            return not self.closed
+        task = f"{_TASKS}/{self.tid}"
+        stat = _task_stat(self.tid)
+        seen = stat is not None and (
+            (self.state, stat[1]),
+            (self.wchan, _read(f"{task}/wchan").strip()),
+            # the call's number; "running" on a CPU, -1 blocked outside a
+            # call (a page fault)
+            (self.syscall, _read(f"{task}/syscall").partition(" ")[0].strip()),
+        )
+        took = registry.monotonic_clock() - now
+        with self.lock:
+            if self.closed:
+                return False
+            self.read_s = max(self.read_s, took)
+            if seen:
+                self.samples += 1
+                for counts, key in seen:
+                    if key and key != "0":
+                        counts[key] = counts.get(key, 0) + 1
+            self._next = now + (
+                0.0 if self.samples < self.FINE_SAMPLES else self.COARSE_S
+            )
+            if self.threads is not None:
+                return True
+        threads, machine = _threads(), _machine()
+        took = registry.monotonic_clock() - now
+        with self.lock:
+            if not self.closed:
+                self.threads, self.machine = threads, machine
+                self.read_s = max(self.read_s, took)
+        return not self.closed
+
+    def close(self) -> None:
+        """The span's own thread, at the span's close: no read of its own."""
+        with self.lock:
+            self.closed = True
+        self._closed_at = registry.monotonic_clock()
+        _WATCHER.closing(self)
+
+    def finish(self) -> None:
+        """The watcher's thread, woken by ``close``: the threads that used
+        the most CPU since it came, and the machine's deltas."""
+        if self.threads is not None:
+            now = registry.monotonic_clock()
+            busy = []
+            for tid, (name, cpu_s, sys_s) in _threads().items():
+                if tid not in (self.tid, self._watcher):
+                    _, cpu0_s, sys0_s = self.threads.get(tid, (name, 0.0, 0.0))
+                    busy.append((cpu_s - cpu0_s, sys_s - sys0_s, name))
+            busy.sort(reverse=True)
+            after = _machine()
+            psi = {
+                key: round(after[key] - before, 6)
+                for key, before in self.machine.items() if key in after
+            }
+            if "runnable" in psi:
+                psi["runnable"] = [self.machine["runnable"], after["runnable"]]
+            took = registry.monotonic_clock() - now
+            with self.lock:
+                self.read_s = max(self.read_s, took)
+                self.psi = psi
+                self.busy = [
+                    [name, round(cpu_s, 3), round(sys_s, 3)]
+                    for cpu_s, sys_s, name in busy[:BUSY_THREADS] if cpu_s > 0
+                ]
+        self.done.set()
+
+    def seen(self) -> Dict[str, Any]:
+        """What was seen, for the record's entry (the span's own thread, when
+        the record ends): the samples' counts and the closing reading —
+        ``busy_threads`` None where the watcher never got that far."""
+        self.done.wait(self.FINISH_WAIT_S)
+        return {
+            "samples": self.samples,
+            "watched_s": round(self._closed_at - self._since, 6),
+            "read_s": round(self.read_s, 6),
+            "state": self.state, "wchan": self.wchan,
+            "syscall": self.syscall, "frames": self.frames,
+            "others": self.others, "busy_threads": self.busy, "psi": self.psi,
+        }
+
+
+def hold_line(hold: Dict[str, Any]) -> str:
+    """The ``held:`` line of one entry of a record's ``holds``
+    (docs/observability.md "Hold record" has its grammar)."""
+
+    def counts(by_key: Dict[str, int], top: int = 2) -> str:
+        return " ".join(
+            f"{key} {by_key[key]}/{hold['samples']}"
+            for key in sorted(by_key, key=by_key.get, reverse=True)[:top]
+        )
+
+    parts = [
+        f"held: span={hold['span']} {hold['held_s']:.3f} s "
+        f"(usual {hold['usual_s']:.3f}) cpu {hold['cpu_s']:.3f}"
+    ]
+    if hold.get("sampled_in"):
+        parts.append(f"sampled in {hold['sampled_in']}")
+    if "frames" in hold:  # the watcher came while the span was open
+        for key in ("state", "wchan", "syscall"):
+            if hold[key]:
+                parts.append(f"{key} {counts(hold[key])}")
+        if hold["read_s"] >= SLOW_READ_S:
+            parts.append(f"/proc answered in {hold['read_s']:.3f}")
+        if hold["frames"]:
+            parts.append("at " + " < ".join(hold["frames"]))
+        if hold["busy_threads"]:
+            parts.append("busy: " + ", ".join(
+                f"{name} {cpu_s:.2f} s (sys {sys_s:.2f})"
+                for name, cpu_s, sys_s in hold["busy_threads"]
+            ))
+        elif hold["busy_threads"] is None:
+            parts.append("busy: unread")  # the watcher never got that far
+        if hold["others"]:
+            parts.append("others: " + ", ".join(
+                f"{name} in {where}" for name, where in hold["others"]
+            ))
+        psi = hold["psi"]
+        pressure = [
+            f"{key.removesuffix('_s')} {psi[key]:.3f}"
+            for key in ("cpu_s", "memory_s", "io_s") if key in psi
+        ]
+        if pressure:  # a kernel without /proc/pressure has none to give
+            parts.append("psi " + " ".join(pressure) + (
+                f" runnable {psi['runnable'][0]:.0f}>{psi['runnable'][1]:.0f}"
+                if "runnable" in psi else ""
+            ))
+    else:
+        parts.append(f"unsampled ({hold['note']})")
+    if hold.get("watcher_away_s", 0.0) >= WATCHER_AWAY_S:
+        parts.append(f"watcher kept out {hold['watcher_away_s']:.3f} s")
+    if hold.get("beside"):
+        parts.append("beside: " + ", ".join(
+            f"{name} {a0:+.3f}..{a1:+.3f}" for name, a0, a1 in hold["beside"]
+        ))
+    return " ".join(parts)
+
+
+class _Watcher:
+    """ONE daemon thread a process, alive while a ``StepRecorder`` is: every
+    ``WATCH_PERIOD_S`` it puts the hold rule to the innermost open span of
+    each live record (``StepRecorder._look``) and, while one is held,
+    samples it every ``SAMPLE_PERIOD_S``. On a scripted timeline it looks
+    at nothing."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._recorders: "weakref.WeakSet[StepRecorder]" = weakref.WeakSet()
+        self._thread: Optional[threading.Thread] = None
+        self._wake = threading.Event()
+        self._closing: Deque[_Hold] = deque()  # closed, not finished yet
+        self.looked = 0.0  # when the last visit began (monotonic clock)
+        self.gap = (0.0, 0.0)  # the last two looks WATCHER_AWAY_S apart
+
+    def away(self, open_s: float) -> float:
+        """The longest the watcher went WITHOUT a look inside the last
+        ``open_s`` seconds: a period when all is well. More means it was
+        kept out itself — by the interpreter lock (some thread kept it
+        through a C call: no Python thread ran), by a read of /proc that did
+        not return, or because the process was not run at all."""
+        now = registry.monotonic_clock()
+        since = now - open_s
+        gap0, gap1 = self.gap
+        closed = min(gap1, now) - max(gap0, since) if gap1 > since else 0.0
+        return max(closed, now - max(self.looked, since), 0.0)
+
+    def add(self, recorder: "StepRecorder") -> None:
+        with self._lock:
+            self._recorders.add(recorder)
+            if self._thread is None and _PROC:
+                self._thread = threading.Thread(
+                    target=self._run, name="dedloc-hold-watcher", daemon=True,
+                )
+                self._thread.start()
+
+    def closing(self, hold: _Hold) -> None:
+        """A held span has closed (its own thread): the watcher is woken to
+        take the hold's closing reading."""
+        self._closing.append(hold)
+        self._wake.set()
+
+    def remove(self, recorder: "StepRecorder") -> None:
+        """After the last recorder's, the thread has ended."""
+        with self._lock:
+            self._recorders.discard(recorder)
+            thread = None if len(self._recorders) else self._thread
+        if thread is not None:
+            self._wake.set()
+            thread.join(timeout=2.0)
+
+    def _run(self) -> None:
+        self.looked = registry.monotonic_clock()  # no gap before its start
+        period = StepRecorder.WATCH_PERIOD_S
+        while period is not None:
+            self._wake.wait(period)
+            self._wake.clear()
+            # the visit's references die with its frame: a recorder nobody
+            # closed is still collected, and the thread ends after it
+            period = self._visit()
+
+    def _visit(self) -> Optional[float]:
+        """Look at every live record once; the seconds to sleep before the
+        next look, None when the thread is to end."""
+        before, self.looked = self.looked, registry.monotonic_clock()
+        if self.looked - before >= WATCHER_AWAY_S:
+            self.gap = (before, self.looked)
+        with self._lock:
+            recorders = list(self._recorders)
+            if not recorders:
+                self._thread = None
+                return None
+        period = min(r.WATCH_PERIOD_S for r in recorders)
+        if registry.scripted_time():
+            return period
+        try:
+            while self._closing:
+                self._closing.popleft().finish()
+            for recorder in recorders:
+                if recorder._look():
+                    period = min(period, recorder.SAMPLE_PERIOD_S)
+        except Exception:  # noqa: BLE001 — the thread's boundary
+            # the rule still runs at every span's close; the samples are
+            # lost until the next recorder starts a watcher (INFO: a
+            # WARNING is a failed step to whoever counts them)
+            logger.info("hold watcher stopped", exc_info=True)
+            with self._lock:
+                self._thread = None
+            return None
+        return period
+
+
+_WATCHER = _Watcher()
+
+
 class StepRecorder:
     """Bounded ring of per-boundary records + an online MFU gauge + the
-    slow-step notice.
+    hold rule + the slow-step notice.
 
     One recorder per trainer loop. ``model_tflops_per_sample`` and
     ``peak_tflops`` enable the MFU gauge (0 disables it — e.g. CPU smoke
@@ -682,6 +1157,18 @@ class StepRecorder:
     SLOW_FACTOR = 1.5
     SLOW_WINDOW = 32
     SLOW_MIN_STEPS = 4
+    # a SPAN is held when it runs for longer than max(HOLD_MIN_S,
+    # HOLD_FACTOR x the ninth decile of the last SLOW_WINDOW closed spans of
+    # its name), its children's own holds taken out, once HOLD_MIN_SPANS of
+    # its name have closed since the set-up record did; the first GLOBAL
+    # STEP behind the set-up only shows what is usual
+    HOLD_MIN_S = 0.5
+    HOLD_FACTOR = 2.0
+    HOLD_MIN_SPANS = 3
+    # the watcher looks at the open spans this often, and samples a held one
+    # this often
+    WATCH_PERIOD_S = 0.1
+    SAMPLE_PERIOD_S = 0.05
 
     def __init__(
         self,
@@ -706,7 +1193,16 @@ class StepRecorder:
         self._step_wall = 0.0
         self._step_totals: Dict[str, float] = {}
         self._step_attached: Dict[str, float] = {}
+        self._step_holds: List[Dict[str, Any]] = []
         self._recent_steps: Deque[tuple] = deque(maxlen=self.SLOW_WINDOW)
+        # span name -> its last SLOW_WINDOW closed durations (children in)
+        self._durations: Dict[str, Deque[float]] = {}
+        self._live: Optional[_StepContext] = None  # the watcher reads it
+        # a whole global step has run behind the set-up: what happens once a
+        # step (the first enqueue behind an apply that finds the runtime
+        # full) has been seen once, and the rule begins
+        self._learned = False
+        _WATCHER.add(self)
 
     @contextmanager
     def step(
@@ -723,14 +1219,18 @@ class StepRecorder:
         ctx = _StepContext(
             step, boundary, samples,
             tele.clock if tele is not None else registry.monotonic_clock,
+            recorder=self,
         )
         annotation = registry.trace_annotation("boundary", step_num=boundary)
         if annotation is not None:
             annotation.__enter__()
+        ctx._judged = self._learned and ctx._steady
         token = _CURRENT.set(ctx)
+        self._live = ctx
         try:
             yield ctx
         finally:
+            self._live = None
             _CURRENT.reset(token)
             wall = ctx.elapsed()
             if annotation is not None:
@@ -738,9 +1238,117 @@ class StepRecorder:
             self._finish(tele, ctx, wall)
 
     def close(self) -> None:
-        """End of the loop: a profiler window still open is closed."""
+        """End of the loop: a profiler window still open is closed, and the
+        watcher lets go of this recorder (it ends with the last one)."""
+        _WATCHER.remove(self)
         if self.profile is not None:
             self.profile.close()
+
+    # -------------------------------------------------------- the hold rule
+
+    def _usual(self, name: str) -> Optional[float]:
+        """What the closed spans called ``name`` usually stay under — the
+        ninth decile of their durations, so that BOTH lengths of a span
+        that has two are usual (Ouro's ``fwd_bwd`` enqueues in 2 ms when the
+        runtime has room and in 540 ms when it has not, turn about: its
+        median flips between the two, and behind an apply the short one has
+        three in four) — None until HOLD_MIN_SPANS of them have closed."""
+        history = self._durations.get(name)
+        if history is None or len(history) < self.HOLD_MIN_SPANS:
+            return None
+        try:
+            return statistics.quantiles(history, n=10, method="inclusive")[-1]
+        except RuntimeError:  # the watcher's read met the span's thread's append
+            return None
+
+    def _held(self, name: str, seconds: float) -> Optional[float]:
+        """The usual duration of ``name`` if ``seconds`` of it (its
+        children's holds out) is a hold, else None."""
+        if seconds <= self.HOLD_MIN_S:
+            return None
+        usual = self._usual(name)
+        if usual is None or seconds <= self.HOLD_FACTOR * usual:
+            return None
+        return usual
+
+    def _look(self) -> bool:
+        """The watcher's visit, on ITS thread: put the rule to the innermost
+        open span of the live record and sample it while it is held. Reads
+        the top of a list and a span's fields: nothing a span's own thread
+        waits for. True while a hold is being sampled."""
+        ctx = self._live
+        if ctx is None or not ctx._judged:
+            return False
+        try:
+            top = ctx._stack[-1]
+        except IndexError:
+            return False
+        hold = top.hold
+        if hold is None:
+            open_s = ctx._clock() - top.t0 - top.excused_s
+            if self._held(top.name, open_s) is None:
+                return False
+            hold = top.hold = _Hold(ctx.tid, ctx.ident)
+        return hold.sample()
+
+    def _span_closed(
+        self, ctx: _StepContext, span: Span, parent: Optional[Span],
+        dur: float,
+    ) -> None:
+        """The rule where no hold is missed: at a span's close, on its own
+        thread (an ``add``-ed span too). A held span is an entry of the
+        record's ``holds``, with what the watcher sampled of it if it came
+        in time; its excess is excused from every span around it, so the
+        entries' ``excess_s`` never count a second twice."""
+        own = dur - span.excused_s
+        usual = self._held(span.name, own) if ctx._judged else None
+        if usual is not None:
+            entry = {
+                "span": span.name,
+                "parent": parent.name if parent is not None else None,
+                "t0_s": round(span.t0 - ctx._start, 6),
+                "held_s": round(dur, 6), "usual_s": round(usual, 6),
+                "excess_s": round(own - usual, 6),
+                "cpu_s": round(span.cpu_s or 0.0, 6), "samples": 0,
+            }
+            if registry.scripted_time():
+                entry["note"] = "scripted clock"
+            elif not _PROC:
+                entry["note"] = "no /proc"
+            else:
+                entry["watcher_away_s"] = round(_WATCHER.away(dur), 3)
+                if span.hold is not None:
+                    span.hold.close()
+                    ctx._watched.append((entry, span.hold))
+                else:
+                    # the watcher never got to it: an ``add``-ed span, a
+                    # close between two looks — or it was kept out itself:
+                    # ``watcher_away_s`` says which
+                    entry["note"] = "not watched"
+            ctx.holds.append(entry)
+            span.excused_s += own - usual
+        elif span.hold is not None:
+            # the watcher held this span for what a CHILD's close has since
+            # explained (an ``add``-ed child is never open: ``d2h_stream``
+            # inside ``avg_wire``): the samples are that child's
+            for entry in reversed(ctx.holds):
+                if (
+                    entry["parent"] == span.name
+                    and entry.get("note") == "not watched"
+                ):
+                    del entry["note"]
+                    entry["sampled_in"] = span.name
+                    span.hold.close()
+                    ctx._watched.append((entry, span.hold))
+                    break
+        if not ctx._steady:
+            return  # a span of the set-up holds compilation: no usual length
+        history = self._durations.get(span.name)
+        if history is None:
+            history = self._durations[span.name] = deque(
+                maxlen=self.SLOW_WINDOW
+            )
+        history.append(dur)
 
     # ------------------------------------------------------------- internal
 
@@ -750,6 +1358,8 @@ class StepRecorder:
     ) -> None:
         phases = dict(ctx.phases)
         untimed = max(0.0, wall - sum(phases.values()))
+        for entry, hold in ctx._watched:
+            entry.update(hold.seen())
         record: Dict[str, Any] = {
             "step": ctx.step,
             "boundary": ctx.boundary,
@@ -758,8 +1368,30 @@ class StepRecorder:
             "phases": phases,
             "untimed_s": untimed,
             "spans": ctx.finished_spans(),
+            **ctx.counters(),
+            "holds": ctx.holds,
+            "held_excess_s": sum(h["excess_s"] for h in ctx.holds),
             **ctx.attrs,
         }
+        for hold in ctx.holds:
+            # the attached spans that overlap it, each with where it began
+            # and ended against the hold's START (a ``backup_transfer`` that
+            # ends with the hold: the hold is the transfer's last act)
+            t0 = ctx._start + hold["t0_s"]
+            beside: Dict[str, list] = {}
+            for name, a0, a1 in ctx._attached_at:
+                if a0 < t0 + hold["held_s"] and a1 > t0:
+                    beside.setdefault(
+                        name, [name, round(a0 - t0, 3), round(a1 - t0, 3)]
+                    )
+            hold["beside"] = list(beside.values())[:BESIDE_SPANS]
+            logger.info(
+                hold_line(hold) + f" | step {ctx.step} boundary {ctx.boundary}"
+                f": {wall:.3f} s, cpu {record['cpu_s']:.3f} of them"
+                f" {record['sys_s']:.3f} in the kernel, faults"
+                f" {record['minflt']}+{record['majflt']}, preempted"
+                f" {record['nivcsw']}"
+            )
         dominant = max(phases, key=phases.get) if phases else None
         if dominant is not None:
             record["dominant"] = dominant
@@ -767,6 +1399,9 @@ class StepRecorder:
         if mfu is not None:
             record["mfu"] = mfu
         self.records.append(record)
+        if ctx._steady and record.get("stepped"):
+            self._learned = True
+        self._step_holds.extend(ctx.holds)
         self._notice_slow_step(record, ctx.totals, ctx.attached)
         if self.perf is not None:
             self.perf.metric("boundary").update(wall)
@@ -775,6 +1410,9 @@ class StepRecorder:
         if tele is None:
             return
         tele.histogram("step.wall").observe(wall)
+        for hold in ctx.holds:
+            tele.counter("step.holds").inc()
+            tele.counter("step.held_s").inc(hold["excess_s"])
         for name, dur in phases.items():
             tele.histogram(f"step.phase.{name}").observe(dur)
             tele.event("step.phase", phase=name, dur_s=dur, step=ctx.step)
@@ -799,10 +1437,12 @@ class StepRecorder:
                 into[name] = into.get(name, 0.0) + seconds
         if not record.get("stepped"):
             return
-        wall, spans, beside = (
-            self._step_wall, self._step_totals, self._step_attached
+        wall, spans, beside, holds = (
+            self._step_wall, self._step_totals, self._step_attached,
+            self._step_holds,
         )
         self._step_wall, self._step_totals, self._step_attached = 0.0, {}, {}
+        self._step_holds = []
         recent = list(self._recent_steps)
         self._recent_steps.append((wall, spans, beside))
         if len(recent) < self.SLOW_MIN_STEPS:
@@ -830,6 +1470,10 @@ class StepRecorder:
             "(s, against their median): " + over_usual(spans, 1, 6) + (
                 "; on other threads beside it: " + over_usual(beside, 2, 4)
                 if beside else ""
+            ) + (
+                "; held: " + ", ".join(
+                    f"{h['span']} {h['excess_s']:+.3f}" for h in holds
+                ) if holds else ""
             )
         )
 

@@ -262,6 +262,45 @@ def test_runlog_steps_waterfall_skew_and_overlap(tmp_path, capsys):
     assert "overall overlap efficiency" in out
 
 
+@pytest.mark.parametrize("as_json", [False, True])
+def test_runlog_steps_shows_the_holds_off_the_same_records(
+    tmp_path, capsys, as_json
+):
+    """The recorder's own events through ``--steps``: a peer's header counts
+    its holds, and each is a line with what the record says of it."""
+    tele = Telemetry(peer="held")
+    rec = StepRecorder(telemetry=tele)
+    try:
+        with FakeClock(frozen=True) as clock:
+            for step, wire in enumerate([0.25] * 4 + [2.25]):
+                with rec.step(step=step) as record:
+                    record.attrs["stepped"] = True
+                    with steps.phase("avg_wire"):
+                        clock.advance(wire)
+    finally:
+        rec.close()
+    rows = [e for e in tele.events if e["event"].startswith("step.")]
+    rows += [_step_record("clean", i, {"avg_wire": 0.25}, t=float(i))
+             for i in range(3)]  # an older program's records: no counters
+    path = _write_jsonl(tmp_path, "held.jsonl", rows)
+    runlog_summary.main((["--json"] if as_json else []) + ["--steps", path])
+    out = capsys.readouterr().out
+    if as_json:
+        doc = json.loads(out)["per_peer"]
+        (hold,) = doc["held"]["holds"]
+        assert hold["span"] == "avg_wire" and hold["step"] == 4
+        assert doc["held"]["held_s"] == pytest.approx(2.0)
+        assert doc["clean"]["holds"] == [] and doc["clean"]["held_s"] == 0
+        return
+    header = next(l for l in out.splitlines() if l.startswith("peer held"))
+    assert "holds=1 (2.000s over their usual)" in header
+    assert "holds=0" in next(
+        l for l in out.splitlines() if l.startswith("peer clean")
+    )
+    (line,) = [l for l in out.splitlines() if l.startswith("  held step")]
+    assert "held step 4: avg_wire 2.250s (usual 0.250)" in line
+
+
 def test_runlog_steps_survives_jammed_and_truncated_logs(tmp_path, capsys):
     rows = [_step_record("p0", 0, {"data_wait": 0.5, "fwd_bwd": 0.1})]
     jammed = (

@@ -963,7 +963,10 @@ def _phase_order(names):
 
 def _steps_from_events(rows):
     """{peer: {"steps": n, "wall": mean_s|None, "untimed": mean_s|None,
-    "phases": {name: mean_s}}} from step.record events. Per-PEER fallback:
+    "phases": {name: mean_s}, "holds": [...], "held_s": total}} from
+    step.record events (``holds``: the records' hold entries, each with
+    its record's ``step``; ``held_s``: the sum of ``held_excess_s``).
+    Per-PEER fallback:
     a peer whose step.record rows were lost (truncated/jammed log — the
     churn these views debug) is rebuilt from its bare step.phase events
     (phase means only, no wall/untimed) instead of silently vanishing
@@ -974,11 +977,17 @@ def _steps_from_events(rows):
             continue
         acc = per_peer.setdefault(
             r.get("peer", "?"),
-            {"steps": 0, "wall": 0.0, "untimed": 0.0, "phases": {}},
+            {"steps": 0, "wall": 0.0, "untimed": 0.0, "phases": {},
+             "holds": [], "held_s": 0.0},
         )
         acc["steps"] += 1
         acc["wall"] += float(r.get("dur_s", 0.0))
         acc["untimed"] += float(r.get("untimed_s", 0.0))
+        acc["held_s"] += float(r.get("held_excess_s") or 0.0)
+        acc["holds"] += [
+            {"step": r.get("step"), **h} for h in r.get("holds") or ()
+            if isinstance(h, dict)
+        ]
         phases = r.get("phases") or {}
         for name, dur in phases.items():
             try:
@@ -1230,6 +1239,10 @@ def print_steps(all_rows):
             header += f"  dominant {dominant} ({share * 100.0:.0f}%)"
         if acc.get("mfu") is not None:
             header += f"  mfu {acc['mfu']:.3f}"
+        if acc.get("holds") is not None:
+            header += f"  holds={len(acc['holds'])}"
+            if acc["holds"]:
+                header += f" ({acc['held_s']:.3f}s over their usual)"
         print(header)
         full = wall if wall is not None else total
         for name in _phase_order(phases):
@@ -1239,6 +1252,24 @@ def print_steps(all_rows):
             covered = 100.0 * (wall - acc["untimed"]) / wall
             print(f"  {'(untimed)':<14} {acc['untimed']:9.3f}s  "
                   f"phase coverage {covered:.1f}% of wall")
+        # the hold record: spans that overran, with what the operating
+        # system said of the blocked thread (docs/observability.md)
+        for hold in acc.get("holds") or ():
+            state, wchan, syscall = (
+                max(hold[key], key=hold[key].get) if hold.get(key) else "-"
+                for key in ("state", "wchan", "syscall")
+            )
+            print(
+                f"  held step {hold.get('step')}: {hold.get('span')} "
+                f"{hold.get('held_s', 0.0):.3f}s (usual "
+                f"{hold.get('usual_s', 0.0):.3f}) cpu "
+                f"{hold.get('cpu_s', 0.0):.3f} state {state} wchan "
+                f"{wchan} syscall {syscall}"
+                + (f" busy {hold['busy_threads'][0][0]}"
+                   if hold.get("busy_threads") else "")
+                + (f" beside {','.join(b[0] for b in hold['beside'])}"
+                   if hold.get("beside") else "")
+            )
 
     # phase skew: for every phase, the peer furthest above the swarm median
     # — the cross-peer "who is slow and WHY" ranking (DeDLOC heterogeneous
