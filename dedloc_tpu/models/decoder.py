@@ -49,6 +49,11 @@ BIAS = "e_score_correction_bias"  # the leaf the sign rule steps
 # the collection GRAD_SINKS beside ``params``, the same names and stacking
 EXPERT_LEAVES = ("experts_gate", "experts_up", "experts_down")
 GRAD_SINKS = "grad_sinks"
+# the same leaves ALREADY in the compute dtype, a third collection of the same
+# names and stacking: what ``parallel/train_step.make_accumulate_step`` casts
+# once per set of weights where every micro-batch's forward and remat replay
+# cast them again (``held_expert_ffn``)
+COMPUTE_COPIES = "compute_copies"
 
 
 def named_config(model_size: str, ctors: Dict[str, Callable]) -> Callable:
@@ -362,7 +367,21 @@ def held_expert_ffn(module: nn.Module, tokens, choice, weights,
     the three ``EXPERT_LEAVES`` created in ``module``'s scope (call it inside
     a compact method) and, where the apply carries the collection
     ``GRAD_SINKS``, this layer's three buffers handed to the tile loop's
-    backward. ``tokens`` [T, H]; returns (y [T, H] float32, counts)."""
+    backward. ``tokens`` [T, H]; returns (y [T, H] float32, counts —
+    ``routed_experts``' stats and ``compute_copy_leaves``, 3 or 0).
+
+    Who makes the matrices the loop reads in the compute dtype: where the
+    apply carries the collection ``COMPUTE_COPIES`` (a ``GradSinkLoss`` on one
+    device: the accumulate step casts the marked leaves ONCE per set of
+    weights and hands the result in), the loop reads those three arrays and
+    this function casts nothing; anywhere else — a mesh, evaluation, a
+    float32 reference — the float32 leaves are cast here, whole, in the
+    forward and again in a remat replay. The two are the same ``astype`` of
+    the same weights. The copies exist exactly where the sinks do, where the
+    cast's cotangent is zero anyway (``parallel/moe._grouped_swiglu_bwd``
+    leaves the sums in the sinks and hands the matrices ``zeros_like``): no
+    gradient ever flowed back through the cast under sinks, so taking it out
+    of the differentiated function changes no gradient's arithmetic."""
     cfg = module.cfg
     H, F = tokens.shape[-1], cfg.moe_intermediate_size
     first, held = cfg.held_experts
@@ -373,13 +392,23 @@ def held_expert_ffn(module: nn.Module, tokens, choice, weights,
             EXPERT_LEAVES, ((held, H, F), (held, H, F), (held, F, H))
         )
     )
-    sinks = tuple(
-        module.get_variable(GRAD_SINKS, name) for name in EXPERT_LEAVES
-    ) if module.has_variable(GRAD_SINKS, EXPERT_LEAVES[0]) else None
-    return routed_experts(
-        tokens, choice, weights, gate.astype(cfg.dtype),
-        up.astype(cfg.dtype), down.astype(cfg.dtype), (first, held),
-        tile=cfg.moe_row_tile, grad_sinks=sinks, activation=activation,
+
+    def beside(collection):  # this layer's three arrays of it, or None
+        if not module.has_variable(collection, EXPERT_LEAVES[0]):
+            return None
+        return tuple(
+            module.get_variable(collection, name) for name in EXPERT_LEAVES
+        )
+
+    sinks, copies = beside(GRAD_SINKS), beside(COMPUTE_COPIES)
+    y, counts = routed_experts(
+        tokens, choice, weights,
+        *(copies or (w.astype(cfg.dtype) for w in (gate, up, down))),
+        (first, held), tile=cfg.moe_row_tile, grad_sinks=sinks,
+        activation=activation,
+    )
+    return y, dict(
+        counts, compute_copy_leaves=jnp.float32(len(copies or ())),
     )
 
 
@@ -506,10 +535,10 @@ class ScannedBlock(nn.Module):
 
 def scan_layers(body, length: int):
     """``body`` under ``nn.scan``, ``length`` steps: carry = hidden; rope
-    broadcast; every parameter (and gradient sink) of a step stacked on
-    axis 0."""
+    broadcast; every parameter (and gradient sink, and compute-dtype copy)
+    of a step stacked on axis 0."""
     return nn.scan(
-        body, variable_axes={"params": 0, GRAD_SINKS: 0},
+        body, variable_axes={"params": 0, GRAD_SINKS: 0, COMPUTE_COPIES: 0},
         split_rngs={"params": True}, in_axes=nn.broadcast, length=length,
     )
 
@@ -566,13 +595,18 @@ def chunked_cross_entropy(hiddens, lm_head, labels, chunk_tokens: int):
     return jax.lax.map(lambda hy: one(*hy), (h, y)).reshape(T, N)
 
 
-def apply_with_grad_sinks(model, params, input_ids, grad_sinks):
+def apply_with_grad_sinks(model, params, input_ids, grad_sinks,
+                          compute_copies=None):
     """``model.apply`` on ``params``, with ``grad_sinks`` (None, or the
     subtree of a float32 gradient accumulator that ``routed_grad_sink_mask``
-    marks) riding beside them as the collection ``GRAD_SINKS``."""
+    marks) riding beside them as the collection ``GRAD_SINKS`` and
+    ``compute_copies`` (None, or the same subtree of ``params`` in the
+    compute dtype) as ``COMPUTE_COPIES``."""
     variables = {"params": params}
     if grad_sinks is not None:
         variables[GRAD_SINKS] = grad_sinks
+    if compute_copies is not None:
+        variables[COMPUTE_COPIES] = compute_copies
     return model.apply(variables, input_ids)
 
 
@@ -594,23 +628,26 @@ def routed_metrics(routing, params, gauges: Dict[str, Callable]):
         **{name: gauge(params, routing) for name, gauge in gauges.items()},
         "moe.dropped_slots": jnp.sum(routing["dropped_slots"]),
         "moe.grad_sink_leaves": jnp.sum(routing["grad_sink_leaves"]),
+        "moe.compute_copy_leaves": jnp.sum(routing["compute_copy_leaves"]),
         "moe.choice": routing["choice"],
         "moe.scores": routing["scores"],
     }
 
 
 def expert_lm_loss(model, params, batch: Dict[str, jnp.ndarray], grad_sinks,
-                   head: Callable, gauges: Dict[str, Callable]):
+                   head: Callable, gauges: Dict[str, Callable],
+                   compute_copies=None):
     """(loss, metrics) of one micro-batch of an expert decoder under the
     plain next-token objective: ``input_ids`` and next-token ``labels``,
     [B, S] each, no padding; the mean cross-entropy under ``head(params)``
     ([H, V], in the compute dtype) a chunk of tokens at a time, beside
-    ``routed_metrics``. ``grad_sinks``: ``apply_with_grad_sinks``'s;
-    differentiated with respect to them too, their cotangent is ``sink +
-    gradient`` of the leaf of that name, whose own gradient is then zero."""
+    ``routed_metrics``. ``grad_sinks``, ``compute_copies``:
+    ``apply_with_grad_sinks``'s; differentiated with respect to the sinks
+    too, their cotangent is ``sink + gradient`` of the leaf of that name,
+    whose own gradient is then zero."""
     cfg = model.cfg
     hidden, routing = apply_with_grad_sinks(
-        model, params, batch["input_ids"], grad_sinks
+        model, params, batch["input_ids"], grad_sinks, compute_copies
     )
     ce = chunked_cross_entropy(
         hidden.reshape(1, -1, cfg.hidden_size), head(params),

@@ -247,11 +247,12 @@ class DeepseekV3ForCausalLM(nn.Module):
 
 
 def deepseek_v3_loss(model: DeepseekV3ForCausalLM, params,
-                     batch: Dict[str, jnp.ndarray], grad_sinks=None):
+                     batch: Dict[str, jnp.ndarray], grad_sinks=None,
+                     compute_copies=None):
     """``decoder.expert_lm_loss`` under the untied head, with the largest
     bias magnitude as a gauge."""
     return expert_lm_loss(
-        model, params, batch, grad_sinks,
+        model, params, batch, grad_sinks, compute_copies=compute_copies,
         head=lambda p: p["lm_head"].astype(model.cfg.dtype),
         gauges={"moe.bias_abs_max": lambda p, _r: jnp.max(jnp.abs(
             p["layers"]["block"]["mlp"][BIAS]

@@ -343,7 +343,8 @@ def band_visible_share(cfg: LagunaConfig, seq: int) -> float:
 
 
 def laguna_loss(model: LagunaForCausalLM, params,
-                batch: Dict[str, jnp.ndarray], grad_sinks=None):
+                batch: Dict[str, jnp.ndarray], grad_sinks=None,
+                compute_copies=None):
     """``decoder.expert_lm_loss`` under the untied head (no bias to
     report), with the sliding layers' two tile shares and each attention
     kind's mean gate as gauges."""
@@ -358,7 +359,7 @@ def laguna_loss(model: LagunaForCausalLM, params,
         for kind in (FULL, SLIDING)
     }
     return expert_lm_loss(
-        model, params, batch, grad_sinks,
+        model, params, batch, grad_sinks, compute_copies=compute_copies,
         head=lambda p: p["lm_head"].astype(cfg.dtype),
         gauges={
             **{name: lambda _p, _r, share=share: jnp.float32(share)

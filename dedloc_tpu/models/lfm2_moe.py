@@ -263,11 +263,12 @@ class Lfm2MoeForCausalLM(nn.Module):
 
 
 def lfm2_moe_loss(model: Lfm2MoeForCausalLM, params,
-                  batch: Dict[str, jnp.ndarray], grad_sinks=None):
+                  batch: Dict[str, jnp.ndarray], grad_sinks=None,
+                  compute_copies=None):
     """``decoder.expert_lm_loss`` under the TIED head, with the largest bias
     magnitude of any layer as a gauge."""
     return expert_lm_loss(
-        model, params, batch, grad_sinks,
+        model, params, batch, grad_sinks, compute_copies=compute_copies,
         head=lambda p: p["embed_tokens"].astype(model.cfg.dtype).T,
         gauges={"moe.bias_abs_max": lambda p, _r: jnp.max(jnp.stack([
             jnp.max(jnp.abs(leaf))

@@ -226,18 +226,21 @@ def bd_tile_share(cfg: SdarMoeConfig, seq: int) -> float:
 
 
 def sdar_moe_loss(model: SdarMoeForDiffusionLM, params,
-                  batch: Dict[str, jnp.ndarray], grad_sinks=None):
+                  batch: Dict[str, jnp.ndarray], grad_sinks=None,
+                  compute_copies=None):
     """(loss, metrics) of one micro-batch of ``data/block_diffusion.py``:
     ``input_ids`` (x~), ``labels`` (x) and ``loss_weights`` (w), [B, L]
     each. The routed metrics are ``decoder.routed_metrics``' (``moe.scores``
     holds the router's LOGITS, over both streams; no bias to report) and
-    ``grad_sinks`` ``decoder.expert_lm_loss``'s; beside them the masked
+    ``grad_sinks`` and ``compute_copies`` ``decoder.expert_lm_loss``'s;
+    beside them the masked
     positions' share and count, and ``attn.bd_tile_share``."""
     cfg = model.cfg
     clean, weights = batch["labels"], batch["loss_weights"]
     hidden, routing = apply_with_grad_sinks(
         model, params,
         jnp.concatenate([batch["input_ids"], clean], axis=1), grad_sinks,
+        compute_copies,
     )
     ce = chunked_cross_entropy(
         hidden.reshape(1, -1, cfg.hidden_size),

@@ -230,13 +230,14 @@ def band_tile_share(cfg: SmallThinkerConfig, seq: int) -> float:
 
 
 def smallthinker_loss(model: SmallThinkerForCausalLM, params,
-                      batch: Dict[str, jnp.ndarray], grad_sinks=None):
+                      batch: Dict[str, jnp.ndarray], grad_sinks=None,
+                      compute_copies=None):
     """``decoder.expert_lm_loss`` under the untied head (``moe.scores`` holds
     the router's LOGITS; no bias to report), with ``attn.band_tile_share``
     as a gauge."""
     share = band_tile_share(model.cfg, batch["input_ids"].shape[1])
     return expert_lm_loss(
-        model, params, batch, grad_sinks,
+        model, params, batch, grad_sinks, compute_copies=compute_copies,
         head=lambda p: p["lm_head"].astype(model.cfg.dtype),
         gauges={"attn.band_tile_share": lambda _p, _r: jnp.float32(share)},
     )

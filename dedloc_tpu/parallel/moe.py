@@ -47,7 +47,14 @@ is the sinks' cotangent — ``sink + d`` in float32, never rounded to the
 compute dtype — and the matrices' own cotangent is zero. An accumulator that
 adds a gradient into a running sum anyway (``parallel/train_step.
 make_accumulate_step``) takes the sink's cotangent as its new leaf: no zero
-fill, no second pass over a held matrix to add it.
+fill, no second pass over a held matrix to add it. The loops read the held
+matrices in the COMPUTE dtype and never cast: the caller hands them over so
+(``models/decoder.held_expert_ffn``). Under sinks the matrices' own cotangent
+is zero, so whatever cast made them has no backward to run — which is why the
+accumulate step may make them ONCE per set of weights, outside the function
+it differentiates, and feed them in as an input (``train_step.
+_StepWithComputeCopies``; 2 x 2.2 ms of whole-matrix float32 -> bf16 passes a
+micro-batch in the LFM2 cell, forward and remat replay, before PR 50).
 
 **The walk: runs of tiles, then tails.** Rows sorted by expert lie in RUNS,
 and an iteration pays some costs whatever its rows: the expert's three bf16
@@ -556,8 +563,10 @@ def routed_experts(x, choice, weights, gate, up, down,
     ``x`` [T, H] in the compute dtype; ``choice`` / ``weights`` [T, k] from
     ``route_top_k`` or ``route_top_k_softmax``; ``gate`` / ``up`` [n, H, F]
     and ``down`` [n, F, H] the
-    HELD experts' matrices in the compute dtype, expert ``held[0] + i`` at
-    index i; ``grad_sinks``: None, or three float32 buffers of the held
+    HELD experts' matrices ALREADY in the compute dtype (the layer's casts
+    of its float32 leaves or, on one device under sinks, the accumulate
+    step's copies of them, rebuilt when the weights change), expert
+    ``held[0] + i`` at index i; ``grad_sinks``: None, or three float32 buffers of the held
     matrices' shapes whose COTANGENT is ``sink + d matrix`` while the
     matrices' own is zero (the module docstring says who wants that);
     ``run_tiles``: consecutive tiles of one expert a bulk iteration of the

@@ -63,13 +63,21 @@ class GradSinkLoss:
     accumulator's marked leaves as ``grad_sinks`` (a nested dict, branches
     without one dropped) and differentiated with respect to them too, the
     sinks' cotangent is ``sink + gradient`` and the marked parameters' own
-    gradient zero. Called without them it is any other loss."""
+    gradient zero. Called without them it is any other loss.
+
+    ``compute_dtype`` (the model's, ``cfg.dtype``; None: the loss takes no
+    copies): the loss also takes ``compute_copies``, the marked leaves of
+    ``params`` already cast to it, and then casts none of them itself — the
+    marked parameters' gradient is zero under sinks, so nothing flows back
+    through that cast and it need not be part of what is differentiated."""
 
     loss: Callable
     sink_mask: Callable
+    compute_dtype: Optional[Any] = None
 
-    def __call__(self, params, batch, rng, grad_sinks=None):
-        return self.loss(params, batch, rng, grad_sinks=grad_sinks)
+    def __call__(self, params, batch, rng, **beside):
+        # ``grad_sinks=``, ``compute_copies=``: whichever the step hands over
+        return self.loss(params, batch, rng, **beside)
 
 
 def zeros_like_grads(params):
@@ -174,19 +182,33 @@ def make_accumulate_step(
     A marked leaf that no module read would come back zero: tracing the step
     raises on one.
 
+    Where that loss states its ``compute_dtype``, the marked leaves of
+    ``params`` in that dtype are an INPUT of the device program, not a value
+    every micro-batch's forward and remat replay recompute: what is returned
+    (``_StepWithComputeCopies``, the same call, ``.lower`` and ``.gauges``)
+    owns them, casts them in a jitted program of its own
+    (``expert_compute_copies``) when ``params``' marked leaves are not the
+    arrays it cast last — once a global step: an apply, a NaN rollback and a
+    state download all yield new arrays, nothing else does — and runs the
+    six-argument ``accumulate_step`` on them. Any other loss, and any loss on
+    a mesh, builds the five-argument program it always did.
+
     ``step.gauges`` (a dict, filled when the step is traced): ``remat.
     kept_bytes`` — the bytes one micro-batch's forward hands its backward
     besides the step's arguments (``stash_bytes``: the mechanism's counter
     of the model's layer remat policy), read off the trace itself.
+    ``step.counters`` (a dict of running totals; empty without copies):
+    ``moe.compute_copy_builds`` — runs of the cast program.
     """
     sink_mask = getattr(loss_fn, "sink_mask", None) if mesh is None else None
+    copy_dtype = None if sink_mask is None else getattr(
+        loss_fn, "compute_dtype", None
+    )
     # host-side readings of the step's last TRACE (no output of the program):
     # on the returned step as ``step.gauges``, empty until it is traced
     gauges: Dict[str, float] = {}
 
-    # jitted programs carry stable names: a trace, an IR dump or a compile
-    # event finds "accumulate_step" after any refactor
-    def accumulate_step(params, grad_acc, n_acc, batch, rng):
+    def accumulate(params, copies, grad_acc, n_acc, batch, rng):
         if mesh is not None and seq_axis is not None:
             def _constrain(x):
                 if x.ndim >= 2 and seq_length and x.shape[1] == seq_length:
@@ -208,15 +230,17 @@ def make_accumulate_step(
         def loss(params, sinks):
             if not sinks:
                 return loss_fn(params, batch, rng)
-            return loss_fn(
-                params, batch, rng,
-                grad_sinks=_noting_cotangents(sinks, reached),
-            )
+            beside = {"grad_sinks": _noting_cotangents(sinks, reached)}
+            if copies is not None:
+                beside["compute_copies"] = copies
+            return loss_fn(params, batch, rng, **beside)
 
         # ``jax.value_and_grad`` in its two halves (the same program), so
         # that the trace can read what the forward keeps for the backward
         value, backward, metrics = jax.vjp(loss, params, sinks, has_aux=True)
-        kept = _residual_bytes(backward, (params, grad_acc, batch, rng))
+        kept = _residual_bytes(
+            backward, (params, copies, grad_acc, batch, rng)
+        )
         gauges["remat.kept_bytes"] = float(kept)
         # once a trace, beside whatever the caller logs next
         logger.info(f"accumulate_step traced: kept_bytes={kept}")
@@ -237,6 +261,23 @@ def make_accumulate_step(
         )
         return grad_acc, n_acc + 1, metrics
 
+    # jitted programs carry stable names: a trace, an IR dump or a compile
+    # event finds "accumulate_step" after any refactor
+    if copy_dtype is None:
+        def accumulate_step(params, grad_acc, n_acc, batch, rng):
+            return accumulate(params, None, grad_acc, n_acc, batch, rng)
+    else:
+        def accumulate_step(params, compute_copies, grad_acc, n_acc, batch,
+                            rng):
+            return accumulate(
+                params, compute_copies, grad_acc, n_acc, batch, rng
+            )
+
+        return _StepWithComputeCopies(
+            jax.jit(accumulate_step, donate_argnums=(2, 3)), sink_mask,
+            copy_dtype, gauges,
+        )
+
     kwargs = dict(donate_argnums=(1, 2))
     if mesh is not None:
         repl = NamedSharding(mesh, P())
@@ -253,8 +294,74 @@ def make_accumulate_step(
             out_shardings=(p_sh, repl, repl),
         )
     step = jax.jit(accumulate_step, **kwargs)
-    step.gauges = gauges
+    step.gauges, step.counters = gauges, {}
     return step
+
+
+class _StepWithComputeCopies:
+    """``make_accumulate_step``'s step for a loss that takes compute-dtype
+    copies of its marked leaves: ``(params, grad_acc, n_acc, batch, rng)``
+    as any other, ``.lower`` and ``.gauges`` too, around ``inner``, the
+    jitted six-argument ``accumulate_step`` (the copies second).
+
+    It keeps the copies and the arrays they were cast from. A call compares
+    ``params``' marked leaves with those BY IDENTITY (a reference each: an
+    ``id()`` can be another array's after a collection) and, where one
+    differs, lets the old copies go, THEN casts the new ones — two sets never
+    stand side by side — in ``expert_compute_copies``, a jitted program of
+    ONE signature, first build and rebuilds alike (a second one, say a
+    donated previous set, would compile after the first global step).
+    ``counters['moe.compute_copy_builds']`` counts its runs: one a set of
+    weights. Between two applies the copies stay resident (2 bytes a held
+    element)."""
+
+    def __init__(self, inner, mask, dtype, gauges):
+        self._inner, self._mask, self._dtype = inner, mask, dtype
+        self.gauges = gauges
+        self.counters = {"moe.compute_copy_builds": 0}
+        self._sources: Tuple = ()
+        self._copies = None
+        # where ``params``' marked leaves lie among its leaves, for the
+        # treedef it was read off: the mask walks every path (0.3 ms at 74
+        # leaves), a call only flattens
+        self._treedef, self._marked = None, ()
+
+        def expert_compute_copies(marked):
+            return jax.tree.map(lambda w: w.astype(dtype), marked)
+
+        self._cast = jax.jit(expert_compute_copies)
+
+    def __call__(self, params, grad_acc, n_acc, batch, rng):
+        leaves, treedef = jax.tree.flatten(params)
+        if self._treedef is None or treedef != self._treedef:
+            self._treedef, self._marked = treedef, tuple(
+                i for i, keep in enumerate(
+                    jax.tree.leaves(self._mask(params))
+                ) if keep
+            )
+        sources = tuple(leaves[i] for i in self._marked)
+        if len(sources) != len(self._sources) or any(
+            new is not old for new, old in zip(sources, self._sources)
+        ):
+            self._sources, self._copies = (), None
+            self._copies = self._cast(self._marked_of(params))
+            self._sources = sources
+            self.counters["moe.compute_copy_builds"] += 1
+        return self._inner(params, self._copies, grad_acc, n_acc, batch, rng)
+
+    def _marked_of(self, params):
+        return _marked_subtree(params, self._mask(params))
+
+    def lower(self, params, grad_acc, n_acc, batch, rng):
+        """The inner program lowered, the copies' shapes (and placement)
+        derived from ``params``: abstract arguments do."""
+        copies = jax.tree.map(
+            lambda w: jax.ShapeDtypeStruct(
+                w.shape, self._dtype, sharding=getattr(w, "sharding", None)
+            ),
+            self._marked_of(params),
+        )
+        return self._inner.lower(params, copies, grad_acc, n_acc, batch, rng)
 
 
 def make_apply_step(

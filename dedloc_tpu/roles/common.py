@@ -219,7 +219,8 @@ DEEPSEEK_V3 = ModelFamily(
     weight_decay_mask=deepseek_v3_weight_decay_mask,
     step_gauges=(
         "moe.load_max_over_mean", "moe.local_slot_share", "moe.bias_abs_max",
-        "moe.grad_sink_leaves", "moe.bulk_row_share",
+        "moe.grad_sink_leaves", "moe.compute_copy_leaves",
+        "moe.bulk_row_share",
     ),
     step_counters=("moe.dropped_slots",),
     sign_step_mask=sign_step_mask,
@@ -242,7 +243,8 @@ SMALLTHINKER = dataclasses.replace(
     weight_decay_mask=smallthinker_weight_decay_mask,
     step_gauges=(
         "moe.load_max_over_mean", "moe.local_slot_share",
-        "moe.grad_sink_leaves", "moe.bulk_row_share", "attn.band_tile_share",
+        "moe.grad_sink_leaves", "moe.compute_copy_leaves",
+        "moe.bulk_row_share", "attn.band_tile_share",
     ),
     sign_step_mask=None, sign_step=0.0,
 )
@@ -255,8 +257,8 @@ SDAR_MOE = dataclasses.replace(
     tflops_per_sample=sdar_moe_train_tflops_per_sample,
     step_gauges=(
         "moe.load_max_over_mean", "moe.local_slot_share",
-        "moe.grad_sink_leaves", "moe.bulk_row_share", "attn.bd_tile_share",
-        "diffusion.masked_share",
+        "moe.grad_sink_leaves", "moe.compute_copy_leaves",
+        "moe.bulk_row_share", "attn.bd_tile_share", "diffusion.masked_share",
     ),
     step_counters=("moe.dropped_slots", "diffusion.masked_tokens"),
 )
@@ -682,13 +684,15 @@ def publish_step_metrics(
 
 def build_loss_fn(model) -> Callable:
     """The family's loss for ``model``: (params, batch, rng) -> (loss,
-    metrics) — a ``GradSinkLoss`` where the family marks sink leaves, so
-    whoever builds ``make_accumulate_step`` on it builds the same program."""
+    metrics) — a ``GradSinkLoss`` where the family marks sink leaves (it
+    also says the dtype the model computes in: the step hands it those
+    leaves already cast), so whoever builds ``make_accumulate_step`` on it
+    builds the same program."""
     family = model_family(model)
     loss_fn = family.loss(model)
     if family.grad_sink_mask is None:
         return loss_fn
-    return GradSinkLoss(loss_fn, family.grad_sink_mask)
+    return GradSinkLoss(loss_fn, family.grad_sink_mask, model.cfg.dtype)
 
 
 def synthetic_mlm_batches(

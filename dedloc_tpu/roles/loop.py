@@ -57,6 +57,9 @@ class LoopModel:
     # no output of the device program), a live dict: what it holds goes
     # onto every stepping record beside them
     host_gauges: Dict[str, float] = dataclasses.field(default_factory=dict)
+    # ... and running totals they keep on the host, a live dict too: what a
+    # total grew by since the last stepping record goes onto this one
+    host_counters: Dict[str, float] = dataclasses.field(default_factory=dict)
     # analytic model TFLOPs of one fwd+bwd sample, for the MFU gauge
     # (0: no gauge)
     tflops_per_sample: float = 0.0
@@ -81,6 +84,7 @@ def run_boundary_loop(
     # distribution and per-pass loss)
     summed = ("loss",) + model.step_gauges + model.step_counters
     sums_dev: dict = {}
+    host_counted: Dict[str, float] = {}
     mini_steps = 0
     boundary = 0
     last_saved_step = opt.local_step
@@ -175,11 +179,17 @@ def run_boundary_loop(
                             for key, value in values.items():
                                 srec.attrs[key] = float(value)
                                 if tele is not None:
-                                    tele.gauge(key).set(float(value))  # dedlint: emits=gauge:lm.exit_prob.*,gauge:lm.loss.*,gauge:moe.load_max_over_mean.*,gauge:moe.local_slot_share,gauge:moe.bias_abs_max,gauge:moe.grad_sink_leaves,gauge:moe.bulk_row_share,gauge:attn.band_tile_share,gauge:attn.band_visible_share,gauge:attn.gate_mean.*,gauge:attn.bd_tile_share,gauge:diffusion.masked_share
+                                    tele.gauge(key).set(float(value))  # dedlint: emits=gauge:lm.exit_prob.*,gauge:lm.loss.*,gauge:moe.load_max_over_mean.*,gauge:moe.local_slot_share,gauge:moe.bias_abs_max,gauge:moe.grad_sink_leaves,gauge:moe.compute_copy_leaves,gauge:moe.bulk_row_share,gauge:attn.band_tile_share,gauge:attn.band_visible_share,gauge:attn.gate_mean.*,gauge:attn.bd_tile_share,gauge:diffusion.masked_share
                         for key, value in model.host_gauges.items():
                             srec.attrs[key] = value
                             if tele is not None:
                                 tele.gauge(key).set(value)  # dedlint: emits=gauge:remat.kept_bytes
+                        for key, total in model.host_counters.items():
+                            grown = total - host_counted.get(key, 0)
+                            host_counted[key] = total
+                            srec.attrs[key] = float(grown)
+                            if tele is not None:
+                                tele.counter(key).inc(grown)  # dedlint: emits=counter:moe.compute_copy_builds
                         for name in model.step_counters:
                             srec.attrs[name] = float(sums[name])
                             if tele is not None:

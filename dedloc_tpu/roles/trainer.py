@@ -369,6 +369,8 @@ def _run_trainer(args: CollaborationArguments) -> TrainState:
             step_counters=family.step_counters,
             # ``remat.kept_bytes``, once the step has been traced
             host_gauges=accumulate.gauges,
+            # ``moe.compute_copy_builds``, where the step keeps copies
+            host_counters=accumulate.counters,
             # the MFU gauge uses the same analytic model-FLOPs formula and
             # peak table as bench.py
             tflops_per_sample=family.tflops_per_sample(cfg, seq),

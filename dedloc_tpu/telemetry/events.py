@@ -78,6 +78,8 @@ MM_ROUNDS_ATTEMPTED = "mm.rounds_attempted"
 MM_ROUNDS_FORMED = "mm.rounds_formed"
 MOE_BIAS_ABS_MAX = "moe.bias_abs_max"
 MOE_BULK_ROW_SHARE = "moe.bulk_row_share"
+MOE_COMPUTE_COPY_BUILDS = "moe.compute_copy_builds"
+MOE_COMPUTE_COPY_LEAVES = "moe.compute_copy_leaves"
 MOE_DROPPED_SLOTS = "moe.dropped_slots"
 MOE_GRAD_SINK_LEAVES = "moe.grad_sink_leaves"
 MOE_LOCAL_SLOT_SHARE = "moe.local_slot_share"
@@ -198,6 +200,7 @@ COUNTERS = frozenset({
     "mm.rounds_aborted",
     "mm.rounds_attempted",
     "mm.rounds_formed",
+    "moe.compute_copy_builds",
     "moe.dropped_slots",
     "net.bytes_in",
     "net.bytes_out",
@@ -251,6 +254,7 @@ GAUGES = frozenset({
     "expert.load_ewma",
     "moe.bias_abs_max",
     "moe.bulk_row_share",
+    "moe.compute_copy_leaves",
     "moe.grad_sink_leaves",
     "moe.local_slot_share",
     "opt.ef_residual_norm",

@@ -307,6 +307,12 @@ def test_two_width_kernels_contract_over_a_heads_own_lane_tiles():
     # ``old + term`` adds of each backward loop riding their dots' fusions
     assert (passes["tile_loops"], passes["fused_adds"],
             passes["loose_adds"]) == (4, 6, 0)
+    # the held matrices arrive in bf16 (PR 50: the step's compute-dtype
+    # copies, stacked like the leaves): no whole-matrix float32 -> bf16 pass
+    # inside the program (3 before: XLA hoisted the stack's casts out of the
+    # scan), and the bf16 stack's 0.30 GB of scratch gone (3,557,284,864)
+    assert passes["held_casts"] == 0
+    assert rows["kanana_accumulate_step"]["memory"]["temp_bytes"] <= 3.3e9
     # 0.05 GB under the line: the layers keep the kernels' OUTPUTS alone
     assert rows["kanana_accumulate_step"]["remat_policy"] == "kernel_outputs"
 
@@ -389,13 +395,16 @@ def test_lfm2_accumulate_step_keeps_what_its_backward_reads():
     # accumulator's twelve expert leaves and leaves the sums there — no
     # zeroed float32 carry, no ``grad_acc + result`` pass (12 + 12 before),
     # and the scratch those buffers took is gone (1,170,841,600 before)
+    # … no float32 -> bf16 pass over a held matrix (PR 50: 24 before, the
+    # forward's and the remat replay's twelve; the step is handed the bf16
+    # matrices, cast once a global step: ``held_casts``) …
     # … and the walk's loops (PR 42): four routed layers x two directions x
     # the bulk and the tail loop (8 loops with the single-size walk),
     # every backward loop's three ``old + term`` adds inside the fusion of
     # their weight-gradient dot: a slice read and written once, no ``term``
     assert row["expert_grad_passes"] == {
         "adds": 0, "zero_fills": 0, "tile_loops": 16, "fused_adds": 24,
-        "loose_adds": 0,
+        "loose_adds": 0, "held_casts": 0,
     }
     assert row["memory"]["temp_bytes"] <= 1_170_841_600
     # since PR 41 the conv layers keep B | C | u and the attention layer
@@ -445,7 +454,7 @@ def test_smallthinker_accumulate_step_takes_the_band_and_a_group_of_seven():
     # their weight-gradient dot: a slice read and written once, no ``term``
     assert row["expert_grad_passes"] == {
         "adds": 0, "zero_fills": 0, "tile_loops": 16, "fused_adds": 24,
-        "loose_adds": 0,
+        "loose_adds": 0, "held_casts": 0,
     }
     assert 370_547_200 * 28 + row["memory"]["temp_bytes"] <= 15.3e9
     # since PR 41 the layers keep q / k / v for their backward kernels
@@ -492,7 +501,7 @@ def test_sdar_accumulate_step_takes_the_block_rule_and_a_group_of_eight():
     # their weight-gradient dot: a slice read and written once, no ``term``
     assert row["expert_grad_passes"] == {
         "adds": 0, "zero_fills": 0, "tile_loops": 16, "fused_adds": 24,
-        "loose_adds": 0,
+        "loose_adds": 0, "held_casts": 0,
     }
     assert row["layer_body_copies"] == []
     assert 456_346_624 * 28 + row["memory"]["temp_bytes"] <= 15.3e9
@@ -546,7 +555,7 @@ def test_laguna_accumulate_step_takes_a_band_equal_to_the_tile_and_a_group_of_si
     assert row["flash_fwd_forms"] == {"one_tile": 0, "tiles": 5}
     assert row["expert_grad_passes"] == {
         "adds": 0, "zero_fills": 0, "tile_loops": 16, "fused_adds": 24,
-        "loose_adds": 0,
+        "loose_adds": 0, "held_casts": 0,
     }
     assert row["layer_body_copies"] == []
     # the gates and their gradients, [1, 8192, 64 | 48]: 9.4 MB

@@ -99,6 +99,10 @@ def test_kanana2_tiny_trainer_steps_the_bias_by_gamma(tmp_path, shard):
     count = int(shard.split("/")[1])
     for n, rec in enumerate(stepped, start=1):
         assert rec["moe.dropped_slots"] == 0.0
+        # the scanned stack's three held leaves in bf16 beside the sinks,
+        # cast once a global step (``test_compute_copies.py``)
+        assert rec["moe.compute_copy_leaves"] == 6.0
+        assert rec["moe.compute_copy_builds"] == 1.0
         # the walk's counter (``parallel/moe.py``): a share of the held rows
         assert 0.0 <= rec["moe.bulk_row_share"] <= 1.0
         assert all(rec[f"moe.load_max_over_mean.{i}"] >= 1.0 for i in (1, 2))

@@ -120,6 +120,12 @@ def test_lfm2_tiny_trainer_steps_the_bias_by_gamma(tmp_path, shard, layers):
     count = int(shard.split("/")[1])
     for n, rec in enumerate(stepped, start=1):
         assert rec["moe.dropped_slots"] == 0.0
+        # the held matrices in bf16, cast ONCE a set of weights: three a
+        # routed layer ride beside the sinks, one run of the cast program a
+        # global step (``parallel/train_step._StepWithComputeCopies``)
+        assert rec["moe.compute_copy_leaves"] == 3.0 * expert_layers
+        assert rec["moe.grad_sink_leaves"] == 3.0 * expert_layers
+        assert rec["moe.compute_copy_builds"] == 1.0
         # the walk's counter (``parallel/moe.py``): a share of the held rows
         assert 0.0 <= rec["moe.bulk_row_share"] <= 1.0
         assert all(

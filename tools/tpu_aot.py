@@ -587,7 +587,13 @@ def expert_grad_passes(hlo_text: str) -> dict:
     / ``loose_adds`` — the ``old + term`` adds over one expert's float32
     ``[1, H, F]`` slice inside those loops' bodies, by whether the add rides
     the fusion of its weight-gradient ``convolution`` (the slice read and
-    written once, under the dot) or is a pass of its own."""
+    written once, under the dot) or is a pass of its own. ``held_casts`` —
+    whole float32 -> bf16 passes over a held matrix (a layer's, or the
+    scanned stack of them): ``convert``s outside fused computations and
+    fusions that take the float32 matrix and return it in bf16; 0 since PR 50
+    (the step is handed the bf16 matrices, ``train_step.
+    _StepWithComputeCopies``), 24 in LFM2 and SDAR before (forward + remat
+    replay), 3 in kanana-2 (XLA hoisted the stack's casts out of its scan)."""
     entry = hlo_text[hlo_text.index("\nENTRY "):]
     shapes = set()
     for dims in re.findall(
@@ -609,7 +615,34 @@ def expert_grad_passes(hlo_text: str) -> dict:
         elif not fused:
             fill = re.match(r"\s+%[\w.\-]+ = f32\[([\d,]+)\]\S* broadcast\(", line)
             fills += bool(fill) and fill.group(1) in shapes
-    return {"adds": adds, "zero_fills": fills, **_tile_loops(hlo_text, shapes)}
+    return {"adds": adds, "zero_fills": fills, **_tile_loops(hlo_text, shapes),
+            "held_casts": _held_casts(hlo_text, shapes)}
+
+
+def _held_casts(hlo_text: str, shapes: set) -> int:
+    """``expert_grad_passes``' count of float32 -> bf16 passes over a held
+    matrix; ``shapes``: the held matrices' dims, ``_tile_loops``'s."""
+    held = "|".join(re.escape(dims) for dims in shapes)
+    casting = set(re.findall(
+        rf"^%?([\w.\-]+) \([^)]*f32\[(?:\d+,)*(?:{held})\][^)]*\) -> "
+        rf"bf16\[(?:\d+,)*(?:{held})\]",
+        hlo_text, re.M,
+    ))
+    casts, fused = 0, False
+    for line in hlo_text.splitlines():
+        if not line.startswith(" "):
+            fused = "fused_computation" in line
+            continue
+        made = not fused and re.match(
+            rf"\s+(?:ROOT )?%[\w.\-]+ = bf16\[(?:\d+,)*(?:{held})\]\S* "
+            r"(convert|fusion)\(", line,
+        )
+        if made:
+            called = re.search(r"\bcalls=%?([\w.\-]+)", line)
+            casts += made.group(1) == "convert" or (
+                bool(called) and called.group(1) in casting
+            )
+    return casts
 
 
 def _tile_loops(hlo_text: str, shapes: set) -> dict:
