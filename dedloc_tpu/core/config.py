@@ -295,7 +295,10 @@ class TrainingArguments:
     # expert decoder, models/sdar_moe.py: seq_length counts a row's CLEAN
     # tokens, the stack sees twice as many positions); laguna_tiny |
     # laguna_xs2_33b_a3b (full and window-512 attention with a head count,
-    # a RoPE and a gate a head per kind, models/laguna.py) —
+    # a RoPE and a gate a head per kind, models/laguna.py); keye_vl2_tiny |
+    # keye_vl2_30b_a3b (grouped-query attention over the keys a learned
+    # indexer selects, three position streams a token from the batch,
+    # models/keye_vl2.py) —
     # roles/common.MODEL_FAMILIES is the table
     model_size: str = "large"
     # depth override (0 = the model's own): a chip's share of a deeper
@@ -305,11 +308,19 @@ class TrainingArguments:
     # peer's chip holds, as one of ``count`` chips that divide a layer (a
     # model with a dropless routed layer: models/deepseek_v3.py,
     # models/lfm2_moe.py, models/smallthinker.py, models/sdar_moe.py,
-    # models/laguna.py). The
+    # models/laguna.py, models/keye_vl2.py). The
     # layer scores ALL experts and computes its own experts' part; "0/1" =
     # every expert. Together with ``vocab_size`` (rows of the vocabulary held)
     # and ``num_hidden_layers`` it states a chip's share of a deployment.
     expert_shard: str = "0/1"
+    # the share of every synthetic row's positions that lies in IMAGE SPANS
+    # (runs of g_h x g_w ids standing for a vision tower's features: three
+    # position streams a token — M-RoPE — and no loss on an image label;
+    # data/causal_lm.py). 0: text rows, as ever — and no ``position_ids``
+    # key built unless the model family reads its positions from the batch
+    # (keye_vl2, whose text rows carry three equal streams); above 0 only
+    # with such a family, an error with any other.
+    image_token_share: float = 0.0
     # override model remat: nothing|kernel_outputs|kernel_operands|
     # whole_mixer|dots|dots_no_batch|dots_no_batch_attn|fused_ln|
     # fused_ln_gelu (fused_ln — saved Pallas outputs + named matmuls, pairs
@@ -320,7 +331,9 @@ class TrainingArguments:
     # kernels READ: q / k / v, the convolution's B | C | u; whole_mixer —
     # those, the stream after the mixer and a q / k norm's input, so the
     # replay runs no matmul of the mixer — is the default of smallthinker,
-    # sdar, lfm2 and laguna, and kernel_operands, then kernel_outputs, is what a
+    # sdar, lfm2 and laguna, and kernel_operands (keye_vl2's default: there
+    # with the selection the flash kernels read, int8 [B, S, S] a layer),
+    # then kernel_outputs, is what a
     # peer with less memory to spare passes there; under any, the five
     # rotate-half decoders — ouro, smallthinker, sdar, lfm2, laguna — hand the flash
     # kernels q / k / v from behind decoder.GroupedQueryAttention's

@@ -123,6 +123,34 @@ def rope_tables(seq: int, head_dim: int, theta: float, inv_freq=None,
     return jnp.cos(angles) * scale, jnp.sin(angles) * scale
 
 
+def mrope_tables(position_ids, head_dim: int, theta: float,
+                 sections: Sequence[int]):
+    """cos, sin [B, S, D] of M-RoPE (``rope_scaling.mrope_section``; Qwen2-VL,
+    arXiv 2409.12191): ``position_ids`` [3, B, S] are a token's temporal,
+    height and width positions, and frequency pair i of the D/2 — f_i =
+    theta^(-2i/D) as ever — turns by the stream its CONTIGUOUS section
+    names: the first ``sections[0]`` pairs by the first stream, the next
+    ``sections[1]`` by the second, the rest by the third; rotate-half, the
+    pairs repeated over both halves. Where a token's three positions are
+    equal (text) the angles are ``rope_tables``' at that position, the same
+    floats: one multiply each."""
+    if sum(sections) != head_dim // 2 or len(sections) != 3:
+        raise ValueError(
+            f"mrope sections {tuple(sections)}: three, and {head_dim // 2} "
+            "frequency pairs in all"
+        )
+    with jax.named_scope("mrope"):
+        inv_freq = 1.0 / (
+            theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+        )
+        stream = np.repeat(np.arange(3), sections)  # [D/2] of 0 / 1 / 2
+        # [B, S, D/2]: each pair's own stream's position
+        positions = jnp.moveaxis(position_ids.astype(jnp.float32), 0, -1)
+        angles = jnp.take(positions, stream, axis=-1) * inv_freq
+        angles = jnp.concatenate([angles, angles], axis=-1)
+        return jnp.cos(angles), jnp.sin(angles)
+
+
 def yarn_inv_freq(dim: int, theta: float, factor: float, original: int,
                   beta_fast: float, beta_slow: float) -> np.ndarray:
     """YaRN's dim/2 inverse frequencies (``rope_type: yarn``; arXiv
@@ -152,7 +180,8 @@ def yarn_inv_freq(dim: int, theta: float, factor: float, original: int,
 def apply_rope(x, cos, sin):
     """x [B, S, H, D]: x·cos + rotate_half(x)·sin, in float32. Tables
     narrower than D (partial rotary) rotate the head's FIRST lanes and pass
-    the others as they are."""
+    the others as they are; tables [S, D] serve every row, tables [B, S, D]
+    (``mrope_tables``) each token its own."""
     width = cos.shape[-1]
     if width < x.shape[-1]:
         with jax.named_scope("rope_partial"):
@@ -163,6 +192,10 @@ def apply_rope(x, cos, sin):
     half = x.shape[-1] // 2
     x32 = x.astype(jnp.float32)
     rotated = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
+    if cos.ndim == 3:  # a token's own angles
+        return (
+            x32 * cos[:, :, None, :] + rotated * sin[:, :, None, :]
+        ).astype(x.dtype)
     return (
         x32 * cos[None, :, None, :] + rotated * sin[None, :, None, :]
     ).astype(x.dtype)
@@ -211,16 +244,23 @@ def embed_tokens(module: nn.Module, input_ids, tied_head: bool = False):
 class Visibility:
     """Which keys a query sees, in ``ops/flash_attention.flash_attention``'s
     own keywords: ``causal`` (keys 0..i), with it ``band`` (keys i-band+1..i),
-    or ``block_diffusion`` (a noisy then a clean stream, blocks this long)."""
+    or ``block_diffusion`` (a noisy then a clean stream, blocks this long),
+    or ``selected``: of the keys 0..i those a ``selection`` [B, S, S] marks —
+    a visibility that is DATA, handed to ``attend`` beside q / k / v by the
+    caller that states it."""
 
     causal: bool = False
     band: Optional[int] = None
     block_diffusion: Optional[int] = None
+    selected: bool = False
 
-    def matrix(self, seq: int):
-        """[S, S] bool, rows queries: the same rule for the dense reference."""
+    def matrix(self, seq: int, selection=None):
+        """[S, S] bool ([B, 1, 1, S, S] under a selection), rows queries:
+        the same rule for the dense reference."""
         i = jnp.arange(seq)
         q, k = i[:, None], i[None, :]
+        if self.selected:
+            return ((selection != 0) & (k <= q))[:, None, None]
         if self.block_diffusion is not None:
             # over [noisy ; clean]: a clean query sees the clean blocks up to
             # its own, a noisy one ITS noisy block and the clean ones BEFORE it
@@ -236,17 +276,27 @@ class Visibility:
         return visible
 
 
-def attend(cfg, q, k, v, visible: Visibility):
+def attend(cfg, q, k, v, visible: Visibility, selection=None):
     """softmax(q kᵀ / sqrt(q's width) + visibility) v for q [B, S, H, D],
     k [B, S, KV, D], v [B, S, KV, Dv], kv head j serving H / KV adjacent query
     heads: the flash kernels (``cfg.attention_impl`` "flash"; [B, S, H, Dv])
     or XLA's materialized S² scores ("dense", for tests and tiny models;
-    [B, S, KV, H / KV, Dv] — the same bytes)."""
+    [B, S, KV, H / KV, Dv] — the same bytes). Under a ``selected``
+    visibility the caller hands the ``selection`` [B, S, S] (int8, rows
+    queries; named ``attn_selection`` where it is made, for the remat
+    policies that keep the kernels' operands) in and gets (context, lse [B, H, S] float32 — each row's
+    log-sum-exp over its selected keys, DETACHED: for a loss of the
+    caller's on the probabilities) back."""
+    if visible.selected and selection is None:
+        raise ValueError("a selected visibility takes a selection")
     if cfg.attention_impl == "flash":
         from dedloc_tpu.ops.flash_attention import flash_attention
 
+        mask = dataclasses.asdict(visible)
+        if mask.pop("selected"):  # the kernels read it from the operand
+            mask["selection"] = selection
         return flash_attention(
-            q, k, v, **dataclasses.asdict(visible),
+            q, k, v, **mask,
             block_q=cfg.attention_block_size,
             block_k=cfg.attention_block_size, mesh=cfg.mesh,
         )
@@ -262,9 +312,13 @@ def attend(cfg, q, k, v, visible: Visibility):
         "bqcgd,bkcd->bcgqk", q.reshape(B, S, KV, H // KV, D), k,
         preferred_element_type=jnp.float32,
     ) / jnp.sqrt(jnp.float32(D))
-    logits = jnp.where(visible.matrix(S), logits, -1e30)
+    logits = jnp.where(visible.matrix(S, selection), logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(cfg.dtype)
-    return jnp.einsum("bcgqk,bkcd->bqcgd", probs, v)
+    ctx = jnp.einsum("bcgqk,bkcd->bqcgd", probs, v)
+    if not visible.selected:
+        return ctx
+    lse = jax.nn.logsumexp(logits, axis=-1).reshape(B, H, S)
+    return ctx, jax.lax.stop_gradient(lse)
 
 
 def mixer_residual(hidden, mixed):
@@ -298,7 +352,10 @@ class GroupedQueryAttention(nn.Module):
     projection — ``ops/head_gate.gate_heads``' kernels behind the flash
     kernels on one device, XLA's expression behind ``"dense"`` attention and
     on a mesh (the op carries no ``shard_map``; no gated model runs on
-    one)."""
+    one). Under a ``selected`` visibility the call takes the ``selection``
+    and returns (output, (q, k, lse)): the kernels' operands after norm and
+    RoPE and ``attend``'s log-sum-exp, what a loss on the attention's
+    probabilities is computed from."""
 
     cfg: Any
     visible: Visibility
@@ -308,7 +365,7 @@ class GroupedQueryAttention(nn.Module):
     heads: Optional[int] = None
 
     @nn.compact
-    def __call__(self, hidden, rope, gate=None):
+    def __call__(self, hidden, rope, gate=None, selection=None):
         cfg = self.cfg
         B, S, _ = hidden.shape
         H, KV, D = (self.heads or cfg.num_attention_heads,
@@ -343,7 +400,9 @@ class GroupedQueryAttention(nn.Module):
             # GB without it, 2.53 with it; SDAR 185.9 → 166.2 → 159.9 ms,
             # 2.55 → 1.28 GB; LFM2's scratch 1.03 → 0.72 GB
             q, k, v = jax.lax.optimization_barrier((q, k, v))
-        ctx = attend(cfg, q, k, v, self.visible)
+        ctx = attend(cfg, q, k, v, self.visible, selection)
+        if self.visible.selected:
+            ctx, lse = ctx
         # [B, S, KV, H / KV, D] (dense) is [B, S, H, D]: adjacent heads
         ctx = ctx.reshape(B, S, H * D)
         if gate is not None:
@@ -358,7 +417,8 @@ class GroupedQueryAttention(nn.Module):
             fused = cfg.attention_impl == "flash" and cfg.mesh is None
             with jax.named_scope("attn_gate"):
                 ctx = (gate_heads if fused else gate_heads_xla)(ctx, gate)
-        return dense(cfg.hidden_size, cfg, self.out_name)(ctx)
+        out = dense(cfg.hidden_size, cfg, self.out_name)(ctx)
+        return (out, (q, k, lse)) if self.visible.selected else out
 
 
 def held_expert_ffn(module: nn.Module, tokens, choice, weights,
@@ -596,18 +656,19 @@ def chunked_cross_entropy(hiddens, lm_head, labels, chunk_tokens: int):
 
 
 def apply_with_grad_sinks(model, params, input_ids, grad_sinks,
-                          compute_copies=None):
+                          compute_copies=None, **inputs):
     """``model.apply`` on ``params``, with ``grad_sinks`` (None, or the
     subtree of a float32 gradient accumulator that ``routed_grad_sink_mask``
     marks) riding beside them as the collection ``GRAD_SINKS`` and
     ``compute_copies`` (None, or the same subtree of ``params`` in the
-    compute dtype) as ``COMPUTE_COPIES``."""
+    compute dtype) as ``COMPUTE_COPIES``; ``inputs``: the model's further
+    keyword inputs (a batch's position streams)."""
     variables = {"params": params}
     if grad_sinks is not None:
         variables[GRAD_SINKS] = grad_sinks
     if compute_copies is not None:
         variables[COMPUTE_COPIES] = compute_copies
-    return model.apply(variables, input_ids)
+    return model.apply(variables, input_ids, **inputs)
 
 
 def routed_metrics(routing, params, gauges: Dict[str, Callable]):

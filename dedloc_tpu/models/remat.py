@@ -52,11 +52,20 @@ def remat_policy_object(name: str):
         # "kernel_outputs", a layer a micro-batch: B·S·(H + 2·H_kv)·D·2
         # bytes for an attention (151 MB at SmallThinker's 16,384 x
         # (28 + 2·4) x 128), B·S·3·hidden·2 for a convolution (50 MB at
-        # LFM2's 4,096 x 3 x 2,048). The rung under "whole_mixer"
+        # LFM2's 4,096 x 3 x 2,048). A SELECTED attention's kernels also
+        # read the selection ("attn_selection": int8 [B, S, S], the
+        # visibility a learned indexer chose, models/keye_vl2.py — 268 MB a
+        # layer at S = 16,384): KEPT here and above, so the dq / dkv
+        # kernels read the very array the forward kernel read and the
+        # replay runs neither the index-score pass nor the top-k for their
+        # sake; under the rungs below it is REPLAYED — the same ops on the
+        # replayed indexer's values, in the same program: bit-equal
+        # (tests/test_remat_operands.py holds every rung to that). The rung
+        # under "whole_mixer"
         "kernel_operands": (
             jax.checkpoint_policies.save_from_both_policies(
                 jax.checkpoint_policies.save_only_these_names(
-                    "flash_qkv", "short_conv_bcu"
+                    "flash_qkv", "short_conv_bcu", "attn_selection"
                 ),
                 _pallas_outputs_saveable,
             )
@@ -96,7 +105,7 @@ def remat_policy_object(name: str):
             jax.checkpoint_policies.save_from_both_policies(
                 jax.checkpoint_policies.save_only_these_names(
                     "flash_qkv", "short_conv_bcu", "mixer_residual",
-                    "qk_norm_input", "attn_gate",
+                    "qk_norm_input", "attn_gate", "attn_selection",
                 ),
                 _pallas_outputs_saveable,
             )

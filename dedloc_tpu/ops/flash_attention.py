@@ -143,6 +143,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -538,6 +539,8 @@ class _Mask(NamedTuple):
     causal: bool = False  # keys at the query's position and before
     band: Optional[int] = None  # and only the last ``band`` of those
     blocks: Optional[_Blocks] = None  # or: the two-stream block rule
+    # of the keys before it, those an OPERAND names (see "selected tiles")
+    selected: bool = False
 
 
 def _clip(x, low=None, high=None):
@@ -656,9 +659,17 @@ def visited_tiles(seq: int, block_q: int, block_k: int, causal: bool,
 
 
 def _mask_of(causal: bool, band: Optional[int], seq: int,
-             block_diffusion: Optional[int] = None) -> _Mask:
+             block_diffusion: Optional[int] = None,
+             selected: bool = False) -> _Mask:
     """The call's mask; a band as long as the sequence IS the causal mask
     (and the causal kernels, under their names)."""
+    if selected:
+        if band is not None or block_diffusion is not None:
+            raise ValueError(
+                "selected: the selection is a mask of its own over the "
+                "causal triangle (no band, no block rule)"
+            )
+        return _Mask(causal=True, selected=True)
     if block_diffusion is not None:
         if causal or band is not None or block_diffusion < 1 or seq % (
             2 * block_diffusion
@@ -715,9 +726,70 @@ def _masked(s, mask):
     return s if mask is None else jnp.where(mask, s, NEG_INF)
 
 
+# --------------------------------------------------------- selected tiles
+#
+# A SELECTION (``selected``: learned sparse attention) is a visibility that
+# is DATA: query t sees the keys s <= t that an int8 operand ``selection``
+# [B, S, S] marks (rows queries; what it marks above the diagonal is never
+# read). The sweeps are the causal triangle's — the index maps, the grid and
+# the count of visited tiles are the causal call's — and every kernel reads
+# the selection's (query tile, key tile) block beside q / k / v, which IS the
+# tile's mask: no iota. A tile that holds no selected pair runs no body (no
+# MXU work; its blocks are still fetched: the index maps do not read data):
+# ``flags`` [B · tiles] int32 in SMEM, one a tile, made from the selection
+# by ``selection_tile_flags`` once a call, say which. The backward kernels
+# read the same two operands the forward did — they are residuals of the
+# custom VJP. A row of a tile with nothing selected scores -1e30 everywhere
+# and, while its running max is still the floor, takes weight exp(0) for
+# every key of the tile; the first tile that holds one of the row's selected
+# keys multiplies that away by exp(-1e30 - m) = 0, and every row has one (a
+# selection keeps at least one key a query). Named ``flash_sel_*``.
+
+
+def selection_tile_flags(selection, block_q: int, block_k: int):
+    """[B, S / Bq, S / Bk] int32: 1 where a (query tile, key tile) of
+    ``selection`` [B, S, S] holds a selected pair, at the tiles a call with
+    these preferred blocks takes."""
+    b, s, _ = selection.shape
+    bq, bk = _pick_block(s, block_q), _pick_block(s, block_k)
+    return jnp.any(
+        selection.reshape(b, s // bq, bq, s // bk, bk) != 0, axis=(2, 4)
+    ).astype(jnp.int32)
+
+
+def _selected_kernel(kernel, inputs: int):
+    """``kernel`` for a call with a selection: its tile and the tile flags
+    ride behind the ``inputs`` other inputs and reach it as ``sel``."""
+    def with_selection(*refs, **static):
+        return kernel(
+            *refs[:inputs], *refs[inputs + 2:],
+            sel=refs[inputs:inputs + 2], **static,
+        )
+
+    return with_selection
+
+
+def _for_selected_tile(sel, valid, qi, ki, bk: int, seq: int, body) -> None:
+    """Run ``body(the selection's tile)`` for tile (qi, ki) of a step that
+    is ``valid`` (inside its sweep), where the tile holds a selected pair."""
+    sel_ref, flags_ref = sel
+    nk = seq // bk
+    tiles = (seq // sel_ref.shape[0]) * nk
+    flag = flags_ref[pl.program_id(0) * tiles + qi * nk + ki]
+
+    @pl.when(valid & (flag > 0))
+    def _some():
+        body(sel_ref[:].astype(jnp.int32) != 0)
+
+
 def _for_k_step(mask: _Mask, qi, step, bq: int, bk: int, seq: int,
-                body) -> None:
+                body, sel=None) -> None:
     """``_for_tile`` for step ``step`` of query tile ``qi``'s key sweep."""
+    if mask.selected:
+        last = _last_k_tile(qi, bq, bk)
+        _for_selected_tile(sel, step <= last, qi, jnp.minimum(step, last),
+                           bk, seq, body)
+        return
     if mask.blocks is not None:
         _bd_for_step(mask.blocks, "k", qi, step, bq, bk, body)
         return
@@ -726,9 +798,14 @@ def _for_k_step(mask: _Mask, qi, step, bq: int, bk: int, seq: int,
 
 
 def _for_q_step(mask: _Mask, ki, step, bq: int, bk: int, seq: int,
-                body) -> None:
+                body, sel=None) -> None:
     """``_for_tile`` for step ``step`` of key tile ``ki``'s query sweep (a
     band's starts at the key tile's first query tile)."""
+    if mask.selected:
+        first = _first_q_tile(ki, bq, bk)
+        _for_selected_tile(sel, step >= first, jnp.maximum(step, first), ki,
+                           bk, seq, body)
+        return
     if mask.blocks is not None:
         _bd_for_step(mask.blocks, "q", ki, step, bq, bk, body)
         return
@@ -886,7 +963,7 @@ def _bd_for_step(blocks: _Blocks, over: str, outer, step, bq: int, bk: int,
 
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref, *, scale, d, dv, g, mask, seq,
-                group=1):
+                group=1, sel=None):
     kb = pl.program_id(3)  # a step of the key sweep, not yet a tile
     nk = pl.num_programs(3)
     qi, bq, bk = pl.program_id(2), q_ref.shape[0], k_ref.shape[0]
@@ -949,7 +1026,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                     acc_ref[:, at] * _per_head_lanes(corrs, dv, seg) + pv_seg
                 )
 
-    _for_k_step(mask, qi, kb, bq, bk, seq, tile)
+    _for_k_step(mask, qi, kb, bq, bk, seq, tile, sel)
 
     @pl.when(kb == nk - 1)
     def _flush():
@@ -1009,6 +1086,8 @@ def _name(kernel: str, mask: _Mask, d: int, dv: int,
     grouped-query ones (fewer kv heads than heads) and every call with a
     band (its metadata says how long, and its head counts) or the block
     rule (its blocks' and its streams' length)."""
+    if mask.selected:
+        return f"flash_sel_{kernel}"
     if mask.blocks is not None:
         return f"flash_bd_{kernel}"
     if mask.band is not None:
@@ -1021,17 +1100,32 @@ def _name(kernel: str, mask: _Mask, d: int, dv: int,
     return f"flash_causal_{kernel}" if causal else f"flash_{kernel}"
 
 
-def _fwd(q, k, v, bias, d, dv, block_q, block_k, mask, interpret):
-    """Returns (out [B, S, H·dv], lse [B·H, 1, S])."""
+def _fwd(q, k, v, bias, d, dv, block_q, block_k, mask, interpret,
+         sel=None):
+    """Returns (out [B, S, H·dv], lse [B·H, 1, S]). ``sel``: a selected
+    call's (selection, tile flags)."""
     # grouped-query calls take the tiled form at every length (one tile is
-    # then a grid of one): the one-tile kernels have no kv block of their own
-    if _one_tile(q.shape[1], block_q, block_k) and k.shape == q.shape:
+    # then a grid of one): the one-tile kernels have no kv block of their
+    # own — nor a selection's tile
+    if (_one_tile(q.shape[1], block_q, block_k) and k.shape == q.shape
+            and sel is None):
         return _fwd_one_tile(q, k, v, bias, d, dv, mask, interpret)
     return _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, mask,
-                      interpret)
+                      interpret, sel)
 
 
-def _fwd_vmem(q, bq: int, bk: int, hp: int, kvb: int, d: int, dv: int):
+def _selection_specs(bq: int, bk: int, at):
+    """The in_specs of a selected call's two extra operands: the selection's
+    (query tile, key tile) block, at ``at(*grid indices)`` = (batch, query
+    tile, key tile), and the tile flags whole in SMEM."""
+    return [
+        pl.BlockSpec((None, bq, bk), at),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
+    ]
+
+
+def _fwd_vmem(q, bq: int, bk: int, hp: int, kvb: int, d: int, dv: int,
+              selected: bool = False):
     """Compiler parameters of a tiled forward call: None — the compiler's
     own scoped-VMEM limit, 16 MiB on a v5e — unless the call needs more.
     With the state lane-dense nothing orders a program's heads, so the
@@ -1044,13 +1138,16 @@ def _fwd_vmem(q, bq: int, bk: int, hp: int, kvb: int, d: int, dv: int):
     size = q.dtype.itemsize
     blocks = 2 * size * (bq * hp * (d + dv) + bk * kvb * (d + dv))
     state = 4 * bq * hp * (dv + 2 * STATE_LANES)
-    need = blocks + state + hp * 6 * bq * bk
+    # a selection's int8 tile, twice (the pipeline's two buffers), and the
+    # tile's mask widened for the select
+    need = blocks + state + hp * 6 * bq * bk + (6 * bq * bk if selected else 0)
     if need <= 14 * 2**20:
         return None
     return pltpu.CompilerParams(vmem_limit_bytes=need + 4 * 2**20)
 
 
-def _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, mask, interpret):
+def _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, mask, interpret,
+               sel=None):
     b, s, h, g, hp, bq, bk = _geometry(q, d, dv, block_q, block_k,
                                        budget_mb=6.0)
     group, kvb, hp = _grouped(q, k, d, dv, g, hp)
@@ -1062,11 +1159,15 @@ def _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, mask, interpret):
     def kv_at(p):  # the kv block of query program p
         return p if group == 1 else p * hp // group // kvb
 
+    kernel = functools.partial(
+        _fwd_kernel, scale=1.0 / (d ** 0.5), d=d, dv=dv, g=g,
+        mask=mask, seq=s, group=group,
+    )
+    selection = [] if sel is None else _selection_specs(
+        bq, bk, lambda n, p, j, kb: (n, j, k_at(j, kb))
+    )
     out, lse = pl.pallas_call(
-        functools.partial(
-            _fwd_kernel, scale=1.0 / (d ** 0.5), d=d, dv=dv, g=g,
-            mask=mask, seq=s, group=group,
-        ),
+        kernel if sel is None else _selected_kernel(kernel, 4),
         grid=(b, hpb, s // bq, _sweep(mask, s // bq, s // bk, bq, bk, "k")),
         in_specs=[
             pl.BlockSpec((None, bq, hp * d), lambda n, p, j, kb: (n, j, p)),
@@ -1076,6 +1177,7 @@ def _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, mask, interpret):
                          lambda n, p, j, kb: (n, k_at(j, kb), kv_at(p))),
             pl.BlockSpec((None, 1, bk),
                          lambda n, p, j, kb: (n, 0, k_at(j, kb))),
+            *selection,
         ],
         out_specs=[
             pl.BlockSpec((None, bq, hp * dv), lambda n, p, j, kb: (n, j, p)),
@@ -1094,8 +1196,9 @@ def _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, mask, interpret):
         interpret=interpret,
         name=_name("fwd", mask, d, dv, group),
         metadata=_metadata(d, dv, g, q, k, mask),
-        compiler_params=_fwd_vmem(q, bq, bk, hp, kvb, d, dv),
-    )(q, k, v, bias)
+        compiler_params=_fwd_vmem(q, bq, bk, hp, kvb, d, dv,
+                                  sel is not None),
+    )(q, k, v, bias, *(sel or ()))
     return out, lse
 
 
@@ -1178,7 +1281,8 @@ def _backward_heads(refs, bias_ref, lse_ref, h0, cols, vcols, mask, *,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
-               dq_ref, dq_acc_ref, *, scale, d, dv, g, mask, seq, group=1):
+               dq_ref, dq_acc_ref, *, scale, d, dv, g, mask, seq, group=1,
+               sel=None):
     kb = pl.program_id(3)  # a step of the key sweep, not yet a tile
     nk = pl.num_programs(3)
     qi, bq, bk = pl.program_id(2), q_ref.shape[0], k_ref.shape[0]
@@ -1206,7 +1310,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
             for at, total in zip(dq_at, dq):
                 dq_acc_ref[:, at] = total
 
-    _for_k_step(mask, qi, kb, bq, bk, seq, tile)
+    _for_k_step(mask, qi, kb, bq, bk, seq, tile, sel)
 
     @pl.when(kb == nk - 1)
     def _flush():
@@ -1215,7 +1319,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
                 dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, scale, d, dv, g,
-                mask, seq, group=1):
+                mask, seq, group=1, sel=None):
     # grid (B, across the kv width, kv tile, [the kv block's query programs
     # — a grouped call's ``members`` —], a step of the query sweep): the
     # accumulators live over everything inside the kv tile's axis
@@ -1265,7 +1369,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
             for at, total in zip(dv_at, dv_):
                 dv_acc_ref[:, at] = total
 
-    _for_q_step(mask, ki, qb, bq, bk, seq, tile)
+    _for_q_step(mask, ki, qb, bq, bk, seq, tile, sel)
 
     @pl.when(of_group(qb == nq - 1, -1))
     def _flush():
@@ -1300,11 +1404,11 @@ def _dqkv_fused_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref,
 
 
 def _bwd(q, k, v, bias, lse, do, out, d, dv, block_q, block_k, mask,
-         interpret):
+         interpret, sel=None):
     if k.shape != q.shape:
         return _bwd_grouped(q, k, v, bias, lse, do, out, d, block_q, block_k,
-                            mask, interpret)
-    if _one_tile(q.shape[1], block_q, block_k):
+                            mask, interpret, sel)
+    if _one_tile(q.shape[1], block_q, block_k) and sel is None:
         return _bwd_fused(q, k, v, bias, lse, do, out, d, dv, mask,
                           interpret)
     # bwd transients per head are ~3x the fwd's (s, p, dp, ds live at once)
@@ -1321,7 +1425,7 @@ def _bwd(q, k, v, bias, lse, do, out, d, dv, block_q, block_k, mask,
         return pl.BlockSpec((None, rows, hp * width),
                             lambda n, p, x, y: (n, at(x, y), p))
 
-    def in_specs(q_at, k_at):  # q, k, v, bias, lse, dO, O
+    def in_specs(q_at, k_at):  # q, k, v, bias, lse, dO, O(, selection)
         return [
             wide(bq, q_at), wide(bk, k_at), wide(bk, k_at, dv),
             pl.BlockSpec((None, 1, bk),
@@ -1329,7 +1433,15 @@ def _bwd(q, k, v, bias, lse, do, out, d, dv, block_q, block_k, mask,
             pl.BlockSpec((hp, 1, bq),
                          lambda n, p, x, y: (n * hpb + p, 0, q_at(x, y))),
             wide(bq, q_at, dv), wide(bq, q_at, dv),
-        ]
+        ] + ([] if sel is None else _selection_specs(
+            bq, bk, lambda n, p, x, y: (n, q_at(x, y), k_at(x, y))
+        ))
+
+    def kernel_of(kernel):
+        kernel = functools.partial(kernel, **kernel_args)
+        return kernel if sel is None else _selected_kernel(kernel, 7)
+
+    operands = (q, k, v, bias, lse, do, out, *(sel or ()))
 
     def outer(x, y):
         return x
@@ -1343,7 +1455,7 @@ def _bwd(q, k, v, bias, lse, do, out, d, dv, block_q, block_k, mask,
         return _q_tile(mask, x, y, bq, bk, nq)
 
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, **kernel_args),
+        kernel_of(_dq_kernel),
         grid=(b, hpb, nq, _sweep(mask, nq, nk, bq, bk, "k")),
         in_specs=in_specs(q_at=outer, k_at=inner_k),
         out_specs=wide(bq, outer),
@@ -1352,10 +1464,10 @@ def _bwd(q, k, v, bias, lse, do, out, d, dv, block_q, block_k, mask,
         interpret=interpret,
         name=_name("bwd_dq", mask, d, dv),
         metadata=_metadata(d, dv, g, q, k, mask),
-    )(q, k, v, bias, lse, do, out)
+    )(*operands)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, **kernel_args),
+        kernel_of(_dkv_kernel),
         grid=(b, hpb, nk, _sweep(mask, nq, nk, bq, bk, "q")),
         in_specs=in_specs(q_at=inner_q, k_at=outer),
         out_specs=[wide(bk, outer), wide(bk, outer, dv)],
@@ -1370,12 +1482,12 @@ def _bwd(q, k, v, bias, lse, do, out, d, dv, block_q, block_k, mask,
         interpret=interpret,
         name=_name("bwd_dkv", mask, d, dv),
         metadata=_metadata(d, dv, g, q, k, mask),
-    )(q, k, v, bias, lse, do, out)
+    )(*operands)
     return dq, dk, dv
 
 
 def _bwd_grouped(q, k, v, bias, lse, do, out, d, block_q, block_k, mask,
-                 interpret):
+                 interpret, sel=None):
     """The two-kernel backward with fewer kv heads than heads (see
     "grouped-query heads"): dq as ever, its k / v blocks the group's; dk and
     dv on a grid with one more axis, the query programs that share a kv
@@ -1398,12 +1510,20 @@ def _bwd_grouped(q, k, v, bias, lse, do, out, d, block_q, block_k, mask,
     def kv_at(p):
         return p * hp // group // kvb
 
+    def kernel_of(kernel):
+        kernel = functools.partial(kernel, **kernel_args)
+        return kernel if sel is None else _selected_kernel(kernel, 7)
+
+    def selection(at):
+        return [] if sel is None else _selection_specs(bq, bk, at)
+
+    operands = (q, k, v, bias, lse, do, out, *(sel or ()))
     q_side = pl.BlockSpec((None, bq, hp * d), lambda n, p, j, kb: (n, j, p))
     kv_side = pl.BlockSpec(
         (None, bk, kvb * d), lambda n, p, j, kb: (n, last_k(j, kb), kv_at(p))
     )
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, **kernel_args),
+        kernel_of(_dq_kernel),
         grid=(b, hpb, nq, _sweep(mask, nq, nk, bq, bk, "k")),
         in_specs=[
             q_side, kv_side, kv_side,
@@ -1412,12 +1532,13 @@ def _bwd_grouped(q, k, v, bias, lse, do, out, d, block_q, block_k, mask,
             pl.BlockSpec((hp, 1, bq),
                          lambda n, p, j, kb: (n * hpb + p, 0, j)),
             q_side, q_side,
+            *selection(lambda n, p, j, kb: (n, j, last_k(j, kb))),
         ],
         out_specs=q_side,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, hp * d), jnp.float32)],
         name=_name("bwd_dq", mask, d, d, group), **names,
-    )(q, k, v, bias, lse, do, out)
+    )(*operands)
 
     # (B, kv blocks, kv tile, query programs of the block, query tile)
     q_side = pl.BlockSpec(
@@ -1428,7 +1549,7 @@ def _bwd_grouped(q, k, v, bias, lse, do, out, d, block_q, block_k, mask,
         (None, bk, kvb * d), lambda n, c, kt, m, qt: (n, kt, c)
     )
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, **kernel_args),
+        kernel_of(_dkv_kernel),
         grid=(b, h // hp // members, nk, members,
               _sweep(mask, nq, nk, bq, bk, "q")),
         in_specs=[
@@ -1441,6 +1562,9 @@ def _bwd_grouped(q, k, v, bias, lse, do, out, d, block_q, block_k, mask,
                 ),
             ),
             q_side, q_side,
+            *selection(
+                lambda n, c, kt, m, qt: (n, first_q(kt, qt), kt)
+            ),
         ],
         out_specs=[kv_side, kv_side],
         out_shape=[
@@ -1449,7 +1573,7 @@ def _bwd_grouped(q, k, v, bias, lse, do, out, d, block_q, block_k, mask,
         ],
         scratch_shapes=[pltpu.VMEM((bk, kvb * d), jnp.float32)] * 2,
         name=_name("bwd_dkv", mask, d, d, group), **names,
-    )(q, k, v, bias, lse, do, out)
+    )(*operands)
     return dq, dk, dv
 
 
@@ -1514,6 +1638,38 @@ def _flash_bwd(d, dv, block_q, block_k, mask, interpret, residuals, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
+def _flash_selected(q, k, v, bias, selection, flags, d, dv, block_q, block_k,
+                    mask, interpret):
+    """The selected call: (out, lse [B·H, 1, S]). The log-sum-exp is an
+    OUTPUT here — a caller's second loss reads the probabilities the
+    kernels normalised by — with no gradient of its own: whoever reads it
+    reads a detached value, and the backward drops its cotangent."""
+    return _fwd(q, k, v, bias, d, dv, block_q, block_k, mask, interpret,
+                (selection, flags))
+
+
+def _flash_selected_fwd(q, k, v, bias, selection, flags, d, dv, block_q,
+                        block_k, mask, interpret):
+    out, lse = _fwd(q, k, v, bias, d, dv, block_q, block_k, mask, interpret,
+                    (selection, flags))
+    # the backward's selection IS the forward's: a residual, not a replay
+    return (out, lse), (q, k, v, bias, selection, flags, out, lse)
+
+
+def _flash_selected_bwd(d, dv, block_q, block_k, mask, interpret, residuals,
+                        g):
+    q, k, v, bias, selection, flags, out, lse = residuals
+    dq, dk, dv_ = _bwd(q, k, v, bias, lse, g[0], out, d, dv, block_q,
+                       block_k, mask, interpret, (selection, flags))
+    return dq, dk, dv_, jnp.zeros_like(bias), *(
+        np.zeros(x.shape, jax.dtypes.float0) for x in (selection, flags)
+    )
+
+
+_flash_selected.defvjp(_flash_selected_fwd, _flash_selected_bwd)
+
+
 def _flash_local(q, k, v, bias, d, dv, block_q, block_k, mask, interpret):
     """The op on [B, S, H·D] operands as ONE device sees them (the whole
     arrays off-mesh, this device's batch/head shard under shard_map)."""
@@ -1537,6 +1693,7 @@ def flash_attention(
     causal: bool = False,
     band: Optional[int] = None,
     block_diffusion: Optional[int] = None,
+    selection: Optional[jnp.ndarray] = None,  # [B, S, S] int8, rows queries
 ) -> jnp.ndarray:
     """Exact fused attention; drop-in for dense/blockwise attention.
 
@@ -1566,7 +1723,15 @@ def flash_attention(
     no power of two (28 heads over 4: seven) gets a whole group a program
     — its kv head fetched once, dk / dv summed inside the program — and is
     taken at head width 128 alone (at 64 two heads share a lane tile and
-    a kv head: the group must be even).
+    a kv head: the group must be even). ``selection``: a mask of its own
+    that is an operand — query t sees the keys s <= t where this
+    [B, S, S] (int8, rows queries) is not 0; see "selected tiles". The call
+    then returns (out, lse [B, H, S] float32): the log-sum-exp over the
+    selected keys is handed out DETACHED (for a loss on the probabilities,
+    computed outside), and the backward kernels read the selection the
+    forward read (a residual; under a layer remat policy it is kept where
+    the caller named it ``attn_selection``, else replayed). One device: no
+    ``mesh``.
     """
     if interpret is None:
         interpret = pallas_interpret()
@@ -1574,7 +1739,27 @@ def flash_attention(
     kvh, dv = v.shape[-2:]
     if bias is None:
         bias = jnp.zeros((b, s), jnp.float32)
-    mask = _mask_of(causal, band, s, block_diffusion)
+    mask = _mask_of(causal, band, s, block_diffusion, selection is not None)
+    if mask.selected:
+        if mesh is not None:
+            raise ValueError(
+                "selected: a call with a ``selection`` runs on one device "
+                "(no mesh)"
+            )
+        # the caller names the selection where it MAKES it ("attn_selection",
+        # models/remat.py: kept with the kernels' operands): every reader of
+        # it then reads the one kept array — named here it would be kept a
+        # second time for whoever else reads the caller's own value
+        flags = selection_tile_flags(selection, block_q, block_k).reshape(-1)
+        q, k, v = (
+            checkpoint_name(x.reshape(b, s, -1), "flash_qkv")
+            for x in (q, k, v)
+        )
+        out, lse = _flash_selected(
+            q, k, v, bias[:, None, :].astype(jnp.float32), selection, flags,
+            d, dv, block_q, block_k, mask, interpret,
+        )
+        return out.reshape(b, s, h, dv), lse.reshape(b, h, s)
     if mask.blocks is not None:
         block_q, block_k = _bd_blocks(mask.blocks, block_q, block_k)
     op = functools.partial(

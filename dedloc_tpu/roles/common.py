@@ -30,6 +30,13 @@ from dedloc_tpu.models.deepseek_v3 import (
     deepseek_v3_train_tflops_per_sample,
     deepseek_v3_weight_decay_mask,
 )
+from dedloc_tpu.models.keye_vl2 import (
+    KeyeVL2Config,
+    KeyeVL2ForCausalLM,
+    keye_vl2_loss,
+    keye_vl2_train_tflops_per_sample,
+    keye_vl2_weight_decay_mask,
+)
 from dedloc_tpu.models.laguna import (
     LagunaConfig,
     LagunaForCausalLM,
@@ -105,6 +112,10 @@ class ModelFamily:
     # backward can add into the float32 accumulator in place: the loss then
     # takes ``grad_sinks`` (``parallel/train_step.GradSinkLoss``); None: none
     grad_sink_mask: Optional[Callable] = None
+    # the model reads a token's positions from the batch: its synthetic
+    # source takes the run's ``image_token_share`` and builds
+    # ``position_ids`` and ``loss_weights`` (``data/causal_lm.py``)
+    batch_positions: bool = False
 
 
 def _albert_loss(model: AlbertForPreTraining) -> Callable:
@@ -181,6 +192,20 @@ def _ouro_batches(cfg, batch_size: int, seq_length: int,
     return synthetic_causal_lm_batches(
         cfg.vocab_size, batch_size,
         min(seq_length, cfg.max_position_embeddings), seed,
+    )
+
+
+def _keye_batches(cfg, batch_size: int, seq_length: int, seed: int,
+                  image_token_share: float = 0.0,
+                  ) -> Iterator[Dict[str, np.ndarray]]:
+    from dedloc_tpu.data.causal_lm import synthetic_causal_lm_batches
+
+    # three position streams a token and the weight of every label, with
+    # image spans at the run's share (0: text alone)
+    return synthetic_causal_lm_batches(
+        cfg.vocab_size, batch_size,
+        min(seq_length, cfg.max_position_embeddings), seed,
+        image_token_share=image_token_share, positions=True,
     )
 
 
@@ -273,6 +298,21 @@ LAGUNA = dataclasses.replace(
         "attn.gate_mean.sliding_attention",
     ),
 )
+KEYE_VL2 = dataclasses.replace(
+    SMALLTHINKER,  # the same counter and sinks; no bias: no sign rule
+    config=KeyeVL2Config, module=KeyeVL2ForCausalLM,
+    loss=_without_rng(keye_vl2_loss),
+    synthetic_batches=_keye_batches, batch_positions=True,
+    tflops_per_sample=keye_vl2_train_tflops_per_sample,
+    weight_decay_mask=keye_vl2_weight_decay_mask,
+    step_gauges=(
+        "moe.load_max_over_mean", "moe.local_slot_share",
+        "moe.grad_sink_leaves", "moe.compute_copy_leaves",
+        "moe.bulk_row_share", "attn.select_kept_share",
+        "attn.select_tile_share", "attn.index_peak", "loss.index_kl",
+        "data.image_token_share",
+    ),
+)
 MODEL_FAMILIES: Dict[str, ModelFamily] = {
     "tiny": ALBERT, "large": ALBERT, "ouro_tiny": OURO, "ouro_2p6b": OURO,
     "kanana2_tiny": DEEPSEEK_V3, "kanana2_30b_a3b": DEEPSEEK_V3,
@@ -280,6 +320,7 @@ MODEL_FAMILIES: Dict[str, ModelFamily] = {
     "smallthinker_tiny": SMALLTHINKER, "smallthinker_21b_a3b": SMALLTHINKER,
     "sdar_tiny": SDAR_MOE, "sdar_30b_a3b": SDAR_MOE,
     "laguna_tiny": LAGUNA, "laguna_xs2_33b_a3b": LAGUNA,
+    "keye_vl2_tiny": KEYE_VL2, "keye_vl2_30b_a3b": KEYE_VL2,
 }
 
 
@@ -733,6 +774,8 @@ def drop_collator_keys(batch: Dict[str, np.ndarray]) -> Dict[str, jnp.ndarray]:
             "mlm_weights",
             "sop_labels",
         )
+    elif "position_ids" in batch:  # causal LM with positions from the data
+        keep = ("input_ids", "labels", "position_ids", "loss_weights")
     elif "loss_weights" in batch:  # block diffusion: x~, x and 1 / t
         keep = ("input_ids", "labels", "loss_weights")
     elif "labels" in batch:  # causal LM: inputs and next-token labels

@@ -389,9 +389,16 @@ def _make_batches(
     seed = peer_shuffle_seed(public_key)  # per-peer independent shuffling
     batch_size = slice_batch or args.training.per_device_batch_size
     family = model_family(cfg)
+    share = args.training.image_token_share
+    if share > 0 and not family.batch_positions:
+        raise ValueError(
+            f"model_size {args.training.model_size!r} reads no positions "
+            f"from its batches (--training.image_token_share {share})"
+        )
     if not args.training.streaming_files and not args.training.dataset_path:
+        data = {"image_token_share": share} if family.batch_positions else {}
         return family.synthetic_batches(
-            cfg, batch_size, args.training.seq_length, seed
+            cfg, batch_size, args.training.seq_length, seed, **data
         )
     if family is not ALBERT:
         raise ValueError(

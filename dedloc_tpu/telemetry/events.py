@@ -21,6 +21,8 @@ ALLREDUCE_STRAGGLERS = "allreduce.stragglers"
 ATTN_BAND_TILE_SHARE = "attn.band_tile_share"
 ATTN_BAND_VISIBLE_SHARE = "attn.band_visible_share"
 ATTN_BD_TILE_SHARE = "attn.bd_tile_share"
+ATTN_SELECT_KEPT_SHARE = "attn.select_kept_share"
+ATTN_SELECT_TILE_SHARE = "attn.select_tile_share"
 AVG_ROUND = "avg.round"
 AVG_TOPOLOGY_FALLBACK = "avg.topology.fallback"
 AVG_TOPOLOGY_FALLBACKS = "avg.topology.fallbacks"
@@ -47,6 +49,7 @@ CKPT_SHARDS_FETCHED = "ckpt.shards_fetched"
 CKPT_SHARDS_RESUMED = "ckpt.shards_resumed"
 CKPT_SHARDS_SERVED = "ckpt.shards_served"
 CKPT_VERIFY_FAILURES = "ckpt.verify_failures"
+DATA_IMAGE_TOKEN_SHARE = "data.image_token_share"
 DIFFUSION_MASKED_SHARE = "diffusion.masked_share"
 DIFFUSION_MASKED_TOKENS = "diffusion.masked_tokens"
 EXPERT_ANNOUNCES = "expert.announces"
@@ -65,6 +68,7 @@ LEDGER_DISCREPANCIES = "ledger.discrepancies"
 LEDGER_RECEIPT = "ledger.receipt"
 LEDGER_RECEIPTS = "ledger.receipts"
 LINK_STATS = "link.stats"
+LOSS_INDEX_KL = "loss.index_kl"
 METRICS_MALFORMED_RECORDS = "metrics.malformed_records"
 MM_FORM_GROUP = "mm.form_group"
 MM_JOIN_SERVE = "mm.join.serve"
@@ -250,8 +254,12 @@ GAUGES = frozenset({
     "attn.band_tile_share",
     "attn.band_visible_share",
     "attn.bd_tile_share",
+    "attn.select_kept_share",
+    "attn.select_tile_share",
+    "data.image_token_share",
     "diffusion.masked_share",
     "expert.load_ewma",
+    "loss.index_kl",
     "moe.bias_abs_max",
     "moe.bulk_row_share",
     "moe.compute_copy_leaves",
@@ -358,6 +366,7 @@ EMITTED = COUNTERS | GAUGES | HISTOGRAMS | EVENTS
 # declared dynamic-name families (emit-site pragmas)
 EMITTED_PREFIXES = (
     "attn.gate_mean.",
+    "attn.index_peak.",
     "link.",
     "lm.exit_prob.",
     "lm.loss.",

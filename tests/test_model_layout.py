@@ -2,13 +2,14 @@
 no parameter.
 
 (a) By ``ast``: ``models/decoder.py`` and ``models/remat.py`` import no other
-module of ``dedloc_tpu/models/``; the six decoder files import from
+module of ``dedloc_tpu/models/``; the seven decoder files import from
 ``dedloc_tpu.models`` nothing but those two, and no private name of theirs.
 (b) For every name of ``roles/common.MODEL_FAMILIES``: ``model_family`` agrees
 with itself on a name, a config and a module, and the parameter tree of
 ``jax.eval_shape(model.init, ...)`` equals ``fixtures/model_param_trees.json``,
 recorded from the tree BEFORE ``models/decoder.py`` existed (PR 43's parent;
-a model added since — Laguna, PR 47 — from the tree that added it)
+a model added since — Laguna, PR 47; Keye-VL-2.0, PR 51 — from the tree that
+added it)
 by this file's own ``param_tree``:
 
     git archive <commit> | tar -x -C <dir>; cd <dir>
@@ -31,12 +32,12 @@ FIXTURE = os.path.join(
 )
 SHARED = ("decoder", "remat")
 DECODERS = ("ouro", "deepseek_v3", "lfm2_moe", "smallthinker", "sdar_moe",
-            "laguna")
+            "laguna", "keye_vl2")
 NAMES = (
     "tiny", "large", "ouro_tiny", "ouro_2p6b", "kanana2_tiny",
     "kanana2_30b_a3b", "lfm2_tiny", "lfm2_24b_a2b", "smallthinker_tiny",
     "smallthinker_21b_a3b", "sdar_tiny", "sdar_30b_a3b", "laguna_tiny",
-    "laguna_xs2_33b_a3b",
+    "laguna_xs2_33b_a3b", "keye_vl2_tiny", "keye_vl2_30b_a3b",
 )
 
 

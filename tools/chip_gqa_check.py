@@ -51,6 +51,21 @@ differ, ``d_gate`` by relative L2) and timed against the bytes a call moves:
     chiprun --chips 1 -- python tools/chip_gqa_check.py --heads 64 \
         --kv-heads 8 --head-dim 128 --seq 8192 --band --conv 0 --head-gate 1
 
+A SELECTION (``flash_sel_*``: the visibility an operand, an int8 [S, S]
+mask read tile by tile; ``benchmark/flops_keye.py``'s cost over the tiles
+that hold a selected pair) by ``--select-topk N --select random|prefix`` IN
+PLACE of the bands — Keye-VL-2.0's 32 query heads over 4 kv heads of 128 at
+S=16,384, 2,048 keys a query: ``random`` keeps N of each query's keys drawn
+uniformly (every tile of the triangle holds a pair), ``prefix`` the first N
+(window-shaped: the tiles past the N-th key hold nothing and run no body) —
+and, beside the kernels, ``select_ms``: the host-clock ms a call of the
+layer's index-score pass + exact top-N (``models/keye_vl2.select_keys``) on
+random indexer operands at the published 16 x 64:
+
+    chiprun --chips 1 -- python tools/chip_gqa_check.py --heads 32 \
+        --kv-heads 4 --head-dim 128 --seq 16384 --select-topk 2048 \
+        --select random prefix --conv 0
+
 Prints one JSON line; exit code 1 if an error exceeds 0.02 relative L2
 (bf16 rounding of the operands alone is ~0.004) or a gated element's bits
 differ."""
@@ -65,6 +80,7 @@ import os
 import statistics
 import sys
 import tempfile
+import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -73,6 +89,7 @@ import jax.numpy as jnp
 
 from benchmark.flops import roofline_seconds
 from benchmark.flops_lfm2 import conv_kernel_cost, gqa_kernel_cost
+from benchmark.flops_keye import sel_kernel_cost, triangle_tiles
 from benchmark.flops_sdar import bd_kernel_cost
 from benchmark.flops_smallthinker import band_kernel_cost
 from benchmark.peaks import chip_peaks
@@ -94,11 +111,51 @@ class Blocks(int):
     """A mask spec beside a band's length: the two-stream rule's blocks."""
 
 
+class Selected:
+    """A mask spec: ``topk`` keys a query, chosen ``how`` (random |
+    prefix), as the int8 [1, S, S] ``selection`` and the share of the
+    triangle's 512 x 512 tiles that hold a selected pair."""
+
+    def __init__(self, topk: int, how: str, seq: int):
+        from dedloc_tpu.ops.flash_attention import selection_tile_flags
+        from dedloc_tpu.ops.index_select import top_k_mask
+
+        self.topk, self.how = topk, how
+        position = jnp.arange(seq)
+        rows = min(512, seq)
+
+        def block(args):  # 512 query rows at a time
+            first, key = args
+            causal = position[None, :] <= first + jnp.arange(rows)[:, None]
+            scores = (
+                jax.random.uniform(key, (rows, seq)) if how == "random"
+                else jnp.broadcast_to(-position.astype(jnp.float32),
+                                      (rows, seq))
+            )
+            return top_k_mask(scores, causal, topk).astype(jnp.int8)
+
+        self.selection = jax.jit(lambda: jax.lax.map(block, (
+            jnp.arange(0, seq, rows),
+            jax.random.split(jax.random.PRNGKey(7), seq // rows),
+        )).reshape(1, seq, seq))()
+        self.tile_share = float(
+            jnp.sum(selection_tile_flags(self.selection, 512, 512))
+        ) / triangle_tiles(seq, 512, 512)
+
+    def __str__(self):
+        return f"{self.how}_{self.topk}"
+
+
 def attention_cost(kernel: str, shape, band, block_k: int = 512):
     """(FLOPs, bytes) of one ``flash_gqa_*`` / ``flash_band_*`` /
     ``flash_bd_*`` call at query tiles of 512 and key tiles of
     ``block_k``."""
     s, h, kv, d = shape
+    if isinstance(band, Selected):
+        return sel_kernel_cost(
+            f"flash_sel_{kernel}", B, h, kv, s, d, 512, block_k,
+            band.tile_share,
+        )
     if isinstance(band, Blocks):
         return bd_kernel_cost(
             f"flash_bd_{kernel}", B, h, kv, s // 2, d, 512, block_k, int(band)
@@ -163,7 +220,9 @@ def dense(q, k, v, band):
     ``jax.checkpoint`` (28 heads of 16,384 x 16,384 scores do not fit)."""
     s, group = q.shape[1], q.shape[2] // k.shape[2]
     i = jnp.arange(s)
-    if isinstance(band, Blocks):
+    if isinstance(band, Selected):
+        seen = band.selection[0] != 0
+    elif isinstance(band, Blocks):
         # [noisy ; clean]: a clean query sees the clean blocks up to its
         # own, a noisy one those BEFORE its own and its own noisy block
         clean, blk = i >= s // 2, (i % (s // 2)) // int(band)
@@ -235,6 +294,15 @@ def main(argv=None) -> int:
         help="key tiles to run every band at (query tiles stay 512); a "
              "tile other than 512 is tagged .bk<n>",
     )
+    parser.add_argument(
+        "--select-topk", type=int, default=0,
+        help="check the selected kernels at this many keys a query IN PLACE "
+             "of the bands, under each selection of --select",
+    )
+    parser.add_argument(
+        "--select", nargs="+", default=["random"],
+        choices=("random", "prefix"),
+    )
     parser.add_argument("--conv", type=int, choices=(0, 1), default=1)
     parser.add_argument("--head-gate", type=int, choices=(0, 1), default=0)
     opts = parser.parse_args(argv)
@@ -243,6 +311,8 @@ def main(argv=None) -> int:
     bands = [None if b == "none" else int(b) for b in opts.band]
     if opts.block_diffusion:
         bands = [Blocks(opts.block_diffusion)]
+    if opts.select_topk:
+        bands = [Selected(opts.select_topk, how, S) for how in opts.select]
 
     keys = jax.random.split(jax.random.PRNGKey(0), 8)
     q, w = (jax.random.normal(x, (B, S, H, D), jnp.float32) for x in keys[:2])
@@ -261,6 +331,11 @@ def main(argv=None) -> int:
 
     def flash_at(block_k):
         def flash(q, k, v, band):
+            if isinstance(band, Selected):
+                return flash_attention(
+                    q, k, v, selection=band.selection,
+                    block_k=block_k,
+                )[0]
             if isinstance(band, Blocks):
                 return flash_attention(
                     q, k, v, block_diffusion=int(band), block_k=block_k
@@ -276,6 +351,8 @@ def main(argv=None) -> int:
         family = "flash_gqa" if band is None else "flash_band"
         if isinstance(band, Blocks):
             tag, family = f"block_diffusion_{band}", "flash_bd"
+        if isinstance(band, Selected):
+            tag, family = f"selected_{band}", "flash_sel"
         if block_k != 512:
             tag += f".bk{block_k}"
         flash = flash_at(block_k)
@@ -310,6 +387,31 @@ def main(argv=None) -> int:
                 kernels[f"{tag}.one_head"] = device_times(
                     traced_ops(lambda: single(bf(q), bf(k), bf(v))), costs
                 )
+
+    extra = {}
+    if opts.select_topk:
+        from dedloc_tpu.models.keye_vl2 import KeyeVL2Config, select_keys
+
+        cfg = KeyeVL2Config(index_topk=opts.select_topk)
+        q_index = bf(jax.random.normal(
+            keys[4], (B, S, cfg.index_n_heads, cfg.index_head_dim)
+        ))
+        k_index = bf(jax.random.normal(keys[5], (B, S, cfg.index_head_dim)))
+        weights = jax.random.normal(keys[6], (B, S, cfg.index_n_heads))
+        select = jax.jit(lambda *x: select_keys(cfg, *x))
+        chosen = jax.block_until_ready(select(q_index, k_index, weights))
+        start = time.perf_counter()
+        for _ in range(5):
+            chosen = select(q_index, k_index, weights)
+        jax.block_until_ready(chosen)
+        extra["select_ms"] = (time.perf_counter() - start) / 5 * 1e3
+        extra["select_rows_ok"] = bool(jnp.all(
+            jnp.sum(chosen[0], axis=-1, dtype=jnp.int32)
+            == jnp.minimum(jnp.arange(S) + 1, opts.select_topk)
+        ))
+        extra["select_tile_share"] = {
+            str(band): band.tile_share for band in bands
+        }
 
     if opts.conv:
         bcu = jax.random.normal(keys[4], (B, S, 3 * HIDDEN), jnp.float32)
@@ -373,7 +475,7 @@ def main(argv=None) -> int:
     print(json.dumps({
         "device": jax.devices()[0].device_kind,
         "shape": {"attention": [B, S, H, KV, D], "conv": [B, S, 3 * HIDDEN]},
-        "relative_l2": errors, "kernels": kernels,
+        "relative_l2": errors, "kernels": kernels, **extra,
     }))
     exact = errors.get("gate.out_bits_differ", 0.0) == 0.0
     return 0 if exact and max(errors.values(), default=0.0) <= 0.02 else 1

@@ -18,7 +18,11 @@ that gained arguments) beside its own two, ``laguna_kernels`` and
 (under a ``GroupedQueryAttention`` whose gate became a kernel pair and a
 ``_pallas_outputs_saveable`` that reads a kernel's name) beside
 ``laguna_accumulate_step``, which it changed, and its own
-``head_gate_kernels``."""
+``head_gate_kernels``; PR 51 all sixteen (under flash kernels, an ``attend``,
+a ``GroupedQueryAttention`` and an ``apply_rope`` that gained a selection
+and per-token tables: the ORDER of two multiplies in ``apply_rope`` moved
+Ouro's and Laguna's text until it was put back) beside its own
+``sel_kernels`` and ``keye_accumulate_step``."""
 from __future__ import annotations
 
 import hashlib

@@ -15,13 +15,15 @@ Each line: {"program", "compile_s", "tpu_custom_calls", "flash_fwd_forms",
 "flash_windows", "layer_body_copies", "memory"} (and, for the programs of
 ``COUNT_KERNEL_CALLS``, "kernel_calls": call sites by kernel name; for the
 expert programs, "expert_grad_passes": ``expert_grad_passes``' counts; for
-the six decoder programs of ``LM_CELLS``, "remat_policy": the layer policy
+the seven decoder programs of ``LM_CELLS``, "remat_policy": the layer policy
 they were built under — the model's default, or ``<PREFIX>_REMAT`` from the
 environment, e.g. ``SMALLTHINKER_REMAT=kernel_outputs``: read ``memory``'s
 ``temp_bytes`` under both before asking a chip for the stash's room; for
 ``laguna_accumulate_step``, "attn_gate_float32_mb": ``scoped_float32_mb`` of
 the per-head gate's scope, and in its ``kernel_calls`` the gate's own pair —
-``head_gate_fwd`` 10 for five layers: the forward is replayed, PR 48) —
+``head_gate_fwd`` 10 for five layers: the forward is replayed, PR 48; for
+``keye_accumulate_step``, "largest_buffers_mb": the largest array shapes the
+compiled module names — nothing [heads, S, S], PR 51) —
 ``flash_windows`` is each flash kernel's lane window beside its column
 block, from the call's metadata (``"block"`` for a call that carries none:
 D=64, D=128; its head counts for a grouped-query call);
@@ -293,6 +295,27 @@ def laguna_kernels(device):
     )
 
 
+def sel_kernels(device):
+    """The selected kernels at the Keye cell's shape: 32 query heads over 4
+    kv heads of 128 (a group of eight), S=16,384 in 32 x 32 tiles of 512
+    over the causal triangle (528 tiles), the int8 [S, S] selection read
+    tile by tile beside q / k / v and the tile flags from SMEM — fwd+bwd."""
+    from dedloc_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v, selection):
+        out, _lse = flash_attention(
+            q, k, v, selection=selection
+        )
+        return jnp.sum(out.astype(jnp.float32))
+
+    q = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 16384, 4, 128), jnp.bfloat16)
+    selection = jax.ShapeDtypeStruct((1, 16384, 16384), jnp.int8)
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_on_device(device, (q, kv, kv, selection))
+    )
+
+
 def head_gate_kernels(device):
     """The per-head output gate's kernel pair alone at the Laguna cell's two
     shapes: (1, 8192, 64 x 128) and (1, 8192, 48 x 128), bf16 context, float32
@@ -366,6 +389,7 @@ LM_CELLS = {
     ),
     "sdar_accumulate_step": ("sdar_30b_a3b_s4096.json", "SDAR"),
     "laguna_accumulate_step": ("laguna_xs2_33b_a3b_s8192.json", "LAGUNA"),
+    "keye_accumulate_step": ("keye_vl2_30b_a3b_s16384.json", "KEYE"),
 }
 
 
@@ -499,7 +523,7 @@ def flash_fwd_forms(lowered_text: str) -> dict:
     site inside a scan body counts once; a remat replay is a site more."""
     calls = [
         line for line in lowered_text.splitlines()
-        if re.search(r'kernel_name = "flash_(causal_|mla_|gqa_|band_|bd_)?fwd"', line)
+        if re.search(r'kernel_name = "flash_(causal_|mla_|gqa_|band_|bd_|sel_)?fwd"', line)
     ]
     # the serialized kernel body on the same line is base64: no "_" in it
     one_tile = sum("one_tile" in line for line in calls)
@@ -687,17 +711,58 @@ def _tile_loops(hlo_text: str, shapes: set) -> dict:
     return {"tile_loops": loops, "fused_adds": fused, "loose_adds": loose}
 
 
+def keye_accumulate_step(device):
+    """Keye-VL-2.0-30B-A3B's language model at one chip's share
+    (``benchmark/configs/keye_vl2_30b_a3b_s16384.json``; ``KEYE_LAYERS`` /
+    ``KEYE_BATCH`` / ``KEYE_REMAT`` size another cut): four unrolled layers
+    at S=16,384 — the indexer's score pass and exact top-2,048 a block of
+    512 query rows at a time, the selected kernels at a group of eight
+    reading the int8 selection, the indexer's loss a block of 256 rows at a
+    time, every layer routed (SwiGLU experts, 8 of 128 held) — three
+    position streams and a weight a label from the batch, the untied chunked
+    head. Its row carries ``largest_buffers_mb``: nothing [heads, S, S]."""
+    args, model, state, ids = _lm_model_and_state(
+        *LM_CELLS["keye_accumulate_step"]
+    )
+    return _lm_accumulate_step(
+        device, args, model, state, ids,
+        position_ids=jnp.zeros((3,) + ids.shape, jnp.int32),
+        loss_weights=jnp.zeros(ids.shape, jnp.float32),
+    )
+
+
+def largest_buffers_mb(hlo_text: str, count: int = 6) -> list:
+    """The largest array shapes a compiled module names, [[shape, MB], ...]:
+    what a program materialises at most (a [heads, S, S] tensor would lead
+    the list)."""
+    sizes = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
+             "u32": 4, "f32": 4}
+    found = {}
+    for dtype, dims in re.findall(r"\b(pred|s8|u8|bf16|f16|s32|u32|f32)"
+                                  r"\[([0-9,]+)\]", hlo_text):
+        n = sizes[dtype]
+        for d in dims.split(","):
+            n *= int(d)
+        found[f"{dtype}[{dims}]"] = n / 2**20
+    return [
+        [shape, round(mb, 1)]
+        for shape, mb in sorted(found.items(), key=lambda kv: -kv[1])[:count]
+    ]
+
+
 # programs whose row also carries ``kernel_calls`` (the others print the
 # rows they always did), and those with a routed expert layer, whose row
 # carries ``expert_grad_passes``
 COUNT_KERNEL_CALLS = {"gqa_kernels", "lfm2_accumulate_step", "band_kernels",
                       "smallthinker_accumulate_step", "bd_kernels",
                       "sdar_accumulate_step", "laguna_kernels",
-                      "head_gate_kernels", "laguna_accumulate_step"}
+                      "head_gate_kernels", "laguna_accumulate_step",
+                      "sel_kernels", "keye_accumulate_step"}
 COUNT_EXPERT_GRAD_PASSES = {"kanana_accumulate_step", "lfm2_accumulate_step",
                             "smallthinker_accumulate_step",
                             "sdar_accumulate_step",
-                            "laguna_accumulate_step"}
+                            "laguna_accumulate_step",
+                            "keye_accumulate_step"}
 NO_V5E = 3  # exit code: nothing to compile with, which is not a failure
 
 
@@ -708,6 +773,7 @@ PROGRAMS = {
         gqa_kernels, lfm2_accumulate_step, band_kernels,
         smallthinker_accumulate_step, bd_kernels, sdar_accumulate_step,
         laguna_kernels, laguna_accumulate_step, head_gate_kernels,
+        sel_kernels, keye_accumulate_step,
     )
 }
 
@@ -749,6 +815,8 @@ def main(argv=None) -> int:
             extra["attn_gate_float32_mb"] = scoped_float32_mb(
                 compiled_text, "attn_gate"
             )
+        if name == "keye_accumulate_step":
+            extra["largest_buffers_mb"] = largest_buffers_mb(compiled_text)
         if name in LM_CELLS:
             # the layer policy the program was built under; ``memory`` below
             # is what it costs (``temp_bytes``: the stash is inside it)
