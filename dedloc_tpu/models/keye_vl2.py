@@ -52,9 +52,11 @@ sort), as an int8 [B, S, S] mask that the three ``flash_sel_*`` kernels read
 tile by tile beside q / k / v (``ops/flash_attention.py``, "selected tiles";
 named ``attn_selection`` for the remat policies: kept from
 ``kernel_operands`` up, so the backward kernels read the forward's own
-array). Nothing of size [heads, S, S] exists: the indexer's loss takes pbar
-a block of query rows at a time from the kernels' log-sum-exp
-(``index_loss``, each block under ``jax.checkpoint``). Scopes in a trace:
+array). Nothing of size [heads, S, S] exists: the indexer's loss rebuilds
+pbar from the kernels' log-sum-exp a (query tile, key tile) pair of the
+causal triangle at a time, in VMEM (``index_loss``: a kernel pair of its own,
+``ops/index_loss.py``; behind ``"dense"`` attention a loop over blocks of
+query rows, each under ``jax.checkpoint``). Scopes in a trace:
 ``dsa_index`` (the score passes), ``dsa_select``, ``dsa_loss``, ``mrope``,
 ``moe_routed``.
 
@@ -93,15 +95,18 @@ from dedloc_tpu.models.decoder import (
 )
 from dedloc_tpu.models.remat import remat_layer
 from dedloc_tpu.ops.flash_attention import selection_tile_flags, visited_tiles
+from dedloc_tpu.ops.index_loss import index_loss_rows
 from dedloc_tpu.ops.index_select import top_k_mask
 
 # layers a scan step runs, unrolled: ``models/sdar_moe.py``'s reason (the
 # routed loop's gradient sinks are the accumulator's own leaves)
 SCAN_PERIOD = 4
 # query rows a step of the index-score + top-k pass / of the indexer's loss
-# takes: what bounds their transients ([rows, 16, S] and [32, rows, S]
-# float32). They DIFFER, and a device trace tells the two passes' loops apart
-# by that (the selection cut into their blocks, ``s8[blocks, rows, S]``:
+# (its block loop, the ``"dense"`` path: behind the flash kernels the loss is
+# ``ops/index_loss.py``'s kernels and no loop) takes: what bounds their
+# transients ([rows, 16, S] and [32, rows, S] float32). They DIFFER, and a
+# device trace tells the two passes' loops apart by that (the selection cut
+# into their blocks, ``s8[blocks, rows, S]``:
 # ``benchmark/reducers/keye_block_loop_time.py`` reads both from here and
 # reports neither where they coincide)
 INDEX_BLOCK_ROWS = 256
@@ -283,10 +288,25 @@ def index_loss(cfg, q_index, k_index, weights, selection, q, k, lse):
     recomputed a block of query rows at a time from q, k [B, S, heads, D]
     (as the kernels read them) and the kernels' ``lse`` [B, H, S], all
     DETACHED; the gradient reaches ``q_index``, ``k_index`` and ``weights``
-    alone. Every block runs under ``jax.checkpoint``: the backward recomputes
-    it from its inputs, nothing [H, rows, S] is kept."""
+    alone. Behind the flash kernels (``cfg.attention_impl == "flash"``) the
+    loss is a kernel pair of its own over the tiles of the causal triangle
+    (``ops/index_loss.py``); behind ``"dense"`` attention it is this loop
+    over blocks of ``INDEX_LOSS_BLOCK_ROWS`` query rows — the kernels'
+    oracle — each block under ``jax.checkpoint``: the backward recomputes it
+    from its inputs, nothing [H, rows, S] is kept."""
     B, S, H, D = q.shape
     KV = k.shape[2]
+    if cfg.attention_impl == "flash":
+        # the same loss as Pallas kernels over the causal triangle's tiles
+        # (``ops/index_loss.py``): nothing [rows, J, S] or [H, rows, S]
+        # leaves VMEM, and the backward recomputes a tile, not a block
+        with jax.named_scope("dsa_loss"):
+            kl, peak = index_loss_rows(
+                q_index, k_index, weights, selection, q, k, lse,
+                block_q=cfg.attention_block_size,
+                block_k=cfg.attention_block_size,
+            )
+        return jnp.sum(kl) / (B * S), jnp.sum(peak) / (B * S)
     q, k, lse = jax.lax.stop_gradient((q, k, lse))
     rows, batch_row, _first = _blocks_of(S, B, INDEX_LOSS_BLOCK_ROWS)
     # [B, H, S] -> a block's [H, rows]
@@ -429,8 +449,11 @@ def keye_vl2_loss(model: KeyeVL2ForCausalLM, params,
     ``decoder.routed_metrics``: ``attn.select_kept_share`` (selected pairs
     over the triangle's, from the shapes), ``attn.select_tile_share`` (tiles
     of the kernels' triangle holding a selected pair, from the selection),
-    ``attn.index_peak`` [L] and ``data.image_token_share`` (labels that carry
-    no loss). ``grad_sinks`` and ``compute_copies``:
+    ``attn.index_loss_tile_share`` ((query tile, key tile) pairs the
+    indexer's loss walks over the square's, from the shapes: the causal
+    sweep of ``ops/index_loss.py``'s kernels — 528 / 1,024 at 16,384 — or
+    1.0, the block loop's whole rows), ``attn.index_peak`` [L] and
+    ``data.image_token_share`` (labels that carry no loss). ``grad_sinks`` and ``compute_copies``:
     ``decoder.expert_lm_loss``'s."""
     cfg = model.cfg
     labels = batch["labels"]
@@ -455,6 +478,9 @@ def keye_vl2_loss(model: KeyeVL2ForCausalLM, params,
     block = cfg.attention_block_size
     tiles = B * visited_tiles(S, block, block, True)
     kept = selected_pairs(cfg, S) / (S * (S + 1) // 2)
+    # the loss's kernels walk the kernels' triangle; the block loop the square
+    loss_tiles = visited_tiles(S, block, block, cfg.attention_impl == "flash")
+    loss_share = loss_tiles / visited_tiles(S, block, block, False)
     metrics = {
         "loss": loss, "loss.lm": lm, "loss.index_kl": index_kl,
         "data.image_token_share": 1.0 - jnp.mean(weights),
@@ -464,6 +490,9 @@ def keye_vl2_loss(model: KeyeVL2ForCausalLM, params,
                 r["select_tiles"]
             ) / tiles,
             "attn.index_peak": lambda _p, r: r["index_peak"],
+            "attn.index_loss_tile_share": lambda _p, _r: jnp.float32(
+                loss_share
+            ),
         }),
     }
     if cfg.emit_selection:

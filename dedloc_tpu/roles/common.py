@@ -309,8 +309,8 @@ KEYE_VL2 = dataclasses.replace(
         "moe.load_max_over_mean", "moe.local_slot_share",
         "moe.grad_sink_leaves", "moe.compute_copy_leaves",
         "moe.bulk_row_share", "attn.select_kept_share",
-        "attn.select_tile_share", "attn.index_peak", "loss.index_kl",
-        "data.image_token_share",
+        "attn.select_tile_share", "attn.index_loss_tile_share",
+        "attn.index_peak", "loss.index_kl", "data.image_token_share",
     ),
 )
 MODEL_FAMILIES: Dict[str, ModelFamily] = {

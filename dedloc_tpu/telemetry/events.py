@@ -21,6 +21,7 @@ ALLREDUCE_STRAGGLERS = "allreduce.stragglers"
 ATTN_BAND_TILE_SHARE = "attn.band_tile_share"
 ATTN_BAND_VISIBLE_SHARE = "attn.band_visible_share"
 ATTN_BD_TILE_SHARE = "attn.bd_tile_share"
+ATTN_INDEX_LOSS_TILE_SHARE = "attn.index_loss_tile_share"
 ATTN_SELECT_KEPT_SHARE = "attn.select_kept_share"
 ATTN_SELECT_TILE_SHARE = "attn.select_tile_share"
 AVG_ROUND = "avg.round"
@@ -254,6 +255,7 @@ GAUGES = frozenset({
     "attn.band_tile_share",
     "attn.band_visible_share",
     "attn.bd_tile_share",
+    "attn.index_loss_tile_share",
     "attn.select_kept_share",
     "attn.select_tile_share",
     "data.image_token_share",

@@ -12,7 +12,9 @@ def test_keye_accumulate_step_reads_a_selection_and_holds_nothing_heads_by_s_by_
     selected kernels — the int8 [S, S] selection a tile operand, the tile
     flags in SMEM — get through Mosaic at 32 query heads over 4 kv heads;
     under the model's default remat ``kernel_operands`` no kernel is
-    replayed (4 sites each: the selection is KEPT with the operands); the
+    replayed (4 sites each: the selection is KEPT with the operands), the
+    indexer's loss is its own kernel pair over the same tiles and nothing
+    of its XLA block loop is left; the
     tile loop's backward sums into the accumulator's twelve expert leaves;
     NOTHING of size [heads, S, S] is materialised — the largest array the
     compiled module names is 256 MB (the int8 selection itself, a block
@@ -20,7 +22,14 @@ def test_keye_accumulate_step_reads_a_selection_and_holds_nothing_heads_by_s_by_
     heads' bf16 ones 17 GB —; and the program's scratch beside 28 bytes a
     parameter of state with a draining snapshot (+ the held experts' bf16
     copies) stays under the 15.3 GB line."""
-    rows = _tpu_aot("sel_kernels", "keye_accumulate_step")
+    rows = _tpu_aot("sel_kernels", "index_loss_kernels",
+                    "keye_accumulate_step")
+    # the indexer's loss kernels alone (``ops/index_loss.py``): Mosaic takes
+    # the forward sweep and the one backward sweep that holds the key
+    # head's whole gradient, [16384, 128] float32, in VMEM
+    assert rows.pop("index_loss_kernels")["kernel_calls"] == {
+        "index_loss_fwd": 1, "index_loss_bwd": 1,
+    }
     heads = {"heads": 32, "kv_heads": 4}
     for row in rows.values():
         assert row["flash_windows"] == {
@@ -31,10 +40,18 @@ def test_keye_accumulate_step_reads_a_selection_and_holds_nothing_heads_by_s_by_
         "flash_sel_fwd": 1, "flash_sel_bwd_dq": 1, "flash_sel_bwd_dkv": 1,
     }
     row = rows["keye_accumulate_step"]
+    # ... and the loss's pair: one forward sweep a layer (its logZ rides in
+    # a Pallas output, which the policy keeps: no replay), one backward
     assert row["kernel_calls"] == {
         "flash_sel_fwd": 4, "flash_sel_bwd_dq": 4, "flash_sel_bwd_dkv": 4,
+        "index_loss_fwd": 4, "index_loss_bwd": 4,
     }
-    assert row["tpu_custom_calls"] == 12
+    assert row["tpu_custom_calls"] == 20
+    # no float32 [128, 16, 16384] index scores, no [4, 8, 128, 16384] main
+    # scores, no selection cut into the loss's blocks of 128 rows — what the
+    # XLA block loop made, 128 blocks a layer and direction (PR 51) — in the
+    # lowered or the compiled module
+    assert row["loss_block_transients"] == []
     grads = row["expert_grad_passes"]
     assert (grads["adds"], grads["zero_fills"], grads["held_casts"]) == (
         0, 0, 0

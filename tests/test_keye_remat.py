@@ -4,7 +4,9 @@ top-8 of up to 64 keys, tiles with nothing selected among them. The
 selection is an operand of the three flash kernels: KEPT with their other
 operands from ``kernel_operands`` (this model's default) up, REPLAYED from
 the replayed indexer below — and either way loss and every gradient leaf
-are the same bits in float32; and the kept bytes at the published widths
+are the same bits in float32, the indexer's loss through its own kernel pair
+(``ops/index_loss.py``: the model under test runs the flash path) among
+them; and the kept bytes at the published widths
 are the shapes' arithmetic. (The other four families' cases of the same
 assertions: ``tests/test_remat_operands.py``, whose helpers these are; a
 file of its own because that one is the suite's longest, ROADMAP C9.)"""
@@ -63,6 +65,27 @@ def test_the_selection_kept_or_replayed_gives_the_same_bits(policy):
         ),
         got, ref,
     )
+
+
+@pytest.mark.parametrize("policy,forward_sites", [
+    ("nothing", 2), ("kernel_outputs", 1), ("kernel_operands", 1),
+    ("whole_mixer", 1),
+])
+def test_the_loss_kernels_forward_sweep_is_kept_not_replayed(policy,
+                                                             forward_sites):
+    """Behind the flash kernels the indexer's loss is a kernel pair of its
+    own (``ops/index_loss.py``) — the bits above are ITS bits, kept or
+    replayed. Its backward sweep reads the forward's logZ and sum pbar out
+    of the forward kernel's output: a Pallas output, so every policy from
+    ``kernel_outputs`` up keeps it and the replay runs no second forward
+    sweep (one site a layer; two under ``nothing``), and one backward sweep
+    a layer either way."""
+    cfg, _matmuls, kernels = shared._sites("keye", policy)
+    layers = cfg.num_hidden_layers
+    assert cfg.attention_impl == "flash"
+    assert kernels["index_loss_fwd"] == forward_sites * layers
+    assert kernels["index_loss_bwd"] == layers
+    assert kernels["flash_sel_fwd"] == forward_sites * layers
 
 
 def test_the_selection_is_kept_with_the_operands():

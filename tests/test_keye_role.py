@@ -84,6 +84,9 @@ def test_keye_tiny_trainer_makes_global_steps(tmp_path, monkeypatch, shard,
         # top-8 of up to 32 keys: 36 + 24 x 8 selected pairs of 528
         assert rec["attn.select_kept_share"] == pytest.approx(228 / 528)
         assert rec["attn.select_tile_share"] == 1.0  # S=32: one tile
+        # the tiny preset's attention is dense: the loss's block loop takes
+        # whole rows (the kernels' causal sweep: 528 / 1,024 at 16,384)
+        assert rec["attn.index_loss_tile_share"] == 1.0
         for i in range(1, depth + 1):  # near the initialiser: nearly flat
             assert 1.0 <= rec[f"attn.index_peak.{i}"] < 1.5
         assert 0.0 <= rec["loss.index_kl"] < 0.5
@@ -129,7 +132,7 @@ def test_the_table_builds_keye():
     assert KEYE_VL2.sign_step_mask is None
     assert KEYE_VL2.grad_sink_mask is DEEPSEEK_V3.grad_sink_mask
     assert {"attn.select_kept_share", "attn.select_tile_share",
-            "attn.index_peak", "loss.index_kl",
+            "attn.index_loss_tile_share", "attn.index_peak", "loss.index_kl",
             "data.image_token_share"} <= set(KEYE_VL2.step_gauges)
     published = KeyeVL2Config.keye_vl2_30b_a3b()
     assert (published.hidden_size, published.num_attention_heads,

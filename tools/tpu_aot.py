@@ -23,7 +23,9 @@ environment, e.g. ``SMALLTHINKER_REMAT=kernel_outputs``: read ``memory``'s
 the per-head gate's scope, and in its ``kernel_calls`` the gate's own pair —
 ``head_gate_fwd`` 10 for five layers: the forward is replayed, PR 48; for
 ``keye_accumulate_step``, "largest_buffers_mb": the largest array shapes the
-compiled module names — nothing [heads, S, S], PR 51) —
+compiled module names — nothing [heads, S, S], PR 51 — and
+"loss_block_transients": ``loss_block_transients`` of the lowered and the
+compiled module — [] since the indexer's loss is a kernel pair, PR 52) —
 ``flash_windows`` is each flash kernel's lane window beside its column
 block, from the call's metadata (``"block"`` for a call that carries none:
 D=64, D=128; its head counts for a grouped-query call);
@@ -313,6 +315,34 @@ def sel_kernels(device):
     selection = jax.ShapeDtypeStruct((1, 16384, 16384), jnp.int8)
     return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         *_on_device(device, (q, kv, kv, selection))
+    )
+
+
+def index_loss_kernels(device):
+    """The indexer's loss kernels alone at the Keye cell's shape (16 index
+    heads of 64 and one key head beside 32 / 4 main heads of 128, S=16,384
+    in 512 x 512 tiles of the causal triangle, the int8 selection a tile
+    operand): the forward sweep and, from a cotangent, the one backward
+    sweep that holds the key head's whole gradient in VMEM."""
+    from dedloc_tpu.ops.index_loss import index_loss_rows
+
+    def loss(q_index, k_index, weights, selection, q, k, lse):
+        kl, _peak = index_loss_rows(q_index, k_index, weights, selection, q,
+                                    k, lse)
+        return jnp.sum(kl)
+
+    seq = 16384
+    operands = (
+        jax.ShapeDtypeStruct((1, seq, 16, 64), jnp.bfloat16),
+        jax.ShapeDtypeStruct((1, seq, 64), jnp.bfloat16),
+        jax.ShapeDtypeStruct((1, seq, 16), jnp.float32),
+        jax.ShapeDtypeStruct((1, seq, seq), jnp.int8),
+        jax.ShapeDtypeStruct((1, seq, 32, 128), jnp.bfloat16),
+        jax.ShapeDtypeStruct((1, seq, 4, 128), jnp.bfloat16),
+        jax.ShapeDtypeStruct((1, 32, seq), jnp.float32),
+    )
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_on_device(device, operands)
     )
 
 
@@ -716,9 +746,9 @@ def keye_accumulate_step(device):
     (``benchmark/configs/keye_vl2_30b_a3b_s16384.json``; ``KEYE_LAYERS`` /
     ``KEYE_BATCH`` / ``KEYE_REMAT`` size another cut): four unrolled layers
     at S=16,384 — the indexer's score pass and exact top-2,048 a block of
-    512 query rows at a time, the selected kernels at a group of eight
-    reading the int8 selection, the indexer's loss a block of 256 rows at a
-    time, every layer routed (SwiGLU experts, 8 of 128 held) — three
+    256 query rows at a time, the selected kernels at a group of eight
+    reading the int8 selection, the indexer's loss as its own kernel pair
+    over the same tiles (``ops/index_loss.py``), every layer routed (SwiGLU experts, 8 of 128 held) — three
     position streams and a weight a label from the batch, the untied chunked
     head. Its row carries ``largest_buffers_mb``: nothing [heads, S, S]."""
     args, model, state, ids = _lm_model_and_state(
@@ -750,6 +780,27 @@ def largest_buffers_mb(hlo_text: str, count: int = 6) -> list:
     ]
 
 
+def loss_block_transients(text: str, rows: int, seq: int) -> list:
+    """The float32 arrays of three or more dims — [..., ``seq``] with a dim
+    of ``rows`` — and the int8 [blocks, ``rows``, ``seq``] slabs that a
+    module's text names (HLO's ``f32[128,16,16384]`` or MLIR's
+    ``tensor<128x16x16384xf32>``): the transients of the indexer's loss
+    where it is a loop over blocks of ``rows`` query rows (the index scores
+    [rows, 16, S], the main scores [4, 8, rows, S], the selection cut into
+    its blocks). [] since the loss is a kernel pair (PR 52)."""
+    found = set()
+    for dtype, dims in re.findall(r"\b(f32|s8)\[([0-9,]+)\]", text) + [
+        (dtype, dims.replace("x", ","))
+        for dims, dtype in re.findall(r"tensor<([0-9x]+)x(f32|i8)>", text)
+    ]:
+        sizes = [int(d) for d in dims.split(",")]
+        if len(sizes) >= 3 and sizes[-1] == seq and rows in sizes[:-1] and (
+            dtype == "f32" or sizes[-2] == rows
+        ):
+            found.add(f"{dtype}[{dims}]")
+    return sorted(found)
+
+
 # programs whose row also carries ``kernel_calls`` (the others print the
 # rows they always did), and those with a routed expert layer, whose row
 # carries ``expert_grad_passes``
@@ -757,7 +808,8 @@ COUNT_KERNEL_CALLS = {"gqa_kernels", "lfm2_accumulate_step", "band_kernels",
                       "smallthinker_accumulate_step", "bd_kernels",
                       "sdar_accumulate_step", "laguna_kernels",
                       "head_gate_kernels", "laguna_accumulate_step",
-                      "sel_kernels", "keye_accumulate_step"}
+                      "sel_kernels", "keye_accumulate_step",
+                      "index_loss_kernels"}
 COUNT_EXPERT_GRAD_PASSES = {"kanana_accumulate_step", "lfm2_accumulate_step",
                             "smallthinker_accumulate_step",
                             "sdar_accumulate_step",
@@ -773,7 +825,7 @@ PROGRAMS = {
         gqa_kernels, lfm2_accumulate_step, band_kernels,
         smallthinker_accumulate_step, bd_kernels, sdar_accumulate_step,
         laguna_kernels, laguna_accumulate_step, head_gate_kernels,
-        sel_kernels, keye_accumulate_step,
+        sel_kernels, keye_accumulate_step, index_loss_kernels,
     )
 }
 
@@ -816,7 +868,13 @@ def main(argv=None) -> int:
                 compiled_text, "attn_gate"
             )
         if name == "keye_accumulate_step":
+            from dedloc_tpu.models.keye_vl2 import INDEX_LOSS_BLOCK_ROWS
+
             extra["largest_buffers_mb"] = largest_buffers_mb(compiled_text)
+            extra["loss_block_transients"] = loss_block_transients(
+                lowered_text + compiled_text, INDEX_LOSS_BLOCK_ROWS,
+                _lm_model_and_state(*LM_CELLS[name])[3].shape[1],
+            )
         if name in LM_CELLS:
             # the layer policy the program was built under; ``memory`` below
             # is what it costs (``temp_bytes``: the stash is inside it)
