@@ -298,7 +298,10 @@ class TrainingArguments:
     # a RoPE and a gate a head per kind, models/laguna.py); keye_vl2_tiny |
     # keye_vl2_30b_a3b (grouped-query attention over the keys a learned
     # indexer selects, three position streams a token from the batch,
-    # models/keye_vl2.py) —
+    # models/keye_vl2.py); kimi_linear_tiny | kimi_linear_48b_a3b (Kimi
+    # Delta Attention — a gated delta rule with a decay per channel,
+    # ops/kda.py — in three layers of four beside latent attention without
+    # RoPE, models/kimi_linear.py) —
     # roles/common.MODEL_FAMILIES is the table
     model_size: str = "large"
     # depth override (0 = the model's own): a chip's share of a deeper
@@ -313,6 +316,12 @@ class TrainingArguments:
     # every expert. Together with ``vocab_size`` (rows of the vocabulary held)
     # and ``num_hidden_layers`` it states a chip's share of a deployment.
     expert_shard: str = "0/1"
+    # "index/count": the share of every MIXER's heads this peer's chip holds,
+    # as one of ``count`` chips a layer's heads are divided over (tensor
+    # parallel by heads; models/kimi_linear.py): the projections exist for
+    # the held heads alone and the out-projection gives the mixer's partial
+    # sum. The count must divide every mixer's head count; "0/1" = every head.
+    head_shard: str = "0/1"
     # the share of every synthetic row's positions that lies in IMAGE SPANS
     # (runs of g_h x g_w ids standing for a vision tower's features: three
     # position streams a token — M-RoPE — and no loss on an image label;
@@ -331,7 +340,8 @@ class TrainingArguments:
     # kernels READ: q / k / v, the convolution's B | C | u; whole_mixer —
     # those, the stream after the mixer and a q / k norm's input, so the
     # replay runs no matmul of the mixer — is the default of smallthinker,
-    # sdar, lfm2 and laguna, and kernel_operands (keye_vl2's default: there
+    # sdar, lfm2, laguna and kimi_linear (there with a KDA kernel's q / k /
+    # v / g / beta), and kernel_operands (keye_vl2's default: there
     # with the selection the flash kernels read, int8 [B, S, S] a layer),
     # then kernel_outputs, is what a
     # peer with less memory to spare passes there; under any, the five

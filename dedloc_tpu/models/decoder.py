@@ -1,12 +1,15 @@
 """What the decoders share, written once; it imports no model's file.
 
 A decoder of this tree (``models/ouro.py``, ``deepseek_v3.py``,
-``lfm2_moe.py``, ``smallthinker.py``, ``sdar_moe.py``) is its config, the
+``lfm2_moe.py``, ``smallthinker.py``, ``sdar_moe.py``, ``laguna.py``,
+``keye_vl2.py``, ``kimi_linear.py``) is its config, the
 mixer that is its own, its layer's wiring of norms and residuals, its FLOP
 model and — where the objective is its own — its loss; a new one is one such
 module + one ``roles/common.MODEL_FAMILIES`` entry. The rest is here: the
-blocks, ONE description of who sees whom (``Visibility``) under ``attend``
-and ``GroupedQueryAttention``, the two routed layers over ONE
+blocks, ONE description of who sees whom (``Visibility``) under ``attend``,
+``GroupedQueryAttention`` and ``LatentAttention`` (two models' mixer since
+Kimi Linear: its head count and its RoPE are arguments), the two routed
+layers over ONE
 ``held_expert_ffn``, the stack (``scan_periods``), the head + loss tail and the
 leaf masks. What differs between models arrives as an argument (a name, a
 rule, a function of ``params``), never by a model's name or config class;
@@ -419,6 +422,66 @@ class GroupedQueryAttention(nn.Module):
                 ctx = (gate_heads if fused else gate_heads_xla)(ctx, gate)
         out = dense(cfg.hidden_size, cfg, self.out_name)(ctx)
         return (out, (q, k, lse)) if self.visible.selected else out
+
+
+def apply_rope_interleaved(x, cos, sin):
+    """x [B, S, H, D], rotated in pairs (2i, 2i+1) by the i-th frequency
+    (``rope_interleave``), in float32; cos, sin [S, D/2]."""
+    x32 = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
+    even, odd = x32[..., 0], x32[..., 1]
+    cos, sin = cos[None, :, None, :], sin[None, :, None, :]
+    return jnp.stack(
+        [even * cos - odd * sin, odd * cos + even * sin], axis=-1
+    ).reshape(x.shape).astype(x.dtype)
+
+
+class LatentAttention(nn.Module):
+    """Latent attention over ``heads`` query heads (None:
+    ``cfg.num_attention_heads``; a chip that holds a share of the heads
+    states how many), the one shared key head and each query's last
+    ``qk_rope_head_dim`` lanes rotated by ``rope`` where ``rotated`` (a
+    model without positional embedding in this layer — Kimi Linear — passes
+    them as they are). ``cfg``: any config with the fields read here."""
+
+    cfg: Any
+    heads: Optional[int] = None
+    rotated: bool = True
+
+    @nn.compact
+    def __call__(self, hidden, rope):
+        cfg = self.cfg
+        B, S, _ = hidden.shape
+        H, rank = self.heads or cfg.num_attention_heads, cfg.kv_lora_rank
+        nope, rot, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                         cfg.v_head_dim)
+        q = dense(H * (nope + rot), cfg, "q_proj")(hidden).reshape(
+            B, S, H, nope + rot
+        )
+        latent = dense(rank + rot, cfg, "kv_a_proj_with_mqa")(hidden)
+        kv = dense(H * (nope + dv), cfg, "kv_b_proj")(
+            RMSNorm(cfg, name="kv_a_layernorm")(latent[..., :rank])
+        ).reshape(B, S, H, nope + dv)
+        if self.rotated:  # (q's lanes first: the order kanana-2 traced)
+            cos, sin = rope
+            q_rope = apply_rope_interleaved(q[..., nope:], cos, sin)
+            k_rope = apply_rope_interleaved(
+                latent[..., rank:].reshape(B, S, 1, rot), cos, sin
+            )
+            q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+        else:
+            k_rope = latent[..., rank:].reshape(B, S, 1, rot)
+        # the ONE rotary key head, broadcast into k's 192-wide layout
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_rope, (B, S, H, rot))],
+            axis=-1,
+        )
+        v = kv[..., nope:]
+        # no optimization_barrier (decoder.GroupedQueryAttention's): offline
+        # it moves none of this program's 23 layer-body copies (PR 45)
+        ctx = attend(cfg, q, k, v, Visibility(causal=True))
+        return dense(cfg.hidden_size, cfg, "o_proj")(
+            ctx.reshape(B, S, H * dv)
+        )
 
 
 def held_expert_ffn(module: nn.Module, tokens, choice, weights,

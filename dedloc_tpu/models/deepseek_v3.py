@@ -21,8 +21,9 @@ same in plain ``jax.numpy`` — are:
 
 The program's shape: the leading dense layer(s) outside the scan, the expert
 layers stacked under ``nn.scan`` + remat as Ouro's are; the blocks, the
-routed layer (``RoutedFFN``), the loss tail and the leaf masks are
-``models/decoder.py``'s. The kernels take q and k 192 wide beside v 128 wide
+latent attention itself (``LatentAttention``: Kimi Linear runs it too, at a
+share of heads and without RoPE), the routed layer (``RoutedFFN``), the loss
+tail and the leaf masks are ``models/decoder.py``'s. The kernels take q and k 192 wide beside v 128 wide
 as they are (``ops/flash_attention.py``: two column-block widths, nothing
 padded); the one rotary key head is broadcast into k's layout first.
 
@@ -49,12 +50,11 @@ import jax.numpy as jnp
 
 from dedloc_tpu.models.decoder import (
     BIAS,
+    LatentAttention,
     RMSNorm,
     RoutedFFN,
     ScannedBlock,
     SwiGLU,
-    Visibility,
-    attend,
     dense,
     embed_tokens,
     expert_lm_loss,
@@ -144,54 +144,6 @@ class DeepseekV3Config:
         )
         base.update(overrides)
         return DeepseekV3Config(**base)
-
-
-def apply_rope_interleaved(x, cos, sin):
-    """x [B, S, H, D], rotated in pairs (2i, 2i+1) by the i-th frequency
-    (``rope_interleave``), in float32; cos, sin [S, D/2]."""
-    x32 = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
-    even, odd = x32[..., 0], x32[..., 1]
-    cos, sin = cos[None, :, None, :], sin[None, :, None, :]
-    return jnp.stack(
-        [even * cos - odd * sin, odd * cos + even * sin], axis=-1
-    ).reshape(x.shape).astype(x.dtype)
-
-
-class LatentAttention(nn.Module):
-    cfg: DeepseekV3Config
-
-    @nn.compact
-    def __call__(self, hidden, rope):
-        cfg = self.cfg
-        B, S, _ = hidden.shape
-        H, rank = cfg.num_attention_heads, cfg.kv_lora_rank
-        nope, rot, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-                         cfg.v_head_dim)
-        cos, sin = rope
-        q = dense(H * (nope + rot), cfg, "q_proj")(hidden).reshape(
-            B, S, H, nope + rot
-        )
-        latent = dense(rank + rot, cfg, "kv_a_proj_with_mqa")(hidden)
-        kv = dense(H * (nope + dv), cfg, "kv_b_proj")(
-            RMSNorm(cfg, name="kv_a_layernorm")(latent[..., :rank])
-        ).reshape(B, S, H, nope + dv)
-        q_rope = apply_rope_interleaved(q[..., nope:], cos, sin)
-        k_rope = apply_rope_interleaved(
-            latent[..., rank:].reshape(B, S, 1, rot), cos, sin
-        )
-        q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
-        # the ONE rotary key head, broadcast into k's 192-wide layout
-        k = jnp.concatenate(
-            [kv[..., :nope], jnp.broadcast_to(k_rope, (B, S, H, rot))],
-            axis=-1,
-        )
-        v = kv[..., nope:]
-        # no optimization_barrier (decoder.GroupedQueryAttention's): offline
-        # it moves none of this program's 23 layer-body copies (PR 45)
-        ctx = attend(cfg, q, k, v, Visibility(causal=True))
-        return dense(cfg.hidden_size, cfg, "o_proj")(
-            ctx.reshape(B, S, H * dv)
-        )
 
 
 class DecoderLayer(nn.Module):

@@ -60,12 +60,21 @@ def remat_policy_object(name: str):
         # replay runs neither the index-score pass nor the top-k for their
         # sake; under the rungs below it is REPLAYED — the same ops on the
         # replayed indexer's values, in the same program: bit-equal
-        # (tests/test_remat_operands.py holds every rung to that). The rung
+        # (tests/test_remat_operands.py holds every rung to that). A KDA
+        # mixer's kernels (ops/kda.py, models/kimi_linear.py) read q / k / v
+        # after convolution, SiLU and the L2 norm, the float32 log-decays g
+        # and the write strengths ("kda_operands": B·S·heads·(3·2 + 4)·128
+        # bytes + beta, 84 MB a layer at 8,192 x 8 heads) beside their
+        # outputs — o and the float32 state entering every chunk of 64
+        # tokens (67 MB a layer there): kept here and above, so the backward
+        # kernel reads what the forward one read and the replay runs the
+        # prelude for the prelude's own backward alone. The rung
         # under "whole_mixer"
         "kernel_operands": (
             jax.checkpoint_policies.save_from_both_policies(
                 jax.checkpoint_policies.save_only_these_names(
-                    "flash_qkv", "short_conv_bcu", "attn_selection"
+                    "flash_qkv", "short_conv_bcu", "attn_selection",
+                    "kda_operands",
                 ),
                 _pallas_outputs_saveable,
             )
@@ -106,6 +115,7 @@ def remat_policy_object(name: str):
                 jax.checkpoint_policies.save_only_these_names(
                     "flash_qkv", "short_conv_bcu", "mixer_residual",
                     "qk_norm_input", "attn_gate", "attn_selection",
+                    "kda_operands",
                 ),
                 _pallas_outputs_saveable,
             )

@@ -37,6 +37,14 @@ from dedloc_tpu.models.keye_vl2 import (
     keye_vl2_train_tflops_per_sample,
     keye_vl2_weight_decay_mask,
 )
+from dedloc_tpu.models.kimi_linear import (
+    KDA_GAUGES,
+    KimiLinearConfig,
+    KimiLinearForCausalLM,
+    kimi_linear_loss,
+    kimi_linear_train_tflops_per_sample,
+    kimi_linear_weight_decay_mask,
+)
 from dedloc_tpu.models.laguna import (
     LagunaConfig,
     LagunaForCausalLM,
@@ -313,6 +321,15 @@ KEYE_VL2 = dataclasses.replace(
         "attn.index_peak", "loss.index_kl", "data.image_token_share",
     ),
 )
+KIMI_LINEAR = dataclasses.replace(
+    DEEPSEEK_V3,  # the same source, gauges, counter, sign rule and sinks
+    config=KimiLinearConfig, module=KimiLinearForCausalLM,
+    loss=_without_rng(kimi_linear_loss),
+    tflops_per_sample=kimi_linear_train_tflops_per_sample,
+    weight_decay_mask=kimi_linear_weight_decay_mask,
+    step_gauges=DEEPSEEK_V3.step_gauges + KDA_GAUGES,
+    sign_step=KimiLinearConfig.bias_update_speed,
+)
 MODEL_FAMILIES: Dict[str, ModelFamily] = {
     "tiny": ALBERT, "large": ALBERT, "ouro_tiny": OURO, "ouro_2p6b": OURO,
     "kanana2_tiny": DEEPSEEK_V3, "kanana2_30b_a3b": DEEPSEEK_V3,
@@ -321,6 +338,7 @@ MODEL_FAMILIES: Dict[str, ModelFamily] = {
     "sdar_tiny": SDAR_MOE, "sdar_30b_a3b": SDAR_MOE,
     "laguna_tiny": LAGUNA, "laguna_xs2_33b_a3b": LAGUNA,
     "keye_vl2_tiny": KEYE_VL2, "keye_vl2_30b_a3b": KEYE_VL2,
+    "kimi_linear_tiny": KIMI_LINEAR, "kimi_linear_48b_a3b": KIMI_LINEAR,
 }
 
 
@@ -355,6 +373,7 @@ def build_model(
     moe_aux_weight: float = -1.0,
     num_hidden_layers: int = 0,
     expert_shard: str = "0/1",
+    head_shard: str = "0/1",
 ):
     """(config, module) of a ``--training.model_size`` name, with the
     trainer's overrides. No WIDTH is an override: the depth is the one size
@@ -379,6 +398,14 @@ def build_model(
             )
         index, _, count = expert_shard.partition("/")
         overrides["expert_shard"] = (int(index), int(count))
+    if head_shard != "0/1":
+        if "head_shard" not in family.config.__dataclass_fields__:
+            raise ValueError(
+                f"model_size {model_size!r} has no mixer that holds a share "
+                f"of its heads (--training.head_shard {head_shard})"
+            )
+        index, _, count = head_shard.partition("/")
+        overrides["head_shard"] = (int(index), int(count))
     if family is ALBERT:
         if remat_policy:
             from dedloc_tpu.models.albert import fused_ln_for_policy

@@ -161,6 +161,7 @@ def _run_trainer(args: CollaborationArguments) -> TrainState:
         moe_aux_weight=args.training.moe_aux_weight,
         num_hidden_layers=args.training.num_hidden_layers,
         expert_shard=args.training.expert_shard,
+        head_shard=args.training.head_shard,
     )
     family = model_family(cfg)
     tx = build_optimizer(args)

@@ -369,6 +369,9 @@ EMITTED = COUNTERS | GAUGES | HISTOGRAMS | EVENTS
 EMITTED_PREFIXES = (
     "attn.gate_mean.",
     "attn.index_peak.",
+    "kda.beta_mean.",
+    "kda.chunk_log_decay_min.",
+    "kda.state_abs_max.",
     "link.",
     "lm.exit_prob.",
     "lm.loss.",

@@ -368,6 +368,31 @@ def head_gate_kernels(device):
     return jax.jit(pairs).lower(*_on_device(device, operands))
 
 
+def kda_kernels(device):
+    """Kimi Delta Attention's kernel pair alone at the Kimi cell's shape:
+    (1, 8192, 8 heads of 128), bf16 q / k / v, float32 log-decays and write
+    strengths; the output and, from a cotangent, all five gradients."""
+    from dedloc_tpu.ops import kda as kda_ops
+    from dedloc_tpu.ops.kda import kda
+
+    # a grid step's share, to size another one offline (bundle counts)
+    kda_ops.HEADS_PER_STEP = int(
+        os.environ.get("KDA_HEADS_PER_STEP", kda_ops.HEADS_PER_STEP)
+    )
+
+    def both(q, k, v, g, beta, do):
+        out, vjp = jax.vjp(kda, q, k, v, g, beta)
+        return out, vjp(do)
+
+    wide = (1, 8192, 8, 128)
+    operands = [jax.ShapeDtypeStruct(wide, jnp.bfloat16)] * 3 + [
+        jax.ShapeDtypeStruct(wide, jnp.float32),
+        jax.ShapeDtypeStruct(wide[:3], jnp.float32),
+        jax.ShapeDtypeStruct(wide, jnp.bfloat16),
+    ]
+    return jax.jit(both).lower(*_on_device(device, operands))
+
+
 @functools.lru_cache(maxsize=None)
 def _lm_model_and_state(config: str, prefix: str):
     """(args, model, state, ids) of a causal-LM cell's recipe, from its
@@ -398,6 +423,7 @@ def _lm_model_and_state(config: str, prefix: str):
         t.model_size, os.environ.get(f"{prefix}_REMAT", t.remat_policy),
         vocab_size=t.vocab_size,
         num_hidden_layers=t.num_hidden_layers, expert_shard=t.expert_shard,
+        head_shard=t.head_shard,
     )
     state = jax.eval_shape(
         lambda r: TrainState.create(
@@ -420,6 +446,7 @@ LM_CELLS = {
     "sdar_accumulate_step": ("sdar_30b_a3b_s4096.json", "SDAR"),
     "laguna_accumulate_step": ("laguna_xs2_33b_a3b_s8192.json", "LAGUNA"),
     "keye_accumulate_step": ("keye_vl2_30b_a3b_s16384.json", "KEYE"),
+    "kimi_accumulate_step": ("kimi_linear_48b_a3b_s8192.json", "KIMI"),
 }
 
 
@@ -511,6 +538,18 @@ def laguna_accumulate_step(device):
     other expert decoders' (``guarded_apply_step`` over another tree)."""
     return _lm_accumulate_step(device, *_lm_model_and_state(
         *LM_CELLS["laguna_accumulate_step"]
+    ))
+
+
+def kimi_accumulate_step(device):
+    """Kimi-Linear-48B-A3B at one chip's share (``benchmark/configs/
+    kimi_linear_48b_a3b_s8192.json``; ``KIMI_LAYERS`` / ``KIMI_BATCH`` /
+    ``KIMI_REMAT`` size another cut): four KDA mixers (``kda_fwd`` /
+    ``kda_bwd`` at 8 held heads of 128) and one latent-attention layer
+    without RoPE (the two-width kernels at 8 heads), the dense layer and one
+    unrolled period of routed layers, the untied chunked head."""
+    return _lm_accumulate_step(device, *_lm_model_and_state(
+        *LM_CELLS["kimi_accumulate_step"]
     ))
 
 
@@ -809,12 +848,14 @@ COUNT_KERNEL_CALLS = {"gqa_kernels", "lfm2_accumulate_step", "band_kernels",
                       "sdar_accumulate_step", "laguna_kernels",
                       "head_gate_kernels", "laguna_accumulate_step",
                       "sel_kernels", "keye_accumulate_step",
-                      "index_loss_kernels"}
+                      "index_loss_kernels", "kda_kernels",
+                      "kimi_accumulate_step"}
 COUNT_EXPERT_GRAD_PASSES = {"kanana_accumulate_step", "lfm2_accumulate_step",
                             "smallthinker_accumulate_step",
                             "sdar_accumulate_step",
                             "laguna_accumulate_step",
-                            "keye_accumulate_step"}
+                            "keye_accumulate_step",
+                            "kimi_accumulate_step"}
 NO_V5E = 3  # exit code: nothing to compile with, which is not a failure
 
 
@@ -826,6 +867,7 @@ PROGRAMS = {
         smallthinker_accumulate_step, bd_kernels, sdar_accumulate_step,
         laguna_kernels, laguna_accumulate_step, head_gate_kernels,
         sel_kernels, keye_accumulate_step, index_loss_kernels,
+        kda_kernels, kimi_accumulate_step,
     )
 }
 
