@@ -46,18 +46,25 @@ carry no loss — arrives in the batch (``data/causal_lm.py``).
     (W_qI, W_kI, W_wI, the LayerNorm) dL_I only.
 
 The program's shape. The selection is exact and it is DATA: each layer makes
-its own, once (``select_keys``: index scores a block of query rows at a
-time, the k-th largest of each row by ``ops/index_select.top_k_mask`` — no
-sort), as an int8 [B, S, S] mask that the three ``flash_sel_*`` kernels read
-tile by tile beside q / k / v (``ops/flash_attention.py``, "selected tiles";
-named ``attn_selection`` for the remat policies: kept from
-``kernel_operands`` up, so the backward kernels read the forward's own
-array). Nothing of size [heads, S, S] exists: the indexer's loss rebuilds
-pbar from the kernels' log-sum-exp a (query tile, key tile) pair of the
-causal triangle at a time, in VMEM (``index_loss``: a kernel pair of its own,
-``ops/index_loss.py``; behind ``"dense"`` attention a loop over blocks of
-query rows, each under ``jax.checkpoint``). Scopes in a trace:
-``dsa_index`` (the score passes), ``dsa_select``, ``dsa_loss``, ``mrope``,
+its own, once (``select_keys``: the index scores and the k-th largest of
+each row by bisection — no sort), as an int8 [B, S, S] mask that the three
+``flash_sel_*`` kernels read tile by tile beside q / k / v
+(``ops/flash_attention.py``, "selected tiles"; named ``attn_selection`` for
+the remat policies: kept from ``kernel_operands`` up, so the backward
+kernels read the forward's own array). Which path runs where is
+``attention_impl``'s to say, for the selection as for the loss: behind the
+flash kernels (``"flash"``: the published widths, the benchmark's cell) the
+selection is ONE Pallas call a layer (``ops/index_select.index_select``: a
+block of query rows' scores and their exact top-k in VMEM, over the causal
+triangle) and the indexer's loss a kernel pair of its own
+(``ops/index_loss.py``: pbar rebuilt from the kernels' log-sum-exp a (query
+tile, key tile) pair of the triangle at a time); behind ``"dense"``
+attention (tests, tiny models, widths that are no whole lane tiles) each is
+a ``lax.map`` over blocks of query rows in XLA — ``index_scores`` +
+``ops/index_select.top_k_mask`` for the selection, the loss's blocks under
+``jax.checkpoint`` — which is also the kernels' oracle. Nothing of size
+[heads, S, S] exists on either path. Scopes in a trace: ``dsa_index`` (the
+score passes in XLA), ``dsa_select``, ``dsa_loss``, ``mrope``,
 ``moe_routed``.
 
 **A chip's share**, as for the other expert decoders: ``expert_shard``,
@@ -96,15 +103,15 @@ from dedloc_tpu.models.decoder import (
 from dedloc_tpu.models.remat import remat_layer
 from dedloc_tpu.ops.flash_attention import selection_tile_flags, visited_tiles
 from dedloc_tpu.ops.index_loss import index_loss_rows
-from dedloc_tpu.ops.index_select import top_k_mask
+from dedloc_tpu.ops.index_select import index_select, top_k_mask_and_ties
 
 # layers a scan step runs, unrolled: ``models/sdar_moe.py``'s reason (the
 # routed loop's gradient sinks are the accumulator's own leaves)
 SCAN_PERIOD = 4
 # query rows a step of the index-score + top-k pass / of the indexer's loss
-# (its block loop, the ``"dense"`` path: behind the flash kernels the loss is
-# ``ops/index_loss.py``'s kernels and no loop) takes: what bounds their
-# transients ([rows, 16, S] and [32, rows, S] float32). They DIFFER, and a
+# (their block loops, the ``"dense"`` path: behind the flash kernels they are
+# ``ops/index_select.py``'s and ``ops/index_loss.py``'s kernels and no loop)
+# takes: what bounds their transients ([rows, 16, S] and [32, rows, S] float32). They DIFFER, and a
 # device trace tells the two passes' loops apart by that (the selection cut
 # into their blocks, ``s8[blocks, rows, S]``:
 # ``benchmark/reducers/keye_block_loop_time.py`` reads both from here and
@@ -256,14 +263,28 @@ def _blocks_of(seq: int, batch: int, rows: int):
 
 
 def select_keys(cfg, q_index, k_index, weights):
-    """The layer's selection, int8 [B, S, S] (rows queries): 1 at the
+    """(the layer's selection, int8 [B, S, S] (rows queries): 1 at the
     ``cfg.index_topk`` keys s <= t with the largest index score of each
-    query t — all of them where t < top-k, ties to the lower s. Exactly the
-    top-k of the program's own scores; no gradient."""
+    query t — all of them where t < top-k, ties to the lower s —, the share
+    of its blocks of query rows in which a row had more keys EQUAL to its
+    threshold than it may take, so that the bisection over the position
+    ran: 0 for an indexer whose scores are distinct). Exactly the top-k of
+    the program's own scores; no gradient. Behind the flash kernels
+    (``cfg.attention_impl == "flash"``) ONE Pallas call over the causal
+    triangle (``ops/index_select.index_select``); behind ``"dense"``
+    attention this loop over blocks of ``INDEX_BLOCK_ROWS`` query rows — the
+    kernel's oracle — whose every step scores the block against ALL keys."""
     B, S = weights.shape[:2]
     q_index, k_index, weights = jax.lax.stop_gradient(
         (q_index, k_index, weights)
     )
+    if cfg.attention_impl == "flash":
+        with jax.named_scope("dsa_select"):
+            selection, tied = index_select(
+                q_index, k_index, weights, cfg.index_topk,
+                block_rows=INDEX_BLOCK_ROWS,
+            )
+        return selection, jnp.mean(tied.astype(jnp.float32))
     rows, batch_row, first = _blocks_of(S, B, INDEX_BLOCK_ROWS)
     key_at = jnp.arange(S)[None, :]
 
@@ -272,12 +293,14 @@ def select_keys(cfg, q_index, k_index, weights):
         scores = index_scores(cfg, q_rows, k_index[b], w_rows)
         with jax.named_scope("dsa_select"):
             valid = key_at <= t0 + jnp.arange(rows)[:, None]
-            return top_k_mask(scores, valid, cfg.index_topk).astype(jnp.int8)
+            chosen, tied = top_k_mask_and_ties(scores, valid, cfg.index_topk)
+            return chosen.astype(jnp.int8), tied
 
-    return jax.lax.map(block, (
+    selection, tied = jax.lax.map(block, (
         _row_blocks(q_index, rows), _row_blocks(weights, rows), batch_row,
         first,
-    )).reshape(B, S, S)
+    ))
+    return selection.reshape(B, S, S), jnp.mean(tied.astype(jnp.float32))
 
 
 def index_loss(cfg, q_index, k_index, weights, selection, q, k, lse):
@@ -351,9 +374,10 @@ def index_loss(cfg, q_index, k_index, weights, selection, q, k, lse):
 class DecoderLayer(nn.Module):
     """h = x + Attn_S(n), n = RMSNorm(x), S the indexer's selection from the
     detached n; y = h + Experts(RMSNorm(h)). Returns (y, routing): the
-    routed layer's, and this layer's ``index_kl`` (its L_I), ``index_peak``
-    and ``select_tiles`` (tiles of the kernels' triangle that hold a selected
-    pair), with ``cfg.emit_selection`` the ``selection`` itself."""
+    routed layer's, and this layer's ``index_kl`` (its L_I), ``index_peak``,
+    ``select_tiles`` (tiles of the kernels' triangle that hold a selected
+    pair) and ``select_tie_blocks`` (``select_keys``' share), with
+    ``cfg.emit_selection`` the ``selection`` itself."""
 
     cfg: KeyeVL2Config
 
@@ -370,9 +394,8 @@ class DecoderLayer(nn.Module):
         # reader of the un-named value would make the backward's replay run
         # the index pass and the top-k again for it (78.9 ms an execution at
         # the benchmark's cell: PERF.md section 5)
-        selection = checkpoint_name(
-            select_keys(cfg, q_index, k_index, weights), "attn_selection"
-        )
+        selection, tie_blocks = select_keys(cfg, q_index, k_index, weights)
+        selection = checkpoint_name(selection, "attn_selection")
         mixed, (q, k, lse) = GroupedQueryAttention(
             cfg, Visibility(selected=True), qk_norms=("q_norm", "k_norm"),
             name="self_attn",
@@ -387,6 +410,7 @@ class DecoderLayer(nn.Module):
         block = cfg.attention_block_size
         routing = dict(
             routing, index_kl=index_kl, index_peak=index_peak,
+            select_tie_blocks=tie_blocks,
             select_tiles=jnp.sum(
                 selection_tile_flags(selection, block, block)
             ).astype(jnp.float32),
@@ -452,8 +476,10 @@ def keye_vl2_loss(model: KeyeVL2ForCausalLM, params,
     ``attn.index_loss_tile_share`` ((query tile, key tile) pairs the
     indexer's loss walks over the square's, from the shapes: the causal
     sweep of ``ops/index_loss.py``'s kernels — 528 / 1,024 at 16,384 — or
-    1.0, the block loop's whole rows), ``attn.index_peak`` [L] and
-    ``data.image_token_share`` (labels that carry no loss). ``grad_sinks`` and ``compute_copies``:
+    1.0, the block loop's whole rows), ``attn.index_peak`` [L],
+    ``attn.select_tie_block_share`` [L] (``select_keys``' second result: the
+    share of a layer's blocks of query rows that resolved ties by position)
+    and ``data.image_token_share`` (labels that carry no loss). ``grad_sinks`` and ``compute_copies``:
     ``decoder.expert_lm_loss``'s."""
     cfg = model.cfg
     labels = batch["labels"]
@@ -490,6 +516,9 @@ def keye_vl2_loss(model: KeyeVL2ForCausalLM, params,
                 r["select_tiles"]
             ) / tiles,
             "attn.index_peak": lambda _p, r: r["index_peak"],
+            "attn.select_tie_block_share": lambda _p, r: r[
+                "select_tie_blocks"
+            ],
             "attn.index_loss_tile_share": lambda _p, _r: jnp.float32(
                 loss_share
             ),

@@ -10,8 +10,12 @@ import jax
 # the flash kernel's ``out`` and the gate: keeping it is 134 MB a layer at
 # 8,192 x 64 x 128 for a 0.37 ms pass, and on the v5e the step takes the same
 # time either way (remat's reduce-precision pass over a kept value costs what
-# the replayed call does: PERF.md section 5, PR 48)
-REPLAYED_KERNELS = ("head_gate_fwd",)
+# the replayed call does: PERF.md section 5, PR 48). ``index_select``
+# (ops/index_select.py) writes a selected attention's int8 [B, S, S]
+# selection, 268 MB a layer at S = 16,384: it is kept BY NAME
+# ("attn_selection") from "kernel_operands" up and replayed below, as it was
+# when XLA wrote it
+REPLAYED_KERNELS = ("head_gate_fwd", "index_select")
 
 
 def _pallas_outputs_saveable(prim, *_, **params) -> bool:

@@ -318,7 +318,8 @@ KEYE_VL2 = dataclasses.replace(
         "moe.grad_sink_leaves", "moe.compute_copy_leaves",
         "moe.bulk_row_share", "attn.select_kept_share",
         "attn.select_tile_share", "attn.index_loss_tile_share",
-        "attn.index_peak", "loss.index_kl", "data.image_token_share",
+        "attn.index_peak", "attn.select_tie_block_share", "loss.index_kl",
+        "data.image_token_share",
     ),
 )
 KIMI_LINEAR = dataclasses.replace(

@@ -369,6 +369,7 @@ EMITTED = COUNTERS | GAUGES | HISTOGRAMS | EVENTS
 EMITTED_PREFIXES = (
     "attn.gate_mean.",
     "attn.index_peak.",
+    "attn.select_tie_block_share.",
     "kda.beta_mean.",
     "kda.chunk_log_decay_min.",
     "kda.state_abs_max.",

@@ -61,7 +61,9 @@ def _selection(kind, batch, q_index, k_index, weights):
     if kind == "tie":  # the layer's own top-8, keys in equal PAIRS: the
         # 8th and 9th largest of a row tie, the lower key is kept
         k_index = k_index.at[:, 1::2].set(k_index[:, 0::2])
-        return keye_vl2.select_keys(_cfg("dense"), q_index, k_index, weights)
+        return keye_vl2.select_keys(
+            _cfg("dense"), q_index, k_index, weights
+        )[0]
     if kind == "window":  # the last 16 keys: tiles off the band hold nothing
         return (causal & (t - s < TILE)).astype(jnp.int8)
     assert kind == "one_key"  # ... and one row that keeps its own key alone

@@ -13,17 +13,22 @@ def test_keye_accumulate_step_reads_a_selection_and_holds_nothing_heads_by_s_by_
     flags in SMEM — get through Mosaic at 32 query heads over 4 kv heads;
     under the model's default remat ``kernel_operands`` no kernel is
     replayed (4 sites each: the selection is KEPT with the operands), the
-    indexer's loss is its own kernel pair over the same tiles and nothing
-    of its XLA block loop is left; the
+    selection is ONE ``index_select`` call a layer and the indexer's loss
+    its own kernel pair over the same tiles, and nothing of either's XLA
+    block loop is left; the
     tile loop's backward sums into the accumulator's twelve expert leaves;
     NOTHING of size [heads, S, S] is materialised — the largest array the
-    compiled module names is 256 MB (the int8 selection itself, a block
-    loop's slabs), where ONE head's float32 scores are 1,024 MB and 32
+    compiled module names is 256 MB (the int8 selection itself), where ONE
+    head's float32 scores are 1,024 MB and 32
     heads' bf16 ones 17 GB —; and the program's scratch beside 28 bytes a
     parameter of state with a draining snapshot (+ the held experts' bf16
     copies) stays under the 15.3 GB line."""
-    rows = _tpu_aot("sel_kernels", "index_loss_kernels",
+    rows = _tpu_aot("sel_kernels", "index_loss_kernels", "select_kernels",
                     "keye_accumulate_step")
+    # the selection's kernel alone (``ops/index_select.py``): Mosaic takes a
+    # block of 256 query rows' ordered keys, [256, 16384] int32, as VMEM
+    # scratch beside the resident key head and the int8 rows it writes
+    assert rows.pop("select_kernels")["kernel_calls"] == {"index_select": 1}
     # the indexer's loss kernels alone (``ops/index_loss.py``): Mosaic takes
     # the forward sweep and the one backward sweep that holds the key
     # head's whole gradient, [16384, 128] float32, in VMEM
@@ -44,14 +49,18 @@ def test_keye_accumulate_step_reads_a_selection_and_holds_nothing_heads_by_s_by_
     # a Pallas output, which the policy keeps: no replay), one backward
     assert row["kernel_calls"] == {
         "flash_sel_fwd": 4, "flash_sel_bwd_dq": 4, "flash_sel_bwd_dkv": 4,
-        "index_loss_fwd": 4, "index_loss_bwd": 4,
+        "index_loss_fwd": 4, "index_loss_bwd": 4, "index_select": 4,
     }
-    assert row["tpu_custom_calls"] == 20
+    assert row["tpu_custom_calls"] == 24
     # no float32 [128, 16, 16384] index scores, no [4, 8, 128, 16384] main
     # scores, no selection cut into the loss's blocks of 128 rows — what the
     # XLA block loop made, 128 blocks a layer and direction (PR 51) — in the
     # lowered or the compiled module
     assert row["loss_block_transients"] == []
+    # ... and no float32 [256, 16, 16384] index scores, no selection written
+    # a block of 256 rows at a time (``s8[64,256,16384]``: the XLA loop's
+    # slabs, 64 steps a layer, PR 51)
+    assert row["select_block_transients"] == []
     grads = row["expert_grad_passes"]
     assert (grads["adds"], grads["zero_fills"], grads["held_casts"]) == (
         0, 0, 0

@@ -259,10 +259,13 @@ def test_at_most_top_k_keys_is_the_causal_layer(impl):
     q_index = jax.random.normal(jax.random.PRNGKey(1), (2, seq, 2, 8))
     k_index = jax.random.normal(jax.random.PRNGKey(2), (2, seq, 8))
     w = jax.random.normal(jax.random.PRNGKey(3), (2, seq, 2))
-    selection = select_keys(cfg, q_index, k_index, w)
+    # behind "flash" the kernel (``ops/index_select.py``: every block
+    # keeps every valid key and computes nothing), behind "dense" the loop
+    selection, tie_blocks = select_keys(cfg, q_index, k_index, w)
     np.testing.assert_array_equal(
         selection, np.broadcast_to(np.tril(np.ones((seq, seq))), (2, seq, seq))
     )
+    assert float(tie_blocks) == 0.0
     selected = _CausalLayer(cfg, Visibility(selected=True))
     causal = _CausalLayer(cfg, Visibility(causal=True))
     params = causal.init(jax.random.PRNGKey(4), x, rope)["params"]
@@ -282,6 +285,38 @@ def test_at_most_top_k_keys_is_the_causal_layer(impl):
     np.testing.assert_array_equal(got, want)
     for a, b in zip(jax.tree.leaves(got_grads), jax.tree.leaves(want_grads)):
         np.testing.assert_array_equal(a, b)
+
+
+def test_the_selection_is_the_kernels_behind_flash_and_the_loops_behind_dense():
+    """``attention_impl`` decides the selection's path as it decides the
+    loss's: ONE ``index_select`` call a layer behind the flash kernels, the
+    block loop (``index_scores`` + ``top_k_mask``, no kernel) behind
+    ``"dense"`` — and at the tiny model, on the same parameters and rows,
+    the two paths select the same keys (float32 scores summed in another
+    order: a key within an ulp of a row's threshold may fall the other way)
+    and give the same losses."""
+    sizes = dict(head_dim=128, mrope_section=(16, 24, 24),
+                 attention_block_size=16, emit_selection=True)
+    out = {}
+    for impl in ("dense", "flash"):
+        cfg, model, params, batch = _setup(64, 0.25, attention_impl=impl,
+                                           **sizes)
+        fn = jax.jit(lambda p: keye_vl2_loss(model, p, batch))
+        text = str(jax.make_jaxpr(lambda p: keye_vl2_loss(model, p, batch))(
+            params
+        ))
+        assert ("name=index_select" in text) == (impl == "flash")
+        out[impl] = fn(params)
+    (dense_loss, dense), (flash_loss, flash) = out["dense"], out["flash"]
+    differ = np.asarray(dense["attn.selection"] != flash["attn.selection"])
+    assert differ.mean() <= 1e-4
+    for term in ("loss.lm", "loss.index_kl"):
+        assert float(flash[term]) == pytest.approx(float(dense[term]),
+                                                   rel=1e-4)
+    assert float(flash_loss) == pytest.approx(float(dense_loss), rel=1e-4)
+    np.testing.assert_array_equal(flash["attn.select_tie_block_share"],
+                                  dense["attn.select_tie_block_share"])
+    assert flash["attn.select_tie_block_share"].shape == (2,)
 
 
 def test_mrope_is_plain_rope_on_text_and_the_table_elsewhere():

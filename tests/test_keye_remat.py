@@ -67,25 +67,30 @@ def test_the_selection_kept_or_replayed_gives_the_same_bits(policy):
     )
 
 
-@pytest.mark.parametrize("policy,forward_sites", [
-    ("nothing", 2), ("kernel_outputs", 1), ("kernel_operands", 1),
-    ("whole_mixer", 1),
+@pytest.mark.parametrize("policy,forward_sites,select_sites", [
+    ("nothing", 2, 2), ("kernel_outputs", 1, 2), ("kernel_operands", 1, 1),
+    ("whole_mixer", 1, 1),
 ])
 def test_the_loss_kernels_forward_sweep_is_kept_not_replayed(policy,
-                                                             forward_sites):
+                                                             forward_sites,
+                                                             select_sites):
     """Behind the flash kernels the indexer's loss is a kernel pair of its
     own (``ops/index_loss.py``) — the bits above are ITS bits, kept or
     replayed. Its backward sweep reads the forward's logZ and sum pbar out
     of the forward kernel's output: a Pallas output, so every policy from
     ``kernel_outputs`` up keeps it and the replay runs no second forward
     sweep (one site a layer; two under ``nothing``), and one backward sweep
-    a layer either way."""
+    a layer either way. The SELECTION is a kernel's output too
+    (``index_select``, ``ops/index_select.py``) and is not kept for that: by
+    its name, from ``kernel_operands`` up (``remat.REPLAYED_KERNELS``) — one
+    site a layer there, the forward's and the replay's below."""
     cfg, _matmuls, kernels = shared._sites("keye", policy)
     layers = cfg.num_hidden_layers
     assert cfg.attention_impl == "flash"
     assert kernels["index_loss_fwd"] == forward_sites * layers
     assert kernels["index_loss_bwd"] == layers
     assert kernels["flash_sel_fwd"] == forward_sites * layers
+    assert kernels["index_select"] == select_sites * layers
 
 
 def test_the_selection_is_kept_with_the_operands():

@@ -89,6 +89,11 @@ def test_keye_tiny_trainer_makes_global_steps(tmp_path, monkeypatch, shard,
         assert rec["attn.index_loss_tile_share"] == 1.0
         for i in range(1, depth + 1):  # near the initialiser: nearly flat
             assert 1.0 <= rec[f"attn.index_peak.{i}"] < 1.5
+            # the share of a layer's blocks of query rows (one a batch
+            # row, at 32 positions) whose rows tied at their threshold,
+            # averaged over the global step's micro-batches: a gauge a layer
+            assert 0.0 <= rec[f"attn.select_tie_block_share.{i}"] <= 1.0
+        assert f"attn.select_tie_block_share.{depth + 1}" not in rec
         assert 0.0 <= rec["loss.index_kl"] < 0.5
         if float(share):
             assert 0.1 < rec["data.image_token_share"] <= 0.3
@@ -132,7 +137,8 @@ def test_the_table_builds_keye():
     assert KEYE_VL2.sign_step_mask is None
     assert KEYE_VL2.grad_sink_mask is DEEPSEEK_V3.grad_sink_mask
     assert {"attn.select_kept_share", "attn.select_tile_share",
-            "attn.index_loss_tile_share", "attn.index_peak", "loss.index_kl",
+            "attn.index_loss_tile_share", "attn.index_peak",
+            "attn.select_tie_block_share", "loss.index_kl",
             "data.image_token_share"} <= set(KEYE_VL2.step_gauges)
     published = KeyeVL2Config.keye_vl2_30b_a3b()
     assert (published.hidden_size, published.num_attention_heads,

@@ -59,8 +59,16 @@ S=16,384, 2,048 keys a query: ``random`` keeps N of each query's keys drawn
 uniformly (every tile of the triangle holds a pair), ``prefix`` the first N
 (window-shaped: the tiles past the N-th key hold nothing and run no body) —
 and, beside the kernels, ``select_ms``: the host-clock ms a call of the
-layer's index-score pass + exact top-N (``models/keye_vl2.select_keys``) on
-random indexer operands at the published 16 x 64:
+layer's index scores + exact top-N (``models/keye_vl2.select_keys``) on
+random indexer operands at the published 16 x 64, by BOTH its paths —
+``kernel`` (``ops/index_select.index_select``, what runs behind the flash
+kernels) and ``loop`` (the ``"dense"`` path's block loop in XLA, the
+kernel's oracle) — with ``select_device_ms`` (the kernel's call in a
+profiler window), ``select_rows_ok`` (every row holds min(t + 1, N) ones,
+either path), ``select_disagree_share`` (entries of the two masks that
+differ: keys within a float32 ulp of a row's threshold, where the kernel
+sums a row's sixteen terms in another order than XLA) and
+``select_tie_block_share`` (either path):
 
     chiprun --chips 1 -- python tools/chip_gqa_check.py --heads 32 \
         --kv-heads 4 --head-dim 128 --seq 16384 --select-topk 2048 \
@@ -392,23 +400,36 @@ def main(argv=None) -> int:
     if opts.select_topk:
         from dedloc_tpu.models.keye_vl2 import KeyeVL2Config, select_keys
 
-        cfg = KeyeVL2Config(index_topk=opts.select_topk)
-        q_index = bf(jax.random.normal(
-            keys[4], (B, S, cfg.index_n_heads, cfg.index_head_dim)
-        ))
-        k_index = bf(jax.random.normal(keys[5], (B, S, cfg.index_head_dim)))
-        weights = jax.random.normal(keys[6], (B, S, cfg.index_n_heads))
-        select = jax.jit(lambda *x: select_keys(cfg, *x))
-        chosen = jax.block_until_ready(select(q_index, k_index, weights))
-        start = time.perf_counter()
-        for _ in range(5):
-            chosen = select(q_index, k_index, weights)
-        jax.block_until_ready(chosen)
-        extra["select_ms"] = (time.perf_counter() - start) / 5 * 1e3
-        extra["select_rows_ok"] = bool(jnp.all(
-            jnp.sum(chosen[0], axis=-1, dtype=jnp.int32)
-            == jnp.minimum(jnp.arange(S) + 1, opts.select_topk)
-        ))
+        q_index = bf(jax.random.normal(keys[4], (B, S, 16, 64)))
+        k_index = bf(jax.random.normal(keys[5], (B, S, 64)))
+        weights = jax.random.normal(keys[6], (B, S, 16))
+        rows = jnp.minimum(jnp.arange(S) + 1, opts.select_topk)
+        chosen, extra["select_ms"] = {}, {}
+        for path, impl in (("kernel", "flash"), ("loop", "dense")):
+            cfg = KeyeVL2Config(index_topk=opts.select_topk,
+                                attention_impl=impl)
+            select = jax.jit(lambda *x, cfg=cfg: select_keys(cfg, *x))
+            chosen[path], tied = jax.block_until_ready(
+                select(q_index, k_index, weights)
+            )
+            start = time.perf_counter()
+            for _ in range(5):
+                out = select(q_index, k_index, weights)
+            jax.block_until_ready(out)
+            extra["select_ms"][path] = (time.perf_counter() - start) / 5 * 1e3
+            extra.setdefault("select_rows_ok", {})[path] = bool(jnp.all(
+                jnp.sum(chosen[path][0], axis=-1, dtype=jnp.int32) == rows
+            ))
+            extra.setdefault("select_tie_block_share", {})[path] = float(tied)
+            if path == "kernel":
+                extra["select_device_ms"] = device_times(
+                    traced_ops(lambda: select(q_index, k_index, weights), 5),
+                    {"index_select": lambda _on_chip: (0.0, 0.0)},
+                ).get("index_select", {}).get("device_ms")
+        extra["select_disagree_share"] = float(
+            jnp.mean(chosen["kernel"] != chosen["loop"])
+        )
+        del chosen
         extra["select_tile_share"] = {
             str(band): band.tile_share for band in bands
         }

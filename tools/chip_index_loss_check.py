@@ -81,7 +81,7 @@ def main(argv=None) -> int:
     weights = jax.random.normal(keys[5], (b, s, cfg.index_n_heads),
                                 jnp.float32)
     selection = jax.jit(
-        lambda *x: keye_vl2.select_keys(cfg, *x)
+        lambda *x: keye_vl2.select_keys(cfg, *x)[0]
     )(q_index, k_index, weights)
     _out, lse = jax.jit(lambda q, k, v, sel: flash_attention(
         q, k, v, selection=sel, block_q=args.block, block_k=args.block,

@@ -25,7 +25,9 @@ the per-head gate's scope, and in its ``kernel_calls`` the gate's own pair —
 ``keye_accumulate_step``, "largest_buffers_mb": the largest array shapes the
 compiled module names — nothing [heads, S, S], PR 51 — and
 "loss_block_transients": ``loss_block_transients`` of the lowered and the
-compiled module — [] since the indexer's loss is a kernel pair, PR 52) —
+compiled module — [] since the indexer's loss is a kernel pair, PR 52 — and
+"select_block_transients": the same at the selection's blocks of 256 rows —
+[] since the selection is a kernel, PR 54) —
 ``flash_windows`` is each flash kernel's lane window beside its column
 block, from the call's metadata (``"block"`` for a call that carries none:
 D=64, D=128; its head counts for a grouped-query call);
@@ -342,6 +344,24 @@ def index_loss_kernels(device):
         jax.ShapeDtypeStruct((1, 32, seq), jnp.float32),
     )
     return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_on_device(device, operands)
+    )
+
+
+def select_kernels(device):
+    """The indexer's selection kernel alone at the Keye cell's shape (16
+    index heads of 64 and one key head, S=16,384, top-2,048): a block of 256
+    query rows' index scores and their exact top-k in VMEM, over the causal
+    triangle (``ops/index_select.py``)."""
+    from dedloc_tpu.ops.index_select import index_select
+
+    seq = 16384
+    operands = (
+        jax.ShapeDtypeStruct((1, seq, 16, 64), jnp.bfloat16),
+        jax.ShapeDtypeStruct((1, seq, 64), jnp.bfloat16),
+        jax.ShapeDtypeStruct((1, seq, 16), jnp.float32),
+    )
+    return jax.jit(lambda *x: index_select(*x, 2048)).lower(
         *_on_device(device, operands)
     )
 
@@ -784,10 +804,11 @@ def keye_accumulate_step(device):
     """Keye-VL-2.0-30B-A3B's language model at one chip's share
     (``benchmark/configs/keye_vl2_30b_a3b_s16384.json``; ``KEYE_LAYERS`` /
     ``KEYE_BATCH`` / ``KEYE_REMAT`` size another cut): four unrolled layers
-    at S=16,384 — the indexer's score pass and exact top-2,048 a block of
-    256 query rows at a time, the selected kernels at a group of eight
-    reading the int8 selection, the indexer's loss as its own kernel pair
-    over the same tiles (``ops/index_loss.py``), every layer routed (SwiGLU experts, 8 of 128 held) — three
+    at S=16,384 — the indexer's scores and their exact top-2,048 as one
+    kernel over the causal triangle (``ops/index_select.py``), the selected
+    kernels at a group of eight reading the int8 selection, the indexer's
+    loss as its own kernel pair over the same tiles (``ops/index_loss.py``),
+    every layer routed (SwiGLU experts, 8 of 128 held) — three
     position streams and a weight a label from the batch, the untied chunked
     head. Its row carries ``largest_buffers_mb``: nothing [heads, S, S]."""
     args, model, state, ids = _lm_model_and_state(
@@ -826,7 +847,9 @@ def loss_block_transients(text: str, rows: int, seq: int) -> list:
     ``tensor<128x16x16384xf32>``): the transients of the indexer's loss
     where it is a loop over blocks of ``rows`` query rows (the index scores
     [rows, 16, S], the main scores [4, 8, rows, S], the selection cut into
-    its blocks). [] since the loss is a kernel pair (PR 52)."""
+    its blocks) — and of the selection where IT is such a loop (the index
+    scores, the selection written a block at a time). [] since the loss is
+    a kernel pair (PR 52) and the selection a kernel (PR 54)."""
     found = set()
     for dtype, dims in re.findall(r"\b(f32|s8)\[([0-9,]+)\]", text) + [
         (dtype, dims.replace("x", ","))
@@ -849,7 +872,7 @@ COUNT_KERNEL_CALLS = {"gqa_kernels", "lfm2_accumulate_step", "band_kernels",
                       "head_gate_kernels", "laguna_accumulate_step",
                       "sel_kernels", "keye_accumulate_step",
                       "index_loss_kernels", "kda_kernels",
-                      "kimi_accumulate_step"}
+                      "kimi_accumulate_step", "select_kernels"}
 COUNT_EXPERT_GRAD_PASSES = {"kanana_accumulate_step", "lfm2_accumulate_step",
                             "smallthinker_accumulate_step",
                             "sdar_accumulate_step",
@@ -867,7 +890,7 @@ PROGRAMS = {
         smallthinker_accumulate_step, bd_kernels, sdar_accumulate_step,
         laguna_kernels, laguna_accumulate_step, head_gate_kernels,
         sel_kernels, keye_accumulate_step, index_loss_kernels,
-        kda_kernels, kimi_accumulate_step,
+        kda_kernels, kimi_accumulate_step, select_kernels,
     )
 }
 
@@ -910,13 +933,18 @@ def main(argv=None) -> int:
                 compiled_text, "attn_gate"
             )
         if name == "keye_accumulate_step":
-            from dedloc_tpu.models.keye_vl2 import INDEX_LOSS_BLOCK_ROWS
+            from dedloc_tpu.models.keye_vl2 import (
+                INDEX_BLOCK_ROWS,
+                INDEX_LOSS_BLOCK_ROWS,
+            )
 
             extra["largest_buffers_mb"] = largest_buffers_mb(compiled_text)
-            extra["loss_block_transients"] = loss_block_transients(
-                lowered_text + compiled_text, INDEX_LOSS_BLOCK_ROWS,
-                _lm_model_and_state(*LM_CELLS[name])[3].shape[1],
-            )
+            seq = _lm_model_and_state(*LM_CELLS[name])[3].shape[1]
+            for key, rows in (("loss", INDEX_LOSS_BLOCK_ROWS),
+                              ("select", INDEX_BLOCK_ROWS)):
+                extra[f"{key}_block_transients"] = loss_block_transients(
+                    lowered_text + compiled_text, rows, seq
+                )
         if name in LM_CELLS:
             # the layer policy the program was built under; ``memory`` below
             # is what it costs (``temp_bytes``: the stash is inside it)
