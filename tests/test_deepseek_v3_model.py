@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import decoder_cases as cases
 from benchmark.reference import deepseek_v3 as reference
 from dedloc_tpu.models.decoder import BIAS, RoutedFFN, sign_step_mask
 from dedloc_tpu.models.deepseek_v3 import (
@@ -25,29 +26,6 @@ from dedloc_tpu.models.deepseek_v3 import (
 LOSS_TOL, LEAF_TOL = 2e-6, 2e-4
 
 
-def _setup(impl="dense", **overrides):
-    cfg = DeepseekV3Config.tiny(
-        dtype=jnp.float32, attention_impl=impl, attention_block_size=32,
-        **overrides,
-    )
-    model = DeepseekV3ForCausalLM(cfg)
-    rows = np.random.default_rng(0).integers(
-        0, cfg.vocab_size, (2, 65)
-    ).astype(np.int32)
-    batch = {"input_ids": jnp.asarray(rows[:, :-1]),
-             "labels": jnp.asarray(rows[:, 1:])}
-    params = model.init(jax.random.PRNGKey(1), batch["input_ids"])["params"]
-    # away from the initialiser's symmetry: norms off 1, the bias off 0 by
-    # more than neighbouring scores differ
-    leaves, treedef = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(2), len(leaves))
-    params = jax.tree.unflatten(treedef, [
-        leaf + 0.1 * jax.random.normal(key, leaf.shape)
-        for leaf, key in zip(leaves, keys)
-    ])
-    return cfg, model, params, batch
-
-
 def _reference_kwargs(cfg, **changes):
     kwargs = dict(
         num_heads=cfg.num_attention_heads, nope=cfg.qk_nope_head_dim,
@@ -60,60 +38,32 @@ def _reference_kwargs(cfg, **changes):
     return kwargs
 
 
-def _model_grads(model, params, batch):
-    return jax.value_and_grad(
-        lambda p: deepseek_v3_loss(model, p, batch), has_aux=True
-    )(params)
+def _tiny(**overrides):
+    return DeepseekV3Config.tiny(attention_block_size=32, **overrides)
 
 
-def _reference_grads(cfg, params, batch, **changes):
-    with jax.default_matmul_precision("highest"):
-        return jax.value_and_grad(
-            lambda p: (lambda out: (out["loss"], out))(
-                reference.forward(p, batch, **_reference_kwargs(cfg, **changes))
-            ), has_aux=True,
-        )(params)
-
-
-def _without_bias(tree):
-    return jax.tree_util.tree_map_with_path(
-        lambda path, x: jnp.zeros_like(x) if path[-1].key == BIAS else x, tree
-    )
-
-
-def _worst_leaf(got, want):
-    worst = 0.0
-    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        norm = float(jnp.linalg.norm(b))
-        if norm > 0:
-            worst = max(worst, float(jnp.linalg.norm(a - b)) / norm)
-    return worst
+DEEPSEEK = cases.Family(
+    tiny=_tiny, module=DeepseekV3ForCausalLM, loss=deepseek_v3_loss,
+    reference=reference, reference_kwargs=_reference_kwargs,
+    loss_tol=LOSS_TOL, leaf_tol=LEAF_TOL, comparable=cases.without_bias,
+)
 
 
 @pytest.mark.parametrize(
-    "impl,shard", [("dense", (0, 1)), ("flash", (0, 1)), ("dense", (1, 4))],
+    "overrides", [dict(), dict(attention_impl="flash"),
+                  dict(expert_shard=(1, 4))],
     ids=["dense", "flash", "share_1_of_4"],
 )
-def test_model_matches_reference(impl, shard):
-    cfg, model, params, batch = _setup(impl, expert_shard=shard)
-    (loss, metrics), grads = _model_grads(model, params, batch)
-    (ref_loss, ref), ref_grads = _reference_grads(cfg, params, batch)
-    # float32 on both sides: the choices agree exactly, nothing is forced
-    np.testing.assert_array_equal(metrics["moe.choice"], ref["choice"])
-    assert abs(float(loss) - float(ref_loss)) <= LOSS_TOL * float(ref_loss)
-    assert _worst_leaf(
-        _without_bias(grads), _without_bias(ref_grads)
-    ) <= LEAF_TOL
+def test_model_matches_reference(overrides):
+    _cfg, _metrics, grads, ref, ref_grads = (
+        cases.check_model_matches_reference(DEEPSEEK, **overrides)
+    )
     # the bias leaf carries the load statistic, not a gradient — exactly
     # what the reference counts from the same choices
     np.testing.assert_allclose(
         grads["layers"]["block"]["mlp"][BIAS], ref["load_excess"], atol=1e-7
     )
     assert float(jnp.max(jnp.abs(ref_grads["layers"]["block"]["mlp"][BIAS]))) == 0
-    assert float(metrics["moe.dropped_slots"]) == 0.0
-    assert abs(
-        float(metrics["moe.local_slot_share"]) - 1.0 / shard[1]
-    ) < (0.0 if shard[1] == 1 else 0.15) + 1e-6
 
 
 @pytest.mark.parametrize(
@@ -121,26 +71,15 @@ def test_model_matches_reference(impl, shard):
     ids=["no_bias_in_choice", "no_scaling_factor"],
 )
 def test_a_different_function_fails(changes):
-    cfg, model, params, batch = _setup()
-    (loss, metrics), grads = _model_grads(model, params, batch)
-    (ref_loss, ref), ref_grads = _reference_grads(cfg, params, batch, **changes)
-    off = _worst_leaf(_without_bias(grads), _without_bias(ref_grads))
-    assert off > 100 * LEAF_TOL, off
+    metrics, ref = cases.check_a_different_function_fails(
+        DEEPSEEK, changes, given_choices=False
+    )
     if "bias_in_choice" in changes:
-        assert np.mean(
-            np.asarray(metrics["moe.choice"]) != np.asarray(ref["choice"])
-        ) > 0.05
+        cases.check_the_choices_differ(metrics, ref)
 
 
 def test_reference_routed_by_given_choices():
-    """Routed by the program's choices the reference reproduces its own
-    result (the chip check routes it so)."""
-    cfg, _model, params, batch = _setup()
-    (loss, own), _ = _reference_grads(cfg, params, batch)
-    (again, _), _ = _reference_grads(
-        cfg, params, batch, choices=own["choice"]
-    )
-    assert float(loss) == pytest.approx(float(again), rel=1e-6)
+    cases.check_reference_routed_by_given_choices(DEEPSEEK)
 
 
 def test_the_shares_add_up_to_the_uncut_layer():
@@ -148,7 +87,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
     (each told its share, holding 1 of the 16 experts), plus what every chip
     computes alike — the shared experts — counted once, are the uncut
     reference's layer output."""
-    cfg, _model, params, _batch = _setup()
+    cfg, _model, params, _batch = cases.case(DEEPSEEK)
     layer = jax.tree.map(lambda x: x[0], params["layers"]["block"]["mlp"])
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 64, cfg.hidden_size))
     with jax.default_matmul_precision("highest"):
@@ -185,7 +124,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
 
 
 def test_masks_and_flops():
-    cfg, _model, params, _batch = _setup()
+    params = cases.case(DEEPSEEK).params
     decay = deepseek_v3_weight_decay_mask(params)
     signed = sign_step_mask(params)
     mlp = "mlp"

@@ -195,10 +195,11 @@ def test_pull_and_save_publishes_sharded_manifest(rng, tmp_path):
     assert os.path.isfile(os.path.join(uploaded[0], "manifest.bin"))
 
 
-def test_coordinator_publishes_to_hub(tmp_path):
+def test_coordinator_publishes_to_hub(tmp_path, monkeypatch):
     """End-to-end: a sharing trainer peer + coordinator loop with
     upload_interval -> checkpoint lands in the hub mirror."""
     from dedloc_tpu.core.config import CollaborationArguments, parse_config
+    from dedloc_tpu.roles import coordinator
     from dedloc_tpu.roles.common import build_dht
     from dedloc_tpu.roles.coordinator import (
         CoordinatorExtraArguments,
@@ -240,6 +241,26 @@ def test_coordinator_publishes_to_hub(tmp_path):
         t.start()
 
         mirror = str(tmp_path / "hub")
+
+        def published():
+            return [
+                d for d in (os.listdir(mirror) if os.path.isdir(mirror) else [])
+                if d.startswith("checkpoint-")
+            ]
+
+        # the loop's one way out before ``max_iterations`` (75 s): its next
+        # refresh after the first checkpoint has landed leaves through the
+        # role's own ``finally``
+        class Published(Exception):
+            pass
+
+        def fetch_until_published(*args, **kwargs):
+            if published():
+                raise Published
+            return fetch_metrics(*args, **kwargs)
+
+        fetch_metrics = coordinator.fetch_metrics
+        monkeypatch.setattr(coordinator, "fetch_metrics", fetch_until_published)
         coord_args = parse_config(
             CollaborationArguments,
             base + [
@@ -247,22 +268,21 @@ def test_coordinator_publishes_to_hub(tmp_path):
                 "--training.output_dir", str(tmp_path / "coord"),
             ],
         )
-        run_coordinator(
-            coord_args,
-            CoordinatorExtraArguments(
-                refresh_period=0.5,
-                upload_interval=0.1,
-                metrics_log_path=str(tmp_path / "metrics.jsonl"),
-                hub_mirror_dir=mirror,
-            ),
-            max_iterations=150,
-        )
+        try:
+            run_coordinator(
+                coord_args,
+                CoordinatorExtraArguments(
+                    refresh_period=0.5,
+                    upload_interval=0.1,
+                    metrics_log_path=str(tmp_path / "metrics.jsonl"),
+                    hub_mirror_dir=mirror,
+                ),
+                max_iterations=150,
+            )
+        except Published:
+            pass
         t.join(timeout=60)
-        published = [
-            d for d in (os.listdir(mirror) if os.path.isdir(mirror) else [])
-            if d.startswith("checkpoint-")
-        ]
-        assert published, "coordinator never published a checkpoint to the hub"
+        assert published(), "coordinator never published a checkpoint to the hub"
     finally:
         root_dht.shutdown()
 

@@ -17,13 +17,6 @@ from dedloc_tpu.ops.index_loss import index_loss_rows
 SEQ, TILE, TOPK = 64, 16, 8
 
 
-@pytest.fixture(autouse=True, scope="module")
-def release_compiled_programs():
-    """This file's executables go when it ends (ROADMAP C9)."""
-    yield
-    jax.clear_caches()
-
-
 @pytest.fixture(autouse=True)
 def blocks_of_query_rows(monkeypatch):
     """The oracle's loop takes several blocks at 64 positions."""

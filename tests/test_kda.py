@@ -15,12 +15,6 @@ from dedloc_tpu.ops import kda as kda_ops
 from dedloc_tpu.ops.kda import CHUNK, kda, kda_recurrence
 
 
-@pytest.fixture(autouse=True, scope="module")
-def release_compiled_programs():
-    yield
-    jax.clear_caches()
-
-
 def _operands(batch, seq, heads, dim, dtype=jnp.float32, seed=0, decay=1.0):
     keys = jax.random.split(jax.random.PRNGKey(seed), 7)
     shape = (batch, seq, heads, dim)

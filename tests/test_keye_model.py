@@ -6,11 +6,14 @@ spans; the two gradient paths are disjoint; at S <= top-k the layer is the
 same layer under ``causal`` bit for bit; M-RoPE against plain RoPE and a
 hand-written table; the weights zero the image labels; a bf16 reference
 fails; THE SHARE TEST; the cut's parameter count."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import decoder_cases as cases
 from benchmark.reference import keye_vl2 as reference
 from dedloc_tpu.data.causal_lm import synthetic_causal_lm_batches
 from dedloc_tpu.models import decoder, keye_vl2
@@ -37,15 +40,6 @@ from dedloc_tpu.roles.common import KEYE_VL2, drop_collator_keys
 LOSS_TOL, LEAF_TOL = 1e-5, 2e-4
 
 
-@pytest.fixture(autouse=True, scope="module")
-def release_compiled_programs():
-    """This file's executables go when it ends: each holds memory mappings,
-    and a worker that keeps every file's crosses ``vm.max_map_count``
-    (ROADMAP C9)."""
-    yield
-    jax.clear_caches()
-
-
 @pytest.fixture(autouse=True)
 def blocks_of_query_rows(monkeypatch):
     """Both passes over blocks of query rows take several steps at the
@@ -54,64 +48,43 @@ def blocks_of_query_rows(monkeypatch):
     monkeypatch.setattr(keye_vl2, "INDEX_LOSS_BLOCK_ROWS", 8)
 
 
-def _setup(seq=32, share=0.25, **overrides):
-    cfg = KeyeVL2Config.tiny(dtype=jnp.float32, **overrides)
-    model = KeyeVL2ForCausalLM(cfg)
-    # the family's rows, with image spans that fit a row of 32
-    batch = drop_collator_keys(next(synthetic_causal_lm_batches(
-        cfg.vocab_size, 2, seq, 3, image_token_share=share,
-        image_grids=((2, 2), (2, 3)), positions=True,
-    )))
-    params = model.init(jax.random.PRNGKey(1), batch["input_ids"])["params"]
-    # away from the initialiser's symmetry: norms off 1, the indexer far
-    # from flat, every matrix of the size at which a different function shows
-    leaves, treedef = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(2), len(leaves))
-    params = jax.tree.unflatten(treedef, [
-        leaf + 0.1 * jax.random.normal(key, leaf.shape)
-        for leaf, key in zip(leaves, keys)
-    ])
-    return cfg, model, params, batch
+def _rows(share):
+    """The family's rows, with image spans that fit a row of 32."""
+    def batch(cfg, seq):
+        return drop_collator_keys(next(synthetic_causal_lm_batches(
+            cfg.vocab_size, 2, seq, 3, image_token_share=share,
+            image_grids=((2, 2), (2, 3)), positions=True,
+        )))
+
+    return batch
 
 
-def _reference_kwargs(cfg):
+def _reference_kwargs(cfg, **changes):
     return dict(
         num_heads=cfg.num_attention_heads, kv_heads=cfg.num_key_value_heads,
         eps=cfg.rms_norm_eps, theta=cfg.rope_theta,
         sections=cfg.mrope_section, index_heads=cfg.index_n_heads,
         index_top_k=cfg.index_topk, top_k=cfg.num_experts_per_tok,
-        held=cfg.held_experts,
+        held=cfg.held_experts, **changes,
     )
 
 
-def _model_grads(model, params, batch, term="loss"):
-    def loss(p):
-        total, metrics = keye_vl2_loss(model, p, batch)
+def _term(term):
+    """The loss whose gradient is taken: one term of the model's metrics."""
+    def loss(model, params, batch):
+        _total, metrics = keye_vl2_loss(model, params, batch)
         return metrics[term], metrics
 
-    return jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
+    return loss
 
 
-def _reference_grads(cfg, params, batch, choices=None, selections=None,
-                     **changes):
-    def loss(p):
-        with jax.default_matmul_precision("highest"):
-            out = reference.forward(
-                p, batch, choices=choices, selections=selections,
-                **dict(_reference_kwargs(cfg), **changes),
-            )
-        return out["loss"], out
-
-    return jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
-
-
-def _worst_leaf(got, want):
-    worst = 0.0
-    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        norm = float(jnp.linalg.norm(b))
-        if norm > 0:
-            worst = max(worst, float(jnp.linalg.norm(a - b)) / norm)
-    return worst
+# a quarter of every row image spans; and text rows alone
+KEYE = cases.Family(
+    tiny=KeyeVL2Config.tiny, module=KeyeVL2ForCausalLM, loss=_term("loss"),
+    reference=reference, reference_kwargs=_reference_kwargs, seq=32,
+    batch=_rows(0.25), loss_tol=LOSS_TOL, leaf_tol=LEAF_TOL,
+)
+ROWS = {0.25: KEYE, 0.0: dataclasses.replace(KEYE, batch=_rows(0.0))}
 
 
 @pytest.mark.parametrize(
@@ -124,11 +97,12 @@ def _worst_leaf(got, want):
     ids=["text_rows", "image_spans", "share_1_of_4", "top_16_of_64"],
 )
 def test_model_matches_reference(seq, share, overrides):
-    cfg, model, params, batch = _setup(
-        seq, share, emit_selection=True, **overrides
+    overrides = dict(overrides, emit_selection=True)
+    cfg, _model, _params, batch = cases.case(ROWS[share], seq, **overrides)
+    (loss, metrics), grads = cases.own(ROWS[share], seq, **overrides)
+    (ref_loss, ref), ref_grads = cases.reference_own(
+        ROWS[share], seq, **overrides
     )
-    (loss, metrics), grads = _model_grads(model, params, batch)
-    (ref_loss, ref), ref_grads = _reference_grads(cfg, params, batch)
     # float32 on both sides: the choices and the selection agree exactly
     np.testing.assert_array_equal(metrics["moe.choice"], ref["choice"])
     np.testing.assert_array_equal(
@@ -142,7 +116,7 @@ def test_model_matches_reference(seq, share, overrides):
         1e-4 * float(ref["index_kl"])
     )
     assert float(loss) == pytest.approx(float(ref_loss), rel=LOSS_TOL)
-    assert _worst_leaf(grads, ref_grads) <= LEAF_TOL
+    assert cases.worst_leaf(grads, ref_grads) <= LEAF_TOL
     np.testing.assert_allclose(
         metrics["attn.index_peak"], ref["index_peak"], rtol=1e-4
     )
@@ -168,9 +142,11 @@ def test_model_matches_reference(seq, share, overrides):
 def test_the_two_gradient_paths_are_disjoint():
     """dL_I reaches the indexer's leaves alone, dL_LM every other leaf
     alone: exact zeros, not small numbers."""
-    cfg, model, params, batch = _setup()
-    (_l, _m), of_kl = _model_grads(model, params, batch, "loss.index_kl")
-    (_l, _m), of_lm = _model_grads(model, params, batch, "loss.lm")
+    _cfg, model, params, batch = cases.case(KEYE)
+    (_l, _m), of_kl = cases.model_grads(
+        _term("loss.index_kl"), model, params, batch
+    )
+    (_l, _m), of_lm = cases.model_grads(_term("loss.lm"), model, params, batch)
 
     def norms(tree, indexer):
         return [
@@ -190,18 +166,19 @@ def test_reference_given_the_programs_choices_and_selection():
     """Given the program's router choices and selection the reference
     reproduces its own result (the chip check gives it both), and a
     DIFFERENT selection moves it."""
-    cfg, _model, params, batch = _setup()
-    (loss, own), _ = _reference_grads(cfg, params, batch)
-    (again, _), _ = _reference_grads(
-        cfg, params, batch, choices=own["choice"],
-        selections=own["selection"],
+    cfg, _model, params, batch = cases.case(KEYE)
+    (loss, own), _ = cases.reference_own(KEYE)
+    (again, _), _ = cases.reference_grads(
+        reference, _reference_kwargs(cfg, selections=own["selection"]),
+        params, batch, choices=own["choice"],
     )
     assert float(again) == pytest.approx(float(loss), rel=1e-6)
     causal = jnp.broadcast_to(
         jnp.tril(jnp.ones((32, 32), bool)), own["selection"].shape
     )
-    (other, out), _ = _reference_grads(
-        cfg, params, batch, choices=own["choice"], selections=causal,
+    (other, out), _ = cases.reference_grads(
+        reference, _reference_kwargs(cfg, selections=causal), params, batch,
+        choices=own["choice"],
     )
     assert abs(float(other) - float(loss)) > 1e-3
     # ... and what it would have selected itself is still reported
@@ -211,15 +188,15 @@ def test_reference_given_the_programs_choices_and_selection():
 def test_a_bf16_reference_fails():
     """The reading below the cell's precision is far off at least one of
     the tolerances the float32 comparison meets."""
-    cfg, model, params, batch = _setup()
-    (_loss, metrics), grads = _model_grads(model, params, batch)
-    (_r, ref), ref_grads = _reference_grads(
-        cfg, params, batch, choices=metrics["moe.choice"],
-        dtype=jnp.bfloat16,
+    cfg, _model, params, batch = cases.case(KEYE)
+    (_loss, metrics), grads = cases.own(KEYE)
+    (_r, ref), ref_grads = cases.reference_grads(
+        reference, _reference_kwargs(cfg, dtype=jnp.bfloat16), params, batch,
+        choices=metrics["moe.choice"],
     )
     ref_grads = jax.tree.map(lambda x: x.astype(jnp.float32), ref_grads)
     assert (
-        _worst_leaf(grads, ref_grads) > 10 * LEAF_TOL
+        cases.worst_leaf(grads, ref_grads) > 10 * LEAF_TOL
         or abs(float(metrics["loss.lm"]) - float(ref["lm"]))
         > 10 * LOSS_TOL * float(metrics["loss.lm"])
     )
@@ -299,8 +276,9 @@ def test_the_selection_is_the_kernels_behind_flash_and_the_loops_behind_dense():
                  attention_block_size=16, emit_selection=True)
     out = {}
     for impl in ("dense", "flash"):
-        cfg, model, params, batch = _setup(64, 0.25, attention_impl=impl,
-                                           **sizes)
+        _cfg, model, params, batch = cases.case(
+            KEYE, 64, attention_impl=impl, **sizes
+        )
         fn = jax.jit(lambda p: keye_vl2_loss(model, p, batch))
         text = str(jax.make_jaxpr(lambda p: keye_vl2_loss(model, p, batch))(
             params
@@ -353,7 +331,7 @@ def test_mrope_is_plain_rope_on_text_and_the_table_elsewhere():
 def test_loss_weights_zero_the_image_labels():
     """A label inside an image span carries no loss: changing it changes
     nothing; a text label's change does."""
-    cfg, model, params, batch = _setup()
+    cfg, model, params, batch = cases.case(KEYE)
     weights = np.asarray(batch["loss_weights"])
     assert (weights == 0).any() and (weights == 1).any()
     loss = jax.jit(lambda b: keye_vl2_loss(model, params, b)[1]["loss.lm"])
@@ -387,7 +365,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
     layer output — no shared expert, so nothing is computed alike on every
     chip but the router, whose choices agree; the mixer and the indexer are
     data-parallel: every chip computes them whole."""
-    cfg, _model, params, _batch = _setup()
+    cfg, _model, params, _batch = cases.case(KEYE)
     layer = jax.tree.map(lambda x: x[0], params["layers"]["layer_1"]["mlp"])
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 32, cfg.hidden_size))
     with jax.default_matmul_precision("highest"):
@@ -395,29 +373,15 @@ def test_the_shares_add_up_to_the_uncut_layer():
             x.reshape(-1, cfg.hidden_size), layer, held=(0, 8),
             top_k=cfg.num_experts_per_tok,
         )
-    total, local = 0.0, 0.0
-    for index in range(4):
-        share = KeyeVL2Config.tiny(dtype=jnp.float32, expert_shard=(index, 4))
-        first, held = share.held_experts
-        mine = dict(layer, **{
-            name: layer[name][first:first + held]
-            for name in reference.EXPERTS
-        })
-        y, routing = RoutedGLU(share, activation="silu").apply(
-            {"params": mine}, x, x
-        )
-        total = total + y
-        local += float(routing["local_slot_share"])
-        np.testing.assert_array_equal(routing["choice"], choice)
-        assert float(routing["dropped_slots"]) == 0.0
-    assert local == pytest.approx(1.0, abs=1e-6)
-    want = whole.reshape(x.shape)
-    np.testing.assert_allclose(total, want, atol=2e-5, rtol=2e-5)
-    assert float(jnp.max(jnp.abs(y - want))) > 1e-3  # no share is the layer
+    cases.check_the_routed_shares_add_up(
+        KEYE, layer, lambda share: RoutedGLU(share, activation="silu"),
+        (x, x), dict(routed=whole, choice=choice), reference.EXPERTS,
+        shares=4,
+    )
 
 
 def test_masks_flops_and_the_cut():
-    cfg, _model, params, _batch = _setup()
+    params = cases.case(KEYE).params
     decay = KEYE_VL2.weight_decay_mask(params)
     assert decay["norm"]["weight"] is False and decay["lm_head"] is True
     layer = decay["layers"]["layer_0"]

@@ -8,8 +8,10 @@ are the same bits in float32, the indexer's loss through its own kernel pair
 (``ops/index_loss.py``: the model under test runs the flash path) among
 them; and the kept bytes at the published widths
 are the shapes' arithmetic. (The other four families' cases of the same
-assertions: ``tests/test_remat_operands.py``, whose helpers these are; a
-file of its own because that one is the suite's longest, ROADMAP C9.)"""
+assertions: ``tests/test_remat_operands_<family>.py``; the helpers of both
+are ``tests/remat_cases.py``.) Last, what the model's accumulate_step keeps
+once it is compiled for a TPU v5e (its row of ``tools/tpu_aot.py``, no chip:
+``tests/tpu_aot_rows.py``)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -23,21 +25,13 @@ from dedloc_tpu.roles.common import (
     drop_collator_keys,
     model_family,
 )
-from tests import test_remat_operands as shared
+import remat_cases as shared
+from tpu_aot_rows import tpu_aot
 
 shared.TINY["keye"] = ("keye_vl2_tiny", dict(
     head_dim=128, mrope_section=(16, 24, 24), num_hidden_layers=2,
     attention_block_size=16,
 ))
-
-
-@pytest.fixture(autouse=True, scope="module")
-def release_compiled_programs():
-    """This file's executables go when it ends: each holds memory mappings,
-    and a worker that keeps every file's crosses ``vm.max_map_count``
-    (ROADMAP C9)."""
-    yield
-    jax.clear_caches()
 
 
 @pytest.fixture(autouse=True)
@@ -125,3 +119,72 @@ def test_the_selection_is_kept_with_the_operands():
     assert selection == 1_073_741_824
     assert operands - outputs == 4 * seq * (32 + 2 * 4) * 128 * 2 + selection
     assert mixer - operands == 4 * seq * (4096 + 512 + 2048) * 2
+
+
+def test_keye_accumulate_step_reads_a_selection_and_holds_nothing_heads_by_s_by_s():
+    """Keye-VL-2.0's language model at the cell's cut (four layers, 1 row of
+    16,384), compiled for a v5e alone and inside its accumulate_step: the
+    selected kernels — the int8 [S, S] selection a tile operand, the tile
+    flags in SMEM — get through Mosaic at 32 query heads over 4 kv heads;
+    under the model's default remat ``kernel_operands`` no kernel is
+    replayed (4 sites each: the selection is KEPT with the operands), the
+    selection is ONE ``index_select`` call a layer and the indexer's loss
+    its own kernel pair over the same tiles, and nothing of either's XLA
+    block loop is left; the
+    tile loop's backward sums into the accumulator's twelve expert leaves;
+    NOTHING of size [heads, S, S] is materialised — the largest array the
+    compiled module names is 256 MB (the int8 selection itself), where ONE
+    head's float32 scores are 1,024 MB and 32
+    heads' bf16 ones 17 GB —; and the program's scratch beside 28 bytes a
+    parameter of state with a draining snapshot (+ the held experts' bf16
+    copies) stays under the 15.3 GB line."""
+    rows = tpu_aot("sel_kernels", "index_loss_kernels", "select_kernels",
+                    "keye_accumulate_step")
+    # the selection's kernel alone (``ops/index_select.py``): Mosaic takes a
+    # block of 256 query rows' ordered keys, [256, 16384] int32, as VMEM
+    # scratch beside the resident key head and the int8 rows it writes
+    assert rows.pop("select_kernels")["kernel_calls"] == {"index_select": 1}
+    # the indexer's loss kernels alone (``ops/index_loss.py``): Mosaic takes
+    # the forward sweep and the one backward sweep that holds the key
+    # head's whole gradient, [16384, 128] float32, in VMEM
+    assert rows.pop("index_loss_kernels")["kernel_calls"] == {
+        "index_loss_fwd": 1, "index_loss_bwd": 1,
+    }
+    heads = {"heads": 32, "kv_heads": 4}
+    for row in rows.values():
+        assert row["flash_windows"] == {
+            "flash_sel_fwd": heads, "flash_sel_bwd_dq": heads,
+            "flash_sel_bwd_dkv": heads,
+        }
+    assert rows["sel_kernels"]["kernel_calls"] == {
+        "flash_sel_fwd": 1, "flash_sel_bwd_dq": 1, "flash_sel_bwd_dkv": 1,
+    }
+    row = rows["keye_accumulate_step"]
+    # ... and the loss's pair: one forward sweep a layer (its logZ rides in
+    # a Pallas output, which the policy keeps: no replay), one backward
+    assert row["kernel_calls"] == {
+        "flash_sel_fwd": 4, "flash_sel_bwd_dq": 4, "flash_sel_bwd_dkv": 4,
+        "index_loss_fwd": 4, "index_loss_bwd": 4, "index_select": 4,
+    }
+    assert row["tpu_custom_calls"] == 24
+    # no float32 [128, 16, 16384] index scores, no [4, 8, 128, 16384] main
+    # scores, no selection cut into the loss's blocks of 128 rows — what the
+    # XLA block loop made, 128 blocks a layer and direction (PR 51) — in the
+    # lowered or the compiled module
+    assert row["loss_block_transients"] == []
+    # ... and no float32 [256, 16, 16384] index scores, no selection written
+    # a block of 256 rows at a time (``s8[64,256,16384]``: the XLA loop's
+    # slabs, 64 steps a layer, PR 51)
+    assert row["select_block_transients"] == []
+    grads = row["expert_grad_passes"]
+    assert (grads["adds"], grads["zero_fills"], grads["held_casts"]) == (
+        0, 0, 0
+    )
+    assert row["remat_policy"] == "kernel_operands"
+    largest = row["largest_buffers_mb"]
+    assert largest and max(mb for _shape, mb in largest) <= 256.0, largest
+    assert any(shape == "s8[1,16384,16384]" for shape, _mb in largest)
+    # 5,336,333,312 bytes of scratch (PR 51) beside 8.80 + 0.30 GB
+    assert row["memory"]["temp_bytes"] <= 5.5e9
+    held = 4 * 8 * 3 * 2048 * 768
+    assert 314_396_160 * 28 + held * 2 + row["memory"]["temp_bytes"] <= 15.3e9

@@ -6,41 +6,19 @@ loss on an image label); the step records carry the routing gauges, the
 selection's two shares, an index peak a layer, the indexer's own loss term
 and the image share; the held experts' gradients land in the accumulator
 (gradient sinks); the indexer's leaves move (its loss reaches them)."""
-import json
-
-import jax
 import numpy as np
 import pytest
 
-from dedloc_tpu.core.config import CollaborationArguments, parse_config
+import decoder_cases as cases
 from dedloc_tpu.data import causal_lm
 from dedloc_tpu.models.keye_vl2 import KeyeVL2Config, selected_pairs
-from dedloc_tpu.parallel.train_step import stash_bytes
 from dedloc_tpu.roles.common import (
     DEEPSEEK_V3,
     KEYE_VL2,
-    build_loss_fn,
     build_model,
     model_family,
 )
-from dedloc_tpu.roles.trainer import _make_batches, run_trainer
-
-
-def _args(tmp_path, argv=()):
-    base = [
-        "--dht.listen_host", "127.0.0.1",
-        "--training.model_size", "keye_vl2_tiny",
-        "--training.seq_length", "32",
-        "--training.per_device_batch_size", "2",
-        "--training.gradient_accumulation_steps", "2",
-        "--training.warmup_steps", "2",
-        "--training.total_steps", "50",
-        "--training.output_dir", str(tmp_path / "out"),
-        "--averager.averaging_expiration", "1.0",
-        "--averager.min_refresh_period", "0.1",
-        "--averager.default_refresh_period", "0.3",
-    ]
-    return parse_config(CollaborationArguments, base + list(argv))
+from dedloc_tpu.roles.trainer import _make_batches
 
 
 @pytest.mark.parametrize(
@@ -51,36 +29,16 @@ def test_keye_tiny_trainer_makes_global_steps(tmp_path, monkeypatch, shard,
                                               layers, share):
     # image spans that fit a quarter of a row of 32
     monkeypatch.setattr(causal_lm, "IMAGE_GRIDS", ((2, 2), (2, 3)))
-    events = tmp_path / "events.jsonl"
-    args = _args(tmp_path, [
-        "--optimizer.target_batch_size", "8",
-        "--training.max_local_steps", "9",
-        "--training.expert_shard", shard,
-        "--training.num_hidden_layers", layers,
-        "--training.image_token_share", share,
-        "--telemetry.enabled", "true",
-        "--telemetry.event_log_path", str(events),
-    ])
-    state = run_trainer(args)
-    assert int(state.step) >= 2
+    state, stepped, _records = cases.run_tiny_trainer(
+        tmp_path, "keye_vl2_tiny", [
+            "--training.expert_shard", shard,
+            "--training.num_hidden_layers", layers,
+            "--training.image_token_share", share,
+        ]
+    )
     depth = int(layers) or 2
-    log = [json.loads(line) for line in events.read_text().splitlines()]
-    stepped = [
-        e for e in log if e.get("event") == "step.record" and e.get("stepped")
-    ]
-    assert len(stepped) >= 2
-    count = int(shard.split("/")[1])
+    cases.check_routing_records(stepped, shard, depth)
     for rec in stepped:
-        assert rec["moe.dropped_slots"] == 0.0
-        assert all(
-            rec[f"moe.load_max_over_mean.{i}"] >= 1.0
-            for i in range(1, depth + 1)
-        )
-        assert f"moe.load_max_over_mean.{depth + 1}" not in rec
-        assert rec["moe.local_slot_share"] == pytest.approx(
-            1.0 / count, abs=0.0 if count == 1 else 0.25
-        )
-        assert rec["moe.grad_sink_leaves"] == 3.0 * depth
         # top-8 of up to 32 keys: 36 + 24 x 8 selected pairs of 528
         assert rec["attn.select_kept_share"] == pytest.approx(228 / 528)
         assert rec["attn.select_tile_share"] == 1.0  # S=32: one tile
@@ -99,18 +57,10 @@ def test_keye_tiny_trainer_makes_global_steps(tmp_path, monkeypatch, shard,
             assert 0.1 < rec["data.image_token_share"] <= 0.3
         else:
             assert rec["data.image_token_share"] == 0.0
-    losses = [rec["loss"] for rec in stepped if "loss" in rec]
-    assert all(np.isfinite(losses))
-    cfg, model = build_model(
-        "keye_vl2_tiny", num_hidden_layers=int(layers), expert_shard=shard,
+    cases.check_kept_bytes_is_the_shapes(  # the model's default policy
+        stepped, KEYE_VL2, "kernel_operands", state.params, "keye_vl2_tiny",
+        num_hidden_layers=int(layers), expert_shard=shard,
     )
-    assert cfg.remat_policy == "kernel_operands"  # the model's default
-    kept = stash_bytes(  # the same number, from the shapes alone
-        build_loss_fn(model), state.params,
-        next(KEYE_VL2.synthetic_batches(cfg, 2, 32, 0)),
-        jax.random.PRNGKey(0),
-    )
-    assert {rec["remat.kept_bytes"] for rec in stepped} == {float(kept)}
     # the indexer's loss reached the indexer: its leaves left the
     # initialiser (a LayerNorm's weight starts at exactly 1)
     first = state.params["layers"]["layer_0"]["indexer"]
@@ -164,15 +114,18 @@ def test_the_share_of_image_spans_is_the_runs(tmp_path, monkeypatch):
     it and builds no ``position_ids``."""
     assert KEYE_VL2.batch_positions and not DEEPSEEK_V3.batch_positions
     cfg, _model = build_model("keye_vl2_tiny")
-    text = next(_make_batches(_args(tmp_path), cfg, b"peer"))
+
+    def _args(argv=()):
+        return cases.trainer_args(tmp_path, "keye_vl2_tiny", argv)
+
+    text = next(_make_batches(_args(), cfg, b"peer"))
     assert (text["loss_weights"] == 1).all()
     np.testing.assert_array_equal(
         text["position_ids"], np.broadcast_to(np.arange(32), (3, 2, 32))
     )
     monkeypatch.setattr(causal_lm, "IMAGE_GRIDS", ((2, 2), (2, 3)))
     spans = next(_make_batches(
-        _args(tmp_path, ["--training.image_token_share", "0.25"]), cfg,
-        b"peer",
+        _args(["--training.image_token_share", "0.25"]), cfg, b"peer",
     ))
     assert 0.1 < 1 - spans["loss_weights"].mean() <= 0.3
     assert (spans["position_ids"][0] != spans["position_ids"][2]).any()
@@ -181,10 +134,10 @@ def test_the_share_of_image_spans_is_the_runs(tmp_path, monkeypatch):
     ]
     cfg, _model = build_model("sdar_tiny")
     assert "position_ids" not in next(
-        _make_batches(_args(tmp_path, sdar), cfg, b"peer")
+        _make_batches(_args(sdar), cfg, b"peer")
     )
     with pytest.raises(ValueError, match="reads no positions"):
         _make_batches(
-            _args(tmp_path, sdar + ["--training.image_token_share", "0.25"]),
-            cfg, b"peer",
+            _args(sdar + ["--training.image_token_share", "0.25"]), cfg,
+            b"peer",
         )

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import decoder_cases as cases
 from benchmark.reference import kimi_linear as reference
 from dedloc_tpu.models.decoder import (
     BIAS,
@@ -36,37 +37,18 @@ LOSS_TOL, LEAF_TOL, SCORE_TOL = 1e-5, 3e-4, 1e-5
 SEQ = 128  # two chunks of the kernels
 
 
-@pytest.fixture(autouse=True, scope="module")
-def release_compiled_programs():
-    """This file's executables go when it ends (ROADMAP C9)."""
-    yield
-    jax.clear_caches()
-
-
-def _perturbed(params, seed=2, scale=0.1):
-    leaves, treedef = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
-    return jax.tree.unflatten(treedef, [
-        leaf + scale * jax.random.normal(key, leaf.shape)
-        for leaf, key in zip(leaves, keys)
-    ])
-
-
 @pytest.fixture(scope="module")
 def setup():
     cfg = KimiLinearConfig.tiny(dtype=jnp.float32, num_hidden_layers=5)
     model = KimiLinearForCausalLM(cfg)
     ids = jax.random.randint(jax.random.PRNGKey(3), (1, SEQ + 1), 0, 256)
     batch = {"input_ids": ids[:, :-1], "labels": ids[:, 1:]}
-    params = _perturbed(
+    params = cases.perturbed(
         model.init(jax.random.PRNGKey(1), batch["input_ids"])["params"]
     )
-    loss, metrics = jax.jit(
-        lambda p: kimi_linear_loss(model, p, batch)
-    )(params)
-    grads = jax.jit(jax.grad(
-        lambda p: kimi_linear_loss(model, p, batch)[0]
-    ))(params)
+    (loss, metrics), grads = cases.model_grads(
+        kimi_linear_loss, model, params, batch
+    )
     return cfg, model, params, batch, loss, metrics, grads
 
 
@@ -79,17 +61,10 @@ def _reference_kwargs(cfg):
 
 
 def _reference(cfg, params, batch, choices, **mutations):
-    def loss(p):
-        with jax.default_matmul_precision("highest"):
-            out = reference.forward(
-                p, batch, **_reference_kwargs(cfg), choices=choices,
-                **mutations,
-            )
-        return out["loss"], out
-
-    (value, out), grads = jax.jit(
-        jax.value_and_grad(loss, has_aux=True)
-    )(params)
+    (value, out), grads = cases.reference_grads(
+        reference, dict(_reference_kwargs(cfg), **mutations), params, batch,
+        choices=choices,
+    )
     return value, out, grads
 
 
@@ -214,7 +189,7 @@ def test_the_head_shards_add_up_to_the_uncut_mixer(count=4):
     ):
         is_kda = isinstance(whole, KimiDeltaAttention)
         args = (x,) if is_kda else (x, None)
-        params = _perturbed(whole.init(jax.random.PRNGKey(1), *args)["params"])
+        params = cases.perturbed(whole.init(jax.random.PRNGKey(1), *args)["params"])
         full = whole.apply({"params": params}, *args)
         parts = []
         for index in range(count):
@@ -235,7 +210,7 @@ def test_the_expert_shards_add_up_to_the_uncut_layer():
     cfg = KimiLinearConfig.tiny(dtype=jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 32, cfg.hidden_size))
     layer = RoutedFFN(cfg, shared_width=cfg.moe_intermediate_size)
-    params = _perturbed(layer.init(jax.random.PRNGKey(1), x)["params"])
+    params = cases.perturbed(layer.init(jax.random.PRNGKey(1), x)["params"])
     full, _routing = layer.apply({"params": params}, x)
     shared = SwiGLU(cfg, cfg.moe_intermediate_size).apply(
         {"params": params["shared_experts"]}, x

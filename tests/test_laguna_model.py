@@ -10,13 +10,12 @@ partial rotary, YaRN's table, the gate's gradient; the depth rule; the
 parameter count of the cell's cut; and THE SHARE TEST: the shares' routed
 parts and the shared expert counted once add up to the uncut reference's
 layer output."""
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import decoder_cases as cases
 from benchmark.reference import laguna as reference
 from benchmark.roles.trainer_laguna_lm import (
     reference_kwargs as role_reference_kwargs,
@@ -48,63 +47,17 @@ from dedloc_tpu.models.laguna import (
 LOSS_TOL, LEAF_TOL = 1e-5, 1e-4
 
 
-def _setup(seq=32, **overrides):
-    cfg = LagunaConfig.tiny(dtype=jnp.float32, **overrides)
-    model = LagunaForCausalLM(cfg)
-    rows = np.random.default_rng(0).integers(
-        0, cfg.vocab_size, (2, seq + 1)
-    ).astype(np.int32)
-    batch = {"input_ids": jnp.asarray(rows[:, :-1]),
-             "labels": jnp.asarray(rows[:, 1:])}
-    params = model.init(jax.random.PRNGKey(1), batch["input_ids"])["params"]
-    # away from the initialiser's symmetry: norms off 1, every matrix of
-    # the size at which a different function shows (gates off one half)
-    leaves, treedef = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(2), len(leaves))
-    params = jax.tree.unflatten(treedef, [
-        leaf + 0.1 * jax.random.normal(key, leaf.shape)
-        for leaf, key in zip(leaves, keys)
-    ])
-    return cfg, model, params, batch
-
-
 def reference_kwargs(cfg, **changes):
     """``reference.forward``'s arguments from the config's own keys (the
     benchmark role's), with a test's departures."""
     return dict(role_reference_kwargs(cfg), **changes)
 
 
-@functools.lru_cache(maxsize=None)
-def _default():
-    """The default case and the model's own result on it, computed once."""
-    cfg, model, params, batch = _setup()
-    return cfg, model, params, batch, _model_grads(model, params, batch)
-
-
-def _model_grads(model, params, batch):
-    return jax.jit(jax.value_and_grad(
-        lambda p: laguna_loss(model, p, batch), has_aux=True
-    ))(params)
-
-
-def _reference_grads(cfg, params, batch, choices=None, **changes):
-    def loss(p, choices):
-        with jax.default_matmul_precision("highest"):
-            out = reference.forward(
-                p, batch, choices=choices, **reference_kwargs(cfg, **changes)
-            )
-        return out["loss"], out
-
-    return jax.jit(jax.value_and_grad(loss, has_aux=True))(params, choices)
-
-
-def _worst_leaf(got, want):
-    worst = 0.0
-    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        norm = float(jnp.linalg.norm(b))
-        if norm > 0:
-            worst = max(worst, float(jnp.linalg.norm(a - b)) / norm)
-    return worst
+LAGUNA = cases.Family(
+    tiny=LagunaConfig.tiny, module=LagunaForCausalLM, loss=laguna_loss,
+    reference=reference, reference_kwargs=reference_kwargs, seq=32,
+    loss_tol=LOSS_TOL, leaf_tol=LEAF_TOL,
+)
 
 
 @pytest.mark.parametrize(
@@ -113,18 +66,10 @@ def _worst_leaf(got, want):
     ids=["whole", "share_1_of_4", "cut_to_2_layers"],
 )
 def test_model_matches_reference(overrides):
-    if overrides:
-        cfg, model, params, batch = _setup(**overrides)
-        (loss, metrics), grads = _model_grads(model, params, batch)
-    else:
-        cfg, model, params, batch, ((loss, metrics), grads) = _default()
-    (ref_loss, ref), ref_grads = _reference_grads(cfg, params, batch)
-    # float32 on both sides: the choices agree exactly, nothing is forced
-    np.testing.assert_array_equal(metrics["moe.choice"], ref["choice"])
+    cfg, metrics, _grads, ref, _ref_grads = (
+        cases.check_model_matches_reference(LAGUNA, **overrides)
+    )
     np.testing.assert_allclose(metrics["moe.scores"], ref["scores"], atol=1e-5)
-    assert abs(float(loss) - float(ref_loss)) <= LOSS_TOL * float(ref_loss)
-    assert _worst_leaf(grads, ref_grads) <= LEAF_TOL
-    assert float(metrics["moe.dropped_slots"]) == 0.0
     assert float(metrics["moe.grad_sink_leaves"]) == 0.0  # none handed
     sparse = sum(ffn == SPARSE for ffn in cfg.mlp_layer_types[
         :cfg.num_hidden_layers
@@ -137,10 +82,6 @@ def test_model_matches_reference(overrides):
         got = float(metrics[f"attn.gate_mean.{kind}"])
         assert got == pytest.approx(want, abs=1e-6)
         assert 0.3 < got < 0.7  # a gate at 0 or 1 is a dead mechanism
-    shards = cfg.expert_shard[1]
-    assert abs(
-        float(metrics["moe.local_slot_share"]) - 1.0 / shards
-    ) < (0.0 if shards == 1 else 0.15) + 1e-6
 
 
 @pytest.mark.parametrize(
@@ -151,19 +92,21 @@ def test_model_matches_reference(overrides):
          "plain_frequencies", "softmax_router", "window_off"],
 )
 def test_a_different_function_fails(changes):
-    cfg, _model, params, batch, ((_loss, metrics), grads) = _default()
-    # the same routing, so that what differs is the function alone
-    (_ref_loss, _ref), ref_grads = _reference_grads(
-        cfg, params, batch, choices=metrics["moe.choice"], **changes
-    )
-    off = _worst_leaf(grads, ref_grads)
-    assert off > 100 * LEAF_TOL, off
+    cases.check_a_different_function_fails(LAGUNA, changes)
+
+
+def test_the_models_own_gradients_are_computed_once():
+    """What holds the cases above to a reference's compile each: the
+    default case's gradients come from the cache however often asked."""
+    assert cases.own(LAGUNA) is cases.own(LAGUNA)
+    assert cases.OWN_COMPUTED and set(cases.OWN_COMPUTED.values()) == {1}
 
 
 def test_all_lanes_rotated_fails():
     """A reference that rotates the WHOLE head of a full layer (partial
     rotary factor 1 where the config says 0.5) is far off."""
-    cfg, _model, params, batch, ((_loss, metrics), grads) = _default()
+    cfg, _model, params, batch = cases.case(LAGUNA)
+    (_loss, metrics), grads = cases.own(LAGUNA)
     kwargs = reference_kwargs(cfg)
     kwargs["rope"][FULL]["partial_rotary_factor"] = 1
 
@@ -173,7 +116,9 @@ def test_all_lanes_rotated_fails():
                 p, batch, choices=metrics["moe.choice"], **kwargs
             )["loss"]
 
-    assert _worst_leaf(grads, jax.jit(jax.grad(loss))(params)) > 100 * LEAF_TOL
+    assert cases.worst_leaf(
+        grads, jax.jit(jax.grad(loss))(params)
+    ) > 100 * LEAF_TOL
 
 
 def test_the_reference_routed_by_given_choices_and_in_bf16():
@@ -182,17 +127,18 @@ def test_the_reference_routed_by_given_choices_and_in_bf16():
     it is off by more than the float32 tolerances (the chip check's limits
     are set between the role's reading and this one's). A dense and a
     sparse layer are enough to show both."""
-    cfg, _model, params, batch = _setup(num_hidden_layers=2)
-    (loss, own), grads = _reference_grads(cfg, params, batch)
-    (again, _), _ = _reference_grads(
-        cfg, params, batch, choices=own["choice"]
+    cfg, _model, params, batch = cases.case(LAGUNA, num_hidden_layers=2)
+    (loss, own), grads = cases.reference_own(LAGUNA, num_hidden_layers=2)
+    (again, _), _ = cases.reference_grads(
+        reference, reference_kwargs(cfg), params, batch, choices=own["choice"]
     )
     assert float(loss) == pytest.approx(float(again), rel=1e-6)
-    (low_loss, _), low_grads = _reference_grads(
-        cfg, params, batch, choices=own["choice"], dtype=jnp.bfloat16
+    (low_loss, _), low_grads = cases.reference_grads(
+        reference, reference_kwargs(cfg, dtype=jnp.bfloat16), params, batch,
+        choices=own["choice"],
     )
     assert abs(float(low_loss) - float(loss)) > LOSS_TOL * float(loss)
-    assert _worst_leaf(low_grads, grads) > LEAF_TOL
+    assert cases.worst_leaf(low_grads, grads) > LEAF_TOL
 
 
 @pytest.mark.parametrize("window", [8, 16, 24], ids=[
@@ -204,16 +150,11 @@ def test_the_flash_kernels_inside_the_model(window):
     whole group of THREE a program in the full layers (6 / 2), a group of
     four under ``band=`` in the others (8 / 2) — in interpreter mode against
     the reference, at 16 x 16 tiles."""
-    cfg, model, params, batch = _setup(
-        seq=64, head_dim=128, num_hidden_layers=2, attention_impl="flash",
-        attention_block_size=16, sliding_window=window,
+    metrics = cases.check_the_model_under_overrides(
+        LAGUNA, 64, head_dim=128, num_hidden_layers=2,
+        attention_impl="flash", attention_block_size=16,
+        sliding_window=window,
     )
-    (loss, metrics), grads = _model_grads(model, params, batch)
-    (ref_loss, _ref), ref_grads = _reference_grads(
-        cfg, params, batch, choices=metrics["moe.choice"]
-    )
-    assert abs(float(loss) - float(ref_loss)) <= LOSS_TOL * float(ref_loss)
-    assert _worst_leaf(grads, ref_grads) <= LEAF_TOL
     # 4 query tiles of 16: 1 + 2 + 2 + 2 tiles at a band of 8 or 16 (the
     # second crossed tile holds ONE visible pair at 16, none of whose pairs
     # at 8 but the tile before the diagonal's last column), 1 + 2 + 3 + 3 at 24
@@ -308,7 +249,8 @@ def test_the_gates_gradient():
     ``test_model_matches_reference``: every leaf), and here the gate alone:
     one dense layer (no router: a smooth function) against a central
     difference along one direction."""
-    cfg, _model, params, batch, (_, grads) = _default()
+    cfg, _model, params, _batch = cases.case(LAGUNA)
+    _, grads = cases.own(LAGUNA)
     for tree in ("dense_layer_0", "tail_layer_0"):
         assert float(jnp.linalg.norm(grads[tree]["g_proj"]["kernel"])) > 1e-4
     layer = DecoderLayer(cfg, FULL, 6, False)
@@ -382,7 +324,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
     told its share, holding 2 of the 16 experts) and the shared expert
     COUNTED ONCE — every chip computes it alike — are the uncut reference's
     layer output."""
-    cfg, _model, params, _batch = _setup()
+    cfg, _model, params, _batch = cases.case(LAGUNA)
     layer = jax.tree.map(lambda x: x[0], params["layers"]["layer_1"]["mlp"])
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 32, cfg.hidden_size))
     with jax.default_matmul_precision("highest"):
@@ -428,7 +370,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
 
 
 def test_masks_parameters_and_flops():
-    cfg, _model, params, _batch = _setup()
+    params = cases.case(LAGUNA).params
     decay = laguna_weight_decay_mask(params)
     assert decay["norm"]["weight"] is False and decay["lm_head"] is True
     assert decay["layers"]["layer_0"]["input_layernorm"]["weight"] is False

@@ -5,40 +5,16 @@ a sign (the routed layer has no bias); the step records carry the routing
 gauges, the sliding layers' two tile shares, a mean gate per attention kind
 and the counter that must read 0; the held experts' gradients land in the
 accumulator (gradient sinks)."""
-import json
-
-import jax
-import numpy as np
 import pytest
 
-from dedloc_tpu.core.config import CollaborationArguments, parse_config
+import decoder_cases as cases
 from dedloc_tpu.models.laguna import FULL, SLIDING, LagunaConfig
-from dedloc_tpu.parallel.train_step import stash_bytes
 from dedloc_tpu.roles.common import (
     DEEPSEEK_V3,
     LAGUNA,
-    build_loss_fn,
     build_model,
     model_family,
 )
-from dedloc_tpu.roles.trainer import run_trainer
-
-
-def _args(tmp_path, argv=()):
-    base = [
-        "--dht.listen_host", "127.0.0.1",
-        "--training.model_size", "laguna_tiny",
-        "--training.seq_length", "32",
-        "--training.per_device_batch_size", "2",
-        "--training.gradient_accumulation_steps", "2",
-        "--training.warmup_steps", "2",
-        "--training.total_steps", "50",
-        "--training.output_dir", str(tmp_path / "out"),
-        "--averager.averaging_expiration", "1.0",
-        "--averager.min_refresh_period", "0.1",
-        "--averager.default_refresh_period", "0.3",
-    ]
-    return parse_config(CollaborationArguments, base + list(argv))
 
 
 @pytest.mark.parametrize(
@@ -46,54 +22,26 @@ def _args(tmp_path, argv=()):
     ids=["whole", "share_1_of_4_cut_to_5"],
 )
 def test_laguna_tiny_trainer_makes_global_steps(tmp_path, shard, layers):
-    events = tmp_path / "events.jsonl"
-    args = _args(tmp_path, [
-        "--optimizer.target_batch_size", "8",
-        "--training.max_local_steps", "9",
-        "--training.expert_shard", shard,
-        "--training.num_hidden_layers", layers,
-        "--telemetry.enabled", "true",
-        "--telemetry.event_log_path", str(events),
-    ])
-    state = run_trainer(args)
-    assert int(state.step) >= 2
+    state, stepped, _records = cases.run_tiny_trainer(
+        tmp_path, "laguna_tiny", [
+            "--training.expert_shard", shard,
+            "--training.num_hidden_layers", layers,
+        ]
+    )
     sparse = (int(layers) or 6) - 1  # one leading dense layer
-    log = [json.loads(line) for line in events.read_text().splitlines()]
-    stepped = [
-        e for e in log if e.get("event") == "step.record" and e.get("stepped")
-    ]
-    assert len(stepped) >= 2
-    count = int(shard.split("/")[1])
+    cases.check_routing_records(stepped, shard, sparse)
     for rec in stepped:
-        assert rec["moe.dropped_slots"] == 0.0
         assert 0.0 <= rec["moe.bulk_row_share"] <= 1.0
-        assert all(
-            rec[f"moe.load_max_over_mean.{i}"] >= 1.0
-            for i in range(1, sparse + 1)
-        )
-        assert f"moe.load_max_over_mean.{sparse + 1}" not in rec
-        assert rec["moe.local_slot_share"] == pytest.approx(
-            1.0 / count, abs=0.0 if count == 1 else 0.25
-        )
-        assert rec["moe.grad_sink_leaves"] == 3.0 * sparse
         assert rec["attn.band_tile_share"] == 1.0  # S=32: one tile
         # a band of 8 inside one 32 x 32 tile: 228 visible pairs of 1,024
         assert rec["attn.band_visible_share"] == pytest.approx(228 / 1024)
         for kind in (FULL, SLIDING):  # at the initialiser a gate is a half
             assert 0.4 < rec[f"attn.gate_mean.{kind}"] < 0.6
         assert "moe.bias_abs_max" not in rec  # no bias leaf, no sign step
-    losses = [rec["loss"] for rec in stepped if "loss" in rec]
-    assert all(np.isfinite(losses))
-    # the remat policy's counter: what the builder read from the shapes
-    cfg, model = build_model(
-        "laguna_tiny", num_hidden_layers=int(layers), expert_shard=shard
+    cases.check_kept_bytes_is_the_shapes(
+        stepped, LAGUNA, "whole_mixer", state.params, "laguna_tiny",
+        num_hidden_layers=int(layers), expert_shard=shard,
     )
-    assert cfg.remat_policy == "whole_mixer"
-    kept = stash_bytes(  # the same number, from the shapes alone
-        build_loss_fn(model), state.params,
-        next(LAGUNA.synthetic_batches(cfg, 2, 32, 0)), jax.random.PRNGKey(0),
-    )
-    assert {rec["remat.kept_bytes"] for rec in stepped} == {float(kept)}
 
 
 def test_the_table_builds_laguna():

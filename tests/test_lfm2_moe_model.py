@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import decoder_cases as cases
 from benchmark.reference import lfm2_moe as reference
 from dedloc_tpu.models.decoder import BIAS, RoutedFFN, sign_step_mask
 from dedloc_tpu.models.lfm2_moe import (
@@ -28,26 +29,6 @@ from dedloc_tpu.models.lfm2_moe import (
 LOSS_TOL, LEAF_TOL = 1e-5, 1e-4
 
 
-def _setup(**overrides):
-    cfg = Lfm2MoeConfig.tiny(dtype=jnp.float32, **overrides)
-    model = Lfm2MoeForCausalLM(cfg)
-    rows = np.random.default_rng(0).integers(
-        0, cfg.vocab_size, (2, 65)
-    ).astype(np.int32)
-    batch = {"input_ids": jnp.asarray(rows[:, :-1]),
-             "labels": jnp.asarray(rows[:, 1:])}
-    params = model.init(jax.random.PRNGKey(1), batch["input_ids"])["params"]
-    # away from the initialiser's symmetry: norms off 1, the bias off 0 by
-    # more than neighbouring scores differ, taps of the size of a weight
-    leaves, treedef = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(2), len(leaves))
-    params = jax.tree.unflatten(treedef, [
-        leaf + 0.1 * jax.random.normal(key, leaf.shape)
-        for leaf, key in zip(leaves, keys)
-    ])
-    return cfg, model, params, batch
-
-
 def _reference_kwargs(cfg, **changes):
     kwargs = dict(
         num_heads=cfg.num_attention_heads, kv_heads=cfg.num_key_value_heads,
@@ -59,40 +40,11 @@ def _reference_kwargs(cfg, **changes):
     return kwargs
 
 
-def _model_grads(model, params, batch):
-    return jax.value_and_grad(
-        lambda p: lfm2_moe_loss(model, p, batch), has_aux=True
-    )(params)
-
-
-def _reference_grads(cfg, params, batch, **changes):
-    with jax.default_matmul_precision("highest"):
-        return jax.value_and_grad(
-            lambda p: (lambda out: (out["loss"], out))(
-                reference.forward(p, batch, **_reference_kwargs(cfg, **changes))
-            ), has_aux=True,
-        )(params)
-
-
-def _bias_apart(tree):
-    taken = []
-
-    def split(path, x):
-        if path[-1].key != BIAS:
-            return x
-        taken.append(x)
-        return jnp.zeros_like(x)
-
-    return jax.tree_util.tree_map_with_path(split, tree), taken
-
-
-def _worst_leaf(got, want):
-    worst = 0.0
-    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        norm = float(jnp.linalg.norm(b))
-        if norm > 0:
-            worst = max(worst, float(jnp.linalg.norm(a - b)) / norm)
-    return worst
+LFM2 = cases.Family(
+    tiny=Lfm2MoeConfig.tiny, module=Lfm2MoeForCausalLM, loss=lfm2_moe_loss,
+    reference=reference, reference_kwargs=_reference_kwargs,
+    loss_tol=LOSS_TOL, leaf_tol=LEAF_TOL, comparable=cases.without_bias,
+)
 
 
 @pytest.mark.parametrize(
@@ -101,29 +53,18 @@ def _worst_leaf(got, want):
     ids=["whole", "share_1_of_4", "cut_to_5_layers"],
 )
 def test_model_matches_reference(overrides):
-    cfg, model, params, batch = _setup(**overrides)
-    (loss, metrics), grads = _model_grads(model, params, batch)
-    (ref_loss, ref), ref_grads = _reference_grads(cfg, params, batch)
-    # float32 on both sides: the choices agree exactly, nothing is forced
-    np.testing.assert_array_equal(metrics["moe.choice"], ref["choice"])
+    cfg, metrics, grads, ref, ref_grads = (
+        cases.check_model_matches_reference(LFM2, **overrides)
+    )
     np.testing.assert_allclose(metrics["moe.scores"], ref["scores"], atol=1e-5)
-    assert abs(float(loss) - float(ref_loss)) <= LOSS_TOL * float(ref_loss)
-    grads, load = _bias_apart(grads)
-    ref_grads, ref_bias_grads = _bias_apart(ref_grads)
-    assert _worst_leaf(grads, ref_grads) <= LEAF_TOL
     # the bias leaves carry the load statistic, not a gradient — exactly
     # what the reference counts from the same choices, layer by layer
     np.testing.assert_allclose(
-        np.concatenate([np.asarray(x).reshape(-1, cfg.num_experts)
-                        for x in load]),
+        np.concatenate([x.reshape(-1, cfg.num_experts)
+                        for x in cases.bias_leaves(grads)]),
         ref["load_excess"], atol=1e-7,
     )
-    assert all(float(jnp.max(jnp.abs(x))) == 0 for x in ref_bias_grads)
-    assert float(metrics["moe.dropped_slots"]) == 0.0
-    shards = cfg.expert_shard[1]
-    assert abs(
-        float(metrics["moe.local_slot_share"]) - 1.0 / shards
-    ) < (0.0 if shards == 1 else 0.15) + 1e-6
+    assert all(np.abs(x).max() == 0 for x in cases.bias_leaves(ref_grads))
 
 
 @pytest.mark.parametrize(
@@ -133,26 +74,15 @@ def test_model_matches_reference(overrides):
          "non_causal_conv"],
 )
 def test_a_different_function_fails(changes):
-    cfg, model, params, batch = _setup()
-    (loss, metrics), grads = _model_grads(model, params, batch)
-    (ref_loss, ref), ref_grads = _reference_grads(cfg, params, batch, **changes)
-    off = _worst_leaf(_bias_apart(grads)[0], _bias_apart(ref_grads)[0])
-    assert off > 100 * LEAF_TOL, off
+    metrics, ref = cases.check_a_different_function_fails(
+        LFM2, changes, given_choices=False
+    )
     if "bias_in_choice" in changes:
-        assert np.mean(
-            np.asarray(metrics["moe.choice"]) != np.asarray(ref["choice"])
-        ) > 0.05
+        cases.check_the_choices_differ(metrics, ref)
 
 
 def test_reference_routed_by_given_choices():
-    """Routed by the program's choices the reference reproduces its own
-    result (the chip check routes it so)."""
-    cfg, _model, params, batch = _setup()
-    (loss, own), _ = _reference_grads(cfg, params, batch)
-    (again, _), _ = _reference_grads(
-        cfg, params, batch, choices=own["choice"]
-    )
-    assert float(loss) == pytest.approx(float(again), rel=1e-6)
+    cases.check_reference_routed_by_given_choices(LFM2)
 
 
 def test_the_depth_rule():
@@ -188,7 +118,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
     (each told its share, holding 2 of the 16 experts) are the uncut
     reference's layer output — this model has no shared expert, so nothing
     is computed alike on every chip but the router, whose choices agree."""
-    cfg, _model, params, _batch = _setup()
+    cfg, _model, params, _batch = cases.case(LFM2)
     layer = jax.tree.map(
         lambda x: x[0], params["layers"]["layer_1"]["feed_forward"]
     )
@@ -199,28 +129,14 @@ def test_the_shares_add_up_to_the_uncut_layer():
             top_k=cfg.num_experts_per_tok, scale=cfg.routed_scaling_factor,
             route_eps=cfg.route_eps,
         )
-    total, local = 0.0, 0.0
-    for index in range(8):
-        share = Lfm2MoeConfig.tiny(dtype=jnp.float32, expert_shard=(index, 8))
-        first, held = share.held_experts
-        mine = dict(layer, **{
-            name: layer[name][first:first + held]
-            for name in ("experts_gate", "experts_up", "experts_down")
-        })
-        y, routing = RoutedFFN(share).apply({"params": mine}, x)
-        total = total + y
-        local += float(routing["local_slot_share"])
-        np.testing.assert_array_equal(routing["choice"], whole["choice"])
-        assert float(routing["dropped_slots"]) == 0.0
-    assert local == pytest.approx(1.0, abs=1e-6)
-    want = whole["routed"].reshape(x.shape)
-    np.testing.assert_allclose(total, want, atol=2e-5, rtol=2e-5)
-    # and no share alone is the layer
-    assert float(jnp.max(jnp.abs(y - want))) > 1e-3
+    cases.check_the_routed_shares_add_up(
+        LFM2, layer, RoutedFFN, (x,), whole,
+        ("experts_gate", "experts_up", "experts_down"),
+    )
 
 
 def test_masks_and_flops():
-    cfg, _model, params, _batch = _setup()
+    params = cases.case(LFM2).params
     decay = lfm2_moe_weight_decay_mask(params)
     signed = sign_step_mask(params)
     ffn = "feed_forward"

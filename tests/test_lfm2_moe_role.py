@@ -5,14 +5,11 @@ other model; every correction-bias entry — a leaf per position of the
 scanned period and one in the tail — moves by exactly ±gamma or 0 a global
 step; the step records carry the routing gauges and the counter that must
 read 0."""
-import json
-
 import jax
 import numpy as np
 import pytest
 
-from dedloc_tpu.core.config import CollaborationArguments, parse_config
-from dedloc_tpu.models.decoder import BIAS, EXPERT_LEAVES
+import decoder_cases as cases
 from dedloc_tpu.models.lfm2_moe import Lfm2MoeConfig
 from dedloc_tpu.roles.common import (
     DEEPSEEK_V3,
@@ -20,58 +17,6 @@ from dedloc_tpu.roles.common import (
     build_model,
     model_family,
 )
-from dedloc_tpu.roles.trainer import run_trainer
-
-
-def _args(tmp_path, argv=()):
-    base = [
-        "--dht.listen_host", "127.0.0.1",
-        "--training.model_size", "lfm2_tiny",
-        "--training.seq_length", "32",
-        "--training.per_device_batch_size", "2",
-        "--training.gradient_accumulation_steps", "2",
-        "--training.warmup_steps", "2",
-        "--training.total_steps", "50",
-        "--training.output_dir", str(tmp_path / "out"),
-        "--averager.averaging_expiration", "1.0",
-        "--averager.min_refresh_period", "0.1",
-        "--averager.default_refresh_period", "0.3",
-    ]
-    return parse_config(CollaborationArguments, base + list(argv))
-
-
-def _bias_leaves(tree):
-    return [
-        np.asarray(leaf)
-        for path, leaf in jax.tree_util.tree_leaves_with_path(tree)
-        if path[-1].key == BIAS
-    ]
-
-
-def _two_micro_batches(accumulate, params, batches):
-    import jax.numpy as jnp
-
-    from dedloc_tpu.parallel.train_step import zeros_like_grads
-
-    acc, n = zeros_like_grads(params), jnp.zeros([], jnp.int32)
-    for i, batch in enumerate(batches):
-        acc, n, metrics = accumulate(
-            params, acc, n, batch, jax.random.PRNGKey(i)
-        )
-    return acc, metrics
-
-
-def _sink_case(size, **overrides):
-    """(model, params, two batches, the table's loss) of a tiny decoder."""
-    import jax.numpy as jnp
-
-    from dedloc_tpu.roles.common import build_loss_fn
-
-    cfg, model = build_model(size, **overrides)
-    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 2, 32), 0, cfg.vocab_size)
-    params = model.init(jax.random.PRNGKey(0), ids[0])["params"]
-    batches = [{"input_ids": x, "labels": jnp.roll(x, -1, 1)} for x in ids]
-    return model, params, batches, build_loss_fn(model)
 
 
 @pytest.mark.parametrize(
@@ -79,20 +24,13 @@ def _sink_case(size, **overrides):
     ids=["whole", "share_1_of_4_cut_to_5"],
 )
 def test_lfm2_tiny_trainer_steps_the_bias_by_gamma(tmp_path, shard, layers):
-    events = tmp_path / "events.jsonl"
-    args = _args(tmp_path, [
-        "--optimizer.target_batch_size", "8",
-        "--training.max_local_steps", "9",
+    state, stepped, _records = cases.run_tiny_trainer(tmp_path, "lfm2_tiny", [
         "--training.expert_shard", shard,
         "--training.num_hidden_layers", layers,
-        "--telemetry.enabled", "true",
-        "--telemetry.event_log_path", str(events),
     ])
-    state = run_trainer(args)
     steps = int(state.step)
-    assert steps >= 2
     gamma = Lfm2MoeConfig.bias_update_speed
-    biases = _bias_leaves(state.params)
+    biases = cases.bias_leaves(state.params)
     # the period's four positions (+ the whole model's tail layer)
     expert_layers = 5 if layers == "0" else 4
     assert len(biases) == expert_layers
@@ -109,32 +47,18 @@ def test_lfm2_tiny_trainer_steps_the_bias_by_gamma(tmp_path, shard, layers):
     lamb_state = _find_opt_state(state.opt_state, ScaleByLambState)
     for moments in (lamb_state.mu, lamb_state.nu):
         assert all(
-            float(np.abs(m).max()) == 0.0 for m in _bias_leaves(moments)
+            float(np.abs(m).max()) == 0.0 for m in cases.bias_leaves(moments)
         )
 
-    log = [json.loads(line) for line in events.read_text().splitlines()]
-    stepped = [
-        e for e in log if e.get("event") == "step.record" and e.get("stepped")
-    ]
-    assert len(stepped) >= 2
-    count = int(shard.split("/")[1])
+    cases.check_routing_records(stepped, shard, expert_layers, slack=0.2)
     for n, rec in enumerate(stepped, start=1):
-        assert rec["moe.dropped_slots"] == 0.0
         # the held matrices in bf16, cast ONCE a set of weights: three a
         # routed layer ride beside the sinks, one run of the cast program a
         # global step (``parallel/train_step._StepWithComputeCopies``)
         assert rec["moe.compute_copy_leaves"] == 3.0 * expert_layers
-        assert rec["moe.grad_sink_leaves"] == 3.0 * expert_layers
         assert rec["moe.compute_copy_builds"] == 1.0
         # the walk's counter (``parallel/moe.py``): a share of the held rows
         assert 0.0 <= rec["moe.bulk_row_share"] <= 1.0
-        assert all(
-            rec[f"moe.load_max_over_mean.{i}"] >= 1.0
-            for i in range(1, expert_layers + 1)
-        )
-        assert rec["moe.local_slot_share"] == pytest.approx(
-            1.0 / count, abs=0.0 if count == 1 else 0.2
-        )
         # read with the loss BEFORE this step's apply: n − 1 steps so far
         assert rec["moe.bias_abs_max"] <= (n - 1) * gamma + 1e-9
     assert stepped[-1]["moe.bias_abs_max"] > 0
@@ -191,32 +115,11 @@ def test_ouro_takes_grouped_heads():
 
 def test_accumulate_step_leaves_expert_gradients_in_the_accumulator():
     """The scanned period's expert leaves (a layer each) AND the tail
-    layer's are sinks of ``make_accumulate_step(build_loss_fn(model))``:
-    float32 sums where the plain step adds bf16-rounded gradients, every
-    other leaf exactly the plain step's."""
-    from dedloc_tpu.parallel.train_step import make_accumulate_step
-
-    _model, params, batches, loss_fn = _sink_case("lfm2_tiny")
-    sunk, metrics = _two_micro_batches(
-        make_accumulate_step(loss_fn), params, batches
+    layer's: five layers, each with leaves of its own."""
+    _model, params, batches, loss_fn = cases.sink_case("lfm2_tiny")
+    cases.check_accumulate_step_leaves_expert_gradients_in_the_accumulator(
+        params, batches, loss_fn, sink_leaves=15.0, expert_leaves=15
     )
-    plain, plain_metrics = _two_micro_batches(
-        make_accumulate_step(loss_fn.loss), params, batches
-    )
-    assert float(metrics["moe.grad_sink_leaves"]) == 15.0  # 5 layers x 3
-    assert float(plain_metrics["moe.grad_sink_leaves"]) == 0.0
-    assert float(metrics["loss"]) == float(plain_metrics["loss"])
-    seen = 0
-    for (path, got), want in zip(
-        jax.tree_util.tree_leaves_with_path(sunk), jax.tree.leaves(plain)
-    ):
-        if path[-1].key in EXPERT_LEAVES:
-            seen += 1
-            apart = float(np.linalg.norm(got - want) / np.linalg.norm(want))
-            assert 0.0 < apart < 2.0 ** -8, (path, apart)
-        else:
-            np.testing.assert_array_equal(got, want, err_msg=str(path))
-    assert seen == 15
 
 
 def test_accumulate_step_under_a_mesh_keeps_the_plain_path():
@@ -228,7 +131,7 @@ def test_accumulate_step_under_a_mesh_keeps_the_plain_path():
         zeros_like_grads,
     )
 
-    _model, params, batches, loss_fn = _sink_case("lfm2_tiny")
+    _model, params, batches, loss_fn = cases.sink_case("lfm2_tiny")
     mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
     operands = (
         params, zeros_like_grads(params), jnp.zeros([], jnp.int32),

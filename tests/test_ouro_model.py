@@ -8,6 +8,7 @@ reference one pass short, or with the final norm outside the loop, failing
 by orders of magnitude; and what the layer keeps under remat (the flash
 kernel's outputs): the same bits as replaying everything, one forward kernel
 call in the gradient's jaxpr instead of two."""
+import functools
 import re
 
 import jax
@@ -15,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import decoder_cases as cases
 from benchmark.reference import ouro as reference
 from dedloc_tpu.models.decoder import chunked_cross_entropy
 from dedloc_tpu.models.ouro import (
@@ -38,19 +40,11 @@ def _setup(impl, **overrides):
         **overrides,
     )
     model = OuroForCausalLM(cfg)
-    rows = np.random.default_rng(0).integers(
-        0, cfg.vocab_size, (2, 65)
-    ).astype(np.int32)
-    batch = {"input_ids": jnp.asarray(rows[:, :-1]),
-             "labels": jnp.asarray(rows[:, 1:])}
-    params = model.init(jax.random.PRNGKey(1), batch["input_ids"])["params"]
+    batch = cases.token_batch(cfg, 64)
     # away from the initialiser's symmetry: norms off 1, gate bias off 0
-    leaves, treedef = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(2), len(leaves))
-    params = jax.tree.unflatten(treedef, [
-        leaf + 0.1 * jax.random.normal(key, leaf.shape)
-        for leaf, key in zip(leaves, keys)
-    ])
+    params = cases.perturbed(
+        model.init(jax.random.PRNGKey(1), batch["input_ids"])["params"]
+    )
     return cfg, model, params, batch
 
 
@@ -75,19 +69,28 @@ def _compare(grads, ref_grads):
     return whole, worst
 
 
-def _role_and_reference(impl, **reference_changes):
+@functools.lru_cache(maxsize=None)
+def _role(impl):
+    """The model's own side, once a process: the different-function cases
+    compile their reference alone."""
     cfg, model, params, batch = _setup(impl)
     with jax.default_matmul_precision("highest"):
         (loss, metrics), grads = jax.value_and_grad(
             lambda p: ouro_loss(model, p, batch), has_aux=True
         )(params)
+        hiddens, _gates = model.apply({"params": params}, batch["input_ids"])
+        logits = ouro_logits(params, hiddens, cfg)
+    return cfg, params, batch, loss, metrics, grads, logits
+
+
+def _role_and_reference(impl, **reference_changes):
+    cfg, params, batch, loss, metrics, grads, logits = _role(impl)
+    with jax.default_matmul_precision("highest"):
         kwargs = _reference_kwargs(cfg, **reference_changes)
         ref_loss, ref_grads = jax.value_and_grad(
             lambda p: reference.loss_fn(p, batch, **kwargs)
         )(params)
         out = reference.forward(params, batch, **kwargs)
-        hiddens, _gates = model.apply({"params": params}, batch["input_ids"])
-        logits = ouro_logits(params, hiddens, cfg)
     return cfg, loss, metrics, grads, logits, ref_loss, ref_grads, out
 
 

@@ -1,13 +1,15 @@
 """The looped decoder through the trainer role: ``--training.model_size
 ouro_tiny`` makes global steps solo on the CPU through the same
 ``run_trainer`` / ``CollaborativeOptimizer`` path as ALBERT, and its step
-records carry what this model adds to the tracing."""
+records carry what this model adds to the tracing; and the model's row of
+``tools/tpu_aot.py`` (its accumulate_step compiled for a TPU v5e WITHOUT a
+chip: ``tests/tpu_aot_rows.py``)."""
 import json
 
 import numpy as np
 import pytest
 
-from dedloc_tpu.core.config import CollaborationArguments, parse_config
+import decoder_cases as cases
 from dedloc_tpu.roles.common import (
     ALBERT,
     OURO,
@@ -16,45 +18,17 @@ from dedloc_tpu.roles.common import (
     drop_collator_keys,
     model_family,
 )
-from dedloc_tpu.roles.trainer import run_trainer
-
-
-def _args(tmp_path, argv=()):
-    base = [
-        "--dht.listen_host", "127.0.0.1",
-        "--training.model_size", "ouro_tiny",
-        "--training.seq_length", "32",
-        "--training.per_device_batch_size", "2",
-        "--training.gradient_accumulation_steps", "2",
-        "--training.warmup_steps", "2",
-        "--training.total_steps", "50",
-        "--training.output_dir", str(tmp_path / "out"),
-        "--averager.averaging_expiration", "1.0",
-        "--averager.min_refresh_period", "0.1",
-        "--averager.default_refresh_period", "0.3",
-    ]
-    return parse_config(CollaborationArguments, base + list(argv))
+from tpu_aot_rows import tpu_aot
 
 
 def test_ouro_tiny_trainer_makes_global_steps_and_traces_them(tmp_path):
-    events = tmp_path / "events.jsonl"
     train_log = tmp_path / "train.jsonl"
-    args = _args(tmp_path, [
-        "--optimizer.target_batch_size", "8",
-        "--training.max_local_steps", "7",
-        "--training.train_log_path", str(train_log),
-        "--telemetry.enabled", "true",
-        "--telemetry.event_log_path", str(events),
-    ])
-    state = run_trainer(args)
-    assert int(state.step) >= 2
+    state, stepped, records = cases.run_tiny_trainer(
+        tmp_path, "ouro_tiny", ["--training.train_log_path", str(train_log)],
+        max_local_steps=7,
+    )
     rows = [json.loads(line) for line in train_log.read_text().splitlines()]
     assert len(rows) >= 2 and all(np.isfinite(r["loss"]) for r in rows)
-
-    log = [json.loads(line) for line in events.read_text().splitlines()]
-    records = [e for e in log if e.get("event") == "step.record"]
-    stepped = [r for r in records if r.get("stepped")]
-    assert len(stepped) >= 2
     passes = 3  # ouro_tiny's total_ut_steps
     for rec in stepped:
         exit_prob = [rec[f"lm.exit_prob.{t}"] for t in range(1, passes + 1)]
@@ -129,3 +103,31 @@ def test_solo_mean_takes_the_accumulators_buffers():
     assert norm == pytest.approx(1.0, rel=1e-5)
     for fn in (optimizer._fused_mean_clip, optimizer._fused_mean_clip_in_place):
         assert fn.__wrapped__.__name__ == "_fused_mean_clip"
+
+
+def test_ouro_accumulate_step_keeps_the_flash_outputs_and_fits_the_cap():
+    """The looped decoder's accumulate_step (Ouro-2.6B cut to the cell's 3
+    layers, 1 row of 4,096), compiled for a v5e: the layer's remat policy
+    keeps the causal flash kernel's out + lse, so the lowered module calls
+    the forward kernel ONCE (the forward scan's body) and the backward's
+    replay of the layer holds none — 2 call sites under policy ``nothing``,
+    12 of 24 executions a micro-batch (PR 28). The stash is paid in the
+    program's scratch: 5.30 GB against 5.04 — 5.16 since the kernels'
+    operands sit behind ``decoder.GroupedQueryAttention``'s barrier (PR 45:
+    the 11 float32 relayouts of RoPE's pieces left the layer bodies) —
+    which with a draining snapshot's 9.96 GB of state stays under the 15.3
+    GB the cell is sized by; a policy that also kept ``flash_qkv`` would
+    read 6.2 GB here."""
+    row = tpu_aot("ouro_accumulate_step")["ouro_accumulate_step"]
+    assert row["remat_policy"] == "kernel_outputs"
+    assert row["flash_fwd_forms"] == {"one_tile": 0, "tiles": 1}
+    assert row["flash_windows"] == {  # D=128: a head is one lane tile
+        name: "block" for name in (
+            "flash_causal_fwd", "flash_causal_bwd_dq", "flash_causal_bwd_dkv"
+        )
+    }
+    # forward, dq, dkv: one site each
+    assert row["tpu_custom_calls"] == 3
+    assert row["memory"]["temp_bytes"] <= 5.2e9, row["memory"]
+    copies = row["layer_body_copies"]
+    assert not [shape for shape in copies if shape.startswith("f32")], copies

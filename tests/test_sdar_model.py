@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import decoder_cases as cases
 from benchmark.reference import sdar_moe as reference
 from dedloc_tpu.data.block_diffusion import block_diffusion_batches
 from dedloc_tpu.models import decoder
@@ -34,25 +35,13 @@ from dedloc_tpu.roles.common import SDAR_MOE
 LOSS_TOL, LEAF_TOL = 1e-5, 1e-4
 
 
-def _setup(seq=64, **overrides):
-    cfg = SdarMoeConfig.tiny(dtype=jnp.float32, **overrides)
-    model = SdarMoeForDiffusionLM(cfg)
+def _batch(cfg, seq):
     rows = np.random.default_rng(0).integers(
         0, cfg.vocab_size - 1, (2, seq)
     ).astype(np.int32)
-    batch = jax.tree.map(jnp.asarray, next(block_diffusion_batches(
+    return jax.tree.map(jnp.asarray, next(block_diffusion_batches(
         [rows], cfg.block_length, cfg.mask_token_id, seed=3
     )))
-    params = model.init(jax.random.PRNGKey(1), batch["input_ids"])["params"]
-    # away from the initialiser's symmetry: norms off 1, every matrix of
-    # the size at which a different function shows
-    leaves, treedef = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(2), len(leaves))
-    params = jax.tree.unflatten(treedef, [
-        leaf + 0.1 * jax.random.normal(key, leaf.shape)
-        for leaf, key in zip(leaves, keys)
-    ])
-    return cfg, model, params, batch
 
 
 def _reference_kwargs(cfg, **changes):
@@ -66,30 +55,14 @@ def _reference_kwargs(cfg, **changes):
     return kwargs
 
 
-def _model_grads(model, params, batch):
-    return jax.jit(jax.value_and_grad(
-        lambda p: sdar_moe_loss(model, p, batch), has_aux=True
-    ))(params)
-
-
-def _reference_grads(cfg, params, batch, choices=None, **changes):
-    def loss(p, choices):
-        with jax.default_matmul_precision("highest"):
-            out = reference.forward(
-                p, batch, choices=choices, **_reference_kwargs(cfg, **changes)
-            )
-        return out["loss"], out
-
-    return jax.jit(jax.value_and_grad(loss, has_aux=True))(params, choices)
-
-
-def _worst_leaf(got, want):
-    worst = 0.0
-    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        norm = float(jnp.linalg.norm(b))
-        if norm > 0:
-            worst = max(worst, float(jnp.linalg.norm(a - b)) / norm)
-    return worst
+SDAR = cases.Family(
+    tiny=SdarMoeConfig.tiny, module=SdarMoeForDiffusionLM, loss=sdar_moe_loss,
+    reference=reference, reference_kwargs=_reference_kwargs, batch=_batch,
+    loss_tol=LOSS_TOL, leaf_tol=LEAF_TOL,
+)
+# two layers through the block-diffusion kernels: heads of 128, tiles of 16
+FLASH = dict(head_dim=128, num_hidden_layers=2, attention_impl="flash",
+             attention_block_size=16)
 
 
 @pytest.mark.parametrize(
@@ -100,14 +73,11 @@ def _worst_leaf(got, want):
          "a_period_and_a_tail_layer"],
 )
 def test_model_matches_reference(overrides):
-    cfg, model, params, batch = _setup(**overrides)
-    (loss, metrics), grads = _model_grads(model, params, batch)
-    (ref_loss, ref), ref_grads = _reference_grads(cfg, params, batch)
-    # float32 on both sides: the choices agree exactly, nothing is forced
-    np.testing.assert_array_equal(metrics["moe.choice"], ref["choice"])
+    cfg, metrics, _grads, ref, _ref_grads = (
+        cases.check_model_matches_reference(SDAR, **overrides)
+    )
+    _cfg, model, params, batch = cases.case(SDAR, **overrides)
     np.testing.assert_allclose(metrics["moe.scores"], ref["scores"], atol=1e-5)
-    assert abs(float(loss) - float(ref_loss)) <= LOSS_TOL * float(ref_loss)
-    assert _worst_leaf(grads, ref_grads) <= LEAF_TOL
     hidden, _routing = model.apply(
         {"params": params},
         jnp.concatenate([batch["input_ids"], batch["labels"]], axis=1),
@@ -115,7 +85,6 @@ def test_model_matches_reference(overrides):
     np.testing.assert_allclose(
         hidden @ params["lm_head"], ref["logits"], atol=2e-4, rtol=2e-4
     )
-    assert float(metrics["moe.dropped_slots"]) == 0.0
     assert float(metrics["moe.grad_sink_leaves"]) == 0.0  # none handed
     assert metrics["moe.load_max_over_mean"].shape == (cfg.num_hidden_layers,)
     weights = np.asarray(batch["loss_weights"])
@@ -123,10 +92,6 @@ def test_model_matches_reference(overrides):
     assert float(metrics["diffusion.masked_share"]) == pytest.approx(
         (weights > 0).mean()
     )
-    shards = cfg.expert_shard[1]
-    assert abs(
-        float(metrics["moe.local_slot_share"]) - 1.0 / shards
-    ) < (0.0 if shards == 1 else 0.15) + 1e-6
 
 
 WRONG = {
@@ -140,25 +105,11 @@ WRONG = {
 
 @pytest.mark.parametrize("changes", WRONG.values(), ids=WRONG.keys())
 def test_a_different_function_fails(changes):
-    cfg, model, params, batch = _setup()
-    (_loss, metrics), grads = _model_grads(model, params, batch)
-    # the same routing, so that what differs is the function alone
-    (_ref_loss, _ref), ref_grads = _reference_grads(
-        cfg, params, batch, choices=metrics["moe.choice"], **changes
-    )
-    off = _worst_leaf(grads, ref_grads)
-    assert off > 100 * LEAF_TOL, off
+    cases.check_a_different_function_fails(SDAR, changes)
 
 
 def test_reference_routed_by_given_choices():
-    """Routed by the program's choices the reference reproduces its own
-    result (the chip check routes it so)."""
-    cfg, _model, params, batch = _setup()
-    (loss, own), _ = _reference_grads(cfg, params, batch)
-    (again, _), _ = _reference_grads(
-        cfg, params, batch, choices=own["choice"]
-    )
-    assert float(loss) == pytest.approx(float(again), rel=1e-6)
+    cases.check_reference_routed_by_given_choices(SDAR)
 
 
 def _both_streams(model, params, noisy, clean):
@@ -172,9 +123,9 @@ def _both_streams(model, params, noisy, clean):
 def _no_leak(impl, same):
     """``test_no_leak``'s three changes; ``same(got, want)`` holds the
     positions that may not move."""
-    extra = dict(head_dim=128, attention_impl="flash",
-                 attention_block_size=16) if impl == "flash" else {}
-    cfg, model, params, batch = _setup(seq=32, num_hidden_layers=2, **extra)
+    cfg, model, params, batch = cases.case(
+        SDAR, 32, **(FLASH if impl == "flash" else dict(num_hidden_layers=2))
+    )
     noisy, clean = batch["input_ids"], batch["labels"]
     length, blk, b = 32, cfg.block_length, 3
     lo, hi = b * blk, (b + 1) * blk
@@ -240,16 +191,7 @@ def test_the_block_diffusion_kernels_inside_the_model():
     """``attention_impl="flash"``: the grouped kernels (8 / 1 x 128, a group
     of eight) under the block rule, tiles of 16 = four blocks, in
     interpreter mode, against the reference."""
-    cfg, model, params, batch = _setup(
-        seq=64, head_dim=128, num_hidden_layers=2, attention_impl="flash",
-        attention_block_size=16,
-    )
-    (loss, metrics), grads = _model_grads(model, params, batch)
-    (ref_loss, _ref), ref_grads = _reference_grads(
-        cfg, params, batch, choices=metrics["moe.choice"]
-    )
-    assert abs(float(loss) - float(ref_loss)) <= LOSS_TOL * float(ref_loss)
-    assert _worst_leaf(grads, ref_grads) <= LEAF_TOL
+    metrics = cases.check_the_model_under_overrides(SDAR, **FLASH)
     # 4 tiles a stream: 10 + 10 + 4 of the 36 a causal call over 2L visits
     assert float(metrics["attn.bd_tile_share"]) == pytest.approx(24 / 36)
     assert bd_tile_share(SdarMoeConfig(), 4096) == 80 / 136
@@ -264,7 +206,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
     told its share, holding 2 of the 16 experts) are the uncut reference's
     layer output — there is no shared expert, so nothing is computed alike
     on every chip but the router, whose choices agree."""
-    cfg, _model, params, _batch = _setup()
+    cfg, _model, params, _batch = cases.case(SDAR)
     layer = jax.tree.map(lambda x: x[0], params["layers"]["layer_1"]["mlp"])
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 64, cfg.hidden_size))
     with jax.default_matmul_precision("highest"):
@@ -272,30 +214,14 @@ def test_the_shares_add_up_to_the_uncut_layer():
             x.reshape(-1, cfg.hidden_size), layer, held=(0, 16),
             top_k=cfg.num_experts_per_tok,
         )
-    total, local = 0.0, 0.0
-    for index in range(8):
-        share = SdarMoeConfig.tiny(dtype=jnp.float32, expert_shard=(index, 8))
-        first, held = share.held_experts
-        mine = dict(layer, **{
-            name: layer[name][first:first + held]
-            for name in reference.EXPERTS
-        })
-        y, routing = RoutedGLU(share, activation="silu").apply(
-            {"params": mine}, x, x
-        )
-        total = total + y
-        local += float(routing["local_slot_share"])
-        np.testing.assert_array_equal(routing["choice"], whole["choice"])
-        assert float(routing["dropped_slots"]) == 0.0
-    assert local == pytest.approx(1.0, abs=1e-6)
-    want = whole["routed"].reshape(x.shape)
-    np.testing.assert_allclose(total, want, atol=2e-5, rtol=2e-5)
-    # and no share alone is the layer
-    assert float(jnp.max(jnp.abs(y - want))) > 1e-3
+    cases.check_the_routed_shares_add_up(
+        SDAR, layer, lambda share: RoutedGLU(share, activation="silu"),
+        (x, x), whole, reference.EXPERTS,
+    )
 
 
 def test_masks_and_flops():
-    cfg, _model, params, _batch = _setup()
+    params = cases.case(SDAR).params
     decay = SDAR_MOE.weight_decay_mask(params)
     assert decay["norm"]["weight"] is False and decay["lm_head"] is True
     attn = decay["layers"]["layer_0"]["self_attn"]

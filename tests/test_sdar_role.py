@@ -12,8 +12,7 @@ import jax
 import numpy as np
 import pytest
 
-from dedloc_tpu.core.config import CollaborationArguments, parse_config
-from dedloc_tpu.models.decoder import EXPERT_LEAVES
+import decoder_cases as cases
 from dedloc_tpu.models.sdar_moe import SdarMoeConfig
 from dedloc_tpu.roles.common import (
     DEEPSEEK_V3,
@@ -26,61 +25,19 @@ from dedloc_tpu.roles.common import (
 from dedloc_tpu.roles.trainer import run_trainer
 
 
-def _args(tmp_path, argv=()):
-    base = [
-        "--dht.listen_host", "127.0.0.1",
-        "--training.model_size", "sdar_tiny",
-        "--training.seq_length", "32",
-        "--training.per_device_batch_size", "2",
-        "--training.gradient_accumulation_steps", "2",
-        "--training.warmup_steps", "2",
-        "--training.total_steps", "50",
-        "--training.output_dir", str(tmp_path / "out"),
-        "--averager.averaging_expiration", "1.0",
-        "--averager.min_refresh_period", "0.1",
-        "--averager.default_refresh_period", "0.3",
-    ]
-    return parse_config(CollaborationArguments, base + list(argv))
-
-
-def _stepped(events):
-    log = [json.loads(line) for line in events.read_text().splitlines()]
-    return [
-        e for e in log if e.get("event") == "step.record" and e.get("stepped")
-    ]
-
-
 @pytest.mark.parametrize(
     "shard,layers", [("0/1", "0"), ("1/4", "2")],
     ids=["whole", "share_1_of_4_cut_to_2"],
 )
 def test_sdar_tiny_trainer_makes_global_steps(tmp_path, shard, layers):
-    events = tmp_path / "events.jsonl"
-    state = run_trainer(_args(tmp_path, [
-        "--optimizer.target_batch_size", "8",
-        "--training.max_local_steps", "9",
+    _state, stepped, _records = cases.run_tiny_trainer(tmp_path, "sdar_tiny", [
         "--training.expert_shard", shard,
         "--training.num_hidden_layers", layers,
-        "--telemetry.enabled", "true",
-        "--telemetry.event_log_path", str(events),
-    ]))
-    assert int(state.step) >= 2
-    n_layers = int(layers) or 3
-    stepped = _stepped(events)
-    assert len(stepped) >= 2
-    count = int(shard.split("/")[1])
+    ])
+    cases.check_routing_records(stepped, shard, int(layers) or 3)
     for rec in stepped:
-        assert rec["moe.dropped_slots"] == 0.0
         # the walk's counter (``parallel/moe.py``): a share of the held rows
         assert 0.0 <= rec["moe.bulk_row_share"] <= 1.0
-        assert all(
-            rec[f"moe.load_max_over_mean.{i}"] >= 1.0
-            for i in range(1, n_layers + 1)
-        )
-        assert rec["moe.local_slot_share"] == pytest.approx(
-            1.0 / count, abs=0.0 if count == 1 else 0.25
-        )
-        assert rec["moe.grad_sink_leaves"] == 3.0 * n_layers
         assert 0.0 < rec["diffusion.masked_share"] < 1.0
         assert rec["diffusion.masked_tokens"] > 0  # the step's total
         assert rec["attn.bd_tile_share"] == 1.0  # L=32: one tile a stream
@@ -108,7 +65,7 @@ def test_the_loss_falls(tmp_path, monkeypatch):
 
     monkeypatch.setattr(role, "_make_batches", four_batches)
     log = tmp_path / "train.jsonl"
-    run_trainer(_args(tmp_path, [
+    run_trainer(cases.trainer_args(tmp_path, "sdar_tiny", [
         "--optimizer.target_batch_size", "8",
         "--training.max_local_steps", "91",
         "--training.learning_rate", "0.02",
@@ -160,16 +117,9 @@ def test_the_table_builds_the_block_diffusion_decoder():
 
 
 def test_accumulate_step_leaves_expert_gradients_in_the_accumulator():
-    """Every layer's expert leaves are sinks of
-    ``make_accumulate_step(build_loss_fn(model))``: float32 sums where the
-    plain step adds bf16-rounded gradients, every other leaf exactly the
-    plain step's."""
+    """Three layers, each with leaves of its own, on the family's own
+    batches (three arrays a row)."""
     import jax.numpy as jnp
-
-    from dedloc_tpu.parallel.train_step import (
-        make_accumulate_step,
-        zeros_like_grads,
-    )
 
     cfg, model = build_model("sdar_tiny")
     source = SDAR_MOE.synthetic_batches(cfg, 2, 32, 1)
@@ -180,27 +130,7 @@ def test_accumulate_step_leaves_expert_gradients_in_the_accumulator():
     params = model.init(jax.random.PRNGKey(0), batches[0]["input_ids"])[
         "params"
     ]
-    loss_fn = build_loss_fn(model)
-
-    def two(step):
-        acc, n = zeros_like_grads(params), jnp.zeros([], jnp.int32)
-        for i, batch in enumerate(batches):
-            acc, n, metrics = step(params, acc, n, batch, jax.random.PRNGKey(i))
-        return acc, metrics
-
-    sunk, metrics = two(make_accumulate_step(loss_fn))
-    plain, plain_metrics = two(make_accumulate_step(loss_fn.loss))
-    assert float(metrics["moe.grad_sink_leaves"]) == 9.0  # 3 layers x 3
-    assert float(plain_metrics["moe.grad_sink_leaves"]) == 0.0
-    assert float(metrics["loss"]) == float(plain_metrics["loss"])
-    seen = 0
-    for (path, got), want in zip(
-        jax.tree_util.tree_leaves_with_path(sunk), jax.tree.leaves(plain)
-    ):
-        if path[-1].key in EXPERT_LEAVES:
-            seen += 1
-            apart = float(np.linalg.norm(got - want) / np.linalg.norm(want))
-            assert 0.0 < apart < 2.0 ** -8, (path, apart)
-        else:
-            np.testing.assert_array_equal(got, want, err_msg=str(path))
-    assert seen == 9  # three layers, each with leaves of its own
+    cases.check_accumulate_step_leaves_expert_gradients_in_the_accumulator(
+        params, batches, build_loss_fn(model), sink_leaves=9.0,
+        expert_leaves=9,
+    )

@@ -1,86 +1,96 @@
-"""Tier-1 timing budget: rank the suite's slowest tests against the cap.
+"""Tier-1 timing budget: where the suite's test-seconds are, against the cap.
 
-The ROADMAP tier-1 command runs under ``timeout 870``; on this container the
-suite already overruns that cap (memory/tier1-timing-budget.md), so every
-new slow test silently pushes passing tests past the kill line. This tool
-turns a ``pytest --durations=0`` log into an attribution: which tests (and
-which files) spend the budget, and which are candidates for a ``slow`` mark.
+The driver runs tier-1 under ``timeout 1470`` with six xdist workers and
+``--dist loadfile`` and leaves a junit file (``/tmp/_t1.xml``); a run cut at
+the cap counts only as far as it got. This tool turns that file into the
+table a test-repair needs: per-file sums (``loadfile`` spreads FILES, so the
+heaviest file is a floor under the wall), the sum over workers divided by
+their number against the wall the run took (what imbalance costs), the wall
+against the cap, and the slowest tests.
 
 Usage::
 
-    # run tier-1 with durations reporting, then attribute:
-    pytest tests/ -q -m 'not slow' --durations=0 2>&1 | tee /tmp/_t1.log
-    python tools/t1_budget.py /tmp/_t1.log
-    python tools/t1_budget.py --cap 870 --top 25 --slow-threshold 10 /tmp/_t1.log
+    # after the driver's command (ROADMAP.md "Tier-1 verify"):
+    python tools/t1_budget.py /tmp/_t1.xml
+    python tools/t1_budget.py --cap 1470 --top 25 /tmp/_t1.xml
 
     # CI gate: exit nonzero when a baselined test regressed >25%
-    python tools/t1_budget.py --gate tools/t1_baseline.json /tmp/_t1.log
+    python tools/t1_budget.py --gate tools/t1_baseline.json /tmp/_t1.xml
     # refresh the baseline from a trusted idle-box run
-    python tools/t1_budget.py --record-baseline tools/t1_baseline.json /tmp/_t1.log
+    python tools/t1_budget.py --record-baseline tools/t1_baseline.json /tmp/_t1.xml
 
-Reads stdin when no file is given. Only stdlib, no pytest plugin — it
-parses the human-readable durations block, so it also works on archived CI
-logs.
+Only stdlib, no pytest plugin; the junit file is the one input (stdin when
+no file is given).
 
 ``--gate`` compares each test named in the baseline JSON (``{"test id":
-seconds}``) against the log's measured total and exits nonzero when any
+seconds}``) against the measured total and exits nonzero when any
 regressed more than ``--gate-tolerance`` (default 0.25 = +25%) beyond a
 small absolute slack (``--gate-slack``, default 1s — sub-second tests jitter
 by whole multiples on a loaded box). Tests in the baseline but absent from
-the log are reported as warnings, not failures (a deselected or renamed test
-must not wedge CI, but it must not vanish silently either).
+the file are reported as warnings, not failures (a deselected or renamed
+test must not wedge CI, but it must not vanish silently either).
 """
 from __future__ import annotations
 
 import argparse
 import json
-import re
 import sys
+import xml.etree.ElementTree as ET
 from collections import defaultdict
 from typing import Dict, List, Tuple
 
-# "12.34s call     tests/test_roles.py::test_x" (also setup/teardown rows)
-_DURATION_RE = re.compile(
-    r"^\s*(\d+(?:\.\d+)?)s\s+(call|setup|teardown)\s+(\S+)\s*$"
-)
+TIER1_CAP_S = 1470.0  # the driver's ``timeout``
+TIER1_WORKERS = 6  # its ``-n``
 
-
-def parse_durations(lines) -> List[Tuple[str, str, float]]:
-    """(test id, phase, seconds) rows from a pytest --durations block."""
-    rows = []
-    for line in lines:
-        m = _DURATION_RE.match(line)
-        if m:
-            rows.append((m.group(3), m.group(2), float(m.group(1))))
-    return rows
+def parse_junit(text: str) -> Tuple[List[Tuple[str, float]], float]:
+    """((test id, seconds) rows, the run's wall seconds) from a pytest junit
+    file: a testcase's ``time`` is its setup + call + teardown, the
+    testsuite's the whole run's."""
+    root = ET.fromstring(text)
+    suites = [root] if root.tag == "testsuite" else root.findall("testsuite")
+    rows = [
+        (
+            f"{case.get('classname', '').replace('.', '/')}.py"
+            f"::{case.get('name')}",
+            float(case.get("time") or 0.0),
+        )
+        for suite in suites for case in suite.iter("testcase")
+    ]
+    return rows, sum(float(suite.get("time") or 0.0) for suite in suites)
 
 
 def aggregate(rows) -> Tuple[Dict[str, float], Dict[str, float]]:
-    """Sum phases per test and per file."""
+    """Seconds per test and per file."""
     per_test: Dict[str, float] = defaultdict(float)
     per_file: Dict[str, float] = defaultdict(float)
-    for test_id, _phase, seconds in rows:
+    for test_id, seconds in rows:
         per_test[test_id] += seconds
         per_file[test_id.split("::", 1)[0]] += seconds
     return dict(per_test), dict(per_file)
 
 
 def report(
-    rows, cap: float = 870.0, top: int = 20, slow_threshold: float = 10.0
+    rows, cap: float = TIER1_CAP_S, top: int = 20,
+    slow_threshold: float = 10.0, wall: float = 0.0,
 ) -> str:
     if not rows:
-        return (
-            "no duration rows found — run pytest with --durations=0 "
-            "(--durations=N hides everything under its cutoff)"
-        )
+        return "no testcase in the junit file"
     per_test, per_file = aggregate(rows)
-    total = sum(seconds for _t, _p, seconds in rows)
+    total = sum(seconds for _t, seconds in rows)
     out = []
-    out.append(f"accounted test time: {total:.0f}s vs tier-1 cap {cap:.0f}s "
-               f"({total / cap * 100:.0f}% of budget)")
-    if total > cap:
+    heaviest, heaviest_s = max(per_file.items(), key=lambda kv: kv[1])
+    balanced = total / TIER1_WORKERS
+    out.append(
+        f"test-seconds: {total:.0f}s in {len(per_test)} tests; over "
+        f"{TIER1_WORKERS} workers {balanced:.0f}s at perfect balance; heaviest "
+        f"file {heaviest} {heaviest_s:.0f}s"
+    )
+    out.append(f"wall {wall:.0f}s: imbalance costs {wall - balanced:.0f}s")
+    out.append(f"{wall:.0f}s of the tier-1 cap {cap:.0f}s "
+               f"({wall / cap * 100:.0f}% of budget)")
+    if wall > cap:
         out.append(
-            f"OVER BUDGET by {total - cap:.0f}s — the cap kills the run "
+            f"OVER BUDGET by {wall - cap:.0f}s — the cap kills the run "
             "before the suite finishes; slow-mark or split the offenders"
         )
     out.append("")
@@ -121,24 +131,16 @@ def gate(
 
     Returns (report text, exit code): 0 when every baselined test that ran
     stayed within ``baseline * (1 + tolerance) + slack_s``, 1 when any
-    regressed past it. Tests missing from the log only warn — but they DO
+    regressed past it. Tests missing from the file only warn — but they DO
     warn, so a silent rename/deselection stays visible."""
     per_test, _per_file = aggregate(rows)
     out: List[str] = []
     regressed: List[Tuple[str, float, float]] = []
     missing: List[str] = []
-    floor_missing: List[str] = []
     for test_id, base_s in sorted(baseline.items()):
         measured = per_test.get(test_id)
         if measured is None:
-            # baselined at the 0.01s recording floor = a sub-5ms test:
-            # pytest's durations block hides anything under 5ms, so these
-            # are EXPECTED to be absent from every gate log — one
-            # informational line, not a per-test warning storm
-            if float(base_s) <= 0.011:
-                floor_missing.append(test_id)
-            else:
-                missing.append(test_id)
+            missing.append(test_id)
             continue
         limit = float(base_s) * (1.0 + tolerance) + slack_s
         if measured > limit:
@@ -150,22 +152,15 @@ def gate(
             )
     for test_id in missing:
         out.append(
-            f"warning: baselined test not in this log (deselected or "
+            f"warning: baselined test not in this run (deselected or "
             f"renamed?): {test_id}"
-        )
-    if floor_missing:
-        out.append(
-            f"info: {len(floor_missing)} baselined sub-5ms test(s) not in "
-            "this log — expected (pytest hides durations <5ms): "
-            + ", ".join(floor_missing)
         )
     if regressed:
         out.append("")
         out.append(
             f"GATE FAILED: {len(regressed)} test(s) regressed more than "
             f"{tolerance * 100:.0f}% (+{slack_s:.1f}s slack) vs baseline — "
-            "the 870s overrun must not silently worsen "
-            "(memory/tier1-timing-budget.md):"
+            "the suite's clock must not silently worsen:"
         )
         for test_id, base_s, measured in regressed:
             # a 0.0 baseline (legal JSON, and what rounding a sub-5ms test
@@ -181,7 +176,7 @@ def gate(
     out.append("")
     out.append(
         f"gate passed: "
-        f"{len(baseline) - len(missing) - len(floor_missing)}"
+        f"{len(baseline) - len(missing)}"
         f"/{len(baseline)} baselined tests within budget"
     )
     return "\n".join(out), 0
@@ -204,9 +199,11 @@ def record_baseline(rows, tests: List[str]) -> Dict[str, float]:
 
 def main(argv=None) -> None:
     parser = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    parser.add_argument("log", nargs="?", help="pytest log (default: stdin)")
-    parser.add_argument("--cap", type=float, default=870.0,
-                        help="tier-1 wall cap in seconds (ROADMAP: 870)")
+    parser.add_argument("junit", nargs="?",
+                        help="the run's junit file (default: stdin)")
+    parser.add_argument("--cap", type=float, default=TIER1_CAP_S,
+                        help="tier-1 wall cap in seconds (the driver's "
+                             "timeout: 1470)")
     parser.add_argument("--top", type=int, default=20)
     parser.add_argument("--slow-threshold", type=float, default=10.0,
                         help="per-test seconds above which to suggest a "
@@ -227,14 +224,15 @@ def main(argv=None) -> None:
                              "set (values refreshed only), a new file "
                              "records every parsed test")
     args = parser.parse_args(argv)
-    if args.log:
-        with open(args.log, encoding="utf-8", errors="replace") as f:
-            rows = parse_durations(f)
+    if args.junit:
+        with open(args.junit, encoding="utf-8", errors="replace") as f:
+            text = f.read()
     else:
-        rows = parse_durations(sys.stdin)
+        text = sys.stdin.read()
+    rows, wall = parse_junit(text)
     if args.record_baseline:
         # refreshing an EXISTING baseline re-records only the tests it
-        # already curates — a full-suite durations log must not replace a
+        # already curates — a whole run's file must not replace a
         # hand-picked gate set with hundreds of entries. A new file records
         # everything (the bootstrap case).
         curated: List[str] = []
@@ -260,7 +258,7 @@ def main(argv=None) -> None:
         print(text)
         sys.exit(code)
     print(report(rows, cap=args.cap, top=args.top,
-                 slow_threshold=args.slow_threshold))
+                 slow_threshold=args.slow_threshold, wall=wall))
 
 
 if __name__ == "__main__":
