@@ -11,14 +11,58 @@ Kernel structure (the canonical Pallas flash shape): the reduction axis is
 the INNERMOST GRID DIMENSION, not an in-kernel loop over a resident slab —
 TPU grids execute sequentially, so the online-softmax state (acc, m, l) lives
 in VMEM scratch across the inner iterations, initialized at the first and
-flushed to the output block at the last. VMEM use is O(block), independent of
-S: sequence length is bounded by HBM, not VMEM (verified S=16k on a v5e).
+flushed to the output block at the last. The forward's VMEM use is O(block),
+independent of S: its sequence length is bounded by HBM, not VMEM (verified
+S=16k on a v5e). The BACKWARD's is O(S): see "The tiled backward".
 
 Backward follows the standard flash recipe: save only (out, logsumexp) as
-residuals, recompute probability tiles on the fly in two kernels (dq over
-query blocks, kv innermost; dk/dv over kv blocks, q innermost) — or in ONE
-when a single tile covers the sequence — with delta = rowsum(dO ⊙ O) taken
-inside the kernels from the dO and O blocks they load.
+residuals and recompute probability tiles on the fly — in ONE kernel a call,
+on the forward's walk, in both forms (``_bwd_fused`` where a single tile
+covers the sequence, ``_bwd_tiled`` else) — with delta = rowsum(dO ⊙ O)
+taken inside the kernel from the dO and O blocks it loads.
+
+The tiled backward. ONE sweep (``_bwd_tiled_kernel``, ``flash_*_bwd_tiled``
+in a device trace): grid (B, kv block, the kv block's query programs —
+``members``: the programs of a group that share its kv head; one where k has
+as many heads as q —, query tile, a step of the key sweep), the query tile
+outer and the key sweep inner exactly as the forward walks them (``_k_tile``,
+``_sweep``, ``_for_k_step``: the steps above the diagonal, before a band,
+past the block rule's runs, and a selection's empty tiles run no body). q,
+dO, O and lse stay resident over a sweep; k, v, the bias and a selection's
+tile are the inner fetch. A tile's s, p = exp(s - lse), dp, delta and ds are
+made ONCE a head (``_backward_heads``) and feed three products: dq += ds·k
+into a [Bq, heads·d] float32 scratch zeroed at a sweep's first step and
+flushed at its last; dv += pᵀ·dO and dk += dsᵀ·q into float32 accumulators
+that hold the kv block's dk and dv for the WHOLE sequence, [S, kv block·d]
+and [S, kv block·dv], added at the rows of the step's key tile, zeroed at
+the kv block's first step and cast and written once at its last, through
+output blocks that are the whole sequence too. That residency is what lets
+dq and dk / dv share a walk: no read-modify-write of HBM, no per-key-tile
+partial dq. 5 matmuls, one ``exp``, one mask pass and one fetch stream a
+tile, where the two kernels this replaced (``*_bwd_dq`` over query tiles,
+``*_bwd_dkv`` over key tiles, each rebuilding the tile: until PR 56) spent
+7, 2, 2 and 2. The members' axis lies OUTSIDE the query tile's, so a key
+tile's rows meet their terms in the order the dkv kernel summed them (a
+group's first program over every query tile, then its second): dq, dk and dv
+are the pair's bits. What bounds S now is VMEM (``_bwd_vmem``): 4 + 4 bytes
+a row and lane of the kv block (the accumulators; the output blocks' two
+buffers in bf16) beside a step's blocks and a program's transients — 16 +
+16 MiB at S=16,384 and one kv head of 128, and the call asks for its scoped
+limit (49.5 MiB at Keye's shape, 76.75 for SmallThinker's seven heads a
+program, 22.75-57.5 at the other cells'; a v5e core has 128); a call whose
+need passes 112 MiB — S=65,536 at those widths, 49,152 under a group of
+seven — is refused with an error that says so. A call on a v5e, the pair →
+the sweep, ms (PR 56; each mode alone, ``tools/chip_gqa_check.py`` /
+``chip_causal_check.py`` / ``chip_mla_check.py``): selected, 32 / 4 x 128
+at S=16,384, 26.02 + 30.33 → 38.19; band 4,096 and causal at 28 / 4 x 128,
+S=16,384 (seven heads a program), 8.04 + 10.67 → 13.12 and 17.19 + 22.38 →
+27.88; the block rule, 32 / 4 x 128 over 2 x 4,096, 3.95 + 5.18 → 5.99;
+band 512 at 64 / 8 x 128 and causal at 48 / 8 x 128 (six heads a program),
+S=8,192, 2.76 + 3.45 → 4.27 and 7.95 + 9.97 → 12.77; 32 / 8 x 64 at
+S=4,096, 1.63 + 2.16 → 2.50; 16 x 128 causal at S=4,096, 0.76 + 0.99 →
+1.28; 32 x 192 / 128, 2.54 + 2.81 → 3.82: 0.66-0.73 of the pair in every
+mode, 1.22-1.36 x the dkv kernel alone, so ONE path serves every tiled
+call and no shape keeps the pair.
 
 One-tile forms. When one (Bq, Bk) tile covers the sequence (``_pick_block``
 gives S for both blocks: S=512 under the default 512, every tiny test
@@ -121,9 +165,9 @@ them. A tile then needs up to TWO runs of tiles of the other axis (see
 group that is no power of two (seven
 query heads a kv head) is ONE program, where a column block is one head
 (D=128): halving the heads a program takes never lands on a divisor of it,
-and one head a program — what halving falls to — ran the backward kernels
-at 63 / 68 % of their roofline against 90 / 90 % (v5e, 28 / 4 x 128 at
-S=16,384, PR 36).
+and one head a program — what halving falls to — ran the two backward
+kernels of the time at 63 / 68 % of their roofline against 90 / 90 % (v5e,
+28 / 4 x 128 at S=16,384, PR 36).
 
 Off-TPU (CPU tests, CI) the same kernels run under ``interpret=True``
 (``utils.backend.pallas_interpret`` decides, once, for every op here).
@@ -402,9 +446,9 @@ def _dot(a, b, contract_a: int, contract_b: int):
 # per-head products then zero the other head's half exactly as the
 # equal-count kernels do. dK / dV: the heads of a column block sum into the
 # block's tile slot by slot, the tile is folded onto the kv head's half
-# (``_fold_group``) and added to the kv block's float32 accumulator, which
-# lives across the group's query programs — the ``members`` axis of the dkv
-# grid — and is written once per kv head.
+# (``_fold_group``) and added, at the key tile's rows, to the kv block's
+# float32 accumulator, which lives across the group's query programs — the
+# ``members`` axis of the backward's grid — and is written once per kv head.
 
 
 def _grouped(q, k, d: int, dv: int, g: int, hp: int):
@@ -489,17 +533,18 @@ def _group_tiles(refs, h0, hp, group, g, d, program, made):
     return made[key], at
 
 
-def _fold_group(acc_ref, at, total, d: int, g: int) -> None:
+def _fold_group(acc_ref, rows, at, total, d: int, g: int) -> None:
     """Add a query column block's dK or dV ``total`` (head i's part in slot
-    i) to its kv head's slot of the accumulator."""
+    i) to its kv head's slot of the accumulator, at the key tile's
+    ``rows``."""
     block, slot = at
     lanes = slice(block * g * d, (block + 1) * g * d)
     if g == 1:
-        acc_ref[:, lanes] += total
+        acc_ref[rows, lanes] += total
         return
     both = total + _swap_halves(total, d)  # every slot: the heads' sum
     mine = _low_half(total.shape, d) == (slot == 0)
-    acc_ref[:, lanes] += jnp.where(mine, both, jnp.zeros_like(both))
+    acc_ref[rows, lanes] += jnp.where(mine, both, jnp.zeros_like(both))
 
 
 # ------------------------------------------------------------ causal tiles
@@ -519,8 +564,8 @@ def _fold_group(acc_ref, at, total, d: int, g: int) -> None:
 # S=16,384, w=4,096, 512 x 512 tiles) and sweeps from the outer tile's FIRST
 # needed tile on. What a query may see is ONE description, ``_Mask``, and
 # everything that depends on it reads it: the tile a grid step names
-# (``_k_tile`` / ``_q_tile``), the sweep's length (``_sweep``), the three
-# cases (``_for_tile``), the iota mask (``_tile_mask``), the kernels' names
+# (``_k_tile``), the sweep's length (``_sweep``), the three cases
+# (``_for_tile``), the iota mask (``_tile_mask``), the kernels' names
 # and the count of visited tiles (``visited_tiles``).
 
 
@@ -566,19 +611,6 @@ def _first_k_tile(mask: _Mask, qi, bq: int, bk: int):
     return _clip(qi * bq - mask.band + 1, low=0) // bk
 
 
-def _first_q_tile(ki, bq: int, bk: int):
-    """The first query tile that sees key tile ``ki``."""
-    return (ki * bk) // bq
-
-
-def _last_q_tile(mask: _Mask, ki, bq: int, bk: int, nq: int):
-    """The last query tile that sees key tile ``ki``: the one that holds
-    the last query whose band reaches the tile's last key."""
-    if mask.band is None:
-        return nq - 1
-    return _clip((ki * bk + bk + mask.band - 2) // bq, high=nq - 1)
-
-
 def _from(first, step):
     """Tile ``step`` of a sweep that starts at ``first``."""
     return step if isinstance(first, int) and first == 0 else first + step
@@ -589,7 +621,7 @@ def _k_tile(mask: _Mask, qi, step, bq: int, bk: int):
     (an index map's answer): a step past the last needed tile re-names that
     tile, which is not fetched again."""
     if mask.blocks is not None:
-        return _bd_tile(mask.blocks, qi, step, bq, bk, "k", clamp=True)[0]
+        return _bd_tile(mask.blocks, qi, step, bq, bk, clamp=True)[0]
     if not mask.causal:
         return step
     return jnp.minimum(
@@ -597,41 +629,17 @@ def _k_tile(mask: _Mask, qi, step, bq: int, bk: int):
     )
 
 
-def _q_tile(mask: _Mask, ki, step, bq: int, bk: int, nq: int):
-    """The query tile that step ``step`` of key tile ``ki``'s sweep names.
-    Without a band the sweep is over every query tile and the steps before
-    the first needed one name it; with one it starts there."""
-    if mask.blocks is not None:
-        return _bd_tile(mask.blocks, ki, step, bk, bq, "q", clamp=True)[0]
-    if not mask.causal:
-        return step
-    first = _first_q_tile(ki, bq, bk)
-    if mask.band is None:
-        return jnp.maximum(step, first)
-    return jnp.minimum(first + step, _last_q_tile(mask, ki, bq, bk, nq))
-
-
-def _sweep(mask: _Mask, nq: int, nk: int, bq: int, bk: int, over: str) -> int:
-    """Steps of the inner grid axis: the most key tiles a query tile needs
-    (``over`` "k") or query tiles a key tile is seen by ("q"). Every tile
-    of the axis unless there is a band (or the block rule: the longer of
+def _sweep(mask: _Mask, nq: int, nk: int, bq: int, bk: int) -> int:
+    """Steps of the inner grid axis: the most key tiles a query tile needs.
+    Every key tile unless there is a band (or the block rule: the longer of
     its two runs together)."""
     if mask.blocks is not None:
-        outer, inner = (bq, bk) if over == "k" else (bk, bq)
-        return max(
-            _bd_tiles_of(mask.blocks, t, outer, inner, over)
-            for t in range(nq if over == "k" else nk)
-        )
+        return max(_bd_tiles_of(mask.blocks, qi, bq, bk) for qi in range(nq))
     if mask.band is None:
-        return nk if over == "k" else nq
-    if over == "k":
-        return max(
-            _last_k_tile(qi, bq, bk) - _first_k_tile(mask, qi, bq, bk) + 1
-            for qi in range(nq)
-        )
+        return nk
     return max(
-        _last_q_tile(mask, ki, bq, bk, nq) - _first_q_tile(ki, bq, bk) + 1
-        for ki in range(nk)
+        _last_k_tile(qi, bq, bk) - _first_k_tile(mask, qi, bq, bk) + 1
+        for qi in range(nq)
     )
 
 
@@ -646,8 +654,7 @@ def visited_tiles(seq: int, block_q: int, block_k: int, causal: bool,
     if mask.blocks is not None:
         bq, bk = _bd_blocks(mask.blocks, block_q, block_k)
         return sum(
-            _bd_tiles_of(mask.blocks, qi, bq, bk, "k")
-            for qi in range(seq // bq)
+            _bd_tiles_of(mask.blocks, qi, bq, bk) for qi in range(seq // bq)
         )
     bq, bk = _pick_block(seq, block_q), _pick_block(seq, block_k)
     if not mask.causal:
@@ -791,26 +798,10 @@ def _for_k_step(mask: _Mask, qi, step, bq: int, bk: int, seq: int,
                            bk, seq, body)
         return
     if mask.blocks is not None:
-        _bd_for_step(mask.blocks, "k", qi, step, bq, bk, body)
+        _bd_for_step(mask.blocks, qi, step, bq, bk, body)
         return
     _for_tile(mask, qi, _from(_first_k_tile(mask, qi, bq, bk), step), bq, bk,
               seq, body)
-
-
-def _for_q_step(mask: _Mask, ki, step, bq: int, bk: int, seq: int,
-                body, sel=None) -> None:
-    """``_for_tile`` for step ``step`` of key tile ``ki``'s query sweep (a
-    band's starts at the key tile's first query tile)."""
-    if mask.selected:
-        first = _first_q_tile(ki, bq, bk)
-        _for_selected_tile(sel, step >= first, jnp.maximum(step, first), ki,
-                           bk, seq, body)
-        return
-    if mask.blocks is not None:
-        _bd_for_step(mask.blocks, "q", ki, step, bq, bk, body)
-        return
-    first = 0 if mask.band is None else _first_q_tile(ki, bq, bk)
-    _for_tile(mask, _from(first, step), ki, bq, bk, seq, body)
 
 
 # -------------------------------------------------- block-diffusion tiles
@@ -826,10 +817,9 @@ def _for_q_step(mask: _Mask, ki, step, bq: int, bk: int, seq: int,
 # copy of its own block, and no clean query sees a noisy key. Tiles never
 # straddle the two streams, nor a block a tile (``_bd_blocks``). In tiles, a
 # query tile needs up to TWO runs of key tiles — the clean tiles up to its
-# diagonal, and (noisy) its own noisy diagonal tile — and a clean key tile
-# is seen by two runs of query tiles, the noisy ones and the clean ones from
-# its diagonal on (``_bd_runs``). A sweep walks the first run, then the
-# second (``_bd_tile``); a step past both re-names the last tile and is
+# diagonal, and (noisy) its own noisy diagonal tile (``_bd_runs``; forward
+# and backward both walk a query tile's keys). A sweep walks the first run,
+# then the second (``_bd_tile``); a step past both re-names the last tile and is
 # skipped, as a causal sweep's. Within a tile the rule is ONE comparison of
 # block indices, low <= block(i) - block(j) <= high, with (low, high) by
 # quadrant — clean x clean (0, any), noisy x clean (1, any), noisy x noisy
@@ -862,52 +852,33 @@ def _bd_blocks(blocks: _Blocks, block_q: int, block_k: int):
     return bq, bk
 
 
-def _bd_runs(blocks: _Blocks, outer, b_outer: int, b_inner: int, over: str):
-    """The two runs of inner tiles that outer tile ``outer`` needs, each
-    (first tile, count), in sweep order; a count may be 0. ``over`` "k":
-    the key tiles of a query tile; "q": the query tiles of a key tile.
-    ``outer`` is a Python int or a traced scalar."""
-    b, n_outer = blocks.length, blocks.stream // b_outer
-    n_inner = blocks.stream // b_inner
-    clean = outer >= n_outer
-    lo = (outer - _where(clean, n_outer, 0)) * b_outer  # in its stream
-    hi = lo + b_outer - 1
-    if over == "k":
-        # the CLEAN keys of the blocks up to a clean query's own, before a
-        # noisy query's own; then a noisy query's own NOISY blocks
-        own = lo // b_inner
-        return (
-            (n_inner, _where(clean, hi, hi - b) // b_inner + 1),
-            (own, _where(clean, 0, hi // b_inner - own + 1)),
-        )
-    # the NOISY queries of a noisy key's own blocks, of the blocks after a
-    # clean key's; then the CLEAN queries from a clean key's own block on
-    first = _where(clean, lo + b, lo) // b_inner
-    own = lo // b_inner
+def _bd_runs(blocks: _Blocks, qi, bq: int, bk: int):
+    """The two runs of key tiles that query tile ``qi`` needs, each (first
+    tile, count), in sweep order; a count may be 0. ``qi`` is a Python int
+    or a traced scalar."""
+    b, nq, nk = blocks.length, blocks.stream // bq, blocks.stream // bk
+    clean = qi >= nq
+    lo = (qi - _where(clean, nq, 0)) * bq  # in its stream
+    hi = lo + bq - 1
+    # the CLEAN keys of the blocks up to a clean query's own, before a noisy
+    # query's own; then a noisy query's own NOISY blocks
+    own = lo // bk
     return (
-        (first, _where(clean, n_inner, hi // b_inner + 1) - first),
-        (n_inner + own, _where(clean, n_inner - own, 0)),
+        (nk, _where(clean, hi, hi - b) // bk + 1),
+        (own, _where(clean, 0, hi // bk - own + 1)),
     )
 
 
-def _bd_tiles_of(blocks: _Blocks, outer, b_outer: int, b_inner: int,
-                 over: str):
-    """Inner tiles outer tile ``outer`` needs: both runs together."""
-    return sum(
-        count for _first, count in _bd_runs(
-            blocks, outer, b_outer, b_inner, over
-        )
-    )
+def _bd_tiles_of(blocks: _Blocks, qi, bq: int, bk: int):
+    """Key tiles query tile ``qi`` needs: both runs together."""
+    return sum(count for _first, count in _bd_runs(blocks, qi, bq, bk))
 
 
-def _bd_tile(blocks: _Blocks, outer, step, b_outer: int, b_inner: int,
-             over: str, clamp: bool):
-    """(inner tile, valid) of step ``step`` of outer tile ``outer``'s
-    sweep. ``clamp``: an index map's answer, a step past both runs naming
-    the last tile again."""
-    (a_first, a_count), (b_first, b_count) = _bd_runs(
-        blocks, outer, b_outer, b_inner, over
-    )
+def _bd_tile(blocks: _Blocks, qi, step, bq: int, bk: int, clamp: bool):
+    """(key tile, valid) of step ``step`` of query tile ``qi``'s sweep.
+    ``clamp``: an index map's answer, a step past both runs naming the last
+    tile again."""
+    (a_first, a_count), (b_first, b_count) = _bd_runs(blocks, qi, bq, bk)
     valid = step < a_count + b_count
     if clamp:
         step = jnp.minimum(step, a_count + b_count - 1)
@@ -923,17 +894,12 @@ def _block_of(x, length: int):
     return jax.lax.shift_right_logical(x, jnp.int32(length.bit_length() - 1))
 
 
-def _bd_for_step(blocks: _Blocks, over: str, outer, step, bq: int, bk: int,
-                 body) -> None:
-    """Run ``body(tile mask)`` for the tile that step ``step`` of outer
-    tile ``outer``'s sweep visits: None where the whole tile is visible, not
-    at all for a step past the sweep's runs."""
+def _bd_for_step(blocks: _Blocks, qi, step, bq: int, bk: int, body) -> None:
+    """Run ``body(tile mask)`` for the tile that step ``step`` of query
+    tile ``qi``'s sweep visits: None where the whole tile is visible, not at
+    all for a step past the sweep's runs."""
     b, nq, nk = blocks.length, blocks.stream // bq, blocks.stream // bk
-    inner, valid = _bd_tile(
-        blocks, outer, step, *((bq, bk) if over == "k" else (bk, bq)), over,
-        clamp=False,
-    )
-    qi, ki = (outer, inner) if over == "k" else (inner, outer)
+    ki, valid = _bd_tile(blocks, qi, step, bq, bk, clamp=False)
     q_clean, k_clean = qi >= nq, ki >= nk
     q0 = (qi - jnp.where(q_clean, nq, 0)) * bq  # in their streams
     k0 = (ki - jnp.where(k_clean, nk, 0)) * bk
@@ -1168,7 +1134,7 @@ def _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, mask, interpret,
     )
     out, lse = pl.pallas_call(
         kernel if sel is None else _selected_kernel(kernel, 4),
-        grid=(b, hpb, s // bq, _sweep(mask, s // bq, s // bk, bq, bk, "k")),
+        grid=(b, hpb, s // bq, _sweep(mask, s // bq, s // bk, bq, bk)),
         in_specs=[
             pl.BlockSpec((None, bq, hp * d), lambda n, p, j, kb: (n, j, p)),
             pl.BlockSpec((None, bk, kvb * d),
@@ -1280,35 +1246,82 @@ def _backward_heads(refs, bias_ref, lse_ref, h0, cols, vcols, mask, *,
                     _only_head(do, i, dv, g))
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
-               dq_ref, dq_acc_ref, *, scale, d, dv, g, mask, seq, group=1,
-               sel=None):
-    kb = pl.program_id(3)  # a step of the key sweep, not yet a tile
-    nk = pl.num_programs(3)
-    qi, bq, bk = pl.program_id(2), q_ref.shape[0], k_ref.shape[0]
-    program = pl.program_id(1) if group > 1 else None
+def _each_key_tile(ref, bk: int, body) -> None:
+    """``body(rows)`` for each run of ``bk`` rows of a whole-sequence
+    ``ref``, as a loop (a sequence of tiles is not unrolled)."""
+    def step(t, carry):
+        body(pl.ds(pl.multiple_of(t * bk, bk), bk))
+        return carry
+
+    jax.lax.fori_loop(0, ref.shape[0] // bk, step, 0)
+
+
+def _bwd_tiled_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
+                      dq_ref, dk_ref, dv_ref, dq_acc_ref, dk_acc_ref,
+                      dv_acc_ref, *, scale, d, dv, g, mask, seq, group=1,
+                      sel=None):
+    """The tiled backward in ONE sweep (see "The tiled backward"): grid (B,
+    kv blocks, the kv block's query programs — a grouped call's ``members``
+    —, query tile, a step of the key sweep). dq's accumulator lives over a
+    key sweep; dk's and dv's hold the kv block's WHOLE sequence and live
+    over everything inside the kv block's axis."""
+    member, members = pl.program_id(2), pl.num_programs(2)
+    qi, nq = pl.program_id(3), pl.num_programs(3)
+    kb, nk = pl.program_id(4), pl.num_programs(4)  # a step, not yet a tile
+    bq, bk = q_ref.shape[0], k_ref.shape[0]
+    program = pl.program_id(1) * members + member
+    # the rows of dk / dv this step's key tile owns
+    rows = pl.ds(pl.multiple_of(_k_tile(mask, qi, kb, bq, bk) * bk, bk), bk)
 
     @pl.when(kb == 0)
     def _init():
         dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
+
+    @pl.when((member == 0) & (qi == 0) & (kb == 0))
+    def _init_kv():
+        def zero(tile):
+            dk_acc_ref[tile, :] = jnp.zeros((bk, dk_acc_ref.shape[-1]),
+                                            jnp.float32)
+            dv_acc_ref[tile, :] = jnp.zeros((bk, dv_acc_ref.shape[-1]),
+                                            jnp.float32)
+
+        _each_key_tile(dk_acc_ref, bk, zero)
 
     def tile(mask):
         made = {}
         for h0, cols, vcols in _column_blocks(q_ref.shape[-1], g, d, dv):
             dq_at = _segments_at(cols, d, g)
             dq = [dq_acc_ref[:, at] for at in dq_at]
-            group_kv = None if group == 1 else _group_tiles(
-                (k_ref, v_ref), h0, q_ref.shape[-1] // d, group, g, d,
-                program, made,
-            )[0]
+            if group == 1:
+                group_kv = None
+                dk_at, dv_at = dq_at, _segments_at(vcols, dv, g)
+                dk = [dk_acc_ref[rows, at] for at in dk_at]
+                dv_ = [dv_acc_ref[rows, at] for at in dv_at]
+            else:  # sum the block's heads, fold onto their kv head
+                group_kv, slot = _group_tiles(
+                    (k_ref, v_ref), h0, q_ref.shape[-1] // d, group, g, d,
+                    program, made,
+                )
+                dk, dv_ = [None], [None]
+            # ONE score / probability tile a head feeds the three products
             for i, head in enumerate(_backward_heads(
                 (q_ref, k_ref, v_ref, do_ref, o_ref), bias_ref, lse_ref, h0,
                 cols, vcols, mask, scale=scale, d=d, dv=dv, g=g,
                 group_kv=group_kv,
             )):
+                _into(dv_, i, dv, g, _dot(head.p, head.do, 0, 0))
+                _into(dk, i, d, g, _dot(head.ds, head.q, 0, 0))
                 _into(dq, i, d, g, _dot(head.ds, head.k, 1, 0))
             for at, total in zip(dq_at, dq):
                 dq_acc_ref[:, at] = total
+            if group > 1:
+                _fold_group(dk_acc_ref, rows, slot, dk[0], d, g)
+                _fold_group(dv_acc_ref, rows, slot, dv_[0], d, g)
+            else:
+                for at, total in zip(dk_at, dk):
+                    dk_acc_ref[rows, at] = total
+                for at, total in zip(dv_at, dv_):
+                    dv_acc_ref[rows, at] = total
 
     _for_k_step(mask, qi, kb, bq, bk, seq, tile, sel)
 
@@ -1316,65 +1329,13 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
     def _flush():
         dq_ref[:] = dq_acc_ref[:].astype(dq_ref.dtype)
 
+    @pl.when((member == members - 1) & (qi == nq - 1) & (kb == nk - 1))
+    def _flush_kv():
+        def cast(tile):
+            dk_ref[tile, :] = dk_acc_ref[tile, :].astype(dk_ref.dtype)
+            dv_ref[tile, :] = dv_acc_ref[tile, :].astype(dv_ref.dtype)
 
-def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, o_ref,
-                dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, scale, d, dv, g,
-                mask, seq, group=1, sel=None):
-    # grid (B, across the kv width, kv tile, [the kv block's query programs
-    # — a grouped call's ``members`` —], a step of the query sweep): the
-    # accumulators live over everything inside the kv tile's axis
-    qb = pl.program_id(3 if group == 1 else 4)
-    nq = pl.num_programs(3 if group == 1 else 4)
-    ki, bq, bk = pl.program_id(2), q_ref.shape[0], k_ref.shape[0]
-    if group > 1:
-        member, members = pl.program_id(3), pl.num_programs(3)
-        program = pl.program_id(1) * members + member
-
-    def of_group(mine, at):  # ... and in the group's first / last program
-        return mine if group == 1 else mine & (member == at % members)
-
-    @pl.when(of_group(qb == 0, 0))
-    def _init():
-        dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
-        dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
-
-    def tile(mask):
-        made = {}
-        for h0, cols, vcols in _column_blocks(q_ref.shape[-1], g, d, dv):
-            heads = functools.partial(
-                _backward_heads, (q_ref, k_ref, v_ref, do_ref, o_ref),
-                bias_ref, lse_ref, h0, cols, vcols, mask, scale=scale, d=d,
-                dv=dv, g=g,
-            )
-            if group > 1:  # sum the block's heads, fold onto their kv head
-                group_kv, at = _group_tiles(
-                    (k_ref, v_ref), h0, q_ref.shape[-1] // d, group, g, d,
-                    program, made,
-                )
-                dk, dv_ = [None], [None]
-                for i, head in enumerate(heads(group_kv=group_kv)):
-                    _into(dv_, i, dv, g, _dot(head.p, head.do, 0, 0))
-                    _into(dk, i, d, g, _dot(head.ds, head.q, 0, 0))
-                _fold_group(dk_acc_ref, at, dk[0], d, g)
-                _fold_group(dv_acc_ref, at, dv_[0], d, g)
-                continue
-            dk_at, dv_at = _segments_at(cols, d, g), _segments_at(vcols, dv, g)
-            dk = [dk_acc_ref[:, at] for at in dk_at]
-            dv_ = [dv_acc_ref[:, at] for at in dv_at]
-            for i, head in enumerate(heads()):
-                _into(dv_, i, dv, g, _dot(head.p, head.do, 0, 0))
-                _into(dk, i, d, g, _dot(head.ds, head.q, 0, 0))
-            for at, total in zip(dk_at, dk):
-                dk_acc_ref[:, at] = total
-            for at, total in zip(dv_at, dv_):
-                dv_acc_ref[:, at] = total
-
-    _for_q_step(mask, ki, qb, bq, bk, seq, tile, sel)
-
-    @pl.when(of_group(qb == nq - 1, -1))
-    def _flush():
-        dk_ref[:] = dk_acc_ref[:].astype(dk_ref.dtype)
-        dv_ref[:] = dv_acc_ref[:].astype(dv_ref.dtype)
+        _each_key_tile(dk_acc_ref, bk, cast)
 
 
 def _dqkv_fused_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref,
@@ -1403,178 +1364,145 @@ def _dqkv_fused_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref,
                 ref[:, at] = total.astype(ref.dtype)
 
 
-def _bwd(q, k, v, bias, lse, do, out, d, dv, block_q, block_k, mask,
-         interpret, sel=None):
-    if k.shape != q.shape:
-        return _bwd_grouped(q, k, v, bias, lse, do, out, d, block_q, block_k,
-                            mask, interpret, sel)
-    if _one_tile(q.shape[1], block_q, block_k) and sel is None:
-        return _bwd_fused(q, k, v, bias, lse, do, out, d, dv, mask,
-                          interpret)
+# what a core's VMEM can be asked for (a v5e's is 128 MiB; the compiler's own
+# scoped limit is 16): the tiled backward's ceiling
+_VMEM_CEILING = 112 * 2**20
+
+
+def _bwd_geometry(q, k, d: int, dv: int, block_q: int, block_k: int):
+    """``_geometry`` of a tiled backward call with ``_grouped``'s (group, kv
+    heads per kv block) behind it, the heads per program as the group
+    leaves them."""
     # bwd transients per head are ~3x the fwd's (s, p, dp, ds live at once)
     b, s, h, g, hp, bq, bk = _geometry(q, d, dv, block_q, block_k,
                                        budget_mb=4.0)
-    hpb, nq, nk = h // hp, s // bq, s // bk
-    kernel_args = dict(scale=1.0 / (d ** 0.5), d=d, dv=dv, g=g, mask=mask,
-                       seq=s)
+    group, kvb, hp = _grouped(q, k, d, dv, g, hp)
+    return b, s, h, g, hp, bq, bk, group, kvb
 
-    # grid (B, programs across the width, outer S block, inner S block):
-    # each spec says which of the two S positions it follows, and which of
-    # the two widths (q, k and their gradients; v, out, dO and dv)
-    def wide(rows, at, width=d):
-        return pl.BlockSpec((None, rows, hp * width),
-                            lambda n, p, x, y: (n, at(x, y), p))
 
-    def in_specs(q_at, k_at):  # q, k, v, bias, lse, dO, O(, selection)
-        return [
-            wide(bq, q_at), wide(bk, k_at), wide(bk, k_at, dv),
+def _bwd_resident(s: int, kvb: int, d: int, dv: int, size: int):
+    """Bytes a tiled backward call keeps in VMEM for a kv block's WHOLE
+    sequence: (the float32 dk and dv accumulators, the dk and dv output
+    blocks in their own dtype, twice — the pipeline's two buffers)."""
+    return 4 * s * kvb * (d + dv), 2 * size * s * kvb * (d + dv)
+
+
+def _bwd_vmem(q, k, d: int, dv: int, block_q: int, block_k: int,
+              selected: bool = False):
+    """Compiler parameters of the tiled backward call on [B, S, H·d] ``q``
+    and [B, S, H_kv·d] ``k`` (arrays or their shapes): the scoped VMEM it
+    asks for — what is resident for the whole sequence (``_bwd_resident``),
+    the blocks of a step (q, dO, O and dq a query tile; k and v a key tile;
+    twice), dq's accumulator, and a program's heads' transients (s, p, dp
+    and ds of a head: three times ``_pick_heads``' forward figure). None —
+    the compiler's own limit, 16 MiB on a v5e — where that fits it (the
+    test models). The sequence is bounded HERE: a call whose need passes
+    ``_VMEM_CEILING`` is refused (4 + 4 bytes a row and lane of the kv
+    block: S = 32,768 still fits at a kv block of one head of 128 and two
+    heads a program, 65,536 does not; every cell is at or under 16,384)."""
+    _b, s, _h, _g, hp, bq, bk, _group, kvb = _bwd_geometry(
+        q, k, d, dv, block_q, block_k
+    )
+    size = q.dtype.itemsize
+    accumulators, outputs = _bwd_resident(s, kvb, d, dv, size)
+    blocks = 2 * size * (2 * bq * hp * (d + dv) + bk * kvb * (d + dv))
+    transients = hp * 18 * bq * bk + (6 * bq * bk if selected else 0)
+    need = accumulators + outputs + blocks + 4 * bq * hp * d + transients
+    if need <= 14 * 2**20:
+        return None
+    if need + 4 * 2**20 > _VMEM_CEILING:
+        raise ValueError(
+            f"tiled flash backward at S={s}: dk and dv of a kv block "
+            f"({kvb} heads of {d} / {dv}) are held for the whole sequence "
+            f"in VMEM, {(accumulators + outputs) / 2**20:.0f} MiB of the "
+            f"{need / 2**20:.0f} the call needs, past the "
+            f"{_VMEM_CEILING // 2**20} MiB a core can be asked for; run "
+            "the sequence in shorter rows"
+        )
+    return pltpu.CompilerParams(vmem_limit_bytes=need + 4 * 2**20)
+
+
+def _bwd(q, k, v, bias, lse, do, out, d, dv, block_q, block_k, mask,
+         interpret, sel=None):
+    # grouped-query calls take the tiled form at every length, as a
+    # selection's (see ``_fwd``)
+    if (_one_tile(q.shape[1], block_q, block_k) and k.shape == q.shape
+            and sel is None):
+        return _bwd_fused(q, k, v, bias, lse, do, out, d, dv, mask,
+                          interpret)
+    return _bwd_tiled(q, k, v, bias, lse, do, out, d, dv, block_q, block_k,
+                      mask, interpret, sel)
+
+
+def _bwd_tiled(q, k, v, bias, lse, do, out, d, dv, block_q, block_k, mask,
+               interpret, sel=None):
+    """dq, dk and dv of a tiled call from ONE kernel on the forward's walk
+    (query tile outer, key sweep inner), with one more axis outside the
+    query tile's: the query programs that share a kv block (``members``;
+    one where k has as many heads as q)."""
+    b, s, h, g, hp, bq, bk, group, kvb = _bwd_geometry(
+        q, k, d, dv, block_q, block_k
+    )
+    hpb, members, nq, nk = h // hp, kvb * group // hp, s // bq, s // bk
+
+    def k_at(j, kb):  # a step past the sweep re-names the last needed tile
+        return _k_tile(mask, j, kb, bq, bk)
+
+    # grid (B, kv block, member, query tile, key step): each spec follows
+    # the query tile or the key tile, at one of the two widths (q, k and
+    # their gradients; v, out, dO and dv)
+    def q_side(width):
+        return pl.BlockSpec(
+            (None, bq, hp * width),
+            lambda n, c, m, j, kb: (n, j, c * members + m),
+        )
+
+    def kv_side(width):
+        return pl.BlockSpec((None, bk, kvb * width),
+                            lambda n, c, m, j, kb: (n, k_at(j, kb), c))
+
+    def kv_whole(width):  # resident over the kv block's whole walk
+        return pl.BlockSpec((None, s, kvb * width),
+                            lambda n, c, m, j, kb: (n, 0, c))
+
+    kernel = functools.partial(
+        _bwd_tiled_kernel, scale=1.0 / (d ** 0.5), d=d, dv=dv, g=g,
+        mask=mask, seq=s, group=group,
+    )
+    selection = [] if sel is None else _selection_specs(
+        bq, bk, lambda n, c, m, j, kb: (n, j, k_at(j, kb))
+    )
+    return pl.pallas_call(
+        kernel if sel is None else _selected_kernel(kernel, 7),
+        grid=(b, hpb // members, members, nq, _sweep(mask, nq, nk, bq, bk)),
+        in_specs=[  # q, k, v, bias, lse, dO, O(, selection, flags)
+            q_side(d), kv_side(d), kv_side(dv),
             pl.BlockSpec((None, 1, bk),
-                         lambda n, p, x, y: (n, 0, k_at(x, y))),
-            pl.BlockSpec((hp, 1, bq),
-                         lambda n, p, x, y: (n * hpb + p, 0, q_at(x, y))),
-            wide(bq, q_at, dv), wide(bq, q_at, dv),
-        ] + ([] if sel is None else _selection_specs(
-            bq, bk, lambda n, p, x, y: (n, q_at(x, y), k_at(x, y))
-        ))
-
-    def kernel_of(kernel):
-        kernel = functools.partial(kernel, **kernel_args)
-        return kernel if sel is None else _selected_kernel(kernel, 7)
-
-    operands = (q, k, v, bias, lse, do, out, *(sel or ()))
-
-    def outer(x, y):
-        return x
-
-    # the inner position of a causal grid stays on the tiles its outer one
-    # needs (see "causal tiles"): nothing outside the mask is fetched
-    def inner_k(x, y):
-        return _k_tile(mask, x, y, bq, bk)
-
-    def inner_q(x, y):
-        return _q_tile(mask, x, y, bq, bk, nq)
-
-    dq = pl.pallas_call(
-        kernel_of(_dq_kernel),
-        grid=(b, hpb, nq, _sweep(mask, nq, nk, bq, bk, "k")),
-        in_specs=in_specs(q_at=outer, k_at=inner_k),
-        out_specs=wide(bq, outer),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, hp * d), jnp.float32)],
-        interpret=interpret,
-        name=_name("bwd_dq", mask, d, dv),
-        metadata=_metadata(d, dv, g, q, k, mask),
-    )(*operands)
-
-    dk, dv = pl.pallas_call(
-        kernel_of(_dkv_kernel),
-        grid=(b, hpb, nk, _sweep(mask, nq, nk, bq, bk, "q")),
-        in_specs=in_specs(q_at=inner_q, k_at=outer),
-        out_specs=[wide(bk, outer), wide(bk, outer, dv)],
+                         lambda n, c, m, j, kb: (n, 0, k_at(j, kb))),
+            pl.BlockSpec(
+                (hp, 1, bq),
+                lambda n, c, m, j, kb: (n * hpb + c * members + m, 0, j),
+            ),
+            q_side(dv), q_side(dv),
+            *selection,
+        ],
+        out_specs=[q_side(d), kv_whole(d), kv_whole(dv)],
         out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bk, hp * d), jnp.float32),
-            pltpu.VMEM((bk, hp * dv), jnp.float32),
+            pltpu.VMEM((bq, hp * d), jnp.float32),
+            pltpu.VMEM((s, kvb * d), jnp.float32),
+            pltpu.VMEM((s, kvb * dv), jnp.float32),
         ],
         interpret=interpret,
-        name=_name("bwd_dkv", mask, d, dv),
+        name=_name("bwd_tiled", mask, d, dv, group),
         metadata=_metadata(d, dv, g, q, k, mask),
-    )(*operands)
-    return dq, dk, dv
-
-
-def _bwd_grouped(q, k, v, bias, lse, do, out, d, block_q, block_k, mask,
-                 interpret, sel=None):
-    """The two-kernel backward with fewer kv heads than heads (see
-    "grouped-query heads"): dq as ever, its k / v blocks the group's; dk and
-    dv on a grid with one more axis, the query programs that share a kv
-    block, inside the kv tile's and outside the query tile's."""
-    b, s, h, g, hp, bq, bk = _geometry(q, d, d, block_q, block_k,
-                                       budget_mb=4.0)
-    group, kvb, hp = _grouped(q, k, d, d, g, hp)
-    hpb, members, nq, nk = h // hp, kvb * group // hp, s // bq, s // bk
-    kernel_args = dict(scale=1.0 / (d ** 0.5), d=d, dv=d, g=g, mask=mask,
-                       seq=s, group=group)
-    names = dict(interpret=interpret,
-                 metadata=_metadata(d, d, g, q, k, mask))
-
-    def last_k(j, kb):
-        return _k_tile(mask, j, kb, bq, bk)
-
-    def first_q(kt, qt):
-        return _q_tile(mask, kt, qt, bq, bk, nq)
-
-    def kv_at(p):
-        return p * hp // group // kvb
-
-    def kernel_of(kernel):
-        kernel = functools.partial(kernel, **kernel_args)
-        return kernel if sel is None else _selected_kernel(kernel, 7)
-
-    def selection(at):
-        return [] if sel is None else _selection_specs(bq, bk, at)
-
-    operands = (q, k, v, bias, lse, do, out, *(sel or ()))
-    q_side = pl.BlockSpec((None, bq, hp * d), lambda n, p, j, kb: (n, j, p))
-    kv_side = pl.BlockSpec(
-        (None, bk, kvb * d), lambda n, p, j, kb: (n, last_k(j, kb), kv_at(p))
-    )
-    dq = pl.pallas_call(
-        kernel_of(_dq_kernel),
-        grid=(b, hpb, nq, _sweep(mask, nq, nk, bq, bk, "k")),
-        in_specs=[
-            q_side, kv_side, kv_side,
-            pl.BlockSpec((None, 1, bk),
-                         lambda n, p, j, kb: (n, 0, last_k(j, kb))),
-            pl.BlockSpec((hp, 1, bq),
-                         lambda n, p, j, kb: (n * hpb + p, 0, j)),
-            q_side, q_side,
-            *selection(lambda n, p, j, kb: (n, j, last_k(j, kb))),
-        ],
-        out_specs=q_side,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, hp * d), jnp.float32)],
-        name=_name("bwd_dq", mask, d, d, group), **names,
-    )(*operands)
-
-    # (B, kv blocks, kv tile, query programs of the block, query tile)
-    q_side = pl.BlockSpec(
-        (None, bq, hp * d),
-        lambda n, c, kt, m, qt: (n, first_q(kt, qt), c * members + m),
-    )
-    kv_side = pl.BlockSpec(
-        (None, bk, kvb * d), lambda n, c, kt, m, qt: (n, kt, c)
-    )
-    dk, dv = pl.pallas_call(
-        kernel_of(_dkv_kernel),
-        grid=(b, h // hp // members, nk, members,
-              _sweep(mask, nq, nk, bq, bk, "q")),
-        in_specs=[
-            q_side, kv_side, kv_side,
-            pl.BlockSpec((None, 1, bk), lambda n, c, kt, m, qt: (n, 0, kt)),
-            pl.BlockSpec(
-                (hp, 1, bq),
-                lambda n, c, kt, m, qt: (
-                    n * hpb + c * members + m, 0, first_q(kt, qt)
-                ),
-            ),
-            q_side, q_side,
-            *selection(
-                lambda n, c, kt, m, qt: (n, first_q(kt, qt), kt)
-            ),
-        ],
-        out_specs=[kv_side, kv_side],
-        out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((bk, kvb * d), jnp.float32)] * 2,
-        name=_name("bwd_dkv", mask, d, d, group), **names,
-    )(*operands)
-    return dq, dk, dv
+        compiler_params=_bwd_vmem(q, k, d, dv, block_q, block_k,
+                                  sel is not None),
+    )(q, k, v, bias, lse, do, out, *(sel or ()))
 
 
 def _bwd_fused(q, k, v, bias, lse, do, out, d, dv, mask, interpret):

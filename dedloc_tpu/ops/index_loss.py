@@ -440,7 +440,7 @@ def _bwd_kernel(qi_ref, ki_ref, w_ref, q_ref, k_ref, lse_ref, stats_ref,
 def _geometry(q, block_q: int, block_k: int):
     b, s, _ = q.shape
     bq, bk = _pick_block(s, block_q), _pick_block(s, block_k)
-    return b, s, bq, bk, s // bq, _sweep(_MASK, s // bq, s // bk, bq, bk, "k")
+    return b, s, bq, bk, s // bq, _sweep(_MASK, s // bq, s // bk, bq, bk)
 
 
 def _in_specs(shape: _Shape, bq: int, bk: int):

@@ -230,7 +230,7 @@ def check_the_projections_that_feed_a_kernel_run_once(family, policy):
         w: n for w, n in after.items() if w not in expected
     }
     assert kernels_after == kernels_before
-    assert any(name.endswith("bwd_dq") for name in kernels_after)
+    assert any(name.endswith("bwd_tiled") for name in kernels_after)
     if family == "lfm2":
         assert kernels_after["short_conv_bwd"] == kernels_after[
             "short_conv_fwd"

@@ -237,14 +237,14 @@ def test_flat_apply_and_kernels_compile_for_a_v5e_in_seconds():
     rows = _tpu_aot("flat_apply_step", "kernels")
     assert rows["flat_apply_step"]["compile_s"] < 60
     assert rows["kernels"]["compile_s"] < 60
-    # flash fwd (x3), fused bwd (x2), dq, dkv, ln fwd, ln bwd
-    assert rows["kernels"]["tpu_custom_calls"] == 9
+    # flash fwd (x3), fused bwd (x2), the tiled bwd, ln fwd, ln bwd
+    assert rows["kernels"]["tpu_custom_calls"] == 8
     # one tile at (12, 512, 16 x 64) and, causal, at D=128; tiles at S=2,048
     assert rows["kernels"]["flash_fwd_forms"] == {"one_tile": 2, "tiles": 1}
     # D=64 (two heads a lane tile) and D=128: a head's window is its block
     assert set(rows["kernels"]["flash_windows"]) == {
         "flash_fwd", "flash_bwd_fused", "flash_causal_fwd",
-        "flash_causal_bwd_fused", "flash_bwd_dq", "flash_bwd_dkv",
+        "flash_causal_bwd_fused", "flash_bwd_tiled",
     }
     # ("block": no window metadata on the call, the parent's programs)
     assert set(rows["kernels"]["flash_windows"].values()) == {"block"}

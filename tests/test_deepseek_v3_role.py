@@ -151,8 +151,7 @@ def test_two_width_kernels_contract_over_a_heads_own_lane_tiles():
     }
     for row in rows.values():
         assert row["flash_windows"] == {
-            "flash_mla_fwd": windowed, "flash_mla_bwd_dq": windowed,
-            "flash_mla_bwd_dkv": windowed,
+            "flash_mla_fwd": windowed, "flash_mla_bwd_tiled": windowed,
         }
     # the dense layer and the scanned expert layers: a forward site each
     assert rows["kanana_accumulate_step"]["flash_fwd_forms"] == {

@@ -42,7 +42,7 @@ def test_forward_with_mask_bias(rng):
 def test_gradients_match_dense(rng, block_q, block_k):
     # (64, 64) covers the whole sequence per tile -> the FUSED single-kernel
     # backward (_dqkv_fused_kernel), the path production seq-512 training
-    # takes with the default block sizes; (32, 16) covers the two-kernel path
+    # takes with the default block sizes; (32, 16) covers the tiled (one-sweep) path
     q, k, v = _qkv(rng, b=1, s=64, h=2, d=16)
     bias = jnp.zeros((1, 64))
 
@@ -138,7 +138,7 @@ def test_model_layout_matches_dense(rng, h, d, block, masked):
     whole width where the heads do not fill or divide into 128-lane blocks
     ((4, 16): 64 lanes; (3, 64): 192). Forward and all three gradients
     against dense attention; block 64 covers S (the fused backward), 32
-    takes the two-kernel one."""
+    takes the tiled one."""
     b, s = 2, 64
     q, k, v = _qkv(rng, b=b, s=s, h=h, d=d)
     bias = None
@@ -217,7 +217,7 @@ def test_forward_form_follows_the_shapes(rng):
     alone: one tile -> the one-tile forward beside the fused backward (a
     lowering carries the form in the kernel's metadata, which
     tools/tpu_aot.py counts); several tiles -> the online-softmax kernel
-    beside the two-kernel backward. Both forms keep the kernel's name."""
+    beside the one-sweep backward. Both forms keep the kernel's name."""
     q, k, v = _qkv(rng, b=1, s=64, h=2, d=64)
 
     def traced(block):
@@ -228,7 +228,8 @@ def test_forward_form_follows_the_shapes(rng):
     one_tile, tiles = traced(64), traced(32)
     assert "flash_fwd" in one_tile and "flash_fwd" in tiles
     assert "one_tile" in one_tile and "flash_bwd_fused" in one_tile
-    assert "one_tile" not in tiles and "flash_bwd_dq" in tiles
+    assert "one_tile" not in tiles and "flash_bwd_tiled" in tiles
+    assert "bwd_dq" not in tiles and "bwd_dkv" not in tiles
 
 
 def test_under_a_mesh_matches_one_device(rng):

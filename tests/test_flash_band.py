@@ -121,22 +121,21 @@ def test_band_calls_keep_their_own_names_and_a_short_sweep():
     the metadata; the inner grid axis is the band's tiles (2 of 4 at a band
     of one block), not the sequence's; a group of seven is one program."""
     found = _kernels(28, 4, 128, 32, seq=128)
-    assert sorted(found) == ["flash_band_bwd_dkv", "flash_band_bwd_dq",
-                             "flash_band_fwd"]
+    assert sorted(found) == ["flash_band_bwd_tiled", "flash_band_fwd"]
     grid, metadata = found["flash_band_fwd"]
     assert grid == (1, 4, 4, 2)  # 4 groups of 7 heads, 4 query tiles, 2 keys
     assert metadata == {"heads": 28, "kv_heads": 4, "band": 32}
-    assert found["flash_band_bwd_dq"][0] == (1, 4, 4, 2)
-    assert found["flash_band_bwd_dkv"][0] == (1, 4, 4, 1, 2)
+    # ONE backward kernel on the forward's walk: (B, kv blocks, the block's
+    # query programs, query tiles, key steps)
+    assert found["flash_band_bwd_tiled"][0] == (1, 4, 1, 4, 2)
     # a band the sequence is no longer than: the causal kernels, as they were
     causal = _kernels(28, 4, 128, 128, seq=128)
-    assert sorted(causal) == ["flash_gqa_bwd_dkv", "flash_gqa_bwd_dq",
-                              "flash_gqa_fwd"]
+    assert sorted(causal) == ["flash_gqa_bwd_tiled", "flash_gqa_fwd"]
     assert causal["flash_gqa_fwd"] == (
         (1, 4, 4, 4), {"heads": 28, "kv_heads": 4}
     )
     assert sorted(_kernels(4, 4, 128, 8)) == [
-        "flash_band_bwd_dkv", "flash_band_bwd_dq", "flash_band_fwd"
+        "flash_band_bwd_tiled", "flash_band_fwd"
     ]
 
 
@@ -149,11 +148,14 @@ def test_visited_tiles():
     assert visited_tiles(8192, 512, 512, False) == 256
     assert visited_tiles(128, 32, 32, True, band=8) == 7
     mask = fa._Mask(True, 4096)
-    assert fa._sweep(mask, 32, 32, 512, 512, "k") == 9
-    assert fa._sweep(mask, 32, 32, 512, 512, "q") == 9
+    assert fa._sweep(mask, 32, 32, 512, 512) == 9
     assert fa._first_k_tile(mask, 9, 512, 512) == 1
-    assert fa._last_q_tile(mask, 0, 512, 512, 32) == 8
-    assert fa._last_q_tile(mask, 30, 512, 512, 32) == 31
+    # the backward's sweep is the forward's: query tile 30 starts at key
+    # tile 22 and a step past its last names tile 30 again
+    assert [int(fa._k_tile(mask, 30, step, 512, 512)) for step in (0, 8)] == [
+        22, 30
+    ]
+    assert int(fa._k_tile(mask, 3, 8, 512, 512)) == 3
 
 
 @pytest.mark.parametrize("kwargs", [dict(causal=False, band=8),
@@ -204,8 +206,7 @@ def test_a_band_equal_to_the_tile_visits_two_crossed_tiles():
     assert visited_tiles(8192, 512, 512, True, band=512) == 31
     assert visited_tiles(8192, 512, 512, True) == 136
     mask = fa._Mask(True, 512)
-    assert fa._sweep(mask, 16, 16, 512, 512, "k") == 2
-    assert fa._sweep(mask, 16, 16, 512, 512, "q") == 2
+    assert fa._sweep(mask, 16, 16, 512, 512) == 2
     assert [fa._first_k_tile(mask, qi, 512, 512) for qi in (0, 1, 9)] == [
         0, 0, 8
     ]

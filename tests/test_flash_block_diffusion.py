@@ -150,18 +150,17 @@ def _kernels(h, kv, d, length, block, tile):
 def test_block_diffusion_calls_keep_their_own_names_and_sweeps():
     """``flash_bd_*`` in a device trace, the blocks and the head counts in
     the metadata; a query tile's sweep is its stream's tiles + its noisy
-    diagonal one (n + 1), a key tile's both runs of query tiles (2n)."""
+    diagonal one (n + 1), forward and backward: the ONE backward kernel
+    walks the forward's grid under one more axis, a kv block's programs."""
     found = _kernels(32, 4, 128, 128, 4, 32)
-    assert sorted(found) == ["flash_bd_bwd_dkv", "flash_bd_bwd_dq",
-                             "flash_bd_fwd"]
+    assert sorted(found) == ["flash_bd_bwd_tiled", "flash_bd_fwd"]
     grid, metadata = found["flash_bd_fwd"]
     assert grid == (1, 4, 8, 5)  # 4 programs of 8 heads, 8 query tiles
     assert metadata == {"heads": 32, "kv_heads": 4, "block": 4,
                         "stream": 128}
-    assert found["flash_bd_bwd_dq"][0] == (1, 4, 8, 5)
-    assert found["flash_bd_bwd_dkv"][0][2:] == (8, 1, 8)
+    assert found["flash_bd_bwd_tiled"][0] == (1, 4, 1, 8, 5)
     assert sorted(_kernels(4, 4, 128, 64, 4, 32)) == [
-        "flash_bd_bwd_dkv", "flash_bd_bwd_dq", "flash_bd_fwd"
+        "flash_bd_bwd_tiled", "flash_bd_fwd"
     ]
 
 

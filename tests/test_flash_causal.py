@@ -88,7 +88,7 @@ def test_causal_is_a_mode_with_kernel_names_of_its_own():
         ))))(q))
 
     plain, causal = traced(False), traced(True)
-    for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
+    for kernel in ("fwd", "bwd_tiled"):
         assert f"flash_causal_{kernel}" in causal
         assert f"flash_causal_{kernel}" not in plain
         assert f"flash_{kernel}" in plain
@@ -103,12 +103,16 @@ def test_causal_is_a_mode_with_kernel_names_of_its_own():
 def test_causal_tile_bookkeeping():
     """Which tiles a causal grid visits, by hand: with 4 query tiles of 32
     and 2 key tiles of 64, query tile j needs key tiles 0..(32j+31)//64 =
-    0, 0, 1, 1; key tile 1 is first seen by query tile 64//32 = 2."""
-    from dedloc_tpu.ops.flash_attention import _first_q_tile, _last_k_tile
+    0, 0, 1, 1; a step of the sweep past a query tile's last key tile names
+    that tile again (the index maps' answer, and the rows of dk / dv the
+    one-sweep backward would add to: the step runs no body)."""
+    from dedloc_tpu.ops.flash_attention import _k_tile, _last_k_tile, _Mask
 
     assert [_last_k_tile(j, 32, 64) for j in range(4)] == [0, 0, 1, 1]
-    assert [_first_q_tile(i, 32, 64) for i in range(2)] == [0, 2]
+    assert [[int(_k_tile(_Mask(True), j, step, 32, 64)) for step in range(2)]
+            for j in range(4)] == [[0, 0], [0, 0], [0, 1], [0, 1]]
     assert [_last_k_tile(j, 64, 32) for j in range(2)] == [1, 3]
-    assert [_first_q_tile(i, 64, 32) for i in range(4)] == [0, 0, 1, 1]
+    assert [int(_k_tile(_Mask(True), 0, step, 64, 32))
+            for step in range(4)] == [0, 1, 1, 1]
     # equal tiles: the diagonal
     assert [_last_k_tile(j, 512, 512) for j in range(8)] == list(range(8))

@@ -138,9 +138,9 @@ def _names(h, kv, causal=True):
     text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, k))
     return {
         name for name in (
-            "flash_causal_fwd", "flash_causal_bwd_dq", "flash_causal_bwd_dkv",
-            "flash_gqa_fwd", "flash_gqa_bwd_dq", "flash_gqa_bwd_dkv",
-            "flash_gqa_full_fwd",
+            "flash_causal_fwd", "flash_causal_bwd_tiled",
+            "flash_gqa_fwd", "flash_gqa_bwd_tiled", "flash_gqa_full_fwd",
+            "bwd_dq", "bwd_dkv",
         ) if name in text
     }, text
 
@@ -150,13 +150,12 @@ def test_kernel_names_and_metadata_follow_the_head_counts():
     roofline metrics read) and carry their head counts; a call with as many
     kv heads as heads stays ``flash_causal_*`` and carries nothing."""
     names, text = _names(8, 2)
-    assert {"flash_gqa_fwd", "flash_gqa_bwd_dq", "flash_gqa_bwd_dkv"} <= names
-    assert "flash_causal_fwd" not in names
+    # ONE backward kernel a tiled call: no dq / dkv pair under any name
+    assert names == {"flash_gqa_fwd", "flash_gqa_bwd_tiled"}
     assert "kv_heads" in text
     names, text = _names(8, 8)
-    assert {"flash_causal_fwd", "flash_causal_bwd_dq",
-            "flash_causal_bwd_dkv"} <= names
-    assert "flash_gqa_fwd" not in names and "kv_heads" not in text
+    assert names == {"flash_causal_fwd", "flash_causal_bwd_tiled"}
+    assert "kv_heads" not in text
     assert "flash_gqa_full_fwd" in _names(8, 2, causal=False)[0]
 
 

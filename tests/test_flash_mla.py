@@ -199,9 +199,9 @@ def _kernel_names(d, dv, s, block):
     text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, q, v))
     return {
         name for name in (
-            "flash_causal_fwd", "flash_causal_bwd_dq", "flash_causal_bwd_dkv",
-            "flash_causal_bwd_fused", "flash_mla_fwd", "flash_mla_bwd_dq",
-            "flash_mla_bwd_dkv", "flash_mla_bwd_fused",
+            "flash_causal_fwd", "flash_causal_bwd_tiled",
+            "flash_causal_bwd_fused", "flash_mla_fwd", "flash_mla_bwd_tiled",
+            "flash_mla_bwd_fused", "bwd_dq", "bwd_dkv",
         ) if name in text
     }
 
@@ -211,11 +211,11 @@ def test_kernel_names_follow_the_widths():
     the accepted roofline metrics read); two widths get names of their own
     (``flash_mla_*``)."""
     assert _kernel_names(16, 16, 64, 32) == {
-        "flash_causal_fwd", "flash_causal_bwd_dq", "flash_causal_bwd_dkv"
+        "flash_causal_fwd", "flash_causal_bwd_tiled"
     }
     assert _kernel_names(16, 16, 64, 64) == {
         "flash_causal_fwd", "flash_causal_bwd_fused"
     }
     assert _kernel_names(24, 16, 64, 32) == {
-        "flash_mla_fwd", "flash_mla_bwd_dq", "flash_mla_bwd_dkv"
+        "flash_mla_fwd", "flash_mla_bwd_tiled"
     }

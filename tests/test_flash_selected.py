@@ -133,7 +133,7 @@ def test_tile_flags_names_and_the_backwards_selection(monkeypatch):
     )
     vjp((do, jnp.zeros((1, 8, S))))
     assert [name for name, _ in calls] == [
-        "flash_sel_fwd", "flash_sel_bwd_dq", "flash_sel_bwd_dkv"
+        "flash_sel_fwd", "flash_sel_bwd_tiled"
     ]
     (selection, tile_flags) = calls[0][1]
     assert selection.dtype == jnp.int8 and tile_flags.shape == (9,)

@@ -153,20 +153,19 @@ def test_keye_accumulate_step_reads_a_selection_and_holds_nothing_heads_by_s_by_
     heads = {"heads": 32, "kv_heads": 4}
     for row in rows.values():
         assert row["flash_windows"] == {
-            "flash_sel_fwd": heads, "flash_sel_bwd_dq": heads,
-            "flash_sel_bwd_dkv": heads,
+            "flash_sel_fwd": heads, "flash_sel_bwd_tiled": heads,
         }
     assert rows["sel_kernels"]["kernel_calls"] == {
-        "flash_sel_fwd": 1, "flash_sel_bwd_dq": 1, "flash_sel_bwd_dkv": 1,
+        "flash_sel_fwd": 1, "flash_sel_bwd_tiled": 1,
     }
     row = rows["keye_accumulate_step"]
     # ... and the loss's pair: one forward sweep a layer (its logZ rides in
     # a Pallas output, which the policy keeps: no replay), one backward
     assert row["kernel_calls"] == {
-        "flash_sel_fwd": 4, "flash_sel_bwd_dq": 4, "flash_sel_bwd_dkv": 4,
+        "flash_sel_fwd": 4, "flash_sel_bwd_tiled": 4,
         "index_loss_fwd": 4, "index_loss_bwd": 4, "index_select": 4,
     }
-    assert row["tpu_custom_calls"] == 24
+    assert row["tpu_custom_calls"] == 20
     # no float32 [128, 16, 16384] index scores, no [4, 8, 128, 16384] main
     # scores, no selection cut into the loss's blocks of 128 rows — what the
     # XLA block loop made, 128 blocks a layer and direction (PR 51) — in the

@@ -259,9 +259,9 @@ def test_forward_kernel_call_sites_in_the_gradient(policy, forward_calls):
     fn, params = _flash_value_and_grad(policy)
     jaxpr = str(jax.make_jaxpr(fn)(params))  # traced, not run
     assert _pallas_calls(jaxpr, "flash_causal_fwd") == forward_calls
-    # the two-kernel backward runs once under either policy
-    assert _pallas_calls(jaxpr, "flash_causal_bwd_dq") == 1
-    assert _pallas_calls(jaxpr, "flash_causal_bwd_dkv") == 1
+    # the one-sweep backward runs once under either policy
+    assert _pallas_calls(jaxpr, "flash_causal_bwd_tiled") == 1
+    assert "bwd_dq" not in jaxpr and "bwd_dkv" not in jaxpr
 
 
 def test_default_policy_is_a_table_entry_and_unknown_names_raise():

@@ -52,20 +52,19 @@ def test_laguna_accumulate_step_takes_a_band_equal_to_the_tile_and_a_group_of_si
     full = {"heads": 48, "kv_heads": 8}
     for name in ("laguna_kernels", "laguna_accumulate_step"):
         assert rows[name]["flash_windows"] == {
-            "flash_band_fwd": band, "flash_band_bwd_dq": band,
-            "flash_band_bwd_dkv": band, "flash_gqa_fwd": full,
-            "flash_gqa_bwd_dq": full, "flash_gqa_bwd_dkv": full,
+            "flash_band_fwd": band, "flash_band_bwd_tiled": band,
+            "flash_gqa_fwd": full, "flash_gqa_bwd_tiled": full,
         }
     assert rows["head_gate_kernels"]["kernel_calls"] == {
         "head_gate_fwd": 2, "head_gate_bwd": 2,  # 64 heads, 48 heads
     }
     row = rows["laguna_accumulate_step"]
     assert row["kernel_calls"] == {
-        "flash_band_fwd": 3, "flash_band_bwd_dq": 3, "flash_band_bwd_dkv": 3,
-        "flash_gqa_fwd": 2, "flash_gqa_bwd_dq": 2, "flash_gqa_bwd_dkv": 2,
+        "flash_band_fwd": 3, "flash_band_bwd_tiled": 3,
+        "flash_gqa_fwd": 2, "flash_gqa_bwd_tiled": 2,
         "head_gate_fwd": 10, "head_gate_bwd": 5,
     }
-    assert row["tpu_custom_calls"] == 30
+    assert row["tpu_custom_calls"] == 25
     assert row["flash_fwd_forms"] == {"one_tile": 0, "tiles": 5}
     assert row["expert_grad_passes"] == {
         "adds": 0, "zero_fills": 0, "tile_loops": 16, "fused_adds": 24,

@@ -36,22 +36,21 @@ def test_lfm2_accumulate_step_keeps_what_its_backward_reads():
     at 8 heads beside q's 32 (their metadata says so; no window metadata);
     under remat ``kernel_outputs`` every kernel's outputs are kept, so each
     forward kernel has ONE call site per mixer and the backward replays
-    none — short_conv 4 + 4, flash_gqa 1 + 1 + 1; and the program's scratch
+    none — short_conv 4 + 4, flash_gqa 1 + 1 (ONE backward kernel); and the program's scratch
     beside 28 bytes a parameter of state with a draining snapshot (13.14 GB)
     stays under the allocator's 16.91 GB with 1 GB to spare."""
     rows = tpu_aot("gqa_kernels", "lfm2_accumulate_step")
     heads = {"heads": 32, "kv_heads": 8}
     for row in rows.values():
         assert row["flash_windows"] == {
-            "flash_gqa_fwd": heads, "flash_gqa_bwd_dq": heads,
-            "flash_gqa_bwd_dkv": heads,
+            "flash_gqa_fwd": heads, "flash_gqa_bwd_tiled": heads,
         }
     row = rows["lfm2_accumulate_step"]
     assert row["kernel_calls"] == {
-        "flash_gqa_fwd": 1, "flash_gqa_bwd_dq": 1, "flash_gqa_bwd_dkv": 1,
+        "flash_gqa_fwd": 1, "flash_gqa_bwd_tiled": 1,
         "short_conv_fwd": 4, "short_conv_bwd": 4,
     }
-    assert row["tpu_custom_calls"] == 11
+    assert row["tpu_custom_calls"] == 10
     assert row["flash_fwd_forms"] == {"one_tile": 0, "tiles": 1}
     assert 469_285_248 * 28 + row["memory"]["temp_bytes"] <= 15.9e9
     # gradient sinks (PR 33): the tile loops' backward starts from the

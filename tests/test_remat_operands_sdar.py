@@ -47,14 +47,13 @@ def test_sdar_accumulate_step_takes_the_block_rule_and_a_group_of_eight():
     blocks = {"heads": 32, "kv_heads": 4, "block": 4, "stream": 4096}
     for row in rows.values():
         assert row["flash_windows"] == {
-            "flash_bd_fwd": blocks, "flash_bd_bwd_dq": blocks,
-            "flash_bd_bwd_dkv": blocks,
+            "flash_bd_fwd": blocks, "flash_bd_bwd_tiled": blocks,
         }
     row = rows["sdar_accumulate_step"]
     assert row["kernel_calls"] == {
-        "flash_bd_fwd": 4, "flash_bd_bwd_dq": 4, "flash_bd_bwd_dkv": 4,
+        "flash_bd_fwd": 4, "flash_bd_bwd_tiled": 4,
     }
-    assert row["tpu_custom_calls"] == 12
+    assert row["tpu_custom_calls"] == 8
     assert row["flash_fwd_forms"] == {"one_tile": 0, "tiles": 4}
     # … and the walk's loops (PR 42): four routed layers x two directions x
     # the bulk and the tail loop (8 loops with the single-size walk),

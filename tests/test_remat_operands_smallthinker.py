@@ -48,16 +48,15 @@ def test_smallthinker_accumulate_step_takes_the_band_and_a_group_of_seven():
     band = dict(heads, band=4096)
     for row in rows.values():
         assert row["flash_windows"] == {
-            "flash_band_fwd": band, "flash_band_bwd_dq": band,
-            "flash_band_bwd_dkv": band, "flash_gqa_fwd": heads,
-            "flash_gqa_bwd_dq": heads, "flash_gqa_bwd_dkv": heads,
+            "flash_band_fwd": band, "flash_band_bwd_tiled": band,
+            "flash_gqa_fwd": heads, "flash_gqa_bwd_tiled": heads,
         }
     row = rows["smallthinker_accumulate_step"]
     assert row["kernel_calls"] == {
-        "flash_band_fwd": 3, "flash_band_bwd_dq": 3, "flash_band_bwd_dkv": 3,
-        "flash_gqa_fwd": 1, "flash_gqa_bwd_dq": 1, "flash_gqa_bwd_dkv": 1,
+        "flash_band_fwd": 3, "flash_band_bwd_tiled": 3,
+        "flash_gqa_fwd": 1, "flash_gqa_bwd_tiled": 1,
     }
-    assert row["tpu_custom_calls"] == 12
+    assert row["tpu_custom_calls"] == 8
     assert row["flash_fwd_forms"] == {"one_tile": 0, "tiles": 4}
     # … and the walk's loops (PR 42): four routed layers x two directions x
     # the bulk and the tail loop (8 loops with the single-size walk),

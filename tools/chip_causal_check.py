@@ -25,10 +25,25 @@ import jax.numpy as jnp
 
 from benchmark.flops_lm import causal_kernel_cost
 from dedloc_tpu.ops.flash_attention import flash_attention
-from tools.chip_gqa_check import device_times, traced_ops
+from tools.chip_gqa_check import (
+    bwd_vmem_mb,
+    device_times,
+    kernel_cost,
+    traced_ops,
+)
 
 B, S, H, D = 1, 4096, 16, 128
-KERNELS = ("flash_causal_fwd", "flash_causal_bwd_dq", "flash_causal_bwd_dkv")
+KERNELS = ("flash_causal_fwd", "flash_causal_bwd_tiled")
+
+
+def causal_cost(kernel: str):
+    """(FLOPs, bytes) of one call of ``kernel``; the one-sweep backward's
+    from the accepted three (``chip_gqa_check.kernel_cost``)."""
+    return kernel_cost(
+        lambda part: causal_kernel_cost(
+            f"flash_causal_{part}", B, H, S, D, 512, 512
+        ), kernel.removeprefix("flash_causal_"),
+    )
 
 
 def dense(q, k, v):
@@ -77,11 +92,11 @@ def main() -> int:
         "device": jax.devices()[0].device_kind, "shape": [B, S, H, D],
         "relative_l2": errors,
         "fwd_plus_bwd_wall_ms": wall_ms,
+        "bwd_vmem_mb": bwd_vmem_mb(q, k, v),
         "kernels": device_times(
             traced_ops(lambda: flash(bf(q), bf(k), bf(v))), {
-                kernel: (lambda _on_chip, kernel=kernel: causal_kernel_cost(
-                    kernel, B, H, S, D, 512, 512
-                )) for kernel in KERNELS
+                kernel: (lambda _on_chip, kernel=kernel: causal_cost(kernel))
+                for kernel in KERNELS
             },
         ),
     }))

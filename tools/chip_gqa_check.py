@@ -7,7 +7,9 @@ float32 reference at matmul precision 'highest'; then each kernel's DEVICE
 time per call with its share of the roofline, from a profiler window over
 the same calls, read as the benchmark reads its ``flash_gqa_*_roofline`` and
 ``short_conv_*_roofline`` metrics (``benchmark/trace.py``,
-``benchmark/flops_lfm2.py``).
+``benchmark/flops_lfm2.py``; the ONE backward kernel, ``*_bwd_tiled``, by
+``kernel_cost``: 5 matmuls a tile), and the scoped VMEM that backward asks
+for (``bwd_vmem_mb``: it holds dk and dv for the whole sequence).
 
     chiprun --chips 1 -- python tools/chip_gqa_check.py
 
@@ -108,7 +110,7 @@ from dedloc_tpu.ops.head_gate import gate_heads, gate_heads_xla
 from dedloc_tpu.ops.short_conv import short_conv, short_conv_reference
 
 B, HIDDEN = 1, 2048
-KERNELS = ("fwd", "bwd_dq", "bwd_dkv")
+KERNELS = ("fwd", "bwd_tiled")
 CONV = ("short_conv_fwd", "short_conv_bwd")
 # the gate's kernels: [B, S, H·D] bf16 arrays read + written a call, beside
 # the float32 gate [B, S, H] once each way
@@ -154,10 +156,47 @@ class Selected:
         return f"{self.how}_{self.topk}"
 
 
+def kernel_cost(cost, kernel: str):
+    """(FLOPs, bytes) of one call of ``kernel`` ("fwd" | "bwd_tiled") from
+    the accepted cost functions (``cost(kernel)`` for "fwd", "bwd_dq",
+    "bwd_dkv"; the benchmark's files have no row for the one sweep yet,
+    ROADMAP A12 (13)): the sweep makes the dq and the dkv kernel's products
+    less the QK^T and dP it no longer repeats — the forward's two matmuls at
+    the same widths — so 3 + 4 - 2 = 5 a tile; its bytes are at least the
+    larger of the pair's (it reads what dq read and writes what both
+    wrote), which no call's roofline turns on: the MXU bounds them all."""
+    if kernel != "bwd_tiled":
+        return cost(kernel)
+    (fwd, _), (dq, dq_bytes), (dkv, dkv_bytes) = (
+        cost(kernel) for kernel in ("fwd", "bwd_dq", "bwd_dkv")
+    )
+    return dq + dkv - fwd, max(dq_bytes, dkv_bytes)
+
+
+def bwd_vmem_mb(q, k, v, block_k: int = 512, selected: bool = False):
+    """MiB of scoped VMEM the tiled backward of a call on [B, S, H, D]
+    operands asks for (None: the compiler's own 16), and of them what holds
+    dk and dv for the whole sequence."""
+    fa = importlib.import_module("dedloc_tpu.ops.flash_attention")
+    d, dv = q.shape[-1], v.shape[-1]
+    flat = [jax.ShapeDtypeStruct((*x.shape[:2], x.shape[2] * x.shape[3]),
+                                 jnp.bfloat16) for x in (q, k)]
+    asked = fa._bwd_vmem(*flat, d, dv, 512, block_k, selected)
+    kvb = fa._bwd_geometry(*flat, d, dv, 512, block_k)[-1]
+    return {
+        "asked": asked and asked.vmem_limit_bytes / 2**20,
+        "resident": sum(fa._bwd_resident(q.shape[1], kvb, d, dv, 2)) / 2**20,
+    }
+
+
 def attention_cost(kernel: str, shape, band, block_k: int = 512):
     """(FLOPs, bytes) of one ``flash_gqa_*`` / ``flash_band_*`` /
-    ``flash_bd_*`` call at query tiles of 512 and key tiles of
-    ``block_k``."""
+    ``flash_bd_*`` / ``flash_sel_*`` call at query tiles of 512 and key
+    tiles of ``block_k``."""
+    if kernel == "bwd_tiled":
+        return kernel_cost(
+            lambda part: attention_cost(part, shape, band, block_k), kernel
+        )
     s, h, kv, d = shape
     if isinstance(band, Selected):
         return sel_kernel_cost(
@@ -353,7 +392,7 @@ def main(argv=None) -> int:
             )
         return flash
 
-    errors, kernels = {}, {}
+    errors, kernels, vmem = {}, {}, {}
     for band, block_k in itertools.product(bands, opts.block_k):
         tag = "causal" if band is None else f"band_{band}"
         family = "flash_gqa" if band is None else "flash_band"
@@ -364,6 +403,7 @@ def main(argv=None) -> int:
         if block_k != 512:
             tag += f".bk{block_k}"
         flash = flash_at(block_k)
+        vmem[tag] = bwd_vmem_mb(q, k, v, block_k, isinstance(band, Selected))
         step = attention(flash, band)
         (_, out), grads = step(bf(q), bf(k), bf(v))
         (_, ref_out), ref_grads = attention(dense, band)(r(q), r(k), r(v))
@@ -496,7 +536,8 @@ def main(argv=None) -> int:
     print(json.dumps({
         "device": jax.devices()[0].device_kind,
         "shape": {"attention": [B, S, H, KV, D], "conv": [B, S, 3 * HIDDEN]},
-        "relative_l2": errors, "kernels": kernels, **extra,
+        "relative_l2": errors, "kernels": kernels, "bwd_vmem_mb": vmem,
+        **extra,
     }))
     exact = errors.get("gate.out_bits_differ", 0.0) == 0.0
     return 0 if exact and max(errors.values(), default=0.0) <= 0.02 else 1

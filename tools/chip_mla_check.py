@@ -30,9 +30,21 @@ from benchmark.flops_moe import mla_kernel_cost
 from benchmark.peaks import chip_peaks
 from benchmark.trace import OPS, load_xplane, op_name
 from dedloc_tpu.ops.flash_attention import flash_attention
+from tools.chip_gqa_check import bwd_vmem_mb, kernel_cost
 
 B, S, H, D, DV = 1, 4096, 32, 192, 128
-KERNELS = ("flash_mla_fwd", "flash_mla_bwd_dq", "flash_mla_bwd_dkv")
+KERNELS = ("flash_mla_fwd", "flash_mla_bwd_tiled")
+
+
+def mla_cost(kernel: str):
+    """(FLOPs, bytes) of one call of ``kernel`` at the heads' own widths;
+    the one-sweep backward's from the accepted three
+    (``chip_gqa_check.kernel_cost``)."""
+    return kernel_cost(
+        lambda part: mla_kernel_cost(
+            f"flash_mla_{part}", B, H, S, D, DV, 512, 512
+        ), kernel.removeprefix("flash_mla_"),
+    )
 
 
 def device_times(run, calls: int = 10) -> dict:
@@ -46,7 +58,7 @@ def device_times(run, calls: int = 10) -> dict:
             jax.block_until_ready(result)
         trace = load_xplane(trace_dir)
     # under a plain jit(grad) the trace names a kernel's op by JAX's name
-    # stack around the kernel's name (``transpose_jvp_flash_mla_bwd_dq__``;
+    # stack around the kernel's name (``transpose_jvp_flash_mla_bwd_tiled__``;
     # inside the trainer's custom-VJP + remat it is the bare name)
     ops = [
         (op_name(name), duration / 1e9) for lines in trace.values()
@@ -60,9 +72,7 @@ def device_times(run, calls: int = 10) -> dict:
         seconds = [d for name, d in ops if kernel in name]
         if not seconds:
             continue
-        least, _which = roofline_seconds(
-            *mla_kernel_cost(kernel, B, H, S, D, DV, 512, 512), peaks
-        )
+        least, _which = roofline_seconds(*mla_cost(kernel), peaks)
         median = statistics.median(seconds)
         out[kernel] = {
             "calls": len(seconds), "device_ms": median * 1e3,
@@ -123,6 +133,7 @@ def main() -> int:
         "device": jax.devices()[0].device_kind, "shape": [B, S, H, D, DV],
         "relative_l2": errors,
         "fwd_plus_bwd_wall_ms": wall_ms,
+        "bwd_vmem_mb": bwd_vmem_mb(q, k, v),
         "kernels": device_times(lambda: flash(bf(q), bf(k), bf(v))),
     }))
     return 0 if max(errors.values()) <= 0.02 else 1

@@ -12,7 +12,7 @@ speed or numerics; those need the chip (``chip_smoke.py``).
     OURO_LAYERS=4 OURO_BATCH=1 python tools/tpu_aot.py ouro_accumulate_step
 
 Each line: {"program", "compile_s", "tpu_custom_calls", "flash_fwd_forms",
-"flash_windows", "layer_body_copies", "memory"} (and, for the programs of
+"flash_windows", "flash_vmem_mb", "layer_body_copies", "memory"} (and, for the programs of
 ``COUNT_KERNEL_CALLS``, "kernel_calls": call sites by kernel name; for the
 expert programs, "expert_grad_passes": ``expert_grad_passes``' counts; for
 the seven decoder programs of ``LM_CELLS``, "remat_policy": the layer policy
@@ -31,7 +31,9 @@ compiled module — [] since the indexer's loss is a kernel pair, PR 52 — and
 ``flash_windows`` is each flash kernel's lane window beside its column
 block, from the call's metadata (``"block"`` for a call that carries none:
 D=64, D=128; its head counts for a grouped-query call);
-``flash_fwd_forms`` counts the flash
+``flash_vmem_mb`` is the scoped VMEM a flash kernel asks for beyond the
+compiler's own 16 MiB (the tiled backward holds dk and dv for the whole
+sequence there, PR 56); ``flash_fwd_forms`` counts the flash
 forward call SITES of the lowered module by the form their shapes chose
 (``one_tile``: one tile covers the sequence; ``tiles``: the online-softmax
 kernel). A scanned layer body is one site however often it runs, and a
@@ -169,7 +171,7 @@ def kernels(device):
     """Every Pallas kernel, fwd+bwd, in one program: flash attention at the
     recipe shape (one tile covers S=512: the one-tile forward and the fused
     backward), at one causal tile of D=128 (one head per column block), and
-    at S=2048 (several tiles: the online-softmax forward and the two-kernel
+    at S=2048 (several tiles: the online-softmax forward and the one-sweep
     backward every long-sequence run takes); the fused add+LayerNorm at the
     recipe's 6,144 x 1,024 rows."""
     from dedloc_tpu.ops.flash_attention import flash_attention
@@ -261,7 +263,7 @@ def bd_kernels(device):
     over 4 kv heads of 128 (a whole group of eight a program), 2 x 4,096
     positions (a noisy then a clean stream) in 16 x 16 tiles of 512 under
     the two-stream rule with blocks of 4 — 80 tiles visited, a sweep of 9
-    key tiles a query tile and 16 query tiles a key tile — fwd+bwd."""
+    key tiles a query tile, forward and backward — fwd+bwd."""
     from dedloc_tpu.ops.flash_attention import flash_attention
 
     def loss(q, k, v):
@@ -650,6 +652,30 @@ def flash_windows(lowered_text: str) -> dict:
     }
 
 
+def flash_vmem_mb(lowered_text: str) -> dict:
+    """MiB of scoped VMEM each flash kernel of a lowered module asks for
+    (its custom call's ``scoped_memory_configs``), by kernel name; a kernel
+    that runs under the compiler's own limit (16 on a v5e) is left out. The
+    tiled backward holds a kv block's dk and dv for the whole sequence
+    there (``ops/flash_attention._bwd_vmem``): 49.5 at the Keye cell's
+    shape, 76.75 for SmallThinker's group of seven."""
+    found = {}
+    for line in lowered_text.splitlines():
+        name = re.search(r'kernel_name = "(flash_\w+)"', line)
+        size = re.search(
+            r'scoped_memory_configs\\22: \[\{[^}]*\\22size\\22: (\d+)', line
+        )
+        if name and size:
+            sizes = found.setdefault(name.group(1), [])
+            mb = round(int(size.group(1)) / 2**20, 2)
+            if mb not in sizes:
+                sizes.append(mb)
+    return {
+        name: sizes[0] if len(sizes) == 1 else sizes
+        for name, sizes in sorted(found.items())
+    }
+
+
 def kernel_calls(lowered_text: str) -> dict:
     """Call SITES of every Pallas kernel of a lowered module, by kernel
     name: which kernels a remat policy replays (a replay is a second site of
@@ -958,6 +984,7 @@ def main(argv=None) -> int:
             "tpu_custom_calls": lowered_text.count("tpu_custom_call"),
             "flash_fwd_forms": flash_fwd_forms(lowered_text),
             "flash_windows": flash_windows(lowered_text),
+            "flash_vmem_mb": flash_vmem_mb(lowered_text),
             "layer_body_copies": layer_body_copies(compiled_text),
             "memory": {
                 "argument_bytes": memory.argument_size_in_bytes,
