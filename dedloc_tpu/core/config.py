@@ -301,7 +301,10 @@ class TrainingArguments:
     # models/keye_vl2.py); kimi_linear_tiny | kimi_linear_48b_a3b (Kimi
     # Delta Attention — a gated delta rule with a decay per channel,
     # ops/kda.py — in three layers of four beside latent attention without
-    # RoPE, models/kimi_linear.py) —
+    # RoPE, models/kimi_linear.py); nemotron_h_tiny | nemotron3_nano_30b_a3b
+    # (layers that are ONE sublayer each: a Mamba-2 mixer — ops/ssd.py —,
+    # NoPE grouped attention or un-gated relu² experts beside a shared one,
+    # models/nemotron_h.py) —
     # roles/common.MODEL_FAMILIES is the table
     model_size: str = "large"
     # depth override (0 = the model's own): a chip's share of a deeper
@@ -318,9 +321,12 @@ class TrainingArguments:
     expert_shard: str = "0/1"
     # "index/count": the share of every MIXER's heads this peer's chip holds,
     # as one of ``count`` chips a layer's heads are divided over (tensor
-    # parallel by heads; models/kimi_linear.py): the projections exist for
-    # the held heads alone and the out-projection gives the mixer's partial
-    # sum. The count must divide every mixer's head count; "0/1" = every head.
+    # parallel by heads; models/kimi_linear.py, models/nemotron_h.py): the
+    # projections exist for the held heads alone and the out-projection gives
+    # the mixer's partial sum. The count must divide every mixer's head count
+    # (a Mamba-2 mixer's GROUPS: a group's B and C live with its heads; key
+    # heads are split while the count allows and shared beyond it); "0/1" =
+    # every head.
     head_shard: str = "0/1"
     # the share of every synthetic row's positions that lies in IMAGE SPANS
     # (runs of g_h x g_w ids standing for a vision tower's features: three
@@ -340,8 +346,9 @@ class TrainingArguments:
     # kernels READ: q / k / v, the convolution's B | C | u; whole_mixer —
     # those, the stream after the mixer and a q / k norm's input, so the
     # replay runs no matmul of the mixer — is the default of smallthinker,
-    # sdar, lfm2, laguna and kimi_linear (there with a KDA kernel's q / k /
-    # v / g / beta), and kernel_operands (keye_vl2's default: there
+    # sdar, lfm2, laguna, kimi_linear (there with a KDA kernel's q / k /
+    # v / g / beta) and nemotron_h (there with the Mamba mixer's
+    # in-projection in place of the scan's operands), and kernel_operands (keye_vl2's default: there
     # with the selection the flash kernels read, int8 [B, S, S] a layer),
     # then kernel_outputs, is what a
     # peer with less memory to spare passes there; under any, the five

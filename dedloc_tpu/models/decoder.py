@@ -2,13 +2,16 @@
 
 A decoder of this tree (``models/ouro.py``, ``deepseek_v3.py``,
 ``lfm2_moe.py``, ``smallthinker.py``, ``sdar_moe.py``, ``laguna.py``,
-``keye_vl2.py``, ``kimi_linear.py``) is its config, the
+``keye_vl2.py``, ``kimi_linear.py``, ``nemotron_h.py``) is its config, the
 mixer that is its own, its layer's wiring of norms and residuals, its FLOP
 model and — where the objective is its own — its loss; a new one is one such
 module + one ``roles/common.MODEL_FAMILIES`` entry. The rest is here: the
 blocks, ONE description of who sees whom (``Visibility``) under ``attend``,
 ``GroupedQueryAttention`` and ``LatentAttention`` (two models' mixer since
-Kimi Linear: its head count and its RoPE are arguments), the two routed
+Kimi Linear: its head count and its RoPE are arguments), the causal
+depthwise convolution + SiLU of the recurrent mixers (``causal_conv_silu``:
+Kimi Linear's, Nemotron-H's with a bias) and the count of a head share
+(``held_heads``), the two routed
 layers over ONE
 ``held_expert_ffn``, the stack (``scan_periods``), the head + loss tail and the
 leaf masks. What differs between models arrives as an argument (a name, a
@@ -39,6 +42,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from dedloc_tpu.parallel.moe import (
+    PLAIN_ACTIVATIONS,
     expert_load,
     route_top_k,
     route_top_k_softmax,
@@ -82,10 +86,25 @@ def held_range(expert_shard: Tuple[int, int],
     return index * (num_experts // count), num_experts // count
 
 
-def dense(features: int, cfg, name: str) -> nn.Dense:
+def held_heads(head_shard: Tuple[int, int], heads: int) -> int:
+    """How many of a mixer's ``heads`` the share ``head_shard`` = (index,
+    count) holds."""
+    index, count = head_shard
+    if not (0 <= index < count) or heads % count:
+        raise ValueError(
+            f"head_shard {index}/{count}: the count must divide the "
+            f"{heads} heads, 0 <= index < count"
+        )
+    return heads // count
+
+
+def dense(features: int, cfg, name: str, init_scale: float = 1.0) -> nn.Dense:
+    """A bias-free projection; ``init_scale``: a factor on the initialiser's
+    deviation (a model that rescales its out-projections by its depth)."""
     return nn.Dense(
         features, use_bias=False, dtype=cfg.dtype, param_dtype=jnp.float32,
-        kernel_init=nn.initializers.normal(cfg.initializer_range), name=name,
+        kernel_init=nn.initializers.normal(cfg.initializer_range * init_scale),
+        name=name,
     )
 
 
@@ -226,6 +245,41 @@ class SwiGLU(nn.Module):
         return swiglu(self.cfg, x, self.width)
 
 
+def causal_conv_silu(x, taps, bias=None):
+    """SiLU(causal depthwise convolution) of x [B, S, W] with ``taps``
+    [W, K] (``taps[:, K - 1]`` multiplies the current position, zeros before
+    the row) + ``bias`` [W] where the convolution has one (Mamba-2's), in
+    float32, back in x's dtype."""
+    seq, width = x.shape[1], taps.shape[1]
+    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (width - 1, 0), (0, 0)))
+    conv = sum(
+        taps[:, k].astype(jnp.float32) * padded[:, k:k + seq]
+        for k in range(width)
+    )
+    if bias is not None:
+        conv = conv + bias.astype(jnp.float32)
+    return nn.silu(conv).astype(x.dtype)
+
+
+class PlainMLP(nn.Module):
+    """down(act(up x)): an UN-gated feed-forward of two matrices
+    (``up_proj`` / ``down_proj``), ``activation`` a name of
+    ``moe.PLAIN_ACTIVATIONS`` ("relu2": Nemotron-H's relu(.)²)."""
+
+    cfg: Any
+    width: int
+    activation: str = "relu2"
+    down_init_scale: float = 1.0
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        up = checkpoint_name(dense(self.width, cfg, "up_proj")(x), "ffn_up")
+        return dense(
+            cfg.hidden_size, cfg, "down_proj", self.down_init_scale
+        )(PLAIN_ACTIVATIONS[self.activation][0](up))
+
+
 def embed_tokens(module: nn.Module, input_ids, tied_head: bool = False):
     """The ids embedded by ``embed_tokens`` [V, H], in the compute dtype.
     Created in ``module``'s scope (call it inside a compact method), and
@@ -350,7 +404,9 @@ class GroupedQueryAttention(nn.Module):
     lanes of q and of k where ``qk_norms`` names the two (a weight each,
     shared by the heads), THEN rotate-half RoPE where ``rotated``, over as
     many of a head's first lanes as the tables of ``rope`` are wide; the
-    output projection ``out_name``. ``gate`` [B, S, heads] (``head_gate``):
+    output projection ``out_name`` (``out_init_scale``: ``dense``'s).
+    ``kv_heads``: the kv heads the call HAS where a chip holds a share of
+    them (None: ``cfg.num_key_value_heads``). ``gate`` [B, S, heads] (``head_gate``):
     each head's context times its gate, between the kernels and the output
     projection — ``ops/head_gate.gate_heads``' kernels behind the flash
     kernels on one device, XLA's expression behind ``"dense"`` attention and
@@ -366,13 +422,15 @@ class GroupedQueryAttention(nn.Module):
     qk_norms: Tuple[Optional[str], Optional[str]] = (None, None)
     out_name: str = "o_proj"
     heads: Optional[int] = None
+    kv_heads: Optional[int] = None
+    out_init_scale: float = 1.0
 
     @nn.compact
     def __call__(self, hidden, rope, gate=None, selection=None):
         cfg = self.cfg
         B, S, _ = hidden.shape
         H, KV, D = (self.heads or cfg.num_attention_heads,
-                    cfg.num_key_value_heads, cfg.head_dim)
+                    self.kv_heads or cfg.num_key_value_heads, cfg.head_dim)
         q = dense(H * D, cfg, "q_proj")(hidden).reshape(B, S, H, D)
         k = dense(KV * D, cfg, "k_proj")(hidden).reshape(B, S, KV, D)
         v = dense(KV * D, cfg, "v_proj")(hidden).reshape(B, S, KV, D)
@@ -420,7 +478,9 @@ class GroupedQueryAttention(nn.Module):
             fused = cfg.attention_impl == "flash" and cfg.mesh is None
             with jax.named_scope("attn_gate"):
                 ctx = (gate_heads if fused else gate_heads_xla)(ctx, gate)
-        out = dense(cfg.hidden_size, cfg, self.out_name)(ctx)
+        out = dense(
+            cfg.hidden_size, cfg, self.out_name, self.out_init_scale
+        )(ctx)
         return (out, (q, k, lse)) if self.visible.selected else out
 
 
@@ -485,13 +545,18 @@ class LatentAttention(nn.Module):
 
 
 def held_expert_ffn(module: nn.Module, tokens, choice, weights,
-                    activation: str = "silu"):
+                    activation: str = "silu", down_init_scale: float = 1.0):
     """``parallel/moe.routed_experts`` over the experts ``module.cfg`` HOLDS:
     the three ``EXPERT_LEAVES`` created in ``module``'s scope (call it inside
     a compact method) and, where the apply carries the collection
     ``GRAD_SINKS``, this layer's three buffers handed to the tile loop's
     backward. ``tokens`` [T, H]; returns (y [T, H] float32, counts —
     ``routed_experts``' stats and ``compute_copy_leaves``, 3 or 0).
+    ``activation``: the expert's FORM with it — a gate's ("silu", "relu":
+    three matrices) or an UN-gated expert's (a name of
+    ``moe.PLAIN_ACTIVATIONS``, "relu2": ``experts_up`` and ``experts_down``
+    alone, two sinks, two copies). ``down_init_scale``: a factor on the
+    down matrices' initial deviation (``dense``'s ``init_scale``).
 
     Who makes the matrices the loop reads in the compute dtype: where the
     apply carries the collection ``COMPUTE_COPIES`` (a ``GradSinkLoss`` on one
@@ -509,24 +574,30 @@ def held_expert_ffn(module: nn.Module, tokens, choice, weights,
     H, F = tokens.shape[-1], cfg.moe_intermediate_size
     first, held = cfg.held_experts
     init = nn.initializers.normal(cfg.initializer_range)
-    gate, up, down = (
-        module.param(name, init, shape, jnp.float32)
-        for name, shape in zip(
-            EXPERT_LEAVES, ((held, H, F), (held, H, F), (held, F, H))
+    gated = activation not in PLAIN_ACTIVATIONS
+    leaves = EXPERT_LEAVES if gated else EXPERT_LEAVES[1:]
+    inits = (init,) * (len(leaves) - 1) + (
+        nn.initializers.normal(cfg.initializer_range * down_init_scale),
+    )
+    matrices = tuple(
+        module.param(name, leaf_init, shape, jnp.float32)
+        for name, leaf_init, shape in zip(
+            leaves, inits,
+            ((held, H, F),) * (len(leaves) - 1) + ((held, F, H),),
         )
     )
 
-    def beside(collection):  # this layer's three arrays of it, or None
-        if not module.has_variable(collection, EXPERT_LEAVES[0]):
+    def beside(collection):  # this layer's arrays of it, or None
+        if not module.has_variable(collection, leaves[0]):
             return None
         return tuple(
-            module.get_variable(collection, name) for name in EXPERT_LEAVES
+            module.get_variable(collection, name) for name in leaves
         )
 
     sinks, copies = beside(GRAD_SINKS), beside(COMPUTE_COPIES)
     y, counts = routed_experts(
-        tokens, choice, weights,
-        *(copies or (w.astype(cfg.dtype) for w in (gate, up, down))),
+        tokens, choice, weights, *(() if gated else (None,)),
+        *(copies or (w.astype(cfg.dtype) for w in matrices)),
         (first, held), tile=cfg.moe_row_tile, grad_sinks=sinks,
         activation=activation,
     )
@@ -540,7 +611,9 @@ class RoutedFFN(nn.Module):
     wide on every chip (0: the model has none), chosen by sigmoid scores
     (DeepSeek-V3's rule; kanana-2, LFM2, Laguna) — where ``biased`` with
     the stepped ``BIAS`` in the CHOICE — the chosen scores renormalised x
-    ``cfg.routed_scaling_factor``. Returns (y, routing): scores [T, E],
+    ``cfg.routed_scaling_factor``. ``activation``: the experts' form,
+    ``held_expert_ffn``'s — the shared expert has the same (a SwiGLU, or
+    ``PlainMLP`` of two matrices). Returns (y, routing): scores [T, E],
     choice [T, k], load [E] and ``parallel/moe.routed_experts``' counts;
     the load leaves the backward as the bias leaf's cotangent (``moe.py``'s
     rule) where there is one."""
@@ -548,6 +621,8 @@ class RoutedFFN(nn.Module):
     cfg: Any
     shared_width: int = 0
     biased: bool = True
+    activation: str = "silu"
+    down_init_scale: float = 1.0
 
     @nn.compact
     def __call__(self, x):
@@ -569,12 +644,19 @@ class RoutedFFN(nn.Module):
             scores, bias, cfg.num_experts_per_tok, cfg.routed_scaling_factor,
             cfg.route_eps,
         )
-        routed, counts = held_expert_ffn(self, tokens, choice, weights)
+        routed, counts = held_expert_ffn(
+            self, tokens, choice, weights, self.activation,
+            self.down_init_scale,
+        )
         routed = routed.reshape(B, S, H)
         if self.shared_width:
-            routed = routed + SwiGLU(
-                cfg, self.shared_width, name="shared_experts",
-            )(x).astype(jnp.float32)
+            shared = (
+                PlainMLP(cfg, self.shared_width, self.activation,
+                         self.down_init_scale, name="shared_experts")
+                if self.activation in PLAIN_ACTIVATIONS
+                else SwiGLU(cfg, self.shared_width, name="shared_experts")
+            )
+            routed = routed + shared(x).astype(jnp.float32)
         load = expert_load(choice, E)
         y = routed.astype(cfg.dtype)
         if self.biased:

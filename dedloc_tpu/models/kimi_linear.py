@@ -64,9 +64,11 @@ from dedloc_tpu.models.decoder import (
     RMSNorm,
     RoutedFFN,
     SwiGLU,
+    causal_conv_silu,
     dense,
     embed_tokens,
     expert_lm_loss,
+    held_heads,
     held_range,
     mixer_residual,
     named_config,
@@ -81,18 +83,6 @@ PERIOD = 4  # three KDA layers and a latent-attention one
 # a KDA mixer's leaves that are no matrix: exempt from weight decay
 KDA_VECTORS = ("A_log", "dt_bias", "q_conv", "k_conv", "v_conv", "g_b_bias")
 KDA_GAUGES = ("kda.chunk_log_decay_min", "kda.beta_mean", "kda.state_abs_max")
-
-
-def held_heads(head_shard: Tuple[int, int], heads: int) -> int:
-    """How many of a mixer's ``heads`` the share ``head_shard`` = (index,
-    count) holds."""
-    index, count = head_shard
-    if not (0 <= index < count) or heads % count:
-        raise ValueError(
-            f"head_shard {index}/{count}: the count must divide the "
-            f"{heads} heads, 0 <= index < count"
-        )
-    return heads // count
 
 
 @dataclasses.dataclass(frozen=True)
@@ -206,19 +196,6 @@ class KimiLinearConfig:
         )
         base.update(overrides)
         return KimiLinearConfig(**base)
-
-
-def causal_conv_silu(x, taps):
-    """SiLU(causal depthwise convolution) of x [B, S, W] with ``taps``
-    [W, K] (``taps[:, K - 1]`` multiplies the current position, zeros before
-    the row), in float32, back in x's dtype."""
-    seq, width = x.shape[1], taps.shape[1]
-    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (width - 1, 0), (0, 0)))
-    conv = sum(
-        taps[:, k].astype(jnp.float32) * padded[:, k:k + seq]
-        for k in range(width)
-    )
-    return nn.silu(conv).astype(x.dtype)
 
 
 def _dt_bias_init(key, shape, dtype=jnp.float32):

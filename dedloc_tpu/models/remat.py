@@ -72,13 +72,19 @@ def remat_policy_object(name: str):
         # outputs — o and the float32 state entering every chunk of 64
         # tokens (67 MB a layer there): kept here and above, so the backward
         # kernel reads what the forward one read and the replay runs the
-        # prelude for the prelude's own backward alone. The rung
+        # prelude for the prelude's own backward alone. A Mamba-2 mixer's
+        # (ops/ssd.py, models/nemotron_h.py) read x | B | C after the
+        # convolution and SiLU, the float32 time steps and log-decays
+        # ("ssd_operands": B·S·(heads·64 + 2·groups·128)·2 bytes + 2 float32
+        # a token-head, 52 MB a layer at 8,192 x 32 heads in 4 groups)
+        # beside their outputs — y and the float32 state entering every
+        # chunk of 128 tokens (34 + 67 MB a layer there). The rung
         # under "whole_mixer"
         "kernel_operands": (
             jax.checkpoint_policies.save_from_both_policies(
                 jax.checkpoint_policies.save_only_these_names(
                     "flash_qkv", "short_conv_bcu", "attn_selection",
-                    "kda_operands",
+                    "kda_operands", "ssd_operands",
                 ),
                 _pallas_outputs_saveable,
             )
@@ -113,13 +119,25 @@ def remat_policy_object(name: str):
         # 134 MB) are kept HERE, so this rung's replay still runs no matmul
         # of the mixer; under the rungs below the replay runs ``g_proj``
         # (2·B·S·hidden·H FLOPs, a 1/128th of q_proj's) behind the input
-        # norm it runs anyway
+        # norm it runs anyway. A Mamba-2 mixer keeps, IN PLACE of the
+        # kernels' operands, the fused in-projection's output
+        # ("ssd_in_proj": z | x | B | C | dt as the matmul wrote it, 84 MB
+        # a layer at 8,192 x 5,152): the convolution's and the gate's
+        # backward read it, the operands are one element-wise pass behind
+        # it, and the replay then runs the prelude — convolution, SiLU,
+        # softplus, the gate, the group norm — and no matmul and no kernel
+        # (the scan's y and chunk states are kept as every kernel's
+        # outputs are). A layer that is a routed feed-forward ALONE
+        # (models/nemotron_h.py) has no stream after a mixer to keep: it
+        # names the normed input its router reads ("routed_input",
+        # B·S·hidden·2 bytes), so the replay routes by the very tensor the
+        # forward routed by
         "whole_mixer": (
             jax.checkpoint_policies.save_from_both_policies(
                 jax.checkpoint_policies.save_only_these_names(
                     "flash_qkv", "short_conv_bcu", "mixer_residual",
                     "qk_norm_input", "attn_gate", "attn_selection",
-                    "kda_operands",
+                    "kda_operands", "ssd_in_proj", "routed_input",
                 ),
                 _pallas_outputs_saveable,
             )

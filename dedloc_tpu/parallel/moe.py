@@ -26,7 +26,11 @@ their weights; the weights are the chosen scores renormalised and scaled.
 (``route_top_k_softmax`` is the other published rule, ``models/
 smallthinker.py``'s: the top k of the logits, a softmax over the chosen,
 no bias; its experts gate with a ReLU — ``routed_experts(activation=
-"relu")`` — where the others gate with a SiLU.)
+"relu")`` — where the others gate with a SiLU. Nemotron-H's experts have NO
+gate: ``routed_experts(..., gate=None, activation="relu2")`` runs
+``down_e(relu(up_e x)²)``, two matrices and two sinks an expert, through the
+same plan, walk and loops — the form is decided when the loop is traced, a
+gated caller traces what it traced before.)
 The layer is TOLD which experts it holds (``held = (first, count)``: one
 chip's share of an expert-parallel deployment): it routes over all of them
 and computes its own part — slots that chose an absent expert contribute
@@ -392,14 +396,33 @@ ACTIVATIONS = {
     "silu": (jax.nn.silu, _silu_bwd),  # SwiGLU
     "relu": (jax.nn.relu, _relu_bwd),  # ReGLU
 }
+# an UN-gated expert's (two matrices, ``gate`` None): act, and d u of
+# hidden = act(u)
+PLAIN_ACTIVATIONS = {
+    "relu2": (  # relu(u)², Nemotron-H's
+        lambda u: jnp.square(jax.nn.relu(u)),
+        lambda u, d_hidden: 2.0 * jax.nn.relu(u) * d_hidden,
+    ),
+}
+
+
+def _held_matrices(gate, up, down):
+    """The matrices an expert HAS: three, or two where it has no gate."""
+    return (up, down) if gate is None else (gate, up, down)
 
 
 def _tile_forward(x, gate, up, down, tokens, activation):
-    """One tile through its expert: (rows, gate·x, up·x, hidden, out)."""
+    """One tile through its expert: (rows, gate·x, up·x, hidden, out);
+    ``gate`` None: an un-gated expert, hidden = act(up·x)."""
     rows = x.at[tokens].get(mode="promise_in_bounds")
-    g = jnp.dot(rows, gate, preferred_element_type=jnp.float32)
-    u = jnp.dot(rows, up, preferred_element_type=jnp.float32)
-    hidden = (ACTIVATIONS[activation][0](g) * u).astype(x.dtype)
+    if gate is None:
+        g = None
+        u = jnp.dot(rows, up, preferred_element_type=jnp.float32)
+        hidden = PLAIN_ACTIVATIONS[activation][0](u).astype(x.dtype)
+    else:
+        g = jnp.dot(rows, gate, preferred_element_type=jnp.float32)
+        u = jnp.dot(rows, up, preferred_element_type=jnp.float32)
+        hidden = (ACTIVATIONS[activation][0](g) * u).astype(x.dtype)
     out = jnp.dot(hidden, down, preferred_element_type=jnp.float32)
     return rows, g, u, hidden, out
 
@@ -447,7 +470,8 @@ def _grouped_swiglu(x, slot_weight, gate, up, down, sinks, row_slot,
     down)-shaped buffers the forward ignores and the backward accumulates
     into; ``walk``: ``_run_schedule``'s; ``shape``: (k, tile, tiles a bulk
     iteration); ``activation``: the gate's, a name of ``ACTIVATIONS`` (the
-    function keeps its first name)."""
+    function keeps its first name) — or, with ``gate`` None (an un-gated
+    expert: two matrices, two sinks), a name of ``PLAIN_ACTIVATIONS``."""
     out, _ = _grouped_swiglu_fwd(
         x, slot_weight, gate, up, down, sinks, row_slot, tile_expert, walk,
         shape, activation,
@@ -461,8 +485,10 @@ def _grouped_swiglu_fwd(x, slot_weight, gate, up, down, sinks, row_slot,
 
     def body(start, rows, total):
         _slots, tokens, scale, weights, _e = _tile_operands(
-            start, rows, plan, slot_weight, (gate, up, down)
+            start, rows, plan, slot_weight, _held_matrices(gate, up, down)
         )
+        if gate is None:
+            weights = (None, *weights)
         out = _tile_forward(x, *weights, tokens, activation)[-1]
         return total.at[tokens].add(out * scale[:, None])
 
@@ -477,14 +503,16 @@ def _grouped_swiglu_fwd(x, slot_weight, gate, up, down, sinks, row_slot,
 def _grouped_swiglu_bwd(shape, activation, residuals, d_total):
     (x, slot_weight, gate, up, down, sinks, row_slot, tile_expert,
      walk) = residuals
-    held = (gate, up, down)
+    gated = gate is not None
+    held = _held_matrices(gate, up, down)
     plan = (row_slot, tile_expert, *shape[:2])
 
     def body(start, rows, carry):
-        dx, d_weight, d_gate, d_up, d_down = carry
-        slots, tokens, scale, (w_gate, w_up, w_down), expert = (
+        dx, d_weight, *d_held = carry
+        slots, tokens, scale, weights, expert = (
             _tile_operands(start, rows, plan, slot_weight, held)
         )
+        w_gate, w_up, w_down = weights if gated else (None, *weights)
         rows, g, u, hidden, out = _tile_forward(
             x, w_gate, w_up, w_down, tokens, activation
         )
@@ -497,17 +525,24 @@ def _grouped_swiglu_bwd(shape, activation, residuals, d_total):
             d_out, w_down, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        d_g, d_u = (
-            d.astype(x.dtype)
-            for d in ACTIVATIONS[activation][1](g, u, d_hidden)
-        )
-        d_rows = jax.lax.dot_general(
-            d_g, w_gate, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) + jax.lax.dot_general(
-            d_u, w_up, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        if gated:
+            d_g, d_u = (
+                d.astype(x.dtype)
+                for d in ACTIVATIONS[activation][1](g, u, d_hidden)
+            )
+            d_rows = jax.lax.dot_general(
+                d_g, w_gate, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) + jax.lax.dot_general(
+                d_u, w_up, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        else:
+            d_u = PLAIN_ACTIVATIONS[activation][1](u, d_hidden).astype(x.dtype)
+            d_rows = jax.lax.dot_general(
+                d_u, w_up, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
 
         def add(acc, lhs, rhs):  # acc[expert] += lhsᵀ rhs, in place
             term = jax.lax.dot_general(
@@ -519,13 +554,20 @@ def _grouped_swiglu_bwd(shape, activation, residuals, d_total):
                 acc, old + term[None], expert, 0
             )
 
+        if not gated:
+            d_up, d_down = d_held
+            return (
+                dx.at[tokens].add(d_rows), d_weight, add(d_up, rows, d_u),
+                add(d_down, hidden, d_out),
+            )
+        d_gate, d_up, d_down = d_held
         return (
             dx.at[tokens].add(d_rows), d_weight, add(d_gate, rows, d_g),
             add(d_up, rows, d_u), add(d_down, hidden, d_out),
         )
 
     with jax.named_scope("moe_routed"):
-        dx, d_weight, d_gate, d_up, d_down = _walk(
+        dx, d_weight, *summed = _walk(
             body, (
                 jnp.zeros(x.shape, jnp.float32),
                 jnp.zeros(slot_weight.shape, jnp.float32),
@@ -535,13 +577,15 @@ def _grouped_swiglu_bwd(shape, activation, residuals, d_total):
                 )),
             ), walk, *shape[1:],
         )
-    summed = (d_gate, d_up, d_down)
+    summed = tuple(summed)
     if sinks is None:
         d_held = tuple(d.astype(w.dtype) for d, w in zip(summed, held))
         d_sinks = None
     else:  # the sums left in the sinks, float32 as they are
         d_held = tuple(jnp.zeros_like(w) for w in held)
         d_sinks = summed
+    if not gated:
+        d_held = (None, *d_held)
     int_zero = lambda a: np.zeros(a.shape, jax.dtypes.float0)  # noqa: E731
     return (
         dx.astype(x.dtype), d_weight, *d_held, d_sinks,
@@ -558,7 +602,9 @@ def routed_experts(x, choice, weights, gate, up, down,
                    activation: str = "silu", run_tiles: int = RUN_TILES):
     """The held experts' part of Σ_{e in choice} w_e · GLU_e(x), with
     GLU_e(x) = down_e(act(gate_e x) ⊙ up_e x) and ``activation`` the gate's:
-    "silu" (SwiGLU) or "relu" (ReGLU).
+    "silu" (SwiGLU) or "relu" (ReGLU) — or, with ``gate`` None, of
+    Σ w_e · down_e(act(up_e x)): an UN-gated expert of two matrices,
+    ``activation`` "relu2" (relu(.)², Nemotron-H's), and two sinks.
 
     ``x`` [T, H] in the compute dtype; ``choice`` / ``weights`` [T, k] from
     ``route_top_k`` or ``route_top_k_softmax``; ``gate`` / ``up`` [n, H, F]

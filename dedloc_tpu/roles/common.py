@@ -59,6 +59,14 @@ from dedloc_tpu.models.lfm2_moe import (
     lfm2_moe_train_tflops_per_sample,
     lfm2_moe_weight_decay_mask,
 )
+from dedloc_tpu.models.nemotron_h import (
+    SSD_GAUGES,
+    NemotronHConfig,
+    NemotronHForCausalLM,
+    nemotron_h_loss,
+    nemotron_h_train_tflops_per_sample,
+    nemotron_h_weight_decay_mask,
+)
 from dedloc_tpu.models.ouro import (
     OuroConfig,
     OuroForCausalLM,
@@ -331,6 +339,15 @@ KIMI_LINEAR = dataclasses.replace(
     step_gauges=DEEPSEEK_V3.step_gauges + KDA_GAUGES,
     sign_step=KimiLinearConfig.bias_update_speed,
 )
+NEMOTRON_H = dataclasses.replace(
+    DEEPSEEK_V3,  # the same source, gauges, counter, sign rule and sinks
+    config=NemotronHConfig, module=NemotronHForCausalLM,
+    loss=_without_rng(nemotron_h_loss),
+    tflops_per_sample=nemotron_h_train_tflops_per_sample,
+    weight_decay_mask=nemotron_h_weight_decay_mask,
+    step_gauges=DEEPSEEK_V3.step_gauges + SSD_GAUGES,
+    sign_step=NemotronHConfig.bias_update_speed,
+)
 MODEL_FAMILIES: Dict[str, ModelFamily] = {
     "tiny": ALBERT, "large": ALBERT, "ouro_tiny": OURO, "ouro_2p6b": OURO,
     "kanana2_tiny": DEEPSEEK_V3, "kanana2_30b_a3b": DEEPSEEK_V3,
@@ -340,6 +357,7 @@ MODEL_FAMILIES: Dict[str, ModelFamily] = {
     "laguna_tiny": LAGUNA, "laguna_xs2_33b_a3b": LAGUNA,
     "keye_vl2_tiny": KEYE_VL2, "keye_vl2_30b_a3b": KEYE_VL2,
     "kimi_linear_tiny": KIMI_LINEAR, "kimi_linear_48b_a3b": KIMI_LINEAR,
+    "nemotron_h_tiny": NEMOTRON_H, "nemotron3_nano_30b_a3b": NEMOTRON_H,
 }
 
 

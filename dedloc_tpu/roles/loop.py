@@ -179,7 +179,7 @@ def run_boundary_loop(
                             for key, value in values.items():
                                 srec.attrs[key] = float(value)
                                 if tele is not None:
-                                    tele.gauge(key).set(float(value))  # dedlint: emits=gauge:lm.exit_prob.*,gauge:lm.loss.*,gauge:moe.load_max_over_mean.*,gauge:moe.local_slot_share,gauge:moe.bias_abs_max,gauge:moe.grad_sink_leaves,gauge:moe.compute_copy_leaves,gauge:moe.bulk_row_share,gauge:attn.band_tile_share,gauge:attn.band_visible_share,gauge:attn.gate_mean.*,gauge:attn.bd_tile_share,gauge:diffusion.masked_share,gauge:attn.select_kept_share,gauge:attn.select_tile_share,gauge:attn.index_loss_tile_share,gauge:attn.index_peak.*,gauge:attn.select_tie_block_share.*,gauge:loss.index_kl,gauge:data.image_token_share,gauge:kda.chunk_log_decay_min.*,gauge:kda.beta_mean.*,gauge:kda.state_abs_max.*
+                                    tele.gauge(key).set(float(value))  # dedlint: emits=gauge:lm.exit_prob.*,gauge:lm.loss.*,gauge:moe.load_max_over_mean.*,gauge:moe.local_slot_share,gauge:moe.bias_abs_max,gauge:moe.grad_sink_leaves,gauge:moe.compute_copy_leaves,gauge:moe.bulk_row_share,gauge:attn.band_tile_share,gauge:attn.band_visible_share,gauge:attn.gate_mean.*,gauge:attn.bd_tile_share,gauge:diffusion.masked_share,gauge:attn.select_kept_share,gauge:attn.select_tile_share,gauge:attn.index_loss_tile_share,gauge:attn.index_peak.*,gauge:attn.select_tie_block_share.*,gauge:loss.index_kl,gauge:data.image_token_share,gauge:kda.chunk_log_decay_min.*,gauge:kda.beta_mean.*,gauge:kda.state_abs_max.*,gauge:ssd.dt_mean.*,gauge:ssd.chunk_log_decay_min.*,gauge:ssd.state_abs_max.*
                         for key, value in model.host_gauges.items():
                             srec.attrs[key] = value
                             if tele is not None:

@@ -378,6 +378,9 @@ EMITTED_PREFIXES = (
     "lm.loss.",
     "moe.load_max_over_mean.",
     "perf.",
+    "ssd.chunk_log_decay_min.",
+    "ssd.dt_mean.",
+    "ssd.state_abs_max.",
     "step.phase.",
 )
 

@@ -8,8 +8,8 @@ module of ``dedloc_tpu/models/``; the seven decoder files import from
 with itself on a name, a config and a module, and the parameter tree of
 ``jax.eval_shape(model.init, ...)`` equals ``fixtures/model_param_trees.json``,
 recorded from the tree BEFORE ``models/decoder.py`` existed (PR 43's parent;
-a model added since — Laguna, PR 47; Keye-VL-2.0, PR 51; Kimi Linear, PR 53 —
-from the tree that added it)
+a model added since — Laguna, PR 47; Keye-VL-2.0, PR 51; Kimi Linear, PR 53;
+Nemotron-H, PR 57 — from the tree that added it)
 by this file's own ``param_tree``:
 
     git archive <commit> | tar -x -C <dir>; cd <dir>
@@ -32,13 +32,14 @@ FIXTURE = os.path.join(
 )
 SHARED = ("decoder", "remat")
 DECODERS = ("ouro", "deepseek_v3", "lfm2_moe", "smallthinker", "sdar_moe",
-            "laguna", "keye_vl2", "kimi_linear")
+            "laguna", "keye_vl2", "kimi_linear", "nemotron_h")
 NAMES = (
     "tiny", "large", "ouro_tiny", "ouro_2p6b", "kanana2_tiny",
     "kanana2_30b_a3b", "lfm2_tiny", "lfm2_24b_a2b", "smallthinker_tiny",
     "smallthinker_21b_a3b", "sdar_tiny", "sdar_30b_a3b", "laguna_tiny",
     "laguna_xs2_33b_a3b", "keye_vl2_tiny", "keye_vl2_30b_a3b",
-    "kimi_linear_tiny", "kimi_linear_48b_a3b",
+    "kimi_linear_tiny", "kimi_linear_48b_a3b", "nemotron_h_tiny",
+    "nemotron3_nano_30b_a3b",
 )
 
 

@@ -415,6 +415,30 @@ def kda_kernels(device):
     return jax.jit(both).lower(*_on_device(device, operands))
 
 
+def ssd_kernels(device):
+    """Mamba-2's scan as a kernel pair alone at the Nemotron cell's shape:
+    (1, 8192, 32 heads of 64 in 4 groups, state 128), bf16 x / B / C,
+    float32 time steps and log-decays; the output and, from a cotangent, all
+    six gradients."""
+    from dedloc_tpu.ops.ssd import ssd
+
+    def both(x, dt, a, b, c, d, dy):
+        out, vjp = jax.vjp(ssd, x, dt, a, b, c, d)
+        return out, vjp(dy)
+
+    wide, keys = (1, 8192, 32, 64), (1, 8192, 4, 128)
+    operands = [
+        jax.ShapeDtypeStruct(wide, jnp.bfloat16),
+        jax.ShapeDtypeStruct(wide[:3], jnp.float32),
+        jax.ShapeDtypeStruct(wide[:3], jnp.float32),
+        jax.ShapeDtypeStruct(keys, jnp.bfloat16),
+        jax.ShapeDtypeStruct(keys, jnp.bfloat16),
+        jax.ShapeDtypeStruct(wide[2:3], jnp.float32),
+        jax.ShapeDtypeStruct(wide, jnp.bfloat16),
+    ]
+    return jax.jit(both).lower(*_on_device(device, operands))
+
+
 @functools.lru_cache(maxsize=None)
 def _lm_model_and_state(config: str, prefix: str):
     """(args, model, state, ids) of a causal-LM cell's recipe, from its
@@ -469,6 +493,9 @@ LM_CELLS = {
     "laguna_accumulate_step": ("laguna_xs2_33b_a3b_s8192.json", "LAGUNA"),
     "keye_accumulate_step": ("keye_vl2_30b_a3b_s16384.json", "KEYE"),
     "kimi_accumulate_step": ("kimi_linear_48b_a3b_s8192.json", "KIMI"),
+    "nemotron_accumulate_step": (
+        "nemotron3_nano_30b_a3b_s8192.json", "NEMOTRON"
+    ),
 }
 
 
@@ -572,6 +599,19 @@ def kimi_accumulate_step(device):
     unrolled period of routed layers, the untied chunked head."""
     return _lm_accumulate_step(device, *_lm_model_and_state(
         *LM_CELLS["kimi_accumulate_step"]
+    ))
+
+
+def nemotron_accumulate_step(device):
+    """Nemotron-3-Nano-30B-A3B at one chip's share (``benchmark/configs/
+    nemotron3_nano_30b_a3b_s8192.json``; ``NEMOTRON_LAYERS`` /
+    ``NEMOTRON_BATCH`` / ``NEMOTRON_REMAT`` size another cut): three Mamba-2
+    mixers (``ssd_fwd`` / ``ssd_bwd`` at 32 held heads in 4 groups), one
+    NoPE grouped-attention layer (16 query heads over key head 0) and three
+    routed layers of un-gated experts (two matrices, two sinks a layer),
+    seven unrolled single-sublayer layers, the untied chunked head."""
+    return _lm_accumulate_step(device, *_lm_model_and_state(
+        *LM_CELLS["nemotron_accumulate_step"]
     ))
 
 
@@ -898,13 +938,15 @@ COUNT_KERNEL_CALLS = {"gqa_kernels", "lfm2_accumulate_step", "band_kernels",
                       "head_gate_kernels", "laguna_accumulate_step",
                       "sel_kernels", "keye_accumulate_step",
                       "index_loss_kernels", "kda_kernels",
-                      "kimi_accumulate_step", "select_kernels"}
+                      "kimi_accumulate_step", "select_kernels",
+                      "ssd_kernels", "nemotron_accumulate_step"}
 COUNT_EXPERT_GRAD_PASSES = {"kanana_accumulate_step", "lfm2_accumulate_step",
                             "smallthinker_accumulate_step",
                             "sdar_accumulate_step",
                             "laguna_accumulate_step",
                             "keye_accumulate_step",
-                            "kimi_accumulate_step"}
+                            "kimi_accumulate_step",
+                            "nemotron_accumulate_step"}
 NO_V5E = 3  # exit code: nothing to compile with, which is not a failure
 
 
@@ -917,6 +959,7 @@ PROGRAMS = {
         laguna_kernels, laguna_accumulate_step, head_gate_kernels,
         sel_kernels, keye_accumulate_step, index_loss_kernels,
         kda_kernels, kimi_accumulate_step, select_kernels,
+        ssd_kernels, nemotron_accumulate_step,
     )
 }
 
