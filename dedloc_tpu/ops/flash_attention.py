@@ -43,26 +43,66 @@ tile, where the two kernels this replaced (``*_bwd_dq`` over query tiles,
 ``*_bwd_dkv`` over key tiles, each rebuilding the tile: until PR 56) spent
 7, 2, 2 and 2. The members' axis lies OUTSIDE the query tile's, so a key
 tile's rows meet their terms in the order the dkv kernel summed them (a
-group's first program over every query tile, then its second): dq, dk and dv
-are the pair's bits. What bounds S now is VMEM (``_bwd_vmem``): 4 + 4 bytes
-a row and lane of the kv block (the accumulators; the output blocks' two
-buffers in bf16) beside a step's blocks and a program's transients — 16 +
-16 MiB at S=16,384 and one kv head of 128, and the call asks for its scoped
-limit (49.5 MiB at Keye's shape, 76.75 for SmallThinker's seven heads a
-program, 22.75-57.5 at the other cells'; a v5e core has 128); a call whose
-need passes 112 MiB — S=65,536 at those widths, 49,152 under a group of
-seven — is refused with an error that says so. A call on a v5e, the pair →
-the sweep, ms (PR 56; each mode alone, ``tools/chip_gqa_check.py`` /
-``chip_causal_check.py`` / ``chip_mla_check.py``): selected, 32 / 4 x 128
-at S=16,384, 26.02 + 30.33 → 38.19; band 4,096 and causal at 28 / 4 x 128,
-S=16,384 (seven heads a program), 8.04 + 10.67 → 13.12 and 17.19 + 22.38 →
-27.88; the block rule, 32 / 4 x 128 over 2 x 4,096, 3.95 + 5.18 → 5.99;
-band 512 at 64 / 8 x 128 and causal at 48 / 8 x 128 (six heads a program),
-S=8,192, 2.76 + 3.45 → 4.27 and 7.95 + 9.97 → 12.77; 32 / 8 x 64 at
-S=4,096, 1.63 + 2.16 → 2.50; 16 x 128 causal at S=4,096, 0.76 + 0.99 →
-1.28; 32 x 192 / 128, 2.54 + 2.81 → 3.82: 0.66-0.73 of the pair in every
-mode, 1.22-1.36 x the dkv kernel alone, so ONE path serves every tiled
-call and no shape keeps the pair.
+group's first program over every query tile, then its second). What bounds
+S now is VMEM (``_bwd_vmem``): 4 + 4 bytes a row and lane of the kv block
+(the accumulators; the output blocks' two buffers in bf16) beside a step's
+blocks and a program's transients — 16 + 16 MiB at S=16,384 and one kv head
+of 128, and the call asks for its scoped limit (84 MiB at Keye's shape,
+76.75 for SmallThinker's seven heads a program, 53.5-71 at the other
+cells'; a v5e core has 128); a call whose ask passes 112 MiB at ONE column
+block a program — S=53,248 at those widths — is refused with an error that
+says so. A call on a v5e, the pair → the sweep, ms (PR 56; each mode alone,
+``tools/chip_gqa_check.py`` / ``chip_causal_check.py`` /
+``chip_mla_check.py``; two heads a program then, except the groups of seven
+and six): selected, 32 / 4 x 128 at S=16,384, 26.02 + 30.33 → 38.19; band
+4,096 and causal at 28 / 4 x 128, S=16,384 (seven heads a program), 8.04 +
+10.67 → 13.12 and 17.19 + 22.38 → 27.88; the block rule, 32 / 4 x 128 over
+2 x 4,096, 3.95 + 5.18 → 5.99; band 512 at 64 / 8 x 128 and causal at 48 /
+8 x 128 (six heads a program), S=8,192, 2.76 + 3.45 → 4.27 and 7.95 + 9.97
+→ 12.77; 32 / 8 x 64 at S=4,096, 1.63 + 2.16 → 2.50; 16 x 128 causal at
+S=4,096, 0.76 + 0.99 → 1.28; 32 x 192 / 128, 2.54 + 2.81 → 3.82: 0.66-0.73
+of the pair in every mode, 1.22-1.36 x the dkv kernel alone, so ONE path
+serves every tiled call and no shape keeps the pair.
+
+Heads a program. ONE rule, forward and backward (``_heads_a_program``): a
+tiled call's program takes the MOST query heads — whole column blocks that
+divide the head count, eight at most (``_head_plans``) — whose scoped-VMEM
+ask (``_fwd_ask`` / ``_bwd_ask``) leaves an eighth of the 112 MiB ceiling
+free; the last plan, one column block, may ask up to the ceiling. A grouped
+program's kv block is whole column blocks of kv heads, so a group of eight
+or fewer is ONE program — k / v, the bias and a selection's tile fetched
+once a group, dk / dv summed inside the program, no ``members`` — a group
+of seven or six as one of eight (no branch of its own: seven has no divisor
+between itself and one), sixteen heads over one kv head two programs of
+eight that share the kv block, four heads a kv head of 64 the eight heads
+over a column block's two kv heads. With as many kv heads as heads the kv
+block is the program's own heads and dk / dv, resident for the sequence,
+grow with them: the backward takes 4 of 16 x 128 at S=4,096 (61 MiB; eight
+would ask 118), 4 of 32 x 192 / 128 at 4,096 (71), 2 at 8,192 (57.5; four:
+111), while the forward, which holds nothing for the sequence, takes eight
+everywhere (21-32 MiB). A longer row gives heads up before it is refused:
+32 / 4 x 128 takes 8 at S=16,384, 4 at 32,768, 2 at 40,960, 1 at 49,152.
+Nothing but shapes enters — group, widths, S, tiles: no argument, no
+environment, no model's name. The forward's out and lse are the same bits
+at every count (no sum crosses heads), dq too; dk / dv of a grouped call
+move in float32's late digits with the order a group's heads meet in
+(2-4e-5 relative, on the chip). Until PR 58 the count was a budget from the
+days of a 16 MiB scoped limit — 6 MB of score tiles forward, 4 backward:
+four and two heads at 512 x 512 — halved until the kv heads divided, with
+a group of seven special-cased whole. A call alone on a v5e at the old
+count → the new, ms (PR 58, the three chip checks with ``--at-most-heads 4
+2``), forward | backward: selected 32 / 4 x 128 at S=16,384, 15.63 → 14.22
+(73.6 → 81.0 % of its roofline) | 38.18 → 32.22 (75.4 → 89.3 %); the block
+rule 32 / 4 x 128 over 2 x 4,096, 2.47 → 2.29 | 6.01 → 5.13 (72.6 → 85.0);
+band 512 at 64 / 8 x 128, S=8,192, 2.02 → 1.92 | 4.27 → 3.78 (79.2 →
+89.5); causal 16 / 1 x 128 at 8,192, 1.85 → 1.79 | 4.57 → 4.09 (81.1 →
+90.6); 32 / 8 x 64 at 4,096, 1.22 → 1.13 | 2.50 → 2.19; 16 x 128 at 4,096,
+0.540 → 0.526 | 1.284 → 1.204 (76.4 → 81.5); 32 x 192 / 128 at 4,096, 1.51
+→ 1.45 | 3.82 → 3.69; at 8,192, 5.62 → 5.36 | 14.12 (two heads, as it
+was). One head a program, the other end, ran the backward kernels of the
+time at 63 / 68 % against seven heads' 90 / 90 (28 / 4 x 128 at S=16,384,
+PR 36). Eight heads' larger asks leave XLA less fast memory for what it
+places around a call (Ouro's accumulate step: 47 MB more scratch in HBM).
 
 One-tile forms. When one (Bq, Bk) tile covers the sequence (``_pick_block``
 gives S for both blocks: S=512 under the default 512, every tiny test
@@ -105,7 +145,8 @@ ms where this reads 0.594 (holding p·v across a branch costs more than the
 multiply it saves, and the body is MXU-bound), and at random weights no
 tile of any cell's shape takes the skip. With nothing ordering the heads
 the scheduler holds every head's score tile on its stack at once, so a
-program of seven heads asks for its scoped VMEM (``_fwd_vmem``).
+program of more than two heads of 128 asks for its scoped VMEM
+(``_fwd_vmem``).
 
 The softmax scale stays a multiply on the float32 scores in both forms:
 folding it into q where 1/sqrt(D) is a power of two (exact: the same bits)
@@ -134,7 +175,8 @@ are read and written by the aligned SEGMENTS between window edges
 (``_segments``), each head's product added into the segments of its window
 (``_into``). A call whose windows are narrower than its blocks says so in
 its kernel ``metadata`` (``_lanes``). A program takes several column
-blocks (``_pick_heads``); batch is a grid axis. Per-position scalars ride
+blocks (see "Heads a program"; a one-tile call ``_pick_heads``' budget);
+batch is a grid axis. Per-position scalars ride
 as ROW vectors — the additive bias [B, 1, S], lse [B·H, 1, S]: a [.., S, 1]
 column layout would be 128×-padded by the TPU's (8, 128) tiling — 2 GB of
 HBM for S=16k — so rows travel packed and are transposed to columns in
@@ -161,13 +203,7 @@ a row followed by the CLEAN one, both cut into blocks of B positions, and a
 query sees the clean blocks before its own (a clean query: its own too) and,
 where it is noisy, the noisy keys of its own block — keys AFTER it among
 them. A tile then needs up to TWO runs of tiles of the other axis (see
-"block-diffusion tiles" below); the kernels are named ``flash_bd_*``. A
-group that is no power of two (seven
-query heads a kv head) is ONE program, where a column block is one head
-(D=128): halving the heads a program takes never lands on a divisor of it,
-and one head a program — what halving falls to — ran the two backward
-kernels of the time at 63 / 68 % of their roofline against 90 / 90 % (v5e,
-28 / 4 x 128 at S=16,384, PR 36).
+"block-diffusion tiles" below); the kernels are named ``flash_bd_*``.
 
 Off-TPU (CPU tests, CI) the same kernels run under ``interpret=True``
 (``utils.backend.pallas_interpret`` decides, once, for every op here).
@@ -224,6 +260,9 @@ def _t(x):
 # --------------------------------------------- heads inside a column block
 
 
+_MOST_HEADS = 8  # of one program, in any call
+
+
 def _heads_per_block(h: int, d: int, dv: int) -> int:
     """Adjacent heads that share one column block: the fewest whose lanes
     fill whole 128-lane tiles at BOTH widths (2 at D=64, 1 at D=128, 2 at
@@ -236,27 +275,26 @@ def _heads_per_block(h: int, d: int, dv: int) -> int:
 
 def _pick_heads(h: int, g: int, block_q: int, block_k: int,
                 budget_mb: float) -> int:
-    """Heads per program, in whole column blocks of ``g``: amortise
-    grid-step overhead while keeping the per-head transient (fp32 scores +
-    bf16 probs ≈ 6·Bq·Bk bytes) within a conservative VMEM budget (~16
-    MB/core total on v5e)."""
+    """Heads per program of a ONE-TILE call, in whole column blocks of
+    ``g``: amortise grid-step overhead while keeping the per-head transient
+    (fp32 scores + bf16 probs ≈ 6·Bq·Bk bytes) within a conservative VMEM
+    budget (the compiler's own scoped limit: 16 MiB a core on a v5e). A
+    TILED call asks for its VMEM and takes what that holds
+    (``_heads_a_program``)."""
     per_head_mb = 6.0 * block_q * block_k / 2**20
-    n = max(1, 8 // g)
+    n = max(1, _MOST_HEADS // g)
     while n > 1 and ((h // g) % n or n * g * per_head_mb > budget_mb):
         n //= 2
     return n * g
 
 
-def _geometry(q, d: int, dv: int, block_q: int, block_k: int,
-              budget_mb: float):
-    """(B, S, H, heads per column block, heads per program, Bq, Bk) for
-    [B, S, H·D] operands (``q``: [B, S, H·d]; v and out are H·dv wide)."""
+def _geometry(q, d: int, dv: int, block_q: int, block_k: int):
+    """(B, S, H, heads per column block, Bq, Bk) for [B, S, H·D] operands
+    (``q``: [B, S, H·d]; v and out are H·dv wide)."""
     b, s, width = q.shape
     h = width // d
-    g = _heads_per_block(h, d, dv)
-    bq = _pick_block(s, block_q)
-    bk = _pick_block(s, block_k)
-    return b, s, h, g, _pick_heads(h, g, bq, bk, budget_mb), bq, bk
+    return (b, s, h, _heads_per_block(h, d, dv), _pick_block(s, block_q),
+            _pick_block(s, block_k))
 
 
 def _column_blocks(width: int, g: int, d: int, dv: int):
@@ -409,11 +447,19 @@ def _lanes(d: int, dv: int, g: int) -> Optional[dict]:
     return None if whole else lanes
 
 
-def _metadata(d: int, dv: int, g: int, q, k, mask) -> Optional[dict]:
+def _metadata(d: int, dv: int, g: int, q, k, mask,
+              hp: Optional[int] = None) -> Optional[dict]:
     """A call's kernel ``metadata``: its windows where they are narrower
     than its blocks (``_lanes``), its head counts where k has fewer than q,
     its band or its blocks where it has them; None for every other call
-    (see ``_lanes`` for why not more)."""
+    (see ``_lanes`` for why not more). Beside those, the query heads ONE
+    PROGRAM of a tiled call takes (``hp``: ``heads_a_program``, what
+    ``_heads_a_program`` chose) — except where the program is one whole
+    group of a grouped call, which ``heads`` / ``kv_heads`` say already, so
+    the calls that were whole groups before the count followed VMEM keep
+    their programs; and not as the ONLY field, for ``_lanes``' reason (in
+    Ouro's accumulate step it cost 47 MB of scratch the cell does not
+    have: there ``flash_vmem_mb`` says the count)."""
     h, kvh = q.shape[-1] // d, k.shape[-1] // d
     found = _lanes(d, dv, g) if kvh == h else {"heads": h, "kv_heads": kvh}
     if mask.band is not None:
@@ -421,6 +467,8 @@ def _metadata(d: int, dv: int, g: int, q, k, mask) -> Optional[dict]:
     if mask.blocks is not None:
         found = dict(found or {}, block=mask.blocks.length,
                      stream=mask.blocks.stream)
+    if found and hp is not None and hp * kvh != h:
+        found = dict(found, heads_a_program=hp)
     return found
 
 
@@ -451,15 +499,13 @@ def _dot(a, b, contract_a: int, contract_b: int):
 # ``members`` axis of the backward's grid — and is written once per kv head.
 
 
-def _grouped(q, k, d: int, dv: int, g: int, hp: int):
-    """(group, kv heads per kv block, query heads per program) of a call:
-    (1, hp, hp) with as many kv heads as heads. A grouped program's kv block
-    is whole column blocks (``g`` kv heads at least); where a program's
-    query heads span fewer kv heads than that, ``g·group / hp`` programs
-    share the block."""
+def _group(q, k, d: int, dv: int, g: int) -> int:
+    """Query heads a kv head of a call (1: as many kv heads as heads). A
+    grouped call's column block of ``g`` heads is whole lane tiles and
+    shares one kv head."""
     h, kvh = q.shape[-1] // d, k.shape[-1] // d
     if kvh == h:
-        return 1, hp, hp
+        return 1
     group = h // kvh
     if (d != dv or h % kvh or g > 2 or (g * d) % 128 or kvh % g
             or group % g):
@@ -468,18 +514,61 @@ def _grouped(q, k, d: int, dv: int, g: int, hp: int):
             f"heads of {d} only where a column block of {g} heads is whole "
             "lane tiles and shares one kv head (head widths 64 and 128)"
         )
+    return group
 
-    def kv_block(hp):
-        return max(g, hp // group)
 
-    if g == 1 and group & (group - 1):
-        # halving never lands on a divisor of a group that is no power of
-        # two (seven): a program takes one WHOLE group — its kv block
-        # fetched once, dk / dv summed inside the program
-        return group, 1, group
-    while hp > g and (kvh % kv_block(hp) or (kv_block(hp) * group) % hp):
-        hp //= 2
-    return group, kv_block(hp), hp
+def _head_plans(h: int, group: int, g: int):
+    """Every (query heads a program, kv heads a kv block) a tiled call of
+    ``h`` heads in groups of ``group`` may take, most heads first: whole
+    column blocks of ``g`` that divide the head count, ``_MOST_HEADS`` at
+    most. With as many kv heads as heads the kv block is the program's own
+    heads. A grouped program's kv block is whole column blocks (``g`` kv
+    heads at least) that divide the kv heads: the kv heads of a program
+    that spans whole groups, else ONE column block that ``g·group / heads``
+    programs share (the backward's ``members``) — so a group of eight or
+    fewer is one program (seven, six: no divisor between them and one),
+    sixteen heads over one kv head are two of eight."""
+    plans = []
+    for n in range(max(1, min(_MOST_HEADS // g, h // g)), 0, -1):
+        hp = n * g
+        kvb = hp if group == 1 else max(g, hp // group)
+        if (h % hp == 0 and (h // group) % kvb == 0 and kvb % g == 0
+                and (kvb * group) % hp == 0):
+            plans.append((hp, kvb))
+    return plans
+
+
+# what a core's VMEM can be asked for (a v5e's is 128 MiB; the compiler's own
+# scoped limit is 16): the ceiling of a tiled call, and of it what a program
+# of more than the fewest heads leaves free — the transients' figures are the
+# scheduler's habits at two and four heads, not a bound on it at eight
+_VMEM_CEILING = 112 * 2**20
+_VMEM_MARGIN = _VMEM_CEILING // 8
+
+
+def _heads_a_program(plans, ask):
+    """The (heads a program, kv heads a kv block) of a tiled call: the
+    first of ``plans`` (most heads first) whose ``ask(heads, kv heads)`` —
+    the scoped VMEM the call would ask for, bytes — leaves the margin under
+    ``_VMEM_CEILING``; the last, one column block, whatever it asks (the
+    backward refuses what passes the ceiling: ``_bwd_vmem``). The count
+    follows from the call's shapes alone — group, widths, S, tiles."""
+    for hp, kvb in plans[:-1]:
+        if ask(hp, kvb) <= _VMEM_CEILING - _VMEM_MARGIN:
+            return hp, kvb
+    return plans[-1]
+
+
+def _tiled_geometry(q, k, d: int, dv: int, block_q: int, block_k: int, ask):
+    """(B, S, H, heads per column block, heads per program, Bq, Bk, group,
+    kv heads per kv block) of a tiled call; ``ask(heads, kv heads, Bq,
+    Bk)``: the direction's VMEM ask."""
+    b, s, h, g, bq, bk = _geometry(q, d, dv, block_q, block_k)
+    group = _group(q, k, d, dv, g)
+    hp, kvb = _heads_a_program(
+        _head_plans(h, group, g), lambda hp, kvb: ask(hp, kvb, bq, bk)
+    )
+    return b, s, h, g, hp, bq, bk, group, kvb
 
 
 def _group_slot(h0: int, hp: int, kvb: int, group: int, g: int, program):
@@ -1090,33 +1179,49 @@ def _selection_specs(bq: int, bk: int, at):
     ]
 
 
-def _fwd_vmem(q, bq: int, bk: int, hp: int, kvb: int, d: int, dv: int,
-              selected: bool = False):
-    """Compiler parameters of a tiled forward call: None — the compiler's
-    own scoped-VMEM limit, 16 MiB on a v5e — unless the call needs more.
-    With the state lane-dense nothing orders a program's heads, so the
+def _fwd_ask(hp: int, kvb: int, bq: int, bk: int, *, d: int, dv: int,
+             size: int, selected: bool = False) -> int:
+    """Bytes of scoped VMEM a tiled forward call of ``hp`` heads a program
+    asks for (nothing in it grows with the sequence). With the state lane-dense nothing orders a program's heads, so the
     scheduler starts every head's q·kᵀ ahead of the first head's softmax and
     holds each head's score tile (float32, then bf16 probabilities:
-    6·Bq·Bk bytes, ``_pick_heads``' figure) on its stack at once. Four heads
-    at 512 x 512 fit beside the blocks and the state; a whole group of
-    seven (``_grouped``: 10.5 MB of tiles, 16.5 MB in all) does not, and
-    asks for what it needs."""
-    size = q.dtype.itemsize
+    6·Bq·Bk bytes) on its stack at once, beside the blocks (twice: the
+    pipeline's two buffers) and the state."""
     blocks = 2 * size * (bq * hp * (d + dv) + bk * kvb * (d + dv))
     state = 4 * bq * hp * (dv + 2 * STATE_LANES)
     # a selection's int8 tile, twice (the pipeline's two buffers), and the
     # tile's mask widened for the select
     need = blocks + state + hp * 6 * bq * bk + (6 * bq * bk if selected else 0)
-    if need <= 14 * 2**20:
+    return need + 4 * 2**20
+
+
+def _fwd_vmem(q, bq: int, bk: int, hp: int, kvb: int, d: int, dv: int,
+              selected: bool = False):
+    """Compiler parameters of a tiled forward call: None — the compiler's
+    own scoped-VMEM limit, 16 MiB on a v5e — unless the call needs more
+    (``_fwd_ask``): two heads of 128 at 512 x 512 fit it, a group of seven
+    or eight (26-28 MiB) asks for what it needs."""
+    ask = _fwd_ask(hp, kvb, bq, bk, d=d, dv=dv, size=q.dtype.itemsize,
+                   selected=selected)
+    if ask <= 18 * 2**20:
         return None
-    return pltpu.CompilerParams(vmem_limit_bytes=need + 4 * 2**20)
+    return pltpu.CompilerParams(vmem_limit_bytes=ask)
+
+
+def _fwd_geometry(q, k, d: int, dv: int, block_q: int, block_k: int,
+                  selected: bool = False):
+    """``_tiled_geometry`` of a tiled forward call: it holds nothing for the
+    sequence, so at the cells' tiles the most heads the call may take."""
+    return _tiled_geometry(q, k, d, dv, block_q, block_k, functools.partial(
+        _fwd_ask, d=d, dv=dv, size=q.dtype.itemsize, selected=selected,
+    ))
 
 
 def _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, mask, interpret,
                sel=None):
-    b, s, h, g, hp, bq, bk = _geometry(q, d, dv, block_q, block_k,
-                                       budget_mb=6.0)
-    group, kvb, hp = _grouped(q, k, d, dv, g, hp)
+    b, s, h, g, hp, bq, bk, group, kvb = _fwd_geometry(
+        q, k, d, dv, block_q, block_k, sel is not None
+    )
     hpb = h // hp  # programs across the width
 
     def k_at(j, kb):  # a tile above the diagonal re-names the last needed
@@ -1161,7 +1266,7 @@ def _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, mask, interpret,
         ],
         interpret=interpret,
         name=_name("fwd", mask, d, dv, group),
-        metadata=_metadata(d, dv, g, q, k, mask),
+        metadata=_metadata(d, dv, g, q, k, mask, hp),
         compiler_params=_fwd_vmem(q, bq, bk, hp, kvb, d, dv,
                                   sel is not None),
     )(q, k, v, bias, *(sel or ()))
@@ -1169,8 +1274,8 @@ def _fwd_tiled(q, k, v, bias, d, dv, block_q, block_k, mask, interpret,
 
 
 def _fwd_one_tile(q, k, v, bias, d, dv, mask, interpret):
-    b, s, h, g, hp, _bq, _bk = _geometry(q, d, dv, q.shape[1], q.shape[1],
-                                         budget_mb=6.0)
+    b, s, h, g, _bq, _bk = _geometry(q, d, dv, q.shape[1], q.shape[1])
+    hp = _pick_heads(h, g, s, s, budget_mb=6.0)
     hpb = h // hp
     wide = pl.BlockSpec((None, s, hp * d), lambda n, p: (n, 0, p))
     wide_v = pl.BlockSpec((None, s, hp * dv), lambda n, p: (n, 0, p))
@@ -1364,22 +1469,6 @@ def _dqkv_fused_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref,
                 ref[:, at] = total.astype(ref.dtype)
 
 
-# what a core's VMEM can be asked for (a v5e's is 128 MiB; the compiler's own
-# scoped limit is 16): the tiled backward's ceiling
-_VMEM_CEILING = 112 * 2**20
-
-
-def _bwd_geometry(q, k, d: int, dv: int, block_q: int, block_k: int):
-    """``_geometry`` of a tiled backward call with ``_grouped``'s (group, kv
-    heads per kv block) behind it, the heads per program as the group
-    leaves them."""
-    # bwd transients per head are ~3x the fwd's (s, p, dp, ds live at once)
-    b, s, h, g, hp, bq, bk = _geometry(q, d, dv, block_q, block_k,
-                                       budget_mb=4.0)
-    group, kvb, hp = _grouped(q, k, d, dv, g, hp)
-    return b, s, h, g, hp, bq, bk, group, kvb
-
-
 def _bwd_resident(s: int, kvb: int, d: int, dv: int, size: int):
     """Bytes a tiled backward call keeps in VMEM for a kv block's WHOLE
     sequence: (the float32 dk and dv accumulators, the dk and dv output
@@ -1387,39 +1476,61 @@ def _bwd_resident(s: int, kvb: int, d: int, dv: int, size: int):
     return 4 * s * kvb * (d + dv), 2 * size * s * kvb * (d + dv)
 
 
+def _bwd_ask(s: int, hp: int, kvb: int, bq: int, bk: int, *, d: int,
+             dv: int, size: int, selected: bool = False) -> int:
+    """Bytes of scoped VMEM the tiled backward of ``hp`` heads a program
+    asks for: what is resident for the whole sequence (``_bwd_resident``),
+    the blocks of a step (q, dO, O and dq a query tile; k and v a key tile;
+    twice), dq's accumulator, and a program's heads' transients (s, p, dp
+    and ds of a head, 18·Bq·Bk bytes: three times the forward's figure)."""
+    blocks = 2 * size * (2 * bq * hp * (d + dv) + bk * kvb * (d + dv))
+    transients = hp * 18 * bq * bk + (6 * bq * bk if selected else 0)
+    return (sum(_bwd_resident(s, kvb, d, dv, size)) + blocks
+            + 4 * bq * hp * d + transients + 4 * 2**20)
+
+
+def _bwd_geometry(q, k, d: int, dv: int, block_q: int, block_k: int,
+                  selected: bool = False):
+    """``_tiled_geometry`` of a tiled backward call: the most heads a
+    program whose transients fit beside what the kv block holds for the
+    whole sequence — a long sequence takes fewer heads (and, with as many
+    kv heads as heads, a narrower kv block) before it is refused."""
+    return _tiled_geometry(q, k, d, dv, block_q, block_k, functools.partial(
+        _bwd_ask, q.shape[1], d=d, dv=dv, size=q.dtype.itemsize,
+        selected=selected,
+    ))
+
+
 def _bwd_vmem(q, k, d: int, dv: int, block_q: int, block_k: int,
               selected: bool = False):
     """Compiler parameters of the tiled backward call on [B, S, H·d] ``q``
     and [B, S, H_kv·d] ``k`` (arrays or their shapes): the scoped VMEM it
-    asks for — what is resident for the whole sequence (``_bwd_resident``),
-    the blocks of a step (q, dO, O and dq a query tile; k and v a key tile;
-    twice), dq's accumulator, and a program's heads' transients (s, p, dp
-    and ds of a head: three times ``_pick_heads``' forward figure). None —
+    asks for (``_bwd_ask`` at the heads ``_bwd_geometry`` chose). None —
     the compiler's own limit, 16 MiB on a v5e — where that fits it (the
-    test models). The sequence is bounded HERE: a call whose need passes
-    ``_VMEM_CEILING`` is refused (4 + 4 bytes a row and lane of the kv
-    block: S = 32,768 still fits at a kv block of one head of 128 and two
-    heads a program, 65,536 does not; every cell is at or under 16,384)."""
+    test models). The sequence is bounded HERE: a call whose ask passes
+    ``_VMEM_CEILING`` at ONE column block a program is refused (4 + 4 bytes
+    a row and lane of the kv block: S = 32,768 still fits at a kv block of
+    one head of 128, four heads a program where 16,384 takes the group's
+    eight, and 49,152 at one; 53,248 does not; every cell is at or under
+    16,384)."""
     _b, s, _h, _g, hp, bq, bk, _group, kvb = _bwd_geometry(
-        q, k, d, dv, block_q, block_k
+        q, k, d, dv, block_q, block_k, selected
     )
     size = q.dtype.itemsize
-    accumulators, outputs = _bwd_resident(s, kvb, d, dv, size)
-    blocks = 2 * size * (2 * bq * hp * (d + dv) + bk * kvb * (d + dv))
-    transients = hp * 18 * bq * bk + (6 * bq * bk if selected else 0)
-    need = accumulators + outputs + blocks + 4 * bq * hp * d + transients
-    if need <= 14 * 2**20:
+    ask = _bwd_ask(s, hp, kvb, bq, bk, d=d, dv=dv, size=size,
+                   selected=selected)
+    if ask <= 18 * 2**20:
         return None
-    if need + 4 * 2**20 > _VMEM_CEILING:
+    if ask > _VMEM_CEILING:
         raise ValueError(
             f"tiled flash backward at S={s}: dk and dv of a kv block "
             f"({kvb} heads of {d} / {dv}) are held for the whole sequence "
-            f"in VMEM, {(accumulators + outputs) / 2**20:.0f} MiB of the "
-            f"{need / 2**20:.0f} the call needs, past the "
-            f"{_VMEM_CEILING // 2**20} MiB a core can be asked for; run "
-            "the sequence in shorter rows"
+            f"in VMEM, {sum(_bwd_resident(s, kvb, d, dv, size)) / 2**20:.0f}"
+            f" MiB of the {ask / 2**20:.0f} the call asks for at {hp} heads "
+            f"a program, past the {_VMEM_CEILING // 2**20} MiB a core can "
+            "be asked for; run the sequence in shorter rows"
         )
-    return pltpu.CompilerParams(vmem_limit_bytes=need + 4 * 2**20)
+    return pltpu.CompilerParams(vmem_limit_bytes=ask)
 
 
 def _bwd(q, k, v, bias, lse, do, out, d, dv, block_q, block_k, mask,
@@ -1441,7 +1552,7 @@ def _bwd_tiled(q, k, v, bias, lse, do, out, d, dv, block_q, block_k, mask,
     query tile's: the query programs that share a kv block (``members``;
     one where k has as many heads as q)."""
     b, s, h, g, hp, bq, bk, group, kvb = _bwd_geometry(
-        q, k, d, dv, block_q, block_k
+        q, k, d, dv, block_q, block_k, sel is not None
     )
     hpb, members, nq, nk = h // hp, kvb * group // hp, s // bq, s // bk
 
@@ -1499,16 +1610,16 @@ def _bwd_tiled(q, k, v, bias, lse, do, out, d, dv, block_q, block_k, mask,
         ],
         interpret=interpret,
         name=_name("bwd_tiled", mask, d, dv, group),
-        metadata=_metadata(d, dv, g, q, k, mask),
+        metadata=_metadata(d, dv, g, q, k, mask, hp),
         compiler_params=_bwd_vmem(q, k, d, dv, block_q, block_k,
                                   sel is not None),
     )(q, k, v, bias, lse, do, out, *(sel or ()))
 
 
 def _bwd_fused(q, k, v, bias, lse, do, out, d, dv, mask, interpret):
+    b, s, h, g, _bq, _bk = _geometry(q, d, dv, q.shape[1], q.shape[1])
     # fused kernel holds s, p, dp, ds (~4 full tiles) at once per head
-    b, s, h, g, hp, _bq, _bk = _geometry(q, d, dv, q.shape[1], q.shape[1],
-                                         budget_mb=3.0)
+    hp = _pick_heads(h, g, s, s, budget_mb=3.0)
     hpb = h // hp
     wide = pl.BlockSpec((None, s, hp * d), lambda n, p: (n, 0, p))
     wide_v = pl.BlockSpec((None, s, hp * dv), lambda n, p: (n, 0, p))
@@ -1647,11 +1758,13 @@ def flash_attention(
     padded. ``k`` and ``v`` may have FEWER heads than ``q`` (grouped-query
     attention: each kv head serves H / H_kv adjacent query heads): they are
     read at their own width, dk / dv are summed over a group inside the
-    kernel, named ``flash_gqa_*``; head widths 64 and 128. A group that is
-    no power of two (28 heads over 4: seven) gets a whole group a program
-    — its kv head fetched once, dk / dv summed inside the program — and is
-    taken at head width 128 alone (at 64 two heads share a lane tile and
-    a kv head: the group must be even). ``selection``: a mask of its own
+    kernel, named ``flash_gqa_*``; head widths 64 and 128. A group of
+    eight or fewer gets a whole group a program — its kv head fetched
+    once, dk / dv summed inside the program — where the VMEM the call asks
+    for holds it (module docstring, "Heads a program"); an odd group (28
+    heads over 4: seven) is taken at head width 128 alone (at 64 two heads
+    share a lane tile and a kv head: the group must be even).
+    ``selection``: a mask of its own
     that is an operand — query t sees the keys s <= t where this
     [B, S, S] (int8, rows queries) is not 0; see "selected tiles". The call
     then returns (out, lse [B, H, S] float32): the log-sum-exp over the

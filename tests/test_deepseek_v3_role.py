@@ -153,6 +153,10 @@ def test_two_width_kernels_contract_over_a_heads_own_lane_tiles():
         assert row["flash_windows"] == {
             "flash_mla_fwd": windowed, "flash_mla_bwd_tiled": windowed,
         }
+        # dk / dv of a program's OWN kv heads are resident in the backward
+        assert row["flash_heads"] == {
+            "flash_mla_fwd": 8, "flash_mla_bwd_tiled": 4,
+        }
     # the dense layer and the scanned expert layers: a forward site each
     assert rows["kanana_accumulate_step"]["flash_fwd_forms"] == {
         "one_tile": 0, "tiles": 2

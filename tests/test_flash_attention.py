@@ -352,23 +352,24 @@ def test_tiled_forward_carries_its_state(rng, h, kv, d, dv, band):
 
 
 @pytest.mark.parametrize(
-    "hp,kvb,d,dv,asks",
-    [(4, 4, 128, 128, False), (4, 4, 192, 128, False), (4, 2, 64, 64, False),
-     (7, 1, 128, 128, True)],
-    ids=["ouro", "kanana2", "lfm2", "smallthinker_group_of_seven"],
+    "hp,kvb,d,dv,asked_mb",
+    [(2, 2, 128, 128, None), (8, 8, 128, 128, 30.0), (8, 8, 192, 128, 32.0),
+     (8, 2, 64, 64, 23.5), (7, 1, 128, 128, 23.75), (8, 1, 128, 128, 26.5)],
+    ids=["two_heads", "ouro", "kanana2", "lfm2",
+         "smallthinker_group_of_seven", "a_group_of_eight"],
 )
-def test_only_a_program_of_seven_heads_asks_for_its_vmem(hp, kvb, d, dv,
-                                                         asks):
+def test_a_program_of_many_heads_asks_for_its_vmem(hp, kvb, d, dv, asked_mb):
     """The tiled forward's heads overlap, so the compiler holds a score
-    tile a head on its stack: four heads at 512 x 512 stay inside its
-    default limit and their calls carry no compiler parameters (the
-    programs they had); a whole group of seven asks for what it needs
-    (16.48 MB by the v5e compiler's own count, PR 37)."""
+    tile a head on its stack: two heads at 512 x 512 stay inside its
+    default limit and their call carries no compiler parameters; the eight
+    heads (a whole group of seven) a cell's program takes since PR 58 ask
+    for what they need (seven: 16.48 MB by the v5e compiler's own count, PR
+    37, 23.75 MiB asked) — a fraction of what the backward holds."""
     from dedloc_tpu.ops.flash_attention import _fwd_vmem
 
     q = jax.ShapeDtypeStruct((1, 4096, hp * d), jnp.bfloat16)
     params = _fwd_vmem(q, 512, 512, hp, kvb, d, dv)
-    if not asks:
+    if asked_mb is None:
         assert params is None
     else:
-        assert 16.48 * 2**20 < params.vmem_limit_bytes <= 32 * 2**20
+        assert params.vmem_limit_bytes == asked_mb * 2**20

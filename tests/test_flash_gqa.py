@@ -9,7 +9,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dedloc_tpu.ops.flash_attention import _grouped, flash_attention
+from dedloc_tpu.ops.flash_attention import (
+    _bwd_geometry,
+    _fwd_geometry,
+    flash_attention,
+)
 from tests.test_flash_mla import (
     PARENT_DIGESTS,
     _arithmetic_canary,
@@ -160,20 +164,27 @@ def test_kernel_names_and_metadata_follow_the_head_counts():
 
 
 def test_the_kv_block_plan():
-    """(group, kv heads a kv block, query heads a program): a program's kv
-    block is whole column blocks; fewer query heads than a block's groups
-    share it between programs."""
-    def plan(h, kv, d, hp):
-        q = jnp.zeros((1, 8, h * d))
-        k = jnp.zeros((1, 8, kv * d))
-        return _grouped(q, k, d, d, 2 if d == 64 else 1, hp)
+    """(query heads a program, group, kv heads a kv block) of a call at the
+    cells' tiles, forward | backward: a program's kv block is whole column
+    blocks; a program takes a whole kv block's query heads where eight
+    heads hold them (and the VMEM it asks for holds THEM), and shares the
+    block with other programs where they do not."""
+    def plan(h, kv, d, seq=4096):
+        q = jax.ShapeDtypeStruct((1, seq, h * d), jnp.bfloat16)
+        k = jax.ShapeDtypeStruct((1, seq, kv * d), jnp.bfloat16)
+        return tuple(
+            geometry(q, k, d, d, 512, 512)[i]
+            for geometry in (_fwd_geometry, _bwd_geometry) for i in (4, 7, 8)
+        )
 
-    assert plan(32, 32, 64, 4) == (1, 4, 4)
-    assert plan(32, 8, 64, 4) == (4, 2, 4)  # two programs a kv block
-    assert plan(32, 8, 64, 2) == (4, 2, 2)  # four (the backward's)
-    assert plan(32, 16, 64, 8) == (2, 4, 8)  # one program, four kv heads
-    assert plan(16, 4, 128, 4) == (4, 1, 4)
-    assert plan(16, 4, 128, 2) == (4, 1, 2)
+    assert plan(32, 32, 64) == (8, 1, 8) * 2
+    assert plan(32, 8, 64) == (8, 4, 2) * 2  # one program a kv block
+    assert plan(32, 16, 64) == (8, 2, 4) * 2  # one program, four kv heads
+    assert plan(16, 4, 128) == (8, 4, 2) * 2  # two whole groups of four
+    assert plan(16, 1, 128) == (8, 16, 1) * 2  # two programs a kv block
+    # dk / dv of eight kv heads of 128 do not fit at S=16,384 beside eight
+    # heads' transients: four heads over their two kv heads
+    assert plan(16, 8, 128, 16384) == (8, 2, 4, 4, 2, 2)
 
 
 @pytest.mark.parametrize(
@@ -188,3 +199,19 @@ def test_shapes_the_grouped_kernels_do_not_take(h, kv, d, dv):
     v = jnp.zeros((1, 64, kv, dv), jnp.float32)
     with pytest.raises(ValueError, match="grouped-query"):
         flash_attention(q, k, v, causal=True, block_q=32, block_k=32)
+
+
+def test_a_row_of_32768_still_compiles_at_four_heads_a_program():
+    """The degrade path through Mosaic: at twice the cells' longest row dk /
+    dv of a kv block leave no room for a group of eight's transients, and
+    the backward compiles for a v5e at FOUR heads a program inside the
+    112 MiB a core can be asked for; the forward keeps the eight."""
+    from tests.tpu_aot_rows import tpu_aot
+
+    row = tpu_aot("long_row_kernels")["long_row_kernels"]
+    assert row["flash_heads"] == {
+        "flash_gqa_fwd": 8, "flash_gqa_bwd_tiled": 4,
+    }
+    assert row["flash_vmem_mb"] == {
+        "flash_gqa_fwd": 26.5, "flash_gqa_bwd_tiled": 91.5,
+    }

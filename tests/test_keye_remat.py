@@ -155,6 +155,13 @@ def test_keye_accumulate_step_reads_a_selection_and_holds_nothing_heads_by_s_by_
         assert row["flash_windows"] == {
             "flash_sel_fwd": heads, "flash_sel_bwd_tiled": heads,
         }
+        # a whole group of eight a program, forward and backward (PR 58)
+        assert row["flash_heads"] == {
+            "flash_sel_fwd": 8, "flash_sel_bwd_tiled": 8,
+        }
+        assert row["flash_vmem_mb"] == {
+            "flash_sel_fwd": 28.0, "flash_sel_bwd_tiled": 84.0,
+        }
     assert rows["sel_kernels"]["kernel_calls"] == {
         "flash_sel_fwd": 1, "flash_sel_bwd_tiled": 1,
     }

@@ -55,6 +55,10 @@ def test_laguna_accumulate_step_takes_a_band_equal_to_the_tile_and_a_group_of_si
             "flash_band_fwd": band, "flash_band_bwd_tiled": band,
             "flash_gqa_fwd": full, "flash_gqa_bwd_tiled": full,
         }
+        assert rows[name]["flash_heads"] == {  # whole groups: 8 and 6
+            "flash_band_fwd": 8, "flash_band_bwd_tiled": 8,
+            "flash_gqa_fwd": 6, "flash_gqa_bwd_tiled": 6,
+        }
     assert rows["head_gate_kernels"]["kernel_calls"] == {
         "head_gate_fwd": 2, "head_gate_bwd": 2,  # 64 heads, 48 heads
     }

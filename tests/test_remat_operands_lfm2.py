@@ -45,6 +45,10 @@ def test_lfm2_accumulate_step_keeps_what_its_backward_reads():
         assert row["flash_windows"] == {
             "flash_gqa_fwd": heads, "flash_gqa_bwd_tiled": heads,
         }
+        # the four column blocks over ONE column block of two kv heads
+        assert row["flash_heads"] == {
+            "flash_gqa_fwd": 8, "flash_gqa_bwd_tiled": 8,
+        }
     row = rows["lfm2_accumulate_step"]
     assert row["kernel_calls"] == {
         "flash_gqa_fwd": 1, "flash_gqa_bwd_tiled": 1,

@@ -49,6 +49,9 @@ def test_sdar_accumulate_step_takes_the_block_rule_and_a_group_of_eight():
         assert row["flash_windows"] == {
             "flash_bd_fwd": blocks, "flash_bd_bwd_tiled": blocks,
         }
+        assert row["flash_heads"] == {  # a whole group a program
+            "flash_bd_fwd": 8, "flash_bd_bwd_tiled": 8,
+        }
     row = rows["sdar_accumulate_step"]
     assert row["kernel_calls"] == {
         "flash_bd_fwd": 4, "flash_bd_bwd_tiled": 4,

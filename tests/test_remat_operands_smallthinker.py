@@ -51,6 +51,7 @@ def test_smallthinker_accumulate_step_takes_the_band_and_a_group_of_seven():
             "flash_band_fwd": band, "flash_band_bwd_tiled": band,
             "flash_gqa_fwd": heads, "flash_gqa_bwd_tiled": heads,
         }
+        assert set(row["flash_heads"].values()) == {7}  # as before PR 58
     row = rows["smallthinker_accumulate_step"]
     assert row["kernel_calls"] == {
         "flash_band_fwd": 3, "flash_band_bwd_tiled": 3,

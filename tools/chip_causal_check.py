@@ -5,7 +5,11 @@ three gradients, in bf16 against a float32 reference at matmul precision
 DEVICE time per call with its share of the roofline — a profiler window
 over the same calls, read as the benchmark reads its
 ``flash_causal_*_roofline`` metrics (``benchmark/trace.py``,
-``benchmark/flops_lm.causal_kernel_cost``).
+``benchmark/flops_lm.causal_kernel_cost``), beside what the call chose from
+its shapes (``plan``: the heads a program of each direction, ``fwd_vmem_mb``
+/ ``bwd_vmem_mb``). ``--at-most-heads 4 2`` also times the call at no more
+than those heads a program (4 forward, 2 backward: the counts until PR 58)
+and holds the forward's output to the chosen count's bit for bit.
 
     chiprun --chips 1 -- python tools/chip_causal_check.py
 
@@ -13,10 +17,10 @@ Prints one JSON line; exit code 1 if an error exceeds 0.02 relative L2
 (bf16 rounding of the operands alone is ~0.004)."""
 from __future__ import annotations
 
+import argparse
 import json
 import os
 import sys
-import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -24,13 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmark.flops_lm import causal_kernel_cost
-from dedloc_tpu.ops.flash_attention import flash_attention
-from tools.chip_gqa_check import (
-    bwd_vmem_mb,
-    device_times,
-    kernel_cost,
-    traced_ops,
-)
+from tools.chip_gqa_check import causal_call_report, kernel_cost
 
 B, S, H, D = 1, 4096, 16, 128
 KERNELS = ("flash_causal_fwd", "flash_causal_bwd_tiled")
@@ -46,61 +44,18 @@ def causal_cost(kernel: str):
     )
 
 
-def dense(q, k, v):
-    with jax.default_matmul_precision("highest"):
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(jnp.float32(D))
-        s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -jnp.inf)
-        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
-
-
-def rel(a, b):
-    a, b = a.astype(jnp.float32), b.astype(jnp.float32)
-    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
-
-
-def main() -> int:
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--at-most-heads", type=int, nargs="*", default=[])
+    opts = parser.parse_args(argv)
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
     q, k, v, w = (jax.random.normal(x, (B, S, H, D), jnp.float32) for x in keys)
-    bf = lambda x: x.astype(jnp.bfloat16)  # noqa: E731
-
-    def flash_loss(q, k, v):
-        out = flash_attention(q, k, v, causal=True)
-        return jnp.sum(out.astype(jnp.float32) * w), out
-
-    def dense_loss(q, k, v):
-        out = dense(q, k, v)
-        return jnp.sum(out * w), out
-
-    flash = jax.jit(jax.value_and_grad(flash_loss, (0, 1, 2), has_aux=True))
-    (_, out), grads = flash(bf(q), bf(k), bf(v))
-    # the reference sees the same bf16-rounded operands, in float32
-    r = lambda x: bf(x).astype(jnp.float32)  # noqa: E731
-    (_, ref_out), ref_grads = jax.jit(
-        jax.value_and_grad(dense_loss, (0, 1, 2), has_aux=True)
-    )(r(q), r(k), r(v))
-    errors = {"out": rel(out, ref_out)}
-    errors.update(
-        {n: rel(g, rg) for n, g, rg in zip(("dq", "dk", "dv"), grads, ref_grads)}
-    )
-    jax.block_until_ready(flash(bf(q), bf(k), bf(v)))
-    start = time.perf_counter()
-    for _ in range(20):
-        result = flash(bf(q), bf(k), bf(v))
-    jax.block_until_ready(result)
-    wall_ms = (time.perf_counter() - start) / 20 * 1e3
-    print(json.dumps({
-        "device": jax.devices()[0].device_kind, "shape": [B, S, H, D],
-        "relative_l2": errors,
-        "fwd_plus_bwd_wall_ms": wall_ms,
-        "bwd_vmem_mb": bwd_vmem_mb(q, k, v),
-        "kernels": device_times(
-            traced_ops(lambda: flash(bf(q), bf(k), bf(v))), {
-                kernel: (lambda _on_chip, kernel=kernel: causal_cost(kernel))
-                for kernel in KERNELS
-            },
-        ),
-    }))
-    return 0 if max(errors.values()) <= 0.02 else 1
+    report, ok = causal_call_report(q, k, v, w, {
+        kernel: (lambda _on_chip, kernel=kernel: causal_cost(kernel))
+        for kernel in KERNELS
+    }, opts.at_most_heads)
+    print(json.dumps(report))
+    return 0 if ok else 1
 
 
 if __name__ == "__main__":

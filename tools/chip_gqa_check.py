@@ -8,8 +8,10 @@ time per call with its share of the roofline, from a profiler window over
 the same calls, read as the benchmark reads its ``flash_gqa_*_roofline`` and
 ``short_conv_*_roofline`` metrics (``benchmark/trace.py``,
 ``benchmark/flops_lfm2.py``; the ONE backward kernel, ``*_bwd_tiled``, by
-``kernel_cost``: 5 matmuls a tile), and the scoped VMEM that backward asks
-for (``bwd_vmem_mb``: it holds dk and dv for the whole sequence).
+``kernel_cost``: 5 matmuls a tile), and what the call chose from its shapes
+(``plan``: the query heads a program of each direction, ``fwd_vmem_mb`` and
+``bwd_vmem_mb`` — the scoped VMEM each asks for; the backward holds dk and dv
+for the whole sequence).
 
     chiprun --chips 1 -- python tools/chip_gqa_check.py
 
@@ -18,11 +20,14 @@ Another shape and a BAND (a sliding window inside the kernels, named
 the tiles inside the band) by flags — SmallThinker's 28 query heads over 4 kv
 heads of 128 at S=16,384, its band of 4,096 and the global layer's full
 triangle, each also with ONE query head a program in place of the whole
-group of seven the kernels take (the question PR 36 settled on the chip):
+group of seven the kernels take (the question PR 36 settled on the chip;
+``--at-most-heads 4 2`` times a group of eight's call at the four heads a
+program forward and the two backward it took until PR 58, and holds the
+forward's output to the chosen count's bit for bit):
 
     chiprun --chips 1 -- python tools/chip_gqa_check.py --heads 28 \
         --kv-heads 4 --head-dim 128 --seq 16384 --band 4096 none \
-        --one-head-programs --conv 0
+        --at-most-heads 1 --conv 0
 
 The two-stream BLOCK-DIFFUSION rule (``flash_bd_*``, held to
 ``benchmark/flops_sdar.py``'s count of the rule's tiles) by ``--block-diffusion
@@ -173,19 +178,31 @@ def kernel_cost(cost, kernel: str):
     return dq + dkv - fwd, max(dq_bytes, dkv_bytes)
 
 
-def bwd_vmem_mb(q, k, v, block_k: int = 512, selected: bool = False):
-    """MiB of scoped VMEM the tiled backward of a call on [B, S, H, D]
-    operands asks for (None: the compiler's own 16), and of them what holds
-    dk and dv for the whole sequence."""
+def call_plan(q, k, v, block_k: int = 512, selected: bool = False):
+    """What a tiled call on [B, S, H, D] operands chose from its shapes
+    (``ops/flash_attention._heads_a_program``): the query heads ONE PROGRAM
+    of the forward and of the backward takes, the MiB of scoped VMEM each
+    asks for (None: the compiler's own 16) and, of the backward's, what
+    holds dk and dv for the whole sequence."""
     fa = importlib.import_module("dedloc_tpu.ops.flash_attention")
     d, dv = q.shape[-1], v.shape[-1]
     flat = [jax.ShapeDtypeStruct((*x.shape[:2], x.shape[2] * x.shape[3]),
                                  jnp.bfloat16) for x in (q, k)]
-    asked = fa._bwd_vmem(*flat, d, dv, 512, block_k, selected)
-    kvb = fa._bwd_geometry(*flat, d, dv, 512, block_k)[-1]
+    fwd = fa._fwd_geometry(*flat, d, dv, 512, block_k, selected)
+    bwd = fa._bwd_geometry(*flat, d, dv, 512, block_k, selected)
+    asks = (
+        fa._fwd_vmem(flat[0], 512, fwd[6], fwd[4], fwd[8], d, dv, selected),
+        fa._bwd_vmem(*flat, d, dv, 512, block_k, selected),
+    )
+    fwd_mb, bwd_mb = (
+        ask and ask.vmem_limit_bytes / 2**20 for ask in asks
+    )
     return {
-        "asked": asked and asked.vmem_limit_bytes / 2**20,
-        "resident": sum(fa._bwd_resident(q.shape[1], kvb, d, dv, 2)) / 2**20,
+        "heads_a_program": {"fwd": fwd[4], "bwd": bwd[4]},
+        "fwd_vmem_mb": fwd_mb, "bwd_vmem_mb": bwd_mb,
+        "bwd_resident_mb": sum(
+            fa._bwd_resident(q.shape[1], bwd[8], d, dv, 2)
+        ) / 2**20,
     }
 
 
@@ -298,23 +315,97 @@ def dense(q, k, v, band):
 
 
 @contextlib.contextmanager
-def one_head_programs():
-    """The plan ``_grouped`` would fall to by halving: ONE query head a
-    program, a group's programs sharing its kv block."""
+def at_most_heads(n: int):
+    """The kernels with at most ``n`` query heads a program — the plans
+    ``_heads_a_program`` chooses among cut to those (a group with no such
+    divisor but one, seven: ONE head a program, its programs sharing the kv
+    block) — to time a call at another count than its shapes choose: 4
+    forward and 2 backward were every power-of-two group's until PR 58."""
     fa = importlib.import_module("dedloc_tpu.ops.flash_attention")
-    whole = fa._grouped
-    fa._grouped = lambda q, k, d, dv, g, hp: (
-        q.shape[-1] // k.shape[-1], 1, 1
-    )
+    plans = fa._head_plans
+    fa._head_plans = lambda h, group, g: [
+        plan for plan in plans(h, group, g) if plan[0] <= n
+    ] or plans(h, group, g)[-1:]
     try:
         yield
     finally:
-        fa._grouped = whole
+        fa._head_plans = plans
+
+
+def bits_differ(a, b) -> float:
+    """Share of elements of two arrays of one dtype whose bits differ."""
+    return float(jnp.mean(a != b))
 
 
 def rel(a, b):
     a, b = a.astype(jnp.float32), b.astype(jnp.float32)
     return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+
+def causal_call_report(q, k, v, w, costs, at_most=()):
+    """What ``chip_causal_check.py`` and ``chip_mla_check.py`` print for
+    ONE causal flash call on float32 [B, S, H, D] operands (``w``: the
+    weights of the scalar loss; ``costs``: ``device_times``' kernels): bf16
+    against the float32 ``dense`` on the same rounded operands, the wall of
+    a forward + backward, each kernel's device ms and roofline share and
+    the call's ``plan`` — then the same with at most each of ``at_most``
+    heads a program, the forward held to the chosen count's bit for bit.
+    Returns (the report, whether every error is inside its limit)."""
+    bf = lambda x: x.astype(jnp.bfloat16)  # noqa: E731
+    r = lambda x: bf(x).astype(jnp.float32)  # noqa: E731
+    operands = bf(q), bf(k), bf(v)
+
+    def loss(op):
+        def scalar(q, k, v):
+            out = op(q, k, v)
+            return jnp.sum(out.astype(jnp.float32) * w), out
+        return jax.jit(jax.value_and_grad(scalar, (0, 1, 2), has_aux=True))
+
+    def build():
+        return loss(lambda q, k, v: flash_attention(q, k, v, causal=True))
+
+    def timed(step):
+        jax.block_until_ready(step(*operands))
+        start = time.perf_counter()
+        for _ in range(20):
+            result = step(*operands)
+        jax.block_until_ready(result)
+        return {
+            "fwd_plus_bwd_wall_ms": (time.perf_counter() - start) / 20 * 1e3,
+            "plan": call_plan(q, k, v),
+            "kernels": device_times(
+                traced_ops(lambda: step(*operands)), costs
+            ),
+        }
+
+    flash = build()
+    (_, out), grads = flash(*operands)
+    (_, ref_out), ref_grads = loss(
+        lambda q, k, v: dense(q, k, v, None)
+    )(r(q), r(k), r(v))
+    errors = {"out": rel(out, ref_out)}
+    errors.update({
+        n: rel(g, rg) for n, g, rg in zip(("dq", "dk", "dv"), grads, ref_grads)
+    })
+    del ref_out, ref_grads
+    found, exact = timed(flash), {}
+    for most in at_most:
+        with at_most_heads(most):
+            capped = build()
+            (_, out1), grads1 = capped(*operands)
+            exact[f"at_most_{most}.out_bits_differ"] = bits_differ(out1, out)
+            errors.update({
+                f"at_most_{most}.{n}": rel(g1, g)
+                for n, g1, g in zip(("dq", "dk", "dv"), grads1, grads)
+            })
+            found[f"at_most_{most}"] = timed(capped)
+    report = {
+        "device": jax.devices()[0].device_kind, "shape": list(q.shape),
+        "v_width": v.shape[-1], "relative_l2": errors, "bits_differ": exact,
+        **found,
+    }
+    same = max(exact.values(), default=0.0) == 0.0
+    return report, same and max(errors.values()) <= 0.02
 
 
 def main(argv=None) -> int:
@@ -328,8 +419,10 @@ def main(argv=None) -> int:
         help="bands to check, each a length or 'none' (the causal mask)",
     )
     parser.add_argument(
-        "--one-head-programs", action="store_true",
-        help="also time each band with one query head a program",
+        "--at-most-heads", type=int, nargs="*", default=[],
+        help="also time each band with at most this many query heads a "
+             "program (each count given), the forward held to the chosen "
+             "count's bit for bit",
     )
     parser.add_argument(
         "--block-diffusion", type=int, default=0,
@@ -392,7 +485,7 @@ def main(argv=None) -> int:
             )
         return flash
 
-    errors, kernels, vmem = {}, {}, {}
+    errors, exact, kernels, plans = {}, {}, {}, {}
     for band, block_k in itertools.product(bands, opts.block_k):
         tag = "causal" if band is None else f"band_{band}"
         family = "flash_gqa" if band is None else "flash_band"
@@ -403,7 +496,8 @@ def main(argv=None) -> int:
         if block_k != 512:
             tag += f".bk{block_k}"
         flash = flash_at(block_k)
-        vmem[tag] = bwd_vmem_mb(q, k, v, block_k, isinstance(band, Selected))
+        selected = isinstance(band, Selected)
+        plans[tag] = call_plan(q, k, v, block_k, selected)
         step = attention(flash, band)
         (_, out), grads = step(bf(q), bf(k), bf(v))
         (_, ref_out), ref_grads = attention(dense, band)(r(q), r(k), r(v))
@@ -423,17 +517,19 @@ def main(argv=None) -> int:
         kernels[tag] = device_times(
             traced_ops(lambda: step(bf(q), bf(k), bf(v))), costs
         )
-        if opts.one_head_programs:
-            with one_head_programs():
-                single = attention(flash, band)
-                (_, out1), grads1 = single(bf(q), bf(k), bf(v))
-                errors[f"{tag}.one_head.out"] = rel(out1, out)
+        for most in opts.at_most_heads:
+            with at_most_heads(most):
+                capped, at = attention(flash, band), f"{tag}.at_most_{most}"
+                plans[at] = call_plan(q, k, v, block_k, selected)
+                (_, out1), grads1 = capped(bf(q), bf(k), bf(v))
+                # heads are independent in the forward: the same bits
+                exact[f"{at}.out_bits_differ"] = bits_differ(out1, out)
                 errors.update({
-                    f"{tag}.one_head.{n}": rel(g1, g) for n, g1, g in
+                    f"{at}.{n}": rel(g1, g) for n, g1, g in
                     zip(("dq", "dk", "dv"), grads1, grads)
                 })
-                kernels[f"{tag}.one_head"] = device_times(
-                    traced_ops(lambda: single(bf(q), bf(k), bf(v))), costs
+                kernels[at] = device_times(
+                    traced_ops(lambda: capped(bf(q), bf(k), bf(v))), costs
                 )
 
     extra = {}
@@ -522,8 +618,8 @@ def main(argv=None) -> int:
         pair = gated(gate_heads)
         out, d_ctx, d_gate = pair(ctx, gate, dy)
         ref_out, ref_d_ctx, ref_d_gate = gated(gate_heads_xla)(ctx, gate, dy)
-        errors["gate.out_bits_differ"] = float(jnp.mean(out != ref_out))
-        errors["gate.d_ctx_bits_differ"] = float(jnp.mean(d_ctx != ref_d_ctx))
+        exact["gate.out_bits_differ"] = bits_differ(out, ref_out)
+        exact["gate.d_ctx_bits_differ"] = bits_differ(d_ctx, ref_d_ctx)
         errors["gate.d_gate"] = rel(d_gate, ref_d_gate)
         kernels["head_gate"] = device_times(
             traced_ops(lambda: pair(ctx, gate, dy)), {
@@ -536,11 +632,11 @@ def main(argv=None) -> int:
     print(json.dumps({
         "device": jax.devices()[0].device_kind,
         "shape": {"attention": [B, S, H, KV, D], "conv": [B, S, 3 * HIDDEN]},
-        "relative_l2": errors, "kernels": kernels, "bwd_vmem_mb": vmem,
-        **extra,
+        "relative_l2": errors, "bits_differ": exact, "kernels": kernels,
+        "plan": plans, **extra,
     }))
-    exact = errors.get("gate.out_bits_differ", 0.0) == 0.0
-    return 0 if exact and max(errors.values(), default=0.0) <= 0.02 else 1
+    same = max(exact.values(), default=0.0) == 0.0
+    return 0 if same and max(errors.values(), default=0.0) <= 0.02 else 1
 
 
 if __name__ == "__main__":

@@ -12,7 +12,7 @@ speed or numerics; those need the chip (``chip_smoke.py``).
     OURO_LAYERS=4 OURO_BATCH=1 python tools/tpu_aot.py ouro_accumulate_step
 
 Each line: {"program", "compile_s", "tpu_custom_calls", "flash_fwd_forms",
-"flash_windows", "flash_vmem_mb", "layer_body_copies", "memory"} (and, for the programs of
+"flash_windows", "flash_vmem_mb", "flash_heads", "layer_body_copies", "memory"} (and, for the programs of
 ``COUNT_KERNEL_CALLS``, "kernel_calls": call sites by kernel name; for the
 expert programs, "expert_grad_passes": ``expert_grad_passes``' counts; for
 the seven decoder programs of ``LM_CELLS``, "remat_policy": the layer policy
@@ -33,7 +33,9 @@ block, from the call's metadata (``"block"`` for a call that carries none:
 D=64, D=128; its head counts for a grouped-query call);
 ``flash_vmem_mb`` is the scoped VMEM a flash kernel asks for beyond the
 compiler's own 16 MiB (the tiled backward holds dk and dv for the whole
-sequence there, PR 56); ``flash_fwd_forms`` counts the flash
+sequence there, PR 56); ``flash_heads`` is the query heads ONE PROGRAM of a
+tiled flash kernel takes — the most that VMEM holds, a whole kv group where
+it fits (``ops/flash_attention._heads_a_program``, PR 58); ``flash_fwd_forms`` counts the flash
 forward call SITES of the lowered module by the form their shapes chose
 (``one_tile``: one tile covers the sequence; ``tiles``: the online-softmax
 kernel). A scanned layer body is one site however often it runs, and a
@@ -319,6 +321,28 @@ def sel_kernels(device):
     selection = jax.ShapeDtypeStruct((1, 16384, 16384), jnp.int8)
     return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         *_on_device(device, (q, kv, kv, selection))
+    )
+
+
+def long_row_kernels(device):
+    """The degrade path of the tiled backward's heads a program
+    (``ops/flash_attention._heads_a_program``): the grouped causal kernels
+    at 32 query heads over 4 kv heads of 128 and S=32,768 — twice the cells'
+    longest row, where dk / dv of a kv block (64 MiB) leave no room for a
+    group of eight's transients, so a program takes FOUR heads (91.5 MiB
+    asked) and two programs share the kv block; the forward takes the
+    eight — fwd+bwd."""
+    from dedloc_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True).astype(
+            jnp.float32
+        ))
+
+    q = jax.ShapeDtypeStruct((1, 32768, 32, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 32768, 4, 128), jnp.bfloat16)
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_on_device(device, (q, kv, kv))
     )
 
 
@@ -661,6 +685,34 @@ def flash_fwd_forms(lowered_text: str) -> dict:
     return {"one_tile": one_tile, "tiles": len(calls) - one_tile}
 
 
+def _flash_metadata(lowered_text: str):
+    """(kernel name, its call's kernel metadata: {} where it carries none)
+    of each flash call site of a lowered module."""
+    for line in lowered_text.splitlines():
+        name = re.search(r'kernel_name = "(flash_\w+)"', line)
+        if not name:
+            continue
+        meta = re.search(r'kernel_metadata = "(\{[^"]*\})"', line)
+        yield name.group(1), json.loads(
+            meta.group(1).replace("\\0A", "").replace("\\22", '"')
+        ) if meta else {}
+
+
+def _by_kernel(found: dict) -> dict:
+    """``found`` (kernel name -> what each call site says, repeats dropped):
+    the one value where the sites agree, a list where they differ."""
+    return {
+        name: sites[0] if len(sites) == 1 else sites
+        for name, sites in sorted(found.items())
+    }
+
+
+def _site(found: dict, name: str, value) -> None:
+    sites = found.setdefault(name, [])
+    if value not in sites:
+        sites.append(value)
+
+
 def flash_windows(lowered_text: str) -> dict:
     """The flash kernels of a lowered module, by kernel name: the lanes a
     head's products contract over and land in (``qk_window``, ``v_window``)
@@ -672,24 +724,30 @@ def flash_windows(lowered_text: str) -> dict:
     programs that do not need it. A list where call sites of one name
     differ."""
     found = {}
-    for line in lowered_text.splitlines():
-        name = re.search(r'kernel_name = "(flash_\w+)"', line)
-        if not name:
-            continue
-        meta = re.search(r'kernel_metadata = "(\{[^"]*\})"', line)
-        meta = json.loads(
-            meta.group(1).replace("\\0A", "").replace("\\22", '"')
-        ) if meta else {}
-        windows = {
-            key: value for key, value in meta.items() if key != "form"
-        } or "block"
-        sites = found.setdefault(name.group(1), [])
-        if windows not in sites:
-            sites.append(windows)
-    return {
-        name: sites[0] if len(sites) == 1 else sites
-        for name, sites in sorted(found.items())
-    }
+    for name, meta in _flash_metadata(lowered_text):
+        _site(found, name, {
+            key: value for key, value in meta.items()
+            if key not in ("form", "heads_a_program")
+        } or "block")
+    return _by_kernel(found)
+
+
+def flash_heads(lowered_text: str) -> dict:
+    """The query heads ONE PROGRAM of each tiled flash kernel of a lowered
+    module takes, by kernel name — what ``ops/flash_attention.
+    _heads_a_program`` chose from the call's shapes and the VMEM it asks
+    for: the call's ``heads_a_program``, or one whole group (``heads`` /
+    ``kv_heads``) for the grouped call that leaves the field out. Eight for
+    Keye's selected kernels, seven for SmallThinker's, 8 forward and 4
+    backward for Ouro's sixteen heads (dk / dv of the program's own kv heads
+    are resident there). A one-tile call has no entry."""
+    found = {}
+    for name, meta in _flash_metadata(lowered_text):
+        if "heads_a_program" in meta:
+            _site(found, name, meta["heads_a_program"])
+        elif "kv_heads" in meta:
+            _site(found, name, meta["heads"] // meta["kv_heads"])
+    return _by_kernel(found)
 
 
 def flash_vmem_mb(lowered_text: str) -> dict:
@@ -697,8 +755,8 @@ def flash_vmem_mb(lowered_text: str) -> dict:
     (its custom call's ``scoped_memory_configs``), by kernel name; a kernel
     that runs under the compiler's own limit (16 on a v5e) is left out. The
     tiled backward holds a kv block's dk and dv for the whole sequence
-    there (``ops/flash_attention._bwd_vmem``): 49.5 at the Keye cell's
-    shape, 76.75 for SmallThinker's group of seven."""
+    there (``ops/flash_attention._bwd_vmem``): 84 at the Keye cell's shape
+    (eight heads a program), 76.75 for SmallThinker's group of seven."""
     found = {}
     for line in lowered_text.splitlines():
         name = re.search(r'kernel_name = "(flash_\w+)"', line)
@@ -706,14 +764,9 @@ def flash_vmem_mb(lowered_text: str) -> dict:
             r'scoped_memory_configs\\22: \[\{[^}]*\\22size\\22: (\d+)', line
         )
         if name and size:
-            sizes = found.setdefault(name.group(1), [])
-            mb = round(int(size.group(1)) / 2**20, 2)
-            if mb not in sizes:
-                sizes.append(mb)
-    return {
-        name: sizes[0] if len(sizes) == 1 else sizes
-        for name, sizes in sorted(found.items())
-    }
+            _site(found, name.group(1),
+                  round(int(size.group(1)) / 2**20, 2))
+    return _by_kernel(found)
 
 
 def kernel_calls(lowered_text: str) -> dict:
@@ -959,7 +1012,7 @@ PROGRAMS = {
         laguna_kernels, laguna_accumulate_step, head_gate_kernels,
         sel_kernels, keye_accumulate_step, index_loss_kernels,
         kda_kernels, kimi_accumulate_step, select_kernels,
-        ssd_kernels, nemotron_accumulate_step,
+        ssd_kernels, nemotron_accumulate_step, long_row_kernels,
     )
 }
 
@@ -1028,6 +1081,7 @@ def main(argv=None) -> int:
             "flash_fwd_forms": flash_fwd_forms(lowered_text),
             "flash_windows": flash_windows(lowered_text),
             "flash_vmem_mb": flash_vmem_mb(lowered_text),
+            "flash_heads": flash_heads(lowered_text),
             "layer_body_copies": layer_body_copies(compiled_text),
             "memory": {
                 "argument_bytes": memory.argument_size_in_bytes,
