@@ -12,9 +12,10 @@ from __future__ import annotations
 
 import asyncio
 import concurrent.futures
+import contextlib
 import hashlib
 import threading
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -28,15 +29,20 @@ from dedloc_tpu.averaging.matchmaking import (
     Matchmaking,
     MatchmakingFailed,
 )
-from dedloc_tpu.averaging.partition import FlatTree, TreeLayout
+from dedloc_tpu.averaging.partition import (
+    FlatTree,
+    SnapshotBuffers,
+    TreeLayout,
+    tree_spec,
+)
 from dedloc_tpu.averaging.planwire import MAX_PLAN_FETCH_FAILURES, fetch_plan
 from dedloc_tpu.averaging.topology import TopologyPlan
 from dedloc_tpu.checkpointing import (
     CheckpointAnnouncement,
     CheckpointManifest,
     ShardStore,
-    build_manifest,
     catalog_key,
+    manifest_of_flat,
     parse_announcements,
     publish_announcement,
     shard_bytes,
@@ -67,6 +73,17 @@ from dedloc_tpu.testing import faults
 from dedloc_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
+
+
+class _HeldState(NamedTuple):
+    """What ``DecentralizedAverager._leased_state`` read under one hold of
+    the state lock."""
+
+    snapshot: Optional[Tuple[SnapshotBuffers, Dict[str, Any]]]
+    generation: int
+    blob: Optional[Tuple[bytes, bytes]]
+    sharded: Optional[Tuple[CheckpointManifest, np.ndarray]]
+    error: Optional[str]
 
 
 def schema_fingerprint(tree: Dict[str, np.ndarray]) -> bytes:
@@ -206,15 +223,24 @@ class DecentralizedAverager:
         self.telemetry = telemetry_registry
         self._listen = (listen_host, listen_port)
         self._advertised_host = advertised_host or "127.0.0.1"
-        self._shared_state: Optional[Tuple[Dict[str, np.ndarray], Dict[str, Any]]] = None
+        # the shared state lives in KEPT host buffers: two sets, written in
+        # turn by the backups and never freed (a snapshot's worth of host
+        # memory mapped and unmapped beside the loop held the loop thread
+        # 0.65-0.70 s a backup, PERF.md PR 49 / 60). ``_shared_state`` is the
+        # published (set, metadata); ``_state_generation`` counts publishes —
+        # a set is reused, so "not replaced meanwhile" asks the generation,
+        # never an array's identity
+        self._state_sets: List[SnapshotBuffers] = []
+        self._shared_state: Optional[Tuple[SnapshotBuffers, Dict[str, Any]]] = None
+        self._state_generation = 0
         # serialized snapshot cache: (blob, sha256 digest) — the digest rides
         # every state.get reply so downloaders detect truncation/corruption
         self._shared_state_blob: Optional[Tuple[bytes, bytes]] = None
         self._state_lock = threading.Lock()
         self._serialize_task: Optional[asyncio.Task] = None
-        # sharded snapshot cache: (manifest, flat fp32 vector) cut from the
-        # SAME shared-state snapshot — built lazily (first ckpt RPC or
-        # catalog publish), invalidated with the snapshot
+        # sharded snapshot cache: (manifest, the published set's own flat
+        # fp32 vector) — hashed in place at the catalog publish behind every
+        # backup (or the first ckpt RPC), invalidated with the snapshot
         self.checkpoint_shard_size = int(checkpoint_shard_size)
         self.checkpoint_fetch_parallelism = int(checkpoint_fetch_parallelism)
         self.checkpoint_max_providers = int(checkpoint_max_providers)
@@ -223,10 +249,10 @@ class DecentralizedAverager:
             ShardStore(checkpoint_dir) if checkpoint_dir else None
         )
         self._sharded_state: Optional[Tuple[CheckpointManifest, np.ndarray]] = None
-        # (snapshot, message) when the snapshot cannot roundtrip the fp32
-        # flat layout — cached so the full-state flatten is not retried
-        # (and the warning not repeated) on every publish cadence / ckpt RPC
-        self._sharded_state_error: Optional[Tuple[Any, str]] = None
+        # the message when the published snapshot cannot roundtrip the fp32
+        # flat layout — cached so the roundtrip check is not retried (and
+        # the warning not repeated) on every publish cadence / ckpt RPC
+        self._sharded_state_error: Optional[str] = None
         self._shard_task: Optional[asyncio.Task] = None
         self.server: Optional[RPCServer] = None
         self.endpoint = None
@@ -1283,16 +1309,108 @@ class DecentralizedAverager:
 
     def set_shared_state(
         self, tree: Dict[str, np.ndarray], metadata: Dict[str, Any]
-    ) -> None:
+    ) -> bool:
         """Snapshot current training state for late joiners
-        (load_state_from_peers counterpart, albert/run_trainer.py:124-128).
-        Stores references only — serialization is deferred to the moment a
-        peer actually requests the state (off the training thread)."""
+        (load_state_from_peers counterpart, albert/run_trainer.py:124-128):
+        ``tree``'s bytes are copied into the kept set that is not published,
+        which is then published in the other's place. False — and nothing
+        changed — when a reader still holds that set (``claim_state_buffers``).
+        Serialization is deferred to the moment a peer actually requests the
+        state (off the training thread). The optimizer's backup drives the
+        same three steps itself, a leaf at a time behind its transfer."""
+        claimed = self.claim_state_buffers(tree_spec(tree))
+        if claimed is None:
+            return False
+        buffers, _allocated = claimed
+        for name, leaf in tree.items():
+            buffers.write(name, leaf)
+        self.publish_shared_state(buffers, metadata)
+        return True
+
+    def claim_state_buffers(
+        self, spec
+    ) -> Optional[Tuple[SnapshotBuffers, int]]:
+        """The kept set the NEXT snapshot is written into — never the
+        published one, so backup N+1 writes where backup N-1 did — and the
+        bytes that had to be allocated for it: the set's size at a process's
+        first backup (and where the state's layout changed), 0 ever after.
+        None while a reader still leases the set (a ``state.get``
+        serialization or a shard read that began before the LAST publish): a
+        set under read is not written, the caller skips this snapshot. One
+        writer at a time — the optimizer's one backup thread."""
+        spec = list(spec)
         with self._state_lock:
-            self._shared_state = (tree, metadata)
+            published = self._shared_state and self._shared_state[0]
+            spare = next(
+                (b for b in self._state_sets if b is not published), None
+            )
+            if spare is not None and spare.layout.spec == spec:
+                return None if spare.readers else (spare, 0)
+        # no spare set of this layout (allocated outside the lock: mapping
+        # gigabytes must not keep a request waiting)
+        buffers = SnapshotBuffers(spec)
+        with self._state_lock:
+            # a spare of another layout goes (to its last reader, if any)
+            self._state_sets = [
+                b for b in self._state_sets if b is published
+            ] + [buffers]
+        return buffers, buffers.nbytes
+
+    def publish_shared_state(
+        self, buffers: SnapshotBuffers, metadata: Dict[str, Any]
+    ) -> None:
+        """Put the written ``buffers`` (from ``claim_state_buffers``) in the
+        published set's place: a swap of references under the lock, nothing
+        snapshot-sized mapped or freed."""
+        with self._state_lock:
+            self._shared_state = (buffers, metadata)
+            self._state_generation += 1
             self._shared_state_blob = None  # invalidate serialized cache
             self._sharded_state = None  # and the sharded form
             self._sharded_state_error = None
+
+    def reserve_state_buffers(self) -> int:
+        """Allocate — and touch — the published set's twin where there is
+        none yet; the bytes allocated (0 from a process's second backup on).
+        Called behind the FIRST backup's publish, so that the second backup,
+        the first the steady loop runs beside, already writes into touched
+        memory: first touch inside the transfer kept the snapshot on the
+        device 2-4x longer, past the next apply (PERF.md, PR 59 / 60: 4.4-8.5
+        s where it takes 2.2, and 0.5 GB more HBM at the peak)."""
+        with self._state_lock:
+            if self._shared_state is None or len(self._state_sets) > 1:
+                return 0
+            spec = self._shared_state[0].layout.spec
+        twin = SnapshotBuffers(spec)
+        twin.touch()
+        with self._state_lock:
+            self._state_sets.append(twin)
+        return twin.nbytes
+
+    @contextlib.contextmanager
+    def _leased_state(self):
+        """The published snapshot as it stands now — (set, metadata),
+        generation, cached blob, cached sharded form, cached build error —
+        read under ONE hold of the lock, with a count on the set until the
+        block is left: what a request reads is not written over by a later
+        backup, however long the request stays open (the backup that finds
+        its target counted skips its snapshot). Taken on the thread that
+        READS — an executor call leases for itself, its request may be
+        cancelled under it."""
+        with self._state_lock:
+            snapshot = self._shared_state
+            held = _HeldState(
+                snapshot, self._state_generation, self._shared_state_blob,
+                self._sharded_state, self._sharded_state_error,
+            )
+            if snapshot is not None:
+                snapshot[0].readers += 1
+        try:
+            yield held
+        finally:
+            if snapshot is not None:
+                with self._state_lock:
+                    snapshot[0].readers -= 1
 
     def _serve_span(self, name: str, **attrs):
         """Server-side serve span for a state/checkpoint RPC handler: under
@@ -1322,6 +1440,24 @@ class DecentralizedAverager:
                 ctx["bytes"] = len(reply["state"])
             return reply
 
+    def _serialize_state(self) -> Tuple[int, Tuple[bytes, bytes]]:
+        """(generation, (blob, sha256)) of the published snapshot, serialized
+        under a lease of its own: the bytes are one snapshot's, whatever the
+        backups do meanwhile."""
+        with self._leased_state() as held:
+            if held.snapshot is None:
+                raise FileNotFoundError("no state snapshot available yet")
+            buffers, metadata = held.snapshot
+            data = pack_obj(
+                {
+                    "metadata": pack_obj(metadata),
+                    "tree": serialize_tree(buffers.tree, CompressionType.NONE),
+                }
+            )
+        # digest computed once at serialization time (the blob can be
+        # hundreds of MB; rehashing per request would be pure waste)
+        return held.generation, (data, hashlib.sha256(data).digest())
+
     async def _rpc_state_get_inner(self, peer, args) -> dict:
         if not self.allow_state_sharing:
             raise PermissionError("state sharing disabled on this peer")
@@ -1334,35 +1470,24 @@ class DecentralizedAverager:
             # tensor names+shapes only (a few KB): what an aux peer needs to
             # bootstrap its gradient template without downloading the full
             # params+optimizer blob (hundreds of MB for real models)
-            tree, _metadata = snapshot
             return {
-                "schema": {k: list(v.shape) for k, v in tree.items()}
+                "schema": {
+                    name: list(shape)
+                    for name, shape, _dtype in snapshot[0].layout.spec
+                }
             }
         if blob is None:
-            tree, metadata = snapshot
-
-            def _serialize() -> Tuple[bytes, bytes]:
-                data = pack_obj(
-                    {
-                        "metadata": pack_obj(metadata),
-                        "tree": serialize_tree(tree, CompressionType.NONE),
-                    }
-                )
-                # digest computed once at serialization time (the blob can be
-                # hundreds of MB; rehashing per request would be pure waste)
-                return data, hashlib.sha256(data).digest()
-
             # off the event loop (serializing the full model+optimizer state
             # can take seconds and must not stall live matchmaking/allreduce),
             # and deduplicated: concurrent late joiners await ONE serialization
             if self._serialize_task is None or self._serialize_task.done():
                 loop = asyncio.get_running_loop()
                 self._serialize_task = asyncio.ensure_future(
-                    loop.run_in_executor(None, _serialize)
+                    loop.run_in_executor(None, self._serialize_state)
                 )
-            blob = await asyncio.shield(self._serialize_task)
+            generation, blob = await asyncio.shield(self._serialize_task)
             with self._state_lock:
-                if self._shared_state is snapshot:  # not replaced meanwhile
+                if self._state_generation == generation:  # not replaced meanwhile
                     self._shared_state_blob = blob
         data, digest = blob
         tele = telemetry.resolve(self.telemetry)
@@ -1390,51 +1515,52 @@ class DecentralizedAverager:
     def _sharded_state_sync(
         self,
     ) -> Optional[Tuple[CheckpointManifest, np.ndarray]]:
-        """Build (or return the cached) sharded form of the current shared
-        state: manifest + fresh flat fp32 vector. Thread-safe and idempotent
-        — callable from the backup thread (catalog publish) and from the
-        DHT loop's executor (first ckpt RPC); a rare concurrent double
-        build computes the identical result. Returns None when there is no
-        snapshot; raises ValueError when the tree cannot roundtrip through
-        the fp32 layout (callers then stay blob-only)."""
+        """Build (or return the cached) sharded form of the published
+        snapshot: its manifest + the set's own flat fp32 vector, hashed IN
+        PLACE under a lease (nothing snapshot-sized is allocated or copied).
+        Thread-safe and idempotent — callable from the backup thread (catalog
+        publish) and from the DHT loop's executor (first ckpt RPC); a rare
+        concurrent double build computes the identical result. Returns None
+        when there is no snapshot; raises ValueError when the tree cannot
+        roundtrip through the fp32 layout (callers then stay blob-only)."""
         if self.checkpoint_shard_size <= 0:
             return None
+        with self._leased_state() as held:
+            if held.snapshot is None:
+                return None
+            if held.sharded is not None:
+                return held.sharded
+            if held.error is not None:
+                # this snapshot already failed the roundtrip check —
+                # re-raise without paying it again
+                raise ValueError(held.error)
+            buffers, metadata = held.snapshot
+            step = int(metadata.get("local_step", metadata.get("step", 0)) or 0)
+            try:
+                manifest = manifest_of_flat(
+                    buffers.layout, buffers.flat, buffers.tree, step,
+                    shard_size=self.checkpoint_shard_size, metadata=metadata,
+                )
+            except ValueError as e:
+                # warn ONCE per snapshot (here, at build time); cached
+                # retries and the publish cadence stay silent
+                logger.warning(f"sharded checkpoint serving unavailable: {e}")
+                with self._state_lock:
+                    if self._state_generation == held.generation:
+                        self._sharded_state_error = str(e)
+                raise
+            built = (manifest, buffers.flat)
         with self._state_lock:
-            snapshot = self._shared_state
-            cached = self._sharded_state
-            failed = self._sharded_state_error
-        if snapshot is None:
-            return None
-        if cached is not None:
-            return cached
-        if failed is not None and failed[0] is snapshot:
-            # this exact snapshot already failed the roundtrip check —
-            # re-raise without paying the full-state flatten again
-            raise ValueError(failed[1])
-        tree, metadata = snapshot
-        step = int(metadata.get("local_step", metadata.get("step", 0)) or 0)
-        try:
-            built = build_manifest(
-                tree, step, shard_size=self.checkpoint_shard_size,
-                metadata=metadata,
-            )
-        except ValueError as e:
-            # warn ONCE per snapshot (here, at build time); cached retries
-            # and the publish cadence stay silent
-            logger.warning(f"sharded checkpoint serving unavailable: {e}")
-            with self._state_lock:
-                if self._shared_state is snapshot:
-                    self._sharded_state_error = (snapshot, str(e))
-            raise
-        with self._state_lock:
-            if self._shared_state is snapshot:  # not replaced meanwhile
+            if self._state_generation == held.generation:  # not replaced meanwhile
                 self._sharded_state = built
         return built
 
     async def _sharded_snapshot(self) -> Tuple[CheckpointManifest, np.ndarray]:
         """Sharded snapshot for the RPC handlers: built off the event loop
-        (flatten + sha256 over the full state takes seconds at real model
-        sizes) and deduplicated like the blob serialization."""
+        (sha256 over the full state takes seconds at real model sizes) and
+        deduplicated like the blob serialization. Whoever READS the vector
+        does so under ``_leased_state``, having checked there that the pair
+        is still the published one (``_rpc_ckpt_shard_inner``)."""
         if not self.allow_state_sharing:
             raise PermissionError("state sharing disabled on this peer")
         if self.checkpoint_shard_size <= 0:
@@ -1480,9 +1606,16 @@ class DecentralizedAverager:
             return reply
 
     async def _rpc_ckpt_shard_inner(self, peer, args) -> dict:
-        manifest, flat = await self._sharded_snapshot()
         index = int(args["index"])
-        raw = shard_bytes(flat, manifest, index)
+        raw = None
+        while raw is None:
+            manifest, flat = await self._sharded_snapshot()
+            with self._leased_state() as held:
+                # the reply's own copy, taken while the pair is the
+                # published one and counted as read; a backup that got in
+                # between (the build was awaited) sends the read around
+                if held.sharded is not None and held.sharded[0] is manifest:
+                    raw = shard_bytes(flat, manifest, index)
         if faults._active is not None:  # fault injection (testing/faults.py)
             fault = faults.fire("checkpoint.shard_get", index=index,
                                 size=len(raw))

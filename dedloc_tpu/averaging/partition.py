@@ -38,11 +38,7 @@ class TreeLayout:
 
     @classmethod
     def for_tree(cls, tree: Dict[str, np.ndarray]) -> "TreeLayout":
-        spec = []
-        for name in sorted(tree):
-            arr = np.asarray(tree[name])
-            spec.append((name, arr.shape, arr.dtype))
-        return cls(spec)
+        return cls(tree_spec(tree))
 
     def matches(self, tree: Dict[str, np.ndarray]) -> bool:
         if len(tree) != len(self.spec):
@@ -89,6 +85,78 @@ class TreeLayout:
         averager's wire path, the fused flat apply) skip the re-flatten."""
         assert flat.size == self.total_size, "buffer does not match layout"
         return FlatTree(self.unflatten(flat), flat=flat, spec=self.spec)
+
+
+def tree_spec(tree) -> List[Tuple[str, Tuple[int, ...], np.dtype]]:
+    """The layout spec of a {name: array} tree — (name, shape, dtype), sorted
+    by name — read off each leaf's own ``shape`` / ``dtype``, so a tree of
+    DEVICE arrays is described without bringing a byte to the host."""
+    spec = []
+    for name in sorted(tree):
+        leaf = tree[name]
+        if not hasattr(leaf, "dtype"):
+            leaf = np.asarray(leaf)
+        spec.append((name, tuple(leaf.shape), np.dtype(leaf.dtype)))
+    return spec
+
+
+class SnapshotBuffers:
+    """One KEPT host form of a {name: array} state, written over by every
+    other backup and never freed: ``flat`` is the fp32 vector in TreeLayout
+    order (what the sharded checkpoint path hashes and serves), ``tree`` the
+    named leaves (what the blob path serializes) — and they are the SAME
+    memory: an fp32 leaf is the reshaped view of its span of ``flat``. A leaf
+    of any other dtype (a step counter, a bf16 moment) cannot be such a view,
+    so it keeps storage of its own dtype in ``tree`` and ``flat`` holds its
+    fp32 cast, as ``TreeLayout.flatten_into`` would write it.
+
+    ``readers`` counts the requests reading the set (the averager's lease,
+    under its state lock): a set under read is not written."""
+
+    __slots__ = ("layout", "flat", "tree", "_casts", "readers")
+
+    def __init__(self, spec: Sequence[Tuple[str, Tuple[int, ...], np.dtype]]):
+        self.layout = TreeLayout(spec)
+        self.flat = np.empty((self.layout.total_size,), np.float32)
+        self.tree: Dict[str, np.ndarray] = {}
+        self._casts: Dict[str, np.ndarray] = {}
+        for (name, shape, dtype), offset in zip(
+            self.layout.spec, self.layout.offsets
+        ):
+            size = int(np.prod(shape)) if shape else 1
+            span = self.flat[offset : offset + size]
+            if dtype == np.float32:
+                self.tree[name] = span.reshape(shape)
+            else:
+                self.tree[name] = np.empty(shape, dtype)
+                self._casts[name] = span
+        self.readers = 0
+
+    @property
+    def nbytes(self) -> int:
+        return self.flat.nbytes + sum(
+            self.tree[name].nbytes for name in self._casts
+        )
+
+    def touch(self) -> None:
+        """Bring every page in, so no later ``write`` pays for it. TWO
+        passes: under the chip machine's sandbox kernel a page's first
+        access runs at 1.0 GB/s, its second at 2.4 and only the third at
+        memory speed, 20 (PERF.md, PR 60); elsewhere the second pass costs
+        a memset."""
+        for _ in range(2):
+            self.flat.fill(0.0)
+            for name in self._casts:
+                self.tree[name].fill(0)
+
+    def write(self, name: str, value) -> None:
+        """Copy one leaf's bytes in (``value``: its shape and dtype)."""
+        leaf = self.tree[name]
+        np.copyto(leaf, value, casting="no")
+        cast = self._casts.get(name)
+        if cast is not None:
+            # the cast happens inside the copy — no astype temporary
+            np.copyto(cast, leaf.reshape(-1), casting="unsafe")
 
 
 class FlatTree(dict):

@@ -30,6 +30,7 @@ from dedloc_tpu.checkpointing.manifest import (
     CheckpointManifest,
     assemble_tree,
     build_manifest,
+    manifest_of_flat,
     shard_bytes,
     verify_shard,
 )
@@ -51,6 +52,7 @@ __all__ = [
     "fetch_manifest",
     "fetch_shards",
     "load_sharded_checkpoint",
+    "manifest_of_flat",
     "parse_announcements",
     "publish_announcement",
     "save_sharded_checkpoint",

@@ -153,6 +153,8 @@ def build_manifest(
     into content-addressed shards. Returns (manifest, flat) where ``flat``
     is a FRESH fp32 vector the caller owns (checkpoint shards outlive
     averaging rounds, so the averager's reused round buffer is never used).
+    A state that already lies in its flat form (the averager's kept
+    ``SnapshotBuffers``) goes straight to ``manifest_of_flat``.
 
     Raises ValueError when a non-fp32 leaf does not roundtrip exactly
     through the fp32 flat vector (e.g. int64 counters past 2**24) — such a
@@ -160,10 +162,27 @@ def build_manifest(
     """
     from dedloc_tpu.averaging.partition import TreeLayout
 
-    if shard_size <= 0:
-        raise ValueError(f"shard_size must be positive, got {shard_size}")
     layout = TreeLayout.for_tree(tree)
     flat = layout.flatten_into(tree, np.empty((layout.total_size,), np.float32))
+    return manifest_of_flat(layout, flat, tree, step, shard_size, metadata), flat
+
+
+def manifest_of_flat(
+    layout,
+    flat: np.ndarray,
+    tree: Dict[str, np.ndarray],
+    step: int,
+    shard_size: int = DEFAULT_SHARD_SIZE,
+    metadata: Optional[Dict[str, Any]] = None,
+) -> CheckpointManifest:
+    """The manifest of ``flat``, the fp32 vector that holds ``tree`` in
+    ``layout``'s (TreeLayout) order, IN PLACE: nothing of the state's size
+    is allocated or copied. Each shard's sha256 reads its slice of the
+    vector through the buffer protocol; the exact-roundtrip check reads the
+    non-fp32 leaves alone (an fp32 leaf IS its span). Raises ValueError as
+    ``build_manifest`` does."""
+    if shard_size <= 0:
+        raise ValueError(f"shard_size must be positive, got {shard_size}")
     for (name, shape, dtype), offset in zip(layout.spec, layout.offsets):
         if dtype == np.float32:
             continue
@@ -174,11 +193,11 @@ def build_manifest(
                 f"leaf {name!r} ({dtype}) does not roundtrip exactly through "
                 "the fp32 flat layout; use the full-blob state path"
             )
-    digests = []
-    for start in range(0, layout.total_size, shard_size):
-        chunk = flat[start : start + shard_size]
-        digests.append(hashlib.sha256(np.ascontiguousarray(chunk).tobytes()).digest())
-    manifest = CheckpointManifest(
+    digests = [
+        hashlib.sha256(flat[start : start + shard_size]).digest()
+        for start in range(0, layout.total_size, shard_size)
+    ]
+    return CheckpointManifest(
         step=int(step),
         shard_size=int(shard_size),
         total_size=layout.total_size,
@@ -189,7 +208,6 @@ def build_manifest(
         shard_digests=tuple(digests),
         metadata=dict(metadata or {}),
     )
-    return manifest, flat
 
 
 def shard_bytes(flat: np.ndarray, manifest: CheckpointManifest, index: int) -> bytes:

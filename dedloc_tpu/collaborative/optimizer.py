@@ -21,6 +21,7 @@ TPU-native split (SURVEY.md §7 hard-parts b,c):
 from __future__ import annotations
 
 import collections
+import concurrent.futures
 import threading
 import time
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -32,7 +33,11 @@ import optax
 from dedloc_tpu.averaging.allreduce import DEFAULT_CHUNK_SIZE
 from dedloc_tpu.averaging.averager import DecentralizedAverager
 from dedloc_tpu.averaging.device_flat import DeviceFlatPipeline
-from dedloc_tpu.averaging.partition import FlatTree
+from dedloc_tpu.averaging.partition import (
+    FlatTree,
+    SnapshotBuffers,
+    tree_spec,
+)
 from dedloc_tpu.collaborative.error_feedback import ErrorFeedback
 from dedloc_tpu.collaborative.progress import (
     CollaborationState,
@@ -50,7 +55,11 @@ from dedloc_tpu.parallel.train_step import (
     make_guarded_apply_step,
     zeros_like_grads,
 )
-from dedloc_tpu.utils.checkpoint import named_to_tree, tree_to_named
+from dedloc_tpu.utils.checkpoint import (
+    named_leaves,
+    named_to_tree,
+    tree_to_named,
+)
 from dedloc_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -348,10 +357,12 @@ class CollaborativeOptimizer:
         self.backup_duty_cycle = 0.5
         self._backup_done_at = 0.0
         self._backup_took = 0.0
-        # (start, end, bytes) of the transfers the backup thread has
-        # finished, on the step records' clock: ``step`` attaches each to
+        # (span name, start, end, counts) of what the backup thread has
+        # finished — a ``backup_transfer``, then the ``backup_publish``
+        # behind it — on the step records' clock: ``step`` attaches each to
         # the record that is live when it next runs (the averager's
-        # ``last_round_timing`` is the pattern)
+        # ``last_round_timing`` is the pattern) and adds its counts to the
+        # ``opt.backup_*`` counters
         self._finished_backups: collections.deque = collections.deque()
         # the error-feedback residual's norm, launched on the round's path
         # (a device scalar on its way to the host) for the
@@ -427,17 +438,7 @@ class CollaborativeOptimizer:
             tele = telemetry.resolve(self.telemetry)
             if tele is not None:
                 tele.gauge("opt.ef_residual_norm").set(float(ef_norm))
-        while self._finished_backups:
-            t0, t1, nbytes = self._finished_backups.popleft()
-            tele = telemetry.resolve(self.telemetry)
-            if tele is not None:
-                tele.counter("opt.backup_bytes").inc(nbytes)
-            if record is not None:
-                # another thread's time: a span beside this thread's own
-                record.attach("backup_transfer", t0, t1)
-                record.attrs["opt.backup_bytes"] = (
-                    record.attrs.get("opt.backup_bytes", 0) + nbytes
-                )
+        self._note_backups(record)
         with self._lock:
             out = self._step(state, grad_acc, n_acc, samples)
         if record is not None:
@@ -450,6 +451,26 @@ class CollaborativeOptimizer:
                 global_steps_total=self.contrib_rounds_total,
             )
         return out
+
+    def _note_backups(self, record) -> None:
+        """What the backup thread finished since the last call — its spans
+        onto ``record`` (another thread's time: spans beside this thread's
+        own), its counts into the record's attrs and the counters."""
+        while self._finished_backups:
+            name, t0, t1, counts = self._finished_backups.popleft()
+            if record is not None:
+                record.attach(name, t0, t1)
+            for key, n in counts.items():
+                self._count_backup(record, key, n)
+
+    def _count_backup(self, record, key: str, n: int) -> None:
+        """``n`` more of an ``opt.backup*`` count: the counter, and the
+        record's attr of the same name."""
+        tele = telemetry.resolve(self.telemetry)
+        if tele is not None:
+            tele.counter(key).inc(n)  # dedlint: emits=counter:opt.backup_bytes,counter:opt.backup_host_alloc_bytes,counter:opt.backups_skipped.duty_cycle,counter:opt.backups_skipped.busy,counter:opt.backups_skipped.leased
+        if record is not None:
+            record.attrs[key] = record.attrs.get(key, 0) + n
 
     def _step(self, state: TrainState, grad_acc, n_acc, samples: int):
         """``step`` proper, under its lock."""
@@ -1426,40 +1447,60 @@ class CollaborativeOptimizer:
         Runs on a background thread: the transfer is read-only w.r.t. the
         next round (a fresh grad accumulator), so the next accumulation phase
         overlaps the hundreds of MB of device→host traffic instead of
-        stalling behind it.
+        stalling behind it. The host form lives in buffers the averager
+        KEEPS (two sets, written in turn): a backup maps and frees nothing
+        of the state's size beside the loop.
 
-        Duty-cycle cap: when the transfer takes longer than
-        ``backup_duty_cycle`` of the time between global steps, skip this
-        step's snapshot instead of queueing behind it — late joiners get a
-        slightly older state, training throughput stays intact. (On PCIe the
-        transfer is ~ms and effectively every step is shared; the cap only
-        bites on slow links.)
+        Duty-cycle cap: when the backup (transfer + publish) takes longer
+        than ``backup_duty_cycle`` of the time between global steps, skip
+        this step's snapshot instead of queueing behind it — late joiners
+        get a slightly older state, training throughput stays intact. (On
+        PCIe the transfer is ~ms and effectively every step is shared; the
+        cap only bites on slow links.) A snapshot is skipped the same way
+        while a peer still downloads from the set it would be written into.
+        Every skip is counted by its reason (``opt.backups_skipped.*``).
         """
         if not self.averager.allow_state_sharing:
             return
+        record = steps.current()
         if self._backup_thread is not None and self._backup_thread.is_alive():
-            return  # previous snapshot still draining; don't stall the step
+            # previous snapshot still draining; don't stall the step
+            return self._count_backup(record, "opt.backups_skipped.busy", 1)
         now = time.perf_counter()
         idle_needed = self._backup_took * (1.0 / self.backup_duty_cycle - 1.0)
         if now < self._backup_done_at + idle_needed:
-            return
+            return self._count_backup(
+                record, "opt.backups_skipped.duty_cycle", 1
+            )
+        snapshot = (state.params, state.opt_state)
+        claimed = self.averager.claim_state_buffers(
+            tree_spec(dict(named_leaves(snapshot)))
+        )
+        if claimed is None:
+            # a reader holds the set this snapshot would be written into
+            return self._count_backup(record, "opt.backups_skipped.leased", 1)
         with steps.phase("backup_launch"):
-            self._launch_backup(state)
+            self._launch_backup(state.step, snapshot, *claimed)
 
-    def _launch_backup(self, state: TrainState) -> None:
+    def _launch_backup(
+        self, state_step, snapshot, buffers: SnapshotBuffers, allocated: int
+    ) -> None:
         """The part of a backup the training thread pays: one host sync on
         the apply program (``int(state.step)``), an on-device copy of the
-        state, and the start of the thread that takes it to the host."""
+        state (``snapshot``: params and optimizer state), and the start of
+        the thread that takes it to the host, into ``buffers`` (the
+        averager's kept set that is not published; ``allocated``: the bytes
+        it had to allocate for it)."""
         self._join_backup()
-        step, local_step = int(state.step), self.local_step
+        step, local_step = int(state_step), self.local_step
         # snapshot ON DEVICE first (an HBM copy, ~ms): the next global step's
         # apply DONATES state's buffers, so the thread must never hold the
         # live arrays — device_get on a donated buffer would raise "Array has
         # been deleted" mid-transfer on exactly the slow links the duty cycle
         # exists for
-        leaves, treedef = jax.tree.flatten(jax.tree.map(
-            jax.numpy.copy, (state.params, state.opt_state)
-        ))
+        names, leaves = map(list, zip(*named_leaves(
+            jax.tree.map(jax.numpy.copy, snapshot)
+        )))
 
         def backup() -> None:
             t0, started = time.perf_counter(), monotonic_clock()
@@ -1471,26 +1512,53 @@ class CollaborativeOptimizer:
             # one being read, and each device copy is let go as soon as its
             # bytes are on the host: the snapshot (12 bytes a parameter
             # under LAMB) shrinks while the transfer runs instead of
-            # staying whole until its end.
-            nbytes = 0
-            leaves[0].copy_to_host_async()
-            for i in range(len(leaves)):
-                if i + 1 < len(leaves):
-                    leaves[i + 1].copy_to_host_async()
-                leaves[i] = np.asarray(leaves[i])
-                nbytes += leaves[i].nbytes
-            host_state = jax.tree.unflatten(treedef, leaves)
-            self.averager.set_shared_state(
-                tree_to_named(host_state),
-                {"step": step, "local_step": local_step},
+            # staying whole until its end. That loop is ALL this thread
+            # does: the copy of each leaf into the kept set runs on a thread
+            # of its own beside it (numpy copies without the interpreter
+            # lock), because with the copy between two leaves the device
+            # gave its bytes up a third slower — 2.2 s for 5.63 GB where the
+            # loop alone takes 1.5, and 0.26 GB more HBM at the step's peak
+            # (PERF.md, PR 60). The runtime's own host copy of a leaf goes
+            # with its copy job: a leaf's worth of host memory mapped and
+            # freed at a time, never a snapshot's.
+            nbytes, copies = 0, []
+            with concurrent.futures.ThreadPoolExecutor(
+                1, thread_name_prefix="backup-copy"
+            ) as copier:
+                leaves[0].copy_to_host_async()
+                for i, name in enumerate(names):
+                    if i + 1 < len(leaves):
+                        leaves[i + 1].copy_to_host_async()
+                    host = np.asarray(leaves[i])
+                    leaves[i] = None  # the device copy goes first
+                    nbytes += host.nbytes
+                    copies.append(copier.submit(buffers.write, name, host))
+                    del host
+            for copy in copies:
+                copy.result()  # a failed copy fails the backup, unpublished
+            self.averager.publish_shared_state(
+                buffers, {"step": step, "local_step": local_step}
             )
-            self._finished_backups.append(
-                (started, monotonic_clock(), nbytes)
-            )
+            published = monotonic_clock()
+            self._finished_backups.append((
+                "backup_transfer", started, published,
+                {"opt.backup_bytes": nbytes,
+                 "opt.backup_host_alloc_bytes": allocated},
+            ))
+            # provider record, the sharded form's manifest (sha256 over the
+            # set in place) and its announcement; at a process's first
+            # backup also the second kept set, allocated and touched here,
+            # where no device copy waits for it
             self.averager.publish_state_provider(
                 expiration=self.tracker.metadata_expiration * 4,
                 step=local_step,
             )
+            announced = monotonic_clock()
+            reserved = self.averager.reserve_state_buffers()
+            self._finished_backups.append((
+                "backup_publish", published, announced,
+                {"opt.backup_host_alloc_bytes": reserved},
+            ))
             end = time.perf_counter()
             self._backup_done_at, self._backup_took = end, end - t0
             self.seam_ms["backup"] = (end - t0) * 1e3

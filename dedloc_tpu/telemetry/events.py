@@ -91,6 +91,10 @@ MOE_LOCAL_SLOT_SHARE = "moe.local_slot_share"
 NET_BYTES_IN = "net.bytes_in"
 NET_BYTES_OUT = "net.bytes_out"
 OPT_BACKUP_BYTES = "opt.backup_bytes"
+OPT_BACKUP_HOST_ALLOC_BYTES = "opt.backup_host_alloc_bytes"
+OPT_BACKUPS_SKIPPED_BUSY = "opt.backups_skipped.busy"
+OPT_BACKUPS_SKIPPED_DUTY_CYCLE = "opt.backups_skipped.duty_cycle"
+OPT_BACKUPS_SKIPPED_LEASED = "opt.backups_skipped.leased"
 OPT_BOUNDARIES = "opt.boundaries"
 OPT_CATCH_UP = "opt.catch_up"
 OPT_CATCH_UPS = "opt.catch_ups"
@@ -210,6 +214,10 @@ COUNTERS = frozenset({
     "net.bytes_in",
     "net.bytes_out",
     "opt.backup_bytes",
+    "opt.backup_host_alloc_bytes",
+    "opt.backups_skipped.busy",
+    "opt.backups_skipped.duty_cycle",
+    "opt.backups_skipped.leased",
     "opt.boundaries",
     "opt.catch_ups",
     "opt.d2h_bytes",

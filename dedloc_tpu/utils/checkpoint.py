@@ -70,26 +70,30 @@ def sweep_orphan_tmpdirs(
     return swept
 
 
-def tree_to_named(tree) -> Dict[str, np.ndarray]:
-    """Flatten a pytree into {path: np.array} with deterministic names: the
-    naming of checkpoints, shared state and the gradient wire."""
+def named_leaves(tree) -> List[Tuple[str, Any]]:
+    """``tree``'s leaves in flatten order, each under its deterministic
+    name: the naming of checkpoints, shared state and the gradient wire."""
     flat = jax.tree_util.tree_flatten_with_path(tree)[0]
-    out = {}
-    for i, (path, leaf) in enumerate(flat):
-        name = jax.tree_util.keystr(path) or f"leaf{i}"
-        out[name] = np.asarray(leaf)
-    return out
+    return [
+        (jax.tree_util.keystr(path) or f"leaf{i}", leaf)
+        for i, (path, leaf) in enumerate(flat)
+    ]
+
+
+def tree_to_named(tree) -> Dict[str, np.ndarray]:
+    """Flatten a pytree into {name: np.array} (``named_leaves``' names)."""
+    return {name: np.asarray(leaf) for name, leaf in named_leaves(tree)}
 
 
 def named_to_tree(named: Dict[str, np.ndarray], like):
     """Inverse of ``tree_to_named`` given a structural template."""
-    flat, treedef = jax.tree_util.tree_flatten_with_path(like)
-    leaves = []
-    for i, (path, leaf) in enumerate(flat):
-        name = jax.tree_util.keystr(path) or f"leaf{i}"
-        arr = named[name]
-        leaves.append(np.asarray(arr, dtype=leaf.dtype).reshape(leaf.shape))
-    return jax.tree_util.tree_unflatten(treedef, leaves)
+    leaves = [
+        np.asarray(named[name], dtype=leaf.dtype).reshape(leaf.shape)
+        for name, leaf in named_leaves(like)
+    ]
+    return jax.tree_util.tree_unflatten(
+        jax.tree_util.tree_structure(like), leaves
+    )
 
 
 def list_checkpoints(output_dir: str) -> List[Tuple[int, str]]:

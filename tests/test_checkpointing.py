@@ -725,7 +725,6 @@ def test_unshardable_state_build_failure_is_cached(monkeypatch):
     build ONCE per snapshot — the publish cadence / ckpt RPCs must not pay
     a full-state flatten (plus a warning) on every retry."""
     import threading
-    from types import SimpleNamespace
 
     from dedloc_tpu.averaging import averager as averager_mod
     from dedloc_tpu.averaging.averager import DecentralizedAverager
@@ -736,24 +735,27 @@ def test_unshardable_state_build_failure_is_cached(monkeypatch):
         calls["n"] += 1
         raise ValueError("leaf not representable in fp32")
 
-    monkeypatch.setattr(averager_mod, "build_manifest", failing_build)
-    snapshot = ({"p": np.arange(4, dtype=np.float32)}, {"step": 1})
-    self = SimpleNamespace(
-        checkpoint_shard_size=4,
-        _state_lock=threading.Lock(),
-        _shared_state=snapshot,
-        _sharded_state=None,
-        _sharded_state_error=None,
+    monkeypatch.setattr(averager_mod, "manifest_of_flat", failing_build)
+    # the state-sharing half of an averager, without its DHT
+    self = DecentralizedAverager.__new__(DecentralizedAverager)
+    self.checkpoint_shard_size = 4
+    self._state_lock = threading.Lock()
+    self._state_sets, self._shared_state, self._state_generation = [], None, 0
+    self._shared_state_blob = self._sharded_state = None
+    self._sharded_state_error = None
+    assert self.set_shared_state(
+        {"p": np.arange(4, dtype=np.float32)}, {"step": 1}
     )
     for _ in range(3):
         with pytest.raises(ValueError, match="not representable"):
-            DecentralizedAverager._sharded_state_sync(self)
+            self._sharded_state_sync()
     assert calls["n"] == 1  # built once, cached failure re-raised after
     # a NEW snapshot clears the cached failure and builds again
-    self._shared_state = ({"p": np.arange(5, dtype=np.float32)}, {"step": 2})
-    self._sharded_state_error = None  # set_shared_state invalidation
+    assert self.set_shared_state(
+        {"p": np.arange(5, dtype=np.float32)}, {"step": 2}
+    )
     with pytest.raises(ValueError):
-        DecentralizedAverager._sharded_state_sync(self)
+        self._sharded_state_sync()
     assert calls["n"] == 2
 
 
