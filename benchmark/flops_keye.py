@@ -111,10 +111,10 @@ def sel_kernel_cost(
     dtype_bytes: int = 2,
 ) -> Tuple[float, float]:
     """(FLOPs, bytes) of one call of ``kernel`` (``flash_sel_fwd`` /
-    ``_bwd_dq`` / ``_bwd_dkv``) on ``batch`` rows of ``seq``: its grouped
-    causal twin's matmuls a tile and tensors (``flops_lfm2._GQA``) over
-    ``tile_share`` of the triangle's tiles, and those tiles of the int8
-    selection."""
+    ``_bwd_dq`` / ``_bwd_dkv`` / ``_bwd_tiled``) on ``batch`` rows of
+    ``seq``: its grouped causal twin's matmuls a tile and tensors
+    (``flops_lfm2._GQA``) over ``tile_share`` of the triangle's tiles, and
+    those tiles of the int8 selection."""
     if not kernel.startswith("flash_sel_"):
         raise KeyError(f"no cost function for kernel {kernel!r}")
     k = _GQA[kernel.replace("flash_sel_", "flash_gqa_")]

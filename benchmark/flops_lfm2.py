@@ -71,6 +71,9 @@ _GQA = {
     "flash_gqa_bwd_dq": dict(matmuls=3, q_tensors=4, kv_tensors=2),
     # QK^T, dP, dK, dV; reads q dO O | reads k v, writes dk dv
     "flash_gqa_bwd_dkv": dict(matmuls=4, q_tensors=3, kv_tensors=4),
+    # the one-sweep backward: QK^T, dP, dV, dK, dQ from ONE score tile;
+    # reads q dO O, writes dq | reads k v, writes dk dv
+    "flash_gqa_bwd_tiled": dict(matmuls=5, q_tensors=4, kv_tensors=4),
 }
 
 

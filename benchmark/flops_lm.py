@@ -52,6 +52,9 @@ _KERNELS = {
     "flash_causal_bwd_dq": (3, 5, 1),
     # QK^T, dP, dV = P^T·dO, dK = dS^T·Q; reads q k v dO O, writes dk dv
     "flash_causal_bwd_dkv": (4, 5, 2),
+    # the one-sweep backward: QK^T, dP, dV, dK, dQ from ONE score tile;
+    # reads q k v dO O, writes dq dk dv
+    "flash_causal_bwd_tiled": (5, 5, 3),
 }
 
 

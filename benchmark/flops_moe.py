@@ -61,6 +61,9 @@ _KERNELS = {
     # QK^T, dK = dS^T·Q | dP, dV = P^T·dO; reads q k, writes dk | reads
     # v dO O, writes dv
     "flash_mla_bwd_dkv": dict(qk=2, v=2, qk_tensors=3, v_tensors=4),
+    # the one-sweep backward: QK^T, dK, dQ | dP, dV from ONE score tile;
+    # reads q k, writes dq dk | reads v dO O, writes dv
+    "flash_mla_bwd_tiled": dict(qk=3, v=2, qk_tensors=4, v_tensors=4),
 }
 
 

@@ -27,7 +27,6 @@ from __future__ import annotations
 from typing import Dict, Tuple
 
 from benchmark.flops_lfm2 import gqa_kernel_cost
-from benchmark.flops_lm import causal_tiles
 
 
 def ssd_chunk_flops(chunk: int, heads: int, dim: int,
@@ -77,23 +76,15 @@ def ssd_kernel_cost(kernel: str, batch: int, heads: int, groups: int,
 
 def held_gqa_kernel_cost(kernel: str, batch: int, sizes: Dict[str, float],
                          seq: int, dtype_bytes: int = 2) -> Tuple[float, float]:
-    """(FLOPs, bytes) of a grouped causal kernel at the heads the call HAS
-    (``sizes['held_heads']`` over ``sizes['held_kv_heads']``, not the
-    published 32 / 2). ``flash_gqa_fwd``: ``flops_lfm2.gqa_kernel_cost``;
-    ``flash_gqa_bwd_tiled`` (the one-sweep backward): 5 matmuls a visited
-    tile and query head — QKᵀ, dP, dQ, dK, dV — and q dO O dq at the query
-    heads' width, k v dk dv at the kv heads'."""
-    heads, kv, d = sizes["held_heads"], sizes["held_kv_heads"], sizes["head_dim"]
+    """``flops_lfm2.gqa_kernel_cost`` of a grouped causal kernel
+    (``flash_gqa_fwd``, or ``flash_gqa_bwd_tiled``, the one-sweep backward)
+    at the heads the call HAS: ``sizes['held_heads']`` over
+    ``sizes['held_kv_heads']``, not the published 32 / 2."""
     block = min(sizes["attention_block_size"], seq)
-    if kernel != "flash_gqa_bwd_tiled":
-        return gqa_kernel_cost(kernel, batch, heads, kv, seq, d, block, block)
-    flops = (
-        5 * 2.0 * block * block * d * causal_tiles(seq, block, block)
-        * batch * heads
+    return gqa_kernel_cost(
+        kernel, batch, sizes["held_heads"], sizes["held_kv_heads"], seq,
+        sizes["head_dim"], block, block, dtype_bytes,
     )
-    tensors = batch * seq * d * dtype_bytes * (4 * heads + 4 * kv)
-    rows = (batch * heads + batch) * seq * 4  # lse per head, bias per row
-    return flops, float(tensors + rows)
 
 
 def _mamba_widths(sizes: Dict[str, float]) -> Tuple[int, int, int]:

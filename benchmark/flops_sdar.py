@@ -107,10 +107,10 @@ def bd_kernel_cost(
     dtype_bytes: int = 2,
 ) -> Tuple[float, float]:
     """(FLOPs, bytes) of one call of ``kernel`` (``flash_bd_fwd`` /
-    ``_bwd_dq`` / ``_bwd_dkv``) on ``batch`` rows of ``length`` clean tokens
-    (2 x ``length`` positions): its grouped causal twin's matmuls a tile and
-    tensors (``flops_lfm2._GQA``), over the rule's tiles and both streams'
-    positions."""
+    ``_bwd_dq`` / ``_bwd_dkv`` / ``_bwd_tiled``) on ``batch`` rows of
+    ``length`` clean tokens (2 x ``length`` positions): its grouped causal
+    twin's matmuls a tile and tensors (``flops_lfm2._GQA``), over the rule's
+    tiles and both streams' positions."""
     if not kernel.startswith("flash_bd_"):
         raise KeyError(f"no cost function for kernel {kernel!r}")
     k = _GQA[kernel.replace("flash_bd_", "flash_gqa_")]

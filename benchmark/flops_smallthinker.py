@@ -106,9 +106,10 @@ def band_kernel_cost(
     dtype_bytes: int = 2,
 ) -> Tuple[float, float]:
     """(FLOPs, bytes) of one call of ``kernel`` (``flash_band_fwd`` /
-    ``_bwd_dq`` / ``_bwd_dkv``) on ``batch`` rows: its grouped causal twin's
-    matmuls a tile and tensors (``flops_lfm2.gqa_kernel_cost``), over the
-    band's tiles in place of the triangle's."""
+    ``_bwd_dq`` / ``_bwd_dkv`` / ``_bwd_tiled``) on ``batch`` rows: its
+    grouped causal twin's matmuls a tile and tensors
+    (``flops_lfm2.gqa_kernel_cost``), over the band's tiles in place of the
+    triangle's."""
     if not kernel.startswith("flash_band_"):
         raise KeyError(f"no cost function for kernel {kernel!r}")
     flops, bytes_ = gqa_kernel_cost(

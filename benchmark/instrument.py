@@ -4,8 +4,11 @@ learns here, from OUTSIDE the program.
 Three wrappers, installed by ``run.py`` before any peer starts:
 
 - the role's batch source (the role adapter says which function builds it):
-  every ``next()`` is timed and its rows counted where they are drawn, and
-  the source is where a run ends — it stops once the window is over;
+  every ``next()`` is timed and its rows counted for the peer that ASKED for
+  the source (its record is resolved once, where the source is built, on the
+  peer's own thread — a producer thread that draws ahead of the loop counts
+  for that peer too), and the source is where a run ends — it stops once
+  the window is over;
 - ``CollaborativeOptimizer.step``: wall per call and whether it stepped;
 - ``CollaborativeOptimizer.report_loss``: the loss each global step
   advertises (both roles call it once per global step).
@@ -97,15 +100,11 @@ class Recorder:
 
     # ------------------------------------------------------------- events
 
-    def on_draw(self, t0: float, t1: float, rows: int) -> None:
-        peer = self.peer()
-        if peer is not None:
-            peer.draws.append((t0, t1, rows))
+    def on_draw(self, peer: PeerRecord, t0: float, t1: float,
+                rows: int) -> None:
+        peer.draws.append((t0, t1, rows))
 
-    def should_stop(self) -> bool:
-        peer = self.peer()
-        if peer is None:
-            return False
+    def should_stop(self, peer: PeerRecord) -> bool:
         if self.abort:
             return True
         return (
@@ -196,11 +195,15 @@ class _LogHandler(logging.Handler):
 
 
 class InstrumentedSource:
-    """Iterator around the role's own batch iterator."""
+    """Iterator around the role's own batch iterator, counting for ``peer``
+    — the record of the peer that built the source, whichever thread
+    draws."""
 
-    def __init__(self, inner, recorder: Recorder, rows: int, stop_exc):
+    def __init__(self, inner, recorder: Recorder, peer: PeerRecord,
+                 rows: int, stop_exc):
         self.inner = iter(inner)
         self.recorder = recorder
+        self.peer = peer
         self.rows = rows
         self.stop_exc = stop_exc
 
@@ -208,11 +211,11 @@ class InstrumentedSource:
         return self
 
     def __next__(self):
-        if self.recorder.should_stop():
+        if self.recorder.should_stop(self.peer):
             raise self.stop_exc()
         t0 = now()
         batch = next(self.inner)
-        self.recorder.on_draw(t0, now(), self.rows)
+        self.recorder.on_draw(self.peer, t0, now(), self.rows)
         return batch
 
 

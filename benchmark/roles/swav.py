@@ -42,7 +42,7 @@ def install_source(recorder: Recorder, seed: int):
         if peer is None:
             return orig(spec, batch_size, seed=seed, **kw)
         inner = orig(spec, batch_size, seed=seed + peer.index, **kw)
-        return InstrumentedSource(inner, recorder, batch_size, STOP)
+        return InstrumentedSource(inner, recorder, peer, batch_size, STOP)
 
     role.synthetic_multicrop_batches = batches
 

@@ -45,7 +45,7 @@ def install_source(recorder: Recorder, seed: int):
         key = f"benchmark-seed-{seed}-peer-{peer.index}".encode()
         rows = slice_batch or args.training.per_device_batch_size
         return InstrumentedSource(
-            orig(args, cfg, key, slice_batch), recorder, rows, STOP
+            orig(args, cfg, key, slice_batch), recorder, peer, rows, STOP
         )
 
     role._make_batches = make_batches

@@ -13,7 +13,6 @@ import pytest
 from benchmark import flops_keye as fk, peaks
 from benchmark.flops import roofline_seconds
 from benchmark.reducers import (
-    keye_block_loop_time,
     keye_kernel_roofline,
     keye_mfu,
     moe_routed_time,
@@ -167,8 +166,10 @@ def test_reducers_read_the_trace_and_stay_under_the_peaks():
         ("%flash_sel_bwd_dq.2 = bf16[1,16384,4096] custom-call()", 0, 30 * ms),
         ("%flash_sel_bwd_dkv.3 = (bf16[1,16384,512]) custom-call()", 0,
          40 * ms),
-        # the selection's loop over blocks of 256 rows, with a nested
-        # bisection loop inside its event; the loss's loops over 128 rows
+        ("%flash_sel_bwd_tiled.4 = (bf16[1,16384,4096]) custom-call()", 0,
+         32 * ms),
+        # the selection's and the loss's block loops of the tree before
+        # PR 52 / PR 54 (kernels since): no routed metric may count them
         ("%while.10 = (s32[], s8[64,256,16384], bf16[64,256,16,64]) "
          "while(...)", 0, 90 * ms),
         ("%while.11 = (u32[], u32[256], u32[256,16384]) while(...)", 0,
@@ -200,16 +201,13 @@ def test_reducers_read_the_trace_and_stay_under_the_peaks():
     for kernel in ("flash_sel_bwd_dq", "flash_sel_bwd_dkv"):
         share = keye_kernel_roofline.reduce(run, {"kernel": kernel})
         assert 50 < share < 60
+    # the one sweep: five products a tile, 28.78 ms at the peak
+    assert keye_kernel_roofline.reduce(
+        run, {"kernel": "flash_sel_bwd_tiled"}
+    ) == pytest.approx(100 * 28.778 / 32, rel=1e-3)
     assert keye_kernel_roofline.reduce(run, {"kernel": "flash_gqa_fwd"}) is None
     assert keye_mfu.reduce(run, {}) == pytest.approx(
         100 * 22.6488e12 / 3.0 / 197e12, rel=1e-3
-    )
-    # the two passes, told apart by the selection's blocks in the loop state
-    assert keye_block_loop_time.reduce(run, {"pass": "select"}) == (
-        pytest.approx(90.0)
-    )
-    assert keye_block_loop_time.reduce(run, {"pass": "index_loss"}) == (
-        pytest.approx(340.0)
     )
     # the routed metric counts the sort and the loop with the held matrices
     # — and none of the selection's or the loss's loops
@@ -222,42 +220,8 @@ def test_reducers_read_the_trace_and_stay_under_the_peaks():
     }})
     for reducer, params in (
         (keye_kernel_roofline, {"kernel": "flash_sel_fwd"}),
-        (keye_block_loop_time, {"pass": "select"}),
-        (keye_block_loop_time, {"pass": "index_loss"}),
+        (keye_kernel_roofline, {"kernel": "flash_sel_bwd_tiled"}),
     ):
         assert reducer.reduce(bare, params) is None
         assert reducer.reduce(_run(None), params) is None
     assert keye_mfu.reduce(_run(None), {}) is None
-
-
-def test_the_two_passes_are_told_apart_only_while_their_blocks_differ(
-        monkeypatch):
-    """A later change that gives both passes one block size leaves the two
-    metrics EMPTY, not each with the other's time too; so does a program
-    that has no such model."""
-    import sys
-
-    from dedloc_tpu.models import keye_vl2
-
-    ms = 1e6
-    trace = {"/device:TPU:0": {
-        "XLA Modules": [("jit_accumulate_step(1)", 0, 3000 * ms)],
-        "XLA Ops": [
-            ("%while.1 = (s32[], s8[64,256,16384]) while(...)", 0, 90 * ms),
-            ("%while.2 = (s32[], s8[128,128,16384]) while(...)", 0, 140 * ms),
-        ],
-    }}
-    run = _run(trace)
-    assert keye_block_loop_time.block_rows(SEQ) == {
-        "select": 256, "index_loss": 128,
-    }
-    assert keye_block_loop_time.reduce(run, {"pass": "select"}) == (
-        pytest.approx(90.0)
-    )
-    monkeypatch.setattr(keye_vl2, "INDEX_LOSS_BLOCK_ROWS", 256)
-    for which in ("select", "index_loss"):
-        assert keye_block_loop_time.reduce(run, {"pass": which}) is None
-    monkeypatch.undo()
-    monkeypatch.setitem(sys.modules, "dedloc_tpu.models.keye_vl2", None)
-    for which in ("select", "index_loss"):
-        assert keye_block_loop_time.reduce(run, {"pass": which}) is None

@@ -15,12 +15,18 @@ HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(HERE)
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 CELL = "keye_vl2_30b_a3b_s16384.solo"
-METRICS = [
+# the cell's own per-layer metrics, in BENCHMARK.json's order: PR 51's, the
+# kernels' times of PR 52, PR 54 and PR 56, the one-sweep backward's share of
+# PR 61 (which retired the two block-loop times); the gauge is the program's
+# own and is on the step records anywhere
+TRACE_METRICS = [
     "keye.mfu_pct", "keye.flash_sel_fwd_roofline",
-    "keye.flash_sel_bwd_dq_roofline", "keye.flash_sel_bwd_dkv_roofline",
-    "keye.select_device_ms", "keye.index_loss_device_ms",
-    "keye.routed_device_ms",
+    "keye.flash_sel_bwd_tiled_roofline", "keye.routed_device_ms",
+    "keye.index_loss_fwd.device_us", "keye.index_loss_bwd.device_us",
+    "keye.select.device_us", "flash_sel_bwd_tiled.device_us",
 ]
+GAUGE_METRICS = ["keye.select_tie_block_share"]
+METRICS = TRACE_METRICS[:7] + GAUGE_METRICS + TRACE_METRICS[7:]
 
 
 def _config():
@@ -176,8 +182,10 @@ def test_rehearse_keye_cell():
                  "device.peak_hbm_gb", "step.untimed_pct"):
         assert f"smoke.{name}" in metrics, name
     # no device trace on the CPU: the trace-read metrics are left out
-    for name in METRICS:
+    for name in TRACE_METRICS:
         assert f"smoke.{name}" not in metrics
+    for name in GAUGE_METRICS:
+        assert f"smoke.{name}" in metrics
     assert all(name.startswith("smoke.") for name in metrics)
     # the role's gauges, on the reference check's line of the log
     line = next(

@@ -19,8 +19,7 @@ CELL = "kimi_linear_48b_a3b_s8192.solo"
 TRACE_METRICS = [
     "kimi.mfu_pct", "kimi.kda_fwd_roofline", "kimi.kda_bwd_roofline",
     "kimi.kda_device_ms", "kimi.flash_mla_fwd_roofline",
-    "kimi.flash_mla_bwd_dq_roofline", "kimi.flash_mla_bwd_dkv_roofline",
-    "kimi.routed_device_ms",
+    "kimi.flash_mla_bwd_tiled_roofline", "kimi.routed_device_ms",
 ]
 GAUGE_METRICS = [
     "kimi.kda_chunk_log_decay_min", "kimi.kda_beta_mean",

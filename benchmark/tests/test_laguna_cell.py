@@ -17,9 +17,8 @@ CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 CELL = "laguna_xs2_33b_a3b_s8192.solo"
 METRICS = [
     "laguna.mfu_pct", "laguna.flash_band_fwd_roofline",
-    "laguna.flash_band_bwd_dq_roofline", "laguna.flash_band_bwd_dkv_roofline",
-    "laguna.flash_full_fwd_roofline", "laguna.flash_full_bwd_dq_roofline",
-    "laguna.flash_full_bwd_dkv_roofline", "laguna.routed_device_ms",
+    "laguna.flash_band_bwd_tiled_roofline", "laguna.flash_full_fwd_roofline",
+    "laguna.flash_full_bwd_tiled_roofline", "laguna.routed_device_ms",
 ]
 
 

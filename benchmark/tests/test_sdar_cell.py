@@ -98,12 +98,14 @@ def test_the_cell_is_the_issues():
     mine = [m["name"] for m in declared["per_layer"]
             if m.get("workloads") == [CELL]]
     assert mine == [
-        "sdar.mfu_pct", "flash_bd_fwd_roofline", "flash_bd_bwd_dq_roofline",
-        "flash_bd_bwd_dkv_roofline", "sdar.routed_device_ms",
+        "sdar.mfu_pct", "flash_bd_fwd_roofline",
+        "flash_bd_bwd_tiled_roofline", "sdar.routed_device_ms",
+        "flash_bd_bwd_tiled.device_us",
     ]
-    assert declared["workloads"][-1]["name"] == CELL
-    assert declared["workloads"][-1]["why"] == cell["why"]
-    assert declared["configs"][-1]["name"] == config["name"]
+    # membership, not "the last entry": later cells come after this one
+    (entry,) = [w for w in declared["workloads"] if w["name"] == CELL]
+    assert entry["why"] == cell["why"] and entry["config"] == config["name"]
+    assert config["name"] in [c["name"] for c in declared["configs"]]
     # each limit lies between its two readings, both in the file
     why = config["check"]["tolerance_why"]
     for name in config["check"]["tolerance"]:
@@ -130,7 +132,7 @@ def test_rehearse_sdar_cell():
         assert f"smoke.{name}" in metrics, name
     # no device trace on the CPU: the trace-read metrics are left out
     for name in ("sdar.mfu_pct", "flash_bd_fwd_roofline",
-                 "flash_bd_bwd_dkv_roofline", "sdar.routed_device_ms"):
+                 "flash_bd_bwd_tiled_roofline", "sdar.routed_device_ms"):
         assert f"smoke.{name}" not in metrics
     assert all(name.startswith("smoke.") for name in metrics)
     # the role's gauges, on the reference check's line of the log

@@ -100,14 +100,15 @@ def test_the_cell_is_the_issues():
             if m.get("workloads") == [CELL]]
     assert mine == [
         "smallthinker.mfu_pct", "flash_band_fwd_roofline",
-        "flash_band_bwd_dq_roofline", "flash_band_bwd_dkv_roofline",
+        "flash_band_bwd_tiled_roofline",
         "smallthinker.flash_full_fwd_roofline",
-        "smallthinker.flash_full_bwd_dq_roofline",
-        "smallthinker.flash_full_bwd_dkv_roofline",
+        "smallthinker.flash_full_bwd_tiled_roofline",
         "smallthinker.routed_device_ms",
     ]
-    assert declared["workloads"][-1]["name"] == CELL
-    assert declared["configs"][-1]["name"] == config["name"]
+    # membership, not "the last entry": later cells come after this one
+    (entry,) = [w for w in declared["workloads"] if w["name"] == CELL]
+    assert entry["why"] == cell["why"] and entry["config"] == config["name"]
+    assert config["name"] in [c["name"] for c in declared["configs"]]
     # each limit lies between its two readings, both in the file
     why = config["check"]["tolerance_why"]
     for name in config["check"]["tolerance"]:
@@ -134,7 +135,7 @@ def test_rehearse_smallthinker_cell():
         assert f"smoke.{name}" in metrics, name
     # no device trace on the CPU: the trace-read metrics are left out
     for name in ("smallthinker.mfu_pct", "flash_band_fwd_roofline",
-                 "smallthinker.flash_full_bwd_dkv_roofline",
+                 "smallthinker.flash_full_bwd_tiled_roofline",
                  "smallthinker.routed_device_ms"):
         assert f"smoke.{name}" not in metrics
     assert all(name.startswith("smoke.") for name in metrics)
