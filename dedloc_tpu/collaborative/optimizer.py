@@ -55,6 +55,7 @@ from dedloc_tpu.parallel.train_step import (
     make_guarded_apply_step,
     zeros_like_grads,
 )
+from dedloc_tpu.utils.backend import hbm_bytes_in_use
 from dedloc_tpu.utils.checkpoint import (
     named_leaves,
     named_to_tree,
@@ -102,6 +103,23 @@ _fused_mean_clip_in_place = jax.jit(
     _fused_mean_clip, donate_argnums=(0,), static_argnames=("exempt",)
 )
 _fused_mean_clip = jax.jit(_fused_mean_clip, static_argnames=("exempt",))
+
+
+def _requested(leaf: jax.Array) -> jax.Array:
+    """``leaf``'s transfer to the host, started through a second Array over
+    its device buffers (no program, no bytes). The runtime keeps a fetched
+    value on the Array it was fetched through, so a backup reads the live
+    state through aliases that die with the read: no live leaf ever holds a
+    host copy (``_launch_backup``). A one-device leaf is its own shard:
+    asking it for its shards would leave a view of it behind."""
+    shards = [leaf] if len(leaf.sharding.device_set) == 1 else [
+        shard.data for shard in leaf.addressable_shards
+    ]
+    alias = jax.make_array_from_single_device_arrays(
+        leaf.shape, leaf.sharding, shards
+    )
+    alias.copy_to_host_async()
+    return alias
 
 
 class CollaborativeOptimizer:
@@ -346,11 +364,12 @@ class CollaborativeOptimizer:
         # full device round-trip per global step)
         self._pending_apply_ok: Optional[Tuple[str, Any]] = None
         self._lock = threading.Lock()
-        # the state backup (device_get of params+opt_state) runs on this
-        # thread, OFF the critical path: it is read-only w.r.t. the next
-        # round's gradients, so the next accumulation phase overlaps it
-        # (SURVEY.md §7 hard-part b; seam cost published in BASELINE.md)
+        # the state backup runs on this thread, OFF the critical path: it
+        # READS the live state, which nothing writes before the next apply —
+        # and that apply, which donates it, waits while the event is clear
         self._backup_thread: Optional[threading.Thread] = None
+        self._backup_read_done = threading.Event()
+        self._backup_read_done.set()
         # the backup transfer may use at most this fraction of wall time, so
         # a slow device↔host link degrades to periodic backups instead of
         # serializing every global step behind a full state download
@@ -468,7 +487,7 @@ class CollaborativeOptimizer:
         record's attr of the same name."""
         tele = telemetry.resolve(self.telemetry)
         if tele is not None:
-            tele.counter(key).inc(n)  # dedlint: emits=counter:opt.backup_bytes,counter:opt.backup_host_alloc_bytes,counter:opt.backups_skipped.duty_cycle,counter:opt.backups_skipped.busy,counter:opt.backups_skipped.leased
+            tele.counter(key).inc(n)  # dedlint: emits=counter:opt.backup_bytes,counter:opt.backup_host_alloc_bytes,counter:opt.backups_skipped.duty_cycle,counter:opt.backups_skipped.busy,counter:opt.backups_skipped.leased,counter:opt.backup_waits
         if record is not None:
             record.attrs[key] = record.attrs.get(key, 0) + n
 
@@ -1369,11 +1388,15 @@ class CollaborativeOptimizer:
             # previous boundary's NaN verdict has settled by now — read it
             # without stalling this boundary's dispatch
             self._check_apply_ok()
-            # NaN guard now lives INSIDE the jitted apply (a fused
-            # all-finite reduce + jnp.where rollback): no pre-apply HBM
-            # copy of (step, params, opt_state), no host-synced finite
-            # check per global step (make_guarded_apply_step). post_apply
-            # is folded into the same program.
+            with steps.phase("backup_wait"):
+                # the apply DONATES the buffers a backup reads in place: it
+                # waits for the read's end (not the copies or the publish)
+                if not self._backup_read_done.is_set():
+                    self._count_backup(steps.current(), "opt.backup_waits", 1)
+                    self._backup_read_done.wait()
+            # the NaN guard (an all-finite reduce + jnp.where rollback) and
+            # post_apply are INSIDE the jitted apply: no pre-apply HBM copy
+            # of the state, no host-synced check (make_guarded_apply_step)
             flat_fn = (
                 self._ensure_flat_apply(state, mean_grads.spec)
                 if isinstance(mean_grads, FlatTree) else None
@@ -1439,25 +1462,25 @@ class CollaborativeOptimizer:
 
     def _backup_and_share(self, state: TrainState) -> None:
         """Host snapshot of (params, opt_state) for late joiners
-        (load_state_from_peers counterpart, run_trainer.py:124-128). The
-        NaN-rollback backup is NOT here — it lives on device
-        (see ``_apply_and_advance``) — so this transfer is pure state
-        sharing and can be skipped entirely when sharing is off.
+        (``load_state_from_peers``' counterpart): pure state sharing — the
+        NaN guard lives inside the apply — so it is skipped when sharing is
+        off.
 
-        Runs on a background thread: the transfer is read-only w.r.t. the
-        next round (a fresh grad accumulator), so the next accumulation phase
-        overlaps the hundreds of MB of device→host traffic instead of
-        stalling behind it. The host form lives in buffers the averager
-        KEEPS (two sets, written in turn): a backup maps and frees nothing
-        of the state's size beside the loop.
+        Runs on a background thread that reads the LIVE state (no second
+        copy on the device): nothing writes it between two applies, so the
+        next accumulation phase overlaps the device→host traffic, and the
+        next APPLY, which donates it, waits for the read's end
+        (``backup_wait``; ``load_state_from_peers`` and ``shutdown`` join the
+        thread). The loop pays one host sync and a thread start. The host
+        form lives in buffers the averager KEEPS (two sets, written in turn):
+        a backup maps and frees nothing of the state's size beside the loop.
 
         Duty-cycle cap: when the backup (transfer + publish) takes longer
         than ``backup_duty_cycle`` of the time between global steps, skip
         this step's snapshot instead of queueing behind it — late joiners
-        get a slightly older state, training throughput stays intact. (On
-        PCIe the transfer is ~ms and effectively every step is shared; the
-        cap only bites on slow links.) A snapshot is skipped the same way
-        while a peer still downloads from the set it would be written into.
+        get a slightly older state, training throughput stays intact. A
+        snapshot is skipped the same way while a peer still downloads from
+        the set it would be written into.
         Every skip is counted by its reason (``opt.backups_skipped.*``).
         """
         if not self.averager.allow_state_sharing:
@@ -1486,54 +1509,47 @@ class CollaborativeOptimizer:
         self, state_step, snapshot, buffers: SnapshotBuffers, allocated: int
     ) -> None:
         """The part of a backup the training thread pays: one host sync on
-        the apply program (``int(state.step)``), an on-device copy of the
-        state (``snapshot``: params and optimizer state), and the start of
-        the thread that takes it to the host, into ``buffers`` (the
-        averager's kept set that is not published; ``allocated``: the bytes
-        it had to allocate for it)."""
+        the apply program (``int(state.step)``) and the start of the thread
+        that takes ``snapshot`` (the LIVE params and optimizer state) to the
+        host, into ``buffers`` (the averager's kept set that is not
+        published; ``allocated``: the bytes it had to allocate for it)."""
         self._join_backup()
         step, local_step = int(state_step), self.local_step
-        # snapshot ON DEVICE first (an HBM copy, ~ms): the next global step's
-        # apply DONATES state's buffers, so the thread must never hold the
-        # live arrays — device_get on a donated buffer would raise "Array has
-        # been deleted" mid-transfer on exactly the slow links the duty cycle
-        # exists for
-        names, leaves = map(list, zip(*named_leaves(
-            jax.tree.map(jax.numpy.copy, snapshot)
-        )))
+        in_use, record = hbm_bytes_in_use(), steps.current()
+        if in_use is not None and record is not None:
+            record.attrs["opt.hbm_after_launch_bytes"] = in_use
+        names, leaves = map(list, zip(*named_leaves(snapshot)))
+        self._backup_read_done.clear()
 
         def backup() -> None:
             t0, started = time.perf_counter(), monotonic_clock()
             # Transfers are served in order: with every leaf requested up
             # front (what ``device_get`` does) the training thread's next
             # read of a scalar, or its next eager dispatch, waits behind
-            # the whole snapshot — 0.86 s of a 4.27 GB one, with the device
+            # the whole state — 0.86 s of a 4.27 GB one, with the device
             # idle (PERF.md, PR 25). So one leaf is requested ahead of the
-            # one being read, and each device copy is let go as soon as its
-            # bytes are on the host: the snapshot (12 bytes a parameter
-            # under LAMB) shrinks while the transfer runs instead of
-            # staying whole until its end. That loop is ALL this thread
-            # does: the copy of each leaf into the kept set runs on a thread
-            # of its own beside it (numpy copies without the interpreter
-            # lock), because with the copy between two leaves the device
-            # gave its bytes up a third slower — 2.2 s for 5.63 GB where the
-            # loop alone takes 1.5, and 0.26 GB more HBM at the step's peak
-            # (PERF.md, PR 60). The runtime's own host copy of a leaf goes
-            # with its copy job: a leaf's worth of host memory mapped and
-            # freed at a time, never a snapshot's.
+            # one being read, and that loop is ALL this thread does until
+            # the read's end, which the next apply may wait for: each leaf
+            # is copied into the kept set on a thread of its own (between
+            # two leaves it slowed the device's 5.63 GB from 1.5 s to 2.2;
+            # PERF.md, PR 60). The runtime's host copy of a leaf goes with
+            # its alias and its copy job: a leaf's worth, never a state's.
             nbytes, copies = 0, []
             with concurrent.futures.ThreadPoolExecutor(
                 1, thread_name_prefix="backup-copy"
             ) as copier:
-                leaves[0].copy_to_host_async()
-                for i, name in enumerate(names):
-                    if i + 1 < len(leaves):
-                        leaves[i + 1].copy_to_host_async()
-                    host = np.asarray(leaves[i])
-                    leaves[i] = None  # the device copy goes first
-                    nbytes += host.nbytes
-                    copies.append(copier.submit(buffers.write, name, host))
-                    del host
+                try:
+                    leaves[0] = _requested(leaves[0])
+                    for i, name in enumerate(names):
+                        if i + 1 < len(leaves):
+                            leaves[i + 1] = _requested(leaves[i + 1])
+                        host = np.asarray(leaves[i])
+                        leaves[i] = None
+                        nbytes += host.nbytes
+                        copies.append(copier.submit(buffers.write, name, host))
+                        del host
+                finally:  # the read's end, reached or given up
+                    self._backup_read_done.set()
             for copy in copies:
                 copy.result()  # a failed copy fails the backup, unpublished
             self.averager.publish_shared_state(
@@ -1548,7 +1564,7 @@ class CollaborativeOptimizer:
             # provider record, the sharded form's manifest (sha256 over the
             # set in place) and its announcement; at a process's first
             # backup also the second kept set, allocated and touched here,
-            # where no device copy waits for it
+            # behind the read's end, where nothing waits for it
             self.averager.publish_state_provider(
                 expiration=self.tracker.metadata_expiration * 4,
                 step=local_step,

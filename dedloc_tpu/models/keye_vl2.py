@@ -157,8 +157,8 @@ class KeyeVL2Config:
     # the very array the forward read. Not "whole_mixer", the other expert
     # decoders' default: the stream after attention and the q / k norm's
     # input are 218 MB a layer more (0.87 GB in the benchmark's cell, where
-    # accumulate_step's scratch beside 9.4 GB of state while a backup drains
-    # has to stay under 5.9 GB), and what they save — q_proj, k_proj and
+    # accumulate_step's scratch reads 5.34 GB beside 5.3 GB of state and
+    # accumulator), and what they save — q_proj, k_proj and
     # o_proj in the replay, 0.2 TFLOP a layer — is a hundredth of this
     # layer's replay. Below "kernel_operands" the selection is REPLAYED from
     # the replayed indexer (the same ops on the same values)

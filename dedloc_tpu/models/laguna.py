@@ -148,8 +148,8 @@ class LagunaConfig:
     # a full one; in the benchmark's cell of five layers accumulate_step's
     # scratch reads 2.47 GB (2.94 before the gate was a kernel pair, PR
     # 48; then 2.80 under "kernel_operands", 2.14 under "kernel_outputs")
-    # beside 10.91 GB of state while a backup drains, and the allocator's
-    # peak (the boundary's: 12.06 GB) does not move with it. A smaller
+    # beside 6.23 GB of state and accumulator, and the allocator's peak
+    # does not move with it (PERF.md section 5). A smaller
     # chip or a larger share: --training.remat_policy kernel_operands,
     # then kernel_outputs
     remat_policy: str = "whole_mixer"

@@ -115,8 +115,8 @@ class Lfm2MoeConfig:
     # 4,096 x ((32 + 2·8) x 64 + (32 + 8) x 64 + 2,048) x 2 = 63 MB the
     # attention layer, 0.33 GB in the benchmark's cell (0.23 of them
     # "kernel_operands"'), where accumulate_step's scratch reads 0.81 GB
-    # (0.72 under "kernel_operands") beside 13.14 GB of state while a backup
-    # drains. A smaller chip or a larger share: --training.remat_policy
+    # (0.72 under "kernel_operands") beside 8.1 GB of state, accumulator and
+    # bf16 copies. A smaller chip or a larger share: --training.remat_policy
     # kernel_operands, then kernel_outputs
     remat_policy: str = "whole_mixer"
     attention_impl: str = "flash"  # or "dense" (tests, tiny models)

@@ -113,8 +113,8 @@ class SdarMoeConfig:
     # no relayout: 8,192 x ((32 + 2·4) x 128 + (32 + 4) x 128 + 2,048) x 2
     # bytes = 193 MB a layer a micro-batch (84 of them "kernel_operands"'),
     # 0.77 GB in the benchmark's cell of four layers, where accumulate_step's
-    # scratch reads 1.70 GB (1.28 under "kernel_operands") beside 12.78 GB
-    # of state while a backup drains. A smaller chip or a larger share:
+    # scratch reads 1.70 GB (1.28 under "kernel_operands") beside 7.30 GB
+    # of state and accumulator. A smaller chip or a larger share:
     # --training.remat_policy kernel_operands, then kernel_outputs
     remat_policy: str = "whole_mixer"
     attention_impl: str = "flash"  # or "dense" (tests, tiny models)

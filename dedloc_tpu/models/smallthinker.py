@@ -108,8 +108,8 @@ class SmallThinkerConfig:
     # no stash): 16,384 x ((28 + 2·4) x 128 + 2,560) x 2 bytes = 235 MB a
     # layer a micro-batch (151 of them "kernel_operands"'), 0.94 GB in the
     # benchmark's cell of four layers, where accumulate_step's scratch reads
-    # 2.91 GB (2.53 under "kernel_operands") beside 10.38 GB of state while
-    # a backup drains. A smaller chip or a larger share:
+    # 2.91 GB (2.53 under "kernel_operands") beside 5.93 GB of state and
+    # accumulator. A smaller chip or a larger share:
     # --training.remat_policy kernel_operands, then kernel_outputs
     remat_policy: str = "whole_mixer"
     attention_impl: str = "flash"  # or "dense" (tests, tiny models)

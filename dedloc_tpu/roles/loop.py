@@ -25,6 +25,7 @@ from dedloc_tpu.roles.common import open_train_log, publish_step_metrics
 from dedloc_tpu.telemetry import steps
 from dedloc_tpu.telemetry.profile import profile_gate
 from dedloc_tpu.telemetry.steps import StepRecorder, chip_peak_tflops
+from dedloc_tpu.utils.backend import hbm_bytes_in_use
 from dedloc_tpu.utils.logging import get_logger
 from dedloc_tpu.utils.perf import PerfStats
 
@@ -205,7 +206,7 @@ def run_boundary_loop(
                                 dht, args, public_key, opt, tele, row,
                                 samples=samples, loss=loss_sum,
                                 mini_steps=mini_steps, sps=sps,
-                                hbm_bytes=_hbm_bytes_in_use(),
+                                hbm_bytes=hbm_bytes_in_use(),
                             )
                         mini_steps = 0
                         with steps.phase("log"):
@@ -251,13 +252,3 @@ def run_boundary_loop(
         dht.shutdown()
     return state
 
-
-def _hbm_bytes_in_use() -> Optional[int]:
-    """Device bytes_in_use via PJRT memory_stats (None off-TPU/unsupported)."""
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-        if stats:
-            return int(stats.get("bytes_in_use", 0)) or None
-    except Exception:  # noqa: BLE001 — telemetry must never kill training
-        pass
-    return None

@@ -92,6 +92,7 @@ NET_BYTES_IN = "net.bytes_in"
 NET_BYTES_OUT = "net.bytes_out"
 OPT_BACKUP_BYTES = "opt.backup_bytes"
 OPT_BACKUP_HOST_ALLOC_BYTES = "opt.backup_host_alloc_bytes"
+OPT_BACKUP_WAITS = "opt.backup_waits"
 OPT_BACKUPS_SKIPPED_BUSY = "opt.backups_skipped.busy"
 OPT_BACKUPS_SKIPPED_DUTY_CYCLE = "opt.backups_skipped.duty_cycle"
 OPT_BACKUPS_SKIPPED_LEASED = "opt.backups_skipped.leased"
@@ -215,6 +216,7 @@ COUNTERS = frozenset({
     "net.bytes_out",
     "opt.backup_bytes",
     "opt.backup_host_alloc_bytes",
+    "opt.backup_waits",
     "opt.backups_skipped.busy",
     "opt.backups_skipped.duty_cycle",
     "opt.backups_skipped.leased",

@@ -46,10 +46,11 @@ spans (docs/observability.md "Step-phase flight recorder"):
                    thread's CPU time over ``allreduce``,
                    ``ar_attached_chunks`` the chunk payloads its frames
                    carried by reference
-- ``opt_apply``    optimizer apply + NaN guard; child ``h2d_result`` (the
-                   averaged flat buffer going back to the device)
+- ``opt_apply``    optimizer apply + NaN guard; children ``backup_wait``
+                   (for the end of a backup's read of the state the apply
+                   donates) and ``h2d_result`` (the averaged flat buffer)
 - ``backup_launch`` the on-thread part of the state backup: one host sync
-                   on the apply program + an on-device copy of the state
+                   on the apply program + a thread start
 - ``acc_reset``    the fresh gradient accumulator after an apply (one small
                    eager program per leaf)
 - ``collab``       progress-tracker reads/reports (DHT overhead)
@@ -149,8 +150,8 @@ logger = get_logger(__name__)
 PHASES = (
     "data_wait", "h2d", "fwd_bwd", "drain", "round_plan", "grad_flatten",
     "ef_norm", "avg_wire",
-    "d2h_stream", "opt_apply", "h2d_result", "backup_launch", "acc_reset",
-    "collab", "post_step", "loss_sync", "publish", "log",
+    "d2h_stream", "opt_apply", "backup_wait", "h2d_result", "backup_launch",
+    "acc_reset", "collab", "post_step", "loss_sync", "publish", "log",
 )
 
 # a record holding more spans than this folds its repeated leaf spans

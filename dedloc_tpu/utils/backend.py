@@ -11,7 +11,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import os
-from typing import Dict, Iterator
+from typing import Dict, Iterator, Optional
 
 import jax
 
@@ -76,3 +76,12 @@ def describe_backend() -> Dict[str, object]:
         "device_count": len(devices),
         "kernel_mode": "interpret" if pallas_interpret() else "compiled",
     }
+
+
+def hbm_bytes_in_use() -> Optional[int]:
+    """Device bytes_in_use via PJRT memory_stats (None off-TPU/unsupported)."""
+    try:
+        stats = jax.local_devices()[0].memory_stats() or {}
+        return int(stats.get("bytes_in_use", 0)) or None
+    except Exception:  # noqa: BLE001 — telemetry must never kill training
+        return None

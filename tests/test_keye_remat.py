@@ -135,9 +135,11 @@ def test_keye_accumulate_step_reads_a_selection_and_holds_nothing_heads_by_s_by_
     NOTHING of size [heads, S, S] is materialised — the largest array the
     compiled module names is 256 MB (the int8 selection itself), where ONE
     head's float32 scores are 1,024 MB and 32
-    heads' bf16 ones 17 GB —; and the program's scratch beside 28 bytes a
-    parameter of state with a draining snapshot (+ the held experts' bf16
-    copies) stays under the 15.3 GB line."""
+    heads' bf16 ones 17 GB —; and the program's scratch stays where the cell
+    was sized: under the 15.3 GB line beside 28 bytes a parameter (+ the held
+    experts' bf16 copies). The tree holds 16 since PR 62 (a backup reads the
+    live state); the bound stays, so that scratch does not grow into the
+    room unnoticed."""
     rows = tpu_aot("sel_kernels", "index_loss_kernels", "select_kernels",
                     "keye_accumulate_step")
     # the selection's kernel alone (``ops/index_select.py``): Mosaic takes a

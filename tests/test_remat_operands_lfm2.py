@@ -37,8 +37,10 @@ def test_lfm2_accumulate_step_keeps_what_its_backward_reads():
     under remat ``kernel_outputs`` every kernel's outputs are kept, so each
     forward kernel has ONE call site per mixer and the backward replays
     none — short_conv 4 + 4, flash_gqa 1 + 1 (ONE backward kernel); and the program's scratch
-    beside 28 bytes a parameter of state with a draining snapshot (13.14 GB)
-    stays under the allocator's 16.91 GB with 1 GB to spare."""
+    stays where the cell was sized: beside 28 bytes a parameter (13.14 GB)
+    under the allocator's 16.91 GB with 1 GB to spare (the tree holds 16
+    since PR 62; the bound stays, so that scratch does not grow into the
+    room unnoticed)."""
     rows = tpu_aot("gqa_kernels", "lfm2_accumulate_step")
     heads = {"heads": 32, "kv_heads": 8}
     for row in rows.values():

@@ -40,9 +40,9 @@ def test_sdar_accumulate_step_takes_the_block_rule_and_a_group_of_eight():
     SiLU-gated tile loop's backward sums into the accumulator's twelve
     expert leaves (a scan over single layers zero-filled, copied and cast
     3.0 GB of stacked expert matrices: ``models/sdar_moe._Period``); and the
-    program's scratch beside 28 bytes a parameter of state with a draining
-    snapshot stays under the 15.3 GB line this tree's cells are sized
-    under."""
+    program's scratch stays where the cell was sized: beside 28 bytes a
+    parameter under the 15.3 GB line (the tree holds 16 since PR 62; the
+    bound stays, so that scratch does not grow into the room unnoticed)."""
     rows = tpu_aot("bd_kernels", "sdar_accumulate_step")
     blocks = {"heads": 32, "kv_heads": 4, "block": 4, "stream": 4096}
     for row in rows.values():

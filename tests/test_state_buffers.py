@@ -1,11 +1,15 @@
 """The shared state in KEPT host buffers (PR 60): a backup maps and frees
 nothing of the state's size after a process's first, the sharded form is
 hashed in place and equals the flattened one's, a set under read is not
-written, and joiners load whole snapshots while backups run."""
+written, and joiners load whole snapshots while backups run. And ONE state
+on the device (PR 62): a backup reads the live state through aliases, makes
+no copy of it, leaves no host value on it, and the next apply — which
+donates it — waits for the read's end and for nothing behind it."""
 import hashlib
 import threading
 import time
 
+import jax
 import jax.numpy as jnp
 import ml_dtypes
 import numpy as np
@@ -27,11 +31,14 @@ from dedloc_tpu.checkpointing import (
     verify_shard,
 )
 from dedloc_tpu.collaborative import CollaborativeOptimizer
+from dedloc_tpu.collaborative import optimizer as optimizer_mod
 from dedloc_tpu.core.serialization import deserialize_tree, unpack_obj
 from dedloc_tpu.dht import DHT
 from dedloc_tpu.optim import lamb
 from dedloc_tpu.parallel.train_step import TrainState
 from dedloc_tpu.telemetry.registry import Telemetry
+from dedloc_tpu.telemetry.steps import StepRecorder
+from dedloc_tpu.utils.checkpoint import named_leaves
 
 BF16 = np.dtype(ml_dtypes.bfloat16)
 
@@ -470,3 +477,227 @@ def test_joiner_loads_whole_snapshots_while_backups_run(shard_size):
         joiner.shutdown(); provider.shutdown()
         second.shutdown(); root.shutdown()
     assert written["k"] >= 3
+
+
+# ------------------------------------- (e) one state on the device (PR 62)
+
+
+def _live_state(k: float = 1.0) -> TrainState:
+    """Three parameter leaves under LAMB: ten leaves a snapshot."""
+    return TrainState.create(
+        {"w": jnp.arange(12.0).reshape(3, 4) + k, "b": jnp.full((5,), k),
+         "e": jnp.full((2, 3), -k)},
+        lamb(0.05),
+    )
+
+
+def _snapshot_leaves(state):
+    return dict(named_leaves((state.params, state.opt_state)))
+
+
+def _device_buffers():
+    """The device buffers under this process's live arrays."""
+    out = set()
+    for array in jax.live_arrays():
+        try:
+            out.add(array.unsafe_buffer_pointer())
+        except Exception:  # noqa: BLE001 — over several devices: not ours
+            pass
+    return out
+
+
+class _Fetches:
+    """Every Array the backup thread asks the runtime to fetch, in order;
+    the ``hold_at``-th request stays open until ``release``."""
+
+    def __init__(self, monkeypatch, hold_at=None):
+        from jax._src.array import ArrayImpl
+
+        self.through, self.hold_at = [], hold_at
+        self.entered, self.release = threading.Event(), threading.Event()
+        real = ArrayImpl.copy_to_host_async
+
+        def spy(array):
+            if threading.current_thread() is not threading.main_thread():
+                self.through.append(array)
+                if len(self.through) == self.hold_at:
+                    self.entered.set()
+                    assert self.release.wait(30.0)
+            return real(array)
+
+        monkeypatch.setattr(ArrayImpl, "copy_to_host_async", spy)
+
+
+def test_a_backup_copies_nothing_on_the_device(monkeypatch):
+    """Held open at its FIRST request, the reader has made one alias — an
+    Array more, not a buffer more; the parent held a copy of every leaf
+    here. Behind the read nothing is left of it."""
+    dht = DHT(start=True, listen_host="127.0.0.1")
+    opt = _optimizer("nocopy", dht)
+    fetches = _Fetches(monkeypatch, hold_at=1)
+    try:
+        state = _live_state()
+        # (reading an Array's buffer pointer, or its value, leaves a view
+        # of it behind: those first, and the arrays counted first)
+        assert int(state.step) == 0
+        buffers, arrays = _device_buffers(), len(jax.live_arrays())
+        opt._backup_took = 0.0
+        opt.seed_state_sharing(state)
+        assert fetches.entered.wait(15.0), "the reader never asked"
+        assert len(jax.live_arrays()) <= arrays + 1
+        assert _device_buffers() == buffers
+        fetches.release.set()
+        opt._join_backup()
+        fetches.through.clear()
+        assert len(jax.live_arrays()) == arrays
+        assert _device_buffers() == buffers
+    finally:
+        fetches.release.set()
+        opt.shutdown()
+        dht.shutdown()
+
+
+def test_a_backup_leaves_no_host_value_on_the_live_state(monkeypatch):
+    """The runtime keeps a fetched value on the Array it was fetched
+    through (on a chip: a host copy of it), so no leaf of the live state is
+    ever fetched through: each is read through an Array of its own over the
+    SAME buffer, which the thread drops."""
+    dht = DHT(start=True, listen_host="127.0.0.1")
+    opt = _optimizer("nocache", dht)
+    fetches = _Fetches(monkeypatch)
+    try:
+        state = _live_state()
+        leaves = _snapshot_leaves(state)
+        for _ in range(2):
+            assert len(_backup(opt, state)) == 2
+        assert len(fetches.through) == 2 * len(leaves)
+        live = {id(leaf) for leaf in leaves.values()}
+        assert not live & {id(array) for array in fetches.through}
+        assert [a.unsafe_buffer_pointer() for a in fetches.through] == 2 * [
+            leaves[name].unsafe_buffer_pointer() for name in sorted(leaves)
+        ]
+        assert all(leaf._npy_value is None for leaf in leaves.values())
+        tree = opt.averager._shared_state[0].tree
+        for name, leaf in leaves.items():
+            np.testing.assert_array_equal(tree[name], np.array(leaf))
+    finally:
+        opt.shutdown()
+        dht.shutdown()
+
+
+@pytest.mark.parametrize("reader", ["held_open", "done_first"])
+def test_an_apply_waits_for_the_reads_end_and_not_for_the_publish(
+    monkeypatch, reader
+):
+    """A backup launched from S, then the apply that donates S. With the
+    read held open at its fourth leaf the apply blocks until the read's end
+    — and goes on while the PUBLISH is still held; the snapshot that lands
+    is S at S's step, bit for bit. With the reader done first nothing
+    blocks and nothing is counted."""
+    dht = DHT(start=True, listen_host="127.0.0.1")
+    tele = Telemetry(peer="solo")
+    opt = _optimizer("wait", dht, tele)
+    held = reader == "held_open"
+    fetches = _Fetches(monkeypatch, hold_at=4 if held else None)
+    publish_entered, publish = threading.Event(), threading.Event()
+    real_publish = opt.averager.publish_shared_state
+
+    def held_publish(*args, **kwargs):
+        publish_entered.set()
+        assert publish.wait(30.0)
+        return real_publish(*args, **kwargs)
+
+    recorder, out = StepRecorder(), {}
+    try:
+        state = _live_state()
+        before = _snapshot_leaves(state)
+        expected = {name: np.array(leaf) for name, leaf in before.items()}
+        grads = jax.tree.map(jnp.ones_like, state.params)
+        collab = opt.tracker.fetch_collaboration_state()
+        opt._backup_took = 0.0
+        if held:
+            opt.averager.publish_shared_state = held_publish
+        opt.seed_state_sharing(state)
+        if held:
+            assert fetches.entered.wait(15.0), "the reader never got there"
+        else:
+            opt._join_backup()
+
+        def apply():
+            with recorder.step(step=0):
+                out["state"] = opt._apply_and_advance(state, grads, collab, 1)[0]
+
+        applying = threading.Thread(target=apply, daemon=True)
+        applying.start()
+        if held:
+            applying.join(0.5)
+            assert applying.is_alive(), "the apply did not wait for the read"
+            assert not any(leaf.is_deleted() for leaf in before.values())
+            fetches.release.set()
+        applying.join(30.0)
+        assert not applying.is_alive(), "the apply waited for the publish"
+        record = recorder.records[-1]
+        spans = {s[0]: s for s in record["spans"]}
+        assert spans["backup_wait"][1] == "opt_apply"
+        waited = spans["backup_wait"][3] - spans["backup_wait"][2]
+        if held:
+            # the read is over, the publish is not: nothing is shared yet,
+            # and the backup this apply would launch is skipped as busy
+            assert publish_entered.wait(15.0)
+            assert opt._backup_thread.is_alive()
+            assert opt.averager._shared_state is None
+            assert record["opt.backup_waits"] == 1 and waited > 0.3
+            assert record["opt.backups_skipped.busy"] == 1
+            publish.set()
+            opt._join_backup()
+        else:
+            # nobody read S any more: the apply took its buffers
+            assert all(leaf.is_deleted() for leaf in before.values())
+            assert "opt.backup_waits" not in record and waited < 0.2
+        assert tele.counter("opt.backup_waits").value == int(held)
+        assert int(out["state"].step) == 1
+        assert np.all(np.array(out["state"].params["b"]) != expected["[0]['b']"])
+        if held:
+            buffers, metadata = opt.averager._shared_state
+            assert metadata["step"] == 0
+            assert sorted(buffers.tree) == sorted(expected)
+            for name, leaf in expected.items():
+                assert buffers.tree[name].dtype == leaf.dtype
+                np.testing.assert_array_equal(buffers.tree[name], leaf)
+    finally:
+        fetches.release.set()
+        publish.set()
+        opt.shutdown()
+        dht.shutdown()
+
+
+def test_the_record_that_launches_a_backup_says_what_the_device_holds(
+    monkeypatch,
+):
+    """``opt.hbm_after_launch_bytes``: the device's bytes in use, read once
+    inside ``backup_launch`` — on the record that launched a backup, on no
+    other, and nowhere the runtime reports nothing (the CPU)."""
+    dht = DHT(start=True, listen_host="127.0.0.1")
+    opt = _optimizer("hbm", dht)
+    recorder = StepRecorder()
+    try:
+        state = _live_state()
+        readings = iter([None, 7_654_321_000])
+        monkeypatch.setattr(
+            optimizer_mod, "hbm_bytes_in_use", lambda: next(readings)
+        )
+        for took in (0.0, 0.0, 3600.0):  # launched twice, then skipped
+            opt._join_backup()
+            opt._backup_took = took
+            with recorder.step(step=0):
+                opt.seed_state_sharing(state)
+        silent, launched, skipped = list(recorder.records)[-3:]
+        for record in (silent, launched):
+            assert "backup_launch" in {s[0] for s in record["spans"]}
+        assert launched["opt.hbm_after_launch_bytes"] == 7_654_321_000
+        assert "opt.hbm_after_launch_bytes" not in silent
+        assert "opt.hbm_after_launch_bytes" not in skipped
+        assert skipped["opt.backups_skipped.duty_cycle"] == 1
+    finally:
+        opt.shutdown()
+        dht.shutdown()

@@ -362,6 +362,14 @@ def test_loop_record_with_a_real_optimizer_is_disjoint_and_counted(tmp_path):
         assert parents[name] is None, (name, stepping["spans"])
     for name in ("publish", "log"):
         assert parents[name] == "post_step"
+    # the apply donates the state a backup reads in place: it asks whether
+    # a read is still open on EVERY stepping record, and here none was
+    for record in records:
+        if record.get("stepped"):
+            assert ["backup_wait", "opt_apply"] in [
+                s[:2] for s in record["spans"]
+            ]
+        assert "opt.backup_waits" not in record
 
 
 # ------------------------------------------------------- the profiler's clock

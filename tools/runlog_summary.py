@@ -950,8 +950,8 @@ def print_topology(all_rows):
 _CANONICAL_PHASES = (
     "data_wait", "h2d", "fwd_bwd", "drain", "round_plan", "grad_flatten",
     "ef_norm", "avg_wire",
-    "d2h_stream", "opt_apply", "h2d_result", "backup_launch", "acc_reset",
-    "collab", "post_step", "loss_sync", "publish", "log",
+    "d2h_stream", "opt_apply", "backup_wait", "h2d_result", "backup_launch",
+    "acc_reset", "collab", "post_step", "loss_sync", "publish", "log",
 )
 
 
