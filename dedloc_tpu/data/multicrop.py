@@ -19,9 +19,13 @@ reproducible per peer seed.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import os
-from typing import Iterator, List, Optional, Sequence, Tuple
+import queue
+import sys
+import threading
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -54,29 +58,241 @@ def crop_groups(spec: MultiCropSpec, batch_size: int) -> List[Tuple[int, int]]:
     return [(c * batch_size, s) for s, c in zip(spec.sizes, spec.counts)]
 
 
+# a batch of fewer bytes is built in line, into fresh arrays; from here up
+# the synthetic source builds AHEAD of its consumer, into kept buffers
+PIPELINE_MIN_BYTES = 16 << 20
+# kept batches: the two a consumer may hold and the one being built
+RING_SLOTS = 3
+# later draws a batch of the pipelined source stays valid for: the draw
+# after that one may rewrite its buffers
+VALID_DRAWS = 1
+# float64 normals of one piece of a batch: 8 MB of kept scratch, still in
+# the cache when the arithmetic reads what the draw wrote
+_PIECE_ELEMS = 1 << 20
+_THREAD_PREFIX = "dedloc-multicrop"
+
+
+def _pieces(spec: MultiCropSpec, batch_size: int, piece_elems: int):
+    """(group, first row of the group, first image, images, size) of every
+    piece of one batch IN THE ORDER THE ONE STREAM IS DRAWN: view by view,
+    a view's images in order, whole images, ``piece_elems`` normals a piece
+    at most (one image at least)."""
+    for group, (size, count) in enumerate(zip(spec.sizes, spec.counts)):
+        step = max(1, piece_elems // (size * size * spec.channels))
+        for view in range(count):
+            for image in range(0, batch_size, step):
+                yield (group, view * batch_size + image, image,
+                       min(step, batch_size - image), size)
+
+
+def _finish_piece(draw: np.ndarray, means: np.ndarray, out: np.ndarray):
+    """``out[...] = (means + draw.astype(float32) * 0.1).astype(float32)``,
+    every rounding of it in that order and no temporary: ``draw`` (float64)
+    is scratch, the float64 sum lands in it."""
+    np.copyto(out, draw, casting="same_kind")
+    np.multiply(out, np.float32(0.1), out=out)
+    np.add(means, out, out=draw)
+    np.copyto(out, draw, casting="same_kind")
+
+
+def _group_buffers(spec: MultiCropSpec, batch_size: int) -> List[np.ndarray]:
+    return [
+        np.empty((rows, size, size, spec.channels), np.float32)
+        for rows, size in crop_groups(spec, batch_size)
+    ]
+
+
+class _Slot:
+    """One kept batch of the ring and the pieces of it still unfinished."""
+
+    __slots__ = ("groups", "pending")
+
+    def __init__(self, groups: List[np.ndarray]):
+        self.groups, self.pending = groups, 0
+
+
+class _Ahead:
+    """The pipelined schedule of ``synthetic_multicrop_batches``. ONE thread
+    owns the generator and draws the stream, piece by piece, into kept
+    float64 scratch (numpy releases the GIL inside the draw); a small pool
+    finishes each piece into its slice of a kept batch, a ring of
+    ``RING_SLOTS``; a finished batch waits in ``ready`` (in stream order,
+    whichever was finished first), where an exception of any of these
+    threads lands too. Constructing starts nothing; ``close`` ends what
+    ``start`` began."""
+
+    def __init__(self, rng, spec: MultiCropSpec, batch_size: int, pieces):
+        self.rng, self.spec, self.batch_size = rng, spec, batch_size
+        self.pieces = pieces
+        self.free: queue.SimpleQueue = queue.SimpleQueue()  # ring slots
+        self.scratch: queue.SimpleQueue = queue.SimpleQueue()
+        self.work: queue.SimpleQueue = queue.SimpleQueue()  # drawn pieces
+        self.ready: queue.SimpleQueue = queue.SimpleQueue()
+        self.closing = threading.Event()
+        # the slots being built, in the order they were drawn, and their
+        # ``pending``
+        self.lock = threading.Lock()
+        self.building: collections.deque = collections.deque()
+        self.threads: List[threading.Thread] = []
+
+    def start(self) -> None:
+        spec = self.spec
+        # one core stays the consumer's, one is the draw's
+        finishers = max(1, min(spec.num_crops, (os.cpu_count() or 1) - 2))
+        largest = max(n * size * size for *_, n, size in self.pieces)
+        slots = [_Slot(_group_buffers(spec, self.batch_size))
+                 for _ in range(RING_SLOTS)]
+        # the draw runs two pieces ahead of a busy pool
+        scratch = [np.empty(largest * spec.channels)
+                   for _ in range(finishers + 2)]
+        for buffer in [g for slot in slots for g in slot.groups] + scratch:
+            # TWO passes before the first use: on the chip machine a page's
+            # first access runs at 1.0 GB/s, its second at 2.4, only the
+            # third at memory speed (``averaging/partition.SnapshotBuffers
+            # .touch``, PERF.md, PR 60)
+            buffer.fill(0.0)
+            buffer.fill(0.0)
+        for slot in slots:
+            self.free.put(slot)
+        for buffer in scratch:
+            self.scratch.put(buffer)
+        self.threads = [
+            threading.Thread(target=self._run, args=(body,), daemon=True,
+                             name=f"{_THREAD_PREFIX}-{name}")
+            for name, body in [("draw", self._draw)] + [
+                (f"finish-{i}", self._finish) for i in range(finishers)
+            ]
+        ]
+        for thread in self.threads:
+            thread.start()
+
+    def _run(self, body) -> None:
+        try:
+            body()
+        except Exception as e:  # ``take`` re-raises it, at the consumer
+            self.ready.put(e)
+
+    def _draw(self) -> None:
+        rng, channels = self.rng, self.spec.channels
+        while True:
+            slot = self.free.get()
+            if slot is None:
+                return
+            means = rng.standard_normal(
+                (self.batch_size, 1, 1, channels)
+            ) * 0.5
+            with self.lock:
+                slot.pending = len(self.pieces)
+                self.building.append(slot)
+            for group, row, image, n, size in self.pieces:
+                buffer = self.scratch.get()
+                if buffer is None or self.closing.is_set():
+                    return
+                draw = buffer[: n * size * size * channels].reshape(
+                    n, size, size, channels
+                )
+                rng.standard_normal(out=draw)
+                self.work.put((
+                    buffer, draw, means[image : image + n],
+                    slot.groups[group][row : row + n], slot,
+                ))
+
+    def _finish(self) -> None:
+        while True:
+            piece = self.work.get()
+            if piece is None:
+                return
+            buffer, draw, means, out, slot = piece
+            _finish_piece(draw, means, out)
+            self.scratch.put(buffer)
+            with self.lock:
+                slot.pending -= 1
+                # in stream order: a batch whose last piece is slow holds
+                # back the finished ones behind it
+                while self.building and not self.building[0].pending:
+                    self.ready.put(self.building.popleft())
+
+    def take(self, stats: Dict[str, int]) -> _Slot:
+        """The next batch in stream order; counted in ``data.draws_ready``
+        if it was waiting for its consumer, not the consumer for it."""
+        try:
+            slot = self.ready.get_nowait()
+            stats["data.draws_ready"] += 1
+        except queue.Empty:
+            slot = self.ready.get()
+        if isinstance(slot, Exception):
+            raise slot
+        return slot
+
+    def close(self) -> None:
+        self.closing.set()
+        self.free.put(None)
+        for _thread in self.threads:
+            self.scratch.put(None)
+            self.work.put(None)
+        # at interpreter exit a daemon thread never runs again
+        if not sys.is_finalizing():
+            for thread in self.threads:
+                thread.join()
+
+
 def synthetic_multicrop_batches(
     spec: MultiCropSpec,
     batch_size: int,
     seed: int = 0,
     num_classes: int = 8,
+    stats: Optional[Dict[str, int]] = None,
 ) -> Iterator[List[np.ndarray]]:
     """Synthetic multicrop stream (SyntheticImageDataset capability): each
     "image" is a class-dependent mean plus noise; crops of one image share
     its mean, so crops agree like real augmented views do. Yields one
-    [count*B, S, S, C] float32 array per resolution group, in crop order."""
+    [count*B, S, S, C] float32 array per resolution group, in crop order.
+
+    ONE stream a seed, whatever the schedule: means, then each view's
+    normals, from one ``default_rng(seed)``. A batch under
+    ``PIPELINE_MIN_BYTES`` is built in line, into fresh arrays the consumer
+    may keep. A larger one is built AHEAD of the consumer, on this source's
+    own threads, into a ring of kept buffers (``_Ahead``; threads and ring
+    exist from the first ``next()`` until the generator is closed, dropped
+    or raises): a batch it yields stays valid while ``VALID_DRAWS`` later
+    batches are drawn — the draw after that one may rewrite its arrays, so
+    whoever reads them later (an asynchronous upload) finishes before it
+    draws twice more (``roles/swav.py``'s ``put_crops`` does).
+
+    ``stats``, a live dict: running totals ``data.draws`` (batches yielded)
+    and ``data.draws_ready`` (those that were ready and waiting when asked
+    for: 0 in line)."""
     rng = np.random.default_rng(seed)
-    while True:
-        means = rng.standard_normal((batch_size, 1, 1, spec.channels)) * 0.5
-        groups: List[np.ndarray] = []
-        for size, count in zip(spec.sizes, spec.counts):
-            views = []
-            for _ in range(count):
-                noise = rng.standard_normal(
-                    (batch_size, size, size, spec.channels)
-                ).astype(np.float32) * 0.1
-                views.append((means + noise).astype(np.float32))
-            groups.append(np.concatenate(views, axis=0))
-        yield groups
+    stats = {} if stats is None else stats
+    stats.update({"data.draws": 0, "data.draws_ready": 0})
+    channels = spec.channels
+    pieces = list(_pieces(spec, batch_size, _PIECE_ELEMS))
+    batch_bytes = 4 * channels * sum(
+        rows * size * size for rows, size in crop_groups(spec, batch_size)
+    )
+    if batch_bytes < PIPELINE_MIN_BYTES:
+        while True:
+            means = rng.standard_normal((batch_size, 1, 1, channels)) * 0.5
+            groups = _group_buffers(spec, batch_size)
+            for group, row, image, n, size in pieces:
+                _finish_piece(
+                    rng.standard_normal((n, size, size, channels)),
+                    means[image : image + n], groups[group][row : row + n],
+                )
+            stats["data.draws"] += 1
+            yield groups
+    ahead = _Ahead(rng, spec, batch_size, pieces)
+    try:
+        ahead.start()
+        held: collections.deque = collections.deque()
+        while True:
+            if len(held) > VALID_DRAWS:
+                ahead.free.put(held.popleft())
+            held.append(ahead.take(stats))
+            stats["data.draws"] += 1
+            yield held[-1].groups
+    finally:
+        ahead.close()
 
 
 def _random_resized_crop(

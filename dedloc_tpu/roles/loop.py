@@ -190,7 +190,7 @@ def run_boundary_loop(
                             host_counted[key] = total
                             srec.attrs[key] = float(grown)
                             if tele is not None:
-                                tele.counter(key).inc(grown)  # dedlint: emits=counter:moe.compute_copy_builds
+                                tele.counter(key).inc(grown)  # dedlint: emits=counter:moe.compute_copy_builds,counter:data.draws,counter:data.draws_ready
                         for name in model.step_counters:
                             srec.attrs[name] = float(sums[name])
                             if tele is not None:

@@ -103,6 +103,31 @@ def _build_flat_lars_factory(t):
     return factory
 
 
+def make_put_crops(crop_sharding=None, upload=jnp.asarray):
+    """Host crop groups -> device arrays (onto ``crop_sharding`` under a
+    mesh), for ``LoopModel.put``. ``upload`` is asynchronous: the runtime may
+    read a host array until the transfer is done, and the synthetic source
+    rewrites a batch's arrays once ``VALID_DRAWS`` + 1 later batches are
+    drawn (``data/multicrop.py``) — so each call first waits for the
+    PREVIOUS batch's arrays: when batch k + 2 is drawn, batch k is on the
+    device. (An upload has long finished by then; the wait is for the
+    contract, not for time.)"""
+    in_flight = None
+
+    def put_crops(crops):
+        nonlocal in_flight
+        jax.block_until_ready(in_flight)
+        # dropped BEFORE this batch goes up: the last one's device arrays
+        # are not held beside it for the wait's sake
+        in_flight = None
+        in_flight = [upload(c) for c in crops]
+        if crop_sharding is not None:
+            in_flight = [jax.device_put(c, crop_sharding) for c in in_flight]
+        return in_flight
+
+    return put_crops
+
+
 def run_swav(args: SwAVCollaborationArguments) -> TrainState:
     # this peer's set-up record (telemetry/steps.py), under the same lap
     # names as ``run_trainer``; a phase this role lacks has no lap
@@ -211,6 +236,9 @@ def _run_swav(args: SwAVCollaborationArguments) -> TrainState:
     accumulate = make_swav_accumulate_step(
         model, cfg, mesh=mesh, num_crop_groups=len(spec.sizes)
     )
+    # the synthetic source's running totals (``data.draws``,
+    # ``data.draws_ready``): onto every stepping record
+    data_counters: dict = {}
     if t.image_folder:
         # real JPEGs through the full SSL augmentation stack
         # (ImgPilToMultiCrop + flip + color distortion + blur + normalize)
@@ -218,7 +246,9 @@ def _run_swav(args: SwAVCollaborationArguments) -> TrainState:
             t.image_folder, spec, slice_batch, seed=t.seed
         )
     else:
-        batches = synthetic_multicrop_batches(spec, slice_batch, seed=t.seed)
+        batches = synthetic_multicrop_batches(
+            spec, slice_batch, seed=t.seed, stats=data_counters
+        )
     steps.lap("data_source")
 
     queue_engaged = False
@@ -260,11 +290,6 @@ def _run_swav(args: SwAVCollaborationArguments) -> TrainState:
         )
         return grad_acc, n_acc, metrics
 
-    def put_crops(crops):
-        if crop_sharding is None:
-            return [jnp.asarray(c) for c in crops]
-        return [jax.device_put(jnp.asarray(c), crop_sharding) for c in crops]
-
     def save(state, step):
         host = jax.device_get((state.params, batch_stats))
         save_checkpoint(
@@ -275,13 +300,20 @@ def _run_swav(args: SwAVCollaborationArguments) -> TrainState:
             save_total_limit=t.save_total_limit,
         )
 
-    state = run_boundary_loop(
-        args,
-        LoopModel(
-            batches=batches, micro_step=micro_step, save=save, put=put_crops
-        ),
-        state, opt, dht, public_key, tele, tele_close,
-    )
+    try:
+        state = run_boundary_loop(
+            args,
+            LoopModel(
+                batches=batches, micro_step=micro_step, save=save,
+                put=make_put_crops(crop_sharding),
+                host_counters=data_counters,
+            ),
+            state, opt, dht, public_key, tele, tele_close,
+        )
+    finally:
+        # the source's threads end with the run, not with the last
+        # reference to it (a wrapper that cannot close drops it instead)
+        getattr(batches, "close", lambda: None)()
     if t.save_steps:
         # vissl saves at every phase end (log_hooks.py:268-330): the run
         # that ends leaves its last state on disk, whatever the cadence

@@ -50,6 +50,8 @@ CKPT_SHARDS_FETCHED = "ckpt.shards_fetched"
 CKPT_SHARDS_RESUMED = "ckpt.shards_resumed"
 CKPT_SHARDS_SERVED = "ckpt.shards_served"
 CKPT_VERIFY_FAILURES = "ckpt.verify_failures"
+DATA_DRAWS = "data.draws"
+DATA_DRAWS_READY = "data.draws_ready"
 DATA_IMAGE_TOKEN_SHARE = "data.image_token_share"
 DIFFUSION_MASKED_SHARE = "diffusion.masked_share"
 DIFFUSION_MASKED_TOKENS = "diffusion.masked_tokens"
@@ -194,6 +196,8 @@ COUNTERS = frozenset({
     "ckpt.shards_resumed",
     "ckpt.shards_served",
     "ckpt.verify_failures",
+    "data.draws",
+    "data.draws_ready",
     "diffusion.masked_tokens",
     "expert.announces",
     "expert.bytes_served",

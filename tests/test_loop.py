@@ -305,6 +305,28 @@ def test_the_trace_s_gauge_is_on_every_stepping_record(runs):
     ] * len(stepping)
 
 
+def test_the_source_s_draws_are_counted_on_the_stepping_records(runs):
+    """``data.draws`` / ``data.draws_ready``: the synthetic multicrop
+    source's running totals (``data/multicrop.py``), handed to the loop as
+    ``LoopModel.host_counters`` by SwAV's role alone: what they grew by
+    since the last stepping record. The tiny batch is built in line, so
+    none was ready ahead of its consumer."""
+    family, first, _second, _saved = runs
+    stepping = [i for i, r in enumerate(first.records) if r.get("stepped")]
+    if family != "swav":
+        assert not any("data.draws" in r for r in first.records)
+        return
+    draws = [first.records[i]["data.draws"] for i in stepping]
+    # gradient_accumulation_steps 2: two draws a boundary, every one counted
+    assert draws == [
+        2.0 * (i - before)
+        for before, i in zip([-1] + stepping[:-1], stepping)
+    ]
+    assert {first.records[i]["data.draws_ready"] for i in stepping} == {0.0}
+    quiet = set(range(len(first.records))) - set(stepping)
+    assert not any("data.draws" in first.records[i] for i in quiet)
+
+
 def test_max_local_steps_ends_the_run_and_shuts_everything_down(runs):
     _family, first, _second, _saved = runs
     assert len(first.opt_calls) == BOUNDARIES
